@@ -5,7 +5,7 @@ The gather baseline (ops/paged_attention.py) materialises every slot's full
 token regardless of the sequence's actual length. This kernel reads only the
 pages a sequence owns:
 
-- Grid (B, maxP), page index innermost. The page arrays stay in HBM; each
+- Grid (B, query tiles, maxP), page index innermost. The page arrays stay in HBM; each
   grid step's BlockSpec uses the scalar-prefetched block table to DMA one
   physical page — ALL kv heads, [Nkv, PS, D] — into VMEM
   (``PrefetchScalarGridSpec`` — the pallas_guide.md pattern for
@@ -81,7 +81,7 @@ def _extend_kernel(tables_ref, starts_ref,        # scalar prefetch
     else:
         q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
-    p = pl.program_id(1)
+    p = pl.program_id(2)
     tg = window * groups                  # query rows per kv head
     d = q_ref.shape[-1]
 
@@ -91,8 +91,11 @@ def _extend_kernel(tables_ref, starts_ref,        # scalar prefetch
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    start = starts_ref[b]
-    max_len = start + window             # last window token's length
+    # this grid step's query tile: ``window`` rows starting at
+    # start + tile * window (one tile unless the wrapper split a long
+    # suffix-prefill window — see _query_tile)
+    start = starts_ref[b] + pl.program_id(1) * window
+    max_len = start + window             # last tile token's length
 
     @pl.when(p * page_size < max_len)
     def _body():
@@ -135,11 +138,30 @@ def _extend_kernel(tables_ref, starts_ref,        # scalar prefetch
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    @pl.when(p == pl.num_programs(1) - 1)
+    @pl.when(p == pl.num_programs(2) - 1)
     def _finalize():
         l = l_ref[...]
         o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
             o_ref.dtype).reshape(o_ref.shape)
+
+
+# Largest folded score tile [Nq*tile, Nkv*PS] (fp32 elements) the kernel
+# builds per grid step. The v5e compiler gives a kernel 16 MB of scoped
+# VMEM: an untiled 256-token window at gpt-1b (a 4096 x 1024 score tile)
+# asked for 19.3 MB and was refused, and 2**21 tiled (q/out blocks double-
+# buffered across tiles) was refused for the GQA 32/8 layout. 2**20 (4 MB:
+# 64 query tokens per step at both layouts) compiles at every window the
+# engine can pass — tests/test_tpu_compile.py holds it there.
+_MAX_SCORE_ELEMS = 1 << 20
+
+
+def _query_tile(T: int, Nq: int, Nkv: int, PS: int) -> int:
+    """Query rows per grid step: the whole window when its folded score
+    tile fits, else the largest power of two (>= 8) that does."""
+    if Nq * T * Nkv * PS <= _MAX_SCORE_ELEMS:
+        return T
+    tile = max(_MAX_SCORE_ELEMS // (Nq * Nkv * PS), 8)
+    return 1 << (tile.bit_length() - 1)
 
 
 def paged_attention_pallas_multi(
@@ -156,35 +178,51 @@ def paged_attention_pallas_multi(
     from .paged_attention import Int4Pages, QuantPages
     kv_quant = ("int4" if isinstance(k_pages, Int4Pages)
                 else "int8" if isinstance(k_pages, QuantPages) else "none")
-    B, T, Nq, D = q.shape
+    B, T_in, Nq, D = q.shape
     NP, Nkv, PS, _ = k_pages.shape
     maxP = block_tables.shape[1]
     groups = Nq // Nkv
     scale = 1.0 / float(D) ** 0.5
 
+    # long windows (suffix / chunked prefill) are tiled along the query
+    # axis: one more grid dimension, each tile an independent online-
+    # softmax pass over the slot's pages. A window that is not a whole
+    # number of tiles is padded; the pad rows are computed and dropped.
+    tile = _query_tile(T_in, Nq, Nkv, PS)
+    T = -(-T_in // tile) * tile
+    if T != T_in:
+        q = jnp.pad(q, ((0, 0), (0, T - T_in), (0, 0), (0, 0)))
+    n_tiles = T // tile
+
     # [B, Nkv, T*G, D]: T outer, groups inner, so row // groups == j
     qg = q.reshape(B, T, Nkv, groups, D).transpose(0, 2, 1, 3, 4).reshape(
         B, Nkv, T * groups, D)
     starts = start_positions.astype(jnp.int32)
-    lengths = starts + T
-    last_used = jnp.maximum((lengths + PS - 1) // PS - 1, 0)
-    clamped_p = jnp.minimum(
-        jnp.arange(maxP, dtype=jnp.int32)[None, :], last_used[:, None])
-    tables_clamped = jnp.take_along_axis(
-        block_tables.astype(jnp.int32), clamped_p, axis=1)
+    tables = block_tables.astype(jnp.int32)
 
-    # head-folded grid (B, maxP): one whole page (all kv heads) per step.
-    # The scale tile [Nkv, PS] rides the SAME clamped block-table index
-    # map as its page, so Pallas elides its re-fetch together with the
-    # page's on consecutive identical indices. int4 pages DMA the packed
-    # [Nkv, PS/2, D] byte tile — half the int8 bytes per page.
+    def page_of(b, t, p, tbl, st):
+        # pages past the tile's live length are CLAMPED to its last used
+        # page: consecutive identical block indices elide the DMA
+        last_used = jnp.maximum((st[b] + (t + 1) * tile + PS - 1) // PS - 1,
+                                0)
+        return tbl[b, jnp.minimum(p, last_used)]
+
+    # head-folded grid (B, tiles, maxP): one whole page (all kv heads)
+    # per step. The scale tile [Nkv, PS] rides the SAME clamped
+    # block-table index map as its page, so Pallas elides its re-fetch
+    # together with the page's on consecutive identical indices. int4
+    # pages DMA the packed [Nkv, PS/2, D] byte tile — half the int8
+    # bytes per page.
     page_rows = PS // 2 if kv_quant == "int4" else PS
-    page_spec = pl.BlockSpec((None, Nkv, page_rows, D),
-                             lambda b, p, t, u: (t[b, p], 0, 0, 0))
-    scale_spec = pl.BlockSpec((None, Nkv, PS),
-                              lambda b, p, t, u: (t[b, p], 0, 0))
-    in_specs = [pl.BlockSpec((None, Nkv, T * groups, D),
-                             lambda b, p, t, u: (b, 0, 0, 0))]      # q
+    page_spec = pl.BlockSpec(
+        (None, Nkv, page_rows, D),
+        lambda b, t, p, tbl, st: (page_of(b, t, p, tbl, st), 0, 0, 0))
+    scale_spec = pl.BlockSpec(
+        (None, Nkv, PS),
+        lambda b, t, p, tbl, st: (page_of(b, t, p, tbl, st), 0, 0))
+    q_spec = pl.BlockSpec((None, Nkv, tile * groups, D),
+                          lambda b, t, p, tbl, st: (b, 0, t, 0))
+    in_specs = [q_spec]
     inputs = [qg]
     if kv_quant != "none":
         in_specs += [page_spec, scale_spec, page_spec, scale_spec]
@@ -195,28 +233,27 @@ def paged_attention_pallas_multi(
         inputs += [k_pages, v_pages]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,       # tables_clamped, starts
-        grid=(B, maxP),
+        num_scalar_prefetch=2,       # tables, starts
+        grid=(B, n_tiles, maxP),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, Nkv, T * groups, D),
-                               lambda b, p, t, u: (b, 0, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((Nkv * T * groups, D), jnp.float32),
-            pltpu.VMEM((Nkv * T * groups, 1), jnp.float32),
-            pltpu.VMEM((Nkv * T * groups, 1), jnp.float32),
+            pltpu.VMEM((Nkv * tile * groups, D), jnp.float32),
+            pltpu.VMEM((Nkv * tile * groups, 1), jnp.float32),
+            pltpu.VMEM((Nkv * tile * groups, 1), jnp.float32),
         ],
     )
 
     out = pl.pallas_call(
         functools.partial(_extend_kernel, page_size=PS, scale=scale,
-                          groups=groups, window=T, num_kv=Nkv,
+                          groups=groups, window=tile, num_kv=Nkv,
                           kv_quant=kv_quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Nkv, T * groups, D), q.dtype),
         interpret=interpret,
-    )(tables_clamped, starts, *inputs)
+    )(tables, starts, *inputs)
     return out.reshape(B, Nkv, T, groups, D).transpose(0, 2, 1, 3, 4).reshape(
-        B, T, Nq, D)
+        B, T, Nq, D)[:, :T_in]
 
 
 def paged_attention_pallas(

@@ -271,6 +271,35 @@ def test_paged_attention_multi_pallas_matches_gather():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("T", [32, 20])
+def test_paged_attention_multi_tiled_window_matches_gather(monkeypatch, T):
+    """A window whose folded score tile exceeds the kernel's VMEM budget is
+    split into query tiles (the v5e compiler refuses the untiled 256/512-
+    token suffix-prefill windows). Shrink the budget so a small window
+    tiles: whole tiles (T=32 -> 4 x 8) and a padded tail (T=20 -> 3 x 8)
+    must both match the gather baseline."""
+    from distributed_llm_training_and_inference_system_tpu.ops import (
+        paged_attention_pallas as pap)
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
+        paged_attention_multi)
+
+    B, Nq, Nkv, D, PS, NP, maxP = 2, 8, 4, 64, 16, 12, 5
+    monkeypatch.setattr(pap, "_MAX_SCORE_ELEMS", Nq * 8 * Nkv * PS)
+    assert pap._query_tile(T, Nq, Nkv, PS) == 8
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (B, T, Nq, D), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (NP, Nkv, PS, D), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (NP, Nkv, PS, D), jnp.float32)
+    bt = jnp.asarray([[3, 7, 1, 2, 0], [4, 5, 6, 8, 9]], jnp.int32)
+    starts = jnp.asarray([13, 37], jnp.int32)
+    ref = paged_attention_multi(q, k_pages, v_pages, bt, starts,
+                                impl="gather")
+    out = paged_attention_multi(q, k_pages, v_pages, bt, starts,
+                                impl="pallas")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_paged_attention_multi_window_is_causal():
     """Within the window, query j must NOT see tokens j+1..T-1: writing
     garbage into the positions after query j's own must not change its

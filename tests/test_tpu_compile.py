@@ -1,0 +1,192 @@
+"""The main path's Pallas kernels, compiled by the TPU v5e compiler.
+
+Every other test runs the kernels in interpret mode on the CPU, which
+cannot see what the chip's compiler refuses: a slice not aligned to the
+tiling, a kernel that wants more than its 16 MB of scoped VMEM (the
+multi-query paged-attention kernel did, at the 256- and 512-token windows
+of cached-prefix and chunked prefill). libtpu is installed here and
+compiles for a chip that is DESCRIBED, not attached; nothing runs, so
+these tests say nothing about results or times — `chip_smoke.py` checks
+each kernel's result against its XLA reference on the real chip.
+
+Rules this file keeps (pytest-xdist runs six workers and each imports
+every test file; only one process may load libtpu):
+
+- the topology is described inside the module-scoped ``topo`` fixture,
+  never at import, never in a ``skipif``/``parametrize`` argument, never
+  in ``conftest.py``; shardings and shapes are built in fixtures/tests;
+- all compile tests live in THIS file (one worker, one libtpu load), and
+  compile in the test's own process;
+- the kernels pick ``interpret`` from ``jax.default_backend()``, which
+  still says ``cpu`` here: the ``as_tpu`` fixture steers that, and every
+  test asserts ``tpu_custom_call`` is in the compiled text so an
+  interpreted lowering cannot pass.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+# gpt-1b / gpt-750m head layout and the mistral-7b GQA layout
+LAYOUTS = {"mha16": (16, 16), "gqa32x8": (32, 8)}
+D, PS, MAXP = 128, 64, 32          # head_dim, ServeConfig.kv_block_size,
+                                   # max_seq_len 2048 / page 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Make the package's own ``jax.default_backend()`` checks take their
+    TPU branch (compiled kernel, not interpret) for this test."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "lowered without a Mosaic kernel (interpret mode?)"
+    return compiled
+
+
+def _sds(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+def _pages(sds, num_pages, nkv, kv):
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
+        QuantPages)
+    if kv == "int8":
+        return QuantPages(sds((num_pages, nkv, PS, D), jnp.int8),
+                          sds((num_pages, nkv, PS), jnp.float32))
+    return sds((num_pages, nkv, PS, D), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_paged_decode_kernel_compiles(one_chip, as_tpu, layout, kv):
+    """Single-query decode attention: 8 slots, 32 pages of 64 per slot."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
+        paged_attention)
+    nq, nkv = LAYOUTS[layout]
+    sds = _sds(one_chip)
+    B = 8
+    pages = _pages(sds, B * MAXP + 1, nkv, kv)
+    _compile(functools.partial(paged_attention, impl="auto"),
+             sds((B, nq, D), jnp.bfloat16), pages, pages,
+             sds((B, MAXP), jnp.int32), sds((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+@pytest.mark.parametrize("window", [8, 64, 128, 256, 512])
+def test_paged_multi_query_kernel_compiles(one_chip, as_tpu, window, layout,
+                                           kv):
+    """Every window the engine can hand the multi-query kernel at default
+    settings: the speculative verify window (8, all slots) and the
+    cached-prefix / chunked-prefill suffix buckets 64..512 (one slot;
+    engine._suffix_bucket, prefill_chunk 512)."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
+        paged_attention_multi)
+    nq, nkv = LAYOUTS[layout]
+    sds = _sds(one_chip)
+    B = 8 if window == 8 else 1
+    pages = _pages(sds, 8 * MAXP + 1, nkv, kv)
+    _compile(functools.partial(paged_attention_multi, impl="auto"),
+             sds((B, window, nq, D), jnp.bfloat16), pages, pages,
+             sds((B, MAXP), jnp.int32), sds((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("shape", ["gpt-750m-b4", "gqa32x8-b2"])
+def test_flash_attention_compiles(one_chip, as_tpu, shape, grad):
+    """Training attention at sequence 2048: gpt-750m's micro-batch of 4
+    and the GQA 32/8 layout at 2."""
+    from distributed_llm_training_and_inference_system_tpu.ops.attention import (
+        flash_attention)
+    B, (nq, nkv) = {"gpt-750m-b4": (4, LAYOUTS["mha16"]),
+                    "gqa32x8-b2": (2, LAYOUTS["gqa32x8"])}[shape]
+    sds = _sds(one_chip)
+    q = sds((B, 2048, nq, D), jnp.bfloat16)
+    kv = sds((B, 2048, nkv, D), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd, q, kv, kv)
+
+
+def test_rmsnorm_kernel_compiles(one_chip, as_tpu):
+    from distributed_llm_training_and_inference_system_tpu.ops.rmsnorm import (
+        rms_norm_pallas)
+    sds = _sds(one_chip)
+    _compile(rms_norm_pallas, sds((4, 2048, 2048), jnp.bfloat16),
+             sds((2048,), jnp.float32))
+
+
+@pytest.mark.parametrize("leaf", ["qkv_stack", "ffn_stack", "embedding"])
+def test_fused_adamw_kernel_compiles(one_chip, as_tpu, leaf):
+    """The fused AdamW update over gpt-750m's leaf shapes (layer-stacked
+    [L, in, out] kernels, the [V, H] embedding), fp32 params with the
+    bf16 moments `bench.py` uses."""
+    from distributed_llm_training_and_inference_system_tpu.exec.fused_update import (
+        fused_adamw_apply)
+    shape = {"qkv_stack": (12, 2048, 2048), "ffn_stack": (12, 2048, 5632),
+             "embedding": (50304, 2048)}[leaf]
+    sds = _sds(one_chip)
+    p = {"w": sds(shape, jnp.float32)}
+    m = {"w": sds(shape, jnp.bfloat16)}
+
+    def step(p, g, mu, nu, count, lr, clip):
+        return fused_adamw_apply(p, g, mu, nu, count, lr=lr, b1=0.9,
+                                 b2=0.95, eps=1e-8, weight_decay=0.1,
+                                 decay_mask={"w": True}, clip_scale=clip)
+
+    _compile(step, p, p, m, m, sds((), jnp.int32), sds((), jnp.float32),
+             sds((), jnp.float32))
+
+
+@pytest.mark.parametrize("shape", [(8, 2048, 5632), (8, 4096, 11008)],
+                         ids=["gpt-1b-ffn", "7b-ffn"])
+def test_int4_matmul_kernel_compiles(one_chip, shape):
+    """W4A16 decode matmul (takes ``interpret`` as an argument)."""
+    from distributed_llm_training_and_inference_system_tpu.ops.int4_matmul_pallas import (
+        matmul_w4)
+    rows, n_in, n_out = shape
+    sds = _sds(one_chip)
+    _compile(functools.partial(matmul_w4, group=128, interpret=False),
+             sds((rows, n_in), jnp.bfloat16),
+             sds((n_in // 2, n_out), jnp.uint8),
+             sds((n_in // 128, n_out), jnp.float32),
+             sds((n_in,), jnp.float32))
