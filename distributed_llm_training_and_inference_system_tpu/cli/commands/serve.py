@@ -671,8 +671,8 @@ def start(model_name, artifact, host, port, max_batch_size, max_seq_len,
                 f"weight ship to "
                 f"{','.join(fleet_cfg.kv_store_endpoint_list())} failed "
                 f"— spawned workers could not bootstrap: {e}")
+    from ...utils.platform import device_line
     click.echo(f"serving {model_name} on {host}:{port} "
-               f"(backend={jax.default_backend()}, dtype={dtype}, "
-               f"scheduler={scheduler}"
+               f"({device_line()}, dtype={dtype}, scheduler={scheduler}"
                + (f", replicas={replicas}" if replicas > 1 else "") + ")")
     server.run_forever()

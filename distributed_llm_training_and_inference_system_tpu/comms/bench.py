@@ -16,7 +16,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import collectives as cc

@@ -61,8 +61,7 @@ def axis_index(axis: str) -> jax.Array:
 
 
 def axis_size(axis: str) -> int:
-    from ..utils.compat import axis_size as _axis_size
-    return _axis_size(axis)
+    return lax.axis_size(axis)
 
 
 def barrier(axis: str) -> None:
@@ -74,17 +73,20 @@ def barrier(axis: str) -> None:
 # Overlap engine
 # ---------------------------------------------------------------------------
 
-# XLA flags enabling the latency-hiding scheduler: the TPU equivalent of the
-# reference's (absent) "overlap engine". Applied by runtime/launcher.py to
-# every spawned training process.
-OVERLAP_XLA_FLAGS = (
-    "--xla_enable_async_collective_permute=true "
+# TPU compiler flags enabling async collective fusion / compute-collective
+# overlap: the TPU equivalent of the reference's (absent) "overlap engine".
+# Applied by runtime/launcher.py to every spawned training process through
+# LIBTPU_INIT_ARGS — the installed jaxlib (0.9.0) aborts on ANY ``xla_tpu_``
+# flag in XLA_FLAGS ("Unknown flag in XLA_FLAGS", fatal, whatever the
+# backend), while libtpu 0.0.34 parses them from its own variable, which a
+# CPU process never reads.
+OVERLAP_LIBTPU_ARGS = (
     "--xla_tpu_enable_async_collective_fusion=true "
     "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true "
-    "--xla_tpu_overlap_compute_collective_tc=true "
-    "--xla_enable_async_all_gather=true "
+    "--xla_tpu_overlap_compute_collective_tc=true"
 )
 
 
 def overlap_flags() -> str:
-    return OVERLAP_XLA_FLAGS
+    """The overlap flags, for ``LIBTPU_INIT_ARGS`` (never ``XLA_FLAGS``)."""
+    return OVERLAP_LIBTPU_ARGS

@@ -130,7 +130,27 @@ def fused_adamw_apply(params: Any, grads: Any, mu: Any, nu: Any,
                           b1=b1, b2=b2, eps=eps, wd=wd, mu_dtype=m.dtype,
                           nu_dtype=v.dtype)
 
+    from ..parallel.sharding import _current_mesh
+    from ..utils.platform import kernel_impl, report_impl
     flat_p, treedef = jax.tree_util.tree_flatten(params)
+    mesh = _current_mesh()
+    if use_pallas and mesh is not None and mesh.size > 1:
+        # the kernel is a custom call GSPMD cannot partition ("Mosaic
+        # kernels cannot be automatically partitioned" on the TPU
+        # lowering), and the leaves' shardings are not known here: on a
+        # multi-device mesh the same math runs as fused XLA elementwise
+        # code, which partitions trivially — said out loud below
+        use_pallas = False
+        detail = (f"fused adamw, {dict(mesh.shape)} mesh: the Mosaic kernel "
+                  "cannot be partitioned by GSPMD")
+    else:
+        n_kernel = sum(use_pallas and p.ndim >= 2 and p.size >= 1 << 16
+                       for p in flat_p)
+        use_pallas = bool(n_kernel)
+        detail = (f"fused adamw, {n_kernel}/{len(flat_p)} leaves through "
+                  "the kernel")
+    report_impl("optimizer_update",
+                kernel_impl() if use_pallas else "jnp", detail)
     flat_g = treedef.flatten_up_to(grads)
     flat_mu = treedef.flatten_up_to(mu)
     flat_nu = treedef.flatten_up_to(nu)

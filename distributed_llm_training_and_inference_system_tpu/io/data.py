@@ -34,6 +34,8 @@ from typing import Iterator, Optional
 
 import numpy as np
 
+from ..utils.platform import report_impl
+
 
 # ---------------------------------------------------------------------------
 # Shard format
@@ -211,8 +213,10 @@ class MemmapDataset(DatasetIterator):
             self._native = NativePacker(
                 self.shards, np.asarray(docs, np.int64), pack,
                 drop_tail_docs)
-        except (RuntimeError, OSError, ValueError):
-            pass   # numpy fallback (LLMCTL_NO_NATIVE, no toolchain, ...)
+            report_impl("data_packer", "native", "native/dataloader.cpp")
+        except (RuntimeError, OSError, ValueError) as e:
+            # LLMCTL_NO_NATIVE, no toolchain, ...
+            report_impl("data_packer", "numpy", str(e))
 
     @property
     def num_documents(self) -> int:

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config.schema import ModelConfig
+from ..utils.platform import kernel_impl, report_impl
 
 Params = Any  # nested dict pytree of jnp arrays
 
@@ -29,7 +30,9 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5,
     """RMSNorm. Reduction in fp32 regardless of activation dtype."""
     if impl == "pallas":
         from ..ops.rmsnorm import rms_norm_pallas
+        report_impl("rms_norm", kernel_impl(), f"x{tuple(x.shape)}")
         return rms_norm_pallas(x, scale, eps)
+    report_impl("rms_norm", "xla", f"x{tuple(x.shape)}")
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     normed = xf * jax.lax.rsqrt(var + eps)
@@ -114,6 +117,36 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, Sq, Nq, D).astype(q.dtype)
 
 
+def _flash_on_mesh(q, k, v, segment_ids):
+    """The flash kernel under the ambient mesh. A Pallas kernel is a custom
+    call GSPMD cannot partition — the TPU lowering of a tp/fsdp-sharded
+    step raises "Mosaic kernels cannot be automatically partitioned",
+    which interpret mode never shows — so on a multi-device mesh it runs
+    per shard: batch over (dp, fsdp), heads over tp. Attention needs no
+    communication across either. (Inside the pipeline schedule's own
+    shard_map the call stays as it is.)"""
+    from jax.sharding import PartitionSpec as P
+
+    from ..ops.attention import flash_attention
+    from ..parallel.sharding import _current_mesh
+    mesh = _current_mesh()
+    on_mesh = (mesh is not None and mesh.size > 1
+               and mesh.shape.get("pp", 1) == 1)
+    report_impl("attention", kernel_impl("flash"), f"q{tuple(q.shape)}"
+                + (f", shard_map over {dict(mesh.shape)}" if on_mesh else ""))
+    if not on_mesh:
+        return flash_attention(q, k, v, segment_ids=segment_ids, causal=True)
+    if segment_ids is None:
+        segment_ids = jnp.ones(q.shape[:2], jnp.int32)
+    qspec = P(("dp", "fsdp"), None, "tp", None)
+    fn = jax.shard_map(
+        lambda q_, k_, v_, s_: flash_attention(q_, k_, v_, segment_ids=s_,
+                                               causal=True),
+        mesh=mesh, in_specs=(qspec, qspec, qspec, P(("dp", "fsdp"), None)),
+        out_specs=qspec, check_vma=False)
+    return fn(q, k, v, segment_ids)
+
+
 def attention_block(
     x: jax.Array,
     layer: Params,
@@ -158,14 +191,15 @@ def attention_block(
         k_cache = k_cache.at[b_idx, write_idx].set(k.astype(k_cache.dtype))
         v_cache = v_cache.at[b_idx, write_idx].set(v.astype(v_cache.dtype))
         new_cache = (k_cache, v_cache)
+        report_impl("prefill_attention", "xla",
+                    f"q{tuple(q.shape)} over a [{B}, {S_max}] cache")
         kv_positions = jnp.arange(S_max)[None, :].repeat(B, axis=0)
         valid = kv_positions < (cache_offset[:, None] + S)
         mask = (positions[..., :, None] >= kv_positions[..., None, :]) & valid[:, None, :]
         out = dot_product_attention(q, k_cache.astype(q.dtype),
                                     v_cache.astype(q.dtype), mask)
     elif attn_impl == "flash":
-        from ..ops.attention import flash_attention
-        out = flash_attention(q, k, v, segment_ids=segment_ids, causal=True)
+        out = _flash_on_mesh(q, k, v, segment_ids)
     elif attn_impl == "ring":
         from ..ops.ring_attention import ring_attention
         out = ring_attention(q, k, v, positions=positions,
@@ -175,6 +209,7 @@ def attention_block(
         out = ulysses_attention(q, k, v, positions=positions,
                                 segment_ids=segment_ids, axis_name="sp")
     else:
+        report_impl("attention", "xla", f"q{tuple(q.shape)}")
         mask = attention_mask(positions, positions, segment_ids, segment_ids)
         out = dot_product_attention(q, k, v, mask)
 

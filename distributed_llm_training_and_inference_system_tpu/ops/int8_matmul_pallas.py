@@ -125,6 +125,10 @@ def matmul_w8(x: jax.Array, values: jax.Array, scale: jax.Array,
     # bf16 dot over converted weights — same math as the kernel.
     if auto_tile and n_in * bo > budget and not (bk and bo_k > bo):
         import warnings
+
+        from ..utils.platform import report_impl
+        report_impl("matmul_w8", "xla-dequant",
+                    f"[{n_in}, {n_out}]: no k tile fits the VMEM budget")
         warnings.warn(
             f"matmul_w8: reduction dim {n_in} has no clean k tile and a "
             f"whole-K [{n_in}, {bo}] block exceeds the ~2 MB VMEM budget "

@@ -26,7 +26,33 @@ import jax.numpy as jnp
 from ..models.layers import NEG_INF
 
 
+from ..utils.platform import kernel_impl, report_impl
 from .quantization import QuantTensor
+
+
+def _resolve_impl(op: str, impl: str, q: jax.Array) -> tuple[str, bool]:
+    """``auto`` -> the page-streaming Pallas kernel on TPU, the gather
+    baseline elsewhere; returns (impl, interpret). The choice is reported
+    (once per traced program) — a gather path on the chip is a line in
+    the log, never a silent detour."""
+    on_tpu = jax.default_backend() == "tpu"
+    D = q.shape[-1]
+    detail = f"q{tuple(q.shape)}"
+    if impl == "auto":
+        # the Pallas kernels tile head_dim onto the 128-lane axis; D < 128
+        # (e.g. gpt-350m's 64) fails Mosaic layout inference ("unsupported
+        # shape cast") — those shapes take the gather path instead of
+        # crashing the serve engine
+        if not on_tpu:
+            impl, detail = "gather", detail + f", backend {jax.default_backend()}"
+        elif D % 128:
+            impl, detail = "gather", detail + f", head_dim {D} % 128 != 0"
+        else:
+            impl = "pallas"
+    else:
+        detail += ", requested by caller"
+    report_impl(op, kernel_impl() if impl == "pallas" else impl, detail)
+    return impl, impl == "pallas" and not on_tpu
 
 
 @jax.tree_util.register_pytree_node_class
@@ -135,18 +161,18 @@ def paged_attention(
     traffic proportional to live length) and this gather baseline
     elsewhere.
     """
-    if impl == "auto":
-        # the Pallas kernels tile head_dim onto the 128-lane axis; D < 128
-        # (e.g. gpt-350m's 64) fails Mosaic layout inference ("unsupported
-        # shape cast", measured round 4) — those shapes take the gather
-        # path instead of crashing the serve engine
-        on_tpu = jax.default_backend() == "tpu"
-        impl = "pallas" if on_tpu and q.shape[-1] % 128 == 0 else "gather"
+    impl, interpret = _resolve_impl("paged_attention", impl, q)
     if impl == "pallas":
         from .paged_attention_pallas import paged_attention_pallas
         return paged_attention_pallas(
             q, k_pages, v_pages, block_tables, lengths,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret)
+    return _gather_attention(q, k_pages, v_pages, block_tables, lengths)
+
+
+def _gather_attention(q, k_pages, v_pages, block_tables, lengths):
+    """The portable baseline: materialise each row's [Nkv, maxP*PS, D]
+    prefix through the block table, then plain masked attention."""
     B, Nq, D = q.shape
     NP, Nkv, PS, _ = k_pages.shape
     maxP = block_tables.shape[1]
@@ -326,21 +352,21 @@ def paged_attention_multi(
     motivation for the kernel).
     """
     B, T, Nq, D = q.shape
-    if impl == "auto":
-        # same D % 128 == 0 constraint as paged_attention (Mosaic lane
-        # tiling); small-head models serve via the gather fallback
-        on_tpu = jax.default_backend() == "tpu"
-        impl = "pallas" if on_tpu and D % 128 == 0 else "gather"
+    # same D % 128 == 0 constraint as paged_attention (Mosaic lane
+    # tiling); small-head models serve via the gather fallback. Every
+    # window size takes the kernel (it tiles long windows itself).
+    impl, interpret = _resolve_impl(
+        "paged_attention" if T == 1 else "paged_attention_multi", impl, q)
     if impl == "pallas":
         from .paged_attention_pallas import paged_attention_pallas_multi
         return paged_attention_pallas_multi(
             q, k_pages, v_pages, block_tables, start_positions,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret)
     flat_pos = (start_positions[:, None]
                 + jnp.arange(T, dtype=jnp.int32)).reshape(B * T)
-    out = paged_attention(
+    out = _gather_attention(
         q.reshape(B * T, Nq, D), k_pages, v_pages,
-        jnp.repeat(block_tables, T, axis=0), flat_pos + 1, impl="gather")
+        jnp.repeat(block_tables, T, axis=0), flat_pos + 1)
     return out.reshape(B, T, Nq, D)
 
 

@@ -63,8 +63,7 @@ def _merge(acc, w, m_run, r, lse):
 
 
 def _ring_perm(axis_name):
-    from ..utils.compat import axis_size
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     return sp, [(i, (i + 1) % sp) for i in range(sp)]
 
 
@@ -193,8 +192,7 @@ def ring_attention(
                     scale, bq, block_k)
         return unfold(out).astype(q_.dtype)
 
-    from ..utils.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, qspec, qspec, sspec, sspec),
         out_specs=qspec, check_vma=False)

@@ -34,8 +34,7 @@ from .attention import flash_attention
 
 def _ulysses_body(q, k, v, pos, seg, axis_name, block_q, block_k):
     """Per-shard body. q/k/v: [B, S_local, N, D]; pos/seg: [B, S_local]."""
-    from ..utils.compat import axis_size
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     B, S_local, Nq, D = q.shape
     Nkv = k.shape[2]
 
@@ -111,8 +110,7 @@ def ulysses_attention(
         return _ulysses_body(q_, k_, v_, pos_, seg_, axis_name,
                              block_q, block_k)
 
-    from ..utils.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, qspec, qspec, sspec, sspec),
         out_specs=qspec, check_vma=False)

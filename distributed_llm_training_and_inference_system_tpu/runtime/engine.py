@@ -27,6 +27,7 @@ from ..io.data import make_dataset
 from ..models.gpt import flops_per_token
 from ..parallel.api import ShardedTrainer
 from ..parallel.mesh import infer_data_parallel
+from ..utils.platform import chip_peaks
 
 logger = logging.getLogger("llmctl.engine")
 
@@ -77,6 +78,11 @@ class TrainingEngine:
             cfg.checkpoint.path, keep_latest=cfg.checkpoint.keep_latest,
             async_save=cfg.checkpoint.async_save)
         self._flops_per_token = flops_per_token(cfg.model, cfg.data.max_length)
+        # MFU is measured against the published peak of the device this
+        # engine actually runs on (None on the CPU: no MFU is logged there;
+        # an accelerator missing from the table raises)
+        peaks = chip_peaks(devices[0].platform, devices[0].device_kind)
+        self._peak_flops = peaks and peaks["peak_bf16_tflops"] * 1e12
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -147,23 +153,26 @@ class TrainingEngine:
                 loss = float(metrics["loss"])
                 dt = time.perf_counter() - window_t0
                 tokens_per_sec = window_tokens / dt
-                mfu = (tokens_per_sec * self._flops_per_token
-                       / (chips * self.cfg.hardware.peak_bf16_tflops * 1e12))
                 last_metrics = {
                     "step": step + 1, "loss": loss,
                     "grad_norm": float(metrics["grad_norm"]),
                     "lr": float(metrics["lr"]),
                     "tokens_per_sec": tokens_per_sec,
                     "tokens_per_sec_per_chip": tokens_per_sec / chips,
-                    "mfu": mfu,
                 }
+                mfu_text = ""
+                if self._peak_flops:
+                    mfu = (tokens_per_sec * self._flops_per_token
+                           / (chips * self._peak_flops))
+                    last_metrics["mfu"] = mfu
+                    mfu_text = f" | mfu {100 * mfu:.1f}%"
                 self.observer("train_step", last_metrics)
                 logger.info(
                     "step %d | loss %.4f | grad %.3f | lr %.2e | "
-                    "%.0f tok/s (%.0f/chip) | mfu %.1f%%",
+                    "%.0f tok/s (%.0f/chip)%s",
                     step + 1, loss, last_metrics["grad_norm"],
                     last_metrics["lr"], tokens_per_sec,
-                    tokens_per_sec / chips, 100 * mfu)
+                    tokens_per_sec / chips, mfu_text)
                 window_t0, window_tokens = time.perf_counter(), 0.0
 
             if (step + 1) % t_cfg.eval_interval == 0 and step + 1 < max_steps:

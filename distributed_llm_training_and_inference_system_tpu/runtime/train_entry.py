@@ -13,8 +13,7 @@ import logging
 import os
 import sys
 
-
-from ..utils.platform import honor_jax_platforms as _honor_jax_platforms
+from ..utils.platform import device_line, enable_compile_cache
 
 
 def maybe_init_distributed() -> bool:
@@ -69,9 +68,11 @@ def main(argv: list[str] | None = None) -> int:
         level=os.environ.get("LLMCTL_LOG_LEVEL", "INFO"),
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
 
+    cache_dir = enable_compile_cache()
     # multi-host rendezvous (set by runtime/launcher.py)
-    _honor_jax_platforms()
     maybe_init_distributed()
+    log = logging.getLogger("llmctl.train")
+    log.info("%s | compile cache %s", device_line(), cache_dir)
 
     from ..config.loader import load_run_config
     overrides = parse_overrides(args.set)
@@ -89,7 +90,7 @@ def main(argv: list[str] | None = None) -> int:
         final = engine.train(resume=not args.no_resume)
     finally:
         engine.close()
-    logging.getLogger("llmctl.train").info("finished: %s", final)
+    log.info("finished: %s", final)
     return 0
 
 

@@ -147,8 +147,12 @@ class InferenceServer:
                 self.engine.step()
                 # a successful step clears the degraded flag so a transient
                 # error doesn't leave /health at 503 forever (the cumulative
-                # count stays visible for operators)
-                self._engine_error = None
+                # count stays visible for operators) — unless a program
+                # failed to compile: other shapes still serve, but the next
+                # request of that shape fails again, so /health keeps
+                # saying so
+                if not self.engine.failed_programs:
+                    self._engine_error = None
             except Exception as e:  # device/runtime error: fail loudly, not
                 # silently — in-flight requests get FAILED (waiters fire),
                 # /health reports the outage, and the loop keeps serving.

@@ -11,10 +11,7 @@ from __future__ import annotations
 
 import json
 
-try:
-    import tomllib
-except ModuleNotFoundError:          # Python < 3.11: tomllib is stdlib-3.11+
-    import tomli as tomllib          # API-identical backport
+import tomllib
 from datetime import date, datetime
 from pathlib import Path
 from typing import Any

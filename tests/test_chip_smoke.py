@@ -1,0 +1,65 @@
+"""`chip_smoke.py` and the compile-cache function, as far as the CPU can
+show: the smoke must FAIL where there is no TPU (it has no CPU mode), and
+the cache directory must be placeable from outside."""
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+import pytest
+
+from distributed_llm_training_and_inference_system_tpu.utils import platform
+
+ROOT = Path(__file__).resolve().parent.parent
+
+
+def test_chip_smoke_fails_without_a_tpu():
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("CHIP_SMOKE_REHEARSAL", "CHIP_SMOKE_PHASE")}
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run([sys.executable, str(ROOT / "chip_smoke.py")],
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode != 0
+    last = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False
+    assert last["device"]["platform"] == "cpu"
+    assert '"platform": "tpu"' not in proc.stdout
+
+
+@pytest.fixture
+def restore_cache_dir():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_obeys_env_else_fixed_checkout_path(
+        monkeypatch, tmp_path, restore_cache_dir):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+    assert platform.enable_compile_cache() == str(tmp_path / "c")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "c")
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = platform.enable_compile_cache()
+    assert fixed == str(ROOT / ".jax_cache")
+    # the choice travels to children through the environment, and a second
+    # call (another entry point of the same process) names the same place
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == fixed
+    assert platform.enable_compile_cache() == fixed
+
+
+@pytest.mark.parametrize("kind,family", [("TPU v5 lite", "v5e"),
+                                         ("TPU v5e", "v5e"),
+                                         ("TPU v4", "v4")])
+def test_chip_peaks_known_kinds(kind, family):
+    assert platform.chip_peaks("tpu", kind)["chip_family"] == family
+
+
+def test_chip_peaks_cpu_has_none_and_unknown_tpu_is_an_error():
+    assert platform.chip_peaks("cpu", "cpu") is None
+    with pytest.raises(platform.UnknownChipError):
+        platform.chip_peaks("tpu", "TPU v99")

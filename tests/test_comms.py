@@ -3,8 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from distributed_llm_training_and_inference_system_tpu.utils.compat import (
-    shard_map)
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_llm_training_and_inference_system_tpu.comms import (

@@ -110,15 +110,17 @@ class TestSharedFileStateStore:
         assert seen == ["hello"]
 
     def test_registry_attach_heartbeat_alive_expiry(self, tmp_path):
-        a = SharedFileStateStore(tmp_path, front_id="A", expiry_s=0.05)
-        b = SharedFileStateStore(tmp_path, front_id="B", expiry_s=0.05)
+        # expiry well above a loaded CI worker's stall between b's
+        # heartbeat and its view (50 ms flaked under six xdist workers)
+        a = SharedFileStateStore(tmp_path, front_id="A", expiry_s=0.5)
+        b = SharedFileStateStore(tmp_path, front_id="B", expiry_s=0.5)
         ea = a.attach(info={"port": 1234})
         eb = b.attach()
         assert eb == ea + 1                  # monotone fencing epochs
         view = b.fronts_view()
         assert view["A"]["port"] == 1234 and view["A"]["alive"]
         assert a.front_alive("B")
-        time.sleep(0.08)
+        time.sleep(0.7)
         b.heartbeat()
         view = b.fronts_view()
         assert not view["A"]["alive"] and view["B"]["alive"]
@@ -137,12 +139,12 @@ class TestSharedFileStateStore:
         assert [r["op"] for r in b.poll()] == ["fresh"]
 
     def test_adopter_is_smallest_alive_front(self, tmp_path):
-        a = SharedFileStateStore(tmp_path, front_id="A", expiry_s=0.05)
-        b = SharedFileStateStore(tmp_path, front_id="B", expiry_s=0.05)
+        a = SharedFileStateStore(tmp_path, front_id="A", expiry_s=0.5)
+        b = SharedFileStateStore(tmp_path, front_id="B", expiry_s=0.5)
         a.attach()
         b.attach()
         assert a.is_adopter() and not b.is_adopter()
-        time.sleep(0.08)                     # A goes stale
+        time.sleep(0.7)                      # A goes stale
         b.heartbeat()
         assert b.is_adopter()
 

@@ -378,6 +378,47 @@ class TestHTTPServer:
             "prompt": [1, 2, 3], "max_tokens": 2}, timeout=30)
         assert r.status_code == 200
 
+    def test_compile_failure_stays_degraded(self, server):
+        """A program that fails its FIRST call failed to compile (on the
+        chip: Mosaic refusing a kernel). recover()'s device probe cannot
+        see that and the same shape fails again, so the engine must not be
+        reported healthy — even after other shapes serve fine."""
+        import requests as rq
+        srv, port = server
+        base = f"http://127.0.0.1:{port}"
+        eng = srv.engine
+
+        def refuse(*a, **k):
+            raise RuntimeError("RESOURCE_EXHAUSTED: scoped vmem")
+        from distributed_llm_training_and_inference_system_tpu.serve import (
+            engine as engine_mod)
+        # one prefill bucket covers every short prompt: a prompt longer
+        # than it needs a program this engine has not compiled yet
+        fresh = eng._bucket(1) + 1
+        bucket = eng._bucket(fresh)
+        assert bucket not in eng._prefill_cache
+        eng._prefill_cache[bucket] = engine_mod._Program(
+            f"prefill {bucket}", refuse, eng.failed_programs)
+        try:
+            r = rq.post(f"{base}/v1/completions", json={
+                "prompt": [7] * fresh, "max_tokens": 2}, timeout=60)
+            assert r.status_code == 500
+            assert f"prefill {bucket}" in eng.failed_programs
+            assert not eng.recover()
+            # a shape that compiles still serves ...
+            r = rq.post(f"{base}/v1/completions", json={
+                "prompt": [1, 2, 3], "max_tokens": 2}, timeout=60)
+            assert r.status_code == 200
+            # ... and /health still names the failure
+            h = rq.get(f"{base}/health", timeout=10)
+            assert h.status_code == 503
+            assert "scoped vmem" in h.json()["last_engine_error"]
+            assert h.json()["engine_error_count"] >= 1
+        finally:
+            eng.failed_programs.clear()
+            del eng._prefill_cache[bucket]
+            srv._engine_error = None
+
 
 class TestReviewRegressions:
     def test_top_p_zero_is_greedy(self, model_cfg):
