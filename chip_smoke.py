@@ -7,6 +7,8 @@ the full width of a model the repo supports (random weights from the seed).
     python chip_smoke.py --chips 4    the four-chip paths ONLY: fsdp x tp
                                       training against one device, and
                                       tensor-parallel serving against tp=1
+                                      (over HTTP as users run it, reported;
+                                      the engine in float32, judged)
 
 One chip, in this order (each phase is a child process that has exited
 before the next starts; this parent never imports jax, so it never holds
@@ -99,7 +101,6 @@ def child_env(cache_dir: str) -> dict:
     env["PYTHONPATH"] = str(ROOT) + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env["JAX_LOG_COMPILES"] = "1"      # "Finished XLA compilation of ..."
-    env["LLMCTL_LOG_LEVEL"] = "INFO"   # the `impl ...` lines
     env["PYTHONUNBUFFERED"] = "1"
     return env
 
@@ -560,32 +561,58 @@ def phase_mesh_train(env: dict) -> dict:
                           "partition the Mosaic kernel)"})
     secs, n = compile_seconds(text)
     say(f"  mesh_train: {n} programs compiled in {secs:.1f}s")
+
+    # the same eight steps through the entry point a user calls: the
+    # child above drives TrainingEngine itself (it needs the compiled
+    # step's text and the shards); the CLI's losses must be the child's
+    model = "gpt-test" if REHEARSAL else "gpt-750m"
+    say(f"== 4 chips: cli.main train launch --model {model} --max-steps 8 "
+        "--set parallel.fsdp=2 --set parallel.tensor_parallel=2 ==")
+    ckpt = SCRATCH / "mesh_cli_ckpt"
+    try:
+        text = run_child(
+            "mesh_train_cli", CLI + [
+                "train", "launch", "--model", model, "--max-steps", "8",
+                "--no-resume", "--set", "parallel.fsdp=2",
+                "--set", "parallel.tensor_parallel=2"]
+            + training_overrides(ckpt), env, timeout=1000)
+    finally:
+        shutil.rmtree(ckpt, ignore_errors=True)
+    if device_from_log(text) != device:
+        raise SmokeFailure(f"the CLI's trainer saw {device_from_log(text)}")
+    losses = [float(m.group(2)) for m in _STEP_RE.finditer(text)]
+    say("  CLI      : " + " ".join(f"{l:.4f}" for l in losses))
+    if (len(losses) != 8 or max(abs(a - b) for a, b in
+                                zip(losses, rec["loss_4"])) > 1e-3):
+        raise SmokeFailure(f"the CLI's 4-device losses {losses} are not the "
+                           f"child's {rec['loss_4']}")
+    report_impls("mesh_train_cli", text, ("attention", "optimizer_update"),
+                 device["platform"] == "tpu",
+                 allowed={"optimizer_update": "as above"})
     return device
 
 
+def tp_case() -> tuple:
+    """(tp degree, model, prompt tokens, new tokens, extra serve options) of
+    the tensor-parallel comparison; gpt-test has 2 kv heads, so the
+    rehearsal can only split them in two."""
+    if REHEARSAL:
+        return 2, "gpt-test", 16, 8, {"kv_block_size": 16}
+    return 4, "gpt-1b", 32, 32, {}
+
+
 def phase_tp_serve(env: dict, device: dict) -> None:
-    model = "gpt-test" if REHEARSAL else "gpt-1b"
+    tp, model, n_a, new_a, extra = tp_case()
     vocab = 256 if REHEARSAL else 50304
-    # gpt-test has 2 kv heads: the rehearsal can only split them in two
-    tp = 2 if REHEARSAL else 4
-    # Greedy tokens must be identical up to the first near-tie. tp splits
-    # every row-parallel reduction four ways and takes the gather attention
-    # path, so bf16 logits differ from tp=1's by rounding, and over RANDOM
-    # weights the top two logits are often closer than that. For THIS seed
-    # and prompt the dense forward's margins were measured on the chip
-    # (PERF.md, PR 22): tokens 0-15 lead by >= 0.049 (logit std 0.895),
-    # token 16 is a three-way tie within 0.033 (top two 0.003 apart) — tp=4
-    # and tp=1 agreed on 0-15 and each picked a different one of the three.
-    extra = ["--kv-block-size", "16"] if REHEARSAL else []
-    n_a, new_a, must_agree = (16, 8, 8) if REHEARSAL else (32, 32, 16)
     prompt = prompt_tokens(1, n_a, vocab)
     tokens = {}
     for degree in (tp, 1):
         say(f"== 4 chips: serve start --model {model} --tensor-parallel "
             f"{degree} ==")
-        srv = Server(f"serve_tp{degree}", ["--model", model,
-                                           "--tensor-parallel", str(degree)]
-                     + extra, env)
+        srv = Server(f"serve_tp{degree}", [
+            "--model", model, "--tensor-parallel", str(degree)]
+            + [a for k, v in extra.items()
+               for a in ("--" + k.replace("_", "-"), str(v))], env)
         try:
             ready = srv.wait_ready(900)
             out = srv.complete(prompt, new_a)
@@ -609,13 +636,40 @@ def phase_tp_serve(env: dict, device: dict) -> None:
                              "Mosaic kernel)"
                          for op in ("paged_attention",
                                     "paged_attention_multi")})
+    # The servers compute in the model's bfloat16 (`--dtype` sets storage
+    # only), where tp=4 and tp=1 differ by rounding, and over RANDOM
+    # weights some greedy step is a near-tie that rounding decides: their
+    # common prefix is reported, not judged. What is judged is the same
+    # comparison where rounding cannot decide: the engine in float32 with
+    # full-precision matmuls, all tokens identical.
     common = next((i for i, (a, b) in enumerate(zip(tokens[tp], tokens[1]))
                    if a != b), len(tokens[1]))
-    say(f"  tp={tp} and tp=1 greedy tokens: {common}/{len(tokens[1])} "
-        f"identical from the start (the first {must_agree} must be: "
-        "see the near-tie note in chip_smoke.py)")
-    if common < must_agree:
-        raise SmokeFailure(f"tp={tp} {tokens[tp]} != tp=1 {tokens[1]}")
+    say(f"  the servers' greedy tokens at tp={tp} and tp=1 are identical for "
+        f"the first {common} of {len(tokens[1])} (reported, not judged)")
+    say(f"== 4 chips: InferenceEngine tp={tp} against tp=1 in float32, "
+        "matmul precision highest ==")
+    text = run_child("tp_exact", [sys.executable, str(ROOT / "chip_smoke.py")],
+                     {**env, PHASE_ENV: "tp_exact"}, timeout=1200)
+    [rec] = smoke_records(text, "tp_exact")
+    for degree in (tp, 1):
+        say(f"  tp={degree}: {rec['tokens'][str(degree)]}")
+    report_impls("tp_exact", text, ("paged_attention",),
+                 device["platform"] == "tpu",
+                 allowed={"paged_attention": "tp>1 asks for the gather path",
+                          "paged_attention_multi": "tp>1 asks for the gather "
+                                                   "path",
+                          "attention": "this comparison's own dense forward "
+                                       "(gpt.forward, XLA attention), not "
+                                       "the engine's"})
+    norm = rec["logit_diff"] / rec["logit_max"]
+    say(f"  identical: {rec['identical']} of {new_a}; dense logits along "
+        f"tp=1's tokens, sharded against unsharded parameters: largest "
+        f"difference {rec['logit_diff']:.2e} of {rec['logit_max']:.2f} "
+        f"(normalised {norm:.1e}, tolerance 1e-4); smallest top-two margin "
+        f"{rec['min_margin']:.5f} at token {rec['min_margin_at']}")
+    if rec["identical"] != new_a or norm > 1e-4:
+        raise SmokeFailure(f"tp={tp} differs from tp=1 in float32: sharding, "
+                           "not rounding")
 
 
 def phase_replica_placement(env: dict) -> None:
@@ -963,8 +1017,69 @@ def child_replicas() -> None:
                       "available": device["count"]})
 
 
+def child_tp_exact() -> None:
+    """The engine at tp=1 and tp=N with float32 weights, activations and
+    pages and full-precision matmuls: what is left between the two is the
+    order of float32 sums. Greedy tokens must all agree, and the dense
+    forward's logits along tp=1's tokens, with the engine's sharded
+    parameters against the unsharded ones, must agree to float32 noise
+    (their margins are printed: the smallest says how near a tie the
+    comparison came)."""
+    device = child_setup()
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_llm_training_and_inference_system_tpu.config.presets import (
+        get_model_config)
+    from distributed_llm_training_and_inference_system_tpu.config.schema import (
+        ServeConfig)
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    from distributed_llm_training_and_inference_system_tpu.serve.engine import (
+        InferenceEngine)
+    from distributed_llm_training_and_inference_system_tpu.serve.scheduler import (
+        SamplingParams)
+
+    tp, model, n_a, new_a, extra = tp_case()
+    if device["count"] < tp:
+        print(f"tp={tp} needs {tp} devices, jax reports {device}",
+              file=sys.stderr)
+        sys.exit(2)
+    jax.config.update("jax_default_matmul_precision", "highest")
+    cfg = get_model_config(model)
+    cfg.dtype = "float32"
+    prompt = prompt_tokens(1, n_a, cfg.vocab_size)
+    tokens, logits = {}, {}
+    for degree in (1, tp):
+        eng = InferenceEngine(cfg, ServeConfig(
+            model=model, dtype="float32", tensor_parallel=degree,
+            max_seq_len=min(2048, cfg.max_position_embeddings), **extra),
+            seed=0)
+        [req] = eng.generate([prompt], SamplingParams(temperature=0.0,
+                                                      max_tokens=new_a))
+        tokens[degree] = list(req.generated_tokens)
+        forced = jnp.asarray([prompt + tokens[1]], jnp.int32)
+        logits[degree] = np.asarray(jax.jit(
+            lambda p, t: gpt.forward(p, t, cfg))(eng.params, forced)
+            [0, n_a - 1:-1], np.float32)
+        del eng, req
+        gc.collect()
+    same = next((i for i, (a, b) in enumerate(zip(tokens[tp], tokens[1]))
+                 if a != b), min(len(tokens[tp]), len(tokens[1])))
+    top2 = np.sort(logits[1], axis=-1)[:, -2:]
+    margins = top2[:, 1] - top2[:, 0]
+    emit("tp_exact", {
+        "tokens": {str(k): v for k, v in tokens.items()}, "identical": same,
+        "logit_diff": float(np.abs(logits[tp] - logits[1]).max()),
+        "logit_max": float(np.abs(logits[1]).max()),
+        "min_margin": float(margins.min()),
+        "min_margin_at": int(margins.argmin())})
+
+
 CHILDREN = {"kernels": child_kernels, "mesh_train": child_mesh_train,
-            "replicas": child_replicas}
+            "tp_exact": child_tp_exact, "replicas": child_replicas}
 
 
 # ---------------------------------------------------------------------------

@@ -1270,9 +1270,8 @@ def battery(spec, out_dir, resume, wait_for_chip, probe_interval,
     if not items:
         raise click.ClickException(f"{spec}: no [[item]] entries")
     # spec-level [env] table: exported to every item's subprocess. The
-    # shell batteries source battery_lib.sh for JAX_COMPILATION_CACHE_DIR
-    # (7B programs take minutes to compile; cached rebuilds are
-    # seconds) — TOML batteries declare the same thing here.
+    # compile cache is not declared there: cli/main.py has already put
+    # its directory in this process's environment, which items inherit.
     import os as _os
     spec_env = {str(k): str(v)
                 for k, v in (items_spec.get("env") or {}).items()}

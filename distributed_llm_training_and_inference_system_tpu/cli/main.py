@@ -94,8 +94,7 @@ def main(ctx, **global_opts):
     ctx.ensure_object(dict)
     ctx.obj.update(global_opts)
     logging.basicConfig(
-        level=os.environ.get("LLMCTL_LOG_LEVEL",
-                             global_opts["log_level"]).upper(),
+        level=global_opts["log_level"].upper(),
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
     if global_opts.get("fake_devices"):
         flags = os.environ.get("XLA_FLAGS", "")

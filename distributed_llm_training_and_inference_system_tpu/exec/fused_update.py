@@ -130,10 +130,10 @@ def fused_adamw_apply(params: Any, grads: Any, mu: Any, nu: Any,
                           b1=b1, b2=b2, eps=eps, wd=wd, mu_dtype=m.dtype,
                           nu_dtype=v.dtype)
 
-    from ..parallel.sharding import _current_mesh
+    from ..parallel.sharding import current_mesh
     from ..utils.platform import kernel_impl, report_impl
     flat_p, treedef = jax.tree_util.tree_flatten(params)
-    mesh = _current_mesh()
+    mesh = current_mesh()
     if use_pallas and mesh is not None and mesh.size > 1:
         # the kernel is a custom call GSPMD cannot partition ("Mosaic
         # kernels cannot be automatically partitioned" on the TPU

@@ -128,23 +128,24 @@ def _flash_on_mesh(q, k, v, segment_ids):
     from jax.sharding import PartitionSpec as P
 
     from ..ops.attention import flash_attention
-    from ..parallel.sharding import _current_mesh
-    mesh = _current_mesh()
+    from ..parallel.sharding import current_mesh
+    mesh = current_mesh()
     on_mesh = (mesh is not None and mesh.size > 1
                and mesh.shape.get("pp", 1) == 1)
     report_impl("attention", kernel_impl("flash"), f"q{tuple(q.shape)}"
                 + (f", shard_map over {dict(mesh.shape)}" if on_mesh else ""))
     if not on_mesh:
         return flash_attention(q, k, v, segment_ids=segment_ids, causal=True)
-    if segment_ids is None:
-        segment_ids = jnp.ones(q.shape[:2], jnp.int32)
     qspec = P(("dp", "fsdp"), None, "tp", None)
+    operands, specs = (q, k, v), (qspec, qspec, qspec)
+    if segment_ids is not None:
+        operands += (segment_ids,)
+        specs += (P(("dp", "fsdp"), None),)
     fn = jax.shard_map(
-        lambda q_, k_, v_, s_: flash_attention(q_, k_, v_, segment_ids=s_,
-                                               causal=True),
-        mesh=mesh, in_specs=(qspec, qspec, qspec, P(("dp", "fsdp"), None)),
-        out_specs=qspec, check_vma=False)
-    return fn(q, k, v, segment_ids)
+        lambda q_, k_, v_, s_=None: flash_attention(
+            q_, k_, v_, segment_ids=s_, causal=True),
+        mesh=mesh, in_specs=specs, out_specs=qspec, check_vma=False)
+    return fn(*operands)
 
 
 def attention_block(

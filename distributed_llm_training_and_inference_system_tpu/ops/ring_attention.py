@@ -160,7 +160,7 @@ def ring_attention(
 ) -> jax.Array:
     """Causal ring attention. Runs under the ambient mesh (use_mesh); with
     no mesh or sp == 1 it reduces to single-chunk flash attention."""
-    from ..parallel.sharding import _current_mesh
+    from ..parallel.sharding import current_mesh
     from jax.sharding import PartitionSpec as P
 
     B, S, Nq, D = q.shape
@@ -173,7 +173,7 @@ def ring_attention(
     positions = positions.astype(jnp.int32)
     block_q = _fit_block(block_q, S)
 
-    mesh = _current_mesh()
+    mesh = current_mesh()
     if mesh is None or mesh.shape.get(axis_name, 1) == 1:
         from .attention import flash_attention
         return flash_attention(q, k, v, segment_ids=segment_ids,

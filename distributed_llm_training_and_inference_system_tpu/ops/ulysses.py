@@ -81,7 +81,7 @@ def ulysses_attention(
 ) -> jax.Array:
     """Causal Ulysses attention under the ambient mesh; with no mesh or
     sp == 1 it reduces to plain flash attention."""
-    from ..parallel.sharding import _current_mesh
+    from ..parallel.sharding import current_mesh
 
     B, S, Nq, D = q.shape
     Nkv = k.shape[2]
@@ -92,7 +92,7 @@ def ulysses_attention(
     positions = positions.astype(jnp.int32)
     segment_ids = segment_ids.astype(jnp.int32)
 
-    mesh = _current_mesh()
+    mesh = current_mesh()
     sp = 1 if mesh is None else mesh.shape.get(axis_name, 1)
     if sp == 1:
         return flash_attention(q, k, v, segment_ids=segment_ids,

@@ -39,11 +39,11 @@ from ..config.schema import ModelConfig, ParallelConfig
 from ..models.gpt import _block_fn, _remat_wrap, unembed
 from ..models.layers import rope_frequencies
 from ..models.loss import next_token_loss
-from .sharding import _current_mesh, _shrink_to_fit
+from .sharding import current_mesh, _shrink_to_fit
 
 
 def _constrain(x, spec):
-    mesh = _current_mesh()
+    mesh = current_mesh()
     if mesh is None or mesh.size == 1:
         return x
     spec = _shrink_to_fit(P(*spec[: x.ndim]), x.shape, mesh)

@@ -173,7 +173,7 @@ def constrain(x: jax.Array, name: str, mesh: Optional[Mesh] = None) -> jax.Array
     Used inside model forward to anchor GSPMD propagation at block
     boundaries — the TPU replacement for hand-placed NCCL calls.
     """
-    mesh = mesh or _current_mesh()
+    mesh = mesh or current_mesh()
     if mesh is None or mesh.empty or mesh.size == 1:
         return x
     spec = ACTIVATION_RULES[name]
@@ -190,7 +190,8 @@ import threading
 _ctx = threading.local()
 
 
-def _current_mesh() -> Optional[Mesh]:
+def current_mesh() -> Optional[Mesh]:
+    """The mesh made ambient by ``use_mesh`` on this thread, or None."""
     return getattr(_ctx, "mesh", None)
 
 
@@ -198,7 +199,7 @@ def _current_mesh() -> Optional[Mesh]:
 def use_mesh(mesh: Mesh):
     """Make *mesh* ambient so models/ops can place sharding constraints
     without threading a mesh argument through every call."""
-    prev = _current_mesh()
+    prev = current_mesh()
     _ctx.mesh = mesh
     try:
         with mesh:

@@ -51,12 +51,14 @@ logger = logging.getLogger("llmctl.serve.engine")
 class _Program:
     """A jitted engine program that remembers whether it has ever run.
 
-    A failure on a program's FIRST call is a failure to compile it (the
-    TPU compiler refusing a kernel, a program that does not fit): it will
-    fail the same way for every later request of that shape, and a probe
-    of the device says nothing about it. The engine records such a
-    failure in ``failed_programs``; ``recover()`` then reports the engine
-    unhealthy for good instead of clearing the error."""
+    A failure before a program has ever run is most likely a failure to
+    compile it (the TPU compiler refusing a kernel, a program that does
+    not fit): it will fail the same way for every later request of that
+    shape, and a probe of the device says nothing about it. The engine
+    records such a failure in ``failed_programs``; ``recover()`` then
+    reports the engine unhealthy until that same program runs — a
+    transient first-call error (HBM full, a deleted donated buffer) is
+    cleared by the program's next successful call."""
 
     def __init__(self, name: str, fn: Callable, failed: dict, **jit_kwargs):
         self.name = name
@@ -71,7 +73,9 @@ class _Program:
             if not self._ran:
                 self._failed[self.name] = f"{type(e).__name__}: {e}"[:400]
             raise
-        self._ran = True
+        if not self._ran:
+            self._ran = True
+            self._failed.pop(self.name, None)
         return out
 
 
@@ -1884,10 +1888,11 @@ class InferenceEngine:
         at deleted arrays, so every later step would raise "Array has been
         deleted" forever. Reallocate them (all requests were already failed
         by fail_all, so no live KV is lost) and run a tiny device op to
-        check the backend is usable again. Returns True when healthy — never
-        again once a program failed its first (compiling) call: the probe
-        below cannot see that failure, and the same program would fail
-        the next request of its shape (``failed_programs``)."""
+        check the backend is usable again. Returns True when healthy — not
+        while a program that failed before its first run has yet to run:
+        the probe below cannot see that failure, and a program that did
+        not compile fails the next request of its shape too
+        (``failed_programs``)."""
         try:
             reallocated = False
             for name in ("k_pages", "v_pages"):

@@ -17,6 +17,7 @@ Weights are built once (module fixture) and shared across every engine,
 so each test pays only its replicas' compile time.
 """
 
+import dataclasses
 import threading
 import time
 
@@ -66,6 +67,15 @@ def make_fleet(model_cfg, params, *, replicas=2, plan=None, fleet_kw=None,
                  restart_backoff_s=0.05, probe_interval_s=0.05)
     fc_kw.update(fleet_kw or {})
     fc = FleetConfig(**fc_kw)
+    if warm and (plan is None or plan.slow_replica is None):
+        # slow-replica widener for every mid-decode scenario (the seeded
+        # compressed-courier test used to carry its own): on a loaded host
+        # the test thread can be descheduled long enough for a 48-token
+        # run to finish before its drain lands, leaving nothing to
+        # migrate. Replica 0 is where a fresh fleet's load-tie routes the
+        # first request, and the one these tests drain.
+        plan = dataclasses.replace(plan or FaultPlan(), slow_replica=0,
+                                   slow_ms=3.0)
     fleet = ServeFleet(model_cfg, serve_cfg(**(serve_kw or {})), fc,
                        params=params, fault_plan=plan, supervise=False,
                        seed=0)
@@ -698,14 +708,8 @@ class TestCourierCompressed:
                                  params=ref_engine.params, seed=0)
         ref = [r.generated_tokens
                for r in q8_ref.generate([PROMPTS[0]], sampled)]
-        # slow-replica widener (same latent flake the fleet2+migrate
-        # regime fixed): on a warm process the 32-token run can finish
-        # before the drain lands on the engine thread, leaving nothing
-        # to migrate and an empty courier ledger. The fresh fleet's
-        # load-tie routes PROMPTS[0] to replica 0 deterministically.
         fleet = make_fleet(model_cfg, ref_engine.params, warm=True,
-                           plan=FaultPlan(**TestCourierChaos.CHAOS_PLAN,
-                                          slow_replica=0, slow_ms=3.0),
+                           plan=FaultPlan(**TestCourierChaos.CHAOS_PLAN),
                            serve_kw={"kv_quantization": "int8"},
                            fleet_kw=dict(self.COMP_KW))
         try:

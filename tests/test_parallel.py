@@ -88,7 +88,9 @@ def test_sharded_step_matches_single_device(devices8, par):
     np.testing.assert_allclose(losses, ref_losses, rtol=2e-4, atol=2e-5)
 
 
-def test_flash_attention_runs_per_shard_on_a_mesh(devices8):
+@pytest.mark.parametrize("packed", [False, True],
+                         ids=["no-segment-ids", "segment-ids"])
+def test_flash_attention_runs_per_shard_on_a_mesh(devices8, packed):
     """On a multi-device mesh the flash kernel is wrapped in a shard_map
     (batch over dp/fsdp, heads over tp): GSPMD cannot partition a Mosaic
     custom call, which the TPU lowering of a sharded step refuses and
@@ -98,6 +100,9 @@ def test_flash_attention_runs_per_shard_on_a_mesh(devices8):
     opt_cfg = OptimizerConfig(lr=1e-2)
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 32), 1,
                                           model_cfg.vocab_size)}
+    if packed:      # two documents per row: a shard_map operand of its own
+        batch["segment_ids"] = jnp.broadcast_to(
+            1 + (jnp.arange(32) >= 12).astype(jnp.int32), (4, 32))
     step_fn, tx, _ = make_train_step(model_cfg, opt_cfg)
     ref_state = TrainState.create(init(model_cfg, jax.random.PRNGKey(0)), tx)
     jstep = jax.jit(step_fn)

@@ -419,6 +419,50 @@ class TestHTTPServer:
             del eng._prefill_cache[bucket]
             srv._engine_error = None
 
+    def test_transient_first_call_failure_clears(self, server):
+        """A program whose first call failed for a passing reason (HBM
+        full while other slots held it) and whose next call runs is not a
+        compile failure: /health returns to ok with that call."""
+        import requests as rq
+        srv, port = server
+        base = f"http://127.0.0.1:{port}"
+        eng = srv.engine
+        from distributed_llm_training_and_inference_system_tpu.serve import (
+            engine as engine_mod)
+        fresh = eng._bucket(1) + 1
+        bucket = eng._bucket(fresh)
+        cached = eng._prefill_cache.pop(bucket, None)
+        real = eng._prefill_fn(bucket)._fn
+        calls = []
+
+        def once(*a):
+            calls.append(1)
+            if len(calls) == 1:
+                raise RuntimeError("RESOURCE_EXHAUSTED: hbm")
+            return real(*a)
+        eng._prefill_cache[bucket] = engine_mod._Program(
+            f"prefill {bucket}", once, eng.failed_programs)
+        body = {"prompt": [7] * fresh, "max_tokens": 2}
+        try:
+            r = rq.post(f"{base}/v1/completions", json=body, timeout=60)
+            assert r.status_code == 500
+            assert f"prefill {bucket}" in eng.failed_programs
+            assert rq.get(f"{base}/health", timeout=10).status_code == 503
+            r = rq.post(f"{base}/v1/completions", json=body, timeout=60)
+            assert r.status_code == 200
+            assert not eng.failed_programs
+            assert eng.recover()
+            h = rq.get(f"{base}/health", timeout=10)
+            assert h.status_code == 200
+            assert h.json()["last_engine_error"] is None
+        finally:
+            eng.failed_programs.clear()
+            if cached is None:
+                del eng._prefill_cache[bucket]
+            else:
+                eng._prefill_cache[bucket] = cached
+            srv._engine_error = None
+
 
 class TestReviewRegressions:
     def test_top_p_zero_is_greedy(self, model_cfg):
