@@ -82,7 +82,8 @@ class _LazyGroup(click.Group):
 @click.option("--deterministic", is_flag=True, default=False,
               help="Bit-deterministic mode (fixed PRNG keys + deterministic XLA ops).")
 @click.option("--log-level", default="INFO", show_default=True)
-@click.option("--otlp-endpoint", default=None, help="OTLP collector endpoint.")
+@click.option("--otlp-endpoint", default=None,
+              help="Accepted and ignored: nothing is exported over OTLP.")
 @click.option("--platform", default=None, type=click.Choice(["tpu", "cpu"]),
               help="Force the JAX platform (cpu = host simulation).")
 @click.option("--fake-devices", default=None, type=int,
@@ -96,6 +97,10 @@ def main(ctx, **global_opts):
     logging.basicConfig(
         level=global_opts["log_level"].upper(),
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
+    if global_opts.get("otlp_endpoint"):
+        logging.getLogger("llmctl").warning(
+            "--otlp-endpoint is not supported and is ignored: metrics leave "
+            "through --prometheus-port, /v1/stats and `llmctl trace`")
     if global_opts.get("fake_devices"):
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:

@@ -76,24 +76,26 @@ def _update_leaf_pallas(p, g, mu, nu, scalars, *, b1, b2, eps, wd,
     br = min(block_rows, R)
     grid = (pl.cdiv(R, br), pl.cdiv(C, bc))
     spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
-    out = pl.pallas_call(
-        functools.partial(_adamw_kernel, b1=b1, b2=b2, eps=eps, wd=wd,
-                          mu_dtype=mu.dtype, nu_dtype=nu.dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),   # scalars, whole array
-            spec, spec, spec, spec,
-        ],
-        out_specs=(spec, spec, spec),
-        out_shape=(
-            jax.ShapeDtypeStruct((R, C), p.dtype),
-            jax.ShapeDtypeStruct((R, C), mu.dtype),
-            jax.ShapeDtypeStruct((R, C), nu.dtype),
-        ),
-        # in-place: p -> p', mu -> mu', nu -> nu' (0 is the scalar vector)
-        input_output_aliases={1: 0, 3: 1, 4: 2},
-        interpret=jax.default_backend() != "tpu",
-    )(scalars, p2, g2, mu2, nu2)
+    with jax.named_scope("fused_adamw"):
+        out = pl.pallas_call(
+            functools.partial(_adamw_kernel, b1=b1, b2=b2, eps=eps, wd=wd,
+                              mu_dtype=mu.dtype, nu_dtype=nu.dtype),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),   # scalars, whole array
+                spec, spec, spec, spec,
+            ],
+            out_specs=(spec, spec, spec),
+            out_shape=(
+                jax.ShapeDtypeStruct((R, C), p.dtype),
+                jax.ShapeDtypeStruct((R, C), mu.dtype),
+                jax.ShapeDtypeStruct((R, C), nu.dtype),
+            ),
+            # in-place: p -> p', mu -> mu', nu -> nu' (0 is the scalar vector)
+            input_output_aliases={1: 0, 3: 1, 4: 2},
+            interpret=jax.default_backend() != "tpu",
+            name="fused_adamw",
+        )(scalars, p2, g2, mu2, nu2)
     new_p, new_mu, new_nu = out
     return (new_p.reshape(shape), new_mu.reshape(shape),
             new_nu.reshape(shape))

@@ -142,30 +142,31 @@ def make_train_step(
         else:
             clip_scale = jnp.float32(1.0)
 
-        if opt_cfg.fused and opt_cfg.type in ("adamw", "adam"):
-            # One HBM pass per leaf: clip folded into the update, no
-            # clipped-grads / updates trees materialised
-            # (exec/fused_update.py; numerics == the optax chain below).
-            adam = state.opt_state[0]   # ScaleByAdamState (chain head)
-            lr = schedule(adam.count)
-            wd = opt_cfg.weight_decay if opt_cfg.type == "adamw" else 0.0
-            new_params, new_mu, new_nu = fused_adamw_apply(
-                state.params, grads, adam.mu, adam.nu, adam.count,
-                lr=lr, b1=opt_cfg.betas[0], b2=opt_cfg.betas[1],
-                eps=opt_cfg.eps, weight_decay=wd,
-                decay_mask=_decay_mask(state.params),
-                clip_scale=clip_scale)
-            new_opt_state = (adam._replace(count=adam.count + 1,
-                                           mu=new_mu, nu=new_nu),
-                             ) + tuple(
-                s._replace(count=s.count + 1)
-                if "count" in getattr(s, "_fields", ()) else s
-                for s in state.opt_state[1:])
-        else:
-            grads = jax.tree_util.tree_map(lambda g: g * clip_scale, grads)
-            updates, new_opt_state = tx.update(grads, state.opt_state,
-                                               state.params)
-            new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer_update"):
+            if opt_cfg.fused and opt_cfg.type in ("adamw", "adam"):
+                # One HBM pass per leaf: clip folded into the update, no
+                # clipped-grads / updates trees materialised
+                # (exec/fused_update.py; numerics == the optax chain below).
+                adam = state.opt_state[0]   # ScaleByAdamState (chain head)
+                lr = schedule(adam.count)
+                wd = opt_cfg.weight_decay if opt_cfg.type == "adamw" else 0.0
+                new_params, new_mu, new_nu = fused_adamw_apply(
+                    state.params, grads, adam.mu, adam.nu, adam.count,
+                    lr=lr, b1=opt_cfg.betas[0], b2=opt_cfg.betas[1],
+                    eps=opt_cfg.eps, weight_decay=wd,
+                    decay_mask=_decay_mask(state.params),
+                    clip_scale=clip_scale)
+                new_opt_state = (adam._replace(count=adam.count + 1,
+                                               mu=new_mu, nu=new_nu),
+                                 ) + tuple(
+                    s._replace(count=s.count + 1)
+                    if "count" in getattr(s, "_fields", ()) else s
+                    for s in state.opt_state[1:])
+            else:
+                grads = jax.tree_util.tree_map(lambda g: g * clip_scale, grads)
+                updates, new_opt_state = tx.update(grads, state.opt_state,
+                                                   state.params)
+                new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(step=state.step + 1, params=new_params,
                                opt_state=new_opt_state)
         metrics = {

@@ -69,6 +69,13 @@ METRICS: dict[str, MetricSpec] = {
         HISTOGRAM, "Time to first token",
         buckets=(.01, .025, .05, .1, .15, .2, .3, .5, 1, 2)),
     "llmctl_inference_queue_depth": MetricSpec(GAUGE, "Queued requests"),
+    # buckets are the scheduler's own (metrics/spans.py QUEUE_WAIT_LE_MS):
+    # its histogram is exported as it stands
+    "llmctl_inference_queue_wait_seconds": MetricSpec(
+        HISTOGRAM, "Time a request waited for a decode slot"),
+    "llmctl_engine_phase_seconds_total": MetricSpec(
+        COUNTER, "Engine-thread self time by llmctl.engine.* span",
+        ("phase",)),
     "llmctl_decode_tokens_per_sec": MetricSpec(
         GAUGE, "Decode throughput"),
     "llmctl_inference_preemptions": MetricSpec(COUNTER, "KV preemptions"),

@@ -1,8 +1,9 @@
-"""Observability: metrics collection + Prometheus/OTLP export, WIRED IN.
+"""Observability: metrics collection + Prometheus export, WIRED IN.
 
 Parity: reference metrics/observability.py (MetricsCollector :63,
-PrometheusExporter :230, OpenTelemetryExporter :276, ObservabilityManager
-:331) — with the crucial difference that the reference never connects any
+PrometheusExporter :230, ObservabilityManager :331; the reference's span
+exporter is not rebuilt: spans are ``metrics/spans.py``, on the profiler's
+clock) — with the crucial difference that the reference never connects any
 of it to the engine/server (SURVEY §5.5: "nothing in engine/server feeds
 the collector"). Here runtime/engine.py and serve/server.py call
 ``engine_observer()`` / ``record_inference`` on every step.
@@ -144,6 +145,32 @@ class MetricsCollector:
         return out
 
 
+class _QueueWaitCollector:
+    """A histogram the program has already counted (``QueueWaitHistogram``
+    .snapshot(): bounds in ms, counts by bucket, sum, n), as a Prometheus
+    histogram in seconds."""
+
+    def __init__(self, name: str):
+        from prometheus_client import REGISTRY
+
+        from .names import METRICS
+        self.name, self.help = name, METRICS[name].help
+        self.snap = {"le": ["+inf"], "counts": [0], "sum": 0.0}
+        REGISTRY.register(self)
+
+    def collect(self):
+        from prometheus_client.core import HistogramMetricFamily
+        from prometheus_client.utils import floatToGoString
+        family = HistogramMetricFamily(self.name, self.help)
+        running, buckets = 0, []
+        for le, count in zip(self.snap["le"], self.snap["counts"]):
+            running += count
+            buckets.append((floatToGoString(
+                float("inf") if le == "+inf" else le / 1e3), running))
+        family.add_metric([], buckets, self.snap["sum"] / 1e3)
+        yield family
+
+
 class PrometheusExporter:
     """llmctl_* gauges/counters/histograms on a scrape port (reference
     PrometheusExporter observability.py:230-274)."""
@@ -184,6 +211,14 @@ class PrometheusExporter:
         self.infer_latency = mk("llmctl_inference_latency_seconds")
         self.infer_ttft = mk("llmctl_inference_ttft_seconds")
         self.infer_queue = mk("llmctl_inference_queue_depth")
+        # the scheduler's own histogram (engine.stats()["queue_wait_ms"]:
+        # admit time - arrival, once an admission), handed to the scrape as
+        # it stands, so that /v1/stats and Prometheus show ONE distribution
+        self.infer_queue_wait = _QueueWaitCollector(
+            "llmctl_inference_queue_wait_seconds")
+        # the engine thread's self time by llmctl.engine.* span
+        # (metrics/spans.py), from the running totals of engine.stats()
+        self.engine_phase_seconds = mk("llmctl_engine_phase_seconds_total")
         self.decode_tokens_per_sec = mk("llmctl_decode_tokens_per_sec")
         # on-demand admission telemetry (round 3): preemption pressure and
         # swap-in counts are the KV-capacity health signals. Cumulative
@@ -367,6 +402,14 @@ class PrometheusExporter:
             self.infer_ttft.observe(m["ttft_ms"] / 1e3)
         if "queue_depth" in m:
             self.infer_queue.set(m["queue_depth"])
+        if "queue_wait_ms" in m:
+            self.infer_queue_wait.snap = m["queue_wait_ms"]
+        for phase, cell in m.get("phases", {}).items():
+            key = f"phase:{phase}"
+            delta = cell["s"] - self._last_totals.get(key, 0.0)
+            if delta > 0:
+                self.engine_phase_seconds.labels(phase=phase).inc(delta)
+            self._last_totals[key] = cell["s"]
         if "decode_tokens_per_sec" in m:
             self.decode_tokens_per_sec.set(m["decode_tokens_per_sec"])
         for key, counter in (("preemptions", self.infer_preemptions),
@@ -640,58 +683,20 @@ class PrometheusExporter:
             self._last_totals[f"fleet_au_{key}"] = total
 
 
-class OTLPExporter:
-    """OpenTelemetry spans + histograms for train/inference events
-    (reference OpenTelemetryExporter observability.py:276-329)."""
-
-    def __init__(self, endpoint: str, service: str = "llmctl"):
-        from opentelemetry import metrics as om, trace
-        from opentelemetry.sdk.resources import Resource
-        from opentelemetry.sdk.trace import TracerProvider
-        from opentelemetry.sdk.trace.export import BatchSpanProcessor
-        from opentelemetry.exporter.otlp.proto.http.trace_exporter import (
-            OTLPSpanExporter)
-        resource = Resource.create({"service.name": service})
-        provider = TracerProvider(resource=resource)
-        provider.add_span_processor(BatchSpanProcessor(
-            OTLPSpanExporter(endpoint=f"{endpoint}/v1/traces")))
-        trace.set_tracer_provider(provider)
-        self.tracer = trace.get_tracer("llmctl")
-
-    def record_training_step(self, m: dict) -> None:
-        with self.tracer.start_as_current_span("training_step") as span:
-            for k, v in m.items():
-                if isinstance(v, (int, float)):
-                    span.set_attribute(f"train.{k}", v)
-
-    def record_inference_request(self, m: dict) -> None:
-        with self.tracer.start_as_current_span("inference_request") as span:
-            for k, v in m.items():
-                if isinstance(v, (int, float)):
-                    span.set_attribute(f"inference.{k}", v)
-
-
 class ObservabilityManager:
     """Composition + export pump (reference ObservabilityManager
     observability.py:331-415)."""
 
     def __init__(self, prometheus_port: Optional[int] = None,
-                 otlp_endpoint: Optional[str] = None,
                  collect_interval: float = 1.0):
         self.collector = MetricsCollector(interval=collect_interval)
         self.prometheus: Optional[PrometheusExporter] = None
-        self.otlp: Optional[OTLPExporter] = None
         if prometheus_port:
             try:
                 self.prometheus = PrometheusExporter(prometheus_port)
                 self.prometheus.serve()
             except Exception as e:
                 logger.warning("prometheus exporter disabled: %s", e)
-        if otlp_endpoint:
-            try:
-                self.otlp = OTLPExporter(otlp_endpoint)
-            except Exception as e:
-                logger.warning("otlp exporter disabled: %s", e)
         self._export_thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
 
@@ -713,8 +718,6 @@ class ObservabilityManager:
         self.collector.record_training(m)
         if self.prometheus:
             self.prometheus.export_training(m)
-        if self.otlp:
-            self.otlp.record_training_step(m)
 
     def record_eval(self, m: dict) -> None:
         self.collector.record_training({"eval": True, **m})
@@ -725,8 +728,6 @@ class ObservabilityManager:
         self.collector.record_inference(m)
         if self.prometheus:
             self.prometheus.export_inference(m)
-        if self.otlp:
-            self.otlp.record_inference_request(m)
 
     def record_fleet(self, snap: dict) -> None:
         """Per-replica fleet snapshot (supervisor poll cadence)."""
@@ -739,17 +740,15 @@ class ObservabilityManager:
 _manager: Optional[ObservabilityManager] = None
 
 
-def setup_observability(prometheus_port: Optional[int] = None,
-                        otlp_endpoint: Optional[str] = None) -> ObservabilityManager:
+def setup_observability(prometheus_port: Optional[int] = None
+                        ) -> ObservabilityManager:
     global _manager
     if _manager is None:
         import os
         if prometheus_port is None:
             port = os.environ.get("LLMCTL_METRICS_PORT")
             prometheus_port = int(port) if port else None
-        if otlp_endpoint is None:
-            otlp_endpoint = os.environ.get("LLMCTL_OTLP_ENDPOINT")
-        _manager = ObservabilityManager(prometheus_port, otlp_endpoint)
+        _manager = ObservabilityManager(prometheus_port)
         _manager.start()
     return _manager
 

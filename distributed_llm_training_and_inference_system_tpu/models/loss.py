@@ -58,6 +58,7 @@ def next_token_loss(
     return cross_entropy(shift_logits, shift_targets, weights, z_loss_weight)
 
 
+@jax.named_scope("chunked_loss")
 def chunked_next_token_loss(
     hidden: jax.Array,           # [B, S, H] final-normed hidden (bf16 ok)
     unembed_w: jax.Array,        # [V, H] (tied embedding) or [H, V] (head)

@@ -149,33 +149,35 @@ def _fwd(q, k, v, q_segments, kv_segments, q_positions, kv_positions,
     kernel = functools.partial(_fwd_kernel, causal=causal, block_q=bq,
                                scale=scale)
 
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((None, 1, bk), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((None, 1, bk), lambda b, i, j: (b, 0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(q, k, v, q_segments, kv_segments, q_positions, kv_positions)
+    with jax.named_scope("flash_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
+                pl.BlockSpec((None, 1, bk), lambda b, i, j: (b, 0, j)),
+                pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
+                pl.BlockSpec((None, 1, bk), lambda b, i, j: (b, 0, j)),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((None, bq, 1), lambda b, i, j: (b, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((BH, S, D), q.dtype),
+                jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+            ],
+            interpret=_interpret(),
+            name="flash_fwd",
+        )(q, k, v, q_segments, kv_segments, q_positions, kv_positions)
     return out, lse
 
 
@@ -265,58 +267,62 @@ def _bwd_impl(q, k, v, q_segments, kv_segments, q_positions, kv_positions,
     bk = _fit_block(block_k, Skv)
     do = do.astype(q.dtype)
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, block_q=bq,
-                          scale=scale),
-        grid=(BH, S // bq, Skv // bk),
-        in_specs=[
-            pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((None, 1, bk), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((None, 1, bk), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=_interpret(),
-    )(q, k, v, q_segments, kv_segments, q_positions, kv_positions, do, lse,
-      delta)
+    with jax.named_scope("flash_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, causal=causal, block_q=bq,
+                              scale=scale),
+            grid=(BH, S // bq, Skv // bk),
+            in_specs=[
+                pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
+                pl.BlockSpec((None, 1, bk), lambda b, i, j: (b, 0, j)),
+                pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
+                pl.BlockSpec((None, 1, bk), lambda b, i, j: (b, 0, j)),
+                pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((None, bq, 1), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((None, bq, 1), lambda b, i, j: (b, i, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            interpret=_interpret(),
+            name="flash_bwd_dq",
+        )(q, k, v, q_segments, kv_segments, q_positions, kv_positions, do, lse,
+          delta)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, block_q=bq,
-                          scale=scale),
-        grid=(BH, Skv // bk, S // bq),
-        in_specs=[
-            pl.BlockSpec((None, bq, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((None, 1, bq), lambda b, j, i: (b, 0, i)),
-            pl.BlockSpec((None, 1, bk), lambda b, j, i: (b, 0, j)),
-            pl.BlockSpec((None, 1, bq), lambda b, j, i: (b, 0, i)),
-            pl.BlockSpec((None, 1, bk), lambda b, j, i: (b, 0, j)),
-            pl.BlockSpec((None, bq, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((None, bq, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((None, bq, 1), lambda b, j, i: (b, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Skv, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, Skv, D), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
-        interpret=_interpret(),
-    )(q, k, v, q_segments, kv_segments, q_positions, kv_positions, do, lse,
-      delta)
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, causal=causal, block_q=bq,
+                              scale=scale),
+            grid=(BH, Skv // bk, S // bq),
+            in_specs=[
+                pl.BlockSpec((None, bq, D), lambda b, j, i: (b, i, 0)),
+                pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((None, 1, bq), lambda b, j, i: (b, 0, i)),
+                pl.BlockSpec((None, 1, bk), lambda b, j, i: (b, 0, j)),
+                pl.BlockSpec((None, 1, bq), lambda b, j, i: (b, 0, i)),
+                pl.BlockSpec((None, 1, bk), lambda b, j, i: (b, 0, j)),
+                pl.BlockSpec((None, bq, D), lambda b, j, i: (b, i, 0)),
+                pl.BlockSpec((None, bq, 1), lambda b, j, i: (b, i, 0)),
+                pl.BlockSpec((None, bq, 1), lambda b, j, i: (b, i, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((BH, Skv, D), k.dtype),
+                jax.ShapeDtypeStruct((BH, Skv, D), v.dtype),
+            ],
+            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                            pltpu.VMEM((bk, D), jnp.float32)],
+            interpret=_interpret(),
+            name="flash_bwd_dkv",
+        )(q, k, v, q_segments, kv_segments, q_positions, kv_positions, do, lse,
+          delta)
 
     return dq, dk, dv
 

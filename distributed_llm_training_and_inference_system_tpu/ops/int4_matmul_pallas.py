@@ -108,17 +108,19 @@ def matmul_w4(x: jax.Array, packed: jax.Array, scale: jax.Array,
     xe, xo = xf[:, 0::2], xf[:, 1::2]              # [Bp, in/2]
 
     n_groups = n_in // group
-    out = pl.pallas_call(
-        _make_kernel(wdtype),
-        grid=(n_out // bo,),
-        in_specs=[
-            pl.BlockSpec((Bp, n_in // 2), lambda i: (0, 0)),
-            pl.BlockSpec((Bp, n_in // 2), lambda i: (0, 0)),
-            pl.BlockSpec((n_in // 2, bo), lambda i: (0, i)),
-            pl.BlockSpec((n_groups, bo), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((Bp, bo), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((Bp, n_out), jnp.float32),
-        interpret=interpret,
-    )(xe, xo, packed, scale)
+    with jax.named_scope("int4_matmul"):
+        out = pl.pallas_call(
+            _make_kernel(wdtype),
+            grid=(n_out // bo,),
+            in_specs=[
+                pl.BlockSpec((Bp, n_in // 2), lambda i: (0, 0)),
+                pl.BlockSpec((Bp, n_in // 2), lambda i: (0, 0)),
+                pl.BlockSpec((n_in // 2, bo), lambda i: (0, i)),
+                pl.BlockSpec((n_groups, bo), lambda i: (0, i)),
+            ],
+            out_specs=pl.BlockSpec((Bp, bo), lambda i: (0, i)),
+            out_shape=jax.ShapeDtypeStruct((Bp, n_out), jnp.float32),
+            interpret=interpret,
+            name="int4_matmul",
+        )(xe, xo, packed, scale)
     return out[:B].astype(x.dtype)

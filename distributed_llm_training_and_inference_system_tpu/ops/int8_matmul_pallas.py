@@ -140,28 +140,32 @@ def matmul_w8(x: jax.Array, values: jax.Array, scale: jax.Array,
     if (auto_tile and bo < 512 and n_in * 512 > budget and bk
             and bo_k > bo):
         bo = bo_k
-        out = pl.pallas_call(
-            _make_ksplit_kernel(wdtype),
-            grid=(n_out // bo, n_in // bk),
-            in_specs=[
-                pl.BlockSpec((Bp, bk), lambda i, j: (0, j)),
-                pl.BlockSpec((bk, bo), lambda i, j: (j, i)),
-            ],
-            out_specs=pl.BlockSpec((Bp, bo), lambda i, j: (0, i)),
-            out_shape=jax.ShapeDtypeStruct((Bp, n_out), jnp.float32),
-            interpret=interpret,
-        )(xf, values)
+        with jax.named_scope("int8_matmul"):
+            out = pl.pallas_call(
+                _make_ksplit_kernel(wdtype),
+                grid=(n_out // bo, n_in // bk),
+                in_specs=[
+                    pl.BlockSpec((Bp, bk), lambda i, j: (0, j)),
+                    pl.BlockSpec((bk, bo), lambda i, j: (j, i)),
+                ],
+                out_specs=pl.BlockSpec((Bp, bo), lambda i, j: (0, i)),
+                out_shape=jax.ShapeDtypeStruct((Bp, n_out), jnp.float32),
+                interpret=interpret,
+                name="int8_matmul",
+            )(xf, values)
         return out[:B].astype(x.dtype)
 
-    out = pl.pallas_call(
-        _make_kernel(wdtype),
-        grid=(n_out // bo,),
-        in_specs=[
-            pl.BlockSpec((Bp, n_in), lambda i: (0, 0)),
-            pl.BlockSpec((n_in, bo), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((Bp, bo), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((Bp, n_out), jnp.float32),
-        interpret=interpret,
-    )(xf, values)
+    with jax.named_scope("int8_matmul"):
+        out = pl.pallas_call(
+            _make_kernel(wdtype),
+            grid=(n_out // bo,),
+            in_specs=[
+                pl.BlockSpec((Bp, n_in), lambda i: (0, 0)),
+                pl.BlockSpec((n_in, bo), lambda i: (0, i)),
+            ],
+            out_specs=pl.BlockSpec((Bp, bo), lambda i: (0, i)),
+            out_shape=jax.ShapeDtypeStruct((Bp, n_out), jnp.float32),
+            interpret=interpret,
+            name="int8_matmul",
+        )(xf, values)
     return out[:B].astype(x.dtype)

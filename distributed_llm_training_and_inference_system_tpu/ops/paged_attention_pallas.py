@@ -244,14 +244,19 @@ def paged_attention_pallas_multi(
         ],
     )
 
-    out = pl.pallas_call(
-        functools.partial(_extend_kernel, page_size=PS, scale=scale,
-                          groups=groups, window=tile, num_kv=Nkv,
-                          kv_quant=kv_quant),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Nkv, T * groups, D), q.dtype),
-        interpret=interpret,
-    )(tables, starts, *inputs)
+    # one query a sequence is the decode step; a window is the
+    # speculative verify or a suffix prefill
+    name = "paged_attention" if T_in == 1 else "paged_attention_mq"
+    with jax.named_scope(name):
+        out = pl.pallas_call(
+            functools.partial(_extend_kernel, page_size=PS, scale=scale,
+                              groups=groups, window=tile, num_kv=Nkv,
+                              kv_quant=kv_quant),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, Nkv, T * groups, D), q.dtype),
+            interpret=interpret,
+            name=name,
+        )(tables, starts, *inputs)
     return out.reshape(B, Nkv, T, groups, D).transpose(0, 2, 1, 3, 4).reshape(
         B, T, Nq, D)[:, :T_in]
 

@@ -36,15 +36,17 @@ def rms_norm_pallas(x: jax.Array, scale: jax.Array, eps: float = 1e-5,
     x2 = x.reshape(rows, H)
     br = min(block_rows, rows)
     grid = (pl.cdiv(rows, br),)
-    out = pl.pallas_call(
-        functools.partial(_kernel, eps=eps),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, H), lambda i: (i, 0)),
-            pl.BlockSpec((H,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((br, H), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, H), x.dtype),
-        interpret=jax.default_backend() != "tpu",
-    )(x2, scale)
+    with jax.named_scope("rmsnorm"):
+        out = pl.pallas_call(
+            functools.partial(_kernel, eps=eps),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((br, H), lambda i: (i, 0)),
+                pl.BlockSpec((H,), lambda i: (0,)),
+            ],
+            out_specs=pl.BlockSpec((br, H), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((rows, H), x.dtype),
+            interpret=jax.default_backend() != "tpu",
+            name="rmsnorm",
+        )(x2, scale)
     return out.reshape(orig_shape)

@@ -13,6 +13,7 @@ import functools
 from typing import Any, Callable, Optional
 
 import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config.schema import ModelConfig, OptimizerConfig, ParallelConfig
@@ -96,6 +97,7 @@ class ShardedTrainer:
         else:
             self._batch_spec_fn = functools.partial(batch_specs, mesh=self.mesh)
         self.state: Optional[TrainState] = None
+        self._steps_dispatched = 0
 
     # -- state ---------------------------------------------------------------
 
@@ -129,8 +131,16 @@ class ShardedTrainer:
 
     def step(self, batch: Any):
         assert self.state is not None, "call init_state() first"
-        with use_mesh(self.mesh):
-            self.state, metrics = self.train_step(self.state, self.shard_batch(batch))
+        # host spans on the profiler's clock (`llmctl trace summarize` reads
+        # them); step_num is the trainer's own count of dispatched steps
+        self._steps_dispatched += 1
+        with StepTraceAnnotation("llmctl.train.step",
+                                 step_num=self._steps_dispatched), \
+                use_mesh(self.mesh):
+            with TraceAnnotation("llmctl.train.shard_batch"):
+                batch = self.shard_batch(batch)
+            with TraceAnnotation("llmctl.train.dispatch"):
+                self.state, metrics = self.train_step(self.state, batch)
         return metrics
 
     def evaluate(self, batch: Any):

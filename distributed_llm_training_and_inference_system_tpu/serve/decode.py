@@ -192,20 +192,21 @@ def extend_step_forward(
         q = apply_rope(q, positions, inv_freq)
         k = apply_rope(k, positions, inv_freq)
 
-        if use_window_write:
-            # page-granular write (2B whole-page DMAs) instead of a
-            # B*T-row scatter — the r2-measured verify-window suspect;
-            # A/B via LLMCTL_EXTEND_WRITE=paged|scatter (default paged;
-            # QuantPages quantize-on-write inside the same merge)
-            kp = write_window_to_pages(kp, k, block_tables,
-                                       start_positions, write_ok)
-            vp = write_window_to_pages(vp, v, block_tables,
-                                       start_positions, write_ok)
-        else:
-            kp = write_token_to_pages(kp, k.reshape(B * T, Nkv, D),
-                                      flat_tables, flat_pos, flat_ok)
-            vp = write_token_to_pages(vp, v.reshape(B * T, Nkv, D),
-                                      flat_tables, flat_pos, flat_ok)
+        with jax.named_scope("kv_page_write"):
+            if use_window_write:
+                # page-granular write (2B whole-page DMAs) instead of a
+                # B*T-row scatter — the r2-measured verify-window suspect;
+                # A/B via LLMCTL_EXTEND_WRITE=paged|scatter (default paged;
+                # QuantPages quantize-on-write inside the same merge)
+                kp = write_window_to_pages(kp, k, block_tables,
+                                           start_positions, write_ok)
+                vp = write_window_to_pages(vp, v, block_tables,
+                                           start_positions, write_ok)
+            else:
+                kp = write_token_to_pages(kp, k.reshape(B * T, Nkv, D),
+                                          flat_tables, flat_pos, flat_ok)
+                vp = write_token_to_pages(vp, v.reshape(B * T, Nkv, D),
+                                          flat_tables, flat_pos, flat_ok)
         attn = paged_attention_multi(q, kp, vp, block_tables,
                                      start_positions, impl=attn_impl)
         attn = attn.reshape(B, T, Nq * D)

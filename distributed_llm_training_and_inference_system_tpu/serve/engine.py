@@ -24,7 +24,6 @@ known upgrade path).
 
 from __future__ import annotations
 
-import functools
 import logging
 import math
 import threading
@@ -44,6 +43,7 @@ from .sampling import sample_tokens
 from .scheduler import (ContinuousBatchingScheduler, Request, RequestState,
                         SamplingParams)
 from ..analysis.annotations import engine_thread_only
+from ..metrics.spans import SpanRecorder
 
 logger = logging.getLogger("llmctl.serve.engine")
 
@@ -213,6 +213,9 @@ class InferenceEngine:
         # guards scheduler/kv bookkeeping shared with the serving thread;
         # NEVER held across device compute (prefill/decode dispatch)
         self.lock = threading.Lock()
+        # where the engine thread's time goes (llmctl.engine.* spans) and
+        # how long the device had nothing to run; read through stats()
+        self.spans = SpanRecorder()
         # fired (from the engine thread) whenever a request leaves its slot
         self.on_finish: Optional[Callable[[Request], None]] = None
         # fired (engine thread) with each batch of newly accepted tokens for
@@ -328,12 +331,14 @@ class InferenceEngine:
         # The admission lookahead derives from units * unit_len, so page
         # reservation tracks the actual group length.
         self._decode_units = -(-K // L) if L > 0 else 1
+        # jitted from the bound methods, so that a profile shows the
+        # programs as jit__decode_impl_n and jit__spec_impl (a
+        # functools.partial has no name: jit__unknown)
         self._decode_jit = _Program(
-            f"decode x{self._decode_unit_len}",
-            functools.partial(self._decode_impl_n, self._decode_unit_len),
+            "_decode_impl_n", self._decode_impl_n,
             self.failed_programs, donate_argnums=(1, 2))
         self.total_short_dispatches = 0
-        self._spec_jit = (_Program("speculative verify", self._spec_impl,
+        self._spec_jit = (_Program("_spec_impl", self._spec_impl,
                                    self.failed_programs,
                                    donate_argnums=(1, 2))
                           if serve_cfg.speculative == "ngram" else None)
@@ -845,6 +850,17 @@ class InferenceEngine:
             covered + k, getattr(req, "prefix_owner", None))
 
     @engine_thread_only
+    def _seed_slot(self, slot: int, seed: int):
+        """The slot's sampling key, its data copied into ``_slot_keys``. The
+        copy is a fetch from the device, which serves it after whatever it
+        is running (a pipelined dispatch): a device wait, so it has a span
+        of its own and is no part of the host's."""
+        slot_key = jax.random.PRNGKey(seed)
+        with self.spans.phase("llmctl.engine.prefill.key_wait"):
+            self._slot_keys[slot] = np.asarray(jax.random.key_data(slot_key))
+        return slot_key
+
+    @engine_thread_only
     def _start_chunked_prefill(self, req: Request) -> None:
         """Allocate the slot's pages and enqueue the context for chunk-at-a-
         time prefill (one chunk per engine step, interleaved with decode)."""
@@ -871,12 +887,12 @@ class InferenceEngine:
                 self._base_seed + self._admitted_counter)
         self._admitted_counter += 1
         self._slot_seq[slot] = self._admitted_counter
-        slot_key = jax.random.PRNGKey(req.assigned_seed)
-        self._slot_keys[slot] = np.asarray(jax.random.key_data(slot_key))
+        slot_key = self._seed_slot(slot, req.assigned_seed)
         cached = len(pins) * self.kv.page_size
         self.total_prefix_cached_tokens += cached
         if req.prefill_dispatch_time is None:
             req.prefill_dispatch_time = time.monotonic()
+        self.spans.annotate(cached=cached)
         self._partial_prefills[rid] = {
             "req": req, "ctx": ctx, "done": cached, "pins": len(pins),
             "table_row": table_row, "slot_key": slot_key}
@@ -958,6 +974,7 @@ class InferenceEngine:
                     self._extend_prefill_fn(bucket)(
                         *common, first_key, jnp.float32(s.temperature),
                         jnp.int32(s.top_k), jnp.float32(s.top_p))
+                self.spans.dispatched()
                 if self.serve_cfg.prefix_caching and req.prefix_hashes:
                     with self.lock:
                         table = self.kv.block_tables[req.slot]
@@ -1075,8 +1092,7 @@ class InferenceEngine:
                 self._base_seed + self._admitted_counter)
         self._admitted_counter += 1
         self._slot_seq[slot] = self._admitted_counter  # preemption priority
-        slot_key = jax.random.PRNGKey(req.assigned_seed)
-        self._slot_keys[slot] = np.asarray(jax.random.key_data(slot_key))
+        slot_key = self._seed_slot(slot, req.assigned_seed)
         first_key = jax.random.fold_in(slot_key, n)
         # first prefill only: a preemption RESUME must not restamp these —
         # TTFT is arrival->FIRST token, and the resume bucket is a suffix
@@ -1115,6 +1131,8 @@ class InferenceEngine:
                     jnp.float32(s.temperature), jnp.int32(s.top_k),
                     jnp.float32(s.top_p))
             self.total_prefix_cached_tokens += cached
+        self.spans.dispatched()
+        self.spans.annotate(bucket=bucket, cached=cached)
 
         # publish this prompt's freshly-written full pages for future hits
         if self.serve_cfg.prefix_caching and req.prefix_hashes:
@@ -1175,24 +1193,30 @@ class InferenceEngine:
     def _finish_prefill(self, req: Request, token) -> None:
         """Resolve a dispatched prefill: fetch its first token and make the
         slot live for decode."""
-        ctx = req.context_tokens       # BEFORE recording the new token
-        n = len(ctx)
-        req.record_token(int(token))
-        if self.on_token is not None:
-            self.on_token(req, [int(token)])
-        self._arm_slot(req, int(token), n, ctx + [int(token)])
+        with self.spans.phase("llmctl.engine.prefill.wait",
+                              request_id=req.request_id):
+            token = int(token)
+        self.spans.fetched()
+        with self.spans.phase("llmctl.engine.apply"):
+            ctx = req.context_tokens   # BEFORE recording the new token
+            n = len(ctx)
+            req.record_token(token)
+            if self.on_token is not None:
+                with self.spans.phase("llmctl.engine.deliver"):
+                    self.on_token(req, [token])
+            self._arm_slot(req, token, n, ctx + [token])
 
     # -- decode --------------------------------------------------------------
 
-    def _decode_impl_n(self, num_steps, params, k_pages, v_pages, tokens,
-                       positions, tables, stops, slot_keys, temp, top_k,
-                       top_p):
+    def _decode_impl_n(self, params, k_pages, v_pages, tokens, positions,
+                       tables, stops, slot_keys, temp, top_k, top_p):
+        # _decode_unit_len steps: fixed at construction, so ONE program
         # the final scan carry (tokens, positions) comes back as DEVICE
         # arrays so a pipelined follow-up dispatch can chain on them
         # without a host round trip (step() pipelining below)
         (toks, pos, k_pages, v_pages), toks_seq = decode_scan(
             params, tokens, positions, k_pages, v_pages, tables, stops,
-            slot_keys, temp, top_k, top_p, self.cfg, num_steps,
+            slot_keys, temp, top_k, top_p, self.cfg, self._decode_unit_len,
             attn_impl=self._attn_impl, write_mode=self._extend_write,
             w4_kernel_ok=self._w4_kernel_ok,
             w8_kernel_ok=self._w8_kernel_ok)
@@ -1297,10 +1321,13 @@ class InferenceEngine:
         pipelined path exactly like units chain onto units."""
         units = []
         pend = chain_from
-        shared = self._shared_decode_args()
-        for _ in range(n_units):
-            pend = self._submit_decode(chain_from=pend, shared=shared)
-            units.append(pend)
+        with self.spans.phase("llmctl.engine.decode.submit", units=n_units,
+                              active=int(self.active.sum())):
+            shared = self._shared_decode_args()
+            for _ in range(n_units):
+                pend = self._submit_decode(chain_from=pend, shared=shared)
+                units.append(pend)
+        self.spans.dispatched()
         return {
             "units": units,
             "next_tokens": units[-1]["next_tokens"],
@@ -1315,7 +1342,11 @@ class InferenceEngine:
         [n_units * unit_len, B]. jax.device_get issues the per-unit
         transfers together, so the link round trip is paid once per
         group, not per unit."""
-        arrs = jax.device_get([u["sampled"] for u in group["units"]])
+        with self.spans.phase(
+                "llmctl.engine.decode.wait",
+                steps=len(group["units"]) * self._decode_unit_len):
+            arrs = jax.device_get([u["sampled"] for u in group["units"]])
+        self.spans.fetched()
         out = np.concatenate([np.asarray(a) for a in arrs], axis=0)
         self.total_decode_steps += out.shape[0]
         self.total_padded_slot_steps += out.shape[0] * int(
@@ -1332,7 +1363,7 @@ class InferenceEngine:
         if prev is None:
             return
         sampled = self._fetch_group(prev)
-        with self.lock:
+        with self.spans.phase("llmctl.engine.apply"), self.lock:
             self._apply_decode(sampled, snapshot=prev)
             self.scheduler.step_finished(self.eos_token_id)
 
@@ -1375,56 +1406,61 @@ class InferenceEngine:
         lookup over each slot's prompt+generated context), then verify +
         K-1 decode steps on device. Returns (emitted [B, T], n_emit [B],
         decode_seq [K-1, B])."""
-        T = max(self.serve_cfg.speculative_tokens, 2)
-        B = self.serve_cfg.max_batch_size
-        tokens = np.zeros((B, T), np.int32)
-        tokens[:, 0] = self.last_tokens
-        # draftless rows repeat the last token — acceptance is self-
-        # verifying (draft == argmax), so a lucky repeat is correct greedy
-        # output, not an error
-        tokens[:, 1:] = self.last_tokens[:, None]
-        from .speculative import propose_ngram_draft
-        n_drafted = 0
-        for slot, req in enumerate(self.scheduler.slots):
-            if req is None or not self.active[slot] \
-                    or self.temperature[slot] > 0:
-                continue
-            # per-slot ADAPTIVE window (SpecState): only w-1 drafts are
-            # proposed and counted for this row; positions [w, T) keep
-            # the repeat-last fallback (the compiled program's T is
-            # static — the window bounds proposal work and the
-            # acceptance statistics, not the dispatch shape). Every
-            # greedy row counts its window's drafts (ngram or the
-            # repeat fallback) — counting only ngram rows would let
-            # fallback acceptances push spec_acceptance above 1.0.
-            st = self._spec_state[slot]
-            w = min(st.window, T) if st is not None else T
-            n_drafted += w - 1
-            # bounded lookback keeps proposal O(window), not O(context)
-            ctx = self._ctx[slot, max(self._ctx_len[slot] - 1024, 0):
-                            self._ctx_len[slot]]
-            # draft_fn is injectable (benchmarks dial acceptance exactly
-            # via oracle/corrupted drafts — experiments/spec_crossover.py);
-            # production default is the prompt-lookup proposer
-            draft_fn = getattr(self, "draft_fn", None)
-            if draft_fn is not None:
-                draft = draft_fn(ctx, w - 1,
-                                 self.serve_cfg.speculative_ngram)
-            else:
-                draft = propose_ngram_draft(
-                    ctx, w - 1, self.serve_cfg.speculative_ngram)
-            if draft is not None:
-                tokens[slot, 1:w] = draft
-        emitted, n_emit, decode_seq, self.kv.k_pages, self.kv.v_pages = \
-            self._spec_jit(
-                self.params, self.kv.k_pages, self.kv.v_pages,
-                jnp.asarray(tokens), jnp.asarray(self.positions),
-                jnp.asarray(self.kv.block_tables),
-                jnp.asarray(self.stop_positions),
-                jnp.asarray(self._slot_keys), jnp.asarray(self.temperature),
-                jnp.asarray(self.top_k), jnp.asarray(self.top_p))
-        emitted, n_emit = np.asarray(emitted), np.asarray(n_emit)
-        decode_seq = np.asarray(decode_seq)
+        with self.spans.phase("llmctl.engine.decode.submit", units=1,
+                              active=int(self.active.sum())):
+            T = max(self.serve_cfg.speculative_tokens, 2)
+            B = self.serve_cfg.max_batch_size
+            tokens = np.zeros((B, T), np.int32)
+            tokens[:, 0] = self.last_tokens
+            # draftless rows repeat the last token — acceptance is self-
+            # verifying (draft == argmax), so a lucky repeat is correct greedy
+            # output, not an error
+            tokens[:, 1:] = self.last_tokens[:, None]
+            from .speculative import propose_ngram_draft
+            n_drafted = 0
+            for slot, req in enumerate(self.scheduler.slots):
+                if req is None or not self.active[slot] \
+                        or self.temperature[slot] > 0:
+                    continue
+                # per-slot ADAPTIVE window (SpecState): only w-1 drafts are
+                # proposed and counted for this row; positions [w, T) keep
+                # the repeat-last fallback (the compiled program's T is
+                # static — the window bounds proposal work and the
+                # acceptance statistics, not the dispatch shape). Every
+                # greedy row counts its window's drafts (ngram or the
+                # repeat fallback) — counting only ngram rows would let
+                # fallback acceptances push spec_acceptance above 1.0.
+                st = self._spec_state[slot]
+                w = min(st.window, T) if st is not None else T
+                n_drafted += w - 1
+                # bounded lookback keeps proposal O(window), not O(context)
+                ctx = self._ctx[slot, max(self._ctx_len[slot] - 1024, 0):
+                                self._ctx_len[slot]]
+                # draft_fn is injectable (benchmarks dial acceptance exactly
+                # via oracle/corrupted drafts — experiments/spec_crossover.py);
+                # production default is the prompt-lookup proposer
+                draft_fn = getattr(self, "draft_fn", None)
+                if draft_fn is not None:
+                    draft = draft_fn(ctx, w - 1,
+                                     self.serve_cfg.speculative_ngram)
+                else:
+                    draft = propose_ngram_draft(
+                        ctx, w - 1, self.serve_cfg.speculative_ngram)
+                if draft is not None:
+                    tokens[slot, 1:w] = draft
+            emitted, n_emit, decode_seq, self.kv.k_pages, self.kv.v_pages = \
+                self._spec_jit(
+                    self.params, self.kv.k_pages, self.kv.v_pages,
+                    jnp.asarray(tokens), jnp.asarray(self.positions),
+                    jnp.asarray(self.kv.block_tables),
+                    jnp.asarray(self.stop_positions),
+                    jnp.asarray(self._slot_keys), jnp.asarray(self.temperature),
+                    jnp.asarray(self.top_k), jnp.asarray(self.top_p))
+        self.spans.dispatched()
+        with self.spans.phase("llmctl.engine.decode.wait"):
+            emitted, n_emit = np.asarray(emitted), np.asarray(n_emit)
+            decode_seq = np.asarray(decode_seq)
+        self.spans.fetched()
         self.total_spec_dispatches += 1
         self.total_spec_drafts += n_drafted
         self.total_decode_steps += 1 + decode_seq.shape[0]
@@ -1474,7 +1510,8 @@ class InferenceEngine:
                     # state that migrates with the sequence
                     st.observe(acc, w - 1, max_window=T)
             if accepted and self.on_token is not None:
-                self.on_token(req, accepted)
+                with self.spans.phase("llmctl.engine.deliver"):
+                    self.on_token(req, accepted)
 
     @engine_thread_only
     def _apply_decode(self, sampled_seq: np.ndarray,
@@ -1514,7 +1551,8 @@ class InferenceEngine:
             self._ctx[slot, self._ctx_len[slot]:end] = accepted
             self._ctx_len[slot] = end
             if accepted and self.on_token is not None:
-                self.on_token(req, accepted)
+                with self.spans.phase("llmctl.engine.deliver"):
+                    self.on_token(req, accepted)
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -1587,8 +1625,7 @@ class InferenceEngine:
             self._req_slot[rid] = slot
         self._admitted_counter += 1
         self._slot_seq[slot] = self._admitted_counter
-        slot_key = jax.random.PRNGKey(req.assigned_seed)
-        self._slot_keys[slot] = np.asarray(jax.random.key_data(slot_key))
+        slot_key = self._seed_slot(slot, req.assigned_seed)
         # migrated speculative state rides the payload manifest (the
         # courier-aware half: a handed-off/migrated sequence resumes
         # with its tuned window, not a cold proposer); _arm_slot reads
@@ -1714,7 +1751,10 @@ class InferenceEngine:
             self.stop_positions[slot] = 0
             self._spec_state[slot] = None
         if self.on_finish is not None:
-            self.on_finish(req)
+            # an HTTP handler's cancel gets here too: there the span is a
+            # bare annotation (SpanRecorder.phase)
+            with self.spans.phase("llmctl.engine.deliver"):
+                self.on_finish(req)
 
     @engine_thread_only
     def step(self) -> int:
@@ -1726,7 +1766,8 @@ class InferenceEngine:
         cheap scheduler/page bookkeeping is serialized.
         """
         static = self.serve_cfg.scheduler == "static"
-        with self.lock:
+        spans = self.spans
+        with spans.phase("llmctl.engine.admit"), self.lock:
             if static:
                 # static batches form only when fully drained — there are no
                 # resident streams to protect, so no prefill budget applies
@@ -1735,6 +1776,9 @@ class InferenceEngine:
             else:
                 admitted = self.scheduler.admit(
                     self.serve_cfg.prefill_budget_tokens)
+            spans.set_busy(self.scheduler.active_count > 0)
+            if admitted:
+                spans.annotate(admitted=len(admitted))
         C = self.serve_cfg.chunked_prefill_tokens
         pending = []
         for req in admitted:
@@ -1745,7 +1789,9 @@ class InferenceEngine:
                 # PARTIAL payloads (crash-salvaged migration pre-copies)
                 # are not decode-resumable — they take the _prefill path,
                 # which writes the covered pages and computes the tail.
-                if self._restore_swapped(req):
+                with spans.phase("llmctl.engine.capacity"):
+                    restored = self._restore_swapped(req)
+                if restored:
                     continue
                 req.swapped_kv = None
             # route on the full re-prefill CONTEXT: a preempted request
@@ -1760,16 +1806,24 @@ class InferenceEngine:
                     and req.swapped_kv is None) \
                     or (req.pipeline_stage is not None
                         and req.swapped_kv is None):
-                self._start_chunked_prefill(req)
+                start = self._start_chunked_prefill
             else:
-                pending.append(self._prefill(req))
+                start = self._prefill
+            with spans.phase("llmctl.engine.prefill.host",
+                             request_id=req.request_id,
+                             tokens=len(req.context_tokens)):
+                dispatched = start(req)
+            if dispatched is not None:
+                pending.append(dispatched)
         # advance every in-flight chunked prefill by one chunk; completed
         # ones join this step's finish batch
-        pending += self._advance_chunked_prefills()
+        if self._partial_prefills:
+            with spans.phase("llmctl.engine.prefill.host"):
+                pending += self._advance_chunked_prefills()
         for req, token in pending:
             self._finish_prefill(req, token)
         if pending:
-            with self.lock:
+            with spans.phase("llmctl.engine.apply"), self.lock:
                 # prompt-is-whole-request edge: finished on the first token
                 self.scheduler.step_finished(self.eos_token_id)
             if self.on_prefill_complete is not None:
@@ -1780,12 +1834,17 @@ class InferenceEngine:
                 # single decode dispatch on it
                 for req, _tok in pending:
                     if req.state is RequestState.RUNNING:
-                        self.on_prefill_complete(req)
-        with self.lock:
+                        with spans.phase("llmctl.engine.deliver",
+                                         request_id=req.request_id):
+                            self.on_prefill_complete(req)
+        with spans.phase("llmctl.engine.capacity"), self.lock:
             # on-demand admission: make sure every active slot has pages
             # for one dispatch of writes, preempting newest-first if the
             # pool is dry — BEFORE the dispatch reads the block tables
+            preempted = self.total_preemptions
             self._ensure_decode_capacity()
+            if self.total_preemptions > preempted:
+                spans.annotate(preempted=self.total_preemptions - preempted)
             # latency-adaptive dispatch decision (needs the lock: it
             # inspects the queue head's admissibility)
             use_short = self._short_dispatch_ok()
@@ -1816,7 +1875,7 @@ class InferenceEngine:
                 # state, so it must catch up first
                 self._drain_pending()
                 emitted, n_emit, decode_seq = self._spec_device()
-                with self.lock:
+                with spans.phase("llmctl.engine.apply"), self.lock:
                     self._apply_speculative(emitted, n_emit, decode_seq)
                     self.scheduler.step_finished(self.eos_token_id)
             elif (self.serve_cfg.pipelined_decode and not static
@@ -1848,7 +1907,7 @@ class InferenceEngine:
                     self._decode_units, chain_from=prev)
                 if prev is not None:
                     sampled = self._fetch_group(prev)
-                    with self.lock:
+                    with spans.phase("llmctl.engine.apply"), self.lock:
                         self._apply_decode(sampled, snapshot=prev)
                         self.scheduler.step_finished(self.eos_token_id)
             else:
@@ -1857,11 +1916,13 @@ class InferenceEngine:
                 # don't burn a dispatch on an all-inactive batch
                 if any(self.active):
                     sampled = self._decode_device(use_short)
-                    with self.lock:
+                    with spans.phase("llmctl.engine.apply"), self.lock:
                         self._apply_decode(sampled)
                         self.scheduler.step_finished(self.eos_token_id)
         with self.lock:
-            return self.scheduler.active_count
+            active = self.scheduler.active_count
+        spans.set_busy(active > 0)
+        return active
 
     def fail_all(self, error: str) -> None:
         """Fail every queued and resident request (engine-thread crash path);
@@ -1871,6 +1932,7 @@ class InferenceEngine:
             # in-flight pipelined dispatch references the failed slots'
             # state; its results must never be applied
             self._pending = None
+            self.spans.reset_in_flight()
             # fail_all released every slot (incl. PREFILLING); advancing a
             # stale chunked prefill would write into freed pages
             self._partial_prefills.clear()
@@ -1987,6 +2049,7 @@ class InferenceEngine:
         return out
 
     def run_until_idle(self, max_steps: int = 100_000) -> None:
+        self.spans.bind_thread()
         for _ in range(max_steps):
             if self.step() == 0 and self.scheduler.queue_depth == 0:
                 return
@@ -2042,6 +2105,9 @@ class InferenceEngine:
             "spec_acceptance": round(
                 self.total_spec_accepted / max(self.total_spec_drafts, 1), 4),
             "compiled_programs": self.compiled_programs(),
+            # cumulative, with their own clock: "clock_s", "phases"
+            # ({span: {"s": self seconds, "n": calls}}), "starved_s"
+            **self.spans.snapshot(),
         }
 
     def compiled_programs(self) -> dict:

@@ -319,6 +319,7 @@ class EngineReplica:
     def _loop(self) -> None:
         logger.info("replica %d engine thread started", self.replica_id)
         eng = self.engine
+        eng.spans.bind_thread()
         while not self._stop.is_set():
             if self._drain_requested.is_set():
                 self._drain_on_thread()

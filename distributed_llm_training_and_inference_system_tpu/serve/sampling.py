@@ -184,6 +184,7 @@ def _filtered_fast_or_exact(scaled: jax.Array, top_k: jax.Array,
         None)
 
 
+@jax.named_scope("sample_tokens")
 def sample_tokens(
     logits: jax.Array,       # [B, V] fp32
     keys: jax.Array,         # [B] PRNG keys (uint32[2] each)

@@ -19,6 +19,8 @@ from dataclasses import dataclass, field
 from enum import Enum
 from typing import Callable, Optional
 
+from ..metrics.spans import QueueWaitHistogram
+
 
 class RequestState(str, Enum):
     QUEUED = "queued"
@@ -210,6 +212,9 @@ class ContinuousBatchingScheduler:
         self.total_admitted = 0
         self.total_finished = 0
         self.total_rejected = 0
+        # time each admitted request waited for its slot (a preempted
+        # request counts again, from its arrival)
+        self.queue_wait_ms = QueueWaitHistogram()
 
     # -- admission ----------------------------------------------------------
 
@@ -343,6 +348,8 @@ class ContinuousBatchingScheduler:
             if req.swapped_kv is None:
                 spent += len(req.context_tokens)
             self.total_admitted += 1
+            self.queue_wait_ms.observe(
+                (time.monotonic() - req.arrival_time) * 1e3)
         return admitted
 
     def preempt_slot(self, slot: int) -> Optional[Request]:
@@ -412,4 +419,5 @@ class ContinuousBatchingScheduler:
             "admitted": self.total_admitted,
             "finished": self.total_finished,
             "rejected": self.total_rejected,
+            "queue_wait_ms": self.queue_wait_ms.snapshot(),
         }

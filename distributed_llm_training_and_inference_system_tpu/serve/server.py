@@ -134,13 +134,16 @@ class InferenceServer:
 
     def _engine_loop(self) -> None:
         logger.info("engine thread started")
+        spans = self.engine.spans
+        spans.bind_thread()
         while not self._stop.is_set():
             with self._lock:
                 busy = (self.engine.scheduler.queue_depth > 0
                         or self.engine.scheduler.active_count > 0)
             if not busy:
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
+                with spans.phase("llmctl.engine.idle"):
+                    self._wake.wait(timeout=0.05)
+                    self._wake.clear()
                 continue
             # step() does its own fine-grained locking; compute runs unlocked
             try:
@@ -373,6 +376,10 @@ class InferenceServer:
             "prompt_tokens": req.num_prompt_tokens,
             "tokens": len(req.generated_tokens),
             "queue_depth": st["queue_depth"],
+            # running totals, exported as they stand: the scheduler's
+            # histogram and the engine's spans, not this request's own
+            "queue_wait_ms": st["queue_wait_ms"],
+            "phases": st["phases"],
             "preemptions": st["preemptions"],
             "swap_ins": st["swap_ins"],
             "swapped_host_bytes": st["swapped_host_bytes"],
