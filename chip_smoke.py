@@ -443,6 +443,25 @@ def phase_serve(env: dict, device: dict) -> None:
                   "paged_attention_multi", "rms_norm"), on_tpu)
     secs, n = compile_seconds(text)
     say(f"  serve: {n} programs compiled in {secs:.1f}s")
+    # the server is gone and the chip free: one more child builds the same
+    # engine and reads what the compiler holds beside the decode program's
+    # arguments. The pools are donated and updated in place, so a pool-
+    # sized temporary is a copy that came back
+    text = run_child("decode_memory",
+                     [sys.executable, str(ROOT / "chip_smoke.py")],
+                     {**env, PHASE_ENV: "decode_memory",
+                      "CHIP_SMOKE_MODEL": model}, timeout=900)
+    [mem] = smoke_records(text, "decode_memory")
+    say(f"  decode program ({mem['program']}, {mem['steps']} steps, "
+        f"{mem['slots']} slots): temp {mem['temp_bytes'] / 1e6:.1f} MB, "
+        f"arguments {mem['argument_bytes'] / 1e6:.1f} MB (aliased to "
+        f"results: {mem['alias_bytes'] / 1e6:.1f} MB); the K pool is "
+        f"{mem['k_pool_bytes'] / 1e6:.1f} MB, one layer of it "
+        f"{mem['k_pool_bytes'] / mem['layers'] / 1e6:.1f} MB")
+    # (gpt-test's whole pool is smaller than its logits: not judged)
+    if mem["temp_bytes"] >= mem["k_pool_bytes"] and not REHEARSAL:
+        raise SmokeFailure("the decode program holds a pool-sized "
+                           "temporary: the KV pool is copied again")
 
 
 _STEP_RE = re.compile(r"step (\d+) \| loss ([0-9.naninf-]+) \|.*?"
@@ -755,16 +774,34 @@ def child_kernels() -> None:
     layouts = ({"mha4": (4, 4), "gqa4x2": (4, 2)} if small
                else {"mha16": (16, 16), "gqa32x8": (32, 8)})
 
+    # the kernel gets the pools as the serve programs carry them, stacked
+    # [LAYERS, NP, Nkv, PS, D], with a traced non-zero layer index; the
+    # gather reference gets that layer's own [NP, Nkv, PS, D] pages
+    LAYERS, LAYER = 3, 2
+
     def pages(nkv, slots, kv):
-        kp = jax.random.normal(next(key), (slots * MAXP + 1, nkv, PS, D),
-                               jnp.bfloat16)
+        kp = jax.random.normal(
+            next(key), (LAYERS, slots * MAXP + 1, nkv, PS, D), jnp.bfloat16)
         vp = jax.random.normal(next(key), kp.shape, jnp.bfloat16)
         if kv == "int8":
-            # [NP, Nkv, PS, D] -> per-token rows, as the engine writes them
+            # [L, NP, Nkv, PS, D] -> per-token rows, as the engine writes them
             kp, vp = (QuantPages(*quantize_kv_token(p)) for p in (kp, vp))
         tables = 1 + jax.random.permutation(
             next(key), slots * MAXP).reshape(slots, MAXP).astype(jnp.int32)
         return kp, vp, tables
+
+    def one_layer(pool):
+        return jax.tree_util.tree_map(lambda a: a[LAYER], pool)
+
+    def kernel_and_gather(fn):
+        """(the Pallas kernel on the stacked pool at LAYER, the gather
+        baseline on that layer's pages), both jitted."""
+        kernel = jax.jit(functools.partial(fn, impl="pallas"))
+        gather = jax.jit(functools.partial(fn, impl="gather"))
+        return (lambda q, kp, vp, *at: kernel(q, kp, vp, *at,
+                                              layer=jnp.int32(LAYER)),
+                lambda q, kp, vp, *at: gather(q, one_layer(kp),
+                                              one_layer(vp), *at))
 
     # -- paged attention, single query -------------------------------------
     B = 2 if small else 8
@@ -773,10 +810,10 @@ def child_kernels() -> None:
             kp, vp, tables = pages(nkv, B, kv)
             q = jax.random.normal(next(key), (B, nq, D), jnp.bfloat16)
             lengths = jax.random.randint(next(key), (B,), 1, MAXP * PS + 1)
-            run = lambda impl: jax.jit(functools.partial(
-                paged_attention, impl=impl))(q, kp, vp, tables, lengths)
+            kernel, gather = kernel_and_gather(paged_attention)
             check(f"paged_attention decode {lname} {kv}-pages",
-                  run("pallas"), run("gather"))
+                  kernel(q, kp, vp, tables, lengths),
+                  gather(q, kp, vp, tables, lengths))
 
     # -- paged attention, multi query --------------------------------------
     windows = ([(8, "mha4", "bf16"), (32, "mha4", "bf16"),
@@ -792,13 +829,11 @@ def child_kernels() -> None:
         q = jax.random.normal(next(key), (B, T, nq, D), jnp.bfloat16)
         starts = jax.random.randint(next(key), (B,), 0,
                                     MAXP * PS - T).astype(jnp.int32)
-        got = jax.jit(functools.partial(paged_attention_multi, impl="pallas"))(
-            q, kp, vp, tables, starts)
+        kernel, gather = kernel_and_gather(paged_attention_multi)
+        got = kernel(q, kp, vp, tables, starts)
         # the gather reference re-materialises the whole prefix per query
         # row: feed it the window in slices of 64 rows
         step = min(T, 64)
-        gather = jax.jit(functools.partial(paged_attention_multi,
-                                           impl="gather"))
         ref = jnp.concatenate([
             gather(q[:, j:j + step], kp, vp, tables, starts + j)
             for j in range(0, T, step)], axis=1)
@@ -988,6 +1023,42 @@ def child_mesh_train() -> None:
         sys.exit(1)
 
 
+def child_decode_memory() -> None:
+    """Lower and compile the engine's own decode function with its own
+    arguments (the server child compiled the same program a moment ago:
+    a read of the compile cache) and report its memory analysis."""
+    child_setup()
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llm_training_and_inference_system_tpu.config.presets import (
+        get_model_config)
+    from distributed_llm_training_and_inference_system_tpu.config.schema import (
+        ServeConfig)
+    from distributed_llm_training_and_inference_system_tpu.serve.engine import (
+        InferenceEngine)
+    name = os.environ["CHIP_SMOKE_MODEL"]
+    model = get_model_config(name)
+    # what `serve start --model <name>` builds in phase_serve
+    engine = InferenceEngine(model, ServeConfig(
+        model=name, max_seq_len=min(2048, model.max_position_embeddings),
+        **({"kv_block_size": 16, "dtype": "float32"} if REHEARSAL else {})))
+    program = engine._decode_jit
+    mem = program.lower(
+        engine.params, engine.kv.k_pages, engine.kv.v_pages,
+        jnp.asarray(engine.last_tokens), jnp.asarray(engine.positions),
+        *engine._shared_decode_args()).compile().memory_analysis()
+    emit("decode_memory", {
+        "program": program.name, "layers": model.num_layers,
+        "steps": engine.serve_cfg.decode_steps_per_dispatch,
+        "slots": engine.serve_cfg.max_batch_size,
+        "temp_bytes": mem.temp_size_in_bytes,
+        "argument_bytes": mem.argument_size_in_bytes,
+        "alias_bytes": mem.alias_size_in_bytes,
+        "k_pool_bytes": sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(engine.kv.k_pages))})
+
+
 def child_replicas() -> None:
     device = child_setup()
     import jax
@@ -1079,7 +1150,8 @@ def child_tp_exact() -> None:
 
 
 CHILDREN = {"kernels": child_kernels, "mesh_train": child_mesh_train,
-            "tp_exact": child_tp_exact, "replicas": child_replicas}
+            "tp_exact": child_tp_exact, "replicas": child_replicas,
+            "decode_memory": child_decode_memory}
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,12 @@ during generation, so every decode step recomputes the full prefix
 is the real thing: KV lives in fixed-size pages in HBM, each sequence owns a
 block table of page indices, and decode attends through the table.
 
-Layout (per layer): pages [num_pages, Nkv, page_size, D]. Static shapes
+Layout: pages [L, num_pages, Nkv, page_size, D], one pool for all layers.
+Every function here takes the pool WHOLE plus ``layer`` (a traced int32
+scalar) and addresses ``pages[layer, phys, ...]`` directly, so the serve
+programs carry the donated pools through their layer loop and update them
+in place: no layer's slab is ever sliced out or stacked back. ``layer=None``
+is the one-layer case: pages [num_pages, Nkv, page_size, D]. Static shapes
 throughout — the block table has a fixed ``max_pages_per_seq`` width and
 unused entries point at the reserved scratch page 0, so XLA compiles one
 program regardless of how many sequences or tokens are live (SURVEY §7.3.2:
@@ -28,6 +33,12 @@ from ..models.layers import NEG_INF
 
 from ..utils.platform import kernel_impl, report_impl
 from .quantization import QuantTensor
+
+
+def _at(layer, *index):
+    """The index of ``pages[layer, *index]`` in an [L, NP, ...] pool, or of
+    ``pages[*index]`` in one layer's [NP, ...] pages (``layer`` None)."""
+    return index if layer is None else (layer, *index)
 
 
 def _resolve_impl(op: str, impl: str, q: jax.Array) -> tuple[str, bool]:
@@ -145,12 +156,13 @@ def quantize_kv_token_int4(new_kv: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 def paged_attention(
     q: jax.Array,            # [B, Nq, D] — one query token per sequence
-    k_pages: jax.Array,      # [NP, Nkv, PS, D]
-    v_pages: jax.Array,      # [NP, Nkv, PS, D]
+    k_pages: jax.Array,      # [L, NP, Nkv, PS, D] ([NP, ...] if layer is None)
+    v_pages: jax.Array,
     block_tables: jax.Array, # [B, maxP] int32 physical page ids
     lengths: jax.Array,      # [B] int32 — tokens already in cache INCLUDING
                              #   the current one (i.e. attend to [0, lengths))
     impl: str = "auto",      # auto | pallas | gather
+    layer=None,              # int32 scalar: which layer's pages to read
 ) -> jax.Array:
     """Decode attention: each row attends over its paged KV prefix.
 
@@ -166,17 +178,20 @@ def paged_attention(
         from .paged_attention_pallas import paged_attention_pallas
         return paged_attention_pallas(
             q, k_pages, v_pages, block_tables, lengths,
-            interpret=interpret)
-    return _gather_attention(q, k_pages, v_pages, block_tables, lengths)
+            layer=layer, interpret=interpret)
+    return _gather_attention(q, k_pages, v_pages, block_tables, lengths,
+                             layer)
 
 
-def _gather_attention(q, k_pages, v_pages, block_tables, lengths):
+def _gather_attention(q, k_pages, v_pages, block_tables, lengths,
+                      layer=None):
     """The portable baseline: materialise each row's [Nkv, maxP*PS, D]
     prefix through the block table, then plain masked attention."""
     B, Nq, D = q.shape
-    NP, Nkv, PS, _ = k_pages.shape
+    Nkv, PS = k_pages.shape[-3:-1]
     maxP = block_tables.shape[1]
     groups = Nq // Nkv
+    idx = _at(layer, block_tables)      # one gather: pages[layer, table]
 
     def gather(pages):
         # [B, maxP, Nkv, PS, D] -> [B, Nkv, Lmax, D]; quantized pages
@@ -184,14 +199,14 @@ def _gather_attention(q, k_pages, v_pages, block_tables, lengths):
         # anyway). Int4Pages unpack along the page-slot axis first.
         if isinstance(pages, Int4Pages):
             from .quantization import unpack_int4_rows
-            vals = unpack_int4_rows(pages.values[block_tables], axis=-2)
+            vals = unpack_int4_rows(pages.values[idx], axis=-2)
             g = (vals.astype(jnp.float32)
-                 * pages.scale[block_tables][..., None]).astype(q.dtype)
+                 * pages.scale[idx][..., None]).astype(q.dtype)
         elif isinstance(pages, QuantPages):
-            g = (pages.values[block_tables].astype(jnp.float32)
-                 * pages.scale[block_tables][..., None]).astype(q.dtype)
+            g = (pages.values[idx].astype(jnp.float32)
+                 * pages.scale[idx][..., None]).astype(q.dtype)
         else:
-            g = pages[block_tables]
+            g = pages[idx]
         return g.transpose(0, 2, 1, 3, 4).reshape(B, Nkv, maxP * PS, D)
 
     k = gather(k_pages)
@@ -213,21 +228,27 @@ def _gather_attention(q, k_pages, v_pages, block_tables, lengths):
 
 
 def write_window_to_pages(
-    pages: jax.Array,          # [NP, Nkv, PS, D]
+    pages: jax.Array,          # [L, NP, Nkv, PS, D] ([NP, ...] if no layer)
     new_kv: jax.Array,         # [B, T, Nkv, D] — T consecutive tokens/slot
     block_tables: jax.Array,   # [B, maxP]
     start_positions: jax.Array,  # [B] int32 — position of new_kv[:, 0]
     write_ok: jax.Array = None,  # [B, T] bool
+    layer=None,                # int32 scalar: which layer's pages to write
 ) -> jax.Array:
     """Page-granular window write: the whole-page alternative to T
     row-scatters (``write_token_to_pages`` over B*T rows).
 
-    A slot's T consecutive tokens (T <= PS) span at most two physical
-    pages. This gathers those 2B pages, merges the window in registers
-    (one-hot select over the 2*PS staging positions), and scatters 2B
-    WHOLE pages back — regular page-sized DMAs instead of a B*T-row
-    scatter with duplicate page indices, the round-2-measured suspect in
-    the speculative verify window's ~9-decode-step cost (BASELINE.md).
+    A slot's T consecutive tokens span at most n = (T + 2 PS - 2) // PS
+    physical pages (two for a verify window). This gathers those n*B
+    pages out of the pool, merges the window in registers (one-hot select
+    over the n*PS staging positions), and scatters n*B WHOLE pages back —
+    regular page-sized DMAs that leave the pool in the layout the Pallas
+    kernel reads. The B*T-row scatter does not: compiled for the v5e it
+    makes XLA keep the carried pool slot-major and copy it WHOLE to the
+    kernel's layout and back in every layer (3.8 GB of temporaries in the
+    decode program at the benchmark's shapes, PERF.md 6, PR 26), which is
+    why windows longer than a page (suffix and chunked prefill) take this
+    route too since the pools ride the layer loop.
     A/B-select via LLMCTL_EXTEND_WRITE=paged|scatter (default paged);
     numerics asserted equal to the scatter path in
     tests/test_ops.py::test_window_write_matches_row_scatter.
@@ -253,28 +274,25 @@ def write_window_to_pages(
     B, T, Nkv, D = new_kv.shape
     # logical page geometry (Int4Pages.shape reports the UNPACKED slot
     # count; its values buffer holds PS/2 bytes along that axis)
-    NP, _, PS, _ = pages.shape
+    PS = pages.shape[-2]
     maxP = block_tables.shape[1]
-    if T > PS:
-        raise ValueError(f"window {T} exceeds page size {PS}")
-    # T == 1 never crosses a page boundary: one staging page per slot
-    # (the second page would be gathered and rewritten byte-identical —
-    # pure no-op DMA on the hottest per-step path)
-    n_stage = 1 if T == 1 else 2
+    # T consecutive tokens starting anywhere in a page touch at most this
+    # many pages: 1 for T == 1 (it never crosses a boundary — a second
+    # page would be gathered and rewritten byte-identical on the hottest
+    # per-step path), 2 up to T == PS + 1 (verify windows), 5 and 9 for
+    # the 256- and 512-token suffix / chunked-prefill buckets at PS 64
+    n_stage = (T + 2 * PS - 2) // PS
     offs = jnp.arange(T, dtype=jnp.int32)
     pos = start_positions[:, None] + offs                     # [B, T]
     p0 = jnp.clip(start_positions // PS, 0, maxP - 1)         # [B]
-    if n_stage == 1:
-        lp = p0[:, None]                                      # [B, 1]
-        phys = jnp.take_along_axis(block_tables, lp, axis=1)
-    else:
-        lp = jnp.stack([p0, jnp.clip(p0 + 1, 0, maxP - 1)], 1)  # [B, 2]
-        phys = jnp.take_along_axis(block_tables, lp, axis=1)    # [B, 2]
-        # duplicate-page edge (window entirely in the last logical page):
-        # the second staging half would rewrite the SAME page with stale
-        # content — redirect it to scratch instead
-        phys = phys.at[:, 1].set(jnp.where(lp[:, 1] == lp[:, 0], 0,
-                                           phys[:, 1]))
+    lp = jnp.clip(p0[:, None] + jnp.arange(n_stage, dtype=jnp.int32),
+                  0, maxP - 1)                                # [B, n]
+    phys = jnp.take_along_axis(block_tables, lp, axis=1)      # [B, n]
+    # duplicate-page edge (window ending in the last logical page): a
+    # staging page clipped onto the one before it would rewrite the SAME
+    # page with stale content — redirect it to scratch instead
+    phys = phys.at[:, 1:].set(jnp.where(lp[:, 1:] == lp[:, :-1], 0,
+                                        phys[:, 1:]))
 
     off = pos - p0[:, None] * PS                       # [B,T] in [0,n*PS)
     ok = jnp.ones((B, T), bool) if write_ok is None else write_ok
@@ -284,7 +302,9 @@ def write_window_to_pages(
     onehot = (off[:, :, None] == jnp.arange(n_stage * PS)[None, None]) \
         & ok[:, :, None]                                      # [B,T,nPS]
     hit = onehot.any(axis=1)                                  # [B, nPS]
-    flat_phys = phys.reshape(-1)
+    # the staging pages are read from, and written back into, the pool
+    # itself: pages[layer, phys] -> merge -> pages.at[layer, phys]
+    stage, back = _at(layer, phys), _at(layer, phys.reshape(-1))
 
     def merge_rows(staging, rows, dtype):
         """Select window rows into their staging positions: staging
@@ -312,33 +332,34 @@ def write_window_to_pages(
         # (asserted in tests/test_int4_kv.py).
         from .quantization import pack_int4_rows, unpack_int4_rows
         qv, qs = quantize_kv_token_int4(new_kv)  # [B,T,Nkv,D] i8, [B,T,Nkv]
-        staging = unpack_int4_rows(pages.values[phys], axis=-2)
+        staging = unpack_int4_rows(pages.values[stage], axis=-2)
         merged_v = merge_rows(staging, qv, jnp.int8)      # [B*n,Nkv,PS,D]
         packed_v = pack_int4_rows(merged_v, axis=-2)
-        merged_s = merge_rows(pages.scale[phys][..., None], qs[..., None],
+        merged_s = merge_rows(pages.scale[stage][..., None], qs[..., None],
                               jnp.float32)[..., 0]        # [B*n,Nkv,PS]
-        return Int4Pages(pages.values.at[flat_phys].set(packed_v),
-                         pages.scale.at[flat_phys].set(merged_s))
+        return Int4Pages(pages.values.at[back].set(packed_v),
+                         pages.scale.at[back].set(merged_s))
     if quant:
         # fused quantize-on-write: one absmax pass over the window's rows,
         # then values and scales ride the same whole-page merge
         qv, qs = quantize_kv_token(new_kv)     # [B,T,Nkv,D] i8, [B,T,Nkv]
-        merged_v = merge_rows(pages.values[phys], qv, jnp.int8)
-        merged_s = merge_rows(pages.scale[phys][..., None], qs[..., None],
+        merged_v = merge_rows(pages.values[stage], qv, jnp.int8)
+        merged_s = merge_rows(pages.scale[stage][..., None], qs[..., None],
                               jnp.float32)[..., 0]        # [B*n,Nkv,PS]
-        return QuantPages(pages.values.at[flat_phys].set(merged_v),
-                          pages.scale.at[flat_phys].set(merged_s))
-    merged = merge_rows(pages[phys], new_kv.astype(pages.dtype), pages.dtype)
-    return pages.at[flat_phys].set(merged)
+        return QuantPages(pages.values.at[back].set(merged_v),
+                          pages.scale.at[back].set(merged_s))
+    merged = merge_rows(pages[stage], new_kv.astype(pages.dtype), pages.dtype)
+    return pages.at[back].set(merged)
 
 
 def paged_attention_multi(
     q: jax.Array,              # [B, T, Nq, D] — T consecutive tokens/slot
-    k_pages: jax.Array,        # [NP, Nkv, PS, D]
+    k_pages: jax.Array,        # [L, NP, Nkv, PS, D] ([NP, ...] if no layer)
     v_pages: jax.Array,
     block_tables: jax.Array,   # [B, maxP]
     start_positions: jax.Array,  # [B] int32 — position of q[:, 0]
     impl: str = "auto",
+    layer=None,                # int32 scalar: which layer's pages to read
 ) -> jax.Array:
     """Multi-query paged attention: query j of slot b attends causally over
     [0, start_b + j] through the pages (the window's own K/V must already
@@ -361,28 +382,29 @@ def paged_attention_multi(
         from .paged_attention_pallas import paged_attention_pallas_multi
         return paged_attention_pallas_multi(
             q, k_pages, v_pages, block_tables, start_positions,
-            interpret=interpret)
+            layer=layer, interpret=interpret)
     flat_pos = (start_positions[:, None]
                 + jnp.arange(T, dtype=jnp.int32)).reshape(B * T)
     out = _gather_attention(
         q.reshape(B * T, Nq, D), k_pages, v_pages,
-        jnp.repeat(block_tables, T, axis=0), flat_pos + 1)
+        jnp.repeat(block_tables, T, axis=0), flat_pos + 1, layer)
     return out.reshape(B, T, Nq, D)
 
 
 def write_token_to_pages(
-    pages: jax.Array,        # [NP, Nkv, PS, D]
+    pages: jax.Array,        # [L, NP, Nkv, PS, D] ([NP, ...] if no layer)
     new_kv: jax.Array,       # [B, Nkv, D] — this step's K or V
     block_tables: jax.Array, # [B, maxP]
     positions: jax.Array,    # [B] int32 — slot-local position to write
     active: jax.Array = None,  # [B] bool — rows past their stop write scratch
+    layer=None,              # int32 scalar: which layer's pages to write
 ) -> jax.Array:
     """Scatter one token per sequence into its page. Rows whose table entry
     is the scratch page (0) — or whose ``active`` mask is False (multi-step
     decode continuing past a row's token budget) — harmlessly overwrite
     scratch page 0 instead of corrupting pages beyond the block table.
     ``QuantPages`` get the token quantized per (row, head) on the way in."""
-    page_size = pages.shape[2]
+    page_size = pages.shape[-2]
     maxP = block_tables.shape[1]
     logical_page = jnp.clip(positions // page_size, 0, maxP - 1)
     offset = positions % page_size
@@ -390,6 +412,7 @@ def write_token_to_pages(
                                axis=1)[:, 0]                         # [B]
     if active is not None:
         phys = jnp.where(active, phys, 0)
+    row = _at(layer, phys, slice(None), offset)   # one token row per slot
     if isinstance(pages, Int4Pages):
         # two tokens share a byte along the page-slot axis, so a single-
         # token write is a read-modify-write of its byte column: fetch
@@ -399,16 +422,16 @@ def write_token_to_pages(
         qv, scale = quantize_kv_token_int4(new_kv)        # [B,Nkv,D] i8
         nib = (qv & 0xF).astype(jnp.uint8)
         byte = offset // 2
-        cur = pages.values[phys, :, byte]                 # [B,Nkv,D] u8
+        col = _at(layer, phys, slice(None), byte)
+        cur = pages.values[col]                           # [B,Nkv,D] u8
         is_lo = (offset % 2 == 0)[:, None, None]
         new = jnp.where(is_lo, (cur & 0xF0) | nib,
                         (cur & 0x0F) | (nib << 4)).astype(jnp.uint8)
         return Int4Pages(
-            pages.values.at[phys, :, byte].set(new),
-            pages.scale.at[phys, :, offset].set(scale))
+            pages.values.at[col].set(new),
+            pages.scale.at[row].set(scale))
     if isinstance(pages, QuantPages):
         qv, scale = quantize_kv_token(new_kv)
-        return QuantPages(
-            pages.values.at[phys, :, offset].set(qv),
-            pages.scale.at[phys, :, offset].set(scale))
-    return pages.at[phys, :, offset].set(new_kv.astype(pages.dtype))
+        return QuantPages(pages.values.at[row].set(qv),
+                          pages.scale.at[row].set(scale))
+    return pages.at[row].set(new_kv.astype(pages.dtype))

@@ -5,9 +5,10 @@ The gather baseline (ops/paged_attention.py) materialises every slot's full
 token regardless of the sequence's actual length. This kernel reads only the
 pages a sequence owns:
 
-- Grid (B, query tiles, maxP), page index innermost. The page arrays stay in HBM; each
-  grid step's BlockSpec uses the scalar-prefetched block table to DMA one
-  physical page — ALL kv heads, [Nkv, PS, D] — into VMEM
+- Grid (B, query tiles, maxP), page index innermost. The pools
+  [L, NP, Nkv, PS, D] come in WHOLE and stay in HBM; each grid step's
+  BlockSpec uses the scalar-prefetched layer index and block table to DMA
+  one physical page of that layer — ALL kv heads, [Nkv, PS, D] — into VMEM
   (``PrefetchScalarGridSpec`` — the pallas_guide.md pattern for
   data-dependent addressing). Pallas double-buffers the copies,
   overlapping page DMA with compute. Heads are folded into one dot pair
@@ -44,7 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..models.layers import NEG_INF
 
 
-def _extend_kernel(tables_ref, starts_ref,        # scalar prefetch
+def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
                    *refs,                          # see unpack below
                    page_size: int, scale: float, groups: int,
                    window: int, num_kv: int, kv_quant: str):
@@ -75,6 +76,7 @@ def _extend_kernel(tables_ref, starts_ref,        # scalar prefetch
     VMEM right before the fp32 dot, so HBM page traffic is halved
     (int8) or quartered (int4) — the whole point of the quantized KV
     cache."""
+    # (layer_ref is for the index maps alone: they pick the layer's page)
     if kv_quant != "none":
         (q_ref, k_ref, ks_ref, v_ref, vs_ref,
          o_ref, acc_ref, m_ref, l_ref) = refs
@@ -166,20 +168,26 @@ def _query_tile(T: int, Nq: int, Nkv: int, PS: int) -> int:
 
 def paged_attention_pallas_multi(
     q: jax.Array,              # [B, T, Nq, D] — T consecutive tokens/slot
-    k_pages: jax.Array,        # [NP, Nkv, PS, D]
+    k_pages: jax.Array,        # [L, NP, Nkv, PS, D] ([NP, ...] if no layer)
     v_pages: jax.Array,
     block_tables: jax.Array,   # [B, maxP] int32
     start_positions: jax.Array,  # [B] int32 — position of q[:, 0]
     *,
+    layer=None,                # int32 scalar: which layer's pages to read
     interpret: bool = False,
 ) -> jax.Array:
     """Returns [B, T, Nq, D]; query j attends over [0, start+j] via pages
-    (the window's own K/V must already be written to the pages)."""
+    (the window's own K/V must already be written to the pages).
+
+    The pools are operands as they are, never a layer's slice of them: the
+    layer rides the scalar prefetch beside the block table and the index
+    maps address ``pages[layer, page]``. One layer's [NP, ...] pages with
+    ``layer=None`` are the L = 1 pool."""
     from .paged_attention import Int4Pages, QuantPages
     kv_quant = ("int4" if isinstance(k_pages, Int4Pages)
                 else "int8" if isinstance(k_pages, QuantPages) else "none")
     B, T_in, Nq, D = q.shape
-    NP, Nkv, PS, _ = k_pages.shape
+    Nkv, PS = k_pages.shape[-3:-1]
     maxP = block_tables.shape[1]
     groups = Nq // Nkv
     scale = 1.0 / float(D) ** 0.5
@@ -200,12 +208,12 @@ def paged_attention_pallas_multi(
     starts = start_positions.astype(jnp.int32)
     tables = block_tables.astype(jnp.int32)
 
-    def page_of(b, t, p, tbl, st):
+    def page_of(b, t, p, tbl, st, ly):
         # pages past the tile's live length are CLAMPED to its last used
         # page: consecutive identical block indices elide the DMA
         last_used = jnp.maximum((st[b] + (t + 1) * tile + PS - 1) // PS - 1,
                                 0)
-        return tbl[b, jnp.minimum(p, last_used)]
+        return ly[0], tbl[b, jnp.minimum(p, last_used)]
 
     # head-folded grid (B, tiles, maxP): one whole page (all kv heads)
     # per step. The scale tile [Nkv, PS] rides the SAME clamped
@@ -215,27 +223,27 @@ def paged_attention_pallas_multi(
     # bytes per page.
     page_rows = PS // 2 if kv_quant == "int4" else PS
     page_spec = pl.BlockSpec(
-        (None, Nkv, page_rows, D),
-        lambda b, t, p, tbl, st: (page_of(b, t, p, tbl, st), 0, 0, 0))
+        (None, None, Nkv, page_rows, D),
+        lambda *grid_and_scalars: (*page_of(*grid_and_scalars), 0, 0, 0))
     scale_spec = pl.BlockSpec(
-        (None, Nkv, PS),
-        lambda b, t, p, tbl, st: (page_of(b, t, p, tbl, st), 0, 0))
+        (None, None, Nkv, PS),
+        lambda *grid_and_scalars: (*page_of(*grid_and_scalars), 0, 0))
     q_spec = pl.BlockSpec((None, Nkv, tile * groups, D),
-                          lambda b, t, p, tbl, st: (b, 0, t, 0))
-    in_specs = [q_spec]
-    inputs = [qg]
+                          lambda b, t, p, tbl, st, ly: (b, 0, t, 0))
     if kv_quant != "none":
-        in_specs += [page_spec, scale_spec, page_spec, scale_spec]
-        inputs += [k_pages.values, k_pages.scale,
-                   v_pages.values, v_pages.scale]
+        in_specs = [page_spec, scale_spec, page_spec, scale_spec]
+        pools = [k_pages.values, k_pages.scale,
+                 v_pages.values, v_pages.scale]
     else:
-        in_specs += [page_spec, page_spec]
-        inputs += [k_pages, v_pages]
+        in_specs = [page_spec, page_spec]
+        pools = [k_pages, v_pages]
+    if layer is None:
+        pools, layer = [a[None] for a in pools], 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,       # tables, starts
+        num_scalar_prefetch=3,       # tables, starts, layer
         grid=(B, n_tiles, maxP),
-        in_specs=in_specs,
+        in_specs=[q_spec, *in_specs],
         out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((Nkv * tile * groups, D), jnp.float32),
@@ -256,18 +264,20 @@ def paged_attention_pallas_multi(
             out_shape=jax.ShapeDtypeStruct((B, Nkv, T * groups, D), q.dtype),
             interpret=interpret,
             name=name,
-        )(tables, starts, *inputs)
+        )(tables, starts, jnp.asarray(layer, jnp.int32).reshape(1),
+          qg, *pools)
     return out.reshape(B, Nkv, T, groups, D).transpose(0, 2, 1, 3, 4).reshape(
         B, T, Nq, D)[:, :T_in]
 
 
 def paged_attention_pallas(
     q: jax.Array,            # [B, Nq, D] — one query token per sequence
-    k_pages: jax.Array,      # [NP, Nkv, PS, D]
+    k_pages: jax.Array,      # [L, NP, Nkv, PS, D] ([NP, ...] if no layer)
     v_pages: jax.Array,
     block_tables: jax.Array, # [B, maxP] int32 physical page ids
     lengths: jax.Array,      # [B] int32 — attend over [0, lengths)
     *,
+    layer=None,
     interpret: bool = False,
 ) -> jax.Array:
     """Returns [B, Nq, D] in q.dtype; same contract as the gather baseline.
@@ -278,5 +288,5 @@ def paged_attention_pallas(
     """
     out = paged_attention_pallas_multi(
         q[:, None], k_pages, v_pages, block_tables,
-        lengths.astype(jnp.int32) - 1, interpret=interpret)
+        lengths.astype(jnp.int32) - 1, layer=layer, interpret=interpret)
     return out[:, 0]

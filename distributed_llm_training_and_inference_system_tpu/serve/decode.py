@@ -49,9 +49,11 @@ def decode_step_forward(
 
     The T=1 case of ``extend_step_forward`` (one layer-body implementation
     for both, so the paths can never diverge numerically). The new token's
-    K/V are written into the pages *inside* the traced function (page
-    arrays should be donated by the jit wrapper so XLA updates them in
-    place in HBM).
+    K/V are written into the pages *inside* the traced function. The
+    returned pools ARE the argument buffers when the jit wrapper donates
+    them (the engine does): the layer loop carries them and writes by
+    layer index, and the compiled program holds no pool-sized temporary
+    (tests/test_tpu_compile.py::test_decode_program_updates_pool_in_place).
     """
     write_ok = None if active is None else active[:, None]
     logits, new_k, new_v = extend_step_forward(
@@ -74,8 +76,10 @@ def extend_step_forward(
                               # tensor-parallel engine forces "gather" (the
                               # Pallas kernel is opaque to GSPMD and would
                               # be replicated, gathering all pages per chip)
-    write_mode: str = "paged",  # "paged" (2B whole-page DMAs) | "scatter"
-                              # (B*T row scatter). A traced constant: the
+    write_mode: str = "paged",  # "paged" (whole-page merge, every T) |
+                              # "scatter" (B*T row scatter, every T: an
+                              # A/B arm, see use_window_write below). A
+                              # traced constant: the
                               # caller fixes it at program-build time (the
                               # engine reads LLMCTL_EXTEND_WRITE once at
                               # construction) — reading env HERE would
@@ -94,11 +98,14 @@ def extend_step_forward(
 
     Token j sits at position ``start_positions + j`` and attends causally
     over the paged prefix *including* earlier tokens of this same call: all
-    T tokens' K/V are scattered into the pages first, then attention runs
-    with per-query length ``start + j + 1``. This one primitive powers both
-    speculative-decode verification (serve/speculative.py: score K draft
-    tokens in one weight-streaming pass — decode is HBM-bound on weights,
-    so T<=8 tokens cost nearly the same as 1) and cached-prefix suffix
+    T tokens' K/V are written into the pages first, then attention runs
+    with per-query length ``start + j + 1``. The pools are the layer
+    loop's carry, written and read at ``pages[layer, page]``: nothing
+    pool-sized is sliced out per layer or stacked back. This one
+    primitive powers both speculative-decode verification
+    (serve/speculative.py: score K draft tokens in one weight-streaming
+    pass — decode is HBM-bound on weights, so T<=8 tokens cost nearly
+    the same as 1) and cached-prefix suffix
     prefill (only the un-cached tail of a prompt is computed).
 
     Attention goes through ops.paged_attention_multi: on TPU the
@@ -114,14 +121,14 @@ def extend_step_forward(
     flat_pos = positions.reshape(B * T)
     flat_tables = jnp.repeat(block_tables, T, axis=0)        # [B*T, maxP]
     flat_ok = None if write_ok is None else write_ok.reshape(B * T)
-    # T == 1 (plain decode) included: the whole-page merge beat the B-row
-    # scatter by ~1 ms/step in the round-3 decode ablation once the
-    # folded attention kernel removed the larger overheads. QuantPages
-    # take the same route (round 6): quantize-on-write is fused into the
-    # whole-page merge, so int8/int4-KV decode no longer detours through
-    # the B*T-row scatter that dominated the 7B 16-slot wall
-    # (BASELINE.md:205-218).
-    use_window_write = (T <= k_pages.shape[-2] and write_mode != "scatter")
+    # Every T takes the whole-page merge (T == 1: one page a slot, 4 MB
+    # read and 4 MB written a layer and pool at 32 slots), QuantPages and
+    # Int4Pages with quantize-on-write fused into it. The row scatter is
+    # the slower arm on the chip now that the pools ride the layer loop:
+    # XLA lays a row-scattered pool out slot-major, the Pallas kernel
+    # reads it head-major, and the WHOLE pool is copied between the two in
+    # every layer (PERF.md 6, PR 26, has both step times).
+    use_window_write = write_mode != "scatter"
 
     x = params["embed"]["embedding"][tokens].astype(compute_dtype)  # [B,T,H]
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope.base,
@@ -168,8 +175,13 @@ def extend_step_forward(
             w = w.dequant(compute_dtype)
         return a @ w
 
-    def body(x, layer_and_pages):
-        layer, kp, vp = layer_and_pages
+    def body(carry, layer_and_index):
+        # the pools must stay a CARRY that every layer writes and reads
+        # by its index: as scanned inputs and stacked outputs XLA slices
+        # each layer's slab out (94 MB at mistral-7b, 715 pages), writes
+        # it back, and copies the step's fresh pool whole
+        x, kp, vp = carry
+        layer, li = layer_and_index
         # per-layer cast/dequant: quantized serving weights either stay
         # packed for the Pallas matmuls above (TPU) or materialise one
         # layer of bf16 at a time (ops.quantization)
@@ -194,21 +206,22 @@ def extend_step_forward(
 
         with jax.named_scope("kv_page_write"):
             if use_window_write:
-                # page-granular write (2B whole-page DMAs) instead of a
-                # B*T-row scatter — the r2-measured verify-window suspect;
-                # A/B via LLMCTL_EXTEND_WRITE=paged|scatter (default paged;
-                # QuantPages quantize-on-write inside the same merge)
+                # page-granular write (whole-page DMAs) instead of a
+                # B*T-row scatter; A/B via LLMCTL_EXTEND_WRITE=paged|scatter
+                # (default paged; QuantPages quantize-on-write inside the
+                # same merge)
                 kp = write_window_to_pages(kp, k, block_tables,
-                                           start_positions, write_ok)
+                                           start_positions, write_ok, li)
                 vp = write_window_to_pages(vp, v, block_tables,
-                                           start_positions, write_ok)
+                                           start_positions, write_ok, li)
             else:
                 kp = write_token_to_pages(kp, k.reshape(B * T, Nkv, D),
-                                          flat_tables, flat_pos, flat_ok)
+                                          flat_tables, flat_pos, flat_ok, li)
                 vp = write_token_to_pages(vp, v.reshape(B * T, Nkv, D),
-                                          flat_tables, flat_pos, flat_ok)
+                                          flat_tables, flat_pos, flat_ok, li)
         attn = paged_attention_multi(q, kp, vp, block_tables,
-                                     start_positions, impl=attn_impl)
+                                     start_positions, impl=attn_impl,
+                                     layer=li)
         attn = attn.reshape(B, T, Nq * D)
         x = x + mm(attn, layer["o"]["kernel"]).astype(x.dtype)
 
@@ -217,11 +230,12 @@ def extend_step_forward(
             ffn, _ = moe_block(h, layer["moe"], cfg)
         else:
             ffn = mlp_block(h, layer["mlp"], cfg, matmul=mm)
-        return x + ffn.astype(x.dtype), (kp, vp)
+        return (x + ffn.astype(x.dtype), kp, vp), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (precast_params(params["blocks"], compute_dtype),
-                  k_pages, v_pages))
+    (x, new_k, new_v), _ = jax.lax.scan(
+        body, (x, k_pages, v_pages),
+        (precast_params(params["blocks"], compute_dtype),
+         jnp.arange(cfg.num_layers, dtype=jnp.int32)))
 
     x = rms_norm(x, params["final_norm"]["scale"].astype(x.dtype), cfg.norm_eps)
     if cfg.tie_word_embeddings:

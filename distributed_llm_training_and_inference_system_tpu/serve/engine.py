@@ -66,6 +66,11 @@ class _Program:
         self._failed = failed
         self._ran = False
 
+    def lower(self, *args):
+        """The program lowered for ``args`` and not run: ``chip_smoke.py``
+        compiles the result to read the decode program's memory analysis."""
+        return self._fn.lower(*args)
+
     def __call__(self, *args):
         try:
             out = self._fn(*args)

@@ -401,14 +401,16 @@ def _warm(fleet):
 class TestInt4FleetIdentity:
     @pytest.mark.parametrize(
         "sampling",
-        [SamplingParams(temperature=0.0, max_tokens=40),
-         SamplingParams(temperature=0.8, seed=123, max_tokens=40)],
+        [SamplingParams(temperature=0.0, max_tokens=112),
+         SamplingParams(temperature=0.8, seed=123, max_tokens=112)],
         ids=["greedy", "seeded"])
     def test_drain_migration_chunk_chaos(self, model_cfg, params,
                                          sampling):
         """Mid-decode drain moves int4 payloads over the chaotic courier:
         zero re-prefill, token-identical to the undisturbed int4 engine,
-        no aborts."""
+        no aborts. (112 new tokens, 14 dispatches: at 40 the requests
+        were done ~35 ms after their second token, and a main thread
+        held up that long drained a replica with nothing to migrate.)"""
         ref = _ref_tokens(model_cfg, params, sampling)
         fleet = ServeFleet(
             model_cfg, _serve_cfg(),

@@ -356,3 +356,168 @@ def test_window_write_matches_row_scatter():
     got = write_window_to_pages(pages0, new_kv, tables, starts, ok)
     # scratch page 0 is garbage by contract on both paths — compare the rest
     np.testing.assert_array_equal(np.asarray(want)[1:], np.asarray(got)[1:])
+
+
+def _layered_pool(kv, key, L=3, NP=12, Nkv=2, PS=16, D=64):
+    """An [L, NP, Nkv, PS, D] pool of the given page type, random content."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (  # noqa: E501
+        Int4Pages, QuantPages, quantize_kv_token, quantize_kv_token_int4)
+    from distributed_llm_training_and_inference_system_tpu.ops.quantization import (  # noqa: E501
+        pack_int4_rows)
+    dense = jax.random.normal(key, (L, NP, Nkv, PS, D), jnp.float32)
+    if kv == "int8":
+        return QuantPages(*quantize_kv_token(dense))
+    if kv == "int4":
+        qv, sc = quantize_kv_token_int4(dense)
+        return Int4Pages(pack_int4_rows(qv, axis=-2), sc)
+    return dense.astype(jnp.bfloat16)
+
+
+def _layer_of(pool, l):
+    return jax.tree.map(lambda a: a[l], pool)
+
+
+def _assert_pages_equal(got, want, first_page=0):
+    """Bit for bit, leaf by leaf (values and scales of quantized pages)."""
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(
+            np.asarray(g).view(np.uint8)[first_page:],
+            np.asarray(w).view(np.uint8)[first_page:])
+
+
+_WINDOW_TABLES = [[1, 2, 3],      # window crosses a page boundary
+                  [4, 5, 0],      # short chain
+                  [0, 0, 0],      # inactive slot: scratch page only
+                  [6, 7, 8]]      # window inside the last logical page
+_WINDOW_STARTS = {1: [13, 16, 0, 40], 8: [13, 16, 0, 36]}
+
+
+@pytest.mark.parametrize("T", [1, 8])
+@pytest.mark.parametrize("kv", ["bf16", "int8", "int4"])
+def test_layer_indexed_pool_matches_per_layer(kv, T):
+    """The serve programs carry the WHOLE [L, NP, ...] pools and pass the
+    layer as a traced index. For every layer the indexed writes (whole-page
+    merge and row scatter) and the indexed attention (Pallas kernel in
+    interpret mode, and the gather path) must equal, bit for bit, the
+    per-layer call on ``pool[l]`` — the form every other test here and
+    the pre-carry layer scan used."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (  # noqa: E501
+        paged_attention_multi, write_token_to_pages, write_window_to_pages)
+
+    L, Nkv, Nq, D, B = 3, 2, 4, 64, 4
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    k_pool, v_pool = _layered_pool(kv, ks[0], L), _layered_pool(kv, ks[1], L)
+    new_kv = jax.random.normal(ks[2], (B, T, Nkv, D), jnp.float32)
+    q = jax.random.normal(ks[3], (B, T, Nq, D), jnp.float32)
+    tables = jnp.asarray(_WINDOW_TABLES, jnp.int32)
+    starts = jnp.asarray(_WINDOW_STARTS[T], jnp.int32)
+    ok = jax.random.uniform(ks[4], (B, T)) > 0.3
+    flat_pos = (starts[:, None] + jnp.arange(T)).reshape(-1)
+    flat_tab = jnp.repeat(tables, T, axis=0)
+    rows = new_kv.reshape(B * T, Nkv, D)
+
+    @jax.jit
+    def indexed(k_pool, v_pool, layer):       # layer is TRACED, as in scan
+        return (
+            write_window_to_pages(k_pool, new_kv, tables, starts, ok, layer),
+            write_token_to_pages(k_pool, rows, flat_tab, flat_pos,
+                                 ok.reshape(-1), layer),
+            paged_attention_multi(q, k_pool, v_pool, tables, starts,
+                                  impl="pallas", layer=layer),
+            paged_attention_multi(q, k_pool, v_pool, tables, starts,
+                                  impl="gather", layer=layer))
+
+    @jax.jit
+    def per_layer(kp, vp):
+        return (
+            write_window_to_pages(kp, new_kv, tables, starts, ok),
+            write_token_to_pages(kp, rows, flat_tab, flat_pos,
+                                 ok.reshape(-1)),
+            paged_attention_multi(q, kp, vp, tables, starts, impl="pallas"),
+            paged_attention_multi(q, kp, vp, tables, starts, impl="gather"))
+
+    for l in range(L):
+        got = indexed(k_pool, v_pool, jnp.int32(l))
+        want = per_layer(_layer_of(k_pool, l), _layer_of(v_pool, l))
+        # scratch page 0 is garbage by contract (masked rows collide there)
+        _assert_pages_equal(_layer_of(got[0], l), want[0], first_page=1)
+        _assert_pages_equal(_layer_of(got[1], l), want[1], first_page=1)
+        np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
+        np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(want[3]))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8", "int4"])
+def test_layer_indexed_write_leaves_other_layers_untouched(kv):
+    """A write to layer ``l`` of the carried pool changes that layer's
+    pages only: every other layer keeps every byte (values and scales),
+    on both write routes and at T = 1 and T = 8."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (  # noqa: E501
+        write_token_to_pages, write_window_to_pages)
+
+    L, Nkv, D, B = 3, 2, 64, 4
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    pool = _layered_pool(kv, ks[0], L)
+    tables = jnp.asarray(_WINDOW_TABLES, jnp.int32)
+    for T in (1, 8):
+        new_kv = jax.random.normal(ks[1], (B, T, Nkv, D), jnp.float32)
+        starts = jnp.asarray(_WINDOW_STARTS[T], jnp.int32)
+        flat_pos = (starts[:, None] + jnp.arange(T)).reshape(-1)
+        for l in range(L):
+            layer = jnp.int32(l)
+            for got in (
+                    write_window_to_pages(pool, new_kv, tables, starts,
+                                          None, layer),
+                    write_token_to_pages(
+                        pool, new_kv.reshape(B * T, Nkv, D),
+                        jnp.repeat(tables, T, axis=0), flat_pos, None,
+                        layer)):
+                for other in range(L):
+                    if other != l:
+                        _assert_pages_equal(_layer_of(got, other),
+                                            _layer_of(pool, other))
+                changed = any(
+                    (np.asarray(g) != np.asarray(w)).any()
+                    for g, w in zip(jax.tree.leaves(_layer_of(got, l)),
+                                    jax.tree.leaves(_layer_of(pool, l))))
+                assert changed, "the write did not reach its own layer"
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8", "int4"])
+def test_long_window_write_matches_row_scatter(kv):
+    """Windows longer than a page (suffix and chunked prefill: 40 tokens
+    over pages of 16 stage four pages a slot) take the whole-page merge
+    too, and must leave every real page bit-identical to the row
+    scatter: a window from mid-page, a padded tail masked off, a scratch
+    slot, and a window that ends in the table's last page (its last
+    staging page is clipped onto the one before and goes to scratch)."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (  # noqa: E501
+        write_token_to_pages, write_window_to_pages)
+
+    L, NP, Nkv, PS, D, B, T = 2, 14, 2, 16, 64, 4, 40
+    ks = jax.random.split(jax.random.PRNGKey(13), 2)
+    pool = _layered_pool(kv, ks[0], L, NP, Nkv, PS, D)
+    new_kv = jax.random.normal(ks[1], (B, T, Nkv, D), jnp.float32)
+    tables = jnp.asarray([[1, 2, 3, 4, 5],
+                          [6, 7, 8, 0, 0],
+                          [0, 0, 0, 0, 0],
+                          [9, 10, 11, 12, 13]], jnp.int32)
+    starts = jnp.asarray([13, 5, 0, 44], jnp.int32)
+    pos = starts[:, None] + jnp.arange(T)
+    # slot 1 holds 30 real tokens; slot 3's window runs past its 80 slots
+    ok = (pos < 5 * PS) & (jnp.arange(T)[None] < jnp.asarray(
+        [T, 30, T, T])[:, None])
+    layer = jnp.int32(1)
+    got = write_window_to_pages(pool, new_kv, tables, starts, ok, layer)
+    # token by token: two int4 tokens share a byte, and rows of ONE
+    # scatter that land in the same byte would each splice into the old one
+    want = pool
+    for j in range(T):
+        want = write_token_to_pages(want, new_kv[:, j], tables, pos[:, j],
+                                    ok[:, j], layer)
+    _assert_pages_equal(_layer_of(got, 1), _layer_of(want, 1), first_page=1)
+    _assert_pages_equal(_layer_of(got, 0), _layer_of(pool, 0))
+    wrote = np.asarray(jax.tree.leaves(_layer_of(got, 1))[0]) != np.asarray(
+        jax.tree.leaves(_layer_of(pool, 1))[0])
+    assert wrote[[1, 2, 3, 4, 6, 7, 8, 11, 12, 13]].any(axis=(1, 2, 3)).all()
+    assert not wrote[[5, 9, 10]].any()

@@ -125,6 +125,56 @@ def test_paged_multi_query_kernel_compiles(one_chip, as_tpu, window, layout,
              sds((B, MAXP), jnp.int32), sds((B,), jnp.int32))
 
 
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_decode_program_updates_pool_in_place(one_chip, as_tpu, kv):
+    """The multi-step decode program (serve.decode.decode_scan, what the
+    engine jits as ``_decode_impl_n``) at the GQA 32/8 layout, 4 layers,
+    8 slots, donated pools of 2,049 pages: the pools ride the step and
+    layer loops as carries and every layer writes and reads them by its
+    index, so nothing pool-sized is a temporary. With the pools as scanned
+    inputs and stacked outputs the program held a second copy of both
+    (temp >= two whole pools: 4 GB at the benchmark's size, PERF.md 4)."""
+    import dataclasses
+
+    from distributed_llm_training_and_inference_system_tpu.config.presets import (
+        get_model_config)
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        decode_scan)
+    nq, nkv = LAYOUTS["gqa32x8"]
+    cfg = dataclasses.replace(
+        get_model_config("mistral-7b"), num_layers=4, hidden_size=512,
+        ffn_size=1408, dtype="bfloat16")
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim) == (nq, nkv, D)
+    sds = _sds(one_chip)
+    B, num_pages = 8, 8 * 8 * MAXP + 1
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda k: gpt.init(cfg, k, jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+    layer_pages = _pages(sds, num_pages, nkv, kv)
+    pool = jax.tree.map(
+        lambda a: sds((cfg.num_layers, *a.shape), a.dtype), layer_pages)
+    layer_pool_bytes = sum(a.size * a.dtype.itemsize
+                           for a in jax.tree.leaves(layer_pages))
+
+    def program(params, k_pages, v_pages, tokens, positions, tables, stops,
+                keys, temp, top_k, top_p):
+        return decode_scan(params, tokens, positions, k_pages, v_pages,
+                           tables, stops, keys, temp, top_k, top_p, cfg, 8)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    compiled = jax.jit(program, donate_argnums=(1, 2)).lower(
+        params, pool, pool, i32(B), i32(B), i32(B, MAXP), i32(B),
+        sds((B, 2), jnp.uint32), sds((B,), jnp.float32), i32(B),
+        sds((B,), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_pool_bytes, (
+        f"decode program holds {temp / 1e6:.1f} MB of temporaries; one "
+        f"layer's K pool is {layer_pool_bytes / 1e6:.1f} MB")
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("shape", ["gpt-750m-b4", "gqa32x8-b2"])
 def test_flash_attention_compiles(one_chip, as_tpu, shape, grad):
