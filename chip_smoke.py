@@ -909,6 +909,72 @@ def child_kernels() -> None:
                         interpret=not on_tpu)
         check(f"matmul_w4 {rows}x{n_in}x{n_out}", got, ref)
 
+    # -- dropless MoE layer (ops/moe_gmm.py) and the q/k projection norm ------
+    # OLMoE's published widths (64 experts of 1024, 8 a token, hidden 2048,
+    # 16 heads of 128), the decode step's 32 rows and a 1,024-token prefill,
+    # the expert stack three layers deep with a traced non-zero layer index,
+    # against the same layer in float32 with every expert on every row
+    import dataclasses
+
+    from distributed_llm_training_and_inference_system_tpu.config.presets import (
+        get_model_config)
+    from distributed_llm_training_and_inference_system_tpu.models.layers import (
+        moe_block, qk_project_norm)
+    moe_cfg = get_model_config("olmoe-test" if small else "olmoe-1b-7b")
+    moe_cfg = dataclasses.replace(moe_cfg, dtype="bfloat16")
+    E, K = moe_cfg.moe.num_experts, moe_cfg.moe.experts_per_token
+    H, F = moe_cfg.hidden_size, moe_cfg.ffn_size
+
+    def moe_reference(x, layer):
+        x = x.astype(jnp.float32)
+        w = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), layer)
+        mm = functools.partial(jnp.matmul, precision="highest")
+        p = jax.nn.softmax(mm(x, w["router"]["kernel"]), -1)
+        top_p, top_e = jax.lax.top_k(p, K)
+        weights = jnp.zeros_like(p).at[
+            jnp.arange(x.shape[0])[:, None], top_e].set(top_p)
+        out = jnp.zeros_like(x)
+        for e in range(E):
+            hidden = jax.nn.silu(mm(x, w["gate"]["kernel"][e])) * mm(
+                x, w["up"]["kernel"][e])
+            out += weights[:, e, None] * mm(hidden, w["down"]["kernel"][e])
+        return out
+
+    stack = {
+        "router": {"kernel": jax.random.normal(next(key), (H, E)) * 0.05},
+        **{n: {"kernel": (jax.random.normal(next(key), (LAYERS, E, *shape),
+                                            jnp.float32) * 0.03
+                          ).astype(jnp.bfloat16)}
+           for n, shape in (("gate", (H, F)), ("up", (H, F)),
+                            ("down", (F, H)))}}
+    layer = {n: {"kernel": v["kernel"] if n == "router"
+                 else v["kernel"][LAYER]} for n, v in stack.items()}
+    # (the kernel on the chip; off it, moe_block's ragged_dot route)
+    kernel = jax.jit(lambda x, st, li: moe_block(
+        x, st, moe_cfg, layer_index=li)[0])
+    for batch, seq in ((8, 1), (1, 16)) if small else ((32, 1), (1, 1024)):
+        x = jax.random.normal(next(key), (batch, seq, H), jnp.bfloat16)
+        got = kernel(x, stack, jnp.int32(LAYER))
+        ref = jax.jit(moe_reference)(x.reshape(-1, H), layer)
+        check(f"moe_block dropless [{batch * seq} rows, {E} experts of "
+              f"{F}, top-{K}]", got.reshape(-1, H), ref)
+
+    Nq, Dh = moe_cfg.num_heads, moe_cfg.head_dim
+    norms = {n: {"scale": 0.3 * jax.random.normal(next(key), (Nq * Dh,))}
+             for n in ("q_norm", "k_norm")}
+    q = jax.random.normal(next(key), (32, 1, Nq * Dh), jnp.bfloat16) * 3.0
+    k = jax.random.normal(next(key), (32, 1, Nq * Dh), jnp.bfloat16) * 0.2
+
+    def norm_reference(x, scale):
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
+                                 + moe_cfg.norm_eps) * (1.0 + scale)
+    got = jax.jit(lambda q, k: (qk_project_norm(q, norms, "q", moe_cfg),
+                                qk_project_norm(k, norms, "k", moe_cfg)))(q, k)
+    check(f"qk_project_norm [{Nq * Dh}-wide projection]", got,
+          (norm_reference(q, norms["q_norm"]["scale"]),
+           norm_reference(k, norms["k_norm"]["scale"])))
+
     # -- data packer: built here from native/dataloader.cpp -------------------
     from distributed_llm_training_and_inference_system_tpu.io import native
     from distributed_llm_training_and_inference_system_tpu.io.data import (

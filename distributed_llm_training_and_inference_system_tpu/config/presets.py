@@ -98,6 +98,19 @@ MODEL_TEMPLATES: dict[str, ModelConfig] = {
         max_position_embeddings=4096, activation="silu",
         moe=MoEConfig(num_experts=8, experts_per_token=2),
     ),
+    # OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct config.json): 64 small
+    # experts of width 1024, 8 a token, weights NOT renormalised over the
+    # top-8, RMSNorm over the whole q / k projection before rope, MHA.
+    # 6.9 B parameters, 1.3 B active a token. Served dropless
+    # (models/layers.py moe_block).
+    "olmoe-1b-7b": ModelConfig(
+        name="olmoe-1b-7b", num_layers=16, hidden_size=2048, ffn_size=1024,
+        num_heads=16, num_kv_heads=16, head_dim=128, vocab_size=50304,
+        max_position_embeddings=4096, activation="silu", norm_eps=1e-5,
+        rope=RopeConfig(base=10000.0), qk_norm="projection",
+        moe=MoEConfig(num_experts=64, experts_per_token=8,
+                      norm_topk_prob=False),
+    ),
     # Depth-truncated gpt-7b: the SAME H=4096/D=128/F=11008 layer at 4
     # layers, so one 16 GB chip can STEP the north-star model's real
     # matmul shapes (full gpt-7b training state needs ~27 GB params+Adam
@@ -131,6 +144,16 @@ TEST_TEMPLATES: dict[str, ModelConfig] = {
         num_heads=4, num_kv_heads=4, head_dim=16, vocab_size=256,
         max_position_embeddings=128, activation="silu", dtype="float32",
         moe=MoEConfig(num_experts=4, experts_per_token=2),
+    ),
+    # OLMoE's shape in small: many narrow experts, top-k weights not
+    # renormalised, q/k projection norms, MHA.
+    "olmoe-test": ModelConfig(
+        name="olmoe-test", num_layers=2, hidden_size=64, ffn_size=32,
+        num_heads=4, num_kv_heads=4, head_dim=16, vocab_size=256,
+        max_position_embeddings=128, activation="silu", dtype="float32",
+        qk_norm="projection",
+        moe=MoEConfig(num_experts=8, experts_per_token=2,
+                      norm_topk_prob=False),
     ),
 }
 

@@ -72,17 +72,30 @@ class MoEConfig:
     num_experts: int = 0            # 0 = dense model
     experts_per_token: int = 2
     router_aux_loss_weight: float = 0.01
+    # training's capacity dispatch only (models/layers.py
+    # moe_block_capacity); serving is dropless and never reads it
     capacity_factor: float = 1.25
+    # True: a token's top-k router probabilities are renormalised to sum
+    # to 1 (Mixtral, the gpt-moe-* templates). False: they weigh the
+    # experts as the softmax over ALL experts gave them (OLMoE's
+    # published ``norm_topk_prob: false``)
+    norm_topk_prob: bool = True
 
     @classmethod
     def from_dict(cls, d: dict[str, Any] | None) -> "MoEConfig":
+        """``d`` is the nested ``moe`` table, or a model's published
+        ``config.json`` keys (``num_experts``, ``num_experts_per_tok``,
+        ``norm_topk_prob``) at the top level of the model dict."""
         if not d:
             return cls()
         return cls(
             num_experts=int(_take(d, "num_experts", "experts", default=0)),
-            experts_per_token=int(_take(d, "experts_per_token", "top_k", default=2)),
+            experts_per_token=int(_take(d, "experts_per_token", "top_k",
+                                        "num_experts_per_tok", default=2)),
             router_aux_loss_weight=float(_take(d, "router_aux_loss_weight", default=0.01)),
             capacity_factor=float(_take(d, "capacity_factor", default=1.25)),
+            norm_topk_prob=_parse_bool("norm_topk_prob", _take(
+                d, "norm_topk_prob", default=True)),
         )
 
 
@@ -113,6 +126,11 @@ class ModelConfig:
     dropout: float = 0.0
     dtype: str = "bfloat16"         # activations/weights compute dtype
     moe: MoEConfig = field(default_factory=MoEConfig)
+    # RMSNorm on the query and key projections before rope. "projection":
+    # one norm over the WHOLE [Nq*D] (and [Nkv*D]) projection, before the
+    # split into heads (OLMoE; its config.json has no key for it, it
+    # follows from ``model_type: olmoe``). "none": llama-style.
+    qk_norm: str = "none"
 
     @property
     def is_moe(self) -> bool:
@@ -134,6 +152,14 @@ class ModelConfig:
             raise ConfigError(f"unknown activation {self.activation!r}")
         if self.arch != "decoder-only":
             raise ConfigError(f"unsupported arch {self.arch!r} (decoder-only only)")
+        if self.qk_norm not in ("none", "projection"):
+            raise ConfigError(f"qk_norm must be none|projection (got "
+                              f"{self.qk_norm!r})")
+        if self.is_moe and not (
+                1 <= self.moe.experts_per_token <= self.moe.num_experts):
+            raise ConfigError(
+                f"experts_per_token ({self.moe.experts_per_token}) must lie "
+                f"in 1..num_experts ({self.moe.num_experts})")
 
     @property
     def param_count(self) -> int:
@@ -155,6 +181,8 @@ class ModelConfig:
         else:
             mlp = mlp_dense
         norms = 2 * h
+        if self.qk_norm == "projection":
+            norms += q_dim + kv_dim
         per_layer = attn + mlp + norms
         emb = v * h
         head = 0 if self.tie_word_embeddings else v * h
@@ -186,7 +214,11 @@ class ModelConfig:
             attention_bias=_parse_bool("attention_bias", attn.get("bias", _take(d, "attention_bias", default=False))),
             dropout=float(attn.get("dropout", _take(d, "dropout", default=0.0))),
             dtype=str(_take(d, "dtype", default="bfloat16")),
-            moe=MoEConfig.from_dict(d.get("moe")),
+            # the nested table, else the published top-level keys: a
+            # config.json with ``num_experts: 64`` is never loaded dense
+            # (its ``intermediate_size`` is then ONE expert's width)
+            moe=MoEConfig.from_dict(d.get("moe") or d),
+            qk_norm=str(_take(d, "qk_norm", default="none")),
         )
         cfg.validate()
         return cfg

@@ -54,6 +54,9 @@ def _loss_fn(params, batch, model_cfg: ModelConfig, attn_impl: str, remat: str,
         attn_impl=attn_impl, remat=remat,
         return_aux=model_cfg.is_moe,
         return_hidden=loss_chunk > 0,
+        # training keeps the capacity dispatch (plain einsums: it has a
+        # gradient and shards over 'ep'); serving is dropless
+        moe_impl="capacity",
     )
     if model_cfg.is_moe:
         head_in, aux = out
@@ -186,7 +189,7 @@ def make_eval_step(model_cfg: ModelConfig, attn_impl: str = "xla") -> Callable:
         logits = forward(params, batch["tokens"], model_cfg,
                          positions=batch.get("positions"),
                          segment_ids=batch.get("segment_ids"),
-                         attn_impl=attn_impl)
+                         attn_impl=attn_impl, moe_impl="capacity")
         loss, count = next_token_loss(logits, batch["tokens"],
                                       batch.get("segment_ids"))
         return {"loss": loss, "tokens": count}

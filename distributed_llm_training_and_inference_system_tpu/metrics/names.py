@@ -76,6 +76,9 @@ METRICS: dict[str, MetricSpec] = {
     "llmctl_engine_phase_seconds_total": MetricSpec(
         COUNTER, "Engine-thread self time by llmctl.engine.* span",
         ("phase",)),
+    "llmctl_moe_expert_choices_total": MetricSpec(
+        COUNTER, "Live tokens' choices of an MoE model's expert, summed "
+                 "over its layers", ("expert",)),
     "llmctl_decode_tokens_per_sec": MetricSpec(
         GAUGE, "Decode throughput"),
     "llmctl_inference_preemptions": MetricSpec(COUNTER, "KV preemptions"),

@@ -219,6 +219,8 @@ class PrometheusExporter:
         # the engine thread's self time by llmctl.engine.* span
         # (metrics/spans.py), from the running totals of engine.stats()
         self.engine_phase_seconds = mk("llmctl_engine_phase_seconds_total")
+        # an MoE model's routing (engine.stats()["moe"]["choices"])
+        self.moe_expert_choices = mk("llmctl_moe_expert_choices_total")
         self.decode_tokens_per_sec = mk("llmctl_decode_tokens_per_sec")
         # on-demand admission telemetry (round 3): preemption pressure and
         # swap-in counts are the KV-capacity health signals. Cumulative
@@ -410,6 +412,12 @@ class PrometheusExporter:
             if delta > 0:
                 self.engine_phase_seconds.labels(phase=phase).inc(delta)
             self._last_totals[key] = cell["s"]
+        for expert, total in enumerate(m.get("moe_choices", ())):
+            key = f"moe:{expert}"
+            delta = total - self._last_totals.get(key, 0)
+            if delta > 0:
+                self.moe_expert_choices.labels(expert=str(expert)).inc(delta)
+            self._last_totals[key] = total
         if "decode_tokens_per_sec" in m:
             self.decode_tokens_per_sec.set(m["decode_tokens_per_sec"])
         for key, counter in (("preemptions", self.infer_preemptions),

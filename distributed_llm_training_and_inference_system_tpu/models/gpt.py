@@ -32,6 +32,8 @@ from .layers import (
     attention_block,
     mlp_block,
     moe_block,
+    moe_block_capacity,
+    moe_stats,
     rms_norm,
     rope_frequencies,
 )
@@ -69,6 +71,9 @@ def init(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
         "o": {"kernel": dense(next(keys), L, Nq * D, H, scale=resid_std)},
         "mlp_norm": {"scale": norm_init(L, H)},
     }
+    if cfg.qk_norm == "projection":
+        blocks["q_norm"] = {"scale": norm_init(L, Nq * D)}
+        blocks["k_norm"] = {"scale": norm_init(L, Nkv * D)}
     if cfg.attention_bias:
         blocks["q"]["bias"] = jnp.zeros((L, Nq * D), dtype)
         blocks["k"]["bias"] = jnp.zeros((L, Nkv * D), dtype)
@@ -102,10 +107,45 @@ def init(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
 # Forward
 # ---------------------------------------------------------------------------
 
+# the expert kernels of blocks["moe"]: the dropless block takes them as
+# the whole [L, E, in, out] stacks with the layer's index
+_EXPERT_KERNELS = ("gate", "up", "down")
+
+
+def split_expert_stacks(blocks: Params):
+    """(blocks without the experts' kernels, those kernels' stacks) when
+    they are plain arrays; (blocks, None) for a dense model or quantized
+    experts, which ride the layer scan and dequantize a layer at a time.
+    A scan over the stacked blocks hands each layer its slice, and a slice
+    that feeds a kernel (a custom call) is COPIED out: 805 MB a layer at
+    OLMoE's widths. The stacks stay outside the scan instead and the
+    kernel addresses ``stack[layer, expert]`` (ops/moe_gmm.py)."""
+    moe = blocks.get("moe")
+    if moe is None or not all(
+            isinstance(moe[n]["kernel"], jax.Array) for n in _EXPERT_KERNELS):
+        return blocks, None
+    scanned = dict(blocks, moe={k: v for k, v in moe.items()
+                                if k not in _EXPERT_KERNELS})
+    return scanned, {n: moe[n] for n in _EXPERT_KERNELS}
+
+
+def layer_experts(layer_moe: Params, expert_stacks, layer_index):
+    """(the parameters ``moe_block`` takes for one layer of the scan, its
+    ``layer_index``): the layer's router beside the whole expert stacks
+    when ``split_expert_stacks`` kept them out of the scan."""
+    if expert_stacks is None:
+        return layer_moe, None
+    return dict(layer_moe, **expert_stacks), layer_index
+
+
 def _block_fn(cfg: ModelConfig, attn_impl: str, norm_impl: str,
               x, layer, positions, segment_ids, inv_freq,
-              kv_cache=None, cache_offset=None):
-    """One transformer block (pre-norm). Returns (x, new_kv_cache, aux_loss)."""
+              kv_cache=None, cache_offset=None, *, moe_impl: str = "dropless",
+              expert_stacks=None, layer_index=None):
+    """One transformer block (pre-norm). Returns (x, new_kv_cache, aux):
+    ``aux`` is the router's load-balancing loss, or under
+    ``moe_impl="dropless"`` the layer's ``moe_stats`` (``segment_ids`` 0
+    marks a token that is not live)."""
     h = rms_norm(x, layer["attn_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
     attn_out, new_cache = attention_block(
         h, layer, cfg, positions, segment_ids, inv_freq,
@@ -116,8 +156,15 @@ def _block_fn(cfg: ModelConfig, attn_impl: str, norm_impl: str,
     attn_out = checkpoint_name(attn_out, "attn_out")
     x = x + attn_out
     h = rms_norm(x, layer["mlp_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
-    if cfg.is_moe:
-        ffn_out, aux = moe_block(h, layer["moe"], cfg)
+    if cfg.is_moe and moe_impl == "dropless":
+        moe, layer_index = layer_experts(layer["moe"], expert_stacks,
+                                         layer_index)
+        ffn_out, counts = moe_block(
+            h, moe, cfg, live=None if segment_ids is None
+            else segment_ids != 0, layer_index=layer_index)
+        aux = moe_stats(counts)
+    elif cfg.is_moe:
+        ffn_out, aux = moe_block_capacity(h, layer["moe"], cfg)
     else:
         ffn_out, aux = mlp_block(h, layer["mlp"], cfg), jnp.float32(0.0)
     x = x + ffn_out
@@ -181,6 +228,8 @@ def forward(
     return_aux: bool = False,
     unembed_positions: Optional[jax.Array] = None,
     return_hidden: bool = False,
+    moe_impl: str = "dropless",      # dropless | capacity (training)
+    return_moe_stats: bool = False,
 ):
     """Compute logits [B, S, V] (fp32) — or, with ``return_hidden=True``,
     the final-normed hidden states [B, S, H] in the compute dtype (consumed
@@ -196,6 +245,13 @@ def forward(
       logits, and skipping the [S, V] unembed saves HBM and MXU time
       (the reference recomputes and discards full-vocab logits every step,
       reference serve/server.py:199-204).
+    - ``moe_impl``: an MoE model's feed-forward is dropless (every token
+      served by all of its k experts; inference) unless the caller asks for
+      training's ``capacity`` dispatch, the only route with the router's
+      aux loss (``return_aux``). ``return_moe_stats`` (dropless) appends
+      the [E + 1] int32 vector of the live tokens' choices per expert
+      summed over the layers and, last, the (layer, expert) pairs that got
+      any (``segment_ids`` 0 = not live: prefill padding is never counted).
     """
     compute_dtype = jnp.dtype(cfg.dtype)
     B, S = tokens.shape
@@ -211,8 +267,15 @@ def forward(
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope.base,
                                 cfg.rope.scaling, cfg.rope.scaling_factor)
 
-    block = functools.partial(_block_fn, cfg, attn_impl, norm_impl)
-    block = _remat_wrap(block, remat)
+    if moe_impl not in ("dropless", "capacity"):
+        raise ValueError(f"moe_impl must be dropless|capacity: {moe_impl!r}")
+    dropless = cfg.is_moe and moe_impl == "dropless"
+    if dropless and return_aux:
+        raise ValueError(
+            "the router's aux loss belongs to training's capacity dispatch: "
+            "pass moe_impl='capacity' with return_aux")
+    if return_moe_stats and not dropless:
+        raise ValueError("return_moe_stats needs a dropless MoE model")
 
     # plain leaves are cast to the compute dtype ONCE before the scan
     # (casting inside the body would stream fp32 master weights from HBM
@@ -222,33 +285,47 @@ def forward(
     from ..ops.quantization import cast_params as _cast, precast_params
 
     blocks = precast_params(params["blocks"], compute_dtype)
+    layer_ids = None        # scanned only where a kernel indexes a stack
+    if dropless:
+        blocks, expert_stacks = split_expert_stacks(blocks)
+        if expert_stacks is not None:
+            layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        block = functools.partial(_block_fn, cfg, attn_impl, norm_impl,
+                                  moe_impl="dropless",
+                                  expert_stacks=expert_stacks)
+        aux0 = jnp.zeros((cfg.moe.num_experts + 1,), jnp.int32)
+    else:
+        block = functools.partial(_block_fn, cfg, attn_impl, norm_impl,
+                                  moe_impl="capacity")
+        aux0 = jnp.float32(0.0)
+    block = _remat_wrap(block, remat)
 
     if kv_cache is None:
-        def body(carry, layer):
+        def body(carry, layer_and_index):
             x, aux = carry
+            layer, li = layer_and_index
             x, _, aux_l = block(x.astype(compute_dtype),
                                 _cast(layer, compute_dtype), positions,
-                                segment_ids, inv_freq)
+                                segment_ids, inv_freq, layer_index=li)
             return (x, aux + aux_l), None
 
-        (x, aux_total), _ = jax.lax.scan(
-            body, (x, jnp.float32(0.0)), blocks)
+        (x, aux_total), _ = jax.lax.scan(body, (x, aux0), (blocks, layer_ids))
         new_cache = None
     else:
         k_cache, v_cache = kv_cache
 
         def body(carry, layer_and_cache):
             x, aux = carry
-            layer, kc, vc = layer_and_cache
+            layer, li, kc, vc = layer_and_cache
             x, new_kv, aux_l = block(x.astype(compute_dtype),
                                      _cast(layer, compute_dtype), positions,
                                      segment_ids, inv_freq,
-                                     kv_cache=(kc, vc), cache_offset=cache_offset)
+                                     kv_cache=(kc, vc), cache_offset=cache_offset,
+                                     layer_index=li)
             return (x, aux + aux_l), new_kv
 
         (x, aux_total), new_kvs = jax.lax.scan(
-            body, (x, jnp.float32(0.0)),
-            (blocks, k_cache, v_cache))
+            body, (x, aux0), (blocks, layer_ids, k_cache, v_cache))
         new_cache = new_kvs
 
     if unembed_positions is not None:
@@ -264,7 +341,7 @@ def forward(
     result = [out]
     if kv_cache is not None:
         result.append(new_cache)
-    if return_aux:
+    if return_aux or return_moe_stats:
         result.append(aux_total)
     return tuple(result) if len(result) > 1 else result[0]
 

@@ -10,6 +10,7 @@ fp32-master friendly, XLA-fusable, with hooks for Pallas kernels in ops/.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
@@ -148,6 +149,19 @@ def _flash_on_mesh(q, k, v, segment_ids):
     return fn(*operands)
 
 
+def qk_project_norm(x: jax.Array, layer: Params, which: str,
+                    cfg: ModelConfig) -> jax.Array:
+    """The norm ``cfg.qk_norm`` puts on the query (``which="q"``) or key
+    (``"k"``) PROJECTION [..., N*D], before the split into heads and
+    before rope. "projection" (OLMoE): one RMSNorm over the whole
+    projection width, scaled by ``layer["q_norm"]`` / ``layer["k_norm"]``.
+    The one place both the training-side block and the paged serving block
+    take it from."""
+    if cfg.qk_norm == "none":
+        return x
+    return rms_norm(x, layer[f"{which}_norm"]["scale"], cfg.norm_eps)
+
+
 def attention_block(
     x: jax.Array,
     layer: Params,
@@ -170,8 +184,10 @@ def attention_block(
     B, S, H = x.shape
     D, Nq, Nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
 
-    q = jnp.einsum("bsh,hd->bsd", x, layer["q"]["kernel"]).reshape(B, S, Nq, D)
-    k = jnp.einsum("bsh,hd->bsd", x, layer["k"]["kernel"]).reshape(B, S, Nkv, D)
+    q = qk_project_norm(jnp.einsum("bsh,hd->bsd", x, layer["q"]["kernel"]),
+                        layer, "q", cfg).reshape(B, S, Nq, D)
+    k = qk_project_norm(jnp.einsum("bsh,hd->bsd", x, layer["k"]["kernel"]),
+                        layer, "k", cfg).reshape(B, S, Nkv, D)
     v = jnp.einsum("bsh,hd->bsd", x, layer["v"]["kernel"]).reshape(B, S, Nkv, D)
     if cfg.attention_bias:
         q = q + layer["q"]["bias"].reshape(Nq, D)
@@ -247,9 +263,138 @@ def mlp_block(x: jax.Array, layer: Params, cfg: ModelConfig,
     return matmul(h, layer["down"]["kernel"]).astype(x.dtype)
 
 
+def moe_route(xt: jax.Array, router_kernel: jax.Array, cfg: ModelConfig
+              ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Token-choice routing of xt [N, H]: float32 softmax over ALL experts,
+    then the top-k. Returns (probs [N, E], top_w [N, K], top_e [N, K]);
+    ``top_w`` sums to 1 per token iff ``cfg.moe.norm_topk_prob``. Shared by
+    the dropless serving block and training's capacity block."""
+    with jax.named_scope("moe_router"):
+        # full float32 passes: at the TPU's default precision a float32
+        # matmul multiplies in bfloat16, and a router logit off by 1e-3
+        # flips a token's k-th choice to another expert wherever two are
+        # close (1 row in ~1,000 at OLMoE's widths: 7 % of that row's
+        # output). [N, H] x [H, E] is too small to cost anything.
+        logits = jnp.einsum("nh,he->ne", xt.astype(jnp.float32),
+                            router_kernel.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)                  # [N,E]
+        top_w, top_e = jax.lax.top_k(probs, cfg.moe.experts_per_token)
+        if cfg.moe.norm_topk_prob:
+            top_w = top_w / jnp.maximum(
+                jnp.sum(top_w, axis=-1, keepdims=True), 1e-9)
+    return probs, top_w, top_e
+
+
+def moe_row_tile(n_choices: int, num_experts: int, dtype) -> int:
+    """Rows of one expert tile: the power of two nearest the mean rows an
+    expert gets, between the dtype's sublane packing (16 rows of bf16, 8 of
+    float32) and 128 (the MXU's edge)."""
+    floor = 16 if jnp.dtype(dtype).itemsize < 4 else 8
+    mean = max(n_choices // num_experts, 1)
+    return int(min(128, max(floor, 1 << (mean - 1).bit_length())))
+
+
 def moe_block(x: jax.Array, layer: Params, cfg: ModelConfig,
-              router_key: Optional[jax.Array] = None) -> tuple[jax.Array, jax.Array]:
-    """Token-choice top-k MoE with GShard-style capacity dispatch.
+              live: Optional[jax.Array] = None, layer_index=None
+              ) -> tuple[jax.Array, jax.Array]:
+    """Dropless token-choice top-k MoE: every live token is served by ALL
+    of its k experts whatever else is in the batch (the serving path;
+    OLMoE, Mixtral and their kin are dropless).
+
+    Static shapes without a capacity: the N*K choices are ranked within
+    their expert in token order (the stable sort by expert, computed as a
+    running count over a one-hot), each expert's rows are laid out in a
+    buffer padded to whole ``tm``-row tiles (at most E*(tm-1) rows of
+    padding, a static bound), one grouped matmul over the ragged groups
+    runs gate/up and another down (ops/moe_gmm.py), and each token gathers
+    its K rows back weighted by its router probabilities. No row can
+    displace another: a token's output does not depend on its batch
+    companions. ``live`` [B, S] bool marks real tokens; idle decode slots
+    and prefill padding get no rows, hit no expert (their experts'
+    weights are not read) and return zeros.
+
+    ``layer`` holds ``router`` and the experts' ``gate`` / ``up`` / ``down``
+    kernels, either one layer's [E, in, out] (``layer_index=None``) or the
+    whole stack [L, E, in, out] with ``layer_index`` (the router [H, E] is
+    always one layer's): the stack is handed to the kernel as it lies, so
+    no layer's 3 x [E, H, F] slab is copied out per layer.
+
+    Returns (output [B, S, H], counts [E] int32: live tokens' choices per
+    expert).
+    """
+    from ..ops.moe_gmm import grouped_matmul
+    B, S, H = x.shape
+    E, K = cfg.moe.num_experts, cfg.moe.experts_per_token
+    N = B * S
+    xt = x.reshape(N, H)
+    _, top_w, top_e = moe_route(xt, layer["router"]["kernel"], cfg)
+
+    with jax.named_scope("moe_dispatch"):
+        tm = moe_row_tile(N * K, E, x.dtype)
+        # every expert holds whole tiles: sum_e ceil(c_e / tm) is at most
+        # (N*K + E*(tm-1)) / tm, and an expert has at most N rows
+        n_tiles = min((N * K + E * (tm - 1)) // tm, E * (-(-N // tm)))
+        M = n_tiles * tm
+        flat_e = top_e.reshape(N * K)
+        flat_live = (jnp.ones((N * K,), bool) if live is None
+                     else jnp.repeat(live.reshape(N), K))
+        onehot = (flat_e[:, None] == jnp.arange(E)[None, :]) \
+            & flat_live[:, None]                                 # [NK,E]
+        running = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
+        counts = running[-1]                                     # [E]
+        rank = jnp.take_along_axis(running, flat_e[:, None], 1)[:, 0] - 1
+        tiles_of = (counts + tm - 1) // tm                       # [E]
+        tile_end = jnp.cumsum(tiles_of)                          # [E]
+        tiles_used = tile_end[-1]
+        row_start = (tile_end - tiles_of) * tm                   # [E]
+        # a dead choice goes out of range: its scatter is dropped and its
+        # gather reads row 0 under a zero weight
+        dest = jnp.where(flat_live, row_start[flat_e] + rank, M)  # [NK]
+        # tile t belongs to the first expert whose tiles end past it; the
+        # unused tail is clamped to the last used tile's expert (same
+        # weight block index: no DMA)
+        t = jnp.minimum(jnp.arange(n_tiles), tiles_used - 1)
+        tile_group = jnp.minimum(
+            jnp.sum(tile_end[None, :] <= t[:, None], axis=1), E - 1
+        ).astype(jnp.int32)
+        row_token = jnp.zeros((M,), jnp.int32).at[dest].set(
+            jnp.repeat(jnp.arange(N, dtype=jnp.int32), K), mode="drop")
+        xs = xt[row_token]                                       # [M,H]
+
+    with jax.named_scope("moe_experts"):
+        # one token a sequence is the decode step; a window is a prefill
+        # (the names a device trace tells the two by, as paged_attention's)
+        mm = functools.partial(
+            grouped_matmul, tile_group=tile_group, tiles_used=tiles_used,
+            layer=layer_index, tm=tm,
+            name="moe_gmm" if S == 1 else "moe_gmm_prefill")
+        hidden = _activate(mm(xs, layer["gate"]["kernel"]),
+                           cfg.activation) * mm(xs, layer["up"]["kernel"])
+        ys = mm(hidden, layer["down"]["kernel"])                 # [M,H]
+
+    with jax.named_scope("moe_combine"):
+        rows = ys[jnp.minimum(dest, M - 1)].astype(jnp.float32)  # [NK,H]
+        # (masked, not weighed by zero: a dead choice reads a row no
+        # expert wrote)
+        rows = jnp.where(flat_live[:, None], rows, 0.0)
+        out = jnp.sum((rows * top_w.reshape(N * K, 1)).reshape(N, K, H),
+                      axis=1)
+    return out.reshape(B, S, H).astype(x.dtype), counts
+
+
+def moe_stats(counts: jax.Array) -> jax.Array:
+    """One dropless block's routing as the [E + 1] int32 vector the serve
+    programs sum over layers and steps and hand the engine: the live
+    tokens' choices per expert and, last, how many experts got any."""
+    return jnp.append(counts, jnp.sum(counts > 0, dtype=counts.dtype))
+
+
+def moe_block_capacity(x: jax.Array, layer: Params, cfg: ModelConfig
+                       ) -> tuple[jax.Array, jax.Array]:
+    """Token-choice top-k MoE with GShard-style capacity dispatch: the
+    TRAINING route (differentiable through plain einsums, experts sharded
+    on 'ep'). Serving never takes it: see ``moe_block``.
 
     Static shapes throughout (XLA requirement): tokens are dispatched into
     a fixed per-expert capacity C; overflow tokens fall back to the
@@ -277,13 +422,7 @@ def moe_block(x: jax.Array, layer: Params, cfg: ModelConfig,
     C = max(int(cfg.moe.capacity_factor * K * N / E), 1)
 
     xt = x.reshape(N, H)
-    logits = jnp.einsum("nh,he->ne", xt.astype(jnp.float32),
-                        layer["router"]["kernel"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                      # [N,E]
-
-    # top-k expert choice per token
-    top_p, top_e = jax.lax.top_k(probs, K)                       # [N,K]
-    top_p = top_p / jnp.maximum(jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
+    probs, top_p, top_e = moe_route(xt, layer["router"]["kernel"], cfg)
 
     flat_e = top_e.reshape(N * K)
     flat_w = top_p.reshape(N * K)

@@ -91,7 +91,8 @@ def make_pipeline_loss_fn(
             return x.reshape(pp, L // pp, *x.shape[1:]).astype(compute_dtype)
         stage_blocks = jax.tree_util.tree_map(to_stages, params["blocks"])
 
-        block = functools.partial(_block_fn, model_cfg, attn_impl, "xla")
+        block = functools.partial(_block_fn, model_cfg, attn_impl, "xla",
+                                  moe_impl="capacity")
         block = _remat_wrap(block, remat)
 
         def stage_fn(blocks_one, x, positions, segments):
@@ -250,7 +251,8 @@ def make_pipeline_grad_fn(
             head_params["lm_head"] = cast(params["lm_head"])
         emb_c = params["embed"]["embedding"].astype(compute_dtype)
 
-        block = functools.partial(_block_fn, model_cfg, attn_impl, "xla")
+        block = functools.partial(_block_fn, model_cfg, attn_impl, "xla",
+                                  moe_impl="capacity")
         block = _remat_wrap(block, remat)
 
         def stage_fn(blocks_one, x, positions, segments):

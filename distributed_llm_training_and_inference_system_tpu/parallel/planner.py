@@ -201,8 +201,9 @@ class MeshPlanner:
         }[par.activation_checkpoint]
         per_layer /= par.tensor_parallel
         if m.is_moe:
-            # sort-based capacity dispatch (models/layers.py moe_block):
-            # the per-layer extras are the [E, C, H] expert input+output
+            # TRAINING's sort-based capacity dispatch (models/layers.py
+            # moe_block_capacity; serving is dropless and priced by
+            # ServePlanner.moe_dispatch_bytes): the per-layer extras are the [E, C, H] expert input+output
             # buffers (E*C = capacity_factor * K * tokens, independent of
             # how E shards over ep) plus the [E*C, F] expert hidden.
             # Residency follows the SAME remat semantics as the dense
@@ -715,6 +716,44 @@ class ServePlanner:
         return 2 * m.num_layers * page_size * m.num_kv_heads \
             * m.head_dim * BYTES_BF16
 
+    def active_param_count(self) -> float:
+        """Parameters a token's forward multiplies: of an MoE's experts
+        only its k chosen ones."""
+        m = self.model
+        if not m.is_moe:
+            return m.param_count
+        idle = m.moe.num_experts - m.moe.experts_per_token
+        return m.param_count - m.num_layers * idle * 3 * m.hidden_size \
+            * m.ffn_size
+
+    def moe_decode_weight_fraction(self, batch: int) -> float:
+        """Share of the weights a decode step of ``batch`` live tokens
+        reads: serving is dropless and the grouped matmul streams only the
+        experts some token chose, 1 - (1 - k/E)^batch of them under
+        uniform routing; everything else is read whole."""
+        m = self.model
+        if not m.is_moe:
+            return 1.0
+        experts = (m.num_layers * m.moe.num_experts * 3 * m.hidden_size
+                   * m.ffn_size) / m.param_count
+        hit = 1.0 - (1.0 - m.moe.experts_per_token
+                     / m.moe.num_experts) ** max(batch, 1)
+        return 1.0 - experts * (1.0 - hit)
+
+    def moe_dispatch_bytes(self, tokens: int) -> float:
+        """The dropless MoE block's buffers for ``tokens`` tokens in one
+        program (models/layers.py moe_block): tokens*k routed rows plus at
+        most E*(tile-1) rows of per-expert padding, each row held as
+        input [H], hidden [F] twice and output [H]. No capacity factor: a
+        row is never dropped, and one layer's buffers are live at a time."""
+        m = self.model
+        if not m.is_moe:
+            return 0.0
+        from ..models.layers import moe_row_tile
+        k, e = m.moe.experts_per_token, m.moe.num_experts
+        rows = tokens * k + e * (moe_row_tile(tokens * k, e, "bfloat16") - 1)
+        return rows * (2 * m.hidden_size + 2 * m.ffn_size) * BYTES_BF16
+
     # -- the estimate -------------------------------------------------------
 
     def estimate(self, *, batch: int = 8, context_len: int = 1024,
@@ -725,7 +764,8 @@ class ServePlanner:
         tp = max(tensor_parallel, 1)
         wb = self.weight_bytes(quant) / tp
         hbm = hw.hbm_gb_per_chip * 1e9
-        pool = hbm - wb - self.workspace_gb * 1e9
+        pool = (hbm - wb - self.workspace_gb * 1e9
+                - self.moe_dispatch_bytes(max(prompt_len, batch)))
         pb = self.page_bytes(page_size, kv_quant) / tp
         pages = max(int(pool // pb), 0)
         fits = pages > 0
@@ -742,7 +782,8 @@ class ServePlanner:
         # decode: one step reads all weights + the resident KV
         kv_read = batch * context_len * (pb / max(page_size, 1))
         bw = hw.hbm_bw_gbps * 1e9 * self.decode_efficiency
-        decode_s = (wb + kv_read) / max(bw, 1.0)
+        decode_s = (wb * self.moe_decode_weight_fraction(batch)
+                    + kv_read) / max(bw, 1.0)
         if kv_quant in ("int8", "int4"):
             # int8 KV pages switch the page writes to the per-row scatter
             # path and add in-kernel dequant — a program-structure cost,
@@ -767,7 +808,7 @@ class ServePlanner:
             overhead = max(1.0, 1.18 + 0.45 * (nkv_chip - 16) / 16)
             decode_s *= overhead
         # prefill: FLOPs-bound on this chip's share
-        flops = 2.0 * m.param_count * prompt_len / tp
+        flops = 2.0 * self.active_param_count() * prompt_len / tp
         prefill_s = flops / (hw.peak_bf16_tflops * 1e12 * self.mfu_prefill)
 
         return ServePlan(

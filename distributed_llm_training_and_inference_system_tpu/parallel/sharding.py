@@ -39,6 +39,9 @@ PARAM_RULES: list[tuple[str, P]] = [
     (r"final_norm\.scale$",       P(None)),
     (r"blocks\.(q|k|v)\.kernel$", P("fsdp", "tp")),
     (r"blocks\.(q|k|v)\.bias$",   P("tp")),
+    # the q / k projection norm's scale lies along the projection's output
+    # axis, which tp shards: GSPMD turns the norm's mean into an all-reduce
+    (r"blocks\.(q|k)_norm\.scale$", P("tp")),
     (r"blocks\.o\.kernel$",       P("tp", "fsdp")),
     (r"blocks\.mlp\.(gate|up)\.kernel$", P("fsdp", "tp")),
     (r"blocks\.mlp\.down\.kernel$",      P("tp", "fsdp")),

@@ -16,10 +16,13 @@ import jax
 import jax.numpy as jnp
 
 from ..config.schema import ModelConfig
+from ..models.gpt import layer_experts, split_expert_stacks
 from ..models.layers import (
     apply_rope,
     mlp_block,
     moe_block,
+    moe_stats,
+    qk_project_norm,
     rms_norm,
     rope_frequencies,
 )
@@ -44,8 +47,11 @@ def decode_step_forward(
     write_mode: str = "paged",
     w4_kernel_ok: bool = True,
     w8_kernel_ok: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns (logits [B, V] fp32, new k_pages, new v_pages).
+    return_moe_stats: bool = False,
+) -> tuple:
+    """Returns (logits [B, V] fp32, new k_pages, new v_pages) and, asked
+    with ``return_moe_stats``, the live slots' expert choices (see
+    ``extend_step_forward``).
 
     The T=1 case of ``extend_step_forward`` (one layer-body implementation
     for both, so the paths can never diverge numerically). The new token's
@@ -56,11 +62,12 @@ def decode_step_forward(
     (tests/test_tpu_compile.py::test_decode_program_updates_pool_in_place).
     """
     write_ok = None if active is None else active[:, None]
-    logits, new_k, new_v = extend_step_forward(
+    logits, *rest = extend_step_forward(
         params, tokens[:, None], positions, k_pages, v_pages, block_tables,
         cfg, write_ok=write_ok, attn_impl=attn_impl, write_mode=write_mode,
-        w4_kernel_ok=w4_kernel_ok, w8_kernel_ok=w8_kernel_ok)
-    return logits[:, 0], new_k, new_v
+        w4_kernel_ok=w4_kernel_ok, w8_kernel_ok=w8_kernel_ok,
+        return_moe_stats=return_moe_stats)
+    return (logits[:, 0], *rest)
 
 
 def extend_step_forward(
@@ -92,9 +99,14 @@ def extend_step_forward(
     w8_kernel_ok: bool = False,  # OPT-IN (ServeConfig.int8_pallas_matmul):
                               # int8 dequant fuses in XLA, so the Pallas
                               # route needs a measured per-chip win first
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+    return_moe_stats: bool = False,
+) -> tuple:
     """Paged forward over T tokens per slot: the multi-token sibling of
-    ``decode_step_forward``. Returns (logits [B, T, V] fp32, k_pages, v_pages).
+    ``decode_step_forward``. Returns (logits [B, T, V] fp32, k_pages,
+    v_pages) and, asked with ``return_moe_stats`` (MoE models), a fourth:
+    the [E + 1] int32 vector of the LIVE tokens' choices per expert summed
+    over the layers (``write_ok`` rows; idle slots and padding get no
+    expert) and, last, the (layer, expert) pairs that got any.
 
     Token j sits at position ``start_positions + j`` and attends causally
     over the paged prefix *including* earlier tokens of this same call: all
@@ -175,12 +187,20 @@ def extend_step_forward(
             w = w.dequant(compute_dtype)
         return a @ w
 
+    blocks = precast_params(params["blocks"], compute_dtype)
+    expert_stacks = None
+    if cfg.is_moe:
+        # plain expert kernels stay OUT of the layer scan, whole, and the
+        # kernel takes them by layer index (models/gpt.py)
+        blocks, expert_stacks = split_expert_stacks(blocks)
+    return_moe_stats = return_moe_stats and cfg.is_moe
+
     def body(carry, layer_and_index):
         # the pools must stay a CARRY that every layer writes and reads
         # by its index: as scanned inputs and stacked outputs XLA slices
         # each layer's slab out (94 MB at mistral-7b, 715 pages), writes
         # it back, and copies the step's fresh pool whole
-        x, kp, vp = carry
+        x, kp, vp, *stats = carry
         layer, li = layer_and_index
         # per-layer cast/dequant: quantized serving weights either stay
         # packed for the Pallas matmuls above (TPU) or materialise one
@@ -194,8 +214,10 @@ def extend_step_forward(
             layer = dict(layer, moe=cast_params(layer["moe"],
                                                 compute_dtype))
         h = rms_norm(x, layer["attn_norm"]["scale"], cfg.norm_eps)
-        q = mm(h, layer["q"]["kernel"]).reshape(B, T, Nq, D)
-        k = mm(h, layer["k"]["kernel"]).reshape(B, T, Nkv, D)
+        q = qk_project_norm(mm(h, layer["q"]["kernel"]), layer, "q",
+                            cfg).reshape(B, T, Nq, D)
+        k = qk_project_norm(mm(h, layer["k"]["kernel"]), layer, "k",
+                            cfg).reshape(B, T, Nkv, D)
         v = mm(h, layer["v"]["kernel"]).reshape(B, T, Nkv, D)
         if cfg.attention_bias:
             q = q + layer["q"]["bias"].reshape(Nq, D)
@@ -227,15 +249,19 @@ def extend_step_forward(
 
         h = rms_norm(x, layer["mlp_norm"]["scale"], cfg.norm_eps)
         if cfg.is_moe:
-            ffn, _ = moe_block(h, layer["moe"], cfg)
+            moe, moe_li = layer_experts(layer["moe"], expert_stacks, li)
+            ffn, counts = moe_block(h, moe, cfg, live=write_ok,
+                                    layer_index=moe_li)
+            stats = [total + moe_stats(counts) for total in stats]
         else:
             ffn = mlp_block(h, layer["mlp"], cfg, matmul=mm)
-        return (x + ffn.astype(x.dtype), kp, vp), None
+        return (x + ffn.astype(x.dtype), kp, vp, *stats), None
 
-    (x, new_k, new_v), _ = jax.lax.scan(
-        body, (x, k_pages, v_pages),
-        (precast_params(params["blocks"], compute_dtype),
-         jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+    stats0 = ([jnp.zeros((cfg.moe.num_experts + 1,), jnp.int32)]
+              if return_moe_stats else [])
+    (x, new_k, new_v, *stats), _ = jax.lax.scan(
+        body, (x, k_pages, v_pages, *stats0),
+        (blocks, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
 
     x = rms_norm(x, params["final_norm"]["scale"].astype(x.dtype), cfg.norm_eps)
     if cfg.tie_word_embeddings:
@@ -246,7 +272,7 @@ def extend_step_forward(
         logits = jnp.einsum("bth,hv->btv", x,
                             params["lm_head"]["kernel"].astype(x.dtype),
                             preferred_element_type=jnp.float32)
-    return logits.astype(jnp.float32), new_k, new_v
+    return (logits.astype(jnp.float32), new_k, new_v, *stats)
 
 
 def decode_multi_step(
@@ -298,24 +324,32 @@ def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
                 stop_positions, slot_keys, temperature, top_k, top_p,
                 cfg: ModelConfig, num_steps: int, attn_impl: str = "auto",
                 write_mode: str = "paged", w4_kernel_ok: bool = True,
-                w8_kernel_ok: bool = False):
+                w8_kernel_ok: bool = False,
+                return_moe_stats: bool = False):
     """The decode+sample scan shared by ``decode_multi_step`` and the fused
     speculative dispatch (speculative.verify_and_decode). Returns
-    ((tokens, positions, k_pages, v_pages), toks_seq [K, B])."""
+    ((tokens, positions, k_pages, v_pages), toks_seq [K, B]); with
+    ``return_moe_stats`` (MoE models) the carry ends in the steps' summed
+    ``moe_stats`` (see ``extend_step_forward``)."""
     from .sampling import sample_tokens
+    return_moe_stats = return_moe_stats and cfg.is_moe
 
     def one(carry, _):
-        toks, pos, kp, vp = carry
+        toks, pos, kp, vp, *stats = carry
         act = pos < stop_positions
-        logits, kp, vp = decode_step_forward(
+        logits, kp, vp, *step_stats = decode_step_forward(
             params, toks, pos, kp, vp, block_tables, cfg, active=act,
             attn_impl=attn_impl, write_mode=write_mode,
-            w4_kernel_ok=w4_kernel_ok, w8_kernel_ok=w8_kernel_ok)
+            w4_kernel_ok=w4_kernel_ok, w8_kernel_ok=w8_kernel_ok,
+            return_moe_stats=return_moe_stats)
         keys = jax.vmap(jax.random.fold_in)(
             jax.vmap(jax.random.wrap_key_data)(slot_keys), pos + 1)
         nxt = sample_tokens(logits, keys, temperature, top_k, top_p)
         nxt = jnp.where(act, nxt, toks)
-        return (nxt, pos + 1, kp, vp), nxt
+        stats = [a + b for a, b in zip(stats, step_stats)]
+        return (nxt, pos + 1, kp, vp, *stats), nxt
 
-    return jax.lax.scan(one, (tokens, positions, k_pages, v_pages), None,
-                        length=num_steps)
+    stats0 = ([jnp.zeros((cfg.moe.num_experts + 1,), jnp.int32)]
+              if return_moe_stats else [])
+    return jax.lax.scan(one, (tokens, positions, k_pages, v_pages, *stats0),
+                        None, length=num_steps)

@@ -174,6 +174,14 @@ class InferenceEngine:
             from ..config.schema import ParallelConfig
             from ..parallel.mesh import build_mesh
             from ..parallel.sharding import shard_params
+            if model_cfg.is_moe:
+                # the grouped-matmul kernel (ops/moe_gmm.py) is a custom
+                # call GSPMD cannot partition, and jax.lax.ragged_dot over
+                # tp-sharded expert stacks has never run on the chip
+                raise ValueError(
+                    f"tensor_parallel={tp} with an MoE model is refused: "
+                    "dropless MoE serving has only run on one chip "
+                    "(ROADMAP B1: ep=4 or a tp smoke of ragged_dot first)")
             if model_cfg.num_kv_heads % tp or model_cfg.num_heads % tp:
                 raise ValueError(
                     f"tensor_parallel={tp} must divide num_heads="
@@ -348,6 +356,14 @@ class InferenceEngine:
                                    donate_argnums=(1, 2))
                           if serve_cfg.speculative == "ngram" else None)
         self.total_decode_steps = 0
+        # MoE routing counters (live tokens only; idle slots and prefill
+        # padding get no expert): choices per expert, (layer, step) expert
+        # hits and the (layer, step) pairs they are out of; the decode
+        # programs' part of both again, for the decode step's byte floor.
+        # Read off the fetch a step makes anyway (_count_moe).
+        self.moe_choices = np.zeros(model_cfg.moe.num_experts, np.int64)
+        self.moe_experts_hit = self.moe_layer_steps = 0
+        self.moe_decode_experts_hit = self.moe_decode_layer_steps = 0
         self.total_prefill_tokens = 0      # tokens actually computed
         self.total_prefix_cached_tokens = 0  # prompt tokens skipped via cache
         # of the cached tokens, the ones on fleet-requeued orphans (warm-
@@ -610,10 +626,18 @@ class InferenceEngine:
             def prefill(params, tokens, length, k_pages, v_pages, entries,
                         key, temp, top_k, top_p):
                 zeros = gpt.init_kv_cache(cfg, 1, bucket, dtype=dtype)
-                logits, (kd, vd) = gpt.forward(
+                moe = {}
+                if cfg.is_moe:
+                    # the bucket's padding is not live: it gets no expert
+                    # and is not counted (segment id 0; the cached
+                    # attention route masks by length and ignores it)
+                    moe = {"return_moe_stats": True, "segment_ids": (
+                        jnp.arange(bucket, dtype=jnp.int32)[None]
+                        < length[:, None]).astype(jnp.int32)}
+                logits, (kd, vd), *moe_stats = gpt.forward(
                     params, tokens, cfg, kv_cache=zeros,
                     cache_offset=jnp.zeros((1,), jnp.int32),
-                    unembed_positions=length - 1)
+                    unembed_positions=length - 1, **moe)
                 # dense [L, bucket, Nkv, D] -> paged [L, n_pages, Nkv, PS, D]
                 kd = kd[:, 0].reshape(
                     cfg.num_layers, n_pages, self.kv.page_size,
@@ -650,6 +674,8 @@ class InferenceEngine:
                 v_pages = scatter(v_pages, vd)
                 token = sample_tokens(logits[:, 0], key[None], temp[None],
                                       top_k[None], top_p[None])[0]
+                if moe_stats:
+                    token = self._with_moe_stats(token, moe_stats[0])
                 return token, k_pages, v_pages
 
             self._prefill_cache[bucket] = _Program(
@@ -670,16 +696,19 @@ class InferenceEngine:
                                table, key, temp, top_k, top_p):
                 write_ok = (jnp.arange(bucket, dtype=jnp.int32)[None]
                             < m[:, None])
-                logits, k_pages, v_pages = extend_step_forward(
+                logits, k_pages, v_pages, *moe_stats = extend_step_forward(
                     params, tokens, start, k_pages, v_pages, table, cfg,
                     write_ok=write_ok, attn_impl=self._attn_impl,
                     write_mode=self._extend_write,
                     w4_kernel_ok=self._w4_kernel_ok,
-                    w8_kernel_ok=self._w8_kernel_ok)
+                    w8_kernel_ok=self._w8_kernel_ok,
+                    return_moe_stats=True)
                 last = jnp.take_along_axis(
                     logits, (m - 1)[:, None, None], axis=1)[:, 0]   # [1, V]
                 token = sample_tokens(last, key[None], temp[None],
                                       top_k[None], top_p[None])[0]
+                if moe_stats:
+                    token = self._with_moe_stats(token, moe_stats[0])
                 return token, k_pages, v_pages
 
             self._prefill_cache[key_] = _Program(
@@ -700,6 +729,8 @@ class InferenceEngine:
                              table):
                 write_ok = (jnp.arange(bucket, dtype=jnp.int32)[None]
                             < m[:, None])
+                # (returns no token, so an MoE model's mid-prompt chunks
+                # have no fetch to carry their routing counts: not counted)
                 _, k_pages, v_pages = extend_step_forward(
                     params, tokens, start, k_pages, v_pages, table, cfg,
                     write_ok=write_ok, attn_impl=self._attn_impl,
@@ -712,6 +743,26 @@ class InferenceEngine:
                 f"prefill chunk {bucket}", extend_chunk,
                 self.failed_programs, donate_argnums=(4, 5))
         return self._prefill_cache[key_]
+
+    @staticmethod
+    def _with_moe_stats(token, moe_stats):
+        """A prefill program's first token and its routing counts as ONE
+        int32 vector [token, choices (E), experts hit]: the counts reach
+        the host on the fetch of the token, with no sync of their own."""
+        return jnp.concatenate([token.reshape(1).astype(jnp.int32),
+                                moe_stats])
+
+    def _count_moe(self, moe_stats: np.ndarray, steps: int,
+                   decode: bool) -> None:
+        """Add one program's [choices (E), experts hit] (summed over its
+        layers and ``steps`` steps) to the engine's counters."""
+        hit, layer_steps = int(moe_stats[-1]), steps * self.cfg.num_layers
+        self.moe_choices += moe_stats[:-1]
+        self.moe_experts_hit += hit
+        self.moe_layer_steps += layer_steps
+        if decode:
+            self.moe_decode_experts_hit += hit
+            self.moe_decode_layer_steps += layer_steps
 
     @engine_thread_only
     def _maybe_fetch_prefix(self, req: Request) -> None:
@@ -1200,7 +1251,12 @@ class InferenceEngine:
         slot live for decode."""
         with self.spans.phase("llmctl.engine.prefill.wait",
                               request_id=req.request_id):
-            token = int(token)
+            if self.cfg.is_moe:
+                fetched = np.asarray(token)
+                token = int(fetched[0])
+                self._count_moe(fetched[1:], steps=1, decode=False)
+            else:
+                token = int(token)
         self.spans.fetched()
         with self.spans.phase("llmctl.engine.apply"):
             ctx = req.context_tokens   # BEFORE recording the new token
@@ -1219,13 +1275,15 @@ class InferenceEngine:
         # the final scan carry (tokens, positions) comes back as DEVICE
         # arrays so a pipelined follow-up dispatch can chain on them
         # without a host round trip (step() pipelining below)
-        (toks, pos, k_pages, v_pages), toks_seq = decode_scan(
+        # an MoE model's program also returns its routing counts
+        # (decode_scan's moe_stats), fetched with the tokens
+        (toks, pos, k_pages, v_pages, *moe_stats), toks_seq = decode_scan(
             params, tokens, positions, k_pages, v_pages, tables, stops,
             slot_keys, temp, top_k, top_p, self.cfg, self._decode_unit_len,
             attn_impl=self._attn_impl, write_mode=self._extend_write,
             w4_kernel_ok=self._w4_kernel_ok,
-            w8_kernel_ok=self._w8_kernel_ok)
-        return toks_seq, toks, pos, k_pages, v_pages
+            w8_kernel_ok=self._w8_kernel_ok, return_moe_stats=True)
+        return (toks_seq, toks, pos, k_pages, v_pages, *moe_stats)
 
     def _short_dispatch_ok(self) -> bool:
         """Should the next decode dispatch run the SHORT program? (caller
@@ -1304,12 +1362,13 @@ class InferenceEngine:
             positions = jnp.asarray(self.positions)
         if shared is None:
             shared = self._shared_decode_args()
-        sampled_seq, next_toks, next_pos, self.kv.k_pages, self.kv.v_pages \
-            = self._decode_jit(
+        (sampled_seq, next_toks, next_pos, self.kv.k_pages, self.kv.v_pages,
+         *moe_stats) = self._decode_jit(
                 self.params, self.kv.k_pages, self.kv.v_pages,
                 tokens, positions, *shared)
         return {
-            "sampled": sampled_seq, "next_tokens": next_toks,
+            "sampled": sampled_seq, "moe_stats": moe_stats,
+            "next_tokens": next_toks,
             "next_positions": next_pos,
             "req_ids": [r.request_id if r is not None else None
                         for r in self.scheduler.slots],
@@ -1350,8 +1409,13 @@ class InferenceEngine:
         with self.spans.phase(
                 "llmctl.engine.decode.wait",
                 steps=len(group["units"]) * self._decode_unit_len):
-            arrs = jax.device_get([u["sampled"] for u in group["units"]])
+            arrs, moe_stats = jax.device_get(
+                ([u["sampled"] for u in group["units"]],
+                 [u["moe_stats"] for u in group["units"]]))
         self.spans.fetched()
+        for unit_stats in moe_stats:
+            for st in unit_stats:       # none for a dense model
+                self._count_moe(st, steps=self._decode_unit_len, decode=True)
         out = np.concatenate([np.asarray(a) for a in arrs], axis=0)
         self.total_decode_steps += out.shape[0]
         self.total_padded_slot_steps += out.shape[0] * int(
@@ -2015,7 +2079,7 @@ class InferenceEngine:
                     jnp.int32(0), jnp.float32(1.0))
             token, kp, vp = fn(self.params, tokens, *args)   # warm/compile
             self.kv.k_pages, self.kv.v_pages = kp, vp
-            int(token)
+            np.asarray(token)
             t0 = time.perf_counter()
             for _ in range(iters):
                 token, kp, vp = fn(self.params, tokens,
@@ -2024,7 +2088,7 @@ class InferenceEngine:
                                    jnp.float32(0.0), jnp.int32(0),
                                    jnp.float32(1.0))
                 self.kv.k_pages, self.kv.v_pages = kp, vp
-            int(token)                                        # one fence
+            np.asarray(token)                                 # one fence
             out["prefill_ms"][bucket] = (time.perf_counter() - t0) \
                 / iters * 1e3
         # decode: K steps per dispatch, all slots
@@ -2039,13 +2103,13 @@ class InferenceEngine:
                  jnp.ones(self.serve_cfg.max_batch_size, jnp.float32),
                  jnp.zeros(self.serve_cfg.max_batch_size, jnp.int32),
                  jnp.ones(self.serve_cfg.max_batch_size, jnp.float32))
-        sampled, _, _, kp, vp = self._decode_jit(
+        sampled, _, _, kp, vp, *_ = self._decode_jit(
             self.params, kp, vp, zeros_i, zeros_i, *dargs)
         self.kv.k_pages, self.kv.v_pages = kp, vp
         np.asarray(sampled)
         t0 = time.perf_counter()
         for _ in range(iters):
-            sampled, _, _, kp, vp = self._decode_jit(
+            sampled, _, _, kp, vp, *_ = self._decode_jit(
                 self.params, kp, vp, zeros_i, zeros_i, *dargs)
             self.kv.k_pages, self.kv.v_pages = kp, vp
         np.asarray(sampled)
@@ -2110,6 +2174,13 @@ class InferenceEngine:
             "spec_acceptance": round(
                 self.total_spec_accepted / max(self.total_spec_drafts, 1), 4),
             "compiled_programs": self.compiled_programs(),
+            **({"moe": {
+                "choices": self.moe_choices.tolist(),
+                "experts_hit": self.moe_experts_hit,
+                "layer_steps": self.moe_layer_steps,
+                "decode_experts_hit": self.moe_decode_experts_hit,
+                "decode_layer_steps": self.moe_decode_layer_steps,
+            }} if self.cfg.is_moe else {}),
             # cumulative, with their own clock: "clock_s", "phases"
             # ({span: {"s": self seconds, "n": calls}}), "starved_s"
             **self.spans.snapshot(),

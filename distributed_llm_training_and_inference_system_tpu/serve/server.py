@@ -380,6 +380,7 @@ class InferenceServer:
             # histogram and the engine's spans, not this request's own
             "queue_wait_ms": st["queue_wait_ms"],
             "phases": st["phases"],
+            "moe_choices": st.get("moe", {}).get("choices", ()),
             "preemptions": st["preemptions"],
             "swap_ins": st["swap_ins"],
             "swapped_host_bytes": st["swapped_host_bytes"],

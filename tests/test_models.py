@@ -113,12 +113,14 @@ def test_moe_forward_and_grads():
     cfg = get_model_config("gpt-test-moe")
     params = init(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
-    logits, aux = forward(params, tokens, cfg, return_aux=True)
+    logits, aux = forward(params, tokens, cfg, return_aux=True,
+                          moe_impl="capacity")
     assert logits.shape == (2, 16, cfg.vocab_size)
     assert float(aux) > 0.0  # router aux loss is live
 
     def loss_fn(p):
-        lg, aux = forward(p, tokens, cfg, return_aux=True)
+        lg, aux = forward(p, tokens, cfg, return_aux=True,
+                              moe_impl="capacity")
         return next_token_loss(lg, tokens)[0] + aux
 
     grads = jax.grad(loss_fn)(params)
@@ -181,7 +183,7 @@ def test_moe_sort_dispatch_matches_onehot(capacity_factor):
     import dataclasses
 
     from distributed_llm_training_and_inference_system_tpu.models.layers import (
-        moe_block)
+        moe_block_capacity)
     cfg = get_model_config("gpt-test-moe")
     cfg = dataclasses.replace(
         cfg, moe=dataclasses.replace(cfg.moe,
@@ -191,7 +193,7 @@ def test_moe_sort_dispatch_matches_onehot(capacity_factor):
                                    params["blocks"]["moe"])
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 16, cfg.hidden_size),
                           jnp.float32)
-    got, _ = moe_block(x, layer, cfg)
+    got, _ = moe_block_capacity(x, layer, cfg)
     want = _moe_block_onehot_reference(x, layer, cfg)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -215,7 +217,8 @@ def test_moe_learns_under_tight_capacity():
     @jax.jit
     def step(p):
         def loss_fn(p):
-            lg, aux = forward(p, tokens, cfg, return_aux=True)
+            lg, aux = forward(p, tokens, cfg, return_aux=True,
+                              moe_impl="capacity")
             return next_token_loss(lg, tokens)[0] + aux
         l, g = jax.value_and_grad(loss_fn)(p)
         return l, jax.tree_util.tree_map(lambda w, gr: w - 0.05 * gr, p, g)

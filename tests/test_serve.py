@@ -632,8 +632,10 @@ class TestMultiStepDecode:
 
 class TestMoEServing:
     """Serving an MoE model: the decode/extend bodies route through
-    moe_block (token-choice top-k experts) — greedy must match the dense
-    training-side forward exactly, like the dense-model tests above."""
+    moe_block (token-choice top-k experts, dropless since PR 27, as the
+    training-side forward's default is) — greedy must match that forward
+    exactly, like the dense-model tests above. Against an independent
+    reference: tests/test_olmoe.py."""
 
     def test_moe_greedy_matches_dense(self):
         cfg = get_model_config("gpt-test-moe")

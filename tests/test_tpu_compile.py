@@ -175,6 +175,73 @@ def test_decode_program_updates_pool_in_place(one_chip, as_tpu, kv):
         f"layer's K pool is {layer_pool_bytes / 1e6:.1f} MB")
 
 
+@pytest.mark.parametrize("rows,tm", [(256, 16), (4096, 64), (8192, 128)])
+@pytest.mark.parametrize("which", ["gate_up", "down"])
+def test_moe_grouped_matmul_kernel_compiles(one_chip, as_tpu, rows, tm, which):
+    """The dropless MoE block's grouped matmul at OLMoE's published widths
+    (64 experts, 2048 x 1024) on the ten-layer expert stack as it lies, with
+    a traced layer index: the decode step's 32 tokens x 8 choices, and the
+    512- and 1,024-token prefill buckets. The stack is an operand WHOLE:
+    nothing expert-sized may be a temporary."""
+    from distributed_llm_training_and_inference_system_tpu.ops.moe_gmm import (
+        grouped_matmul)
+    E, H, F, L = 64, 2048, 1024, 10
+    k, n = (H, F) if which == "gate_up" else (F, H)
+    n_tiles = (rows + E * (tm - 1)) // tm
+    sds = _sds(one_chip)
+    compiled = _compile(
+        functools.partial(grouped_matmul, tm=tm),
+        sds((n_tiles * tm, k), jnp.bfloat16), sds((L, E, k, n), jnp.bfloat16),
+        sds((n_tiles,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32))
+    assert "moe_gmm" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < E * k * n * 2 // 8, f"{temp / 1e6:.1f} MB of temporaries"
+
+
+def test_olmoe_decode_program_takes_the_expert_stacks_whole(one_chip, as_tpu):
+    """The multi-step decode program of an OLMoE-shaped model (published
+    widths, 3 layers, 8 slots): the experts' [L, E, H, F] stacks stay
+    outside the layer scan and the kernel indexes them, so the program
+    holds no layer's 805 MB of experts as a temporary, and returns the
+    routing counts beside the tokens."""
+    import dataclasses
+
+    from distributed_llm_training_and_inference_system_tpu.config.presets import (
+        get_model_config)
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        decode_scan)
+    cfg = dataclasses.replace(get_model_config("olmoe-1b-7b"), num_layers=3,
+                              dtype="bfloat16")
+    sds = _sds(one_chip)
+    B, num_pages = 8, 8 * MAXP + 1
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda k: gpt.init(cfg, k, jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+    pool = sds((cfg.num_layers, num_pages, cfg.num_kv_heads, PS, D),
+               jnp.bfloat16)
+
+    def program(params, k_pages, v_pages, tokens, positions, tables, stops,
+                keys, temp, top_k, top_p):
+        return decode_scan(params, tokens, positions, k_pages, v_pages,
+                           tables, stops, keys, temp, top_k, top_p, cfg, 8,
+                           return_moe_stats=True)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    compiled = jax.jit(program, donate_argnums=(1, 2)).lower(
+        params, pool, pool, i32(B), i32(B), i32(B, MAXP), i32(B),
+        sds((B, 2), jnp.uint32), sds((B,), jnp.float32), i32(B),
+        sds((B,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "paged_attention" in text
+    one_layer_of_experts = 64 * 3 * 2048 * 1024 * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < one_layer_of_experts // 4, (
+        f"decode program holds {temp / 1e6:.1f} MB of temporaries; one "
+        f"layer's experts are {one_layer_of_experts / 1e6:.1f} MB")
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("shape", ["gpt-750m-b4", "gqa32x8-b2"])
 def test_flash_attention_compiles(one_chip, as_tpu, shape, grad):
