@@ -815,6 +815,27 @@ def child_kernels() -> None:
                   kernel(q, kp, vp, tables, lengths),
                   gather(q, kp, vp, tables, lengths))
 
+    # -- paged attention, the serving cells' batch --------------------------
+    # 32 slots of ragged lengths (the benchmark's 32-1,408 tokens), every
+    # fourth one idle (position 0, a table of scratch pages), one ending on
+    # a page boundary and one filling its table: the lengths the kernel's
+    # page loop and its copies across slots are bounded by
+    B = 4 if small else 32
+    for lname, (nq, nkv) in layouts.items():
+        kp, vp, tables = pages(nkv, B, "bf16")
+        q = jax.random.normal(next(key), (B, nq, D), jnp.bfloat16)
+        lengths = jax.random.randint(next(key), (B,), PS // 2,
+                                     min(22, MAXP) * PS + 1)
+        lengths = lengths.at[1].set(2 * PS).at[2].set(MAXP * PS)
+        idle = jnp.arange(B) % 4 == 3
+        lengths = jnp.where(idle, 1, lengths)
+        tables = jnp.where(idle[:, None], 0, tables)
+        kernel, gather = kernel_and_gather(paged_attention)
+        check(f"paged_attention decode {lname} {B} ragged slots, "
+              f"{int(idle.sum())} idle",
+              kernel(q, kp, vp, tables, lengths),
+              gather(q, kp, vp, tables, lengths))
+
     # -- paged attention, multi query --------------------------------------
     windows = ([(8, "mha4", "bf16"), (32, "mha4", "bf16"),
                 (32, "gqa4x2", "int8")] if small else

@@ -142,11 +142,14 @@ def load_profile(path: str) -> dict:
     "host_spans": {thread: [(name, s, e)]}} in seconds, from an
     ``.xplane.pb``. Devices are the ``/device:`` planes (lines ``XLA
     Modules`` and ``XLA Ops``); host spans are the ``llmctl.*`` events of
-    every other plane, by the line (the thread) they were opened on."""
+    every other plane, by the line (the thread) they were opened on.
+    "page_walk" sums the ``live_pages`` / ``table_pages`` ids that every
+    decode dispatch's span carries (serve/engine.py ``_submit_group``)."""
     from jax.profiler import ProfileData
     profile = ProfileData.from_file(str(path))
     devices: dict = {}
     host_spans: dict = {}
+    page_walk = {"live_pages": 0, "table_pages": 0}
     for plane in profile.planes:
         is_device = plane.name.startswith("/device:")
         for line in plane.lines:
@@ -157,14 +160,21 @@ def load_profile(path: str) -> dict:
                              (e.start_ns + e.duration_ns) * 1e-9)
                             for e in line.events]
             elif not is_device:
-                found = [(e.name, e.start_ns * 1e-9,
-                          (e.start_ns + e.duration_ns) * 1e-9)
-                         for e in line.events
-                         if e.name.startswith(SPAN_PREFIX)]
+                found = []
+                for e in line.events:
+                    if not e.name.startswith(SPAN_PREFIX):
+                        continue
+                    found.append((e.name, e.start_ns * 1e-9,
+                                  (e.start_ns + e.duration_ns) * 1e-9))
+                    if e.name == SPAN_PREFIX + "engine.decode.submit":
+                        for key, value in e.stats:
+                            if key in page_walk:
+                                page_walk[key] += int(value)
                 if found:
                     host_spans.setdefault(
                         f"{plane.name} | {line.name}", []).extend(found)
-    return {"devices": devices, "host_spans": host_spans}
+    return {"devices": devices, "host_spans": host_spans,
+            "page_walk": page_walk}
 
 
 def _union(intervals) -> list:
@@ -298,6 +308,12 @@ def summarize(trace_dir):
                        f"idle)")
         click.echo(f"  {100 * acc['idle_named_share']:.1f} % of the idle "
                    f"seconds lie under a named {SPAN_PREFIX}* span")
+    walk = loaded["page_walk"]
+    if walk["table_pages"]:
+        click.echo(f"paged attention walks {walk['live_pages']} live pages "
+                   f"of {walk['table_pages']} in the block tables "
+                   f"({100 * walk['live_pages'] / walk['table_pages']:.1f} "
+                   f"%), summed over the decode dispatches")
     if spans:
         click.echo("host spans (calls, self seconds):")
         for name, (n, sec) in sorted(host_span_totals(spans).items(),

@@ -419,6 +419,9 @@ COUNTER_FLOW: tuple[CounterFlow, ...] = (
                 "partial_restores", None),
     CounterFlow("InferenceEngine", "total_padded_slot_steps",
                 "padded_slot_steps", None),
+    CounterFlow("InferenceEngine", "total_live_pages", "live_pages", None),
+    CounterFlow("InferenceEngine", "total_table_pages", "table_pages",
+                None),
     CounterFlow("InferenceEngine", "total_spec_dispatches",
                 "spec_dispatches", "llmctl_fleet_spec_dispatches"),
     CounterFlow("InferenceEngine", "total_spec_drafts", "spec_drafts",

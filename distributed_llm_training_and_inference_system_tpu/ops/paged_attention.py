@@ -78,9 +78,10 @@ class QuantPages(QuantTensor):
     Pallas scale block a [Nkv, PS, 1] ref — a degenerate 1-wide lane tile
     Mosaic pads to a full [8, 128] vector register per scale — and every
     whole-page merge had to carry the dangling axis. [..., Nkv, PS] makes
-    the per-page scale block a clean [Nkv, PS] tile that rides the SAME
-    block-table index map as its page, so the fused decode kernel DMAs
-    (page, scales) together and dequantizes in VMEM.
+    the per-page scale block a clean [Nkv, PS] tile: the decode kernel
+    gets a slot's tiles gathered through its block table (a 64-wide
+    minor dimension cannot be sliced out of HBM by the kernel's own
+    page copies) and dequantizes in VMEM.
 
     The (values, scale) pytree mechanics come from QuantTensor; the
     distinct TYPE keeps page buffers out of ``cast_params``' weight-dequant
@@ -114,7 +115,7 @@ class Int4Pages(QuantPages):
 
     Packing along the PAGE-SLOT axis (not head_dim) keeps D minor, so
     the Pallas page tile stays a clean [Nkv, PS/2, D] 128-lane block
-    riding the same block-table index map, and unpack in VMEM is a
+    that the kernel copies like any other page, and unpack in VMEM is a
     sublane relabel (ops.quantization.unpack_int4_rows) — the KV-side
     twin of the weight kernels' [.., in/2, out] layout lesson.
 

@@ -1,33 +1,39 @@
-"""Pallas TPU kernel for paged decode attention: stream pages HBM->VMEM.
+"""Pallas TPU kernel for paged attention: stream a slot's LIVE pages
+HBM->VMEM.
 
 The gather baseline (ops/paged_attention.py) materialises every slot's full
-[Nkv, maxP*PS, D] KV prefix in HBM each decode step — O(max_seq) traffic per
+[Nkv, maxP*PS, D] KV prefix in HBM each decode step: O(max_seq) traffic per
 token regardless of the sequence's actual length. This kernel reads only the
-pages a sequence owns:
+pages a sequence owns, and spends time only on them:
 
-- Grid (B, query tiles, maxP), page index innermost. The pools
-  [L, NP, Nkv, PS, D] come in WHOLE and stay in HBM; each grid step's
-  BlockSpec uses the scalar-prefetched layer index and block table to DMA
-  one physical page of that layer — ALL kv heads, [Nkv, PS, D] — into VMEM
-  (``PrefetchScalarGridSpec`` — the pallas_guide.md pattern for
-  data-dependent addressing). Pallas double-buffers the copies,
-  overlapping page DMA with compute. Heads are folded into one dot pair
-  per page (cross-head blocks masked): the earlier (B, Nkv, maxP) grid
-  paid ~10 us of pipeline overhead per [1,128]x[128,64] dot at MHA decode
-  — 12.3 ms of a 24.2 ms gpt-1b decode step (round-3 ablation,
-  BASELINE.md).
-- Pages past a sequence's live length are CLAMPED to its last used page in
-  the index map. Consecutive identical block indices elide the re-fetch
-  entirely (the pipeline emitter skips the DMA), so per-token HBM traffic is
-  proportional to the sequence's true length — the whole point of paging.
+- Grid (B, query tiles): one grid step a slot and query tile. The pools
+  [L, NP, Nkv, PS, D] come in WHOLE and stay in HBM (never a layer's
+  slice); the layer index, the block table and the start positions ride
+  the scalar prefetch (``PrefetchScalarGridSpec``).
+- Inside a grid step a loop walks the pages the tile's queries can see,
+  live = ceil((start + tile) / PS) of them and no more, so the trip count
+  follows the slot's length and not the block table's width: table entries
+  past the live length are never looked at. (With the page axis in the grid
+  a call paid ~0.15 us for each of its B * maxP steps, live or not: 155 us
+  of a 200 us call at 32 slots x 32 pages.)
+- The body copies ``pages[layer, table[b, p]]``, ALL kv heads [Nkv, PS, D],
+  into a ring of ``_PAGES_AHEAD + 1`` VMEM buffers with
+  ``pltpu.make_async_copy``. The copies run ``_PAGES_AHEAD`` pages ahead of
+  the page being scored, in grid order and ACROSS grid steps: while a slot's
+  last pages are scored the next slot's first ones are already on their
+  way, so a copy's ~0.5 us latency is hidden even where every slot holds
+  one page. Every grid step fetches at least the page its table names first
+  (an idle slot sits at position 0 and sees one token of it), which keeps
+  that order free of any search for the next slot with work.
 - Online softmax in fp32 VMEM scratch across pages (same recurrence as the
   training-side flash kernel); GQA folds the q-head group into the tile,
-  and head folding means each KV page is loaded ONCE per slot — not per
+  and head folding means each KV page is loaded ONCE per slot, not per
   kv head, let alone per q head.
 
-Numerics match ops.paged_attention.paged_attention (the gather baseline) —
-asserted in tests/test_serve.py. The baseline remains the CPU/interpret
-fallback.
+Numerics match ops.paged_attention.paged_attention (the gather baseline),
+asserted in tests/test_ops.py for every page type, head layout and the
+lengths a loop bound can get wrong. The baseline remains the CPU /
+``tp > 1`` / ``head_dim % 128`` route.
 
 Reference defect this replaces: the dead KVCacheManager + full-prefix
 recompute at reference serve/server.py:57-87,199-204.
@@ -46,78 +52,125 @@ from ..models.layers import NEG_INF
 
 
 def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
-                   *refs,                          # see unpack below
+                   q_ref, *refs,
                    page_size: int, scale: float, groups: int,
-                   window: int, num_kv: int, kv_quant: str):
-    """Multi-query variant: ``window`` consecutive query tokens per slot
-    (speculative verify / cached-prefix suffix prefill). Each page is
-    DMA'd ONCE per slot and scored against all T queries of ALL kv heads —
-    the flattened-row fallback re-streams the prefix T times. Query row
-    j (= row // groups within a head) sits at position start + j and
-    attends causally over [0, start + j].
+                   window: int, queries: int, num_kv: int, kv_quant: str):
+    """One grid step: one slot's query tile against that slot's LIVE pages.
 
-    Head folding (round-3 redesign): the original grid (B, Nkv, maxP) ran
-    one [T*G, D] x [D, PS] dot per grid step — at MHA decode (T=G=1)
-    that is a [1,128]x[128,64] dot per step and 1,280 grid steps/layer,
-    measured 12.3 ms of a 24.2 ms decode step in pure per-step pipeline
-    overhead (the data floor is ~1.2 ms). This kernel folds ALL kv heads
-    into one grid step: q rows [Nkv*T*G, D] against the whole page
-    [Nkv*PS, D] in ONE dot pair per page. Cross-head score blocks are
-    masked to NEG_INF, so their post-softmax probabilities are exactly
-    zero and the folded AV dot needs no block-diagonal bookkeeping. The
-    dot does Nkv x the useful FLOPs, but decode attention FLOPs are
-    trivia next to per-grid-step overhead (16 GFLOPs/step at gpt-1b B=8
-    vs a ~100 us MXU budget).
+    ``refs``: (the slot's [maxP, Nkv, PS] scale tiles for K and V, when the
+    pages are quantised,) the K and V pools (HBM, whole), the output block,
+    the float32 online-softmax scratch (acc, m, l), the K and V rings of
+    page buffers in VMEM, the DMA semaphores [K / V, buffer] and the SMEM
+    cells that carry the ring's state from one grid step to the next.
+
+    Query row j (= row // groups within a head) of the tile sits at
+    position start + j and attends causally over [0, start + j], so the
+    tile needs the pages that hold the start + window tokens its last
+    query sees, and the page loop runs that many times. Each iteration starts the copy of the
+    page ``_PAGES_AHEAD`` places further on in grid order (this tile's, or
+    the next grid step's once this tile's are all under way), waits for
+    its own page and scores it. A slot at length 0 waits for the one page
+    fetched for it and scores nothing.
+
+    Head folding: q rows [Nkv*T*G, D] against the whole page [Nkv*PS, D]
+    in ONE dot pair per page. Cross-head score blocks are masked to
+    NEG_INF, so their post-softmax probabilities are exactly zero and the
+    folded AV dot needs no block-diagonal bookkeeping. The dot does Nkv x
+    the useful FLOPs; a dot pair per head would load as many MXU weight
+    tiles for an Nkv-th of the rows each.
 
     ``kv_quant``: "int8" pages carry a per-page [Nkv, PS] scale tile
-    (one row scale per token — QuantPages layout); "int4" pages pack two
+    (one row scale per token, QuantPages layout); "int4" pages pack two
     page slots per byte along the slot axis ([Nkv, PS/2, D] uint8 tile,
-    Int4Pages) with the SAME scale tile. Either way dequant happens in
-    VMEM right before the fp32 dot, so HBM page traffic is halved
-    (int8) or quartered (int4) — the whole point of the quantized KV
-    cache."""
-    # (layer_ref is for the index maps alone: they pick the layer's page)
+    Int4Pages) with the SAME scale tile. Dequantisation happens in VMEM
+    right before the float32 dot, so HBM page traffic is halved (int8) or
+    quartered (int4)."""
     if kv_quant != "none":
-        (q_ref, k_ref, ks_ref, v_ref, vs_ref,
-         o_ref, acc_ref, m_ref, l_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    b = pl.program_id(0)
-    p = pl.program_id(2)
+        ks_ref, vs_ref, *refs = refs
+    (k_hbm, v_hbm, o_ref, acc_ref, m_ref, l_ref,
+     k_buf, v_buf, sems, ring_ref) = refs
+    b, t = pl.program_id(0), pl.program_id(1)
+    n_slots, n_tiles = pl.num_programs(0), pl.num_programs(1)
+    max_pages = tables_ref.shape[1]
+    n_bufs = k_buf.shape[0]
     tg = window * groups                  # query rows per kv head
     d = q_ref.shape[-1]
+    layer = layer_ref[0]
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def visible(slot, tile):
+        # tokens the tile's last query sees (the rows that pad the last
+        # tile of a window are not queries: they see what it sees)
+        return starts_ref[slot] + jnp.minimum((tile + 1) * window, queries)
+
+    def live_pages(slot, tile):
+        # the pages holding them; at least the one fetched
+        return jnp.clip((visible(slot, tile) + page_size - 1) // page_size,
+                        1, max_pages)
+
+    def page_copies(slot, p, buf):
+        page = tables_ref[slot, p]
+        return [pltpu.make_async_copy(pool.at[layer, page], ring.at[buf],
+                                      sems.at[i, buf])
+                for i, (pool, ring) in enumerate(((k_hbm, k_buf),
+                                                  (v_hbm, v_buf)))]
+
+    def next_buffer(buf):
+        return jnp.where(buf + 1 == n_bufs, 0, buf + 1)
+
+    def fetch_next(lead):
+        """Start the copy of the page the lead stands on, if any is left,
+        and move the lead on in grid order: a tile's pages, a slot's
+        tiles, the slots."""
+        slot, tile, p, buf = lead
+
+        @pl.when(slot < n_slots)
+        def _start():
+            for copy in page_copies(slot, p, buf):
+                copy.start()
+
+        tile_done = p + 1 >= live_pages(jnp.minimum(slot, n_slots - 1), tile)
+        slot_done = tile_done & (tile + 1 >= n_tiles)
+        return (jnp.where(slot_done, slot + 1, slot),
+                jnp.where(slot_done, 0, jnp.where(tile_done, tile + 1, tile)),
+                jnp.where(tile_done, 0, p + 1),
+                next_buffer(buf))
+
+    # the ring's state rides SMEM from one grid step to the next: where
+    # the lead stands (slot, tile, page, buffer) and which buffer holds
+    # the next page to score
+    @pl.when((b == 0) & (t == 0))
+    def _prime():
+        lead = (jnp.int32(0),) * 4
+        for _ in range(n_bufs - 1):
+            lead = fetch_next(lead)
+        for i, x in enumerate((*lead, jnp.int32(0))):
+            ring_ref[i] = x
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
     # this grid step's query tile: ``window`` rows starting at
     # start + tile * window (one tile unless the wrapper split a long
-    # suffix-prefill window — see _query_tile)
-    start = starts_ref[b] + pl.program_id(1) * window
-    max_len = start + window             # last tile token's length
+    # suffix-prefill window, see _query_tile)
+    start = starts_ref[b] + t * window
+    max_len = visible(b, t)
 
-    @pl.when(p * page_size < max_len)
-    def _body():
+    def score_page(p, buf):
         q = q_ref[...].astype(jnp.float32).reshape(num_kv * tg, d)
-        if kv_quant == "int4":
-            # shared nibble math (ops.quantization): unpack is a sublane
-            # relabel of the [Nkv, PS/2, D] byte tile, then the same
-            # row-scale multiply as int8
-            from .quantization import dequantize_int4_rows
-            k = dequantize_int4_rows(k_ref[...], ks_ref[...], jnp.float32)
-            v = dequantize_int4_rows(v_ref[...], vs_ref[...], jnp.float32)
-        elif kv_quant == "int8":
-            # shared absmax math (ops.quantization): pure jnp, safe in a
-            # Pallas body — page scales are the [Nkv, PS] per-page tile
-            from .quantization import dequantize_int8_rows
-            k = dequantize_int8_rows(k_ref[...], ks_ref[...])
-            v = dequantize_int8_rows(v_ref[...], vs_ref[...])
+        if kv_quant == "none":
+            k = k_buf[buf].astype(jnp.float32)       # [Nkv, PS, D]
+            v = v_buf[buf].astype(jnp.float32)
         else:
-            k = k_ref[...].astype(jnp.float32)        # [Nkv, PS, D]
-            v = v_ref[...].astype(jnp.float32)
+            # shared nibble / absmax math (ops.quantization): pure jnp,
+            # safe in a Pallas body. int4 unpack is a sublane relabel of
+            # the [Nkv, PS/2, D] byte tile, then int8's row-scale multiply
+            from .quantization import (dequantize_int4_rows,
+                                       dequantize_int8_rows)
+            dequantize = (dequantize_int4_rows if kv_quant == "int4"
+                          else dequantize_int8_rows)
+            k = dequantize(k_buf[buf], ks_ref[p])
+            v = dequantize(v_buf[buf], vs_ref[p])
         k = k.reshape(num_kv * page_size, d)
         v = v.reshape(num_kv * page_size, d)
         s = jax.lax.dot_general(
@@ -140,11 +193,26 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    @pl.when(p == pl.num_programs(2) - 1)
-    def _finalize():
-        l = l_ref[...]
-        o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
-            o_ref.dtype).reshape(o_ref.shape)
+    def one_page(p, ring):
+        *lead, buf = ring
+        lead = fetch_next(lead)
+        for copy in page_copies(b, p, buf):
+            copy.wait()
+
+        @pl.when(p * page_size < max_len)       # false only at length 0
+        def _score():
+            score_page(p, buf)
+
+        return (*lead, next_buffer(buf))
+
+    ring = jax.lax.fori_loop(0, live_pages(b, t), one_page,
+                             tuple(ring_ref[i] for i in range(5)))
+    for i, x in enumerate(ring):
+        ring_ref[i] = x
+
+    l = l_ref[...]
+    o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
+        o_ref.dtype).reshape(o_ref.shape)
 
 
 # Largest folded score tile [Nq*tile, Nkv*PS] (fp32 elements) the kernel
@@ -155,6 +223,12 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
 # 64 query tokens per step at both layouts) compiles at every window the
 # engine can pass — tests/test_tpu_compile.py holds it there.
 _MAX_SCORE_ELEMS = 1 << 20
+
+# How many pages the copies run ahead of the page being scored (the ring
+# holds one buffer more). Alone on a v5e (32 slots, 200 live pages, us a
+# call at GQA 32/8 | MHA 16/16; PERF.md 6, PR 28): 1 ahead 128 | 168,
+# 2 ahead 105 | 144, 3 ahead 103 | 143, 5 and 7 the same as 3.
+_PAGES_AHEAD = 3
 
 
 def _query_tile(T: int, Nq: int, Nkv: int, PS: int) -> int:
@@ -180,10 +254,10 @@ def paged_attention_pallas_multi(
     (the window's own K/V must already be written to the pages).
 
     The pools are operands as they are, never a layer's slice of them: the
-    layer rides the scalar prefetch beside the block table and the index
-    maps address ``pages[layer, page]``. One layer's [NP, ...] pages with
+    layer rides the scalar prefetch beside the block table and the body's
+    copies address ``pages[layer, page]``. One layer's [NP, ...] pages with
     ``layer=None`` are the L = 1 pool."""
-    from .paged_attention import Int4Pages, QuantPages
+    from .paged_attention import Int4Pages, QuantPages, _at
     kv_quant = ("int4" if isinstance(k_pages, Int4Pages)
                 else "int8" if isinstance(k_pages, QuantPages) else "none")
     B, T_in, Nq, D = q.shape
@@ -208,47 +282,42 @@ def paged_attention_pallas_multi(
     starts = start_positions.astype(jnp.int32)
     tables = block_tables.astype(jnp.int32)
 
-    def page_of(b, t, p, tbl, st, ly):
-        # pages past the tile's live length are CLAMPED to its last used
-        # page: consecutive identical block indices elide the DMA
-        last_used = jnp.maximum((st[b] + (t + 1) * tile + PS - 1) // PS - 1,
-                                0)
-        return ly[0], tbl[b, jnp.minimum(p, last_used)]
-
-    # head-folded grid (B, tiles, maxP): one whole page (all kv heads)
-    # per step. The scale tile [Nkv, PS] rides the SAME clamped
-    # block-table index map as its page, so Pallas elides its re-fetch
-    # together with the page's on consecutive identical indices. int4
-    # pages DMA the packed [Nkv, PS/2, D] byte tile — half the int8
-    # bytes per page.
-    page_rows = PS // 2 if kv_quant == "int4" else PS
-    page_spec = pl.BlockSpec(
-        (None, None, Nkv, page_rows, D),
-        lambda *grid_and_scalars: (*page_of(*grid_and_scalars), 0, 0, 0))
-    scale_spec = pl.BlockSpec(
-        (None, None, Nkv, PS),
-        lambda *grid_and_scalars: (*page_of(*grid_and_scalars), 0, 0))
+    # one grid step a slot and query tile; the pools stay whole in HBM and
+    # the body copies pages[layer, table[b, p]] itself (int4 pages as the
+    # packed [Nkv, PS/2, D] byte tile, half the int8 bytes per page).
+    # A quantised page's [Nkv, PS] scale tile cannot ride those copies:
+    # Mosaic refuses to slice an HBM operand whose minor dimension is
+    # under 128 lanes (PS is 64). The slot's scale tiles are gathered
+    # through its table beforehand instead and come in with q: 2 KB a
+    # page beside 64 KB of int8, at the table's width.
     q_spec = pl.BlockSpec((None, Nkv, tile * groups, D),
-                          lambda b, t, p, tbl, st, ly: (b, 0, t, 0))
+                          lambda b, t, tbl, st, ly: (b, 0, t, 0))
+    in_specs, inputs = [q_spec], [qg]
     if kv_quant != "none":
-        in_specs = [page_spec, scale_spec, page_spec, scale_spec]
-        pools = [k_pages.values, k_pages.scale,
-                 v_pages.values, v_pages.scale]
+        scale_spec = pl.BlockSpec((None, maxP, Nkv, PS),
+                                  lambda b, t, tbl, st, ly: (b, 0, 0, 0))
+        in_specs += [scale_spec, scale_spec]
+        inputs += [k_pages.scale[_at(layer, tables)],
+                   v_pages.scale[_at(layer, tables)]]
+        pools = [k_pages.values, v_pages.values]
     else:
-        in_specs = [page_spec, page_spec]
         pools = [k_pages, v_pages]
     if layer is None:
         pools, layer = [a[None] for a in pools], 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,       # tables, starts, layer
-        grid=(B, n_tiles, maxP),
-        in_specs=[q_spec, *in_specs],
+        grid=(B, n_tiles),
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((Nkv * tile * groups, D), jnp.float32),
             pltpu.VMEM((Nkv * tile * groups, 1), jnp.float32),
             pltpu.VMEM((Nkv * tile * groups, 1), jnp.float32),
+            *[pltpu.VMEM((_PAGES_AHEAD + 1, *a.shape[2:]), a.dtype)
+              for a in pools],
+            pltpu.SemaphoreType.DMA((2, _PAGES_AHEAD + 1)),  # [K / V, buffer]
+            pltpu.SMEM((5,), jnp.int32),
         ],
     )
 
@@ -258,14 +327,18 @@ def paged_attention_pallas_multi(
     with jax.named_scope(name):
         out = pl.pallas_call(
             functools.partial(_extend_kernel, page_size=PS, scale=scale,
-                              groups=groups, window=tile, num_kv=Nkv,
-                              kv_quant=kv_quant),
+                              groups=groups, window=tile, queries=T_in,
+                              num_kv=Nkv, kv_quant=kv_quant),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, Nkv, T * groups, D), q.dtype),
+            # the ring of page copies runs across grid steps: in order,
+            # on one core
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
             name=name,
         )(tables, starts, jnp.asarray(layer, jnp.int32).reshape(1),
-          qg, *pools)
+          *inputs, *pools)
     return out.reshape(B, Nkv, T, groups, D).transpose(0, 2, 1, 3, 4).reshape(
         B, T, Nq, D)[:, :T_in]
 

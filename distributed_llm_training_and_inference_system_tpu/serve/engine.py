@@ -372,6 +372,11 @@ class InferenceEngine:
         # decode always runs over all slots (one compiled program); padded
         # slots are wasted work — tracked so batch-size tuning isn't blind
         self.total_padded_slot_steps = 0
+        # how far the paged-attention kernel's page walk engages: pages
+        # the slots' lengths cover at each decode dispatch's first step,
+        # against the block table's whole width (slots x pages a slot)
+        self.total_live_pages = 0
+        self.total_table_pages = 0
         # speculative-decode accounting (acceptance rate drives the
         # use-it-or-not decision per deployment)
         self.total_spec_dispatches = 0
@@ -1385,8 +1390,20 @@ class InferenceEngine:
         pipelined path exactly like units chain onto units."""
         units = []
         pend = chain_from
+        # a chained dispatch starts where the one in flight ends, which the
+        # host's positions have not seen yet; an idle slot sits at position
+        # 0 and the kernel still fetches the one page its table names
+        lag = (len(chain_from["units"]) * self._decode_unit_len
+               if chain_from is not None else 0)
+        live_pages = int(np.minimum(
+            (self.positions + lag * self.active) // self.kv.page_size + 1,
+            self.kv.max_pages_per_slot).sum())
+        self.total_live_pages += live_pages
+        self.total_table_pages += self.kv.block_tables.size
         with self.spans.phase("llmctl.engine.decode.submit", units=n_units,
-                              active=int(self.active.sum())):
+                              active=int(self.active.sum()),
+                              live_pages=live_pages,
+                              table_pages=self.kv.block_tables.size):
             shared = self._shared_decode_args()
             for _ in range(n_units):
                 pend = self._submit_decode(chain_from=pend, shared=shared)
@@ -2147,7 +2164,9 @@ class InferenceEngine:
             "weight_bytes": tree_weight_bytes(self.params),
             "quantization": self.quantization,
             **self.scheduler.stats(),
-            "kv": self.kv.stats(),
+            "kv": {**self.kv.stats(),
+                   "live_pages": self.total_live_pages,
+                   "table_pages": self.total_table_pages},
             "admission": self.serve_cfg.admission,
             "preemptions": self.total_preemptions,
             "preemption_mode": self.serve_cfg.preemption,

@@ -521,3 +521,85 @@ def test_long_window_write_matches_row_scatter(kv):
         jax.tree.leaves(_layer_of(pool, 1))[0])
     assert wrote[[1, 2, 3, 4, 6, 7, 8, 11, 12, 13]].any(axis=(1, 2, 3)).all()
     assert not wrote[[5, 9, 10]].any()
+
+
+def _poison_page0(pool):
+    """Scratch page 0 of every layer as NaN: bf16 values themselves, a
+    quantised page through its scale tile (its values are integers)."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (  # noqa: E501
+        QuantPages)
+    if isinstance(pool, QuantPages):
+        return type(pool)(pool.values, pool.scale.at[..., 0, :, :].set(
+            jnp.nan))
+    return pool.at[..., 0, :, :, :].set(jnp.nan)
+
+
+# what the kernel's page loop can get wrong, one slot each: the table
+# names scratch page 0 (a page of NaNs in these tests) wherever the slot
+# owns nothing, so a read past the live length shows in the result.
+# T = 1 attends over [0, length); a window of T = 20 starts at ``start``
+# and its last query sees start + 20 tokens.
+_WALK = {
+    1: ([[0, 0, 0, 0],          # length 0: nothing to read
+         [9, 0, 0, 0],          # 1: one token
+         [3, 0, 0, 0],          # 16: ends exactly on a page boundary
+         [1, 2, 4, 5],          # 64: fills all maxP pages
+         [6, 8, 10, 0],         # 35: ragged, and shares page 6 ...
+         [6, 7, 0, 0]],         # 20: ... with this one
+        [-1, 0, 15, 63, 34, 19]),
+    20: ([[11, 12, 0, 0],       # 0 + 20: the window starts the sequence
+          [9, 13, 0, 0],        # 1 + 20 = 21
+          [3, 14, 0, 0],        # 12 + 20 = 32: a page boundary
+          [1, 2, 4, 5],         # 44 + 20 = 64: all maxP pages, and the
+                                #   padded last tile reaches past them
+          [6, 8, 10, 0],        # 15 + 20 = 35, sharing page 6 with
+          [6, 7, 0, 0]],        # 4 + 20 = 24
+         [0, 1, 12, 44, 15, 4])}
+
+
+@pytest.mark.parametrize("heads", ["gqa", "mha"])
+@pytest.mark.parametrize("layered", [False, True], ids=["one-layer", "pool"])
+@pytest.mark.parametrize("kv", ["bf16", "int8", "int4"])
+@pytest.mark.parametrize("T", [1, 20], ids=["decode", "tiled-window"])
+def test_paged_kernel_walks_exactly_the_live_pages(monkeypatch, T, kv,
+                                                   layered, heads):
+    """The kernel (interpret mode) against the gather route over everything
+    one body serves: T = 1 and a window tiled along the query axis (3 tiles
+    of 8, the last one padded), every page type, ``layer=None`` and a layer
+    index into an [L, NP, ...] pool, GQA and MHA, and the lengths of
+    ``_WALK``. The kernel reads a pool whose scratch page is NaN;
+    the gather route, which gathers the table's whole width, reads the same
+    pool with a finite scratch page."""
+    from distributed_llm_training_and_inference_system_tpu.ops import (
+        paged_attention_pallas as pap)
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (  # noqa: E501
+        paged_attention_multi)
+
+    Nkv, PS, D, L = 2, 16, 64, 2
+    Nq = 4 if heads == "gqa" else Nkv
+    if T > 1:
+        monkeypatch.setattr(pap, "_MAX_SCORE_ELEMS", Nq * 8 * Nkv * PS)
+        assert pap._query_tile(T, Nq, Nkv, PS) == 8
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    k_pool = _layered_pool(kv, ks[0], L, NP=16, Nkv=Nkv, PS=PS, D=D)
+    v_pool = _layered_pool(kv, ks[1], L, NP=16, Nkv=Nkv, PS=PS, D=D)
+    layer = 1 if layered else None
+    if not layered:
+        k_pool, v_pool = _layer_of(k_pool, 1), _layer_of(v_pool, 1)
+    tables, starts = (jnp.asarray(a, jnp.int32) for a in _WALK[T])
+    q = jax.random.normal(ks[2], (len(starts), T, Nq, D), jnp.float32)
+
+    want = paged_attention_multi(q, k_pool, v_pool, tables, starts,
+                                 impl="gather", layer=layer)
+    got = paged_attention_multi(q, _poison_page0(k_pool),
+                                _poison_page0(v_pool), tables, starts,
+                                impl="pallas", layer=layer)
+    got, want = np.asarray(got), np.asarray(want)
+    first = 0
+    if T == 1:          # length 0: nothing to attend to, and nothing read
+        np.testing.assert_array_equal(got[0], 0.0)
+        first = 1
+    # the gather route rounds the probabilities to a bf16 page's dtype
+    # before its AV product; a wrong or missing page is off by O(1)
+    tol = 1e-2 if kv == "bf16" else 2e-5
+    np.testing.assert_allclose(got[first:], want[first:], rtol=tol, atol=tol)

@@ -198,6 +198,27 @@ def test_engine_phases_add_up_to_its_clock(engine, monkeypatch):
     assert engine.stats()["starved_s"] == after["starved_s"]
 
 
+@pytest.mark.parametrize("prompt_len,pages_of_its_slot", [(9, 1), (70, 2)])
+def test_decode_dispatches_count_the_pages_they_walk(engine, prompt_len,
+                                                     pages_of_its_slot):
+    """One request among four slots of two 64-token pages: every decode
+    dispatch walks the pages its length covers plus the one page an idle
+    slot's table names, out of slots x pages a slot."""
+    before = engine.stats()
+    engine.generate([[7] * prompt_len],
+                    SamplingParams(temperature=0.0, max_tokens=20))
+    after = engine.stats()
+    json.dumps(after["kv"])
+    dispatches = (after["phases"]["llmctl.engine.decode.submit"]["n"]
+                  - before["phases"]["llmctl.engine.decode.submit"]["n"])
+    assert dispatches > 0
+    assert engine.kv.block_tables.shape == (4, 2)
+    assert (after["kv"]["table_pages"] - before["kv"]["table_pages"]
+            == dispatches * 8)
+    assert (after["kv"]["live_pages"] - before["kv"]["live_pages"]
+            == dispatches * (pages_of_its_slot + 3))
+
+
 def test_decode_program_is_found_by_the_benchmarks_committed_names(engine):
     engine.generate(PROMPTS[:1], SamplingParams(temperature=0.0,
                                                 max_tokens=4))
@@ -301,6 +322,7 @@ def test_capture_serve_then_summarize_through_the_cli(tmp_path):
     res = runner.invoke(trace_cli.app, ["summarize", str(tmp_path)])
     assert res.exit_code == 0, res.output[-2000:]
     assert "llmctl.engine.decode.wait" in res.output
+    assert "paged attention walks" in res.output
     assert runner.invoke(trace_cli.app, ["capture", "--serve"]).exit_code != 0
 
 

@@ -76,18 +76,62 @@ def _compile(fn, *args):
     return compiled
 
 
+def _kernel_grids(fn, *args) -> list[tuple[int, ...]]:
+    """The grid (``iteration_bounds``) of every Mosaic kernel in the lowered
+    text of ``fn``: the custom call carries its module as MLIR bytecode."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    text = jax.jit(fn).lower(*args).as_text()
+    bodies = re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)', text)
+    assert bodies, "no tpu_custom_call in the lowered text"
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True    # the versioned wrapper
+    grids = []
+    with ctx:
+        for body in bodies:
+            module = str(ir.Module.parse(base64.b64decode(body)))
+            bounds, = re.findall(r"iteration_bounds = array<i64: ([^>]*)>",
+                                 module)
+            grids.append(tuple(int(n) for n in bounds.split(",")))
+    return grids
+
+
 def _sds(sharding):
     return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                      sharding=sharding)
 
 
-def _pages(sds, num_pages, nkv, kv):
+def _pages(sds, num_pages, nkv, kv, layers=()):
+    """One layer's pages, or the [L, NP, ...] pool with ``layers=(L,)``."""
     from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
         QuantPages)
     if kv == "int8":
-        return QuantPages(sds((num_pages, nkv, PS, D), jnp.int8),
-                          sds((num_pages, nkv, PS), jnp.float32))
-    return sds((num_pages, nkv, PS, D), jnp.bfloat16)
+        return QuantPages(sds((*layers, num_pages, nkv, PS, D), jnp.int8),
+                          sds((*layers, num_pages, nkv, PS), jnp.float32))
+    return sds((*layers, num_pages, nkv, PS, D), jnp.bfloat16)
+
+
+# the serving cells' pools (benchmark/configs): layers, pages a layer
+CELL_POOLS = {"gqa32x8": (16, 715), "mha16": (10, 715)}
+
+
+def _cell_call(fn, sds, layout, kv, q_shape):
+    """``fn`` on a cell's whole pool with a traced layer index, 32 slots:
+    (callable, argument shapes)."""
+    nq, nkv = LAYOUTS[layout]
+    n_layers, num_pages = CELL_POOLS[layout]
+    pool = _pages(sds, num_pages, nkv, kv, layers=(n_layers,))
+
+    def call(q, kp, vp, tables, lengths, layer):
+        return fn(q, kp, vp, tables, lengths, impl="auto", layer=layer)
+    return call, (sds(q_shape(32, nq), jnp.bfloat16), pool, pool,
+                  sds((32, MAXP), jnp.int32), sds((32,), jnp.int32),
+                  sds((), jnp.int32))
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -103,6 +147,24 @@ def test_paged_decode_kernel_compiles(one_chip, as_tpu, layout, kv):
     _compile(functools.partial(paged_attention, impl="auto"),
              sds((B, nq, D), jnp.bfloat16), pages, pages,
              sds((B, MAXP), jnp.int32), sds((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_paged_decode_kernel_compiles_at_the_cells_shapes(one_chip, as_tpu,
+                                                          layout, kv):
+    """The decode kernel as the serving cells run it: 32 slots, a block
+    table 32 pages wide, the whole [L, 715, ...] pool with a traced layer
+    index. Its grid is one step a slot: the page axis is a loop inside."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
+        paged_attention)
+    call, args = _cell_call(paged_attention, _sds(one_chip), layout, kv,
+                            lambda slots, nq: (slots, nq, D))
+    compiled = _compile(call, *args)
+    assert "paged_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * MAXP * 4096, \
+        "a pool-sized temporary beside the kernel"
+    assert _kernel_grids(call, *args) == [(32, 1)]
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -123,6 +185,28 @@ def test_paged_multi_query_kernel_compiles(one_chip, as_tpu, window, layout,
     _compile(functools.partial(paged_attention_multi, impl="auto"),
              sds((B, window, nq, D), jnp.bfloat16), pages, pages,
              sds((B, MAXP), jnp.int32), sds((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_paged_multi_query_kernel_compiles_at_the_cells_shapes(
+        one_chip, as_tpu, layout, kv):
+    """The speculative-verify window (8 tokens) over all 32 slots of a
+    cell's pool, and a 512-token suffix tiled 8 x 64 along the query axis:
+    the grid is (slots, query tiles) and never the table's width."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
+        paged_attention_multi)
+    call, args = _cell_call(paged_attention_multi, _sds(one_chip), layout, kv,
+                            lambda slots, nq: (slots, 8, nq, D))
+    _compile(call, *args)
+    assert _kernel_grids(call, *args) == [(32, 1)]
+    nq, nkv = LAYOUTS[layout]
+    sds = _sds(one_chip)
+    pages = _pages(sds, 8 * MAXP + 1, nkv, kv)
+    assert _kernel_grids(
+        functools.partial(paged_attention_multi, impl="auto"),
+        sds((1, 512, nq, D), jnp.bfloat16), pages, pages,
+        sds((1, MAXP), jnp.int32), sds((1,), jnp.int32)) == [(1, 8)]
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
