@@ -25,15 +25,12 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 from ..config.schema import ModelConfig
 from .layers import (
-    attention_block,
-    mlp_block,
-    moe_block,
-    moe_block_capacity,
-    moe_stats,
+    attend_dense_cache,
+    attend_fresh,
+    decoder_block,
     rms_norm,
     rope_frequencies,
 )
@@ -129,45 +126,34 @@ def split_expert_stacks(blocks: Params):
     return scanned, {n: moe[n] for n in _EXPERT_KERNELS}
 
 
-def layer_experts(layer_moe: Params, expert_stacks, layer_index):
-    """(the parameters ``moe_block`` takes for one layer of the scan, its
-    ``layer_index``): the layer's router beside the whole expert stacks
-    when ``split_expert_stacks`` kept them out of the scan."""
+def layer_experts(layer: Params, expert_stacks, layer_index):
+    """(one layer of the scan as ``decoder_block`` takes it, the
+    ``layer_index`` its ``moe_block`` needs): the layer's router beside the
+    whole expert stacks when ``split_expert_stacks`` kept them out of the
+    scan."""
     if expert_stacks is None:
-        return layer_moe, None
-    return dict(layer_moe, **expert_stacks), layer_index
+        return layer, None
+    return dict(layer, moe=dict(layer["moe"], **expert_stacks)), layer_index
 
 
 def _block_fn(cfg: ModelConfig, attn_impl: str, norm_impl: str,
               x, layer, positions, segment_ids, inv_freq,
               kv_cache=None, cache_offset=None, *, moe_impl: str = "dropless",
               expert_stacks=None, layer_index=None):
-    """One transformer block (pre-norm). Returns (x, new_kv_cache, aux):
-    ``aux`` is the router's load-balancing loss, or under
-    ``moe_impl="dropless"`` the layer's ``moe_stats`` (``segment_ids`` 0
-    marks a token that is not live)."""
-    h = rms_norm(x, layer["attn_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
-    attn_out, new_cache = attention_block(
-        h, layer, cfg, positions, segment_ids, inv_freq,
-        kv_cache=kv_cache, cache_offset=cache_offset, attn_impl=attn_impl)
-    # named so remat policies can pin it resident: the flash kernel's output
-    # is a custom call, not a dot, so dots_* policies rematerialise it —
-    # which re-runs the whole O(S^2) flash forward inside the backward pass
-    attn_out = checkpoint_name(attn_out, "attn_out")
-    x = x + attn_out
-    h = rms_norm(x, layer["mlp_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
+    """``decoder_block`` with no cache or over one layer's dense
+    ``kv_cache``. Returns (x, new_kv_cache, aux): ``aux`` is the router's
+    load-balancing loss, or under ``moe_impl="dropless"`` the layer's
+    ``moe_stats`` (``segment_ids`` 0 marks a token that is not live)."""
+    attend = (attend_fresh(positions, segment_ids, attn_impl)
+              if kv_cache is None
+              else attend_dense_cache(kv_cache, cache_offset, positions))
     if cfg.is_moe and moe_impl == "dropless":
-        moe, layer_index = layer_experts(layer["moe"], expert_stacks,
-                                         layer_index)
-        ffn_out, counts = moe_block(
-            h, moe, cfg, live=None if segment_ids is None
-            else segment_ids != 0, layer_index=layer_index)
-        aux = moe_stats(counts)
-    elif cfg.is_moe:
-        ffn_out, aux = moe_block_capacity(h, layer["moe"], cfg)
-    else:
-        ffn_out, aux = mlp_block(h, layer["mlp"], cfg), jnp.float32(0.0)
-    x = x + ffn_out
+        layer, layer_index = layer_experts(layer, expert_stacks, layer_index)
+    x, new_cache, aux = decoder_block(
+        x, layer, cfg, positions, inv_freq, attend, norm_impl=norm_impl,
+        live=segment_ids, moe_impl=moe_impl, layer_index=layer_index)
+    if aux is None:
+        aux = jnp.float32(0.0)
     # anchor GSPMD propagation at the block boundary (no-op off-mesh)
     from ..parallel.sharding import constrain
     return constrain(x, "activations"), new_cache, aux
@@ -185,8 +171,8 @@ def _remat_wrap(fn, policy: str):
     if policy == "selective_attn":
         # dots + the named flash-attention output: avoids re-running the
         # O(S^2) attention forward during backward at the cost of one
-        # [B, S, Nq*D] residual per layer (measured +1.9% MFU on v5e,
-        # BASELINE.md round-2 notes)
+        # [B, S, Nq*D] residual per layer (not measured on the attached
+        # chip: both training cells run "selective")
         return jax.checkpoint(fn, policy=jax.checkpoint_policies.save_from_both_policies(
             dots, jax.checkpoint_policies.save_only_these_names("attn_out")))
     # selective: keep matmul outputs resident, recompute the cheap stuff
@@ -279,7 +265,7 @@ def forward(
 
     # plain leaves are cast to the compute dtype ONCE before the scan
     # (casting inside the body would stream fp32 master weights from HBM
-    # every layer — measured -0.05 MFU); int8 QuantTensor leaves ride the
+    # every layer); int8 QuantTensor leaves ride the
     # scan quantized and dequantize one layer at a time inside the body,
     # so the whole-tree int8 storage saving survives the forward
     from ..ops.quantization import cast_params as _cast, precast_params

@@ -236,8 +236,8 @@ def write_window_to_pages(
     write_ok: jax.Array = None,  # [B, T] bool
     layer=None,                # int32 scalar: which layer's pages to write
 ) -> jax.Array:
-    """Page-granular window write: the whole-page alternative to T
-    row-scatters (``write_token_to_pages`` over B*T rows).
+    """Page-granular window write: what every serve program writes K and V
+    with (``write_token_to_pages`` over B*T rows is its reference).
 
     A slot's T consecutive tokens span at most n = (T + 2 PS - 2) // PS
     physical pages (two for a verify window). This gathers those n*B
@@ -249,19 +249,17 @@ def write_window_to_pages(
     kernel's layout and back in every layer (3.8 GB of temporaries in the
     decode program at the benchmark's shapes, PERF.md 6, PR 26), which is
     why windows longer than a page (suffix and chunked prefill) take this
-    route too since the pools ride the layer loop.
-    A/B-select via LLMCTL_EXTEND_WRITE=paged|scatter (default paged);
-    numerics asserted equal to the scatter path in
+    route too. Numerics asserted equal to the scatter in
     tests/test_ops.py::test_window_write_matches_row_scatter.
 
     ``QuantPages`` take the SAME whole-page route with a fused
     quantize-on-write: the window's rows are absmax-quantized once
     ([B, T, Nkv] int8 rows + scales), then values AND scales merge
     through one shared one-hot select and scatter back as whole
-    (page, scale-tile) pairs. No per-row scatter, and no full-precision
-    copy of any cache page is ever materialised — the round-5-measured
-    QuantPages decode wall (BASELINE.md:205-218) was exactly this path
-    falling back to B*T row scatters on values and scales separately.
+    (page, scale-tile) pairs. No per-row scatter (of values and scales
+    separately), and no full-precision copy of any cache page is ever
+    materialised; not measured on the attached chip: no cell has quantized
+    pages (ROADMAP A3).
     Bit-identical to the scatter path (same quantize_int8_rows math,
     untouched rows copied int8/fp32-exact), asserted in
     tests/test_kv_quant.py.

@@ -574,10 +574,13 @@ def activation_channel_scales(
     the stacked-layer layout (kernels [L, in, out]), so this returns
     {stacked param path: [L, in_features] fp32} for the q/k/v and mlp
     gate/up/down kernels (o and MoE expert kernels keep plain absmax: o's
-    input never leaves attention_block, and experts are token-routed).
+    input is attention's output, and experts are token-routed).
+
+    The pass drives ``models.layers.decoder_block`` with a matmul that
+    records what it is given: the kernels to calibrate go in as
+    (path, kernel) pairs.
     """
-    from ..models.layers import (
-        _activate, attention_block, rms_norm, rope_frequencies)
+    from ..models.layers import attend_fresh, decoder_block, rope_frequencies
 
     compute_dtype = jnp.dtype(model_cfg.dtype)
     x = params["embed"]["embedding"][calib_tokens].astype(compute_dtype)
@@ -586,40 +589,29 @@ def activation_channel_scales(
                                 model_cfg.rope.scaling_factor)
     B, S = calib_tokens.shape
     positions = jnp.arange(S, dtype=jnp.int32)[None, :].repeat(B, axis=0)
-    scales: dict[str, jax.Array] = {}
-
-    def rms_over_channels(h):
-        return jnp.sqrt(jnp.mean(
-            h.astype(jnp.float32) ** 2,
-            axis=tuple(range(h.ndim - 1)))) + 1e-6
-
+    calibrated = {f"blocks.{n}.kernel" for n in (
+        "q", "k", "v", "mlp.gate", "mlp.up", "mlp.down")}
     per_layer: dict[str, list[jax.Array]] = {}
 
-    def record(key, h):
-        per_layer.setdefault(key, []).append(rms_over_channels(h))
+    def recording_matmul(a, w):
+        if isinstance(w, tuple):
+            path, w = w
+            per_layer.setdefault(path, []).append(jnp.sqrt(jnp.mean(
+                a.astype(jnp.float32) ** 2,
+                axis=tuple(range(a.ndim - 1)))) + 1e-6)
+        return a @ w
+
+    def layer_of(i):
+        def one(path_entries, p):
+            path = "blocks." + ".".join(str(k.key) for k in path_entries)
+            p = p[i].astype(compute_dtype)
+            return (path, p) if path in calibrated else p
+        return jax.tree_util.tree_map_with_path(one, params["blocks"])
 
     for i in range(model_cfg.num_layers):
-        layer = jax.tree_util.tree_map(
-            lambda p: p[i].astype(compute_dtype), params["blocks"])
-        h_attn = rms_norm(x, layer["attn_norm"]["scale"], model_cfg.norm_eps)
-        for name in ("q", "k", "v"):
-            record(f"blocks.{name}.kernel", h_attn)
-        attn_out, _ = attention_block(h_attn, layer, model_cfg, positions,
-                                      None, inv_freq)
-        x = x + attn_out
-        h_mlp = rms_norm(x, layer["mlp_norm"]["scale"], model_cfg.norm_eps)
-        if not model_cfg.is_moe:
-            for name in ("gate", "up"):
-                record(f"blocks.mlp.{name}.kernel", h_mlp)
-            a = _activate(h_mlp @ layer["mlp"]["gate"]["kernel"],
-                          model_cfg.activation)
-            a = a * (h_mlp @ layer["mlp"]["up"]["kernel"])
-            record("blocks.mlp.down.kernel", a)
-            x = x + (a @ layer["mlp"]["down"]["kernel"]).astype(x.dtype)
-        else:
-            from ..models.layers import moe_block
-            ffn, _ = moe_block(h_mlp, layer["moe"], model_cfg)
-            x = x + ffn.astype(x.dtype)
+        x, _, _ = decoder_block(
+            x, layer_of(i), model_cfg, positions, inv_freq,
+            attend_fresh(positions, None), matmul=recording_matmul)
     return {k: jnp.stack(v) for k, v in per_layer.items()}   # [L, in]
 
 

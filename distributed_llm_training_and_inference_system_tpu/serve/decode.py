@@ -1,11 +1,13 @@
-"""Single-token decode forward over the paged KV cache.
+"""Decode and extend forwards over the paged KV cache.
 
 Serving on TPU wants prefill and decode as separate compiled programs
-(SURVEY §7.3.2): prefill is a large-matmul batch-1 pass through the standard
-``models.gpt.forward``; decode is this function — one token for EVERY slot
-per call, static shapes, paged attention. Reuses the same param pytree and
-layer building blocks as training, so numerics can never diverge from the
-train-side model (tested in tests/test_serve.py against the dense path).
+(SURVEY §7.3.2): cold prefill is a large-matmul batch-1 pass through
+``models.gpt.forward`` over a dense cache; decode, suffix and chunked
+prefill and speculative verification are ``extend_step_forward`` — T tokens
+for EVERY slot per call, static shapes, paged attention. Both run
+``models.layers.decoder_block``: what this module adds is where K and V
+live (the page pools, ``attend`` below) and how a quantized weight is
+multiplied (``mm``).
 """
 
 from __future__ import annotations
@@ -16,19 +18,10 @@ import jax
 import jax.numpy as jnp
 
 from ..config.schema import ModelConfig
-from ..models.gpt import layer_experts, split_expert_stacks
-from ..models.layers import (
-    apply_rope,
-    mlp_block,
-    moe_block,
-    moe_stats,
-    qk_project_norm,
-    rms_norm,
-    rope_frequencies,
-)
+from ..models.gpt import layer_experts, split_expert_stacks, unembed
+from ..models.layers import decoder_block, rope_frequencies
 from ..ops.paged_attention import (
     paged_attention_multi,
-    write_token_to_pages,
     write_window_to_pages,
 )
 from ..ops.quantization import cast_params, precast_params
@@ -44,7 +37,6 @@ def decode_step_forward(
     cfg: ModelConfig,
     active: Any = None,       # [B] bool — inactive rows write scratch page
     attn_impl: str = "auto",
-    write_mode: str = "paged",
     w4_kernel_ok: bool = True,
     w8_kernel_ok: bool = False,
     return_moe_stats: bool = False,
@@ -64,7 +56,7 @@ def decode_step_forward(
     write_ok = None if active is None else active[:, None]
     logits, *rest = extend_step_forward(
         params, tokens[:, None], positions, k_pages, v_pages, block_tables,
-        cfg, write_ok=write_ok, attn_impl=attn_impl, write_mode=write_mode,
+        cfg, write_ok=write_ok, attn_impl=attn_impl,
         w4_kernel_ok=w4_kernel_ok, w8_kernel_ok=w8_kernel_ok,
         return_moe_stats=return_moe_stats)
     return (logits[:, 0], *rest)
@@ -83,14 +75,6 @@ def extend_step_forward(
                               # tensor-parallel engine forces "gather" (the
                               # Pallas kernel is opaque to GSPMD and would
                               # be replicated, gathering all pages per chip)
-    write_mode: str = "paged",  # "paged" (whole-page merge, every T) |
-                              # "scatter" (B*T row scatter, every T: an
-                              # A/B arm, see use_window_write below). A
-                              # traced constant: the
-                              # caller fixes it at program-build time (the
-                              # engine reads LLMCTL_EXTEND_WRITE once at
-                              # construction) — reading env HERE would
-                              # bake a stale value into cached programs
     w4_kernel_ok: bool = True,  # engine passes False under tensor-parallel:
                               # like the Pallas attention kernel, the W4
                               # matmul is a custom call GSPMD cannot
@@ -126,35 +110,21 @@ def extend_step_forward(
     the single-token path (correct, but re-streams the prefix T-fold).
     """
     compute_dtype = jnp.dtype(cfg.dtype)
-    B, T = tokens.shape
-    D, Nq, Nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
-
+    T = tokens.shape[1]
     positions = start_positions[:, None] + jnp.arange(T, dtype=jnp.int32)
-    flat_pos = positions.reshape(B * T)
-    flat_tables = jnp.repeat(block_tables, T, axis=0)        # [B*T, maxP]
-    flat_ok = None if write_ok is None else write_ok.reshape(B * T)
-    # Every T takes the whole-page merge (T == 1: one page a slot, 4 MB
-    # read and 4 MB written a layer and pool at 32 slots), QuantPages and
-    # Int4Pages with quantize-on-write fused into it. The row scatter is
-    # the slower arm on the chip now that the pools ride the layer loop:
-    # XLA lays a row-scattered pool out slot-major, the Pallas kernel
-    # reads it head-major, and the WHOLE pool is copied between the two in
-    # every layer (PERF.md 6, PR 26, has both step times).
-    use_window_write = write_mode != "scatter"
 
     x = params["embed"]["embedding"][tokens].astype(compute_dtype)  # [B,T,H]
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope.base,
                                 cfg.rope.scaling, cfg.rope.scaling_factor)
 
-    # W4A16 weights route through the in-kernel-dequant Pallas matmul on
-    # TPU: the XLA dequant chain round-trips the full bf16 tensor through
-    # HBM (measured 2.5x bf16 traffic — int4 decoded 4x SLOWER than bf16,
-    # BASELINE r3/r4), while the kernel streams packed nibbles at 4-bit
-    # width (measured FASTER than bf16 at decode shapes, battery 13).
-    # W8A16 can take the int8 sibling kernel (ops.int8_matmul_pallas),
-    # but OPT-IN (ServeConfig.int8_pallas_matmul -> w8_kernel_ok): XLA
-    # fuses the plain int8 dequant (battery 13: 384 GB/s vs bf16's 555),
-    # so unlike int4 the Pallas route needs a measured win first.
+    # W4A16 weights go through the in-kernel-dequant Pallas matmul on the
+    # TPU: the XLA dequant chain writes the whole bf16 tensor to HBM and
+    # reads it back, where the kernel streams the packed nibbles at their
+    # 4-bit width. W8A16 can take the int8 sibling kernel
+    # (ops.int8_matmul_pallas), but OPT-IN (ServeConfig.int8_pallas_matmul
+    # -> w8_kernel_ok): XLA fuses the plain int8 dequant into the matmul.
+    # Neither is measured on the attached chip: no cell has quantized
+    # weights yet (ROADMAP A4 is the A/B).
     use_w4_kernel = w4_kernel_ok and jax.default_backend() == "tpu"
     use_w8_kernel = w8_kernel_ok and jax.default_backend() == "tpu"
 
@@ -163,10 +133,10 @@ def extend_step_forward(
 
         from ..ops.quantization import Quant4Tensor, QuantTensor
         # rows <= 64 keeps the Pallas kernels' whole-K activation blocks
-        # in the 1-2 MB VMEM regime they were designed for (decode T=1,
-        # verify windows T<=8); long-T chunked/suffix prefill through
-        # those tiles would blow VMEM — it takes the dequant path, where
-        # T amortises the bf16 round trip anyway
+        # in the 1-2 MB of VMEM they were designed for (decode T=1, verify
+        # windows T<=8); long-T chunked/suffix prefill through those tiles
+        # would blow VMEM — it takes the dequant path, where T amortises
+        # the bf16 round trip anyway
         rows = math.prod(a.shape[:-1])
         if isinstance(w, QuantTensor):
             if (use_w8_kernel and rows <= 64
@@ -207,55 +177,36 @@ def extend_step_forward(
         # layer of bf16 at a time (ops.quantization)
         layer = cast_params(layer, compute_dtype, keep_w4=use_w4_kernel,
                             keep_w8=use_w8_kernel)
-        if cfg.is_moe and "moe" in layer:
+        moe_li = None
+        if cfg.is_moe:
             # moe_block contracts expert weights directly (no matmul
             # injection) — a passed-through Quant[4]Tensor would hit
             # `a @ w` untyped; experts take the dequant path
-            layer = dict(layer, moe=cast_params(layer["moe"],
-                                                compute_dtype))
-        h = rms_norm(x, layer["attn_norm"]["scale"], cfg.norm_eps)
-        q = qk_project_norm(mm(h, layer["q"]["kernel"]), layer, "q",
-                            cfg).reshape(B, T, Nq, D)
-        k = qk_project_norm(mm(h, layer["k"]["kernel"]), layer, "k",
-                            cfg).reshape(B, T, Nkv, D)
-        v = mm(h, layer["v"]["kernel"]).reshape(B, T, Nkv, D)
-        if cfg.attention_bias:
-            q = q + layer["q"]["bias"].reshape(Nq, D)
-            k = k + layer["k"]["bias"].reshape(Nkv, D)
-            v = v + layer["v"]["bias"].reshape(Nkv, D)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
+            layer = dict(layer, moe=cast_params(layer["moe"], compute_dtype))
+            layer, moe_li = layer_experts(layer, expert_stacks, li)
 
-        with jax.named_scope("kv_page_write"):
-            if use_window_write:
-                # page-granular write (whole-page DMAs) instead of a
-                # B*T-row scatter; A/B via LLMCTL_EXTEND_WRITE=paged|scatter
-                # (default paged; QuantPages quantize-on-write inside the
-                # same merge)
-                kp = write_window_to_pages(kp, k, block_tables,
-                                           start_positions, write_ok, li)
-                vp = write_window_to_pages(vp, v, block_tables,
-                                           start_positions, write_ok, li)
-            else:
-                kp = write_token_to_pages(kp, k.reshape(B * T, Nkv, D),
-                                          flat_tables, flat_pos, flat_ok, li)
-                vp = write_token_to_pages(vp, v.reshape(B * T, Nkv, D),
-                                          flat_tables, flat_pos, flat_ok, li)
-        attn = paged_attention_multi(q, kp, vp, block_tables,
-                                     start_positions, impl=attn_impl,
-                                     layer=li)
-        attn = attn.reshape(B, T, Nq * D)
-        x = x + mm(attn, layer["o"]["kernel"]).astype(x.dtype)
+        def attend(q, k, v):
+            # K and V live in pages. Every T takes the whole-page merge
+            # (T == 1: one page a slot), QuantPages and Int4Pages with
+            # quantize-on-write fused into it: a row scatter lays the pool
+            # out slot-major, the Pallas kernel reads it head-major, and
+            # the WHOLE pool is copied between the two in every layer
+            # (PERF.md 6, PR 26, has both step times)
+            with jax.named_scope("kv_page_write"):
+                new_k = write_window_to_pages(kp, k, block_tables,
+                                              start_positions, write_ok, li)
+                new_v = write_window_to_pages(vp, v, block_tables,
+                                              start_positions, write_ok, li)
+            out = paged_attention_multi(q, new_k, new_v, block_tables,
+                                        start_positions, impl=attn_impl,
+                                        layer=li)
+            return out, (new_k, new_v)
 
-        h = rms_norm(x, layer["mlp_norm"]["scale"], cfg.norm_eps)
-        if cfg.is_moe:
-            moe, moe_li = layer_experts(layer["moe"], expert_stacks, li)
-            ffn, counts = moe_block(h, moe, cfg, live=write_ok,
-                                    layer_index=moe_li)
-            stats = [total + moe_stats(counts) for total in stats]
-        else:
-            ffn = mlp_block(h, layer["mlp"], cfg, matmul=mm)
-        return (x + ffn.astype(x.dtype), kp, vp, *stats), None
+        x, (kp, vp), layer_stats = decoder_block(
+            x, layer, cfg, positions, inv_freq, attend, matmul=mm,
+            live=write_ok, layer_index=moe_li)
+        stats = [total + layer_stats for total in stats]
+        return (x, kp, vp, *stats), None
 
     stats0 = ([jnp.zeros((cfg.moe.num_experts + 1,), jnp.int32)]
               if return_moe_stats else [])
@@ -263,16 +214,7 @@ def extend_step_forward(
         body, (x, k_pages, v_pages, *stats0),
         (blocks, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
 
-    x = rms_norm(x, params["final_norm"]["scale"].astype(x.dtype), cfg.norm_eps)
-    if cfg.tie_word_embeddings:
-        logits = jnp.einsum("bth,vh->btv", x,
-                            params["embed"]["embedding"].astype(x.dtype),
-                            preferred_element_type=jnp.float32)
-    else:
-        logits = jnp.einsum("bth,hv->btv", x,
-                            params["lm_head"]["kernel"].astype(x.dtype),
-                            preferred_element_type=jnp.float32)
-    return (logits.astype(jnp.float32), new_k, new_v, *stats)
+    return (unembed(params, x, cfg), new_k, new_v, *stats)
 
 
 def decode_multi_step(
@@ -290,7 +232,6 @@ def decode_multi_step(
     cfg: ModelConfig,
     num_steps: int,
     attn_impl: str = "auto",
-    write_mode: str = "paged",
     w4_kernel_ok: bool = True,
     w8_kernel_ok: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -316,15 +257,14 @@ def decode_multi_step(
     (_, _, k_pages, v_pages), toks_seq = decode_scan(
         params, tokens, positions, k_pages, v_pages, block_tables,
         stop_positions, slot_keys, temperature, top_k, top_p, cfg,
-        num_steps, attn_impl, write_mode, w4_kernel_ok, w8_kernel_ok)
+        num_steps, attn_impl, w4_kernel_ok, w8_kernel_ok)
     return toks_seq, k_pages, v_pages
 
 
 def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
                 stop_positions, slot_keys, temperature, top_k, top_p,
                 cfg: ModelConfig, num_steps: int, attn_impl: str = "auto",
-                write_mode: str = "paged", w4_kernel_ok: bool = True,
-                w8_kernel_ok: bool = False,
+                w4_kernel_ok: bool = True, w8_kernel_ok: bool = False,
                 return_moe_stats: bool = False):
     """The decode+sample scan shared by ``decode_multi_step`` and the fused
     speculative dispatch (speculative.verify_and_decode). Returns
@@ -339,9 +279,8 @@ def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
         act = pos < stop_positions
         logits, kp, vp, *step_stats = decode_step_forward(
             params, toks, pos, kp, vp, block_tables, cfg, active=act,
-            attn_impl=attn_impl, write_mode=write_mode,
-            w4_kernel_ok=w4_kernel_ok, w8_kernel_ok=w8_kernel_ok,
-            return_moe_stats=return_moe_stats)
+            attn_impl=attn_impl, w4_kernel_ok=w4_kernel_ok,
+            w8_kernel_ok=w8_kernel_ok, return_moe_stats=return_moe_stats)
         keys = jax.vmap(jax.random.fold_in)(
             jax.vmap(jax.random.wrap_key_data)(slot_keys), pos + 1)
         nxt = sample_tokens(logits, keys, temperature, top_k, top_p)

@@ -296,16 +296,6 @@ class InferenceEngine:
         self._ctx = np.zeros((S, serve_cfg.max_seq_len), np.int32)
         self._ctx_len = np.zeros(S, np.int64)
 
-        # extend-path KV write mode, fixed at construction so every
-        # compiled program in this engine uses one mode (a trace-time env
-        # read would bake stale values into cached programs)
-        import os as _os
-        self._extend_write = _os.environ.get("LLMCTL_EXTEND_WRITE", "paged")
-        if self._extend_write not in ("paged", "scatter"):
-            raise ValueError(
-                f"LLMCTL_EXTEND_WRITE={self._extend_write!r} "
-                "(must be paged|scatter) — a typo here would silently "
-                "select the paged path and poison A/B data")
         self._prefill_cache: dict[int, callable] = {}
         # program name -> the error of its first call (see _Program)
         self.failed_programs: dict[str, str] = {}
@@ -704,7 +694,6 @@ class InferenceEngine:
                 logits, k_pages, v_pages, *moe_stats = extend_step_forward(
                     params, tokens, start, k_pages, v_pages, table, cfg,
                     write_ok=write_ok, attn_impl=self._attn_impl,
-                    write_mode=self._extend_write,
                     w4_kernel_ok=self._w4_kernel_ok,
                     w8_kernel_ok=self._w8_kernel_ok,
                     return_moe_stats=True)
@@ -739,7 +728,6 @@ class InferenceEngine:
                 _, k_pages, v_pages = extend_step_forward(
                     params, tokens, start, k_pages, v_pages, table, cfg,
                     write_ok=write_ok, attn_impl=self._attn_impl,
-                    write_mode=self._extend_write,
                     w4_kernel_ok=self._w4_kernel_ok,
                     w8_kernel_ok=self._w8_kernel_ok)
                 return k_pages, v_pages
@@ -1285,8 +1273,7 @@ class InferenceEngine:
         (toks, pos, k_pages, v_pages, *moe_stats), toks_seq = decode_scan(
             params, tokens, positions, k_pages, v_pages, tables, stops,
             slot_keys, temp, top_k, top_p, self.cfg, self._decode_unit_len,
-            attn_impl=self._attn_impl, write_mode=self._extend_write,
-            w4_kernel_ok=self._w4_kernel_ok,
+            attn_impl=self._attn_impl, w4_kernel_ok=self._w4_kernel_ok,
             w8_kernel_ok=self._w8_kernel_ok, return_moe_stats=True)
         return (toks_seq, toks, pos, k_pages, v_pages, *moe_stats)
 
@@ -1482,8 +1469,7 @@ class InferenceEngine:
             slot_keys, temp, top_k, top_p, self.cfg,
             num_decode_steps=max(
                 self.serve_cfg.decode_steps_per_dispatch - 1, 0),
-            attn_impl=self._attn_impl, write_mode=self._extend_write,
-            w4_kernel_ok=self._w4_kernel_ok,
+            attn_impl=self._attn_impl, w4_kernel_ok=self._w4_kernel_ok,
             w8_kernel_ok=self._w8_kernel_ok)
 
     @engine_thread_only

@@ -46,8 +46,8 @@ from .sampling import sample_tokens
 # weights recent dispatches (a sequence's acceptance drifts as it moves
 # from grounded prompt-copying into free generation); the warmup floor
 # keeps one lucky/unlucky first window from whipsawing the window; the
-# grow/shrink thresholds bracket the ~50% acceptance break-even the
-# verify window's ~9-decode-step cost implies (BASELINE.md round 2).
+# grow/shrink thresholds bracket a ~50% acceptance break-even (not
+# measured on the attached chip: no cell speculates).
 SPEC_EWMA_ALPHA = 0.25
 SPEC_WARMUP_DISPATCHES = 4
 SPEC_GROW_AT = 0.5
@@ -180,7 +180,6 @@ def speculative_verify(
     top_p: jax.Array,
     cfg: ModelConfig,
     attn_impl: str = "auto",
-    write_mode: str = "paged",
     w4_kernel_ok: bool = True,
     w8_kernel_ok: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
@@ -200,7 +199,7 @@ def speculative_verify(
     write_ok = (positions[:, None] + offs) < stop_positions[:, None]
     logits, k_pages, v_pages = extend_step_forward(
         params, tokens, positions, k_pages, v_pages, block_tables, cfg,
-        write_ok=write_ok, attn_impl=attn_impl, write_mode=write_mode,
+        write_ok=write_ok, attn_impl=attn_impl,
         w4_kernel_ok=w4_kernel_ok, w8_kernel_ok=w8_kernel_ok)
 
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)     # [B, T]
@@ -234,7 +233,6 @@ def verify_and_decode(
     cfg: ModelConfig,
     num_decode_steps: int,
     attn_impl: str = "auto",
-    write_mode: str = "paged",
     w4_kernel_ok: bool = True,
     w8_kernel_ok: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
@@ -242,15 +240,15 @@ def verify_and_decode(
     decode iterations, all on device.
 
     Why fused: a verify-only dispatch yields avg ``acceptance*(T-1) + 1``
-    tokens per host round trip — on an RTT-bound link that LOSES to
-    multi-step decode's guaranteed K (measured 21 vs 94 tok/s at 8%
-    acceptance, BASELINE.md). Chaining R decode steps after the verify
-    makes every dispatch yield ``n_acc + 1 + R`` tokens for ``1 + R``
-    forward passes. The verify forward is NOT free, though: measured ~9
-    decode-steps of cost at gpt-1b (extend-path page scatter + per-query
-    prefix streaming, BASELINE.md round 2), so below roughly 50%
-    acceptance this still trails plain multi-step decode — the engine's
-    adaptive check (speculative_min_acceptance) exists for exactly that.
+    tokens per host round trip, which at low acceptance loses to multi-step
+    decode's guaranteed K. Chaining R decode steps after the verify makes
+    every dispatch yield ``n_acc + 1 + R`` tokens for ``1 + R`` forward
+    passes. The verify forward is not free (a T-token window write and
+    per-query prefix streaming), so below some acceptance this still
+    trails plain multi-step decode — the engine's adaptive check
+    (speculative_min_acceptance) exists for exactly that. Where the
+    crossover lies is not measured on the attached chip: no cell
+    speculates.
 
     Returns (emitted [B, T], n_emit [B], decode_seq [R, B], k_pages,
     v_pages). Host applies emitted[:n_emit] then decode_seq rows.
@@ -258,8 +256,8 @@ def verify_and_decode(
     emitted, n_emit, k_pages, v_pages = speculative_verify(
         params, tokens, positions, k_pages, v_pages, block_tables,
         stop_positions, slot_keys, temperature, top_k, top_p, cfg,
-        attn_impl=attn_impl, write_mode=write_mode,
-        w4_kernel_ok=w4_kernel_ok, w8_kernel_ok=w8_kernel_ok)
+        attn_impl=attn_impl, w4_kernel_ok=w4_kernel_ok,
+        w8_kernel_ok=w8_kernel_ok)
     if num_decode_steps < 1:
         B = tokens.shape[0]
         return (emitted, n_emit,
@@ -271,6 +269,5 @@ def verify_and_decode(
     (_, _, k_pages, v_pages), decode_seq = decode_scan(
         params, last, positions + n_emit, k_pages, v_pages, block_tables,
         stop_positions, slot_keys, temperature, top_k, top_p, cfg,
-        num_decode_steps, attn_impl, write_mode, w4_kernel_ok,
-        w8_kernel_ok)
+        num_decode_steps, attn_impl, w4_kernel_ok, w8_kernel_ok)
     return emitted, n_emit, decode_seq, k_pages, v_pages

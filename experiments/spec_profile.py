@@ -30,12 +30,6 @@ def main() -> None:
     from distributed_llm_training_and_inference_system_tpu.serve.speculative import (
         speculative_verify)
 
-    # honor the battery's paged-vs-scatter A/B (the engine reads this at
-    # construction; this script builds programs directly, so it must too)
-    write_mode = os.environ.get("LLMCTL_EXTEND_WRITE", "paged")
-    if write_mode not in ("paged", "scatter"):
-        raise SystemExit(f"bad LLMCTL_EXTEND_WRITE {write_mode!r}")
-
     model = sys.argv[1] if len(sys.argv) > 1 else "gpt-1b"
     cfg = get_model_config(model)
     B, T, PS, NP, maxP = 4, 8, 64, 80, 18
@@ -78,12 +72,10 @@ def main() -> None:
         cfg, num_steps=8)[0])
     v8 = jax.jit(lambda p, kp_, vp_: speculative_verify(
         p, toksT, pos, kp_, vp_, tables, stops, keys, temp, tk, tp_,
-        cfg, write_mode=write_mode)[0])
+        cfg)[0])
     e8 = jax.jit(lambda p, kp_, vp_: extend_step_forward(
-        p, toksT, pos, kp_, vp_, tables, cfg,
-        write_mode=write_mode)[0])
+        p, toksT, pos, kp_, vp_, tables, cfg)[0])
 
-    out["write_mode"] = write_mode
     which = (sys.argv[2] if len(sys.argv) > 2 else "d8,v8").split(",")
     progs = {"d1": ("decode1_ms", d1), "d8": ("decode8_ms", d8),
              "v8": ("verify8_ms", v8), "e8": ("extend8_ms", e8)}

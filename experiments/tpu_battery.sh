@@ -38,12 +38,10 @@ run serve_load_reserve 900 python -m distributed_llm_training_and_inference_syst
     --prompt-len 512 --gen-len 128 --rps 2,6,12 --concurrency 4,8,16 \
     --admission reserve --kv-blocks 96
 
-# 4a. verify-window cost isolation: paged vs scatter KV window write
-LLMCTL_EXTEND_WRITE=paged   run spec_profile_paged 700 python experiments/spec_profile.py gpt-1b
-LLMCTL_EXTEND_WRITE=scatter run spec_profile_scatter 700 python experiments/spec_profile.py gpt-1b
+# 4a. verify-window cost isolation
+run spec_profile_paged 700 python experiments/spec_profile.py gpt-1b
 
-# 4b. speculation crossover (oracle acceptance sweep; window write = the
-#     faster mode from 4a — default paged)
+# 4b. speculation crossover (oracle acceptance sweep)
 run spec_crossover 1200 python experiments/spec_crossover.py gpt-1b 8 7
 
 # 5. int4 decode throughput vs int8 vs bf16

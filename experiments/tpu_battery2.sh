@@ -23,8 +23,7 @@ run mfu_b4_selattn_accum4 900 python experiments/mfu_sweep.py 4 selective_attn g
 
 # spec-profile rerun: the first battery's runs timed out lowering 2.9 GB
 # of closure-captured weights (fixed: params passed as a jit argument)
-LLMCTL_EXTEND_WRITE=paged   run spec_profile_paged 700 python experiments/spec_profile.py gpt-1b
-LLMCTL_EXTEND_WRITE=scatter run spec_profile_scatter 700 python experiments/spec_profile.py gpt-1b
+run spec_profile_paged 700 python experiments/spec_profile.py gpt-1b
 
 # reserve-admission load sweep rerun: the first battery's run died
 # RESOURCE_EXHAUSTED on its 4th engine (fixed: engine.release() between

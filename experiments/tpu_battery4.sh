@@ -31,6 +31,6 @@ run serve_load_light_v2 900 python -m distributed_llm_training_and_inference_sys
     --admission ondemand --kv-blocks 96
 
 # spec profile rerun: verify-window cost under the folded kernel
-LLMCTL_EXTEND_WRITE=paged run spec_profile_v2 700 python experiments/spec_profile.py gpt-1b
+run spec_profile_v2 700 python experiments/spec_profile.py gpt-1b
 
 echo "battery4 complete; results in $OUT/"

@@ -367,9 +367,16 @@ class TestRouterSharedLedger:
         rb.store.heartbeat()
         ra.store.heartbeat()
         assert rb.flush_parked() == 0
-        time.sleep(0.08)                      # A's heartbeat goes stale
-        rb.store.heartbeat()
-        placed = rb.flush_parked()
+        # A falls silent. Wait for the adoption, not for the clock: B is
+        # the adopter only while its OWN heartbeat is younger than the
+        # store's 50 ms, and a pass in which it went stale before
+        # flush_parked looked adopts nothing, changes nothing, and is
+        # made again
+        placed, deadline = 0, time.monotonic() + 10.0
+        while not placed and time.monotonic() < deadline:
+            time.sleep(0.08)                  # A's heartbeat goes stale
+            rb.store.heartbeat()
+            placed = rb.flush_parked()
         assert placed == 1
         assert fb.reqs and fb.reqs[0].request_id == req.request_id
         assert rb.total_parked_adopted == 1

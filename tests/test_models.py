@@ -279,3 +279,129 @@ def test_chunked_loss_matches_dense(cfg, params):
     for r, c in zip(flat_r, flat_c):
         np.testing.assert_allclose(np.asarray(c), np.asarray(r),
                                    rtol=2e-4, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# One block: every program that runs a layer runs models.layers.decoder_block,
+# so every route gives gpt.forward's numbers for every feature of the block.
+# A route that spells the equations out again fails here the day the block
+# learns something the copy did not.
+# ---------------------------------------------------------------------------
+
+_FEATURES = {
+    "attention_bias": ("gpt-test", {"attention_bias": True}),
+    "qk_norm": ("gpt-test", {"qk_norm": "projection"}),
+    "tied_embeddings": ("gpt-test", {"tie_word_embeddings": True}),
+    "dropless_moe": ("gpt-test-moe", {}),
+}
+_B, _S, _PS = 2, 16, 8
+
+
+def _feature_model(feature):
+    import dataclasses
+    name, changes = _FEATURES[feature]
+    cfg = dataclasses.replace(get_model_config(name), **changes)
+    params = init(cfg, jax.random.PRNGKey(7))
+
+    # init leaves biases and norm scales at zero, where a route that forgot
+    # one of them would still agree
+    def visible(path, p):
+        if path[-1].key in ("bias", "scale"):
+            key = jax.random.fold_in(jax.random.PRNGKey(8), p.size)
+            return p + 0.3 * jax.random.normal(key, p.shape, p.dtype)
+        return p
+    params = jax.tree_util.tree_map_with_path(visible, params)
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (_B, _S), 0,
+                                cfg.vocab_size)
+    return cfg, params, tokens
+
+
+def _route_training(cfg, params, tokens):
+    want = forward(params, tokens, cfg)
+    got = forward(params, tokens, cfg, remat="selective",
+                  segment_ids=jnp.ones_like(tokens))
+    return got, want
+
+
+def _route_cold_prefill(cfg, params, tokens):
+    cache = init_kv_cache(cfg, _B, 2 * _S, dtype=jnp.float32)
+    got, _ = forward(params, tokens, cfg, kv_cache=cache,
+                     cache_offset=jnp.zeros((_B,), jnp.int32))
+    return got, forward(params, tokens, cfg)
+
+
+def _paged_extend(cfg, params, tokens, window):
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        extend_step_forward)
+    pages_a_slot = _S // _PS
+    shape = (cfg.num_layers, 1 + _B * pages_a_slot, cfg.num_kv_heads, _PS,
+             cfg.head_dim)
+    kp, vp = jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
+    tables = 1 + jnp.arange(_B * pages_a_slot, dtype=jnp.int32).reshape(
+        _B, pages_a_slot)                       # page 0 is the scratch page
+    step = jax.jit(lambda t, start, kp, vp: extend_step_forward(
+        params, t, start, kp, vp, tables, cfg))
+    out = []
+    for start in range(0, _S, window):
+        logits, kp, vp = step(tokens[:, start:start + window],
+                              jnp.full((_B,), start, jnp.int32), kp, vp)
+        out.append(logits)
+    return jnp.concatenate(out, axis=1), forward(params, tokens, cfg)
+
+
+def _route_pipeline_stage(cfg, params, tokens):
+    """Two stages of one layer each over two microbatches: the schedule's
+    loss against the loss of gpt.forward's logits, microbatch by microbatch
+    (training's capacity dispatch counts a microbatch's tokens)."""
+    from distributed_llm_training_and_inference_system_tpu.config.schema import (
+        ParallelConfig)
+    from distributed_llm_training_and_inference_system_tpu.parallel.pipeline import (
+        make_pipeline_loss_fn)
+    par = ParallelConfig(pipeline_parallel=2, num_microbatches=2,
+                         activation_checkpoint="none")
+    micro = tokens.reshape(2, _B // 2, _S)
+    _, (got, _) = jax.jit(make_pipeline_loss_fn(cfg, par))(
+        params, {"tokens": micro})
+    losses = [next_token_loss(forward(params, m, cfg, moe_impl="capacity"),
+                              m) for m in micro]
+    total = sum(n for _, n in losses)
+    return got, sum(loss * n for loss, n in losses) / total
+
+
+def _route_calibration(cfg, params, tokens):
+    """The AWQ pass records the RMS of what layer i's q/k/v matmul is
+    given: the residual stream after i layers under layer i's attn_norm,
+    which gpt.forward returns for the model cut to i layers with that norm
+    as its final norm."""
+    import dataclasses
+    from distributed_llm_training_and_inference_system_tpu.ops.quantization import (
+        activation_channel_scales)
+    got = activation_channel_scales(params, cfg, tokens)["blocks.q.kernel"]
+    want = []
+    for i in range(1, cfg.num_layers):
+        cut = dict(params, blocks=jax.tree_util.tree_map(
+            lambda p: p[:i], params["blocks"]),
+            final_norm={"scale": params["blocks"]["attn_norm"]["scale"][i]})
+        h = forward(cut, tokens, dataclasses.replace(cfg, num_layers=i),
+                    return_hidden=True)
+        want.append(jnp.sqrt(jnp.mean(h ** 2, axis=(0, 1))) + 1e-6)
+    return got[1:], jnp.stack(want)
+
+
+_ROUTES = {
+    "training": _route_training,
+    "cold_prefill": _route_cold_prefill,
+    "paged_extend_t1": lambda *a: _paged_extend(*a, window=1),
+    "paged_extend_t8": lambda *a: _paged_extend(*a, window=8),
+    "pipeline_stage": _route_pipeline_stage,
+    "calibration": _route_calibration,
+}
+
+
+@pytest.mark.parametrize("feature", list(_FEATURES))
+@pytest.mark.parametrize("route", list(_ROUTES))
+def test_every_route_runs_the_one_block(route, feature):
+    cfg, params, tokens = _feature_model(feature)
+    got, want = _ROUTES[route](cfg, params, tokens)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)

@@ -367,7 +367,13 @@ class TestHTTPServer:
             r = rq.post(f"{base}/v1/completions", json={
                 "prompt": [1, 2, 3], "max_tokens": 5}, timeout=30)
             assert r.status_code == 500     # the in-flight request still fails
+            # fail_all answers the request BEFORE the engine thread runs
+            # recover(): wait for the flag to clear, not for the clock
+            deadline = time.monotonic() + 10.0
             h = rq.get(f"{base}/health", timeout=10)
+            while h.status_code != 200 and time.monotonic() < deadline:
+                time.sleep(0.02)
+                h = rq.get(f"{base}/health", timeout=10)
             assert h.status_code == 200
             assert h.json()["last_engine_error"] is None
         finally:
