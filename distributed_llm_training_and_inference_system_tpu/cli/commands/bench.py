@@ -389,6 +389,7 @@ def e2e(model_name, mode, steps, batch, seq_len, prompt_len, gen_len,
             # reset left warmup padded-slot steps in the utilization
             # denominator's sibling (review r4)
             eng.total_prefill_tokens = 0
+            eng.total_prefill_padded_tokens = 0
             eng.total_decode_steps = 0
             eng.total_padded_slot_steps = 0
             eng.total_short_dispatches = 0

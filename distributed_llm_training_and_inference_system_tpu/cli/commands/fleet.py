@@ -333,7 +333,8 @@ def migrate(request_id, replica, url):
 @click.option("--max-batch-size", default=8, show_default=True, type=int)
 @click.option("--max-seq-len", default=2048, show_default=True, type=int)
 @click.option("--prefill-chunk", default=0, show_default=True, type=int,
-              help="Chunked prefill size (0 = engine default).")
+              help="Finest step of the prefill bucket ladder (0 = engine "
+                   "default).")
 @click.option("--kv-block-size", default=64, show_default=True, type=int)
 @click.option("--dtype", default=None,
               type=click.Choice(["bfloat16", "float32"]))

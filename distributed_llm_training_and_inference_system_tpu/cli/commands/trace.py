@@ -144,12 +144,15 @@ def load_profile(path: str) -> dict:
     Modules`` and ``XLA Ops``); host spans are the ``llmctl.*`` events of
     every other plane, by the line (the thread) they were opened on.
     "page_walk" sums the ``live_pages`` / ``table_pages`` ids that every
-    decode dispatch's span carries (serve/engine.py ``_submit_group``)."""
+    decode dispatch's span carries (serve/engine.py ``_submit_group``);
+    "prefill_rows" the ``bucket`` (rows the program computed) and
+    ``tokens`` less ``cached`` (the live ones) of every prefill span."""
     from jax.profiler import ProfileData
     profile = ProfileData.from_file(str(path))
     devices: dict = {}
     host_spans: dict = {}
     page_walk = {"live_pages": 0, "table_pages": 0}
+    prefill_rows = {"rows": 0, "tokens": 0}
     for plane in profile.planes:
         is_device = plane.name.startswith("/device:")
         for line in plane.lines:
@@ -170,11 +173,17 @@ def load_profile(path: str) -> dict:
                         for key, value in e.stats:
                             if key in page_walk:
                                 page_walk[key] += int(value)
+                    elif e.name == SPAN_PREFIX + "engine.prefill.host":
+                        ids = dict(e.stats)
+                        if "bucket" in ids:
+                            prefill_rows["rows"] += int(ids["bucket"])
+                            prefill_rows["tokens"] += (
+                                int(ids["tokens"]) - int(ids.get("cached", 0)))
                 if found:
                     host_spans.setdefault(
                         f"{plane.name} | {line.name}", []).extend(found)
     return {"devices": devices, "host_spans": host_spans,
-            "page_walk": page_walk}
+            "page_walk": page_walk, "prefill_rows": prefill_rows}
 
 
 def _union(intervals) -> list:
@@ -314,6 +323,11 @@ def summarize(trace_dir):
                    f"of {walk['table_pages']} in the block tables "
                    f"({100 * walk['live_pages'] / walk['table_pages']:.1f} "
                    f"%), summed over the decode dispatches")
+    rows = loaded["prefill_rows"]
+    if rows["rows"]:
+        click.echo(f"prefill computed {rows['rows']} rows for "
+                   f"{rows['tokens']} tokens, "
+                   f"{100 * rows['tokens'] / rows['rows']:.1f} %")
     if spans:
         click.echo("host spans (calls, self seconds):")
         for name, (n, sec) in sorted(host_span_totals(spans).items(),

@@ -556,7 +556,10 @@ class ServeConfig:
     port: int = 8080
     max_batch_size: int = 8
     max_seq_len: int = 2048
-    prefill_chunk: int = 512        # prefill length bucketing granularity
+    # the finest step of the prefill bucket ladder: a cold prompt is padded
+    # to the next of c, 2c, 4c, then two rungs an octave (6c, 8c, 12c, ...),
+    # c being this rounded up to a page (serve/engine.py _bucket)
+    prefill_chunk: int = 256
     # max prompt tokens prefetched between two decode steps; bounds the
     # inter-token stall resident streams see during a long-prompt burst
     prefill_budget_tokens: int = 2048

@@ -402,6 +402,8 @@ COUNTER_FLOW: tuple[CounterFlow, ...] = (
                 "short_dispatches", None),
     CounterFlow("InferenceEngine", "total_prefill_tokens",
                 "prefill_tokens", None),
+    CounterFlow("InferenceEngine", "total_prefill_padded_tokens",
+                "prefill_padded_tokens", None),
     CounterFlow("InferenceEngine", "total_prefix_cached_tokens",
                 "prefix_cached_tokens", None),
     # feeds reprefill_tokens_avoided through the supervisor snapshot's

@@ -7,9 +7,10 @@ actually read — decode is O(1) in prompt length instead of recomputing the
 full prefix every token.
 
 TPU-shaped execution model:
-- **Prefill** — one compiled program per prompt-length bucket (lengths are
-  rounded up to ``prefill_chunk`` multiples so a handful of programs cover
-  all prompts; XLA static shapes, SURVEY §7.3.2). Runs the standard
+- **Prefill** — one compiled program per prompt-length bucket (a length is
+  rounded up to the next rung of a ladder: ``prefill_chunk`` times 1, 2,
+  4, then two rungs an octave (6, 8, 12, ...), so a handful of programs
+  cover all prompts; XLA static shapes, SURVEY §7.3.2). Runs the standard
   training-side ``models.gpt.forward`` and scatters the dense K/V into
   pages.
 - **Decode** — ONE compiled program, ever: every slot advances one token per
@@ -355,6 +356,9 @@ class InferenceEngine:
         self.moe_experts_hit = self.moe_layer_steps = 0
         self.moe_decode_experts_hit = self.moe_decode_layer_steps = 0
         self.total_prefill_tokens = 0      # tokens actually computed
+        # rows the prefill programs computed for them: the bucket of a cold
+        # or suffix prefill, the chunk program's rows of a chunked one
+        self.total_prefill_padded_tokens = 0
         self.total_prefix_cached_tokens = 0  # prompt tokens skipped via cache
         # of the cached tokens, the ones on fleet-requeued orphans (warm-
         # prefix requeue payoff — feeds reprefill_tokens_avoided)
@@ -597,11 +601,24 @@ class InferenceEngine:
         return True
 
     def _bucket(self, n: int) -> int:
-        chunk = max(self.serve_cfg.prefill_chunk, self.kv.page_size)
-        chunk = int(math.ceil(chunk / self.kv.page_size)) * self.kv.page_size
-        return min(int(math.ceil(max(n, 1) / chunk)) * chunk,
-                   int(math.ceil(self.serve_cfg.max_seq_len
-                                 / self.kv.page_size)) * self.kv.page_size)
+        """Rows of the cold-prefill program for an ``n``-token prompt: the
+        smallest rung at or above ``n`` of the ladder c, 2c, 4c, then two
+        rungs an octave (6c, 8c, 12c, 16c, ...), where c is
+        ``prefill_chunk`` rounded up to a page; capped at ``max_seq_len``
+        rounded up to a page. Padding stays under one c up to 2c, under
+        half the rung up to 4c and under a third of it above, and the
+        program count grows with the logarithm of ``max_seq_len`` (13 at
+        32k for c = 256). There is no 3c: every resident program costs a
+        replica 0.7-1.0 s at each start (trace, lowering, the executable's
+        read from the compile cache; ~13 s to compile on a cold one), which
+        the 3c rung does not earn back (PERF.md 6, PR 30)."""
+        PS = self.kv.page_size
+        c = math.ceil(max(self.serve_cfg.prefill_chunk, PS) / PS) * PS
+        k = math.ceil(max(n, 1) / c)
+        if k > 2:
+            octave = 1 << (k - 1).bit_length()      # power of two >= k
+            k = octave * 3 // 4 if 4 < k <= octave * 3 // 4 else octave
+        return min(k * c, math.ceil(self.serve_cfg.max_seq_len / PS) * PS)
 
     def _suffix_bucket(self, m: int) -> int:
         """Bucket for the un-cached prompt tail: page-granular, power-of-two
@@ -959,7 +976,7 @@ class InferenceEngine:
         completed = []
         C = self.serve_cfg.chunked_prefill_tokens
         budget = max(self.serve_cfg.prefill_budget_tokens, C)
-        spent = 0
+        spent = live = 0
         rids = list(self._partial_prefills)
         # resume point is a request_id, not an index: entries complete or
         # cancel between steps, so an index into last step's snapshot can
@@ -992,7 +1009,8 @@ class InferenceEngine:
                 self._chunk_rr = rid   # resume at this request next step
                 break
             spent += cost
-            bucket = self._suffix_bucket(this)
+            live += this
+            bucket = cost
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :this] = ctx[done:done + this]
             common = (self.params, jnp.asarray(tokens),
@@ -1034,9 +1052,11 @@ class InferenceEngine:
                 completed.append((req, token))
                 del self._partial_prefills[rid]
             self.total_prefill_tokens += this
+            self.total_prefill_padded_tokens += bucket
             if stage is not None and self.pipeline_chunk_hook is not None:
                 # no locks held: the coordinator side only enqueues
                 self.pipeline_chunk_hook(req, st["done"], st["done"] >= n)
+        self.spans.annotate(tokens=live, bucket=spent)
         return completed
 
     @engine_thread_only
@@ -1192,6 +1212,7 @@ class InferenceEngine:
                      for i in range(len(pins), n // PS)])
 
         self.total_prefill_tokens += computed
+        self.total_prefill_padded_tokens += bucket
         return req, token
 
     @engine_thread_only
@@ -2161,6 +2182,7 @@ class InferenceEngine:
             "decode_steps": self.total_decode_steps,
             "short_dispatches": self.total_short_dispatches,
             "prefill_tokens": self.total_prefill_tokens,
+            "prefill_padded_tokens": self.total_prefill_padded_tokens,
             "prefix_cached_tokens": self.total_prefix_cached_tokens,
             "requeue_cached_tokens": self.total_requeue_cached_tokens,
             "prefix_fetched_tokens": self.total_prefix_fetched_tokens,
@@ -2200,7 +2222,10 @@ class InferenceEngine:
         pipelining, and speculation all multiply resident executables the
         same way, so the count is first-class observable state: a user
         seeing an unexplained throughput delta can check whether the
-        program population changed before suspecting the schedule."""
+        program population changed before suspecting the schedule. (More
+        resident PREFILL programs have since measured free: three reached
+        where there were two left the decode step where it was, PERF.md
+        6, PR 30. What each costs is set-up time.)"""
         # snapshot: the engine thread inserts new buckets lock-free while
         # a stats request iterates — list() prevents "dict changed size"
         keys = list(self._prefill_cache)

@@ -365,6 +365,7 @@ class ServeFleet:
                                              max_tokens=2))
             n <<= 1
         r.engine.total_prefill_tokens = 0
+        r.engine.total_prefill_padded_tokens = 0
         r.engine.total_decode_steps = 0
         r.engine.total_padded_slot_steps = 0
         r.engine.total_short_dispatches = 0

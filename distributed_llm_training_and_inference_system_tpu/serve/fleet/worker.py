@@ -125,6 +125,7 @@ class FleetWorker:
             eng.generate([[1, 2, 3]], SamplingParams(
                 temperature=0.0, max_tokens=4))
             eng.total_prefill_tokens = 0
+            eng.total_prefill_padded_tokens = 0
             if hasattr(eng, "total_unexpected_prefills"):
                 eng.total_unexpected_prefills = 0
         with self._lock:

@@ -3,6 +3,7 @@ itself, through a tiny engine, in a CPU profile, and as `llmctl trace
 summarize` reduces them. No number here is a device metric."""
 
 import json
+import re
 import time
 
 import jax
@@ -323,6 +324,8 @@ def test_capture_serve_then_summarize_through_the_cli(tmp_path):
     assert res.exit_code == 0, res.output[-2000:]
     assert "llmctl.engine.decode.wait" in res.output
     assert "paged attention walks" in res.output
+    assert re.search(r"prefill computed \d+ rows for \d+ tokens, [\d.]+ %",
+                     res.output)
     assert runner.invoke(trace_cli.app, ["capture", "--serve"]).exit_code != 0
 
 

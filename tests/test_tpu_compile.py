@@ -175,7 +175,7 @@ def test_paged_multi_query_kernel_compiles(one_chip, as_tpu, window, layout,
     """Every window the engine can hand the multi-query kernel at default
     settings: the speculative verify window (8, all slots) and the
     cached-prefix / chunked-prefill suffix buckets 64..512 (one slot;
-    engine._suffix_bucket, prefill_chunk 512)."""
+    engine._suffix_bucket, prefill_chunk 256)."""
     from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
         paged_attention_multi)
     nq, nkv = LAYOUTS[layout]
@@ -259,13 +259,15 @@ def test_decode_program_updates_pool_in_place(one_chip, as_tpu, kv):
         f"layer's K pool is {layer_pool_bytes / 1e6:.1f} MB")
 
 
-@pytest.mark.parametrize("rows,tm", [(256, 16), (4096, 64), (8192, 128)])
+@pytest.mark.parametrize("rows,tm", [(256, 16), (2048, 32), (4096, 64),
+                                     (8192, 128)])
 @pytest.mark.parametrize("which", ["gate_up", "down"])
 def test_moe_grouped_matmul_kernel_compiles(one_chip, as_tpu, rows, tm, which):
     """The dropless MoE block's grouped matmul at OLMoE's published widths
     (64 experts, 2048 x 1024) on the ten-layer expert stack as it lies, with
     a traced layer index: the decode step's 32 tokens x 8 choices, and the
-    512- and 1,024-token prefill buckets. The stack is an operand WHOLE:
+    256-, 512- and 1,024-token prefill buckets with the tile
+    ``moe_row_tile`` gives each. The stack is an operand WHOLE:
     nothing expert-sized may be a temporary."""
     from distributed_llm_training_and_inference_system_tpu.ops.moe_gmm import (
         grouped_matmul)
