@@ -996,6 +996,109 @@ def child_kernels() -> None:
           (norm_reference(q, norms["q_norm"]["scale"]),
            norm_reference(k, norms["k_norm"]["scale"])))
 
+    # -- the hybrid layers (Nemotron-3-Nano's widths): the state-space mixer
+    # and the expert layer with HALF of the router's experts held and a
+    # shared expert, each against float32 with full-precision matmuls
+    from benchmark.reference import hybrid_decoder
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    from distributed_llm_training_and_inference_system_tpu.models.layers import (
+        experts_mixer, rms_norm, ssm_mixer)
+    from distributed_llm_training_and_inference_system_tpu.ops import ssm
+    hy = get_model_config("nemotron-h-test" if small
+                          else "nemotron-3-nano-30b-a3b")
+    held = hy.moe.num_experts if small else hy.moe.num_experts // 2
+    hy = dataclasses.replace(
+        hy, dtype="bfloat16", num_layers=4, layer_pattern="MEME",
+        moe=dataclasses.replace(hy.moe, num_experts=held,
+                                router_experts=hy.moe.router_width))
+    blocks = jax.jit(lambda k: gpt.init(hy, k, jnp.bfloat16)["blocks"])(
+        next(key))
+    sm, H = hy.ssm, hy.hidden_size
+    mix = jax.tree_util.tree_map(lambda a: a[1], blocks["ssm"])
+    mix["D"] = jax.random.uniform(next(key), mix["D"].shape, minval=0.5,
+                                  maxval=1.5)
+    mix["gate_norm"] = {"scale": jax.random.uniform(
+        next(key), mix["gate_norm"]["scale"].shape, minval=-0.5, maxval=0.5
+    ).astype(jnp.bfloat16)}
+    S, n_live, steps, slots = (32, 21, 3, 4) if small else (256, 200, 4, 8)
+    x = jax.random.normal(next(key), (1, S + steps, H), jnp.float32)
+    h_norm = rms_norm(x, mix["norm"]["scale"], hy.norm_eps).astype(
+        jnp.bfloat16)
+    # the reference sees the live rows and then the decoded ones
+    seq = jnp.concatenate([x[0, :n_live], x[0, S:]])
+    w = {"norm": mix["norm"]["scale"], "in_proj": mix["in_proj"]["kernel"],
+         "conv_kernel": mix["conv"]["kernel"],
+         "conv_bias": mix["conv"]["bias"], "dt_bias": mix["dt_bias"],
+         "A_log": mix["A_log"], "D": mix["D"],
+         "gate_norm": mix["gate_norm"]["scale"],
+         "out_proj": mix["out_proj"]["kernel"]}
+    ref = hybrid_decoder._mamba(
+        seq, w, jnp.ones((seq.shape[0],)), nh=sm.num_heads, p=sm.head_dim,
+        n=sm.state_size, g=sm.n_groups, eps=hy.norm_eps, float8=False,
+        norm_before_gate=False) - seq
+    live = jnp.arange(S)[None] < n_live
+    # (weights are ARGUMENTS of every jitted function here: closed over,
+    # a gigabyte of experts becomes a constant of the program)
+    got_w, (tail, hstate) = jax.jit(lambda h, p: ssm_mixer(
+        h, p, hy, ssm.recur_window(hy, live)))(h_norm[:, :S], mix)
+    check(f"ssm_mixer window [{S} rows, {n_live} live, {sm.num_heads} heads "
+          f"of {sm.head_dim}, state {sm.state_size}]", got_w[0, :n_live],
+          ref[:n_live])
+    conv_pool = jnp.zeros((2, slots, *tail.shape[1:]), jnp.bfloat16
+                          ).at[1, 2].set(tail[0].astype(jnp.bfloat16))
+    ssm_pool = jnp.zeros((2, slots, *hstate.shape[1:]), jnp.float32
+                         ).at[1, 2].set(hstate[0])
+    ok = jnp.arange(slots)[:, None] == 2
+    step = jax.jit(lambda h, c, s_, p: ssm_mixer(
+        h, p, hy, ssm.recur_step(hy, c, s_, 1, ok)), donate_argnums=(1, 2))
+    decoded = []
+    for t in range(steps):
+        h_t = jnp.zeros((slots, 1, H), jnp.bfloat16).at[2, 0].set(
+            h_norm[0, S + t])
+        out, (conv_pool, ssm_pool) = step(h_t, conv_pool, ssm_pool, mix)
+        decoded.append(out[2, 0])
+    check(f"ssm_mixer decode [{steps} steps over the state pools, slot 2 of "
+          f"{slots} live]", jnp.stack(decoded), ref[n_live:])
+    if float(jnp.abs(ssm_pool[:, jnp.asarray([0, 1, 3])]).max()) != 0.0:
+        failures.append("ssm decode wrote an idle slot's state")
+
+    moe_l = jax.tree_util.tree_map(lambda a: a[1], {
+        k: v for k, v in blocks["moe"].items() if k not in ("up", "down")})
+    moe_l["router"]["bias"] = jax.random.uniform(
+        next(key), moe_l["router"]["bias"].shape, minval=-0.1, maxval=0.1)
+    moe_l.update(up=blocks["moe"]["up"], down=blocks["moe"]["down"])
+    Kx, Er = hy.moe.experts_per_token, hy.moe.router_width
+
+    def experts_reference(u, moe_l):
+        u = u.astype(jnp.float32)
+        f32 = lambda a: a.astype(jnp.float32)
+        mm = functools.partial(jnp.matmul, precision="highest")
+        sc = jax.nn.sigmoid(mm(u, f32(moe_l["router"]["kernel"])))
+        _, top_e = jax.lax.top_k(sc + moe_l["router"]["bias"], Kx)
+        top_w = jnp.take_along_axis(sc, top_e, -1)
+        top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-20) \
+            * hy.moe.routed_scaling_factor
+        weights = jnp.zeros_like(sc).at[
+            jnp.arange(u.shape[0])[:, None], top_e].set(top_w)
+        act = lambda a: jnp.square(jax.nn.relu(a))
+        out = mm(act(mm(u, f32(moe_l["shared"]["up"]["kernel"]))),
+                 f32(moe_l["shared"]["down"]["kernel"]))
+        for e in range(held):               # experts 0..held-1 are here
+            out += weights[:, e, None] * mm(
+                act(mm(u, f32(moe_l["up"]["kernel"][1, e]).T)),
+                f32(moe_l["down"]["kernel"][1, e]))
+        return out
+
+    experts = jax.jit(lambda u, layer, li: experts_mixer(
+        u, layer, hy, None, "dropless", li)[0])
+    for batch, seq_len in ((8, 1), (1, 16)) if small else ((64, 1), (1, 512)):
+        u = jax.random.normal(next(key), (batch, seq_len, H), jnp.bfloat16)
+        got = experts(u, moe_l, jnp.int32(1))
+        check(f"experts_mixer [{batch * seq_len} rows, {held} of {Er} "
+              f"experts held, top-{Kx}, shared expert]",
+              got.reshape(-1, H), jax.jit(experts_reference)(
+                  u.reshape(-1, H), moe_l))
+
     # -- data packer: built here from native/dataloader.cpp -------------------
     from distributed_llm_training_and_inference_system_tpu.io import native
     from distributed_llm_training_and_inference_system_tpu.io.data import (

@@ -144,14 +144,15 @@ def load_profile(path: str) -> dict:
     Modules`` and ``XLA Ops``); host spans are the ``llmctl.*`` events of
     every other plane, by the line (the thread) they were opened on.
     "page_walk" sums the ``live_pages`` / ``table_pages`` ids that every
-    decode dispatch's span carries (serve/engine.py ``_submit_group``);
+    decode dispatch's span carries (serve/engine.py ``_submit_group``), and
+    for a model with state-space layers its ``ssm_slot_steps``;
     "prefill_rows" the ``bucket`` (rows the program computed) and
     ``tokens`` less ``cached`` (the live ones) of every prefill span."""
     from jax.profiler import ProfileData
     profile = ProfileData.from_file(str(path))
     devices: dict = {}
     host_spans: dict = {}
-    page_walk = {"live_pages": 0, "table_pages": 0}
+    page_walk = {"live_pages": 0, "table_pages": 0, "ssm_slot_steps": 0}
     prefill_rows = {"rows": 0, "tokens": 0}
     for plane in profile.planes:
         is_device = plane.name.startswith("/device:")
@@ -323,6 +324,10 @@ def summarize(trace_dir):
                    f"of {walk['table_pages']} in the block tables "
                    f"({100 * walk['live_pages'] / walk['table_pages']:.1f} "
                    f"%), summed over the decode dispatches")
+    if walk["ssm_slot_steps"]:
+        click.echo(f"state-space layers advanced {walk['ssm_slot_steps']} "
+                   f"slot states (live slots x decode steps), summed over "
+                   f"the decode dispatches")
     rows = loaded["prefill_rows"]
     if rows["rows"]:
         click.echo(f"prefill computed {rows['rows']} rows for "

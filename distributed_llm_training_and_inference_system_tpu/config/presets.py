@@ -9,7 +9,13 @@ TPU slices.
 
 from __future__ import annotations
 
-from .schema import HardwareConfig, ModelConfig, MoEConfig, RopeConfig
+from .schema import (
+    HardwareConfig,
+    ModelConfig,
+    MoEConfig,
+    RopeConfig,
+    SSMConfig,
+)
 
 # ---------------------------------------------------------------------------
 # Model templates. vocab_size padded to a multiple of 128 (MXU lane width)
@@ -111,6 +117,30 @@ MODEL_TEMPLATES: dict[str, ModelConfig] = {
         moe=MoEConfig(num_experts=64, experts_per_token=8,
                       norm_topk_prob=False),
     ),
+    # NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 (huggingface.co/nvidia/...,
+    # config.json, model_type nemotron_h) at its published sizes: a LAYER
+    # TABLE of 52 layers, each one norm and one mixer: 23 Mamba-2
+    # state-space mixers (64 heads of 64, state 128, 8 groups, conv 4), 6
+    # GQA attention layers (32 / 2 heads of 128, NO position embedding)
+    # and 23 expert layers (128 routed experts of width 1856, sigmoid
+    # router with a selection bias, top-6 renormalised x 2.5, squared-ReLU
+    # experts without a gate, one shared expert of 3712). 31.6 B
+    # parameters: it fits no chip here whole; the benchmark serves one
+    # chip's share (benchmark/configs/nemotron-3-nano-30b-a3b-14l-ep2.json).
+    "nemotron-3-nano-30b-a3b": ModelConfig(
+        name="nemotron-3-nano-30b-a3b", num_layers=52, hidden_size=2688,
+        ffn_size=1856, num_heads=32, num_kv_heads=2, head_dim=128,
+        vocab_size=131072, max_position_embeddings=262144,
+        activation="relu2", mlp_gated=False, norm_eps=1e-5,
+        position_embedding="none",
+        layer_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+        ssm=SSMConfig(num_heads=64, head_dim=64, state_size=128, n_groups=8,
+                      conv_kernel=4, chunk_size=128),
+        moe=MoEConfig(num_experts=128, experts_per_token=6,
+                      norm_topk_prob=True, router_score="sigmoid",
+                      selection_bias=True, routed_scaling_factor=2.5,
+                      shared_expert_size=3712),
+    ),
     # Depth-truncated gpt-7b: the SAME H=4096/D=128/F=11008 layer at 4
     # layers, so one 16 GB chip can STEP the north-star model's real
     # matmul shapes (full gpt-7b training state needs ~27 GB params+Adam
@@ -154,6 +184,23 @@ TEST_TEMPLATES: dict[str, ModelConfig] = {
         qk_norm="projection",
         moe=MoEConfig(num_experts=8, experts_per_token=2,
                       norm_topk_prob=False),
+    ),
+    # nemotron_h's shape in small: one 7-layer motif of its layer table,
+    # state-space mixers beside GQA attention without rope and sigmoid-
+    # routed squared-ReLU experts, HALF of the router's 8 experts held
+    # here (the other half is ``first_expert=4``), a shared expert.
+    "nemotron-h-test": ModelConfig(
+        name="nemotron-h-test", num_layers=7, hidden_size=64, ffn_size=32,
+        num_heads=4, num_kv_heads=2, head_dim=16, vocab_size=256,
+        max_position_embeddings=256, activation="relu2", mlp_gated=False,
+        dtype="float32", position_embedding="none",
+        layer_pattern="MEMEM*E",
+        ssm=SSMConfig(num_heads=8, head_dim=8, state_size=16, n_groups=2,
+                      conv_kernel=4, chunk_size=16),
+        moe=MoEConfig(num_experts=4, router_experts=8, experts_per_token=3,
+                      norm_topk_prob=True, router_score="sigmoid",
+                      selection_bias=True, routed_scaling_factor=2.5,
+                      shared_expert_size=48),
     ),
 }
 

@@ -80,16 +80,70 @@ class MoEConfig:
     # experts as the softmax over ALL experts gave them (OLMoE's
     # published ``norm_topk_prob: false``)
     norm_topk_prob: bool = True
+    # ``num_experts`` counts the experts HELD here: this chip's share of a
+    # layer that several chips divide. The router keeps its published
+    # width ``router_experts`` (0 = ``num_experts``: every expert is
+    # here) and the held experts are ``first_expert ..< first_expert +
+    # num_experts`` of it. A token's choices that fall on absent experts
+    # are computed by nobody here (models/layers.py moe_block): no code
+    # stands in for the absent chips or their exchange.
+    router_experts: int = 0
+    first_expert: int = 0
+    # how the router scores: "softmax" over all experts (OLMoE, Mixtral),
+    # or "sigmoid" of each logit (``n_routed_experts`` models): there the
+    # top-k is taken of score + ``selection_bias`` (a per-expert vector,
+    # ``e_score_correction_bias``, that picks and does not weigh) and the
+    # weights are the chosen scores, renormalised iff ``norm_topk_prob``,
+    # times ``routed_scaling_factor``
+    router_score: str = "softmax"
+    selection_bias: bool = False
+    routed_scaling_factor: float = 1.0
+    # width of the ONE shared expert every token also takes (0 = none):
+    # a plain dense branch beside the routed ones
+    shared_expert_size: int = 0
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
+    def holds_all(self) -> bool:
+        return self.router_width == self.num_experts
+
+    @property
+    def stats_size(self) -> int:
+        """Length of a block's ``moe_stats`` vector (models/layers.py):
+        choices per held expert, experts hit and, where not every expert
+        is held, the live choices over ALL experts."""
+        return self.num_experts + (1 if self.holds_all else 2)
 
     @classmethod
     def from_dict(cls, d: dict[str, Any] | None) -> "MoEConfig":
         """``d`` is the nested ``moe`` table, or a model's published
-        ``config.json`` keys (``num_experts``, ``num_experts_per_tok``,
-        ``norm_topk_prob``) at the top level of the model dict."""
+        ``config.json`` keys (``num_experts`` / ``n_routed_experts``,
+        ``num_experts_per_tok``, ``norm_topk_prob``,
+        ``routed_scaling_factor``, ``moe_shared_expert_intermediate_size``)
+        at the top level of the model dict. ``n_routed_experts`` marks the
+        sigmoid router with a selection bias (DeepSeek-V3's form, which
+        ``nemotron_h`` takes)."""
         if not d:
             return cls()
+        routed = "n_routed_experts" in d
         return cls(
-            num_experts=int(_take(d, "num_experts", "experts", default=0)),
+            num_experts=int(_take(d, "num_experts", "experts",
+                                  "n_routed_experts", default=0)),
+            router_experts=int(_take(d, "router_experts", default=0)),
+            first_expert=int(_take(d, "first_expert", default=0)),
+            router_score=str(_take(d, "router_score", default=(
+                "sigmoid" if routed else "softmax"))),
+            selection_bias=_parse_bool("selection_bias", _take(
+                d, "selection_bias", default=routed)),
+            routed_scaling_factor=float(_take(
+                d, "routed_scaling_factor", default=1.0)),
+            shared_expert_size=int(_take(
+                d, "shared_expert_size",
+                "moe_shared_expert_intermediate_size", default=0))
+            * int(_take(d, "n_shared_experts", default=1)),
             experts_per_token=int(_take(d, "experts_per_token", "top_k",
                                         "num_experts_per_tok", default=2)),
             router_aux_loss_weight=float(_take(d, "router_aux_loss_weight", default=0.01)),
@@ -97,6 +151,65 @@ class MoEConfig:
             norm_topk_prob=_parse_bool("norm_topk_prob", _take(
                 d, "norm_topk_prob", default=True)),
         )
+
+
+@dataclass
+class SSMConfig:
+    """Mamba-2 state-space mixer sizes (the ``M`` layers of a layer table).
+
+    ``num_heads`` heads of ``head_dim`` channels (inner width ``num_heads
+    * head_dim``: NOT ``expand * hidden_size``), a state of ``state_size``
+    a channel, B and C shared by the heads of each of ``n_groups`` groups,
+    a depthwise causal conv of width ``conv_kernel`` over x, B and C, and
+    the chunk length of the prefill scan. The recurrent state is cached
+    between decode steps in float32, by construction (serve/kv_cache.py:
+    at bfloat16 a sequence's logits drift; tests/test_hybrid.py)."""
+    num_heads: int = 0              # 0 = the model has no such layer
+    head_dim: int = 64
+    state_size: int = 128
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk_size: int = 128
+
+    @property
+    def inner_size(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def conv_channels(self) -> int:
+        return self.inner_size + 2 * self.n_groups * self.state_size
+
+    @classmethod
+    def from_dict(cls, d: dict[str, Any] | None,
+                  published: dict[str, Any] | None = None) -> "SSMConfig":
+        """The nested ``ssm`` table ``d``, else the ``nemotron_h`` keys of
+        a ``published`` config.json (``mamba_num_heads``, ``mamba_head_dim``,
+        ``ssm_state_size``, ``n_groups``, ``conv_kernel``, ``chunk_size``)
+        at the top level of the model dict. A file that states the dtype
+        the state is cached in (``ssm_state_dtype``) may only state
+        float32."""
+        if d:
+            return cls(**{f.name: type(f.default)(d[f.name])
+                          for f in dataclasses.fields(cls) if f.name in d})
+        p = published or {}
+        if "mamba_num_heads" not in p:
+            return cls()
+        if p.get("ssm_state_dtype", "float32") != "float32":
+            raise ConfigError(
+                f"ssm_state_dtype {p['ssm_state_dtype']!r}: the recurrent "
+                "state is cached in float32 and in nothing else")
+        return cls(
+            num_heads=int(p["mamba_num_heads"]),
+            head_dim=int(p.get("mamba_head_dim", 64)),
+            state_size=int(p.get("ssm_state_size", 128)),
+            n_groups=int(p.get("n_groups", 1)),
+            conv_kernel=int(p.get("conv_kernel", 4)),
+            chunk_size=int(p.get("chunk_size", 128)),
+        )
+
+
+# what a layer of a layer table may be (``nemotron_h``'s own letters)
+LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "moe"}
 
 
 @dataclass
@@ -131,10 +244,48 @@ class ModelConfig:
     # split into heads (OLMoE; its config.json has no key for it, it
     # follows from ``model_type: olmoe``). "none": llama-style.
     qk_norm: str = "none"
+    # The LAYER TABLE: one letter a layer (``hybrid_override_pattern``):
+    # ``M`` a Mamba-2 state-space mixer, ``*`` attention, ``E`` sparse
+    # experts; each such layer is ONE norm and ONE mixer on the residual
+    # stream. "" = the uniform stack (every layer attention THEN
+    # feed-forward under two norms).
+    layer_pattern: str = ""
+    ssm: SSMConfig = field(default_factory=SSMConfig)
+    # "rope" | "none": ``nemotron_h``'s attention applies no position
+    # embedding (positions come from the state-space layers)
+    position_embedding: str = "rope"
+    # False: the feed-forward is down(act(up(x))), two kernels (no gate)
+    mlp_gated: bool = True
 
     @property
     def is_moe(self) -> bool:
         return self.moe.num_experts > 0
+
+    def layers_of(self, kind: str) -> int:
+        """How many layers of the table are ``kind`` (M | * | E)."""
+        return self.layer_pattern.count(kind)
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep K and V: the attention layers of a table,
+        every layer of a uniform stack."""
+        return self.layers_of("*") if self.layer_pattern else self.num_layers
+
+    @property
+    def moe_layers(self) -> int:
+        if self.layer_pattern:
+            return self.layers_of("E")
+        return self.num_layers if self.is_moe else 0
+
+    @property
+    def ssm_layers(self) -> int:
+        return self.layers_of("M")
+
+    @property
+    def is_recurrent(self) -> bool:
+        """Some layer keeps a fixed-size state a sequence beside (or in
+        place of) K/V pages."""
+        return self.ssm_layers > 0
 
     def validate(self) -> None:
         # hidden_size need not equal num_heads*head_dim (projections go
@@ -148,18 +299,52 @@ class ModelConfig:
                 f"num_kv_heads ({self.num_kv_heads})")
         if self.vocab_size <= 0 or self.num_layers <= 0:
             raise ConfigError("vocab_size and num_layers must be positive")
-        if self.activation not in ("silu", "gelu", "relu"):
+        if self.activation not in ("silu", "gelu", "relu", "relu2"):
             raise ConfigError(f"unknown activation {self.activation!r}")
+        if self.position_embedding not in ("rope", "none"):
+            raise ConfigError("position_embedding must be rope|none (got "
+                              f"{self.position_embedding!r})")
+        if self.layer_pattern:
+            unknown = sorted(set(self.layer_pattern) - set(LAYER_KINDS))
+            if unknown:
+                raise ConfigError(
+                    f"layer_pattern {self.layer_pattern!r}: no layer kind "
+                    f"{unknown} (known: M state-space, * attention, E "
+                    "experts)")
+            if len(self.layer_pattern) != self.num_layers:
+                raise ConfigError(
+                    f"layer_pattern has {len(self.layer_pattern)} layers, "
+                    f"num_layers is {self.num_layers}")
+            if self.layers_of("E") and not self.is_moe:
+                raise ConfigError("layer_pattern has E layers and the "
+                                  "model has no experts")
+            s = self.ssm
+            if self.layers_of("M") and (
+                    s.num_heads < 1 or s.num_heads % max(s.n_groups, 1)
+                    or s.conv_kernel < 2 or s.chunk_size < 1):
+                raise ConfigError(
+                    "layer_pattern has M layers: ssm.num_heads must be a "
+                    "positive multiple of ssm.n_groups, conv_kernel >= 2 "
+                    f"(got {s})")
+        m = self.moe
+        if self.is_moe and (
+                m.router_score not in ("softmax", "sigmoid")
+                or m.first_expert < 0
+                or m.first_expert + m.num_experts > m.router_width):
+            raise ConfigError(
+                f"moe: router_score softmax|sigmoid, and the held experts "
+                f"{m.first_expert}..<{m.first_expert + m.num_experts} must "
+                f"lie inside the router's {m.router_width} (got {m})")
         if self.arch != "decoder-only":
             raise ConfigError(f"unsupported arch {self.arch!r} (decoder-only only)")
         if self.qk_norm not in ("none", "projection"):
             raise ConfigError(f"qk_norm must be none|projection (got "
                               f"{self.qk_norm!r})")
         if self.is_moe and not (
-                1 <= self.moe.experts_per_token <= self.moe.num_experts):
+                1 <= self.moe.experts_per_token <= self.moe.router_width):
             raise ConfigError(
                 f"experts_per_token ({self.moe.experts_per_token}) must lie "
-                f"in 1..num_experts ({self.moe.num_experts})")
+                f"in 1..num_experts ({self.moe.router_width})")
 
     @property
     def param_count(self) -> int:
@@ -172,6 +357,23 @@ class ModelConfig:
         kv_dim = self.num_kv_heads * self.head_dim
         q_dim = self.num_heads * self.head_dim
         attn = h * q_dim + 2 * h * kv_dim + q_dim * h
+        if self.layer_pattern:
+            # one norm and one mixer a layer; the experts HELD here
+            s, m = self.ssm, self.moe
+            per_expert = (3 if self.mlp_gated else 2) * h
+            mixer = {
+                "M": h * (2 * s.inner_size + 2 * s.n_groups * s.state_size
+                          + s.num_heads)
+                + (s.conv_kernel + 1) * s.conv_channels + 3 * s.num_heads
+                + s.inner_size + s.inner_size * h,
+                "*": attn,
+                "E": h * m.router_width
+                + (m.router_width if m.selection_bias else 0)
+                + m.num_experts * per_expert * f
+                + per_expert * m.shared_expert_size,
+            }
+            return (v * h + sum(h + mixer[k] for k in self.layer_pattern)
+                    + h + (0 if self.tie_word_embeddings else v * h))
         if self.activation in ("silu", "gelu"):    # gated: w_gate, w_up, w_down
             mlp_dense = 3 * h * f
         else:
@@ -194,12 +396,17 @@ class ModelConfig:
         attn = d.get("attention", {}) or {}
         num_heads = int(_take(d, "heads", "num_heads", "num_attention_heads", default=12))
         hidden = int(_take(d, "hidden", "hidden_size", "d_model", default=768))
+        activation = str(_take(d, "activation", "hidden_act",
+                               "mlp_hidden_act", default="silu"))
         cfg = cls(
             name=str(_take(d, "name", default="custom")),
             arch=str(_take(d, "arch", default="decoder-only")),
             num_layers=int(_take(d, "layers", "num_layers", "num_hidden_layers", default=12)),
             hidden_size=hidden,
-            ffn_size=int(_take(d, "ffn", "ffn_size", "intermediate_size", default=4 * hidden)),
+            # (a model with ``moe_intermediate_size`` states ONE expert's
+            # width under it)
+            ffn_size=int(_take(d, "ffn", "ffn_size", "moe_intermediate_size",
+                               "intermediate_size", default=4 * hidden)),
             num_heads=num_heads,
             num_kv_heads=int(_take(d, "kv_heads", "num_kv_heads", "num_key_value_heads",
                                    default=num_heads)),
@@ -208,8 +415,9 @@ class ModelConfig:
             max_position_embeddings=int(_take(d, "max_position_embeddings", "max_seq_len",
                                               default=2048)),
             rope=RopeConfig.from_dict(d.get("rope")),
-            activation=str(_take(d, "activation", "hidden_act", default="silu")),
-            norm_eps=float(_take(d, "layer_norm_eps", "norm_eps", "rms_norm_eps", default=1e-5)),
+            activation=activation,
+            norm_eps=float(_take(d, "layer_norm_eps", "norm_eps", "rms_norm_eps",
+                                 "layer_norm_epsilon", default=1e-5)),
             tie_word_embeddings=_parse_bool("tie_word_embeddings", _take(d, "tie_word_embeddings", default=False)),
             attention_bias=_parse_bool("attention_bias", attn.get("bias", _take(d, "attention_bias", default=False))),
             dropout=float(attn.get("dropout", _take(d, "dropout", default=0.0))),
@@ -219,6 +427,15 @@ class ModelConfig:
             # (its ``intermediate_size`` is then ONE expert's width)
             moe=MoEConfig.from_dict(d.get("moe") or d),
             qk_norm=str(_take(d, "qk_norm", default="none")),
+            layer_pattern=str(_take(d, "layer_pattern",
+                                    "hybrid_override_pattern", default="")),
+            ssm=SSMConfig.from_dict(d.get("ssm"), published=d),
+            position_embedding=str(_take(d, "position_embedding",
+                                         default="rope")),
+            # squared ReLU comes without a gate (``nemotron_h``'s
+            # ``mlp_hidden_act: relu2``) unless the dict says otherwise
+            mlp_gated=_parse_bool("mlp_gated", _take(
+                d, "mlp_gated", default=activation != "relu2")),
         )
         cfg.validate()
         return cfg

@@ -60,6 +60,20 @@ def init(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
         return (jax.random.truncated_normal(key_, -3, 3, shape, jnp.float32)
                 * scale).astype(dtype)
 
+    def around(blocks):
+        params = {
+            "embed": {"embedding": dense(next(keys), V, H)},
+            "blocks": blocks,
+            "final_norm": {"scale": norm_init(H)},
+        }
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = {"kernel": dense(next(keys), H, V)}
+        return params
+
+    if cfg.layer_pattern:
+        return around(_init_table_blocks(cfg, keys, norm_init, dense,
+                                         resid_std, dtype))
+
     blocks = {
         "attn_norm": {"scale": norm_init(L, H)},
         "q": {"kernel": dense(next(keys), L, H, Nq * D)},
@@ -90,14 +104,81 @@ def init(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
             "down": {"kernel": dense(next(keys), L, F, H, scale=resid_std)},
         }
 
-    params = {
-        "embed": {"embedding": dense(next(keys), V, H)},
-        "blocks": blocks,
-        "final_norm": {"scale": norm_init(H)},
-    }
-    if not cfg.tie_word_embeddings:
-        params["lm_head"] = {"kernel": dense(next(keys), H, V)}
-    return params
+    return around(blocks)
+
+
+def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
+                       dtype) -> Params:
+    """The blocks of a layer table: one stack a KIND (``ssm``, ``attn``,
+    ``moe``), each leaf with a leading axis over the layers of that kind in
+    table order. The state-space parameters start as Mamba-2's own init:
+    step sizes log-uniform in [1e-3, 1e-1] (``dt_bias`` their inverse
+    softplus), ``A`` uniform in [1, 16], ``D`` one, the conv uniform in
+    +-1/sqrt(K)."""
+    H, D, F = cfg.hidden_size, cfg.head_dim, cfg.ffn_size
+    Nq, Nkv = cfg.num_heads, cfg.num_kv_heads
+    blocks = {}
+    Lm, La, Le = cfg.ssm_layers, cfg.kv_layers, cfg.moe_layers
+    if Lm:
+        s = cfg.ssm
+        nh, d_in, C, K = s.num_heads, s.inner_size, s.conv_channels, \
+            s.conv_kernel
+        step = jnp.exp(jax.random.uniform(
+            next(keys), (Lm, nh), jnp.float32,
+            jnp.log(1e-3), jnp.log(1e-1)))
+        bound = 1.0 / jnp.sqrt(float(K))
+        blocks["ssm"] = {
+            "norm": {"scale": norm_init(Lm, H)},
+            "in_proj": {"kernel": dense(next(keys), Lm, H,
+                                        d_in + C + nh)},
+            "conv": {"kernel": jax.random.uniform(
+                         next(keys), (Lm, K, C), jnp.float32, -bound,
+                         bound).astype(dtype),
+                     "bias": jax.random.uniform(
+                         next(keys), (Lm, C), jnp.float32, -bound,
+                         bound).astype(dtype)},
+            # small vectors the recurrence exponentiates: kept float32
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "A_log": jnp.log(jax.random.uniform(
+                next(keys), (Lm, nh), jnp.float32, 1.0, 16.0)),
+            "D": jnp.ones((Lm, nh), jnp.float32),
+            "gate_norm": {"scale": norm_init(Lm, d_in)},
+            "out_proj": {"kernel": dense(next(keys), Lm, d_in, H,
+                                         scale=resid_std)},
+        }
+    if La:
+        blocks["attn"] = {
+            "norm": {"scale": norm_init(La, H)},
+            "q": {"kernel": dense(next(keys), La, H, Nq * D)},
+            "k": {"kernel": dense(next(keys), La, H, Nkv * D)},
+            "v": {"kernel": dense(next(keys), La, H, Nkv * D)},
+            "o": {"kernel": dense(next(keys), La, Nq * D, H,
+                                  scale=resid_std)},
+        }
+    if Le:
+        m = cfg.moe
+        E, Fs = m.num_experts, m.shared_expert_size
+        names = ("gate", "up") if cfg.mlp_gated else ("up",)
+        moe = {"norm": {"scale": norm_init(Le, H)},
+               "router": {"kernel": dense(next(keys), Le, H, m.router_width)}}
+        if m.selection_bias:
+            moe["router"]["bias"] = jnp.zeros((Le, m.router_width),
+                                              jnp.float32)
+        # a width that is no multiple of the chip's 128 lanes is stored
+        # (out, in) = [F, H]: ops/moe_gmm.py says why. (moe_block reads the
+        # order off the stack's shape, which F == H would not tell.)
+        up_shape = (F, H) if F % 128 and F != H else (H, F)
+        for n in names:
+            moe[n] = {"kernel": dense(next(keys), Le, E, *up_shape)}
+        moe["down"] = {"kernel": dense(next(keys), Le, E, F, H,
+                                       scale=resid_std)}
+        if Fs:
+            moe["shared"] = {n: {"kernel": dense(next(keys), Le, H, Fs)}
+                             for n in names}
+            moe["shared"]["down"] = {"kernel": dense(next(keys), Le, Fs, H,
+                                                     scale=resid_std)}
+        blocks["moe"] = moe
+    return blocks
 
 
 # ---------------------------------------------------------------------------
@@ -118,12 +199,40 @@ def split_expert_stacks(blocks: Params):
     OLMoE's widths. The stacks stay outside the scan instead and the
     kernel addresses ``stack[layer, expert]`` (ops/moe_gmm.py)."""
     moe = blocks.get("moe")
+    names = [n for n in _EXPERT_KERNELS if moe is not None and n in moe]
     if moe is None or not all(
-            isinstance(moe[n]["kernel"], jax.Array) for n in _EXPERT_KERNELS):
+            isinstance(moe[n]["kernel"], jax.Array) for n in names):
         return blocks, None
     scanned = dict(blocks, moe={k: v for k, v in moe.items()
-                                if k not in _EXPERT_KERNELS})
-    return scanned, {n: moe[n] for n in _EXPERT_KERNELS}
+                                if k not in names})
+    return scanned, {n: moe[n] for n in names}
+
+
+def table_layers(cfg: ModelConfig) -> list[tuple[str, int]]:
+    """The layer table as (kind, index among the layers of that kind) in
+    layer order: ``MEM*`` -> [("M", 0), ("E", 0), ("M", 1), ("*", 0)]. The
+    index addresses the kind's parameter stack, and for ``*`` the K/V
+    pools, for ``M`` the state pools, for ``E`` the expert stacks."""
+    seen: dict = {}
+    out = []
+    for kind in cfg.layer_pattern:
+        out.append((kind, seen.get(kind, 0)))
+        seen[kind] = out[-1][1] + 1
+    return out
+
+
+def table_layer(blocks: Params, kind: str, index: int) -> Params:
+    """One layer of ``kind``'s stack (a static index: the table is walked
+    by a Python loop). An expert layer keeps its routed experts' kernels
+    as the WHOLE stacks, which ``moe_block`` addresses by ``index``."""
+    from ..config.schema import LAYER_KINDS
+    stack = blocks[LAYER_KINDS[kind]]
+    whole = {}
+    if kind == "E":
+        stack, whole = split_expert_stacks({"moe": stack})
+        stack, whole = stack["moe"], whole or {}
+    layer = jax.tree_util.tree_map(lambda a: a[index], stack)
+    return dict(layer, **whole)
 
 
 def layer_experts(layer: Params, expert_stacks, layer_index):
@@ -136,10 +245,26 @@ def layer_experts(layer: Params, expert_stacks, layer_index):
     return dict(layer, moe=dict(layer["moe"], **expert_stacks)), layer_index
 
 
+# the float32 vectors of a state-space layer (exponentiated every token)
+# and the router's selection bias: never rounded to the compute dtype
+_KEPT_FLOAT32 = (("ssm", "dt_bias"), ("ssm", "A_log"), ("ssm", "D"),
+                 ("moe", "router", "bias"))
+
+
+def cast_table_blocks(blocks: Params, dtype) -> Params:
+    """``precast_params`` for the blocks of a layer table: every plain leaf
+    to the compute dtype but the state-space layers' ``dt_bias`` / ``A_log``
+    / ``D`` and the router's selection ``bias``, which stay as stored."""
+    def one(path, x):
+        kept = tuple(k.key for k in path) in _KEPT_FLOAT32
+        return x if kept else x.astype(dtype)
+    return jax.tree_util.tree_map_with_path(one, blocks)
+
+
 def _block_fn(cfg: ModelConfig, attn_impl: str, norm_impl: str,
               x, layer, positions, segment_ids, inv_freq,
               kv_cache=None, cache_offset=None, *, moe_impl: str = "dropless",
-              expert_stacks=None, layer_index=None):
+              expert_stacks=None, layer_index=None, kind=None, recur=None):
     """``decoder_block`` with no cache or over one layer's dense
     ``kv_cache``. Returns (x, new_kv_cache, aux): ``aux`` is the router's
     load-balancing loss, or under ``moe_impl="dropless"`` the layer's
@@ -147,11 +272,12 @@ def _block_fn(cfg: ModelConfig, attn_impl: str, norm_impl: str,
     attend = (attend_fresh(positions, segment_ids, attn_impl)
               if kv_cache is None
               else attend_dense_cache(kv_cache, cache_offset, positions))
-    if cfg.is_moe and moe_impl == "dropless":
+    if cfg.is_moe and moe_impl == "dropless" and kind is None:
         layer, layer_index = layer_experts(layer, expert_stacks, layer_index)
     x, new_cache, aux = decoder_block(
         x, layer, cfg, positions, inv_freq, attend, norm_impl=norm_impl,
-        live=segment_ids, moe_impl=moe_impl, layer_index=layer_index)
+        live=segment_ids, moe_impl=moe_impl, layer_index=layer_index,
+        kind=kind, recur=recur)
     if aux is None:
         aux = jnp.float32(0.0)
     # anchor GSPMD propagation at the block boundary (no-op off-mesh)
@@ -216,6 +342,7 @@ def forward(
     return_hidden: bool = False,
     moe_impl: str = "dropless",      # dropless | capacity (training)
     return_moe_stats: bool = False,
+    return_ssm_state: bool = False,
 ):
     """Compute logits [B, S, V] (fp32) — or, with ``return_hidden=True``,
     the final-normed hidden states [B, S, H] in the compute dtype (consumed
@@ -238,6 +365,14 @@ def forward(
       the [E + 1] int32 vector of the live tokens' choices per expert
       summed over the layers and, last, the (layer, expert) pairs that got
       any (``segment_ids`` 0 = not live: prefill padding is never counted).
+    - a model with a LAYER TABLE (``cfg.layer_pattern``) is walked layer by
+      layer in a Python loop over one parameter stack a kind
+      (``table_layers``); ``kv_cache`` then holds the ATTENTION layers
+      alone ([La, B, Smax, Nkv, D]). Its state-space layers start from a
+      zero state and keep padding (``segment_ids`` 0, which must follow
+      the live tokens) out of it; ``return_ssm_state`` appends
+      (conv tails [Lm, B, K-1, C], states [Lm, B, nh, P, N] float32) after
+      the last live token: what cold prefill arms a slot with.
     """
     compute_dtype = jnp.dtype(cfg.dtype)
     B, S = tokens.shape
@@ -262,6 +397,24 @@ def forward(
             "pass moe_impl='capacity' with return_aux")
     if return_moe_stats and not dropless:
         raise ValueError("return_moe_stats needs a dropless MoE model")
+    if return_ssm_state and not cfg.is_recurrent:
+        raise ValueError("return_ssm_state needs a model with state-space "
+                         "layers")
+    if cfg.layer_pattern:
+        if moe_impl != "dropless" or remat != "none":
+            # training's capacity route drops tokens, the grouped matmul
+            # and the chunked scan have no backward written (ROADMAP A5)
+            raise ValueError(
+                "a model with a layer table runs the dropless inference "
+                "forward only (moe_impl='dropless', remat='none')")
+        x, new_cache, aux_total, ssm_state = _walk_table(
+            params, x, cfg, positions, segment_ids, inv_freq, kv_cache,
+            cache_offset, attn_impl, norm_impl, compute_dtype)
+        return _finish_forward(
+            params, x, cfg, norm_impl, unembed_positions, return_hidden,
+            [new_cache] * (kv_cache is not None)
+            + [aux_total] * return_moe_stats
+            + [ssm_state] * return_ssm_state)
 
     # plain leaves are cast to the compute dtype ONCE before the scan
     # (casting inside the body would stream fp32 master weights from HBM
@@ -279,7 +432,7 @@ def forward(
         block = functools.partial(_block_fn, cfg, attn_impl, norm_impl,
                                   moe_impl="dropless",
                                   expert_stacks=expert_stacks)
-        aux0 = jnp.zeros((cfg.moe.num_experts + 1,), jnp.int32)
+        aux0 = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
     else:
         block = functools.partial(_block_fn, cfg, attn_impl, norm_impl,
                                   moe_impl="capacity")
@@ -314,6 +467,55 @@ def forward(
             body, (x, aux0), (blocks, layer_ids, k_cache, v_cache))
         new_cache = new_kvs
 
+    extras = []
+    if kv_cache is not None:
+        extras.append(new_cache)
+    if return_aux or return_moe_stats:
+        extras.append(aux_total)
+    return _finish_forward(params, x, cfg, norm_impl, unembed_positions,
+                           return_hidden, extras)
+
+
+def _walk_table(params, x, cfg: ModelConfig, positions, segment_ids,
+                inv_freq, kv_cache, cache_offset, attn_impl, norm_impl,
+                compute_dtype):
+    """The layers of a layer table over the residual stream ``x``: a Python
+    loop over ``table_layers`` (no scan: the kinds' parameter shapes
+    differ), each layer one ``decoder_block`` of its kind. Returns (x, the
+    attention layers' updated dense cache or None, the summed
+    ``moe_stats``, (conv tails, states) of the state-space layers)."""
+    from ..ops.ssm import recur_window
+    blocks = cast_table_blocks(params["blocks"], compute_dtype)
+    recur = recur_window(cfg, segment_ids)
+    aux_total = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
+    caches, tails, states = [], [], []
+    for kind, i in table_layers(cfg):
+        cache = (None if kv_cache is None or kind != "*"
+                 else (kv_cache[0][i], kv_cache[1][i]))
+        x, state, aux = _block_fn(
+            cfg, attn_impl, norm_impl, x.astype(compute_dtype),
+            table_layer(blocks, kind, i), positions, segment_ids, inv_freq,
+            kv_cache=cache, cache_offset=cache_offset, layer_index=i,
+            kind=kind, recur=recur)
+        if kind == "M":
+            tails.append(state[0])
+            states.append(state[1])
+        elif kind == "*" and cache is not None:
+            caches.append(state)
+        elif kind == "E":
+            aux_total = aux_total + aux
+    new_cache = None
+    if caches:
+        new_cache = (jnp.stack([c[0] for c in caches]),
+                     jnp.stack([c[1] for c in caches]))
+    ssm_state = (jnp.stack(tails), jnp.stack(states)) if tails else None
+    return x, new_cache, aux_total, ssm_state
+
+
+def _finish_forward(params, x, cfg: ModelConfig, norm_impl,
+                    unembed_positions, return_hidden, extras: list):
+    """The head of ``forward``: logits (or the final-normed hidden states)
+    at all or one position a row, then ``extras`` in order."""
     if unembed_positions is not None:
         x = jnp.take_along_axis(
             x, unembed_positions[:, None, None].astype(jnp.int32), axis=1)
@@ -324,11 +526,7 @@ def forward(
                        cfg.norm_eps, impl=norm_impl)
     else:
         out = unembed(params, x, cfg, norm_impl=norm_impl)
-    result = [out]
-    if kv_cache is not None:
-        result.append(new_cache)
-    if return_aux or return_moe_stats:
-        result.append(aux_total)
+    result = [out, *extras]
     return tuple(result) if len(result) > 1 else result[0]
 
 
@@ -339,7 +537,7 @@ def forward(
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
                   dtype=jnp.bfloat16) -> tuple[jax.Array, jax.Array]:
-    shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.kv_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 

@@ -226,6 +226,8 @@ def _activate(x: jax.Array, activation: str) -> jax.Array:
         return jax.nn.silu(x)
     if activation == "gelu":
         return jax.nn.gelu(x)
+    if activation == "relu2":
+        return jnp.square(jax.nn.relu(x))
     return jax.nn.relu(x)
 
 
@@ -236,20 +238,30 @@ def dense_matmul(a: jax.Array, w: jax.Array) -> jax.Array:
 
 def mlp_block(x: jax.Array, layer: Params, cfg: ModelConfig,
               matmul=dense_matmul) -> jax.Array:
-    """Gated FFN (SwiGLU for silu — reference llama-7b.json activation).
-    ``matmul(a, w)``: see ``decoder_block``."""
-    gate = matmul(x, layer["gate"]["kernel"])
-    up = matmul(x, layer["up"]["kernel"])
-    h = _activate(gate, cfg.activation) * up
+    """Gated FFN (SwiGLU for silu — reference llama-7b.json activation),
+    or with ``cfg.mlp_gated`` False the plain two-kernel
+    ``down(act(up(x)))``. ``matmul(a, w)``: see ``decoder_block``."""
+    if cfg.mlp_gated:
+        gate = matmul(x, layer["gate"]["kernel"])
+        up = matmul(x, layer["up"]["kernel"])
+        h = _activate(gate, cfg.activation) * up
+    else:
+        h = _activate(matmul(x, layer["up"]["kernel"]), cfg.activation)
     return matmul(h, layer["down"]["kernel"]).astype(x.dtype)
 
 
-def moe_route(xt: jax.Array, router_kernel: jax.Array, cfg: ModelConfig
+def moe_route(xt: jax.Array, router_kernel: jax.Array, cfg: ModelConfig,
+              selection_bias: Optional[jax.Array] = None
               ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Token-choice routing of xt [N, H]: float32 softmax over ALL experts,
-    then the top-k. Returns (probs [N, E], top_w [N, K], top_e [N, K]);
-    ``top_w`` sums to 1 per token iff ``cfg.moe.norm_topk_prob``. Shared by
-    the dropless serving block and training's capacity block."""
+    """Token-choice routing of xt [N, H] over ALL the router's experts, in
+    float32. ``router_score`` "softmax": softmax, then the top-k;
+    ``top_w`` sums to 1 per token iff ``cfg.moe.norm_topk_prob``.
+    "sigmoid": a score per expert, the top-k taken of score +
+    ``selection_bias`` [E] (the bias picks, it does not weigh), the weights
+    the chosen SCORES, divided by their sum iff ``norm_topk_prob``, times
+    ``routed_scaling_factor``. Returns (scores [N, E], top_w [N, K],
+    top_e [N, K]). Shared by the dropless serving block and training's
+    capacity block."""
     with jax.named_scope("moe_router"):
         # full float32 passes: at the TPU's default precision a float32
         # matmul multiplies in bfloat16, and a router logit off by 1e-3
@@ -259,6 +271,16 @@ def moe_route(xt: jax.Array, router_kernel: jax.Array, cfg: ModelConfig
         logits = jnp.einsum("nh,he->ne", xt.astype(jnp.float32),
                             router_kernel.astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
+        if cfg.moe.router_score == "sigmoid":
+            scores = jax.nn.sigmoid(logits)                      # [N,E]
+            pick = scores if selection_bias is None else (
+                scores + selection_bias.astype(jnp.float32))
+            _, top_e = jax.lax.top_k(pick, cfg.moe.experts_per_token)
+            top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+            if cfg.moe.norm_topk_prob:
+                top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True)
+                                 + 1e-20)
+            return scores, top_w * cfg.moe.routed_scaling_factor, top_e
         probs = jax.nn.softmax(logits, axis=-1)                  # [N,E]
         top_w, top_e = jax.lax.top_k(probs, cfg.moe.experts_per_token)
         if cfg.moe.norm_topk_prob:
@@ -297,13 +319,21 @@ def moe_block(x: jax.Array, layer: Params, cfg: ModelConfig,
     not read) and return zeros.
 
     ``layer`` holds ``router`` and the experts' ``gate`` / ``up`` / ``down``
-    kernels, either one layer's [E, in, out] (``layer_index=None``) or the
+    kernels (no ``gate`` with ``cfg.mlp_gated`` False: two matmuls an
+    expert), either one layer's [E, in, out] (``layer_index=None``) or the
     whole stack [L, E, in, out] with ``layer_index`` (the router [H, E] is
     always one layer's): the stack is handed to the kernel as it lies, so
     no layer's 3 x [E, H, F] slab is copied out per layer.
 
+    ``E`` is the experts HELD here (``cfg.moe.num_experts``). Where the
+    router is wider (``cfg.moe.router_width``: this chip's share of a
+    layer several chips divide), the router runs over all of its experts
+    and only the choices that fall on held experts are ranked, padded,
+    multiplied and gathered back: what the absent experts would add is
+    left out, and nothing stands in for the chips that hold them.
+
     Returns (output [B, S, H], counts [E] int32: live tokens' choices per
-    expert).
+    held expert).
     """
     from ..ops.moe_gmm import grouped_matmul
     B, S, H = x.shape
@@ -312,7 +342,8 @@ def moe_block(x: jax.Array, layer: Params, cfg: ModelConfig,
     if live is not None and live.dtype != jnp.bool_:
         live = live != 0
     xt = x.reshape(N, H)
-    _, top_w, top_e = moe_route(xt, layer["router"]["kernel"], cfg)
+    _, top_w, top_e = moe_route(xt, layer["router"]["kernel"], cfg,
+                                layer["router"].get("bias"))
 
     with jax.named_scope("moe_dispatch"):
         tm = moe_row_tile(N * K, E, x.dtype)
@@ -323,6 +354,10 @@ def moe_block(x: jax.Array, layer: Params, cfg: ModelConfig,
         flat_e = top_e.reshape(N * K)
         flat_live = (jnp.ones((N * K,), bool) if live is None
                      else jnp.repeat(live.reshape(N), K))
+        if not cfg.moe.holds_all:
+            flat_e = flat_e - cfg.moe.first_expert
+            flat_live = flat_live & (flat_e >= 0) & (flat_e < E)
+            flat_e = jnp.clip(flat_e, 0, E - 1)
         onehot = (flat_e[:, None] == jnp.arange(E)[None, :]) \
             & flat_live[:, None]                                 # [NK,E]
         running = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
@@ -353,8 +388,18 @@ def moe_block(x: jax.Array, layer: Params, cfg: ModelConfig,
             grouped_matmul, tile_group=tile_group, tiles_used=tiles_used,
             layer=layer_index, tm=tm,
             name="moe_gmm" if S == 1 else "moe_gmm_prefill")
-        hidden = _activate(mm(xs, layer["gate"]["kernel"]),
-                           cfg.activation) * mm(xs, layer["up"]["kernel"])
+        # gate / up stacks lie [.., H, F], or [.., F, H] (out, in) where F
+        # is no multiple of the chip's 128 lanes (gpt.py lays them so;
+        # ops/moe_gmm.py says why): read off the stack itself
+        up_shape = layer["up"]["kernel"].shape
+        into = (functools.partial(mm, rhs_transposed=True)
+                if up_shape[-1] == H != up_shape[-2] else mm)
+        if cfg.mlp_gated:
+            hidden = _activate(into(xs, layer["gate"]["kernel"]),
+                               cfg.activation) * into(xs, layer["up"]["kernel"])
+        else:
+            hidden = _activate(into(xs, layer["up"]["kernel"]),
+                               cfg.activation)
         ys = mm(hidden, layer["down"]["kernel"])                 # [M,H]
 
     with jax.named_scope("moe_combine"):
@@ -367,11 +412,20 @@ def moe_block(x: jax.Array, layer: Params, cfg: ModelConfig,
     return out.reshape(B, S, H).astype(x.dtype), counts
 
 
-def moe_stats(counts: jax.Array) -> jax.Array:
-    """One dropless block's routing as the [E + 1] int32 vector the serve
-    programs sum over layers and steps and hand the engine: the live
-    tokens' choices per expert and, last, how many experts got any."""
-    return jnp.append(counts, jnp.sum(counts > 0, dtype=counts.dtype))
+def moe_stats(counts: jax.Array, cfg: Optional[ModelConfig] = None,
+              live: Optional[jax.Array] = None, tokens: int = 0) -> jax.Array:
+    """One dropless block's routing as the int32 vector
+    (``cfg.moe.stats_size``) the serve programs sum over layers and steps
+    and hand the engine: the live tokens' choices per held expert, how
+    many held experts got any and, where not every expert is held
+    (``cfg``), the choices of the block's live tokens (``live``, else all
+    ``tokens``) over ALL the router's experts."""
+    stats = jnp.append(counts, jnp.sum(counts > 0, dtype=counts.dtype))
+    if cfg is None or cfg.moe.holds_all:
+        return stats
+    n_live = (jnp.int32(tokens) if live is None
+              else jnp.sum(live != 0, dtype=jnp.int32))
+    return jnp.append(stats, n_live * cfg.moe.experts_per_token)
 
 
 def moe_block_capacity(x: jax.Array, layer: Params, cfg: ModelConfig
@@ -472,6 +526,8 @@ def decoder_block(
     live: Optional[jax.Array] = None,
     moe_impl: str = "dropless",
     layer_index=None,
+    kind: Optional[str] = None,
+    recur=None,
 ) -> tuple[jax.Array, Any, Any]:
     """One pre-norm transformer block: THE layer equations. Training,
     evaluation and the pipeline stages (models/gpt.py ``_block_fn``), cold
@@ -499,14 +555,58 @@ def decoder_block(
     and ``layer_index`` as ``moe_block`` takes them); training's
     ``moe_block_capacity``.
 
-    Returns (x, ``attend``'s state, what the caller sums over the layers:
-    None for a dense layer, the ``moe_stats`` of a dropless one, the
-    router's aux loss for the capacity route).
+    ``kind`` None is a layer of the uniform stack: attention THEN
+    feed-forward under two norms (``attn_norm``, ``mlp_norm``). A layer of
+    a layer table (``cfg.layer_pattern``) is ONE norm (``layer["norm"]``)
+    and ONE mixer, chosen by ``kind``: ``*`` the same attention, ``E`` the
+    same experts (plus the shared expert), ``M`` the Mamba-2 mixer, whose
+    state lives where ``recur`` says, as K and V live where ``attend``
+    says (``ssm_mixer``).
+
+    Returns (x, the mixer's state (``attend``'s or ``recur``'s; None for
+    an expert layer), what the caller sums over the layers: None for a
+    dense layer, the ``moe_stats`` of a dropless one, the router's aux
+    loss for the capacity route).
     """
-    B, S, _ = x.shape
-    D, Nq, Nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    if kind is not None:
+        # a layer of a layer table: ONE norm, ONE mixer
+        h = rms_norm(x, layer["norm"]["scale"], cfg.norm_eps, impl=norm_impl)
+        state = aux = None
+        if kind == "M":
+            out, state = ssm_mixer(h, layer, cfg, recur, matmul)
+        elif kind == "*":
+            out, state = attention_mixer(h, layer, cfg, positions, inv_freq,
+                                         attend, matmul)
+        elif kind == "E":
+            out, aux = experts_mixer(h, layer, cfg, live, moe_impl,
+                                     layer_index, matmul)
+        else:
+            raise ValueError(f"no layer kind {kind!r}")
+        return x + out.astype(x.dtype), state, aux
 
     h = rms_norm(x, layer["attn_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
+    out, state = attention_mixer(h, layer, cfg, positions, inv_freq, attend,
+                                 matmul)
+    x = x + out.astype(x.dtype)
+
+    h = rms_norm(x, layer["mlp_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
+    if cfg.is_moe:
+        ffn, aux = experts_mixer(h, layer["moe"], cfg, live, moe_impl,
+                                 layer_index, matmul)
+    else:
+        ffn, aux = mlp_block(h, layer["mlp"], cfg, matmul=matmul), None
+    return x + ffn.astype(x.dtype), state, aux
+
+
+def attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,
+                    positions: jax.Array, inv_freq: jax.Array, attend,
+                    matmul=dense_matmul) -> tuple[jax.Array, Any]:
+    """Grouped-query attention over the normed stream ``h`` [B, S, H]:
+    projections, optional q/k norms and biases, rope unless
+    ``cfg.position_embedding`` is "none", ``attend``, the output
+    projection. Returns (out [B, S, H], ``attend``'s state)."""
+    B, S, _ = h.shape
+    D, Nq, Nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
     q = qk_project_norm(matmul(h, layer["q"]["kernel"]), layer, "q",
                         cfg).reshape(B, S, Nq, D)
     k = qk_project_norm(matmul(h, layer["k"]["kernel"]), layer, "k",
@@ -516,8 +616,9 @@ def decoder_block(
         q = q + layer["q"]["bias"].reshape(Nq, D)
         k = k + layer["k"]["bias"].reshape(Nkv, D)
         v = v + layer["v"]["bias"].reshape(Nkv, D)
-    q = apply_rope(q, positions, inv_freq)
-    k = apply_rope(k, positions, inv_freq)
+    if cfg.position_embedding == "rope":
+        q = apply_rope(q, positions, inv_freq)
+        k = apply_rope(k, positions, inv_freq)
 
     out, state = attend(q, k, v)
     out = matmul(out.reshape(B, S, Nq * D), layer["o"]["kernel"])
@@ -525,15 +626,42 @@ def decoder_block(
     # is a custom call, not a dot, so dots_* policies rematerialise it —
     # which re-runs the whole O(S^2) flash forward inside the backward pass
     # (the name lowers to nothing in a program that takes no gradient)
-    x = x + checkpoint_name(out.astype(x.dtype), "attn_out")
+    return checkpoint_name(out.astype(h.dtype), "attn_out"), state
 
-    h = rms_norm(x, layer["mlp_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
-    if cfg.is_moe and moe_impl == "dropless":
-        ffn, counts = moe_block(h, layer["moe"], cfg, live=live,
+
+def experts_mixer(h: jax.Array, moe: Params, cfg: ModelConfig, live,
+                  moe_impl: str, layer_index, matmul=dense_matmul
+                  ) -> tuple[jax.Array, Any]:
+    """The sparse feed-forward over the normed stream ``h``: the dropless
+    ``moe_block`` (with its ``moe_stats``) or training's capacity route
+    (with the router's aux loss), plus the shared expert every token takes
+    where the model has one (``moe["shared"]``: a plain dense branch)."""
+    if moe_impl == "dropless":
+        ffn, counts = moe_block(h, moe, cfg, live=live,
                                 layer_index=layer_index)
-        aux = moe_stats(counts)
-    elif cfg.is_moe:
-        ffn, aux = moe_block_capacity(h, layer["moe"], cfg)
+        aux = moe_stats(counts, cfg, live, h.shape[0] * h.shape[1])
     else:
-        ffn, aux = mlp_block(h, layer["mlp"], cfg, matmul=matmul), None
-    return x + ffn.astype(x.dtype), state, aux
+        ffn, aux = moe_block_capacity(h, moe, cfg)
+    if cfg.moe.shared_expert_size:
+        with jax.named_scope("moe_shared_expert"):
+            ffn = ffn + mlp_block(h, moe["shared"], cfg, matmul=matmul)
+    return ffn, aux
+
+
+def ssm_mixer(h: jax.Array, layer: Params, cfg: ModelConfig, recur,
+              matmul=dense_matmul) -> tuple[jax.Array, Any]:
+    """The Mamba-2 mixer over the normed stream ``h`` [B, S, H]:
+    ``[z | xBC | dt] = h W_in``; ``recur(xBC, dt, layer)`` runs the conv
+    and the recurrence wherever its state lives (ops/ssm.py
+    ``recur_window`` / ``recur_step``) and returns (y [B, S, d_in], state);
+    then the gated norm and the output projection."""
+    from ..ops.ssm import ssm_gated_norm
+    s = cfg.ssm
+    d_in, C = s.inner_size, s.conv_channels
+    zxbcdt = matmul(h, layer["in_proj"]["kernel"])
+    z, xbc, dt = (zxbcdt[..., :d_in], zxbcdt[..., d_in:d_in + C],
+                  zxbcdt[..., d_in + C:])
+    y, state = recur(xbc, dt, layer)
+    y = ssm_gated_norm(y, z, layer["gate_norm"]["scale"], s.n_groups,
+                       cfg.norm_eps)
+    return matmul(y, layer["out_proj"]["kernel"]), state

@@ -1,0 +1,250 @@
+"""Mamba-2 state-space mixer: the depthwise conv, the scan and the gated norm.
+
+A Mamba-2 head ``i`` keeps a state ``h[i]`` [P, N] (P channels of the head,
+N the state size) and moves it one token at a time:
+
+    h_t[i] = exp(dt_t[i] A[i]) h_{t-1}[i] + dt_t[i] outer(x_t[i], B_t[g(i)])
+    y_t[i] = h_t[i] C_t[g(i)] + D[i] x_t[i]
+
+with ``dt_t = softplus(raw + dt_bias)``, ``A = -exp(A_log)`` and B, C shared
+by the heads of a group ``g(i)``. Two forms of the same recurrence:
+
+- ``ssm_scan_prefill``: the chunked ("state-space duality") form for a
+  window of tokens: inside a chunk of ``Q`` tokens the outputs are matmuls
+  (C B^T masked by the decays, times x), between chunks a [P, N] state is
+  carried. A position with ``dt = 0`` decays by 1 and adds nothing: that
+  is how a bucket's padding is kept out of the state.
+- ``ssm_decode``: the one-step update over ``[slots]``, elementwise in
+  float32; the step reads and writes every live slot's state once.
+
+``recur_window`` / ``recur_step`` say where the state lives, as ``attend``
+does for K and V (models/layers.py ``decoder_block``): from zeros over a
+window (training-free forward, cold prefill), or in the engine's state
+pools ``[state-space layer, slot, ...]`` (decode).
+
+The scopes (``ssm_conv``, ``ssm_scan_prefill``, ``ssm_decode``,
+``ssm_gated_norm``) are what a device trace names these operations by.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from ..utils.platform import report_impl
+
+
+def ssm_conv(xbc: jax.Array, kernel: jax.Array, bias: jax.Array,
+             tail: Optional[jax.Array] = None
+             ) -> tuple[jax.Array, jax.Array]:
+    """Depthwise causal conv of width K over xbc [B, S, C], then silu.
+
+    ``kernel`` [K, C], ``bias`` [C]; ``tail`` [B, K-1, C] holds the K-1
+    PRE-activation columns before the window (None: zeros, a sequence's
+    start). Returns (activated [B, S, C], padded [B, K-1+S, C]: tail and
+    window, from which the caller cuts the next tail)."""
+    B, S, C = xbc.shape
+    K = kernel.shape[0]
+    if tail is None:
+        tail = jnp.zeros((B, K - 1, C), xbc.dtype)
+    with jax.named_scope("ssm_conv"):
+        padded = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+        acc = bias.astype(jnp.float32)
+        for j in range(K):
+            acc = acc + (padded[:, j:j + S].astype(jnp.float32)
+                         * kernel[j].astype(jnp.float32))
+        return jax.nn.silu(acc).astype(xbc.dtype), padded
+
+
+def ssm_scan_prefill(x: jax.Array, dt: jax.Array, A: jax.Array,
+                     Bm: jax.Array, Cm: jax.Array, D: jax.Array,
+                     chunk: int) -> tuple[jax.Array, jax.Array]:
+    """The chunked scan from a ZERO state.
+
+    x [B, S, nh, P] and Bm, Cm [B, S, G, N] in the compute dtype; dt
+    [B, S, nh] float32, already softplus'd and 0 at positions that must
+    not enter the state; A (negative) and D [nh] float32. Returns
+    (y [B, S, nh, P] in x's dtype, the state after the window
+    [B, nh, P, N] float32). Matmul operands are the compute dtype with
+    float32 accumulation; decays and the carried state are float32."""
+    B, S, nh, P = x.shape
+    G, N = Bm.shape[-2:]
+    r = nh // G                                  # heads a group
+    Q = min(chunk, S)
+    pad = -S % Q
+    if pad:
+        x, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                     for a in (x, Bm, Cm))
+        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+    nc = (S + pad) // Q
+    report_impl("ssm_scan_prefill", "xla",
+                f"x{tuple(x.shape)} chunks {nc}x{Q}")
+    f32 = jnp.float32
+    with jax.named_scope("ssm_scan_prefill"):
+        a = (dt * A).reshape(B, nc, Q, G, r)                 # log decays <= 0
+        cum = jnp.cumsum(a, axis=2)                          # inclusive
+        xdt = (x.astype(f32) * dt[..., None]).astype(x.dtype).reshape(
+            B, nc, Q, G, r, P)
+        Bc = Bm.reshape(B, nc, Q, G, N)
+        Cc = Cm.reshape(B, nc, Q, G, N)
+        # inside a chunk: y_t += sum_{s<=t} exp(cum_t - cum_s) (C_t.B_s) xdt_s
+        cb = jnp.einsum("bcqgn,bckgn->bcgqk", Cc, Bc,
+                        preferred_element_type=f32)          # [B,nc,G,Q,Q]
+        seg = cum[:, :, :, None] - cum[:, :, None]           # [B,nc,Q,Q,G,r]
+        causal = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+        decay = jnp.exp(jnp.where(causal[:, :, None, None], seg, -jnp.inf))
+        m = cb.transpose(0, 1, 3, 4, 2)[..., None] * decay   # [B,nc,Q,Q,G,r]
+        y = jnp.einsum("bcqkgr,bckgrp->bcqgrp", m.astype(x.dtype), xdt,
+                       preferred_element_type=f32)
+        # each chunk's own contribution to the state at its end
+        to_end = jnp.exp(cum[:, :, -1:] - cum)               # [B,nc,Q,G,r]
+        chunk_state = jnp.einsum(
+            "bcqgrp,bcqgn->bcgrpn",
+            (xdt.astype(f32) * to_end[..., None]).astype(x.dtype), Bc,
+            preferred_element_type=f32)                      # [B,nc,G,r,P,N]
+        chunk_decay = jnp.exp(cum[:, :, -1])                 # [B,nc,G,r]
+
+        def carry(h, c):
+            state_c, decay_c = c
+            return h * decay_c[..., None, None] + state_c, h
+
+        h_last, h_in = jax.lax.scan(
+            carry, jnp.zeros((B, G, r, P, N), f32),
+            (chunk_state.swapaxes(0, 1), chunk_decay.swapaxes(0, 1)))
+        h_in = h_in.swapaxes(0, 1)                           # [B,nc,G,r,P,N]
+        # what the state carried into the chunk adds: exp(cum_t) C_t . h_in
+        y = y + jnp.einsum("bcqgn,bcgrpn->bcqgrp", Cc, h_in.astype(x.dtype),
+                           preferred_element_type=f32) \
+            * jnp.exp(cum)[..., None]
+        y = y.reshape(B, S + pad, nh, P)[:, :S]
+        y = y + D[:, None] * x[:, :S].astype(f32)
+        return y.astype(x.dtype), h_last.reshape(B, nh, P, N)
+
+
+def ssm_decode(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
+               Cm: jax.Array, D: jax.Array, h: jax.Array
+               ) -> tuple[jax.Array, jax.Array]:
+    """One token a slot: x [S, nh, P], dt [S, nh] float32, Bm, Cm
+    [S, G, N], h [S, nh, P, N] (the cached dtype). Returns (y [S, nh, P]
+    in x's dtype, the new state in h's dtype). Float32 throughout."""
+    S, nh, P = x.shape
+    G, N = Bm.shape[-2:]
+    r = nh // G
+    f32 = jnp.float32
+    report_impl("ssm_decode", "xla", f"h{tuple(h.shape)} {h.dtype}")
+    with jax.named_scope("ssm_decode"):
+        xf = x.astype(f32).reshape(S, G, r, P)
+        dtg = dt.reshape(S, G, r)
+        hg = h.astype(f32).reshape(S, G, r, P, N)
+        decay = jnp.exp(dtg * A.reshape(G, r))
+        new = (hg * decay[..., None, None]
+               + (dtg[..., None] * xf)[..., None]
+               * Bm.astype(f32)[:, :, None, None, :])
+        y = jnp.sum(new * Cm.astype(f32)[:, :, None, None, :], axis=-1) \
+            + D.reshape(G, r)[..., None] * xf
+        return (y.reshape(S, nh, P).astype(x.dtype),
+                new.reshape(S, nh, P, N).astype(h.dtype))
+
+
+def ssm_gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array,
+                   groups: int, eps: float) -> jax.Array:
+    """``y * silu(z)``, THEN an RMS norm over each of ``groups`` equal
+    channel groups, times ``1 + scale`` (the program's norm-weight
+    convention). y, z [..., d_in]."""
+    with jax.named_scope("ssm_gated_norm"):
+        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        gg = g.reshape(*g.shape[:-1], groups, g.shape[-1] // groups)
+        gg = gg * jax.lax.rsqrt(
+            jnp.mean(jnp.square(gg), axis=-1, keepdims=True) + eps)
+        return (gg.reshape(g.shape)
+                * (1.0 + scale.astype(jnp.float32))).astype(y.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Where the state lives
+# ---------------------------------------------------------------------------
+
+def _split(xbc_act: jax.Array, cfg):
+    """The activated conv output [.., C] as x [.., nh, P], B, C [.., G, N]."""
+    s = cfg.ssm
+    d_in, gn = s.inner_size, s.n_groups * s.state_size
+    lead = xbc_act.shape[:-1]
+    return (xbc_act[..., :d_in].reshape(*lead, s.num_heads, s.head_dim),
+            xbc_act[..., d_in:d_in + gn].reshape(*lead, s.n_groups,
+                                                 s.state_size),
+            xbc_act[..., d_in + gn:].reshape(*lead, s.n_groups,
+                                             s.state_size))
+
+
+def _step_sizes(dt_raw: jax.Array, p: dict) -> tuple[jax.Array, jax.Array,
+                                                     jax.Array]:
+    """(softplus(dt + dt_bias), A = -exp(A_log), D), float32."""
+    f32 = jnp.float32
+    return (jax.nn.softplus(dt_raw.astype(f32) + p["dt_bias"].astype(f32)),
+            -jnp.exp(p["A_log"].astype(f32)), p["D"].astype(f32))
+
+
+def recur_window(cfg, live: Optional[jax.Array] = None):
+    """``recur`` for a window that starts a sequence (a forward with no
+    cache, cold prefill): the conv from a zero tail, the chunked scan from
+    a zero state. ``live`` [B, S] (bool, or segment ids with 0 = padding)
+    marks the real tokens, which must be a PREFIX of each row: padding
+    takes ``dt = 0`` and the state returned is the one after the last live
+    token, (the K-1 pre-activation conv columns before position
+    ``length`` [B, K-1, C], h [B, nh, P, N] float32)."""
+    K = cfg.ssm.conv_kernel
+
+    def recur(xbc, dt_raw, p):
+        B, S, _ = xbc.shape
+        act, padded = ssm_conv(xbc, p["conv"]["kernel"], p["conv"]["bias"])
+        x, Bm, Cm = _split(act, cfg)
+        dt, A, D = _step_sizes(dt_raw, p)
+        if live is None:
+            length = jnp.full((B,), S, jnp.int32)
+        else:
+            alive = live if live.dtype == jnp.bool_ else live != 0
+            dt = jnp.where(alive[..., None], dt, 0.0)
+            length = jnp.sum(alive, axis=1, dtype=jnp.int32)
+        y, h = ssm_scan_prefill(x, dt, A, Bm, Cm, D, cfg.ssm.chunk_size)
+        # position p is padded[p + K - 1]: the columns length-K+1..length-1
+        idx = length[:, None] + jnp.arange(K - 1, dtype=jnp.int32)
+        tail = jnp.take_along_axis(padded, idx[..., None], axis=1)
+        return y.reshape(B, S, -1), (tail, h)
+    return recur
+
+
+def recur_step(cfg, conv_pool: jax.Array, ssm_pool: jax.Array, layer,
+               write_ok: Optional[jax.Array] = None):
+    """``recur`` for one decode step of every slot over the state pools
+    ``conv_pool`` [Lm, slots, K-1, C] and ``ssm_pool``
+    [Lm, slots, nh, P, N], read and written at ``[layer]``. A slot with
+    ``write_ok`` [slots, 1] False (idle, or past its stop position) leaves
+    its state as it is. Returns the two pools as the state."""
+    def recur(xbc, dt_raw, p):
+        B, T, _ = xbc.shape
+        if T != 1:
+            raise ValueError(
+                "a state-space layer advances one token a slot over the "
+                f"state pool; a window of {T} tokens (suffix or chunked "
+                "prefill, speculative verification) is not supported")
+        tail = conv_pool[layer]
+        act, padded = ssm_conv(xbc, p["conv"]["kernel"], p["conv"]["bias"],
+                               tail)
+        x, Bm, Cm = _split(act[:, 0], cfg)
+        dt, A, D = _step_sizes(dt_raw[:, 0], p)
+        old = ssm_pool[layer]
+        y, new = ssm_decode(x, dt, A, Bm, Cm, D, old)
+        new_tail = padded[:, 1:].astype(conv_pool.dtype)
+        # (under the update's scope: XLA fuses the update into the pool's
+        # in-place write, and a trace names the fusion by its ROOT)
+        with jax.named_scope("ssm_decode"):
+            if write_ok is not None:
+                ok = write_ok.reshape(B)
+                new = jnp.where(ok[:, None, None, None], new, old)
+                new_tail = jnp.where(ok[:, None, None], new_tail, tail)
+            return (y.reshape(B, 1, -1),
+                    (conv_pool.at[layer].set(new_tail),
+                     ssm_pool.at[layer].set(new)))
+    return recur

@@ -705,26 +705,42 @@ class ServePlanner:
     def page_bytes(self, page_size: int, kv_quant: str = "none") -> float:
         m = self.model
         if kv_quant == "int8":
-            return 2 * m.num_layers * page_size * m.num_kv_heads \
+            return 2 * m.kv_layers * page_size * m.num_kv_heads \
                 * (m.head_dim + 4)
         if kv_quant == "int4":
             # two page slots per byte + the same fp32 per-row scale
             # (Int4Pages): the Mooncake capacity lever — ~2x int8's
             # slots per HBM byte at D=128
-            return 2 * m.num_layers * page_size * m.num_kv_heads \
+            return 2 * m.kv_layers * page_size * m.num_kv_heads \
                 * (m.head_dim / 2 + 4)
-        return 2 * m.num_layers * page_size * m.num_kv_heads \
+        return 2 * m.kv_layers * page_size * m.num_kv_heads \
             * m.head_dim * BYTES_BF16
 
+    def state_bytes(self, slots: int) -> float:
+        """The state pools of a model's state-space layers for ``slots``
+        slots (serve/kv_cache.py): a slot's [nh, P, N] state in float32
+        and K-1 conv columns in bf16, in every such layer, whatever
+        the sequences' lengths. 0 for a model without such layers."""
+        m, s = self.model, self.model.ssm
+        state = s.num_heads * s.head_dim * s.state_size * 4
+        conv = (s.conv_kernel - 1) * s.conv_channels * BYTES_BF16
+        return m.ssm_layers * slots * (state + conv)
+
+    def _expert_params(self) -> float:
+        """One routed expert's kernels (two without a gate)."""
+        m = self.model
+        return (3 if m.mlp_gated else 2) * m.hidden_size * m.ffn_size
+
     def active_param_count(self) -> float:
-        """Parameters a token's forward multiplies: of an MoE's experts
-        only its k chosen ones."""
+        """Parameters a token's forward multiplies: of the experts HELD
+        here only the share of its k choices that falls on them."""
         m = self.model
         if not m.is_moe:
             return m.param_count
-        idle = m.moe.num_experts - m.moe.experts_per_token
-        return m.param_count - m.num_layers * idle * 3 * m.hidden_size \
-            * m.ffn_size
+        chosen = m.moe.experts_per_token * m.moe.num_experts \
+            / m.moe.router_width
+        idle = m.moe.num_experts - chosen
+        return m.param_count - m.moe_layers * idle * self._expert_params()
 
     def moe_decode_weight_fraction(self, batch: int) -> float:
         """Share of the weights a decode step of ``batch`` live tokens
@@ -734,10 +750,10 @@ class ServePlanner:
         m = self.model
         if not m.is_moe:
             return 1.0
-        experts = (m.num_layers * m.moe.num_experts * 3 * m.hidden_size
-                   * m.ffn_size) / m.param_count
+        experts = (m.moe_layers * m.moe.num_experts
+                   * self._expert_params()) / m.param_count
         hit = 1.0 - (1.0 - m.moe.experts_per_token
-                     / m.moe.num_experts) ** max(batch, 1)
+                     / m.moe.router_width) ** max(batch, 1)
         return 1.0 - experts * (1.0 - hit)
 
     def moe_dispatch_bytes(self, tokens: int) -> float:
@@ -764,7 +780,8 @@ class ServePlanner:
         tp = max(tensor_parallel, 1)
         wb = self.weight_bytes(quant) / tp
         hbm = hw.hbm_gb_per_chip * 1e9
-        pool = (hbm - wb - self.workspace_gb * 1e9
+        state = self.state_bytes(batch)
+        pool = (hbm - wb - state - self.workspace_gb * 1e9
                 - self.moe_dispatch_bytes(max(prompt_len, batch)))
         pb = self.page_bytes(page_size, kv_quant) / tp
         pages = max(int(pool // pb), 0)
@@ -782,8 +799,9 @@ class ServePlanner:
         # decode: one step reads all weights + the resident KV
         kv_read = batch * context_len * (pb / max(page_size, 1))
         bw = hw.hbm_bw_gbps * 1e9 * self.decode_efficiency
+        # (and reads and writes every live slot's recurrent state)
         decode_s = (wb * self.moe_decode_weight_fraction(batch)
-                    + kv_read) / max(bw, 1.0)
+                    + kv_read + 2 * state) / max(bw, 1.0)
         if kv_quant in ("int8", "int4"):
             # int8 KV pages switch the page writes to the per-row scatter
             # path and add in-kernel dequant — a program-structure cost,

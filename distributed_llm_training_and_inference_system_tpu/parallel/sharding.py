@@ -37,6 +37,17 @@ PARAM_RULES: list[tuple[str, P]] = [
     (r"embed\.embedding$",        P("fsdp", "tp")),
     (r"lm_head\.kernel$",         P("fsdp", "tp")),
     (r"final_norm\.scale$",       P(None)),
+    # a layer table's stacks (one a layer KIND: blocks.ssm / .attn / .moe).
+    # The state-space mixer's W_in is [z | xBC | dt] side by side, which tp
+    # cannot cut head by head: fsdp alone, as W_out; its vectors replicate.
+    (r"blocks\.ssm\.in_proj\.kernel$",  P("fsdp", None)),
+    (r"blocks\.ssm\.out_proj\.kernel$", P(None, "fsdp")),
+    (r"blocks\.ssm\.",                  P(None)),
+    (r"blocks\.attn\.(q|k|v)\.kernel$", P("fsdp", "tp")),
+    (r"blocks\.attn\.o\.kernel$",       P("tp", "fsdp")),
+    (r"blocks\.moe\.shared\.up\.kernel$",   P("fsdp", "tp")),
+    (r"blocks\.moe\.shared\.down\.kernel$", P("tp", "fsdp")),
+    (r"blocks\.moe\.router\.bias$",    P(None)),
     (r"blocks\.(q|k|v)\.kernel$", P("fsdp", "tp")),
     (r"blocks\.(q|k|v)\.bias$",   P("tp")),
     # the q / k projection norm's scale lies along the projection's output

@@ -18,7 +18,14 @@ import jax
 import jax.numpy as jnp
 
 from ..config.schema import ModelConfig
-from ..models.gpt import layer_experts, split_expert_stacks, unembed
+from ..models.gpt import (
+    cast_table_blocks,
+    layer_experts,
+    split_expert_stacks,
+    table_layer,
+    table_layers,
+    unembed,
+)
 from ..models.layers import decoder_block, rope_frequencies
 from ..ops.paged_attention import (
     paged_attention_multi,
@@ -40,10 +47,11 @@ def decode_step_forward(
     w4_kernel_ok: bool = True,
     w8_kernel_ok: bool = False,
     return_moe_stats: bool = False,
+    ssm_state: Any = None,
 ) -> tuple:
     """Returns (logits [B, V] fp32, new k_pages, new v_pages) and, asked
-    with ``return_moe_stats``, the live slots' expert choices (see
-    ``extend_step_forward``).
+    with ``return_moe_stats``, the live slots' expert choices, and given
+    ``ssm_state`` the advanced state pools (see ``extend_step_forward``).
 
     The T=1 case of ``extend_step_forward`` (one layer-body implementation
     for both, so the paths can never diverge numerically). The new token's
@@ -58,7 +66,7 @@ def decode_step_forward(
         params, tokens[:, None], positions, k_pages, v_pages, block_tables,
         cfg, write_ok=write_ok, attn_impl=attn_impl,
         w4_kernel_ok=w4_kernel_ok, w8_kernel_ok=w8_kernel_ok,
-        return_moe_stats=return_moe_stats)
+        return_moe_stats=return_moe_stats, ssm_state=ssm_state)
     return (logits[:, 0], *rest)
 
 
@@ -84,13 +92,18 @@ def extend_step_forward(
                               # int8 dequant fuses in XLA, so the Pallas
                               # route needs a measured per-chip win first
     return_moe_stats: bool = False,
+    ssm_state: Any = None,    # {"conv": [Lm, B, K-1, C], "ssm": [Lm, B, nh,
+                              # P, N]}: the state-space layers' pools
 ) -> tuple:
     """Paged forward over T tokens per slot: the multi-token sibling of
     ``decode_step_forward``. Returns (logits [B, T, V] fp32, k_pages,
     v_pages) and, asked with ``return_moe_stats`` (MoE models), a fourth:
     the [E + 1] int32 vector of the LIVE tokens' choices per expert summed
     over the layers (``write_ok`` rows; idle slots and padding get no
-    expert) and, last, the (layer, expert) pairs that got any.
+    expert) and, last, the (layer, expert) pairs that got any. A model
+    with state-space layers takes ``ssm_state`` and returns it LAST,
+    advanced in place for the rows ``write_ok`` marks: its K/V pools hold
+    the attention layers alone ([La, NP, ...]) and it takes T = 1 only.
 
     Token j sits at position ``start_positions + j`` and attends causally
     over the paged prefix *including* earlier tokens of this same call: all
@@ -165,6 +178,58 @@ def extend_step_forward(
         blocks, expert_stacks = split_expert_stacks(blocks)
     return_moe_stats = return_moe_stats and cfg.is_moe
 
+    def attend_pages(kp, vp, li):
+        """``attend`` over the page pools, written and read at layer
+        ``li``."""
+        def attend(q, k, v):
+            # K and V live in pages. Every T takes the whole-page merge
+            # (T == 1: one page a slot), QuantPages and Int4Pages with
+            # quantize-on-write fused into it: a row scatter lays the pool
+            # out slot-major, the Pallas kernel reads it head-major, and
+            # the WHOLE pool is copied between the two in every layer
+            # (PERF.md 6, PR 26, has both step times)
+            with jax.named_scope("kv_page_write"):
+                new_k = write_window_to_pages(kp, k, block_tables,
+                                              start_positions, write_ok, li)
+                new_v = write_window_to_pages(vp, v, block_tables,
+                                              start_positions, write_ok, li)
+            out = paged_attention_multi(q, new_k, new_v, block_tables,
+                                        start_positions, impl=attn_impl,
+                                        layer=li)
+            return out, (new_k, new_v)
+        return attend
+
+    if cfg.layer_pattern:
+        # a layer table: one parameter stack a kind, walked by a Python
+        # loop; every pool (pages, conv tails, states) and every expert
+        # stack stays whole and is addressed at its kind's layer index
+        from ..ops.ssm import recur_step
+        if cfg.is_recurrent and ssm_state is None:
+            raise ValueError("a model with state-space layers needs its "
+                             "ssm_state pools")
+        blocks = cast_table_blocks(params["blocks"], compute_dtype)
+        kp, vp = k_pages, v_pages
+        conv, ssm = (ssm_state["conv"], ssm_state["ssm"]) \
+            if ssm_state is not None else (None, None)
+        stats = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
+        for kind, i in table_layers(cfg):
+            x, state, layer_stats = decoder_block(
+                x, table_layer(blocks, kind, i), cfg, positions, inv_freq,
+                attend_pages(kp, vp, i) if kind == "*" else None, matmul=mm,
+                live=write_ok, layer_index=i, kind=kind,
+                recur=(recur_step(cfg, conv, ssm, i, write_ok)
+                       if kind == "M" else None))
+            if kind == "*":
+                kp, vp = state
+            elif kind == "M":
+                conv, ssm = state
+            else:
+                stats = stats + layer_stats
+        return (unembed(params, x, cfg), kp, vp,
+                *([stats] if return_moe_stats else []),
+                *([{"conv": conv, "ssm": ssm}] if ssm_state is not None
+                  else []))
+
     def body(carry, layer_and_index):
         # the pools must stay a CARRY that every layer writes and reads
         # by its index: as scanned inputs and stacked outputs XLA slices
@@ -185,30 +250,13 @@ def extend_step_forward(
             layer = dict(layer, moe=cast_params(layer["moe"], compute_dtype))
             layer, moe_li = layer_experts(layer, expert_stacks, li)
 
-        def attend(q, k, v):
-            # K and V live in pages. Every T takes the whole-page merge
-            # (T == 1: one page a slot), QuantPages and Int4Pages with
-            # quantize-on-write fused into it: a row scatter lays the pool
-            # out slot-major, the Pallas kernel reads it head-major, and
-            # the WHOLE pool is copied between the two in every layer
-            # (PERF.md 6, PR 26, has both step times)
-            with jax.named_scope("kv_page_write"):
-                new_k = write_window_to_pages(kp, k, block_tables,
-                                              start_positions, write_ok, li)
-                new_v = write_window_to_pages(vp, v, block_tables,
-                                              start_positions, write_ok, li)
-            out = paged_attention_multi(q, new_k, new_v, block_tables,
-                                        start_positions, impl=attn_impl,
-                                        layer=li)
-            return out, (new_k, new_v)
-
         x, (kp, vp), layer_stats = decoder_block(
-            x, layer, cfg, positions, inv_freq, attend, matmul=mm,
-            live=write_ok, layer_index=moe_li)
+            x, layer, cfg, positions, inv_freq, attend_pages(kp, vp, li),
+            matmul=mm, live=write_ok, layer_index=moe_li)
         stats = [total + layer_stats for total in stats]
         return (x, kp, vp, *stats), None
 
-    stats0 = ([jnp.zeros((cfg.moe.num_experts + 1,), jnp.int32)]
+    stats0 = ([jnp.zeros((cfg.moe.stats_size,), jnp.int32)]
               if return_moe_stats else [])
     (x, new_k, new_v, *stats), _ = jax.lax.scan(
         body, (x, k_pages, v_pages, *stats0),
@@ -265,30 +313,36 @@ def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
                 stop_positions, slot_keys, temperature, top_k, top_p,
                 cfg: ModelConfig, num_steps: int, attn_impl: str = "auto",
                 w4_kernel_ok: bool = True, w8_kernel_ok: bool = False,
-                return_moe_stats: bool = False):
+                return_moe_stats: bool = False, ssm_state: Any = None):
     """The decode+sample scan shared by ``decode_multi_step`` and the fused
     speculative dispatch (speculative.verify_and_decode). Returns
     ((tokens, positions, k_pages, v_pages), toks_seq [K, B]); with
     ``return_moe_stats`` (MoE models) the carry ends in the steps' summed
-    ``moe_stats`` (see ``extend_step_forward``)."""
+    ``moe_stats`` (see ``extend_step_forward``), and given ``ssm_state``
+    (a model with state-space layers) in the state pools after that."""
     from .sampling import sample_tokens
     return_moe_stats = return_moe_stats and cfg.is_moe
+    n_stats = int(return_moe_stats)
 
     def one(carry, _):
-        toks, pos, kp, vp, *stats = carry
+        toks, pos, kp, vp, *rest = carry
+        stats, state = rest[:n_stats], rest[n_stats:]
         act = pos < stop_positions
-        logits, kp, vp, *step_stats = decode_step_forward(
+        logits, kp, vp, *out = decode_step_forward(
             params, toks, pos, kp, vp, block_tables, cfg, active=act,
             attn_impl=attn_impl, w4_kernel_ok=w4_kernel_ok,
-            w8_kernel_ok=w8_kernel_ok, return_moe_stats=return_moe_stats)
+            w8_kernel_ok=w8_kernel_ok, return_moe_stats=return_moe_stats,
+            ssm_state=state[0] if state else None)
         keys = jax.vmap(jax.random.fold_in)(
             jax.vmap(jax.random.wrap_key_data)(slot_keys), pos + 1)
         nxt = sample_tokens(logits, keys, temperature, top_k, top_p)
         nxt = jnp.where(act, nxt, toks)
-        stats = [a + b for a, b in zip(stats, step_stats)]
-        return (nxt, pos + 1, kp, vp, *stats), nxt
+        stats = [a + b for a, b in zip(stats, out[:n_stats])]
+        return (nxt, pos + 1, kp, vp, *stats, *out[n_stats:]), nxt
 
-    stats0 = ([jnp.zeros((cfg.moe.num_experts + 1,), jnp.int32)]
+    stats0 = ([jnp.zeros((cfg.moe.stats_size,), jnp.int32)]
               if return_moe_stats else [])
-    return jax.lax.scan(one, (tokens, positions, k_pages, v_pages, *stats0),
-                        None, length=num_steps)
+    state0 = [] if ssm_state is None else [ssm_state]
+    return jax.lax.scan(
+        one, (tokens, positions, k_pages, v_pages, *stats0, *state0),
+        None, length=num_steps)

@@ -111,6 +111,21 @@ class InferenceEngine:
             params, model_cfg, self.quantization = self._load_params(
                 model_cfg, serve_cfg, seed, dtype)
         self.cfg = model_cfg
+        # what a model with state-space layers turns off: every feature
+        # below moves, shares or re-enters K/V PAGES, and a recurrent
+        # layer's state is not in them. Each either carries the state or
+        # is refused by name; none may run and be silently wrong
+        # (ROADMAP C2: state snapshots would unlock them).
+        self.ssm_refused: dict[str, int] = {}
+        if model_cfg.is_recurrent:
+            self._refuse_for_recurrent(serve_cfg)
+        if model_cfg.layer_pattern and (
+                serve_cfg.quantization not in ("", "none")
+                or serve_cfg.tensor_parallel > 1):
+            raise ValueError(
+                f"{model_cfg.name}: a model with a layer table serves plain "
+                "weights on one chip (quantization and tensor_parallel "
+                "walk the uniform stack's [L, in, out] kernels)")
 
         from ..ops.quantization import _is_runtime_quant
         pre_quantized = any(
@@ -340,7 +355,8 @@ class InferenceEngine:
         # functools.partial has no name: jit__unknown)
         self._decode_jit = _Program(
             "_decode_impl_n", self._decode_impl_n,
-            self.failed_programs, donate_argnums=(1, 2))
+            self.failed_programs,
+            donate_argnums=(1, 2, 11) if model_cfg.is_recurrent else (1, 2))
         self.total_short_dispatches = 0
         self._spec_jit = (_Program("_spec_impl", self._spec_impl,
                                    self.failed_programs,
@@ -353,6 +369,9 @@ class InferenceEngine:
         # programs' part of both again, for the decode step's byte floor.
         # Read off the fetch a step makes anyway (_count_moe).
         self.moe_choices = np.zeros(model_cfg.moe.num_experts, np.int64)
+        self.moe_all_choices = 0
+        # state updates of live slots in decode (slots x steps)
+        self.ssm_slot_steps = 0
         self.moe_experts_hit = self.moe_layer_steps = 0
         self.moe_decode_experts_hit = self.moe_decode_layer_steps = 0
         self.total_prefill_tokens = 0      # tokens actually computed
@@ -387,6 +406,45 @@ class InferenceEngine:
         self.total_spec_resumes = 0
 
     # -- setup ---------------------------------------------------------------
+
+    def _refuse_for_recurrent(self, serve_cfg: ServeConfig) -> None:
+        """Refuse, by name, the opt-in features a recurrent layer's state
+        cannot follow, and turn prefix reuse by page hash off (it is ON by
+        default): a page hit would skip tokens whose state-space state
+        nobody kept. Said once in the log and in ``stats()["ssm"]``."""
+        asked = {
+            "chunked_prefill_tokens": serve_cfg.chunked_prefill_tokens > 0,
+            "speculative": serve_cfg.speculative != "off",
+            "preemption: swap": serve_cfg.preemption == "swap",
+        }
+        for feature, on in asked.items():
+            if on:
+                raise ValueError(
+                    f"{self.cfg.name} has state-space layers: {feature} is "
+                    "refused (it re-enters or moves K/V pages, and the "
+                    "layers' recurrent state is not in them; ROADMAP C2)")
+        if serve_cfg.prefix_caching:
+            self.ssm_refused["prefix_caching"] = 0
+            logger.warning(
+                "%s has state-space layers: prefix reuse by page hash is "
+                "off (no page hash is registered or looked up; a repeated "
+                "prompt is prefilled again)", self.cfg.name)
+
+    @property
+    def _prefix_caching(self) -> bool:
+        return self.serve_cfg.prefix_caching and not self.cfg.is_recurrent
+
+    @property
+    def prefix_fetch_hook(self) -> Optional[Callable]:
+        return self._prefix_fetch_hook
+
+    @prefix_fetch_hook.setter
+    def prefix_fetch_hook(self, hook: Optional[Callable]) -> None:
+        if hook is not None and self.cfg.is_recurrent:
+            raise ValueError(
+                f"{self.cfg.name} has state-space layers: fleet prefix "
+                "fetch is refused (fetched pages carry no recurrent state)")
+        self._prefix_fetch_hook = hook
 
     @staticmethod
     def _load_params(model_cfg, serve_cfg, seed, dtype):
@@ -552,7 +610,9 @@ class InferenceEngine:
             return True
         pins: list[int] = []
         usable = 0
-        if self.serve_cfg.prefix_caching:
+        if "prefix_caching" in self.ssm_refused:
+            self.ssm_refused["prefix_caching"] += 1   # admissions not looked up
+        if self._prefix_caching:
             if req.prefix_hashes is None:      # once per request, not per retry
                 from .kv_cache import prefix_page_hashes
                 req.prefix_hashes = prefix_page_hashes(
@@ -636,26 +696,40 @@ class InferenceEngine:
             dtype = self.kv.dtype
 
             def prefill(params, tokens, length, k_pages, v_pages, entries,
-                        key, temp, top_k, top_p):
+                        key, temp, top_k, top_p, state=None, slot=None):
                 zeros = gpt.init_kv_cache(cfg, 1, bucket, dtype=dtype)
                 moe = {}
-                if cfg.is_moe:
+                if cfg.is_moe or cfg.is_recurrent:
                     # the bucket's padding is not live: it gets no expert
-                    # and is not counted (segment id 0; the cached
+                    # and is not counted, and it is kept out of a
+                    # state-space layer's state (segment id 0; the cached
                     # attention route masks by length and ignores it)
-                    moe = {"return_moe_stats": True, "segment_ids": (
+                    moe = {"return_moe_stats": cfg.is_moe, "segment_ids": (
                         jnp.arange(bucket, dtype=jnp.int32)[None]
                         < length[:, None]).astype(jnp.int32)}
-                logits, (kd, vd), *moe_stats = gpt.forward(
+                logits, (kd, vd), *rest = gpt.forward(
                     params, tokens, cfg, kv_cache=zeros,
                     cache_offset=jnp.zeros((1,), jnp.int32),
-                    unembed_positions=length - 1, **moe)
+                    unembed_positions=length - 1,
+                    return_ssm_state=cfg.is_recurrent, **moe)
+                moe_stats = rest[:int(cfg.is_moe)]
+                if cfg.is_recurrent:
+                    # ARM the slot: both pools' rows are overwritten with
+                    # the state after the prompt's last live token,
+                    # computed from a ZERO state, whatever a former
+                    # occupant (or its trailing decode steps) left there
+                    tails, states = rest[-1]
+                    state = {
+                        "conv": state["conv"].at[:, slot].set(
+                            tails[:, 0].astype(state["conv"].dtype)),
+                        "ssm": state["ssm"].at[:, slot].set(
+                            states[:, 0].astype(state["ssm"].dtype))}
                 # dense [L, bucket, Nkv, D] -> paged [L, n_pages, Nkv, PS, D]
                 kd = kd[:, 0].reshape(
-                    cfg.num_layers, n_pages, self.kv.page_size,
+                    cfg.kv_layers, n_pages, self.kv.page_size,
                     cfg.num_kv_heads, cfg.head_dim).transpose(0, 1, 3, 2, 4)
                 vd = vd[:, 0].reshape(
-                    cfg.num_layers, n_pages, self.kv.page_size,
+                    cfg.kv_layers, n_pages, self.kv.page_size,
                     cfg.num_kv_heads, cfg.head_dim).transpose(0, 1, 3, 2, 4)
 
                 def scatter(pages, dense):
@@ -688,11 +762,13 @@ class InferenceEngine:
                                       top_k[None], top_p[None])[0]
                 if moe_stats:
                     token = self._with_moe_stats(token, moe_stats[0])
+                if cfg.is_recurrent:
+                    return token, k_pages, v_pages, state
                 return token, k_pages, v_pages
 
             self._prefill_cache[bucket] = _Program(
                 f"prefill {bucket}", prefill, self.failed_programs,
-                donate_argnums=(3, 4))
+                donate_argnums=(3, 4, 10) if cfg.is_recurrent else (3, 4))
         return self._prefill_cache[bucket]
 
     def _extend_prefill_fn(self, bucket: int):
@@ -766,8 +842,13 @@ class InferenceEngine:
                    decode: bool) -> None:
         """Add one program's [choices (E), experts hit] (summed over its
         layers and ``steps`` steps) to the engine's counters."""
-        hit, layer_steps = int(moe_stats[-1]), steps * self.cfg.num_layers
-        self.moe_choices += moe_stats[:-1]
+        E = self.cfg.moe.num_experts
+        hit, layer_steps = int(moe_stats[E]), steps * self.cfg.moe_layers
+        self.moe_choices += moe_stats[:E]
+        # the live choices over ALL the router's experts: the held ones
+        # alone where every expert is held
+        self.moe_all_choices += int(moe_stats[-1] if len(moe_stats) > E + 1
+                                    else moe_stats[:E].sum())
         self.moe_experts_hit += hit
         self.moe_layer_steps += layer_steps
         if decode:
@@ -786,7 +867,7 @@ class InferenceEngine:
         malformed payload, dry pool) leaves the request exactly as it
         was: plain prefill, correct tokens, extra compute."""
         hook = self.prefix_fetch_hook
-        if (hook is None or not self.serve_cfg.prefix_caching
+        if (hook is None or not self._prefix_caching
                 or req.swapped_kv is not None
                 or getattr(req, "prefix_owner", None) is None
                 or not req.prefix_hashes):
@@ -867,7 +948,7 @@ class InferenceEngine:
         compute. Engine thread, no lock held across the network."""
         hook = self.prefix_fetch_hook
         kvp = req.swapped_kv
-        if (hook is None or not self.serve_cfg.prefix_caching
+        if (hook is None or not self._prefix_caching
                 or not isinstance(kvp, dict) or not kvp.get("partial")
                 or getattr(req, "prefix_owner", None) is None
                 or not req.prefix_hashes):
@@ -1042,7 +1123,7 @@ class InferenceEngine:
                         *common, first_key, jnp.float32(s.temperature),
                         jnp.int32(s.top_k), jnp.float32(s.top_p))
                 self.spans.dispatched()
-                if self.serve_cfg.prefix_caching and req.prefix_hashes:
+                if self._prefix_caching and req.prefix_hashes:
                     with self.lock:
                         table = self.kv.block_tables[req.slot]
                         self.kv.register_pages(
@@ -1068,7 +1149,7 @@ class InferenceEngine:
         compute, which is the transfer-hides-behind-compute half of the
         pipelined prefill (serve/fleet/pipeline.py)."""
         req: Request = st["req"]
-        if not self.serve_cfg.prefix_caching or not req.prefix_hashes:
+        if not self._prefix_caching or not req.prefix_hashes:
             return
         full = min(st["done"] // self.kv.page_size, len(req.prefix_hashes))
         pub = st.setdefault("published", st["pins"])
@@ -1175,11 +1256,16 @@ class InferenceEngine:
             tokens[0, :n] = ctx
             if first_prefill:
                 req.prefill_bucket = bucket
-            token, self.kv.k_pages, self.kv.v_pages = self._prefill_fn(bucket)(
+            out = self._prefill_fn(bucket)(
                 self.params, jnp.asarray(tokens), jnp.asarray([n], jnp.int32),
                 self.kv.k_pages, self.kv.v_pages, jnp.asarray(entries),
                 first_key, jnp.float32(s.temperature),
-                jnp.int32(s.top_k), jnp.float32(s.top_p))
+                jnp.int32(s.top_k), jnp.float32(s.top_p),
+                *((self.kv.state, jnp.int32(slot))
+                  if self.cfg.is_recurrent else ()))
+            if self.cfg.is_recurrent:
+                *out, self.kv.state = out
+            token, self.kv.k_pages, self.kv.v_pages = out
             computed = n
         else:
             computed = n - cached
@@ -1204,7 +1290,7 @@ class InferenceEngine:
         self.spans.annotate(bucket=bucket, cached=cached)
 
         # publish this prompt's freshly-written full pages for future hits
-        if self.serve_cfg.prefix_caching and req.prefix_hashes:
+        if self._prefix_caching and req.prefix_hashes:
             with self.lock:
                 table = self.kv.block_tables[slot]
                 self.kv.register_pages(
@@ -1284,19 +1370,23 @@ class InferenceEngine:
     # -- decode --------------------------------------------------------------
 
     def _decode_impl_n(self, params, k_pages, v_pages, tokens, positions,
-                       tables, stops, slot_keys, temp, top_k, top_p):
+                       tables, stops, slot_keys, temp, top_k, top_p,
+                       state=None):
         # _decode_unit_len steps: fixed at construction, so ONE program
         # the final scan carry (tokens, positions) comes back as DEVICE
         # arrays so a pipelined follow-up dispatch can chain on them
         # without a host round trip (step() pipelining below)
         # an MoE model's program also returns its routing counts
         # (decode_scan's moe_stats), fetched with the tokens
-        (toks, pos, k_pages, v_pages, *moe_stats), toks_seq = decode_scan(
+        # a model with state-space layers also takes and returns their
+        # state pools, LAST (``state``: donated, advanced in place)
+        (toks, pos, k_pages, v_pages, *rest), toks_seq = decode_scan(
             params, tokens, positions, k_pages, v_pages, tables, stops,
             slot_keys, temp, top_k, top_p, self.cfg, self._decode_unit_len,
             attn_impl=self._attn_impl, w4_kernel_ok=self._w4_kernel_ok,
-            w8_kernel_ok=self._w8_kernel_ok, return_moe_stats=True)
-        return (toks_seq, toks, pos, k_pages, v_pages, *moe_stats)
+            w8_kernel_ok=self._w8_kernel_ok, return_moe_stats=True,
+            ssm_state=state)
+        return (toks_seq, toks, pos, k_pages, v_pages, *rest)
 
     def _short_dispatch_ok(self) -> bool:
         """Should the next decode dispatch run the SHORT program? (caller
@@ -1378,7 +1468,10 @@ class InferenceEngine:
         (sampled_seq, next_toks, next_pos, self.kv.k_pages, self.kv.v_pages,
          *moe_stats) = self._decode_jit(
                 self.params, self.kv.k_pages, self.kv.v_pages,
-                tokens, positions, *shared)
+                tokens, positions, *shared,
+                *((self.kv.state,) if self.cfg.is_recurrent else ()))
+        if self.cfg.is_recurrent:
+            *moe_stats, self.kv.state = moe_stats
         return {
             "sampled": sampled_seq, "moe_stats": moe_stats,
             "next_tokens": next_toks,
@@ -1408,10 +1501,17 @@ class InferenceEngine:
             self.kv.max_pages_per_slot).sum())
         self.total_live_pages += live_pages
         self.total_table_pages += self.kv.block_tables.size
+        ids = {}
+        if self.cfg.is_recurrent:
+            # state updates this dispatch asks of the state-space layers
+            # (live slots x steps; stats()["ssm"]["slot_steps"] counts them
+            # at the fetch)
+            ids["ssm_slot_steps"] = (int(self.active.sum()) * n_units
+                                     * self._decode_unit_len)
         with self.spans.phase("llmctl.engine.decode.submit", units=n_units,
                               active=int(self.active.sum()),
                               live_pages=live_pages,
-                              table_pages=self.kv.block_tables.size):
+                              table_pages=self.kv.block_tables.size, **ids):
             shared = self._shared_decode_args()
             for _ in range(n_units):
                 pend = self._submit_decode(chain_from=pend, shared=shared)
@@ -1445,6 +1545,8 @@ class InferenceEngine:
         self.total_decode_steps += out.shape[0]
         self.total_padded_slot_steps += out.shape[0] * int(
             self.serve_cfg.max_batch_size - group["active"].sum())
+        if self.cfg.is_recurrent:
+            self.ssm_slot_steps += out.shape[0] * int(group["active"].sum())
         return out
 
     @engine_thread_only
@@ -1759,7 +1861,7 @@ class InferenceEngine:
             spec = self.spec_state_of(slot)
             if spec is not None:
                 req.swapped_kv["spec"] = spec
-        if self.serve_cfg.prefix_caching:
+        if self._prefix_caching:
             from .kv_cache import prefix_page_hashes
             ctx = req.context_tokens
             full = written // self.kv.page_size
@@ -2057,6 +2159,9 @@ class InferenceEngine:
                     setattr(self.kv, name,
                             self.kv._new_pages(buf.shape, self.kv.dtype))
                     reallocated = True
+            if self.kv.state is not None and any(
+                    leaf.is_deleted() for leaf in self.kv.state.values()):
+                self.kv.state = self.kv.new_state()
             if reallocated:
                 # zeroed buffers invalidate every cached prefix page — a
                 # future hash hit would attend over all-zero K/V
@@ -2085,6 +2190,11 @@ class InferenceEngine:
         device-time figure = host queue wait + this prefill time
         (VERDICT r2 weak #2: the <200 ms claim must rest on a measured
         device-time number, not RTT arithmetic)."""
+        if self.cfg.is_recurrent:
+            raise ValueError(
+                f"{self.cfg.name} has state-space layers: "
+                "measure_device_times is refused (its probes write scratch "
+                "pages, and would arm and advance live slots' state)")
         out: dict = {"prefill_ms": {}, "iters": iters}
         kp, vp = self.kv.k_pages, self.kv.v_pages
         # probes DONATE the page buffers: keep self.kv pointed at the
@@ -2201,8 +2311,21 @@ class InferenceEngine:
             "spec_acceptance": round(
                 self.total_spec_accepted / max(self.total_spec_drafts, 1), 4),
             "compiled_programs": self.compiled_programs(),
+            **({"ssm": {
+                "state_bytes": self.kv.state_bytes(),
+                "slot_steps": self.ssm_slot_steps,
+                # every prefill of such a model is cold: its tokens and
+                # the rows its programs computed all go through the scan
+                "prefill_tokens": self.total_prefill_tokens,
+                "prefill_padded_tokens": self.total_prefill_padded_tokens,
+                "refused": dict(self.ssm_refused),
+            }} if self.cfg.is_recurrent else {}),
             **({"moe": {
                 "choices": self.moe_choices.tolist(),
+                # live choices on the experts HELD here, beside those over
+                # all the router's experts (equal where all are held)
+                "held_choices": int(self.moe_choices.sum()),
+                "all_choices": self.moe_all_choices,
                 "experts_hit": self.moe_experts_hit,
                 "layer_steps": self.moe_layer_steps,
                 "decode_experts_hit": self.moe_decode_experts_hit,
@@ -2212,6 +2335,41 @@ class InferenceEngine:
             # ({span: {"s": self seconds, "n": calls}}), "starved_s"
             **self.spans.snapshot(),
         }
+
+    def program_texts(self) -> dict:
+        """{program name: optimised HLO text} of the resident decode and
+        cold-prefill programs, lowered for shapes like their live
+        arguments' and compiled (from the persistent compile cache where it
+        is on), NOT run. A device trace names an XLA operation by its HLO
+        instruction (``fusion.123``) and says nothing of the named scope it
+        was traced under; each instruction's ``op_name`` in this text does
+        (``.../ssm_decode/mul``): how a reader of a trace tells the
+        state-space update from the matmuls (benchmark/runners/hybrid.py)."""
+        def shapes(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+        state = (shapes(self.kv.state),) if self.cfg.is_recurrent else ()
+        common = shapes((self.params, self.kv.k_pages, self.kv.v_pages))
+        i32, f32 = jnp.int32, jnp.float32
+        texts = {}
+        if self._decode_jit is not None:
+            texts[self._decode_jit.name] = self._decode_jit.lower(
+                *common, *shapes((jnp.asarray(self.last_tokens),
+                                  jnp.asarray(self.positions),
+                                  *self._shared_decode_args())),
+                *state).compile().as_text()
+        scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype)
+        for bucket in [k for k in list(self._prefill_cache)
+                       if isinstance(k, int)]:
+            program = self._prefill_cache[bucket]
+            texts[program.name] = program.lower(
+                common[0], jax.ShapeDtypeStruct((1, bucket), i32),
+                jax.ShapeDtypeStruct((1,), i32), common[1], common[2],
+                jax.ShapeDtypeStruct((bucket // self.kv.page_size,), i32),
+                shapes(jax.random.PRNGKey(0)), scalar(f32), scalar(i32),
+                scalar(f32), *state, *((scalar(i32),) if state else ())
+            ).compile().as_text()
+        return texts
 
     def compiled_programs(self) -> dict:
         """Resident compiled-program inventory by kind. Battery 9 measured

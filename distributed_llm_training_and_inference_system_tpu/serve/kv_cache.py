@@ -126,15 +126,15 @@ class PagedKVCache:
             if kind == "int4":
                 # packed nibbles (D/2 bytes) + fp32 per-(token, kv-head)
                 # scale, K and V — the 2x-over-int8 capacity claim
-                bytes_per_page = (2 * cfg.num_layers * page_size
+                bytes_per_page = (2 * cfg.kv_layers * page_size
                                   * cfg.num_kv_heads
                                   * (cfg.head_dim // 2 + 4))
             elif kind == "int8":
                 # int8 values + fp32 per-(token, kv-head) scale, K and V
-                bytes_per_page = (2 * cfg.num_layers * page_size
+                bytes_per_page = (2 * cfg.kv_layers * page_size
                                   * cfg.num_kv_heads * (cfg.head_dim + 4))
             else:
-                bytes_per_page = (2 * cfg.num_layers * page_size
+                bytes_per_page = (2 * cfg.kv_layers * page_size
                                   * cfg.num_kv_heads * cfg.head_dim
                                   * jnp.dtype(dtype).itemsize)
             num_pages = max(int(hbm_budget_gb * 1e9 // bytes_per_page), 2)
@@ -146,11 +146,18 @@ class PagedKVCache:
         # [L, NP, Nkv, PS, D] — (PS, D) minor-most so the Pallas decode
         # kernel can DMA one [PS, D] page tile per (kv-head, page) grid step
         # (TPU block shapes must end in the tiled dims)
-        shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page_size,
+        shape = (cfg.kv_layers, num_pages, cfg.num_kv_heads, page_size,
                  cfg.head_dim)
         self.page_sharding = page_sharding
         self.k_pages = self._new_pages(shape, dtype)
         self.v_pages = self._new_pages(shape, dtype)
+        # the second kind of cache state: a state-space layer keeps, for
+        # each SLOT, the last K-1 pre-activation conv columns and its
+        # [nh, P, N] state, whatever the sequence's length. A slot costs
+        # no pages for these layers; the pools are addressed
+        # [state-space layer, slot] and ride the programs beside the page
+        # pools (None for a model without such layers).
+        self.state = self.new_state()
 
         # host-side state; page 0 is scratch and never allocated
         self._free: list[int] = list(range(1, num_pages))
@@ -180,6 +187,37 @@ class PagedKVCache:
         # None (the default) changes nothing.
         self.demote_hook = None
         self._demote_pending: list[tuple[bytes, int]] = []
+
+    def new_state(self):
+        """Zeroed state pools of the state-space layers, or None."""
+        cfg = self.cfg
+        if not cfg.is_recurrent:
+            return None
+        s = cfg.ssm
+        return {
+            "conv": jnp.zeros((cfg.ssm_layers, self.num_slots,
+                               s.conv_kernel - 1, s.conv_channels),
+                              self.dtype),
+            "ssm": jnp.zeros((cfg.ssm_layers, self.num_slots, s.num_heads,
+                              s.head_dim, s.state_size),
+                             jnp.float32),
+        }
+
+    def state_bytes(self) -> int:
+        """HBM bytes of the state pools (0 without state-space layers)."""
+        if self.state is None:
+            return 0
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in self.state.values())
+
+    def _pages_only(self, what: str) -> None:
+        """Refuse, by name, to move a sequence as K/V pages alone when the
+        model also keeps recurrent state a slot."""
+        if self.state is not None:
+            raise ValueError(
+                f"{self.cfg.name} has state-space layers: {what} is "
+                "refused (it carries K/V pages, and the slot's recurrent "
+                "state is not in them; ROADMAP C2)")
 
     def _new_pages(self, shape, dtype):
         """Allocate a (possibly int8/int4-quantized, possibly tensor-
@@ -242,7 +280,7 @@ class PagedKVCache:
             if isinstance(buf, QuantPages):
                 return buf.values.size + buf.scale.size * 4
             return int(np.prod(buf.shape)) * jnp.dtype(self.dtype).itemsize
-        return one(self.k_pages) + one(self.v_pages)
+        return one(self.k_pages) + one(self.v_pages) + self.state_bytes()
 
     # -- alloc / grow / free -------------------------------------------------
 
@@ -346,6 +384,10 @@ class PagedKVCache:
         return True
 
     def release(self, slot: int) -> None:
+        """Return ``slot``'s pages. Its rows of the state pools need no
+        device work: the prefill that next arms the slot overwrites them
+        with a state computed from zero (serve/engine.py ``_prefill_fn``),
+        so a reused slot starts from a zero state whatever is left here."""
         for page in self._owned.pop(slot, []):
             self._drop_ref(page)
         self.block_tables[slot, :] = 0
@@ -375,6 +417,7 @@ class PagedKVCache:
         Bounds are validated up front: an out-of-range request would
         otherwise silently gather scratch page 0 (zeros presented as real
         KV — wrong tokens downstream, no error)."""
+        self._pages_only("swap-out / fleet migration (extract_slot)")
         chain = self._chain_len.get(slot, 0)
         if not 0 <= lo <= hi <= chain:
             raise ValueError(
@@ -390,6 +433,7 @@ class PagedKVCache:
         :meth:`extract_slot_pages`. Page ids are bounds-checked (scratch
         page 0 is never a cache page; an out-of-range id would gather
         garbage presented as real KV)."""
+        self._pages_only("fleet prefix export (extract_pages)")
         bad = [int(p) for p in pages if not 0 < int(p) < self.num_pages]
         if bad:
             raise ValueError(
@@ -441,6 +485,7 @@ class PagedKVCache:
         """Swap-in: allocate fresh pages for the slot and write the saved
         K/V back. Returns False (allocating nothing) when the pool can't
         supply the pages — the caller falls back to recompute."""
+        self._pages_only("swap-in / fleet migration (restore_slot)")
         if not isinstance(content, dict) or "num_pages" not in content:
             raise ValueError(
                 "restore payload must be a dict with 'num_pages'; got "
@@ -495,7 +540,7 @@ class PagedKVCache:
     def _validate_pages_shapes(self, content: dict, n: int) -> None:
         from ..ops.paged_attention import Int4Pages, QuantPages
         cfg = self.cfg
-        expect = (cfg.num_layers, n, cfg.num_kv_heads, self.page_size,
+        expect = (cfg.kv_layers, n, cfg.num_kv_heads, self.page_size,
                   cfg.head_dim)
         for name, buf in (("k", self.k_pages), ("v", self.v_pages)):
             data = content[name]
@@ -558,6 +603,7 @@ class PagedKVCache:
         merge — a malformed courier payload must degrade to re-prefill,
         never scatter garbage into the pool.
         """
+        self._pages_only("a migrated payload's write-back (write_slot_pages)")
         n = self._validate_payload(slot, content, lo)
         if n <= 0:
             return
@@ -663,6 +709,7 @@ class PagedKVCache:
 
         Returns the page ids actually claimed (not the skipped
         duplicates)."""
+        self._pages_only("fleet prefix import (insert_prefix_pages)")
         n = self._validate_pages_content(content)
         if n < len(hashes):
             raise ValueError(
@@ -721,6 +768,7 @@ class PagedKVCache:
             "page_size": self.page_size,
             "kv_quantization": self.quant_kind,
             "hbm_bytes": self.hbm_bytes(),
+            "state_bytes": self.state_bytes(),
             "slots_resident": len(self._owned),
             "prefix_cached_pages": len(self._hash_to_page),
             "prefix_hits": self.prefix_hits,

@@ -393,3 +393,137 @@ def test_int4_matmul_kernel_compiles(one_chip, shape):
              sds((n_in // 2, n_out), jnp.uint8),
              sds((n_in // 128, n_out), jnp.float32),
              sds((n_in,), jnp.float32))
+
+
+# -- the hybrid cell (nemotron-3-nano-30b-a3b-14l-ep2) ---------------------------
+
+def _hybrid_cell(one_chip):
+    """(model config, shapes of params / page pool / state pools) of the
+    hybrid cell as its configuration file states it: 64 slots, 1,537 pages
+    of 64, 6 state-space layers, 64 of 128 experts held."""
+    import json
+    from pathlib import Path
+
+    from benchmark import harness
+    from distributed_llm_training_and_inference_system_tpu.config.schema import (
+        ModelConfig)
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    file = (Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+            / "nemotron-3-nano-30b-a3b-14l-ep2.json")
+    config = json.loads(file.read_text())
+    cfg = ModelConfig.from_dict(harness.model_dict(config))
+    sds = _sds(one_chip)
+    B = config["serve"]["max_batch_size"]
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda k: gpt.init(cfg, k, jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+    pool = sds((cfg.kv_layers, 1537, cfg.num_kv_heads, PS, D), jnp.bfloat16)
+    s = cfg.ssm
+    state = {"conv": sds((cfg.ssm_layers, B, s.conv_kernel - 1,
+                          s.conv_channels), jnp.bfloat16),
+             "ssm": sds((cfg.ssm_layers, B, s.num_heads, s.head_dim,
+                         s.state_size), jnp.float32)}
+    return cfg, B, params, pool, state
+
+
+def _no_copy_of(text: str, shapes: list[str]) -> None:
+    for line in text.splitlines():
+        if " copy(" in line:
+            for shape in shapes:
+                assert not line.lstrip().split(" = ", 1)[-1].startswith(
+                    shape), f"a pool- or stack-sized copy: {line[:200]}"
+
+
+@pytest.mark.parametrize("which", ["up", "down"])
+def test_hybrid_grouped_matmul_kernels_compile(one_chip, as_tpu, which):
+    """The grouped matmuls at Nemotron-3-Nano's widths on the six-layer
+    stacks of the 64 held experts, decode's 16-row tiles: ``up`` stored
+    (out, in) = [1856, 2688] and taken transposed with K in blocks (1856
+    is no multiple of 128), ``down`` [1856, 2688] with 2688 in column
+    blocks of 896. Neither stack may be a temporary."""
+    from distributed_llm_training_and_inference_system_tpu.ops.moe_gmm import (
+        grouped_matmul)
+    E, H, F, L, tm = 64, 2688, 1856, 6, 16
+    n_tiles = (384 + E * (tm - 1)) // tm
+    sds = _sds(one_chip)
+    k = H if which == "up" else F
+    compiled = _compile(
+        functools.partial(grouped_matmul, tm=tm,
+                          rhs_transposed=which == "up"),
+        sds((n_tiles * tm, k), jnp.bfloat16), sds((L, E, F, H), jnp.bfloat16),
+        sds((n_tiles,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32))
+    text = compiled.as_text()
+    assert "moe_gmm" in text
+    _no_copy_of(text, ["bf16[6,64,1856,2688]"])
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < E * H * F * 2 // 8, f"{temp / 1e6:.1f} MB of temporaries"
+
+
+def test_hybrid_decode_program_moves_no_pool_and_no_stack(one_chip, as_tpu):
+    """The multi-step decode program at the hybrid cell's shapes: the page
+    pools hold the two attention layers alone, the state pools ride the
+    carry and are written at [layer], the expert stacks stay whole: no
+    temporary the size of a state pool (0.82 GB), of an expert stack
+    (3.8 GB) or of a layer's slab of state (134 MB) beyond the step's own
+    working set, and no copy of any of them in the program."""
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        decode_scan)
+    cfg, B, params, pool, state = _hybrid_cell(one_chip)
+    sds = _sds(one_chip)
+
+    def program(params, k_pages, v_pages, tokens, positions, tables, stops,
+                keys, temp, top_k, top_p, state):
+        return decode_scan(params, tokens, positions, k_pages, v_pages,
+                           tables, stops, keys, temp, top_k, top_p, cfg, 2,
+                           return_moe_stats=True, ssm_state=state)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    compiled = jax.jit(program, donate_argnums=(1, 2, 11)).lower(
+        params, pool, pool, i32(B), i32(B), i32(B, MAXP), i32(B),
+        sds((B, 2), jnp.uint32), sds((B,), jnp.float32), i32(B),
+        sds((B,), jnp.float32), state).compile()
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "paged_attention" in text
+    _no_copy_of(text, ["bf16[6,64,1856,2688]", "f32[6,64,64,64,128]",
+                       "bf16[2,1537,2,64,128]", "bf16[6,64,3,6144]"])
+    mem = compiled.memory_analysis()
+    state_pool = 6 * B * 64 * 64 * 128 * 4
+    assert mem.temp_size_in_bytes < state_pool // 4, (
+        f"decode program holds {mem.temp_size_in_bytes / 1e6:.1f} MB of "
+        f"temporaries; the state pool is {state_pool / 1e6:.1f} MB")
+    # the donated pools come back in place
+    assert mem.alias_size_in_bytes >= state_pool
+
+
+def test_hybrid_prefill_program_compiles(one_chip, as_tpu):
+    """Cold prefill of a 256-row bucket at the hybrid cell's shapes: the
+    chunked scan, the prefill's grouped matmuls, the dense attention cache
+    of the two attention layers, and the slot's rows of both state pools
+    written in place."""
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    cfg, B, params, pool, state = _hybrid_cell(one_chip)
+    sds = _sds(one_chip)
+    bucket = 256
+
+    def prefill(params, tokens, length, state, slot):
+        live = (jnp.arange(bucket)[None] < length[:, None]).astype(jnp.int32)
+        logits, (kd, vd), stats, (tails, hs) = gpt.forward(
+            params, tokens, cfg,
+            kv_cache=gpt.init_kv_cache(cfg, 1, bucket, dtype=jnp.bfloat16),
+            cache_offset=jnp.zeros((1,), jnp.int32),
+            unembed_positions=length - 1, return_moe_stats=True,
+            segment_ids=live, return_ssm_state=True)
+        state = {"conv": state["conv"].at[:, slot].set(
+                     tails[:, 0].astype(jnp.bfloat16)),
+                 "ssm": state["ssm"].at[:, slot].set(hs[:, 0])}
+        return logits, kd, vd, stats, state
+
+    compiled = jax.jit(prefill, donate_argnums=(3,)).lower(
+        params, sds((1, bucket), jnp.int32), sds((1,), jnp.int32), state,
+        sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "moe_gmm_prefill" in text
+    _no_copy_of(text, ["bf16[6,64,1856,2688]", "f32[6,64,64,64,128]"])
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 256e6, f"{temp / 1e6:.1f} MB of temporaries"
