@@ -19,7 +19,13 @@ the chip):
             only place a COMPILED kernel's result is checked (the tests
             run interpret mode)
   serve     `cli.main serve start --model gpt-1b`, defaults otherwise;
-            requests (a)-(f) over HTTP; /health must show no engine error
+            requests (a)-(f) over HTTP; /health must show no engine error;
+            then 8 seeded replies of the engine (temperature 0.8, top-k 40,
+            top-p 0.9, a seed a request) against the direct reference that
+            samples with fold_in(PRNGKey(seed), context length); with
+            `--parent DIR` (the parent commit, unpacked by `git archive`)
+            also greedy and seeded replies of 8 prompts x 64 tokens at
+            mistral-7b-16l, this tree against that one, token for token
   train     `cli.main train launch --model gpt-750m --max-steps 8`,
             sequence 2048, micro-batch 4, flash attention, fused AdamW
   launcher  `train launch --restart-on-failure 1 --max-steps 2` at
@@ -462,6 +468,66 @@ def phase_serve(env: dict, device: dict) -> None:
     if mem["temp_bytes"] >= mem["k_pool_bytes"] and not REHEARSAL:
         raise SmokeFailure("the decode program holds a pool-sized "
                            "temporary: the KV pool is copied again")
+
+
+def seeded_reference(next_logits, prompt: list, sampling, n_new: int) -> list:
+    """What a seeded request must be served, computed directly: token after
+    token, the next token's logits over the whole context
+    (``next_logits(context) -> [V]``) sampled with the key
+    ``fold_in(PRNGKey(seed), len(context))``. That is the engine's contract
+    for a stream's keys whichever program draws the token (prefill's first,
+    a decode step, a verification window) and wherever the slot's key was
+    made; ``tests/test_slot_keys.py`` holds every prefill path to it on the
+    CPU, the serve phase the chip's programs."""
+    import jax
+    import jax.numpy as jnp
+    from distributed_llm_training_and_inference_system_tpu.serve.sampling import (
+        sample_tokens)
+    key = jax.random.PRNGKey(sampling.seed)
+    context = list(prompt)
+    for _ in range(n_new):
+        token = sample_tokens(
+            next_logits(context)[None],
+            jax.random.fold_in(key, len(context))[None],
+            jnp.asarray([sampling.temperature], jnp.float32),
+            jnp.asarray([sampling.top_k], jnp.int32),
+            jnp.asarray([sampling.top_p], jnp.float32))
+        context.append(int(token[0]))
+    return context[len(prompt):]
+
+
+def phase_seeded_replies(env: dict, parent: str | None) -> None:
+    text = run_child("seeded", [sys.executable, str(ROOT / "chip_smoke.py")],
+                     {**env, PHASE_ENV: "seeded"}, timeout=900)
+    [rec] = smoke_records(text, "seeded")
+    say(f"  seeded replies ({rec['model']}, float32, full-precision "
+        f"matmuls): {rec['requests']} requests x {rec['new']} tokens, "
+        f"tokens equal to the direct reference: {rec['identical']}")
+    if any(n != rec["new"] for n in rec["identical"]):
+        raise SmokeFailure("a seeded reply differs from fold_in(PRNGKey("
+                           f"seed), context length): {rec}")
+    if parent is None:
+        return
+    if REHEARSAL:
+        say("  --parent: experiments/served_tokens.py has no CPU mode; "
+            "not run in a rehearsal")
+        return
+    served = {}
+    for side, root in (("this", ROOT), ("parent", Path(parent).resolve())):
+        out = OUT / f"served_tokens_{side}.json"
+        run_child(f"served_tokens_{side}",
+                  [sys.executable, str(root / "experiments"
+                                       / "served_tokens.py"),
+                   "--out", str(out)],
+                  {**env, "PYTHONPATH": str(root)}, timeout=1500)
+        served[side] = json.loads(out.read_text())
+    for mode in ("greedy", "seeded"):
+        same = [a == b for a, b in zip(served["this"][mode],
+                                       served["parent"][mode])]
+        say(f"  {mode} replies equal to {parent}'s: {sum(same)} of "
+            f"{len(same)} (x {len(served['this'][mode][0])} tokens)")
+        if not all(same) or len(same) != 8:
+            raise SmokeFailure(f"{mode} tokens differ from the parent's")
 
 
 _STEP_RE = re.compile(r"step (\d+) \| loss ([0-9.naninf-]+) \|.*?"
@@ -1339,9 +1405,67 @@ def child_tp_exact() -> None:
         "min_margin_at": int(margins.argmin())})
 
 
+def child_seeded() -> None:
+    """The engine's seeded replies against ``seeded_reference``, with
+    float32 weights, activations and pages and full-precision matmuls, so
+    that the paged programs and the dense forward differ in the order of
+    float32 sums alone and a sample does not turn on rounding."""
+    child_setup()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_llm_training_and_inference_system_tpu.config.presets import (
+        get_model_config)
+    from distributed_llm_training_and_inference_system_tpu.config.schema import (
+        ServeConfig)
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    from distributed_llm_training_and_inference_system_tpu.serve.engine import (
+        InferenceEngine)
+    from distributed_llm_training_and_inference_system_tpu.serve.scheduler import (
+        Request, SamplingParams)
+
+    model, lengths, new, span, extra = (
+        ("gpt-test", (9, 17, 26, 33, 40, 48, 57, 64), 24, 128,
+         {"kv_block_size": 16}) if REHEARSAL
+        else ("gpt-125m", (33, 64, 97, 190, 256, 411, 700, 900), 64, 1024,
+              {}))
+    jax.config.update("jax_default_matmul_precision", "highest")
+    cfg = get_model_config(model)
+    cfg.dtype = "float32"
+    eng = InferenceEngine(cfg, ServeConfig(
+        model=model, dtype="float32", max_batch_size=8, max_seq_len=span,
+        **extra), seed=0)
+    prompts = [prompt_tokens(30 + i, n, cfg.vocab_size)
+               for i, n in enumerate(lengths)]
+    reqs = [Request(request_id=f"seeded-{i}", prompt_tokens=p,
+                    sampling=SamplingParams(temperature=0.8, top_k=40,
+                                            top_p=0.9, max_tokens=new,
+                                            seed=1000 + i))
+            for i, p in enumerate(prompts)]
+    for r in reqs:
+        if not eng.scheduler.add_request(r):
+            raise RuntimeError(r.error)
+    eng.run_until_idle()
+    forward = jax.jit(lambda p, t: gpt.forward(p, t, cfg))
+
+    def next_logits(context):
+        padded = np.zeros((1, span), np.int32)
+        padded[0, :len(context)] = context
+        return forward(eng.params, jnp.asarray(padded))[0, len(context) - 1]
+    identical = []
+    for r in reqs:
+        want = seeded_reference(next_logits, r.prompt_tokens, r.sampling, new)
+        got = list(r.generated_tokens)
+        identical.append(next((i for i, (a, b) in enumerate(zip(got, want))
+                               if a != b), min(len(got), len(want))))
+    emit("seeded", {"model": model, "requests": len(reqs), "new": new,
+                    "identical": identical})
+
+
 CHILDREN = {"kernels": child_kernels, "mesh_train": child_mesh_train,
             "tp_exact": child_tp_exact, "replicas": child_replicas,
-            "decode_memory": child_decode_memory}
+            "decode_memory": child_decode_memory, "seeded": child_seeded}
 
 
 # ---------------------------------------------------------------------------
@@ -1357,6 +1481,10 @@ def main() -> int:
                     help="4: run ONLY the four-chip paths (mesh training, "
                          "tensor-parallel serving) and what they are "
                          "compared with")
+    ap.add_argument("--parent", metavar="DIR",
+                    help="the parent commit unpacked beside this tree (git "
+                         "archive): the serve phase also holds this tree's "
+                         "greedy and seeded tokens equal to that one's")
     args = ap.parse_args()
 
     ok = False
@@ -1376,6 +1504,7 @@ def main() -> int:
         if args.chips == 1:
             device = phase_kernels(env)
             phase_serve(env, device)
+            phase_seeded_replies(env, args.parent)
             phase_train(env, device)
             phase_launcher(env, device)
         else:

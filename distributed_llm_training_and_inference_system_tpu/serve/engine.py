@@ -40,7 +40,7 @@ from ..config.schema import ModelConfig, ServeConfig
 from ..models import gpt
 from .decode import decode_scan, extend_step_forward
 from .kv_cache import PagedKVCache
-from .sampling import sample_tokens
+from .sampling import fold_in_key_data, sample_tokens, seed_key_data
 from .scheduler import (ContinuousBatchingScheduler, Request, RequestState,
                         SamplingParams)
 from ..analysis.annotations import engine_thread_only
@@ -95,6 +95,11 @@ class InferenceEngine:
         eos_token_id: Optional[int] = None,
     ):
         serve_cfg.validate()    # one source of truth for config rules
+        if jax.config.jax_default_prng_impl != "threefry2x32":
+            raise ValueError(
+                f"jax_default_prng_impl={jax.config.jax_default_prng_impl}: "
+                "the serving engine keeps a slot's key as threefry2x32 key "
+                "data (uint32[2]) and derives it from the seed on the host")
         self.serve_cfg = serve_cfg
         self.eos_token_id = eos_token_id
         dtype = jnp.dtype(serve_cfg.dtype)
@@ -297,6 +302,9 @@ class InferenceEngine:
         self.temperature = np.full(S, 1.0, np.float32)
         self.top_k = np.zeros(S, np.int32)
         self.top_p = np.ones(S, np.float32)
+        # threefry key DATA a slot, written on the host from the request's
+        # seed (_seed_slot); the decode programs wrap it and fold each
+        # position in
         self._slot_keys = np.zeros((S, 2), np.uint32)
         self._base_seed = seed
         self._admitted_counter = 0
@@ -997,15 +1005,32 @@ class InferenceEngine:
             covered + k, getattr(req, "prefix_owner", None))
 
     @engine_thread_only
-    def _seed_slot(self, slot: int, seed: int):
-        """The slot's sampling key, its data copied into ``_slot_keys``. The
-        copy is a fetch from the device, which serves it after whatever it
-        is running (a pipelined dispatch): a device wait, so it has a span
-        of its own and is no part of the host's."""
-        slot_key = jax.random.PRNGKey(seed)
-        with self.spans.phase("llmctl.engine.prefill.key_wait"):
-            self._slot_keys[slot] = np.asarray(jax.random.key_data(slot_key))
-        return slot_key
+    def _seed_slot(self, slot: int, seed: int) -> np.ndarray:
+        """The slot's sampling key from its request's seed: the key's DATA
+        (uint32[2]), made on the host (``sampling.seed_key_data``: no
+        program on the device, nothing fetched from it), written into
+        ``_slot_keys`` for the decode programs, which fold each position
+        in themselves, and returned for ``_sampling_args``, which folds
+        the prompt's length in for the first token."""
+        key = seed_key_data(seed)
+        self._slot_keys[slot] = key
+        return key
+
+    @staticmethod
+    def _sampling_args(slot_key: np.ndarray, n: int,
+                       s: SamplingParams) -> tuple:
+        """A prefill program's sampling arguments: the first token's key
+        (the slot's key with the context length ``n`` folded in, as the
+        decode programs fold each later position in), temperature, top-k
+        and top-p, every one a numpy value made on the host. The jitted
+        call uploads them, where ``fold_in`` on a device key, ``jnp.float32``
+        of a number or ``jnp.asarray`` of a list with a dtype each runs a
+        one-operation program on the device first, and the engine thread
+        walks from each such program to the next while the device stands
+        idle (PERF.md 6, PR 32). The one place every caller of those
+        programs gets them from."""
+        return (fold_in_key_data(slot_key, n), np.float32(s.temperature),
+                np.int32(s.top_k), np.float32(s.top_p))
 
     @engine_thread_only
     def _start_chunked_prefill(self, req: Request) -> None:
@@ -1094,11 +1119,10 @@ class InferenceEngine:
             bucket = cost
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :this] = ctx[done:done + this]
-            common = (self.params, jnp.asarray(tokens),
-                      jnp.asarray([done], jnp.int32),
-                      jnp.asarray([this], jnp.int32),
+            common = (self.params, tokens, np.array([done], np.int32),
+                      np.array([this], np.int32),
                       self.kv.k_pages, self.kv.v_pages,
-                      jnp.asarray(st["table_row"][None]))
+                      st["table_row"][None])
             if done + this < n or stage is not None:
                 # intermediate chunk — and EVERY chunk of a pipeline
                 # stage request, whose product is pages, not logits:
@@ -1116,12 +1140,10 @@ class InferenceEngine:
                             self.scheduler.finish_prefill_only(rid)
                         del self._partial_prefills[rid]
             else:
-                s = req.sampling
-                first_key = jax.random.fold_in(st["slot_key"], n)
                 token, self.kv.k_pages, self.kv.v_pages = \
                     self._extend_prefill_fn(bucket)(
-                        *common, first_key, jnp.float32(s.temperature),
-                        jnp.int32(s.top_k), jnp.float32(s.top_p))
+                        *common, *self._sampling_args(st["slot_key"], n,
+                                                      req.sampling))
                 self.spans.dispatched()
                 if self._prefix_caching and req.prefix_hashes:
                     with self.lock:
@@ -1165,6 +1187,10 @@ class InferenceEngine:
     def _prefill(self, req: Request):
         """Dispatch one prompt's prefill; returns (req, device token).
 
+        ONE program on the device and nothing fetched from it: the pages,
+        the slot's key and the arguments are host work (numpy), so the
+        dispatch queues behind whatever the device is running, a pipelined
+        decode dispatch included (``tests/test_prefill_dispatch.py``).
         The first-token fetch is DEFERRED (_finish_prefill) so a burst of
         admitted prompts pays one host round trip total, not one per
         prompt — dispatches pipeline on-device."""
@@ -1242,8 +1268,8 @@ class InferenceEngine:
                 self._base_seed + self._admitted_counter)
         self._admitted_counter += 1
         self._slot_seq[slot] = self._admitted_counter  # preemption priority
-        slot_key = self._seed_slot(slot, req.assigned_seed)
-        first_key = jax.random.fold_in(slot_key, n)
+        sampling = self._sampling_args(
+            self._seed_slot(slot, req.assigned_seed), n, s)
         # first prefill only: a preemption RESUME must not restamp these —
         # TTFT is arrival->FIRST token, and the resume bucket is a suffix
         # program the dense calibration table doesn't cover
@@ -1257,11 +1283,9 @@ class InferenceEngine:
             if first_prefill:
                 req.prefill_bucket = bucket
             out = self._prefill_fn(bucket)(
-                self.params, jnp.asarray(tokens), jnp.asarray([n], jnp.int32),
-                self.kv.k_pages, self.kv.v_pages, jnp.asarray(entries),
-                first_key, jnp.float32(s.temperature),
-                jnp.int32(s.top_k), jnp.float32(s.top_p),
-                *((self.kv.state, jnp.int32(slot))
+                self.params, tokens, np.array([n], np.int32),
+                self.kv.k_pages, self.kv.v_pages, entries, *sampling,
+                *((self.kv.state, np.int32(slot))
                   if self.cfg.is_recurrent else ()))
             if self.cfg.is_recurrent:
                 *out, self.kv.state = out
@@ -1278,13 +1302,10 @@ class InferenceEngine:
             # than bill them a full dense prefill
             token, self.kv.k_pages, self.kv.v_pages = \
                 self._extend_prefill_fn(bucket)(
-                    self.params, jnp.asarray(tokens),
-                    jnp.asarray([cached], jnp.int32),
-                    jnp.asarray([computed], jnp.int32),
-                    self.kv.k_pages, self.kv.v_pages,
-                    jnp.asarray(table_row[None]), first_key,
-                    jnp.float32(s.temperature), jnp.int32(s.top_k),
-                    jnp.float32(s.top_p))
+                    self.params, tokens, np.array([cached], np.int32),
+                    np.array([computed], np.int32),
+                    self.kv.k_pages, self.kv.v_pages, table_row[None],
+                    *sampling)
             self.total_prefix_cached_tokens += cached
         self.spans.dispatched()
         self.spans.annotate(bucket=bucket, cached=cached)
@@ -1820,7 +1841,7 @@ class InferenceEngine:
             self._req_slot[rid] = slot
         self._admitted_counter += 1
         self._slot_seq[slot] = self._admitted_counter
-        slot_key = self._seed_slot(slot, req.assigned_seed)
+        self._seed_slot(slot, req.assigned_seed)
         # migrated speculative state rides the payload manifest (the
         # courier-aware half: a handed-off/migrated sequence resumes
         # with its tuned window, not a cold proposer); _arm_slot reads
@@ -2208,19 +2229,17 @@ class InferenceEngine:
             fn = self._prefill_fn(bucket)
             tokens = jnp.ones((1, bucket), jnp.int32)
             entries = jnp.zeros((bucket // self.kv.page_size,), jnp.int32)
-            args = (jnp.asarray([bucket], jnp.int32), kp, vp, entries,
-                    jax.random.PRNGKey(0), jnp.float32(0.0),
-                    jnp.int32(0), jnp.float32(1.0))
-            token, kp, vp = fn(self.params, tokens, *args)   # warm/compile
+            length = jnp.asarray([bucket], jnp.int32)
+            sampling = self._sampling_args(
+                seed_key_data(0), bucket, SamplingParams(temperature=0.0))
+            token, kp, vp = fn(self.params, tokens, length, kp, vp, entries,
+                               *sampling)                    # warm/compile
             self.kv.k_pages, self.kv.v_pages = kp, vp
             np.asarray(token)
             t0 = time.perf_counter()
             for _ in range(iters):
-                token, kp, vp = fn(self.params, tokens,
-                                   jnp.asarray([bucket], jnp.int32), kp, vp,
-                                   entries, jax.random.PRNGKey(0),
-                                   jnp.float32(0.0), jnp.int32(0),
-                                   jnp.float32(1.0))
+                token, kp, vp = fn(self.params, tokens, length, kp, vp,
+                                   entries, *sampling)
                 self.kv.k_pages, self.kv.v_pages = kp, vp
             np.asarray(token)                                 # one fence
             out["prefill_ms"][bucket] = (time.perf_counter() - t0) \
@@ -2350,7 +2369,7 @@ class InferenceEngine:
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
         state = (shapes(self.kv.state),) if self.cfg.is_recurrent else ()
         common = shapes((self.params, self.kv.k_pages, self.kv.v_pages))
-        i32, f32 = jnp.int32, jnp.float32
+        i32 = jnp.int32
         texts = {}
         if self._decode_jit is not None:
             texts[self._decode_jit.name] = self._decode_jit.lower(
@@ -2358,7 +2377,8 @@ class InferenceEngine:
                                   jnp.asarray(self.positions),
                                   *self._shared_decode_args())),
                 *state).compile().as_text()
-        scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype)
+        sampling = shapes(self._sampling_args(seed_key_data(0), 0,
+                                              SamplingParams()))
         for bucket in [k for k in list(self._prefill_cache)
                        if isinstance(k, int)]:
             program = self._prefill_cache[bucket]
@@ -2366,8 +2386,8 @@ class InferenceEngine:
                 common[0], jax.ShapeDtypeStruct((1, bucket), i32),
                 jax.ShapeDtypeStruct((1,), i32), common[1], common[2],
                 jax.ShapeDtypeStruct((bucket // self.kv.page_size,), i32),
-                shapes(jax.random.PRNGKey(0)), scalar(f32), scalar(i32),
-                scalar(f32), *state, *((scalar(i32),) if state else ())
+                *sampling, *state,
+                *((jax.ShapeDtypeStruct((), i32),) if state else ())
             ).compile().as_text()
         return texts
 

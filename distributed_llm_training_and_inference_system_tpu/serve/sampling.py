@@ -36,8 +36,50 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models.layers import NEG_INF
+
+
+_U32 = 0xFFFFFFFF
+_THREEFRY_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
+
+
+def seed_key_data(seed: int) -> np.ndarray:
+    """uint32[2]: the key data of ``jax.random.PRNGKey(seed)`` under the
+    threefry implementation, computed on the host: the seed's high and low
+    32-bit words. Without ``jax_enable_x64`` JAX narrows the seed to 32
+    bits first, so the high word is 0 (also for a negative or an over-wide
+    seed). A key made this way costs the device no program and the caller
+    no fetch; ``tests/test_slot_keys.py`` holds it to JAX's own, bit for
+    bit."""
+    seed = int(seed)
+    high = seed >> 32 if jax.config.jax_enable_x64 else 0
+    return np.array([high & _U32, seed & _U32], np.uint32)
+
+
+def fold_in_key_data(key: np.ndarray, n: int) -> np.ndarray:
+    """uint32[2]: the key data of ``jax.random.fold_in(key, n)`` under the
+    threefry implementation, computed on the host in Python integers:
+    Threefry-2x32 (20 rounds) of the count ``[0, n]`` under ``key``, as
+    ``jax._src.prng.threefry_fold_in`` computes it on the device. ~7 us,
+    against two one-operation programs on the device and the engine
+    thread's walk between them (PERF.md 6, PR 32); the decode programs
+    fold a position into the same slot key themselves
+    (``serve/decode.py``), so the two derivations must agree bit for bit:
+    ``tests/test_slot_keys.py`` holds this one to JAX's over a table of
+    seeds and lengths."""
+    k0, k1 = int(key[0]), int(key[1])
+    ks = (k0, k1, k0 ^ k1 ^ 0x1BD11BDA)
+    x0, x1 = k0, (int(n) + k1) & _U32
+    for i in range(5):
+        for r in _THREEFRY_ROTATIONS[i % 2]:
+            x0 = (x0 + x1) & _U32
+            x1 = ((x1 << r) | (x1 >> (32 - r))) & _U32
+            x1 ^= x0
+        x0 = (x0 + ks[(i + 1) % 3]) & _U32
+        x1 = (x1 + ks[(i + 2) % 3] + i + 1) & _U32
+    return np.array([x0, x1], np.uint32)
 
 
 def _apply_top_k(logits: jax.Array, top_k: jax.Array) -> jax.Array:
