@@ -1165,6 +1165,103 @@ def child_kernels() -> None:
               got.reshape(-1, H), jax.jit(experts_reference)(
                   u.reshape(-1, H), moe_l))
 
+    # -- latent attention and the hyper-connection (Xing4.0's widths): the
+    # mixer's window through the latent pages then decode steps over the
+    # pool, and one hyper-connected dense layer over four streams, each
+    # against float32 with full-precision matmuls
+    from benchmark.reference import latent_decoder
+    from distributed_llm_training_and_inference_system_tpu.config.presets import (
+        XING_TEST_PUBLISHED)
+    from distributed_llm_training_and_inference_system_tpu.config.schema import (
+        ModelConfig)
+    from distributed_llm_training_and_inference_system_tpu.models.layers import (
+        decoder_block, latent_attention_mixer, model_rope_frequencies)
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        attend_latent_pages)
+    pub = dict(XING_TEST_PUBLISHED if small else json.loads((
+        ROOT / "benchmark/configs/xing4.0-29b-a4b-7l.json").read_text()),
+        num_hidden_layers=1, first_k_dense_replace=1, dtype="bfloat16")
+    # (the CPU's dot has no bfloat16 x bfloat16 -> float32: the rehearsal
+    # runs this section in float32)
+    xdt = jnp.float32 if small else jnp.bfloat16
+    xg = ModelConfig.from_published(pub)
+    blocks = jax.jit(lambda k: gpt.init(xg, k, xdt)["blocks"])(
+        next(key))
+    att = jax.tree_util.tree_map(lambda a: a[0], blocks["attn"])
+    for name in ("q_a_norm", "kv_norm"):
+        att[name] = {"scale": jax.random.uniform(
+            next(key), att[name]["scale"].shape, minval=-0.5, maxval=0.5
+        ).astype(xdt)}
+    H, PSx = xg.hidden_size, (8 if small else 256)
+    S, steps, slots = (24, 3, 3) if small else (512, 4, 4)
+    h = jax.random.normal(next(key), (1, S + steps, H), xdt)
+    w = {k_: att[k_]["kernel"] for k_ in ("q_a", "q_b", "kv_a", "kv_b", "o")}
+    w.update(q_a_norm=att["q_a_norm"]["scale"], kv_norm=att["kv_norm"]["scale"])
+    with jax.default_matmul_precision("highest"):
+        ref = latent_decoder._latent_attention(
+            h[0].astype(jnp.float32), w, pub, None)
+    n_pages = (S + steps) // PSx + 2
+    pool = jnp.zeros((1, 1 + slots * n_pages, 1, PSx, xg.mla.page_width),
+                     xdt)
+    tables = jnp.asarray(1 + np.arange(slots * n_pages, dtype=np.int32)
+                         .reshape(slots, n_pages))
+    inv_x = model_rope_frequencies(xg)
+
+    def latent_step(hh, start, ok, pool, layer):
+        T = hh.shape[1]
+        pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)
+        out, (pool, _) = latent_attention_mixer(
+            hh, layer, xg, pos, inv_x, attend_latent_pages(
+                xg, pool, 0, tables, start, ok))
+        return out, pool
+    latent_step = jax.jit(latent_step, donate_argnums=(3,))
+    hh = jnp.zeros((slots, S, H), xdt).at[1].set(h[0, :S])
+    ok = jnp.zeros((slots, S), bool).at[1].set(True)
+    got, pool = latent_step(hh, jnp.zeros((slots,), jnp.int32), ok, pool, att)
+    check(f"latent_attention_mixer window [{S} rows through pages of {PSx}, "
+          f"{xg.num_heads} heads, latent {xg.mla.latent_size} in "
+          f"{xg.mla.page_width}]", got[1], ref[:S])
+    decoded = []
+    for t in range(steps):
+        hh = jnp.zeros((slots, 1, H), xdt).at[1, 0].set(h[0, S + t])
+        start = jnp.zeros((slots,), jnp.int32).at[1].set(S + t)
+        out, pool = latent_step(hh, start, jnp.arange(slots)[:, None] == 1,
+                                pool, att)
+        decoded.append(out[1, 0])
+    check(f"latent_attention_mixer decode [{steps} steps over the latent "
+          f"pool, slot 1 of {slots} live]", jnp.stack(decoded), ref[S:])
+
+    mlp_l = jax.tree_util.tree_map(lambda a: a[0], blocks["mlp"])
+    hc = mlp_l["hc"]
+    n_s = xg.hc_mult
+    hc.update(
+        norm={"scale": jax.random.uniform(next(key), hc["norm"]["scale"].shape,
+                                          minval=-0.5, maxval=0.5)},
+        b_pre=jax.random.uniform(next(key), (n_s,), minval=-1.0, maxval=1.0),
+        b_post=jax.random.uniform(next(key), (n_s,), minval=-1.0, maxval=1.0),
+        b_res=jnp.eye(n_s) + jax.random.uniform(next(key), (n_s, n_s),
+                                                minval=-1.0, maxval=1.0))
+    rows = 16 if small else 256
+    X = jax.random.normal(next(key), (1, rows, n_s, H), xdt)
+    got = jax.jit(lambda X, layer: decoder_block(
+        X, layer, xg, jnp.zeros((1, rows), jnp.int32), inv_x, None,
+        kind="D")[0])(X, mlp_l)
+    with jax.default_matmul_precision("highest"):
+        Xf = X[0].astype(jnp.float32)
+        stack = jax.tree_util.tree_map(lambda a: a[None], mlp_l)
+        pre, post, res = latent_decoder.hyper_connection_maps(
+            Xf, stack["hc"], 0, pub)
+        u = latent_decoder._rms_norm(
+            jnp.einsum("sn,snc->sc", pre, Xf), mlp_l["norm"]["scale"],
+            xg.norm_eps)
+        out = latent_decoder._mlp(u, mlp_l["gate"]["kernel"],
+                                  mlp_l["up"]["kernel"],
+                                  mlp_l["down"]["kernel"])
+        ref = (jnp.einsum("sij,sjc->sic", res, Xf)
+               + post[:, :, None] * out[:, None, :])
+    check(f"hyper-connected dense layer [{rows} rows, {n_s} streams of {H}, "
+          f"{xg.hc_sinkhorn_iters} Sinkhorn iterations]", got[0], ref)
+
     # -- data packer: built here from native/dataloader.cpp -------------------
     from distributed_llm_training_and_inference_system_tpu.io import native
     from distributed_llm_training_and_inference_system_tpu.io.data import (

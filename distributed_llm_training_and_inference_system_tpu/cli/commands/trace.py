@@ -145,15 +145,19 @@ def load_profile(path: str) -> dict:
     every other plane, by the line (the thread) they were opened on.
     "page_walk" sums the ``live_pages`` / ``table_pages`` ids that every
     decode dispatch's span carries (serve/engine.py ``_submit_group``), and
-    for a model with state-space layers its ``ssm_slot_steps``;
-    "prefill_rows" the ``bucket`` (rows the program computed) and
-    ``tokens`` less ``cached`` (the live ones) of every prefill span."""
+    for a model with state-space layers its ``ssm_slot_steps``, for one
+    with latent attention the bytes of a latent page over its layers
+    (``latent_page_bytes``, the last seen, not a sum);
+    "prefill_rows" the ``bucket`` (rows the program computed), ``tokens``
+    less ``cached`` (the live ones) and ``cached`` (the prompt tokens the
+    prefix cache supplied) of every prefill span."""
     from jax.profiler import ProfileData
     profile = ProfileData.from_file(str(path))
     devices: dict = {}
     host_spans: dict = {}
-    page_walk = {"live_pages": 0, "table_pages": 0, "ssm_slot_steps": 0}
-    prefill_rows = {"rows": 0, "tokens": 0}
+    page_walk = {"live_pages": 0, "table_pages": 0, "ssm_slot_steps": 0,
+                 "latent_page_bytes": 0}
+    prefill_rows = {"rows": 0, "tokens": 0, "cached": 0}
     for plane in profile.planes:
         is_device = plane.name.startswith("/device:")
         for line in plane.lines:
@@ -172,10 +176,15 @@ def load_profile(path: str) -> dict:
                                   (e.start_ns + e.duration_ns) * 1e-9))
                     if e.name == SPAN_PREFIX + "engine.decode.submit":
                         for key, value in e.stats:
-                            if key in page_walk:
+                            if key == "latent_page_bytes":
+                                page_walk[key] = int(value)
+                            elif key in page_walk:
                                 page_walk[key] += int(value)
                     elif e.name == SPAN_PREFIX + "engine.prefill.host":
                         ids = dict(e.stats)
+                        # (a chunked prefill's first span carries its
+                        # cached tokens and no bucket)
+                        prefill_rows["cached"] += int(ids.get("cached", 0))
                         if "bucket" in ids:
                             prefill_rows["rows"] += int(ids["bucket"])
                             prefill_rows["tokens"] += (
@@ -329,6 +338,12 @@ def summarize(trace_dir):
                    f"slot states (live slots x decode steps), summed over "
                    f"the decode dispatches")
     rows = loaded["prefill_rows"]
+    if walk["latent_page_bytes"]:
+        prompt = rows["tokens"] + rows["cached"]
+        click.echo(f"latent attention walked {walk['live_pages']} pages of "
+                   f"{walk['latent_page_bytes']} bytes; "
+                   f"{100 * rows['cached'] / max(prompt, 1):.1f} % of prompt "
+                   f"tokens came from the prefix cache")
     if rows["rows"]:
         click.echo(f"prefill computed {rows['rows']} rows for "
                    f"{rows['tokens']} tokens, "

@@ -205,13 +205,41 @@ TEST_TEMPLATES: dict[str, ModelConfig] = {
 }
 
 
+# Xing4.0's shape in small, in its published keys (the plain reference of
+# the benchmark reads these): latent attention (two head sizes, a rope part
+# under YaRN), four residual streams, one leading dense layer, then
+# sigmoid-routed experts with a shared one
+XING_TEST_PUBLISHED = {
+    "name": "xing-test", "num_hidden_layers": 3, "first_k_dense_replace": 1,
+    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 4,
+    "q_lora_rank": 24, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "intermediate_size": 96,
+    "moe_intermediate_size": 32, "n_routed_experts": 8, "n_shared_experts": 1,
+    "num_experts_per_tok": 2, "routed_scaling_factor": 2.0,
+    "norm_topk_prob": True, "vocab_size": 256, "max_position_embeddings": 512,
+    "rms_norm_eps": 1e-6, "dtype": "float32", "hc_mult": 4,
+    "hc_sinkhorn_iters": 20, "hc_eps": 1e-6, "mhc_h_res_clamp_min": -30,
+    "mhc_h_res_clamp_max": 30, "rope_theta": 10000.0,
+    "rope_scaling": {"type": "yarn", "factor": 8, "beta_fast": 32,
+                     "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 64},
+}
+TEST_TEMPLATES["xing-test"] = ModelConfig.from_published(XING_TEST_PUBLISHED)
+
+
 def get_model_config(name: str) -> ModelConfig:
-    """Look up a template by name (also accepts test templates).
+    """Look up a template by name (also accepts test templates), or read a
+    model's published ``config.json`` from a path ending in ``.json``.
 
     Returns a deep copy so callers can mutate freely without corrupting the
     global template table.
     """
     import copy
+    if name.endswith(".json"):
+        # a published config.json (benchmark/configs/*.json)
+        import json
+        from pathlib import Path
+        return ModelConfig.from_published(json.loads(Path(name).read_text()))
     if name in MODEL_TEMPLATES:
         return copy.deepcopy(MODEL_TEMPLATES[name])
     if name in TEST_TEMPLATES:

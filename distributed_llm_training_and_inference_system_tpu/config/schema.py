@@ -48,18 +48,92 @@ def _parse_bool(name: str, v: Any) -> bool:
 @dataclass
 class RopeConfig:
     base: float = 10000.0
-    scaling: str = "none"       # none | linear | ntk
+    scaling: str = "none"       # none | linear | ntk | yarn
     scaling_factor: float = 1.0
+    # YaRN alone (a published ``rope_scaling`` of ``type: yarn``): each
+    # frequency is blended between f and f / factor by a linear ramp between
+    # the correction dims of ``beta_fast`` and ``beta_slow`` rotations over
+    # ``original_max_position``; ``mscale_all_dim`` enters the softmax scale
+    # (``softmax_mscale``) and mscale / mscale_all_dim the cos / sin
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def softmax_mscale(self) -> float:
+        """m of YaRN's softmax scale (the scores are multiplied by m^2):
+        0.1 * mscale_all_dim * ln(factor) + 1, and 1 without YaRN."""
+        import math
+        if self.scaling != "yarn" or self.scaling_factor <= 1 \
+                or not self.mscale_all_dim:
+            return 1.0
+        return 0.1 * self.mscale_all_dim * math.log(self.scaling_factor) + 1.0
 
     @classmethod
-    def from_dict(cls, d: dict[str, Any] | None) -> "RopeConfig":
+    def from_dict(cls, d: dict[str, Any] | None,
+                  published: dict[str, Any] | None = None) -> "RopeConfig":
+        """The nested ``rope`` table ``d``; ``published`` is a config.json's
+        ``rope_scaling`` group (``type: yarn`` is the one form read)."""
+        d = dict(d or {})
+        if published:
+            kind = str(_take(published, "type", "rope_type", default="none"))
+            if kind != "yarn":
+                raise ConfigError(f"rope_scaling type {kind!r}: yarn is the "
+                                  "one published form read")
+            d.update(scaling="yarn", **{
+                k: published[k] for k in (
+                    "factor", "beta_fast", "beta_slow", "mscale",
+                    "mscale_all_dim") if k in published})
+            d["original_max_position"] = published.get(
+                "original_max_position_embeddings", 4096)
         if not d:
             return cls()
         return cls(
             base=float(_take(d, "base", "theta", default=10000.0)),
             scaling=str(_take(d, "scaling", default="none")),
             scaling_factor=float(_take(d, "scaling_factor", "factor", default=1.0)),
+            original_max_position=int(_take(d, "original_max_position",
+                                            default=4096)),
+            beta_fast=float(_take(d, "beta_fast", default=32.0)),
+            beta_slow=float(_take(d, "beta_slow", default=1.0)),
+            mscale=float(_take(d, "mscale", default=1.0)),
+            mscale_all_dim=float(_take(d, "mscale_all_dim", default=0.0)),
         )
+
+
+@dataclass
+class MLAConfig:
+    """Multi-head latent attention (the published ``q_lora_rank`` ...
+    ``v_head_dim`` keys): queries through a low-rank bottleneck, keys and
+    values expanded from ONE compressed row of ``kv_lora_rank`` values a
+    token, plus ``qk_rope_head_dim`` rotated values shared by every head.
+    That row (``latent_size`` values) is all the cache keeps."""
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0           # 0 = the model has plain q / k / v
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    @property
+    def latent_size(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def page_width(self) -> int:
+        """The latent row as a page stores it: ``latent_size`` padded with
+        zeros to whole 128-lane tiles (576 -> 640). The chip's tiled layout
+        pads a 576-wide minor dimension to 640 whatever the program says;
+        stated here, the kernel's copies and matmuls are whole tiles and the
+        bytes a token are counted as they are moved."""
+        return -(-self.latent_size // 128) * 128
+
+    @classmethod
+    def from_dict(cls, d: dict[str, Any] | None) -> "MLAConfig":
+        d = d or {}
+        return cls(**{f.name: int(d.get(f.name, 0))
+                      for f in dataclasses.fields(cls)})
 
 
 @dataclass
@@ -140,9 +214,12 @@ class MoEConfig:
                 d, "selection_bias", default=routed)),
             routed_scaling_factor=float(_take(
                 d, "routed_scaling_factor", default=1.0)),
+            # (``n_shared_experts`` alone: each is one routed expert wide)
             shared_expert_size=int(_take(
                 d, "shared_expert_size",
-                "moe_shared_expert_intermediate_size", default=0))
+                "moe_shared_expert_intermediate_size", default=(
+                    d.get("moe_intermediate_size", 0)
+                    if d.get("n_shared_experts") else 0)))
             * int(_take(d, "n_shared_experts", default=1)),
             experts_per_token=int(_take(d, "experts_per_token", "top_k",
                                         "num_experts_per_tok", default=2)),
@@ -209,7 +286,9 @@ class SSMConfig:
 
 
 # what a layer of a layer table may be (``nemotron_h``'s own letters)
-LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "moe"}
+# ``D`` (this repo's letter): a dense gated MLP as a layer of its own, the
+# feed-forward of a leading dense layer before the expert layers
+LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "moe", "D": "mlp"}
 
 
 @dataclass
@@ -256,10 +335,49 @@ class ModelConfig:
     position_embedding: str = "rope"
     # False: the feed-forward is down(act(up(x))), two kernels (no gate)
     mlp_gated: bool = True
+    # latent attention (``*`` layers keep ONE compressed row a token)
+    mla: MLAConfig = field(default_factory=MLAConfig)
+    # width of a ``D`` layer's MLP (``intermediate_size`` beside
+    # ``moe_intermediate_size``); 0 = ``ffn_size``
+    dense_ffn_size: int = 0
+    # manifold-constrained hyper-connections: ``hc_mult`` residual streams
+    # (1 = the plain residual), each sub-layer reading a mix of them and
+    # writing back through three input-dependent maps, the stream-to-stream
+    # one made doubly stochastic by ``hc_sinkhorn_iters`` Sinkhorn-Knopp
+    # iterations of exp(clip(., ``hc_clamp_min``, ``hc_clamp_max``));
+    # ``hc_eps`` is the maps' RMSNorm's and the Sinkhorn denominators'
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp_min: float = -30.0
+    hc_clamp_max: float = 30.0
 
     @property
     def is_moe(self) -> bool:
         return self.moe.num_experts > 0
+
+    @property
+    def is_latent(self) -> bool:
+        return self.mla.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """Values of a head that rope rotates."""
+        return self.mla.qk_rope_head_dim if self.is_latent else self.head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        """What attention scores are multiplied by: head_dim^-0.5, times
+        YaRN's m^2 where the rope has it."""
+        return self.head_dim ** -0.5 * self.rope.softmax_mscale ** 2
+
+    def kv_bytes_per_token(self, itemsize: int = 2) -> int:
+        """Cache bytes one token costs over all the layers that keep any:
+        K and V of every kv head, or ONE padded latent row."""
+        if self.is_latent:
+            return self.kv_layers * self.mla.page_width * itemsize
+        return (2 * self.kv_layers * self.num_kv_heads * self.head_dim
+                * itemsize)
 
     def layers_of(self, kind: str) -> int:
         """How many layers of the table are ``kind`` (M | * | E)."""
@@ -310,7 +428,7 @@ class ModelConfig:
                 raise ConfigError(
                     f"layer_pattern {self.layer_pattern!r}: no layer kind "
                     f"{unknown} (known: M state-space, * attention, E "
-                    "experts)")
+                    "experts, D dense MLP)")
             if len(self.layer_pattern) != self.num_layers:
                 raise ConfigError(
                     f"layer_pattern has {len(self.layer_pattern)} layers, "
@@ -326,6 +444,30 @@ class ModelConfig:
                     "layer_pattern has M layers: ssm.num_heads must be a "
                     "positive multiple of ssm.n_groups, conv_kernel >= 2 "
                     f"(got {s})")
+        if self.is_latent or self.hc_mult > 1:
+            a = self.mla
+            if not self.layer_pattern:
+                raise ConfigError(
+                    "latent attention and hyper-connections live on the "
+                    "layer table: give layer_pattern (or the published "
+                    "num_hidden_layers / first_k_dense_replace)")
+            if self.is_latent and (
+                    min(a.q_lora_rank, a.qk_nope_head_dim, a.v_head_dim) < 1
+                    or a.qk_rope_head_dim < 2 or a.qk_rope_head_dim % 2
+                    or self.head_dim != a.qk_nope_head_dim
+                    + a.qk_rope_head_dim
+                    or self.num_kv_heads != self.num_heads):
+                raise ConfigError(
+                    "latent attention needs q_lora_rank, qk_nope_head_dim, "
+                    "v_head_dim >= 1, an even qk_rope_head_dim, head_dim = "
+                    "nope + rope and num_kv_heads = num_heads (got "
+                    f"{a}, head_dim {self.head_dim})")
+            if self.hc_mult < 1 or self.hc_sinkhorn_iters < 1:
+                raise ConfigError("hc_mult and hc_sinkhorn_iters must be "
+                                  ">= 1")
+        if self.rope.scaling not in ("none", "linear", "ntk", "yarn"):
+            raise ConfigError(f"rope scaling {self.rope.scaling!r}: "
+                              "none|linear|ntk|yarn")
         m = self.moe
         if self.is_moe and (
                 m.router_score not in ("softmax", "sigmoid")
@@ -361,6 +503,15 @@ class ModelConfig:
             # one norm and one mixer a layer; the experts HELD here
             s, m = self.ssm, self.moe
             per_expert = (3 if self.mlp_gated else 2) * h
+            a, n = self.mla, self.num_heads
+            if self.is_latent:
+                # q_a, its norm, q_b, kv_a, the latent's norm, kv_b, o
+                attn = (h * a.q_lora_rank + a.q_lora_rank
+                        + a.q_lora_rank * n * self.head_dim
+                        + h * a.latent_size + a.kv_lora_rank
+                        + a.kv_lora_rank * n
+                        * (a.qk_nope_head_dim + a.v_head_dim)
+                        + n * a.v_head_dim * h)
             mixer = {
                 "M": h * (2 * s.inner_size + 2 * s.n_groups * s.state_size
                           + s.num_heads)
@@ -371,8 +522,14 @@ class ModelConfig:
                 + (m.router_width if m.selection_bias else 0)
                 + m.num_experts * per_expert * f
                 + per_expert * m.shared_expert_size,
+                "D": per_expert * (self.dense_ffn_size or f),
             }
-            return (v * h + sum(h + mixer[k] for k in self.layer_pattern)
+            # a hyper-connection a sub-layer: the maps' norm, phi, three
+            # scalars, two bias vectors and a bias matrix
+            nc, k = self.hc_mult * h, self.hc_mult
+            hc = (nc + nc * (2 * k + k * k) + 3 + 2 * k + k * k
+                  if k > 1 else 0)
+            return (v * h + sum(h + hc + mixer[k_] for k_ in self.layer_pattern)
                     + h + (0 if self.tie_word_embeddings else v * h))
         if self.activation in ("silu", "gelu"):    # gated: w_gate, w_up, w_down
             mlp_dense = 3 * h * f
@@ -398,10 +555,38 @@ class ModelConfig:
         hidden = int(_take(d, "hidden", "hidden_size", "d_model", default=768))
         activation = str(_take(d, "activation", "hidden_act",
                                "mlp_hidden_act", default="silu"))
+        mla = MLAConfig.from_dict(d.get("mla") or d)
+        latent = mla.kv_lora_rank > 0
+        pattern = str(_take(d, "layer_pattern", "hybrid_override_pattern",
+                            default=""))
+        layers = int(_take(d, "layers", "num_layers", "num_hidden_layers",
+                           default=12))
+        if latent and not pattern and "num_hidden_layers" in d:
+            # a published config.json counts decoder layers: each is an
+            # attention sub-layer then a feed-forward one, two entries of
+            # the table; the first ``first_k_dense_replace`` feed-forwards
+            # are dense MLPs, the rest experts
+            dense = int(_take(d, "first_k_dense_replace", default=0))
+            pattern = "".join("*D" if i < dense else "*E"
+                              for i in range(layers))
+            layers = len(pattern)
+        for key in ("n_group", "topk_group"):
+            if latent and int(d.get(key, 1)) != 1:
+                raise ConfigError(
+                    f"{key} = {d[key]}: group-limited routing is not "
+                    "carried (the router takes its top-k over all experts)")
+        if int(d.get("num_nextn_predict_layers", 0)) > 0:
+            raise ConfigError(
+                f"num_nextn_predict_layers = {d['num_nextn_predict_layers']}"
+                ": the next-token prediction module is not served (no "
+                "drafter, no verification program over latent pages; "
+                "ROADMAP B7): state 0 to serve the model without it")
+        if latent:
+            d = dict(d, head_dim=mla.qk_nope_head_dim + mla.qk_rope_head_dim)
         cfg = cls(
             name=str(_take(d, "name", default="custom")),
             arch=str(_take(d, "arch", default="decoder-only")),
-            num_layers=int(_take(d, "layers", "num_layers", "num_hidden_layers", default=12)),
+            num_layers=layers,
             hidden_size=hidden,
             # (a model with ``moe_intermediate_size`` states ONE expert's
             # width under it)
@@ -414,7 +599,7 @@ class ModelConfig:
             vocab_size=int(_take(d, "vocab_size", default=50304)),
             max_position_embeddings=int(_take(d, "max_position_embeddings", "max_seq_len",
                                               default=2048)),
-            rope=RopeConfig.from_dict(d.get("rope")),
+            rope=RopeConfig.from_dict(d.get("rope"), d.get("rope_scaling")),
             activation=activation,
             norm_eps=float(_take(d, "layer_norm_eps", "norm_eps", "rms_norm_eps",
                                  "layer_norm_epsilon", default=1e-5)),
@@ -427,9 +612,21 @@ class ModelConfig:
             # (its ``intermediate_size`` is then ONE expert's width)
             moe=MoEConfig.from_dict(d.get("moe") or d),
             qk_norm=str(_take(d, "qk_norm", default="none")),
-            layer_pattern=str(_take(d, "layer_pattern",
-                                    "hybrid_override_pattern", default="")),
+            layer_pattern=pattern,
             ssm=SSMConfig.from_dict(d.get("ssm"), published=d),
+            mla=mla,
+            # (a leading dense layer's width: ``intermediate_size`` beside
+            # ``moe_intermediate_size`` where the file has such layers)
+            dense_ffn_size=int(_take(d, "dense_ffn_size", default=(
+                d.get("intermediate_size", 0)
+                if d.get("first_k_dense_replace") else 0))),
+            hc_mult=int(_take(d, "hc_mult", default=1)),
+            hc_sinkhorn_iters=int(_take(d, "hc_sinkhorn_iters", default=20)),
+            hc_eps=float(_take(d, "hc_eps", default=1e-6)),
+            hc_clamp_min=float(_take(d, "hc_clamp_min",
+                                     "mhc_h_res_clamp_min", default=-30.0)),
+            hc_clamp_max=float(_take(d, "hc_clamp_max",
+                                     "mhc_h_res_clamp_max", default=30.0)),
             position_embedding=str(_take(d, "position_embedding",
                                          default="rope")),
             # squared ReLU comes without a gate (``nemotron_h``'s
@@ -439,6 +636,19 @@ class ModelConfig:
         )
         cfg.validate()
         return cfg
+
+    @classmethod
+    def from_published(cls, config: dict[str, Any]) -> "ModelConfig":
+        """A model's published ``config.json`` (as the files under
+        ``benchmark/configs/`` hold it): its scalar keys, ``rope_theta`` and
+        the ``rope_scaling`` group; every other group (``serve``, ``reduced``
+        ...) is not the model's."""
+        d = {k: v for k, v in config.items()
+             if not isinstance(v, (dict, list))}
+        d["rope"] = {"base": config.get("rope_theta", 10000.0)}
+        if config.get("rope_scaling"):
+            d["rope_scaling"] = config["rope_scaling"]
+        return cls.from_dict(d)
 
     def to_dict(self) -> dict[str, Any]:
         d = dataclasses.asdict(self)

@@ -31,8 +31,8 @@ from .layers import (
     attend_dense_cache,
     attend_fresh,
     decoder_block,
+    model_rope_frequencies,
     rms_norm,
-    rope_frequencies,
 )
 
 Params = Any
@@ -119,6 +119,7 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
     Nq, Nkv = cfg.num_heads, cfg.num_kv_heads
     blocks = {}
     Lm, La, Le = cfg.ssm_layers, cfg.kv_layers, cfg.moe_layers
+    Ld = cfg.layers_of("D")
     if Lm:
         s = cfg.ssm
         nh, d_in, C, K = s.num_heads, s.inner_size, s.conv_channels, \
@@ -146,7 +147,23 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
             "out_proj": {"kernel": dense(next(keys), Lm, d_in, H,
                                          scale=resid_std)},
         }
-    if La:
+    if La and cfg.is_latent:
+        a = cfg.mla
+        blocks["attn"] = {
+            "norm": {"scale": norm_init(La, H)},
+            "q_a": {"kernel": dense(next(keys), La, H, a.q_lora_rank)},
+            "q_a_norm": {"scale": norm_init(La, a.q_lora_rank)},
+            "q_b": {"kernel": dense(next(keys), La, a.q_lora_rank, Nq * D)},
+            "kv_a": {"kernel": dense(next(keys), La, H, a.latent_size)},
+            "kv_norm": {"scale": norm_init(La, a.kv_lora_rank)},
+            # a head's [k_nope | v] side by side, as the published kv_b_proj
+            "kv_b": {"kernel": dense(
+                next(keys), La, a.kv_lora_rank,
+                Nq * (a.qk_nope_head_dim + a.v_head_dim))},
+            "o": {"kernel": dense(next(keys), La, Nq * a.v_head_dim, H,
+                                  scale=resid_std)},
+        }
+    elif La:
         blocks["attn"] = {
             "norm": {"scale": norm_init(La, H)},
             "q": {"kernel": dense(next(keys), La, H, Nq * D)},
@@ -178,6 +195,32 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
             moe["shared"]["down"] = {"kernel": dense(next(keys), Le, Fs, H,
                                                      scale=resid_std)}
         blocks["moe"] = moe
+    if Ld:
+        Fd = cfg.dense_ffn_size or F
+        names = ("gate", "up") if cfg.mlp_gated else ("up",)
+        blocks["mlp"] = {"norm": {"scale": norm_init(Ld, H)}, **{
+            n: {"kernel": dense(next(keys), Ld, H, Fd)} for n in names}}
+        blocks["mlp"]["down"] = {"kernel": dense(next(keys), Ld, Fd, H,
+                                                 scale=resid_std)}
+    if cfg.hc_mult > 1:
+        # a hyper-connection a sub-layer, float32. The maps start visibly
+        # NOT the identity: phi's product has a spread of ~0.6 under a = 0.25
+        # (x_hat has unit entries, phi 0.02 over n*H of them), H_pre ~ 1/2 a
+        # stream, H_post ~ 1, H_res doubly stochastic with a diagonal
+        # preferred e : 1
+        n, nH = cfg.hc_mult, cfg.hc_mult * H
+        for stack in blocks.values():
+            L = stack["norm"]["scale"].shape[0]
+            stack["hc"] = {
+                "norm": {"scale": jnp.zeros((L, nH), jnp.float32)},
+                "phi": {"kernel": dense(next(keys), L, nH, 2 * n + n * n
+                                        ).astype(jnp.float32)},
+                "a": jnp.full((L, 3), 0.25, jnp.float32),
+                "b_pre": jnp.zeros((L, n), jnp.float32),
+                "b_post": jnp.zeros((L, n), jnp.float32),
+                "b_res": jnp.broadcast_to(jnp.eye(n, dtype=jnp.float32),
+                                          (L, n, n)),
+            }
     return blocks
 
 
@@ -254,9 +297,11 @@ _KEPT_FLOAT32 = (("ssm", "dt_bias"), ("ssm", "A_log"), ("ssm", "D"),
 def cast_table_blocks(blocks: Params, dtype) -> Params:
     """``precast_params`` for the blocks of a layer table: every plain leaf
     to the compute dtype but the state-space layers' ``dt_bias`` / ``A_log``
-    / ``D`` and the router's selection ``bias``, which stay as stored."""
+    / ``D``, the router's selection ``bias`` and a hyper-connection's
+    parameters (``hc``: its maps are float32), which stay as stored."""
     def one(path, x):
-        kept = tuple(k.key for k in path) in _KEPT_FLOAT32
+        names = tuple(k.key for k in path)
+        kept = names in _KEPT_FLOAT32 or "hc" in names
         return x if kept else x.astype(dtype)
     return jax.tree_util.tree_map_with_path(one, blocks)
 
@@ -280,8 +325,11 @@ def _block_fn(cfg: ModelConfig, attn_impl: str, norm_impl: str,
         kind=kind, recur=recur)
     if aux is None:
         aux = jnp.float32(0.0)
-    # anchor GSPMD propagation at the block boundary (no-op off-mesh)
+    # anchor GSPMD propagation at the block boundary (no-op off-mesh;
+    # residual STREAMS [B, S, n, H] run on one chip and are left alone)
     from ..parallel.sharding import constrain
+    if x.ndim == 4:
+        return x, new_cache, aux
     return constrain(x, "activations"), new_cache, aux
 
 
@@ -343,6 +391,7 @@ def forward(
     moe_impl: str = "dropless",      # dropless | capacity (training)
     return_moe_stats: bool = False,
     return_ssm_state: bool = False,
+    return_latent: bool = False,
 ):
     """Compute logits [B, S, V] (fp32) — or, with ``return_hidden=True``,
     the final-normed hidden states [B, S, H] in the compute dtype (consumed
@@ -373,6 +422,13 @@ def forward(
       the live tokens) out of it; ``return_ssm_state`` appends
       (conv tails [Lm, B, K-1, C], states [Lm, B, nh, P, N] float32) after
       the last live token: what cold prefill arms a slot with.
+    - a model with LATENT attention keeps no dense cache (``kv_cache`` is
+      refused): its window attends in the expanded form over its own
+      tokens, and ``return_latent`` appends the rows a cache would keep,
+      [La, B, S, kv_lora_rank + qk_rope_head_dim], which cold prefill
+      writes to the latent pages. With ``cfg.hc_mult`` > 1 the residual is
+      ``hc_mult`` streams: copies of the embedding at the start, summed
+      before the final norm.
     """
     compute_dtype = jnp.dtype(cfg.dtype)
     B, S = tokens.shape
@@ -385,8 +441,7 @@ def forward(
     emb = params["embed"]["embedding"]
     x = constrain(emb[tokens].astype(compute_dtype), "activations")
 
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope.base,
-                                cfg.rope.scaling, cfg.rope.scaling_factor)
+    inv_freq = model_rope_frequencies(cfg)
 
     if moe_impl not in ("dropless", "capacity"):
         raise ValueError(f"moe_impl must be dropless|capacity: {moe_impl!r}")
@@ -400,6 +455,12 @@ def forward(
     if return_ssm_state and not cfg.is_recurrent:
         raise ValueError("return_ssm_state needs a model with state-space "
                          "layers")
+    if cfg.is_latent and kv_cache is not None or (
+            return_latent and not cfg.is_latent):
+        raise ValueError(
+            "a model with latent attention keeps no dense K/V cache (ask for "
+            "return_latent and write the rows to the latent pages); "
+            "return_latent needs such a model")
     if cfg.layer_pattern:
         if moe_impl != "dropless" or remat != "none":
             # training's capacity route drops tokens, the grouped matmul
@@ -407,12 +468,17 @@ def forward(
             raise ValueError(
                 "a model with a layer table runs the dropless inference "
                 "forward only (moe_impl='dropless', remat='none')")
+        if cfg.hc_mult > 1:
+            x = jnp.broadcast_to(x[:, :, None], (B, S, cfg.hc_mult,
+                                                 cfg.hidden_size))
         x, new_cache, aux_total, ssm_state = _walk_table(
             params, x, cfg, positions, segment_ids, inv_freq, kv_cache,
             cache_offset, attn_impl, norm_impl, compute_dtype)
+        if cfg.hc_mult > 1:
+            x = jnp.sum(x.astype(jnp.float32), axis=2).astype(compute_dtype)
         return _finish_forward(
             params, x, cfg, norm_impl, unembed_positions, return_hidden,
-            [new_cache] * (kv_cache is not None)
+            [new_cache] * (kv_cache is not None or return_latent)
             + [aux_total] * return_moe_stats
             + [ssm_state] * return_ssm_state)
 
@@ -482,13 +548,14 @@ def _walk_table(params, x, cfg: ModelConfig, positions, segment_ids,
     """The layers of a layer table over the residual stream ``x``: a Python
     loop over ``table_layers`` (no scan: the kinds' parameter shapes
     differ), each layer one ``decoder_block`` of its kind. Returns (x, the
-    attention layers' updated dense cache or None, the summed
-    ``moe_stats``, (conv tails, states) of the state-space layers)."""
+    attention layers' updated dense cache (a latent model's rows
+    [La, B, S, latent]) or None, the summed ``moe_stats``, (conv tails,
+    states) of the state-space layers)."""
     from ..ops.ssm import recur_window
     blocks = cast_table_blocks(params["blocks"], compute_dtype)
     recur = recur_window(cfg, segment_ids)
     aux_total = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
-    caches, tails, states = [], [], []
+    caches, tails, states, latents = [], [], [], []
     for kind, i in table_layers(cfg):
         cache = (None if kv_cache is None or kind != "*"
                  else (kv_cache[0][i], kv_cache[1][i]))
@@ -502,9 +569,11 @@ def _walk_table(params, x, cfg: ModelConfig, positions, segment_ids,
             states.append(state[1])
         elif kind == "*" and cache is not None:
             caches.append(state)
+        elif kind == "*" and cfg.is_latent:
+            latents.append(state)
         elif kind == "E":
             aux_total = aux_total + aux
-    new_cache = None
+    new_cache = jnp.stack(latents) if latents else None
     if caches:
         new_cache = (jnp.stack([c[0] for c in caches]),
                      jnp.stack([c[1] for c in caches]))

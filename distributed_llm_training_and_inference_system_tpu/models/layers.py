@@ -46,17 +46,41 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5,
 # ---------------------------------------------------------------------------
 
 def rope_frequencies(head_dim: int, base: float = 10000.0,
-                     scaling: str = "none", factor: float = 1.0) -> jax.Array:
-    """Inverse frequencies for RoPE [head_dim//2], fp32."""
+                     scaling: str = "none", factor: float = 1.0,
+                     yarn=None) -> jax.Array:
+    """Inverse frequencies for RoPE [head_dim//2], fp32. ``yarn`` (the
+    model's ``RopeConfig``, read under ``scaling="yarn"``): frequency i is
+    f_i below the correction dim of ``beta_fast`` rotations over
+    ``original_max_position``, f_i / factor above that of ``beta_slow``,
+    and the linear blend between."""
     exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
     inv_freq = 1.0 / (base ** exponent)
-    if scaling == "linear" and factor != 1.0:
+    if scaling == "yarn" and factor != 1.0:
+        import math
+
+        def correction_dim(rotations):
+            return (head_dim * math.log(yarn.original_max_position
+                                        / (rotations * 2 * math.pi))
+                    / (2 * math.log(base)))
+        low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+        ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        inv_freq = inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
+    elif scaling == "linear" and factor != 1.0:
         inv_freq = inv_freq / factor
     elif scaling == "ntk" and factor != 1.0:
         # NTK-aware: stretch the base instead of the positions
         adjusted = base * (factor ** (head_dim / max(head_dim - 2, 1)))
         inv_freq = 1.0 / (adjusted ** exponent)
     return inv_freq
+
+
+def model_rope_frequencies(cfg: ModelConfig) -> jax.Array:
+    """``rope_frequencies`` of the values ``cfg``'s attention rotates: the
+    whole head, or a latent-attention head's ``qk_rope_head_dim``."""
+    return rope_frequencies(cfg.rope_dim, cfg.rope.base, cfg.rope.scaling,
+                            cfg.rope.scaling_factor, yarn=cfg.rope)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array) -> jax.Array:
@@ -101,6 +125,8 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Reference XLA attention. q:[B,Sq,Nq,D] k,v:[B,Skv,Nkv,D] -> [B,Sq,Nq,D].
 
     GQA: Nq must be a multiple of Nkv; kv heads are broadcast per group.
+    v's head size may differ from q's and k's (latent attention: 192 for
+    the scores, 128 for the values); the output has v's.
     Softmax in fp32 (the flash/pallas path in ops/attention.py matches these
     numerics and is validated against this function in tests).
     """
@@ -116,7 +142,7 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(B, Sq, Nq, D).astype(q.dtype)
+    return out.reshape(B, Sq, Nq, v.shape[-1]).astype(q.dtype)
 
 
 def _flash_on_mesh(q, k, v, segment_ids):
@@ -558,10 +584,13 @@ def decoder_block(
     ``kind`` None is a layer of the uniform stack: attention THEN
     feed-forward under two norms (``attn_norm``, ``mlp_norm``). A layer of
     a layer table (``cfg.layer_pattern``) is ONE norm (``layer["norm"]``)
-    and ONE mixer, chosen by ``kind``: ``*`` the same attention, ``E`` the
-    same experts (plus the shared expert), ``M`` the Mamba-2 mixer, whose
-    state lives where ``recur`` says, as K and V live where ``attend``
-    says (``ssm_mixer``).
+    and ONE mixer, chosen by ``kind``: ``*`` the same attention (or, for a
+    model with latent attention, ``latent_attention_mixer``), ``E`` the
+    same experts (plus the shared expert), ``D`` the dense ``mlp_block``,
+    ``M`` the Mamba-2 mixer, whose state lives where ``recur`` says, as K
+    and V live where ``attend`` says (``ssm_mixer``). With
+    ``cfg.hc_mult`` > 1 the residual is ``hc_mult`` streams ([B, S, n, H])
+    and the table's residual rule is the hyper-connection (``hc_maps``).
 
     Returns (x, the mixer's state (``attend``'s or ``recur``'s; None for
     an expert layer), what the caller sums over the layers: None for a
@@ -569,19 +598,30 @@ def decoder_block(
     loss for the capacity route).
     """
     if kind is not None:
-        # a layer of a layer table: ONE norm, ONE mixer
-        h = rms_norm(x, layer["norm"]["scale"], cfg.norm_eps, impl=norm_impl)
+        # a layer of a layer table: ONE norm, ONE mixer. With residual
+        # STREAMS (``cfg.hc_mult`` > 1: x is [B, S, n, H]) the sub-layer
+        # reads a mix of them and its output goes back through the
+        # hyper-connection's maps; otherwise the plain ``x + out``.
+        maps = hc_maps(x, layer["hc"], cfg) if cfg.hc_mult > 1 else None
+        u = x if maps is None else hc_read(x, maps)
+        h = rms_norm(u, layer["norm"]["scale"], cfg.norm_eps, impl=norm_impl)
         state = aux = None
         if kind == "M":
             out, state = ssm_mixer(h, layer, cfg, recur, matmul)
         elif kind == "*":
-            out, state = attention_mixer(h, layer, cfg, positions, inv_freq,
-                                         attend, matmul)
+            mixer = (latent_attention_mixer if cfg.is_latent
+                     else attention_mixer)
+            out, state = mixer(h, layer, cfg, positions, inv_freq, attend,
+                               matmul)
         elif kind == "E":
             out, aux = experts_mixer(h, layer, cfg, live, moe_impl,
                                      layer_index, matmul)
+        elif kind == "D":
+            out = mlp_block(h, layer, cfg, matmul=matmul)
         else:
             raise ValueError(f"no layer kind {kind!r}")
+        if maps is not None:
+            return hc_write(x, out, maps), state, aux
         return x + out.astype(x.dtype), state, aux
 
     h = rms_norm(x, layer["attn_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
@@ -627,6 +667,130 @@ def attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,
     # which re-runs the whole O(S^2) flash forward inside the backward pass
     # (the name lowers to nothing in a program that takes no gradient)
     return checkpoint_name(out.astype(h.dtype), "attn_out"), state
+
+
+def latent_attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,
+                           positions: jax.Array, inv_freq: jax.Array, attend,
+                           matmul=dense_matmul) -> tuple[jax.Array, Any]:
+    """Multi-head latent attention over the normed stream ``h`` [B, S, H]:
+
+        c_q           = RMSNorm(h W_qa)
+        [q_nope|q_pe] = c_q W_qb   a head;   q_pe = rope(q_pe)
+        [c_kv|k_pe]   = h W_kva;   c_kv = RMSNorm(c_kv);  k_pe = rope(k_pe)
+        [k_nope|v]    = c_kv W_kvb  a head
+
+    scores (q_nope . k_nope + q_pe . k_pe) * ``cfg.softmax_scale``, rope
+    over the ``pe`` values alone (halves paired, as ``apply_rope``). What a
+    cache keeps of a token is the LATENT row [c_kv | k_pe], for all heads.
+
+    ``attend`` comes in two kinds, told by ``attend.latent``. Unset
+    (``attend_fresh``: training-style callers and cold prefill): the
+    EXPANDED form, q / k [B, S, N, nope + rope] and v [B, S, N, v] as
+    ``attention_mixer`` hands them; the state returned is then the latent
+    rows [B, S, latent], which cold prefill writes to the pages. Set
+    (serve/decode.py, over the latent page pool): the ABSORBED form, the
+    same mathematics with W_kvb folded into the query and the output,
+    ``attend(q~ [B, S, N, kv_rank + rope], rows [B, S, latent], scale)``
+    -> (o~ [B, S, N, kv_rank], state), so a page is read once and serves as
+    keys and as values.
+    """
+    B, S, _ = h.shape
+    a, N = cfg.mla, cfg.num_heads
+    dn, dr, dv, r = (a.qk_nope_head_dim, a.qk_rope_head_dim, a.v_head_dim,
+                     a.kv_lora_rank)
+    with jax.named_scope("mla_q_proj"):
+        c_q = rms_norm(matmul(h, layer["q_a"]["kernel"]),
+                       layer["q_a_norm"]["scale"], cfg.norm_eps)
+        q = matmul(c_q, layer["q_b"]["kernel"]).reshape(B, S, N, dn + dr)
+        q_nope, q_pe = q[..., :dn], apply_rope(q[..., dn:], positions,
+                                               inv_freq)
+    with jax.named_scope("mla_kv_compress"):
+        ckv = matmul(h, layer["kv_a"]["kernel"])
+        c_kv = rms_norm(ckv[..., :r], layer["kv_norm"]["scale"], cfg.norm_eps)
+        k_pe = apply_rope(ckv[..., None, r:], positions, inv_freq)[..., 0, :]
+        rows = jnp.concatenate([c_kv, k_pe], axis=-1)            # [B,S,r+dr]
+    w_kvb = layer["kv_b"]["kernel"]                  # [r, N * (dn + dv)]
+    if getattr(attend, "latent", False):
+        w = w_kvb.reshape(r, N, dn + dv)
+        with jax.named_scope("mla_absorb"):
+            q_lat = jnp.concatenate(
+                [jnp.einsum("bsnd,rnd->bsnr", q_nope, w[..., :dn]), q_pe],
+                axis=-1)
+        o_lat, state = attend(q_lat, rows, cfg.softmax_scale)
+        with jax.named_scope("mla_absorb"):
+            out = jnp.einsum("bsnr,rnd->bsnd", o_lat.astype(h.dtype),
+                             w[..., dn:])
+    else:
+        kv = matmul(c_kv, w_kvb).reshape(B, S, N, dn + dv)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_pe[:, :, None], (B, S, N, dr))],
+            axis=-1)
+        # ``dot_product_attention`` divides by sqrt(head_dim) itself: what
+        # is left of the scale (YaRN's m^2) goes onto the query
+        m2 = cfg.rope.softmax_mscale ** 2
+        qf = jnp.concatenate([q_nope, q_pe], axis=-1)
+        out, state = attend(qf if m2 == 1.0 else (qf * m2).astype(qf.dtype),
+                            k, kv[..., dn:])
+        state = rows if state is None else state
+    out = matmul(out.reshape(B, S, N * dv), layer["o"]["kernel"])
+    return checkpoint_name(out.astype(h.dtype), "attn_out"), state
+
+
+def hc_maps(x: jax.Array, hc: Params, cfg: ModelConfig
+            ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The three maps of a manifold-constrained hyper-connection from the
+    residual streams ``x`` [B, S, n, C], in float32 (a 4 x 4 Sinkhorn in
+    bfloat16 is not the mechanism):
+
+        x_hat   = RMSNorm(vec(X))                    over all n * C values
+        [p|q|R] = x_hat phi                          phi [n*C, n + n + n*n]
+        H_pre   = sigmoid(a_pre p + b_pre)           [n]
+        H_post  = 2 sigmoid(a_post q + b_post)       [n]
+        H_res   = Sinkhorn(exp(clip(a_res mat(R) + b_res, lo, hi)))  [n, n]
+
+    ``cfg.hc_sinkhorn_iters`` times rows then columns divided by their sums
+    (+ ``cfg.hc_eps``, also the norm's epsilon): doubly stochastic,
+    ``H_res[i, j]`` weighs stream j into stream i. Returns (H_pre [B,S,n],
+    H_post [B,S,n], H_res [B,S,n,n])."""
+    B, S, n, C = x.shape
+    eps = cfg.hc_eps
+    with jax.named_scope("hc_maps"):
+        xf = x.reshape(B, S, n * C).astype(jnp.float32)
+        x_hat = (xf * jax.lax.rsqrt(
+            jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+            * (1.0 + hc["norm"]["scale"].astype(jnp.float32)))
+        pqr = jnp.einsum("bsk,km->bsm", x_hat,
+                         hc["phi"]["kernel"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        a = hc["a"].astype(jnp.float32)
+        h_pre = jax.nn.sigmoid(a[0] * pqr[..., :n] + hc["b_pre"])
+        h_post = 2.0 * jax.nn.sigmoid(a[1] * pqr[..., n:2 * n] + hc["b_post"])
+        m = jnp.exp(jnp.clip(
+            a[2] * pqr[..., 2 * n:].reshape(B, S, n, n) + hc["b_res"],
+            cfg.hc_clamp_min, cfg.hc_clamp_max))
+        for _ in range(cfg.hc_sinkhorn_iters):
+            m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+            m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return h_pre, h_post, m
+
+
+def hc_read(x: jax.Array, maps) -> jax.Array:
+    """What a sub-layer reads of the streams: ``H_pre @ X`` [B, S, C]."""
+    # (n is 4: a multiply and a sum over the streams on the vector unit in
+    # float32, not a matmul whose operands the chip would round)
+    with jax.named_scope("hc_mix"):
+        return jnp.sum(maps[0][..., None] * x.astype(jnp.float32),
+                       axis=2).astype(x.dtype)
+
+
+def hc_write(x: jax.Array, out: jax.Array, maps) -> jax.Array:
+    """The streams after a sub-layer whose output is ``out`` [B, S, C]:
+    ``H_res @ X + outer(H_post, out)`` [B, S, n, C]."""
+    with jax.named_scope("hc_mix"):
+        mixed = jnp.sum(maps[2][..., None]
+                        * x.astype(jnp.float32)[:, :, None], axis=3)
+        return (mixed + maps[1][..., None]
+                * out.astype(jnp.float32)[:, :, None, :]).astype(x.dtype)
 
 
 def experts_mixer(h: jax.Array, moe: Params, cfg: ModelConfig, live,

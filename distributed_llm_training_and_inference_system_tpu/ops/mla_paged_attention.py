@@ -1,0 +1,252 @@
+"""Paged attention over LATENT pages (multi-head latent attention, absorbed
+form): every query head of a slot scores the same page of latent rows, and
+the page's first ``value_width`` values are the values too.
+
+The cache of a latent-attention model is ONE pool [L, NP, 1, PS, W]: a row
+is [c_kv (kv_lora_rank) | rope(k_pe)], zero-padded to W = whole 128-lane
+tiles (576 -> 640; ``MLAConfig.page_width`` says why). The absorbed query
+``q~`` [B, T, N, W] carries ``q_nope W_kvb[k]^T`` beside ``q_pe`` (and zeros
+over the padding), so
+
+    s[b, t, n, j] = q~[b, t, n] . row[b, j] * scale        j <= start_b + t
+    o~[b, t, n]   = softmax_j(s) @ row[b, :, :value_width]
+
+``mla_paged_attention`` dispatches like ``ops.paged_attention``: the Pallas
+kernel on a TPU (``mla_paged_attention`` for one query a slot,
+``mla_paged_attention_mq`` for a window), the gather twin elsewhere.
+
+The kernel is PR 28's live-page walk (ops/paged_attention_pallas.py) over
+one pool: grid (slots, query tiles), the pool whole in HBM, a ring of VMEM
+page buffers whose copies run ``_PAGES_AHEAD`` pages ahead in grid order and
+across grid steps, the loop's trip count the slot's live pages. What differs:
+one copy a page (not K and V), no head folding (all N heads' rows are the
+query tile: [tile * N, W] against the page's [PS, W], no cross-head mask),
+matmul operands in the pool's dtype with float32 accumulation, and the
+value product over the page's first ``value_width`` lanes.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..models.layers import NEG_INF
+from ..utils.platform import kernel_impl, report_impl
+from .paged_attention import _at
+
+
+def mla_paged_attention(
+    q: jax.Array,              # [B, T, N, W] absorbed queries (zero-padded)
+    pool: jax.Array,           # [L, NP, 1, PS, W] ([NP, 1, PS, W], no layer)
+    block_tables: jax.Array,   # [B, maxP] int32
+    start_positions: jax.Array,  # [B] int32: position of q[:, 0]
+    *,
+    scale: float,
+    value_width: int,
+    impl: str = "auto",        # auto | pallas | gather
+    layer=None,
+) -> jax.Array:
+    """Returns o~ [B, T, N, value_width]; query t of slot b attends causally
+    over [0, start_b + t] through the pages (the window's own rows must
+    already be written)."""
+    on_tpu = jax.default_backend() == "tpu"
+    op = "mla_paged_attention" if q.shape[1] == 1 else \
+        "mla_paged_attention_mq"
+    detail = f"q{tuple(q.shape)}"
+    if impl == "auto":
+        impl = "pallas" if on_tpu else "gather"
+        if not on_tpu:
+            detail += f", backend {jax.default_backend()}"
+    else:
+        detail += ", requested by caller"
+    report_impl(op, kernel_impl() if impl == "pallas" else impl, detail)
+    if impl == "pallas":
+        return mla_paged_attention_pallas(
+            q, pool, block_tables, start_positions, scale=scale,
+            value_width=value_width, layer=layer, interpret=not on_tpu)
+    return _gather(q, pool, block_tables, start_positions, scale,
+                   value_width, layer)
+
+
+def _gather(q, pool, block_tables, start_positions, scale, value_width,
+            layer):
+    """The XLA twin: each slot's rows gathered through its block table, then
+    plain masked attention in float32 statistics."""
+    B, T, N, W = q.shape
+    PS = pool.shape[-2]
+    maxP = block_tables.shape[1]
+    rows = pool[_at(layer, block_tables)].reshape(B, maxP * PS, W)
+    s = jnp.einsum("btnw,bkw->bntk", q, rows.astype(q.dtype),
+                   preferred_element_type=jnp.float32) * scale
+    q_pos = start_positions[:, None] + jnp.arange(T, dtype=jnp.int32)
+    seen = jnp.arange(maxP * PS, dtype=jnp.int32)[None, None] \
+        <= q_pos[:, :, None]                                  # [B, T, K]
+    s = jnp.where(seen[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bntk,bkr->btnr", p,
+                     rows[..., :value_width].astype(q.dtype),
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+# how many pages the copies run ahead (the ring holds one buffer more): PR
+# 28's value for K/V pages; the page-size sweep of experiments/
+# mla_kernel_alone.py is made at it
+_PAGES_AHEAD = 3
+# query rows (tokens x heads) a grid step holds: the float32 accumulator
+# [rows, value_width] is 2 MB at 1,024 x 512, the score tile [rows, PS] 1 MB
+# at pages of 256
+_MAX_QUERY_ROWS = 1024
+
+
+def _kernel(tables_ref, starts_ref, layer_ref,          # scalar prefetch
+            q_ref, pool_hbm, o_ref, acc_ref, m_ref, l_ref, buf, sems,
+            ring_ref, *, page_size: int, scale: float, heads: int,
+            window: int, queries: int, value_width: int):
+    """One grid step: one slot's query tile ([window * heads, W], token
+    major) against that slot's LIVE latent pages. The ring's bookkeeping is
+    ops/paged_attention_pallas.py ``_extend_kernel``'s."""
+    b, t = pl.program_id(0), pl.program_id(1)
+    n_slots, n_tiles = pl.num_programs(0), pl.num_programs(1)
+    max_pages = tables_ref.shape[1]
+    n_bufs = buf.shape[0]
+    layer = layer_ref[0]
+
+    def visible(slot, tile):
+        return starts_ref[slot] + jnp.minimum((tile + 1) * window, queries)
+
+    def live_pages(slot, tile):
+        return jnp.clip((visible(slot, tile) + page_size - 1) // page_size,
+                        1, max_pages)
+
+    def page_copy(slot, p, i):
+        return pltpu.make_async_copy(
+            pool_hbm.at[layer, tables_ref[slot, p]], buf.at[i], sems.at[i])
+
+    def next_buffer(i):
+        return jnp.where(i + 1 == n_bufs, 0, i + 1)
+
+    def fetch_next(lead):
+        slot, tile, p, i = lead
+
+        @pl.when(slot < n_slots)
+        def _start():
+            page_copy(slot, p, i).start()
+
+        tile_done = p + 1 >= live_pages(jnp.minimum(slot, n_slots - 1), tile)
+        slot_done = tile_done & (tile + 1 >= n_tiles)
+        return (jnp.where(slot_done, slot + 1, slot),
+                jnp.where(slot_done, 0, jnp.where(tile_done, tile + 1, tile)),
+                jnp.where(tile_done, 0, p + 1),
+                next_buffer(i))
+
+    @pl.when((b == 0) & (t == 0))
+    def _prime():
+        lead = (jnp.int32(0),) * 4
+        for _ in range(n_bufs - 1):
+            lead = fetch_next(lead)
+        for i, x in enumerate((*lead, jnp.int32(0))):
+            ring_ref[i] = x
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    start = starts_ref[b] + t * window
+    max_len = visible(b, t)
+
+    def score_page(p, i):
+        page = buf[i, 0]                                     # [PS, W]
+        s = jax.lax.dot_general(
+            q_ref[...].astype(page.dtype), page, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # [rows, PS]
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(p * page_size + col <= start + row // heads, s,
+                      NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p_ = jnp.exp(jnp.where(m_new > NEG_INF / 2, s - m_new, NEG_INF))
+        alpha = jnp.exp(jnp.where(m_new > NEG_INF / 2, m_prev - m_new, 0.0))
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p_, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p_.astype(page.dtype), page[:, :value_width],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    def one_page(p, ring):
+        *lead, i = ring
+        lead = fetch_next(lead)
+        page_copy(b, p, i).wait()
+
+        @pl.when(p * page_size < max_len)       # false only at length 0
+        def _score():
+            score_page(p, i)
+
+        return (*lead, next_buffer(i))
+
+    ring = jax.lax.fori_loop(0, live_pages(b, t), one_page,
+                             tuple(ring_ref[i] for i in range(5)))
+    for i, x in enumerate(ring):
+        ring_ref[i] = x
+
+    l = l_ref[...]
+    o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+def mla_paged_attention_pallas(q, pool, block_tables, start_positions, *,
+                               scale: float, value_width: int, layer=None,
+                               interpret: bool = False) -> jax.Array:
+    """The kernel behind ``mla_paged_attention``; same contract."""
+    B, T_in, N, W = q.shape
+    PS = pool.shape[-2]
+    if layer is None:
+        pool, layer = pool[None], 0
+
+    # a long window (suffix / chunked prefill) is tiled along the queries:
+    # each tile an independent online-softmax pass over the slot's pages
+    tile = T_in if T_in * N <= _MAX_QUERY_ROWS else max(
+        _MAX_QUERY_ROWS // N, 1)
+    T = -(-T_in // tile) * tile
+    if T != T_in:
+        q = jnp.pad(q, ((0, 0), (0, T - T_in), (0, 0), (0, 0)))
+    rows = tile * N
+    q_spec = pl.BlockSpec((None, rows, W),
+                          lambda b, t, tbl, st, ly: (b, t, 0))
+    o_spec = pl.BlockSpec((None, rows, value_width),
+                          lambda b, t, tbl, st, ly: (b, t, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,       # tables, starts, layer
+        grid=(B, T // tile),
+        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=o_spec,
+        scratch_shapes=[
+            pltpu.VMEM((rows, value_width), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((_PAGES_AHEAD + 1, *pool.shape[2:]), pool.dtype),
+            pltpu.SemaphoreType.DMA((_PAGES_AHEAD + 1,)),
+            pltpu.SMEM((5,), jnp.int32),
+        ],
+    )
+    name = "mla_paged_attention" if T_in == 1 else "mla_paged_attention_mq"
+    with jax.named_scope(name):
+        out = pl.pallas_call(
+            functools.partial(_kernel, page_size=PS, scale=scale, heads=N,
+                              window=tile, queries=T_in,
+                              value_width=value_width),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, T * N, value_width), q.dtype),
+            # the ring of page copies runs across grid steps: in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret,
+            name=name,
+        )(block_tables.astype(jnp.int32), start_positions.astype(jnp.int32),
+          jnp.asarray(layer, jnp.int32).reshape(1),
+          q.reshape(B, T * N, W), pool)
+    return out.reshape(B, T, N, value_width)[:, :T_in]

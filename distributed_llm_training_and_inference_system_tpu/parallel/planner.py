@@ -704,6 +704,10 @@ class ServePlanner:
 
     def page_bytes(self, page_size: int, kv_quant: str = "none") -> float:
         m = self.model
+        if m.is_latent:
+            # ONE latent row a token a layer (serve/kv_cache.py), in bf16:
+            # quantised latent pages are refused
+            return page_size * m.kv_bytes_per_token(int(BYTES_BF16))
         if kv_quant == "int8":
             return 2 * m.kv_layers * page_size * m.num_kv_heads \
                 * (m.head_dim + 4)
@@ -777,6 +781,11 @@ class ServePlanner:
                  quant: str = "none", kv_quant: str = "none",
                  tensor_parallel: int = 1) -> ServePlan:
         hw, m = self.hw, self.model
+        if m.is_latent and kv_quant != "none":
+            raise ValueError(
+                f"{m.name} keeps latent pages: kv_quantization {kv_quant} "
+                "is refused (serve/kv_cache.py has no quantised layout for "
+                "a latent row; ROADMAP B4)")
         tp = max(tensor_parallel, 1)
         wb = self.weight_bytes(quant) / tp
         hbm = hw.hbm_gb_per_chip * 1e9

@@ -26,7 +26,7 @@ from ..models.gpt import (
     table_layers,
     unembed,
 )
-from ..models.layers import decoder_block, rope_frequencies
+from ..models.layers import decoder_block, model_rope_frequencies
 from ..ops.paged_attention import (
     paged_attention_multi,
     write_window_to_pages,
@@ -104,6 +104,8 @@ def extend_step_forward(
     with state-space layers takes ``ssm_state`` and returns it LAST,
     advanced in place for the rows ``write_ok`` marks: its K/V pools hold
     the attention layers alone ([La, NP, ...]) and it takes T = 1 only.
+    A model with LATENT attention keeps ONE pool: ``k_pages`` is the latent
+    pool [La, NP, 1, PS, W] and ``v_pages`` is None, handed through.
 
     Token j sits at position ``start_positions + j`` and attends causally
     over the paged prefix *including* earlier tokens of this same call: all
@@ -127,8 +129,7 @@ def extend_step_forward(
     positions = start_positions[:, None] + jnp.arange(T, dtype=jnp.int32)
 
     x = params["embed"]["embedding"][tokens].astype(compute_dtype)  # [B,T,H]
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope.base,
-                                cfg.rope.scaling, cfg.rope.scaling_factor)
+    inv_freq = model_rope_frequencies(cfg)
 
     # W4A16 weights go through the in-kernel-dequant Pallas matmul on the
     # TPU: the XLA dequant chain writes the whole bf16 tensor to HBM and
@@ -209,13 +210,25 @@ def extend_step_forward(
                              "ssm_state pools")
         blocks = cast_table_blocks(params["blocks"], compute_dtype)
         kp, vp = k_pages, v_pages
+        if cfg.hc_mult > 1:
+            # the residual STREAMS: copies of the embedding, summed before
+            # the final norm
+            x = jnp.broadcast_to(x[:, :, None], (*x.shape[:2], cfg.hc_mult,
+                                                 x.shape[-1]))
+
+        def attend_at(kp, vp, li):
+            if cfg.is_latent:
+                return attend_latent_pages(
+                    cfg, kp, li, block_tables, start_positions, write_ok,
+                    attn_impl)
+            return attend_pages(kp, vp, li)
         conv, ssm = (ssm_state["conv"], ssm_state["ssm"]) \
             if ssm_state is not None else (None, None)
         stats = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
         for kind, i in table_layers(cfg):
             x, state, layer_stats = decoder_block(
                 x, table_layer(blocks, kind, i), cfg, positions, inv_freq,
-                attend_pages(kp, vp, i) if kind == "*" else None, matmul=mm,
+                attend_at(kp, vp, i) if kind == "*" else None, matmul=mm,
                 live=write_ok, layer_index=i, kind=kind,
                 recur=(recur_step(cfg, conv, ssm, i, write_ok)
                        if kind == "M" else None))
@@ -223,8 +236,10 @@ def extend_step_forward(
                 kp, vp = state
             elif kind == "M":
                 conv, ssm = state
-            else:
+            elif kind == "E":
                 stats = stats + layer_stats
+        if cfg.hc_mult > 1:
+            x = jnp.sum(x.astype(jnp.float32), axis=2).astype(compute_dtype)
         return (unembed(params, x, cfg), kp, vp,
                 *([stats] if return_moe_stats else []),
                 *([{"conv": conv, "ssm": ssm}] if ssm_state is not None
@@ -263,6 +278,33 @@ def extend_step_forward(
         (blocks, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
 
     return (unembed(params, x, cfg), new_k, new_v, *stats)
+
+
+def attend_latent_pages(cfg: ModelConfig, pool: jax.Array, li,
+                        block_tables: jax.Array, start_positions: jax.Array,
+                        write_ok: Any = None, attn_impl: str = "auto"):
+    """The latent ``attend`` (``layers.latent_attention_mixer``) over the ONE
+    latent pool ``pool`` [La, NP, 1, PS, W], written and read at layer
+    ``li``: the window's rows go in by the whole-page merge every window
+    takes, then every head's absorbed query walks the slot's live pages
+    once. The state it returns is (the pool, None): there is no second
+    pool."""
+    from ..ops.mla_paged_attention import mla_paged_attention
+    pad = pool.shape[-1] - cfg.mla.latent_size
+
+    def attend(q_lat, rows, scale):
+        with jax.named_scope("mla_page_write"):
+            rows = jnp.pad(rows, ((0, 0), (0, 0), (0, pad)))
+            new = write_window_to_pages(
+                pool, rows[:, :, None, :], block_tables, start_positions,
+                write_ok, li)
+        out = mla_paged_attention(
+            jnp.pad(q_lat, ((0, 0), (0, 0), (0, 0), (0, pad))), new,
+            block_tables, start_positions, scale=scale,
+            value_width=cfg.mla.kv_lora_rank, impl=attn_impl, layer=li)
+        return out, (new, None)
+    attend.latent = True
+    return attend
 
 
 def decode_multi_step(

@@ -124,6 +124,8 @@ class InferenceEngine:
         self.ssm_refused: dict[str, int] = {}
         if model_cfg.is_recurrent:
             self._refuse_for_recurrent(serve_cfg)
+        if model_cfg.is_latent:
+            self._refuse_for_latent(serve_cfg)
         if model_cfg.layer_pattern and (
                 serve_cfg.quantization not in ("", "none")
                 or serve_cfg.tensor_parallel > 1):
@@ -438,6 +440,40 @@ class InferenceEngine:
                 "off (no page hash is registered or looked up; a repeated "
                 "prompt is prefilled again)", self.cfg.name)
 
+    def _refuse_for_latent(self, serve_cfg: ServeConfig) -> None:
+        """Refuse, by name, what a latent page pool does not carry yet.
+        Prefix reuse by page hash stays ON: a latent page is a function of
+        the token prefix exactly as a K/V page is. (Quantised latent pages
+        are refused where the pool is made, serve/kv_cache.py.)"""
+        asked = {
+            "speculative": serve_cfg.speculative != "off",
+            "preemption: swap": serve_cfg.preemption == "swap",
+        }
+        for feature, on in asked.items():
+            if on:
+                raise ValueError(
+                    f"{self.cfg.name} keeps latent pages: {feature} is "
+                    "refused (no verification program has run over latent "
+                    "pages, and the swap payload is a K and a V pool; "
+                    "ROADMAP B4, B7)")
+
+    # the longest prompt a latent-attention model prefills COLD when no
+    # chunk length is configured: its cold program attends in the expanded
+    # form through XLA, whose float32 scores are heads x S x S (a 12k-token
+    # document: 19 GB); longer prompts go chunk by chunk over the pages
+    LATENT_COLD_TOKENS = 1024
+
+    @property
+    def _chunk_tokens(self) -> int:
+        """Prompts longer than this are prefilled in chunks of it over the
+        pages (0 = never): ``chunked_prefill_tokens``, and for a model with
+        latent attention at least ``LATENT_COLD_TOKENS`` whatever is
+        configured, so the cold ladder holds no rung it cannot run."""
+        C = self.serve_cfg.chunked_prefill_tokens
+        if self.cfg.is_latent and C <= 0:
+            return self.LATENT_COLD_TOKENS
+        return C
+
     @property
     def _prefix_caching(self) -> bool:
         return self.serve_cfg.prefix_caching and not self.cfg.is_recurrent
@@ -452,6 +488,11 @@ class InferenceEngine:
             raise ValueError(
                 f"{self.cfg.name} has state-space layers: fleet prefix "
                 "fetch is refused (fetched pages carry no recurrent state)")
+        if hook is not None and self.cfg.is_latent:
+            raise ValueError(
+                f"{self.cfg.name} keeps latent pages: fleet prefix fetch "
+                "is refused (the page payload is a K and a V pool; "
+                "ROADMAP B4)")
         self._prefix_fetch_hook = hook
 
     @staticmethod
@@ -605,6 +646,15 @@ class InferenceEngine:
         evictable) and only the remainder is reserved."""
         ctx = req.context_tokens   # prompt, + generated after a preemption
         n = len(ctx)
+        # asked again for a request whose pages were promised already (the
+        # scheduler's token budget stopped the admission after this hook
+        # said yes): give the earlier promise back first, or it is counted
+        # twice and its pins are never dropped
+        self._reserved_pages -= self._reserved_by.pop(req.request_id, 0)
+        stale = self._prefix_pins.pop(req.request_id, None)
+        if stale:
+            self.kv.unpin_pages(stale)
+        req.prefix_cached_tokens = 0
         if req.swapped_kv is not None:
             # swap-in admission: the request brings its own pages — no
             # prefix pinning (it would double-count against the restore
@@ -637,7 +687,8 @@ class InferenceEngine:
             # tail costs more than a cold dense prefill, so it is dropped.
             pallas_suffix = (self._attn_impl == "auto"
                              and jax.default_backend() == "tpu"
-                             and self.cfg.head_dim % 128 == 0)
+                             and (self.cfg.head_dim % 128 == 0
+                                  or self.cfg.is_latent))
             computed = n - len(pins) * self.kv.page_size
             if pins and not pallas_suffix and computed > max(
                     len(pins) * self.kv.page_size,
@@ -656,6 +707,8 @@ class InferenceEngine:
             return False
         if pins:
             self._prefix_pins[req.request_id] = pins
+            # what the scheduler's per-step token budget need not charge
+            req.prefix_cached_tokens = len(pins) * self.kv.page_size
         # hit-rate stats once per successful admission (not per retry)
         self.kv.prefix_queries += usable
         self.kv.prefix_hits += len(pins)
@@ -702,6 +755,29 @@ class InferenceEngine:
             cfg = self.cfg
             n_pages = bucket // self.kv.page_size
             dtype = self.kv.dtype
+
+            def prefill_latent(params, tokens, length, k_pages, v_pages,
+                               entries, key, temp, top_k, top_p):
+                """Cold prefill of a latent-attention model: the window
+                attends over its own tokens in the expanded form, and the
+                rows a cache keeps are written to the latent pool's pages
+                whole (the bucket's padding lands in scratch page 0 or
+                behind the slot's length, where nothing reads it)."""
+                live = (jnp.arange(bucket, dtype=jnp.int32)[None]
+                        < length[:, None]).astype(jnp.int32)
+                logits, rows, moe_stats = gpt.forward(
+                    params, tokens, cfg, unembed_positions=length - 1,
+                    segment_ids=live, return_latent=True,
+                    return_moe_stats=True)
+                pad = k_pages.shape[-1] - rows.shape[-1]
+                rows = jnp.pad(rows[:, 0], ((0, 0), (0, 0), (0, pad)))
+                k_pages = k_pages.at[:, entries].set(rows.reshape(
+                    cfg.kv_layers, n_pages, 1, self.kv.page_size,
+                    -1).astype(k_pages.dtype))
+                token = sample_tokens(logits[:, 0], key[None], temp[None],
+                                      top_k[None], top_p[None])[0]
+                return (self._with_moe_stats(token, moe_stats), k_pages,
+                        v_pages)
 
             def prefill(params, tokens, length, k_pages, v_pages, entries,
                         key, temp, top_k, top_p, state=None, slot=None):
@@ -775,7 +851,9 @@ class InferenceEngine:
                 return token, k_pages, v_pages
 
             self._prefill_cache[bucket] = _Program(
-                f"prefill {bucket}", prefill, self.failed_programs,
+                f"prefill {bucket}",
+                prefill_latent if cfg.is_latent else prefill,
+                self.failed_programs,
                 donate_argnums=(3, 4, 10) if cfg.is_recurrent else (3, 4))
         return self._prefill_cache[bucket]
 
@@ -1080,7 +1158,7 @@ class InferenceEngine:
         concurrent prefills progressing fairly. Returns
         [(req, device_token)] for the ones that completed this step."""
         completed = []
-        C = self.serve_cfg.chunked_prefill_tokens
+        C = self._chunk_tokens
         budget = max(self.serve_cfg.prefill_budget_tokens, C)
         spent = live = 0
         rids = list(self._partial_prefills)
@@ -1529,6 +1607,11 @@ class InferenceEngine:
             # at the fetch)
             ids["ssm_slot_steps"] = (int(self.active.sum()) * n_units
                                      * self._decode_unit_len)
+        if self.cfg.is_latent:
+            # what one walked page costs over the layers (llmctl trace
+            # summarize: "latent attention walked N pages of B bytes")
+            ids["latent_page_bytes"] = (self.kv.bytes_per_token
+                                        * self.kv.page_size)
         with self.spans.phase("llmctl.engine.decode.submit", units=n_units,
                               active=int(self.active.sum()),
                               live_pages=live_pages,
@@ -1995,7 +2078,7 @@ class InferenceEngine:
             spans.set_busy(self.scheduler.active_count > 0)
             if admitted:
                 spans.annotate(admitted=len(admitted))
-        C = self.serve_cfg.chunked_prefill_tokens
+        C = self._chunk_tokens
         pending = []
         for req in admitted:
             if req.swapped_kv is not None \

@@ -122,22 +122,27 @@ class PagedKVCache:
                 f"{page_size} must be even")
         self.quant_kind = kind
         self.quantized = kind != "none"
+        if cfg.is_latent and self.quantized:
+            raise ValueError(
+                f"{cfg.name} keeps latent pages: kv_quantization {kind} is "
+                "refused (a latent row is every head's keys AND values: no "
+                "quantised layout or kernel for it exists; ROADMAP B4)")
+        if kind == "int4":
+            # packed nibbles (D/2 bytes) + fp32 per-(token, kv-head)
+            # scale, K and V — the 2x-over-int8 capacity claim
+            self.bytes_per_token = (2 * cfg.kv_layers * cfg.num_kv_heads
+                                    * (cfg.head_dim // 2 + 4))
+        elif kind == "int8":
+            # int8 values + fp32 per-(token, kv-head) scale, K and V
+            self.bytes_per_token = (2 * cfg.kv_layers * cfg.num_kv_heads
+                                    * (cfg.head_dim + 4))
+        else:
+            # K and V of every kv head, or ONE padded latent row
+            self.bytes_per_token = cfg.kv_bytes_per_token(
+                jnp.dtype(dtype).itemsize)
         if num_pages <= 0:
-            if kind == "int4":
-                # packed nibbles (D/2 bytes) + fp32 per-(token, kv-head)
-                # scale, K and V — the 2x-over-int8 capacity claim
-                bytes_per_page = (2 * cfg.kv_layers * page_size
-                                  * cfg.num_kv_heads
-                                  * (cfg.head_dim // 2 + 4))
-            elif kind == "int8":
-                # int8 values + fp32 per-(token, kv-head) scale, K and V
-                bytes_per_page = (2 * cfg.kv_layers * page_size
-                                  * cfg.num_kv_heads * (cfg.head_dim + 4))
-            else:
-                bytes_per_page = (2 * cfg.kv_layers * page_size
-                                  * cfg.num_kv_heads * cfg.head_dim
-                                  * jnp.dtype(dtype).itemsize)
-            num_pages = max(int(hbm_budget_gb * 1e9 // bytes_per_page), 2)
+            num_pages = max(int(hbm_budget_gb * 1e9
+                                // (self.bytes_per_token * page_size)), 2)
         # never more than every slot fully resident (+1 scratch)
         num_pages = min(num_pages, num_slots * self.max_pages_per_slot + 1)
         self.num_pages = num_pages
@@ -149,8 +154,16 @@ class PagedKVCache:
         shape = (cfg.kv_layers, num_pages, cfg.num_kv_heads, page_size,
                  cfg.head_dim)
         self.page_sharding = page_sharding
+        if cfg.is_latent:
+            # the third kind of cache state: ONE pool of latent rows
+            # [c_kv | rope(k_pe)] (zero-padded to whole lane tiles), every
+            # head's keys and values at once. It rides the programs as
+            # ``k_pages``; there is no second pool (``v_pages`` None).
+            shape = (cfg.kv_layers, num_pages, 1, page_size,
+                     cfg.mla.page_width)
         self.k_pages = self._new_pages(shape, dtype)
-        self.v_pages = self._new_pages(shape, dtype)
+        self.v_pages = (None if cfg.is_latent
+                        else self._new_pages(shape, dtype))
         # the second kind of cache state: a state-space layer keeps, for
         # each SLOT, the last K-1 pre-activation conv columns and its
         # [nh, P, N] state, whatever the sequence's length. A slot costs
@@ -218,6 +231,11 @@ class PagedKVCache:
                 f"{self.cfg.name} has state-space layers: {what} is "
                 "refused (it carries K/V pages, and the slot's recurrent "
                 "state is not in them; ROADMAP C2)")
+        if self.cfg.is_latent:
+            raise ValueError(
+                f"{self.cfg.name} keeps latent pages: {what} is refused "
+                "(the payload schema is a K and a V pool; latent pages in "
+                "swap and fleet transfer are ROADMAP B4)")
 
     def _new_pages(self, shape, dtype):
         """Allocate a (possibly int8/int4-quantized, possibly tensor-
@@ -279,6 +297,8 @@ class PagedKVCache:
             from ..ops.paged_attention import QuantPages
             if isinstance(buf, QuantPages):
                 return buf.values.size + buf.scale.size * 4
+            if buf is None:             # a latent pool has no second one
+                return 0
             return int(np.prod(buf.shape)) * jnp.dtype(self.dtype).itemsize
         return one(self.k_pages) + one(self.v_pages) + self.state_bytes()
 
@@ -767,6 +787,10 @@ class PagedKVCache:
             "free_pages": self.free_pages,
             "page_size": self.page_size,
             "kv_quantization": self.quant_kind,
+            # what a token costs the pool, and of which kind its rows are:
+            # "kv" K and V of every kv head, "latent" ONE compressed row
+            "kind": "latent" if self.cfg.is_latent else "kv",
+            "bytes_per_token": self.bytes_per_token,
             "hbm_bytes": self.hbm_bytes(),
             "state_bytes": self.state_bytes(),
             "slots_resident": len(self._owned),

@@ -59,6 +59,10 @@ class Request:
     # Reset on preemption: the resumed context (prompt + generated so far)
     # has a longer chain.
     prefix_hashes: Optional[list] = field(default=None, repr=False)
+    # context tokens the engine's admission hook found in the prefix cache
+    # (pinned pages): no prefill computes them, so ``admit``'s token budget
+    # does not charge them
+    prefix_cached_tokens: int = 0
     # PRNG seed fixed at FIRST prefill so a preempted-and-resumed sampled
     # request continues the same per-position key stream (deterministic
     # across preemption)
@@ -320,7 +324,8 @@ class ContinuousBatchingScheduler:
 
         Returns the newly admitted requests, which need prefill before they
         produce tokens. ``budget_tokens > 0`` caps the total PROMPT tokens
-        admitted per call: the engine interleaves one bounded prefill batch
+        admitted per call (less those the admission hook found in the prefix
+        cache: a document's cached pages are not prefilled again): the engine interleaves one bounded prefill batch
         with each decode step, so a burst of long prompts cannot stall
         resident streams for the whole burst (round-1 verdict weak #4).
         At least one request is always admitted when possible, else a
@@ -334,7 +339,8 @@ class ContinuousBatchingScheduler:
             if not self._can_allocate(req):
                 break  # head-of-line blocks until pages free up (FCFS, no starvation)
             if budget_tokens > 0 and admitted and (
-                    spent + req.num_prompt_tokens > budget_tokens):
+                    spent + req.num_prompt_tokens - req.prefix_cached_tokens
+                    > budget_tokens):
                 break
             self.waiting.popleft()
             slot = free.pop(0)
@@ -346,7 +352,7 @@ class ContinuousBatchingScheduler:
             # swap-in resumes dispatch ZERO prefill — charging their
             # context would stall genuine prefills behind phantom work
             if req.swapped_kv is None:
-                spent += len(req.context_tokens)
+                spent += len(req.context_tokens) - req.prefix_cached_tokens
             self.total_admitted += 1
             self.queue_wait_ms.observe(
                 (time.monotonic() - req.arrival_time) * 1e3)

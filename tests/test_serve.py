@@ -578,9 +578,12 @@ class TestPrefillDecodeInterleaving:
         eng.step()
         assert resident.state is RequestState.RUNNING
 
-        # burst of 5 long prompts (40 tokens each; budget admits ~1/step)
+        # burst of 5 long prompts (40 tokens each; budget admits ~1/step).
+        # They share no prefix: the budget charges a request the tokens the
+        # prefix cache does NOT hold, and five copies of one prompt would
+        # be admitted together behind the first (tests/test_latent.py)
         burst = [Request(request_id=f"b{i}",
-                         prompt_tokens=list(range(1, 41)),
+                         prompt_tokens=list(range(1 + i, 41 + i)),
                          sampling=SamplingParams(temperature=0.0,
                                                  max_tokens=4))
                  for i in range(5)]
@@ -598,6 +601,10 @@ class TestPrefillDecodeInterleaving:
         # the burst was spread over multiple steps, not swallowed in one
         assert max(admits_per_step) <= 2
         assert sum(admits_per_step) >= 4
+        # a request the budget stopped after its pages were promised is
+        # promised them once, not again at every step
+        eng.run_until_idle()
+        assert eng._reserved_pages == 0
 
     def test_padded_slot_accounting(self, model_cfg):
         eng = make_engine(model_cfg, max_batch_size=4)

@@ -527,3 +527,88 @@ def test_hybrid_prefill_program_compiles(one_chip, as_tpu):
     _no_copy_of(text, ["bf16[6,64,1856,2688]", "f32[6,64,64,64,128]"])
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 256e6, f"{temp / 1e6:.1f} MB of temporaries"
+
+
+# -- the latent cell (Xing4.0-29B-A4B, 7 layers): the kernel and the decode
+# program at the published widths and the configuration's page size ---------
+
+LATENT_PS, LATENT_MAXP, LATENT_PAGES = 256, 68, 1307
+
+
+@pytest.mark.parametrize("B,T", [(64, 1), (1, 512), (1, 1024)],
+                         ids=["decode", "suffix-512", "chunk-1024"])
+def test_latent_paged_attention_kernel_compiles(one_chip, as_tpu, B, T):
+    """32 heads over ONE pool of 640-wide rows (576 + padding), pages of
+    256: one query a slot, and the windows of suffix and chunked prefill
+    tiled 32 tokens a grid step."""
+    from distributed_llm_training_and_inference_system_tpu.ops.mla_paged_attention import (
+        mla_paged_attention)
+    sds = _sds(one_chip)
+    compiled = _compile(
+        functools.partial(mla_paged_attention, scale=0.14, value_width=512,
+                          layer=3),
+        sds((B, T, 32, 640), jnp.bfloat16),
+        sds((7, LATENT_PAGES, 1, LATENT_PS, 640), jnp.bfloat16),
+        sds((B, LATENT_MAXP), jnp.int32), sds((B,), jnp.int32))
+    assert "mla_paged_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_a_576_wide_latent_page_is_refused_by_mosaic(one_chip, as_tpu):
+    """Why a latent row is stored 640 wide: the chip's layout pads a
+    576-wide minor dimension to 640, and a page copy of 576 is refused."""
+    from distributed_llm_training_and_inference_system_tpu.ops.mla_paged_attention import (
+        mla_paged_attention)
+    sds = _sds(one_chip)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(functools.partial(mla_paged_attention, scale=0.14,
+                                   value_width=512, layer=3),
+                 sds((64, 1, 32, 576), jnp.bfloat16),
+                 sds((7, LATENT_PAGES, 1, LATENT_PS, 576), jnp.bfloat16),
+                 sds((64, LATENT_MAXP), jnp.int32), sds((64,), jnp.int32))
+
+
+def test_latent_decode_program_moves_no_pool_and_no_stack(one_chip, as_tpu):
+    """The multi-step decode program at the latent cell's shapes: the ONE
+    latent pool (3.0 GB) rides the carry and is aliased to the output, the
+    expert stacks stay whole: no temporary the size of the pool, of a
+    layer's slab of it (428 MB) or of an expert stack (2.8 GB)."""
+    import json
+    from pathlib import Path
+
+    from distributed_llm_training_and_inference_system_tpu.config.schema import (
+        ModelConfig)
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        decode_scan)
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                         / "configs" / "xing4.0-29b-a4b-7l.json").read_text())
+    cfg = ModelConfig.from_published(config)
+    sds = _sds(one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda k: gpt.init(cfg, k, jnp.bfloat16), jax.random.PRNGKey(0)))
+    B = 64
+    pool = sds((7, LATENT_PAGES, 1, LATENT_PS, 640), jnp.bfloat16)
+
+    def program(params, pool, tokens, positions, tables, stops, keys, temp,
+                top_k, top_p):
+        return decode_scan(params, tokens, positions, pool, None, tables,
+                           stops, keys, temp, top_k, top_p, cfg, 2,
+                           return_moe_stats=True)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    compiled = jax.jit(program, donate_argnums=(1,)).lower(
+        params, pool, i32(B), i32(B), i32(B, LATENT_MAXP), i32(B),
+        sds((B, 2), jnp.uint32), sds((B,), jnp.float32), i32(B),
+        sds((B,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "mla_paged_attention" in text
+    _no_copy_of(text, ["bf16[7,1307,1,256,640]", "bf16[1307,1,256,640]",
+                       "bf16[6,64,3584,1024]", "bf16[6,64,1024,3584]"])
+    mem = compiled.memory_analysis()
+    pool_bytes = 7 * LATENT_PAGES * LATENT_PS * 640 * 2
+    assert mem.temp_size_in_bytes < pool_bytes // 7, (
+        f"decode program holds {mem.temp_size_in_bytes / 1e6:.1f} MB of "
+        "temporaries")
+    assert mem.alias_size_in_bytes >= pool_bytes
