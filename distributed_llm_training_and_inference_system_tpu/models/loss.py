@@ -78,6 +78,8 @@ def chunked_next_token_loss(
     d(unembed_w) across chunks via the scan transpose. Numerics match
     ``next_token_loss`` (fp32 softmax, same masking) up to reduction order.
     """
+    from ..parallel.sharding import constrain
+
     B, S, H = hidden.shape
     shift_h = hidden[:, :-1]
     shift_t = tokens[:, 1:]
@@ -103,13 +105,18 @@ def chunked_next_token_loss(
 
     @jax.checkpoint
     def one_chunk(h, t, w):
+        # On a mesh, pin what moves: the chunk's ROWS go to the weight's
+        # vocabulary shards (gathered over fsdp), the weight, its gradient
+        # and the logits stay. Targets and weights with the rows: left on
+        # (dp, fsdp), the partitioner moved the logits to THEM at dp > 1.
+        h, t, w = (constrain(x, "loss_rows") for x in (h, t, w))
         if tied:
             logits = jnp.einsum("bsh,vh->bsv", h, unembed_w.astype(h.dtype),
                                 preferred_element_type=jnp.float32)
         else:
             logits = jnp.einsum("bsh,hv->bsv", h, unembed_w.astype(h.dtype),
                                 preferred_element_type=jnp.float32)
-        logits = logits.astype(jnp.float32)
+        logits = constrain(logits.astype(jnp.float32), "loss_logits")
         logz = jax.nn.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(logits, t[..., None], axis=-1).squeeze(-1)
         nll = logz - tgt

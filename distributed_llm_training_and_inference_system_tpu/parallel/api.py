@@ -143,6 +143,26 @@ class ShardedTrainer:
                 self.state, metrics = self.train_step(self.state, batch)
         return metrics
 
+    def lower_step(self, batch: Any):
+        """``train_step`` lowered for SHAPES alone: the state as
+        ``ShapeDtypeStruct``s on its shardings and *batch* (arrays or
+        shapes, ``[B, S]``) on the batch's. Nothing is placed or run, so
+        the mesh may be made of DESCRIBED devices (``jax.experimental
+        .topologies``): ``.compile()`` then says what the chip's compiler
+        would, its ``as_text()`` which collectives GSPMD chose
+        (``comms.hlo.collectives``), its ``memory_analysis()`` what the
+        step needs."""
+        def shapes(tree, shardings):
+            return jax.tree_util.tree_map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                  sharding=s),
+                tree, shardings)
+        state = shapes(self._abstract, self._state_shardings)
+        batch = shapes(batch, _to_shardings(self._batch_spec_fn(batch),
+                                            self.mesh))
+        with use_mesh(self.mesh):
+            return self.train_step.lower(state, batch)
+
     def evaluate(self, batch: Any):
         assert self.state is not None, "call init_state() first"
         with use_mesh(self.mesh):

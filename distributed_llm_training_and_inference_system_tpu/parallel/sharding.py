@@ -4,10 +4,13 @@ This is the executable form of what the reference only *plans*
 (SURVEY §2.2: TP/PP/ZeRO exist solely as cost-model dimensions in
 plan.py:73-125). Megatron-style tensor parallelism as data layout:
 
-- column-parallel kernels (q/k/v, mlp gate/up, lm_head): output dim on tp
+- column-parallel kernels (q/k/v, mlp gate/up): output dim on tp
 - row-parallel kernels (o, mlp down): input dim on tp
 - embedding: vocab on fsdp, hidden on tp (see PARAM_RULES comment)
-- every 2D kernel additionally shards its other dim on fsdp (ZeRO-3-style)
+- every 2D block kernel additionally shards its other dim on fsdp
+  (ZeRO-3-style)
+- untied lm_head: vocabulary-parallel, its output (vocab) dim on fsdp AND
+  tp, hidden unsharded (see PARAM_RULES comment)
 - MoE expert kernels put their leading E axis on ep
 - stacked-layer leading axis goes on pp (when pipeline_parallel > 1 the
   pipeline runner re-slices it; for pp=1 it is just unsharded)
@@ -32,10 +35,18 @@ PARAM_RULES: list[tuple[str, P]] = [
     # forces GSPMD into "Involuntary full rematerialization" when resharding
     # to the activation spec (observed round 1 on the fsdp x sp x ep mesh).
     # Vocab-on-fsdp partitions the gather as mask+psum and the tied-logits
-    # einsum as a plain contraction — verified warning-free on both dryrun
-    # regimes (tests/test_parallel.py::test_no_involuntary_remat).
+    # einsum ("bsh,vh->bsv", tie_word_embeddings) as a plain contraction —
+    # verified warning-free on both dryrun regimes
+    # (tests/test_parallel.py::test_no_involuntary_remat).
     (r"embed\.embedding$",        P("fsdp", "tp")),
-    (r"lm_head\.kernel$",         P("fsdp", "tp")),
+    # The untied head [H, V] is vocabulary-parallel for the same reason:
+    # fsdp on H would put it on the CONTRACTION of "bsh,hv->bsv" while the
+    # rows shard on fsdp too, and GSPMD then moves the weight — inside the
+    # chunked loss's scans, the whole head gathered and its whole gradient
+    # all-reduced in every chunk (PERF.md 6, PR 34: 379 MB x 3 a chunk at
+    # InternLM2's vocabulary). With the vocabulary on fsdp the loss gathers
+    # a chunk's rows and reduces [B, chunk] softmax statistics.
+    (r"lm_head\.kernel$",         P(None, ("fsdp", "tp"))),
     (r"final_norm\.scale$",       P(None)),
     # a layer table's stacks (one a layer KIND: blocks.ssm / .attn / .moe).
     # The state-space mixer's W_in is [z | xBC | dt] side by side, which tp
@@ -69,6 +80,14 @@ ACTIVATION_RULES: dict[str, P] = {
     "activations": P(("dp", "fsdp"), "sp", None),
     # [B, S, V]: logits vocab dim over tp
     "logits": P(("dp", "fsdp"), "sp", "tp"),
+    # inside models.loss.chunked_next_token_loss, a chunk's logits
+    # [B, chunk, V] and its rows [B, chunk, H] / targets [B, chunk]: the
+    # vocabulary on the head's axes (PARAM_RULES lm_head), the rows on dp
+    # alone. fsdp cannot shard both, and the rows are the small side: they
+    # are gathered over fsdp, never the weight. "logits" above is the full
+    # [B, S, V] of evaluation and tp serving and keeps its meaning.
+    "loss_logits": P("dp", "sp", ("fsdp", "tp")),
+    "loss_rows": P("dp", "sp", None),
     # [B, S] token/segment arrays
     "tokens": P(("dp", "fsdp"), "sp"),
 }

@@ -63,3 +63,68 @@ def test_bench_measures_real_time(devices8):
     for r in results:
         assert r["time_ms"] > 0.0
         assert np.isfinite(r["bus_bandwidth_gbps"])
+
+
+# -- comms.hlo: reading a partitioned program's text ---------------------------
+
+_HLO = '''HloModule jit_step, entry_computation_layout={()->f32[]}
+
+%add.1 (x: f32[], y: f32[]) -> f32[] {
+  %x = f32[] parameter(0)
+  %y = f32[] parameter(1)
+  ROOT %s = f32[] add(%x, %y)
+}
+
+%all-reduce-scatter.2.clone (input.1: bf16[2048,8192]) -> bf16[512,8192] {
+  %input.1 = bf16[2048,8192]{1,0} parameter(0)
+  %all-reduce.7 = bf16[2048,8192]{1,0:T(8,128)(2,1)} all-reduce(%input.1), channel_id=3, replica_groups={{0,1,2,3}}, to_apply=%add.1
+  ROOT %dynamic-slice.1 = bf16[512,8192]{1,0} dynamic-slice(%all-reduce.7), dynamic_slice_sizes={512,8192}
+}
+
+%body.3 (p: (s32[], f32[512,92544])) -> (s32[], f32[512,92544]) {
+  %p = (s32[], f32[512,92544]{1,0}) parameter(0)
+  %all-gather.9 = bf16[2048,92544]{1,0:T(8,128)(2,1)} all-gather(%w), channel_id=1, replica_groups=[1,4]<=[4], dimensions={0}, metadata={op_name="jit(step)/while/body/jvp(chunked_loss)/while/body/bsh,hv->bsv/dot_general" stack_frame_id=7}
+  %fusion.5 = bf16[512,8192]{1,0} fusion(%g), kind=kCustom, calls=%all-reduce-scatter.2.clone, metadata={op_name="jit(step)/while/body/jvp(chunked_loss)/while/body/transpose"}
+  %all-reduce-start.1 = (f32[4,512]{1,0}, f32[4,512,1]{2,1,0}) all-reduce-start(%a, %b), channel_id=4, to_apply=%add.1, metadata={op_name="jit(step)/while/body/jvp(chunked_loss)/while/body/reduce_max"}
+  %all-reduce-done.1 = (f32[4,512]{1,0}, f32[4,512,1]{2,1,0}) all-reduce-done(%all-reduce-start.1)
+  ROOT %t = (s32[], f32[512,92544]{1,0}) tuple(%i, %acc)
+}
+
+%cond.3 (p.1: (s32[], f32[512,92544])) -> pred[] {
+  %p.1 = (s32[], f32[512,92544]{1,0}) parameter(0)
+  ROOT %lt = pred[] compare(%i.1, %n), direction=LT
+}
+
+ENTRY %main.1 () -> f32[] {
+  %while.1 = (s32[], f32[512,92544]{1,0}) while(%init), condition=%cond.3, body=%body.3, metadata={op_name="jit(step)/while"}
+  %all-gather.2 = f32[4,4096,2048]{2,1,0} all-gather(%e), channel_id=9, dimensions={0}, metadata={op_name="jit(step)/scatter-add"}
+  ROOT %r = f32[] constant(0)
+}
+'''
+
+
+def test_hlo_collectives_are_read_with_their_loops():
+    from distributed_llm_training_and_inference_system_tpu.comms.hlo import (
+        collectives)
+    found = {c.name: c for c in collectives(_HLO)}
+    assert set(found) == {"all-reduce.7", "all-gather.9",
+                          "all-reduce-start.1", "all-gather.2"}
+    head = found["all-gather.9"]
+    assert (head.op, head.shapes) == ("all-gather",
+                                      (("bf16", (2048, 92544)),))
+    assert head.nbytes == 2048 * 92544 * 2 and head.has_axis(92544)
+    assert head.in_loop and head.fusion == ""
+    assert head.loop == "jit(step)/while/body/jvp(chunked_loss)/while"
+    # an all-reduce whose fusion keeps one shard: named for what it is, under
+    # the calling fusion's loop and name (what a device trace shows)
+    scatter = found["all-reduce.7"]
+    assert scatter.op == "all-reduce-scatter" and scatter.fusion == "fusion.5"
+    assert scatter.in_loop and "chunked_loss" in scatter.loop
+    assert not scatter.overlapped
+    # an async pair is one transfer; a tuple result counts every member
+    stats = found["all-reduce-start.1"]
+    assert stats.op == "all-reduce" and len(stats.shapes) == 2
+    assert stats.nbytes == 2 * 4 * 512 * 4
+    assert stats.widest == ("f32", (4, 512)) and not stats.has_axis(92544)
+    outside = found["all-gather.2"]
+    assert not outside.in_loop and outside.loop == ""

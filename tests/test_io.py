@@ -108,6 +108,56 @@ def test_checkpoint_roundtrip_sharded(tmp_path, devices8):
     assert np.isfinite(float(m["loss"]))
 
 
+def test_checkpoint_restores_across_a_changed_head_layout(tmp_path, devices8):
+    """A checkpoint written while the untied head sat on P("fsdp", "tp")
+    (hidden over fsdp: the rule before PR 34) restores into the
+    vocabulary-parallel layout with equal values, moments included: restore
+    goes by path and target sharding and assembles every new shard from the
+    saved pieces that overlap it."""
+    import dataclasses
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_llm_training_and_inference_system_tpu.config import (
+        OptimizerConfig, ParallelConfig, get_model_config)
+    from distributed_llm_training_and_inference_system_tpu.parallel import (
+        ShardedTrainer)
+
+    cfg = dataclasses.replace(get_model_config("gpt-test"),
+                              tie_word_embeddings=False)
+    tr = ShardedTrainer(cfg, OptimizerConfig(lr=1e-2),
+                        ParallelConfig(data_parallel=2, fsdp=2,
+                                       tensor_parallel=2),
+                        devices=devices8)
+    new = tr._state_shardings.params["lm_head"]["kernel"]
+    assert new.spec == P(None, ("fsdp", "tp"))
+    tr.init_state(seed=0)
+    tr.step({"tokens": np.random.default_rng(0).integers(
+        1, cfg.vocab_size, size=(8, 16)).astype(np.int32)})   # moments != 0
+
+    old = NamedSharding(tr.mesh, P("fsdp", "tp"))
+
+    def to_old_layout(path, leaf):
+        return (jax.device_put(leaf, old)
+                if jax.tree_util.keystr(path).endswith("['lm_head']['kernel']")
+                else leaf)
+    saved = jax.tree_util.tree_map_with_path(to_old_layout, tr.state)
+    assert saved.params["lm_head"]["kernel"].sharding.spec == old.spec
+    assert saved.opt_state[0].mu["lm_head"]["kernel"].sharding.spec == old.spec
+
+    mgr = CheckpointManager(tmp_path / "ckpt", async_save=False)
+    mgr.save(1, saved)
+    restored, _ = mgr.restore(target=tr.state, shardings=tr._state_shardings)
+    for get in (lambda s: s.params, lambda s: s.opt_state[0].mu,
+                lambda s: s.opt_state[0].nu):
+        was, now = (get(s)["lm_head"]["kernel"] for s in (saved, restored))
+        assert now.sharding == new
+        np.testing.assert_array_equal(np.asarray(now), np.asarray(was))
+    for a, b in zip(jax.tree_util.tree_leaves(saved),
+                    jax.tree_util.tree_leaves(restored)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_checkpoint_gc_and_atomicity(tmp_path):
     state = {"w": jnp.arange(8, dtype=jnp.float32)}
     mgr = CheckpointManager(tmp_path, keep_latest=2, async_save=False)

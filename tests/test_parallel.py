@@ -6,6 +6,8 @@ same loss trajectory as the single-device step — the numerical-equivalence
 guarantee the reference cannot offer for its planned-only TP/ZeRO.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -54,22 +56,55 @@ def test_param_specs_divisibility(devices8):
     # q kernel must actually be tensor-parallel on its output dim
     d = dict(zip([p for p, _ in flat_p], flat_s))
     assert "tp" in str(d["blocks.q.kernel"])
+    # the untied head is vocabulary-parallel over fsdp AND tp, its hidden
+    # (contraction) axis whole: the chunked loss then moves rows, not the
+    # weight (gpt-test is tied and has no head)
+    untied = dataclasses.replace(cfg, tie_word_embeddings=False)
+    par = ParallelConfig(data_parallel=2, fsdp=2, tensor_parallel=2)
+    shardings = ShardedTrainer(untied, OptimizerConfig(), par,
+                               devices=devices8).describe_shardings()
+    assert shardings["lm_head.kernel"] == str(P(None, ("fsdp", "tp")))
+    assert shardings["embed.embedding"] == str(P("fsdp", "tp"))
 
 
-@pytest.mark.parametrize("par", [
-    ParallelConfig(data_parallel=8),                                  # pure DP
-    ParallelConfig(data_parallel=2, fsdp=2, tensor_parallel=2),       # DP+FSDP+TP
-    ParallelConfig(data_parallel=2, fsdp=4, zero_stage=1),            # ZeRO
-], ids=["dp8", "dp2fsdp2tp2", "fsdp4zero1"])
-def test_sharded_step_matches_single_device(devices8, par):
+def _devices_for(devices8, par: ParallelConfig) -> list:
+    from distributed_llm_training_and_inference_system_tpu.parallel.mesh import (
+        mesh_shape_from_config)
+    return devices8[:int(np.prod(list(mesh_shape_from_config(par).values())))]
+
+
+def _packed_batch(rows: int, seq: int, vocab: int) -> dict:
+    """Two documents a row, cut at another place in every row."""
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (rows, seq), 1, vocab)
+    cut = 5 + (jnp.arange(rows) * 7) % (seq - 10)
+    segment_ids = 1 + (jnp.arange(seq)[None, :] >= cut[:, None])
+    return {"tokens": tokens, "segment_ids": segment_ids.astype(jnp.int32)}
+
+
+@pytest.mark.parametrize("par, untied", [
+    (ParallelConfig(data_parallel=8), False),                         # pure DP
+    (ParallelConfig(data_parallel=2, fsdp=2, tensor_parallel=2), False),
+    (ParallelConfig(data_parallel=2, fsdp=4, zero_stage=1), False),   # ZeRO
+    # an UNTIED head (lm_head.kernel's own rule: vocabulary over fsdp and
+    # tp), under gradient accumulation and with packed rows
+    (ParallelConfig(fsdp=4, gradient_accumulation_steps=4), True),
+    (ParallelConfig(data_parallel=2, fsdp=2, tensor_parallel=2,
+                    gradient_accumulation_steps=4), True),
+], ids=["dp8", "dp2fsdp2tp2", "fsdp4zero1", "untied-fsdp4",
+        "untied-dp2fsdp2tp2"])
+def test_sharded_step_matches_single_device(devices8, par, untied):
     model_cfg = get_model_config("gpt-test")
     opt_cfg = OptimizerConfig(lr=1e-2)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 1,
-                                model_cfg.vocab_size)
-    batch = {"tokens": tokens}
+    if untied:
+        model_cfg = dataclasses.replace(model_cfg, tie_word_embeddings=False)
+        batch = _packed_batch(16, 32, model_cfg.vocab_size)
+    else:
+        batch = {"tokens": jax.random.randint(
+            jax.random.PRNGKey(1), (8, 32), 1, model_cfg.vocab_size)}
 
     # single-device reference trajectory
-    step_fn, tx, _ = make_train_step(model_cfg, opt_cfg)
+    step_fn, tx, _ = make_train_step(model_cfg, opt_cfg, ParallelConfig(
+        gradient_accumulation_steps=par.gradient_accumulation_steps))
     ref_state = TrainState.create(init(model_cfg, jax.random.PRNGKey(0)), tx)
     ref_losses = []
     jstep = jax.jit(step_fn)
@@ -78,7 +113,8 @@ def test_sharded_step_matches_single_device(devices8, par):
         ref_losses.append(float(m["loss"]))
 
     # sharded trajectory
-    trainer = ShardedTrainer(model_cfg, opt_cfg, par, devices=devices8)
+    trainer = ShardedTrainer(model_cfg, opt_cfg, par,
+                             devices=_devices_for(devices8, par))
     trainer.init_state(seed=0)
     losses = []
     for _ in range(3):
@@ -86,6 +122,56 @@ def test_sharded_step_matches_single_device(devices8, par):
         losses.append(float(m["loss"]))
 
     np.testing.assert_allclose(losses, ref_losses, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("par, attn_impl", [
+    (ParallelConfig(fsdp=4, gradient_accumulation_steps=2), "xla"),
+    (ParallelConfig(data_parallel=2, fsdp=2, tensor_parallel=2,
+                    gradient_accumulation_steps=2), "xla"),
+    (ParallelConfig(fsdp=2, sequence_parallel=2,
+                    gradient_accumulation_steps=2), "ring"),
+], ids=["fsdp4", "dp2fsdp2tp2", "fsdp2sp2"])
+def test_chunked_loss_moves_rows_not_the_head(devices8, par, attn_impl):
+    """InternLM2's layout in small (untied head, GQA): in the partitioned
+    step the chunked loss's two loops gather a chunk's ROWS and reduce
+    softmax statistics; the head, its gradient and the logits stay where
+    they are. With fsdp on the head's hidden axis (the rule before PR 34)
+    the forward and the backward loop each all-gathered the whole head
+    (``f32[H,V]``) and the backward all-reduced its whole gradient, once a
+    chunk, on every one of these meshes. What a mesh with dp or sp still
+    holds is named below."""
+    from distributed_llm_training_and_inference_system_tpu.comms.hlo import (
+        collectives)
+    V, H, S, rows = 6144, 64, 1024, 8      # 1,023 targets: two chunks of 512
+    model_cfg = dataclasses.replace(
+        get_model_config("gpt-test"), tie_word_embeddings=False, vocab_size=V)
+    assert model_cfg.hidden_size == H
+    trainer = ShardedTrainer(model_cfg, OptimizerConfig(), par,
+                             devices=_devices_for(devices8, par),
+                             attn_impl=attn_impl)
+    text = trainer.lower_step(
+        _packed_batch(rows, S, V)).compile().as_text()
+    found = collectives(text)
+    in_loss = [c for c in found if "chunked_loss" in c.loop]
+    assert in_loss, "no collective is named for the loss's loops"
+    head_shard = V // (par.fsdp * par.tensor_parallel)
+    shards = {V // k for k in (1, 2, 4, 8)}
+    wide = [c for c in found
+            if any(d in shards for _, dims in c.shapes for d in dims)]
+    # rows on dp (or positions on sp) are partial sums of the head's
+    # gradient: its SHARD is all-reduced over dp / sp, in the backward loop
+    # (and the embedding's, [V / fsdp, H / tp], once a micro-batch); nothing
+    # vocabulary-wide is gathered, permuted or exchanged anywhere
+    if par.data_parallel * par.sequence_parallel > 1:
+        wide = [c for c in wide if not (
+            c.op == "all-reduce" and c.widest[1][0] in (head_shard,
+                                                        V // par.fsdp))]
+    assert not wide, [(c.op, c.shapes, c.loop) for c in wide]
+    chunk_rows = rows // 2 * 512 * H * 4     # every shard's rows, float32
+    assert chunk_rows < H * V * 4            # or the head would pass for rows
+    big = [c for c in in_loss if c.nbytes > chunk_rows and not (
+        c.op == "all-reduce" and c.widest[1][0] == head_shard)]
+    assert not big, [(c.op, c.shapes, c.nbytes) for c in big]
 
 
 @pytest.mark.parametrize("packed", [False, True],

@@ -169,6 +169,34 @@ def test_1f1b_matches_gpipe_trajectory(devices8):
                                rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
+def test_pipeline_untied_head_over_fsdp(devices8, schedule):
+    """Both schedules call the chunked loss on the last stage's output, in
+    the same GSPMD program as the stages: with an UNTIED head on a mesh with
+    fsdp > 1 the head is vocabulary-parallel (lm_head.kernel's rule) and the
+    loss pins its logits to that. pp=2 x dp=2 x fsdp=2 with packed rows must
+    give the single-device trajectory."""
+    import dataclasses
+    model_cfg = dataclasses.replace(get_model_config("gpt-test"),
+                                    num_layers=4, tie_word_embeddings=False)
+    par = ParallelConfig(data_parallel=2, fsdp=2, pipeline_parallel=2,
+                         num_microbatches=2, micro_batch_size=4,
+                         global_batch_size=8, pipeline_schedule=schedule,
+                         activation_checkpoint="none")
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (8, 32), 1,
+                                model_cfg.vocab_size)
+    segment_ids = 1 + (jnp.arange(32)[None, :]
+                       >= (6 + 3 * jnp.arange(8))[:, None]).astype(jnp.int32)
+    batch = {"tokens": tokens, "segment_ids": segment_ids}
+    ref = _ref_losses(model_cfg, batch)
+    tr = ShardedTrainer(model_cfg, OptimizerConfig(lr=1e-2), par,
+                        devices=devices8)
+    assert "fsdp" in tr.describe_shardings()["lm_head.kernel"]
+    tr.init_state(seed=0)
+    losses = [float(tr.step(batch)["loss"]) for _ in range(3)]
+    np.testing.assert_allclose(losses, ref, rtol=2e-3, atol=1e-4)
+
+
 def test_1f1b_memory_constant_in_microbatches(devices8):
     """THE property 1F1B exists for (BASELINE config 3, round-1 verdict #4):
     compiled temp memory must be ~constant as the microbatch count grows,

@@ -612,3 +612,52 @@ def test_latent_decode_program_moves_no_pool_and_no_stack(one_chip, as_tpu):
         f"decode program holds {mem.temp_size_in_bytes / 1e6:.1f} MB of "
         "temporaries")
     assert mem.alias_size_in_bytes >= pool_bytes
+
+
+# -- the four-chip training cell (internlm2-1.8b.pretrain-4k-fsdp4) -----------
+
+def test_fsdp4_train_step_moves_rows_not_the_head(topo, as_tpu):
+    """The cell's step (fsdp=4, micro-batch 1 x 4, flash, chunked loss) at
+    InternLM2's widths with 2 of its 24 layers, partitioned for the four
+    described chips: NO collective has a vocabulary-wide operand (92,544 or
+    a quarter of it), and inside the chunked loss's two loops nothing is
+    larger than the rows of one chunk. With fsdp on the head's hidden axis
+    (before PR 34) each loop gathered the head, ``bf16[2048,92544]``, and
+    the backward loop all-reduced its gradient, 379 MB each, once a chunk;
+    that step's temporaries at this depth were 3.86 GiB (2.25 since)."""
+    import json
+    from pathlib import Path
+
+    from benchmark import traffic as traffic_mod
+    from benchmark.runners import train as train_runner
+    from distributed_llm_training_and_inference_system_tpu.comms.hlo import (
+        collectives)
+    from distributed_llm_training_and_inference_system_tpu.parallel import (
+        ShardedTrainer)
+    bench = Path(__file__).resolve().parents[1] / "benchmark"
+    config = json.loads((bench / "configs" / "internlm2-1.8b.json").read_text())
+    config["num_hidden_layers"] = 2
+    traffic = traffic_mod.load(str(bench / "traffic" / "pretrain-4k-fsdp4.json"))
+    cfg = train_runner.run_config(config, traffic, seed=0, ckpt_dir="/unused")
+    assert cfg.parallel.fsdp == 4 and not cfg.model.tie_word_embeddings
+    trainer = ShardedTrainer(cfg.model, cfg.optimizer, cfg.parallel,
+                             devices=list(topo.devices), attn_impl="flash")
+    V, H, S = cfg.model.vocab_size, cfg.model.hidden_size, cfg.data.max_length
+    batch = {k: jax.ShapeDtypeStruct((cfg.parallel.global_batch_size, S),
+                                     jnp.int32)
+             for k in ("tokens", "segment_ids", "positions")}
+    compiled = trainer.lower_step(batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "flash_fwd" in text
+    found = collectives(text)
+    in_loss = [c for c in found if "chunked_loss" in c.loop]
+    assert in_loss, "no collective is named for the loss's loops"
+    wide = [c for c in found if c.has_axis(V) or c.has_axis(V // 4)]
+    assert not wide, [(c.op, c.shapes, c.loop) for c in wide]
+    # every shard's rows of a 512-position chunk; their gradient leaves the
+    # matmul in float32 (16.9 MB with the padding of an all-reduce-scatter)
+    chunk_rows = 4 * cfg.parallel.micro_batch_size * 512 * H * 4
+    big = [c for c in in_loss if c.nbytes > 1.05 * chunk_rows]
+    assert not big, [(c.op, c.shapes, c.nbytes) for c in big]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 3.86 * 2 ** 30, f"{temp / 2 ** 30:.2f} GiB of temporaries"
