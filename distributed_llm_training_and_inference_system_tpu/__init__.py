@@ -27,4 +27,11 @@ Import as::
     import distributed_llm_training_and_inference_system_tpu as dlts
 """
 
+import time as _time
+
+# where ``llmctl.startup.import`` begins (metrics/spans.py STARTUP): the
+# package's first line, before anything heavy is imported. Nothing else may
+# be imported here: config-only commands never pay for jax.
+_IMPORT_T0 = _time.monotonic()
+
 __version__ = "0.1.0"

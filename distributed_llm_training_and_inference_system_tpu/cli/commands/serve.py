@@ -514,16 +514,16 @@ def start(model_name, artifact, host, port, max_batch_size, max_seq_len,
           fleet_priority_headroom_requests,
           fleet_interactive_ttft_target_ms, stream_abort_on_disconnect):
     """Start the OpenAI-compatible inference server."""
-    import jax
-
     from ...config.presets import get_model_config
     from ...config.schema import (FleetConfig, ServeConfig,
                                   parse_fleet_endpoints)
     from ...metrics.observability import setup_observability
     from ...serve.server import create_server
+    from ...utils.platform import devices
 
     if dtype is None:
-        dtype = "bfloat16" if jax.default_backend() == "tpu" else "float32"
+        # the process's first look at its devices (llmctl.startup.backend)
+        dtype = "bfloat16" if devices()[0].platform == "tpu" else "float32"
     model_cfg = get_model_config(model_name)
     serve_cfg = ServeConfig(
         model=model_name, artifact=artifact, host=host, port=port,

@@ -150,7 +150,9 @@ def load_profile(path: str) -> dict:
     (``latent_page_bytes``, the last seen, not a sum);
     "prefill_rows" the ``bucket`` (rows the program computed), ``tokens``
     less ``cached`` (the live ones) and ``cached`` (the prompt tokens the
-    prefix cache supplied) of every prefill span."""
+    prefix cache supplied) of every prefill span; "startup_programs" the
+    ``(name, seconds)`` of every ``llmctl.startup.program`` span (a
+    program's first call: metrics/spans.py ``StartupRecorder``)."""
     from jax.profiler import ProfileData
     profile = ProfileData.from_file(str(path))
     devices: dict = {}
@@ -158,6 +160,7 @@ def load_profile(path: str) -> dict:
     page_walk = {"live_pages": 0, "table_pages": 0, "ssm_slot_steps": 0,
                  "latent_page_bytes": 0}
     prefill_rows = {"rows": 0, "tokens": 0, "cached": 0}
+    startup_programs: list = []
     for plane in profile.planes:
         is_device = plane.name.startswith("/device:")
         for line in plane.lines:
@@ -180,6 +183,10 @@ def load_profile(path: str) -> dict:
                                 page_walk[key] = int(value)
                             elif key in page_walk:
                                 page_walk[key] += int(value)
+                    elif e.name == SPAN_PREFIX + "startup.program":
+                        startup_programs.append(
+                            (str(dict(e.stats).get("name", "?")),
+                             e.duration_ns * 1e-9))
                     elif e.name == SPAN_PREFIX + "engine.prefill.host":
                         ids = dict(e.stats)
                         # (a chunked prefill's first span carries its
@@ -193,7 +200,8 @@ def load_profile(path: str) -> dict:
                     host_spans.setdefault(
                         f"{plane.name} | {line.name}", []).extend(found)
     return {"devices": devices, "host_spans": host_spans,
-            "page_walk": page_walk, "prefill_rows": prefill_rows}
+            "page_walk": page_walk, "prefill_rows": prefill_rows,
+            "startup_programs": startup_programs}
 
 
 def _union(intervals) -> list:
@@ -348,8 +356,17 @@ def summarize(trace_dir):
         click.echo(f"prefill computed {rows['rows']} rows for "
                    f"{rows['tokens']} tokens, "
                    f"{100 * rows['tokens'] / rows['rows']:.1f} %")
+    totals = host_span_totals(spans)
     if spans:
         click.echo("host spans (calls, self seconds):")
-        for name, (n, sec) in sorted(host_span_totals(spans).items(),
-                                     key=lambda kv: -kv[1][1]):
+        for name, (n, sec) in sorted(totals.items(), key=lambda kv: -kv[1][1]):
             click.echo(f"    {name:<40} {n:>6} x {sec:9.4f} s")
+    startup = {k: v for k, v in totals.items()
+               if k.startswith(SPAN_PREFIX + "startup.")}
+    if startup:
+        click.echo(f"start-up: {sum(sec for _, sec in startup.values()):.4f} "
+                   f"s under llmctl.startup.* spans in this trace; programs "
+                   f"first called (trace, lowering, compile or cache read):")
+        for name, sec in sorted(loaded["startup_programs"],
+                                key=lambda kv: -kv[1]):
+            click.echo(f"    {name:<40} {sec:9.4f} s")

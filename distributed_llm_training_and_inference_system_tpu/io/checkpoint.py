@@ -34,6 +34,7 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
+from ..metrics.spans import STARTUP
 from ..utils.tree import flatten_with_paths
 
 _COMMIT = "COMMIT"
@@ -245,6 +246,10 @@ class CheckpointManager:
         structure; ``shardings`` (optional, same structure) places leaves
         on devices. Returns (state, extra_metadata).
         """
+        with STARTUP.phase("llmctl.startup.restore"):
+            return self._restore(step, target, shardings)
+
+    def _restore(self, step, target, shardings) -> tuple[Any, dict]:
         if step is None:
             step = self.latest_step()
         if step is None:

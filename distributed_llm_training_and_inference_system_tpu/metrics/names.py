@@ -76,6 +76,11 @@ METRICS: dict[str, MetricSpec] = {
     "llmctl_engine_phase_seconds_total": MetricSpec(
         COUNTER, "Engine-thread self time by llmctl.engine.* span",
         ("phase",)),
+    "llmctl_startup_phase_seconds": MetricSpec(
+        GAUGE, "Self seconds of the process's start-up by llmctl.startup.* "
+               "span (a program's first call is phase "
+               "llmctl.startup.program; it grows when one compiles later)",
+        ("phase",)),
     "llmctl_moe_expert_choices_total": MetricSpec(
         COUNTER, "Live tokens' choices of an MoE model's expert, summed "
                  "over its layers", ("expert",)),

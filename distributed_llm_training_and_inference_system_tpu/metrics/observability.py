@@ -219,6 +219,8 @@ class PrometheusExporter:
         # the engine thread's self time by llmctl.engine.* span
         # (metrics/spans.py), from the running totals of engine.stats()
         self.engine_phase_seconds = mk("llmctl_engine_phase_seconds_total")
+        # the process's start-up (engine.stats()["startup"]["phases"])
+        self.startup_phase_seconds = mk("llmctl_startup_phase_seconds")
         # an MoE model's routing (engine.stats()["moe"]["choices"])
         self.moe_expert_choices = mk("llmctl_moe_expert_choices_total")
         self.decode_tokens_per_sec = mk("llmctl_decode_tokens_per_sec")
@@ -412,6 +414,8 @@ class PrometheusExporter:
             if delta > 0:
                 self.engine_phase_seconds.labels(phase=phase).inc(delta)
             self._last_totals[key] = cell["s"]
+        for phase, cell in m.get("startup_phases", {}).items():
+            self.startup_phase_seconds.labels(phase=phase).set(cell["s"])
         for expert, total in enumerate(m.get("moe_choices", ())):
             key = f"moe:{expert}"
             delta = total - self._last_totals.get(key, 0)

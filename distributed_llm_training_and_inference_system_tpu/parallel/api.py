@@ -9,6 +9,7 @@ train step; XLA inserts every collective.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable, Optional
 
@@ -18,6 +19,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config.schema import ModelConfig, OptimizerConfig, ParallelConfig
 from ..exec.train_step import TrainState, make_eval_step, make_train_step
+from ..metrics.spans import STARTUP
 from ..models import gpt
 from .mesh import build_mesh
 from .sharding import batch_specs, param_specs, use_mesh
@@ -98,6 +100,17 @@ class ShardedTrainer:
             self._batch_spec_fn = functools.partial(batch_specs, mesh=self.mesh)
         self.state: Optional[TrainState] = None
         self._steps_dispatched = 0
+        # programs whose first call (trace, lowering, compile) has been
+        # made under its llmctl.startup.program span
+        self._first_called: set[str] = set()
+
+    def _first_call(self, name: str):
+        """The ``llmctl.startup.program`` span of a program's first call; a
+        later call gets a context that does nothing."""
+        if name in self._first_called:
+            return contextlib.nullcontext()
+        self._first_called.add(name)
+        return STARTUP.program(name)
 
     # -- state ---------------------------------------------------------------
 
@@ -110,7 +123,9 @@ class ShardedTrainer:
             params = gpt.init(self.model_cfg, jax.random.PRNGKey(seed))
             return TrainState.create(params, self.tx)
 
-        with use_mesh(self.mesh):
+        # ``make`` closes over the seed, so every seed is a new program
+        with STARTUP.phase("llmctl.startup.params"), \
+                STARTUP.program("init_state"), use_mesh(self.mesh):
             self.state = jax.jit(make, out_shardings=self._state_shardings)()
         return self.state
 
@@ -139,7 +154,8 @@ class ShardedTrainer:
                 use_mesh(self.mesh):
             with TraceAnnotation("llmctl.train.shard_batch"):
                 batch = self.shard_batch(batch)
-            with TraceAnnotation("llmctl.train.dispatch"):
+            with TraceAnnotation("llmctl.train.dispatch"), \
+                    self._first_call("train_step"):
                 self.state, metrics = self.train_step(self.state, batch)
         return metrics
 
@@ -168,8 +184,9 @@ class ShardedTrainer:
         with use_mesh(self.mesh):
             # eval always runs the plain (non-pipelined) forward on [B, S]
             shardings = _to_shardings(batch_specs(batch, self.mesh), self.mesh)
-            return self.eval_step(self.state.params,
-                                  jax.device_put(batch, shardings))
+            with self._first_call("eval_step"):
+                return self.eval_step(self.state.params,
+                                      jax.device_put(batch, shardings))
 
     # -- introspection -------------------------------------------------------
 

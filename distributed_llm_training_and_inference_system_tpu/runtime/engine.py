@@ -24,9 +24,11 @@ import numpy as np
 from ..config.schema import RunConfig
 from ..io.checkpoint import CheckpointManager
 from ..io.data import make_dataset
+from ..metrics.spans import STARTUP
 from ..models.gpt import flops_per_token
 from ..parallel.api import ShardedTrainer
 from ..parallel.mesh import infer_data_parallel
+from ..utils import platform
 from ..utils.platform import chip_peaks
 
 logger = logging.getLogger("llmctl.engine")
@@ -39,7 +41,7 @@ class TrainingEngine:
         events — the hook metrics/observability.py plugs into (closing the
         reference's unwired-metrics gap, SURVEY §5.5)."""
         self.cfg = cfg
-        devices = devices if devices is not None else jax.devices()
+        devices = devices if devices is not None else platform.devices()
         self.par = infer_data_parallel(cfg.parallel, len(devices))
         self._start_step = 0
         attn_impl = cfg.training.attn_impl
@@ -64,16 +66,19 @@ class TrainingEngine:
 
         host_id, num_hosts = jax.process_index(), jax.process_count()
         per_host_batch = (self.par.global_batch_size // num_hosts)
-        self.train_data = make_dataset(
-            cfg.data.train, per_host_batch, cfg.data.max_length,
-            cfg.model.vocab_size, seed=cfg.data.seed, host_id=host_id,
-            num_hosts=num_hosts, pack=cfg.data.pack_sequences,
-            num_workers=cfg.data.num_workers,
-            prefetch=cfg.data.prefetch_factor)
-        self.val_data = make_dataset(
-            cfg.data.val, per_host_batch, cfg.data.max_length,
-            cfg.model.vocab_size, seed=cfg.data.seed + 1, host_id=host_id,
-            num_hosts=num_hosts, pack=cfg.data.pack_sequences)
+        # tokenizer, index and (with workers) the first batches' prefetch
+        with STARTUP.phase("llmctl.startup.data"):
+            self.train_data = make_dataset(
+                cfg.data.train, per_host_batch, cfg.data.max_length,
+                cfg.model.vocab_size, seed=cfg.data.seed, host_id=host_id,
+                num_hosts=num_hosts, pack=cfg.data.pack_sequences,
+                num_workers=cfg.data.num_workers,
+                prefetch=cfg.data.prefetch_factor)
+            self.val_data = make_dataset(
+                cfg.data.val, per_host_batch, cfg.data.max_length,
+                cfg.model.vocab_size, seed=cfg.data.seed + 1,
+                host_id=host_id, num_hosts=num_hosts,
+                pack=cfg.data.pack_sequences)
         self.ckpt = CheckpointManager(
             cfg.checkpoint.path, keep_latest=cfg.checkpoint.keep_latest,
             async_save=cfg.checkpoint.async_save)
@@ -147,6 +152,11 @@ class TrainingEngine:
             batch = next(self.train_data)
             metrics = self.trainer.step(batch)
             window_tokens += float(batch["tokens"].size) * jax.process_count()
+            if STARTUP.ready_t is None:
+                # the first finished step ends start-up: one fetch, once
+                jax.block_until_ready(metrics["loss"])
+                STARTUP.ready()
+                logger.info(STARTUP.summary())
 
             if (step + 1) % t_cfg.log_interval == 0 or step + 1 == max_steps:
                 # block only at log boundaries: keeps the device queue full
@@ -233,3 +243,6 @@ class TrainingEngine:
         loss = float(np.sum([l * c for l, c in zip(losses, counts)])) / max(total, 1)
         return {"loss": loss, "perplexity": float(np.exp(min(loss, 30.0))),
                 "tokens": total}
+
+
+STARTUP.imported()      # an entry module: llmctl.startup.import ends here

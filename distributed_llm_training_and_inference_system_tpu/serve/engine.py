@@ -44,7 +44,7 @@ from .sampling import fold_in_key_data, sample_tokens, seed_key_data
 from .scheduler import (ContinuousBatchingScheduler, Request, RequestState,
                         SamplingParams)
 from ..analysis.annotations import engine_thread_only
-from ..metrics.spans import SpanRecorder
+from ..metrics.spans import STARTUP, SpanRecorder
 
 logger = logging.getLogger("llmctl.serve.engine")
 
@@ -72,16 +72,32 @@ class _Program:
         compiles the result to read the decode program's memory analysis."""
         return self._fn.lower(*args)
 
+    def compiled_text(self, *args) -> str:
+        """The optimised HLO of the program compiled for ``args`` and not
+        run; the compile is a start-up span and a ledger entry of its own
+        (``program_texts()`` pays it after the window)."""
+        with STARTUP.program(f"{self.name} (text)"):
+            return self.lower(*args).compile().as_text()
+
     def __call__(self, *args):
+        if self._ran:
+            return self._fn(*args)
+        # the first call traces, lowers and compiles (or reads the compile
+        # cache): ONE llmctl.startup.program span and its ledger entry
         try:
-            out = self._fn(*args)
+            with STARTUP.program(self.name) as span:
+                out = self._fn(*args)
         except Exception as e:
-            if not self._ran:
-                self._failed[self.name] = f"{type(e).__name__}: {e}"[:400]
+            self._failed[self.name] = f"{type(e).__name__}: {e}"[:400]
             raise
-        if not self._ran:
-            self._ran = True
-            self._failed.pop(self.name, None)
+        self._ran = True
+        self._failed.pop(self.name, None)
+        if STARTUP.ready_t is not None:
+            logger.warning(
+                "program %r first ran after the server was ready: %.2f s "
+                "under traffic (GET /v1/stats \"startup\" has its trace, "
+                "lowering, compile and cache-read seconds)",
+                self.name, span.seconds)
         return out
 
 
@@ -113,8 +129,9 @@ class InferenceEngine:
             # the artifact may override architecture facts (e.g. an
             # HF-imported tied-embedding checkpoint under an untied
             # template) — the effective config comes back with the params
-            params, model_cfg, self.quantization = self._load_params(
-                model_cfg, serve_cfg, seed, dtype)
+            with STARTUP.phase("llmctl.startup.params"):
+                params, model_cfg, self.quantization = self._load_params(
+                    model_cfg, serve_cfg, seed, dtype)
         self.cfg = model_cfg
         # what a model with state-space layers turns off: every feature
         # below moves, shares or re-enters K/V PAGES, and a recurrent
@@ -219,13 +236,15 @@ class InferenceEngine:
         self.params = params
 
         S = serve_cfg.max_batch_size
-        self.kv = PagedKVCache(
-            model_cfg, num_slots=S, max_seq_len=serve_cfg.max_seq_len,
-            page_size=serve_cfg.kv_block_size,
-            num_pages=serve_cfg.kv_num_blocks,
-            hbm_budget_gb=serve_cfg.kv_hbm_budget_gb, dtype=dtype,
-            page_sharding=page_sharding,
-            quantized=serve_cfg.kv_quantization)
+        # the K/V (or latent) pages and a recurrent model's state pools
+        with STARTUP.phase("llmctl.startup.pools"):
+            self.kv = PagedKVCache(
+                model_cfg, num_slots=S, max_seq_len=serve_cfg.max_seq_len,
+                page_size=serve_cfg.kv_block_size,
+                num_pages=serve_cfg.kv_num_blocks,
+                hbm_budget_gb=serve_cfg.kv_hbm_budget_gb, dtype=dtype,
+                page_sharding=page_sharding,
+                quantized=serve_cfg.kv_quantization)
 
         self._req_slot: dict[str, int] = {}
         # pages promised to admitted-but-not-yet-prefilled requests; without
@@ -2436,6 +2455,10 @@ class InferenceEngine:
             # cumulative, with their own clock: "clock_s", "phases"
             # ({span: {"s": self seconds, "n": calls}}), "starved_s"
             **self.spans.snapshot(),
+            # the PROCESS's start-up, on the same clock: llmctl.startup.*
+            # phases and one "programs" entry a program's first call
+            # (metrics/spans.py StartupRecorder)
+            "startup": STARTUP.snapshot(),
         }
 
     def program_texts(self) -> dict:
@@ -2455,38 +2478,32 @@ class InferenceEngine:
         i32 = jnp.int32
         texts = {}
         if self._decode_jit is not None:
-            texts[self._decode_jit.name] = self._decode_jit.lower(
+            texts[self._decode_jit.name] = self._decode_jit.compiled_text(
                 *common, *shapes((jnp.asarray(self.last_tokens),
                                   jnp.asarray(self.positions),
                                   *self._shared_decode_args())),
-                *state).compile().as_text()
+                *state)
         sampling = shapes(self._sampling_args(seed_key_data(0), 0,
                                               SamplingParams()))
         for bucket in [k for k in list(self._prefill_cache)
                        if isinstance(k, int)]:
             program = self._prefill_cache[bucket]
-            texts[program.name] = program.lower(
+            texts[program.name] = program.compiled_text(
                 common[0], jax.ShapeDtypeStruct((1, bucket), i32),
                 jax.ShapeDtypeStruct((1,), i32), common[1], common[2],
                 jax.ShapeDtypeStruct((bucket // self.kv.page_size,), i32),
                 *sampling, *state,
-                *((jax.ShapeDtypeStruct((), i32),) if state else ())
-            ).compile().as_text()
+                *((jax.ShapeDtypeStruct((), i32),) if state else ()))
         return texts
 
     def compiled_programs(self) -> dict:
-        """Resident compiled-program inventory by kind. Battery 9 measured
-        an 18% saturation-goodput loss from merely ENABLING the short-
-        dispatch program (zero short dispatches fired — the cost is a side
-        effect of the second resident decode executable, mechanism under
-        diagnosis in experiments/adapt_diag.py). Prefill buckets,
-        pipelining, and speculation all multiply resident executables the
-        same way, so the count is first-class observable state: a user
-        seeing an unexplained throughput delta can check whether the
-        program population changed before suspecting the schedule. (More
-        resident PREFILL programs have since measured free: three reached
-        where there were two left the decode step where it was, PERF.md
-        6, PR 30. What each costs is set-up time.)"""
+        """Resident compiled-program inventory by kind: prefill buckets,
+        pipelining and speculation all multiply resident executables, so
+        the count is first-class observable state: a user seeing an
+        unexplained throughput or start-up delta can check whether the
+        program population changed before suspecting the schedule. What
+        each program cost (trace, lowering, compile or cache read, and
+        when) is ``stats()["startup"]["programs"]``."""
         # snapshot: the engine thread inserts new buckets lock-free while
         # a stats request iterates — list() prevents "dict changed size"
         keys = list(self._prefill_cache)

@@ -27,6 +27,7 @@ from typing import Optional
 from aiohttp import web
 
 from ..config.schema import ModelConfig, ServeConfig
+from ..metrics.spans import STARTUP
 from .engine import InferenceEngine
 from .scheduler import Request, RequestState, SamplingParams
 from .tokenizer import load_tokenizer
@@ -380,6 +381,7 @@ class InferenceServer:
             # histogram and the engine's spans, not this request's own
             "queue_wait_ms": st["queue_wait_ms"],
             "phases": st["phases"],
+            "startup_phases": st["startup"]["phases"],
             "moe_choices": st.get("moe", {}).get("choices", ()),
             "preemptions": st["preemptions"],
             "swap_ins": st["swap_ins"],
@@ -503,6 +505,10 @@ class InferenceServer:
         await site.start()
         logger.info("serving %s on %s:%d", self.model_cfg.name,
                     self.serve_cfg.host, self.serve_cfg.port)
+        # ready: a program that first runs from here on compiles under
+        # traffic and says so (serve/engine.py _Program)
+        STARTUP.ready()
+        logger.info(STARTUP.summary())
         return runner
 
     def run_forever(self) -> None:
@@ -535,3 +541,6 @@ def create_server(model_cfg: ModelConfig, serve_cfg: ServeConfig,
                            observer=observer)
     return InferenceServer(model_cfg, serve_cfg, params=params,
                            observer=observer)
+
+
+STARTUP.imported()      # an entry module: llmctl.startup.import ends here

@@ -39,15 +39,26 @@ def enable_compile_cache() -> str:
     return path
 
 
+def devices() -> list:
+    """``jax.devices()`` under the ``llmctl.startup.backend`` span: the
+    process's first call discovers the devices and initialises the backend
+    (seconds on a TPU host; nothing where a caller, as the benchmark's
+    harness, has asked JAX itself before)."""
+    import jax
+
+    from ..metrics.spans import STARTUP
+    with STARTUP.phase("llmctl.startup.backend"):
+        return jax.devices()
+
+
 def device_summary() -> dict:
     """The device as JAX reports it to THIS process (imports jax and
     initialises the backend — only the process that owns the chip calls
     this)."""
-    import jax
-    devices = jax.devices()
-    return {"platform": devices[0].platform,
-            "kind": devices[0].device_kind,
-            "count": len(devices)}
+    found = devices()
+    return {"platform": found[0].platform,
+            "kind": found[0].device_kind,
+            "count": len(found)}
 
 
 def device_line() -> str:

@@ -111,15 +111,17 @@ def test_starved_only_while_busy_and_nothing_in_flight(busy, in_flight,
 
 
 def test_starved_stops_at_the_dispatch_and_resumes_at_the_fetch():
-    rec = SpanRecorder()
+    now = [50.0]                          # a clock the test steps: no sleep
+    rec = SpanRecorder(clock=lambda: now[0])
     rec.set_busy(True)
-    time.sleep(TICK)                      # starved
+    now[0] += TICK                        # starved
     rec.dispatched()
-    time.sleep(3 * TICK)                  # a program runs
+    now[0] += 3 * TICK                    # a program runs
     rec.fetched()
-    time.sleep(TICK)                      # starved again, still open
-    got = rec.snapshot()["starved_s"]
-    assert 2 * TICK <= got < 3 * TICK, got
+    now[0] += TICK                        # starved again, still open
+    snap = rec.snapshot()
+    assert snap["starved_s"] == pytest.approx(2 * TICK)
+    assert snap["clock_s"] == now[0]
     rec.dispatched()
     rec.reset_in_flight()                 # fail_all: nothing will be fetched
     assert rec.in_flight == 0
