@@ -145,13 +145,15 @@ def load_profile(path: str) -> dict:
     every other plane, by the line (the thread) they were opened on.
     "page_walk" sums the ``live_pages`` / ``table_pages`` ids that every
     decode dispatch's span carries (serve/engine.py ``_submit_group``), and
-    for a model with state-space layers its ``ssm_slot_steps``, the prompt
+    for a model with state-space layers its ``ssm_slot_steps`` (``K``
+    layers: ``kda_slot_steps``), the prompt
     rows that rode the dispatch's steps (``ride_rows``), for one
     with latent attention the bytes of a latent page over its layers
     (``latent_page_bytes``, the last seen, not a sum);
     "prefill_rows" the ``bucket`` (rows the program computed), ``tokens``
-    less ``cached`` (the live ones) and ``cached`` (the prompt tokens the
-    prefix cache supplied) of every prefill span; "startup_programs" the
+    less ``cached`` (the live ones), ``cached`` (the prompt tokens the
+    prefix cache supplied) and ``state_carry`` (the tokens of chunk programs
+    that read and wrote a slot's recurrent state) of every prefill span; "startup_programs" the
     ``(name, seconds)`` of every ``llmctl.startup.program`` span (a
     program's first call: metrics/spans.py ``StartupRecorder``)."""
     from jax.profiler import ProfileData
@@ -159,8 +161,8 @@ def load_profile(path: str) -> dict:
     devices: dict = {}
     host_spans: dict = {}
     page_walk = {"live_pages": 0, "table_pages": 0, "ssm_slot_steps": 0,
-                 "latent_page_bytes": 0, "ride_rows": 0}
-    prefill_rows = {"rows": 0, "tokens": 0, "cached": 0}
+                 "kda_slot_steps": 0, "latent_page_bytes": 0, "ride_rows": 0}
+    prefill_rows = {"rows": 0, "tokens": 0, "cached": 0, "state_carry": 0}
     startup_programs: list = []
     for plane in profile.planes:
         is_device = plane.name.startswith("/device:")
@@ -193,6 +195,8 @@ def load_profile(path: str) -> dict:
                         # (a chunked prefill's first span carries its
                         # cached tokens and no bucket)
                         prefill_rows["cached"] += int(ids.get("cached", 0))
+                        prefill_rows["state_carry"] += int(
+                            ids.get("state_carry", 0))
                         if "bucket" in ids:
                             prefill_rows["rows"] += int(ids["bucket"])
                             prefill_rows["tokens"] += (
@@ -346,6 +350,14 @@ def summarize(trace_dir):
         click.echo(f"state-space layers advanced {walk['ssm_slot_steps']} "
                    f"slot states (live slots x decode steps), summed over "
                    f"the decode dispatches")
+    if walk["kda_slot_steps"]:
+        click.echo(f"delta-rule (K) layers advanced {walk['kda_slot_steps']} "
+                   f"slot states (live slots x decode steps), summed over "
+                   f"the decode dispatches")
+    if loaded["prefill_rows"]["state_carry"]:
+        click.echo(f"{loaded['prefill_rows']['state_carry']} prompt tokens "
+                   f"went chunk by chunk through programs that read and "
+                   f"wrote their slot's recurrent state")
     if walk["ride_rows"]:
         click.echo(f"{walk['ride_rows']} prompt rows rode the decode "
                    f"dispatches (prefilled as rows of the decode steps, no "

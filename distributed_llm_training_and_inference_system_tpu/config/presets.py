@@ -226,6 +226,32 @@ XING_TEST_PUBLISHED = {
 }
 TEST_TEMPLATES["xing-test"] = ModelConfig.from_published(XING_TEST_PUBLISHED)
 
+# Kimi-Linear's shape in small, in its published keys (the plain reference
+# of the benchmark reads these): two periods ``K K K *`` of delta-rule
+# linear attention beside latent attention without a query bottleneck and
+# without rope, one leading dense layer, then sigmoid-routed experts with a
+# shared one, HALF of the router's 8 experts held here. The ``K`` layers'
+# chunk (published 64) is 8 here, so that a short test prompt spans several
+KIMI_LINEAR_TEST_PUBLISHED = {
+    "name": "kimi-linear-test", "model_type": "kimi_linear",
+    "num_hidden_layers": 8, "first_k_dense_replace": 1, "hidden_size": 64,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "q_lora_rank": None,
+    "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+    "v_head_dim": 16, "mla_use_nope": True, "intermediate_size": 96,
+    "moe_intermediate_size": 32, "num_experts": 4, "router_experts": 8,
+    "first_expert": 0, "num_experts_per_token": 3, "num_shared_experts": 1,
+    "moe_renormalize": True, "moe_router_activation_func": "sigmoid",
+    "routed_scaling_factor": 2.446, "num_expert_group": 1, "topk_group": 1,
+    "vocab_size": 256, "max_position_embeddings": 512, "rms_norm_eps": 1e-5,
+    "dtype": "float32", "rope_theta": 10000.0, "hidden_act": "silu",
+    "linear_attn_config": {
+        "num_heads": 4, "head_dim": 16, "short_conv_kernel_size": 4,
+        "kda_layers": [1, 2, 3, 5, 6, 7],
+        "full_attn_layers": [4, 8]},
+}
+TEST_TEMPLATES["kimi-linear-test"] = ModelConfig.from_published(
+    KIMI_LINEAR_TEST_PUBLISHED)
+
 
 def get_model_config(name: str) -> ModelConfig:
     """Look up a template by name (also accepts test templates), or read a

@@ -109,7 +109,10 @@ class MLAConfig:
     ``v_head_dim`` keys): queries through a low-rank bottleneck, keys and
     values expanded from ONE compressed row of ``kv_lora_rank`` values a
     token, plus ``qk_rope_head_dim`` rotated values shared by every head.
-    That row (``latent_size`` values) is all the cache keeps."""
+    That row (``latent_size`` values) is all the cache keeps.
+    ``q_lora_rank`` 0 (the published ``null``) is a DIRECT query projection,
+    hidden -> heads x (nope + rope), with no bottleneck and no query norm
+    (``kimi_linear``)."""
     q_lora_rank: int = 0
     kv_lora_rank: int = 0           # 0 = the model has plain q / k / v
     qk_nope_head_dim: int = 0
@@ -132,7 +135,7 @@ class MLAConfig:
     @classmethod
     def from_dict(cls, d: dict[str, Any] | None) -> "MLAConfig":
         d = d or {}
-        return cls(**{f.name: int(d.get(f.name, 0))
+        return cls(**{f.name: int(d.get(f.name) or 0)
                       for f in dataclasses.fields(cls)})
 
 
@@ -202,7 +205,13 @@ class MoEConfig:
         ``nemotron_h`` takes)."""
         if not d:
             return cls()
-        routed = "n_routed_experts" in d
+        # ``kimi_linear`` spells the same router ``moe_router_activation_func``
+        # / ``moe_renormalize`` / ``num_experts_per_token`` /
+        # ``num_shared_experts``
+        routed = ("n_routed_experts" in d or str(d.get(
+            "moe_router_activation_func", "")) == "sigmoid")
+        shared = int(_take(d, "n_shared_experts", "num_shared_experts",
+                           default=0) or 0)
         return cls(
             num_experts=int(_take(d, "num_experts", "experts",
                                   "n_routed_experts", default=0)),
@@ -218,15 +227,15 @@ class MoEConfig:
             shared_expert_size=int(_take(
                 d, "shared_expert_size",
                 "moe_shared_expert_intermediate_size", default=(
-                    d.get("moe_intermediate_size", 0)
-                    if d.get("n_shared_experts") else 0)))
-            * int(_take(d, "n_shared_experts", default=1)),
+                    d.get("moe_intermediate_size", 0) if shared else 0)))
+            * max(shared, 1),
             experts_per_token=int(_take(d, "experts_per_token", "top_k",
-                                        "num_experts_per_tok", default=2)),
+                                        "num_experts_per_tok",
+                                        "num_experts_per_token", default=2)),
             router_aux_loss_weight=float(_take(d, "router_aux_loss_weight", default=0.01)),
             capacity_factor=float(_take(d, "capacity_factor", default=1.25)),
             norm_topk_prob=_parse_bool("norm_topk_prob", _take(
-                d, "norm_topk_prob", default=True)),
+                d, "norm_topk_prob", "moe_renormalize", default=True)),
         )
 
 
@@ -285,10 +294,54 @@ class SSMConfig:
         )
 
 
+@dataclass
+class KDAConfig:
+    """Kimi Delta Attention sizes (the ``K`` layers of a layer table; the
+    published ``linear_attn_config``): ``num_heads`` heads, each a
+    ``head_dim`` x ``head_dim`` float32 state moved by the gated delta rule
+    with one decay a CHANNEL of the key (ops/kda.py), a depthwise causal
+    conv of width ``conv_kernel`` over q, k and v. The two low-rank pairs (decay and output gate) have
+    the rank ``head_dim``. The state is cached in float32, by construction
+    (serve/kv_cache.py), as ``SSMConfig``'s."""
+    num_heads: int = 0              # 0 = the model has no such layer
+    head_dim: int = 128
+    conv_kernel: int = 4
+
+    @property
+    def inner_size(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def conv_channels(self) -> int:
+        """q, k and v side by side: one conv window a slot."""
+        return 3 * self.inner_size
+
+    @property
+    def in_proj_size(self) -> int:
+        """[q | k | v | decay low-rank | gate low-rank | beta]."""
+        return self.conv_channels + 2 * self.head_dim + self.num_heads
+
+    @classmethod
+    def from_dict(cls, d: dict[str, Any] | None) -> "KDAConfig":
+        """The nested ``kda`` table, or the published
+        ``linear_attn_config`` group (``num_heads``, ``head_dim``,
+        ``short_conv_kernel_size``; its layer lists make the table,
+        ``ModelConfig.from_dict``)."""
+        if not d:
+            return cls()
+        return cls(
+            num_heads=int(d["num_heads"]),
+            head_dim=int(d.get("head_dim", 128)),
+            conv_kernel=int(_take(d, "conv_kernel",
+                                  "short_conv_kernel_size", default=4)),
+        )
+
+
 # what a layer of a layer table may be (``nemotron_h``'s own letters)
 # ``D`` (this repo's letter): a dense gated MLP as a layer of its own, the
 # feed-forward of a leading dense layer before the expert layers
-LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "moe", "D": "mlp"}
+# ``K`` (this repo's letter): a Kimi Delta Attention mixer
+LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "moe", "D": "mlp", "K": "kda"}
 
 
 @dataclass
@@ -330,8 +383,12 @@ class ModelConfig:
     # feed-forward under two norms).
     layer_pattern: str = ""
     ssm: SSMConfig = field(default_factory=SSMConfig)
+    kda: KDAConfig = field(default_factory=KDAConfig)
     # "rope" | "none": ``nemotron_h``'s attention applies no position
-    # embedding (positions come from the state-space layers)
+    # embedding (positions come from the state-space layers), and
+    # ``kimi_linear``'s latent attention carries and scores its ``pe``
+    # values without rotating them (``mla_use_nope``: positions come from
+    # the ``K`` layers)
     position_embedding: str = "rope"
     # False: the feed-forward is down(act(up(x))), two kernels (no gate)
     mlp_gated: bool = True
@@ -400,10 +457,20 @@ class ModelConfig:
         return self.layers_of("M")
 
     @property
+    def kda_layers(self) -> int:
+        return self.layers_of("K")
+
+    @property
+    def recurrent_name(self) -> str:
+        """What a refusal calls this model's recurrent layers."""
+        return ("delta-rule linear-attention (K) layers" if self.kda_layers
+                else "state-space layers")
+
+    @property
     def is_recurrent(self) -> bool:
         """Some layer keeps a fixed-size state a sequence beside (or in
-        place of) K/V pages."""
-        return self.ssm_layers > 0
+        place of) K/V or latent pages."""
+        return self.ssm_layers > 0 or self.kda_layers > 0
 
     def validate(self) -> None:
         # hidden_size need not equal num_heads*head_dim (projections go
@@ -428,7 +495,7 @@ class ModelConfig:
                 raise ConfigError(
                     f"layer_pattern {self.layer_pattern!r}: no layer kind "
                     f"{unknown} (known: M state-space, * attention, E "
-                    "experts, D dense MLP)")
+                    "experts, D dense MLP, K delta-rule linear attention)")
             if len(self.layer_pattern) != self.num_layers:
                 raise ConfigError(
                     f"layer_pattern has {len(self.layer_pattern)} layers, "
@@ -444,6 +511,16 @@ class ModelConfig:
                     "layer_pattern has M layers: ssm.num_heads must be a "
                     "positive multiple of ssm.n_groups, conv_kernel >= 2 "
                     f"(got {s})")
+            if self.layers_of("M") and self.layers_of("K"):
+                raise ConfigError(
+                    "layer_pattern has M and K layers: one recurrent kind "
+                    "a model (the state pools hold one kind's rows)")
+            k = self.kda
+            if self.layers_of("K") and (
+                    k.num_heads < 1 or k.head_dim < 1 or k.conv_kernel < 2):
+                raise ConfigError(
+                    "layer_pattern has K layers: kda.num_heads and head_dim "
+                    f"must be >= 1, conv_kernel >= 2 (got {k})")
         if self.is_latent or self.hc_mult > 1:
             a = self.mla
             if not self.layer_pattern:
@@ -452,14 +529,16 @@ class ModelConfig:
                     "layer table: give layer_pattern (or the published "
                     "num_hidden_layers / first_k_dense_replace)")
             if self.is_latent and (
-                    min(a.q_lora_rank, a.qk_nope_head_dim, a.v_head_dim) < 1
+                    min(a.qk_nope_head_dim, a.v_head_dim) < 1
+                    or a.q_lora_rank < 0
                     or a.qk_rope_head_dim < 2 or a.qk_rope_head_dim % 2
                     or self.head_dim != a.qk_nope_head_dim
                     + a.qk_rope_head_dim
                     or self.num_kv_heads != self.num_heads):
                 raise ConfigError(
-                    "latent attention needs q_lora_rank, qk_nope_head_dim, "
-                    "v_head_dim >= 1, an even qk_rope_head_dim, head_dim = "
+                    "latent attention needs qk_nope_head_dim, v_head_dim "
+                    ">= 1, q_lora_rank >= 0 (0: a direct query projection), "
+                    "an even qk_rope_head_dim, head_dim = "
                     "nope + rope and num_kv_heads = num_heads (got "
                     f"{a}, head_dim {self.head_dim})")
             if self.hc_mult < 1 or self.hc_sinkhorn_iters < 1:
@@ -501,13 +580,15 @@ class ModelConfig:
         attn = h * q_dim + 2 * h * kv_dim + q_dim * h
         if self.layer_pattern:
             # one norm and one mixer a layer; the experts HELD here
-            s, m = self.ssm, self.moe
+            s, m, kd = self.ssm, self.moe, self.kda
             per_expert = (3 if self.mlp_gated else 2) * h
             a, n = self.mla, self.num_heads
             if self.is_latent:
-                # q_a, its norm, q_b, kv_a, the latent's norm, kv_b, o
-                attn = (h * a.q_lora_rank + a.q_lora_rank
-                        + a.q_lora_rank * n * self.head_dim
+                # q_a, its norm, q_b (or the one direct q), kv_a, the
+                # latent's norm, kv_b, o
+                attn = ((h * a.q_lora_rank + a.q_lora_rank
+                         + a.q_lora_rank * n * self.head_dim
+                         if a.q_lora_rank else h * n * self.head_dim)
                         + h * a.latent_size + a.kv_lora_rank
                         + a.kv_lora_rank * n
                         * (a.qk_nope_head_dim + a.v_head_dim)
@@ -523,6 +604,11 @@ class ModelConfig:
                 + m.num_experts * per_expert * f
                 + per_expert * m.shared_expert_size,
                 "D": per_expert * (self.dense_ffn_size or f),
+                # the one input projection, the conv, the two low-rank
+                # pairs' second halves, A_log, dt_bias, the head norm, o
+                "K": h * kd.in_proj_size + kd.conv_kernel * kd.conv_channels
+                + 2 * kd.head_dim * kd.inner_size + kd.num_heads
+                + kd.inner_size + kd.head_dim + kd.inner_size * h,
             }
             # a hyper-connection a sub-layer: the maps' norm, phi, three
             # scalars, two bias vectors and a bias matrix
@@ -561,16 +647,31 @@ class ModelConfig:
                             default=""))
         layers = int(_take(d, "layers", "num_layers", "num_hidden_layers",
                            default=12))
+        linear = d.get("linear_attn_config") or {}
         if latent and not pattern and "num_hidden_layers" in d:
             # a published config.json counts decoder layers: each is an
             # attention sub-layer then a feed-forward one, two entries of
             # the table; the first ``first_k_dense_replace`` feed-forwards
-            # are dense MLPs, the rest experts
+            # are dense MLPs, the rest experts. ``kimi_linear`` lists,
+            # 1-indexed, which decoder layers mix by delta-rule linear
+            # attention (``K``) and which by latent attention (``*``)
             dense = int(_take(d, "first_k_dense_replace", default=0))
-            pattern = "".join("*D" if i < dense else "*E"
+            mixers = ["*"] * layers
+            if linear:
+                kda_at = [int(i) for i in linear.get("kda_layers", [])]
+                full_at = [int(i) for i in linear.get("full_attn_layers", [])]
+                if sorted(kda_at + full_at) != list(range(1, layers + 1)):
+                    raise ConfigError(
+                        f"linear_attn_config: kda_layers {kda_at} and "
+                        f"full_attn_layers {full_at} must between them name "
+                        f"each of the {layers} decoder layers once "
+                        "(1-indexed)")
+                mixers = ["K" if i + 1 in kda_at else "*"
+                          for i in range(layers)]
+            pattern = "".join(mixers[i] + ("D" if i < dense else "E")
                               for i in range(layers))
             layers = len(pattern)
-        for key in ("n_group", "topk_group"):
+        for key in ("n_group", "topk_group", "num_expert_group"):
             if latent and int(d.get(key, 1)) != 1:
                 raise ConfigError(
                     f"{key} = {d[key]}: group-limited routing is not "
@@ -614,6 +715,7 @@ class ModelConfig:
             qk_norm=str(_take(d, "qk_norm", default="none")),
             layer_pattern=pattern,
             ssm=SSMConfig.from_dict(d.get("ssm"), published=d),
+            kda=KDAConfig.from_dict(d.get("kda") or linear),
             mla=mla,
             # (a leading dense layer's width: ``intermediate_size`` beside
             # ``moe_intermediate_size`` where the file has such layers)
@@ -627,8 +729,9 @@ class ModelConfig:
                                      "mhc_h_res_clamp_min", default=-30.0)),
             hc_clamp_max=float(_take(d, "hc_clamp_max",
                                      "mhc_h_res_clamp_max", default=30.0)),
-            position_embedding=str(_take(d, "position_embedding",
-                                         default="rope")),
+            position_embedding=str(_take(
+                d, "position_embedding", default=(
+                    "none" if latent and d.get("mla_use_nope") else "rope"))),
             # squared ReLU comes without a gate (``nemotron_h``'s
             # ``mlp_hidden_act: relu2``) unless the dict says otherwise
             mlp_gated=_parse_bool("mlp_gated", _take(
@@ -646,8 +749,9 @@ class ModelConfig:
         d = {k: v for k, v in config.items()
              if not isinstance(v, (dict, list))}
         d["rope"] = {"base": config.get("rope_theta", 10000.0)}
-        if config.get("rope_scaling"):
-            d["rope_scaling"] = config["rope_scaling"]
+        for group in ("rope_scaling", "linear_attn_config"):
+            if config.get(group):
+                d[group] = config[group]
         return cls.from_dict(d)
 
     def to_dict(self) -> dict[str, Any]:

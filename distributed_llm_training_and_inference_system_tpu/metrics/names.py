@@ -92,6 +92,10 @@ METRICS: dict[str, MetricSpec] = {
         COUNTER, "Prompt tokens prefilled inside decode dispatches, as rows "
                  "of the decode steps (beside llmctl_engine_phase_seconds: "
                  "a busy batch stalls for no prefill program)"),
+    "llmctl_inference_state_carry_tokens": MetricSpec(
+        COUNTER, "Prompt tokens prefilled by chunk programs that read and "
+                 "wrote a slot's recurrent state (a K model's chunked "
+                 "prefill; stats()[\"kda\"] has the chunks beside them)"),
     "llmctl_inference_swapped_host_bytes": MetricSpec(
         GAUGE, "Host bytes held by swapped-out KV"),
     # -- fleet control plane ----------------------------------------------
@@ -418,6 +422,11 @@ COUNTER_FLOW: tuple[CounterFlow, ...] = (
                 "llmctl_inference_prefill_ride_tokens"),
     CounterFlow("InferenceEngine", "total_prefill_ride_steps",
                 "prefill_ride_steps", None),
+    CounterFlow("InferenceEngine", "total_state_carry_tokens",
+                "state_carry_tokens",
+                "llmctl_inference_state_carry_tokens"),
+    CounterFlow("InferenceEngine", "total_state_carry_chunks",
+                "state_carry_chunks", None),
     CounterFlow("InferenceEngine", "total_prefix_cached_tokens",
                 "prefix_cached_tokens", None),
     # feeds reprefill_tokens_avoided through the supervisor snapshot's

@@ -232,6 +232,7 @@ class PrometheusExporter:
         self.infer_preemptions = mk("llmctl_inference_preemptions")
         self.infer_swap_ins = mk("llmctl_inference_swap_ins")
         self.infer_ride_tokens = mk("llmctl_inference_prefill_ride_tokens")
+        self.infer_carry_tokens = mk("llmctl_inference_state_carry_tokens")
         self.infer_swapped_bytes = mk("llmctl_inference_swapped_host_bytes")
         # serve-fleet control plane (serve/fleet/): per-replica health the
         # operator alarms on. Queue depth + outstanding tokens are the
@@ -427,7 +428,9 @@ class PrometheusExporter:
             self.decode_tokens_per_sec.set(m["decode_tokens_per_sec"])
         for key, counter in (("preemptions", self.infer_preemptions),
                              ("swap_ins", self.infer_swap_ins),
-                             ("prefill_ride_tokens", self.infer_ride_tokens)):
+                             ("prefill_ride_tokens", self.infer_ride_tokens),
+                             ("state_carry_tokens",
+                              self.infer_carry_tokens)):
             if key in m:
                 delta = m[key] - self._last_totals.get(key, 0)
                 if delta > 0:

@@ -147,13 +147,21 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
             "out_proj": {"kernel": dense(next(keys), Lm, d_in, H,
                                          scale=resid_std)},
         }
+    if cfg.kda_layers:
+        blocks["kda"] = _init_kda_blocks(cfg, next(keys), norm_init, dense,
+                                         resid_std, dtype)
     if La and cfg.is_latent:
         a = cfg.mla
+        # through a bottleneck and its norm, or (``q_lora_rank`` 0) direct
+        query = ({"q_a": {"kernel": dense(next(keys), La, H, a.q_lora_rank)},
+                  "q_a_norm": {"scale": norm_init(La, a.q_lora_rank)},
+                  "q_b": {"kernel": dense(next(keys), La, a.q_lora_rank,
+                                          Nq * D)}}
+                 if a.q_lora_rank else
+                 {"q": {"kernel": dense(next(keys), La, H, Nq * D)}})
         blocks["attn"] = {
             "norm": {"scale": norm_init(La, H)},
-            "q_a": {"kernel": dense(next(keys), La, H, a.q_lora_rank)},
-            "q_a_norm": {"scale": norm_init(La, a.q_lora_rank)},
-            "q_b": {"kernel": dense(next(keys), La, a.q_lora_rank, Nq * D)},
+            **query,
             "kv_a": {"kernel": dense(next(keys), La, H, a.latent_size)},
             "kv_norm": {"scale": norm_init(La, a.kv_lora_rank)},
             # a head's [k_nope | v] side by side, as the published kv_b_proj
@@ -224,6 +232,39 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
     return blocks
 
 
+def _init_kda_blocks(cfg: ModelConfig, key, norm_init, dense, resid_std,
+                     dtype) -> Params:
+    """The ``K`` stack (Kimi Delta Attention), from ONE key. The decay's
+    vectors start as the hybrid configuration seeds its like: step sizes
+    log-uniform in [1e-3, 1e-1] (``dt_bias`` their inverse softplus, a
+    channel), ``A`` uniform in [1, 16] a head, the conv uniform in
+    +-1/sqrt(K)."""
+    kd, H = cfg.kda, cfg.hidden_size
+    Lk, nh, d_in, K = cfg.kda_layers, kd.num_heads, kd.inner_size, \
+        kd.conv_kernel
+    ks = iter(jax.random.split(key, 8))
+    step = jnp.exp(jax.random.uniform(
+        next(ks), (Lk, d_in), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+    bound = 1.0 / jnp.sqrt(float(K))
+    return {
+        "norm": {"scale": norm_init(Lk, H)},
+        # [q | k | v | decay low-rank | gate low-rank | beta]
+        "in_proj": {"kernel": dense(next(ks), Lk, H, kd.in_proj_size)},
+        "conv": {"kernel": jax.random.uniform(
+            next(ks), (Lk, K, kd.conv_channels), jnp.float32, -bound,
+            bound).astype(dtype)},
+        "f_b": {"kernel": dense(next(ks), Lk, kd.head_dim, d_in)},
+        "g_b": {"kernel": dense(next(ks), Lk, kd.head_dim, d_in)},
+        # small vectors the recurrence exponentiates: kept float32
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "A_log": jnp.log(jax.random.uniform(
+            next(ks), (Lk, nh), jnp.float32, 1.0, 16.0)),
+        "gate_norm": {"scale": norm_init(Lk, kd.head_dim)},
+        "out_proj": {"kernel": dense(next(ks), Lk, d_in, H,
+                                     scale=resid_std)},
+    }
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
@@ -291,6 +332,7 @@ def layer_experts(layer: Params, expert_stacks, layer_index):
 # the float32 vectors of a state-space layer (exponentiated every token)
 # and the router's selection bias: never rounded to the compute dtype
 _KEPT_FLOAT32 = (("ssm", "dt_bias"), ("ssm", "A_log"), ("ssm", "D"),
+                 ("kda", "dt_bias"), ("kda", "A_log"),
                  ("moe", "router", "bias"))
 
 
@@ -421,7 +463,10 @@ def forward(
       zero state and keep padding (``segment_ids`` 0, which must follow
       the live tokens) out of it; ``return_ssm_state`` appends
       (conv tails [Lm, B, K-1, C], states [Lm, B, nh, P, N] float32) after
-      the last live token: what cold prefill arms a slot with.
+      the last live token: what cold prefill arms a slot with. The ``K``
+      layers of a ``kimi_linear`` table (a table has ``M`` or ``K`` layers,
+      not both) do the same: (conv tails [Lk, B, K-1, 3 d_in], states
+      [Lk, B, nh, dk, dv] float32).
     - a model with LATENT attention keeps no dense cache (``kv_cache`` is
       refused): its window attends in the expanded form over its own
       tokens, and ``return_latent`` appends the rows a cache would keep,
@@ -550,10 +595,11 @@ def _walk_table(params, x, cfg: ModelConfig, positions, segment_ids,
     differ), each layer one ``decoder_block`` of its kind. Returns (x, the
     attention layers' updated dense cache (a latent model's rows
     [La, B, S, latent]) or None, the summed ``moe_stats``, (conv tails,
-    states) of the state-space layers)."""
-    from ..ops.ssm import recur_window
+    states) of the state-space or ``K`` layers)."""
+    from ..ops import kda, ssm
     blocks = cast_table_blocks(params["blocks"], compute_dtype)
-    recur = recur_window(cfg, segment_ids)
+    recurs = {"M": ssm.recur_window(cfg, segment_ids),
+              "K": kda.recur_window(cfg, segment_ids)}
     aux_total = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
     caches, tails, states, latents = [], [], [], []
     for kind, i in table_layers(cfg):
@@ -563,8 +609,8 @@ def _walk_table(params, x, cfg: ModelConfig, positions, segment_ids,
             cfg, attn_impl, norm_impl, x.astype(compute_dtype),
             table_layer(blocks, kind, i), positions, segment_ids, inv_freq,
             kv_cache=cache, cache_offset=cache_offset, layer_index=i,
-            kind=kind, recur=recur)
-        if kind == "M":
+            kind=kind, recur=recurs.get(kind))
+        if kind in recurs:
             tails.append(state[0])
             states.append(state[1])
         elif kind == "*" and cache is not None:

@@ -612,7 +612,8 @@ def decoder_block(
     model with latent attention, ``latent_attention_mixer``), ``E`` the
     same experts (plus the shared expert), ``D`` the dense ``mlp_block``,
     ``M`` the Mamba-2 mixer, whose state lives where ``recur`` says, as K
-    and V live where ``attend`` says (``ssm_mixer``). With
+    and V live where ``attend`` says (``ssm_mixer``), ``K`` the Kimi Delta
+    Attention mixer, likewise (``kda_mixer``). With
     ``cfg.hc_mult`` > 1 the residual is ``hc_mult`` streams ([B, S, n, H])
     and the table's residual rule is the hyper-connection (``hc_maps``).
 
@@ -632,6 +633,8 @@ def decoder_block(
         state = aux = None
         if kind == "M":
             out, state = ssm_mixer(h, layer, cfg, recur, matmul)
+        elif kind == "K":
+            out, state = kda_mixer(h, layer, cfg, recur, matmul)
         elif kind == "*":
             mixer = (latent_attention_mixer if cfg.is_latent
                      else attention_mixer)
@@ -706,6 +709,10 @@ def latent_attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,
     scores (q_nope . k_nope + q_pe . k_pe) * ``cfg.softmax_scale``, rope
     over the ``pe`` values alone (halves paired, as ``apply_rope``). What a
     cache keeps of a token is the LATENT row [c_kv | k_pe], for all heads.
+    Without a query bottleneck (``cfg.mla.q_lora_rank`` 0) the query is ONE
+    direct projection ``h W_q`` and has no norm; with
+    ``cfg.position_embedding`` "none" the ``pe`` values are carried and
+    scored and never rotated (``kimi_linear``).
 
     ``attend`` comes in two kinds, told by ``attend.latent``. Unset
     (``attend_fresh``: training-style callers and cold prefill): the
@@ -722,16 +729,23 @@ def latent_attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,
     a, N = cfg.mla, cfg.num_heads
     dn, dr, dv, r = (a.qk_nope_head_dim, a.qk_rope_head_dim, a.v_head_dim,
                      a.kv_lora_rank)
+    def rotate(pe):
+        if cfg.position_embedding == "none":
+            return pe
+        return apply_rope(pe, positions, inv_freq)
     with jax.named_scope("mla_q_proj"):
-        c_q = rms_norm(matmul(h, layer["q_a"]["kernel"]),
-                       layer["q_a_norm"]["scale"], cfg.norm_eps)
-        q = matmul(c_q, layer["q_b"]["kernel"]).reshape(B, S, N, dn + dr)
-        q_nope, q_pe = q[..., :dn], apply_rope(q[..., dn:], positions,
-                                               inv_freq)
+        if a.q_lora_rank:
+            c_q = rms_norm(matmul(h, layer["q_a"]["kernel"]),
+                           layer["q_a_norm"]["scale"], cfg.norm_eps)
+            q = matmul(c_q, layer["q_b"]["kernel"])
+        else:
+            q = matmul(h, layer["q"]["kernel"])
+        q = q.reshape(B, S, N, dn + dr)
+        q_nope, q_pe = q[..., :dn], rotate(q[..., dn:])
     with jax.named_scope("mla_kv_compress"):
         ckv = matmul(h, layer["kv_a"]["kernel"])
         c_kv = rms_norm(ckv[..., :r], layer["kv_norm"]["scale"], cfg.norm_eps)
-        k_pe = apply_rope(ckv[..., None, r:], positions, inv_freq)[..., 0, :]
+        k_pe = rotate(ckv[..., None, r:])[..., 0, :]
         rows = jnp.concatenate([c_kv, k_pe], axis=-1)            # [B,S,r+dr]
     w_kvb = layer["kv_b"]["kernel"]                  # [r, N * (dn + dv)]
     if getattr(attend, "latent", False):
@@ -853,3 +867,26 @@ def ssm_mixer(h: jax.Array, layer: Params, cfg: ModelConfig, recur,
     y = ssm_gated_norm(y, z, layer["gate_norm"]["scale"], s.n_groups,
                        cfg.norm_eps)
     return matmul(y, layer["out_proj"]["kernel"]), state
+
+
+def kda_mixer(h: jax.Array, layer: Params, cfg: ModelConfig, recur,
+              matmul=dense_matmul) -> tuple[jax.Array, Any]:
+    """The Kimi Delta Attention mixer over the normed stream ``h``
+    [B, S, H]: ``[q | k | v | f_lo | g_lo | b] = h W_in`` (the three
+    projections, the first halves of the decay's and the output gate's
+    low-rank pairs and the beta logits: one matmul); ``recur(qkv, f, b,
+    layer)`` runs the conv and the gated delta rule wherever its state
+    lives (ops/kda.py ``recur_window`` / ``recur_step`` / ``recur_chunk``)
+    and returns (o [B, S, d_in], state); then the sigmoid-gated head norm
+    and the output projection."""
+    from ..ops.kda import kda_gated_norm
+    kd = cfg.kda
+    C, r = kd.conv_channels, kd.head_dim
+    proj = matmul(h, layer["in_proj"]["kernel"])
+    qkv, f_lo, g_lo, b = (proj[..., :C], proj[..., C:C + r],
+                          proj[..., C + r:C + 2 * r], proj[..., C + 2 * r:])
+    o, state = recur(qkv, matmul(f_lo, layer["f_b"]["kernel"]), b, layer)
+    o = kda_gated_norm(o, matmul(g_lo, layer["g_b"]["kernel"]),
+                       layer["gate_norm"]["scale"], kd.num_heads,
+                       cfg.norm_eps)
+    return matmul(o, layer["out_proj"]["kernel"]), state

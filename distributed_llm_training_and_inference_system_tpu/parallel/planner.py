@@ -721,11 +721,18 @@ class ServePlanner:
             * m.head_dim * BYTES_BF16
 
     def state_bytes(self, slots: int) -> float:
-        """The state pools of a model's state-space layers for ``slots``
-        slots (serve/kv_cache.py): a slot's [nh, P, N] state in float32
+        """The state pools of a model's state-space (or ``K``) layers for
+        ``slots`` slots (serve/kv_cache.py): a slot's [nh, P, N] state in float32
         and K-1 conv columns in bf16, in every such layer, whatever
         the sequences' lengths. 0 for a model without such layers."""
         m, s = self.model, self.model.ssm
+        if m.kda_layers:
+            # a K layer's [nh, dk, dv] state and its conv window over
+            # q | k | v (ops/kda.py)
+            k = m.kda
+            return m.kda_layers * slots * (
+                k.num_heads * k.head_dim * k.head_dim * 4
+                + (k.conv_kernel - 1) * k.conv_channels * BYTES_BF16)
         state = s.num_heads * s.head_dim * s.state_size * 4
         conv = (s.conv_kernel - 1) * s.conv_channels * BYTES_BF16
         return m.ssm_layers * slots * (state + conv)

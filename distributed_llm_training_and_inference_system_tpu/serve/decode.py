@@ -157,6 +157,8 @@ def extend_step_forward(
     two_bodies: bool = False,  # this step is one of the two bodies of a
                               # program that rides: the B slots' windows go
                               # through ``_shared_windows``
+    state_slot: Any = None,   # int32 []: the ONE slot whose window this is
+                              # (B == 1: chunked prefill through K layers)
 ) -> tuple:
     """Paged forward over T tokens per slot: the multi-token sibling of
     ``decode_step_forward``. Returns (logits [B, T, V] fp32, k_pages,
@@ -167,6 +169,13 @@ def extend_step_forward(
     with state-space layers takes ``ssm_state`` and returns it LAST,
     advanced in place for the rows ``write_ok`` marks: its K/V pools hold
     the attention layers alone ([La, NP, ...]) and it takes T = 1 only.
+    A model with ``K`` (delta-rule linear attention) layers takes the same
+    ``ssm_state`` (its pools under the same two names) and, besides T = 1
+    over every slot, a WINDOW of one slot (B = 1 with ``state_slot``: a
+    chunk of a prompt), which reads that slot's state and conv window,
+    runs the chunked form from them and writes them back (ops/kda.py
+    ``recur_chunk``; a window that starts its sequence, ``start_positions``
+    0, takes them as zero).
     A model with LATENT attention keeps ONE pool: ``k_pages`` is the latent
     pool [La, NP, 1, PS, W] and ``v_pages`` is None, handed through.
 
@@ -289,10 +298,21 @@ def extend_step_forward(
         # a layer table: one parameter stack a kind, walked by a Python
         # loop; every pool (pages, conv tails, states) and every expert
         # stack stays whole and is addressed at its kind's layer index
+        from ..ops import kda
         from ..ops.ssm import recur_step
         if cfg.is_recurrent and ssm_state is None:
-            raise ValueError("a model with state-space layers needs its "
+            raise ValueError(f"a model with {cfg.recurrent_name} needs its "
                              "ssm_state pools")
+
+        def recur_at(kind, conv, ssm, i):
+            if kind == "M":
+                return recur_step(cfg, conv, ssm, i, write_ok)
+            if kind != "K":
+                return None
+            if state_slot is None:
+                return kda.recur_step(cfg, conv, ssm, i, write_ok)
+            return kda.recur_chunk(cfg, slot_tails[i], slot_states[i],
+                                   write_ok)
         blocks = cast_table_blocks(params["blocks"], compute_dtype)
         kp, vp = k_pages, v_pages
         if cfg.hc_mult > 1:
@@ -309,20 +329,31 @@ def extend_step_forward(
             return attend_pages(kp, vp, li)
         conv, ssm = (ssm_state["conv"], ssm_state["ssm"]) \
             if ssm_state is not None else (None, None)
+        if state_slot is not None:
+            # a chunk of ONE slot's prompt: its rows of the pools are read
+            # here, once, and written after the last layer, once
+            slot_tails, slot_states = kda.slot_state(
+                conv, ssm, state_slot, start_positions)
+            new_tails, new_states = [], []
         stats = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
         for kind, i in table_layers(cfg):
             x, state, layer_stats = decoder_block(
                 x, table_layer(blocks, kind, i), cfg, positions, inv_freq,
                 attend_at(kp, vp, i) if kind == "*" else None, matmul=mm,
                 live=live, layer_index=i, kind=kind,
-                recur=(recur_step(cfg, conv, ssm, i, write_ok)
-                       if kind == "M" else None))
+                recur=recur_at(kind, conv, ssm, i))
             if kind == "*":
                 kp, vp = state
-            elif kind == "M":
+            elif kind == "K" and state_slot is not None:
+                new_tails.append(state[0])
+                new_states.append(state[1])
+            elif kind in "MK":
                 conv, ssm = state
             elif kind == "E":
                 stats = stats + layer_stats
+        if state_slot is not None:
+            conv, ssm = kda.write_slot_state(conv, ssm, state_slot,
+                                             new_tails, new_states)
         if cfg.hc_mult > 1:
             x = jnp.sum(x.astype(jnp.float32), axis=2).astype(compute_dtype)
         return (unembed(params, x, cfg), kp, vp,

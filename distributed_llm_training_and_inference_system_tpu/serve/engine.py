@@ -415,6 +415,10 @@ class InferenceEngine:
         self.moe_all_choices = 0
         # state updates of live slots in decode (slots x steps)
         self.ssm_slot_steps = 0
+        # chunk programs that read a slot's recurrent state (a ``K``
+        # model's chunked prefill), and the prompt tokens they prefilled
+        self.total_state_carry_chunks = 0
+        self.total_state_carry_tokens = 0
         self.moe_experts_hit = self.moe_layer_steps = 0
         self.moe_decode_experts_hit = self.moe_decode_layer_steps = 0
         self.total_prefill_tokens = 0      # tokens actually computed
@@ -457,25 +461,36 @@ class InferenceEngine:
     def _refuse_for_recurrent(self, serve_cfg: ServeConfig) -> None:
         """Refuse, by name, the opt-in features a recurrent layer's state
         cannot follow, and turn prefix reuse by page hash off (it is ON by
-        default): a page hit would skip tokens whose state-space state
-        nobody kept. Said once in the log and in ``stats()["ssm"]``."""
+        default): a page hit would skip tokens whose recurrent state nobody
+        kept (no snapshot of a state at a page boundary exists). Said once
+        in the log and in ``stats()["ssm"]`` / ``["kda"]``.
+
+        Chunked prefill is refused for state-space (``M``) layers, whose
+        scan starts from a zero state alone, and CARRIED by ``K`` layers:
+        their chunk program reads the slot's state and conv window and
+        writes them back (ops/kda.py ``recur_chunk``), and a ``K`` model
+        with latent attention must chunk, since its cold program cannot
+        attend past ``LATENT_COLD_TOKENS``."""
         asked = {
-            "chunked_prefill_tokens": serve_cfg.chunked_prefill_tokens > 0,
+            "chunked_prefill_tokens": serve_cfg.chunked_prefill_tokens > 0
+            and self.cfg.ssm_layers > 0,
             "speculative": serve_cfg.speculative != "off",
             "preemption: swap": serve_cfg.preemption == "swap",
         }
         for feature, on in asked.items():
             if on:
                 raise ValueError(
-                    f"{self.cfg.name} has state-space layers: {feature} is "
+                    f"{self.cfg.name} has {self.cfg.recurrent_name}: "
+                    f"{feature} is "
                     "refused (it re-enters or moves K/V pages, and the "
                     "layers' recurrent state is not in them; ROADMAP C2)")
         if serve_cfg.prefix_caching:
             self.ssm_refused["prefix_caching"] = 0
             logger.warning(
-                "%s has state-space layers: prefix reuse by page hash is "
+                "%s has %s: prefix reuse by page hash is "
                 "off (no page hash is registered or looked up; a repeated "
-                "prompt is prefilled again)", self.cfg.name)
+                "prompt is prefilled again)", self.cfg.name,
+                self.cfg.recurrent_name)
 
     def _refuse_for_latent(self, serve_cfg: ServeConfig) -> None:
         """Refuse, by name, what a latent page pool does not carry yet.
@@ -580,7 +595,7 @@ class InferenceEngine:
     def prefix_fetch_hook(self, hook: Optional[Callable]) -> None:
         if hook is not None and self.cfg.is_recurrent:
             raise ValueError(
-                f"{self.cfg.name} has state-space layers: fleet prefix "
+                f"{self.cfg.name} has {self.cfg.recurrent_name}: fleet prefix "
                 "fetch is refused (fetched pages carry no recurrent state)")
         if hook is not None and self.cfg.is_latent:
             raise ValueError(
@@ -851,18 +866,24 @@ class InferenceEngine:
             dtype = self.kv.dtype
 
             def prefill_latent(params, tokens, length, k_pages, v_pages,
-                               entries, key, temp, top_k, top_p):
+                               entries, key, temp, top_k, top_p, state=None,
+                               slot=None):
                 """Cold prefill of a latent-attention model: the window
                 attends over its own tokens in the expanded form, and the
                 rows a cache keeps are written to the latent pool's pages
                 whole (the bucket's padding lands in scratch page 0 or
-                behind the slot's length, where nothing reads it)."""
+                behind the slot's length, where nothing reads it). Its
+                ``K`` layers, where it has them, run the chunked form from
+                a zero state and ARM the slot's rows of the state pools."""
                 live = (jnp.arange(bucket, dtype=jnp.int32)[None]
                         < length[:, None]).astype(jnp.int32)
-                logits, rows, moe_stats = gpt.forward(
+                logits, rows, moe_stats, *rest = gpt.forward(
                     params, tokens, cfg, unembed_positions=length - 1,
                     segment_ids=live, return_latent=True,
-                    return_moe_stats=True)
+                    return_moe_stats=True,
+                    return_ssm_state=cfg.is_recurrent)
+                if cfg.is_recurrent:
+                    state = self._arm_state(state, slot, *rest[0])
                 pad = k_pages.shape[-1] - rows.shape[-1]
                 rows = jnp.pad(rows[:, 0], ((0, 0), (0, 0), (0, pad)))
                 k_pages = k_pages.at[:, entries].set(rows.reshape(
@@ -871,7 +892,7 @@ class InferenceEngine:
                 token = sample_tokens(logits[:, 0], key[None], temp[None],
                                       top_k[None], top_p[None])[0]
                 return (self._with_moe_stats(token, moe_stats), k_pages,
-                        v_pages)
+                        v_pages, *([state] if cfg.is_recurrent else []))
 
             def prefill(params, tokens, length, k_pages, v_pages, entries,
                         key, temp, top_k, top_p, state=None, slot=None):
@@ -896,12 +917,7 @@ class InferenceEngine:
                     # the state after the prompt's last live token,
                     # computed from a ZERO state, whatever a former
                     # occupant (or its trailing decode steps) left there
-                    tails, states = rest[-1]
-                    state = {
-                        "conv": state["conv"].at[:, slot].set(
-                            tails[:, 0].astype(state["conv"].dtype)),
-                        "ssm": state["ssm"].at[:, slot].set(
-                            states[:, 0].astype(state["ssm"].dtype))}
+                    state = self._arm_state(state, slot, *rest[-1])
                 # dense [L, bucket, Nkv, D] -> paged [L, n_pages, Nkv, PS, D]
                 kd = kd[:, 0].reshape(
                     cfg.kv_layers, n_pages, self.kv.page_size,
@@ -951,6 +967,16 @@ class InferenceEngine:
                 donate_argnums=(3, 4, 10) if cfg.is_recurrent else (3, 4))
         return self._prefill_cache[bucket]
 
+    def _arm_state(self, state: dict, slot, tails, states) -> dict:
+        """The state pools with ``slot``'s rows overwritten by a cold
+        prefill's (conv tails [L, 1, K-1, C], states [L, 1, ...]); a ``K``
+        model's conv pool lies [L, K-1, slot, C]."""
+        conv = state["conv"]
+        at = conv.at[:, :, slot] if self.cfg.kda_layers else conv.at[:, slot]
+        return {"conv": at.set(tails[:, 0].astype(conv.dtype)),
+                "ssm": state["ssm"].at[:, slot].set(
+                    states[:, 0].astype(state["ssm"].dtype))}
+
     def _extend_prefill_fn(self, bucket: int):
         """Suffix prefill over a cached paged prefix: only the un-cached
         tail of the prompt is computed (decode.extend_step_forward), writing
@@ -961,26 +987,31 @@ class InferenceEngine:
             cfg = self.cfg
 
             def extend_prefill(params, tokens, start, m, k_pages, v_pages,
-                               table, key, temp, top_k, top_p):
+                               table, key, temp, top_k, top_p, state=None,
+                               slot=None):
                 write_ok = (jnp.arange(bucket, dtype=jnp.int32)[None]
                             < m[:, None])
-                logits, k_pages, v_pages, *moe_stats = extend_step_forward(
+                logits, k_pages, v_pages, *rest = extend_step_forward(
                     params, tokens, start, k_pages, v_pages, table, cfg,
                     write_ok=write_ok, attn_impl=self._attn_impl,
                     w4_kernel_ok=self._w4_kernel_ok,
                     w8_kernel_ok=self._w8_kernel_ok,
-                    return_moe_stats=True)
+                    return_moe_stats=True, ssm_state=state,
+                    state_slot=slot)
+                moe_stats = rest[:int(cfg.is_moe)]
                 last = jnp.take_along_axis(
                     logits, (m - 1)[:, None, None], axis=1)[:, 0]   # [1, V]
                 token = sample_tokens(last, key[None], temp[None],
                                       top_k[None], top_p[None])[0]
                 if moe_stats:
                     token = self._with_moe_stats(token, moe_stats[0])
-                return token, k_pages, v_pages
+                return (token, k_pages, v_pages,
+                        *(rest[-1:] if cfg.is_recurrent else []))
 
             self._prefill_cache[key_] = _Program(
                 f"suffix prefill {bucket}", extend_prefill,
-                self.failed_programs, donate_argnums=(4, 5))
+                self.failed_programs,
+                donate_argnums=(4, 5, 11) if cfg.is_recurrent else (4, 5))
         return self._prefill_cache[key_]
 
     def _extend_chunk_fn(self, bucket: int):
@@ -993,21 +1024,23 @@ class InferenceEngine:
             cfg = self.cfg
 
             def extend_chunk(params, tokens, start, m, k_pages, v_pages,
-                             table):
+                             table, state=None, slot=None):
                 write_ok = (jnp.arange(bucket, dtype=jnp.int32)[None]
                             < m[:, None])
                 # (returns no token, so an MoE model's mid-prompt chunks
                 # have no fetch to carry their routing counts: not counted)
-                _, k_pages, v_pages = extend_step_forward(
+                _, k_pages, v_pages, *state = extend_step_forward(
                     params, tokens, start, k_pages, v_pages, table, cfg,
                     write_ok=write_ok, attn_impl=self._attn_impl,
                     w4_kernel_ok=self._w4_kernel_ok,
-                    w8_kernel_ok=self._w8_kernel_ok)
-                return k_pages, v_pages
+                    w8_kernel_ok=self._w8_kernel_ok, ssm_state=state,
+                    state_slot=slot)
+                return (k_pages, v_pages, *state)
 
             self._prefill_cache[key_] = _Program(
                 f"prefill chunk {bucket}", extend_chunk,
-                self.failed_programs, donate_argnums=(4, 5))
+                self.failed_programs,
+                donate_argnums=(4, 5, 7) if cfg.is_recurrent else (4, 5))
         return self._prefill_cache[key_]
 
     @staticmethod
@@ -1309,12 +1342,21 @@ class InferenceEngine:
                       np.array([this], np.int32),
                       self.kv.k_pages, self.kv.v_pages,
                       st["table_row"][None])
+            # a model with ``K`` layers: the chunk reads the slot's rows
+            # of the state pools and writes them back
+            carried = ((self.kv.state, np.int32(req.slot))
+                       if self.cfg.is_recurrent else ())
+            if carried:
+                self.total_state_carry_chunks += 1
+                self.total_state_carry_tokens += this
             if done + this < n or stage is not None:
                 # intermediate chunk — and EVERY chunk of a pipeline
                 # stage request, whose product is pages, not logits:
                 # even its final chunk runs the sampling-free program
-                self.kv.k_pages, self.kv.v_pages = \
-                    self._extend_chunk_fn(bucket)(*common)
+                self.kv.k_pages, self.kv.v_pages, *state = \
+                    self._extend_chunk_fn(bucket)(*common, *carried)
+                if carried:
+                    self.kv.state = state[0]
                 st["done"] = done + this
                 if stage is not None:
                     self._publish_stage_pages(st)
@@ -1326,10 +1368,13 @@ class InferenceEngine:
                             self.scheduler.finish_prefill_only(rid)
                         del self._partial_prefills[rid]
             else:
-                token, self.kv.k_pages, self.kv.v_pages = \
+                token, self.kv.k_pages, self.kv.v_pages, *state = \
                     self._extend_prefill_fn(bucket)(
                         *common, *self._sampling_args(st["slot_key"], n,
-                                                      req.sampling))
+                                                      req.sampling),
+                        *carried)
+                if carried:
+                    self.kv.state = state[0]
                 self.spans.dispatched()
                 if self._prefix_caching and req.prefix_hashes:
                     with self.lock:
@@ -1345,7 +1390,8 @@ class InferenceEngine:
             if stage is not None and self.pipeline_chunk_hook is not None:
                 # no locks held: the coordinator side only enqueues
                 self.pipeline_chunk_hook(req, st["done"], st["done"] >= n)
-        self.spans.annotate(tokens=live, bucket=spent)
+        self.spans.annotate(tokens=live, bucket=spent, **(
+            {"state_carry": live} if self.cfg.is_recurrent else {}))
         return completed
 
     @engine_thread_only
@@ -1787,11 +1833,12 @@ class InferenceEngine:
         self.total_table_pages += self.kv.block_tables.size
         ids = {}
         if self.cfg.is_recurrent:
-            # state updates this dispatch asks of the state-space layers
-            # (live slots x steps; stats()["ssm"]["slot_steps"] counts them
-            # at the fetch)
-            ids["ssm_slot_steps"] = (int(self.active.sum()) * n_units
-                                     * self._decode_unit_len)
+            # state updates this dispatch asks of the state-space (or K)
+            # layers (live slots x steps; stats()["ssm" | "kda"]
+            # ["slot_steps"] counts them at the fetch)
+            ids["kda_slot_steps" if self.cfg.kda_layers
+                else "ssm_slot_steps"] = (int(self.active.sum()) * n_units
+                                          * self._decode_unit_len)
         if self.cfg.is_latent:
             # what one walked page costs over the layers (llmctl trace
             # summarize: "latent attention walked N pages of B bytes")
@@ -2599,7 +2646,7 @@ class InferenceEngine:
         device-time number, not RTT arithmetic)."""
         if self.cfg.is_recurrent:
             raise ValueError(
-                f"{self.cfg.name} has state-space layers: "
+                f"{self.cfg.name} has {self.cfg.recurrent_name}: "
                 "measure_device_times is refused (its probes write scratch "
                 "pages, and would arm and advance live slots' state)")
         out: dict = {"prefill_ms": {}, "iters": iters}
@@ -2701,6 +2748,8 @@ class InferenceEngine:
             "prefill_padded_tokens": self.total_prefill_padded_tokens,
             "prefill_ride_tokens": self.total_prefill_ride_tokens,
             "prefill_ride_steps": self.total_prefill_ride_steps,
+            "state_carry_chunks": self.total_state_carry_chunks,
+            "state_carry_tokens": self.total_state_carry_tokens,
             "prefix_cached_tokens": self.total_prefix_cached_tokens,
             "requeue_cached_tokens": self.total_requeue_cached_tokens,
             "prefix_fetched_tokens": self.total_prefix_fetched_tokens,
@@ -2719,13 +2768,20 @@ class InferenceEngine:
             "spec_acceptance": round(
                 self.total_spec_accepted / max(self.total_spec_drafts, 1), 4),
             "compiled_programs": self.compiled_programs(),
-            **({"ssm": {
+            # the recurrent kind's counters, under the kind's name
+            # ("ssm": M layers; "kda": K layers)
+            **({("kda" if self.cfg.kda_layers else "ssm"): {
                 "state_bytes": self.kv.state_bytes(),
                 "slot_steps": self.ssm_slot_steps,
-                # every prefill of such a model is cold: its tokens and
-                # the rows its programs computed all go through the scan
+                # every prompt token of such a model goes through the
+                # chunked form (cold or chunk by chunk), as do the rows
+                # its programs computed for them
                 "prefill_tokens": self.total_prefill_tokens,
                 "prefill_padded_tokens": self.total_prefill_padded_tokens,
+                # chunk programs that read a slot's state and conv window
+                # (a K model's chunked prefill) and their prompt tokens
+                "state_carry_chunks": self.total_state_carry_chunks,
+                "state_carry_tokens": self.total_state_carry_tokens,
                 "refused": dict(self.ssm_refused),
             }} if self.cfg.is_recurrent else {}),
             **({"moe": {
@@ -2748,9 +2804,10 @@ class InferenceEngine:
             "startup": STARTUP.snapshot(),
         }
 
-    def program_texts(self) -> dict:
+    def program_texts(self, chunks: bool = False) -> dict:
         """{program name: optimised HLO text} of the resident decode and
-        cold-prefill programs, lowered for shapes like their live
+        cold-prefill programs (with ``chunks`` also the chunk and suffix
+        programs, "prefill chunk N" / "suffix prefill N"), lowered for shapes like their live
         arguments' and compiled (from the persistent compile cache where it
         is on), NOT run. A device trace names an XLA operation by its HLO
         instruction (``fusion.123``) and says nothing of the named scope it
@@ -2780,6 +2837,16 @@ class InferenceEngine:
                 jax.ShapeDtypeStruct((1,), i32), common[1], common[2],
                 jax.ShapeDtypeStruct((bucket // self.kv.page_size,), i32),
                 *sampling, *state,
+                *((jax.ShapeDtypeStruct((), i32),) if state else ()))
+        for key_ in [k for k in list(self._prefill_cache)
+                     if chunks and isinstance(k, tuple)]:
+            program, bucket = self._prefill_cache[key_], key_[1]
+            texts[program.name] = program.compiled_text(
+                common[0], jax.ShapeDtypeStruct((1, bucket), i32),
+                jax.ShapeDtypeStruct((1,), i32),
+                jax.ShapeDtypeStruct((1,), i32), common[1], common[2],
+                jax.ShapeDtypeStruct((1, self.kv.max_pages_per_slot), i32),
+                *(sampling if key_[0] == "extend" else ()), *state,
                 *((jax.ShapeDtypeStruct((), i32),) if state else ()))
         return texts
 

@@ -63,7 +63,8 @@ class RemoteUnavailable(RuntimeError):
 def sampling_to_wire(s: SamplingParams) -> dict:
     return {"temperature": s.temperature, "top_k": s.top_k,
             "top_p": s.top_p, "max_tokens": s.max_tokens,
-            "stop_token_ids": list(s.stop_token_ids), "seed": s.seed}
+            "stop_token_ids": list(s.stop_token_ids), "seed": s.seed,
+            "ignore_eos": s.ignore_eos}
 
 
 def sampling_from_wire(d: dict) -> SamplingParams:
@@ -72,7 +73,7 @@ def sampling_from_wire(d: dict) -> SamplingParams:
         top_k=int(d.get("top_k", 0)), top_p=float(d.get("top_p", 1.0)),
         max_tokens=int(d.get("max_tokens", 64)),
         stop_token_ids=tuple(d.get("stop_token_ids", ())),
-        seed=d.get("seed"))
+        seed=d.get("seed"), ignore_eos=bool(d.get("ignore_eos", False)))
 
 
 def request_to_wire(req: Request) -> dict:

@@ -169,7 +169,11 @@ class PagedKVCache:
         # [nh, P, N] state, whatever the sequence's length. A slot costs
         # no pages for these layers; the pools are addressed
         # [state-space layer, slot] and ride the programs beside the page
-        # pools (None for a model without such layers).
+        # pools (None for a model without such layers). A ``K`` (delta-rule
+        # linear attention) layer keeps the same two things, its conv
+        # window over q | k | v and a [nh, dk, dv] state, under the same two
+        # names; such a model's ``*`` layers may keep LATENT pages, so the
+        # latent pool and the state pools live side by side here.
         self.state = self.new_state()
 
         # host-side state; page 0 is scratch and never allocated
@@ -202,10 +206,24 @@ class PagedKVCache:
         self._demote_pending: list[tuple[bytes, int]] = []
 
     def new_state(self):
-        """Zeroed state pools of the state-space layers, or None."""
+        """Zeroed state pools of the recurrent layers (``M`` or ``K``: a
+        model has one kind), or None: ``conv`` [layer, slot, K-1, C] (``K``
+        layers: [layer, K-1, slot, C], whole tiles of [slots, C]; ops/kda.py
+        ``kda_conv_step``) in the cache's dtype and ``ssm``
+        [layer, slot, heads, ., .] float32."""
         cfg = self.cfg
         if not cfg.is_recurrent:
             return None
+        if cfg.kda_layers:
+            k = cfg.kda
+            return {
+                "conv": jnp.zeros((cfg.kda_layers, k.conv_kernel - 1,
+                                   self.num_slots, k.conv_channels),
+                                  self.dtype),
+                "ssm": jnp.zeros((cfg.kda_layers, self.num_slots,
+                                  k.num_heads, k.head_dim, k.head_dim),
+                                 jnp.float32),
+            }
         s = cfg.ssm
         return {
             "conv": jnp.zeros((cfg.ssm_layers, self.num_slots,
@@ -228,7 +246,7 @@ class PagedKVCache:
         model also keeps recurrent state a slot."""
         if self.state is not None:
             raise ValueError(
-                f"{self.cfg.name} has state-space layers: {what} is "
+                f"{self.cfg.name} has {self.cfg.recurrent_name}: {what} is "
                 "refused (it carries K/V pages, and the slot's recurrent "
                 "state is not in them; ROADMAP C2)")
         if self.cfg.is_latent:
@@ -406,8 +424,10 @@ class PagedKVCache:
     def release(self, slot: int) -> None:
         """Return ``slot``'s pages. Its rows of the state pools need no
         device work: the prefill that next arms the slot overwrites them
-        with a state computed from zero (serve/engine.py ``_prefill_fn``),
-        so a reused slot starts from a zero state whatever is left here."""
+        with a state computed from zero (serve/engine.py ``_prefill_fn``;
+        a chunked prefill's first chunk takes the state as zero,
+        ops/kda.py ``recur_chunk``), so a reused slot starts from a zero
+        state whatever is left here."""
         for page in self._owned.pop(slot, []):
             self._drop_ref(page)
         self.block_tables[slot, :] = 0

@@ -40,6 +40,10 @@ class SamplingParams:
     max_tokens: int = 64
     stop_token_ids: tuple[int, ...] = ()
     seed: Optional[int] = None
+    # run to ``max_tokens`` (or a ``stop_token_ids`` hit) whatever the
+    # tokenizer's EOS: a load test that needs a fixed reply length asks
+    # for this (the request field of the same name)
+    ignore_eos: bool = False
 
 
 @dataclass
@@ -174,7 +178,8 @@ class Request:
     def should_stop(self, eos_token_id: Optional[int]) -> Optional[str]:
         if self.generated_tokens:
             last = self.generated_tokens[-1]
-            if eos_token_id is not None and last == eos_token_id:
+            if (eos_token_id is not None and last == eos_token_id
+                    and not self.sampling.ignore_eos):
                 return "stop"
             if last in self.sampling.stop_token_ids:
                 return "stop"

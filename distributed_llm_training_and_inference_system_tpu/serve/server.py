@@ -69,6 +69,10 @@ def parse_completion_body(body: dict, tokenizer, vocab_size: int
                              or not isinstance(seed, int)):
         # an unvalidated seed would raise inside the engine thread
         raise BadRequest(f"seed must be an integer, got {seed!r}")
+    ignore_eos = body.get("ignore_eos", False)
+    if not isinstance(ignore_eos, bool):
+        raise BadRequest(f"ignore_eos must be true or false, "
+                         f"got {ignore_eos!r}")
     try:
         sampling = SamplingParams(
             temperature=float(body.get("temperature", 1.0)),
@@ -76,6 +80,7 @@ def parse_completion_body(body: dict, tokenizer, vocab_size: int
             top_p=float(body.get("top_p", 1.0)),
             max_tokens=int(body.get("max_tokens", 64)),
             seed=seed,
+            ignore_eos=ignore_eos,
         )
     except (TypeError, ValueError) as e:
         raise BadRequest(f"invalid sampling parameter: {e}") from None
@@ -386,6 +391,7 @@ class InferenceServer:
             "preemptions": st["preemptions"],
             "swap_ins": st["swap_ins"],
             "prefill_ride_tokens": st["prefill_ride_tokens"],
+            "state_carry_tokens": st["state_carry_tokens"],
             "swapped_host_bytes": st["swapped_host_bytes"],
         })
 

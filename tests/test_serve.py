@@ -127,6 +127,43 @@ class TestContinuousBatching:
         [b] = eng.generate([[7, 8, 9]], s)
         assert a.generated_tokens == b.generated_tokens
 
+    @pytest.mark.parametrize("ignore_eos", [False, True])
+    def test_ignore_eos_runs_a_reply_to_max_tokens(self, model_cfg,
+                                                   ignore_eos):
+        """The request field ``ignore_eos``: the engine's EOS ends a reply
+        ("stop") unless the request asked to run to ``max_tokens``; a
+        ``stop_token_ids`` hit ends it either way."""
+        eng = make_engine(model_cfg)
+        greedy = SamplingParams(temperature=0.0, max_tokens=6)
+        [plain] = eng.generate([[1, 2, 3]], greedy)
+        eng.eos_token_id = plain.generated_tokens[2]
+        first = plain.generated_tokens.index(eng.eos_token_id)
+        [r] = eng.generate([[1, 2, 3]], SamplingParams(
+            temperature=0.0, max_tokens=6, ignore_eos=ignore_eos))
+        if ignore_eos:
+            assert r.generated_tokens == plain.generated_tokens
+            assert r.finish_reason == "length"
+        else:
+            assert r.generated_tokens == plain.generated_tokens[:first + 1]
+            assert r.finish_reason == "stop"
+        [s] = eng.generate([[1, 2, 3]], SamplingParams(
+            temperature=0.0, max_tokens=6, ignore_eos=ignore_eos,
+            stop_token_ids=(plain.generated_tokens[0],)))
+        assert s.generated_tokens == plain.generated_tokens[:1]
+
+    def test_ignore_eos_is_parsed_and_goes_over_the_fleet_wire(self):
+        from distributed_llm_training_and_inference_system_tpu.serve.fleet \
+            import remote
+        from distributed_llm_training_and_inference_system_tpu.serve.server \
+            import BadRequest, parse_completion_body
+        body = {"prompt": [1, 2], "max_tokens": 4}
+        assert not parse_completion_body(body, None, 512)[1].ignore_eos
+        s = parse_completion_body(dict(body, ignore_eos=True), None, 512)[1]
+        assert s.ignore_eos
+        assert remote.sampling_from_wire(remote.sampling_to_wire(s)) == s
+        with pytest.raises(BadRequest, match="ignore_eos"):
+            parse_completion_body(dict(body, ignore_eos="yes"), None, 512)
+
     def test_static_scheduler_mode(self, model_cfg):
         eng = make_engine(model_cfg, scheduler="static")
         reqs = eng.generate([[1, 2], [3, 4], [5, 6]],

@@ -739,6 +739,129 @@ def test_latent_decode_program_moves_no_pool_and_no_stack(one_chip, as_tpu):
     assert mem.alias_size_in_bytes >= pool_bytes
 
 
+# -- the linear cell (Kimi-Linear-48B-A3B, 12 layers, 32 of 256 experts): the
+# one-step kernel and the two programs that carry state, at the published
+# widths: 128 slots, 9 K layers (2.4 GB of state), 3 latent layers ----------
+
+LINEAR_PAGES = 1525
+
+
+def _linear_cell(one_chip):
+    """(model config, shapes of params / latent pool / state pools) of the
+    linear cell as its configuration file states it."""
+    import json
+    from pathlib import Path
+
+    from distributed_llm_training_and_inference_system_tpu.config.schema import (
+        ModelConfig)
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                         / "configs" / "kimi-linear-48b-a3b-12l-ep8.json"
+                         ).read_text())
+    cfg = ModelConfig.from_published(config)
+    sds = _sds(one_chip)
+    B = config["serve"]["max_batch_size"]
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda k: gpt.init(cfg, k, jnp.bfloat16), jax.random.PRNGKey(0)))
+    pool = sds((cfg.kv_layers, LINEAR_PAGES, 1, LATENT_PS, 640),
+               jnp.bfloat16)
+    k = cfg.kda
+    state = {"conv": sds((cfg.kda_layers, k.conv_kernel - 1, B,
+                          k.conv_channels), jnp.bfloat16),
+             "ssm": sds((cfg.kda_layers, B, k.num_heads, k.head_dim,
+                         k.head_dim), jnp.float32)}
+    return cfg, B, params, pool, state
+
+
+def test_kda_decode_kernel_updates_the_state_pool_in_place(one_chip, as_tpu):
+    """The one-step delta-rule kernel on the cell's state pool (9 layers x
+    128 slots x 32 heads x 128 x 128 float32 = 2.4 GB), 16 heads a grid
+    step: Mosaic takes the transposes that turn q, k and the decays into
+    columns, the pool is aliased to the output and nothing is temporary."""
+    from distributed_llm_training_and_inference_system_tpu.ops import kda
+    sds = _sds(one_chip)
+    B, nh, d = 128, 32, 128
+    compiled = jax.jit(
+        lambda q, k, v, g, beta, pool: kda.kda_decode_pool(
+            q, k, v, g, beta, pool, 3), donate_argnums=(5,)).lower(
+        *(sds((B, nh, d), jnp.bfloat16),) * 3, sds((B, nh, d), jnp.float32),
+        sds((B, nh), jnp.float32),
+        sds((9, B, nh, d, d), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 9 * B * nh * d * d * 4
+    assert mem.temp_size_in_bytes < 32 << 20
+
+
+def test_linear_decode_program_moves_no_pool(one_chip, as_tpu):
+    """The multi-step decode program at the linear cell's shapes: the latent
+    pool (1.5 GB) and both state pools (2.4 GB + 85 MB) ride the carry and
+    come back in place; no copy of the latent pool, of the K state pool or
+    a layer's slab of it (268 MB), or of an expert stack (1.7 GB). (The
+    85 MB conv-window pool is re-laid once at the program's entry and once
+    at its exit, outside the step loop: the compiler keeps the slots on the
+    lanes inside it, as it computes the 128-row projections.)"""
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        decode_scan)
+    cfg, B, params, pool, state = _linear_cell(one_chip)
+    sds = _sds(one_chip)
+
+    def program(params, pool, tokens, positions, tables, stops, keys, temp,
+                top_k, top_p, state):
+        return decode_scan(params, tokens, positions, pool, None, tables,
+                           stops, keys, temp, top_k, top_p, cfg, 2,
+                           return_moe_stats=True, ssm_state=state)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    compiled = jax.jit(program, donate_argnums=(1, 10)).lower(
+        params, pool, i32(B), i32(B), i32(B, 64), i32(B),
+        sds((B, 2), jnp.uint32), sds((B,), jnp.float32), i32(B),
+        sds((B,), jnp.float32), state).compile()
+    text = compiled.as_text()
+    assert all(k in text for k in ("moe_gmm", "mla_paged_attention",
+                                   "kda_decode"))
+    _no_copy_of(text, ["f32[9,128,32,128,128]", "f32[128,32,128,128]",
+                       "bf16[3,1525,1,256,640]",
+                       "bf16[11,32,2304,1024]", "bf16[11,32,1024,2304]"])
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 512 << 20, (
+        f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries")
+    assert mem.alias_size_in_bytes >= 3.9e9
+
+
+def test_linear_chunk_program_reads_a_slots_state_once(one_chip, as_tpu):
+    """The chunk program (1,024 rows of ONE slot's prompt over the latent
+    pages, the slot's K state and conv window carried) at the cell's shapes.
+    Read a layer at a time between the layers' writes, the compiler kept the
+    state pool as it came beside the pool it wrote: 3.8 GB of temporaries,
+    which the chip does not have beside 10.3 GB of weights and pools. Read
+    once before the layers and written once after them: under 0.5 GB."""
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        extend_step_forward)
+    cfg, B, params, pool, state = _linear_cell(one_chip)
+    sds = _sds(one_chip)
+    T = 1024
+
+    def chunk(params, tokens, start, m, pool, table, state, slot):
+        ok = jnp.arange(T)[None] < m[:, None]
+        _, pool, _, state = extend_step_forward(
+            params, tokens, start, pool, None, table, cfg, write_ok=ok,
+            ssm_state=state, state_slot=slot)
+        return pool, state
+
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    compiled = jax.jit(chunk, donate_argnums=(4, 6)).lower(
+        params, i32(1, T), i32(1), i32(1), pool, i32(1, 64), state,
+        i32()).compile()
+    text = compiled.as_text()
+    assert "mla_paged_attention_mq" in text and "moe_gmm_prefill" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 512 << 20, (
+        f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries")
+    assert mem.alias_size_in_bytes >= 3.9e9
+
+
 # -- the four-chip training cell (internlm2-1.8b.pretrain-4k-fsdp4) -----------
 
 def test_fsdp4_train_step_moves_rows_not_the_head(topo, as_tpu):
