@@ -29,7 +29,9 @@ the chip):
             then four prompts served to a BUSY engine (17 of 32 slots
             resident, mistral-7b's widths, 4 layers, float32: they ride the
             decode dispatches) and again to the drained one (the cold
-            program): equal first tokens and equal 32 greedy tokens
+            program): equal first tokens and equal 32 greedy tokens; and
+            the same at Xing4.0's widths (latent attention, 2 layers, 9 of
+            16 slots) behind a document's cached pages (the suffix program)
   train     `cli.main train launch --model gpt-750m --max-steps 8`,
             sequence 2048, micro-batch 4, flash attention, fused AdamW
   launcher  `train launch --restart-on-failure 1 --max-steps 2` at
@@ -536,19 +538,27 @@ def phase_seeded_replies(env: dict, parent: str | None) -> None:
 
 def phase_ride(env: dict) -> None:
     text = run_child("ride", [sys.executable, str(ROOT / "chip_smoke.py")],
-                     {**env, PHASE_ENV: "ride"}, timeout=900)
-    [rec] = smoke_records(text, "ride")
-    say(f"  riding prompts ({rec['model']}, {rec['layers']} layers, float32; "
-        f"{rec['resident']} of {rec['slots']} slots resident): "
-        f"{rec['rode_tokens']} of {rec['prompt_tokens']} prompt tokens rode "
-        f"{rec['ride_steps']} decode steps, {rec['cold_rode_tokens']} on the "
-        f"drained engine; tokens equal to the cold program's: "
-        f"{rec['identical']} of {rec['new']}")
-    if rec["rode_tokens"] != rec["prompt_tokens"] or rec["cold_rode_tokens"]:
-        raise SmokeFailure(f"the prompts did not ride, or rode cold: {rec}")
-    if any(n != rec["new"] for n in rec["identical"]):
-        raise SmokeFailure("a riding prompt's tokens differ from the cold "
-                           f"program's: {rec}")
+                     {**env, PHASE_ENV: "ride"}, timeout=1500)
+    recs = smoke_records(text, "ride")
+    if len(recs) != 2:
+        raise SmokeFailure(f"{len(recs)} of the 2 riding arms reported")
+    for rec in recs:
+        riding, cold = rec["cached_tokens"]
+        say(f"  riding prompts ({rec['model']}, {rec['layers']} layers, "
+            f"float32; {rec['resident']} of {rec['slots']} slots resident; "
+            f"{riding} prompt tokens from the prefix cache): "
+            f"{rec['rode_tokens']} of {rec['prompt_tokens']} prompt tokens "
+            f"rode {rec['ride_steps']} decode steps, "
+            f"{rec['cold_rode_tokens']} on the drained engine; tokens equal "
+            f"to the prefill program's: {rec['identical']} of {rec['new']}")
+        if (rec["rode_tokens"] != rec["prompt_tokens"]
+                or rec["cold_rode_tokens"] or riding != cold):
+            raise SmokeFailure("the prompts did not ride, rode on the "
+                               "drained engine, or the two passes hit the "
+                               f"cache differently: {rec}")
+        if any(n != rec["new"] for n in rec["identical"]):
+            raise SmokeFailure("a riding prompt's tokens differ from the "
+                               f"prefill program's: {rec}")
 
 
 _STEP_RE = re.compile(r"step (\d+) \| loss ([0-9.naninf-]+) \|.*?"
@@ -1583,13 +1593,17 @@ def child_seeded() -> None:
 
 
 def child_ride() -> None:
-    """Four prompts served twice by one engine at mistral-7b's widths: to
-    the BUSY engine (more than half its slots resident: the prompts ride
-    the decode dispatches) and to the drained one (the cold program), in
-    float32 with full-precision matmuls as ``child_seeded``, so that the
-    two paths differ in the order of float32 sums alone. The benchmark's
-    own check posts its prompts to an idle engine: this is where the chip
-    holds the riding path to the cold program's tokens."""
+    """Four prompts served twice by one engine: to the BUSY engine (more
+    than half its slots resident: the prompts ride the decode dispatches)
+    and to the drained one (a prefill program), in float32 with
+    full-precision matmuls as ``child_seeded``, so that the two paths
+    differ in the order of float32 sums alone. The benchmark's own check
+    posts its prompts to an idle engine: this is where the chip holds the
+    riding path to the prefill programs' tokens. Two arms: mistral-7b's
+    widths from position 0 (the cold program), then a latent-attention
+    model at Xing4.0's widths (``doc-qa-64``'s configuration, the dense
+    layer and one expert layer) behind a document's cached pages (the
+    suffix program, the window through ``mla_paged_attention_mq`` in both)."""
     child_setup()
     import dataclasses
 
@@ -1598,70 +1612,100 @@ def child_ride() -> None:
     from distributed_llm_training_and_inference_system_tpu.config.presets import (
         get_model_config)
     from distributed_llm_training_and_inference_system_tpu.config.schema import (
-        ServeConfig)
+        ModelConfig, ServeConfig)
     from distributed_llm_training_and_inference_system_tpu.serve.engine import (
         InferenceEngine)
     from distributed_llm_training_and_inference_system_tpu.serve.scheduler import (
         Request, SamplingParams)
 
-    model, layers, slots, lengths, new, span, extra = (
-        ("gpt-test", 2, 8, (9, 17, 40, 70), 8, 128, {"kv_block_size": 8})
-        if REHEARSAL
-        else ("mistral-7b", 4, 32, (70, 128, 333, 700), 32, 2048, {}))
     jax.config.update("jax_default_matmul_precision", "highest")
-    cfg = dataclasses.replace(get_model_config(model), num_layers=layers,
-                              dtype="float32")
-    eng = InferenceEngine(cfg, ServeConfig(
-        model=model, dtype="float32", max_batch_size=slots, max_seq_len=span,
-        kv_hbm_budget_gb=2.0, **extra), seed=0)
-    resident = slots // 2 + 1
-    residents = [Request(request_id=f"resident-{i}",
-                         prompt_tokens=prompt_tokens(50 + i, 24 + i,
-                                                     cfg.vocab_size),
-                         sampling=SamplingParams(temperature=0.0,
-                                                 max_tokens=span - 64))
-                 for i in range(resident)]
-    for r in residents:
-        if not eng.scheduler.add_request(r):
-            raise RuntimeError(r.error)
-    while int(eng.active.sum()) < resident:
-        eng.step()
-    prompts = [prompt_tokens(70 + i, n, cfg.vocab_size)
-               for i, n in enumerate(lengths)]
 
-    def serve(tag):
-        reqs = [Request(request_id=f"{tag}-{i}", prompt_tokens=p,
-                        sampling=SamplingParams(temperature=0.0,
-                                                max_tokens=new))
-                for i, p in enumerate(prompts)]
-        before = eng.stats()
-        for r in reqs:
-            if not eng.scheduler.add_request(r):
-                raise RuntimeError(r.error)
-        while any(r.finish_time is None for r in reqs):
-            eng.step()
-        after = eng.stats()
-        return ([list(r.generated_tokens) for r in reqs],
-                {k: after[k] - before[k]
-                 for k in ("prefill_ride_tokens", "prefill_ride_steps")})
-    rode, counted = serve("riding")
-    with eng.lock:
-        for r in residents:
-            eng.scheduler.cancel(r.request_id)
-    eng.run_until_idle()
-    # (or the second pass finds the first's pages and prefills a suffix)
-    eng.kv.flush_prefix_cache()
-    cold, cold_counted = serve("cold")
-    emit("ride", {
-        "model": model, "layers": layers, "slots": slots,
-        "resident": resident, "new": new,
-        "prompt_tokens": sum(lengths),
-        "rode_tokens": counted["prefill_ride_tokens"],
-        "ride_steps": counted["prefill_ride_steps"],
-        "cold_rode_tokens": cold_counted["prefill_ride_tokens"],
-        "identical": [next((i for i, (a, b) in enumerate(zip(x, y))
-                            if a != b), min(len(x), len(y)))
-                      for x, y in zip(rode, cold)]})
+    def arm(model, cfg, slots, lengths, new, span, prefix, extra):
+        """``prefix`` > 0: the prompts share a document of that many
+        tokens, put in the prefix cache before each pass."""
+        eng = InferenceEngine(cfg, ServeConfig(
+            model=model, dtype="float32", max_batch_size=slots,
+            max_seq_len=span, **extra), seed=0)
+        resident = slots // 2 + 1
+        residents = [Request(request_id=f"resident-{i}",
+                             prompt_tokens=prompt_tokens(50 + i, 24 + i,
+                                                         cfg.vocab_size),
+                             sampling=SamplingParams(temperature=0.0,
+                                                     max_tokens=span - 64))
+                     for i in range(resident)]
+        document = prompt_tokens(69, prefix, cfg.vocab_size)
+        prompts = [document + prompt_tokens(70 + i, n, cfg.vocab_size)
+                   for i, n in enumerate(lengths)]
+
+        def submit(reqs):
+            for r in reqs:
+                if not eng.scheduler.add_request(r):
+                    raise RuntimeError(r.error)
+
+        def serve(tag):
+            if prefix:      # the document's whole pages into the cache
+                eng.generate([document + prompt_tokens(68, 9, cfg.vocab_size)],
+                             SamplingParams(temperature=0.0, max_tokens=1))
+            if tag == "riding":
+                submit(residents)
+                while int(eng.active.sum()) < resident:
+                    eng.step()
+            reqs = [Request(request_id=f"{tag}-{i}", prompt_tokens=p,
+                            sampling=SamplingParams(temperature=0.0,
+                                                    max_tokens=new))
+                    for i, p in enumerate(prompts)]
+            before = eng.stats()
+            submit(reqs)
+            while any(r.finish_time is None for r in reqs):
+                eng.step()
+            after = eng.stats()
+            return ([list(r.generated_tokens) for r in reqs],
+                    {k: after[k] - before[k]
+                     for k in ("prefill_ride_tokens", "prefill_ride_steps",
+                               "prefix_cached_tokens")})
+        rode, counted = serve("riding")
+        with eng.lock:
+            for r in residents:
+                eng.scheduler.cancel(r.request_id)
+        eng.run_until_idle()
+        # (or the second pass finds the first's pages and prefills less)
+        eng.kv.flush_prefix_cache()
+        cold, cold_counted = serve("cold")
+        eng.release()
+        emit("ride", {
+            "model": model, "slots": slots,
+            # (a layer table counts a decoder layer's two sub-layers)
+            "layers": (cfg.layers_of("*") if cfg.layer_pattern
+                       else cfg.num_layers),
+            "resident": resident, "new": new,
+            "prompt_tokens": sum(lengths),
+            "cached_tokens": [counted["prefix_cached_tokens"],
+                              cold_counted["prefix_cached_tokens"]],
+            "rode_tokens": counted["prefill_ride_tokens"],
+            "ride_steps": counted["prefill_ride_steps"],
+            "cold_rode_tokens": cold_counted["prefill_ride_tokens"],
+            "identical": [next((i for i, (a, b) in enumerate(zip(x, y))
+                                if a != b), min(len(x), len(y)))
+                          for x, y in zip(rode, cold)]})
+
+    def float32(cfg, **over):
+        return dataclasses.replace(cfg, dtype="float32", **over)
+    if REHEARSAL:
+        arm("gpt-test", float32(get_model_config("gpt-test"), num_layers=2),
+            8, (9, 17, 40, 70), 8, 128, 0, {"kv_block_size": 8})
+        arm("xing-test", float32(get_model_config("xing-test")),
+            8, (9, 17, 40, 70), 8, 256, 64, {"kv_block_size": 8})
+        return
+    arm("mistral-7b", float32(get_model_config("mistral-7b"), num_layers=4),
+        32, (70, 128, 333, 700), 32, 2048, 0, {"kv_hbm_budget_gb": 2.0})
+    published = json.loads((ROOT / "benchmark" / "configs"
+                            / "xing4.0-29b-a4b-7l.json").read_text())
+    # a document of 3 pages of 256, tails of one and two pieces
+    arm(published["name"], float32(ModelConfig.from_published(
+        dict(published, num_hidden_layers=2))),
+        16, (70, 250, 300, 500), 32, 2048, 768,
+        {"kv_block_size": published["serve"]["kv_block_size"],
+         "kv_hbm_budget_gb": 1.0})
 
 
 CHILDREN = {"kernels": child_kernels, "mesh_train": child_mesh_train,

@@ -305,10 +305,26 @@ def table_layers(cfg: ModelConfig) -> list[tuple[str, int]]:
     return out
 
 
-def table_layer(blocks: Params, kind: str, index: int) -> Params:
-    """One layer of ``kind``'s stack (a static index: the table is walked
-    by a Python loop). An expert layer keeps its routed experts' kernels
-    as the WHOLE stacks, which ``moe_block`` addresses by ``index``."""
+def table_period(cfg: ModelConfig) -> tuple[list, list, int]:
+    """The layer table as (head, unit, repetitions): the shortest ``head``
+    after which the kinds repeat ``unit`` at least twice to the table's
+    end; ``*D*E*E*E`` -> ([("*", 0), ("D", 0)], [("*", 1), ("E", 0)], 3).
+    Repetition r's layer of a kind is ``unit``'s index + r x that kind's
+    count in ``unit``. A table that ends in no period is all head."""
+    layers, kinds = table_layers(cfg), cfg.layer_pattern
+    for h in range(len(kinds)):
+        rest = kinds[h:]
+        for p in range(1, len(rest) // 2 + 1):
+            if rest == rest[:p] * (len(rest) // p):
+                return layers[:h], layers[h:h + p], len(rest) // p
+    return layers, [], 0
+
+
+def table_layer(blocks: Params, kind: str, index) -> Params:
+    """One layer of ``kind``'s stack (a static index where the table is
+    walked by a Python loop, a traced one inside ``table_period``'s loop).
+    An expert layer keeps its routed experts' kernels as the WHOLE stacks,
+    which ``moe_block`` addresses by ``index``."""
     from ..config.schema import LAYER_KINDS
     stack = blocks[LAYER_KINDS[kind]]
     whole = {}

@@ -209,8 +209,12 @@ def mla_paged_attention_pallas(q, pool, block_tables, start_positions, *,
 
     # a long window (suffix / chunked prefill) is tiled along the queries:
     # each tile an independent online-softmax pass over the slot's pages
-    tile = T_in if T_in * N <= _MAX_QUERY_ROWS else max(
-        _MAX_QUERY_ROWS // N, 1)
+    # (4-byte operands take half the rows: the tile's query and output
+    # blocks are twice the bytes, and 1,024 rows of 32 heads x 640 float32
+    # asked for more than the kernel's 16 MB of VMEM inside a decode
+    # program: chip_smoke.py's float32 arm, PERF.md 6, PR 41)
+    max_rows = _MAX_QUERY_ROWS * 2 // max(q.dtype.itemsize, 2)
+    tile = T_in if T_in * N <= max_rows else max(max_rows // N, 1)
     T = -(-T_in // tile) * tile
     if T != T_in:
         q = jnp.pad(q, ((0, 0), (0, T - T_in), (0, 0), (0, 0)))

@@ -12,6 +12,7 @@ multiplied (``mm``).
 
 from __future__ import annotations
 
+import collections
 from typing import Any, NamedTuple
 
 import jax
@@ -24,9 +25,11 @@ from ..models.gpt import (
     split_expert_stacks,
     table_layer,
     table_layers,
+    table_period,
     unembed,
 )
 from ..models.layers import decoder_block, model_rope_frequencies
+from ..ops.mla_paged_attention import mla_paged_attention
 from ..ops.paged_attention import (
     paged_attention_multi,
     write_window_to_pages,
@@ -85,6 +88,16 @@ def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl):
 # (PERF.md 6, PR 36: the program's set-up).
 _shared_windows = jax.jit(_windows, static_argnames=("attn_impl",))
 _shared_sampler = jax.jit(sample_tokens)
+
+
+def can_carry(cfg: ModelConfig) -> bool:
+    """Can a decode step of this model carry a ``Piece``? The uniform stack
+    and a layer table of ``D`` / ``E`` / ``*`` over one latent pool: every
+    sub-layer but ``attend`` is per row, and both kinds of ``attend`` take a
+    window of one slot. A recurrent layer (``M``, ``K``) has no form that
+    runs a chunk of ONE slot from its state inside a step over all slots."""
+    return not cfg.layer_pattern or (cfg.is_latent
+                                     and not cfg.is_recurrent)
 
 
 def decode_step_forward(
@@ -152,11 +165,12 @@ def extend_step_forward(
     return_moe_stats: bool = False,
     ssm_state: Any = None,    # {"conv": [Lm, B, K-1, C], "ssm": [Lm, B, nh,
                               # P, N]}: the state-space layers' pools
-    ride: Any = None,         # a Piece (T == 1, the uniform stack): its C
-                              # rows join the B rows of the step
+    ride: Any = None,         # a Piece (T == 1, ``can_carry`` models): its
+                              # C rows join the B rows of the step
     two_bodies: bool = False,  # this step is one of the two bodies of a
                               # program that rides: the B slots' windows go
-                              # through ``_shared_windows``
+                              # through ``_shared_windows``, and a layer
+                              # table's periodic part is walked by a loop
     state_slot: Any = None,   # int32 []: the ONE slot whose window this is
                               # (B == 1: chunked prefill through K layers)
 ) -> tuple:
@@ -211,9 +225,10 @@ def extend_step_forward(
     positions = start_positions[:, None] + jnp.arange(T, dtype=jnp.int32)
     live = write_ok           # [rows, T]: the rows that are tokens
     if ride is not None:
-        if T != 1 or cfg.layer_pattern:
+        if T != 1 or not can_carry(cfg):
             raise ValueError("a piece rides a decode step (T = 1) of the "
-                             "uniform layer stack")
+                             "uniform layer stack or of a latent layer "
+                             "table without recurrent layers")
         offs = jnp.arange(ride.tokens.shape[0], dtype=jnp.int32)
         piece_ok = offs < ride.live
         tokens = jnp.concatenate([tokens, ride.tokens[:, None]])
@@ -294,6 +309,15 @@ def extend_step_forward(
             return jnp.concatenate([out, piece_out[0][:, None]]), state
         return attend
 
+    def head_rows(x):
+        """The rows the head runs over: with a piece B + 1, the piece's
+        last live row alone can become a token (its prompt's first, when
+        the piece is final)."""
+        if ride is None:
+            return x
+        return jnp.concatenate([x[:B], jax.lax.dynamic_slice_in_dim(
+            x, B + jnp.maximum(ride.live - 1, 0), 1)])
+
     if cfg.layer_pattern:
         # a layer table: one parameter stack a kind, walked by a Python
         # loop; every pool (pages, conv tails, states) and every expert
@@ -325,7 +349,7 @@ def extend_step_forward(
             if cfg.is_latent:
                 return attend_latent_pages(
                     cfg, kp, li, block_tables, start_positions, write_ok,
-                    attn_impl)
+                    attn_impl, ride=ride, two_bodies=two_bodies)
             return attend_pages(kp, vp, li)
         conv, ssm = (ssm_state["conv"], ssm_state["ssm"]) \
             if ssm_state is not None else (None, None)
@@ -336,7 +360,9 @@ def extend_step_forward(
                 conv, ssm, state_slot, start_positions)
             new_tails, new_states = [], []
         stats = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
-        for kind, i in table_layers(cfg):
+
+        def sub_layer(carry, kind, i):
+            x, kp, vp, conv, ssm, stats = carry
             x, state, layer_stats = decoder_block(
                 x, table_layer(blocks, kind, i), cfg, positions, inv_freq,
                 attend_at(kp, vp, i) if kind == "*" else None, matmul=mm,
@@ -351,12 +377,34 @@ def extend_step_forward(
                 conv, ssm = state
             elif kind == "E":
                 stats = stats + layer_stats
+            return x, kp, vp, conv, ssm, stats
+        # A program that rides holds two step bodies, and a table walked by
+        # a Python loop holds every layer's kernels once a body: the latent
+        # cell's executable grew from 57 to 148 MB and its first call from
+        # 10.9 to 16.4 s of every start (PERF.md 6, PR 41). Its bodies walk
+        # the table's periodic part (``*E`` x 6) by a loop over traced layer
+        # indices, as the uniform stack's scan does; the pools are the
+        # loop's carry, written in place. Any other program walks it whole.
+        head, unit, reps = (table_period(cfg) if two_bodies
+                            else (table_layers(cfg), [], 0))
+        carry = (x, kp, vp, conv, ssm, stats)
+        for kind, i in head:
+            carry = sub_layer(carry, kind, i)
+        if reps:
+            per_rep = collections.Counter(kind for kind, _ in unit)
+
+            def period(r, carry):
+                for kind, i in unit:
+                    carry = sub_layer(carry, kind, i + r * per_rep[kind])
+                return carry
+            carry = jax.lax.fori_loop(0, reps, period, carry)
+        x, kp, vp, conv, ssm, stats = carry
         if state_slot is not None:
             conv, ssm = kda.write_slot_state(conv, ssm, state_slot,
                                              new_tails, new_states)
         if cfg.hc_mult > 1:
             x = jnp.sum(x.astype(jnp.float32), axis=2).astype(compute_dtype)
-        return (unembed(params, x, cfg), kp, vp,
+        return (unembed(params, head_rows(x), cfg), kp, vp,
                 *([stats] if return_moe_stats else []),
                 *([{"conv": conv, "ssm": ssm}] if ssm_state is not None
                   else []))
@@ -393,37 +441,64 @@ def extend_step_forward(
         body, (x, k_pages, v_pages, *stats0),
         (blocks, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
 
-    if ride is not None:
-        # the head runs over B + 1 rows: the piece's last live row alone
-        # can become a token (its prompt's first, when the piece is final)
-        x = jnp.concatenate([x[:B], jax.lax.dynamic_slice_in_dim(
-            x, B + jnp.maximum(ride.live - 1, 0), 1)])
-    return (unembed(params, x, cfg), new_k, new_v, *stats)
+    return (unembed(params, head_rows(x), cfg), new_k, new_v, *stats)
+
+
+def _latent_windows(q_lat, rows, pool, tables, starts, ok, li, scale,
+                    value_width, attn_impl):
+    """Write each slot's window of latent rows into its pages at layer
+    ``li`` (zero-padded to the pool's row width) and let every head's
+    absorbed query walk the slot's live pages once: (out, new pool)."""
+    pad = pool.shape[-1] - rows.shape[-1]
+    with jax.named_scope("mla_page_write"):
+        rows = jnp.pad(rows, ((0, 0), (0, 0), (0, pad)))
+        new = write_window_to_pages(pool, rows[:, :, None, :], tables,
+                                    starts, ok, li)
+    out = mla_paged_attention(
+        jnp.pad(q_lat, ((0, 0), (0, 0), (0, 0), (0, pad))), new, tables,
+        starts, scale=scale, value_width=value_width, impl=attn_impl,
+        layer=li)
+    return out, new
+
+
+# the latent ``_shared_windows``: a riding program's two bodies AND its
+# layers (a layer table is walked by a Python loop) call ONE function a
+# window shape, the B slots' T = 1 and the piece's T = C
+_shared_latent_windows = jax.jit(
+    _latent_windows, static_argnames=("scale", "value_width", "attn_impl"))
 
 
 def attend_latent_pages(cfg: ModelConfig, pool: jax.Array, li,
                         block_tables: jax.Array, start_positions: jax.Array,
-                        write_ok: Any = None, attn_impl: str = "auto"):
+                        write_ok: Any = None, attn_impl: str = "auto",
+                        ride: Any = None, two_bodies: bool = False):
     """The latent ``attend`` (``layers.latent_attention_mixer``) over the ONE
     latent pool ``pool`` [La, NP, 1, PS, W], written and read at layer
     ``li``: the window's rows go in by the whole-page merge every window
     takes, then every head's absorbed query walks the slot's live pages
-    once. The state it returns is (the pool, None): there is no second
-    pool."""
-    from ..ops.mla_paged_attention import mla_paged_attention
-    pad = pool.shape[-1] - cfg.mla.latent_size
+    once. With ``ride`` the B slots' rows go first, as ever, then the
+    piece's C rows as ONE window over its own slot's pages (the multi-query
+    kernel, what a suffix prefill's program runs). The state it returns is
+    (the pool, None): there is no second pool."""
+    windows = _shared_latent_windows if two_bodies else _latent_windows
+    B = block_tables.shape[0]
 
     def attend(q_lat, rows, scale):
-        with jax.named_scope("mla_page_write"):
-            rows = jnp.pad(rows, ((0, 0), (0, 0), (0, pad)))
-            new = write_window_to_pages(
-                pool, rows[:, :, None, :], block_tables, start_positions,
-                write_ok, li)
-        out = mla_paged_attention(
-            jnp.pad(q_lat, ((0, 0), (0, 0), (0, 0), (0, pad))), new,
-            block_tables, start_positions, scale=scale,
-            value_width=cfg.mla.kv_lora_rank, impl=attn_impl, layer=li)
-        return out, (new, None)
+        how = dict(scale=scale, value_width=cfg.mla.kv_lora_rank,
+                   attn_impl=attn_impl)
+        if ride is None:
+            out, new = windows(q_lat, rows, pool, block_tables,
+                               start_positions, write_ok, li, **how)
+            return out, (new, None)
+        out, new = windows(q_lat[:B], rows[:B], pool, block_tables,
+                           start_positions, write_ok, li, **how)
+        # [C, 1, ...] -> [1, C, ...]; the padding goes to the scratch page
+        piece_ok = jnp.arange(ride.tokens.shape[0]) < ride.live
+        piece_out, new = windows(
+            q_lat[B:, 0][None], rows[B:, 0][None], new,
+            block_tables[ride.slot][None], ride.start[None], piece_ok[None],
+            li, **how)
+        return jnp.concatenate([out, piece_out[0][:, None]]), (new, None)
     attend.latent = True
     return attend
 
@@ -525,12 +600,25 @@ def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
             key_data = jnp.concatenate([slot_keys, slot_keys[slot][None]])
             fold = jnp.append(fold, at)
             sampling = [jnp.append(a, a[slot]) for a in sampling]
+            if cfg.layer_pattern and len(fold) % 8:
+                # row B again, up to whole tiles of 8 rows: at the latent
+                # cell's vocabulary of 131k the sampler's code is 18 MB
+                # over 64 rows, 40 over 65 and 22 over 72, twice in the
+                # executable (compiled for a described v5e, PERF.md 6, PR
+                # 41). The uniform stack's programs keep the B + 1 rows
+                # they were accepted with (3.1 MB at a vocabulary of 32k)
+                def whole(a):
+                    return jnp.concatenate(
+                        [a, jnp.repeat(a[-1:], -len(a) % 8, axis=0)])
+                logits, key_data, fold = map(whole, (logits, key_data, fold))
+                sampling = [whole(a) for a in sampling]
         keys = jax.vmap(jax.random.fold_in)(
             jax.vmap(jax.random.wrap_key_data)(key_data), fold)
         nxt = (sample_tokens if ride is None else _shared_sampler)(
             logits, keys, *sampling)
         first = jnp.int32(0)
         if ride is not None:
+            nxt = nxt[:len(toks) + 1]          # without the tile's padding
             nxt, last = nxt[:-1], nxt[-1]
             if piece is not None:
                 first = jnp.where(piece.stop > 0, last, 0)

@@ -19,8 +19,9 @@ TPU-shaped execution model:
 - **Sampling** — on device, batched, per-request params (serve/sampling.py).
 - **A prompt admitted to a busy batch rides the decode dispatches** — while
   at least half the slots are resident its rows join the decode steps'
-  matmuls, ``RIDE_PAGES`` pages a step, and no prefill program runs between
-  two dispatches (``_start_ride``, ``_lay_pieces``, ``_apply_rides``).
+  matmuls, ``RIDE_PAGES`` pages a step (one where a page holds
+  ``RIDE_ROWS`` rows), and no prefill program runs between two dispatches
+  (``_start_ride``, ``_lay_pieces``, ``_apply_rides``).
 
 Admission reserves pages for prompt+max_tokens up front, so decode can
 never hit KV OOM mid-flight (simple and correct; preemption/swapping is the
@@ -42,7 +43,7 @@ import numpy as np
 
 from ..config.schema import ModelConfig, ServeConfig
 from ..models import gpt
-from .decode import PIECE_META, decode_scan, extend_step_forward
+from .decode import PIECE_META, can_carry, decode_scan, extend_step_forward
 from .kv_cache import PagedKVCache
 from .sampling import fold_in_key_data, sample_tokens, seed_key_data
 from .scheduler import (ContinuousBatchingScheduler, Request, RequestState,
@@ -362,8 +363,9 @@ class InferenceEngine:
         # submitted dispatches; ``done``: tokens of dispatches applied).
         # Rows a decode step can carry: 0 where the engine never rides.
         self._riding: dict[str, dict] = {}
-        self._ride_rows = (self.RIDE_PAGES * self.kv.page_size
-                           if self._can_ride(pre_quantized) else 0)
+        PS = self.kv.page_size
+        self._ride_rows = 0 if not self._can_ride(pre_quantized) else (
+            PS if PS >= self.RIDE_ROWS else self.RIDE_PAGES * PS)
         # decode: ONE compiled executable for every dispatch length.
         # With latency-adaptive dispatch (L > 0) the unit is L steps and
         # a full dispatch chains ceil(K/L) units on the device-resident
@@ -525,6 +527,11 @@ class InferenceEngine:
     # / 29 us a row, and the cells' traffic needs ~70 prompt rows a step,
     # more than 1 page a step can carry
     RIDE_PAGES = 2
+    # ... which came to 128 rows at that page size. A page that holds as
+    # many alone is a step's whole carry: ONE page (a latent model's pages
+    # of 256: two would be a window of 512 padded rows through the
+    # multi-query latent kernel in one step, PERF.md 6, PR 41)
+    RIDE_ROWS = 128
 
     # dispatches that chain onto one another before the host catches up
     # with the device ONCE (``step`` fetches and applies the one in flight
@@ -541,14 +548,14 @@ class InferenceEngine:
 
     def _can_ride(self, pre_quantized: bool) -> bool:
         """Can this engine prefill a prompt inside its decode dispatches at
-        all? Read off the configuration: the uniform layer stack (a layer
-        table's recurrent layers refuse chunked prefill, and a latent
-        window needs the multi-query latent kernel beside the T = 1 one),
-        plain weights (the W4 / W8 kernels take at most 64 rows), one chip
-        (tp forces gather attention), the continuous scheduler, and no
-        speculation (its dispatch is another program)."""
+        all? Read off the configuration: layers whose step can carry a
+        piece (``decode.can_carry``: the uniform stack, a latent layer
+        table; a recurrent layer has no chunk-from-state form inside a
+        batch step), plain weights (the W4 / W8 kernels take at most 64
+        rows), one chip (tp forces gather attention), the continuous
+        scheduler, and no speculation (its dispatch is another program)."""
         c = self.serve_cfg
-        return (not self.cfg.layer_pattern and not pre_quantized
+        return (can_carry(self.cfg) and not pre_quantized
                 and c.quantization in ("", "none")
                 and c.tensor_parallel <= 1 and c.speculative == "off"
                 and c.scheduler != "static")

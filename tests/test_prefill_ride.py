@@ -1,13 +1,16 @@
 """A prompt admitted to a busy batch rides the decode dispatches (PR 36):
 its rows join the decode steps' matmuls, a piece of ``RIDE_PAGES`` pages a
 step, and no prefill program runs between two dispatches. What must hold:
-the tokens a riding prompt is served and the K/V its pages hold are the cold
-program's; under half occupancy nothing rides; a riding prompt that is
-cancelled, preempted or failed gives its pages and slot back; and a model
-that cannot ride (a layer table) keeps the parent's decode program.
+the tokens a riding prompt is served and the K/V (or latent rows) its pages
+hold are the cold program's, and behind a prefix hit the suffix program's;
+under half occupancy nothing rides; a riding prompt that is cancelled,
+preempted or failed gives its pages and slot back; and a model that cannot
+ride (a layer table with recurrent layers) keeps the parent's decode
+program.
 
-CPU, float32, the dense and the MoE test configurations; 4 slots, pages of
-8 tokens, 4 steps a dispatch, so a piece is 16 rows.
+CPU, float32, the dense, the MoE and (PR 41) the latent test
+configurations; 4 slots, pages of 8 tokens, 4 steps a dispatch, so a piece
+is 16 rows.
 """
 
 import jax
@@ -18,6 +21,8 @@ import pytest
 from distributed_llm_training_and_inference_system_tpu.config import get_model_config
 from distributed_llm_training_and_inference_system_tpu.config.schema import ServeConfig
 from distributed_llm_training_and_inference_system_tpu.models import init
+from distributed_llm_training_and_inference_system_tpu.models.gpt import (
+    table_period)
 from distributed_llm_training_and_inference_system_tpu.serve import (
     InferenceEngine,
     Request,
@@ -30,7 +35,7 @@ from distributed_llm_training_and_inference_system_tpu.serve.scheduler import (
 
 PS, STEPS, SLOTS = 8, 4, 4
 C = InferenceEngine.RIDE_PAGES * PS
-MODELS = ["gpt-test", "olmoe-test"]
+MODELS = ["gpt-test", "olmoe-test", "xing-test"]
 RNG = np.random.default_rng(36)
 
 
@@ -59,7 +64,7 @@ SAMPLING = {
 
 def _engine(name, **over):
     cfg = get_model_config(name)
-    opts = dict(model=name, max_batch_size=SLOTS, max_seq_len=128,
+    opts = dict(model=name, max_batch_size=SLOTS, max_seq_len=192,
                 prefill_chunk=32, kv_block_size=PS, dtype="float32",
                 decode_steps_per_dispatch=STEPS)
     opts.update(over)
@@ -75,8 +80,9 @@ def engines(request):
 
 
 def _keep_pages(eng, kept):
-    """Keep each prompt's K and V rows as its pages hold them when its
-    first token is delivered ([L, Nkv, n, D], whichever pages they are)."""
+    """Keep each prompt's K and V rows (a latent model's one pool's rows) as
+    its pages hold them when its first token is delivered ([L, Nkv, n, D],
+    whichever pages they are)."""
     def hook(req, tokens):
         n = req.num_prompt_tokens
         if req.request_id in kept or not req.request_id.startswith("p"):
@@ -85,7 +91,7 @@ def _keep_pages(eng, kept):
         kept[req.request_id] = [
             np.asarray(pool)[:, table].transpose(0, 2, 1, 3, 4).reshape(
                 pool.shape[0], pool.shape[2], -1, pool.shape[4])[:, :, :n]
-            for pool in (eng.kv.k_pages, eng.kv.v_pages)]
+            for pool in (eng.kv.k_pages, eng.kv.v_pages) if pool is not None]
     eng.on_token = hook
 
 
@@ -112,14 +118,28 @@ def _idle(eng):
     assert eng.kv.free_pages == eng.kv.num_pages - 1
 
 
+# where a riding prompt's first piece starts: at 0, or behind the pages of a
+# prefix another request left in the cache (the prompt's ``cached`` tokens:
+# the reference at the idle engine is then the SUFFIX program; 8 pages,
+# since off the TPU admission drops a hit shorter than the tail behind it)
+CACHED = {"from 0": 0, "behind a prefix hit": 8 * PS}
+
+
+@pytest.mark.parametrize("start", list(CACHED))
 @pytest.mark.parametrize("sampling", list(SAMPLING))
 @pytest.mark.parametrize("scenario", list(SCENARIOS))
 def test_a_riding_prompt_is_served_the_cold_programs_tokens_and_pages(
-        engines, scenario, sampling):
+        engines, scenario, sampling, start):
     riding, cold = engines
+    tag = f"-{scenario}-{sampling}-{start}"
     # (fresh tokens a case: a repeated prompt would be a prefix hit)
-    prompts = [_tokens(n) for n in SCENARIOS[scenario]]
-    tag = f"-{scenario}-{sampling}"
+    prefix = _tokens(CACHED[start])
+    if prefix:
+        for eng in engines:     # the prefix's whole pages into the cache
+            _serve(eng, [prefix + _tokens(3)], SAMPLING["greedy"],
+                   residents=0, tag=tag + "-prefix")
+    # the scenario's lengths are what is left to prefill
+    prompts = [prefix + _tokens(n) for n in SCENARIOS[scenario]]
     rode, pages_rode, pages_cold = riding.stats(), {}, {}
     _keep_pages(riding, pages_rode)
     _keep_pages(cold, pages_cold)
@@ -131,9 +151,11 @@ def test_a_riding_prompt_is_served_the_cold_programs_tokens_and_pages(
             == before["prefill_ride_tokens"])
     stats = riding.stats()
     assert (stats["prefill_ride_tokens"] - rode["prefill_ride_tokens"]
-            == sum(map(len, prompts)))
+            == sum(SCENARIOS[scenario]))
     assert (stats["prefill_ride_steps"] - rode["prefill_ride_steps"]
-            == sum(-(-len(p) // C) for p in prompts))
+            == sum(-(-n // C) for n in SCENARIOS[scenario]))
+    assert (stats["prefix_cached_tokens"] - rode["prefix_cached_tokens"]
+            == len(prompts) * len(prefix))
     riding.on_token = cold.on_token = None
     for a, b in zip(got, want):
         assert a.state is RequestState.FINISHED
@@ -360,11 +382,25 @@ def test_engines_that_keep_todays_path_never_ride(over):
     assert eng.stats()["prefill_ride_tokens"] == 0
 
 
-@pytest.mark.parametrize("name", ["nemotron-h-test", "xing-test"])
+@pytest.mark.parametrize("name,page,rows", [
+    ("gpt-test", 8, 16), ("gpt-test", 64, 128), ("olmoe-test", 64, 128),
+    ("gpt-test", 128, 128), ("xing-test", 64, 128), ("xing-test", 256, 256)])
+def test_the_carry_is_read_off_the_page_size(name, page, rows):
+    """``RIDE_PAGES`` pages a step, as chosen on the chip at pages of 64
+    (128 rows); ONE page where a page alone holds as many (a latent model's
+    pages of 256: two would be a window of 512 rows in one step)."""
+    eng = _engine(name, kv_block_size=page, max_seq_len=512)
+    assert eng._ride_rows == rows
+    [pieces] = eng._decode_tail_args()[1:]
+    assert pieces.shape == (STEPS, PIECE_META + rows)
+
+
+@pytest.mark.parametrize("name", ["nemotron-h-test", "kimi-linear-test"])
 def test_a_layer_table_model_keeps_the_parents_decode_program(name):
-    """A hybrid and a latent configuration do not ride: the engine hands
-    their decode program no pieces, and the program lowers to the text of
-    the parent's ``_decode_impl_n`` (written out below as it stood)."""
+    """A hybrid and a linear-attention configuration (recurrent layers in
+    the table) do not ride: the engine hands their decode program no
+    pieces, and the program lowers to the text of the parent's
+    ``_decode_impl_n`` (written out below as it stood)."""
     cfg = get_model_config(name)
     eng = InferenceEngine(
         cfg, ServeConfig(model=name, max_batch_size=SLOTS, max_seq_len=128,
@@ -391,6 +427,17 @@ def test_a_layer_table_model_keeps_the_parents_decode_program(name):
     assert eng._decode_jit.lower(*args).as_text() == parents.as_text()
 
 
+def _pools(cfg, pages, fill=None):
+    """(k_pages, v_pages) of ``pages`` pages, zeros or ``fill``ed; a latent
+    model's ONE pool of padded rows and None."""
+    shape = ((cfg.num_layers, pages, 1, PS, cfg.mla.page_width)
+             if cfg.is_latent else
+             (cfg.num_layers, pages, cfg.num_kv_heads, PS, cfg.head_dim))
+    pool = jnp.asarray(np.zeros(shape) if fill is None else fill(size=shape),
+                       jnp.float32)
+    return pool, (None if cfg.is_latent else pool + 1)
+
+
 @pytest.mark.parametrize("name", MODELS)
 def test_a_step_without_a_piece_samples_what_the_plain_step_samples(name):
     """``decode_scan`` with all-zero pieces branches to the plain step: the
@@ -398,12 +445,10 @@ def test_a_step_without_a_piece_samples_what_the_plain_step_samples(name):
     cfg = get_model_config(name)
     params = init(cfg, jax.random.PRNGKey(0))
     B, pages = 3, 9
-    pool = jnp.asarray(RNG.normal(size=(cfg.num_layers, pages,
-                                        cfg.num_kv_heads, PS, cfg.head_dim)),
-                       jnp.float32)
     tables = jnp.asarray([[1, 2], [3, 4], [5, 6]], jnp.int32)
     args = (jnp.asarray([5, 6, 7], jnp.int32), jnp.asarray([3, 9, 0]),
-            pool, pool + 1, tables, jnp.asarray([16, 16, 0]),
+            *_pools(cfg, pages, RNG.normal), tables,
+            jnp.asarray([16, 16, 0]),
             jnp.zeros((B, 2), jnp.uint32), jnp.zeros(B, jnp.float32),
             jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32))
     plain, toks = decode_scan(params, *args, cfg, STEPS, attn_impl="gather")
@@ -413,7 +458,7 @@ def test_a_step_without_a_piece_samples_what_the_plain_step_samples(name):
     np.testing.assert_array_equal(toks, toks_r)
     np.testing.assert_array_equal(firsts, 0)
     for a, b in zip(plain, rode):
-        np.testing.assert_array_equal(a, b)
+        assert (a is None and b is None) or np.array_equal(a, b)
 
 
 @pytest.mark.parametrize("name", MODELS)
@@ -423,15 +468,16 @@ def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
     ``setup_s``). The slots' page writes with their T = 1 attention and the
     sampler run at the same shapes in both, so each is ONE function of the
     lowered program, called from both bodies (PERF.md 6, PR 36); a program
-    that does not ride holds neither."""
+    that does not ride holds neither. A latent layer table's windows are
+    one function a SHAPE for all its layers too: the slots' T = 1 (both
+    bodies) and the piece's T = C."""
     import re
     cfg = get_model_config(name)
     params = init(cfg, jax.random.PRNGKey(0))
     B, pages = 3, 9
-    pool = jnp.zeros((cfg.num_layers, pages, cfg.num_kv_heads, PS,
-                      cfg.head_dim), jnp.float32)
-    args = (params, jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32), pool,
-            pool, jnp.zeros((B, 2), jnp.int32), jnp.ones(B, jnp.int32),
+    args = (params, jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
+            *_pools(cfg, pages),
+            jnp.zeros((B, 2), jnp.int32), jnp.ones(B, jnp.int32),
             jnp.zeros((B, 2), jnp.uint32), jnp.zeros(B, jnp.float32),
             jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32))
 
@@ -440,12 +486,56 @@ def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
     rides = jax.jit(program).lower(
         *args, ride=jnp.zeros((STEPS, PIECE_META + C), jnp.int32)).as_text()
     plain = jax.jit(program).lower(*args).as_text()
-    for shared in ("_windows", "sample_tokens"):
-        assert len(re.findall(rf"func\.func private @{shared}\(",
-                              rides)) == 1, shared
-        assert len(re.findall(rf"call @{shared}\(", rides)) == 2, shared
-        assert f"@{shared}(" not in plain
+    def functions(shared):
+        """[calls of each private function named ``shared``], most first."""
+        names = re.findall(rf"func\.func private @({shared}(?:_\d+)?)\(",
+                           rides)
+        assert not re.search(rf"@{shared}(_\d+)?\(", plain), shared
+        return sorted((len(re.findall(rf"call @{name}\(", rides))
+                       for name in names), reverse=True)
+    assert functions("sample_tokens") == [2]
+    if cfg.is_latent:
+        # a call site a layer of the table's head and ONE for the loop over
+        # its periodic part (``*D`` then ``*E`` x 2: two sites, not three)
+        head, unit, reps = table_period(cfg)
+        assert reps == 2 and len(head) + reps * len(unit) == cfg.num_layers
+        sites = sum(kind == "*" for kind, _ in head + unit)
+        assert functions("_latent_windows") == [2 * sites, sites]
+        # and the ONE sampler takes whole tiles of 8 rows (B + 1 = 4 here)
+        assert re.search(r"func\.func private @sample_tokens\("
+                         rf"%arg0: tensor<8x{cfg.vocab_size}xf32>", rides)
+    else:
+        assert functions("_windows") == [2]
     assert len(rides) < 2 * len(plain)
+
+
+@pytest.mark.parametrize("pattern,head,unit,reps", [
+    ("*D*E*E*E", "*D", "*E", 3),          # the latent cell's table, shorter
+    ("*D*D*E*E", "*D*D", "*E", 2),
+    ("*E*E", "", "*E", 2),
+    ("*D*E", "*D*E", "", 0),              # nothing repeats
+    ("MEMEM*E", "MEMEM*E", "", 0),        # the hybrid test table
+    ("KDKEKE*EKEKEKE*E", "KDKEKE*EKEKEKE*E", "", 0),   # the linear test table
+    ("KDKE*EKE*E", "KD", "KE*E", 2),
+    ("*EEE*EEE*EEE", "", "*EEE", 3),
+])
+def test_a_tables_periodic_part(pattern, head, unit, reps):
+    """``gpt.table_period``: the shortest head, then the unit that repeats
+    to the table's end; repetition r's layer of a kind is the unit's index
+    + r x the kind's count in the unit, which walks every layer once."""
+    import collections
+    import dataclasses
+    from distributed_llm_training_and_inference_system_tpu.models.gpt import (
+        table_layers)
+    cfg = dataclasses.replace(get_model_config("xing-test"),
+                              layer_pattern=pattern, num_layers=len(pattern))
+    got_head, got_unit, got_reps = table_period(cfg)
+    assert "".join(k for k, _ in got_head) == head
+    assert "".join(k for k, _ in got_unit) == unit and got_reps == reps
+    per_rep = collections.Counter(k for k, _ in got_unit)
+    walked = got_head + [(k, i + r * per_rep[k])
+                         for r in range(got_reps) for k, i in got_unit]
+    assert walked == table_layers(cfg)
 
 
 @pytest.mark.parametrize("rows", [256, 1024, 1280, 2048, 8192, 1300])
