@@ -1,0 +1,11 @@
+"""Share of the live slots' forwards that were COMMITS (a finished block
+forwarded once more to store its K/V, fixing no token), over the window:
+``commit_slot_forwards`` / ``slot_forwards``. 1 in ``denoising_steps + 1``
+under the schedule (20 % at 4)."""
+from benchmark import diffusion_counters
+
+
+def read(run):
+    share = diffusion_counters.ratio(run, "commit_slot_forwards",
+                                     "slot_forwards")
+    return None if share is None else 100.0 * share

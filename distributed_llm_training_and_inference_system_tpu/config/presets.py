@@ -10,6 +10,7 @@ TPU slices.
 from __future__ import annotations
 
 from .schema import (
+    DiffusionConfig,
     HardwareConfig,
     ModelConfig,
     MoEConfig,
@@ -184,6 +185,19 @@ TEST_TEMPLATES: dict[str, ModelConfig] = {
         qk_norm="projection",
         moe=MoEConfig(num_experts=8, experts_per_token=2,
                       norm_topk_prob=False),
+    ),
+    # sdar_moe's shape in small: generation by diffusion over blocks of 4
+    # (rows of a block see each other; the mask token is the vocabulary's
+    # last id), renormalised top-k experts, per-head q/k norms, GQA.
+    "sdar-test": ModelConfig(
+        name="sdar-test", num_layers=2, hidden_size=64, ffn_size=32,
+        num_heads=4, num_kv_heads=2, head_dim=16, vocab_size=256,
+        max_position_embeddings=256, activation="silu", dtype="float32",
+        norm_eps=1e-6, rope=RopeConfig(base=1e6), qk_norm="head",
+        moe=MoEConfig(num_experts=8, experts_per_token=2,
+                      norm_topk_prob=True),
+        diffusion=DiffusionConfig(block_length=4, denoising_steps=4,
+                                  mask_token_id=255),
     ),
     # nemotron_h's shape in small: one 7-layer motif of its layer table,
     # state-space mixers beside GQA attention without rope and sigmoid-

@@ -337,6 +337,56 @@ class KDAConfig:
         )
 
 
+REMASKING_STRATEGIES = ("low_confidence_dynamic", "low_confidence_static",
+                        "sequential")
+
+
+@dataclass
+class DiffusionConfig:
+    """Generation by diffusion over blocks (``model_type: sdar_moe``): the
+    reply is made ``block_length`` positions at a time. A block starts as
+    mask tokens; every denoise forward sees the whole block (rows of a
+    block see each other, blocks are causal among themselves) and FIXES
+    some of its masked rows by ``remasking_strategy``: the schedule's
+    count (``block_length / denoising_steps``, the remainder on the first
+    steps) of most confident rows (``low_confidence_static``), those and
+    every row whose confidence passes ``confidence_threshold``
+    (``low_confidence_dynamic``), or the leftmost (``sequential``). A
+    block with no mask left is forwarded once more, which stores its K/V
+    (the commit). ``block_length`` 0: an autoregressive model."""
+    block_length: int = 0
+    denoising_steps: int = 4
+    mask_token_id: int = 0
+    remasking_strategy: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+
+    @property
+    def transfer_schedule(self) -> tuple[int, ...]:
+        """Rows fixed at denoise step 0, 1, ...: an even split of the
+        block, the remainder on the first steps."""
+        base, rem = divmod(self.block_length, self.denoising_steps)
+        return tuple(base + (s < rem) for s in range(self.denoising_steps))
+
+    @classmethod
+    def from_dict(cls, d: dict[str, Any] | None,
+                  published: bool = False) -> "DiffusionConfig":
+        """The nested ``diffusion`` table or the file's top-level keys.
+        ``published`` (``model_type: sdar_moe``, whose config.json states
+        none of these): the defaults of the published generation loop as
+        the benchmark's configuration assumes them."""
+        d = d or {}
+        if not published and not d.get("block_length"):
+            return cls()
+        return cls(
+            block_length=int(d.get("block_length", 4)),
+            denoising_steps=int(d.get("denoising_steps", 4)),
+            mask_token_id=int(d.get("mask_token_id", 151669)),
+            remasking_strategy=str(d.get("remasking_strategy",
+                                         "low_confidence_dynamic")),
+            confidence_threshold=float(d.get("confidence_threshold", 0.9)),
+        )
+
+
 # what a layer of a layer table may be (``nemotron_h``'s own letters)
 # ``D`` (this repo's letter): a dense gated MLP as a layer of its own, the
 # feed-forward of a leading dense layer before the expert layers
@@ -374,8 +424,12 @@ class ModelConfig:
     # RMSNorm on the query and key projections before rope. "projection":
     # one norm over the WHOLE [Nq*D] (and [Nkv*D]) projection, before the
     # split into heads (OLMoE; its config.json has no key for it, it
-    # follows from ``model_type: olmoe``). "none": llama-style.
+    # follows from ``model_type: olmoe``). "head": one norm over EACH head's
+    # ``head_dim`` values with a learned [head_dim] scale (``sdar_moe``, as
+    # the Qwen3-MoE attention it derives from). "none": llama-style.
     qk_norm: str = "none"
+    # generation by diffusion over blocks (``block_length`` > 0)
+    diffusion: DiffusionConfig = field(default_factory=DiffusionConfig)
     # The LAYER TABLE: one letter a layer (``hybrid_override_pattern``):
     # ``M`` a Mamba-2 state-space mixer, ``*`` attention, ``E`` sparse
     # experts; each such layer is ONE norm and ONE mixer on the residual
@@ -416,6 +470,16 @@ class ModelConfig:
     @property
     def is_latent(self) -> bool:
         return self.mla.kv_lora_rank > 0
+
+    @property
+    def is_diffusion(self) -> bool:
+        return self.diffusion.block_length > 0
+
+    @property
+    def attention_block(self) -> int:
+        """The attention mask's block: rows of one block of this many
+        positions see each other (0: plain causal attention)."""
+        return self.diffusion.block_length
 
     @property
     def rope_dim(self) -> int:
@@ -558,9 +622,25 @@ class ModelConfig:
                 f"lie inside the router's {m.router_width} (got {m})")
         if self.arch != "decoder-only":
             raise ConfigError(f"unsupported arch {self.arch!r} (decoder-only only)")
-        if self.qk_norm not in ("none", "projection"):
-            raise ConfigError(f"qk_norm must be none|projection (got "
+        if self.qk_norm not in ("none", "projection", "head"):
+            raise ConfigError(f"qk_norm must be none|projection|head (got "
                               f"{self.qk_norm!r})")
+        if self.is_diffusion:
+            f = self.diffusion
+            if self.layer_pattern:
+                raise ConfigError(
+                    "generation by diffusion over blocks runs on the uniform "
+                    "layer stack (a latent or recurrent layer has no block "
+                    "rule): layer_pattern must be empty")
+            if not (1 <= f.denoising_steps <= f.block_length
+                    and 0 <= f.mask_token_id < self.vocab_size
+                    and f.remasking_strategy in REMASKING_STRATEGIES
+                    and 0.0 < f.confidence_threshold <= 1.0):
+                raise ConfigError(
+                    "diffusion: 1 <= denoising_steps <= block_length, "
+                    "mask_token_id inside the vocabulary, remasking_strategy "
+                    f"one of {REMASKING_STRATEGIES}, 0 < "
+                    f"confidence_threshold <= 1 (got {f})")
         if self.is_moe and not (
                 1 <= self.moe.experts_per_token <= self.moe.router_width):
             raise ConfigError(
@@ -628,6 +708,8 @@ class ModelConfig:
         norms = 2 * h
         if self.qk_norm == "projection":
             norms += q_dim + kv_dim
+        elif self.qk_norm == "head":
+            norms += 2 * self.head_dim
         per_layer = attn + mlp + norms
         emb = v * h
         head = 0 if self.tie_word_embeddings else v * h
@@ -676,6 +758,16 @@ class ModelConfig:
                 raise ConfigError(
                     f"{key} = {d[key]}: group-limited routing is not "
                     "carried (the router takes its top-k over all experts)")
+        sdar = d.get("model_type") == "sdar_moe"
+        if int(d.get("decoder_sparse_step", 1)) != 1:
+            raise ConfigError(
+                f"decoder_sparse_step = {d['decoder_sparse_step']}: every "
+                "layer of the uniform stack is an expert layer here (1)")
+        if d.get("mlp_only_layers"):
+            raise ConfigError(
+                f"mlp_only_layers = {d['mlp_only_layers']}: dense layers "
+                "among a uniform stack's expert layers are not carried "
+                "(state [] or give a layer_pattern)")
         if int(d.get("num_nextn_predict_layers", 0)) > 0:
             raise ConfigError(
                 f"num_nextn_predict_layers = {d['num_nextn_predict_layers']}"
@@ -712,7 +804,11 @@ class ModelConfig:
             # config.json with ``num_experts: 64`` is never loaded dense
             # (its ``intermediate_size`` is then ONE expert's width)
             moe=MoEConfig.from_dict(d.get("moe") or d),
-            qk_norm=str(_take(d, "qk_norm", default="none")),
+            # (``sdar_moe``'s config.json has no key for its per-head norms)
+            qk_norm=str(_take(d, "qk_norm",
+                              default="head" if sdar else "none")),
+            diffusion=DiffusionConfig.from_dict(d.get("diffusion") or d,
+                                                published=sdar),
             layer_pattern=pattern,
             ssm=SSMConfig.from_dict(d.get("ssm"), published=d),
             kda=KDAConfig.from_dict(d.get("kda") or linear),
@@ -749,7 +845,8 @@ class ModelConfig:
         d = {k: v for k, v in config.items()
              if not isinstance(v, (dict, list))}
         d["rope"] = {"base": config.get("rope_theta", 10000.0)}
-        for group in ("rope_scaling", "linear_attn_config"):
+        for group in ("rope_scaling", "linear_attn_config",
+                      "mlp_only_layers"):
             if config.get(group):
                 d[group] = config[group]
         return cls.from_dict(d)

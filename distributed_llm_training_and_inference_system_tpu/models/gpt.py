@@ -85,6 +85,9 @@ def init(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
     if cfg.qk_norm == "projection":
         blocks["q_norm"] = {"scale": norm_init(L, Nq * D)}
         blocks["k_norm"] = {"scale": norm_init(L, Nkv * D)}
+    elif cfg.qk_norm == "head":
+        blocks["q_norm"] = {"scale": norm_init(L, D)}
+        blocks["k_norm"] = {"scale": norm_init(L, D)}
     if cfg.attention_bias:
         blocks["q"]["bias"] = jnp.zeros((L, Nq * D), dtype)
         blocks["k"]["bias"] = jnp.zeros((L, Nkv * D), dtype)
@@ -372,9 +375,11 @@ def _block_fn(cfg: ModelConfig, attn_impl: str, norm_impl: str,
     ``kv_cache``. Returns (x, new_kv_cache, aux): ``aux`` is the router's
     load-balancing loss, or under ``moe_impl="dropless"`` the layer's
     ``moe_stats`` (``segment_ids`` 0 marks a token that is not live)."""
-    attend = (attend_fresh(positions, segment_ids, attn_impl)
+    attend = (attend_fresh(positions, segment_ids, attn_impl,
+                           cfg.attention_block)
               if kv_cache is None
-              else attend_dense_cache(kv_cache, cache_offset, positions))
+              else attend_dense_cache(kv_cache, cache_offset, positions,
+                                      cfg.attention_block))
     if cfg.is_moe and moe_impl == "dropless" and kind is None:
         layer, layer_index = layer_experts(layer, expert_stacks, layer_index)
     x, new_cache, aux = decoder_block(

@@ -100,19 +100,33 @@ def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array) -> jax.A
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+def block_visible(q_positions: jax.Array, kv_positions: jax.Array,
+                  block: int = 0) -> jax.Array:
+    """Which keys a query may see by POSITION, [..., Sq, Skv]: those at or
+    before it, or, with ``block`` > 0 (generation by diffusion over blocks,
+    ``ModelConfig.attention_block``), those of its own block of ``block``
+    positions and of every earlier one: the rows of a block see each other
+    whole, blocks are causal among themselves."""
+    q, kv = q_positions[..., :, None], kv_positions[..., None, :]
+    if block > 0:
+        return q // block >= kv // block
+    return q >= kv
+
+
 def attention_mask(q_positions: jax.Array, kv_positions: jax.Array,
                    q_segments: Optional[jax.Array] = None,
                    kv_segments: Optional[jax.Array] = None,
-                   causal: bool = True) -> jax.Array:
+                   causal: bool = True, block: int = 0) -> jax.Array:
     """Boolean [B, Sq, Skv] mask: True = attend.
 
     Packed-sequence aware: tokens attend only within their own segment
-    (segment id 0 = padding, never attended).
+    (segment id 0 = padding, never attended). ``block``: see
+    ``block_visible``.
     """
     mask = jnp.ones(q_positions.shape[:-1] + (q_positions.shape[-1],
                     kv_positions.shape[-1]), dtype=bool)
     if causal:
-        mask = q_positions[..., :, None] >= kv_positions[..., None, :]
+        mask = block_visible(q_positions, kv_positions, block)
     if q_segments is not None and kv_segments is not None:
         same = q_segments[..., :, None] == kv_segments[..., None, :]
         valid = kv_segments[..., None, :] != 0
@@ -182,18 +196,31 @@ def qk_project_norm(x: jax.Array, layer: Params, which: str,
     (``"k"``) PROJECTION [..., N*D], before the split into heads and
     before rope. "projection" (OLMoE): one RMSNorm over the whole
     projection width, scaled by ``layer["q_norm"]`` / ``layer["k_norm"]``.
+    "head" (``sdar_moe``): one RMSNorm over EACH head's ``head_dim`` values,
+    every head under the same learned [head_dim] scale.
     Called by ``decoder_block``."""
     if cfg.qk_norm == "none":
         return x
-    return rms_norm(x, layer[f"{which}_norm"]["scale"], cfg.norm_eps)
+    scale = layer[f"{which}_norm"]["scale"]
+    if cfg.qk_norm == "head":
+        heads = x.reshape(*x.shape[:-1], -1, cfg.head_dim)
+        return rms_norm(heads, scale, cfg.norm_eps).reshape(x.shape)
+    return rms_norm(x, scale, cfg.norm_eps)
 
 
 def attend_fresh(positions: jax.Array, segment_ids: Optional[jax.Array],
-                 attn_impl: str = "xla"):
+                 attn_impl: str = "xla", block: int = 0):
     """``attend`` for a block that keeps no cache (training, evaluation,
     the pipeline stages, calibration): causal attention of the window's own
     q over its own k and v, packed sequences apart by ``segment_ids``,
-    through ``attn_impl`` (xla | flash | ring | ulysses)."""
+    through ``attn_impl`` (xla | flash | ring | ulysses). With ``block``
+    (``block_visible``) the mask is the block rule's, which only the xla
+    route has."""
+    if block > 0 and attn_impl != "xla":
+        raise ValueError(f"attn_impl={attn_impl!r} has no block rule: a "
+                         "model that generates by diffusion over blocks "
+                         "attends through xla outside the page kernels")
+
     def attend(q, k, v):
         if attn_impl == "flash":
             out = _flash_on_mesh(q, k, v, segment_ids)
@@ -208,14 +235,15 @@ def attend_fresh(positions: jax.Array, segment_ids: Optional[jax.Array],
         else:
             report_impl("attention", "xla", f"q{tuple(q.shape)}")
             mask = attention_mask(positions, positions, segment_ids,
-                                  segment_ids)
+                                  segment_ids, block=block)
             out = dot_product_attention(q, k, v, mask)
         return out, None
     return attend
 
 
 def attend_dense_cache(kv_cache: tuple[jax.Array, jax.Array],
-                       cache_offset: jax.Array, positions: jax.Array):
+                       cache_offset: jax.Array, positions: jax.Array,
+                       block: int = 0):
     """``attend`` over one layer's dense cache ``(k_cache, v_cache)`` of
     shape [B, S_max, Nkv, D] (cold prefill, ``evals/``): the new K/V are
     written at each row's ``cache_offset`` [B] (its current length) and
@@ -235,7 +263,7 @@ def attend_dense_cache(kv_cache: tuple[jax.Array, jax.Array],
                     f"q{tuple(q.shape)} over a [{B}, {S_max}] cache")
         kv_positions = jnp.arange(S_max)[None, :].repeat(B, axis=0)
         valid = kv_positions < (cache_offset[:, None] + S)
-        mask = (positions[..., :, None] >= kv_positions[..., None, :]) \
+        mask = block_visible(positions, kv_positions, block) \
             & valid[:, None, :]
         out = dot_product_attention(q, kc.astype(q.dtype),
                                     vc.astype(q.dtype), mask)

@@ -359,10 +359,13 @@ def paged_attention_multi(
     start_positions: jax.Array,  # [B] int32 — position of q[:, 0]
     impl: str = "auto",
     layer=None,                # int32 scalar: which layer's pages to read
+    block: int = 0,            # static: > 0 = the block rule
 ) -> jax.Array:
     """Multi-query paged attention: query j of slot b attends causally over
     [0, start_b + j] through the pages (the window's own K/V must already
-    be written). Returns [B, T, Nq, D].
+    be written). Returns [B, T, Nq, D]. With ``block`` > 0 (generation by
+    diffusion over blocks: block-aligned starts) query j sees its whole
+    block: [0, start_b + (j // block + 1) * block).
 
     On TPU this runs the head-folded Pallas kernel (each page DMA'd once
     per SLOT — all kv heads, all T queries); the fallback flattens to
@@ -381,9 +384,13 @@ def paged_attention_multi(
         from .paged_attention_pallas import paged_attention_pallas_multi
         return paged_attention_pallas_multi(
             q, k_pages, v_pages, block_tables, start_positions,
-            layer=layer, interpret=interpret)
-    flat_pos = (start_positions[:, None]
-                + jnp.arange(T, dtype=jnp.int32)).reshape(B * T)
+            layer=layer, interpret=interpret, block=block)
+    if block:       # row j sees up to the last position of its block
+        ends = (jnp.arange(T, dtype=jnp.int32) // block + 1) * block - 1
+        flat_pos = (start_positions[:, None] + ends).reshape(B * T)
+    else:
+        flat_pos = (start_positions[:, None]
+                    + jnp.arange(T, dtype=jnp.int32)).reshape(B * T)
     out = _gather_attention(
         q.reshape(B * T, Nq, D), k_pages, v_pages,
         jnp.repeat(block_tables, T, axis=0), flat_pos + 1, layer)

@@ -54,7 +54,8 @@ from ..models.layers import NEG_INF
 def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
                    q_ref, *refs,
                    page_size: int, scale: float, groups: int,
-                   window: int, queries: int, num_kv: int, kv_quant: str):
+                   window: int, queries: int, num_kv: int, kv_quant: str,
+                   block: int = 0):
     """One grid step: one slot's query tile against that slot's LIVE pages.
 
     ``refs``: (the slot's [maxP, Nkv, PS] scale tiles for K and V, when the
@@ -66,7 +67,10 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
     Query row j (= row // groups within a head) of the tile sits at
     position start + j and attends causally over [0, start + j], so the
     tile needs the pages that hold the start + window tokens its last
-    query sees, and the page loop runs that many times. Each iteration starts the copy of the
+    query sees, and the page loop runs that many times. With ``block`` > 0
+    (generation by diffusion over blocks; the slot's start and a tile's are
+    whole numbers of blocks) row j sees its whole block instead:
+    [0, start + (j // block + 1) * block). Each iteration starts the copy of the
     page ``_PAGES_AHEAD`` places further on in grid order (this tile's, or
     the next grid step's once this tile's are all under way), waits for
     its own page and scores it. A slot at length 0 waits for the one page
@@ -100,7 +104,10 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
     def visible(slot, tile):
         # tokens the tile's last query sees (the rows that pad the last
         # tile of a window are not queries: they see what it sees)
-        return starts_ref[slot] + jnp.minimum((tile + 1) * window, queries)
+        rows = jnp.minimum((tile + 1) * window, queries)
+        if block:       # ... and the last query sees its block's end
+            rows = (rows + block - 1) // block * block
+        return starts_ref[slot] + rows
 
     def live_pages(slot, tile):
         # the pages holding them; at least the one fetched
@@ -181,7 +188,8 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
         pos = p * page_size + col % page_size
         row_j = (row % tg) // groups
         same_head = (row // tg) == (col // page_size)
-        s = jnp.where(same_head & (pos <= start + row_j), s, NEG_INF)
+        last_seen = ((row_j // block + 1) * block - 1 if block else row_j)
+        s = jnp.where(same_head & (pos <= start + last_seen), s, NEG_INF)
 
         m_prev = m_ref[...]                            # [Nkv*TG, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -223,6 +231,9 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
 # 64 query tokens per step at both layouts) compiles at every window the
 # engine can pass — tests/test_tpu_compile.py holds it there.
 _MAX_SCORE_ELEMS = 1 << 20
+# Most folded query rows [Nq*tile] a grid step holds (bfloat16; float32
+# half): 64 query tokens at 32 query heads.
+_MAX_QUERY_ROWS = 1 << 11
 
 # How many pages the copies run ahead of the page being scored (the ring
 # holds one buffer more). Alone on a v5e (32 slots, 200 live pages, us a
@@ -240,9 +251,15 @@ def _query_tile(T: int, Nq: int, Nkv: int, PS: int,
     program that carries a 128-row piece at GQA 32/8 the full tile asked
     for 16.73 of the 16 MB (chip_smoke.py's ``ride`` phase, PR 36)."""
     budget = _MAX_SCORE_ELEMS * 2 // max(itemsize, 2)
-    if Nq * T * Nkv * PS <= budget:
+    # ... and at most ``_MAX_QUERY_ROWS`` folded query rows: what the score
+    # budget alone gives every layout of at least 8 kv heads. With fewer
+    # (GQA 32 / 4) the same score tile is twice the rows, and the q / out
+    # blocks and the accumulator, which grow with the rows, took a
+    # 256-row window to 18.7 of the 16 MB (PERF.md 6, PR 42)
+    rows = _MAX_QUERY_ROWS * 2 // max(itemsize, 2)
+    if Nq * T * Nkv * PS <= budget and Nq * T <= rows:
         return T
-    tile = max(budget // (Nq * Nkv * PS), 8)
+    tile = max(min(budget // (Nq * Nkv * PS), rows // Nq), 8)
     return 1 << (tile.bit_length() - 1)
 
 
@@ -255,9 +272,14 @@ def paged_attention_pallas_multi(
     *,
     layer=None,                # int32 scalar: which layer's pages to read
     interpret: bool = False,
+    block: int = 0,            # static: the block rule's block length
 ) -> jax.Array:
     """Returns [B, T, Nq, D]; query j attends over [0, start+j] via pages
-    (the window's own K/V must already be written to the pages).
+    (the window's own K/V must already be written to the pages). With
+    ``block`` > 0 query j attends over [0, start + (j // block + 1) *
+    block): every start a whole number of blocks, ``block`` dividing the
+    page size, so a block never straddles two pages (the kernel then runs
+    under the name ``paged_attention_blk``).
 
     The pools are operands as they are, never a layer's slice of them: the
     layer rides the scalar prefetch beside the block table and the body's
@@ -277,6 +299,9 @@ def paged_attention_pallas_multi(
     # softmax pass over the slot's pages. A window that is not a whole
     # number of tiles is padded; the pad rows are computed and dropped.
     tile = _query_tile(T_in, Nq, Nkv, PS, q.dtype.itemsize)
+    if block and (PS % block or (tile < T_in and tile % block)):
+        raise ValueError(f"block {block} must divide the page size {PS} "
+                         f"and a query tile ({tile} of {T_in} rows)")
     T = -(-T_in // tile) * tile
     if T != T_in:
         q = jnp.pad(q, ((0, 0), (0, T - T_in), (0, 0), (0, 0)))
@@ -329,12 +354,13 @@ def paged_attention_pallas_multi(
 
     # one query a sequence is the decode step; a window is the
     # speculative verify or a suffix prefill
-    name = "paged_attention" if T_in == 1 else "paged_attention_mq"
+    name = ("paged_attention_blk" if block else
+            "paged_attention" if T_in == 1 else "paged_attention_mq")
     with jax.named_scope(name):
         out = pl.pallas_call(
             functools.partial(_extend_kernel, page_size=PS, scale=scale,
                               groups=groups, window=tile, queries=T_in,
-                              num_kv=Nkv, kv_quant=kv_quant),
+                              num_kv=Nkv, kv_quant=kv_quant, block=block),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, Nkv, T * groups, D), q.dtype),
             # the ring of page copies runs across grid steps: in order,

@@ -611,7 +611,8 @@ def activation_channel_scales(
     for i in range(model_cfg.num_layers):
         x, _, _ = decoder_block(
             x, layer_of(i), model_cfg, positions, inv_freq,
-            attend_fresh(positions, None), matmul=recording_matmul)
+            attend_fresh(positions, None, block=model_cfg.attention_block),
+            matmul=recording_matmul)
     return {k: jnp.stack(v) for k, v in per_layer.items()}   # [L, in]
 
 

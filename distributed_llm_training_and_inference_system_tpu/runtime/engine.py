@@ -41,6 +41,12 @@ class TrainingEngine:
         events — the hook metrics/observability.py plugs into (closing the
         reference's unwired-metrics gap, SURVEY §5.5)."""
         self.cfg = cfg
+        if cfg.model.is_diffusion:
+            raise ValueError(
+                f"{cfg.model.name} generates by diffusion over blocks: "
+                "llmctl train is refused (there is no masked-diffusion loss "
+                "here, and the next-token loss under a causal mask would "
+                "train another model)")
         devices = devices if devices is not None else platform.devices()
         self.par = infer_data_parallel(cfg.parallel, len(devices))
         self._start_step = 0

@@ -35,7 +35,7 @@ from ..ops.paged_attention import (
     write_window_to_pages,
 )
 from ..ops.quantization import cast_params, precast_params
-from .sampling import sample_tokens
+from .sampling import sample_tokens, sample_tokens_with_prob, transfer_rows
 
 
 PIECE_META = 4      # int32 columns before a piece's tokens
@@ -62,9 +62,10 @@ class Piece(NamedTuple):
         return cls(*row[:PIECE_META], row[PIECE_META:])
 
 
-def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl):
+def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl, block=0):
     """Write each slot's window of K and V into its pages at layer ``li``
-    and attend the window over them: (out, (new k_pages, new v_pages))."""
+    and attend the window over them: (out, (new k_pages, new v_pages)).
+    ``block`` > 0: under the block rule (``ModelConfig.attention_block``)."""
     # K and V live in pages. Every T takes the whole-page merge
     # (T == 1: one page a slot), QuantPages and Int4Pages with
     # quantize-on-write fused into it: a row scatter lays the pool
@@ -75,7 +76,7 @@ def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl):
         new_k = write_window_to_pages(kp, k, tables, starts, ok, li)
         new_v = write_window_to_pages(vp, v, tables, starts, ok, li)
     out = paged_attention_multi(q, new_k, new_v, tables, starts,
-                                impl=attn_impl, layer=li)
+                                impl=attn_impl, layer=li, block=block)
     return out, (new_k, new_v)
 
 
@@ -96,6 +97,10 @@ def can_carry(cfg: ModelConfig) -> bool:
     sub-layer but ``attend`` is per row, and both kinds of ``attend`` take a
     window of one slot. A recurrent layer (``M``, ``K``) has no form that
     runs a chunk of ONE slot from its state inside a step over all slots."""
+    if cfg.is_diffusion:
+        # its step is a window of ``block_length`` rows a slot already, and
+        # a ``Piece`` wants T == 1
+        return False
     return not cfg.layer_pattern or (cfg.is_latent
                                      and not cfg.is_recurrent)
 
@@ -192,6 +197,10 @@ def extend_step_forward(
     0, takes them as zero).
     A model with LATENT attention keeps ONE pool: ``k_pages`` is the latent
     pool [La, NP, 1, PS, W] and ``v_pages`` is None, handed through.
+
+    A model that generates by diffusion over blocks (``cfg.is_diffusion``)
+    takes windows that start on a block and attends by the block rule: row
+    j sees the paged prefix and its own WHOLE block of the window.
 
     Token j sits at position ``start_positions + j`` and attends causally
     over the paged prefix *including* earlier tokens of this same call: all
@@ -294,6 +303,12 @@ def extend_step_forward(
         ``li``."""
         def attend(q, k, v):
             slots = _shared_windows if two_bodies else _windows
+            if cfg.is_diffusion:
+                # every window of such a model starts on a block and sees
+                # by the block rule: the denoise window of one block, a
+                # suffix or chunked prefill's of many
+                return slots(q, k, v, kp, vp, block_tables, start_positions,
+                             write_ok, li, attn_impl, cfg.attention_block)
             if ride is None:
                 return slots(q, k, v, kp, vp, block_tables, start_positions,
                              write_ok, li, attn_impl)
@@ -683,3 +698,111 @@ def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
     loop = steps(0, n_carry, (carry0, stop_positions, out0), carrying=True)
     carry, _stops, out = steps(n_carry, num_steps, loop, carrying=False)
     return carry, out
+
+
+# ``fixed_at`` of a window row that is still to fix (a row of the prompt
+# holds -1, a fixed one its denoise step): what "masked" MEANS. The row's
+# token is the mask token, but so may a prompt's be
+UNFIXED = -2
+# what a denoise dispatch counts on the device, summed over its forwards
+# and its live slots (``denoise_scan``; ``stats()["diffusion"]``)
+DENOISE_COUNTS = ("slot_forwards", "commit_slot_forwards", "masked_rows",
+                  "tokens_fixed", "threshold_fixed", "blocks_committed",
+                  "live_pages")
+
+
+def denoise_scan(params, window, starts, k_pages, v_pages, block_tables,
+                 stop_positions, slot_keys, temperature, top_k, top_p,
+                 cfg: ModelConfig, num_steps: int, attn_impl: str = "auto",
+                 w4_kernel_ok: bool = True, w8_kernel_ok: bool = False):
+    """``decode_scan`` for a model that generates by diffusion over blocks:
+    ``num_steps`` forwards of every slot's WINDOW (``block_length`` rows
+    that see each other) chained on the device, the transfer rule and the
+    commit inside the program.
+
+    ``window`` is (tokens [B, Bd] int32, the mask token on the rows still
+    to fix; fixed_at [B, Bd] int32, the denoise step at which a row was
+    fixed, -1 for a row of the prompt, ``UNFIXED`` for a row still to fix;
+    step [B] int32, the denoise forwards the block has had), ``starts`` [B]
+    the block's first position. A slot is live while ``starts <
+    stop_positions``. One forward of every window (``extend_step_forward`` at T = Bd: the window's K/V are written
+    to their pages every time, a later forward overwrites them), then by
+    slot, one uniform body under ``jnp.where``:
+
+    - a window WITH masks: every masked row draws a token and its
+      probability (``sample_tokens_with_prob``; key: the slot's, folded
+      by the row's position then by the step) and ``transfer_rows`` fixes
+      some of them; ``step`` goes on by one;
+    - a window WITHOUT masks: that forward was the COMMIT (the finished
+      block's K/V now stand in the pages). The block is emitted, ``starts``
+      moves on by Bd and the window is masks again.
+
+    Returns ((window, starts, k_pages, v_pages, [moe_stats,] counts),
+    out [K, B, 2 Bd + 1] int32): a step's row of ``out`` holds the slot's
+    window tokens, their ``fixed_at`` and whether the slot emitted its
+    block at that step. ``counts``: ``DENOISE_COUNTS`` summed over the
+    steps and the live slots."""
+    f = cfg.diffusion
+    Bd, mask_id = f.block_length, f.mask_token_id
+    B = starts.shape[0]
+    schedule = jnp.asarray(f.transfer_schedule, jnp.int32)
+    offs = jnp.arange(Bd, dtype=jnp.int32)
+    PS = k_pages.shape[-2]
+
+    def one(carry, _):
+        (toks, fixed_at, step), starts, kp, vp, *stats, counts = carry
+        live = starts < stop_positions
+        walked = jnp.where(live, (starts + Bd - 1) // PS + 1, 0)
+        with jax.named_scope("denoise_step"):
+            logits, kp, vp, *layer_stats = extend_step_forward(
+                params, toks, starts, kp, vp, block_tables, cfg,
+                write_ok=jnp.broadcast_to(live[:, None], (B, Bd)),
+                attn_impl=attn_impl, w4_kernel_ok=w4_kernel_ok,
+                w8_kernel_ok=w8_kernel_ok, return_moe_stats=True)
+            # a row's key: the slot's, by its position, then by the step
+            keys = jax.vmap(jax.random.wrap_key_data)(slot_keys)
+            keys = jax.vmap(lambda key, start, s: jax.vmap(
+                lambda p: jax.random.fold_in(jax.random.fold_in(key, p), s))(
+                    start + offs))(keys, starts, step)
+
+            def rows(a):
+                return jnp.repeat(a, Bd)
+            # the mask token is never drawn: a row fixed TO it would read
+            # as masked for ever (random weights put it first once in a
+            # vocabulary's worth of rows)
+            logits = logits.at[..., mask_id].set(-jnp.inf)
+            x0, prob = sample_tokens_with_prob(
+                logits.reshape(B * Bd, -1), keys.reshape(B * Bd),
+                rows(temperature), rows(top_k), rows(top_p))
+            x0, prob = x0.reshape(B, Bd), prob.reshape(B, Bd)
+        with jax.named_scope("unmask"):
+            masked = fixed_at == UNFIXED
+            has_mask = masked.any(axis=-1)
+            wanted = schedule[jnp.minimum(step, len(f.transfer_schedule) - 1)]
+            fix, beyond = transfer_rows(
+                prob, masked, wanted, f.remasking_strategy,
+                f.confidence_threshold)
+            denoised = jnp.where(fix, x0, toks)
+            denoised_at = jnp.where(fix, step[:, None], fixed_at)
+            done = live & ~has_mask
+            again = done[:, None]
+            new = (jnp.where(again, mask_id, denoised),
+                   jnp.where(again, UNFIXED, denoised_at),
+                   jnp.where(done, 0, step + 1))
+            starts = jnp.where(done, starts + Bd, starts)
+        counts = counts + jnp.stack([
+            jnp.sum(live), jnp.sum(live & ~has_mask),
+            jnp.sum(masked & live[:, None]), jnp.sum(fix & live[:, None]),
+            jnp.sum(jnp.where(live, beyond, 0)), jnp.sum(done),
+            jnp.sum(walked),
+        ]).astype(jnp.int32)
+        out = jnp.concatenate(
+            [toks, fixed_at, done[:, None].astype(jnp.int32)], axis=-1)
+        stats = [a + b for a, b in zip(stats, layer_stats)]
+        return (new, starts, kp, vp, *stats, counts), out
+
+    carry0 = (window, starts, k_pages, v_pages,
+              *([jnp.zeros((cfg.moe.stats_size,), jnp.int32)]
+                if cfg.is_moe else []),
+              jnp.zeros((len(DENOISE_COUNTS),), jnp.int32))
+    return jax.lax.scan(one, carry0, None, length=num_steps)

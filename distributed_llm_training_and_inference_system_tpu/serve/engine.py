@@ -43,7 +43,8 @@ import numpy as np
 
 from ..config.schema import ModelConfig, ServeConfig
 from ..models import gpt
-from .decode import PIECE_META, can_carry, decode_scan, extend_step_forward
+from .decode import (DENOISE_COUNTS, PIECE_META, UNFIXED, can_carry,
+                     decode_scan, denoise_scan, extend_step_forward)
 from .kv_cache import PagedKVCache
 from .sampling import fold_in_key_data, sample_tokens, seed_key_data
 from .scheduler import (ContinuousBatchingScheduler, Request, RequestState,
@@ -148,6 +149,11 @@ class InferenceEngine:
             self._refuse_for_recurrent(serve_cfg)
         if model_cfg.is_latent:
             self._refuse_for_latent(serve_cfg)
+        # what a model that generates by diffusion over blocks refuses
+        # (feature -> requests or admissions it was refused to)
+        self.diffusion_refused: dict[str, int] = {}
+        if model_cfg.is_diffusion:
+            self._refuse_for_diffusion(serve_cfg)
         if model_cfg.layer_pattern and (
                 serve_cfg.quantization not in ("", "none")
                 or serve_cfg.tensor_parallel > 1):
@@ -345,6 +351,27 @@ class InferenceEngine:
         # per dispatch is O(context) host work in the latency-critical loop
         self._ctx = np.zeros((S, serve_cfg.max_seq_len), np.int32)
         self._ctx_len = np.zeros(S, np.int64)
+        # generation by diffusion over blocks: a slot's WINDOW, the block
+        # it is denoising at ``positions[slot]`` (the block's start, a
+        # whole number of blocks: what is committed lies before it): the
+        # rows' tokens (the mask token where a row is still to fix), the
+        # denoise step each was fixed at (-1: a row of the prompt;
+        # ``decode.UNFIXED``: still to fix) and the denoise forwards the
+        # block has had. The device's copy rides the
+        # dispatches' carry; the host's is what a dispatch that chains on
+        # nothing starts from (``_arm_diffusion``, ``_accept_blocks``)
+        Bd = model_cfg.diffusion.block_length
+        self._win = np.full((S, Bd), model_cfg.diffusion.mask_token_id,
+                            np.int32)
+        self._win_at = np.full((S, Bd), UNFIXED, np.int32)
+        self._win_step = np.zeros(S, np.int32)
+        # ``decode.DENOISE_COUNTS`` summed over the dispatches fetched
+        self.diffusion_counts = np.zeros(len(DENOISE_COUNTS), np.int64)
+        # what the prefill programs of such a model returned and nobody has
+        # fetched: a slot is armed with no first token, so nothing waits
+        # for a prefill; its routing counts come down with the tokens of
+        # the next dispatch group (``_submit_group``, ``_fetch_group``)
+        self._unfetched_prefills: list = []
 
         self._prefill_cache: dict[int, callable] = {}
         # program name -> the error of its first call (see _Program)
@@ -511,6 +538,36 @@ class InferenceEngine:
                     "pages, and the swap payload is a K and a V pool; "
                     "ROADMAP B4, B7)")
 
+    def _refuse_for_diffusion(self, serve_cfg: ServeConfig) -> None:
+        """Refuse, by name, what generation by diffusion over blocks has
+        no form of yet. Riding is off by ``decode.can_carry`` (a ``Piece``
+        wants a step of T = 1) and counted in ``stats()["diffusion"]
+        ["refused"]`` an admission. The prefix cache stays ON: a page is a
+        whole number of blocks, so a whole page's K/V depend on nothing
+        after the page."""
+        f, PS = self.cfg.diffusion, serve_cfg.kv_block_size
+        if PS % f.block_length:
+            raise ValueError(
+                f"{self.cfg.name}: kv_block_size {PS} must be a multiple of "
+                f"block_length {f.block_length} (page_size % block_length "
+                "== 0: a block never straddles two pages, and a cached "
+                "page holds whole blocks)")
+        asked = {
+            "speculative": serve_cfg.speculative != "off",
+            "preemption: swap": serve_cfg.preemption == "swap",
+            "tensor_parallel": serve_cfg.tensor_parallel > 1,
+        }
+        for feature, on in asked.items():
+            if on:
+                raise ValueError(
+                    f"{self.cfg.name} generates by diffusion over blocks: "
+                    f"{feature} is refused (a draft is verified a token at "
+                    "a time, a swapped slot would carry a half-denoised "
+                    "window, and the block kernel is opaque to GSPMD; a "
+                    "preempted request is recomputed from its last "
+                    "committed block)")
+        self.diffusion_refused["riding"] = 0
+
     # the longest prompt a latent-attention model prefills COLD when no
     # chunk length is configured: its cold program attends in the expanded
     # form through XLA, whose float32 scores are heads x S x S (a 12k-token
@@ -572,12 +629,16 @@ class InferenceEngine:
         prompt of an engine whose ``on_prefill_complete`` is set (a
         disaggregated fleet's prefill role): the hook is owed the sequence
         before a decode step is spent on it, and a prompt that rode is
-        decoding by the time the host sees its first token."""
-        return (self._ride_rows > 0 and req.swapped_kv is None
-                and req.pipeline_stage is None
-                and self.on_prefill_complete is None
-                and 2 * int(self.active.sum())
-                >= self.serve_cfg.max_batch_size)
+        decoding by the time the host sees its first token. (Asked once
+        an admission: a model that generates by diffusion counts here the
+        prompts that would have ridden, ``stats()["diffusion"]["refused"]``.)"""
+        would = (req.swapped_kv is None and req.pipeline_stage is None
+                 and self.on_prefill_complete is None
+                 and 2 * int(self.active.sum())
+                 >= self.serve_cfg.max_batch_size)
+        if would and self.cfg.is_diffusion:
+            self.diffusion_refused["riding"] += 1
+        return would and self._ride_rows > 0
 
     @property
     def _chunk_tokens(self) -> int:
@@ -737,10 +798,32 @@ class InferenceEngine:
         a co-resident's overflow pattern (caught by the int4+spec
         migration identity tests)."""
         k = self._decode_units * self._decode_unit_len
+        if self.cfg.is_diffusion:
+            # in BLOCKS: a block takes at least two forwards (one that
+            # fixes rows, then the commit), and the forward after a commit
+            # already writes the next window's rows
+            return (k // 2 + 1) * self.cfg.diffusion.block_length
         if self.serve_cfg.speculative == "ngram":
             K = max(self.serve_cfg.decode_steps_per_dispatch, 1)
             k = max(k, self.serve_cfg.speculative_tokens + K - 1)
         return k
+
+    @property
+    def _group_span(self) -> int:
+        """Positions a dispatch group in flight may have moved a slot on
+        by, which the host has not seen yet: a token a step, or for
+        generation by diffusion a block every two forwards."""
+        k = self._decode_units * self._decode_unit_len
+        if self.cfg.is_diffusion:
+            return -(-k // 2) * self.cfg.diffusion.block_length
+        return k
+
+    def _prefill_len(self, n: int) -> int:
+        """Tokens of an ``n``-token context a prefill program runs: all of
+        them, or for generation by diffusion its WHOLE blocks (the rest
+        stands as fixed rows of the first window, ``_arm_diffusion``)."""
+        Bd = self.cfg.diffusion.block_length
+        return n // Bd * Bd if Bd else n
 
     def _admission_tail(self, req: Request) -> int:
         """Tokens beyond the prefill context that admission must cover.
@@ -793,8 +876,10 @@ class InferenceEngine:
                     ctx, self.kv.page_size)
             # keep >=1 suffix token: the last prompt token must be
             # re-processed to produce the first sampled token's logits
+            # (generation by diffusion: one whole BLOCK, and no logits)
             usable = min(len(req.prefix_hashes),
-                         max((n - 1) // self.kv.page_size, 0))
+                         max((self._prefill_len(n) - 1)
+                             // self.kv.page_size, 0))
             pins = self.kv.lookup_prefix(req.prefix_hashes[:usable])
             # On TPU the multi-query Pallas kernel streams each cached page
             # once for all suffix queries, so ANY hit saves compute. The
@@ -1291,6 +1376,8 @@ class InferenceEngine:
         if req.prefill_dispatch_time is None:
             req.prefill_dispatch_time = time.monotonic()
         self.spans.annotate(cached=cached)
+        # (generation by diffusion: the chunks run the whole blocks)
+        ctx = ctx[:self._prefill_len(n)]
         (self._partial_prefills if into is None else into)[rid] = {
             "req": req, "ctx": ctx, "done": cached, "sent": cached,
             "pins": len(pins), "table_row": table_row, "slot_key": slot_key}
@@ -1493,11 +1580,14 @@ class InferenceEngine:
                         "partial restore payload for %s rejected (%s); "
                         "re-prefilling the whole context", rid, e)
                 req.swapped_kv = None
-            if cached == 0:
+            # what the program runs: the context, or for generation by
+            # diffusion its whole blocks (nothing, of a prompt under one)
+            run = self._prefill_len(n)
+            if cached == 0 and run:
                 # table entries for the bucket: beyond-length -> scratch 0
-                bucket = self._bucket(n)
+                bucket = self._bucket(run)
                 entries = np.zeros(bucket // PS, np.int32)
-                used = self.kv.pages_needed(n)
+                used = self.kv.pages_needed(run)
                 entries[:used] = self.kv.block_tables[slot, :used]
             table_row = self.kv.block_tables[slot].copy()
 
@@ -1516,25 +1606,29 @@ class InferenceEngine:
         if first_prefill:
             req.prefill_dispatch_time = time.monotonic()
 
-        if cached == 0:
+        if run == 0:
+            # a prompt shorter than a block: every token of it is a fixed
+            # row of the first window, and no program runs
+            token, bucket, computed = None, 0, 0
+        elif cached == 0:
             tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :n] = ctx
+            tokens[0, :run] = ctx[:run]
             if first_prefill:
                 req.prefill_bucket = bucket
             out = self._prefill_fn(bucket)(
-                self.params, tokens, np.array([n], np.int32),
+                self.params, tokens, np.array([run], np.int32),
                 self.kv.k_pages, self.kv.v_pages, entries, *sampling,
                 *((self.kv.state, np.int32(slot))
                   if self.cfg.is_recurrent else ()))
             if self.cfg.is_recurrent:
                 *out, self.kv.state = out
             token, self.kv.k_pages, self.kv.v_pages = out
-            computed = n
+            computed = run
         else:
-            computed = n - cached
+            computed = run - cached
             bucket = self._suffix_bucket(computed)
             tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :computed] = ctx[cached:]
+            tokens[0, :computed] = ctx[cached:run]
             # NO prefill_bucket here: this is the suffix-extend program,
             # whose bucket ints collide with dense calibration keys —
             # attach_device_times must skip prefix-hit requests rather
@@ -1546,7 +1640,8 @@ class InferenceEngine:
                     self.kv.k_pages, self.kv.v_pages, table_row[None],
                     *sampling)
             self.total_prefix_cached_tokens += cached
-        self.spans.dispatched()
+        if token is not None:
+            self.spans.dispatched()
         self.spans.annotate(bucket=bucket, cached=cached)
 
         # publish this prompt's freshly-written full pages for future hits
@@ -1555,7 +1650,7 @@ class InferenceEngine:
                 table = self.kv.block_tables[slot]
                 self.kv.register_pages(
                     [(req.prefix_hashes[i], int(table[i]))
-                     for i in range(len(pins), n // PS)])
+                     for i in range(len(pins), run // PS)])
 
         self.total_prefill_tokens += computed
         self.total_prefill_padded_tokens += bucket
@@ -1612,7 +1707,19 @@ class InferenceEngine:
     @engine_thread_only
     def _finish_prefill(self, req: Request, token) -> None:
         """Resolve a dispatched prefill: fetch its first token and make the
-        slot live for decode."""
+        slot live for decode (a model that generates by diffusion over
+        blocks: arm the slot, fetch nothing)."""
+        if self.cfg.is_diffusion:
+            # the program's token (its last row's next-token draw) means
+            # nothing to a model whose rows predict themselves, and the
+            # slot is armed from the host's state alone: nothing is fetched
+            # here, the next dispatch queues behind the prefill program,
+            # and what the program returned is fetched with that dispatch
+            if token is not None:       # None: no program ran (``_prefill``)
+                self._unfetched_prefills.append(token)
+            with self.spans.phase("llmctl.engine.apply"):
+                self._arm_diffusion(req)
+            return
         with self.spans.phase("llmctl.engine.prefill.wait",
                               request_id=req.request_id):
             if self.cfg.is_moe:
@@ -1638,6 +1745,23 @@ class InferenceEngine:
                 self.on_token(req, [token])
         self._arm_slot(req, token, n, ctx + [token])
 
+    @engine_thread_only
+    def _arm_diffusion(self, req: Request) -> None:
+        """A prompt's whole blocks have run: make the slot live with NO
+        first token. Its first window starts at the last whole block's end
+        and holds what is left of the context as FIXED rows, masks after
+        them. (A preempted request comes back with a context of whole
+        blocks: every block it was credited with was committed.)"""
+        ctx = req.context_tokens
+        run = self._prefill_len(len(ctx))
+        slot, rest = req.slot, ctx[run:]
+        self._win[slot] = self.cfg.diffusion.mask_token_id
+        self._win[slot, :len(rest)] = rest
+        self._win_at[slot] = UNFIXED
+        self._win_at[slot, :len(rest)] = -1
+        self._win_step[slot] = 0
+        self._arm_slot(req, 0, run, ctx)
+
     # -- decode --------------------------------------------------------------
 
     def _decode_impl_n(self, params, k_pages, v_pages, tokens, positions,
@@ -1654,6 +1778,17 @@ class InferenceEngine:
         # an engine that rides (``_ride_rows``) hands EVERY dispatch its
         # steps' pieces (``ride``: [steps, PIECE_META + C], all zero where
         # nothing rides) and gets the steps' first tokens back, LAST
+        if self.cfg.is_diffusion:
+            # ``tokens`` is the slots' windows, ``positions`` their blocks'
+            # starts; the steps' windows come back with what each emitted,
+            # and the dispatch's counts LAST (``decode.denoise_scan``)
+            (win, pos, k_pages, v_pages, *rest), out = denoise_scan(
+                params, tokens, positions, k_pages, v_pages, tables, stops,
+                slot_keys, temp, top_k, top_p, self.cfg,
+                self._decode_unit_len, attn_impl=self._attn_impl,
+                w4_kernel_ok=self._w4_kernel_ok,
+                w8_kernel_ok=self._w8_kernel_ok)
+            return (out, win, pos, k_pages, v_pages, *rest)
         (toks, pos, k_pages, v_pages, *rest), toks_seq = decode_scan(
             params, tokens, positions, k_pages, v_pages, tables, stops,
             slot_keys, temp, top_k, top_p, self.cfg, self._decode_unit_len,
@@ -1711,6 +1846,17 @@ class InferenceEngine:
         group = self._submit_group(1 if use_short else self._decode_units)
         self._fetch_group(group)
         return group
+
+    def _decode_head_args(self) -> tuple:
+        """The decode program's (tokens, positions) from the HOST's state,
+        as copies (see ``_shared_decode_args``): each slot's newest token
+        and its position, or for generation by diffusion each slot's
+        window (tokens, fixed-at steps, denoise step) and its block's
+        start."""
+        if self.cfg.is_diffusion:
+            return ((self._win.copy(), self._win_at.copy(),
+                     self._win_step.copy()), self.positions.copy())
+        return self.last_tokens.copy(), self.positions.copy()
 
     def _shared_decode_args(self) -> tuple:
         """Device-convert the dispatch args that are invariant across a
@@ -1795,22 +1941,23 @@ class InferenceEngine:
             tokens, positions = (chain_from["next_tokens"],
                                  chain_from["next_positions"])
         else:
-            tokens = jax.device_put(self.last_tokens.copy())    # as above
-            positions = jax.device_put(self.positions.copy())
+            tokens, positions = jax.device_put(self._decode_head_args())
         if shared is None:
             shared = self._shared_decode_args()
         (sampled_seq, next_toks, next_pos, self.kv.k_pages, self.kv.v_pages,
          *moe_stats) = self._decode_jit(
                 self.params, self.kv.k_pages, self.kv.v_pages,
                 tokens, positions, *shared, *self._decode_tail_args(pieces))
-        firsts = None
+        firsts = counts = None
         if self.cfg.is_recurrent:
             *moe_stats, self.kv.state = moe_stats
         elif self._ride_rows:
             *moe_stats, firsts = moe_stats
+        elif self.cfg.is_diffusion:
+            *moe_stats, counts = moe_stats
         return {
             "sampled": sampled_seq, "moe_stats": moe_stats,
-            "firsts": firsts,
+            "firsts": firsts, "denoise_counts": counts,
             "next_tokens": next_toks,
             "next_positions": next_pos,
             "req_ids": [r.request_id if r is not None else None
@@ -1833,6 +1980,9 @@ class InferenceEngine:
         # 0 and the kernel still fetches the one page its table names
         lag = (len(chain_from["units"]) * self._decode_unit_len
                if chain_from is not None else 0)
+        if self.cfg.is_diffusion:       # forwards -> positions, about
+            lag = (lag * self.cfg.diffusion.block_length
+                   // (self.cfg.diffusion.denoising_steps + 1))
         live_pages = int(np.clip(
             (self.positions + lag * self.active) // self.kv.page_size + 1,
             1, self.kv.max_pages_per_slot).sum())
@@ -1871,8 +2021,10 @@ class InferenceEngine:
                 if self._arm_in_flight(unit_laid):
                     shared = None   # a stop changed: anew for a next unit
         self.spans.dispatched()
+        # (prefill programs dispatched before this group end before it)
+        prefills, self._unfetched_prefills = self._unfetched_prefills, []
         return {
-            "units": units,
+            "units": units, "prefills": prefills,
             "next_tokens": units[-1]["next_tokens"],
             "next_positions": units[-1]["next_positions"],
             "req_ids": units[0]["req_ids"],
@@ -1912,13 +2064,25 @@ class InferenceEngine:
         with self.spans.phase(
                 "llmctl.engine.decode.wait",
                 steps=len(group["units"]) * self._decode_unit_len):
-            arrs, moe_stats, firsts = jax.device_get(
+            arrs, moe_stats, firsts, counts, window, prefills = jax.device_get(
                 ([u["sampled"] for u in group["units"]],
                  [u["moe_stats"] for u in group["units"]],
-                 [u["firsts"] for u in group["units"]]))
+                 [u["firsts"] for u in group["units"]],
+                 [u["denoise_counts"] for u in group["units"]],
+                 # where the group left every slot's window: the host's
+                 # copy for a dispatch that chains on nothing
+                 group["next_tokens"] if self.cfg.is_diffusion else None,
+                 group["prefills"]))
         self.spans.fetched()
+        for fetched in prefills:        # [token, routing counts]: the counts
+            self.spans.fetched()
+            if self.cfg.is_moe:
+                self._count_moe(fetched[1:], steps=1, decode=False)
         if self._ride_rows:
             group["firsts"] = np.concatenate(firsts)
+        if self.cfg.is_diffusion:
+            group["window"] = window
+            self.diffusion_counts += np.sum(counts, axis=0)
         for unit_stats in moe_stats:
             for st in unit_stats:       # none for a dense model
                 self._count_moe(st, steps=self._decode_unit_len, decode=True)
@@ -2110,7 +2274,10 @@ class InferenceEngine:
                     or req.request_id != group["req_ids"][slot]
                     or not group["active"][slot]):
                 continue
-            self._accept(slot, req, group["sampled"][:, slot])
+            if self.cfg.is_diffusion:
+                self._accept_blocks(slot, req, group)
+            else:
+                self._accept(slot, req, group["sampled"][:, slot])
 
     @engine_thread_only
     def _accept(self, slot: int, req: Request, tokens: np.ndarray) -> None:
@@ -2125,6 +2292,40 @@ class InferenceEngine:
             if (req.cancel_requested
                     or req.should_stop(self.eos_token_id) is not None):
                 break
+        end = self._ctx_len[slot] + len(accepted)
+        self._ctx[slot, self._ctx_len[slot]:end] = accepted
+        self._ctx_len[slot] = end
+        if accepted and self.on_token is not None:
+            with self.spans.phase("llmctl.engine.deliver"):
+                self.on_token(req, accepted)
+
+    @engine_thread_only
+    def _accept_blocks(self, slot: int, req: Request, group: dict) -> None:
+        """Credit a slot's request with the BLOCKS its forwards of a
+        fetched group committed (several tokens a credit, as the
+        speculative path's ``n_emit``), up to the token that stops it, and
+        take over where the group left the slot's window. A block's rows
+        that were the prompt's (fixed at -1) are not new tokens; a stop
+        token ends the reply where it stands, and the rest of its block is
+        dropped."""
+        Bd = self.cfg.diffusion.block_length
+        accepted = []
+        for row in group["sampled"][:, slot]:
+            if not row[2 * Bd] or req.should_stop(self.eos_token_id):
+                continue
+            self.positions[slot] += Bd
+            for tok, at in zip(row[:Bd].tolist(), row[Bd:2 * Bd].tolist()):
+                if at < 0:
+                    continue
+                req.record_token(tok)
+                req.unmask_steps.append(at)
+                accepted.append(tok)
+                if (req.cancel_requested
+                        or req.should_stop(self.eos_token_id) is not None):
+                    break
+        win, at, step = group["window"]
+        self._win[slot], self._win_at[slot] = win[slot], at[slot]
+        self._win_step[slot] = step[slot]
         end = self._ctx_len[slot] + len(accepted)
         self._ctx[slot, self._ctx_len[slot]:end] = accepted
         self._ctx_len[slot] = end
@@ -2361,8 +2562,7 @@ class InferenceEngine:
         # device is already a full group (units * unit_len >= K; the
         # ceil split can exceed K) past the host's positions, so the
         # NEXT (chained) dispatch writes up to positions + lag + k
-        lag = (self._decode_units * self._decode_unit_len
-               if self._pending is not None else 0)
+        lag = self._group_span if self._pending is not None else 0
         k = self._decode_lookahead + lag
         order = sorted(np.flatnonzero(self.active),
                        key=lambda i: self._slot_seq[i])
@@ -2592,6 +2792,7 @@ class InferenceEngine:
             # stale chunked prefill would write into freed pages
             self._partial_prefills.clear()
             self._riding.clear()
+            self._unfetched_prefills.clear()
         if self.on_finish is not None:
             for r in failed:
                 # slot holders were already notified via _on_release; the
@@ -2656,6 +2857,12 @@ class InferenceEngine:
                 f"{self.cfg.name} has {self.cfg.recurrent_name}: "
                 "measure_device_times is refused (its probes write scratch "
                 "pages, and would arm and advance live slots' state)")
+        if self.cfg.is_diffusion:
+            raise ValueError(
+                f"{self.cfg.name} generates by diffusion over blocks: "
+                "measure_device_times is refused (its decode probe times a "
+                "token a step; a denoise forward's time is the benchmark's "
+                "serve_programs.diffusion_forward_device_ms)")
         out: dict = {"prefill_ms": {}, "iters": iters}
         kp, vp = self.kv.k_pages, self.kv.v_pages
         # probes DONATE the page buffers: keep self.kv pointed at the
@@ -2791,6 +2998,17 @@ class InferenceEngine:
                 "state_carry_tokens": self.total_state_carry_tokens,
                 "refused": dict(self.ssm_refused),
             }} if self.cfg.is_recurrent else {}),
+            # generation by diffusion over blocks: a forward is a decode
+            # step above (``decode_steps``); here what the forwards did,
+            # counted on the device over LIVE slots (``denoise_scan``)
+            **({"diffusion": {
+                "forwards": self.total_decode_steps,
+                **dict(zip(DENOISE_COUNTS, self.diffusion_counts.tolist())),
+                # rows the forwards computed, idle slots' among them
+                "window_rows": self.total_decode_steps * self._win.size,
+                "block_length": self.cfg.diffusion.block_length,
+                "refused": dict(self.diffusion_refused),
+            }} if self.cfg.is_diffusion else {}),
             **({"moe": {
                 "choices": self.moe_choices.tolist(),
                 # live choices on the experts HELD here, beside those over
@@ -2830,8 +3048,7 @@ class InferenceEngine:
         texts = {}
         if self._decode_jit is not None:
             texts[self._decode_jit.name] = self._decode_jit.compiled_text(
-                *common, *shapes((jnp.asarray(self.last_tokens),
-                                  jnp.asarray(self.positions),
+                *common, *shapes((*self._decode_head_args(),
                                   *self._shared_decode_args(),
                                   *self._decode_tail_args())))
         sampling = shapes(self._sampling_args(seed_key_data(0), 0,

@@ -261,3 +261,49 @@ def sample_tokens(
         return jnp.where(is_sampled, toks.astype(jnp.int32), greedy)
 
     return jax.lax.cond(jnp.any(is_sampled), sampled, greedy_only, None)
+
+
+def sample_tokens_with_prob(logits, keys, temperature, top_k, top_p
+                            ) -> tuple[jax.Array, jax.Array]:
+    """``sample_tokens`` and, beside each token, the probability the
+    model gives it: softmax(logits)[token], of the logits as they came
+    (not scaled by the temperature, not filtered), in float32. What a
+    model that generates by diffusion over blocks ranks its masked rows
+    by (``transfer_rows``)."""
+    tokens = sample_tokens(logits, keys, temperature, top_k, top_p)
+    logits = logits.astype(jnp.float32)
+    chosen = jnp.take_along_axis(logits, tokens[:, None], axis=-1)[:, 0]
+    return tokens, jnp.exp(chosen - jax.nn.logsumexp(logits, axis=-1))
+
+
+def transfer_rows(prob: jax.Array, masked: jax.Array, wanted: jax.Array,
+                  strategy: str, threshold: float
+                  ) -> tuple[jax.Array, jax.Array]:
+    """Which masked rows of each window a denoise step FIXES: (fix
+    [B, Bd] bool, beyond [B] int32: rows fixed over the schedule's count).
+    ``prob`` [B, Bd] is each row's confidence, ``masked`` [B, Bd] the rows
+    still to fix, ``wanted`` [B] the schedule's count for this step (never
+    more than are masked: a first window whose leading rows are the
+    prompt's has fewer).
+
+    - ``sequential``: the leftmost ``wanted`` masked rows.
+    - ``low_confidence_static``: the ``wanted`` masked rows of largest
+      confidence (a tie goes to the row further left).
+    - ``low_confidence_dynamic``: every masked row whose confidence is
+      over ``threshold`` if those are at least ``wanted``, else as
+      ``low_confidence_static``."""
+    n = jnp.minimum(wanted, jnp.sum(masked, axis=-1))[:, None]
+    if strategy == "sequential":
+        rank = jnp.cumsum(masked, axis=-1) - 1
+    else:
+        conf = jnp.where(masked, prob, -jnp.inf)
+        col = jnp.arange(conf.shape[-1])
+        ahead = (conf[:, None, :] > conf[:, :, None]) | (
+            (conf[:, None, :] == conf[:, :, None])
+            & (col[None, None, :] < col[None, :, None]))
+        rank = jnp.sum(ahead, axis=-1)          # rows ranked before row i
+    fix = masked & (rank < n)
+    if strategy == "low_confidence_dynamic":
+        high = masked & (prob > threshold)
+        fix = jnp.where(jnp.sum(high, axis=-1, keepdims=True) >= n, high, fix)
+    return fix, (jnp.sum(fix, axis=-1) - n[:, 0]).astype(jnp.int32)

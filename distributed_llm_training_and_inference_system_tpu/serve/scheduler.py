@@ -53,6 +53,10 @@ class Request:
     sampling: SamplingParams = field(default_factory=SamplingParams)
     state: RequestState = RequestState.QUEUED
     generated_tokens: list[int] = field(default_factory=list)
+    # generation by diffusion over blocks: the denoise step (0-based, within
+    # its block) at which each generated token was fixed; the completion
+    # body's ``return_unmask_steps`` returns it beside ``token_ids``
+    unmask_steps: list[int] = field(default_factory=list, repr=False)
     slot: Optional[int] = None
     # set while PREFILLING (when the slot can't be torn down mid-flight);
     # the engine releases the slot at the next step boundary

@@ -218,6 +218,16 @@ class InferenceServer:
                 body, self.tokenizer, self.model_cfg.vocab_size)
         except BadRequest as e:
             return web.json_response({"error": str(e)}, status=400)
+        # generation by diffusion over blocks: beside ``token_ids``, the
+        # denoise step at which each token was fixed (what lets a reference
+        # follow the served trajectory)
+        want_steps = body.get("return_unmask_steps", False)
+        if not isinstance(want_steps, bool) or (
+                want_steps and not self.model_cfg.is_diffusion):
+            return web.json_response({"error": (
+                "return_unmask_steps must be true or false, and true only "
+                f"for a model that generates by diffusion over blocks "
+                f"({self.model_cfg.name} does not)")}, status=400)
         req = Request(request_id=f"cmpl-{uuid.uuid4().hex[:24]}",
                       prompt_tokens=prompt_tokens, sampling=sampling)
         loop = asyncio.get_running_loop()
@@ -265,6 +275,8 @@ class InferenceServer:
                 "index": 0,
                 "text": self.tokenizer.decode(req.generated_tokens),
                 "token_ids": req.generated_tokens,
+                **({"unmask_steps": req.unmask_steps[:n_gen]}
+                   if want_steps else {}),
                 "finish_reason": req.finish_reason,
             }],
             "usage": {
@@ -279,7 +291,10 @@ class InferenceServer:
                                token_q: asyncio.Queue) -> web.StreamResponse:
         """Server-sent events (OpenAI `stream: true` wire format): one
         `data: {...}` chunk per decoded token batch, `data: [DONE]` at the
-        end. Multi-step decode delivers tokens in bursts of up to K."""
+        end. Multi-step decode delivers tokens in bursts of up to K; a
+        model that generates by diffusion over blocks, in bursts of the
+        blocks a dispatch committed (whole blocks of ``block_length``
+        tokens, but for a reply's first and last)."""
         # CORS headers must land BEFORE prepare() — the middleware's
         # post-handler pass is too late for a prepared stream (headers are
         # already on the wire)

@@ -1026,3 +1026,79 @@ def test_fsdp4_train_step_moves_rows_not_the_head(topo, as_tpu):
     assert not big, [(c.op, c.shapes, c.nbytes) for c in big]
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= 3.86 * 2 ** 30, f"{temp / 2 ** 30:.2f} GiB of temporaries"
+
+
+# -- generation by diffusion over blocks (benchmark/configs/sdar-30b-a3b-7l) ---
+
+@pytest.mark.parametrize("B, T", [(64, 4), (1, 256), (1, 512)],
+                         ids=["denoise-window", "suffix-256", "suffix-512"])
+def test_block_rule_page_kernel_compiles_at_the_cells_shapes(one_chip, as_tpu,
+                                                             B, T):
+    """The page kernel under the block rule (``paged_attention_blk``) on
+    the SDAR cell's pool (7 layers, 2,179 pages, GQA 32 / 4): the denoise
+    window of one block over 64 slots, and a prefill window of many."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
+        paged_attention_multi)
+    sds = _sds(one_chip)
+    pool = sds((7, 2179, 4, PS, D), jnp.bfloat16)
+
+    def call(q, kp, vp, tables, starts, layer):
+        return paged_attention_multi(q, kp, vp, tables, starts, impl="auto",
+                                     layer=layer, block=4)
+    compiled = _compile(call, sds((B, T, 32, D), jnp.bfloat16), pool, pool,
+                        sds((B, MAXP), jnp.int32), sds((B,), jnp.int32),
+                        sds((), jnp.int32))
+    assert "paged_attention_blk" in compiled.as_text()
+
+
+def test_diffusion_decode_program_fits_the_chip(one_chip, as_tpu):
+    """The denoise dispatch of the SDAR cell (published widths, 7 layers,
+    64 slots x 4 rows, 8 forwards): it compiles for the chip, updates the
+    pools in place, holds no layer's 1.2 GB of experts as a temporary, and
+    weights + pools + temporaries fit the chip's 16 GB."""
+    import json
+    from pathlib import Path
+
+    from benchmark import harness
+    from distributed_llm_training_and_inference_system_tpu.config.schema import (
+        ModelConfig)
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        denoise_scan)
+    config = json.loads((Path(__file__).parents[1] / "benchmark/configs"
+                         / "sdar-30b-a3b-7l.json").read_text())
+    cfg = ModelConfig.from_dict(harness.model_dict(config))
+    sds = _sds(one_chip)
+    B, Bd, num_pages = 64, cfg.diffusion.block_length, 2179
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda k: gpt.init(cfg, k, jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+    pool = sds((cfg.num_layers, num_pages, cfg.num_kv_heads, PS, D),
+               jnp.bfloat16)
+
+    def program(params, k_pages, v_pages, window, starts, tables, stops,
+                keys, temp, top_k, top_p):
+        return denoise_scan(params, window, starts, k_pages, v_pages, tables,
+                            stops, keys, temp, top_k, top_p, cfg, 8)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    compiled = jax.jit(program, donate_argnums=(1, 2)).lower(
+        params, pool, pool, (i32(B, Bd), i32(B, Bd), i32(B)), i32(B),
+        i32(B, MAXP), i32(B), sds((B, 2), jnp.uint32),
+        sds((B,), jnp.float32), i32(B), sds((B,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "paged_attention_blk" in text and "moe_gmm_prefill" in text
+    mem = compiled.memory_analysis()
+    # (the temporaries are the head's and the sampler's: [256, 151936]
+    # float32 logits are 156 MB, and the sampling branches that a greedy
+    # batch never runs are sized for all the same: 1.58 GB as compiled for
+    # PR 42. One layer's experts are 1.21 GB, the pools 2.0 GB)
+    assert mem.temp_size_in_bytes < 2.0e9, (
+        f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries")
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"denoise program: arguments {mem.argument_size_in_bytes / 1e9:.2f}"
+          f" GB, temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB, total "
+          f"{total / 1e9:.2f} GB")
+    assert total < 14.5e9, f"{total / 1e9:.2f} GB on a 16 GB chip"
