@@ -32,6 +32,8 @@ the chip):
             program): equal first tokens and equal 32 greedy tokens; and
             the same at Xing4.0's widths (latent attention, 2 layers, 9 of
             16 slots) behind a document's cached pages (the suffix program)
+            and at Kimi-Linear's widths (one period K K K *, 65 of 128
+            slots) from position 0 (the chunk and final-chunk programs)
   train     `cli.main train launch --model gpt-750m --max-steps 8`,
             sequence 2048, micro-batch 4, flash attention, fused AdamW
   launcher  `train launch --restart-on-failure 1 --max-steps 2` at
@@ -540,8 +542,8 @@ def phase_ride(env: dict) -> None:
     text = run_child("ride", [sys.executable, str(ROOT / "chip_smoke.py")],
                      {**env, PHASE_ENV: "ride"}, timeout=1500)
     recs = smoke_records(text, "ride")
-    if len(recs) != 2:
-        raise SmokeFailure(f"{len(recs)} of the 2 riding arms reported")
+    if len(recs) != 3:
+        raise SmokeFailure(f"{len(recs)} of the 3 riding arms reported")
     for rec in recs:
         riding, cold = rec["cached_tokens"]
         say(f"  riding prompts ({rec['model']}, {rec['layers']} layers, "
@@ -1603,7 +1605,11 @@ def child_ride() -> None:
     widths from position 0 (the cold program), then a latent-attention
     model at Xing4.0's widths (``doc-qa-64``'s configuration, the dense
     layer and one expert layer) behind a document's cached pages (the
-    suffix program, the window through ``mla_paged_attention_mq`` in both)."""
+    suffix program, the window through ``mla_paged_attention_mq`` in both),
+    then Kimi-Linear's widths (``reason-docs-128``'s configuration, one
+    period ``K K K *``): prompts of 3 and 2 pages from position 0, each
+    piece a window from its slot's own ``K`` state, against the chunk and
+    final-chunk programs (chunks of one page on the drained engine)."""
     child_setup()
     import dataclasses
 
@@ -1630,8 +1636,12 @@ def child_ride() -> None:
         residents = [Request(request_id=f"resident-{i}",
                              prompt_tokens=prompt_tokens(50 + i, 24 + i,
                                                          cfg.vocab_size),
-                             sampling=SamplingParams(temperature=0.0,
-                                                     max_tokens=span - 64))
+                             # (a prompt of 24 + i tokens: with the
+                             # linear arm's 65 residents the longest and
+                             # ``span - 64`` would pass ``max_seq_len``)
+                             sampling=SamplingParams(
+                                 temperature=0.0,
+                                 max_tokens=span - 64 - resident))
                      for i in range(resident)]
         document = prompt_tokens(69, prefix, cfg.vocab_size)
         prompts = [document + prompt_tokens(70 + i, n, cfg.vocab_size)
@@ -1675,7 +1685,7 @@ def child_ride() -> None:
         emit("ride", {
             "model": model, "slots": slots,
             # (a layer table counts a decoder layer's two sub-layers)
-            "layers": (cfg.layers_of("*") if cfg.layer_pattern
+            "layers": (len(cfg.layer_pattern) // 2 if cfg.layer_pattern
                        else cfg.num_layers),
             "resident": resident, "new": new,
             "prompt_tokens": sum(lengths),
@@ -1695,6 +1705,9 @@ def child_ride() -> None:
             8, (9, 17, 40, 70), 8, 128, 0, {"kv_block_size": 8})
         arm("xing-test", float32(get_model_config("xing-test")),
             8, (9, 17, 40, 70), 8, 256, 64, {"kv_block_size": 8})
+        arm("kimi-linear-test", float32(get_model_config("kimi-linear-test")),
+            8, (20, 17, 40, 70), 8, 256, 0,
+            {"kv_block_size": 8, "chunked_prefill_tokens": 8})
         return
     arm("mistral-7b", float32(get_model_config("mistral-7b"), num_layers=4),
         32, (70, 128, 333, 700), 32, 2048, 0, {"kv_hbm_budget_gb": 2.0})
@@ -1706,6 +1719,16 @@ def child_ride() -> None:
         16, (70, 250, 300, 500), 32, 2048, 768,
         {"kv_block_size": published["serve"]["kv_block_size"],
          "kv_hbm_budget_gb": 1.0})
+    published = json.loads((ROOT / "benchmark" / "configs"
+                            / "kimi-linear-48b-a3b-12l-ep8.json").read_text())
+    one_period = {"kda_layers": [1, 2, 3], "full_attn_layers": [4]}
+    arm(published["name"], float32(ModelConfig.from_published(dict(
+        published, num_hidden_layers=4, linear_attn_config=dict(
+            published["linear_attn_config"], **one_period)))),
+        published["serve"]["max_batch_size"], (700, 300), 32, 2048, 0,
+        {"kv_block_size": published["serve"]["kv_block_size"],
+         "kv_hbm_budget_gb": 1.0, "prefix_caching": False,
+         "chunked_prefill_tokens": published["serve"]["kv_block_size"]})
 
 
 CHILDREN = {"kernels": child_kernels, "mesh_train": child_mesh_train,

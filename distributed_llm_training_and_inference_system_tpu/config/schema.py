@@ -294,7 +294,7 @@ class SSMConfig:
         )
 
 
-@dataclass
+@dataclass(frozen=True)     # three ints: hashable, a jitted function's static
 class KDAConfig:
     """Kimi Delta Attention sizes (the ``K`` layers of a layer table; the
     published ``linear_attn_config``): ``num_heads`` heads, each a

@@ -35,8 +35,9 @@ lives, as ``attend`` does for K and V (models/layers.py ``decoder_block``):
 from zeros over a window (a forward with no cache, cold prefill), in the
 engine's state pools ``[K layer, slot, ...]`` for one token of every slot
 (decode), or in ONE slot's rows of those pools for a window of that slot
-(chunked prefill: the chunk reads the state and the conv window the chunk
-before it left, and writes its own).
+(chunked prefill, and a prompt's piece that rides a decode step beside the
+slots' one token each: the window reads the state and the conv window the
+one before it left, and writes its own).
 
 The scopes (``kda_conv``, ``kda_chunk_prefill``, ``kda_decode``,
 ``kda_gated_norm``) are what a device trace names these operations by.
@@ -244,12 +245,13 @@ def kda_decode(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 _HEADS_A_BLOCK = 16     # heads of one slot a grid step: 1 MB of state
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref, o_ref,
-                   new_ref):
+def _decode_kernel(layer_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref,
+                   o_ref, new_ref):
     """One grid step: ``_HEADS_A_BLOCK`` heads of one slot. The state block
     [hb, dk, dv] is read once and written once; q, k and the decays arrive
     as rows [hb, dk] and are turned into columns (constant along dv) by a
-    transpose of their sublane broadcast."""
+    transpose of their sublane broadcast. (``layer_ref``: the scalar the
+    block specs place the state block by.)"""
     S = s_ref[...]
 
     def col(x):                 # [hb, dk] -> [hb, dk, dv]
@@ -263,7 +265,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref, o_ref,
 
 
 def kda_decode_pool(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-                    beta: jax.Array, pool: jax.Array, layer: int,
+                    beta: jax.Array, pool: jax.Array, layer,
                     interpret: bool = False
                     ) -> tuple[jax.Array, jax.Array]:
     """``kda_decode`` as ONE Pallas kernel over ``pool[layer]`` in place
@@ -272,7 +274,10 @@ def kda_decode_pool(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     reads it for the prediction ``S'^T k`` and again for the update (the
     linear cell's trace: 13.8 ms a step against a floor of 6.1; PERF.md 6,
     PR 40). A slot with ``beta = 0`` and ``g = 0`` keeps its state bit for
-    bit. Returns (o [slots, nh, dv] in v's dtype, the pool)."""
+    bit. ``layer`` may be traced (an argument of the jitted ``step_pools``
+    every ``K`` layer of a riding program calls): it reaches the block
+    specs as a prefetched scalar. Returns (o [slots, nh, dv] in v's dtype,
+    the pool)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     B, nh, dk = q.shape
@@ -282,25 +287,28 @@ def kda_decode_pool(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         raise ValueError(f"kda_decode_pool: {nh} heads in blocks of {hb}, "
                          f"state {pool.dtype}")
     f32 = jnp.float32
-    row = lambda w: pl.BlockSpec((None, hb, w), lambda b, h: (b, h, 0))
+    row = lambda w: pl.BlockSpec((None, hb, w), lambda b, h, ly: (b, h, 0))
     state = pl.BlockSpec((None, None, hb, dk, dv),
-                         lambda b, h: (layer, b, h, 0, 0))
+                         lambda b, h, ly: (ly[0], b, h, 0, 0))
     report_impl("kda_decode", "pallas-interpret" if interpret else "pallas",
-                f"S{tuple(pool.shape)} layer {layer}")
+                f"S{tuple(pool.shape)}")
     with jax.named_scope("kda_decode"):
         o, pool = pl.pallas_call(
             _decode_kernel,
-            grid=(B, nh // hb),
-            in_specs=[row(dk), row(dk), row(dv), row(dk), row(dv), state],
-            out_specs=[row(dv), state],
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,      # the layer
+                grid=(B, nh // hb),
+                in_specs=[row(dk), row(dk), row(dv), row(dk), row(dv), state],
+                out_specs=[row(dv), state]),
             out_shape=[jax.ShapeDtypeStruct((B, nh, dv), f32),
                        jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-            input_output_aliases={5: 1},
+            input_output_aliases={6: 1},
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
             name="kda_decode",
-        )(q.astype(f32), k.astype(f32), v.astype(f32), g.astype(f32),
+        )(jnp.asarray(layer, jnp.int32).reshape(1),
+          q.astype(f32), k.astype(f32), v.astype(f32), g.astype(f32),
           jnp.broadcast_to(beta.astype(f32)[..., None], (B, nh, dv)), pool)
     return o.astype(v.dtype), pool
 
@@ -324,14 +332,14 @@ def kda_gated_norm(o: jax.Array, gate: jax.Array, scale: jax.Array,
 # Where the state lives
 # ---------------------------------------------------------------------------
 
-def _heads(act: jax.Array, f: jax.Array, b: jax.Array, p: dict, cfg,
+def _heads(act: jax.Array, f: jax.Array, b: jax.Array, p: dict, kd,
            alive: Optional[jax.Array] = None):
     """(q, k, v, g, beta) a head from the activated conv output ``act``
     [.., 3 * d_in], the decay's second projection ``f`` [.., d_in] and the
     beta logits ``b`` [.., nh]: q and k normalised (q times dk^-1/2), the
     log decays ``-exp(A_log) softplus(f + dt_bias)`` a channel and
-    ``sigmoid(b)``, both float32 and 0 where ``alive`` is False."""
-    kd = cfg.kda
+    ``sigmoid(b)``, both float32 and 0 where ``alive`` is False. ``kd``:
+    the model's ``KDAConfig``."""
     nh, hd, d_in = kd.num_heads, kd.head_dim, kd.inner_size
     lead, f32 = act.shape[:-1], jnp.float32
     q, k, v = (act[..., i * d_in:(i + 1) * d_in].reshape(*lead, nh, hd)
@@ -378,7 +386,7 @@ def recur_window(cfg, live: Optional[jax.Array] = None):
         B, S, _ = qkv.shape
         alive, length = _alive(live, B, S)
         act, padded = kda_conv(qkv, p["conv"]["kernel"])
-        q, k, v, g, beta = _heads(act, f, b, p, cfg, alive)
+        q, k, v, g, beta = _heads(act, f, b, p, kd, alive)
         zero = jnp.zeros((B, kd.num_heads, kd.head_dim, kd.head_dim),
                          jnp.float32)
         o, S1 = kda_chunk_prefill(q, k, v, g, beta, zero, CHUNK)
@@ -387,65 +395,98 @@ def recur_window(cfg, live: Optional[jax.Array] = None):
     return recur
 
 
+def step_pools(qkv: jax.Array, f: jax.Array, b: jax.Array, p: dict,
+               conv_pool: jax.Array, state_pool: jax.Array, layer,
+               write_ok: Optional[jax.Array], kd
+               ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
+    """One decode step of every slot over the state pools ``conv_pool``
+    [Lk, K-1, slots, C] and ``state_pool`` [Lk, slots, nh, dk, dv], read and
+    written at ``[layer]`` (an int, or traced): what ``recur_step``'s
+    ``recur`` does, with everything it reads an argument (``kd``: the
+    ``KDAConfig``), so that a program's two step bodies and its ``K``
+    layers can call ONE jitted form of it (serve/decode.py)."""
+    B, T, _ = qkv.shape
+    if T != 1:
+        raise ValueError(
+            "every slot advances one token over the state pools; a "
+            f"window of {T} tokens a slot (speculative verification) "
+            "is not supported: a prompt's window goes through "
+            "recur_chunk, one slot at a time")
+    tail = conv_pool[layer]
+    act, new_tail = kda_conv_step(qkv[:, 0], p["conv"]["kernel"], tail)
+    ok = None if write_ok is None else write_ok.reshape(B)
+    # a slot that must not move takes beta = 0, g = 0 and keeps its
+    # state bit for bit (S * 1 + k * 0), in both forms
+    q, k, v, g, beta = _heads(act, f[:, 0], b[:, 0], p, kd, ok)
+    if jax.default_backend() == "tpu":
+        o, new_pool = kda_decode_pool(q, k, v, g, beta, state_pool, layer)
+    else:
+        o, new = kda_decode(q, k, v, g, beta, state_pool[layer])
+        new_pool = state_pool.at[layer].set(new)
+    with jax.named_scope("kda_conv"):
+        if ok is not None:
+            new_tail = jnp.where(ok[None, :, None], new_tail, tail)
+        return (o.reshape(B, 1, -1),
+                (conv_pool.at[layer].set(new_tail), new_pool))
+
+
 def recur_step(cfg, conv_pool: jax.Array, state_pool: jax.Array, layer,
-               write_ok: Optional[jax.Array] = None):
+               write_ok: Optional[jax.Array] = None, step=step_pools):
     """``recur`` for one decode step of every slot over the state pools
     ``conv_pool`` [Lk, K-1, slots, C] and ``state_pool``
     [Lk, slots, nh, dk, dv], read and written at ``[layer]``. A slot with
     ``write_ok`` [slots, 1] False (idle, or past its stop position) leaves
-    its state as it is. Returns the two pools as the state."""
+    its state as it is. Returns the two pools as the state. (``step``: a
+    jitted ``step_pools``, where a program calls it from many places.)"""
     def recur(qkv, f, b, p):
-        B, T, _ = qkv.shape
-        if T != 1:
-            raise ValueError(
-                "every slot advances one token over the state pools; a "
-                f"window of {T} tokens a slot (speculative verification) "
-                "is not supported: a prompt's window goes through "
-                "recur_chunk, one slot at a time")
-        tail = conv_pool[layer]
-        act, new_tail = kda_conv_step(qkv[:, 0], p["conv"]["kernel"], tail)
-        ok = None if write_ok is None else write_ok.reshape(B)
-        # a slot that must not move takes beta = 0, g = 0 and keeps its
-        # state bit for bit (S * 1 + k * 0), in both forms
-        q, k, v, g, beta = _heads(act, f[:, 0], b[:, 0], p, cfg, ok)
-        if jax.default_backend() == "tpu":
-            o, new_pool = kda_decode_pool(q, k, v, g, beta, state_pool,
-                                          layer)
-        else:
-            o, new = kda_decode(q, k, v, g, beta, state_pool[layer])
-            new_pool = state_pool.at[layer].set(new)
-        with jax.named_scope("kda_conv"):
-            if ok is not None:
-                new_tail = jnp.where(ok[None, :, None], new_tail, tail)
-            return (o.reshape(B, 1, -1),
-                    (conv_pool.at[layer].set(new_tail), new_pool))
+        return step(qkv, f, b, p, conv_pool, state_pool, layer, write_ok,
+                    kd=cfg.kda)
     return recur
 
 
 def slot_state(conv_pool: jax.Array, state_pool: jax.Array, slot: jax.Array,
                start: jax.Array) -> tuple[jax.Array, jax.Array]:
     """ONE slot's rows of the state pools in every ``K`` layer, read once
-    before a chunk's layers run: (conv windows [Lk, K-1, C], states
-    [Lk, nh, dk, dv] float32), taken as ZERO where the chunk starts its
+    before a window's layers run: (conv windows [Lk, K-1, C], states
+    [Lk, nh, dk, dv] float32), taken as ZERO where the window starts its
     sequence (``start`` [1] == 0: whatever a former occupant of the slot
     left there is not read). (Read a layer at a time between a layer's
     writes, the compiler keeps the pool as it came beside the pool it
-    writes: 2.4 GB at the linear cell's shapes.)"""
+    writes: 2.4 GB at the linear cell's shapes.)
+
+    The conv windows are the sum over the slots of the pool masked to the
+    one slot: the same rows (every other term is 0). A SLICE of that pool
+    hands the layout its consumer likes (the window's 3 columns on the
+    lanes) back to the whole pool wherever a loop carries it, 3.4 GB of
+    padding over 81 MB in a decode step at the linear cell's shapes
+    (compiled for a described v5e, PERF.md 6, PR 43); the sum hands nothing
+    back, in a chunk program or in a step."""
     fresh = start[0] == 0
-    return (jnp.where(fresh, 0, conv_pool[:, :, slot]),
+    mine = (jnp.arange(conv_pool.shape[2]) == slot)[:, None]
+    tails = jnp.sum(jnp.where(mine, conv_pool, 0), axis=2,
+                    dtype=conv_pool.dtype)
+    return (jnp.where(fresh, 0, tails),
             jnp.where(fresh, 0.0, state_pool[:, slot].astype(jnp.float32)))
 
 
 def write_slot_state(conv_pool: jax.Array, state_pool: jax.Array,
-                     slot: jax.Array, tails: list, states: list
-                     ) -> tuple[jax.Array, jax.Array]:
+                     slot: jax.Array, tails: jax.Array, states: jax.Array,
+                     live) -> tuple[jax.Array, jax.Array]:
     """The pools with ``slot``'s rows of every ``K`` layer overwritten by a
-    chunk's (conv windows [K-1, C], states [nh, dk, dv]) a layer: ONE
-    write a pool, after the chunk's last layer."""
-    return (conv_pool.at[:, :, slot].set(
-                jnp.stack(tails).astype(conv_pool.dtype)),
-            state_pool.at[:, slot].set(
-                jnp.stack(states).astype(state_pool.dtype)))
+    window's (conv windows [Lk, K-1, C], states [Lk, nh, dk, dv]): ONE
+    write a pool, after the window's last layer. ``live`` (bool []) False
+    keeps the rows as the pools hold them (a decode step that carries no
+    piece names slot 0). The conv windows go in by a select over the whole
+    pool, for ``slot_state``'s reason: where a step's loops carry it the
+    pool lies with the slots on the lanes, and a slice written there cost
+    0.80 ms a step at the linear cell's shapes against ~0.2 for the
+    2 x 81 MB of a select (my chip run, PR 43, call 1)."""
+    mine = (jnp.arange(conv_pool.shape[2]) == slot)[:, None] & live
+    conv_pool = jnp.where(mine, tails.astype(conv_pool.dtype)[:, :, None],
+                          conv_pool)
+    states = jnp.where(live, states.astype(state_pool.dtype),
+                       state_pool[:, slot])
+    return conv_pool, state_pool.at[:, slot].set(states)
 
 
 def recur_chunk(cfg, tail: jax.Array, S0: jax.Array,
@@ -466,7 +507,7 @@ def recur_chunk(cfg, tail: jax.Array, S0: jax.Array,
         alive, length = _alive(live, B, T)
         act, padded = kda_conv(qkv, p["conv"]["kernel"],
                                tail[None].astype(qkv.dtype))
-        q, k, v, g, beta = _heads(act, f, b, p, cfg, alive)
+        q, k, v, g, beta = _heads(act, f, b, p, kd, alive)
         o, S1 = kda_chunk_prefill(q, k, v, g, beta, S0[None], CHUNK)
         new_tail = _tail_after(padded, length, kd.conv_kernel)
         return o.reshape(B, T, -1), (new_tail[0], S1[0])

@@ -29,6 +29,7 @@ from ..models.gpt import (
     unembed,
 )
 from ..models.layers import decoder_block, model_rope_frequencies
+from ..ops import kda
 from ..ops.mla_paged_attention import mla_paged_attention
 from ..ops.paged_attention import (
     paged_attention_multi,
@@ -89,20 +90,25 @@ def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl, block=0):
 # (PERF.md 6, PR 36: the program's set-up).
 _shared_windows = jax.jit(_windows, static_argnames=("attn_impl",))
 _shared_sampler = jax.jit(sample_tokens)
+# ... and every ``K`` layer's one-token update of all slots over the state
+# pools, at whichever (traced) layer
+_shared_recur_step = jax.jit(kda.step_pools, static_argnames=("kd",))
 
 
 def can_carry(cfg: ModelConfig) -> bool:
     """Can a decode step of this model carry a ``Piece``? The uniform stack
-    and a layer table of ``D`` / ``E`` / ``*`` over one latent pool: every
-    sub-layer but ``attend`` is per row, and both kinds of ``attend`` take a
-    window of one slot. A recurrent layer (``M``, ``K``) has no form that
-    runs a chunk of ONE slot from its state inside a step over all slots."""
+    and a layer table of ``D`` / ``E`` / ``*`` / ``K`` over one latent pool:
+    every sub-layer but ``attend`` and ``recur`` is per row, both kinds of
+    ``attend`` take a window of one slot, and a ``K`` layer runs one from
+    the slot's own state (ops/kda.py ``recur_chunk``). A state-space layer
+    (``M``) has no form that runs a chunk of ONE slot from its state: its
+    scan starts from zero (ops/ssm.py ``ssm_scan_prefill``)."""
     if cfg.is_diffusion:
         # its step is a window of ``block_length`` rows a slot already, and
         # a ``Piece`` wants T == 1
         return False
     return not cfg.layer_pattern or (cfg.is_latent
-                                     and not cfg.is_recurrent)
+                                     and "M" not in cfg.layer_pattern)
 
 
 def decode_step_forward(
@@ -237,7 +243,7 @@ def extend_step_forward(
         if T != 1 or not can_carry(cfg):
             raise ValueError("a piece rides a decode step (T = 1) of the "
                              "uniform layer stack or of a latent layer "
-                             "table without recurrent layers")
+                             "table without state-space layers")
         offs = jnp.arange(ride.tokens.shape[0], dtype=jnp.int32)
         piece_ok = offs < ride.live
         tokens = jnp.concatenate([tokens, ride.tokens[:, None]])
@@ -337,21 +343,41 @@ def extend_step_forward(
         # a layer table: one parameter stack a kind, walked by a Python
         # loop; every pool (pages, conv tails, states) and every expert
         # stack stays whole and is addressed at its kind's layer index
-        from ..ops import kda
         from ..ops.ssm import recur_step
         if cfg.is_recurrent and ssm_state is None:
             raise ValueError(f"a model with {cfg.recurrent_name} needs its "
                              "ssm_state pools")
 
-        def recur_at(kind, conv, ssm, i):
+        def recur_at(kind, conv, ssm, i, piece):
             if kind == "M":
                 return recur_step(cfg, conv, ssm, i, write_ok)
             if kind != "K":
                 return None
-            if state_slot is None:
-                return kda.recur_step(cfg, conv, ssm, i, write_ok)
-            return kda.recur_chunk(cfg, slot_tails[i], slot_states[i],
-                                   write_ok)
+            # the B rows one token a slot over the pools; a program that
+            # rides calls ONE jitted form from both its bodies
+            step = kda.recur_step(
+                cfg, conv, ssm, i, write_ok,
+                step=_shared_recur_step if two_bodies else kda.step_pools)
+            if piece is None:
+                return step
+            # ONE slot's window [1, T] from that slot's own state: a chunk
+            # of its prompt, or the piece a decode step carries
+            chunk = kda.recur_chunk(cfg, piece[0][i], piece[1][i], piece_rows)
+
+            def recur(qkv, f, b, p):
+                # the state: (the pools, the window's (conv window, state)
+                # after this layer)
+                if ride is None:        # the window is all the rows
+                    out, after = chunk(qkv, f, b, p)
+                    return out, ((conv, ssm), after)
+                # (the piece's slot is not armed: ``step`` leaves its rows
+                # of the pools bit for bit)
+                out, pools = step(qkv[:B], f[:B], b[:B], p)
+                piece_out, after = chunk(
+                    *(a[B:, 0][None] for a in (qkv, f, b)), p)
+                return (jnp.concatenate([out, piece_out[0][:, None]]),
+                        (pools, after))
+            return recur
         blocks = cast_table_blocks(params["blocks"], compute_dtype)
         kp, vp = k_pages, v_pages
         if cfg.hc_mult > 1:
@@ -368,41 +394,54 @@ def extend_step_forward(
             return attend_pages(kp, vp, li)
         conv, ssm = (ssm_state["conv"], ssm_state["ssm"]) \
             if ssm_state is not None else (None, None)
+        # a window of ONE slot through the ``K`` layers, a chunk of its
+        # prompt or the piece a decode step carries: the slot's rows of the
+        # pools are read here, once ((conv windows, states), stacked
+        # [Lk, ...]), ride the layer walk's carry, written at a layer's
+        # index (which a loop over the table's periodic part traces), and
+        # are written back after the last layer, once
+        piece = piece_slot = None
         if state_slot is not None:
-            # a chunk of ONE slot's prompt: its rows of the pools are read
-            # here, once, and written after the last layer, once
-            slot_tails, slot_states = kda.slot_state(
-                conv, ssm, state_slot, start_positions)
-            new_tails, new_states = [], []
+            piece_slot, piece_start, piece_rows, piece_live = (
+                state_slot, start_positions, write_ok, True)
+        elif ride is not None and cfg.kda_layers:
+            piece_slot, piece_start, piece_rows, piece_live = (
+                ride.slot, ride.start[None], piece_ok[None],
+                ride.live > 0)      # False: a step that carries nothing
+        if piece_slot is not None:
+            piece = kda.slot_state(conv, ssm, piece_slot, piece_start)
         stats = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
 
         def sub_layer(carry, kind, i):
-            x, kp, vp, conv, ssm, stats = carry
+            x, kp, vp, conv, ssm, stats, piece = carry
             x, state, layer_stats = decoder_block(
                 x, table_layer(blocks, kind, i), cfg, positions, inv_freq,
                 attend_at(kp, vp, i) if kind == "*" else None, matmul=mm,
                 live=live, layer_index=i, kind=kind,
-                recur=recur_at(kind, conv, ssm, i))
+                recur=recur_at(kind, conv, ssm, i, piece))
             if kind == "*":
                 kp, vp = state
-            elif kind == "K" and state_slot is not None:
-                new_tails.append(state[0])
-                new_states.append(state[1])
+            elif kind == "K" and piece is not None:
+                (conv, ssm), after = state
+                piece = tuple(a.at[i].set(new.astype(a.dtype))
+                              for a, new in zip(piece, after))
             elif kind in "MK":
                 conv, ssm = state
             elif kind == "E":
                 stats = stats + layer_stats
-            return x, kp, vp, conv, ssm, stats
+            return x, kp, vp, conv, ssm, stats, piece
         # A program that rides holds two step bodies, and a table walked by
         # a Python loop holds every layer's kernels once a body: the latent
         # cell's executable grew from 57 to 148 MB and its first call from
-        # 10.9 to 16.4 s of every start (PERF.md 6, PR 41). Its bodies walk
-        # the table's periodic part (``*E`` x 6) by a loop over traced layer
-        # indices, as the uniform stack's scan does; the pools are the
-        # loop's carry, written in place. Any other program walks it whole.
+        # 10.9 to 16.4 s of every start (PERF.md 6, PR 41; the linear
+        # cell's from 54 to 125 MB, 95 under the loop: PR 43). Its bodies
+        # walk the table's periodic part (``*E`` x 6; ``KEKEKE*E`` x 2) by a
+        # loop over traced layer indices, as the uniform stack's scan does;
+        # the pools are the loop's carry, written in place. Any other
+        # program walks it whole.
         head, unit, reps = (table_period(cfg) if two_bodies
                             else (table_layers(cfg), [], 0))
-        carry = (x, kp, vp, conv, ssm, stats)
+        carry = (x, kp, vp, conv, ssm, stats, piece)
         for kind, i in head:
             carry = sub_layer(carry, kind, i)
         if reps:
@@ -413,10 +452,10 @@ def extend_step_forward(
                     carry = sub_layer(carry, kind, i + r * per_rep[kind])
                 return carry
             carry = jax.lax.fori_loop(0, reps, period, carry)
-        x, kp, vp, conv, ssm, stats = carry
-        if state_slot is not None:
-            conv, ssm = kda.write_slot_state(conv, ssm, state_slot,
-                                             new_tails, new_states)
+        x, kp, vp, conv, ssm, stats, piece = carry
+        if piece is not None:
+            conv, ssm = kda.write_slot_state(conv, ssm, piece_slot, *piece,
+                                             piece_live)
         if cfg.hc_mult > 1:
             x = jnp.sum(x.astype(jnp.float32), axis=2).astype(compute_dtype)
         return (unembed(params, head_rows(x), cfg), kp, vp,

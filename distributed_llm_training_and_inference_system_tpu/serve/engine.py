@@ -444,8 +444,9 @@ class InferenceEngine:
         self.moe_all_choices = 0
         # state updates of live slots in decode (slots x steps)
         self.ssm_slot_steps = 0
-        # chunk programs that read a slot's recurrent state (a ``K``
-        # model's chunked prefill), and the prompt tokens they prefilled
+        # chunk programs and riding pieces that read a slot's recurrent
+        # state (a ``K`` model's prompt past its first chunk or piece), and
+        # the prompt tokens they prefilled
         self.total_state_carry_chunks = 0
         self.total_state_carry_tokens = 0
         self.moe_experts_hit = self.moe_layer_steps = 0
@@ -607,10 +608,11 @@ class InferenceEngine:
         """Can this engine prefill a prompt inside its decode dispatches at
         all? Read off the configuration: layers whose step can carry a
         piece (``decode.can_carry``: the uniform stack, a latent layer
-        table; a recurrent layer has no chunk-from-state form inside a
-        batch step), plain weights (the W4 / W8 kernels take at most 64
-        rows), one chip (tp forces gather attention), the continuous
-        scheduler, and no speculation (its dispatch is another program)."""
+        table, with delta-rule layers or without; a state-space layer has
+        no chunk-from-state form), plain weights (the W4 / W8 kernels take
+        at most 64 rows), one chip (tp forces gather attention), the
+        continuous scheduler, and no speculation (its dispatch is another
+        program)."""
         c = self.serve_cfg
         return (can_carry(self.cfg) and not pre_quantized
                 and c.quantization in ("", "none")
@@ -1875,16 +1877,15 @@ class InferenceEngine:
 
     def _decode_tail_args(self, pieces=None) -> tuple:
         """The decode program's arguments after the shared ones: a
-        recurrent model's state pools, or, for an engine that rides, one
+        recurrent model's state pools, and, for an engine that rides, one
         unit's ``pieces`` (all zero: a dispatch that carries nothing)."""
-        if self.cfg.is_recurrent:
-            return (self.kv.state,)
+        state = self.kv.state if self.cfg.is_recurrent else None
         if not self._ride_rows:
-            return ()
+            return () if state is None else (state,)
         if pieces is None:
             pieces = np.zeros((self._decode_unit_len,
                                PIECE_META + self._ride_rows), np.int32)
-        return (None, pieces)
+        return (state, pieces)
 
     @engine_thread_only
     def _lay_pieces(self, n_units: int) -> list:
@@ -1949,12 +1950,12 @@ class InferenceEngine:
                 self.params, self.kv.k_pages, self.kv.v_pages,
                 tokens, positions, *shared, *self._decode_tail_args(pieces))
         firsts = counts = None
-        if self.cfg.is_recurrent:
-            *moe_stats, self.kv.state = moe_stats
-        elif self._ride_rows:
+        if self._ride_rows:
             *moe_stats, firsts = moe_stats
         elif self.cfg.is_diffusion:
             *moe_stats, counts = moe_stats
+        if self.cfg.is_recurrent:
+            *moe_stats, self.kv.state = moe_stats
         return {
             "sampled": sampled_seq, "moe_stats": moe_stats,
             "firsts": firsts, "denoise_counts": counts,
@@ -2351,6 +2352,11 @@ class InferenceEngine:
                 req: Request = st["req"]
                 if self._riding.get(req.request_id) is not st:
                     continue
+                if self.cfg.is_recurrent and st["done"] > 0:
+                    # the piece read its slot's state, as a chunk
+                    # program's chunk does
+                    self.total_state_carry_chunks += 1
+                    self.total_state_carry_tokens += live
                 st["done"] += live
                 self.total_prefill_tokens += live
                 self.total_prefill_padded_tokens += self._ride_rows
@@ -2992,8 +2998,8 @@ class InferenceEngine:
                 # its programs computed for them
                 "prefill_tokens": self.total_prefill_tokens,
                 "prefill_padded_tokens": self.total_prefill_padded_tokens,
-                # chunk programs that read a slot's state and conv window
-                # (a K model's chunked prefill) and their prompt tokens
+                # chunk programs and riding pieces that read a slot's state
+                # and conv window, and their prompt tokens
                 "state_carry_chunks": self.total_state_carry_chunks,
                 "state_carry_tokens": self.total_state_carry_tokens,
                 "refused": dict(self.ssm_refused),

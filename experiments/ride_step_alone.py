@@ -5,6 +5,8 @@ step and the longest operations' us a call (PERF.md 6, PR 36: what
     chiprun -- python experiments/ride_step_alone.py [--models dense moe]
     chiprun -- python experiments/ride_step_alone.py --models latent \
         --rows 256 512 --no-dead-rows
+    chiprun -- python experiments/ride_step_alone.py --models linear \
+        --rows 256 --no-dead-rows --cached 0 4096 12288
 
 The benchmark's riding configurations (``benchmark/configs``) at their
 cells' shapes, 8 steps a dispatch, weights made on the device.
@@ -15,7 +17,14 @@ pages each); slot 31 holds the riding prompt. ``xing4.0-29b-a4b-7l`` (PR
 resident behind one of 16 documents of 48 pages each (shared pages, as the
 prefix cache leaves them) and a page of their own, ``doc-qa-64``'s ~12.9k
 live tokens a slot; slot 63's prompt rides behind its document's pages, so
-a piece is a window over ~49 pages. Cases: C = 0 (the program
+a piece is a window over ~49 pages. ``kimi-linear-48b-a3b-12l-ep8`` (PR
+43): 128 slots over a latent pool of 1,525 pages of 256 and the two state
+pools of its 9 ``K`` layers (2.5 GB); 127 slots are resident at
+``reason-docs-128``'s lengths (a question and part of a reply, every 16th
+behind a document of ~8k tokens); slot 127's prompt rides behind
+``--cached`` tokens of itself (0: its first piece starts from a zero
+state; 4,096 and 12,288: the middle and the end of a document, a window
+over 17 and 49 pages from the slot's own state). Cases: C = 0 (the program
 without ``ride``: the parent's), and C = 64 / 128 / 256 rows with
 
 - ``0 live, branch``: no step carries a piece; each step BRANCHES to the
@@ -50,7 +59,8 @@ from benchmark import harness, trace_reduce
 
 PKG = "distributed_llm_training_and_inference_system_tpu"
 CONFIGS = {"dense": "mistral-7b-16l", "moe": "olmoe-1b-7b-10l",
-           "latent": "xing4.0-29b-a4b-7l"}
+           "latent": "xing4.0-29b-a4b-7l",
+           "linear": "kimi-linear-48b-a3b-12l-ep8"}
 K = 8
 REPS, BATCHES = 6, 4
 DOC_PAGES, DOCS = 48, 16    # the latent cell's resident documents
@@ -59,13 +69,15 @@ DOC_PAGES, DOCS = 48, 16    # the latent cell's resident documents
 class Shape:
     """A cell's slots and pages; the LAST slot's prompt rides."""
 
-    def __init__(self, model: str):
-        self.latent = model == "latent"
+    def __init__(self, model: str, cached: int = 0):
+        self.latent, self.linear = model == "latent", model == "linear"
         self.B, self.MAXP, self.PS, self.NP = (
-            (64, 68, 256, 1307) if self.latent else (32, 32, 64, 715))
+            (64, 68, 256, 1307) if self.latent else
+            (128, 64, 256, 1525) if self.linear else (32, 32, 64, 715))
         self.rider = self.B - 1
         # where the rider's first piece starts: behind its cached document
-        self.cached = DOC_PAGES * self.PS if self.latent else 0
+        # (the linear model's: behind so much of its own prompt)
+        self.cached = DOC_PAGES * self.PS if self.latent else cached
 
 
 def model_config(name: str):
@@ -73,6 +85,9 @@ def model_config(name: str):
     schema = import_module(f"{PKG}.config.schema")
     with open(os.path.join("benchmark", "configs", f"{name}.json")) as f:
         config = json.load(f)
+    if "linear_attn_config" in config:
+        from benchmark.runners import linear
+        return schema.ModelConfig.from_dict(linear.model_dict(config))
     if "kv_lora_rank" in config:      # (YaRN lives in a nested group)
         from benchmark.runners import latent
         return schema.ModelConfig.from_dict(latent.model_dict(config))
@@ -93,6 +108,15 @@ def slots(sh: Shape, rng) -> dict:
             # a document's shared pages, then the slot's own
             tables[slot, :DOC_PAGES + 4] = docs[slot % DOCS] + take(4)
             positions[slot] = DOC_PAGES * sh.PS + 200 + 9 * slot
+    elif sh.linear:
+        for slot in range(sh.B - 1):
+            # a question and half a reply; every 16th behind a document
+            at = 300 + 7 * slot + (8192 if slot % 16 == 0 else 0)
+            n = at // sh.PS + 1
+            tables[slot, :n] = take(n)
+            positions[slot] = at
+        n = sh.cached // sh.PS + 4
+        tables[sh.rider, :n] = take(n)
     else:
         for slot in range(sh.B - 1):
             n = 6 + (slot % 4 == 0)         # ~232 live pages, as batch-64
@@ -124,18 +148,41 @@ def program(cfg, C: int, branch: bool):
     from importlib import import_module
     decode = import_module(f"{PKG}.serve.decode")
 
-    def step(params, kp, vp, tokens, positions, tables, stops, keys, temp,
-             top_k, top_p, ride=None):
-        (toks, pos, kp, vp, *_), _ = decode.decode_scan(
+    def step(params, kp, vp, pools, tokens, positions, tables, stops, keys,
+             temp, top_k, top_p, ride=None):
+        (toks, pos, kp, vp, *rest), _ = decode.decode_scan(
             params, tokens, positions, kp, vp, tables, stops, keys, temp,
-            top_k, top_p, cfg, K, return_moe_stats=True, ride=ride,
-            ride_branch=branch)
-        return toks, kp, vp
-    return jax.jit(step, donate_argnums=(1, 2))
+            top_k, top_p, cfg, K, return_moe_stats=True, ssm_state=pools,
+            ride=ride, ride_branch=branch)
+        return toks, kp, vp, (rest[-1] if pools is not None else None)
+    return jax.jit(step, donate_argnums=(1, 2, 3))
 
 
-def run_case(fn, params, pools, state, ride, trace: bool):
-    """(ms a step, {operation: (calls, us a call)} of the traced batch)."""
+def scopes_of(text: str, linear_model: bool):
+    """operation name -> named scope (or None): a kernel by its own name,
+    an XLA operation by its instruction's ``op_name`` in the compiled
+    program's ``text``; the benchmark's own reader, under the model's
+    runner's scopes."""
+    from benchmark.runners import hybrid, linear
+    plain = hybrid.SCOPES
+    names = linear.SCOPES if linear_model else plain
+
+    def under(call, *a):
+        hybrid.SCOPES = names
+        try:
+            return call(*a)
+        finally:
+            hybrid.SCOPES = plain
+    table = under(hybrid.scopes_of_instructions, text)
+    return lambda op: under(hybrid.scope_of, [op]) or table.get(op)
+
+
+def run_case(fn, params, pools, state, ride, trace: bool,
+             linear_model: bool = False):
+    """(ms a step, {operation: (calls, us a call)} of the traced batch, ms
+    a step by named scope, the pools); ``pools``: (k pages, v pages, a
+    recurrent model's state pools). ``fn``: {"jit": the jitted step}, which
+    keeps its executable and its text here."""
     B = len(state["tokens"])
     args = [jnp.asarray(state["tokens"]), jnp.asarray(state["positions"]),
             jnp.asarray(state["tables"]), jnp.asarray(state["stops"]),
@@ -143,26 +190,29 @@ def run_case(fn, params, pools, state, ride, trace: bool):
             jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32)]
     if ride is not None:
         args.append(jnp.asarray(ride))
-    kp, vp = pools
+    if "exe" not in fn:
+        fn["exe"] = fn["jit"].lower(params, *pools, *args).compile()
+        fn["scope_of"] = scopes_of(fn["exe"].as_text(), linear_model)
+    exe = fn["exe"]
 
-    def batch(kp, vp):
+    def batch(pools):
         for _ in range(REPS):
-            toks, kp, vp = fn(params, kp, vp, *args)
+            toks, *pools = exe(params, *pools, *args)
         jax.block_until_ready(toks)
-        return kp, vp
-    kp, vp = batch(kp, vp)                  # compiles
+        return pools
+    pools = batch(pools)                    # compiles
     best = float("inf")
     for _ in range(BATCHES):
         t0 = time.perf_counter()
-        kp, vp = batch(kp, vp)
+        pools = batch(pools)
         best = min(best, time.perf_counter() - t0)
-    ops = {}
+    ops, by_scope = {}, {}
     if trace:
         with harness.scratch_dir("ride_trace_") as tmp:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             jax.profiler.start_trace(tmp, profiler_options=opts)
-            kp, vp = batch(kp, vp)
+            pools = batch(pools)
             jax.profiler.stop_trace()
             planes = trace_reduce.load(jax.profiler.ProfileData.from_file(
                 trace_reduce.find_xplane(tmp)))
@@ -174,7 +224,13 @@ def run_case(fn, params, pools, state, ride, trace: bool):
         top = sorted(seen.items(), key=lambda kv: -kv[1][1])[:12]
         ops = {name: [n, round(s / n * 1e6, 1), round(s / (REPS * K) * 1e3, 3)]
                for name, (n, s) in top}
-    return best / (REPS * K) * 1e3, ops, (kp, vp)
+        scopes: dict = defaultdict(float)
+        for name, (_, s) in seen.items():
+            scopes[fn["scope_of"](name.split(":", 1)[0])
+                   or "no named scope"] += s
+        by_scope = {k: round(v / (REPS * K) * 1e3, 3) for k, v in sorted(
+            scopes.items(), key=lambda kv: -kv[1])}
+    return best / (REPS * K) * 1e3, ops, by_scope, pools
 
 
 def main() -> int:
@@ -186,6 +242,9 @@ def main() -> int:
                     help="leave the ride_branch=False programs out")
     ap.add_argument("--live", nargs="+", type=int, default=[],
                     help="also pieces of so many live rows (<= C)")
+    ap.add_argument("--cached", nargs="+", type=int, default=[0],
+                    help="linear: the rider's tokens before its pieces, "
+                    "a case each (whole pages)")
     ap.add_argument("--out", default="chiprun_out/ride_step_alone.json")
     args = ap.parse_args()
     device = jax.devices()[0]
@@ -197,12 +256,12 @@ def main() -> int:
     gpt = import_module(f"{PKG}.models.gpt")
     result = {"device": {"platform": device.platform,
                          "kind": device.device_kind},
-              "ms_a_step": {}, "ops": {}}
+              "ms_a_step": {}, "ops": {}, "scope_ms_a_step": {}}
     for model in args.models:
         cfg, sh = model_config(CONFIGS[model]), Shape(model)
         params = jax.jit(lambda key: gpt.init(cfg, key, jnp.bfloat16))(
             jax.random.PRNGKey(0))
-        if sh.latent:    # ONE pool of padded latent rows
+        if sh.latent or sh.linear:    # ONE pool of padded latent rows
             pools = (jax.random.normal(
                 jax.random.PRNGKey(1),
                 (cfg.layers_of("*"), sh.NP, 1, sh.PS, cfg.mla.page_width),
@@ -212,29 +271,48 @@ def main() -> int:
                      cfg.head_dim)
             pools = tuple(jax.random.normal(key, shape, jnp.bfloat16)
                           for key in jax.random.split(jax.random.PRNGKey(1)))
+        kd = cfg.kda
+        # the K layers' conv windows (bfloat16) and states (float32, small:
+        # a decayed state's size), as serve/kv_cache.py lays them out
+        pools = (*pools, None if not sh.linear else {
+            "conv": jax.random.normal(
+                jax.random.PRNGKey(2), (cfg.kda_layers, kd.conv_kernel - 1,
+                                        sh.B, kd.conv_channels), jnp.bfloat16),
+            "ssm": 0.05 * jax.random.normal(
+                jax.random.PRNGKey(3), (cfg.kda_layers, sh.B, kd.num_heads,
+                                        kd.head_dim, kd.head_dim))})
         rng = np.random.default_rng(0)
-        state = slots(sh, rng)
-        cases = [("C=0", 0, 0, True)]
+        cases = [("C=0", 0, 0, True, 0)]
         for C in args.rows:
-            cases += [(f"C={C}, 0 live, branch", C, 0, True),
-                      (f"C={C}, 0 live, dead rows", C, 0, False),
-                      *[(f"C={C}, {n} live", C, n, True)
-                        for n in [*args.live, C] if n <= C]]
+            cases += [(f"C={C}, 0 live, branch", C, 0, True, 0),
+                      (f"C={C}, 0 live, dead rows", C, 0, False, 0),
+                      *[(f"C={C}, {n} live" + (
+                          f" behind {cached}" if sh.linear else ""),
+                         C, n, True, cached)
+                        for n in [*args.live, C] if n <= C
+                        for cached in (args.cached if sh.linear else [0])]]
         programs = {}
-        for label, C, live, branch in cases:
+        for label, C, live, branch, cached in cases:
             if args.no_dead_rows and not branch:
                 continue
-            fn = programs.setdefault((C, branch), program(cfg, C, branch))
+            sh = Shape(model, cached)
+            state = slots(sh, np.random.default_rng(0))
+            fn = programs.setdefault((C, branch),
+                                     {"jit": program(cfg, C, branch)})
             ride = pieces(sh, C, live, rng, cfg.vocab_size) if C else None
-            ms, ops, pools = run_case(fn, params, pools, state, ride,
-                                      trace=True)
+            ms, ops, by_scope, pools = run_case(
+                fn, params, pools, state, ride, trace=True,
+                linear_model=sh.linear)
             key = f"{model} | {label}"
             result["ms_a_step"][key] = round(ms, 3)
             result["ops"][key] = ops
+            result["scope_ms_a_step"][key] = by_scope
             print(f"{key:40s} {ms:7.3f} ms a step", flush=True)
             for name, (n, us, ms_step) in ops.items():
                 print(f"    {name:48s} {n:5d} calls {us:8.1f} us a call "
                       f"{ms_step:7.3f} ms a step", flush=True)
+            print("    by scope (ms a step):", json.dumps(by_scope),
+                  flush=True)
         del params, pools
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:

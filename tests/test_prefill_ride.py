@@ -5,13 +5,20 @@ the tokens a riding prompt is served and the K/V (or latent rows) its pages
 hold are the cold program's, and behind a prefix hit the suffix program's;
 under half occupancy nothing rides; a riding prompt that is cancelled,
 preempted or failed gives its pages and slot back; and a model that cannot
-ride (a layer table with recurrent layers) keeps the parent's decode
+ride (a layer table with state-space layers) keeps the parent's decode
 program.
 
-CPU, float32, the dense, the MoE and (PR 41) the latent test
-configurations; 4 slots, pages of 8 tokens, 4 steps a dispatch, so a piece
-is 16 rows.
+CPU, float32, the dense, the MoE, (PR 41) the latent and (PR 43) the
+delta-rule test configurations; 4 slots, pages of 8 tokens, 4 steps a
+dispatch, so a piece is 16 rows. The delta-rule (``K``) layers' chunk is 8
+here (``ops/kda.py CHUNK`` is 64), so a piece is two sub-chunks with the
+state carried between them, and its engines prefill a prompt over 16 tokens
+chunk by chunk: a riding piece is held to the CHUNK programs, which read
+and write the slot's state as it does.
 """
+
+import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -23,20 +30,29 @@ from distributed_llm_training_and_inference_system_tpu.config.schema import Serv
 from distributed_llm_training_and_inference_system_tpu.models import init
 from distributed_llm_training_and_inference_system_tpu.models.gpt import (
     table_period)
+from distributed_llm_training_and_inference_system_tpu.ops import kda
 from distributed_llm_training_and_inference_system_tpu.serve import (
     InferenceEngine,
     Request,
     SamplingParams,
 )
 from distributed_llm_training_and_inference_system_tpu.serve.decode import (
-    PIECE_META, decode_scan)
+    PIECE_META, decode_scan, extend_step_forward)
 from distributed_llm_training_and_inference_system_tpu.serve.scheduler import (
     RequestState)
 
 PS, STEPS, SLOTS = 8, 4, 4
 C = InferenceEngine.RIDE_PAGES * PS
-MODELS = ["gpt-test", "olmoe-test", "xing-test"]
+LINEAR = "kimi-linear-test"
+MODELS = ["gpt-test", "olmoe-test", "xing-test", LINEAR]
 RNG = np.random.default_rng(36)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _short_kda_chunks():
+    plain, kda.CHUNK = kda.CHUNK, 8
+    yield
+    kda.CHUNK = plain
 
 
 def _tokens(n):
@@ -67,6 +83,8 @@ def _engine(name, **over):
     opts = dict(model=name, max_batch_size=SLOTS, max_seq_len=192,
                 prefill_chunk=32, kv_block_size=PS, dtype="float32",
                 decode_steps_per_dispatch=STEPS)
+    if name == LINEAR:
+        opts["chunked_prefill_tokens"] = C
     opts.update(over)
     return InferenceEngine(cfg, ServeConfig(**opts),
                            params=init(cfg, jax.random.PRNGKey(0)), seed=0)
@@ -131,6 +149,8 @@ CACHED = {"from 0": 0, "behind a prefix hit": 8 * PS}
 def test_a_riding_prompt_is_served_the_cold_programs_tokens_and_pages(
         engines, scenario, sampling, start):
     riding, cold = engines
+    if CACHED[start] and riding.cfg.is_recurrent:
+        pytest.skip("a recurrent model reuses no prefix by page hash")
     tag = f"-{scenario}-{sampling}-{start}"
     # (fresh tokens a case: a repeated prompt would be a prefix hit)
     prefix = _tokens(CACHED[start])
@@ -362,6 +382,8 @@ def test_an_engine_with_a_prefill_complete_hook_does_not_ride(engines):
 
 def test_a_prompt_that_rode_is_a_prefix_hit_for_the_next(engines):
     eng, _ = engines
+    if eng.cfg.is_recurrent:
+        pytest.skip("a recurrent model reuses no prefix by page hash")
     prompt = _tokens(3 * PS + 3)
     [a] = _serve(eng, [prompt], SAMPLING["greedy"], tag="-hit-a")
     cached = eng.stats()["prefix_cached_tokens"]
@@ -384,23 +406,25 @@ def test_engines_that_keep_todays_path_never_ride(over):
 
 @pytest.mark.parametrize("name,page,rows", [
     ("gpt-test", 8, 16), ("gpt-test", 64, 128), ("olmoe-test", 64, 128),
-    ("gpt-test", 128, 128), ("xing-test", 64, 128), ("xing-test", 256, 256)])
+    ("gpt-test", 128, 128), ("xing-test", 64, 128), ("xing-test", 256, 256),
+    (LINEAR, 256, 256)])
 def test_the_carry_is_read_off_the_page_size(name, page, rows):
     """``RIDE_PAGES`` pages a step, as chosen on the chip at pages of 64
     (128 rows); ONE page where a page alone holds as many (a latent model's
-    pages of 256: two would be a window of 512 rows in one step)."""
+    pages of 256: two would be a window of 512 rows in one step; the
+    delta-rule model's page is four sub-chunks of 64)."""
     eng = _engine(name, kv_block_size=page, max_seq_len=512)
     assert eng._ride_rows == rows
     [pieces] = eng._decode_tail_args()[1:]
     assert pieces.shape == (STEPS, PIECE_META + rows)
 
 
-@pytest.mark.parametrize("name", ["nemotron-h-test", "kimi-linear-test"])
+@pytest.mark.parametrize("name", ["nemotron-h-test"])
 def test_a_layer_table_model_keeps_the_parents_decode_program(name):
-    """A hybrid and a linear-attention configuration (recurrent layers in
-    the table) do not ride: the engine hands their decode program no
-    pieces, and the program lowers to the text of the parent's
-    ``_decode_impl_n`` (written out below as it stood)."""
+    """A hybrid configuration (state-space layers in the table) does not
+    ride: the engine hands its decode program no pieces, and the program
+    lowers to the text of the parent's ``_decode_impl_n`` (written out
+    below as it stood)."""
     cfg = get_model_config(name)
     eng = InferenceEngine(
         cfg, ServeConfig(model=name, max_batch_size=SLOTS, max_seq_len=128,
@@ -427,6 +451,100 @@ def test_a_layer_table_model_keeps_the_parents_decode_program(name):
     assert eng._decode_jit.lower(*args).as_text() == parents.as_text()
 
 
+# sha256 of the StableHLO of the decode program (``_decode_jit``, pieces and
+# all) as the PARENT of PR 43 (7583964) lowers it for this module's engine:
+# ``git archive`` of that commit, the lowering of the test below run there
+PARENTS_DECODE = {
+    "gpt-test": "73fb4be83a6de6f218be0767da6d0fe56554bd7f369fd873854a1860d7ae8a9e",
+    "olmoe-test": "5df605b7bab9c6587fab1cb6630d94ab3ed668636ad61597cbf67801904c4270",
+    "xing-test": "eb6eb32b4808957f7c034acfa046e0237be0c9751d9d9e7dadda8832c98f193a",
+    "sdar-test": "0a8f164867771f152023bfd616bd6b5e742f5f012fc856f0418430f415fb37c5",
+}
+
+
+@pytest.mark.parametrize("name", list(PARENTS_DECODE))
+def test_the_other_models_decode_programs_are_the_parents(name):
+    """PR 43 taught the table walk a ``K`` layer's piece (a seventh element
+    of its carry, ``recur_at``'s window) and changed what the engine hands
+    and takes (``_decode_tail_args``, ``_submit_decode``): the RIDING
+    programs of the uniform stack (dense, MoE) and of the latent table, and
+    the diffusion model's denoise program, lower byte for byte to the
+    parent's text all the same."""
+    import hashlib
+    eng = _engine(name)
+    assert eng._ride_rows == (0 if name == "sdar-test" else C)
+    text = eng._decode_jit.lower(
+        eng.params, eng.kv.k_pages, eng.kv.v_pages,
+        *eng._decode_head_args(), *eng._shared_decode_args(),
+        *eng._decode_tail_args()).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_DECODE[name]
+
+
+# sha256 of the StableHLO of ``kimi-linear-test``'s prefill programs for the
+# engine below (bucket 32), under this module's chunk of 8. The cold
+# program's is the PARENT's of PR 43 (7583964; ``git archive`` of that
+# commit, ``_linear_prefill_texts`` below run there). The chunk and the
+# final-chunk program read and write their slot's conv windows as a decode
+# step's piece does since PR 43 (a masked sum and a select over the pool,
+# ONE form for both callers: ``ops/kda.py slot_state``), and keep their
+# layers' states stacked in the walk's carry: their texts are this PR's,
+# and what they compute is held to the cold program's tokens and pools
+# elsewhere (tests/test_kimi_linear.py, and this module's pieces against them)
+LINEAR_PREFILL = {
+    "cold": "e2192e2172f2e0ccd913f74ce09ba3915095d80e4942fd98386ffc48592812a0",
+    "chunk": "4920bdf44415b3b3501e028ece8e5e99d64cda72adb178d0df46e4532df9b9f5",
+    "final chunk": "37e80665a1cfdb94fafd1ae40f2c122e10d10de91331b26a37a42d3ead5c5830",
+}
+
+
+def _linear_prefill_texts():
+    """{program: lowered text} of the linear test model's cold, chunk and
+    final-chunk programs as an engine jits them."""
+    from distributed_llm_training_and_inference_system_tpu.serve.sampling import (
+        seed_key_data)
+    cfg = get_model_config(LINEAR)
+    eng = InferenceEngine(
+        cfg, ServeConfig(model=LINEAR, max_batch_size=SLOTS, max_seq_len=128,
+                         dtype="float32", kv_block_size=PS, prefill_chunk=16,
+                         chunked_prefill_tokens=32,
+                         decode_steps_per_dispatch=STEPS),
+        params=init(cfg, jax.random.PRNGKey(0)))
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    def vec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+    params, pool, none = shapes((eng.params, eng.kv.k_pages, eng.kv.v_pages))
+    state = (shapes(eng.kv.state), vec())
+    sampling = shapes(eng._sampling_args(seed_key_data(0), 0,
+                                         SamplingParams()))
+    bucket, table = 32, vec(1, eng.kv.max_pages_per_slot)
+    return {
+        "cold": eng._prefill_fn(bucket).lower(
+            params, vec(1, bucket), vec(1), pool, none, vec(bucket // PS),
+            *sampling, *state).as_text(),
+        "chunk": eng._extend_chunk_fn(bucket).lower(
+            params, vec(1, bucket), vec(1), vec(1), pool, none, table,
+            *state).as_text(),
+        "final chunk": eng._extend_prefill_fn(bucket).lower(
+            params, vec(1, bucket), vec(1), vec(1), pool, none, table,
+            *sampling, *state).as_text(),
+    }
+
+
+def test_the_linear_models_prefill_programs_are_pinned():
+    """The delta-rule model rides since PR 43 (its decode program carries
+    pieces); under the gate its cold, chunk and final-chunk programs still
+    run: the cold one lowers byte for byte to the parent's text, the two
+    that carry a slot's state to the text PR 43 left them."""
+    import hashlib
+    assert {name: hashlib.sha256(text.encode()).hexdigest()
+            for name, text in _linear_prefill_texts().items()
+            } == LINEAR_PREFILL
+
+
 def _pools(cfg, pages, fill=None):
     """(k_pages, v_pages) of ``pages`` pages, zeros or ``fill``ed; a latent
     model's ONE pool of padded rows and None."""
@@ -438,10 +556,36 @@ def _pools(cfg, pages, fill=None):
     return pool, (None if cfg.is_latent else pool + 1)
 
 
+def _state(cfg, slots, fill=None):
+    """{"ssm_state": the ``K`` layers' pools} (zeros or ``fill``ed, the
+    states small as a decayed state is), {} for a model without them."""
+    if not cfg.kda_layers:
+        return {}
+    k = cfg.kda
+    shapes = {"conv": (cfg.kda_layers, k.conv_kernel - 1, slots,
+                       k.conv_channels),
+              "ssm": (cfg.kda_layers, slots, k.num_heads, k.head_dim,
+                      k.head_dim)}
+    return {"ssm_state": {
+        name: jnp.asarray(np.zeros(shape) if fill is None
+                          else 0.1 * fill(size=shape), jnp.float32)
+        for name, shape in shapes.items()}}
+
+
+def _same(a, b):
+    """Two carries of ``decode_scan``, leaf for leaf, bit for bit."""
+    a, b = (jax.tree_util.tree_leaves(t) for t in (a, b))
+    assert len(a) == len(b)
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(x, y)
+
+
 @pytest.mark.parametrize("name", MODELS)
 def test_a_step_without_a_piece_samples_what_the_plain_step_samples(name):
     """``decode_scan`` with all-zero pieces branches to the plain step: the
-    same tokens, the same pools, and no first token."""
+    same tokens, the same pools (a ``K`` model's states and conv windows of
+    every slot bit for bit, the idle slot's untouched), and no first
+    token."""
     cfg = get_model_config(name)
     params = init(cfg, jax.random.PRNGKey(0))
     B, pages = 3, 9
@@ -451,17 +595,22 @@ def test_a_step_without_a_piece_samples_what_the_plain_step_samples(name):
             jnp.asarray([16, 16, 0]),
             jnp.zeros((B, 2), jnp.uint32), jnp.zeros(B, jnp.float32),
             jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32))
-    plain, toks = decode_scan(params, *args, cfg, STEPS, attn_impl="gather")
+    state = _state(cfg, B, RNG.normal)
+    plain, toks = decode_scan(params, *args, cfg, STEPS, attn_impl="gather",
+                              **state)
     rode, (toks_r, firsts) = decode_scan(
-        params, *args, cfg, STEPS, attn_impl="gather",
+        params, *args, cfg, STEPS, attn_impl="gather", **state,
         ride=jnp.zeros((STEPS, PIECE_META + C), jnp.int32))
     np.testing.assert_array_equal(toks, toks_r)
     np.testing.assert_array_equal(firsts, 0)
-    for a, b in zip(plain, rode):
-        assert (a is None and b is None) or np.array_equal(a, b)
+    _same(plain, rode)
+    for name_, pool in state.get("ssm_state", {}).items():
+        idle = (slice(None), 2) if name_ == "ssm" else (
+            slice(None), slice(None), 2)
+        np.testing.assert_array_equal(rode[-1][name_][idle], pool[idle])
 
 
-@pytest.mark.parametrize("name", MODELS)
+@pytest.mark.parametrize("name", MODELS + ["the linear cell's table"])
 def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
     """A program that rides holds a carrying and a plain step body, and
     every start pays for both (trace, lowering, the compile cache's read:
@@ -472,7 +621,8 @@ def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
     one function a SHAPE for all its layers too: the slots' T = 1 (both
     bodies) and the piece's T = C."""
     import re
-    cfg = get_model_config(name)
+    cfg = (get_model_config(name) if name in MODELS
+           else _linear_cfg(CELLS_TABLE))
     params = init(cfg, jax.random.PRNGKey(0))
     B, pages = 3, 9
     args = (params, jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
@@ -481,8 +631,10 @@ def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
             jnp.zeros((B, 2), jnp.uint32), jnp.zeros(B, jnp.float32),
             jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32))
 
+    state = _state(cfg, B)
+
     def program(*a, ride=None):
-        return decode_scan(*a, cfg, STEPS, ride=ride)
+        return decode_scan(*a, cfg, STEPS, ride=ride, **state)
     rides = jax.jit(program).lower(
         *args, ride=jnp.zeros((STEPS, PIECE_META + C), jnp.int32)).as_text()
     plain = jax.jit(program).lower(*args).as_text()
@@ -496,17 +648,25 @@ def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
     assert functions("sample_tokens") == [2]
     if cfg.is_latent:
         # a call site a layer of the table's head and ONE for the loop over
-        # its periodic part (``*D`` then ``*E`` x 2: two sites, not three)
+        # its periodic part (``*D`` then ``*E`` x 2: two sites, not three;
+        # the linear TEST table is all head, the linear cell's is
+        # ``KDKEKE*E`` then ``KEKEKE*E`` x 2)
         head, unit, reps = table_period(cfg)
-        assert reps == 2 and len(head) + reps * len(unit) == cfg.num_layers
-        sites = sum(kind == "*" for kind, _ in head + unit)
-        assert functions("_latent_windows") == [2 * sites, sites]
+        assert reps == (0 if name == LINEAR else 2)
+        assert len(head) + reps * len(unit) == cfg.num_layers
+        sites = collections.Counter(kind for kind, _ in head + unit)
+        assert functions("_latent_windows") == [2 * sites["*"], sites["*"]]
+        # every ``K`` layer's one-token update of all slots is ONE function,
+        # called from both bodies at its (traced) layer
+        assert functions("step_pools") == (
+            [2 * sites["K"]] if sites["K"] else [])
         # and the ONE sampler takes whole tiles of 8 rows (B + 1 = 4 here)
         assert re.search(r"func\.func private @sample_tokens\("
                          rf"%arg0: tensor<8x{cfg.vocab_size}xf32>", rides)
     else:
         assert functions("_windows") == [2]
-    assert len(rides) < 2 * len(plain)
+    # (the chunked delta rule of a piece is text the plain program lacks)
+    assert len(rides) < (3 if cfg.kda_layers else 2) * len(plain)
 
 
 @pytest.mark.parametrize("pattern,head,unit,reps", [
@@ -516,6 +676,8 @@ def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
     ("*D*E", "*D*E", "", 0),              # nothing repeats
     ("MEMEM*E", "MEMEM*E", "", 0),        # the hybrid test table
     ("KDKEKE*EKEKEKE*E", "KDKEKE*EKEKEKE*E", "", 0),   # the linear test table
+    # the linear cell's table
+    ("KDKEKE*EKEKEKE*EKEKEKE*E", "KDKEKE*E", "KEKEKE*E", 2),
     ("KDKE*EKE*E", "KD", "KE*E", 2),
     ("*EEE*EEE*EEE", "", "*EEE", 3),
 ])
@@ -551,3 +713,168 @@ def test_the_running_count_of_expert_choices_is_the_cumsum(rows):
     got = jax.jit(running_count)(jnp.asarray(onehot))
     assert got.dtype == jnp.int32
     np.testing.assert_array_equal(got, np.cumsum(onehot, axis=0))
+
+
+# -- a delta-rule (``K``) model's piece: a window from its slot's own state --
+
+# the linear cell's table: three periods ``K K K *``, so a riding program
+# walks ``KEKEKE*E`` x 2 by a loop (the test model's two periods are all head)
+CELLS_TABLE = "KDKEKE*EKEKEKE*EKEKEKE*E"
+
+
+def _linear_cfg(table=None):
+    """The linear test model, or it with the layer table ``table``."""
+    import dataclasses
+    cfg = get_model_config(LINEAR)
+    return cfg if table is None else dataclasses.replace(
+        cfg, layer_pattern=table, num_layers=len(table))
+
+
+def _linear_step_case(live, start, table=None):
+    """Four slots of the linear test model (or of it with ``table``): slots 0 and 1 decode, slot 2's
+    prompt rides ONE piece of ``live`` rows at ``start`` (pages 5..), slot
+    3 is idle. The pools are random (the states small), so every slot has
+    a former occupant's state; (cfg, params, decode_scan's arguments, the
+    state pools, the piece's row)."""
+    cfg = _linear_cfg(table)
+    params = init(cfg, jax.random.PRNGKey(0))
+    B, pages = 4, 12
+    tables = jnp.asarray([[1, 2, 0, 0], [3, 4, 0, 0], [5, 6, 7, 8],
+                          [0, 0, 0, 0]], jnp.int32)
+    args = (jnp.asarray([5, 6, 7, 8], jnp.int32), jnp.asarray([3, 9, 0, 0]),
+            *_pools(cfg, pages, RNG.normal), tables,
+            jnp.asarray([16, 16, 0, 0]),
+            jnp.zeros((B, 2), jnp.uint32), jnp.zeros(B, jnp.float32),
+            jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32))
+    piece = np.zeros((STEPS, PIECE_META + C), np.int32)
+    piece[0, :PIECE_META] = (2, start, live, 0)
+    piece[0, PIECE_META:] = RNG.integers(1, 250, C)     # padding: garbage
+    return cfg, params, args, _state(cfg, B, RNG.normal)["ssm_state"], piece
+
+
+@functools.cache
+def _linear_programs(table):
+    """(``decode_scan``'s final carry, the chunk program of slot 2) of
+    the linear test model with ``table``, jitted ONCE for the cases below
+    (a piece's slot, start and live rows are values, not shapes)."""
+    cfg = _linear_cfg(table)
+
+    def dispatch(params, args, state, ride=None):
+        return decode_scan(params, *args, cfg, STEPS, attn_impl="gather",
+                           ssm_state=state, ride=ride)[0]
+
+    def chunk(params, tokens, start, pool, table_row, ok, state):
+        return extend_step_forward(
+            params, tokens, start, pool, None, table_row, cfg, write_ok=ok,
+            attn_impl="gather", ssm_state=state, state_slot=jnp.int32(2))
+    return jax.jit(dispatch), jax.jit(chunk)
+
+
+@pytest.mark.parametrize("table", [None, CELLS_TABLE],
+                         ids=["walked whole", "walked by a loop"])
+@pytest.mark.parametrize("start", [0, 2 * PS], ids=["from 0", "from its state"])
+@pytest.mark.parametrize("live", [C, C - 5], ids=["whole", "prefix mask"])
+def test_a_piece_leaves_the_pools_a_chunk_program_leaves(live, start, table):
+    """A decode dispatch whose first step carries a piece, against the
+    plain dispatch followed by the CHUNK program over the same rows of the
+    same slot: the same latent pages, and in both state pools the piece's
+    slot's rows equal to the chunk program's (from ZERO at ``start`` 0,
+    whatever the slot held; from the slot's own rows behind that), the
+    decoding slots' theirs, and the idle slot's rows as they were, bit for
+    bit. (The riding program of the cell's table walks its periodic
+    part by a loop, the piece's rows in the loop's carry; the plain program
+    and the chunk program walk it whole.)"""
+    cfg, params, args, state, piece = _linear_step_case(live, start, table)
+    assert table_period(cfg)[2] == (2 if table else 0)
+    dispatch, chunk = _linear_programs(table)
+    rode = dispatch(params, args, state, jnp.asarray(piece))
+    plain = dispatch(params, args, state)
+    tokens = jnp.asarray(piece[:1, PIECE_META:])
+    ok = (jnp.arange(C) < live)[None]
+    _, pool, _, chunked = chunk(params, tokens, jnp.asarray([start]),
+                                plain[2], args[4][2:3], ok, plain[-1])
+    # the piece's live rows landed in pages 5.. (padding: the scratch page)
+    np.testing.assert_allclose(rode[2][:, 1:], pool[:, 1:], rtol=2e-4,
+                               atol=2e-5)
+    for name, mine in (("conv", (slice(None), slice(None), 2)),
+                       ("ssm", (slice(None), 2))):
+        got, want = np.asarray(rode[-1][name]), np.asarray(chunked[name])
+        np.testing.assert_allclose(got[mine], want[mine], rtol=2e-4,
+                                   atol=2e-5)
+        assert not np.allclose(got[mine], np.asarray(state[name])[mine])
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+        idle = (*mine[:-1], 3)
+        np.testing.assert_array_equal(got[idle],
+                                      np.asarray(state[name])[idle])
+    if start == 0:      # ... and read nothing of the former occupant's
+        fresh = {k: v.at[(slice(None), 2) if k == "ssm" else
+                         (slice(None), slice(None), 2)].set(7.0)
+                 for k, v in state.items()}
+        again = dispatch(params, args, fresh, jnp.asarray(piece))
+        _same(again[-1], rode[-1])
+
+
+@pytest.fixture(scope="module")
+def linear_engines():
+    return _engine(LINEAR), _engine(LINEAR)
+
+
+def test_a_document_rides_from_zero_in_a_slot_that_held_a_state(
+        linear_engines):
+    """A prompt of 5 pages (three pieces) in an engine whose every slot
+    holds a former occupant's state and conv window: its first piece starts
+    at 0 and takes both as zero, the next read what the one before wrote.
+    The tokens are the chunk programs' on a clean engine."""
+    eng, cold = linear_engines
+    eng.kv.state = {name: jnp.asarray(RNG.normal(size=pool.shape) * 0.3,
+                                      pool.dtype)
+                    for name, pool in eng.kv.state.items()}
+    prompt = _tokens(5 * PS)
+    before = eng.stats()
+    [got] = _serve(eng, [prompt], SAMPLING["greedy"], tag="-occupied")
+    [want] = _serve(cold, [prompt], SAMPLING["greedy"], residents=0,
+                    tag="-occupied")
+    after = eng.stats()
+    assert got.generated_tokens == want.generated_tokens
+    # the second and third piece read the slot's state: 3 pages of 5
+    assert (after["state_carry_chunks"] - before["state_carry_chunks"],
+            after["state_carry_tokens"] - before["state_carry_tokens"]
+            ) == (2, 3 * PS)
+    assert after["kda"]["state_carry_tokens"] == after["state_carry_tokens"]
+    # (the clean engine's chunk programs: every chunk but the first)
+    assert cold.stats()["state_carry_chunks"] >= 2
+    _idle(eng)
+    _idle(cold)
+
+
+@pytest.mark.parametrize("how", ["cancel", "preempt", "fail_all"])
+def test_a_slot_is_reused_after_a_riding_document_was_dropped(
+        linear_engines, how):
+    """A riding document dropped in its middle leaves a half-written state
+    in its slot, and pieces of it still in flight; the next prompts (one of
+    them takes that slot) ride from zero and are served the chunk
+    programs' tokens."""
+    eng, cold = linear_engines
+    req = _start_riding(eng, _tokens(STEPS * C + 20), f"dropped-{how}")
+    slot = req.slot
+    with eng.lock:
+        if how == "cancel":
+            assert eng.scheduler.cancel(req.request_id)
+        elif how == "preempt":
+            eng._preempt(slot)
+            assert eng.scheduler.cancel(req.request_id)
+    if how == "fail_all":
+        eng.fail_all("boom")
+    eng.run_until_idle()
+    assert req.state in (RequestState.CANCELLED, RequestState.FAILED)
+    _idle(eng)
+    prompts = [_tokens(2 * C + 3), _tokens(C + 1), _tokens(3 * C)]
+    tag = f"-after-{how}"
+    # two residents and three riders: every slot is taken, ``slot`` too
+    got = _serve(eng, prompts, SAMPLING["greedy"], tag=tag)
+    want = _serve(cold, prompts, SAMPLING["greedy"], residents=0, tag=tag)
+    for a, b in zip(got, want):
+        assert a.state is RequestState.FINISHED
+        assert a.generated_tokens == b.generated_tokens
+    _idle(eng)
+    _idle(cold)

@@ -553,12 +553,49 @@ def _hybrid_cell(one_chip):
     return cfg, B, params, pool, state
 
 
-def _no_copy_of(text: str, shapes: list[str]) -> None:
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+              "u32": 4, "f32": 4}
+
+
+def _no_copy_of(text: str, shapes: list[str],
+                fused_into_at_most: int = 0) -> None:
+    """No ``copy`` of a pool or a stack (a result that starts with one of
+    ``shapes``) in an optimised HLO text, inside a fused computation or
+    out of one.
+
+    ``fused_into_at_most`` (bytes; the carrying linear program alone asks
+    for it): a copy FUSED into an operation that keeps a small part of it
+    (a slot's rows sliced out of a pool: the fusion computes those rows
+    alone) passes where everything the OUTERMOST fusion that holds it hands
+    out is no more than so many bytes; a fusion that hands the copy on
+    converted or transposed is pool-sized, and fails."""
+    import math
+    import re
+    computation, called_from = None, {}     # callee -> (caller, its line)
+    copies = []
     for line in text.splitlines():
-        if " copy(" in line:
-            for shape in shapes:
-                assert not line.lstrip().split(" = ", 1)[-1].startswith(
-                    shape), f"a pool- or stack-sized copy: {line[:200]}"
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            computation = head.group(1)
+            continue
+        for callee in re.findall(r"calls=%?([\w.\-]+)", line):
+            called_from[callee] = (computation, line)
+        if " copy(" in line and any(
+                line.lstrip().split(" = ", 1)[-1].startswith(shape)
+                for shape in shapes):
+            copies.append((computation, line))
+    for at, line in copies:
+        made = line
+        while fused_into_at_most and "fused" in at and at in called_from:
+            at, made = called_from[at]
+        result = made.lstrip().split(" = ", 1)[-1].split(" fusion(")[0]
+        handed_out = sum(
+            _HLO_BYTES.get(kind, 8) * math.prod(int(n) for n in dims.split(",") if n)
+            for kind, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]",
+                                         result))
+        assert made is not line and handed_out <= fused_into_at_most, (
+            f"a pool- or stack-sized copy: {line[:200]}\n"
+            f"(handed out by: {made[:200]})")
 
 
 @pytest.mark.parametrize("which", ["up", "down"])
@@ -863,9 +900,10 @@ def test_float32_carrying_latent_decode_program_fits_the_kernels_vmem(
 LINEAR_PAGES = 1525
 
 
-def _linear_cell(one_chip):
+def _linear_cell(one_chip, periods=3, dtype=jnp.bfloat16):
     """(model config, shapes of params / latent pool / state pools) of the
-    linear cell as its configuration file states it."""
+    linear cell as its configuration file states it (3 periods ``K K K *``;
+    ``chip_smoke.py``'s riding arm has one, in float32)."""
     import json
     from pathlib import Path
 
@@ -875,17 +913,21 @@ def _linear_cell(one_chip):
     config = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
                          / "configs" / "kimi-linear-48b-a3b-12l-ep8.json"
                          ).read_text())
-    cfg = ModelConfig.from_published(config)
+    group = config["linear_attn_config"]
+    cfg = dataclasses.replace(ModelConfig.from_published(dict(
+        config, num_hidden_layers=4 * periods, linear_attn_config=dict(
+            group, **{k: [i for i in group[k] if i <= 4 * periods]
+                      for k in ("kda_layers", "full_attn_layers")}))),
+        dtype=jnp.dtype(dtype).name)
     sds = _sds(one_chip)
     B = config["serve"]["max_batch_size"]
     params = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype), jax.eval_shape(
-            lambda k: gpt.init(cfg, k, jnp.bfloat16), jax.random.PRNGKey(0)))
-    pool = sds((cfg.kv_layers, LINEAR_PAGES, 1, LATENT_PS, 640),
-               jnp.bfloat16)
+            lambda k: gpt.init(cfg, k, dtype), jax.random.PRNGKey(0)))
+    pool = sds((cfg.kv_layers, LINEAR_PAGES, 1, LATENT_PS, 640), dtype)
     k = cfg.kda
     state = {"conv": sds((cfg.kda_layers, k.conv_kernel - 1, B,
-                          k.conv_channels), jnp.bfloat16),
+                          k.conv_channels), dtype),
              "ssm": sds((cfg.kda_layers, B, k.num_heads, k.head_dim,
                          k.head_dim), jnp.float32)}
     return cfg, B, params, pool, state
@@ -911,6 +953,53 @@ def test_kda_decode_kernel_updates_the_state_pool_in_place(one_chip, as_tpu):
     assert mem.temp_size_in_bytes < 32 << 20
 
 
+@functools.cache
+def _linear_decode_program(one_chip, periods=3, dtype=jnp.bfloat16):
+    """``decode_scan`` at the linear cell's shapes, 2 steps: the compile of
+    it with a piece of ``carry`` rows riding each step (what the cell's
+    engine jits as ``_decode_impl_n`` since PR 43), or (0) the program
+    without pieces; (its text, its memory analysis)."""
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        PIECE_META, decode_scan)
+    cfg, B, params, pool, state = _linear_cell(one_chip, periods, dtype)
+    sds = _sds(one_chip)
+    K = 2
+
+    def program(params, pool, tokens, positions, tables, stops, keys, temp,
+                top_k, top_p, state, ride=None):
+        return decode_scan(params, tokens, positions, pool, None, tables,
+                           stops, keys, temp, top_k, top_p, cfg, K,
+                           return_moe_stats=True, ssm_state=state, ride=ride)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)
+
+    @functools.cache     # (two tests read the plain program's analysis)
+    def compile_(carry):
+        ride = (i32(K, PIECE_META + carry),) if carry else ()
+        compiled = jax.jit(program, donate_argnums=(1, 10)).lower(
+            params, pool, i32(B), i32(B), i32(B, 64), i32(B),
+            sds((B, 2), jnp.uint32), sds((B,), jnp.float32), i32(B),
+            sds((B,), jnp.float32), state, *ride).compile()
+        text = compiled.as_text()
+        assert all(k in text for k in ("moe_gmm", "mla_paged_attention",
+                                       "kda_decode"))
+        # the piece's windows: the multi-query latent kernel and the
+        # chunked delta rule, under the names a chunk program's have
+        assert ("mla_paged_attention_mq" in text) == bool(carry)
+        assert ("kda_chunk_prefill" in text) == bool(carry)
+        kind = {"bfloat16": "bf16", "float32": "f32"}[cfg.dtype]
+        Lk, E = cfg.kda_layers, cfg.layers_of("E")
+        _no_copy_of(text, [f"f32[{Lk},128,32,128,128]", "f32[128,32,128,128]",
+                           f"{kind}[{periods},1525,1,256,640]",
+                           f"{kind}[{E},32,2304,1024]",
+                           f"{kind}[{E},32,1024,2304]"],
+                    # the piece's slot's rows of the state pool, read once:
+                    # 19 MB of 2.4 GB
+                    fused_into_at_most=(32 << 20) if carry else 0)
+        return text, compiled.memory_analysis()
+    return compile_
+
+
 def test_linear_decode_program_moves_no_pool(one_chip, as_tpu):
     """The multi-step decode program at the linear cell's shapes: the latent
     pool (1.5 GB) and both state pools (2.4 GB + 85 MB) ride the carry and
@@ -919,32 +1008,51 @@ def test_linear_decode_program_moves_no_pool(one_chip, as_tpu):
     85 MB conv-window pool is re-laid once at the program's entry and once
     at its exit, outside the step loop: the compiler keeps the slots on the
     lanes inside it, as it computes the 128-row projections.)"""
-    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
-        decode_scan)
-    cfg, B, params, pool, state = _linear_cell(one_chip)
-    sds = _sds(one_chip)
-
-    def program(params, pool, tokens, positions, tables, stops, keys, temp,
-                top_k, top_p, state):
-        return decode_scan(params, tokens, positions, pool, None, tables,
-                           stops, keys, temp, top_k, top_p, cfg, 2,
-                           return_moe_stats=True, ssm_state=state)
-
-    i32 = lambda *shape: sds(shape, jnp.int32)
-    compiled = jax.jit(program, donate_argnums=(1, 10)).lower(
-        params, pool, i32(B), i32(B), i32(B, 64), i32(B),
-        sds((B, 2), jnp.uint32), sds((B,), jnp.float32), i32(B),
-        sds((B,), jnp.float32), state).compile()
-    text = compiled.as_text()
-    assert all(k in text for k in ("moe_gmm", "mla_paged_attention",
-                                   "kda_decode"))
-    _no_copy_of(text, ["f32[9,128,32,128,128]", "f32[128,32,128,128]",
-                       "bf16[3,1525,1,256,640]",
-                       "bf16[11,32,2304,1024]", "bf16[11,32,1024,2304]"])
-    mem = compiled.memory_analysis()
+    _, mem = _linear_decode_program(one_chip)(0)
     assert mem.temp_size_in_bytes < 512 << 20, (
         f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries")
     assert mem.alias_size_in_bytes >= 3.9e9
+
+
+# the K in-projections' stack [9, 2304, 12576]: the chip keeps it with the
+# 2,304 inputs on the lanes (12,576 columns are no whole number of lanes),
+# and a loop that takes a layer of it by a traced index wants the other
+# order: ONE copy of the stack, hoisted out of the step loops (a dispatch)
+LINEAR_IN_PROJ_BYTES = 9 * 2304 * 12576 * 2
+
+
+def test_carrying_linear_decode_program_fits_the_chip(one_chip, as_tpu):
+    """The linear decode program with a prompt's piece riding every step
+    (PR 43): ONE page of 256 rows beside the 128 slots' rows, through
+    ``mla_paged_attention_mq`` in the 3 latent layers and through
+    ``kda_chunk_prefill`` (4 sub-chunks of 64 from the slot's own float32
+    state) in the 9 ``K`` layers, the table's periodic part walked by a
+    loop (the one-step kernel takes its layer as a prefetched scalar, the
+    piece's rows of the pools ride the loop's carry). The piece's slot's
+    rows are read once before the first layer and written once after the
+    last: no copy of either state pool (2.5 GB), of the latent pool or of
+    an expert stack, the pools aliased, and no more temporaries than the
+    program without pieces plus half a MB a piece row and the one copy of
+    the ``K`` in-projections the loop costs (a slot's conv windows read by
+    a slice put the 81 MB pool's 3 columns on the lanes instead: 3.4 GB of
+    padding, ``ops/kda.py slot_state``). Both window kernels' scoped VMEM
+    is the compile itself."""
+    compile_ = _linear_decode_program(one_chip)
+    (_, plain), (_, carrying) = compile_(0), compile_(LATENT_PS)
+    assert carrying.alias_size_in_bytes >= 3.9e9
+    assert (carrying.temp_size_in_bytes < plain.temp_size_in_bytes
+            + PIECE_ROWS_BYTES + LINEAR_IN_PROJ_BYTES), (
+        plain.temp_size_in_bytes, carrying.temp_size_in_bytes)
+
+
+@pytest.mark.slow     # ~2 min; run it before the smoke's ride phase changes
+def test_float32_carrying_linear_decode_program_compiles(one_chip, as_tpu):
+    """``chip_smoke.py``'s linear ``ride`` arm: Kimi-Linear's widths, one
+    period ``K K K *``, FLOAT32 weights, pools and conv windows, 128 slots,
+    full-precision matmuls: the window kernel's float32 half tile and the
+    chunked delta rule's float32 operands inside the decode program."""
+    with jax.default_matmul_precision("highest"):     # as the smoke sets it
+        _linear_decode_program(one_chip, 1, jnp.float32)(LATENT_PS)
 
 
 def test_linear_chunk_program_reads_a_slots_state_once(one_chip, as_tpu):
