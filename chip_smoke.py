@@ -1150,16 +1150,55 @@ def child_kernels() -> None:
     ok = jnp.arange(slots)[:, None] == 2
     step = jax.jit(lambda h, c, s_, p: ssm_mixer(
         h, p, hy, ssm.recur_step(hy, c, s_, 1, ok)), donate_argnums=(1, 2))
-    decoded = []
-    for t in range(steps):
-        h_t = jnp.zeros((slots, 1, H), jnp.bfloat16).at[2, 0].set(
-            h_norm[0, S + t])
-        out, (conv_pool, ssm_pool) = step(h_t, conv_pool, ssm_pool, mix)
-        decoded.append(out[2, 0])
+
+    def decode_slot_2(conv_pool, ssm_pool):
+        """(slot 2's outputs of ``steps`` decode steps, the pools)"""
+        decoded = []
+        for t in range(steps):
+            h_t = jnp.zeros((slots, 1, H), jnp.bfloat16).at[2, 0].set(
+                h_norm[0, S + t])
+            out, (conv_pool, ssm_pool) = step(h_t, conv_pool, ssm_pool, mix)
+            decoded.append(out[2, 0])
+        return jnp.stack(decoded), conv_pool, ssm_pool
+    decoded, conv_pool, ssm_pool = decode_slot_2(conv_pool, ssm_pool)
     check(f"ssm_mixer decode [{steps} steps over the state pools, slot 2 of "
-          f"{slots} live]", jnp.stack(decoded), ref[n_live:])
+          f"{slots} live]", decoded, ref[n_live:])
     if float(jnp.abs(ssm_pool[:, jnp.asarray([0, 1, 3])]).max()) != 0.0:
         failures.append("ssm decode wrote an idle slot's state")
+    # (PR 44) the same window as the TWO pieces a decode step would carry:
+    # each a chunk of one slot, run from what ``slot_state`` reads of slot 2
+    # in pools that hold a former occupant's rows (the first piece takes
+    # them as zero), written back; then decode over what the pieces left
+    half = S // 2
+    conv_pool = jax.random.normal(next(key), conv_pool.shape, jnp.bfloat16)
+    ssm_pool = 0.3 * jax.random.normal(next(key), ssm_pool.shape)
+    others = jnp.asarray([0, 1, 3])
+    before = (conv_pool[:, others], ssm_pool[:, others])
+
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
+    def piece(h, c, s_, p, start, n):
+        tails, states = ssm.slot_state(c, s_, jnp.int32(2), start[None])
+        out, after = ssm_mixer(h, p, hy, ssm.recur_chunk(
+            hy, tails[1], states[1], jnp.arange(half)[None] < n))
+        return out, ssm.write_slot_state(
+            c, s_, jnp.int32(2), tails.at[1].set(after[0]),
+            states.at[1].set(after[1]), True)
+    rode = []
+    for start in (0, half):
+        n = min(half, n_live - start)
+        out, (conv_pool, ssm_pool) = piece(
+            h_norm[:, start:start + half], conv_pool, ssm_pool, mix,
+            jnp.int32(start), jnp.int32(n))
+        rode.append(out[0, :n])
+    check(f"ssm_mixer pieces [{S} rows as 2 windows of {half} from the "
+          f"slot's own state, {n_live} live]", jnp.concatenate(rode),
+          ref[:n_live])
+    if any(float(jnp.abs(a[:, others] - b).max()) != 0.0
+           for a, b in zip((conv_pool, ssm_pool), before)):
+        failures.append("a piece wrote another slot's state")
+    decoded, conv_pool, ssm_pool = decode_slot_2(conv_pool, ssm_pool)
+    check(f"ssm_mixer decode behind the pieces [{steps} steps, slot 2 of "
+          f"{slots} live]", decoded, ref[n_live:])
 
     moe_l = jax.tree_util.tree_map(lambda a: a[1], {
         k: v for k, v in blocks["moe"].items() if k not in ("up", "down")})

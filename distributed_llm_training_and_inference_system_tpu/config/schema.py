@@ -239,7 +239,7 @@ class MoEConfig:
         )
 
 
-@dataclass
+@dataclass(frozen=True)     # hashable: a jitted function's static
 class SSMConfig:
     """Mamba-2 state-space mixer sizes (the ``M`` layers of a layer table).
 

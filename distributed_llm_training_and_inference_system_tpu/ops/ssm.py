@@ -17,10 +17,13 @@ by the heads of a group ``g(i)``. Two forms of the same recurrence:
 - ``ssm_decode``: the one-step update over ``[slots]``, elementwise in
   float32; the step reads and writes every live slot's state once.
 
-``recur_window`` / ``recur_step`` say where the state lives, as ``attend``
-does for K and V (models/layers.py ``decoder_block``): from zeros over a
-window (training-free forward, cold prefill), or in the engine's state
-pools ``[state-space layer, slot, ...]`` (decode).
+``recur_window`` / ``recur_step`` / ``recur_chunk`` say where the state
+lives, as ``attend`` does for K and V (models/layers.py ``decoder_block``):
+from zeros over a window (training-free forward, cold prefill), in the
+engine's state pools ``[state-space layer, slot, ...]`` (decode), or, for a
+window of ONE slot behind tokens it has run already (the piece a decode
+step carries, a chunk of a prompt), from that slot's own conv tail and
+state (``slot_state`` reads them, ``write_slot_state`` puts them back).
 
 The scopes (``ssm_conv``, ``ssm_scan_prefill``, ``ssm_decode``,
 ``ssm_gated_norm``) are what a device trace names these operations by.
@@ -60,8 +63,10 @@ def ssm_conv(xbc: jax.Array, kernel: jax.Array, bias: jax.Array,
 
 def ssm_scan_prefill(x: jax.Array, dt: jax.Array, A: jax.Array,
                      Bm: jax.Array, Cm: jax.Array, D: jax.Array,
-                     chunk: int) -> tuple[jax.Array, jax.Array]:
-    """The chunked scan from a ZERO state.
+                     chunk: int, h0: Optional[jax.Array] = None
+                     ) -> tuple[jax.Array, jax.Array]:
+    """The chunked scan, from the state ``h0`` [B, nh, P, N] (float32; the
+    state before the window's first token) or, given none, from ZERO.
 
     x [B, S, nh, P] and Bm, Cm [B, S, G, N] in the compute dtype; dt
     [B, S, nh] float32, already softplus'd and 0 at positions that must
@@ -111,7 +116,8 @@ def ssm_scan_prefill(x: jax.Array, dt: jax.Array, A: jax.Array,
             return h * decay_c[..., None, None] + state_c, h
 
         h_last, h_in = jax.lax.scan(
-            carry, jnp.zeros((B, G, r, P, N), f32),
+            carry, (jnp.zeros((B, G, r, P, N), f32) if h0 is None
+                    else h0.astype(f32).reshape(B, G, r, P, N)),
             (chunk_state.swapaxes(0, 1), chunk_decay.swapaxes(0, 1)))
         h_in = h_in.swapaxes(0, 1)                           # [B,nc,G,r,P,N]
         # what the state carried into the chunk adds: exp(cum_t) C_t . h_in
@@ -166,9 +172,9 @@ def ssm_gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array,
 # Where the state lives
 # ---------------------------------------------------------------------------
 
-def _split(xbc_act: jax.Array, cfg):
-    """The activated conv output [.., C] as x [.., nh, P], B, C [.., G, N]."""
-    s = cfg.ssm
+def _split(xbc_act: jax.Array, s):
+    """The activated conv output [.., C] as x [.., nh, P], B, C [.., G, N]
+    (``s``: the model's ``SSMConfig``)."""
     d_in, gn = s.inner_size, s.n_groups * s.state_size
     lead = xbc_act.shape[:-1]
     return (xbc_act[..., :d_in].reshape(*lead, s.num_heads, s.head_dim),
@@ -186,10 +192,14 @@ def _step_sizes(dt_raw: jax.Array, p: dict) -> tuple[jax.Array, jax.Array,
             -jnp.exp(p["A_log"].astype(f32)), p["D"].astype(f32))
 
 
-def recur_window(cfg, live: Optional[jax.Array] = None):
+def recur_window(cfg, live: Optional[jax.Array] = None,
+                 tail: Optional[jax.Array] = None,
+                 h0: Optional[jax.Array] = None):
     """``recur`` for a window that starts a sequence (a forward with no
     cache, cold prefill): the conv from a zero tail, the chunked scan from
-    a zero state. ``live`` [B, S] (bool, or segment ids with 0 = padding)
+    a zero state; or, given them, from the conv ``tail`` [B, K-1, C] and
+    the state ``h0`` [B, nh, P, N] before the window (``recur_chunk``).
+    ``live`` [B, S] (bool, or segment ids with 0 = padding)
     marks the real tokens, which must be a PREFIX of each row: padding
     takes ``dt = 0`` and the state returned is the one after the last live
     token, (the K-1 pre-activation conv columns before position
@@ -198,8 +208,9 @@ def recur_window(cfg, live: Optional[jax.Array] = None):
 
     def recur(xbc, dt_raw, p):
         B, S, _ = xbc.shape
-        act, padded = ssm_conv(xbc, p["conv"]["kernel"], p["conv"]["bias"])
-        x, Bm, Cm = _split(act, cfg)
+        act, padded = ssm_conv(xbc, p["conv"]["kernel"], p["conv"]["bias"],
+                               tail)
+        x, Bm, Cm = _split(act, cfg.ssm)
         dt, A, D = _step_sizes(dt_raw, p)
         if live is None:
             length = jnp.full((B,), S, jnp.int32)
@@ -207,44 +218,122 @@ def recur_window(cfg, live: Optional[jax.Array] = None):
             alive = live if live.dtype == jnp.bool_ else live != 0
             dt = jnp.where(alive[..., None], dt, 0.0)
             length = jnp.sum(alive, axis=1, dtype=jnp.int32)
-        y, h = ssm_scan_prefill(x, dt, A, Bm, Cm, D, cfg.ssm.chunk_size)
+        y, h = ssm_scan_prefill(x, dt, A, Bm, Cm, D, cfg.ssm.chunk_size, h0)
         # position p is padded[p + K - 1]: the columns length-K+1..length-1
+        # (a window of fewer than K-1 live tokens keeps the tail's last)
         idx = length[:, None] + jnp.arange(K - 1, dtype=jnp.int32)
-        tail = jnp.take_along_axis(padded, idx[..., None], axis=1)
-        return y.reshape(B, S, -1), (tail, h)
+        new_tail = jnp.take_along_axis(padded, idx[..., None], axis=1)
+        return y.reshape(B, S, -1), (new_tail, h)
     return recur
 
 
+def step_pools(xbc: jax.Array, dt_raw: jax.Array, p: dict,
+               conv_pool: jax.Array, ssm_pool: jax.Array, layer,
+               write_ok: Optional[jax.Array], s
+               ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
+    """One decode step of every slot over the state pools ``conv_pool``
+    [Lm, slots, K-1, C] and ``ssm_pool`` [Lm, slots, nh, P, N], read and
+    written at ``[layer]`` (an int, or traced): what ``recur_step``'s
+    ``recur`` does, with everything it reads an argument (``s``: the
+    ``SSMConfig``), so that a program's two step bodies and its ``M``
+    layers can call ONE jitted form of it (serve/decode.py)."""
+    B, T, _ = xbc.shape
+    if T != 1:
+        raise ValueError(
+            "every slot advances one token over the state pools; a "
+            f"window of {T} tokens a slot (speculative verification) is "
+            "not supported: a prompt's window goes through recur_chunk, "
+            "one slot at a time")
+    tail = conv_pool[layer]
+    act, padded = ssm_conv(xbc, p["conv"]["kernel"], p["conv"]["bias"], tail)
+    x, Bm, Cm = _split(act[:, 0], s)
+    dt, A, D = _step_sizes(dt_raw[:, 0], p)
+    old = ssm_pool[layer]
+    y, new = ssm_decode(x, dt, A, Bm, Cm, D, old)
+    new_tail = padded[:, 1:].astype(conv_pool.dtype)
+    # (under the update's scope: XLA fuses the update into the pool's
+    # in-place write, and a trace names the fusion by its ROOT)
+    with jax.named_scope("ssm_decode"):
+        if write_ok is not None:
+            ok = write_ok.reshape(B)
+            new = jnp.where(ok[:, None, None, None], new, old)
+            new_tail = jnp.where(ok[:, None, None], new_tail, tail)
+        return (y.reshape(B, 1, -1),
+                (conv_pool.at[layer].set(new_tail),
+                 ssm_pool.at[layer].set(new)))
+
+
 def recur_step(cfg, conv_pool: jax.Array, ssm_pool: jax.Array, layer,
-               write_ok: Optional[jax.Array] = None):
+               write_ok: Optional[jax.Array] = None, step=step_pools):
     """``recur`` for one decode step of every slot over the state pools
     ``conv_pool`` [Lm, slots, K-1, C] and ``ssm_pool``
     [Lm, slots, nh, P, N], read and written at ``[layer]``. A slot with
     ``write_ok`` [slots, 1] False (idle, or past its stop position) leaves
-    its state as it is. Returns the two pools as the state."""
+    its state as it is. Returns the two pools as the state. (``step``: a
+    jitted ``step_pools``, where a program calls it from many places.)"""
     def recur(xbc, dt_raw, p):
-        B, T, _ = xbc.shape
-        if T != 1:
-            raise ValueError(
-                "a state-space layer advances one token a slot over the "
-                f"state pool; a window of {T} tokens (suffix or chunked "
-                "prefill, speculative verification) is not supported")
-        tail = conv_pool[layer]
-        act, padded = ssm_conv(xbc, p["conv"]["kernel"], p["conv"]["bias"],
-                               tail)
-        x, Bm, Cm = _split(act[:, 0], cfg)
-        dt, A, D = _step_sizes(dt_raw[:, 0], p)
-        old = ssm_pool[layer]
-        y, new = ssm_decode(x, dt, A, Bm, Cm, D, old)
-        new_tail = padded[:, 1:].astype(conv_pool.dtype)
-        # (under the update's scope: XLA fuses the update into the pool's
-        # in-place write, and a trace names the fusion by its ROOT)
-        with jax.named_scope("ssm_decode"):
-            if write_ok is not None:
-                ok = write_ok.reshape(B)
-                new = jnp.where(ok[:, None, None, None], new, old)
-                new_tail = jnp.where(ok[:, None, None], new_tail, tail)
-            return (y.reshape(B, 1, -1),
-                    (conv_pool.at[layer].set(new_tail),
-                     ssm_pool.at[layer].set(new)))
+        return step(xbc, dt_raw, p, conv_pool, ssm_pool, layer, write_ok,
+                    s=cfg.ssm)
+    return recur
+
+
+def slot_state(conv_pool: jax.Array, ssm_pool: jax.Array, slot: jax.Array,
+               start: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """ONE slot's rows of the state pools in every ``M`` layer, read once
+    before a window's layers run: (conv tails [Lm, K-1, C], states
+    [Lm, nh, P, N] float32), taken as ZERO where the window starts its
+    sequence (``start`` [1] == 0: whatever a former occupant of the slot
+    left there is not read).
+
+    The conv tails are the sum over the slots of the pool masked to the
+    one slot: the same rows (every other term is 0). A SLICE of that pool
+    hands the layout its consumer likes (the tail's 3 columns on the
+    lanes) back to the whole pool inside the step loop: a copy of 14 MB
+    into 600 MB of padding a step at the hybrid cell's shapes (compiled
+    for a described v5e, PERF.md 6, PR 44; ``ops/kda.py slot_state`` found
+    the same)."""
+    fresh = start[0] == 0
+    mine = (jnp.arange(conv_pool.shape[1]) == slot)[:, None, None]
+    tails = jnp.sum(jnp.where(mine, conv_pool, 0), axis=1,
+                    dtype=conv_pool.dtype)
+    return (jnp.where(fresh, 0, tails),
+            jnp.where(fresh, 0.0, ssm_pool[:, slot].astype(jnp.float32)))
+
+
+def write_slot_state(conv_pool: jax.Array, ssm_pool: jax.Array,
+                     slot: jax.Array, tails: jax.Array, states: jax.Array,
+                     live) -> tuple[jax.Array, jax.Array]:
+    """The pools with ``slot``'s rows of every ``M`` layer overwritten by a
+    window's (conv tails [Lm, K-1, C], states [Lm, nh, P, N]): ONE write a
+    pool, after the window's last layer. ``live`` (bool []) False keeps
+    the rows as the pools hold them (a decode step that carries no piece
+    names slot 0). The conv tails go in by a select over the whole pool
+    (2 x 14 MB at the hybrid cell's shapes), for ``slot_state``'s
+    reason."""
+    mine = (jnp.arange(conv_pool.shape[1]) == slot)[:, None, None] & live
+    conv_pool = jnp.where(mine, tails.astype(conv_pool.dtype)[:, None],
+                          conv_pool)
+    states = jnp.where(live, states.astype(ssm_pool.dtype),
+                       ssm_pool[:, slot])
+    return conv_pool, ssm_pool.at[:, slot].set(states)
+
+
+def recur_chunk(cfg, tail: jax.Array, h0: jax.Array,
+                live: Optional[jax.Array] = None):
+    """``recur`` for a window of ONE slot's prompt behind tokens the slot
+    has run already (the piece a decode step carries, a chunk of a prompt:
+    the window is [1, T]): the conv from the slot's cached ``tail``
+    [K-1, C] and the chunked scan from the slot's cached state ``h0``
+    [nh, P, N] (``slot_state``'s rows of this layer). The state it returns
+    is (the conv tail [K-1, C], the state [nh, P, N] float32) after the
+    window's last live token (``live`` [1, T], a prefix; fewer than K-1
+    live tokens keep the last of ``tail``), which the caller writes back
+    (``write_slot_state``)."""
+    window = recur_window(cfg, live, tail[None], h0[None])
+
+    def recur(xbc, dt_raw, p):
+        if xbc.shape[0] != 1:
+            raise ValueError("a chunk is one slot's window: [1, T]")
+        y, (new_tail, h) = window(xbc, dt_raw, p)
+        return y, (new_tail[0], h[0])
     return recur

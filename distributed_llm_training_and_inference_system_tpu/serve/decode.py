@@ -29,7 +29,7 @@ from ..models.gpt import (
     unembed,
 )
 from ..models.layers import decoder_block, model_rope_frequencies
-from ..ops import kda
+from ..ops import kda, ssm as ssm_ops
 from ..ops.mla_paged_attention import mla_paged_attention
 from ..ops.paged_attention import (
     paged_attention_multi,
@@ -90,25 +90,34 @@ def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl, block=0):
 # (PERF.md 6, PR 36: the program's set-up).
 _shared_windows = jax.jit(_windows, static_argnames=("attn_impl",))
 _shared_sampler = jax.jit(sample_tokens)
-# ... and every ``K`` layer's one-token update of all slots over the state
-# pools, at whichever (traced) layer
-_shared_recur_step = jax.jit(kda.step_pools, static_argnames=("kd",))
+# ... and every recurrent layer's one-token update of all slots over the
+# state pools, at whichever (traced) layer, by the layer's kind
+_shared_recur_step = {
+    "K": jax.jit(kda.step_pools, static_argnames=("kd",)),
+    "M": jax.jit(ssm_ops.step_pools, static_argnames=("s",)),
+}
+# a recurrent kind's module: both have ``recur_step`` / ``step_pools`` (T = 1
+# over the pools), ``recur_chunk`` (a window of ONE slot from its state) and
+# ``slot_state`` / ``write_slot_state`` for their own pools' layouts
+_RECURRENT = {"K": kda, "M": ssm_ops}
 
 
 def can_carry(cfg: ModelConfig) -> bool:
     """Can a decode step of this model carry a ``Piece``? The uniform stack
-    and a layer table of ``D`` / ``E`` / ``*`` / ``K`` over one latent pool:
-    every sub-layer but ``attend`` and ``recur`` is per row, both kinds of
-    ``attend`` take a window of one slot, and a ``K`` layer runs one from
-    the slot's own state (ops/kda.py ``recur_chunk``). A state-space layer
-    (``M``) has no form that runs a chunk of ONE slot from its state: its
-    scan starts from zero (ops/ssm.py ``ssm_scan_prefill``)."""
+    and a layer table of ``D`` / ``E`` / ``*`` layers over K/V pages or one
+    latent pool: every sub-layer but ``attend`` and ``recur`` is per row,
+    and both kinds of ``attend`` take a window of one slot. A recurrent
+    layer runs a window of ONE slot from the slot's own state
+    (``recur_chunk`` of ops/ssm.py and ops/kda.py), in the combination that
+    is served and tested: state-space (``M``) layers beside K/V pages,
+    delta-rule (``K``) layers beside a latent pool."""
     if cfg.is_diffusion:
         # its step is a window of ``block_length`` rows a slot already, and
         # a ``Piece`` wants T == 1
         return False
-    return not cfg.layer_pattern or (cfg.is_latent
-                                     and "M" not in cfg.layer_pattern)
+    if "M" in cfg.layer_pattern:
+        return not cfg.is_latent
+    return "K" not in cfg.layer_pattern or cfg.is_latent
 
 
 def decode_step_forward(
@@ -193,14 +202,14 @@ def extend_step_forward(
     expert) and, last, the (layer, expert) pairs that got any. A model
     with state-space layers takes ``ssm_state`` and returns it LAST,
     advanced in place for the rows ``write_ok`` marks: its K/V pools hold
-    the attention layers alone ([La, NP, ...]) and it takes T = 1 only.
+    the attention layers alone ([La, NP, ...]).
     A model with ``K`` (delta-rule linear attention) layers takes the same
-    ``ssm_state`` (its pools under the same two names) and, besides T = 1
-    over every slot, a WINDOW of one slot (B = 1 with ``state_slot``: a
-    chunk of a prompt), which reads that slot's state and conv window,
-    runs the chunked form from them and writes them back (ops/kda.py
-    ``recur_chunk``; a window that starts its sequence, ``start_positions``
-    0, takes them as zero).
+    ``ssm_state`` (its pools under the same two names). Either kind takes,
+    besides T = 1 over every slot, a WINDOW of one slot (B = 1 with
+    ``state_slot``: a chunk of a prompt), which reads that slot's state and
+    conv window, runs the chunked form from them and writes them back
+    (``recur_chunk`` of ops/ssm.py and ops/kda.py; a window that starts its
+    sequence, ``start_positions`` 0, takes them as zero).
     A model with LATENT attention keeps ONE pool: ``k_pages`` is the latent
     pool [La, NP, 1, PS, W] and ``v_pages`` is None, handed through.
 
@@ -227,10 +236,12 @@ def extend_step_forward(
 
     ``ride`` (a ``Piece``; T = 1): the block runs ONCE over B + C flat
     rows, the B decode rows and the piece's C. Embedding, norms, matmuls,
-    router and experts are per row and see one batch; only ``attend``
-    tells the two apart: it writes and attends the B rows as it does
-    without a piece, then the piece as one window over its own slot's
-    pages (what a chunked prefill's program does), and joins the outputs.
+    router and experts are per row and see one batch; only ``attend`` and
+    ``recur`` tell the two apart: ``attend`` writes and attends the B rows
+    as it does without a piece, then the piece as one window over its own
+    slot's pages (what a chunked prefill's program does), and joins the
+    outputs; ``recur`` moves the B slots' states one token and runs the
+    piece as one window from its own slot's state (``recur_at``).
     The piece's padding (rows past ``ride.live``) writes the scratch page,
     reaches no expert and is not counted. The logits are then [B + 1, 1,
     V]: the B rows' and the piece's last live row's.
@@ -241,9 +252,9 @@ def extend_step_forward(
     live = write_ok           # [rows, T]: the rows that are tokens
     if ride is not None:
         if T != 1 or not can_carry(cfg):
-            raise ValueError("a piece rides a decode step (T = 1) of the "
-                             "uniform layer stack or of a latent layer "
-                             "table without state-space layers")
+            raise ValueError("a piece rides a decode step (T = 1) of a "
+                             "model whose layers can carry one "
+                             "(decode.can_carry)")
         offs = jnp.arange(ride.tokens.shape[0], dtype=jnp.int32)
         piece_ok = offs < ride.live
         tokens = jnp.concatenate([tokens, ride.tokens[:, None]])
@@ -343,38 +354,38 @@ def extend_step_forward(
         # a layer table: one parameter stack a kind, walked by a Python
         # loop; every pool (pages, conv tails, states) and every expert
         # stack stays whole and is addressed at its kind's layer index
-        from ..ops.ssm import recur_step
         if cfg.is_recurrent and ssm_state is None:
             raise ValueError(f"a model with {cfg.recurrent_name} needs its "
                              "ssm_state pools")
 
         def recur_at(kind, conv, ssm, i, piece):
-            if kind == "M":
-                return recur_step(cfg, conv, ssm, i, write_ok)
-            if kind != "K":
+            ops = _RECURRENT.get(kind)
+            if ops is None:
                 return None
             # the B rows one token a slot over the pools; a program that
             # rides calls ONE jitted form from both its bodies
-            step = kda.recur_step(
+            step = ops.recur_step(
                 cfg, conv, ssm, i, write_ok,
-                step=_shared_recur_step if two_bodies else kda.step_pools)
+                step=_shared_recur_step[kind] if two_bodies
+                else ops.step_pools)
             if piece is None:
                 return step
             # ONE slot's window [1, T] from that slot's own state: a chunk
             # of its prompt, or the piece a decode step carries
-            chunk = kda.recur_chunk(cfg, piece[0][i], piece[1][i], piece_rows)
+            chunk = ops.recur_chunk(cfg, piece[0][i], piece[1][i], piece_rows)
 
-            def recur(qkv, f, b, p):
-                # the state: (the pools, the window's (conv window, state)
+            def recur(*acts_and_layer):
+                # (``M``: xBC, dt; ``K``: qkv, f, b; then the layer.) The
+                # state: (the pools, the window's (conv window, state)
                 # after this layer)
+                *acts, p = acts_and_layer
                 if ride is None:        # the window is all the rows
-                    out, after = chunk(qkv, f, b, p)
+                    out, after = chunk(*acts, p)
                     return out, ((conv, ssm), after)
                 # (the piece's slot is not armed: ``step`` leaves its rows
                 # of the pools bit for bit)
-                out, pools = step(qkv[:B], f[:B], b[:B], p)
-                piece_out, after = chunk(
-                    *(a[B:, 0][None] for a in (qkv, f, b)), p)
+                out, pools = step(*(a[:B] for a in acts), p)
+                piece_out, after = chunk(*(a[B:, 0][None] for a in acts), p)
                 return (jnp.concatenate([out, piece_out[0][:, None]]),
                         (pools, after))
             return recur
@@ -394,22 +405,24 @@ def extend_step_forward(
             return attend_pages(kp, vp, li)
         conv, ssm = (ssm_state["conv"], ssm_state["ssm"]) \
             if ssm_state is not None else (None, None)
-        # a window of ONE slot through the ``K`` layers, a chunk of its
-        # prompt or the piece a decode step carries: the slot's rows of the
-        # pools are read here, once ((conv windows, states), stacked
-        # [Lk, ...]), ride the layer walk's carry, written at a layer's
-        # index (which a loop over the table's periodic part traces), and
-        # are written back after the last layer, once
+        # a window of ONE slot through the recurrent (``M`` or ``K``)
+        # layers, a chunk of its prompt or the piece a decode step carries:
+        # the slot's rows of the pools are read here, once ((conv windows,
+        # states), stacked [Lm | Lk, ...]), ride the layer walk's carry,
+        # written at a layer's index (which a loop over the table's
+        # periodic part traces), and are written back after the last
+        # layer, once
         piece = piece_slot = None
+        recurrent = _RECURRENT["K" if cfg.kda_layers else "M"]
         if state_slot is not None:
             piece_slot, piece_start, piece_rows, piece_live = (
                 state_slot, start_positions, write_ok, True)
-        elif ride is not None and cfg.kda_layers:
+        elif ride is not None and cfg.is_recurrent:
             piece_slot, piece_start, piece_rows, piece_live = (
                 ride.slot, ride.start[None], piece_ok[None],
                 ride.live > 0)      # False: a step that carries nothing
         if piece_slot is not None:
-            piece = kda.slot_state(conv, ssm, piece_slot, piece_start)
+            piece = recurrent.slot_state(conv, ssm, piece_slot, piece_start)
         stats = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
 
         def sub_layer(carry, kind, i):
@@ -421,7 +434,7 @@ def extend_step_forward(
                 recur=recur_at(kind, conv, ssm, i, piece))
             if kind == "*":
                 kp, vp = state
-            elif kind == "K" and piece is not None:
+            elif kind in "MK" and piece is not None:
                 (conv, ssm), after = state
                 piece = tuple(a.at[i].set(new.astype(a.dtype))
                               for a, new in zip(piece, after))
@@ -435,7 +448,8 @@ def extend_step_forward(
         # cell's executable grew from 57 to 148 MB and its first call from
         # 10.9 to 16.4 s of every start (PERF.md 6, PR 41; the linear
         # cell's from 54 to 125 MB, 95 under the loop: PR 43). Its bodies
-        # walk the table's periodic part (``*E`` x 6; ``KEKEKE*E`` x 2) by a
+        # walk the table's periodic part (``*E`` x 6; ``KEKEKE*E`` x 2;
+        # ``MEMEM*E`` x 2) by a
         # loop over traced layer indices, as the uniform stack's scan does;
         # the pools are the loop's carry, written in place. Any other
         # program walks it whole.
@@ -454,8 +468,8 @@ def extend_step_forward(
             carry = jax.lax.fori_loop(0, reps, period, carry)
         x, kp, vp, conv, ssm, stats, piece = carry
         if piece is not None:
-            conv, ssm = kda.write_slot_state(conv, ssm, piece_slot, *piece,
-                                             piece_live)
+            conv, ssm = recurrent.write_slot_state(
+                conv, ssm, piece_slot, *piece, piece_live)
         if cfg.hc_mult > 1:
             x = jnp.sum(x.astype(jnp.float32), axis=2).astype(compute_dtype)
         return (unembed(params, head_rows(x), cfg), kp, vp,

@@ -495,12 +495,16 @@ class InferenceEngine:
         kept (no snapshot of a state at a page boundary exists). Said once
         in the log and in ``stats()["ssm"]`` / ``["kda"]``.
 
-        Chunked prefill is refused for state-space (``M``) layers, whose
-        scan starts from a zero state alone, and CARRIED by ``K`` layers:
-        their chunk program reads the slot's state and conv window and
-        writes them back (ops/kda.py ``recur_chunk``), and a ``K`` model
-        with latent attention must chunk, since its cold program cannot
-        attend past ``LATENT_COLD_TOKENS``."""
+        Chunked prefill is CARRIED by ``K`` layers: their chunk program
+        reads the slot's state and conv window and writes them back
+        (ops/kda.py ``recur_chunk``), and a ``K`` model with latent
+        attention must chunk, since its cold program cannot attend past
+        ``LATENT_COLD_TOKENS``. For state-space (``M``) layers it stays
+        refused though the form exists (ops/ssm.py ``recur_chunk``, which a
+        decode step's riding piece runs, and ``extend_step_forward``'s
+        ``state_slot`` path takes): no cell and no engine test has run
+        their chunk PROGRAMS, and the cold program has no length it
+        cannot run (ROADMAP B8)."""
         asked = {
             "chunked_prefill_tokens": serve_cfg.chunked_prefill_tokens > 0
             and self.cfg.ssm_layers > 0,
@@ -607,9 +611,11 @@ class InferenceEngine:
     def _can_ride(self, pre_quantized: bool) -> bool:
         """Can this engine prefill a prompt inside its decode dispatches at
         all? Read off the configuration: layers whose step can carry a
-        piece (``decode.can_carry``: the uniform stack, a latent layer
-        table, with delta-rule layers or without; a state-space layer has
-        no chunk-from-state form), plain weights (the W4 / W8 kernels take
+        piece (``decode.can_carry``: the uniform stack, a layer table over
+        a latent pool, with delta-rule layers or without, or over K/V
+        pages, with state-space layers or without: a recurrent layer runs
+        the piece from its slot's own state; a model that generates by
+        diffusion does not), plain weights (the W4 / W8 kernels take
         at most 64 rows), one chip (tp forces gather attention), the
         continuous scheduler, and no speculation (its dispatch is another
         program)."""

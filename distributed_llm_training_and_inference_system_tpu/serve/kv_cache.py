@@ -425,8 +425,9 @@ class PagedKVCache:
         """Return ``slot``'s pages. Its rows of the state pools need no
         device work: the prefill that next arms the slot overwrites them
         with a state computed from zero (serve/engine.py ``_prefill_fn``;
-        a chunked prefill's first chunk takes the state as zero,
-        ops/kda.py ``recur_chunk``), so a reused slot starts from a zero
+        a chunked prefill's first chunk and a riding prompt's first piece
+        take the state as zero, ``slot_state`` of ops/kda.py and
+        ops/ssm.py), so a reused slot starts from a zero
         state whatever is left here."""
         for page in self._owned.pop(slot, []):
             self._drop_ref(page)

@@ -7,6 +7,8 @@ step and the longest operations' us a call (PERF.md 6, PR 36: what
         --rows 256 512 --no-dead-rows
     chiprun -- python experiments/ride_step_alone.py --models linear \
         --rows 256 --no-dead-rows --cached 0 4096 12288
+    chiprun -- python experiments/ride_step_alone.py --models hybrid \
+        --rows 128 --no-dead-rows --live 64 --cached 0 384
 
 The benchmark's riding configurations (``benchmark/configs``) at their
 cells' shapes, 8 steps a dispatch, weights made on the device.
@@ -24,7 +26,13 @@ pools of its 9 ``K`` layers (2.5 GB); 127 slots are resident at
 behind a document of ~8k tokens); slot 127's prompt rides behind
 ``--cached`` tokens of itself (0: its first piece starts from a zero
 state; 4,096 and 12,288: the middle and the end of a document, a window
-over 17 and 49 pages from the slot's own state). Cases: C = 0 (the program
+over 17 and 49 pages from the slot's own state).
+``nemotron-3-nano-30b-a3b-14l-ep2`` (PR 44): 64 slots over K/V pools of
+1,537 pages of 64 (its 2 attention layers) and the two state pools of its 6
+``M`` layers (0.82 GB); 63 slots are resident at ``reason-batch-128``'s
+lengths (a prompt and half a reply); slot 63's prompt rides behind
+``--cached`` tokens of itself (0: from a zero state; 384: its fourth piece,
+from the slot's own conv tail and state). Cases: C = 0 (the program
 without ``ride``: the parent's), and C = 64 / 128 / 256 rows with
 
 - ``0 live, branch``: no step carries a piece; each step BRANCHES to the
@@ -60,7 +68,8 @@ from benchmark import harness, trace_reduce
 PKG = "distributed_llm_training_and_inference_system_tpu"
 CONFIGS = {"dense": "mistral-7b-16l", "moe": "olmoe-1b-7b-10l",
            "latent": "xing4.0-29b-a4b-7l",
-           "linear": "kimi-linear-48b-a3b-12l-ep8"}
+           "linear": "kimi-linear-48b-a3b-12l-ep8",
+           "hybrid": "nemotron-3-nano-30b-a3b-14l-ep2"}
 K = 8
 REPS, BATCHES = 6, 4
 DOC_PAGES, DOCS = 48, 16    # the latent cell's resident documents
@@ -71,12 +80,16 @@ class Shape:
 
     def __init__(self, model: str, cached: int = 0):
         self.latent, self.linear = model == "latent", model == "linear"
+        self.hybrid = model == "hybrid"
         self.B, self.MAXP, self.PS, self.NP = (
             (64, 68, 256, 1307) if self.latent else
-            (128, 64, 256, 1525) if self.linear else (32, 32, 64, 715))
+            (128, 64, 256, 1525) if self.linear else
+            (64, 32, 64, 1537) if self.hybrid else (32, 32, 64, 715))
         self.rider = self.B - 1
+        # a recurrent model's rider starts behind so much of its own prompt
+        self.recurrent = self.linear or self.hybrid
         # where the rider's first piece starts: behind its cached document
-        # (the linear model's: behind so much of its own prompt)
+        # (a recurrent model's: behind so much of its own prompt)
         self.cached = DOC_PAGES * self.PS if self.latent else cached
 
 
@@ -117,6 +130,13 @@ def slots(sh: Shape, rng) -> dict:
             positions[slot] = at
         n = sh.cached // sh.PS + 4
         tables[sh.rider, :n] = take(n)
+    elif sh.hybrid:
+        for slot in range(sh.B - 1):
+            at = 150 + 9 * slot         # a prompt and part of a reply
+            n = at // sh.PS + 1
+            tables[slot, :n] = take(n)
+            positions[slot] = at
+        tables[sh.rider, :24] = take(24)
     else:
         for slot in range(sh.B - 1):
             n = 6 + (slot % 4 == 0)         # ~232 live pages, as batch-64
@@ -156,6 +176,24 @@ def program(cfg, C: int, branch: bool):
             ride=ride, ride_branch=branch)
         return toks, kp, vp, (rest[-1] if pools is not None else None)
     return jax.jit(step, donate_argnums=(1, 2, 3))
+
+
+def state_pools(cfg, sh: Shape):
+    """A recurrent model's conv windows (bfloat16) and states (float32,
+    small: a decayed state's size), as serve/kv_cache.py lays them out."""
+    if not sh.recurrent:
+        return None
+    if sh.linear:
+        kd, L = cfg.kda, cfg.kda_layers
+        conv = (L, kd.conv_kernel - 1, sh.B, kd.conv_channels)
+        state = (L, sh.B, kd.num_heads, kd.head_dim, kd.head_dim)
+    else:
+        s, L = cfg.ssm, cfg.ssm_layers
+        conv = (L, sh.B, s.conv_kernel - 1, s.conv_channels)
+        state = (L, sh.B, s.num_heads, s.head_dim, s.state_size)
+    return {"conv": jax.random.normal(jax.random.PRNGKey(2), conv,
+                                      jnp.bfloat16),
+            "ssm": 0.05 * jax.random.normal(jax.random.PRNGKey(3), state)}
 
 
 def scopes_of(text: str, linear_model: bool):
@@ -243,8 +281,8 @@ def main() -> int:
     ap.add_argument("--live", nargs="+", type=int, default=[],
                     help="also pieces of so many live rows (<= C)")
     ap.add_argument("--cached", nargs="+", type=int, default=[0],
-                    help="linear: the rider's tokens before its pieces, "
-                    "a case each (whole pages)")
+                    help="linear, hybrid: the rider's tokens before its "
+                    "pieces, a case each (whole pages)")
     ap.add_argument("--out", default="chiprun_out/ride_step_alone.json")
     args = ap.parse_args()
     device = jax.devices()[0]
@@ -267,30 +305,22 @@ def main() -> int:
                 (cfg.layers_of("*"), sh.NP, 1, sh.PS, cfg.mla.page_width),
                 jnp.bfloat16), None)
         else:
-            shape = (cfg.num_layers, sh.NP, cfg.num_kv_heads, sh.PS,
+            shape = (cfg.kv_layers, sh.NP, cfg.num_kv_heads, sh.PS,
                      cfg.head_dim)
             pools = tuple(jax.random.normal(key, shape, jnp.bfloat16)
                           for key in jax.random.split(jax.random.PRNGKey(1)))
-        kd = cfg.kda
-        # the K layers' conv windows (bfloat16) and states (float32, small:
-        # a decayed state's size), as serve/kv_cache.py lays them out
-        pools = (*pools, None if not sh.linear else {
-            "conv": jax.random.normal(
-                jax.random.PRNGKey(2), (cfg.kda_layers, kd.conv_kernel - 1,
-                                        sh.B, kd.conv_channels), jnp.bfloat16),
-            "ssm": 0.05 * jax.random.normal(
-                jax.random.PRNGKey(3), (cfg.kda_layers, sh.B, kd.num_heads,
-                                        kd.head_dim, kd.head_dim))})
+        pools = (*pools, state_pools(cfg, sh))
         rng = np.random.default_rng(0)
         cases = [("C=0", 0, 0, True, 0)]
         for C in args.rows:
             cases += [(f"C={C}, 0 live, branch", C, 0, True, 0),
                       (f"C={C}, 0 live, dead rows", C, 0, False, 0),
                       *[(f"C={C}, {n} live" + (
-                          f" behind {cached}" if sh.linear else ""),
+                          f" behind {cached}" if sh.recurrent else ""),
                          C, n, True, cached)
                         for n in [*args.live, C] if n <= C
-                        for cached in (args.cached if sh.linear else [0])]]
+                        for cached in (args.cached if sh.recurrent
+                                       else [0])]]
         programs = {}
         for label, C, live, branch, cached in cases:
             if args.no_dead_rows and not branch:

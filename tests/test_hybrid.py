@@ -229,6 +229,103 @@ def test_padding_let_into_the_state_is_seen(cfg, params):
     assert np.abs(np.asarray(tail - tail_all)).max() > 1e-2
 
 
+# -- a window from a state: the form a riding piece and a chunk run (PR 44) -----
+
+def _scan_inputs(cfg, rows, live, seed=0):
+    """``ssm_scan_prefill``'s arguments for a window of ``rows`` rows of
+    which the first ``live`` are tokens (``dt`` = 0 on the rest)."""
+    s = cfg.ssm
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (1, rows, s.num_heads)))
+    return dict(
+        x=jax.random.normal(k[0], (1, rows, s.num_heads, s.head_dim)),
+        dt=jnp.where(jnp.arange(rows)[None, :, None] < live, dt, 0.0),
+        A=-jnp.exp(jax.random.normal(k[2], (s.num_heads,))),
+        Bm=jax.random.normal(k[3], (1, rows, s.n_groups, s.state_size)),
+        Cm=jax.random.normal(k[4], (1, rows, s.n_groups, s.state_size)),
+        D=jax.random.normal(k[5], (s.num_heads,)))
+
+
+@pytest.mark.parametrize("at", [1, 2, 16, 17],
+                         ids=["1", "K-2", "one chunk", "one chunk + 1"])
+@pytest.mark.parametrize("live", [48, 41, 9], ids=["all", "padded", "short"])
+def test_the_scan_from_a_handed_state_is_the_whole_windows(cfg, at, live):
+    """``ssm_scan_prefill`` over a window of 48 rows (three chunks of 16)
+    split after ``at`` rows, the first part's state handed to the second
+    as ``h0``: the outputs and the final state of the whole window, where
+    the split falls inside a chunk, on a chunk's edge or one row past it,
+    and where the padding (``dt`` = 0 past ``live``) fills the second part
+    or reaches into the first. No ``h0`` is a zero ``h0``."""
+    assert cfg.ssm.conv_kernel - 2 == 2 and cfg.ssm.chunk_size == 16
+    a = _scan_inputs(cfg, 48, live, seed=at)
+    rows = {k: v for k, v in a.items() if v.ndim > 1}
+
+    def scan(lo, hi, h0=None):
+        return ssm.ssm_scan_prefill(
+            **{k: v[:, lo:hi] for k, v in rows.items()}, A=a["A"],
+            D=a["D"], chunk=16, h0=h0)
+    y, h = scan(0, 48)
+    y1, h1 = scan(0, at)
+    y2, h2 = scan(at, 48, h1)
+    assert h1.dtype == h2.dtype == jnp.float32
+    np.testing.assert_allclose(jnp.concatenate([y1, y2], axis=1), y,
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(h2, h, rtol=1e-5, atol=1e-6)
+    y0, h0 = scan(0, 48, jnp.zeros_like(h))
+    np.testing.assert_array_equal(y0, y)
+    np.testing.assert_array_equal(h0, h)
+    assert np.abs(np.asarray(h)).max() > 1e-3
+
+
+@pytest.mark.parametrize("n", [2 * 16 + 1, 2 * 16 + 2, 16 + 5, 3 * 16, 7],
+                         ids=["a last piece of 1 row", "of 2 rows",
+                              "of 5 rows", "whole pieces", "one short piece"])
+def test_pieces_from_the_slots_state_are_the_whole_prompt(cfg, params, n):
+    """A prompt of ``n`` tokens through one state-space layer as pieces of
+    16 rows (garbage past a piece's live rows), each ``recur_chunk`` from
+    what ``slot_state`` reads of slot 1 and written back by
+    ``write_slot_state``, in pools that hold a former occupant's rows in
+    every slot: the live rows' outputs, the slot's final conv tail (a last
+    piece of fewer than K-1 rows keeps columns of the piece before) and its
+    state are ``recur_window``'s over the whole prompt; the first piece
+    read nothing of the former occupant; the other slots' rows stay bit
+    for bit."""
+    C, s = 16, cfg.ssm
+    bucket = -(-n // C) * C
+    xbc, dt, layer = _mixer_inputs(cfg, params, bucket, seed=n)
+    y, (tail, h) = ssm.recur_window(
+        cfg, jnp.arange(bucket)[None] < n)(xbc, dt, layer)
+    rng = np.random.default_rng(n)
+    conv0 = jnp.asarray(rng.normal(size=(1, 3, s.conv_kernel - 1,
+                                         s.conv_channels)), jnp.float32)
+    ssm0 = jnp.asarray(rng.normal(size=(1, 3, s.num_heads, s.head_dim,
+                                        s.state_size)), jnp.float32)
+    conv, pool, slot = conv0, ssm0, jnp.int32(1)
+    for start in range(0, n, C):
+        live = min(C, n - start)
+        garbage = jnp.arange(C)[None, :, None] >= live
+        tails, states = ssm.slot_state(conv, pool, slot,
+                                       jnp.asarray([start]))
+        assert states.dtype == jnp.float32
+        out, after = ssm.recur_chunk(
+            cfg, tails[0], states[0], jnp.arange(C)[None] < live)(
+                jnp.where(garbage, 9.0, xbc[:, start:start + C]),
+                jnp.where(garbage, 9.0, dt[:, start:start + C]), layer)
+        np.testing.assert_allclose(out[:, :live], y[:, start:start + live],
+                                   rtol=1e-5, atol=1e-5)
+        conv, pool = ssm.write_slot_state(
+            conv, pool, slot, after[0][None], after[1][None], True)
+    np.testing.assert_array_equal(conv[0, 1], tail[0])
+    np.testing.assert_allclose(pool[0, 1], h[0], rtol=1e-5, atol=1e-6)
+    for got, was in ((conv, conv0), (pool, ssm0)):
+        np.testing.assert_array_equal(got[:, [0, 2]], was[:, [0, 2]])
+    # a step that carries nothing (``live`` False) writes nothing
+    kept = ssm.write_slot_state(conv, pool, slot, after[0][None] + 1,
+                                after[1][None] + 1, False)
+    np.testing.assert_array_equal(kept[0], conv)
+    np.testing.assert_array_equal(kept[1], pool)
+
+
 # -- prefill, then decode, through the pools -------------------------------------
 
 def _cold_prefill(cfg, params, tokens, bucket, kp, vp, state, pages, slot):

@@ -4,13 +4,16 @@ step, and no prefill program runs between two dispatches. What must hold:
 the tokens a riding prompt is served and the K/V (or latent rows) its pages
 hold are the cold program's, and behind a prefix hit the suffix program's;
 under half occupancy nothing rides; a riding prompt that is cancelled,
-preempted or failed gives its pages and slot back; and a model that cannot
-ride (a layer table with state-space layers) keeps the parent's decode
+preempted or failed gives its pages and slot back; and an engine that does
+not ride (the hybrid under the static scheduler) keeps the parent's decode
 program.
 
-CPU, float32, the dense, the MoE, (PR 41) the latent and (PR 43) the
-delta-rule test configurations; 4 slots, pages of 8 tokens, 4 steps a
-dispatch, so a piece is 16 rows. The delta-rule (``K``) layers' chunk is 8
+CPU, float32, the dense, the MoE, (PR 41) the latent, (PR 43) the
+delta-rule and (PR 44) the hybrid state-space test configurations; 4 slots,
+pages of 8 tokens, 4 steps a dispatch, so a piece is 16 rows: ONE chunk of
+the state-space (``M``) layers' scan (``nemotron-h-test``'s ``chunk_size``
+is 16), as the hybrid cell's 128 rows are one chunk of its 128; its riding
+pieces are held to the COLD programs. The delta-rule (``K``) layers' chunk is 8
 here (``ops/kda.py CHUNK`` is 64), so a piece is two sub-chunks with the
 state carried between them, and its engines prefill a prompt over 16 tokens
 chunk by chunk: a riding piece is held to the CHUNK programs, which read
@@ -37,14 +40,15 @@ from distributed_llm_training_and_inference_system_tpu.serve import (
     SamplingParams,
 )
 from distributed_llm_training_and_inference_system_tpu.serve.decode import (
-    PIECE_META, decode_scan, extend_step_forward)
+    PIECE_META, can_carry, decode_scan, extend_step_forward)
 from distributed_llm_training_and_inference_system_tpu.serve.scheduler import (
     RequestState)
 
 PS, STEPS, SLOTS = 8, 4, 4
 C = InferenceEngine.RIDE_PAGES * PS
 LINEAR = "kimi-linear-test"
-MODELS = ["gpt-test", "olmoe-test", "xing-test", LINEAR]
+HYBRID = "nemotron-h-test"
+MODELS = ["gpt-test", "olmoe-test", "xing-test", LINEAR, HYBRID]
 RNG = np.random.default_rng(36)
 
 
@@ -78,8 +82,8 @@ SAMPLING = {
 }
 
 
-def _engine(name, **over):
-    cfg = get_model_config(name)
+def _engine(name, cfg=None, **over):
+    cfg = cfg or get_model_config(name)
     opts = dict(model=name, max_batch_size=SLOTS, max_seq_len=192,
                 prefill_chunk=32, kv_block_size=PS, dtype="float32",
                 decode_steps_per_dispatch=STEPS)
@@ -419,17 +423,19 @@ def test_the_carry_is_read_off_the_page_size(name, page, rows):
     assert pieces.shape == (STEPS, PIECE_META + rows)
 
 
-@pytest.mark.parametrize("name", ["nemotron-h-test"])
-def test_a_layer_table_model_keeps_the_parents_decode_program(name):
-    """A hybrid configuration (state-space layers in the table) does not
-    ride: the engine hands its decode program no pieces, and the program
-    lowers to the text of the parent's ``_decode_impl_n`` (written out
-    below as it stood)."""
+@pytest.mark.parametrize("name,over", [
+    (HYBRID, dict(scheduler="static"))], ids=["hybrid, static scheduler"])
+def test_a_layer_table_model_keeps_the_parents_decode_program(name, over):
+    """A hybrid configuration (state-space layers in the table) rides since
+    PR 44; where its engine does not (the static scheduler: ``_can_ride``)
+    it hands its decode program no pieces, and the program lowers to the
+    text of the parent's ``_decode_impl_n`` (written out below as it
+    stood): the table walked whole, ``recur_step`` unjitted."""
     cfg = get_model_config(name)
     eng = InferenceEngine(
         cfg, ServeConfig(model=name, max_batch_size=SLOTS, max_seq_len=128,
                          dtype="float32", kv_block_size=PS, prefill_chunk=16,
-                         decode_steps_per_dispatch=STEPS),
+                         decode_steps_per_dispatch=STEPS, **over),
         params=init(cfg, jax.random.PRNGKey(0)))
     assert eng._ride_rows == 0
     args = (eng.params, eng.kv.k_pages, eng.kv.v_pages,
@@ -459,6 +465,9 @@ PARENTS_DECODE = {
     "olmoe-test": "5df605b7bab9c6587fab1cb6630d94ab3ed668636ad61597cbf67801904c4270",
     "xing-test": "eb6eb32b4808957f7c034acfa046e0237be0c9751d9d9e7dadda8832c98f193a",
     "sdar-test": "0a8f164867771f152023bfd616bd6b5e742f5f012fc856f0418430f415fb37c5",
+    # (since PR 44, whose parent is 6468bc5: the delta-rule model's riding
+    # program, which PR 43 wrote)
+    LINEAR: "8032e519168cd6a754d641e8efae7ebd8eb2f6f44fba5dfddfcb02b57586bab3",
 }
 
 
@@ -469,7 +478,9 @@ def test_the_other_models_decode_programs_are_the_parents(name):
     and takes (``_decode_tail_args``, ``_submit_decode``): the RIDING
     programs of the uniform stack (dense, MoE) and of the latent table, and
     the diffusion model's denoise program, lower byte for byte to the
-    parent's text all the same."""
+    parent's text all the same. PR 44 taught ``recur_at`` the ``M`` kind by
+    the same seam (one ``recur`` for both kinds' windows): the delta-rule
+    model's riding program lowers to ITS parent's text too."""
     import hashlib
     eng = _engine(name)
     assert eng._ride_rows == (0 if name == "sdar-test" else C)
@@ -534,6 +545,42 @@ def _linear_prefill_texts():
     }
 
 
+# sha256 of the StableHLO of ``nemotron-h-test``'s COLD prefill program
+# (bucket 32) as the PARENT of PR 44 (6468bc5) lowers it for the engine of
+# ``_hybrid_cold_prefill_text``: ``ssm_scan_prefill`` took an ``h0`` and
+# ``recur_window`` a tail and a state in PR 44, and a caller that passes
+# none lowers to the text it lowered to
+HYBRID_COLD_PREFILL = "915412930ee071bfcdd479c0358ba91d6d85058e9bc7ba8c6795babfcd3ac1ab"
+
+
+def _hybrid_cold_prefill_text():
+    from distributed_llm_training_and_inference_system_tpu.serve.sampling import (
+        seed_key_data)
+    eng = _engine(HYBRID)
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    def vec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+    params, kp, vp = shapes((eng.params, eng.kv.k_pages, eng.kv.v_pages))
+    sampling = shapes(eng._sampling_args(seed_key_data(0), 0,
+                                         SamplingParams()))
+    bucket = 32
+    return eng._prefill_fn(bucket).lower(
+        params, vec(1, bucket), vec(1), kp, vp, vec(bucket // PS),
+        *sampling, shapes(eng.kv.state), vec()).as_text()
+
+
+def test_the_hybrid_models_cold_prefill_program_is_the_parents():
+    """The hybrid rides since PR 44; under the gate its cold programs run
+    as before, and lower byte for byte to the parent's text."""
+    import hashlib
+    assert hashlib.sha256(_hybrid_cold_prefill_text().encode()
+                          ).hexdigest() == HYBRID_COLD_PREFILL
+
+
 def test_the_linear_models_prefill_programs_are_pinned():
     """The delta-rule model rides since PR 43 (its decode program carries
     pieces); under the gate its cold, chunk and final-chunk programs still
@@ -550,26 +597,41 @@ def _pools(cfg, pages, fill=None):
     model's ONE pool of padded rows and None."""
     shape = ((cfg.num_layers, pages, 1, PS, cfg.mla.page_width)
              if cfg.is_latent else
-             (cfg.num_layers, pages, cfg.num_kv_heads, PS, cfg.head_dim))
+             (cfg.kv_layers, pages, cfg.num_kv_heads, PS, cfg.head_dim))
     pool = jnp.asarray(np.zeros(shape) if fill is None else fill(size=shape),
                        jnp.float32)
     return pool, (None if cfg.is_latent else pool + 1)
 
 
 def _state(cfg, slots, fill=None):
-    """{"ssm_state": the ``K`` layers' pools} (zeros or ``fill``ed, the
-    states small as a decayed state is), {} for a model without them."""
-    if not cfg.kda_layers:
+    """{"ssm_state": the ``K`` or ``M`` layers' pools} (zeros or ``fill``ed,
+    the states small as a decayed state is), {} for a model without them."""
+    if not cfg.is_recurrent:
         return {}
-    k = cfg.kda
-    shapes = {"conv": (cfg.kda_layers, k.conv_kernel - 1, slots,
-                       k.conv_channels),
-              "ssm": (cfg.kda_layers, slots, k.num_heads, k.head_dim,
-                      k.head_dim)}
+    if cfg.kda_layers:
+        k = cfg.kda
+        shapes = {"conv": (cfg.kda_layers, k.conv_kernel - 1, slots,
+                           k.conv_channels),
+                  "ssm": (cfg.kda_layers, slots, k.num_heads, k.head_dim,
+                          k.head_dim)}
+    else:
+        m = cfg.ssm
+        shapes = {"conv": (cfg.ssm_layers, slots, m.conv_kernel - 1,
+                           m.conv_channels),
+                  "ssm": (cfg.ssm_layers, slots, m.num_heads, m.head_dim,
+                          m.state_size)}
     return {"ssm_state": {
         name: jnp.asarray(np.zeros(shape) if fill is None
                           else 0.1 * fill(size=shape), jnp.float32)
         for name, shape in shapes.items()}}
+
+
+def _slot_rows(cfg, name, slot):
+    """The index of ``slot``'s rows in the state pool ``name``: a ``K``
+    model's conv pool lies [L, K-1, slot, C], every other [L, slot, ...]."""
+    if name == "conv" and cfg.kda_layers:
+        return (slice(None), slice(None), slot)
+    return (slice(None), slot)
 
 
 def _same(a, b):
@@ -605,12 +667,12 @@ def test_a_step_without_a_piece_samples_what_the_plain_step_samples(name):
     np.testing.assert_array_equal(firsts, 0)
     _same(plain, rode)
     for name_, pool in state.get("ssm_state", {}).items():
-        idle = (slice(None), 2) if name_ == "ssm" else (
-            slice(None), slice(None), 2)
+        idle = _slot_rows(cfg, name_, 2)
         np.testing.assert_array_equal(rode[-1][name_][idle], pool[idle])
 
 
-@pytest.mark.parametrize("name", MODELS + ["the linear cell's table"])
+@pytest.mark.parametrize("name", MODELS + ["the linear cell's table",
+                                            "the hybrid cell's table"])
 def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
     """A program that rides holds a carrying and a plain step body, and
     every start pays for both (trace, lowering, the compile cache's read:
@@ -622,6 +684,7 @@ def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
     bodies) and the piece's T = C."""
     import re
     cfg = (get_model_config(name) if name in MODELS
+           else _hybrid_cfg(HYBRID_CELLS_TABLE) if "hybrid" in name
            else _linear_cfg(CELLS_TABLE))
     params = init(cfg, jax.random.PRNGKey(0))
     B, pages = 3, 9
@@ -646,27 +709,33 @@ def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
         return sorted((len(re.findall(rf"call @{name}\(", rides))
                        for name in names), reverse=True)
     assert functions("sample_tokens") == [2]
-    if cfg.is_latent:
+    if cfg.layer_pattern:
         # a call site a layer of the table's head and ONE for the loop over
         # its periodic part (``*D`` then ``*E`` x 2: two sites, not three;
-        # the linear TEST table is all head, the linear cell's is
-        # ``KDKEKE*E`` then ``KEKEKE*E`` x 2)
+        # the linear and the hybrid TEST tables are all head, the linear
+        # cell's is ``KDKEKE*E`` then ``KEKEKE*E`` x 2, the hybrid cell's
+        # ``MEMEM*E`` x 2)
         head, unit, reps = table_period(cfg)
-        assert reps == (0 if name == LINEAR else 2)
+        assert reps == (0 if name in (LINEAR, HYBRID) else 2)
         assert len(head) + reps * len(unit) == cfg.num_layers
         sites = collections.Counter(kind for kind, _ in head + unit)
-        assert functions("_latent_windows") == [2 * sites["*"], sites["*"]]
-        # every ``K`` layer's one-token update of all slots is ONE function,
-        # called from both bodies at its (traced) layer
+        if cfg.is_latent:
+            assert functions("_latent_windows") == [2 * sites["*"],
+                                                    sites["*"]]
+        else:   # K/V pages: the slots' T = 1 windows, from both bodies
+            assert functions("_windows") == [2 * sites["*"]]
+        # every recurrent layer's one-token update of all slots is ONE
+        # function, called from both bodies at its (traced) layer
+        recurrent = sites["K"] + sites["M"]
         assert functions("step_pools") == (
-            [2 * sites["K"]] if sites["K"] else [])
+            [2 * recurrent] if recurrent else [])
         # and the ONE sampler takes whole tiles of 8 rows (B + 1 = 4 here)
         assert re.search(r"func\.func private @sample_tokens\("
                          rf"%arg0: tensor<8x{cfg.vocab_size}xf32>", rides)
     else:
         assert functions("_windows") == [2]
-    # (the chunked delta rule of a piece is text the plain program lacks)
-    assert len(rides) < (3 if cfg.kda_layers else 2) * len(plain)
+    # (the chunked form of a piece is text the plain program lacks)
+    assert len(rides) < (3 if cfg.is_recurrent else 2) * len(plain)
 
 
 @pytest.mark.parametrize("pattern,head,unit,reps", [
@@ -680,6 +749,7 @@ def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
     ("KDKEKE*EKEKEKE*EKEKEKE*E", "KDKEKE*E", "KEKEKE*E", 2),
     ("KDKE*EKE*E", "KD", "KE*E", 2),
     ("*EEE*EEE*EEE", "", "*EEE", 3),
+    ("MEMEM*EMEMEM*E", "", "MEMEM*E", 2),  # the hybrid cell's 14-layer table
 ])
 def test_a_tables_periodic_part(pattern, head, unit, reps):
     """``gpt.table_period``: the shortest head, then the unit that repeats
@@ -722,12 +792,17 @@ def test_the_running_count_of_expert_choices_is_the_cumsum(rows):
 CELLS_TABLE = "KDKEKE*EKEKEKE*EKEKEKE*E"
 
 
-def _linear_cfg(table=None):
-    """The linear test model, or it with the layer table ``table``."""
+def _table_cfg(name, table=None):
+    """A test model, or it with the layer table ``table``."""
     import dataclasses
-    cfg = get_model_config(LINEAR)
+    cfg = get_model_config(name)
     return cfg if table is None else dataclasses.replace(
         cfg, layer_pattern=table, num_layers=len(table))
+
+
+def _linear_cfg(table=None):
+    """The linear test model, or it with the layer table ``table``."""
+    return _table_cfg(LINEAR, table)
 
 
 def _linear_step_case(live, start, table=None):
@@ -870,6 +945,212 @@ def test_a_slot_is_reused_after_a_riding_document_was_dropped(
     _idle(eng)
     prompts = [_tokens(2 * C + 3), _tokens(C + 1), _tokens(3 * C)]
     tag = f"-after-{how}"
+    # two residents and three riders: every slot is taken, ``slot`` too
+    got = _serve(eng, prompts, SAMPLING["greedy"], tag=tag)
+    want = _serve(cold, prompts, SAMPLING["greedy"], residents=0, tag=tag)
+    for a, b in zip(got, want):
+        assert a.state is RequestState.FINISHED
+        assert a.generated_tokens == b.generated_tokens
+    _idle(eng)
+    _idle(cold)
+
+
+# -- a state-space (``M``) model's piece: one chunk of the scan from its slot's
+# -- own conv tail and state (PR 44) ------------------------------------------
+
+# the hybrid cell's table: two motifs, so a riding program walks ``MEMEM*E``
+# x 2 by a loop (the test model's one motif is all head)
+HYBRID_CELLS_TABLE = "MEMEM*EMEMEM*E"
+
+
+def _hybrid_cfg(table=None):
+    """The hybrid test model, or it with the layer table ``table``."""
+    return _table_cfg(HYBRID, table)
+
+
+@pytest.mark.parametrize("name,table,carries", [
+    ("gpt-test", None, True), ("olmoe-test", None, True),
+    ("xing-test", None, True), (LINEAR, None, True), (HYBRID, None, True),
+    (HYBRID, HYBRID_CELLS_TABLE, True), (LINEAR, CELLS_TABLE, True),
+    (HYBRID, "*E*E*E*", True),          # no recurrent layer, K/V pages
+    ("sdar-test", None, False),         # a window of 4 rows a slot already
+    (HYBRID, "KEKEK*E", False),         # delta-rule layers beside K/V pages
+    ("xing-test", "*DME*E", False),     # state-space layers, a latent pool
+], ids=lambda v: str(v))
+def test_which_layer_tables_a_decode_step_can_carry_a_piece_through(
+        name, table, carries):
+    """``can_carry`` reads the layer kinds and the kind of page pool: the
+    uniform stack and every table that is served ride; diffusion and the
+    two combinations no program has run do not."""
+    assert can_carry(_table_cfg(name, table)) is carries
+
+
+def _hybrid_step_case(n, table=None):
+    """Four slots of the hybrid test model (or of it with ``table``): slots
+    0 and 1 decode, slot 2's prompt of ``n`` tokens rides from position 0,
+    a piece a step (pages 5..), slot 3 is idle. The pools are random (the
+    states small), so every slot has a former occupant's state; (cfg,
+    params, decode_scan's arguments, the state pools, the pieces, the
+    prompt)."""
+    cfg = _hybrid_cfg(table)
+    params = init(cfg, jax.random.PRNGKey(0))
+    B, pages = 4, 14
+    tables = np.zeros((B, 8), np.int32)
+    tables[0, :2], tables[1, :2], tables[2] = (1, 2), (3, 4), range(5, 13)
+    tables = jnp.asarray(tables)
+    args = (jnp.asarray([5, 6, 7, 8], jnp.int32), jnp.asarray([3, 9, 0, 0]),
+            *_pools(cfg, pages, RNG.normal), tables,
+            jnp.asarray([16, 16, 0, 0]),
+            jnp.zeros((B, 2), jnp.uint32), jnp.zeros(B, jnp.float32),
+            jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32))
+    prompt = RNG.integers(1, 250, n)
+    pieces = RNG.integers(1, 250, (STEPS, PIECE_META + C))  # padding: garbage
+    pieces[:, :PIECE_META] = 0
+    for k, start in enumerate(range(0, n, C)):
+        live = min(C, n - start)
+        pieces[k, :PIECE_META] = (2, start, live, 0)
+        pieces[k, PIECE_META:PIECE_META + live] = prompt[start:start + live]
+    return (cfg, params, args, _state(cfg, B, RNG.normal)["ssm_state"],
+            pieces.astype(np.int32), prompt)
+
+
+@functools.cache
+def _hybrid_programs(table):
+    """(``decode_scan``'s final carry, the cold program's K/V and state) of
+    the hybrid test model with ``table``, jitted ONCE for the cases below
+    (a piece's slot, start and live rows are values, not shapes)."""
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    cfg = _hybrid_cfg(table)
+
+    def dispatch(params, args, state, ride=None):
+        return decode_scan(params, *args, cfg, STEPS, attn_impl="gather",
+                           ssm_state=state, ride=ride)[0]
+
+    def cold(params, tokens, n):
+        bucket = tokens.shape[1]
+        live = (jnp.arange(bucket)[None] < n).astype(jnp.int32)
+        _, (kd, vd), (tails, hs) = gpt.forward(
+            params, tokens, cfg,
+            kv_cache=gpt.init_kv_cache(cfg, 1, bucket, dtype=jnp.float32),
+            cache_offset=jnp.zeros((1,), jnp.int32), segment_ids=live,
+            return_ssm_state=True)
+        return kd[:, 0], vd[:, 0], tails[:, 0], hs[:, 0]
+    return jax.jit(dispatch), jax.jit(cold)
+
+
+@pytest.mark.parametrize("table", [None, HYBRID_CELLS_TABLE],
+                         ids=["walked whole", "walked by a loop"])
+@pytest.mark.parametrize("n", [5, C, C + 1, 2 * C + 2, 3 * C - 5, 4 * C],
+                         ids=["a short piece", "one piece",
+                              "a last piece of 1 row", "of 2 rows",
+                              "three pieces", "a piece every step"])
+def test_pieces_leave_the_pools_the_cold_program_leaves(n, table):
+    """A decode dispatch whose first steps carry a prompt's pieces (the
+    first from ZERO whatever the slot held, each next from what the one
+    before wrote into the float32 pool), against the COLD program over the
+    whole prompt: in both state pools the prompt's slot's rows are the
+    cold program's conv tail and state (a last piece of fewer than K-1
+    rows keeps columns of the piece before), its pages hold the cold
+    program's K and V, the decoding slots' rows are the plain dispatch's,
+    and the idle slot's rows are as they were, bit for bit. (The riding
+    program of the cell's table walks its two motifs by a loop, the
+    pieces' rows in the loop's carry; the cold program walks it whole.)"""
+    cfg, params, args, state, pieces, prompt = _hybrid_step_case(n, table)
+    assert table_period(cfg)[2] == (2 if table else 0)
+    dispatch, cold = _hybrid_programs(table)
+    rode = dispatch(params, args, state, jnp.asarray(pieces))
+    plain = dispatch(params, args, state)
+    padded = np.full((1, 4 * C), 7, np.int32)
+    padded[0, :n] = prompt
+    kd, vd, tails, hs = cold(params, jnp.asarray(padded), n)
+    for got, want in ((rode[2], kd), (rode[3], vd)):
+        rows = np.asarray(got)[:, 5:13].transpose(0, 1, 3, 2, 4).reshape(
+            got.shape[0], -1, *want.shape[2:])
+        np.testing.assert_allclose(rows[:, :n], want[:, :n], rtol=2e-4,
+                                   atol=2e-5)
+    for name, want in (("conv", tails), ("ssm", hs)):
+        got, was = np.asarray(rode[-1][name]), np.asarray(state[name])
+        np.testing.assert_allclose(got[:, 2], want, rtol=2e-4, atol=2e-5)
+        assert not np.allclose(got[:, 2], was[:, 2])
+        np.testing.assert_allclose(got[:, :2], np.asarray(
+            plain[-1][name])[:, :2], rtol=2e-4, atol=2e-5)
+        assert not np.allclose(got[:, :2], was[:, :2])
+        np.testing.assert_array_equal(got[:, 3], was[:, 3])
+    # ... and read nothing of the former occupant's
+    fresh = {k: v.at[:, 2].set(7.0) for k, v in state.items()}
+    again = dispatch(params, args, fresh, jnp.asarray(pieces))
+    _same(again[-1], rode[-1])
+
+
+@pytest.fixture(scope="module")
+def hybrid_cell_engines():
+    """(riding, cold) engines of the hybrid test model with the CELL's
+    two-motif table: the riding program walks it by a loop."""
+    cfg = _hybrid_cfg(HYBRID_CELLS_TABLE)
+    return _engine(HYBRID, cfg), _engine(HYBRID, cfg)
+
+
+def test_prompts_of_one_two_and_four_pieces_ride_to_the_cold_tokens(
+        hybrid_cell_engines):
+    """Prompts of 1, 2 and 4 pieces (the last of each shorter than a
+    piece; the third's last of ONE row) through the two-motif table, in an
+    engine whose every slot holds a former occupant's state and conv tail:
+    the greedy tokens of the cold programs on a clean engine, and the
+    pieces behind a prompt's first counted as reading their slot's
+    state."""
+    eng, cold = hybrid_cell_engines
+    eng.kv.state = {name: jnp.asarray(RNG.normal(size=pool.shape) * 0.3,
+                                      pool.dtype)
+                    for name, pool in eng.kv.state.items()}
+    prompts = [_tokens(C - 3), _tokens(2 * C - 5), _tokens(3 * C + 1)]
+    before = eng.stats()
+    got = _serve(eng, prompts, SAMPLING["greedy"], tag="-pieces")
+    want = _serve(cold, prompts, SAMPLING["greedy"], residents=0,
+                  tag="-pieces")
+    after = eng.stats()
+    for a, b in zip(got, want):
+        assert a.state is RequestState.FINISHED
+        assert a.generated_tokens == b.generated_tokens
+    assert (after["prefill_ride_tokens"] - before["prefill_ride_tokens"]
+            == sum(map(len, prompts)))
+    assert after["prefill_ride_steps"] - before["prefill_ride_steps"] == 7
+    # the second piece of the second prompt, three of the third
+    assert (after["state_carry_chunks"] - before["state_carry_chunks"],
+            after["state_carry_tokens"] - before["state_carry_tokens"]
+            ) == (4, (C - 5) + (2 * C + 1))
+    assert after["ssm"]["state_carry_tokens"] == after["state_carry_tokens"]
+    # ``slot_steps`` counts the slots' one-token updates alone
+    assert (after["ssm"]["slot_steps"] - before["ssm"]["slot_steps"]
+            <= (after["decode_steps"] - before["decode_steps"]) * SLOTS)
+    assert cold.stats()["state_carry_chunks"] == 0      # cold programs alone
+    _idle(eng)
+    _idle(cold)
+
+
+@pytest.mark.parametrize("how", ["cancel", "preempt", "fail_all"])
+def test_a_slot_is_reused_after_a_riding_hybrid_prompt_was_dropped(
+        hybrid_cell_engines, how):
+    """A riding prompt dropped in its middle leaves a half-written state in
+    its slot, and pieces of it still in flight; the next prompts (one of
+    them takes that slot) ride from zero and decode as on a fresh engine
+    (the cold programs' tokens)."""
+    eng, cold = hybrid_cell_engines
+    req = _start_riding(eng, _tokens(STEPS * C + 20), f"dropped-h-{how}")
+    slot = req.slot
+    with eng.lock:
+        if how == "cancel":
+            assert eng.scheduler.cancel(req.request_id)
+        elif how == "preempt":
+            eng._preempt(slot)
+            assert eng.scheduler.cancel(req.request_id)
+    if how == "fail_all":
+        eng.fail_all("boom")
+    eng.run_until_idle()
+    assert req.state in (RequestState.CANCELLED, RequestState.FAILED)
+    assert np.abs(np.asarray(eng.kv.state["ssm"][:, slot])).max() > 0
+    _idle(eng)
+    prompts = [_tokens(2 * C + 3), _tokens(C + 1), _tokens(3 * C)]
+    tag = f"-after-h-{how}"
     # two residents and three riders: every slot is taken, ``slot`` too
     got = _serve(eng, prompts, SAMPLING["greedy"], tag=tag)
     want = _serve(cold, prompts, SAMPLING["greedy"], residents=0, tag=tag)
