@@ -489,6 +489,20 @@ def write_slot_state(conv_pool: jax.Array, state_pool: jax.Array,
     return conv_pool, state_pool.at[:, slot].set(states)
 
 
+def arm_slot_state(conv_pool: jax.Array, state_pool: jax.Array,
+                   slot: jax.Array, tails: jax.Array, states: jax.Array
+                   ) -> tuple[jax.Array, jax.Array]:
+    """The pools with ``slot``'s rows of every ``K`` layer SET to a cold
+    prefill's (conv windows [Lk, 1, K-1, C], states [Lk, 1, nh, dk, dv],
+    from a zero state), whatever a former occupant left there; the conv
+    pool lies [Lk, K-1, slot, C]. A set at the slot, where
+    ``write_slot_state`` selects over the whole pool: a cold program
+    carries the pools through no loop."""
+    return (conv_pool.at[:, :, slot].set(tails[:, 0].astype(conv_pool.dtype)),
+            state_pool.at[:, slot].set(
+                states[:, 0].astype(state_pool.dtype)))
+
+
 def recur_chunk(cfg, tail: jax.Array, S0: jax.Array,
                 live: Optional[jax.Array] = None):
     """``recur`` for a window of ONE slot's prompt (chunked prefill: the

@@ -73,11 +73,11 @@ class QuantPages(QuantTensor):
     saved on the page data — 2x KV capacity per HBM byte and half the
     decode-attention KV streaming).
 
-    Scale layout (round 6): one dense PER-PAGE tensor of row scales with
-    NO trailing singleton. The pre-round-6 [..., PS, 1] layout made the
-    Pallas scale block a [Nkv, PS, 1] ref — a degenerate 1-wide lane tile
-    Mosaic pads to a full [8, 128] vector register per scale — and every
-    whole-page merge had to carry the dangling axis. [..., Nkv, PS] makes
+    Scale layout: one dense PER-PAGE tensor of row scales with NO
+    trailing singleton. A [..., PS, 1] layout makes the Pallas scale block
+    a [Nkv, PS, 1] ref — a degenerate 1-wide lane tile Mosaic pads to a
+    full [8, 128] vector register per scale — and every whole-page merge
+    has to carry the dangling axis. [..., Nkv, PS] makes
     the per-page scale block a clean [Nkv, PS] tile: the decode kernel
     gets a slot's tiles gathered through its block table (a 64-wide
     minor dimension cannot be sliced out of HBM by the kernel's own
@@ -370,9 +370,8 @@ def paged_attention_multi(
     On TPU this runs the head-folded Pallas kernel (each page DMA'd once
     per SLOT — all kv heads, all T queries); the fallback flattens to
     [B*T] rows of the single-token path — correct everywhere, but it
-    re-streams the prefix T times (measured ~9 decode-steps of overhead
-    for a T=8 verify window at gpt-1b, BASELINE.md round 2 — the
-    motivation for the kernel).
+    re-streams the prefix T times (the motivation for the kernel; its
+    cost is not measured on the attached chip).
     """
     B, T, Nq, D = q.shape
     # same D % 128 == 0 constraint as paged_attention (Mosaic lane
@@ -395,6 +394,65 @@ def paged_attention_multi(
         q.reshape(B * T, Nq, D), k_pages, v_pages,
         jnp.repeat(block_tables, T, axis=0), flat_pos + 1, layer)
     return out.reshape(B, T, Nq, D)
+
+
+def pad_to_page_width(x: jax.Array, pages: jax.Array) -> jax.Array:
+    """``x`` with its last axis zero-padded to the pool's row width: a latent
+    pool stores a row wider than the model makes it (serve/kv_cache.py: the
+    TPU compiler refuses a page copy of the row's own width), and a window's
+    rows and its absorbed queries are padded alike on their way in."""
+    pad = pages.shape[-1] - x.shape[-1]
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+
+
+def write_prompt_to_pages(pages, dense, entries: jax.Array):
+    """A COLD prompt's rows into its pages, whole: the third writer, beside
+    a token's and a window's. ``dense`` is what a cold prefill's forward
+    kept of its batch of ONE prompt, [L, 1, bucket, Nkv, D] (a latent
+    model's rows [L, 1, bucket, W]: they go in at the pool's width, one
+    "head" a page), laid out as pages [L, bucket / PS, Nkv, PS, D] and SET
+    at ``entries`` [bucket / PS], the slot's table entries (the bucket's
+    padding names scratch page 0 or lands behind the slot's length, where
+    nothing reads it). ``QuantPages`` and ``Int4Pages`` quantize on the way
+    in, a scale a token, as the other two writers do. Given a pair of pools
+    and caches (K and V; a None pool is handed through) every cache is laid
+    out before any pool is written: the cold program's order."""
+    if isinstance(pages, tuple):
+        laid = [None if d is None else _prompt_page_layout(p, d)
+                for p, d in zip(pages, dense)]
+        return tuple(p if d is None else _set_pages(p, d, entries)
+                     for p, d in zip(pages, laid))
+    return _set_pages(pages, _prompt_page_layout(pages, dense), entries)
+
+
+def _prompt_page_layout(pages, dense: jax.Array) -> jax.Array:
+    PS = pages.shape[-2]
+    dense = dense[:, 0]
+    L, bucket = dense.shape[:2]
+    if dense.ndim == 3:         # latent rows: no head axis
+        return pad_to_page_width(dense, pages).reshape(
+            L, bucket // PS, 1, PS, -1).astype(pages.dtype)
+    # dense [L, bucket, Nkv, D] -> paged [L, n_pages, Nkv, PS, D]
+    return dense.reshape(L, bucket // PS, PS,
+                         *dense.shape[2:]).transpose(0, 1, 3, 2, 4)
+
+
+def _set_pages(pages, dense: jax.Array, entries: jax.Array):
+    if isinstance(pages, Int4Pages):
+        # same per-token absmax granularity as int8, then the whole-page
+        # pack along the slot axis ([.., PS, D] -> [.., PS/2, D] bytes)
+        from .quantization import pack_int4_rows
+        qv, sc = quantize_kv_token_int4(dense)
+        return Int4Pages(
+            pages.values.at[:, entries].set(pack_int4_rows(qv, axis=-2)),
+            pages.scale.at[:, entries].set(sc))
+    if isinstance(pages, QuantPages):
+        # absmax over D gives the per-token scale [L, nP, Nkv, PS]: exactly
+        # the per-page scale-tile layout, no reshape
+        qv, sc = quantize_kv_token(dense)
+        return QuantPages(pages.values.at[:, entries].set(qv),
+                          pages.scale.at[:, entries].set(sc))
+    return pages.at[:, entries].set(dense)
 
 
 def write_token_to_pages(

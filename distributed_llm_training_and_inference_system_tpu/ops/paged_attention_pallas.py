@@ -229,7 +229,7 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
 # asked for 19.3 MB and was refused, and 2**21 tiled (q/out blocks double-
 # buffered across tiles) was refused for the GQA 32/8 layout. 2**20 (4 MB:
 # 64 query tokens per step at both layouts) compiles at every window the
-# engine can pass — tests/test_tpu_compile.py holds it there.
+# engine can pass — tests/test_tpu_compile_kernels.py holds it there.
 _MAX_SCORE_ELEMS = 1 << 20
 # Most folded query rows [Nq*tile] a grid step holds (bfloat16; float32
 # half): 64 query tokens at 32 query heads.

@@ -318,6 +318,18 @@ def write_slot_state(conv_pool: jax.Array, ssm_pool: jax.Array,
     return conv_pool, ssm_pool.at[:, slot].set(states)
 
 
+def arm_slot_state(conv_pool: jax.Array, ssm_pool: jax.Array,
+                   slot: jax.Array, tails: jax.Array, states: jax.Array
+                   ) -> tuple[jax.Array, jax.Array]:
+    """The pools with ``slot``'s rows of every ``M`` layer SET to a cold
+    prefill's (conv tails [Lm, 1, K-1, C], states [Lm, 1, nh, P, N], from a
+    zero state), whatever a former occupant left there. A set at the slot,
+    where ``write_slot_state`` selects over the whole pool: a cold program
+    carries the pools through no loop."""
+    return (conv_pool.at[:, slot].set(tails[:, 0].astype(conv_pool.dtype)),
+            ssm_pool.at[:, slot].set(states[:, 0].astype(ssm_pool.dtype)))
+
+
 def recur_chunk(cfg, tail: jax.Array, h0: jax.Array,
                 live: Optional[jax.Array] = None):
     """``recur`` for a window of ONE slot's prompt behind tokens the slot

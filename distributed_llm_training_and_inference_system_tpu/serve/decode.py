@@ -32,6 +32,7 @@ from ..models.layers import decoder_block, model_rope_frequencies
 from ..ops import kda, ssm as ssm_ops
 from ..ops.mla_paged_attention import mla_paged_attention
 from ..ops.paged_attention import (
+    pad_to_page_width,
     paged_attention_multi,
     write_window_to_pages,
 )
@@ -61,6 +62,37 @@ class Piece(NamedTuple):
     @classmethod
     def unpack(cls, row: jax.Array) -> "Piece":
         return cls(*row[:PIECE_META], row[PIECE_META:])
+
+
+class StepResult(NamedTuple):
+    """What ONE forward over the pages returns, whatever the model. A field
+    is None where a model has none, and None is an empty pytree: it adds no
+    leaf to a jitted program's arguments or results, a scan's carry or a
+    donation, so callers read fields by name and no program changes with
+    the fields a model fills."""
+    logits: jax.Array       # [B, T, V] fp32 ([B, V] of a decode step)
+    k_pages: Any            # the K pool; a latent model's ONE pool
+    v_pages: Any            # None: a latent model
+    moe_stats: Any = None   # asked with ``return_moe_stats`` of an MoE model
+    state: Any = None       # {"conv", "ssm"}: a recurrent model's pools
+
+
+class DispatchResult(NamedTuple):
+    """What one PROGRAM returns, whatever the model and the program (a
+    decode dispatch, a speculative one, a prefill), None where it has none;
+    in the order the programs' results always had, so that the flattened
+    program is the same."""
+    sampled: Any            # [K, B] tokens; a prefill's first token; a
+                            # denoise dispatch's windows; a speculative
+                            # one's (emitted, n_emit[, seq])
+    tokens: Any = None      # [B]: the final carry, which a next dispatch
+    positions: Any = None   # [B]  chains on
+    k_pages: Any = None
+    v_pages: Any = None
+    moe_stats: Any = None   # summed over the steps
+    state: Any = None
+    firsts: Any = None      # [K]: the steps' first tokens of riding prompts
+    counts: Any = None      # ``DENOISE_COUNTS`` of a denoise dispatch
 
 
 def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl, block=0):
@@ -98,8 +130,15 @@ _shared_recur_step = {
 }
 # a recurrent kind's module: both have ``recur_step`` / ``step_pools`` (T = 1
 # over the pools), ``recur_chunk`` (a window of ONE slot from its state) and
-# ``slot_state`` / ``write_slot_state`` for their own pools' layouts
+# ``slot_state`` / ``write_slot_state`` / ``arm_slot_state`` for their own
+# pools' layouts
 _RECURRENT = {"K": kda, "M": ssm_ops}
+
+
+def recurrent_ops(cfg: ModelConfig):
+    """The module of a recurrent model's kind (a table has ``M`` or ``K``
+    layers, not both): it owns the state pools' layout."""
+    return _RECURRENT["K" if cfg.kda_layers else "M"]
 
 
 def can_carry(cfg: ModelConfig) -> bool:
@@ -136,12 +175,12 @@ def decode_step_forward(
     ssm_state: Any = None,
     ride: Any = None,         # a Piece: its rows join the step's B
     two_bodies: bool = False,  # one of a riding program's two step bodies
-) -> tuple:
-    """Returns (logits [B, V] fp32, new k_pages, new v_pages) and, asked
-    with ``return_moe_stats``, the live slots' expert choices, and given
-    ``ssm_state`` the advanced state pools (see ``extend_step_forward``).
-    With ``ride`` the logits are [B + 1, V]: the last row is the piece's
-    last live row.
+) -> StepResult:
+    """Returns a ``StepResult``: logits [B, V] fp32, the new pools and,
+    asked with ``return_moe_stats``, the live slots' expert choices, and
+    given ``ssm_state`` the advanced state pools (see
+    ``extend_step_forward``). With ``ride`` the logits are [B + 1, V]: the
+    last row is the piece's last live row.
 
     The T=1 case of ``extend_step_forward`` (one layer-body implementation
     for both, so the paths can never diverge numerically). The new token's
@@ -149,16 +188,17 @@ def decode_step_forward(
     returned pools ARE the argument buffers when the jit wrapper donates
     them (the engine does): the layer loop carries them and writes by
     layer index, and the compiled program holds no pool-sized temporary
-    (tests/test_tpu_compile.py::test_decode_program_updates_pool_in_place).
+    (tests/test_tpu_compile_uniform.py::
+    test_decode_program_updates_pool_in_place).
     """
     write_ok = None if active is None else active[:, None]
-    logits, *rest = extend_step_forward(
+    step = extend_step_forward(
         params, tokens[:, None], positions, k_pages, v_pages, block_tables,
         cfg, write_ok=write_ok, attn_impl=attn_impl,
         w4_kernel_ok=w4_kernel_ok, w8_kernel_ok=w8_kernel_ok,
         return_moe_stats=return_moe_stats, ssm_state=ssm_state, ride=ride,
         two_bodies=two_bodies)
-    return (logits[:, 0], *rest)
+    return step._replace(logits=step.logits[:, 0])
 
 
 def extend_step_forward(
@@ -193,16 +233,16 @@ def extend_step_forward(
                               # table's periodic part is walked by a loop
     state_slot: Any = None,   # int32 []: the ONE slot whose window this is
                               # (B == 1: chunked prefill through K layers)
-) -> tuple:
+) -> StepResult:
     """Paged forward over T tokens per slot: the multi-token sibling of
-    ``decode_step_forward``. Returns (logits [B, T, V] fp32, k_pages,
-    v_pages) and, asked with ``return_moe_stats`` (MoE models), a fourth:
-    the [E + 1] int32 vector of the LIVE tokens' choices per expert summed
-    over the layers (``write_ok`` rows; idle slots and padding get no
-    expert) and, last, the (layer, expert) pairs that got any. A model
-    with state-space layers takes ``ssm_state`` and returns it LAST,
-    advanced in place for the rows ``write_ok`` marks: its K/V pools hold
-    the attention layers alone ([La, NP, ...]).
+    ``decode_step_forward``. Returns a ``StepResult``: logits [B, T, V]
+    fp32, k_pages, v_pages and, asked with ``return_moe_stats`` (MoE
+    models), ``moe_stats``: the [E + 1] int32 vector of the LIVE tokens'
+    choices per expert summed over the layers (``write_ok`` rows; idle
+    slots and padding get no expert) and, last, the (layer, expert) pairs
+    that got any. A model with state-space layers takes ``ssm_state`` and
+    returns it as ``state``, advanced in place for the rows ``write_ok``
+    marks: its K/V pools hold the attention layers alone ([La, NP, ...]).
     A model with ``K`` (delta-rule linear attention) layers takes the same
     ``ssm_state`` (its pools under the same two names). Either kind takes,
     besides T = 1 over every slot, a WINDOW of one slot (B = 1 with
@@ -413,7 +453,7 @@ def extend_step_forward(
         # periodic part traces), and are written back after the last
         # layer, once
         piece = piece_slot = None
-        recurrent = _RECURRENT["K" if cfg.kda_layers else "M"]
+        recurrent = recurrent_ops(cfg)
         if state_slot is not None:
             piece_slot, piece_start, piece_rows, piece_live = (
                 state_slot, start_positions, write_ok, True)
@@ -472,17 +512,17 @@ def extend_step_forward(
                 conv, ssm, piece_slot, *piece, piece_live)
         if cfg.hc_mult > 1:
             x = jnp.sum(x.astype(jnp.float32), axis=2).astype(compute_dtype)
-        return (unembed(params, head_rows(x), cfg), kp, vp,
-                *([stats] if return_moe_stats else []),
-                *([{"conv": conv, "ssm": ssm}] if ssm_state is not None
-                  else []))
+        return StepResult(
+            unembed(params, head_rows(x), cfg), kp, vp,
+            stats if return_moe_stats else None,
+            {"conv": conv, "ssm": ssm} if ssm_state is not None else None)
 
     def body(carry, layer_and_index):
         # the pools must stay a CARRY that every layer writes and reads
         # by its index: as scanned inputs and stacked outputs XLA slices
         # each layer's slab out (94 MB at mistral-7b, 715 pages), writes
         # it back, and copies the step's fresh pool whole
-        x, kp, vp, *stats = carry
+        x, kp, vp, stats = carry
         layer, li = layer_and_index
         # per-layer cast/dequant: quantized serving weights either stay
         # packed for the Pallas matmuls above (TPU) or materialise one
@@ -500,16 +540,17 @@ def extend_step_forward(
         x, (kp, vp), layer_stats = decoder_block(
             x, layer, cfg, positions, inv_freq, attend_pages(kp, vp, li),
             matmul=mm, live=live, layer_index=moe_li)
-        stats = [total + layer_stats for total in stats]
-        return (x, kp, vp, *stats), None
+        if stats is not None:
+            stats = stats + layer_stats
+        return (x, kp, vp, stats), None
 
-    stats0 = ([jnp.zeros((cfg.moe.stats_size,), jnp.int32)]
-              if return_moe_stats else [])
-    (x, new_k, new_v, *stats), _ = jax.lax.scan(
-        body, (x, k_pages, v_pages, *stats0),
+    stats0 = (jnp.zeros((cfg.moe.stats_size,), jnp.int32)
+              if return_moe_stats else None)
+    (x, new_k, new_v, stats), _ = jax.lax.scan(
+        body, (x, k_pages, v_pages, stats0),
         (blocks, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
 
-    return (unembed(params, head_rows(x), cfg), new_k, new_v, *stats)
+    return StepResult(unembed(params, head_rows(x), cfg), new_k, new_v, stats)
 
 
 def _latent_windows(q_lat, rows, pool, tables, starts, ok, li, scale,
@@ -517,15 +558,13 @@ def _latent_windows(q_lat, rows, pool, tables, starts, ok, li, scale,
     """Write each slot's window of latent rows into its pages at layer
     ``li`` (zero-padded to the pool's row width) and let every head's
     absorbed query walk the slot's live pages once: (out, new pool)."""
-    pad = pool.shape[-1] - rows.shape[-1]
     with jax.named_scope("mla_page_write"):
-        rows = jnp.pad(rows, ((0, 0), (0, 0), (0, pad)))
+        rows = pad_to_page_width(rows, pool)
         new = write_window_to_pages(pool, rows[:, :, None, :], tables,
                                     starts, ok, li)
     out = mla_paged_attention(
-        jnp.pad(q_lat, ((0, 0), (0, 0), (0, 0), (0, pad))), new, tables,
-        starts, scale=scale, value_width=value_width, impl=attn_impl,
-        layer=li)
+        pad_to_page_width(q_lat, pool), new, tables, starts, scale=scale,
+        value_width=value_width, impl=attn_impl, layer=li)
     return out, new
 
 
@@ -571,48 +610,17 @@ def attend_latent_pages(cfg: ModelConfig, pool: jax.Array, li,
     return attend
 
 
-def decode_multi_step(
-    params: Any,
-    tokens: jax.Array,          # [B] int32 — newest token per slot
-    positions: jax.Array,       # [B] int32 — its position
-    k_pages: jax.Array,         # [L, NP, Nkv, PS, D]
-    v_pages: jax.Array,
-    block_tables: jax.Array,    # [B, maxP]
-    stop_positions: jax.Array,  # [B] — first position a slot must NOT write
-    slot_keys: jax.Array,       # [B, 2] uint32 PRNG key data
-    temperature: jax.Array,     # [B]
-    top_k: jax.Array,           # [B]
-    top_p: jax.Array,           # [B]
-    cfg: ModelConfig,
-    num_steps: int,
-    attn_impl: str = "auto",
-    w4_kernel_ok: bool = True,
-    w8_kernel_ok: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Run ``num_steps`` decode+sample iterations in ONE compiled program.
-
-    The host-driven single-step loop costs one host<->device round trip per
-    generated token: dispatch + sync per token, next to a few ms of decode
-    compute (the round trip is not re-measured on a directly attached
-    chip). Scanning K steps on device amortises that Kx
-    (vLLM-style multi-step scheduling, TPU-shaped: the scan is one XLA
-    program, sampling included).
-
-    Per-slot stop handling: rows at/past ``stop_positions`` redirect KV
-    writes to scratch page 0 and re-emit their previous token. Slots that
-    hit EOS mid-scan keep decoding into their (reserved) pages; the host
-    trims trailing tokens — at most ``num_steps - 1`` wasted iterations per
-    finished request. Sampling folds the per-slot key by position exactly
-    like the single-step path, so generations are bit-identical to
-    ``num_steps=1``.
-
-    Returns ([K, B] sampled tokens, new k_pages, new v_pages).
-    """
-    (_, _, k_pages, v_pages), toks_seq = decode_scan(
+def decode_multi_step(params, tokens, positions, k_pages, v_pages,
+                      block_tables, stop_positions, slot_keys, temperature,
+                      top_k, top_p, cfg: ModelConfig, num_steps: int,
+                      attn_impl: str = "auto", w4_kernel_ok: bool = True,
+                      w8_kernel_ok: bool = False) -> DispatchResult:
+    """``decode_scan`` as the K-step program was first called: no routing
+    counts, no state pools, no pieces (experiments/spec_profile.py)."""
+    return decode_scan(
         params, tokens, positions, k_pages, v_pages, block_tables,
         stop_positions, slot_keys, temperature, top_k, top_p, cfg,
         num_steps, attn_impl, w4_kernel_ok, w8_kernel_ok)
-    return toks_seq, k_pages, v_pages
 
 
 def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
@@ -620,38 +628,51 @@ def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
                 cfg: ModelConfig, num_steps: int, attn_impl: str = "auto",
                 w4_kernel_ok: bool = True, w8_kernel_ok: bool = False,
                 return_moe_stats: bool = False, ssm_state: Any = None,
-                ride: Any = None, ride_branch: bool = True):
-    """The decode+sample scan shared by ``decode_multi_step`` and the fused
-    speculative dispatch (speculative.verify_and_decode). Returns
-    ((tokens, positions, k_pages, v_pages), toks_seq [K, B]); with
-    ``return_moe_stats`` (MoE models) the carry ends in the steps' summed
-    ``moe_stats`` (see ``extend_step_forward``), and given ``ssm_state``
-    (a model with state-space layers) in the state pools after that.
+                ride: Any = None) -> DispatchResult:
+    """``num_steps`` decode+sample iterations in ONE compiled program: the
+    engine's decode dispatch, and the tail of the fused speculative one
+    (speculative.verify_and_decode). A host-driven single-step loop costs
+    a host<->device round trip a token (dispatch + sync; not re-measured on
+    a directly attached chip); K steps scanned on the device amortise it
+    K-fold (vLLM-style multi-step scheduling, TPU-shaped: one XLA program,
+    sampling included).
+
+    ``tokens`` / ``positions`` [B]: each slot's newest token and its
+    position; ``slot_keys`` [B, 2] uint32 key data. Per-slot stop handling:
+    rows at/past ``stop_positions`` [B] (the first a slot must NOT write)
+    redirect KV writes to scratch page 0 and re-emit their previous token.
+    Slots that hit EOS mid-scan keep decoding into their (reserved) pages;
+    the host trims trailing tokens — at most ``num_steps - 1`` wasted
+    iterations per finished request. Sampling folds the per-slot key by
+    position, so generations are bit-identical to ``num_steps=1``.
+
+    Returns a ``DispatchResult``: ``sampled`` [K, B], the final carry
+    (``tokens``, ``positions``) and the pools; with ``return_moe_stats``
+    (MoE models) the steps' summed ``moe_stats`` (``extend_step_forward``),
+    and given ``ssm_state`` (a recurrent model) the pools as ``state``.
 
     ``ride`` ([K, PIECE_META + C] int32, one ``Piece`` a step): each step
     also takes the rows of ONE pending prompt's piece through the block
-    (``extend_step_forward``), and what the scan stacks is then (toks_seq,
-    firsts [K]): a final piece's last live row samples its prompt's first
-    token with the slot's key folded by the prompt's length, as the cold
-    prefill program's caller folds it (``engine._sampling_args``), so the
-    token is that program's token; 0 for any other step. That step also
-    ARMS the slot in the carry (token, position, stop), so the slot
-    decodes from the next step on, whatever the host knows. The carrying
-    steps come first: the steps after the last live piece run the plain
-    step (``ride_branch``; False computes C dead rows in every step:
-    ``experiments/ride_step_alone.py`` measures both)."""
+    (``extend_step_forward``), and the result has ``firsts`` [K] too: a
+    final piece's last live row samples its prompt's first token with the
+    slot's key folded by the prompt's length, as the cold prefill program's
+    caller folds it (``engine._sampling_args``), so the token is that
+    program's token; 0 for any other step. That step also ARMS the slot in
+    the carry (token, position, stop), so the slot decodes from the next
+    step on, whatever the host knows. The carrying steps come first: the
+    steps after the last live piece run the plain step (a step that carried
+    C dead rows instead cost their time in every step: PERF.md 6, PR 36)."""
     return_moe_stats = return_moe_stats and cfg.is_moe
-    n_stats = int(return_moe_stats)
 
     def advance(toks, pos, stops, kp, vp, state, piece):
-        """One step: (next tokens, first token, pools, moe stats, state)."""
+        """One step: (next tokens, first token, the step's ``StepResult``)."""
         act = pos < stops
-        logits, kp, vp, *out = decode_step_forward(
+        step = decode_step_forward(
             params, toks, pos, kp, vp, block_tables, cfg, active=act,
             attn_impl=attn_impl, w4_kernel_ok=w4_kernel_ok,
             w8_kernel_ok=w8_kernel_ok, return_moe_stats=return_moe_stats,
-            ssm_state=state[0] if state else None, ride=piece,
-            two_bodies=ride is not None)
+            ssm_state=state, ride=piece, two_bodies=ride is not None)
+        logits = step.logits
         key_data, fold = slot_keys, pos + 1
         sampling = (temperature, top_k, top_p)
         if ride is not None:
@@ -690,19 +711,19 @@ def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
             nxt, last = nxt[:-1], nxt[-1]
             if piece is not None:
                 first = jnp.where(piece.stop > 0, last, 0)
-        return (jnp.where(act, nxt, toks), first, kp, vp, *out)
+        return jnp.where(act, nxt, toks), first, step
 
-    stats0 = ([jnp.zeros((cfg.moe.stats_size,), jnp.int32)]
-              if return_moe_stats else [])
-    state0 = [] if ssm_state is None else [ssm_state]
-    carry0 = (tokens, positions, k_pages, v_pages, *stats0, *state0)
+    # the scan's carry: (tokens, positions, K pool, V pool, moe stats,
+    # state pools), None where the model has none
+    stats0 = (jnp.zeros((cfg.moe.stats_size,), jnp.int32)
+              if return_moe_stats else None)
+    carry0 = (tokens, positions, k_pages, v_pages, stats0, ssm_state)
 
     def one(carry, piece, stops=stop_positions):
-        toks, pos, kp, vp, *rest = carry
-        stats, state = rest[:n_stats], rest[n_stats:]
-        nxt, first, kp, vp, *out = advance(toks, pos, stops, kp, vp, state,
-                                           piece)
-        stats = [a + b for a, b in zip(stats, out[:n_stats])]
+        toks, pos, kp, vp, stats, state = carry
+        nxt, first, step = advance(toks, pos, stops, kp, vp, state, piece)
+        if stats is not None:
+            stats = stats + step.moe_stats
         pos = pos + 1
         if piece is not None:
             # a piece that ends its prompt ARMS its slot on the device: the
@@ -717,14 +738,19 @@ def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
             nxt, pos, stops = (armed(nxt, first),
                                armed(pos, piece.start + piece.live),
                                armed(stops, piece.stop))
-        return ((nxt, pos, kp, vp, *stats, *out[n_stats:]), stops,
-                (nxt, first))
+        return ((nxt, pos, step.k_pages, step.v_pages, stats, step.state),
+                stops, (nxt, first))
+
+    def result(carry, sampled, firsts=None):
+        toks, pos, kp, vp, stats, state = carry
+        return DispatchResult(sampled, toks, pos, kp, vp, stats, state,
+                              firsts)
 
     if ride is None:
         def plain(carry, _):
             carry, _stops, (nxt, _no_first) = one(carry, None)
             return carry, nxt
-        return jax.lax.scan(plain, carry0, None, length=num_steps)
+        return result(*jax.lax.scan(plain, carry0, None, length=num_steps))
 
     # the steps that carry a piece come FIRST (the engine lays a dispatch's
     # pieces from its first step on): one loop over them, then one over the
@@ -734,8 +760,7 @@ def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
     # over the steps, so that what XLA hoists out of the parent's step loop
     # (the q / k / v stacks' re-layouts, 2.4 ms) it hoists out of these.
     live = Piece.unpack(ride.T).live > 0               # [K]
-    n_carry = (jnp.max(jnp.where(live, jnp.arange(num_steps) + 1, 0))
-               if ride_branch else num_steps)
+    n_carry = jnp.max(jnp.where(live, jnp.arange(num_steps) + 1, 0))
 
     def steps(lo, hi, loop, carrying):
         def body(i, loop):
@@ -750,7 +775,7 @@ def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
             jnp.zeros((num_steps,), jnp.int32))
     loop = steps(0, n_carry, (carry0, stop_positions, out0), carrying=True)
     carry, _stops, out = steps(n_carry, num_steps, loop, carrying=False)
-    return carry, out
+    return result(carry, *out)
 
 
 # ``fixed_at`` of a window row that is still to fix (a row of the prompt
@@ -807,11 +832,15 @@ def denoise_scan(params, window, starts, k_pages, v_pages, block_tables,
         live = starts < stop_positions
         walked = jnp.where(live, (starts + Bd - 1) // PS + 1, 0)
         with jax.named_scope("denoise_step"):
-            logits, kp, vp, *layer_stats = extend_step_forward(
+            step_out = extend_step_forward(
                 params, toks, starts, kp, vp, block_tables, cfg,
                 write_ok=jnp.broadcast_to(live[:, None], (B, Bd)),
                 attn_impl=attn_impl, w4_kernel_ok=w4_kernel_ok,
                 w8_kernel_ok=w8_kernel_ok, return_moe_stats=True)
+            logits, kp, vp = (step_out.logits, step_out.k_pages,
+                              step_out.v_pages)
+            layer_stats = ([] if step_out.moe_stats is None
+                           else [step_out.moe_stats])
             # a row's key: the slot's, by its position, then by the step
             keys = jax.vmap(jax.random.wrap_key_data)(slot_keys)
             keys = jax.vmap(lambda key, start, s: jax.vmap(

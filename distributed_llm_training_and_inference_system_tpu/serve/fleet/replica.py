@@ -31,6 +31,7 @@ from typing import Callable, Optional
 
 from ...config.schema import FleetConfig, ModelConfig, ServeConfig
 from ..engine import InferenceEngine
+from ..kv_cache import refuse
 from ..scheduler import Request, RequestState
 from . import migration
 from .faults import FaultInjector
@@ -209,13 +210,8 @@ class EngineReplica:
         # the engine may refine model_cfg from an artifact; later restarts
         # and sibling replicas must build from the EFFECTIVE config
         self.model_cfg = self.engine.cfg
-        if self.model_cfg.is_recurrent:
-            raise ValueError(
-                f"{self.model_cfg.name} has state-space layers: fleet "
-                "serving is refused (migration, prefill/decode handoff, "
-                "prefix fetch and the tiered KV store move K/V pages, and "
-                "a slot's recurrent state is not in them; ROADMAP C2). "
-                "Serve it with --replicas 1.")
+        refuse(self.model_cfg, "fleet serving",
+               advice=". Serve it with --replicas 1.")
         self._wire_engine()
         self.state = HEALTHY
 

@@ -30,6 +30,95 @@ from ..analysis.annotations import engine_thread_only
 logger = logging.getLogger("llmctl.serve.kv_cache")
 
 
+# What a KIND of model refuses, and why: kind -> {feature -> reason}. A
+# sequence's state is K/V pages; a model that keeps anything else a slot
+# (recurrent state, one latent pool, a half-denoised window) carries it
+# through a feature or refuses the feature BY NAME: none may run and be
+# silently wrong. ``refuse`` raises; ``refused`` answers (prefix reuse and
+# riding are turned OFF and counted instead). The first kind that refuses
+# speaks.
+_MOVES_PAGES = ("it re-enters or moves K/V pages, and the layers' recurrent "
+                "state is not in them; ROADMAP C2")
+REFUSED = {
+    # ``M`` layers alone: ``K`` layers CARRY a chunk (ops/kda.py
+    # ``recur_chunk``), and a ``K`` model with latent attention must chunk.
+    # The ``M`` form exists (a riding piece runs it), but no cell and no
+    # engine test has run its chunk PROGRAMS (ROADMAP B8)
+    "state_space": {"chunked_prefill_tokens": _MOVES_PAGES},
+    "recurrent": {
+        "speculative": _MOVES_PAGES,
+        "preemption: swap": _MOVES_PAGES,
+        "page payload": "it carries K/V pages, and the slot's recurrent "
+                        "state is not in them; ROADMAP C2",
+        "fleet prefix fetch": "fetched pages carry no recurrent state",
+        "fleet serving": "migration, prefill/decode handoff, prefix fetch "
+                         "and the tiered KV store move K/V pages, and a "
+                         "slot's recurrent state is not in them; ROADMAP C2",
+        "measure_device_times": "its probes write scratch pages, and would "
+                                "arm and advance live slots' state",
+        # OFF (it is on by default): a page hit would skip tokens whose
+        # recurrent state nobody kept (no snapshot at a page boundary)
+        "prefix_caching": "no page hash is registered or looked up; a "
+                          "repeated prompt is prefilled again",
+    },
+    # (prefix reuse stays ON: a latent page is a function of the token
+    # prefix exactly as a K/V page is)
+    "latent": {
+        **dict.fromkeys(
+            ("speculative", "preemption: swap"),
+            "no verification program has run over latent pages, and the "
+            "swap payload is a K and a V pool; ROADMAP B4, B7"),
+        "kv_quantization": "a latent row is every head's keys AND values: "
+                           "no quantised layout or kernel for it exists; "
+                           "ROADMAP B4",
+        "page payload": "the payload schema is a K and a V pool; latent "
+                        "pages in swap and fleet transfer are ROADMAP B4",
+        "fleet prefix fetch": "the page payload is a K and a V pool; "
+                              "ROADMAP B4",
+    },
+    # (the prefix cache stays ON: a page is a whole number of blocks, so a
+    # whole page's K/V depend on nothing after the page)
+    "diffusion": {
+        **dict.fromkeys(
+            ("speculative", "preemption: swap", "tensor_parallel"),
+            "a draft is verified a token at a time, a swapped slot would "
+            "carry a half-denoised window, and the block kernel is opaque "
+            "to GSPMD; a preempted request is recomputed from its last "
+            "committed block"),
+        "measure_device_times": "its decode probe times a token a step; a "
+                                "denoise forward's time is the benchmark's "
+                                "serve_programs.diffusion_forward_device_ms",
+        # OFF and counted an admission (``decode.can_carry``)
+        "riding": "a piece wants a step of T = 1, its step is a window",
+    },
+}
+
+
+def refused(cfg: ModelConfig, feature: str) -> Optional[tuple[str, str]]:
+    """(what the model is, why) if a kind of ``cfg`` refuses ``feature``."""
+    kinds = {
+        "state_space": cfg.ssm_layers > 0 and f"has {cfg.recurrent_name}",
+        "recurrent": cfg.is_recurrent and f"has {cfg.recurrent_name}",
+        "latent": cfg.is_latent and "keeps latent pages",
+        "diffusion": cfg.is_diffusion and "generates by diffusion over blocks",
+    }
+    for kind, is_a in kinds.items():
+        if is_a and feature in REFUSED[kind]:
+            return is_a, REFUSED[kind][feature]
+    return None
+
+
+def refuse(cfg: ModelConfig, *features: str, what: str = "",
+           advice: str = "") -> None:
+    """Raise the refusal of the first of ``features`` that a kind of
+    ``cfg`` refuses (named ``what`` where the caller has a closer name)."""
+    for feature in features:
+        why = refused(cfg, feature)
+        if why:
+            raise ValueError(f"{cfg.name} {why[0]}: {what or feature} is "
+                             f"refused ({why[1]}){advice}")
+
+
 def prefix_page_hashes(tokens, page_size: int) -> list[bytes]:
     """Chain hashes for every FULL page of a token prefix.
 
@@ -122,11 +211,8 @@ class PagedKVCache:
                 f"{page_size} must be even")
         self.quant_kind = kind
         self.quantized = kind != "none"
-        if cfg.is_latent and self.quantized:
-            raise ValueError(
-                f"{cfg.name} keeps latent pages: kv_quantization {kind} is "
-                "refused (a latent row is every head's keys AND values: no "
-                "quantised layout or kernel for it exists; ROADMAP B4)")
+        if self.quantized:
+            refuse(cfg, "kv_quantization", what=f"kv_quantization {kind}")
         if kind == "int4":
             # packed nibbles (D/2 bytes) + fp32 per-(token, kv-head)
             # scale, K and V — the 2x-over-int8 capacity claim
@@ -243,17 +329,8 @@ class PagedKVCache:
 
     def _pages_only(self, what: str) -> None:
         """Refuse, by name, to move a sequence as K/V pages alone when the
-        model also keeps recurrent state a slot."""
-        if self.state is not None:
-            raise ValueError(
-                f"{self.cfg.name} has {self.cfg.recurrent_name}: {what} is "
-                "refused (it carries K/V pages, and the slot's recurrent "
-                "state is not in them; ROADMAP C2)")
-        if self.cfg.is_latent:
-            raise ValueError(
-                f"{self.cfg.name} keeps latent pages: {what} is refused "
-                "(the payload schema is a K and a V pool; latent pages in "
-                "swap and fleet transfer are ROADMAP B4)")
+        model keeps anything else a slot (``REFUSED``)."""
+        refuse(self.cfg, "page payload", what=what)
 
     def _new_pages(self, shape, dtype):
         """Allocate a (possibly int8/int4-quantized, possibly tensor-

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config.schema import ModelConfig
-from .decode import extend_step_forward
+from .decode import DispatchResult, extend_step_forward
 from .sampling import sample_tokens
 
 # SpecState tuning constants — deterministic, test-pinned. The EWMA
@@ -182,8 +182,9 @@ def speculative_verify(
     attn_impl: str = "auto",
     w4_kernel_ok: bool = True,
     w8_kernel_ok: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """One verification pass. Returns (emitted [B, T], n_emit [B], kp, vp).
+) -> DispatchResult:
+    """One verification pass. Returns a ``DispatchResult`` whose ``sampled``
+    is (emitted [B, T], n_emit [B]), with the new pools.
 
     Row semantics:
     - greedy row: emitted[:n_emit] = argmax chain; n_emit = accepted + 1
@@ -197,10 +198,11 @@ def speculative_verify(
     B, T = tokens.shape
     offs = jnp.arange(T, dtype=jnp.int32)
     write_ok = (positions[:, None] + offs) < stop_positions[:, None]
-    logits, k_pages, v_pages = extend_step_forward(
+    step = extend_step_forward(
         params, tokens, positions, k_pages, v_pages, block_tables, cfg,
         write_ok=write_ok, attn_impl=attn_impl,
         w4_kernel_ok=w4_kernel_ok, w8_kernel_ok=w8_kernel_ok)
+    logits = step.logits
 
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)     # [B, T]
     is_greedy = temperature <= 0.0
@@ -215,7 +217,8 @@ def speculative_verify(
     emitted = jnp.where(is_greedy[:, None], greedy,
                         jnp.broadcast_to(sampled0[:, None], (B, T)))
     n_emit = jnp.where(is_greedy, n_acc + 1, 1).astype(jnp.int32)
-    return emitted, n_emit, k_pages, v_pages
+    return DispatchResult((emitted, n_emit), k_pages=step.k_pages,
+                          v_pages=step.v_pages)
 
 
 def verify_and_decode(
@@ -235,7 +238,7 @@ def verify_and_decode(
     attn_impl: str = "auto",
     w4_kernel_ok: bool = True,
     w8_kernel_ok: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> DispatchResult:
     """Fused dispatch: one verification window + ``num_decode_steps`` plain
     decode iterations, all on device.
 
@@ -250,24 +253,29 @@ def verify_and_decode(
     crossover lies is not measured on the attached chip: no cell
     speculates.
 
-    Returns (emitted [B, T], n_emit [B], decode_seq [R, B], k_pages,
-    v_pages). Host applies emitted[:n_emit] then decode_seq rows.
+    Returns a ``DispatchResult`` whose ``sampled`` is (emitted [B, T],
+    n_emit [B], decode_seq [R, B]), with the new pools. Host applies
+    emitted[:n_emit] then decode_seq rows (and keeps the positions itself:
+    the final carry is not returned).
     """
-    emitted, n_emit, k_pages, v_pages = speculative_verify(
+    verified = speculative_verify(
         params, tokens, positions, k_pages, v_pages, block_tables,
         stop_positions, slot_keys, temperature, top_k, top_p, cfg,
         attn_impl=attn_impl, w4_kernel_ok=w4_kernel_ok,
         w8_kernel_ok=w8_kernel_ok)
+    emitted, n_emit = verified.sampled
     if num_decode_steps < 1:
         B = tokens.shape[0]
-        return (emitted, n_emit,
-                jnp.zeros((0, B), jnp.int32), k_pages, v_pages)
+        return verified._replace(
+            sampled=(emitted, n_emit, jnp.zeros((0, B), jnp.int32)))
     # device-side carry past the verified window: per-row dynamic position
     last = jnp.take_along_axis(emitted, (n_emit - 1)[:, None],
                                axis=1)[:, 0]
     from .decode import decode_scan
-    (_, _, k_pages, v_pages), decode_seq = decode_scan(
-        params, last, positions + n_emit, k_pages, v_pages, block_tables,
-        stop_positions, slot_keys, temperature, top_k, top_p, cfg,
-        num_decode_steps, attn_impl, w4_kernel_ok, w8_kernel_ok)
-    return emitted, n_emit, decode_seq, k_pages, v_pages
+    decoded = decode_scan(
+        params, last, positions + n_emit, verified.k_pages,
+        verified.v_pages, block_tables, stop_positions, slot_keys,
+        temperature, top_k, top_p, cfg, num_decode_steps, attn_impl,
+        w4_kernel_ok, w8_kernel_ok)
+    return DispatchResult((emitted, n_emit, decoded.sampled),
+                          k_pages=decoded.k_pages, v_pages=decoded.v_pages)

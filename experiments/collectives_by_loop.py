@@ -64,7 +64,7 @@ def compile_step(workload: str, layers: int | None, topology: str | None,
         devices = topologies.get_topology_desc(
             platform="tpu", topology_name=topology).devices[:chips]
         # the kernels pick interpret mode from the backend, which is the
-        # CPU here: take their TPU branch, as tests/test_tpu_compile.py does
+        # CPU here: take their TPU branch, as tests/conftest.py ``as_tpu`` does
         jax.default_backend = lambda: "tpu"
         attn_impl = "flash"
     else:

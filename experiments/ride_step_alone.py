@@ -4,11 +4,11 @@ step and the longest operations' us a call (PERF.md 6, PR 36: what
 
     chiprun -- python experiments/ride_step_alone.py [--models dense moe]
     chiprun -- python experiments/ride_step_alone.py --models latent \
-        --rows 256 512 --no-dead-rows
+        --rows 256 512
     chiprun -- python experiments/ride_step_alone.py --models linear \
-        --rows 256 --no-dead-rows --cached 0 4096 12288
+        --rows 256 --cached 0 4096 12288
     chiprun -- python experiments/ride_step_alone.py --models hybrid \
-        --rows 128 --no-dead-rows --live 64 --cached 0 384
+        --rows 128 --live 64 --cached 0 384
 
 The benchmark's riding configurations (``benchmark/configs``) at their
 cells' shapes, 8 steps a dispatch, weights made on the device.
@@ -36,9 +36,9 @@ from the slot's own conv tail and state). Cases: C = 0 (the program
 without ``ride``: the parent's), and C = 64 / 128 / 256 rows with
 
 - ``0 live, branch``: no step carries a piece; each step BRANCHES to the
-  plain step (what ``chat`` runs),
-- ``0 live, dead rows``: the same through ``ride_branch=False``: every step
-  computes its C dead rows (what the branch saves),
+  plain step (what ``chat`` runs; a step that computed its C dead rows
+  instead was measured once, PERF.md 6, PR 36, and is no longer a form
+  of the program),
 - ``C live``: every step carries a full piece of C rows, each at the next
   page-aligned start of a 1,024-token prompt.
 
@@ -164,17 +164,17 @@ def pieces(sh: Shape, C: int, live: int, rng, vocab: int) -> np.ndarray:
     return rows
 
 
-def program(cfg, C: int, branch: bool):
+def program(cfg, C: int):
     from importlib import import_module
     decode = import_module(f"{PKG}.serve.decode")
 
     def step(params, kp, vp, pools, tokens, positions, tables, stops, keys,
              temp, top_k, top_p, ride=None):
-        (toks, pos, kp, vp, *rest), _ = decode.decode_scan(
+        out = decode.decode_scan(
             params, tokens, positions, kp, vp, tables, stops, keys, temp,
             top_k, top_p, cfg, K, return_moe_stats=True, ssm_state=pools,
-            ride=ride, ride_branch=branch)
-        return toks, kp, vp, (rest[-1] if pools is not None else None)
+            ride=ride)
+        return out.tokens, out.k_pages, out.v_pages, out.state
     return jax.jit(step, donate_argnums=(1, 2, 3))
 
 
@@ -276,8 +276,6 @@ def main() -> int:
     ap.add_argument("--models", nargs="+", default=list(CONFIGS),
                     choices=list(CONFIGS))
     ap.add_argument("--rows", nargs="+", type=int, default=[64, 128, 256])
-    ap.add_argument("--no-dead-rows", action="store_true",
-                    help="leave the ride_branch=False programs out")
     ap.add_argument("--live", nargs="+", type=int, default=[],
                     help="also pieces of so many live rows (<= C)")
     ap.add_argument("--cached", nargs="+", type=int, default=[0],
@@ -311,24 +309,20 @@ def main() -> int:
                           for key in jax.random.split(jax.random.PRNGKey(1)))
         pools = (*pools, state_pools(cfg, sh))
         rng = np.random.default_rng(0)
-        cases = [("C=0", 0, 0, True, 0)]
+        cases = [("C=0", 0, 0, 0)]
         for C in args.rows:
-            cases += [(f"C={C}, 0 live, branch", C, 0, True, 0),
-                      (f"C={C}, 0 live, dead rows", C, 0, False, 0),
+            cases += [(f"C={C}, 0 live, branch", C, 0, 0),
                       *[(f"C={C}, {n} live" + (
                           f" behind {cached}" if sh.recurrent else ""),
-                         C, n, True, cached)
+                         C, n, cached)
                         for n in [*args.live, C] if n <= C
                         for cached in (args.cached if sh.recurrent
                                        else [0])]]
         programs = {}
-        for label, C, live, branch, cached in cases:
-            if args.no_dead_rows and not branch:
-                continue
+        for label, C, live, cached in cases:
             sh = Shape(model, cached)
             state = slots(sh, np.random.default_rng(0))
-            fn = programs.setdefault((C, branch),
-                                     {"jit": program(cfg, C, branch)})
+            fn = programs.setdefault(C, {"jit": program(cfg, C)})
             ride = pieces(sh, C, live, rng, cfg.vocab_size) if C else None
             ms, ops, by_scope, pools = run_case(
                 fn, params, pools, state, ride, trace=True,

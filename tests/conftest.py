@@ -33,6 +33,43 @@ def devices8():
     return devs[:8]
 
 
+# -- compiling for a DESCRIBED TPU (tests/test_tpu_compile_*.py) ---------------
+# libtpu is loaded and the topology described INSIDE the module-scoped
+# fixture, when a test of those files first asks for it: never at import
+# (every xdist worker imports every test file and this one).
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Make the package's own ``jax.default_backend()`` checks take their
+    TPU branch (compiled kernel, not interpret) for this test."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
 # -- slow-test marking --------------------------------------------------------
 # Tests measured >= ~12 s on the CI CPU (full-suite `--durations` run,
 # round 3). `pytest -m "not slow"` is the documented fast path (< 4 min);

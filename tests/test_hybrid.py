@@ -699,7 +699,7 @@ def test_uniform_models_carry_nothing_of_this():
     e = InferenceEngine(c, ServeConfig(model="gpt-test", max_batch_size=2,
                                        max_seq_len=64, dtype="float32"))
     assert e.kv.state is None and e.kv.state_bytes() == 0
-    assert "ssm" not in e.stats() and not e.ssm_refused
+    assert "ssm" not in e.stats() and not e.turned_off
     assert c.kv_layers == c.num_layers and not c.is_recurrent
 
 

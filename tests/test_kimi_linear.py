@@ -137,7 +137,7 @@ def _chunk(cfg, params, kv, pool, state, slot, tokens, start, bucket):
     m = len(tokens)
     window = np.full((1, bucket), 9, np.int32)
     window[0, :m] = tokens
-    lg, pool, _, state = decode.extend_step_forward(
+    lg, pool, _, _, state = decode.extend_step_forward(
         params, jnp.asarray(window), jnp.asarray([start], jnp.int32), pool,
         None, jnp.asarray(kv.block_tables[slot][None]), cfg,
         write_ok=jnp.arange(bucket)[None] < m, ssm_state=state,
@@ -147,7 +147,7 @@ def _chunk(cfg, params, kv, pool, state, slot, tokens, start, bucket):
 
 def _decode(cfg, params, kv, pool, state, tokens, positions, active):
     """One decode step of every slot: (logits [slots, V], pool, state)."""
-    lg, pool, _, state = decode.decode_step_forward(
+    lg, pool, _, _, state = decode.decode_step_forward(
         params, jnp.asarray(tokens, jnp.int32),
         jnp.asarray(positions, jnp.int32), pool, None,
         jnp.asarray(kv.block_tables), cfg, active=jnp.asarray(active),

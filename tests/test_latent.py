@@ -97,7 +97,7 @@ def _paged(cfg, params, tokens, windows):
             window[1] = tokens[at:at + t]
             ok = np.zeros((3, t), bool)
             ok[1] = True
-            lg, pool, none = decode.extend_step_forward(
+            lg, pool, none, *_ = decode.extend_step_forward(
                 params, jnp.asarray(window),
                 jnp.asarray([0, at, 0], jnp.int32), pool, None, tables, cfg,
                 write_ok=jnp.asarray(ok))
@@ -169,7 +169,7 @@ def test_cold_prefill_rows_then_decode_through_the_pages(cfg, params):
             rows.reshape(3, 3, 1, PS, -1))
         outs = [np.asarray(lg[0, :n])]
         for i in range(n, len(tokens)):
-            step, pool, _ = decode.decode_step_forward(
+            step, pool, *_ = decode.decode_step_forward(
                 params, jnp.asarray([tokens[i]]), jnp.asarray([i]), pool,
                 None, jnp.asarray(kv.block_tables), cfg)
             outs.append(np.asarray(step))

@@ -343,8 +343,8 @@ def _paged_extend(cfg, params, tokens, window):
         params, t, start, kp, vp, tables, cfg))
     out = []
     for start in range(0, _S, window):
-        logits, kp, vp = step(tokens[:, start:start + window],
-                              jnp.full((_B,), start, jnp.int32), kp, vp)
+        logits, kp, vp, *_ = step(tokens[:, start:start + window],
+                                  jnp.full((_B,), start, jnp.int32), kp, vp)
         out.append(logits)
     return jnp.concatenate(out, axis=1), forward(params, tokens, cfg)
 

@@ -207,7 +207,7 @@ def test_prefill_then_decode_matches_the_reference(cfg, params, route):
             T = 24 if route == "suffix" else 8      # the window is padded
             window = np.full((1, T), 9, np.int32)
             window[0, :b - a] = seq[a:b]
-            lg, kp, vp = extend_step_forward(
+            lg, kp, vp, *_ = extend_step_forward(
                 params, jnp.asarray(window), jnp.asarray([a], jnp.int32),
                 kp, vp, jnp.asarray(table[1:2]), cfg,
                 write_ok=jnp.arange(T)[None] < (b - a))
@@ -215,7 +215,7 @@ def test_prefill_then_decode_matches_the_reference(cfg, params, route):
     for pos in range(n, len(seq)):
         toks = np.full(4, 11, np.int32)             # idle slots' garbage
         toks[1] = seq[pos]
-        lg, kp, vp, stats = decode_step_forward(
+        lg, kp, vp, stats, _ = decode_step_forward(
             params, jnp.asarray(toks), jnp.full((4,), pos, jnp.int32), kp,
             vp, jnp.asarray(table), cfg,
             active=jnp.asarray([False, True, False, False]),
@@ -242,7 +242,7 @@ def test_a_request_does_not_depend_on_its_companions(cfg, params):
     def extend(batch_rows, slot):
         kp, vp = _pages(cfg)
         window = np.stack(batch_rows).astype(np.int32)
-        lg, _, _ = extend_step_forward(
+        lg, *_ = extend_step_forward(
             params, jnp.asarray(window),
             jnp.zeros((len(batch_rows),), jnp.int32), kp, vp,
             jnp.asarray(table[:len(batch_rows)]), cfg)

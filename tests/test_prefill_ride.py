@@ -22,6 +22,8 @@ and write the slot's state as it does.
 
 import collections
 import functools
+import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,6 +94,18 @@ def _engine(name, cfg=None, **over):
     opts.update(over)
     return InferenceEngine(cfg, ServeConfig(**opts),
                            params=init(cfg, jax.random.PRNGKey(0)), seed=0)
+
+
+def _normalised_sha256(text):
+    """sha256 of a lowered program's StableHLO without the two things a
+    result RECORD in place of a tuple and a renamed closure change (PR 45):
+    the ``jax.result_info`` attributes of the entry function's results
+    (``"result[3]"`` against ``"result.k_pages"``) and the ``@jit_<name>`` of
+    the module line. Nothing else is dropped: operations, parameters,
+    results, aliasing and every inner function's name are hashed."""
+    text = re.sub(r"^module @jit_\w+", "module @jit", text, count=1)
+    text = re.sub(r'jax\.result_info = "[^"]*"', "", text)
+    return hashlib.sha256(text.encode()).hexdigest()
 
 
 @pytest.fixture(scope="module", params=MODELS)
@@ -402,7 +416,7 @@ def test_a_prompt_that_rode_is_a_prefix_hit_for_the_next(engines):
     ids=["speculative", "static scheduler", "int8 weights", "tp 2"])
 def test_engines_that_keep_todays_path_never_ride(over):
     eng = _engine("gpt-test", **over)
-    assert eng._ride_rows == 0 and eng._decode_tail_args() == ()
+    assert eng._ride_rows == 0 and eng._decode_tail_args() == (None, None)
     reqs = _serve(eng, [_tokens(20)], SAMPLING["greedy"])
     assert all(r.state is RequestState.FINISHED for r in reqs)
     assert eng.stats()["prefill_ride_tokens"] == 0
@@ -419,7 +433,7 @@ def test_the_carry_is_read_off_the_page_size(name, page, rows):
     delta-rule model's page is four sub-chunks of 64)."""
     eng = _engine(name, kv_block_size=page, max_seq_len=512)
     assert eng._ride_rows == rows
-    [pieces] = eng._decode_tail_args()[1:]
+    _state, pieces = eng._decode_tail_args()
     assert pieces.shape == (STEPS, PIECE_META + rows)
 
 
@@ -429,8 +443,9 @@ def test_a_layer_table_model_keeps_the_parents_decode_program(name, over):
     """A hybrid configuration (state-space layers in the table) rides since
     PR 44; where its engine does not (the static scheduler: ``_can_ride``)
     it hands its decode program no pieces, and the program lowers to the
-    text of the parent's ``_decode_impl_n`` (written out below as it
-    stood): the table walked whole, ``recur_step`` unjitted."""
+    text of the parent's ``_decode_impl_n`` (written out below as it stood
+    before PR 36, a tuple for its result): the table walked whole,
+    ``recur_step`` unjitted."""
     cfg = get_model_config(name)
     eng = InferenceEngine(
         cfg, ServeConfig(model=name, max_batch_size=SLOTS, max_seq_len=128,
@@ -441,78 +456,112 @@ def test_a_layer_table_model_keeps_the_parents_decode_program(name, over):
     args = (eng.params, eng.kv.k_pages, eng.kv.v_pages,
             jnp.asarray(eng.last_tokens), jnp.asarray(eng.positions),
             *eng._shared_decode_args(), *eng._decode_tail_args())
-    assert len(args) == 11 + int(cfg.is_recurrent)
+    assert len(args) == 13 and args[-1] is None     # the state, no pieces
 
     def _decode_impl_n(params, k_pages, v_pages, tokens, positions, tables,
                        stops, slot_keys, temp, top_k, top_p, state=None):
-        (toks, pos, k_pages, v_pages, *rest), toks_seq = decode_scan(
+        out = decode_scan(
             params, tokens, positions, k_pages, v_pages, tables, stops,
             slot_keys, temp, top_k, top_p, cfg, STEPS, attn_impl="auto",
             w4_kernel_ok=True, w8_kernel_ok=False, return_moe_stats=True,
             ssm_state=state)
-        return (toks_seq, toks, pos, k_pages, v_pages, *rest)
+        return (out.sampled, out.tokens, out.positions, out.k_pages,
+                out.v_pages, out.moe_stats, out.state)
 
-    donate = (1, 2, 11) if cfg.is_recurrent else (1, 2)
-    parents = jax.jit(_decode_impl_n, donate_argnums=donate).lower(*args)
-    assert eng._decode_jit.lower(*args).as_text() == parents.as_text()
+    parents = jax.jit(_decode_impl_n, donate_argnums=(1, 2, 11)).lower(
+        *args[:-1])
+    assert _normalised_sha256(eng._decode_jit.lower(*args).as_text()) \
+        == _normalised_sha256(parents.as_text())
 
 
-# sha256 of the StableHLO of the decode program (``_decode_jit``, pieces and
-# all) as the PARENT of PR 43 (7583964) lowers it for this module's engine:
-# ``git archive`` of that commit, the lowering of the test below run there
+# THE PINS. Every hash below is ``_normalised_sha256`` of a program's lowered
+# StableHLO as the PARENT of PR 45 (2cf92d9) lowers it: ``git archive`` of
+# that commit, this module's lowering helpers run there. The helper drops the
+# ``jax.result_info`` attributes and the module line's ``@jit_<name>`` and
+# nothing else, so a pin that holds says: the same operations over the same
+# parameters, results and aliasing as the parent's program. (PR 45 put a
+# record where a tuple's length depended on the model, one writer for a
+# prompt's pages and the state pools' arming into ops/: none changes a
+# traced operation.) When a PR means to move a program, print the new hash
+# from the failing assertion and say here which commit it is of.
+
+# the decode program (``_decode_jit``, pieces and all) of this module's engine
 PARENTS_DECODE = {
-    "gpt-test": "73fb4be83a6de6f218be0767da6d0fe56554bd7f369fd873854a1860d7ae8a9e",
-    "olmoe-test": "5df605b7bab9c6587fab1cb6630d94ab3ed668636ad61597cbf67801904c4270",
-    "xing-test": "eb6eb32b4808957f7c034acfa046e0237be0c9751d9d9e7dadda8832c98f193a",
-    "sdar-test": "0a8f164867771f152023bfd616bd6b5e742f5f012fc856f0418430f415fb37c5",
-    # (since PR 44, whose parent is 6468bc5: the delta-rule model's riding
-    # program, which PR 43 wrote)
-    LINEAR: "8032e519168cd6a754d641e8efae7ebd8eb2f6f44fba5dfddfcb02b57586bab3",
+    "gpt-test": "54a27c5775c96003af68301635b64e0cd98140d0860de0d3759c8b6b23d64ddc",
+    "olmoe-test": "55e215fc630808589c68ad79c8c7e3d3d2b79fb33059922235717bceadeb5c19",
+    "xing-test": "d64f8944fe2118229021681b196c58cd7db4511bed933866e5c2fd8a824d25da",
+    "sdar-test": "aeb0b472fcaba6553b9a64c546e69025b85c5864120cd6776eff5662ffa7b025",
+    LINEAR: "ca8bac7665a0d8b5f1011e2dcd5c2ed45b6ca30666c9e286dbf4477101b24b26",
+    HYBRID: "74ad821e06394a100e91d3e4d063dc2463f98e81ca46099914c6db821b82677f",
 }
 
 
 @pytest.mark.parametrize("name", list(PARENTS_DECODE))
 def test_the_other_models_decode_programs_are_the_parents(name):
-    """PR 43 taught the table walk a ``K`` layer's piece (a seventh element
-    of its carry, ``recur_at``'s window) and changed what the engine hands
-    and takes (``_decode_tail_args``, ``_submit_decode``): the RIDING
-    programs of the uniform stack (dense, MoE) and of the latent table, and
-    the diffusion model's denoise program, lower byte for byte to the
-    parent's text all the same. PR 44 taught ``recur_at`` the ``M`` kind by
-    the same seam (one ``recur`` for both kinds' windows): the delta-rule
-    model's riding program lowers to ITS parent's text too."""
-    import hashlib
+    """The RIDING decode programs of the uniform stack (dense, MoE), of the
+    latent, the delta-rule and the hybrid table, and the diffusion model's
+    denoise program lower to the parent's text (PR 43 and 44 taught the
+    table walk a recurrent layer's piece by one seam, ``recur_at``; PR 45
+    made what a step and a dispatch return a record)."""
     eng = _engine(name)
     assert eng._ride_rows == (0 if name == "sdar-test" else C)
     text = eng._decode_jit.lower(
         eng.params, eng.kv.k_pages, eng.kv.v_pages,
         *eng._decode_head_args(), *eng._shared_decode_args(),
         *eng._decode_tail_args()).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_DECODE[name]
+    assert _normalised_sha256(text) == PARENTS_DECODE[name]
 
 
-# sha256 of the StableHLO of ``kimi-linear-test``'s prefill programs for the
-# engine below (bucket 32), under this module's chunk of 8. The cold
-# program's is the PARENT's of PR 43 (7583964; ``git archive`` of that
-# commit, ``_linear_prefill_texts`` below run there). The chunk and the
-# final-chunk program read and write their slot's conv windows as a decode
-# step's piece does since PR 43 (a masked sum and a select over the pool,
-# ONE form for both callers: ``ops/kda.py slot_state``), and keep their
-# layers' states stacked in the walk's carry: their texts are this PR's,
-# and what they compute is held to the cold program's tokens and pools
-# elsewhere (tests/test_kimi_linear.py, and this module's pieces against them)
+# ``kimi-linear-test``'s prefill programs for the engine below (bucket 32),
+# under this module's chunk of 8. The chunk and the final-chunk program read
+# and write their slot's conv windows as a decode step's piece does (a
+# masked sum and a select over the pool, ONE form for both callers:
+# ``ops/kda.py slot_state``), and keep their layers' states stacked in the
+# walk's carry; what they compute is held to the cold program's tokens and
+# pools elsewhere (tests/test_kimi_linear.py, and this module's pieces
+# against them)
 LINEAR_PREFILL = {
-    "cold": "e2192e2172f2e0ccd913f74ce09ba3915095d80e4942fd98386ffc48592812a0",
-    "chunk": "4920bdf44415b3b3501e028ece8e5e99d64cda72adb178d0df46e4532df9b9f5",
-    "final chunk": "37e80665a1cfdb94fafd1ae40f2c122e10d10de91331b26a37a42d3ead5c5830",
+    "cold": "4c8e46157ff0c80cfdc174143991a3c5fb3f81e5a3def687f2ea4420256d6f39",
+    "chunk": "6e80a83be8a892eaff4b1026eeaf154982c5ef552f8c5284888d06c2f37a0186",
+    "final chunk": "addc731e988e784fc71c385ca73bdf4bd1a7b8754d203deb2b8095e293456071",
 }
+
+
+def _shapes(tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+
+def _vec(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def _prefill_texts(eng, bucket=32, programs=("cold", "suffix", "chunk")):
+    """{program: lowered text} of an engine's cold, suffix (final-chunk) and
+    chunk programs at one bucket, as the engine jits them."""
+    from distributed_llm_training_and_inference_system_tpu.serve.sampling import (
+        seed_key_data)
+    params, kp, vp = _shapes((eng.params, eng.kv.k_pages, eng.kv.v_pages))
+    # (a recurrent model's programs take its state pools and the slot)
+    state = () if eng.kv.state is None else (_shapes(eng.kv.state), _vec())
+    sampling = _shapes(eng._sampling_args(seed_key_data(0), 0,
+                                          SamplingParams()))
+    window = (params, _vec(1, bucket), _vec(1), _vec(1), kp, vp,
+              _vec(1, eng.kv.max_pages_per_slot))
+    lower = {
+        "cold": lambda: eng._prefill_fn(bucket).lower(
+            params, _vec(1, bucket), _vec(1), kp, vp,
+            _vec(bucket // eng.kv.page_size), *sampling, *state),
+        "suffix": lambda: eng._extend_prefill_fn(bucket).lower(
+            *window, *sampling, *state),
+        "chunk": lambda: eng._extend_chunk_fn(bucket).lower(*window, *state),
+    }
+    return {name: lower[name]().as_text() for name in programs}
 
 
 def _linear_prefill_texts():
     """{program: lowered text} of the linear test model's cold, chunk and
     final-chunk programs as an engine jits them."""
-    from distributed_llm_training_and_inference_system_tpu.serve.sampling import (
-        seed_key_data)
     cfg = get_model_config(LINEAR)
     eng = InferenceEngine(
         cfg, ServeConfig(model=LINEAR, max_batch_size=SLOTS, max_seq_len=128,
@@ -520,76 +569,103 @@ def _linear_prefill_texts():
                          chunked_prefill_tokens=32,
                          decode_steps_per_dispatch=STEPS),
         params=init(cfg, jax.random.PRNGKey(0)))
-
-    def shapes(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
-
-    def vec(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-    params, pool, none = shapes((eng.params, eng.kv.k_pages, eng.kv.v_pages))
-    state = (shapes(eng.kv.state), vec())
-    sampling = shapes(eng._sampling_args(seed_key_data(0), 0,
-                                         SamplingParams()))
-    bucket, table = 32, vec(1, eng.kv.max_pages_per_slot)
-    return {
-        "cold": eng._prefill_fn(bucket).lower(
-            params, vec(1, bucket), vec(1), pool, none, vec(bucket // PS),
-            *sampling, *state).as_text(),
-        "chunk": eng._extend_chunk_fn(bucket).lower(
-            params, vec(1, bucket), vec(1), vec(1), pool, none, table,
-            *state).as_text(),
-        "final chunk": eng._extend_prefill_fn(bucket).lower(
-            params, vec(1, bucket), vec(1), vec(1), pool, none, table,
-            *sampling, *state).as_text(),
-    }
+    texts = _prefill_texts(eng)
+    return {"cold": texts["cold"], "chunk": texts["chunk"],
+            "final chunk": texts["suffix"]}
 
 
-# sha256 of the StableHLO of ``nemotron-h-test``'s COLD prefill program
-# (bucket 32) as the PARENT of PR 44 (6468bc5) lowers it for the engine of
-# ``_hybrid_cold_prefill_text``: ``ssm_scan_prefill`` took an ``h0`` and
-# ``recur_window`` a tail and a state in PR 44, and a caller that passes
-# none lowers to the text it lowered to
-HYBRID_COLD_PREFILL = "915412930ee071bfcdd479c0358ba91d6d85058e9bc7ba8c6795babfcd3ac1ab"
+# ``nemotron-h-test``'s COLD prefill program (bucket 32) for the engine of
+# ``_hybrid_cold_prefill_text``: under the riding gate its cold programs run
+# as before PR 44
+HYBRID_COLD_PREFILL = "ffc2768f7018028473533fbea7e1b8e2311e2623ee681d2061d650237c801541"
 
 
 def _hybrid_cold_prefill_text():
-    from distributed_llm_training_and_inference_system_tpu.serve.sampling import (
-        seed_key_data)
-    eng = _engine(HYBRID)
-
-    def shapes(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
-
-    def vec(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-    params, kp, vp = shapes((eng.params, eng.kv.k_pages, eng.kv.v_pages))
-    sampling = shapes(eng._sampling_args(seed_key_data(0), 0,
-                                         SamplingParams()))
-    bucket = 32
-    return eng._prefill_fn(bucket).lower(
-        params, vec(1, bucket), vec(1), kp, vp, vec(bucket // PS),
-        *sampling, shapes(eng.kv.state), vec()).as_text()
+    return _prefill_texts(_engine(HYBRID), programs=("cold",))["cold"]
 
 
 def test_the_hybrid_models_cold_prefill_program_is_the_parents():
     """The hybrid rides since PR 44; under the gate its cold programs run
-    as before, and lower byte for byte to the parent's text."""
-    import hashlib
-    assert hashlib.sha256(_hybrid_cold_prefill_text().encode()
-                          ).hexdigest() == HYBRID_COLD_PREFILL
+    as before, and lower to the parent's text."""
+    assert _normalised_sha256(_hybrid_cold_prefill_text()) \
+        == HYBRID_COLD_PREFILL
 
 
 def test_the_linear_models_prefill_programs_are_pinned():
     """The delta-rule model rides since PR 43 (its decode program carries
     pieces); under the gate its cold, chunk and final-chunk programs still
-    run: the cold one lowers byte for byte to the parent's text, the two
-    that carry a slot's state to the text PR 43 left them."""
-    import hashlib
-    assert {name: hashlib.sha256(text.encode()).hexdigest()
+    run, and lower to the parent's text."""
+    assert {name: _normalised_sha256(text)
             for name, text in _linear_prefill_texts().items()
             } == LINEAR_PREFILL
+
+
+# every OTHER serve program the six test templates have, at bucket 32 of this
+# module's ``_engine``: the cold prefill, the suffix prefill (a prompt behind
+# a prefix hit, a chunked prompt's last chunk) and the prefill chunk; the
+# dense model's cold prefill again over int8 and int4 pages (the writer's
+# other two page formats). State-space (``M``) layers take no window over
+# their state from an engine (no prefix hit, no chunk: ``kv_cache.REFUSED``)
+SERVE_PROGRAMS = {
+    ("gpt-test", "cold"):
+        "210fa09f5c1aca437b4f155c59c5494c628d1aa99ea601375b6ac5e36a566592",
+    ("gpt-test", "suffix"):
+        "93cd4f2c0056d48d1dbabb6a8fbf4e9dd6cf3681087b4350779254e58d605981",
+    ("gpt-test", "chunk"):
+        "7433216e0dfbbb89644424541c0954c661a7122e554e1b238dc0a90d4b307249",
+    ("gpt-test", "cold, int8 pages"):
+        "02ff8d3a013b076e3df82131eccb6659b7ba520837a405279438de3fd72cc55a",
+    ("gpt-test", "cold, int4 pages"):
+        "0391474717888b103a850b0b2f6282a8b43eda349c1049e1d6ab81d1d3631277",
+    ("olmoe-test", "cold"):
+        "ab21c3118f3f43be52c3541b230418fb2955baf6b0dd4aa8a0008844b9534e0d",
+    ("olmoe-test", "suffix"):
+        "08e897e1b9cbf608786cd15a2fd347c928efccf0f9329f7d3115f94c07621054",
+    ("olmoe-test", "chunk"):
+        "8339b80efceedbe2191e804340127b3eb94a35aa730421cae6fc50c51c5c7c12",
+    (HYBRID, "cold"):
+        "ffc2768f7018028473533fbea7e1b8e2311e2623ee681d2061d650237c801541",
+    ("xing-test", "cold"):
+        "8c542423a6130a07d8dfd02da1b7e6e399e99dc215918a7ebdc97a6e2b4154b2",
+    ("xing-test", "suffix"):
+        "d584bb0235708b2b612d11a8c6bde2763e1e79363dc15aa6dbe5d41fe2d8629c",
+    ("xing-test", "chunk"):
+        "6e9a50f970aea33bf096ee83833f15f9b8e866ef2fa14f59eeac6cef7e6171a5",
+    (LINEAR, "cold"):
+        "c0c621e89ef339225fd1bedf77e76d33bd429dc50f32b85a60e2977fa1ce3646",
+    (LINEAR, "suffix"):
+        "658104beeed0e2f646760e72cd146bcc4378ec3c837ef681b3aebb98ba222760",
+    (LINEAR, "chunk"):
+        "9526342057c850ce28b46ca5af90a4e9c043305f1a2a7719bfe59f26b5e3112c",
+    ("sdar-test", "cold"):
+        "6e71c8a9f8fff78c9cfeebed8c487d3ae44e85ed9f2cecd99efa9cf1eba0ed5c",
+    ("sdar-test", "suffix"):
+        "1c07fca112b5b307939ce2d11242f70d331d9146336db1d1b7adb86b493cff6a",
+    ("sdar-test", "chunk"):
+        "12b6f30238db3a113dd8a99aedf2407a76354e66ec61c37a135aba213538ff07",
+}
+
+
+@functools.cache
+def _serve_program_hashes(name):
+    """{program: normalised hash} of one template's prefill programs: one
+    engine and one lowering a program (no compile), whatever the cases."""
+    programs = tuple(p for n, p in SERVE_PROGRAMS if n == name and "," not in p)
+    hashes = _prefill_texts(_engine(name), programs=programs)
+    for pages in [p for n, p in SERVE_PROGRAMS if n == name and "," in p]:
+        eng = _engine(name, kv_quantization=pages.split()[1])
+        hashes[pages] = _prefill_texts(eng, programs=("cold",))["cold"]
+    return {p: _normalised_sha256(text) for p, text in hashes.items()}
+
+
+@pytest.mark.parametrize("name,program", list(SERVE_PROGRAMS),
+                         ids=[f"{n}: {p}" for n, p in SERVE_PROGRAMS])
+def test_every_serve_program_lowers_to_the_parents_text(name, program):
+    """PR 45 moved the cold prompt's page writer and the state pools' arming
+    out of the engine and made every program return a record: each program
+    of each template is the parent's, operation for operation."""
+    assert _serve_program_hashes(name)[program] \
+        == SERVE_PROGRAMS[(name, program)]
 
 
 def _pools(cfg, pages, fill=None):
@@ -634,8 +710,14 @@ def _slot_rows(cfg, name, slot):
     return (slice(None), slot)
 
 
+def _carry(result):
+    """``decode_scan``'s final carry: its result but for what the steps
+    sampled."""
+    return result._replace(sampled=None, firsts=None)
+
+
 def _same(a, b):
-    """Two carries of ``decode_scan``, leaf for leaf, bit for bit."""
+    """Two trees, leaf for leaf, bit for bit."""
     a, b = (jax.tree_util.tree_leaves(t) for t in (a, b))
     assert len(a) == len(b)
     for x, y in zip(a, b):
@@ -658,17 +740,18 @@ def test_a_step_without_a_piece_samples_what_the_plain_step_samples(name):
             jnp.zeros((B, 2), jnp.uint32), jnp.zeros(B, jnp.float32),
             jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32))
     state = _state(cfg, B, RNG.normal)
-    plain, toks = decode_scan(params, *args, cfg, STEPS, attn_impl="gather",
-                              **state)
-    rode, (toks_r, firsts) = decode_scan(
+    plain = decode_scan(params, *args, cfg, STEPS, attn_impl="gather",
+                        **state)
+    rode = decode_scan(
         params, *args, cfg, STEPS, attn_impl="gather", **state,
         ride=jnp.zeros((STEPS, PIECE_META + C), jnp.int32))
-    np.testing.assert_array_equal(toks, toks_r)
-    np.testing.assert_array_equal(firsts, 0)
-    _same(plain, rode)
+    np.testing.assert_array_equal(plain.sampled, rode.sampled)
+    np.testing.assert_array_equal(rode.firsts, 0)
+    assert plain.firsts is None
+    _same(_carry(plain), _carry(rode))
     for name_, pool in state.get("ssm_state", {}).items():
         idle = _slot_rows(cfg, name_, 2)
-        np.testing.assert_array_equal(rode[-1][name_][idle], pool[idle])
+        np.testing.assert_array_equal(rode.state[name_][idle], pool[idle])
 
 
 @pytest.mark.parametrize("name", MODELS + ["the linear cell's table",
@@ -836,7 +919,7 @@ def _linear_programs(table):
 
     def dispatch(params, args, state, ride=None):
         return decode_scan(params, *args, cfg, STEPS, attn_impl="gather",
-                           ssm_state=state, ride=ride)[0]
+                           ssm_state=state, ride=ride)
 
     def chunk(params, tokens, start, pool, table_row, ok, state):
         return extend_step_forward(
@@ -866,14 +949,15 @@ def test_a_piece_leaves_the_pools_a_chunk_program_leaves(live, start, table):
     plain = dispatch(params, args, state)
     tokens = jnp.asarray(piece[:1, PIECE_META:])
     ok = (jnp.arange(C) < live)[None]
-    _, pool, _, chunked = chunk(params, tokens, jnp.asarray([start]),
-                                plain[2], args[4][2:3], ok, plain[-1])
+    after = chunk(params, tokens, jnp.asarray([start]), plain.k_pages,
+                  args[4][2:3], ok, plain.state)
+    pool, chunked = after.k_pages, after.state
     # the piece's live rows landed in pages 5.. (padding: the scratch page)
-    np.testing.assert_allclose(rode[2][:, 1:], pool[:, 1:], rtol=2e-4,
+    np.testing.assert_allclose(rode.k_pages[:, 1:], pool[:, 1:], rtol=2e-4,
                                atol=2e-5)
     for name, mine in (("conv", (slice(None), slice(None), 2)),
                        ("ssm", (slice(None), 2))):
-        got, want = np.asarray(rode[-1][name]), np.asarray(chunked[name])
+        got, want = np.asarray(rode.state[name]), np.asarray(chunked[name])
         np.testing.assert_allclose(got[mine], want[mine], rtol=2e-4,
                                    atol=2e-5)
         assert not np.allclose(got[mine], np.asarray(state[name])[mine])
@@ -886,7 +970,7 @@ def test_a_piece_leaves_the_pools_a_chunk_program_leaves(live, start, table):
                          (slice(None), slice(None), 2)].set(7.0)
                  for k, v in state.items()}
         again = dispatch(params, args, fresh, jnp.asarray(piece))
-        _same(again[-1], rode[-1])
+        _same(again.state, rode.state)
 
 
 @pytest.fixture(scope="module")
@@ -1024,7 +1108,7 @@ def _hybrid_programs(table):
 
     def dispatch(params, args, state, ride=None):
         return decode_scan(params, *args, cfg, STEPS, attn_impl="gather",
-                           ssm_state=state, ride=ride)[0]
+                           ssm_state=state, ride=ride)
 
     def cold(params, tokens, n):
         bucket = tokens.shape[1]
@@ -1063,23 +1147,23 @@ def test_pieces_leave_the_pools_the_cold_program_leaves(n, table):
     padded = np.full((1, 4 * C), 7, np.int32)
     padded[0, :n] = prompt
     kd, vd, tails, hs = cold(params, jnp.asarray(padded), n)
-    for got, want in ((rode[2], kd), (rode[3], vd)):
+    for got, want in ((rode.k_pages, kd), (rode.v_pages, vd)):
         rows = np.asarray(got)[:, 5:13].transpose(0, 1, 3, 2, 4).reshape(
             got.shape[0], -1, *want.shape[2:])
         np.testing.assert_allclose(rows[:, :n], want[:, :n], rtol=2e-4,
                                    atol=2e-5)
     for name, want in (("conv", tails), ("ssm", hs)):
-        got, was = np.asarray(rode[-1][name]), np.asarray(state[name])
+        got, was = np.asarray(rode.state[name]), np.asarray(state[name])
         np.testing.assert_allclose(got[:, 2], want, rtol=2e-4, atol=2e-5)
         assert not np.allclose(got[:, 2], was[:, 2])
         np.testing.assert_allclose(got[:, :2], np.asarray(
-            plain[-1][name])[:, :2], rtol=2e-4, atol=2e-5)
+            plain.state[name])[:, :2], rtol=2e-4, atol=2e-5)
         assert not np.allclose(got[:, :2], was[:, :2])
         np.testing.assert_array_equal(got[:, 3], was[:, 3])
     # ... and read nothing of the former occupant's
     fresh = {k: v.at[:, 2].set(7.0) for k, v in state.items()}
     again = dispatch(params, args, fresh, jnp.asarray(pieces))
-    _same(again[-1], rode[-1])
+    _same(again.state, rode.state)
 
 
 @pytest.fixture(scope="module")

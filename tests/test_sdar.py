@@ -187,7 +187,7 @@ def test_prefill_then_denoise_windows_through_the_pages(Bd):
     window = jnp.zeros((1, bucket), jnp.int32).at[0, :run].set(
         jnp.asarray(prompt[:run]))
     ok = (jnp.arange(bucket) < run)[None]
-    lg, kp, vp, _ = extend_step_forward(
+    lg, kp, vp, *_ = extend_step_forward(
         params, window, jnp.zeros((1,), jnp.int32), pages, pages, table,
         cfg, write_ok=ok, return_moe_stats=True)
     want = diffusion_decoder.logits(params, prompt[:run], config)
@@ -199,7 +199,7 @@ def test_prefill_then_denoise_windows_through_the_pages(Bd):
         if fixed is not None:
             canvas[-1] = fixed
             step = step.at[0, -1].set(fixed)
-        lg, kp, vp = extend_step_forward(
+        lg, kp, vp, *_ = extend_step_forward(
             params, step, jnp.asarray([run], jnp.int32), kp, vp, table, cfg)
         want = diffusion_decoder.logits(params, canvas, config,
                                         positions=range(run, run + Bd))

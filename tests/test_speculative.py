@@ -86,7 +86,7 @@ class TestExtendForward:
         T = tokens.shape[1]
         kp, vp = self._pages(cfg)
         tables = jnp.asarray([[1, 2, 0, 0]], jnp.int32)  # page 0 = scratch
-        logits, kp, vp = extend_step_forward(
+        logits, kp, vp, *_ = extend_step_forward(
             params, tokens, jnp.zeros((1,), jnp.int32), kp, vp, tables, cfg)
         dense = gpt.forward(params, tokens, cfg)
         np.testing.assert_allclose(np.asarray(logits), np.asarray(dense),
@@ -101,10 +101,10 @@ class TestExtendForward:
         n0 = 6
         kp, vp = self._pages(cfg)
         tables = jnp.asarray([[1, 2, 0, 0]], jnp.int32)
-        _, kp, vp = extend_step_forward(
+        _, kp, vp, *_ = extend_step_forward(
             params, full[:, :n0], jnp.zeros((1,), jnp.int32), kp, vp,
             tables, cfg)
-        logits_tail, kp, vp = extend_step_forward(
+        logits_tail, kp, vp, *_ = extend_step_forward(
             params, full[:, n0:], jnp.full((1,), n0, jnp.int32), kp, vp,
             tables, cfg)
         dense = gpt.forward(params, full, cfg)
@@ -120,7 +120,7 @@ class TestExtendForward:
         kp, vp = self._pages(cfg)
         tables = jnp.asarray([[1, 0, 0, 0]], jnp.int32)
         write_ok = jnp.asarray([[True, True, False, False]])
-        _, kp2, _ = extend_step_forward(
+        _, kp2, *_ = extend_step_forward(
             params, tokens, jnp.zeros((1,), jnp.int32), kp, vp, tables, cfg,
             write_ok=write_ok)
         page1 = np.asarray(kp2[:, 1])          # [Nkv, PS, D]
@@ -157,19 +157,19 @@ class TestSpeculativeEngine:
         shape = (cfg.num_layers, 8, cfg.num_kv_heads, 8, cfg.head_dim)
         kp, vp = jnp.zeros(shape), jnp.zeros(shape)
         tables = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
-        _, kp, vp = extend_step_forward(
+        _, kp, vp, *_ = extend_step_forward(
             params, jnp.asarray([prompt], jnp.int32),
             jnp.zeros((1,), jnp.int32), kp, vp, tables, cfg)
 
         tokens = jnp.asarray([[chain[0], chain[1], chain[2], chain[3]]],
                              jnp.int32)
-        emitted, n_emit, _, _ = speculative_verify(
+        emitted, n_emit = speculative_verify(
             params, tokens, jnp.asarray([n], jnp.int32), kp, vp, tables,
             jnp.asarray([n + 64], jnp.int32),
             jnp.asarray(np.asarray(jax.random.key_data(
                 jax.random.PRNGKey(0)))[None], jnp.uint32),
             jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
-            jnp.ones((1,), jnp.float32), cfg)
+            jnp.ones((1,), jnp.float32), cfg).sampled
         assert int(n_emit[0]) == T
         assert [int(t) for t in np.asarray(emitted[0])] == chain[1:1 + T]
 

@@ -1,0 +1,132 @@
+"""What the files of ``tests/test_tpu_compile_*.py`` share: the cells' shapes
+and the helpers that compile a function for the DESCRIBED v5e and read the
+result (the ``topo`` / ``one_chip`` / ``as_tpu`` fixtures are in
+``tests/conftest.py``). Not a test module: nothing here is collected, and
+nothing here loads libtpu or describes a topology."""
+
+import jax
+import jax.numpy as jnp
+
+# gpt-1b / gpt-750m head layout and the mistral-7b GQA layout
+LAYOUTS = {"mha16": (16, 16), "gqa32x8": (32, 8)}
+D, PS, MAXP = 128, 64, 32          # head_dim, ServeConfig.kv_block_size,
+                                   # max_seq_len 2048 / page 64
+# the latent cell's page size, pages a slot and pages of the pool (the linear
+# cell's latent pool has pages of the same size)
+LATENT_PS, LATENT_MAXP, LATENT_PAGES = 256, 68, 1307
+LATENT_POOL_BYTES = 7 * LATENT_PAGES * LATENT_PS * 640 * 2
+# what a piece's 256 rows may add to the program's temporaries: half a MB a
+# row (a row's four float32 streams are 57 KB, its 32 absorbed queries and
+# outputs 74 KB, its 4 expert choices' hidden rows 8 KB, a few of each live
+# at once); the compiler read 84 MB over the plain program's 270 (PR 41)
+PIECE_ROWS_BYTES = LATENT_PS << 19
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "lowered without a Mosaic kernel (interpret mode?)"
+    return compiled
+
+
+def _kernel_grids(fn, *args) -> list[tuple[int, ...]]:
+    """The grid (``iteration_bounds``) of every Mosaic kernel in the lowered
+    text of ``fn``: the custom call carries its module as MLIR bytecode."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    text = jax.jit(fn).lower(*args).as_text()
+    bodies = re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)', text)
+    assert bodies, "no tpu_custom_call in the lowered text"
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True    # the versioned wrapper
+    grids = []
+    with ctx:
+        for body in bodies:
+            module = str(ir.Module.parse(base64.b64decode(body)))
+            bounds, = re.findall(r"iteration_bounds = array<i64: ([^>]*)>",
+                                 module)
+            grids.append(tuple(int(n) for n in bounds.split(",")))
+    return grids
+
+
+def _sds(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+def _pages(sds, num_pages, nkv, kv, layers=()):
+    """One layer's pages, or the [L, NP, ...] pool with ``layers=(L,)``."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
+        QuantPages)
+    if kv == "int8":
+        return QuantPages(sds((*layers, num_pages, nkv, PS, D), jnp.int8),
+                          sds((*layers, num_pages, nkv, PS), jnp.float32))
+    return sds((*layers, num_pages, nkv, PS, D), jnp.bfloat16)
+
+
+# the serving cells' pools (benchmark/configs): layers, pages a layer
+CELL_POOLS = {"gqa32x8": (16, 715), "mha16": (10, 715)}
+
+
+def _cell_call(fn, sds, layout, kv, q_shape):
+    """``fn`` on a cell's whole pool with a traced layer index, 32 slots:
+    (callable, argument shapes)."""
+    nq, nkv = LAYOUTS[layout]
+    n_layers, num_pages = CELL_POOLS[layout]
+    pool = _pages(sds, num_pages, nkv, kv, layers=(n_layers,))
+
+    def call(q, kp, vp, tables, lengths, layer):
+        return fn(q, kp, vp, tables, lengths, impl="auto", layer=layer)
+    return call, (sds(q_shape(32, nq), jnp.bfloat16), pool, pool,
+                  sds((32, MAXP), jnp.int32), sds((32,), jnp.int32),
+                  sds((), jnp.int32))
+
+
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+              "u32": 4, "f32": 4}
+
+
+def _no_copy_of(text: str, shapes: list[str],
+                fused_into_at_most: int = 0) -> None:
+    """No ``copy`` of a pool or a stack (a result that starts with one of
+    ``shapes``) in an optimised HLO text, inside a fused computation or
+    out of one.
+
+    ``fused_into_at_most`` (bytes; the carrying linear program alone asks
+    for it): a copy FUSED into an operation that keeps a small part of it
+    (a slot's rows sliced out of a pool: the fusion computes those rows
+    alone) passes where everything the OUTERMOST fusion that holds it hands
+    out is no more than so many bytes; a fusion that hands the copy on
+    converted or transposed is pool-sized, and fails."""
+    import math
+    import re
+    computation, called_from = None, {}     # callee -> (caller, its line)
+    copies = []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            computation = head.group(1)
+            continue
+        for callee in re.findall(r"calls=%?([\w.\-]+)", line):
+            called_from[callee] = (computation, line)
+        if " copy(" in line and any(
+                line.lstrip().split(" = ", 1)[-1].startswith(shape)
+                for shape in shapes):
+            copies.append((computation, line))
+    for at, line in copies:
+        made = line
+        while fused_into_at_most and "fused" in at and at in called_from:
+            at, made = called_from[at]
+        result = made.lstrip().split(" = ", 1)[-1].split(" fusion(")[0]
+        handed_out = sum(
+            _HLO_BYTES.get(kind, 8) * math.prod(int(n) for n in dims.split(",") if n)
+            for kind, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]",
+                                         result))
+        assert made is not line and handed_out <= fused_into_at_most, (
+            f"a pool- or stack-sized copy: {line[:200]}\n"
+            f"(handed out by: {made[:200]})")
