@@ -1364,6 +1364,112 @@ def child_kernels() -> None:
     emit("packer", {"impl": impl, "detail": detail})
     shutil.rmtree(shard_dir, ignore_errors=True)
 
+    # (PR 46) ``solar_open2``: one GATED softmax layer without rope and one
+    # delta-rule layer at 64 heads whose beta reaches 2, at
+    # Solar-Open2-250B's widths, each against float32; then a SNAPSHOT of a
+    # slot's state taken and armed
+    from benchmark.reference import sessions_decoder
+    from distributed_llm_training_and_inference_system_tpu.config.presets import (
+        SOLAR_OPEN2_PUBLISHED, SOLAR_OPEN2_TEST_PUBLISHED)
+    from distributed_llm_training_and_inference_system_tpu.models.layers import (
+        attend_fresh, attention_mixer, kda_mixer, model_rope_frequencies)
+    from distributed_llm_training_and_inference_system_tpu.ops import kda
+    pub = dict(SOLAR_OPEN2_TEST_PUBLISHED if small else SOLAR_OPEN2_PUBLISHED,
+               num_hidden_layers=2, gqa_layers=[0])
+    pub.update(n_routed_experts=4, router_experts=pub["n_routed_experts"])
+    so = dataclasses.replace(ModelConfig.from_published(pub), dtype="bfloat16")
+    blocks = jax.jit(lambda k: gpt.init(so, k, jnp.bfloat16)["blocks"])(
+        next(key))
+    kd, H = so.kda, so.hidden_size
+    S, n_live, steps, slots = (32, 21, 3, 4) if small else (256, 200, 4, 8)
+    x = jax.random.normal(next(key), (1, S + steps, H), jnp.float32)
+    pos = jnp.arange(S)[None]
+    att = jax.tree_util.tree_map(lambda a: a[0], blocks["attn"])
+    h_att = rms_norm(x[:, :S], att["norm"]["scale"], so.norm_eps).astype(
+        jnp.bfloat16)
+    got_a, _ = jax.jit(lambda h, p: attention_mixer(
+        h, p, so, pos, model_rope_frequencies(so),
+        attend_fresh(pos, None)))(h_att, att)
+    w_att = {k: att[k]["kernel"] for k in ("q", "k", "v", "gate", "o")}
+    ref_gated, ref_plain = (sessions_decoder._attention(
+        h_att[0].astype(jnp.float32), w_att, pub, wrong)
+        for wrong in (None, "no_gate"))
+    check(f"attention_mixer gated, no rope [{S} rows, {so.num_heads} query / "
+          f"{so.num_kv_heads} key-value heads of {so.head_dim}]", got_a[0],
+          ref_gated)
+    if float(jnp.abs(ref_gated - ref_plain).max()) < 0.1 * float(
+            jnp.abs(ref_gated).max()):
+        failures.append("the gate moves the softmax layer's output by "
+                        "under a tenth of it: the check cannot see it")
+    mix = jax.tree_util.tree_map(lambda a: a[0], blocks["kda"])
+    mix["gate_norm"] = {"scale": jax.random.uniform(
+        next(key), mix["gate_norm"]["scale"].shape, minval=-0.5, maxval=0.5
+    ).astype(jnp.bfloat16)}
+    h_k = rms_norm(x, mix["norm"]["scale"], so.norm_eps).astype(jnp.bfloat16)
+    seq = jnp.concatenate([h_k[0, :n_live], h_k[0, S:]]).astype(jnp.float32)
+    w_k = {k: mix[k]["kernel"] for k in ("in_proj", "conv", "f_b", "g_b",
+                                         "out_proj")}
+    w_k.update(A_log=mix["A_log"], dt_bias=mix["dt_bias"],
+               gate_norm=mix["gate_norm"]["scale"])
+    ref_k, _ = sessions_decoder._kda(seq, w_k, pub, None, seq.shape[0], 0, 0)
+    halved, _ = sessions_decoder._kda(seq, w_k, pub, "beta_unscaled",
+                                      seq.shape[0], 0, 0)
+    if float(jnp.abs(ref_k - halved).max()) < 0.05 * float(
+            jnp.abs(ref_k).max()):
+        failures.append("beta's factor 2 moves the K layer's output by "
+                        "under a twentieth of it: the check cannot see it")
+    live = jnp.arange(S)[None] < n_live
+    got_w, (tail, kstate) = jax.jit(lambda h, p: kda_mixer(
+        h, p, so, kda.recur_window(so, live)))(h_k[:, :S], mix)
+    check(f"kda_mixer window, beta in (0, 2) [{S} rows, {n_live} live, "
+          f"{kd.num_heads} heads of {kd.head_dim}]", got_w[0, :n_live],
+          ref_k[:n_live], tol=3e-2)
+    # the pools as the engine lays them: conv [Lk, K-1, slots, C], state
+    # [Lk, slots, nh, dk, dv]; slot 2 holds the window's state in layer 1
+    conv_pool = jnp.zeros((2, kd.conv_kernel - 1, slots, kd.conv_channels),
+                          jnp.bfloat16).at[1, :, 2].set(
+        tail[0].astype(jnp.bfloat16))
+    state_pool = jnp.zeros((2, slots, *kstate.shape[1:]), jnp.float32
+                           ).at[1, 2].set(kstate[0])
+    snaps = kda.snapshot_pools(conv_pool, state_pool, 3)
+    take = jax.jit(kda.kda_snapshot_take, donate_argnums=(2, 3))
+    arm = jax.jit(kda.kda_snapshot_arm, donate_argnums=(0, 1))
+    snap_conv, snap_state = take(conv_pool, state_pool, snaps["conv"],
+                                 snaps["ssm"], jnp.int32(2), jnp.int32(1))
+
+    def decode_slot(slot, conv_pool, state_pool):
+        ok = jnp.arange(slots)[:, None] == slot
+        step = jax.jit(lambda h, c, s_, p: kda_mixer(
+            h, p, so, kda.recur_step(so, c, s_, 1, ok)),
+            donate_argnums=(1, 2))
+        decoded = []
+        for t in range(steps):
+            h_t = jnp.zeros((slots, 1, H), jnp.bfloat16).at[slot, 0].set(
+                h_k[0, S + t])
+            out, (conv_pool, state_pool) = step(h_t, conv_pool, state_pool,
+                                                mix)
+            decoded.append(out[slot, 0])
+        return jnp.stack(decoded), conv_pool, state_pool
+    decoded, conv_pool, state_pool = decode_slot(2, conv_pool, state_pool)
+    check(f"kda_mixer decode, beta in (0, 2) [{steps} steps over the state "
+          f"pools, slot 2 of {slots} live, {kd.num_heads} heads]", decoded,
+          ref_k[n_live:], tol=3e-2)
+    if float(jnp.abs(state_pool[:, jnp.asarray([0, 1, 3])]).max()) != 0.0:
+        failures.append("kda decode wrote an idle slot's state")
+    # slot 0 armed from the entry decodes the same tokens to the same bits,
+    # whatever slot 2 did to its own rows since the snapshot was taken
+    conv_pool, state_pool = arm(conv_pool, state_pool, snap_conv, snap_state,
+                                jnp.int32(0), jnp.int32(1))
+    again, conv_pool, state_pool = decode_slot(0, conv_pool, state_pool)
+    emit("kernel", {"name": "kda snapshot taken from slot 2, armed into "
+                            "slot 0: the decode steps repeat bit for bit",
+                    "equal": bool(jnp.array_equal(again, decoded))})
+    if not bool(jnp.array_equal(again, decoded)):
+        failures.append("a slot armed from a snapshot decodes other values "
+                        "than the slot the snapshot was taken from")
+    if float(jnp.abs(snap_state[:, jnp.asarray([0, 2])]).max()) != 0.0:
+        failures.append("a snapshot's take wrote another entry")
+
     if failures:
         print("kernel check failures:\n  " + "\n  ".join(failures),
               file=sys.stderr)

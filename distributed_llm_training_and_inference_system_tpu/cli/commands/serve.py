@@ -49,6 +49,13 @@ def app(ctx):
 @click.option("--prefix-cache/--no-prefix-cache", default=True,
               show_default=True,
               help="Share full prompt-prefix KV pages between requests.")
+@click.option("--state-snapshot-entries", default=0, show_default=True,
+              type=int,
+              help="A model with delta-rule (K) layers: entries of the "
+                   "snapshot pool (a slot's recurrent state at a prompt's "
+                   "last page boundary), which lets a prefix-cache hit be "
+                   "followed through the state (0 = such a model's prefix "
+                   "reuse stays off).")
 @click.option("--tensor-parallel", default=1, show_default=True, type=int,
               help="Shard the model over this many local devices "
                    "(Megatron TP; needs num_kv_heads % tp == 0).")
@@ -475,7 +482,8 @@ def app(ctx):
                    "stream log supports reconnect instead.")
 def start(model_name, artifact, host, port, max_batch_size, max_seq_len,
           kv_block_size, kv_hbm_gb, scheduler, dtype, prometheus_port,
-          speculative, spec_tokens, prefix_cache, tensor_parallel,
+          speculative, spec_tokens, prefix_cache, state_snapshot_entries,
+          tensor_parallel,
           quantization, chunked_prefill, prefill_budget_tokens,
           decode_steps, max_queue, swap_space_gb, spec_ngram,
           spec_min_acceptance, kv_quantization, admission,
@@ -532,6 +540,7 @@ def start(model_name, artifact, host, port, max_batch_size, max_seq_len,
         kv_block_size=kv_block_size, kv_hbm_budget_gb=kv_hbm_gb,
         scheduler=scheduler, dtype=dtype, speculative=speculative,
         speculative_tokens=spec_tokens, prefix_caching=prefix_cache,
+        state_snapshot_entries=state_snapshot_entries,
         speculative_ngram=spec_ngram,
         speculative_min_acceptance=spec_min_acceptance,
         tensor_parallel=tensor_parallel, quantization=quantization,

@@ -266,6 +266,57 @@ KIMI_LINEAR_TEST_PUBLISHED = {
 TEST_TEMPLATES["kimi-linear-test"] = ModelConfig.from_published(
     KIMI_LINEAR_TEST_PUBLISHED)
 
+# Solar-Open2-250B as published (``model_type: solar_open2``): 48 decoder
+# layers, every fourth from 0 a gated softmax GQA layer with no position
+# embedding, the others delta-rule linear attention whose beta reaches 2,
+# every feed-forward 320 sigmoid-routed experts of width 1280 (8 a token)
+# and one shared expert. ``intermediate_size`` is used by no layer
+# (``first_k_dense_replace`` 0)
+SOLAR_OPEN2_PUBLISHED = {
+    "name": "solar-open2-250b", "model_type": "solar_open2",
+    "hidden_size": 4096, "num_hidden_layers": 48, "num_attention_heads": 64,
+    "head_dim": 128, "num_key_value_heads": 8, "vocab_size": 196608,
+    "intermediate_size": 10240, "moe_intermediate_size": 1280,
+    "rms_norm_eps": 1e-5, "rope_theta": 10000, "tie_word_embeddings": False,
+    "max_position_embeddings": 1048576, "first_k_dense_replace": 0,
+    "use_rope": False, "gqa_interval": 3,
+    "gqa_layers": list(range(0, 48, 4)), "use_gqa_gate": True,
+    "kda_use_full_proj": False, "kda_allow_neg_eigval": True,
+    "n_routed_experts": 320, "n_shared_experts": 1, "norm_topk_prob": True,
+    "routed_scaling_factor": 1, "num_experts_per_tok": 8,
+    "partial_rotary_factor": 1,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 128,
+                           "num_heads": 64, "num_kv_heads": None},
+}
+MODEL_TEMPLATES["solar-open2-250b"] = ModelConfig.from_published(
+    SOLAR_OPEN2_PUBLISHED)
+
+# ... and its shape in small, in the same keys (the plain reference of the
+# benchmark reads these): one period ``* K K K`` and one more ``*``, every
+# layer's feed-forward 16 sigmoid-routed experts (3 a token) and a shared
+# one. ALL 16 are held here; ``solar_open2_test_share`` is one chip's share
+SOLAR_OPEN2_TEST_PUBLISHED = {
+    **SOLAR_OPEN2_PUBLISHED,
+    "name": "solar-open2-test", "hidden_size": 64, "num_hidden_layers": 5,
+    "num_attention_heads": 4, "head_dim": 16, "num_key_value_heads": 2,
+    "vocab_size": 256, "intermediate_size": 96, "moe_intermediate_size": 32,
+    "max_position_embeddings": 512, "gqa_layers": [0, 4],
+    "n_routed_experts": 16, "num_experts_per_tok": 3, "dtype": "float32",
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 16,
+                           "num_heads": 4, "num_kv_heads": None},
+}
+TEST_TEMPLATES["solar-open2-test"] = ModelConfig.from_published(
+    SOLAR_OPEN2_TEST_PUBLISHED)
+
+
+def solar_open2_test_share(held: int, first: int = 0) -> ModelConfig:
+    """``solar-open2-test`` with ``held`` of its 16 experts held from
+    ``first`` on (a chip's share under expert parallelism 16 / ``held``):
+    the router keeps its 16 outputs."""
+    return ModelConfig.from_published({
+        **SOLAR_OPEN2_TEST_PUBLISHED, "n_routed_experts": held,
+        "router_experts": 16, "first_expert": first})
+
 
 def get_model_config(name: str) -> ModelConfig:
     """Look up a template by name (also accepts test templates), or read a

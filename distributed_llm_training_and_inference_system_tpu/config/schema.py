@@ -294,7 +294,7 @@ class SSMConfig:
         )
 
 
-@dataclass(frozen=True)     # three ints: hashable, a jitted function's static
+@dataclass(frozen=True)     # hashable: a jitted function's static
 class KDAConfig:
     """Kimi Delta Attention sizes (the ``K`` layers of a layer table; the
     published ``linear_attn_config``): ``num_heads`` heads, each a
@@ -302,10 +302,14 @@ class KDAConfig:
     with one decay a CHANNEL of the key (ops/kda.py), a depthwise causal
     conv of width ``conv_kernel`` over q, k and v. The two low-rank pairs (decay and output gate) have
     the rank ``head_dim``. The state is cached in float32, by construction
-    (serve/kv_cache.py), as ``SSMConfig``'s."""
+    (serve/kv_cache.py), as ``SSMConfig``'s. ``allow_neg_eigval``
+    (``solar_open2``'s ``kda_allow_neg_eigval``): ``beta = 2 sigmoid(b)``,
+    so that the Householder factor ``I - beta k k^T`` has eigenvalues in
+    [-1, 1] and the rule may reflect."""
     num_heads: int = 0              # 0 = the model has no such layer
     head_dim: int = 128
     conv_kernel: int = 4
+    allow_neg_eigval: bool = False
 
     @property
     def inner_size(self) -> int:
@@ -334,6 +338,8 @@ class KDAConfig:
             head_dim=int(d.get("head_dim", 128)),
             conv_kernel=int(_take(d, "conv_kernel",
                                   "short_conv_kernel_size", default=4)),
+            allow_neg_eigval=_parse_bool("allow_neg_eigval", d.get(
+                "allow_neg_eigval", False)),
         )
 
 
@@ -444,6 +450,10 @@ class ModelConfig:
     # values without rotating them (``mla_use_nope``: positions come from
     # the ``K`` layers)
     position_embedding: str = "rope"
+    # ``solar_open2``'s ``use_gqa_gate``: a ``*`` layer's attention output
+    # is multiplied by sigmoid(x W_g) (one projection of the layer's normed
+    # input, H -> num_heads * head_dim, elementwise) before ``o``
+    attention_gate: bool = False
     # False: the feed-forward is down(act(up(x))), two kernels (no gate)
     mlp_gated: bool = True
     # latent attention (``*`` layers keep ONE compressed row a token)
@@ -585,6 +595,11 @@ class ModelConfig:
                 raise ConfigError(
                     "layer_pattern has K layers: kda.num_heads and head_dim "
                     f"must be >= 1, conv_kernel >= 2 (got {k})")
+        if self.attention_gate and (not self.layer_pattern or self.is_latent):
+            raise ConfigError(
+                "attention_gate is carried by the layer table's K/V "
+                "attention (``*`` layers of a model without latent "
+                "attention)")
         if self.is_latent or self.hc_mult > 1:
             a = self.mla
             if not self.layer_pattern:
@@ -673,6 +688,8 @@ class ModelConfig:
                         + a.kv_lora_rank * n
                         * (a.qk_nope_head_dim + a.v_head_dim)
                         + n * a.v_head_dim * h)
+            elif self.attention_gate:
+                attn += h * q_dim
             mixer = {
                 "M": h * (2 * s.inner_size + 2 * s.n_groups * s.state_size
                           + s.num_heads)
@@ -730,6 +747,34 @@ class ModelConfig:
         layers = int(_take(d, "layers", "num_layers", "num_hidden_layers",
                            default=12))
         linear = d.get("linear_attn_config") or {}
+        solar = d.get("model_type") == "solar_open2"
+        if solar and not pattern:
+            # ``solar_open2`` lists, 0-INDEXED, the decoder layers that mix
+            # by softmax attention (``gqa_layers``); every other one mixes
+            # by delta-rule linear attention (``K``). A decoder layer is a
+            # mixer entry then a feed-forward entry, as above
+            gqa_at = [int(i) for i in d.get("gqa_layers") or []]
+            if sorted(set(gqa_at)) != gqa_at or any(
+                    not 0 <= i < layers for i in gqa_at):
+                raise ConfigError(
+                    f"gqa_layers {gqa_at} must name decoder layers of the "
+                    f"{layers} once each, ascending (0-indexed)")
+            if d.get("kda_use_full_proj"):
+                raise ConfigError(
+                    "kda_use_full_proj: the K layer's decay and output gate "
+                    "are the two low-rank pairs; full projections are not "
+                    "carried")
+            if linear.get("num_kv_heads") not in (None, linear.get("num_heads")):
+                raise ConfigError(
+                    "linear_attn_config.num_kv_heads: the K layer keeps one "
+                    "key and value head a query head (null)")
+            dense = int(_take(d, "first_k_dense_replace", default=0))
+            pattern = "".join(
+                ("*" if i in gqa_at else "K") + ("D" if i < dense else "E")
+                for i in range(layers))
+            layers = len(pattern)
+            linear = dict(linear, allow_neg_eigval=d.get(
+                "kda_allow_neg_eigval", False))
         if latent and not pattern and "num_hidden_layers" in d:
             # a published config.json counts decoder layers: each is an
             # attention sub-layer then a feed-forward one, two entries of
@@ -827,7 +872,10 @@ class ModelConfig:
                                      "mhc_h_res_clamp_max", default=30.0)),
             position_embedding=str(_take(
                 d, "position_embedding", default=(
-                    "none" if latent and d.get("mla_use_nope") else "rope"))),
+                    "none" if latent and d.get("mla_use_nope")
+                    or d.get("use_rope") is False else "rope"))),
+            attention_gate=_parse_bool("attention_gate", _take(
+                d, "attention_gate", "use_gqa_gate", default=False)),
             # squared ReLU comes without a gate (``nemotron_h``'s
             # ``mlp_hidden_act: relu2``) unless the dict says otherwise
             mlp_gated=_parse_bool("mlp_gated", _take(
@@ -846,7 +894,7 @@ class ModelConfig:
              if not isinstance(v, (dict, list))}
         d["rope"] = {"base": config.get("rope_theta", 10000.0)}
         for group in ("rope_scaling", "linear_attn_config",
-                      "mlp_only_layers"):
+                      "mlp_only_layers", "gqa_layers"):
             if config.get(group):
                 d[group] = config[group]
         return cls.from_dict(d)
@@ -1286,6 +1334,13 @@ class ServeConfig:
     # allocator runs dry). A hit skips that prefix's prefill compute —
     # shared-system-prompt workloads see near-zero marginal TTFT.
     prefix_caching: bool = True
+    # prefix reuse THROUGH a recurrent state (a model with ``K`` layers):
+    # entries of the snapshot pool, each a slot's rows of the state pools
+    # as they stood at a prompt's last whole page boundary. A page hit is
+    # followed as far as a snapshot stands; the slot is armed from the
+    # entry and the prompt prefilled from there. 0: no pool, and such a
+    # model's prefix reuse stays off (serve/kv_cache.py ``REFUSED``)
+    state_snapshot_entries: int = 0
     # Megatron-style tensor-parallel serving over a tp mesh axis: params
     # shard per parallel.sharding.PARAM_RULES, KV pages shard over the
     # kv-head axis, GSPMD inserts the per-layer collectives. Requires
@@ -1364,6 +1419,8 @@ class ServeConfig:
             raise ConfigError("quantization must be none|int8|int4|int4-awq")
         if self.chunked_prefill_tokens < 0:
             raise ConfigError("chunked_prefill_tokens must be >= 0")
+        if self.state_snapshot_entries < 0:
+            raise ConfigError("state_snapshot_entries must be >= 0")
         if self.latency_dispatch_steps < 0:
             raise ConfigError("latency_dispatch_steps must be >= 0")
         # quantized + tensor_parallel is supported for int8 AND int4:

@@ -429,6 +429,14 @@ COUNTER_FLOW: tuple[CounterFlow, ...] = (
                 "state_carry_chunks", None),
     CounterFlow("InferenceEngine", "total_prefix_cached_tokens",
                 "prefix_cached_tokens", None),
+    # prefix reuse through a recurrent state (stats()["kda"], with a
+    # snapshot pool alone)
+    CounterFlow("InferenceEngine", "total_snapshot_hits",
+                "snapshot_hits", None),
+    CounterFlow("InferenceEngine", "total_snapshot_misses",
+                "snapshot_misses", None),
+    CounterFlow("InferenceEngine", "total_snapshot_tokens_skipped",
+                "snapshot_tokens_skipped", None),
     # feeds reprefill_tokens_avoided through the supervisor snapshot's
     # migration section (replica.prefix_cache_stats -> requeue_cached)
     CounterFlow("InferenceEngine", "total_requeue_cached_tokens",

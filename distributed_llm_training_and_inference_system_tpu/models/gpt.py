@@ -183,6 +183,9 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
             "o": {"kernel": dense(next(keys), La, Nq * D, H,
                                   scale=resid_std)},
         }
+        if cfg.attention_gate:
+            blocks["attn"]["gate"] = {
+                "kernel": dense(next(keys), La, H, Nq * D)}
     if Le:
         m = cfg.moe
         E, Fs = m.num_experts, m.shared_expert_size

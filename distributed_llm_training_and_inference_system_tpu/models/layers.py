@@ -698,8 +698,10 @@ def attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,
                     matmul=dense_matmul) -> tuple[jax.Array, Any]:
     """Grouped-query attention over the normed stream ``h`` [B, S, H]:
     projections, optional q/k norms and biases, rope unless
-    ``cfg.position_embedding`` is "none", ``attend``, the output
-    projection. Returns (out [B, S, H], ``attend``'s state)."""
+    ``cfg.position_embedding`` is "none", ``attend``, with
+    ``cfg.attention_gate`` the output times ``sigmoid(h W_g)`` elementwise
+    (``solar_open2``), the output projection. Returns (out [B, S, H],
+    ``attend``'s state)."""
     B, S, _ = h.shape
     D, Nq, Nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
     q = qk_project_norm(matmul(h, layer["q"]["kernel"]), layer, "q",
@@ -716,6 +718,11 @@ def attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,
         k = apply_rope(k, positions, inv_freq)
 
     out, state = attend(q, k, v)
+    if cfg.attention_gate:
+        with jax.named_scope("attn_gate"):
+            gate = matmul(h, layer["gate"]["kernel"]).reshape(B, S, Nq, D)
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(h.dtype)
     out = matmul(out.reshape(B, S, Nq * D), layer["o"]["kernel"])
     # named so remat policies can pin it resident: the flash kernel's output
     # is a custom call, not a dot, so dots_* policies rematerialise it —

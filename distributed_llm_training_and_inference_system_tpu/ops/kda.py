@@ -338,8 +338,9 @@ def _heads(act: jax.Array, f: jax.Array, b: jax.Array, p: dict, kd,
     [.., 3 * d_in], the decay's second projection ``f`` [.., d_in] and the
     beta logits ``b`` [.., nh]: q and k normalised (q times dk^-1/2), the
     log decays ``-exp(A_log) softplus(f + dt_bias)`` a channel and
-    ``sigmoid(b)``, both float32 and 0 where ``alive`` is False. ``kd``:
-    the model's ``KDAConfig``."""
+    ``sigmoid(b)`` (times 2 where ``kd.allow_neg_eigval``: the factor
+    ``I - beta k k^T`` may then reflect), both float32 and 0 where ``alive``
+    is False. ``kd``: the model's ``KDAConfig``."""
     nh, hd, d_in = kd.num_heads, kd.head_dim, kd.inner_size
     lead, f32 = act.shape[:-1], jnp.float32
     q, k, v = (act[..., i * d_in:(i + 1) * d_in].reshape(*lead, nh, hd)
@@ -350,6 +351,8 @@ def _heads(act: jax.Array, f: jax.Array, b: jax.Array, p: dict, kd,
          * jax.nn.softplus(f.astype(f32) + p["dt_bias"].astype(f32)
                            ).reshape(*lead, nh, hd))
     beta = jax.nn.sigmoid(b.astype(f32))
+    if kd.allow_neg_eigval:
+        beta = 2.0 * beta
     if alive is not None:
         g = jnp.where(alive[..., None, None], g, 0.0)
         beta = jnp.where(alive[..., None], beta, 0.0)
@@ -526,3 +529,45 @@ def recur_chunk(cfg, tail: jax.Array, S0: jax.Array,
         new_tail = _tail_after(padded, length, kd.conv_kernel)
         return o.reshape(B, T, -1), (new_tail[0], S1[0])
     return recur
+
+
+# ---------------------------------------------------------------------------
+# Snapshots of a slot's state (prefix reuse through the recurrent state)
+# ---------------------------------------------------------------------------
+
+def snapshot_pools(conv_pool: jax.Array, state_pool: jax.Array,
+                   entries: int) -> dict:
+    """Zeroed snapshot pools in the state pools' own layout, an ENTRY where
+    those have a slot: ``conv`` [Lk, K-1, entries, C], ``ssm``
+    [Lk, entries, nh, dk, dv] float32."""
+    return {
+        "conv": jnp.zeros((*conv_pool.shape[:2], entries,
+                           conv_pool.shape[3]), conv_pool.dtype),
+        "ssm": jnp.zeros((state_pool.shape[0], entries,
+                          *state_pool.shape[2:]), state_pool.dtype),
+    }
+
+
+def kda_snapshot_take(conv_pool: jax.Array, state_pool: jax.Array,
+                      snap_conv: jax.Array, snap_state: jax.Array,
+                      slot: jax.Array, entry: jax.Array
+                      ) -> tuple[jax.Array, jax.Array]:
+    """The snapshot pools with ``entry``'s rows SET to ``slot``'s rows of
+    the state pools in every ``K`` layer (a program of its own between two
+    dispatches: it carries the pools through no loop)."""
+    with jax.named_scope("kda_snapshot_take"):
+        return (snap_conv.at[:, :, entry].set(conv_pool[:, :, slot]),
+                snap_state.at[:, entry].set(state_pool[:, slot]))
+
+
+def kda_snapshot_arm(conv_pool: jax.Array, state_pool: jax.Array,
+                     snap_conv: jax.Array, snap_state: jax.Array,
+                     slot: jax.Array, entry: jax.Array
+                     ) -> tuple[jax.Array, jax.Array]:
+    """The state pools with ``slot``'s rows SET to ``entry``'s rows of the
+    snapshot pools: the slot then stands where the snapshot's token prefix
+    ends, and a window that starts there (``slot_state`` with ``start`` >
+    0) reads it."""
+    with jax.named_scope("kda_snapshot_arm"):
+        return (conv_pool.at[:, :, slot].set(snap_conv[:, :, entry]),
+                state_pool.at[:, slot].set(snap_state[:, entry]))

@@ -147,16 +147,14 @@ def can_carry(cfg: ModelConfig) -> bool:
     latent pool: every sub-layer but ``attend`` and ``recur`` is per row,
     and both kinds of ``attend`` take a window of one slot. A recurrent
     layer runs a window of ONE slot from the slot's own state
-    (``recur_chunk`` of ops/ssm.py and ops/kda.py), in the combination that
-    is served and tested: state-space (``M``) layers beside K/V pages,
-    delta-rule (``K``) layers beside a latent pool."""
+    (``recur_chunk`` of ops/ssm.py and ops/kda.py), in the combinations that
+    are served and tested: state-space (``M``) layers beside K/V pages,
+    delta-rule (``K``) layers beside a latent pool or K/V pages."""
     if cfg.is_diffusion:
         # its step is a window of ``block_length`` rows a slot already, and
         # a ``Piece`` wants T == 1
         return False
-    if "M" in cfg.layer_pattern:
-        return not cfg.is_latent
-    return "K" not in cfg.layer_pattern or cfg.is_latent
+    return "M" not in cfg.layer_pattern or not cfg.is_latent
 
 
 def decode_step_forward(

@@ -168,14 +168,15 @@ class InferenceEngine:
         }.items() if asked])
         # ... and what it turns OFF and counts instead (feature -> the
         # admissions it was not given to; ``stats()[kind]["refused"]``)
+        snapshots = serve_cfg.state_snapshot_entries
         self.turned_off: dict[str, int] = {
             feature: 0 for feature, asked in (
                 ("prefix_caching", serve_cfg.prefix_caching), ("riding", True))
-            if asked and refused(model_cfg, feature)}
+            if asked and refused(model_cfg, feature, snapshots)}
         if "prefix_caching" in self.turned_off:
             logger.warning("%s %s: prefix reuse by page hash is off (%s)",
                            model_cfg.name,
-                           *refused(model_cfg, "prefix_caching"))
+                           *refused(model_cfg, "prefix_caching", snapshots))
         if model_cfg.layer_pattern and (
                 serve_cfg.quantization not in ("", "none")
                 or serve_cfg.tensor_parallel > 1):
@@ -277,7 +278,9 @@ class InferenceEngine:
                 num_pages=serve_cfg.kv_num_blocks,
                 hbm_budget_gb=serve_cfg.kv_hbm_budget_gb, dtype=dtype,
                 page_sharding=page_sharding,
-                quantized=serve_cfg.kv_quantization)
+                quantized=serve_cfg.kv_quantization,
+                snapshot_entries=(snapshots if serve_cfg.prefix_caching
+                                  else 0))
 
         self._req_slot: dict[str, int] = {}
         # pages promised to admitted-but-not-yet-prefilled requests; without
@@ -291,6 +294,11 @@ class InferenceEngine:
         # prefix-cache pins per request: pages pinned at admission (so LRU
         # eviction can't drop them before prefill), unpinned on release
         self._prefix_pins: dict[str, list[int]] = {}
+        # ... and, for a model whose prefix reuse goes through a recurrent
+        # state, the hash of the page whose SNAPSHOT the request will be
+        # armed from (pinned with the pages, unpinned when the slot is
+        # armed or the request released)
+        self._snapshot_pins: dict[str, bytes] = {}
         self.scheduler = ContinuousBatchingScheduler(
             max_batch_size=S, max_queue=serve_cfg.max_queue,
             max_seq_len=serve_cfg.max_seq_len,
@@ -464,6 +472,22 @@ class InferenceEngine:
         # the prompt tokens they prefilled
         self.total_state_carry_chunks = 0
         self.total_state_carry_tokens = 0
+        # prefix reuse through the recurrent state (``state_snapshot_entries``
+        # > 0): admissions armed from a snapshot and the prompt tokens they
+        # skipped, and admissions whose hashed page chain had NO snapshot
+        # on it (prefilled from zero)
+        self.total_snapshot_hits = 0
+        self.total_snapshot_misses = 0
+        self.total_snapshot_tokens_skipped = 0
+        self._snapshot_take = self._snapshot_arm = None
+        if self.kv.snapshot_entries:
+            ops = recurrent_ops(model_cfg)
+            self._snapshot_take = _Program(
+                "kda_snapshot_take", ops.kda_snapshot_take,
+                self.failed_programs, donate_argnums=(2, 3))
+            self._snapshot_arm = _Program(
+                "kda_snapshot_arm", ops.kda_snapshot_arm,
+                self.failed_programs, donate_argnums=(0, 1))
         self.moe_experts_hit = self.moe_layer_steps = 0
         self.moe_decode_experts_hit = self.moe_decode_layer_steps = 0
         self.total_prefill_tokens = 0      # tokens actually computed
@@ -782,6 +806,7 @@ class InferenceEngine:
         stale = self._prefix_pins.pop(req.request_id, None)
         if stale:
             self.kv.unpin_pages(stale)
+        self._unpin_snapshot(req.request_id)
         req.prefix_cached_tokens = 0
         if req.swapped_kv is not None:
             # swap-in admission: the request brings its own pages — no
@@ -830,10 +855,16 @@ class InferenceEngine:
         # allocation later OOMs in _prefill (over-commit)
         if pins:
             self.kv.pin_pages(pins)
+            if self.kv.snapshot_entries:
+                # the chain ends on a page with a snapshot (``lookup_prefix``)
+                h = req.prefix_hashes[len(pins) - 1]
+                self._snapshot_pins[req.request_id] = h
+                self.kv.pin_snapshot(h)
         need = self.kv.pages_needed(n + self._admission_tail(req)) - len(pins)
         if need > self.kv.free_pages - self._reserved_pages:
             if pins:
                 self.kv.unpin_pages(pins)
+                self._unpin_snapshot(req.request_id)
             return False
         if pins:
             self._prefix_pins[req.request_id] = pins
@@ -842,6 +873,14 @@ class InferenceEngine:
         # hit-rate stats once per successful admission (not per retry)
         self.kv.prefix_queries += usable
         self.kv.prefix_hits += len(pins)
+        if self.kv.snapshot_entries and pins:
+            self.total_snapshot_hits += 1
+            self.total_snapshot_tokens_skipped += (len(pins)
+                                                   * self.kv.page_size)
+        elif self.kv.snapshot_entries and usable and self.kv.hashed_pages(
+                req.prefix_hashes[:usable]):
+            # a hashed chain with no snapshot on it: prefilled from zero
+            self.total_snapshot_misses += 1
         if pins and req.fleet_requeued:
             # a crash/drain orphan whose prompt pages are already warm
             # here: these tokens are NOT re-prefilled — the fleet's
@@ -1007,6 +1046,79 @@ class InferenceEngine:
         carries; (None, None), no arguments at all, for any other model."""
         state = self.kv.state
         return state, None if state is None else np.int32(slot)
+
+    def _unpin_snapshot(self, rid: str) -> Optional[bytes]:
+        """Drop ``rid``'s pin on the snapshot it was to be armed from (the
+        caller holds self.lock, or is the admission hook, which does)."""
+        h = self._snapshot_pins.pop(rid, None)
+        if h is not None:
+            self.kv.unpin_snapshot(h)
+        return h
+
+    def _snapshot_cut(self, req: Request) -> int:
+        """The position ``b`` at which this admitted prompt's state is to
+        be copied to a snapshot entry: the largest whole number of pages
+        UNDER the context's length (a next turn's hit keeps one token to
+        prefill at least), 0 where none is taken: no snapshot pool, or the
+        hit already stands there."""
+        if not self.kv.snapshot_entries or not req.prefix_hashes \
+                or req.swapped_kv is not None \
+                or req.pipeline_stage is not None:
+            return 0
+        PS = self.kv.page_size
+        b = (len(req.context_tokens) - 1) // PS * PS
+        return b if b > req.prefix_cached_tokens else 0
+
+    def _snapshot_copy(self, program: _Program, slot: int, entry: int) -> dict:
+        """One of the two copies between a slot's rows of the state pools
+        and an entry's of the snapshot pools: the pools the program wrote
+        (it was handed them donated)."""
+        state, snaps = self.kv.state, self.kv.snapshots
+        conv, ssm = program(state["conv"], state["ssm"], snaps["conv"],
+                            snaps["ssm"], np.int32(slot), np.int32(entry))
+        return {"conv": conv, "ssm": ssm}
+
+    @engine_thread_only
+    def _arm_from_snapshot(self, req: Request) -> Optional[bytes]:
+        """An admission that hit: SET the slot's rows of the state pools to
+        the snapshot that stands on the hit's last page (a program of its
+        own, queued behind whatever the device runs), so that the prefill
+        from there on (a suffix or chunk program, a riding piece: a window
+        that does not start its sequence) reads it. Returns that page's
+        hash (None: the admission hit nothing)."""
+        with self.lock:
+            h = self._unpin_snapshot(req.request_id)
+            entry = None if h is None else self.kv.snapshot_at(h)
+            if entry is not None:
+                self.kv.touch_snapshot(h)
+        if entry is None:
+            return None
+        with self.spans.phase("llmctl.engine.snapshot.arm",
+                              request_id=req.request_id, entry=entry):
+            self.kv.state = self._snapshot_copy(self._snapshot_arm,
+                                                req.slot, entry)
+        return h
+
+    @engine_thread_only
+    def _take_snapshot(self, st: dict) -> None:
+        """The prompt of progress record ``st`` has just been dispatched up
+        to its cut (``st["snap_at"]``): copy its slot's rows of the state
+        pools to a snapshot entry under that page's chain hash (a program
+        of its own, queued behind the chunk or the decode unit that ends
+        there). The snapshot the prompt was armed from, a shorter one of
+        the same chain, is the first to go when room is needed."""
+        req: Request = st["req"]
+        h = req.prefix_hashes[st["snap_at"] // self.kv.page_size - 1]
+        with self.lock:
+            entry = self.kv.claim_snapshot(h)
+            if st.get("armed_from") is not None:
+                self.kv.touch_snapshot(st["armed_from"], cold=True)
+        if entry is None:
+            return
+        with self.spans.phase("llmctl.engine.snapshot.take",
+                              request_id=req.request_id, entry=entry):
+            self.kv.snapshots = self._snapshot_copy(self._snapshot_take,
+                                                    req.slot, entry)
 
     def _keep_pools(self, out: DispatchResult) -> DispatchResult:
         """Point the cache at the pools a program returned (it was handed
@@ -1255,9 +1367,15 @@ class InferenceEngine:
         self.spans.annotate(cached=cached)
         # (generation by diffusion: the chunks run the whole blocks)
         ctx = ctx[:self._prefill_len(n)]
-        (self._partial_prefills if into is None else into)[rid] = {
-            "req": req, "ctx": ctx, "done": cached, "sent": cached,
-            "pins": len(pins), "table_row": table_row, "slot_key": slot_key}
+        st = {"req": req, "ctx": ctx, "done": cached, "sent": cached,
+              "pins": len(pins), "table_row": table_row,
+              "slot_key": slot_key}
+        if self.kv.snapshot_entries:
+            # prefix reuse through the recurrent state: armed from the
+            # hit's snapshot, and ONE chunk or piece made to end at the cut
+            st["snap_at"] = self._snapshot_cut(req)
+            st["armed_from"] = self._arm_from_snapshot(req)
+        (self._partial_prefills if into is None else into)[rid] = st
 
     @engine_thread_only
     def _advance_chunked_prefills(self) -> list:
@@ -1294,8 +1412,12 @@ class InferenceEngine:
             # stage requests reach here even with chunking disabled
             # (C == 0): fall back to the prefill bucketing granularity
             # so the per-chunk page-publish cadence still exists
-            this = min(n - done,
-                       C if C > 0 else max(self.serve_cfg.prefill_chunk, 1))
+            snap_at = st.get("snap_at", 0)
+            this = min(n - done, C if C > 0 else (
+                n if snap_at and stage is None
+                else max(self.serve_cfg.prefill_chunk, 1)))
+            if done < snap_at < done + this:
+                this = snap_at - done       # ONE chunk ends at the cut
             # charge what the program actually computes — the padded
             # suffix bucket — not the raw token count (a 33-token final
             # chunk dispatches a 64-row program) and not the constant C
@@ -1326,6 +1448,8 @@ class InferenceEngine:
                 self._keep_pools(
                     self._extend_chunk_fn(bucket)(*common, *carried))
                 st["done"] = done + this
+                if st["done"] == snap_at:
+                    self._take_snapshot(st)
                 if stage is not None:
                     self._publish_stage_pages(st)
                     if done + this >= n:
@@ -1457,6 +1581,8 @@ class InferenceEngine:
                 used = self.kv.pages_needed(run)
                 entries[:used] = self.kv.block_tables[slot, :used]
             table_row = self.kv.block_tables[slot].copy()
+        if self.kv.snapshot_entries:
+            self._arm_from_snapshot(req)
 
         sampling = self._sampling_args(self._seat(req), n, req.sampling)
         # first prefill only: a preemption RESUME must not restamp these —
@@ -1755,23 +1881,32 @@ class InferenceEngine:
         C, steps = self._ride_rows, self._decode_unit_len
         units = [(np.zeros((steps, PIECE_META + C), np.int32), [])
                  for _ in range(n_units)]
-        k = 0
-        for st in self._riding.values():
-            ctx, n = st["ctx"], len(st["ctx"])
-            while st["sent"] < n and k < n_units * steps:
-                pieces, laid = units[k // steps]
-                row, sent = pieces[k % steps], st["sent"]
-                live = min(C, n - sent)
-                final = sent + live == n
-                row[:PIECE_META] = (
-                    st["req"].slot, sent, live,
-                    self._stop_position(st["req"]) if final else 0)
-                row[PIECE_META:PIECE_META + live] = ctx[sent:sent + live]
-                laid.append((st, live, k if final else -1))
-                st["sent"] = sent + live
-                k += 1
-            if k == n_units * steps:
-                break
+        for u, (pieces, laid) in enumerate(units):
+            k = 0
+            for st in self._riding.values():
+                ctx, n = st["ctx"], len(st["ctx"])
+                snap_at = st.get("snap_at", 0)
+                while st["sent"] < n and k < steps:
+                    row, sent = pieces[k], st["sent"]
+                    live = min(C, n - sent)
+                    if sent < snap_at < sent + live:
+                        live = snap_at - sent   # ONE piece ends at the cut
+                    final = sent + live == n
+                    row[:PIECE_META] = (
+                        st["req"].slot, sent, live,
+                        self._stop_position(st["req"]) if final else 0)
+                    row[PIECE_META:PIECE_META + live] = ctx[sent:sent + live]
+                    laid.append((st, live, u * steps + k if final else -1))
+                    st["sent"] = sent + live
+                    k += 1
+                    if st["sent"] == snap_at:
+                        # the slot's state is copied after THIS unit
+                        # (``_submit_group``); the prompt's next piece
+                        # would move it on, and waits for the next unit
+                        st["snap_due"] = True
+                        break
+                if k == steps:
+                    break
         return units
 
     @engine_thread_only
@@ -1861,6 +1996,9 @@ class InferenceEngine:
                                            pieces=pieces)
                 pend["laid"] = unit_laid
                 units.append(pend)
+                for st, _live, _final in unit_laid:
+                    if st.pop("snap_due", False):
+                        self._take_snapshot(st)
                 if self._arm_in_flight(unit_laid):
                     shared = None   # a stop changed: anew for a next unit
         self.spans.dispatched()
@@ -2435,6 +2573,7 @@ class InferenceEngine:
         pins = self._prefix_pins.pop(req.request_id, None)
         if pins:
             self.kv.unpin_pages(pins)
+        self._unpin_snapshot(req.request_id)
         slot = self._req_slot.pop(req.request_id, None)
         if slot is not None:
             self.kv.release(slot)
@@ -2509,7 +2648,10 @@ class InferenceEngine:
             elif (C > 0 and len(req.context_tokens) > C
                     and req.swapped_kv is None) \
                     or (req.pipeline_stage is not None
-                        and req.swapped_kv is None):
+                        and req.swapped_kv is None) \
+                    or self._snapshot_cut(req):
+                # (a prompt whose state is to be snapshot at its last page
+                # boundary goes chunk by chunk: ONE chunk ends there)
                 start = self._start_chunked_prefill
             else:
                 start = self._prefill
@@ -2664,6 +2806,10 @@ class InferenceEngine:
             if self.kv.state is not None and any(
                     leaf.is_deleted() for leaf in self.kv.state.values()):
                 self.kv.state = self.kv.new_state()
+            if self.kv.snapshots is not None and any(
+                    leaf.is_deleted() for leaf in self.kv.snapshots.values()):
+                self.kv.snapshots = self.kv.new_snapshots()
+                reallocated = True
             if reallocated:
                 # zeroed buffers invalidate every cached prefix page — a
                 # future hash hit would attend over all-zero K/V
@@ -2820,6 +2966,19 @@ class InferenceEngine:
                 "state_carry_chunks": self.total_state_carry_chunks,
                 "state_carry_tokens": self.total_state_carry_tokens,
                 "refused": dict(self.turned_off),
+                # prefix reuse through the state (a snapshot pool alone):
+                # entries written, admissions armed from one and the prompt
+                # tokens they skipped, admissions whose hashed chain had
+                # none, entries lost (room, or their page evicted), live
+                **({"snapshots_taken": self.kv.snapshots_taken,
+                    "snapshot_hits": self.total_snapshot_hits,
+                    "snapshot_misses": self.total_snapshot_misses,
+                    "snapshot_evictions": self.kv.snapshot_evictions,
+                    "snapshot_tokens_skipped":
+                        self.total_snapshot_tokens_skipped,
+                    "snapshot_entries_live": self.kv.snapshots_live,
+                    "snapshot_bytes": self.kv.snapshot_bytes(),
+                    } if self.kv.snapshot_entries else {}),
             }} if self.cfg.is_recurrent else {}),
             # generation by diffusion over blocks: a forward is a decode
             # step above (``decode_steps``); here what the forwards did,
@@ -2910,6 +3069,10 @@ class InferenceEngine:
                             if isinstance(k, tuple) and k[0] == "chunk")
         decode = int(self._decode_jit is not None)   # 0 after release()
         spec = int(self._spec_jit is not None)
+        # (the two copies of a snapshot pool, each counted once it has run,
+        # so that one compiled under traffic shows; no key without a pool)
+        snapshot = sum(p is not None and p._ran for p in (
+            self._snapshot_take, self._snapshot_arm))
         return {
             "prefill_dense_buckets": prefill_dense,
             "prefill_extend_buckets": prefill_extend,
@@ -2919,6 +3082,8 @@ class InferenceEngine:
             # for dashboard compatibility and is always 0
             "decode_short": 0,
             "speculative": spec,
+            **({"snapshot": snapshot} if self.kv is not None
+               and self.kv.snapshot_entries else {}),
             "total": (prefill_dense + prefill_extend + prefill_chunk
-                      + decode + spec),
+                      + decode + spec + snapshot),
         }

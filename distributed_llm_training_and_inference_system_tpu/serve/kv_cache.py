@@ -37,6 +37,9 @@ logger = logging.getLogger("llmctl.serve.kv_cache")
 # silently wrong. ``refuse`` raises; ``refused`` answers (prefix reuse and
 # riding are turned OFF and counted instead). The first kind that refuses
 # speaks.
+# (PR 46: a ``K`` model with a snapshot pool follows a prefix hit THROUGH
+# its recurrent state, one snapshot a prompt: ``keeps_snapshots``. The rows
+# that MOVE a sequence, below, still wait for a snapshot in their payloads)
 _MOVES_PAGES = ("it re-enters or moves K/V pages, and the layers' recurrent "
                 "state is not in them; ROADMAP C2")
 REFUSED = {
@@ -56,10 +59,17 @@ REFUSED = {
                          "slot's recurrent state is not in them; ROADMAP C2",
         "measure_device_times": "its probes write scratch pages, and would "
                                 "arm and advance live slots' state",
-        # OFF (it is on by default): a page hit would skip tokens whose
-        # recurrent state nobody kept (no snapshot at a page boundary)
-        "prefix_caching": "no page hash is registered or looked up; a "
-                          "repeated prompt is prefilled again",
+        # OFF (it is on by default) where no SNAPSHOT pool exists
+        # (``ServeConfig.state_snapshot_entries`` 0, or ``M`` layers, whose
+        # layout has no take / arm pair yet): a page hit would skip tokens
+        # whose recurrent state nobody kept. With a pool, a ``K`` model
+        # keeps ONE snapshot a prompt, at its last whole page boundary, and
+        # a hit is followed as far as a snapshot stands (``lookup_prefix``)
+        "prefix_caching": "no snapshot of the recurrent state at a page "
+                          "boundary is kept (state_snapshot_entries 0, or "
+                          "state-space layers): no page hash is registered "
+                          "or looked up, a repeated prompt is prefilled "
+                          "again",
     },
     # (prefix reuse stays ON: a latent page is a function of the token
     # prefix exactly as a K/V page is)
@@ -94,8 +104,19 @@ REFUSED = {
 }
 
 
-def refused(cfg: ModelConfig, feature: str) -> Optional[tuple[str, str]]:
-    """(what the model is, why) if a kind of ``cfg`` refuses ``feature``."""
+def keeps_snapshots(cfg: ModelConfig, snapshot_entries: int) -> bool:
+    """Does a cache of ``snapshot_entries`` entries keep snapshots of this
+    model's recurrent state? (``K`` layers: ops/kda.py owns the layout.)"""
+    return snapshot_entries > 0 and cfg.kda_layers > 0
+
+
+def refused(cfg: ModelConfig, feature: str, snapshot_entries: int = 0
+            ) -> Optional[tuple[str, str]]:
+    """(what the model is, why) if a kind of ``cfg`` refuses ``feature``
+    (``snapshot_entries``: of the engine's cache; prefix reuse through a
+    recurrent state needs a snapshot pool)."""
+    if feature == "prefix_caching" and keeps_snapshots(cfg, snapshot_entries):
+        return None
     kinds = {
         "state_space": cfg.ssm_layers > 0 and f"has {cfg.recurrent_name}",
         "recurrent": cfg.is_recurrent and f"has {cfg.recurrent_name}",
@@ -189,6 +210,7 @@ class PagedKVCache:
         page_sharding=None,     # NamedSharding over the kv-head axis for
                                 # tensor-parallel serving (None = one device)
         quantized=False,        # False|"none" | True|"int8" | "int4"
+        snapshot_entries: int = 0,  # snapshots of a ``K`` model's state
     ):
         self.cfg = cfg
         self.num_slots = num_slots
@@ -261,6 +283,28 @@ class PagedKVCache:
         # names; such a model's ``*`` layers may keep LATENT pages, so the
         # latent pool and the state pools live side by side here.
         self.state = self.new_state()
+        # the fourth kind: SNAPSHOTS of the state pools' rows, an ENTRY
+        # where the pools have a slot, in the pools' own layout (ops/kda.py
+        # ``snapshot_pools`` / ``kda_snapshot_take`` / ``kda_snapshot_arm``).
+        # An entry holds the state after a whole number of pages of some
+        # token prefix and stands ON that prefix's last page, under the
+        # page's chain hash: a page hit is followed only as far as a
+        # snapshot stands (``lookup_prefix``), and the entry goes when its
+        # page is evicted, or when a new snapshot needs the room (least
+        # recently used first; an entry an admitted request is about to be
+        # armed from is pinned). The device copies are the engine's
+        # (serve/engine.py ``_take_snapshot`` / ``_arm_from_snapshot``);
+        # here is the host's bookkeeping.
+        self.snapshot_entries = (snapshot_entries
+                                 if keeps_snapshots(cfg, snapshot_entries)
+                                 else 0)
+        self.snapshots = self.new_snapshots()
+        self._snap_free: list[int] = list(range(self.snapshot_entries))[::-1]
+        self._snap_of: dict[bytes, int] = {}              # page hash -> entry
+        self._snap_lru: OrderedDict[int, bytes] = OrderedDict()  # cold first
+        self._snap_pins = np.zeros(max(self.snapshot_entries, 1), np.int32)
+        self.snapshots_taken = 0
+        self.snapshot_evictions = 0
 
         # host-side state; page 0 is scratch and never allocated
         self._free: list[int] = list(range(1, num_pages))
@@ -322,10 +366,82 @@ class PagedKVCache:
 
     def state_bytes(self) -> int:
         """HBM bytes of the state pools (0 without state-space layers)."""
-        if self.state is None:
-            return 0
+        return self._pool_bytes(self.state)
+
+    @staticmethod
+    def _pool_bytes(pools) -> int:
         return sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                   for a in self.state.values())
+                   for a in (pools or {}).values())
+
+    # -- snapshots of the recurrent state ------------------------------------
+
+    def new_snapshots(self):
+        """Zeroed snapshot pools (None without any)."""
+        if not self.snapshot_entries:
+            return None
+        from ..ops import kda
+        return kda.snapshot_pools(self.state["conv"], self.state["ssm"],
+                                  self.snapshot_entries)
+
+    def snapshot_bytes(self) -> int:
+        return self._pool_bytes(self.snapshots)
+
+    @property
+    def snapshots_live(self) -> int:
+        """Entries that hold a snapshot."""
+        return len(self._snap_of)
+
+    def snapshot_at(self, h: bytes) -> Optional[int]:
+        """The entry that stands on the page hashed ``h``, or None."""
+        return self._snap_of.get(h)
+
+    def pin_snapshot(self, h: bytes) -> None:
+        """Keep ``h``'s entry from eviction until ``unpin_snapshot``: an
+        admitted request is about to be armed from it."""
+        self._snap_pins[self._snap_of[h]] += 1
+
+    def unpin_snapshot(self, h: bytes) -> None:
+        entry = self._snap_of.get(h)
+        if entry is not None and self._snap_pins[entry] > 0:
+            self._snap_pins[entry] -= 1
+
+    def touch_snapshot(self, h: bytes, cold: bool = False) -> None:
+        """``h``'s entry was used: the last to be evicted now (``cold``:
+        the FIRST, a longer snapshot of the same chain having been taken)."""
+        entry = self._snap_of.get(h)
+        if entry is not None:
+            self._snap_lru.move_to_end(entry, last=not cold)
+
+    def claim_snapshot(self, h: bytes) -> Optional[int]:
+        """An entry to copy a state into, to stand under ``h``: a free one,
+        else the least recently used that nothing pins (whose snapshot is
+        lost). None where ``h`` has its snapshot already (the same token
+        prefix gives the same state) or every entry is pinned."""
+        if h in self._snap_of:
+            self.touch_snapshot(h)
+            return None
+        if self._snap_free:
+            entry = self._snap_free.pop()
+        else:
+            entry = next((e for e in self._snap_lru
+                          if self._snap_pins[e] == 0), None)
+            if entry is None:
+                return None
+            del self._snap_of[self._snap_lru.pop(entry)]
+            self.snapshot_evictions += 1
+        self._snap_of[h] = entry
+        self._snap_lru[entry] = h
+        self.snapshots_taken += 1
+        return entry
+
+    def _drop_snapshot(self, h: bytes) -> None:
+        """The page hashed ``h`` is gone: so is the entry that stood on it."""
+        entry = self._snap_of.pop(h, None)
+        if entry is not None:
+            del self._snap_lru[entry]
+            self._snap_pins[entry] = 0
+            self._snap_free.append(entry)
+            self.snapshot_evictions += 1
 
     def _pages_only(self, what: str) -> None:
         """Refuse, by name, to move a sequence as K/V pages alone when the
@@ -395,7 +511,8 @@ class PagedKVCache:
             if buf is None:             # a latent pool has no second one
                 return 0
             return int(np.prod(buf.shape)) * jnp.dtype(self.dtype).itemsize
-        return one(self.k_pages) + one(self.v_pages) + self.state_bytes()
+        return (one(self.k_pages) + one(self.v_pages) + self.state_bytes()
+                + self.snapshot_bytes())
 
     # -- alloc / grow / free -------------------------------------------------
 
@@ -414,6 +531,7 @@ class PagedKVCache:
             h = self._page_to_hash.pop(page, None)
             if h is not None:
                 self._hash_to_page.pop(h, None)
+                self._drop_snapshot(h)
                 if self.demote_hook is not None:
                     self._demote_pending.append((h, page))
             return page
@@ -767,13 +885,22 @@ class PagedKVCache:
         lookup — hit/query stats are counted by the caller once per
         admission, so a head-of-line request retried every step doesn't
         skew the rate."""
-        pages = []
-        for h in hashes:
-            page = self._hash_to_page.get(h)
-            if page is None:
-                break
-            pages.append(page)
+        pages = [self._hash_to_page[h]
+                 for h in hashes[:self.hashed_pages(hashes)]]
+        if self.snapshot_entries:
+            # a recurrent model: only as far as a snapshot stands (the
+            # state after a page on which none stands nobody kept)
+            last = max((i + 1 for i in range(len(pages))
+                        if hashes[i] in self._snap_of), default=0)
+            pages = pages[:last]
         return pages
+
+    def hashed_pages(self, hashes: list[bytes]) -> int:
+        """Pages of the chain ``hashes`` the cache holds, from its start."""
+        n = 0
+        while n < len(hashes) and hashes[n] in self._hash_to_page:
+            n += 1
+        return n
 
     def pin_pages(self, pages: list[int]) -> None:
         for p in pages:
@@ -793,6 +920,8 @@ class PagedKVCache:
         prefix hits — silently wrong output, no error."""
         self._hash_to_page.clear()
         self._page_to_hash.clear()
+        for h in list(self._snap_of):
+            self._drop_snapshot(h)
         while self._evictable:
             page, _ = self._evictable.popitem(last=False)
             self._free.append(page)
@@ -891,6 +1020,7 @@ class PagedKVCache:
             "bytes_per_token": self.bytes_per_token,
             "hbm_bytes": self.hbm_bytes(),
             "state_bytes": self.state_bytes(),
+            "snapshot_bytes": self.snapshot_bytes(),
             "slots_resident": len(self._owned),
             "prefix_cached_pages": len(self._hash_to_page),
             "prefix_hits": self.prefix_hits,

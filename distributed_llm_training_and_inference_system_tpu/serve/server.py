@@ -305,11 +305,15 @@ class InferenceServer:
         })
         await resp.prepare(http_req)
 
-        def chunk(text, finish_reason=None):
+        def chunk(text, finish_reason=None, token_ids=()):
+            # (``token_ids``: the batch's ids beside its text, which drops
+            # what the tokenizer cannot render: a caller that sends the
+            # reply back as part of its next turn needs the ids)
             return ("data: " + json.dumps({
                 "id": req.request_id, "object": "text_completion",
                 "model": self.model_cfg.name,
                 "choices": [{"index": 0, "text": text,
+                             "token_ids": [int(t) for t in token_ids],
                              "finish_reason": finish_reason}],
             }) + "\n\n").encode()
 
@@ -360,7 +364,7 @@ class InferenceServer:
                     continue
                 if batch is None:               # request left its slot
                     break
-                await resp.write(chunk(decoder.feed(batch)))
+                await resp.write(chunk(decoder.feed(batch), token_ids=batch))
             final = chunk(decoder.finish(), req.finish_reason or "error")
             await resp.write(final)
             await resp.write(b"data: [DONE]\n\n")

@@ -1058,14 +1058,15 @@ def _hybrid_cfg(table=None):
     (HYBRID, HYBRID_CELLS_TABLE, True), (LINEAR, CELLS_TABLE, True),
     (HYBRID, "*E*E*E*", True),          # no recurrent layer, K/V pages
     ("sdar-test", None, False),         # a window of 4 rows a slot already
-    (HYBRID, "KEKEK*E", False),         # delta-rule layers beside K/V pages
+    (HYBRID, "KEKEK*E", True),          # delta-rule layers beside K/V pages
     ("xing-test", "*DME*E", False),     # state-space layers, a latent pool
 ], ids=lambda v: str(v))
 def test_which_layer_tables_a_decode_step_can_carry_a_piece_through(
         name, table, carries):
     """``can_carry`` reads the layer kinds and the kind of page pool: the
-    uniform stack and every table that is served ride; diffusion and the
-    two combinations no program has run do not."""
+    uniform stack and every table that is served ride (delta-rule layers
+    beside K/V pages since ``solar_open2``); diffusion and the one
+    combination no program has run do not."""
     assert can_carry(_table_cfg(name, table)) is carries
 
 
