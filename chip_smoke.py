@@ -960,6 +960,46 @@ def child_kernels() -> None:
         check(f"paged_attention multi-query T={T} {lname} {kv}-pages",
               got, ref)
 
+    # -- paged attention under the block rule: the denoise window ------------
+    # (generation by diffusion, serve/decode.py denoise_scan) two blocks a
+    # slot from one block BEFORE the slot's current one, written and then
+    # attended as the program does it. First half dead: its rows write
+    # nothing and the second half sees the K/V that stand in the pages (a
+    # slot whose current block is its first starts at -Bd). First half
+    # live: its K/V are written first and the second half sees them.
+    # Against the gather path on float32 copies, over the live rows
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
+        write_window_to_pages)
+    Bd, (nq, nkv) = 4, ((4, 2) if small else (32, 4))
+    B = 2 if small else 8
+    for half in ("dead", "live"):
+        kp, vp, tables = pages(nkv, B, "bf16")
+        q = jax.random.normal(next(key), (B, 2 * Bd, nq, D), jnp.bfloat16)
+        k_new, v_new = (jax.random.normal(next(key), (B, 2 * Bd, nkv, D),
+                                          jnp.bfloat16) for _ in range(2))
+        blocks = jax.random.randint(next(key), (B,), 1, MAXP * PS // Bd - 1)
+        # a block that starts a page behind one that ends the page before
+        blocks = blocks.at[1].set(2 * PS // Bd)
+        if half == "dead":
+            blocks = blocks.at[0].set(0)
+        starts = (blocks * Bd - Bd).astype(jnp.int32)
+        ok = jnp.repeat(jnp.asarray([[half == "live", True]] * B), Bd, axis=1)
+
+        def window(q, k_new, v_new, kp, vp, impl32):
+            cast = (lambda a: a.astype(jnp.float32)) if impl32 else (
+                lambda a: a)
+            kp, vp = (write_window_to_pages(cast(p), cast(n), tables, starts,
+                                            ok, jnp.int32(LAYER))
+                      for p, n in ((kp, k_new), (vp, v_new)))
+            out = paged_attention_multi(
+                cast(q), kp, vp, tables, starts, layer=jnp.int32(LAYER),
+                impl="gather" if impl32 else "pallas", block=Bd)
+            return jnp.where(ok[:, :, None, None], out, 0)
+        window = functools.partial(jax.jit(window, static_argnums=5),
+                                   q, k_new, v_new, kp, vp)
+        check(f"paged_attention_blk two-block window, first half {half}, "
+              f"gqa{nq}x{nkv}", window(False), window(True))
+
     # -- flash attention, forward and backward -----------------------------
     S = 256 if small else 2048
     for name, B, (nq, nkv) in (

@@ -358,8 +358,10 @@ class DiffusionConfig:
     steps) of most confident rows (``low_confidence_static``), those and
     every row whose confidence passes ``confidence_threshold``
     (``low_confidence_dynamic``), or the leftmost (``sequential``). A
-    block with no mask left is forwarded once more, which stores its K/V
-    (the commit). ``block_length`` 0: an autoregressive model."""
+    block with no mask left is final, and the next block's first denoise
+    forward carries it along and stores its K/V (the commit:
+    serve/decode.py ``denoise_scan``). ``block_length`` 0: an
+    autoregressive model."""
     block_length: int = 0
     denoising_steps: int = 4
     mask_token_id: int = 0

@@ -151,8 +151,8 @@ def can_carry(cfg: ModelConfig) -> bool:
     are served and tested: state-space (``M``) layers beside K/V pages,
     delta-rule (``K``) layers beside a latent pool or K/V pages."""
     if cfg.is_diffusion:
-        # its step is a window of ``block_length`` rows a slot already, and
-        # a ``Piece`` wants T == 1
+        # its step is a window of 2 x ``block_length`` rows a slot already,
+        # and a ``Piece`` wants T == 1
         return False
     return "M" not in cfg.layer_pattern or not cfg.is_latent
 
@@ -231,6 +231,9 @@ def extend_step_forward(
                               # table's periodic part is walked by a loop
     state_slot: Any = None,   # int32 []: the ONE slot whose window this is
                               # (B == 1: chunked prefill through K layers)
+    head_from: int = 0,       # static: the head runs over the window's rows
+                              # from this one on (``denoise_scan``: its
+                              # second half), logits [B, T - head_from, V]
 ) -> StepResult:
     """Paged forward over T tokens per slot: the multi-token sibling of
     ``decode_step_forward``. Returns a ``StepResult``: logits [B, T, V]
@@ -253,7 +256,9 @@ def extend_step_forward(
 
     A model that generates by diffusion over blocks (``cfg.is_diffusion``)
     takes windows that start on a block and attends by the block rule: row
-    j sees the paged prefix and its own WHOLE block of the window.
+    j sees the paged prefix and its own WHOLE block of the window. Its
+    denoise window is two blocks and only the second draws tokens
+    (``head_from``).
 
     Token j sits at position ``start_positions + j`` and attends causally
     over the paged prefix *including* earlier tokens of this same call: all
@@ -383,6 +388,8 @@ def extend_step_forward(
         """The rows the head runs over: with a piece B + 1, the piece's
         last live row alone can become a token (its prompt's first, when
         the piece is final)."""
+        if head_from:
+            x = x[:, head_from:]
         if ride is None:
             return x
         return jnp.concatenate([x[:B], jax.lax.dynamic_slice_in_dim(
@@ -784,7 +791,7 @@ UNFIXED = -2
 # and its live slots (``denoise_scan``; ``stats()["diffusion"]``)
 DENOISE_COUNTS = ("slot_forwards", "commit_slot_forwards", "masked_rows",
                   "tokens_fixed", "threshold_fixed", "blocks_committed",
-                  "live_pages")
+                  "live_pages", "fused_commits")
 
 
 def denoise_scan(params, window, starts, k_pages, v_pages, block_tables,
@@ -792,32 +799,46 @@ def denoise_scan(params, window, starts, k_pages, v_pages, block_tables,
                  cfg: ModelConfig, num_steps: int, attn_impl: str = "auto",
                  w4_kernel_ok: bool = True, w8_kernel_ok: bool = False):
     """``decode_scan`` for a model that generates by diffusion over blocks:
-    ``num_steps`` forwards of every slot's WINDOW (``block_length`` rows
-    that see each other) chained on the device, the transfer rule and the
-    commit inside the program.
+    ``num_steps`` forwards of every slot's WINDOW chained on the device, the
+    transfer rule and the commit inside the program.
 
-    ``window`` is (tokens [B, Bd] int32, the mask token on the rows still
-    to fix; fixed_at [B, Bd] int32, the denoise step at which a row was
-    fixed, -1 for a row of the prompt, ``UNFIXED`` for a row still to fix;
-    step [B] int32, the denoise forwards the block has had), ``starts`` [B]
-    the block's first position. A slot is live while ``starts <
-    stop_positions``. One forward of every window (``extend_step_forward`` at T = Bd: the window's K/V are written
-    to their pages every time, a later forward overwrites them), then by
-    slot, one uniform body under ``jnp.where``:
+    A slot's window is ``2 * block_length`` rows from one block BEFORE the
+    block it is denoising: the block it finished last (its final tokens),
+    then the current block (``starts`` [B]: its first position), whose rows
+    see the first half and each other. ``window`` is (tokens [B, 2 Bd]
+    int32, the mask token on the rows still to fix; fixed_at [B, Bd] int32
+    of the CURRENT block, the denoise step at which a row was fixed, -1 for
+    a row of the prompt, ``UNFIXED`` for a row still to fix; step [B]
+    int32, the denoise forwards the block has had; pending [B] bool, the
+    first half's K/V are still to store). A slot is live while ``starts <
+    stop_positions``. One forward of every window
+    (``extend_step_forward`` at T = 2 Bd, the head over the second half
+    alone), then by slot, one uniform body under ``jnp.where``:
 
-    - a window WITH masks: every masked row draws a token and its
-      probability (``sample_tokens_with_prob``; key: the slot's, folded
-      by the row's position then by the step) and ``transfer_rows`` fixes
-      some of them; ``step`` goes on by one;
-    - a window WITHOUT masks: that forward was the COMMIT (the finished
-      block's K/V now stand in the pages). The block is emitted, ``starts``
-      moves on by Bd and the window is masks again.
+    - the first half is LIVE where ``pending``: the one forward after the
+      fix of that block's last mask. Its K/V go to the pages before
+      attention runs, so the second half sees them: the COMMIT rides the
+      next block's first denoise forward. In every other forward it is
+      dead: it writes the scratch page, reaches no expert, is not counted,
+      and the second half sees the K/V that stand in the pages (a prefill
+      program's, after an admission);
+    - the second half's K/V are written every time, a later forward
+      overwrites them. Every masked row draws a token and its probability
+      (``sample_tokens_with_prob``; key: the slot's, folded by the row's
+      position then by the step) and ``transfer_rows`` fixes some of them;
+      ``step`` goes on by one;
+    - the step that fixes a block's LAST mask emits it (its tokens are
+      final): ``starts`` moves on by Bd, the block becomes the first half,
+      pending, and the second half is masks again. No forward runs on a
+      window without masks: a reply's last block is never stored, and
+      nothing reads its pages (serve/engine.py ``_preempt`` publishes a
+      slot's pages up to the last STORED block).
 
     Returns ((window, starts, k_pages, v_pages, [moe_stats,] counts),
     out [K, B, 2 Bd + 1] int32): a step's row of ``out`` holds the slot's
-    window tokens, their ``fixed_at`` and whether the slot emitted its
-    block at that step. ``counts``: ``DENOISE_COUNTS`` summed over the
-    steps and the live slots."""
+    current block AFTER the step, tokens then ``fixed_at``, and whether the
+    step emitted it. ``counts``: ``DENOISE_COUNTS`` summed over the steps
+    and the live slots."""
     f = cfg.diffusion
     Bd, mask_id = f.block_length, f.mask_token_id
     B = starts.shape[0]
@@ -826,15 +847,18 @@ def denoise_scan(params, window, starts, k_pages, v_pages, block_tables,
     PS = k_pages.shape[-2]
 
     def one(carry, _):
-        (toks, fixed_at, step), starts, kp, vp, *stats, counts = carry
+        (toks, fixed_at, step, pending), starts, kp, vp, *stats, counts = carry
         live = starts < stop_positions
+        store = live & pending
         walked = jnp.where(live, (starts + Bd - 1) // PS + 1, 0)
         with jax.named_scope("denoise_step"):
             step_out = extend_step_forward(
-                params, toks, starts, kp, vp, block_tables, cfg,
-                write_ok=jnp.broadcast_to(live[:, None], (B, Bd)),
+                params, toks, starts - Bd, kp, vp, block_tables, cfg,
+                write_ok=jnp.repeat(jnp.stack([store, live], axis=1), Bd,
+                                    axis=1),
                 attn_impl=attn_impl, w4_kernel_ok=w4_kernel_ok,
-                w8_kernel_ok=w8_kernel_ok, return_moe_stats=True)
+                w8_kernel_ok=w8_kernel_ok, return_moe_stats=True,
+                head_from=Bd)
             logits, kp, vp = (step_out.logits, step_out.k_pages,
                               step_out.v_pages)
             layer_stats = ([] if step_out.moe_stats is None
@@ -862,22 +886,26 @@ def denoise_scan(params, window, starts, k_pages, v_pages, block_tables,
             fix, beyond = transfer_rows(
                 prob, masked, wanted, f.remasking_strategy,
                 f.confidence_threshold)
-            denoised = jnp.where(fix, x0, toks)
-            denoised_at = jnp.where(fix, step[:, None], fixed_at)
-            done = live & ~has_mask
-            again = done[:, None]
-            new = (jnp.where(again, mask_id, denoised),
-                   jnp.where(again, UNFIXED, denoised_at),
-                   jnp.where(done, 0, step + 1))
+            block = jnp.where(fix, x0, toks[:, Bd:])
+            block_at = jnp.where(fix, step[:, None], fixed_at)
+            done = live & ~(masked & ~fix).any(axis=-1)
+            moved = done[:, None]
+            masks = jnp.full_like(block, mask_id)
+            new = (jnp.where(moved, jnp.concatenate([block, masks], axis=1),
+                             jnp.concatenate([toks[:, :Bd], block], axis=1)),
+                   jnp.where(moved, UNFIXED, block_at),
+                   jnp.where(done, 0, step + 1),
+                   # (a live slot's forward stored what was pending)
+                   jnp.where(live, done, pending))
             starts = jnp.where(done, starts + Bd, starts)
         counts = counts + jnp.stack([
             jnp.sum(live), jnp.sum(live & ~has_mask),
             jnp.sum(masked & live[:, None]), jnp.sum(fix & live[:, None]),
-            jnp.sum(jnp.where(live, beyond, 0)), jnp.sum(done),
-            jnp.sum(walked),
+            jnp.sum(jnp.where(live, beyond, 0)), jnp.sum(store),
+            jnp.sum(walked), jnp.sum(store & has_mask),
         ]).astype(jnp.int32)
         out = jnp.concatenate(
-            [toks, fixed_at, done[:, None].astype(jnp.int32)], axis=-1)
+            [block, block_at, done[:, None].astype(jnp.int32)], axis=-1)
         stats = [a + b for a, b in zip(stats, layer_stats)]
         return (new, starts, kp, vp, *stats, counts), out
 

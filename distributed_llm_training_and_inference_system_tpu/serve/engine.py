@@ -379,20 +379,24 @@ class InferenceEngine:
         # per dispatch is O(context) host work in the latency-critical loop
         self._ctx = np.zeros((S, serve_cfg.max_seq_len), np.int32)
         self._ctx_len = np.zeros(S, np.int64)
-        # generation by diffusion over blocks: a slot's WINDOW, the block
-        # it is denoising at ``positions[slot]`` (the block's start, a
-        # whole number of blocks: what is committed lies before it): the
-        # rows' tokens (the mask token where a row is still to fix), the
-        # denoise step each was fixed at (-1: a row of the prompt;
-        # ``decode.UNFIXED``: still to fix) and the denoise forwards the
-        # block has had. The device's copy rides the
-        # dispatches' carry; the host's is what a dispatch that chains on
-        # nothing starts from (``_arm_diffusion``, ``_accept_blocks``)
+        # generation by diffusion over blocks: a slot's WINDOW, two blocks
+        # that end with the block it is denoising at ``positions[slot]``
+        # (the block's start, a whole number of blocks: what is final lies
+        # before it): the rows' tokens (the block finished last, then the
+        # current one with the mask token where a row is still to fix), of
+        # the current block the denoise step each row was fixed at (-1: a
+        # row of the prompt; ``decode.UNFIXED``: still to fix) and the
+        # denoise forwards it has had, and whether the finished block's K/V
+        # are still to store (the slot's next forward stores them). The
+        # device's copy rides the dispatches' carry; the host's is what a
+        # dispatch that chains on nothing starts from (``_arm_diffusion``,
+        # ``_accept_blocks``)
         Bd = model_cfg.diffusion.block_length
-        self._win = np.full((S, Bd), model_cfg.diffusion.mask_token_id,
+        self._win = np.full((S, 2 * Bd), model_cfg.diffusion.mask_token_id,
                             np.int32)
         self._win_at = np.full((S, Bd), UNFIXED, np.int32)
         self._win_step = np.zeros(S, np.int32)
+        self._win_pending = np.zeros(S, bool)
         # ``decode.DENOISE_COUNTS`` summed over the dispatches fetched
         self.diffusion_counts = np.zeros(len(DENOISE_COUNTS), np.int64)
         # what the prefill programs of such a model returned and nobody has
@@ -752,10 +756,10 @@ class InferenceEngine:
         migration identity tests catch it)."""
         k = self._decode_units * self._decode_unit_len
         if self.cfg.is_diffusion:
-            # in BLOCKS: a block takes at least two forwards (one that
-            # fixes rows, then the commit), and the forward after a commit
-            # already writes the next window's rows
-            return (k // 2 + 1) * self.cfg.diffusion.block_length
+            # in BLOCKS, one a forward: where the threshold fixes every
+            # row a forward finishes its block (the next one stores it,
+            # and may finish its own), and each writes its block's rows
+            return k * self.cfg.diffusion.block_length
         if self.serve_cfg.speculative == "ngram":
             K = max(self.serve_cfg.decode_steps_per_dispatch, 1)
             k = max(k, self.serve_cfg.speculative_tokens + K - 1)
@@ -765,11 +769,9 @@ class InferenceEngine:
     def _group_span(self) -> int:
         """Positions a dispatch group in flight may have moved a slot on
         by, which the host has not seen yet: a token a step, or for
-        generation by diffusion a block every two forwards."""
+        generation by diffusion a block a forward."""
         k = self._decode_units * self._decode_unit_len
-        if self.cfg.is_diffusion:
-            return -(-k // 2) * self.cfg.diffusion.block_length
-        return k
+        return k * (self.cfg.diffusion.block_length or 1)
 
     def _prefill_len(self, n: int) -> int:
         """Tokens of an ``n``-token context a prefill program runs: all of
@@ -1729,18 +1731,22 @@ class InferenceEngine:
     @engine_thread_only
     def _arm_diffusion(self, req: Request) -> None:
         """A prompt's whole blocks have run: make the slot live with NO
-        first token. Its first window starts at the last whole block's end
+        first token. Its first block starts at the last whole block's end
         and holds what is left of the context as FIXED rows, masks after
-        them. (A preempted request comes back with a context of whole
-        blocks: every block it was credited with was committed.)"""
+        them; the window's first half has nothing to store (the prefill
+        program stored those rows). (A preempted request comes back with a
+        context of whole blocks: every block it was credited with is
+        final, and its prefill stores them again.)"""
         ctx = req.context_tokens
         run = self._prefill_len(len(ctx))
         slot, rest = req.slot, ctx[run:]
+        Bd = self.cfg.diffusion.block_length
         self._win[slot] = self.cfg.diffusion.mask_token_id
-        self._win[slot, :len(rest)] = rest
+        self._win[slot, Bd:Bd + len(rest)] = rest
         self._win_at[slot] = UNFIXED
         self._win_at[slot, :len(rest)] = -1
         self._win_step[slot] = 0
+        self._win_pending[slot] = False
         self._arm_slot(req, 0, run, ctx)
 
     # -- decode --------------------------------------------------------------
@@ -1831,11 +1837,12 @@ class InferenceEngine:
         """The decode program's (tokens, positions) from the HOST's state,
         as copies (see ``_shared_decode_args``): each slot's newest token
         and its position, or for generation by diffusion each slot's
-        window (tokens, fixed-at steps, denoise step) and its block's
-        start."""
+        window (tokens, fixed-at steps, denoise step, whether its first
+        half is still to store) and its block's start."""
         if self.cfg.is_diffusion:
             return ((self._win.copy(), self._win_at.copy(),
-                     self._win_step.copy()), self.positions.copy())
+                     self._win_step.copy(), self._win_pending.copy()),
+                    self.positions.copy())
         return self.last_tokens.copy(), self.positions.copy()
 
     def _shared_decode_args(self) -> tuple:
@@ -1960,7 +1967,7 @@ class InferenceEngine:
                if chain_from is not None else 0)
         if self.cfg.is_diffusion:       # forwards -> positions, about
             lag = (lag * self.cfg.diffusion.block_length
-                   // (self.cfg.diffusion.denoising_steps + 1))
+                   // self.cfg.diffusion.denoising_steps)
         live_pages = int(np.clip(
             (self.positions + lag * self.active) // self.kv.page_size + 1,
             1, self.kv.max_pages_per_slot).sum())
@@ -2283,12 +2290,13 @@ class InferenceEngine:
     @engine_thread_only
     def _accept_blocks(self, slot: int, req: Request, group: dict) -> None:
         """Credit a slot's request with the BLOCKS its forwards of a
-        fetched group committed (several tokens a credit, as the
-        speculative path's ``n_emit``), up to the token that stops it, and
-        take over where the group left the slot's window. A block's rows
-        that were the prompt's (fixed at -1) are not new tokens; a stop
-        token ends the reply where it stands, and the rest of its block is
-        dropped."""
+        fetched group finished (several tokens a credit, as the
+        speculative path's ``n_emit``; a step's row holds the block as the
+        step left it, final where the step fixed its last mask), up to the
+        token that stops it, and take over where the group left the slot's
+        window. A block's rows that were the prompt's (fixed at -1) are not
+        new tokens; a stop token ends the reply where it stands, and the
+        rest of its block is dropped."""
         Bd = self.cfg.diffusion.block_length
         accepted = []
         for row in group["sampled"][:, slot]:
@@ -2304,9 +2312,10 @@ class InferenceEngine:
                 if (req.cancel_requested
                         or req.should_stop(self.eos_token_id) is not None):
                     break
-        win, at, step = group["window"]
+        win, at, step, pending = group["window"]
         self._win[slot], self._win_at[slot] = win[slot], at[slot]
         self._win_step[slot] = step[slot]
+        self._win_pending[slot] = pending[slot]
         self._deliver(slot, req, accepted)
 
     @engine_thread_only
@@ -2478,6 +2487,11 @@ class InferenceEngine:
         riding = self._riding.pop(rid, None)
         written = (riding["done"] if riding is not None
                    else int(self.positions[slot]))
+        if self.cfg.is_diffusion and self._win_pending[slot]:
+            # the block finished last is final on the host and its K/V are
+            # a half-masked window's until the slot's next forward stores
+            # them: no page is published over it
+            written -= self.cfg.diffusion.block_length
         if riding is None and self.serve_cfg.preemption == "swap" and \
                 self._swap_bytes_in_queue() < \
                 self.serve_cfg.swap_space_gb * 1e9:

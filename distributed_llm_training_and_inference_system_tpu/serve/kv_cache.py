@@ -94,7 +94,7 @@ REFUSED = {
             "a draft is verified a token at a time, a swapped slot would "
             "carry a half-denoised window, and the block kernel is opaque "
             "to GSPMD; a preempted request is recomputed from its last "
-            "committed block"),
+            "finished block"),
         "measure_device_times": "its decode probe times a token a step; a "
                                 "denoise forward's time is the benchmark's "
                                 "serve_programs.diffusion_forward_device_ms",

@@ -293,7 +293,7 @@ class InferenceServer:
         `data: {...}` chunk per decoded token batch, `data: [DONE]` at the
         end. Multi-step decode delivers tokens in bursts of up to K; a
         model that generates by diffusion over blocks, in bursts of the
-        blocks a dispatch committed (whole blocks of ``block_length``
+        blocks a dispatch finished (whole blocks of ``block_length``
         tokens, but for a reply's first and last)."""
         # CORS headers must land BEFORE prepare() — the middleware's
         # post-handler pass is too late for a prepared stream (headers are

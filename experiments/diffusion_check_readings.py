@@ -7,10 +7,12 @@ the RIGHT model and for the wrong variants:
   (the mechanism itself), no per-head q/k norms, logits shifted by one,
   matmul operands rounded to float8 (the nearest precision under bfloat16);
 - ``--skip-commit``: a wrong SERVER's window against the right reference
-  (``runners/diffusion.py commit_skipping`` put in place of the engine's
-  ``denoise_scan`` before its program is traced): a block is emitted by the
-  forward that fixes its last mask, and the K/V of that half-masked window
-  stay in the pages.
+  (``never_storing`` below put in place of the engine's ``denoise_scan``
+  before its program is traced): no finished block's K/V are ever stored,
+  and the K/V of its last half-masked window stay in the pages. (Since PR
+  47 a finished block is stored by the next block's first denoise forward;
+  ``runners/diffusion.py commit_skipping`` wraps the form in which the
+  commit was a forward of its own, and finds none to skip.)
 
     chiprun -- python experiments/diffusion_check_readings.py --seed N
     chiprun -- python experiments/diffusion_check_readings.py --seed N \
@@ -27,6 +29,36 @@ import time
 from importlib import import_module
 
 sys.path.insert(0, os.getcwd())
+
+
+def never_storing(denoise_scan):
+    """The WRONG server the check is shown to catch (here and in
+    tests/test_sdar.py; never by the program): ``denoise_scan`` a forward
+    at a time with the window's first half never live, so no finished
+    block is stored."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import harness
+    counts = import_module(f"{harness.PKG}.serve.decode").DENOISE_COUNTS
+
+    def scan(params, window, starts, k_pages, v_pages, *rest, **kw):
+        *args, cfg, num_steps = rest
+
+        def one(carry, _):
+            (*window, pending), starts, kp, vp, *sums = carry
+            (window, starts, kp, vp, *new), out = denoise_scan(
+                params, (*window, jnp.zeros_like(pending)), starts, kp, vp,
+                *args, cfg, 1, **kw)
+            return (window, starts, kp, vp,
+                    *[a + b for a, b in zip(sums, new)]), out[0]
+
+        zeros = [jnp.zeros((cfg.moe.stats_size,), jnp.int32)] \
+            if cfg.is_moe else []
+        zeros.append(jnp.zeros((len(counts),), jnp.int32))
+        return jax.lax.scan(one, (window, starts, k_pages, v_pages, *zeros),
+                            None, length=num_steps)
+    return scan
 
 
 def main() -> None:
@@ -48,7 +80,7 @@ def main() -> None:
     os.makedirs(os.path.dirname(a.out), exist_ok=True)
     if a.skip_commit:
         engine = import_module(f"{harness.PKG}.serve.engine")
-        engine.denoise_scan = diffusion.commit_skipping(engine.denoise_scan)
+        engine.denoise_scan = never_storing(engine.denoise_scan)
     served = diffusion.Served(spec["config"], a.seed)
     variants = [None] if a.skip_commit else [None, *diffusion.VARIANTS]
     if a.only:

@@ -490,7 +490,10 @@ PARENTS_DECODE = {
     "gpt-test": "54a27c5775c96003af68301635b64e0cd98140d0860de0d3759c8b6b23d64ddc",
     "olmoe-test": "55e215fc630808589c68ad79c8c7e3d3d2b79fb33059922235717bceadeb5c19",
     "xing-test": "d64f8944fe2118229021681b196c58cd7db4511bed933866e5c2fd8a824d25da",
-    "sdar-test": "aeb0b472fcaba6553b9a64c546e69025b85c5864120cd6776eff5662ffa7b025",
+    # (PR 47 MEANT to move this one: the denoise window is two blocks and
+    # the commit rides the next block's first forward; the hash is of PR
+    # 47's own tree, the parent's was aeb0b472...ffa7b025)
+    "sdar-test": "584661dc89729bd790be67306274f83a07b1e2bc17e713a9e2581f4ed277e604",
     LINEAR: "ca8bac7665a0d8b5f1011e2dcd5c2ed45b6ca30666c9e286dbf4477101b24b26",
     HYBRID: "74ad821e06394a100e91d3e4d063dc2463f98e81ca46099914c6db821b82677f",
 }
@@ -499,8 +502,8 @@ PARENTS_DECODE = {
 @pytest.mark.parametrize("name", list(PARENTS_DECODE))
 def test_the_other_models_decode_programs_are_the_parents(name):
     """The RIDING decode programs of the uniform stack (dense, MoE), of the
-    latent, the delta-rule and the hybrid table, and the diffusion model's
-    denoise program lower to the parent's text (PR 43 and 44 taught the
+    latent, the delta-rule and the hybrid table lower to the parent's text,
+    and the diffusion model's denoise program to PR 47's (PR 43 and 44 taught the
     table walk a recurrent layer's piece by one seam, ``recur_at``; PR 45
     made what a step and a dispatch return a record)."""
     eng = _engine(name)
@@ -589,6 +592,22 @@ def test_the_hybrid_models_cold_prefill_program_is_the_parents():
     as before, and lower to the parent's text."""
     assert _normalised_sha256(_hybrid_cold_prefill_text()) \
         == HYBRID_COLD_PREFILL
+
+
+# ``sdar-test``'s cold, suffix and chunk programs (bucket 32) as the parent
+# of PR 47 (b1ab06b) lowers them: PR 47 moved the commit into the denoise
+# program's window (``extend_step_forward(head_from=)``, whose default leaves
+# every other caller's text alone), and a prompt's programs are as they were
+SDAR_PREFILL = {
+    "cold": "6e71c8a9f8fff78c9cfeebed8c487d3ae44e85ed9f2cecd99efa9cf1eba0ed5c",
+    "suffix": "1c07fca112b5b307939ce2d11242f70d331d9146336db1d1b7adb86b493cff6a",
+    "chunk": "12b6f30238db3a113dd8a99aedf2407a76354e66ec61c37a135aba213538ff07",
+}
+
+
+def test_the_diffusion_models_prefill_programs_are_the_parents():
+    assert {name: _normalised_sha256(text) for name, text in
+            _prefill_texts(_engine("sdar-test")).items()} == SDAR_PREFILL
 
 
 def test_the_linear_models_prefill_programs_are_pinned():

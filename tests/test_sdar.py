@@ -1,7 +1,8 @@
 """SDAR-shaped models through the program, against the plain reference
 (``benchmark/reference/diffusion_decoder.py``): generation by diffusion over
 blocks (rows of a block see each other; a denoise step fixes rows by
-confidence; a finished block is committed by one more forward), renormalised
+confidence; a finished block's K/V are stored by the next block's first
+denoise forward, the first half of ONE window of two blocks), renormalised
 top-k experts, per-head q/k norms. Float32 on the CPU at a tiny size, seeded
 random weights: logits through the pages, then ``engine.generate`` token for
 token and step for step, the wrong variants asserted to FAIL.
@@ -315,7 +316,12 @@ def test_generate_follows_the_reference_token_for_token(Bd, steps, strategy):
     d = engine.stats()["diffusion"]
     assert d["threshold_fixed"] == 0
     assert d["tokens_fixed"] >= sum(len(r.generated_tokens) for r in reqs)
-    assert d["blocks_committed"] == d["commit_slot_forwards"]
+    # no forward runs on a window without masks: every block but a reply's
+    # last is stored by the forward that starts the next one
+    blocks = sum(-(-(n + 11) // Bd) - n // Bd
+                 for n in map(len, prompts))
+    assert d["commit_slot_forwards"] == 0
+    assert d["fused_commits"] == d["blocks_committed"] == blocks - len(reqs)
     assert d["forwards"] == engine.total_decode_steps
 
 
@@ -350,27 +356,44 @@ def _follows(engine, config, prompts, n=12, **forward) -> list:
         engine.params, r.prompt_tokens, config, n, **forward) for r in reqs]
 
 
+def never_storing(denoise_scan):
+    """The WRONG server of ``experiments/diffusion_check_readings.py``
+    (what the chip's check is read against too): the window's first half
+    never live. (The benchmark's ``commit_skipping`` wraps the form in
+    which the commit was a forward of its own: it would find no window
+    without masks to skip.)"""
+    import importlib.util
+    from pathlib import Path
+    spec = importlib.util.spec_from_file_location(
+        "diffusion_check_readings", Path(__file__).parents[1]
+        / "experiments" / "diffusion_check_readings.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.never_storing(denoise_scan)
+
+
 def test_the_wrong_variants_fail(monkeypatch):
     """What the token-for-token comparison must catch: a reference under
     the CAUSAL mask (the mechanism itself), one without the head norms,
-    and a server that skips the commit forward (the K/V of a half-masked
-    window left in the pages; the benchmark's ``commit_skipping`` in place
-    of the engine's ``denoise_scan`` while its program is traced)."""
-    from benchmark.runners.diffusion import commit_skipping
+    and a server that never stores a finished block (the K/V of a
+    half-masked window left in the pages; ``never_storing`` in place of
+    the engine's ``denoise_scan`` while its program is traced)."""
     prompts = [prompt_of(n, seed=n) for n in (9, 16, 22, 41)]
     engine = engine_for(PUBLISHED)
     assert all(_follows(engine, PUBLISHED, prompts))
     assert not all(_follows(engine, PUBLISHED, prompts, mask_block=1))
     assert not all(_follows(engine, published(qk_norm="none"), prompts))
     monkeypatch.setattr(engine_mod, "denoise_scan",
-                        commit_skipping(engine_mod.denoise_scan))
+                        never_storing(engine_mod.denoise_scan))
     skipping = engine_for(PUBLISHED)
+    first = _follows(skipping, PUBLISHED, prompts, n=2)
     follows = _follows(skipping, PUBLISHED, prompts)
     monkeypatch.undo()
-    assert not all(follows)
-    # it spends no forward on a commit, and its first block is still right
+    # its first block is still right (it follows a prefill program's K/V)
+    assert all(first) and not any(follows)
     d = skipping.stats()["diffusion"]
-    assert d["commit_slot_forwards"] == 0 and d["blocks_committed"] > 0
+    assert d["blocks_committed"] == d["fused_commits"] == 0
+    assert d["tokens_fixed"] > 0
 
 
 def test_a_prefix_cache_hit_serves_the_cold_runs_tokens():
@@ -408,18 +431,21 @@ def test_chunked_prefill_and_a_full_batch_serve_the_same_tokens():
 
 
 def test_counters_under_the_schedule():
-    """Prompts of whole blocks, no threshold: 4 tokens in 5 forwards a
-    slot, one forward in 5 a commit, half the live rows masked."""
+    """Prompts of whole blocks, no threshold: 4 tokens in 4 forwards a
+    slot, none of them a commit (a block is stored by the forward that
+    starts the next; a reply's last block by none), 4 + 3 + 2 + 1 of a
+    block's 16 row-forwards masked, windows of 2 x 4 rows."""
     engine = engine_for(PUBLISHED, decode_steps_per_dispatch=5)
     engine.generate([prompt_of(16, seed=s) for s in range(4)],
                     SamplingParams(temperature=0.0, max_tokens=20))
     d = engine.stats()["diffusion"]
-    assert d["tokens_fixed"] / d["slot_forwards"] == pytest.approx(0.8)
-    assert d["commit_slot_forwards"] / d["slot_forwards"] == \
-        pytest.approx(0.2)
-    assert d["masked_rows"] / (4 * d["slot_forwards"]) == pytest.approx(0.5)
-    assert d["blocks_committed"] == 4 * 5
-    assert d["window_rows"] == d["forwards"] * 4 * 4
+    assert d["slot_forwards"] == 4 * 5 * 4
+    assert d["tokens_fixed"] / d["slot_forwards"] == pytest.approx(1.0)
+    assert d["commit_slot_forwards"] == 0
+    assert d["masked_rows"] / (4 * d["slot_forwards"]) == \
+        pytest.approx(0.625)
+    assert d["fused_commits"] == d["blocks_committed"] == 4 * (5 - 1)
+    assert d["window_rows"] == d["forwards"] * 4 * 8
     assert d["refused"] == {"riding": 0}
     stats = engine.stats()
     assert stats["decode_steps"] == d["forwards"]
@@ -428,7 +454,108 @@ def test_counters_under_the_schedule():
     assert "llmctl.engine.prefill.wait" not in stats["phases"]
     moe = stats["moe"]
     assert moe["layer_steps"] - moe["decode_layer_steps"] == 2 * 4
+    # the experts see the live rows alone: a block's rows in each of its
+    # forwards and once more in the forward that stores it
+    assert moe["held_choices"] == 2 * 2 * (4 * 16 + 4 * (
+        d["slot_forwards"] + d["blocks_committed"]))
     assert not engine._unfetched_prefills
+
+
+def test_slots_at_different_phases_share_a_dispatch():
+    """Eight slots whose first blocks hold 0 to 3 rows of their prompts, so
+    that in one forward some store a finished block while others are
+    mid-block, and every reply crosses from a block that ends a page to
+    one that starts the next."""
+    lengths = (8, 9, 10, 11, 12, 13, 14, 7)
+    engine = engine_for(PUBLISHED, max_batch_size=8,
+                        decode_steps_per_dispatch=5)
+    reqs = engine.generate([prompt_of(n, seed=n) for n in lengths],
+                           SamplingParams(temperature=0.0, max_tokens=14))
+    for req in reqs:
+        n = len(req.prompt_tokens)
+        assert n < PS < n + 14 - 4      # [12, 16) ends a page, [16, 20) next
+        assert (req.generated_tokens, req.unmask_steps) == \
+            diffusion_decoder.generate(engine.params, req.prompt_tokens,
+                                       PUBLISHED, 14)
+    d = engine.stats()["diffusion"]
+    # a forward in which a slot stored a block while another did not
+    assert 0 < d["blocks_committed"] < d["slot_forwards"]
+    assert d["commit_slot_forwards"] == 0
+    assert d["fused_commits"] == d["blocks_committed"]
+
+
+@pytest.mark.parametrize("pipelined", [True, False])
+def test_a_block_a_forward_under_ondemand_admission(pipelined):
+    """A head so sharp that the threshold fixes a whole block in ONE
+    forward, block after block: a slot moves on by a block a forward, 8 of
+    them a dispatch, and the pages grow ahead of it by that much
+    (``_decode_lookahead``, ``_group_span``: a bound of a block every two
+    forwards sends rows to the scratch page, and the tokens go wrong)."""
+    cfg = model(PUBLISHED)
+    params = seeded_params(cfg, head_scale=4000.0)
+    engine = engine_for(PUBLISHED, params, decode_steps_per_dispatch=8,
+                        admission="ondemand", max_seq_len=256,
+                        pipelined_decode=pipelined)
+    assert engine._decode_lookahead == engine._group_span == 8 * 4
+    reqs = engine.generate([prompt_of(n, seed=n) for n in (8, 13, 30)],
+                           SamplingParams(temperature=0.0, max_tokens=96))
+    for req in reqs:
+        tokens, steps_at = diffusion_decoder.generate(
+            params, req.prompt_tokens, PUBLISHED, 96)
+        assert (req.generated_tokens, req.unmask_steps) == (tokens, steps_at)
+        # eight blocks in a row and more, each fixed whole at step 0
+        assert steps_at[:40] == [0] * 40
+    d = engine.stats()["diffusion"]
+    assert d["tokens_fixed"] > 3.8 * d["slot_forwards"]
+    assert d["fused_commits"] == d["blocks_committed"] > 60
+    assert engine.total_preemptions == 0
+
+
+def test_nothing_reads_a_block_before_it_is_stored():
+    """Who reads a reply's pages: nobody a finished reply's (the prefix
+    cache publishes a prompt's pages alone), and a PREEMPTED request's are
+    published up to the last block a forward has stored. The block
+    finished last is final on the host, but its K/V are a half-masked
+    window's until the slot's next forward: a page that ends with it is
+    not published."""
+    from distributed_llm_training_and_inference_system_tpu.serve.kv_cache import (
+        prefix_page_hashes)
+    from distributed_llm_training_and_inference_system_tpu.serve.scheduler import (
+        Request)
+    engine = engine_for(PUBLISHED, prefix_caching=True, pipelined_decode=False,
+                        decode_steps_per_dispatch=4)
+    sampling = SamplingParams(temperature=0.0, max_tokens=24)
+    prompt = prompt_of(PS, seed=11)
+    want, _ = diffusion_decoder.generate(engine.params, prompt, PUBLISHED, 24)
+    req = Request(request_id="first", prompt_tokens=list(prompt),
+                  sampling=sampling)
+    assert engine.scheduler.add_request(req)
+    # four blocks in four dispatches of four forwards: [28, 32) was fixed
+    # by the last forward, which nothing has followed
+    while len(req.generated_tokens) < PS:
+        engine.step()
+    slot = req.slot
+    assert engine.positions[slot] == 2 * PS and engine._win_pending[slot]
+    with engine.lock:
+        engine._preempt(slot)
+    hashes = prefix_page_hashes(prompt + want[:PS], PS)
+    assert engine.kv.hashed_pages(hashes) == 1
+    engine.run_until_idle()
+    assert req.generated_tokens == want and req.preemptions == 1
+    # a prompt that goes on from the preempted context: the page of
+    # [16, 32) is whoever published it first's (``register_pages``)
+    longer = prompt + want[:PS] + prompt_of(5, seed=12)
+    other = engine.generate([longer], SamplingParams(temperature=0.0,
+                                                     max_tokens=8))[0]
+    assert (other.generated_tokens, other.unmask_steps) == \
+        diffusion_decoder.generate(engine.params, longer, PUBLISHED, 8)
+    assert other.prefix_cached_tokens == 2 * PS     # the RESTART's page
+    # the finished replies' pages: the restart published the context it
+    # prefilled, [0, 32), and nothing after it was ever published
+    assert engine.kv.hashed_pages(
+        prefix_page_hashes(prompt + want, PS)) == 2
+    assert engine.kv.hashed_pages(prefix_page_hashes(
+        longer + other.generated_tokens + [0] * PS, PS)) == 2
 
 
 def test_sampled_replies_follow_the_seed_and_stop_tokens_end_a_reply():

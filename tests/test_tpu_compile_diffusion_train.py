@@ -26,6 +26,8 @@ a second process's ``topo`` skips):
   interpreted lowering cannot pass.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -90,13 +92,14 @@ def test_fsdp4_train_step_moves_rows_not_the_head(topo, as_tpu):
 
 # -- generation by diffusion over blocks (benchmark/configs/sdar-30b-a3b-7l) ---
 
-@pytest.mark.parametrize("B, T", [(64, 4), (1, 256), (1, 512)],
-                         ids=["denoise-window", "suffix-256", "suffix-512"])
+@pytest.mark.parametrize("B, T", [(64, 4), (64, 8), (1, 256), (1, 512)],
+                         ids=["one-block", "denoise-window", "suffix-256",
+                              "suffix-512"])
 def test_block_rule_page_kernel_compiles_at_the_cells_shapes(one_chip, as_tpu,
                                                              B, T):
     """The page kernel under the block rule (``paged_attention_blk``) on
-    the SDAR cell's pool (7 layers, 2,179 pages, GQA 32 / 4): the denoise
-    window of one block over 64 slots, and a prefill window of many."""
+    the SDAR cell's pool (7 layers, 2,179 pages, GQA 32 / 4): one block and
+    the denoise window of two over 64 slots, and a prefill window of many."""
     from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
         paged_attention_multi)
     sds = _sds(one_chip)
@@ -113,8 +116,9 @@ def test_block_rule_page_kernel_compiles_at_the_cells_shapes(one_chip, as_tpu,
 
 def test_diffusion_decode_program_fits_the_chip(one_chip, as_tpu):
     """The denoise dispatch of the SDAR cell (published widths, 7 layers,
-    64 slots x 4 rows, 8 forwards): it compiles for the chip, updates the
-    pools in place, holds no layer's 1.2 GB of experts as a temporary, and
+    64 slots x 2 blocks of 4 rows, 8 forwards): it compiles for the chip,
+    updates the pools in place, holds no layer's 1.2 GB of experts as a
+    temporary, runs the head over the second block's 256 rows alone, and
     weights + pools + temporaries fit the chip's 16 GB."""
     import json
     from pathlib import Path
@@ -144,11 +148,23 @@ def test_diffusion_decode_program_fits_the_chip(one_chip, as_tpu):
 
     i32 = lambda *shape: sds(shape, jnp.int32)
     compiled = jax.jit(program, donate_argnums=(1, 2)).lower(
-        params, pool, pool, (i32(B, Bd), i32(B, Bd), i32(B)), i32(B),
+        params, pool, pool,
+        (i32(B, 2 * Bd), i32(B, Bd), i32(B), sds((B,), jnp.bool_)), i32(B),
         i32(B, MAXP), i32(B), sds((B, 2), jnp.uint32),
         sds((B,), jnp.float32), i32(B), sds((B,), jnp.float32)).compile()
     text = compiled.as_text()
     assert "paged_attention_blk" in text and "moe_gmm_prefill" in text
+    # the head and the sampler see the rows that draw tokens: no array of
+    # the window's 512 rows by the vocabulary
+    V = cfg.vocab_size
+    assert f"[{B * Bd},{V}]" in text or f"[{B},{Bd},{V}]" in text
+    assert f"[{2 * B * Bd},{V}]" not in text
+    assert f"[{B},{2 * Bd},{V}]" not in text
+    # ... and the pools are written where they stand: no copy of one
+    shape = ",".join(map(str, pool.shape))
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= bf16\[{shape}\]\S* copy\(", line)]
+    assert not copies, copies
     mem = compiled.memory_analysis()
     # (the temporaries are the head's and the sampler's: [256, 151936]
     # float32 logits are 156 MB, and the sampling branches that a greedy
