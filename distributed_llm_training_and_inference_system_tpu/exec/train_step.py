@@ -44,9 +44,11 @@ class TrainState:
 def _loss_fn(params, batch, model_cfg: ModelConfig, attn_impl: str, remat: str,
              loss_chunk: int = 512):
     """Training loss. With ``loss_chunk > 0`` the LM head + cross-entropy run
-    chunked over the sequence (models.loss.chunked_next_token_loss): the
-    [B, S, V] fp32 logits pair is never resident — it was the round-1
-    single-chip HBM ceiling (~3.3 GB at B=4, S=2048, V=50k)."""
+    a piece at a time (models.loss.chunked_next_token_loss), so no [B, S, V]
+    fp32 logits are ever resident: the forward walks chunks of ``loss_chunk``
+    positions and keeps each row's logsumexp, the hand-written backward
+    walks slices of the vocabulary or chunks of rows, whichever rewrites
+    fewer bytes (models.loss.chunked_loss_backward_plan)."""
     out = forward(
         params, batch["tokens"], model_cfg,
         positions=batch.get("positions"),

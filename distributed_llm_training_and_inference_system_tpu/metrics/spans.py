@@ -350,6 +350,7 @@ class StartupRecorder:
         self._threads: list[_ThreadState] = []
         self._events: list[tuple] = []
         self._programs: list[dict] = []
+        self._notes: dict[str, dict] = {}
         self._imported = False
         self._listening = False
 
@@ -369,6 +370,11 @@ class StartupRecorder:
         """The span around a program's FIRST call (trace, lowering, compile
         or cache read, dispatch): its entry in ``programs``."""
         return _StartupSpan(self, PROGRAM, {"name": name}, program=name)
+
+    def note(self, name: str, **facts) -> None:
+        """A static fact of this start, such as the path a program was
+        traced with: ``snapshot()["notes"][name]``, the latest kept."""
+        self._notes[name] = facts
 
     def imported(self) -> None:
         """An entry module has finished importing: closes
@@ -441,9 +447,9 @@ class StartupRecorder:
     def snapshot(self, until: float | None = None) -> dict:
         """{"clock_s", "import_t0", "ready_t", "phases": {name: {"s", "n"}},
         "programs": [{"name", "t0", "s", "trace_s", "lower_s", "compile_s",
-        "cache_read_s", "cache_hit", "run_s"}]} of the CLOSED spans, from
-        any thread. ``until`` keeps what had ended by that instant (of the
-        events that kept their stamps)."""
+        "cache_read_s", "cache_hit", "run_s"}], "notes": {name: facts}} of
+        the CLOSED spans, from any thread. ``until`` keeps what had ended by
+        that instant (of the events that kept their stamps)."""
         phases: dict = {}
         if until is None:
             cells = [(name, s, n) for state in list(self._threads)
@@ -458,6 +464,7 @@ class StartupRecorder:
             cell["n"] += n
         return {"clock_s": self._clock(), "import_t0": self.import_t0,
                 "ready_t": self.ready_t, "phases": phases,
+                "notes": dict(self._notes),
                 # (an entry is never changed once it is kept)
                 "programs": [p for p in list(self._programs)
                              if until is None or p["t0"] + p["s"] <= until]}
@@ -480,9 +487,13 @@ class StartupRecorder:
             f"{p['name']} {p['s']:.2f} (trace {p['trace_s']:.2f}, lower "
             f"{p['lower_s']:.2f}, compile {p['compile_s']:.2f}, cache read "
             f"{p['cache_read_s']:.2f})" for p in programs)
+        notes = "".join(
+            f" | {name}: " + ", ".join(f"{k} {v}" for k, v in facts.items())
+            for name, facts in snap["notes"].items())
         return (f"start-up {end - snap['import_t0']:.2f} s since the package "
                 f"was imported: {phases or 'no spans'}"
-                + (f" | most expensive programs: {costly}" if costly else ""))
+                + (f" | most expensive programs: {costly}" if costly else "")
+                + notes)
 
 
 STARTUP = StartupRecorder(import_t0=_IMPORT_T0)

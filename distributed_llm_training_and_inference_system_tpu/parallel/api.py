@@ -154,10 +154,28 @@ class ShardedTrainer:
                 use_mesh(self.mesh):
             with TraceAnnotation("llmctl.train.shard_batch"):
                 batch = self.shard_batch(batch)
+            if "train_step" not in self._first_called:
+                self._note_loss_backward(batch["tokens"].shape)
             with TraceAnnotation("llmctl.train.dispatch"), \
                     self._first_call("train_step"):
                 self.state, metrics = self.train_step(self.state, batch)
         return metrics
+
+    def _note_loss_backward(self, tokens_shape: tuple[int, ...]) -> None:
+        """The start-up note ``chunked_loss_bwd``, once, beside the
+        ``train_step`` program's span: which axis the loss's backward walks
+        in the step about to be traced, in how many slices of what width,
+        and the bytes its loop carries and holds (``models/loss.py``; the
+        step is built with that module's default chunk). A micro-batch is
+        ``[mb, S]`` of the pipeline's ``[M, mb, S]``, else a
+        ``gradient_accumulation_steps``-th of ``[B, S]``."""
+        from ..models.loss import chunked_loss_backward_plan
+        *_, rows, seq = tokens_shape
+        if not self.pipelined:
+            rows //= max(self.par_cfg.gradient_accumulation_steps, 1)
+        STARTUP.note("chunked_loss_bwd", **chunked_loss_backward_plan(
+            rows, seq, self.model_cfg.hidden_size,
+            self.model_cfg.vocab_size)._asdict())
 
     def lower_step(self, batch: Any):
         """``train_step`` lowered for SHAPES alone: the state as

@@ -21,6 +21,7 @@ to hand-write with NCCL.
 
 from __future__ import annotations
 
+import math
 import re
 from typing import Any, Optional
 
@@ -200,19 +201,42 @@ def shard_batch(batch: Any, mesh: Mesh) -> Any:
                                batch_specs(batch, mesh)))
 
 
+def _activation_spec(name: str, shape: tuple[int, ...],
+                     mesh: Optional[Mesh]) -> Optional[P]:
+    """ACTIVATION_RULES[name] cut to this shape on *mesh* or the ambient
+    one; None where there is no mesh to shard over."""
+    mesh = mesh or current_mesh()
+    if mesh is None or mesh.empty or mesh.size == 1:
+        return None
+    return _shrink_to_fit(P(*ACTIVATION_RULES[name][: len(shape)]), shape,
+                          mesh)
+
+
 def constrain(x: jax.Array, name: str, mesh: Optional[Mesh] = None) -> jax.Array:
     """Apply a named activation sharding constraint (no-op outside a mesh).
 
     Used inside model forward to anchor GSPMD propagation at block
     boundaries — the TPU replacement for hand-placed NCCL calls.
     """
-    mesh = mesh or current_mesh()
-    if mesh is None or mesh.empty or mesh.size == 1:
+    spec = _activation_spec(name, x.shape, mesh)
+    if spec is None:
         return x
-    spec = ACTIVATION_RULES[name]
-    spec = P(*spec[: x.ndim])
-    spec = _shrink_to_fit(spec, x.shape, mesh)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh or current_mesh(), spec))
+
+
+def shard_counts(name: str, shape: tuple[int, ...],
+                 mesh: Optional[Mesh] = None) -> tuple[int, ...]:
+    """Over how many devices ``constrain(x, name)`` spreads each axis of an
+    *x* of this shape (1 everywhere outside a mesh)."""
+    spec = _activation_spec(name, shape, mesh)
+    if spec is None:
+        return (1,) * len(shape)
+    sizes = (mesh or current_mesh()).shape
+    entries = tuple(spec) + (None,) * (len(shape) - len(spec))
+    return tuple(
+        math.prod(sizes[a] for a in (e if isinstance(e, tuple) else (e,)))
+        if e else 1 for e in entries)
 
 
 # -- ambient mesh (context) --------------------------------------------------

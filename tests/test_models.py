@@ -281,6 +281,124 @@ def test_chunked_loss_matches_dense(cfg, params):
                                    rtol=2e-4, atol=1e-5)
 
 
+_LOSS_B, _LOSS_S, _LOSS_H = 2, 64, 32
+# 384 = 3 lane-wide slices of 128 under the limit below; 389 is prime: no cut
+_LOSS_SLICE_LIMIT = _LOSS_B * 72 * 128 * 4
+
+
+@pytest.mark.parametrize("vocab, walk", [(384, "vocabulary"), (389, "rows")],
+                         ids=["lane-wide-slices", "no-cut"])
+@pytest.mark.parametrize("segments", [True, False],
+                         ids=["packed-and-padded", "no-segment-ids"])
+@pytest.mark.parametrize("z_loss", [0.0, 1e-3], ids=["no-z-loss", "z-loss"])
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_chunked_loss_backward_matches_dense(monkeypatch, tied, z_loss,
+                                             segments, vocab, walk):
+    """The hand-written backward, walking the vocabulary in three slices or
+    (a vocabulary with no cut) the rows in three chunks: loss and BOTH
+    gradients are the dense ``next_token_loss``'s, for a head ``[H, V]`` and
+    a tied embedding ``[V, H]``, with z-loss, segment masks and padding, and
+    a ``loss_chunk`` (24) that does not divide the 63 targets."""
+    from distributed_llm_training_and_inference_system_tpu.models import loss
+    monkeypatch.setattr(loss, "SLICE_BYTES_LIMIT", _LOSS_SLICE_LIMIT)
+    B, S, H = _LOSS_B, _LOSS_S, _LOSS_H
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    hidden = jax.random.normal(keys[0], (B, S, H))
+    w = 0.2 * jax.random.normal(keys[1], (vocab, H) if tied else (H, vocab))
+    tokens = jax.random.randint(keys[2], (B, S), 1, vocab)
+    segs = jnp.concatenate([jnp.ones((B, 40), jnp.int32),
+                            2 * jnp.ones((B, 20), jnp.int32),
+                            jnp.zeros((B, 4), jnp.int32)], axis=1
+                           ) if segments else None
+    plan = loss.plan_loss_backward(rows=B * 72, chunks=3, hidden=H,
+                                   vocab=vocab)
+    assert (plan.axis, plan.slices) == (walk, 3)
+
+    def dense(h, w):
+        logits = jnp.einsum("bsh,vh->bsv" if tied else "bsh,hv->bsv", h, w)
+        return next_token_loss(logits, tokens, segs, z_loss)[0]
+
+    def chunked(h, w):
+        return loss.chunked_next_token_loss(h, w, tokens, segs, z_loss,
+                                            chunk=24, tied=tied)[0]
+
+    l_ref, g_ref = jax.value_and_grad(dense, argnums=(0, 1))(hidden, w)
+    l_chk, g_chk = jax.jit(jax.value_and_grad(chunked, argnums=(0, 1)))(
+        hidden, w)
+    np.testing.assert_allclose(float(l_chk), float(l_ref), rtol=1e-5)
+    for r, c in zip(g_ref, g_chk):
+        np.testing.assert_allclose(np.asarray(c), np.asarray(r),
+                                   rtol=2e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("shapes, want", [
+    # internlm2-1.8b-6l.pretrain-4k: [2, 4096] rows, the whole head
+    (dict(rows=8192, chunks=8, hidden=2048, vocab=92544),
+     ("vocabulary", 3, 30848, 67108864, 1010827264)),
+    # internlm2-1.8b.pretrain-4k-fsdp4: [4, 4096] rows, a quarter of the
+    # head a chip: a vocabulary spread over devices walks rows (two slices
+    # of 11,568 a shard measured 40.9 ms against 39.4, PR 48: the rows'
+    # gather and their gradient's reduce stood alone around the loop)
+    (dict(rows=16384, chunks=8, hidden=2048, vocab=92544, shards=4),
+     ("rows", 8, 2048, 189530112, 189530112)),
+    # and so do few rows against a wide shard
+    (dict(rows=2048, chunks=2, hidden=64, vocab=65536, shards=4),
+     ("rows", 2, 1024, 4194304, 67108864)),
+    # the gpt-* templates' 50,304 = 3 x 131 x 128
+    (dict(rows=8192, chunks=8, hidden=2048, vocab=50304),
+     ("vocabulary", 3, 16768, 67108864, 549453824)),
+    # twice a prime: no slice of a lane or more fits, the rows are walked
+    (dict(rows=8192, chunks=8, hidden=2048, vocab=2 * 50021),
+     ("rows", 8, 1024, 819544064, 409772032)),
+    # a test model's whole vocabulary is one slice of any width
+    (dict(rows=30, chunks=1, hidden=64, vocab=250),
+     ("vocabulary", 1, 250, 7680, 30000)),
+    # few rows against a small head: the head's gradient is the smaller carry
+    (dict(rows=65536, chunks=2, hidden=4096, vocab=1024),
+     ("rows", 2, 32768, 16777216, 134217728)),
+], ids=["pretrain-4k", "pretrain-4k-fsdp4", "wide-shard", "gpt-vocabulary",
+        "twice-a-prime", "one-slice", "rows-rewrite-less"])
+def test_loss_backward_plan_from_shapes(shapes, want):
+    """Which axis the loss's backward walks is decided by the bytes each
+    loop would rewrite, from shapes alone: (axis, slices, width, the carry's
+    bytes, one iteration's float32 logits)."""
+    from distributed_llm_training_and_inference_system_tpu.models.loss import (
+        plan_loss_backward)
+    assert plan_loss_backward(**shapes) == want
+
+
+def test_three_steps_agree_between_the_walks(cfg, monkeypatch):
+    """Three optimizer steps of ``gpt-test``: the losses with the backward
+    walking the vocabulary are those with it walking the rows."""
+    from distributed_llm_training_and_inference_system_tpu.config.schema import (
+        OptimizerConfig)
+    from distributed_llm_training_and_inference_system_tpu.exec.train_step import (
+        TrainState, make_train_step)
+    from distributed_llm_training_and_inference_system_tpu.models import loss
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(5), (4, 48), 1,
+                                          cfg.vocab_size)}
+
+    def losses(walk):
+        # no slice fits under 0 bytes: the rows are walked
+        monkeypatch.setattr(loss, "SLICE_BYTES_LIMIT",
+                            0 if walk == "rows" else 1 << 30)
+        step_fn, tx, _ = make_train_step(cfg, OptimizerConfig(lr=1e-2),
+                                         loss_chunk=16)
+        state = TrainState.create(init(cfg, jax.random.PRNGKey(0)), tx)
+        step = jax.jit(step_fn)
+        out = []
+        for _ in range(3):
+            state, metrics = step(state, batch)
+            out.append(float(metrics["loss"]))
+        assert loss.chunked_loss_backward_plan(
+            4, 48, cfg.hidden_size, cfg.vocab_size, 16).axis == walk
+        return out
+
+    by_vocabulary, by_rows = losses("vocabulary"), losses("rows")
+    assert by_vocabulary[2] < by_vocabulary[0]
+    np.testing.assert_allclose(by_vocabulary, by_rows, rtol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # One block: every program that runs a layer runs models.layers.decoder_block,
 # so every route gives gpt.forward's numbers for every feature of the block.

@@ -7,6 +7,7 @@ guarantee the reference cannot offer for its planned-only TP/ZeRO.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -162,15 +163,23 @@ def test_chunked_loss_moves_rows_not_the_head(devices8, par, attn_impl):
     # gradient: its SHARD is all-reduced over dp / sp, in the backward loop
     # (and the embedding's, [V / fsdp, H / tp], once a micro-batch); nothing
     # vocabulary-wide is gathered, permuted or exchanged anywhere
+    # (since PR 48 the backward is written by hand and the shard is laid
+    # [H, V / shards], as the head is: that all-reduce is let through as
+    # the shard's own float32 bytes and nothing more, once a chunk)
+    def head_grad_shard(c):
+        return (c.op == "all-reduce" and c.widest == ("f32", (H, head_shard))
+                and c.nbytes == H * head_shard * 4)
     if par.data_parallel * par.sequence_parallel > 1:
         wide = [c for c in wide if not (
-            c.op == "all-reduce" and c.widest[1][0] in (head_shard,
-                                                        V // par.fsdp))]
+            head_grad_shard(c) or c.op == "all-reduce"
+            and c.widest[1][0] in (head_shard, V // par.fsdp))]
     assert not wide, [(c.op, c.shapes, c.loop) for c in wide]
     chunk_rows = rows // 2 * 512 * H * 4     # every shard's rows, float32
     assert chunk_rows < H * V * 4            # or the head would pass for rows
+    assert sum(map(head_grad_shard, in_loss)) <= 1
     big = [c for c in in_loss if c.nbytes > chunk_rows and not (
-        c.op == "all-reduce" and c.widest[1][0] == head_shard)]
+        head_grad_shard(c)
+        or c.op == "all-reduce" and c.widest[1][0] == head_shard)]
     assert not big, [(c.op, c.shapes, c.nbytes) for c in big]
 
 
@@ -529,3 +538,107 @@ def test_serve_planner_calibration_plumbing(tmp_path, monkeypatch):
     # explicit argument beats everything
     assert ServePlanner(cfg, hw,
                         decode_efficiency=0.9).decode_efficiency == 0.9
+
+
+# -- the loss's backward (PR 48) ------------------------------------------------
+
+def _loss_loops(text: str, scope: str) -> list[tuple[str, str]]:
+    """(the ``while`` instruction, the text of every computation its body
+    reaches) for each loop of a compiled program whose ``op_name`` ends in
+    ``<scope>/while``."""
+    bodies: dict[str, list[str]] = {}
+    name = None
+    for line in text.splitlines():
+        head = re.match(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+    called = re.compile(r"\b(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+    loops = []
+    for line in text.splitlines():
+        if " while(" in line and f'{scope}/while"' in line:
+            todo, seen = [called.search(line.split(" while(")[1]).group(1)], []
+            while todo:
+                c = todo.pop()
+                if c not in seen and c in bodies:
+                    seen.append(c)
+                    todo += called.findall("\n".join(bodies[c]))
+            loops.append((line, "\n".join(
+                l for c in seen for l in bodies[c])))
+    return loops
+
+
+@pytest.mark.parametrize("walk", ["vocabulary", "rows"])
+def test_one_device_loss_backward_carries_rows_not_the_head(
+        devices8, monkeypatch, walk):
+    """InternLM2's layout in small on ONE device (the one-chip cell's step):
+    the loss's backward loop walks three slices of the vocabulary, carries
+    the rows' float32 gradient and holds nothing of the head's shape
+    ``[H, V]``: no such element in the loop's tuple, no such float32 result
+    (an add into a carried gradient) in anything its body calls. Each slice
+    of the head's gradient is written once into the loop's stacked output.
+    Made to walk the rows, the same program carries ``f32[H, V]`` through
+    every chunk: what the cell's step did before PR 48, and what this test
+    has to be able to see."""
+    from distributed_llm_training_and_inference_system_tpu.models import loss
+    V, H, S, rows = 6144, 64, 1024, 8
+    micro_rows = rows // 2 * S                # positions of a micro-batch
+    monkeypatch.setattr(loss, "SLICE_BYTES_LIMIT",
+                        micro_rows * (V // 3) * 4 if walk == "vocabulary"
+                        else 0)
+    model_cfg = dataclasses.replace(
+        get_model_config("gpt-test"), tie_word_embeddings=False, vocab_size=V)
+    trainer = ShardedTrainer(
+        model_cfg, OptimizerConfig(),
+        ParallelConfig(gradient_accumulation_steps=2), devices=devices8[:1])
+    text = trainer.lower_step(_packed_batch(rows, S, V)).compile().as_text()
+    (loop, body), = _loss_loops(text, "chunked_loss_bwd")
+    head = re.compile(rf"f32\[(?:{H},{V}|{V},{H})\]")
+    if walk == "rows":
+        assert head.search(loop.split(" while(")[0])
+        return
+    assert not head.search(loop) and not head.search(body), (
+        head.findall(loop), head.findall(body))
+    carried = loop.split(" while(")[0]
+    assert f"f32[{rows // 2},{S},{H}]" in carried        # the rows' gradient
+    assert f"f32[3,{H},{V // 3}]" in carried             # the slices, stacked
+
+
+@pytest.mark.parametrize("par, attn_impl", [
+    (ParallelConfig(data_parallel=4, gradient_accumulation_steps=2), "xla"),
+    (ParallelConfig(data_parallel=2, sequence_parallel=2,
+                    gradient_accumulation_steps=2), "ring"),
+], ids=["dp4", "dp2sp2"])
+def test_vocabulary_walk_on_a_mesh_with_the_head_whole(devices8, monkeypatch,
+                                                       par, attn_impl):
+    """A mesh that leaves the head whole on every device (dp, sp: no fsdp or
+    tp on its vocabulary axis) walks the vocabulary as one device does, each
+    over its own rows: the slice loop carries the device's share of the
+    rows' float32 gradient and holds no collective but the all-reduce of a
+    slice of the head's gradient (rows on dp / sp are partial sums of it),
+    so the head's gradient crosses the interconnect once a micro-batch and
+    not once a chunk; no row is gathered and nothing there is ``V`` wide."""
+    from distributed_llm_training_and_inference_system_tpu.comms.hlo import (
+        collectives)
+    from distributed_llm_training_and_inference_system_tpu.models import loss
+    from distributed_llm_training_and_inference_system_tpu.parallel.sharding import (
+        use_mesh)
+    V, H, S, rows = 6144, 64, 1024, 8
+    own_rows = rows // 2 * S // 4             # a device's, of a micro-batch
+    monkeypatch.setattr(loss, "SLICE_BYTES_LIMIT", own_rows * (V // 3) * 4)
+    model_cfg = dataclasses.replace(
+        get_model_config("gpt-test"), tie_word_embeddings=False, vocab_size=V)
+    trainer = ShardedTrainer(model_cfg, OptimizerConfig(), par,
+                             devices=_devices_for(devices8, par),
+                             attn_impl=attn_impl)
+    with use_mesh(trainer.mesh):
+        plan = loss.chunked_loss_backward_plan(rows // 2, S, H, V)
+    assert plan == ("vocabulary", 3, V // 3, own_rows * H * 4,
+                    own_rows * (V // 3) * 4)
+    text = trainer.lower_step(_packed_batch(rows, S, V)).compile().as_text()
+    in_loop = [c for c in collectives(text) if "chunked_loss_bwd" in c.loop]
+    assert in_loop and all(
+        c.op == "all-reduce" and c.shapes == (("f32", (H, V // 3)),)
+        for c in in_loop), [(c.op, c.shapes) for c in in_loop]

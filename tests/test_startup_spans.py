@@ -240,6 +240,16 @@ def test_ready_is_stamped_once_and_the_summary_names_the_costly(rec):
     assert "prefill 256" not in costly and UNSCOPED not in costly
 
 
+def test_a_note_is_kept_by_name_and_the_summary_ends_with_it(rec):
+    assert rec.snapshot()["notes"] == {}
+    rec.note("chunked_loss_bwd", axis="rows", slices=8)
+    rec.note("chunked_loss_bwd", axis="vocabulary", slices=3, width=30848)
+    assert rec.snapshot()["notes"] == {"chunked_loss_bwd": {
+        "axis": "vocabulary", "slices": 3, "width": 30848}}
+    assert rec.summary().endswith(
+        " | chunked_loss_bwd: axis vocabulary, slices 3, width 30848")
+
+
 # -- the ledger's listeners ----------------------------------------------------
 
 def test_an_event_lands_in_the_innermost_program_span_of_its_thread(rec):
@@ -426,6 +436,13 @@ def test_a_trainer_leaves_init_state_and_train_step_entries():
         assert sum(entry[k] for k in FOUR) <= entry["s"] + 1e-6, entry
     assert STARTUP.snapshot()["phases"]["llmctl.startup.params"][
         "n"] == params_before + 1
+    # beside the step's span the trainer left the loss's backward plan, and
+    # the evaluations' traces did not touch it: [2, 31] targets in one
+    # chunk against gpt-test's whole vocabulary in one slice
+    assert STARTUP.snapshot()["notes"]["chunked_loss_bwd"] == {
+        "axis": "vocabulary", "slices": 1, "width": cfg.vocab_size,
+        "carry_bytes": 2 * 31 * cfg.hidden_size * 4,
+        "transient_bytes": 2 * 31 * cfg.vocab_size * 4}
 
 
 # -- the operator's surfaces ---------------------------------------------------
