@@ -1510,6 +1510,86 @@ def child_kernels() -> None:
     if float(jnp.abs(snap_state[:, jnp.asarray([0, 2])]).max()) != 0.0:
         failures.append("a snapshot's take wrote another entry")
 
+    # (PR 49) ``falcon_h1``: attention (a query group of FIVE) AND a Mamba-2
+    # mixer (2 groups, state 256) under one norm, twelve muP multipliers,
+    # at Falcon-H1-34B's widths (one published layer, 8,192 rows of the
+    # vocabulary): cold prefill of a padded bucket writes the layer's pages
+    # and arms its state, eight decode steps read and write both pools; the
+    # logits beside the float32 reference's
+    from benchmark.reference import parallel_decoder
+    from distributed_llm_training_and_inference_system_tpu.config.presets import (
+        FALCON_H1_34B_PUBLISHED, FALCON_H1_TEST_PUBLISHED)
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        decode_step_forward)
+    pub = dict(FALCON_H1_TEST_PUBLISHED if small else dict(
+        FALCON_H1_34B_PUBLISHED, vocab_size=8192), num_hidden_layers=1)
+    fh = dataclasses.replace(ModelConfig.from_published(pub),
+                             dtype="bfloat16")
+    fparams = jax.jit(lambda k: gpt.init(fh, k, jnp.bfloat16))(next(key))
+    par = dict(fparams["blocks"]["par"])
+    par["D"] = jax.random.uniform(next(key), par["D"].shape, minval=0.5,
+                                  maxval=1.5)
+    par["gate_norm"] = {"scale": jax.random.uniform(
+        next(key), par["gate_norm"]["scale"].shape, minval=-0.5, maxval=0.5
+    ).astype(jnp.bfloat16)}
+    fparams = dict(fparams, blocks=dict(fparams["blocks"], par=par))
+    S, n_live, steps, slots = (32, 21, 8, 4) if small else (256, 200, 8, 8)
+    seq = jax.random.randint(next(key), (n_live + steps,), 1,
+                             fh.vocab_size).tolist()
+    want = parallel_decoder.logits(fparams, seq, pub,
+                                   positions=range(n_live - 1, len(seq)))
+    live = (jnp.arange(S)[None] < n_live).astype(jnp.int32)
+    padded = jnp.asarray([seq[:n_live] + [7] * (S - n_live)], jnp.int32)
+    first, (kd_, vd_), (tails, hs) = jax.jit(lambda p, t: gpt.forward(
+        p, t, fh, kv_cache=gpt.init_kv_cache(fh, 1, S),
+        cache_offset=jnp.zeros((1,), jnp.int32), segment_ids=live,
+        unembed_positions=jnp.asarray([n_live - 1]),
+        return_ssm_state=True))(fparams, padded)
+    n_pages = S // PS + 2
+    table = np.zeros((slots, n_pages), np.int32)
+    table[2] = np.arange(1, n_pages + 1)
+
+    def paged(d):
+        return d[:, 0].reshape(1, S // PS, PS, fh.num_kv_heads, fh.head_dim
+                               ).transpose(0, 1, 3, 2, 4)
+    pool = jnp.zeros((1, n_pages + 1, fh.num_kv_heads, PS, fh.head_dim),
+                     jnp.bfloat16)
+    kp = pool.at[:, 1:S // PS + 1].set(paged(kd_))
+    vp = pool.at[:, 1:S // PS + 1].set(paged(vd_))
+    fs = fh.ssm
+    fstate = {
+        "conv": jnp.zeros((1, slots, fs.conv_kernel - 1, fs.conv_channels),
+                          jnp.bfloat16).at[:, 2].set(
+            tails[:, 0].astype(jnp.bfloat16)),
+        "ssm": jnp.zeros((1, slots, fs.num_heads, fs.head_dim,
+                          fs.state_size), jnp.float32).at[:, 2].set(hs[:, 0])}
+    step_fn = jax.jit(lambda p, t, pos, kp, vp, st: decode_step_forward(
+        p, t, pos, kp, vp, jnp.asarray(table), fh,
+        active=jnp.arange(slots) == 2, ssm_state=st),
+        donate_argnums=(3, 4, 5))
+    got = [first[0, 0]]
+    for t in range(steps):
+        out = step_fn(fparams,
+                      jnp.full((slots,), seq[n_live + t], jnp.int32),
+                      jnp.full((slots,), n_live + t, jnp.int32), kp, vp,
+                      fstate)
+        kp, vp, fstate = out.k_pages, out.v_pages, out.state
+        got.append(out.logits[2])
+    check(f"falcon_h1 layer: cold prefill [{S} rows, {n_live} live] then "
+          f"{steps} decode steps through pages AND state, {fh.num_heads} / "
+          f"{fh.num_kv_heads} heads, {fs.n_groups} groups of state "
+          f"{fs.state_size}, logits against the float32 reference",
+          jnp.stack(got), want, tol=5e-2)
+    if float(jnp.abs(fstate["ssm"][:, jnp.asarray([0, 1, 3])]).max()) != 0.0:
+        failures.append("falcon_h1 decode wrote an idle slot's state")
+    for wrong in ("drop_attention", "drop_ssm"):
+        moved = float(jnp.abs(want - parallel_decoder.logits(
+            fparams, seq, pub, positions=range(n_live - 1, len(seq)),
+            wrong=wrong)).max())
+        if moved < 0.25 * float(jnp.abs(want).max()):
+            failures.append(f"falcon_h1: {wrong} moves the logits by under "
+                            "a quarter of them: the check cannot see it")
+
     if failures:
         print("kernel check failures:\n  " + "\n  ".join(failures),
               file=sys.stderr)

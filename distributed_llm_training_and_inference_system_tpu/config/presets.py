@@ -318,6 +318,61 @@ def solar_open2_test_share(held: int, first: int = 0) -> ModelConfig:
         "router_experts": 16, "first_expert": first})
 
 
+# Falcon-H1-34B-Instruct as published (``model_type: falcon_h1``): 72
+# layers, each attention (20 / 4 heads of 128, rope base 1e11) AND a Mamba-2
+# mixer (32 heads of 128, 2 groups, state 256) side by side under one norm,
+# then a gated MLP of width 21,504 under a second; twelve muP multipliers
+FALCON_H1_34B_PUBLISHED = {
+    "name": "falcon-h1-34b", "model_type": "falcon_h1",
+    "hidden_size": 5120, "num_hidden_layers": 72, "num_attention_heads": 20,
+    "num_key_value_heads": 4, "head_dim": 128, "intermediate_size": 21504,
+    "vocab_size": 261120, "hidden_act": "silu", "rms_norm_eps": 1e-5,
+    "rope_theta": 100000000000, "rope_scaling": None,
+    "max_position_embeddings": 262144, "tie_word_embeddings": False,
+    "attention_bias": False, "mlp_bias": False, "projectors_bias": False,
+    "attn_layer_indices": None, "num_logits_to_keep": 1,
+    "mlp_expansion_factor": 8,
+    "mamba_n_heads": 32, "mamba_d_head": 128, "mamba_d_ssm": 4096,
+    "mamba_d_state": 256, "mamba_n_groups": 2, "mamba_d_conv": 4,
+    "mamba_chunk_size": 128, "mamba_expand": 2, "mamba_conv_bias": True,
+    "mamba_proj_bias": False, "mamba_rms_norm": True,
+    "mamba_norm_before_gate": False, "mamba_use_mlp": True,
+    "embedding_multiplier": 5.656854249492381,
+    "lm_head_multiplier": 0.0078125, "attention_in_multiplier": 1,
+    "attention_out_multiplier": 0.0375,
+    "key_multiplier": 0.011048543456039804, "ssm_in_multiplier": 0.25,
+    "ssm_out_multiplier": 0.08838834764831845,
+    "ssm_multipliers": [0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                        0.3535533905932738],
+    "mlp_multipliers": [0.1767766952966369, 0.011160714285714284],
+}
+MODEL_TEMPLATES["falcon-h1-34b"] = ModelConfig.from_published(
+    FALCON_H1_34B_PUBLISHED)
+
+# ... and its shape in small, in the same keys (the plain reference of the
+# benchmark reads these): two published layers (``PDPD``), a query group of
+# FIVE (10 / 2 heads), 2 groups of state-space heads, a chunk of 16 so that
+# a short test prompt spans several, and all twelve multipliers at values
+# that differ from each other and from 1 (``attention_in`` too)
+FALCON_H1_TEST_PUBLISHED = {
+    **FALCON_H1_34B_PUBLISHED,
+    "name": "falcon-h1-test", "hidden_size": 64, "num_hidden_layers": 2,
+    "num_attention_heads": 10, "num_key_value_heads": 2, "head_dim": 16,
+    "intermediate_size": 96, "vocab_size": 256,
+    "max_position_embeddings": 512, "dtype": "float32",
+    "mamba_n_heads": 8, "mamba_d_head": 8, "mamba_d_ssm": 64,
+    "mamba_d_state": 16, "mamba_chunk_size": 16,
+    "embedding_multiplier": 2.5, "lm_head_multiplier": 0.6,
+    "attention_in_multiplier": 1.3, "attention_out_multiplier": 0.8,
+    "key_multiplier": 1.7, "ssm_in_multiplier": 0.7,
+    "ssm_out_multiplier": 1.4,
+    "ssm_multipliers": [0.9, 1.2, 0.75, 1.5, 1.1],
+    "mlp_multipliers": [0.65, 1.6],
+}
+TEST_TEMPLATES["falcon-h1-test"] = ModelConfig.from_published(
+    FALCON_H1_TEST_PUBLISHED)
+
+
 def get_model_config(name: str) -> ModelConfig:
     """Look up a template by name (also accepts test templates), or read a
     model's published ``config.json`` from a path ending in ``.json``.

@@ -278,20 +278,33 @@ class SSMConfig:
             return cls(**{f.name: type(f.default)(d[f.name])
                           for f in dataclasses.fields(cls) if f.name in d})
         p = published or {}
-        if "mamba_num_heads" not in p:
+        if "mamba_num_heads" not in p and "mamba_n_heads" not in p:
             return cls()
         if p.get("ssm_state_dtype", "float32") != "float32":
             raise ConfigError(
                 f"ssm_state_dtype {p['ssm_state_dtype']!r}: the recurrent "
                 "state is cached in float32 and in nothing else")
-        return cls(
-            num_heads=int(p["mamba_num_heads"]),
-            head_dim=int(p.get("mamba_head_dim", 64)),
-            state_size=int(p.get("ssm_state_size", 128)),
-            n_groups=int(p.get("n_groups", 1)),
-            conv_kernel=int(p.get("conv_kernel", 4)),
-            chunk_size=int(p.get("chunk_size", 128)),
+        # (``falcon_h1`` spells them ``mamba_n_heads`` / ``mamba_d_head`` /
+        # ``mamba_d_state`` / ``mamba_n_groups`` / ``mamba_d_conv`` /
+        # ``mamba_chunk_size``; its ``mamba_expand`` is not read: the inner
+        # width is ``mamba_d_ssm`` = heads x head size)
+        cfg = cls(
+            num_heads=int(_take(p, "mamba_num_heads", "mamba_n_heads")),
+            head_dim=int(_take(p, "mamba_head_dim", "mamba_d_head",
+                               default=64)),
+            state_size=int(_take(p, "ssm_state_size", "mamba_d_state",
+                                 default=128)),
+            n_groups=int(_take(p, "n_groups", "mamba_n_groups", default=1)),
+            conv_kernel=int(_take(p, "conv_kernel", "mamba_d_conv",
+                                  default=4)),
+            chunk_size=int(_take(p, "chunk_size", "mamba_chunk_size",
+                                 default=128)),
         )
+        if int(p.get("mamba_d_ssm", cfg.inner_size)) != cfg.inner_size:
+            raise ConfigError(
+                f"mamba_d_ssm {p['mamba_d_ssm']} is not mamba_n_heads x "
+                f"mamba_d_head = {cfg.inner_size}")
+        return cfg
 
 
 @dataclass(frozen=True)     # hashable: a jitted function's static
@@ -395,11 +408,60 @@ class DiffusionConfig:
         )
 
 
+@dataclass(frozen=True)     # hashable: a jitted function's static
+class MupConfig:
+    """The muP multipliers of ``model_type: falcon_h1``, one a branch, each
+    under the published key's name less ``_multiplier(s)``. Scalars the
+    model was TRAINED with and applies in its forward pass: on the embedding's
+    rows, on the attention branch's input, keys and output, on the
+    state-space branch's input, on the five parts of its in-projection's
+    output (``ssm``: z, x, B, C, dt) and on its output, on the MLP's gate
+    pre-activation and its output (``mlp``), and on the logits. All 1: a
+    model without them, whose programs hold no multiply for them."""
+    embedding: float = 1.0
+    lm_head: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    ssm_in: float = 1.0
+    ssm_out: float = 1.0
+    ssm: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp: tuple = (1.0, 1.0)
+
+    @classmethod
+    def from_dict(cls, d: dict[str, Any] | None,
+                  published: dict[str, Any] | None = None) -> "MupConfig":
+        """The nested ``mup`` table, else the ``falcon_h1`` keys of a
+        ``published`` config.json (``embedding_multiplier``, ...,
+        ``ssm_multipliers`` [5], ``mlp_multipliers`` [2])."""
+        if d:
+            return cls(**{k: tuple(float(x) for x in v)
+                          if isinstance(v, (list, tuple)) else float(v)
+                          for k, v in d.items()})
+        p = published or {}
+        got = {}
+        for f in dataclasses.fields(cls):
+            plural = isinstance(f.default, tuple)
+            key = f.name + ("_multipliers" if plural else "_multiplier")
+            if p.get(key) is None:
+                continue
+            if plural and len(p[key]) != len(f.default):
+                raise ConfigError(f"{key} has {len(p[key])} entries, not "
+                                  f"{len(f.default)}")
+            got[f.name] = (tuple(float(x) for x in p[key]) if plural
+                           else float(p[key]))
+        return cls(**got)
+
+
 # what a layer of a layer table may be (``nemotron_h``'s own letters)
 # ``D`` (this repo's letter): a dense gated MLP as a layer of its own, the
 # feed-forward of a leading dense layer before the expert layers
 # ``K`` (this repo's letter): a Kimi Delta Attention mixer
-LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "moe", "D": "mlp", "K": "kda"}
+# ``P`` (this repo's letter): attention AND a Mamba-2 mixer side by side
+# under ONE norm (``falcon_h1``): both read the same normed stream, their
+# outputs are summed, and the layer keeps K/V pages and a recurrent state
+LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "moe", "D": "mlp", "K": "kda",
+               "P": "par"}
 
 
 @dataclass
@@ -474,6 +536,8 @@ class ModelConfig:
     hc_eps: float = 1e-6
     hc_clamp_min: float = -30.0
     hc_clamp_max: float = 30.0
+    # ``falcon_h1``'s muP multipliers (all 1: none)
+    mup: MupConfig = field(default_factory=MupConfig)
 
     @property
     def is_moe(self) -> bool:
@@ -518,9 +582,12 @@ class ModelConfig:
 
     @property
     def kv_layers(self) -> int:
-        """Layers that keep K and V: the attention layers of a table,
-        every layer of a uniform stack."""
-        return self.layers_of("*") if self.layer_pattern else self.num_layers
+        """Layers that keep K and V: the attention layers of a table (a
+        ``P`` layer is one, and a state-space layer too), every layer of a
+        uniform stack."""
+        if not self.layer_pattern:
+            return self.num_layers
+        return self.layers_of("*") + self.layers_of("P")
 
     @property
     def moe_layers(self) -> int:
@@ -530,7 +597,8 @@ class ModelConfig:
 
     @property
     def ssm_layers(self) -> int:
-        return self.layers_of("M")
+        """Layers that keep a Mamba-2 state a slot (``M``, and ``P``)."""
+        return self.layers_of("M") + self.layers_of("P")
 
     @property
     def kda_layers(self) -> int:
@@ -571,7 +639,8 @@ class ModelConfig:
                 raise ConfigError(
                     f"layer_pattern {self.layer_pattern!r}: no layer kind "
                     f"{unknown} (known: M state-space, * attention, E "
-                    "experts, D dense MLP, K delta-rule linear attention)")
+                    "experts, D dense MLP, K delta-rule linear attention, "
+                    "P attention and state-space side by side)")
             if len(self.layer_pattern) != self.num_layers:
                 raise ConfigError(
                     f"layer_pattern has {len(self.layer_pattern)} layers, "
@@ -580,17 +649,26 @@ class ModelConfig:
                 raise ConfigError("layer_pattern has E layers and the "
                                   "model has no experts")
             s = self.ssm
-            if self.layers_of("M") and (
+            if self.ssm_layers and (
                     s.num_heads < 1 or s.num_heads % max(s.n_groups, 1)
                     or s.conv_kernel < 2 or s.chunk_size < 1):
                 raise ConfigError(
-                    "layer_pattern has M layers: ssm.num_heads must be a "
-                    "positive multiple of ssm.n_groups, conv_kernel >= 2 "
+                    "layer_pattern has M or P layers: ssm.num_heads must be "
+                    "a positive multiple of ssm.n_groups, conv_kernel >= 2 "
                     f"(got {s})")
-            if self.layers_of("M") and self.layers_of("K"):
+            if self.ssm_layers and self.layers_of("K"):
                 raise ConfigError(
-                    "layer_pattern has M and K layers: one recurrent kind "
-                    "a model (the state pools hold one kind's rows)")
+                    "layer_pattern has M and K layers (a P layer holds an M "
+                    "mixer): one recurrent kind a model (the state pools "
+                    "hold one kind's rows)")
+            if self.layers_of("P") and (
+                    self.layers_of("*") or self.layers_of("M")
+                    or self.is_latent):
+                raise ConfigError(
+                    "layer_pattern has P layers beside * or M layers (or "
+                    "latent attention): a P layer's index addresses the K/V "
+                    "pools AND the state pools, which then hold the P "
+                    "layers alone")
             k = self.kda
             if self.layers_of("K") and (
                     k.num_heads < 1 or k.head_dim < 1 or k.conv_kernel < 2):
@@ -692,12 +770,14 @@ class ModelConfig:
                         + n * a.v_head_dim * h)
             elif self.attention_gate:
                 attn += h * q_dim
-            mixer = {
-                "M": h * (2 * s.inner_size + 2 * s.n_groups * s.state_size
+            mamba = (h * (2 * s.inner_size + 2 * s.n_groups * s.state_size
                           + s.num_heads)
-                + (s.conv_kernel + 1) * s.conv_channels + 3 * s.num_heads
-                + s.inner_size + s.inner_size * h,
+                     + (s.conv_kernel + 1) * s.conv_channels + 3 * s.num_heads
+                     + s.inner_size + s.inner_size * h)
+            mixer = {
+                "M": mamba,
                 "*": attn,
+                "P": attn + mamba,      # both mixers under the one norm
                 "E": h * m.router_width
                 + (m.router_width if m.selection_bias else 0)
                 + m.num_experts * per_expert * f
@@ -800,6 +880,23 @@ class ModelConfig:
             pattern = "".join(mixers[i] + ("D" if i < dense else "E")
                               for i in range(layers))
             layers = len(pattern)
+        if d.get("model_type") == "falcon_h1" and not pattern:
+            # every published layer is attention AND a Mamba-2 mixer under
+            # one norm (``P``), then the gated MLP under a second (``D``)
+            carried = {"mamba_rms_norm": True, "mamba_norm_before_gate": False,
+                       "mamba_conv_bias": True, "mamba_proj_bias": False,
+                       "mamba_use_mlp": True, "mlp_bias": False,
+                       "projectors_bias": False, "attn_layer_indices": None}
+            for key, value in carried.items():
+                if d.get(key, value) != value:
+                    raise ConfigError(
+                        f"{key} = {d[key]!r}: falcon_h1 is carried with "
+                        f"{key} {value!r} alone (the gated RMS norm gates "
+                        "BEFORE it norms, the conv has a bias and no "
+                        "projection has, every layer has both mixers and "
+                        "the MLP)")
+            pattern = "PD" * layers
+            layers = len(pattern)
         for key in ("n_group", "topk_group", "num_expert_group"):
             if latent and int(d.get(key, 1)) != 1:
                 raise ConfigError(
@@ -872,6 +969,7 @@ class ModelConfig:
                                      "mhc_h_res_clamp_min", default=-30.0)),
             hc_clamp_max=float(_take(d, "hc_clamp_max",
                                      "mhc_h_res_clamp_max", default=30.0)),
+            mup=MupConfig.from_dict(d.get("mup"), published=d),
             position_embedding=str(_take(
                 d, "position_embedding", default=(
                     "none" if latent and d.get("mla_use_nope")
@@ -896,7 +994,8 @@ class ModelConfig:
              if not isinstance(v, (dict, list))}
         d["rope"] = {"base": config.get("rope_theta", 10000.0)}
         for group in ("rope_scaling", "linear_attn_config",
-                      "mlp_only_layers", "gqa_layers"):
+                      "mlp_only_layers", "gqa_layers", "ssm_multipliers",
+                      "mlp_multipliers", "attn_layer_indices"):
             if config.get(group):
                 d[group] = config[group]
         return cls.from_dict(d)

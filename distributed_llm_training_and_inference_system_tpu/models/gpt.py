@@ -33,6 +33,7 @@ from .layers import (
     decoder_block,
     model_rope_frequencies,
     rms_norm,
+    scaled,
 )
 
 Params = Any
@@ -60,14 +61,18 @@ def init(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
         return (jax.random.truncated_normal(key_, -3, 3, shape, jnp.float32)
                 * scale).astype(dtype)
 
+    mup_std = _mup_init_std(cfg)
+
     def around(blocks):
         params = {
-            "embed": {"embedding": dense(next(keys), V, H)},
+            "embed": {"embedding": dense(next(keys), V, H,
+                                         scale=mup_std.get("embed", std))},
             "blocks": blocks,
             "final_norm": {"scale": norm_init(H)},
         }
         if not cfg.tie_word_embeddings:
-            params["lm_head"] = {"kernel": dense(next(keys), H, V)}
+            params["lm_head"] = {"kernel": dense(
+                next(keys), H, V, scale=mup_std.get("lm_head", std))}
         return params
 
     if cfg.layer_pattern:
@@ -110,6 +115,40 @@ def init(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
     return around(blocks)
 
 
+def _mup_init_std(cfg: ModelConfig) -> dict:
+    """{kernel: std of its seeded init} for a model WITH muP multipliers
+    (``cfg.mup``; {} for any other, which keeps 0.02). The published
+    multipliers are trained with: under the plain 0.02 an attention branch
+    times 0.0375 and a state-space branch times 0.088 vanish beside an
+    embedding times 5.66, and a comparison of logits is blind to both. So a
+    kernel's std is ``target / sqrt(fan_in) / its multipliers``, ``target``
+    being what the projection puts out for an input of unit RMS AFTER its
+    multipliers: every branch then adds about half the stream's RMS to the
+    residual whatever the widths and whatever the multipliers' values, which
+    keep their meaning (set one to 1 and its branch's scale moves by it).
+    ``o`` and ``down`` reckon with inputs under unit RMS (a softmax average
+    of values; silu(gate) x up at ~0.6). ``in_proj``: the x part; z, B, C
+    and dt follow by ``mup.ssm``'s ratios."""
+    from ..config.schema import MupConfig
+    m = cfg.mup
+    if m == MupConfig():
+        return {}
+    H, F = cfg.hidden_size, cfg.dense_ffn_size or cfg.ffn_size
+    # kernel: (target RMS, fan-in, the multipliers on its way out)
+    table = {"embed": (1.0, 1, m.embedding),
+             "q": (2.0, H, m.attention_in),
+             "k": (1.0, H, m.attention_in * m.key),
+             "v": (1.0, H, m.attention_in),
+             "o": (1.0, cfg.num_heads * cfg.head_dim, m.attention_out),
+             "in_proj": (1.0, H, m.ssm_in * m.ssm[1]),
+             "out_proj": (0.5, cfg.ssm.inner_size, m.ssm_out),
+             "gate": (1.0, H, m.mlp[0]), "up": (1.0, H, 1.0),
+             "down": (0.5, F, 0.6 * m.mlp[1]),
+             "lm_head": (2.0, H, m.lm_head)}
+    return {name: target / (fan_in ** 0.5 * multiplier)
+            for name, (target, fan_in, multiplier) in table.items()}
+
+
 def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
                        dtype) -> Params:
     """The blocks of a layer table: one stack a KIND (``ssm``, ``attn``,
@@ -121,9 +160,19 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
     H, D, F = cfg.hidden_size, cfg.head_dim, cfg.ffn_size
     Nq, Nkv = cfg.num_heads, cfg.num_kv_heads
     blocks = {}
-    Lm, La, Le = cfg.ssm_layers, cfg.kv_layers, cfg.moe_layers
+    mup_std, plain = _mup_init_std(cfg), dense
+
+    def dense(key_, *shape, scale=None, name=None):
+        # (``name``: a kernel that a model with muP multipliers seeds at
+        # its own std)
+        scale = mup_std.get(name, scale)
+        return plain(key_, *shape, **({} if scale is None
+                                      else {"scale": scale}))
+    Lp = cfg.layers_of("P")
+    Lm, La, Le = cfg.ssm_layers - Lp, cfg.kv_layers - Lp, cfg.moe_layers
     Ld = cfg.layers_of("D")
-    if Lm:
+
+    def ssm_stack(Lm):
         s = cfg.ssm
         nh, d_in, C, K = s.num_heads, s.inner_size, s.conv_channels, \
             s.conv_kernel
@@ -131,10 +180,10 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
             next(keys), (Lm, nh), jnp.float32,
             jnp.log(1e-3), jnp.log(1e-1)))
         bound = 1.0 / jnp.sqrt(float(K))
-        blocks["ssm"] = {
+        return {
             "norm": {"scale": norm_init(Lm, H)},
             "in_proj": {"kernel": dense(next(keys), Lm, H,
-                                        d_in + C + nh)},
+                                        d_in + C + nh, name="in_proj")},
             "conv": {"kernel": jax.random.uniform(
                          next(keys), (Lm, K, C), jnp.float32, -bound,
                          bound).astype(dtype),
@@ -148,8 +197,23 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
             "D": jnp.ones((Lm, nh), jnp.float32),
             "gate_norm": {"scale": norm_init(Lm, d_in)},
             "out_proj": {"kernel": dense(next(keys), Lm, d_in, H,
-                                         scale=resid_std)},
+                                         scale=resid_std, name="out_proj")},
         }
+
+    def attention_stack(La):
+        return {
+            "norm": {"scale": norm_init(La, H)},
+            "q": {"kernel": dense(next(keys), La, H, Nq * D, name="q")},
+            "k": {"kernel": dense(next(keys), La, H, Nkv * D, name="k")},
+            "v": {"kernel": dense(next(keys), La, H, Nkv * D, name="v")},
+            "o": {"kernel": dense(next(keys), La, Nq * D, H,
+                                  scale=resid_std, name="o")},
+        }
+    if Lm:
+        blocks["ssm"] = ssm_stack(Lm)
+    if Lp:
+        # ONE stack, ONE norm: the attention's kernels beside the mixer's
+        blocks["par"] = {**attention_stack(Lp), **ssm_stack(Lp)}
     if cfg.kda_layers:
         blocks["kda"] = _init_kda_blocks(cfg, next(keys), norm_init, dense,
                                          resid_std, dtype)
@@ -175,14 +239,7 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
                                   scale=resid_std)},
         }
     elif La:
-        blocks["attn"] = {
-            "norm": {"scale": norm_init(La, H)},
-            "q": {"kernel": dense(next(keys), La, H, Nq * D)},
-            "k": {"kernel": dense(next(keys), La, H, Nkv * D)},
-            "v": {"kernel": dense(next(keys), La, H, Nkv * D)},
-            "o": {"kernel": dense(next(keys), La, Nq * D, H,
-                                  scale=resid_std)},
-        }
+        blocks["attn"] = attention_stack(La)
         if cfg.attention_gate:
             blocks["attn"]["gate"] = {
                 "kernel": dense(next(keys), La, H, Nq * D)}
@@ -213,9 +270,10 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
         Fd = cfg.dense_ffn_size or F
         names = ("gate", "up") if cfg.mlp_gated else ("up",)
         blocks["mlp"] = {"norm": {"scale": norm_init(Ld, H)}, **{
-            n: {"kernel": dense(next(keys), Ld, H, Fd)} for n in names}}
-        blocks["mlp"]["down"] = {"kernel": dense(next(keys), Ld, Fd, H,
-                                                 scale=resid_std)}
+            n: {"kernel": dense(next(keys), Ld, H, Fd, name=n)}
+            for n in names}}
+        blocks["mlp"]["down"] = {"kernel": dense(
+            next(keys), Ld, Fd, H, scale=resid_std, name="down")}
     if cfg.hc_mult > 1:
         # a hyper-connection a sub-layer, float32. The maps start visibly
         # NOT the identity: phi's product has a spread of ~0.6 under a = 0.25
@@ -302,7 +360,8 @@ def table_layers(cfg: ModelConfig) -> list[tuple[str, int]]:
     """The layer table as (kind, index among the layers of that kind) in
     layer order: ``MEM*`` -> [("M", 0), ("E", 0), ("M", 1), ("*", 0)]. The
     index addresses the kind's parameter stack, and for ``*`` the K/V
-    pools, for ``M`` the state pools, for ``E`` the expert stacks."""
+    pools, for ``M`` the state pools, for ``P`` both, for ``E`` the expert
+    stacks."""
     seen: dict = {}
     out = []
     for kind in cfg.layer_pattern:
@@ -354,6 +413,7 @@ def layer_experts(layer: Params, expert_stacks, layer_index):
 # the float32 vectors of a state-space layer (exponentiated every token)
 # and the router's selection bias: never rounded to the compute dtype
 _KEPT_FLOAT32 = (("ssm", "dt_bias"), ("ssm", "A_log"), ("ssm", "D"),
+                 ("par", "dt_bias"), ("par", "A_log"), ("par", "D"),
                  ("kda", "dt_bias"), ("kda", "A_log"),
                  ("moe", "router", "bias"))
 
@@ -428,15 +488,18 @@ def unembed(params: Params, x: jax.Array, cfg: ModelConfig,
     """
     x = rms_norm(x, params["final_norm"]["scale"].astype(x.dtype),
                  cfg.norm_eps, impl=norm_impl)
-    if cfg.tie_word_embeddings:
-        logits = jnp.einsum(
-            "bsh,vh->bsv", x, params["embed"]["embedding"].astype(x.dtype),
-            preferred_element_type=jnp.float32)
-    else:
-        logits = jnp.einsum(
-            "bsh,hv->bsv", x, params["lm_head"]["kernel"].astype(x.dtype),
-            preferred_element_type=jnp.float32)
-    return logits.astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_word_embeddings:
+            logits = jnp.einsum(
+                "bsh,vh->bsv", x,
+                params["embed"]["embedding"].astype(x.dtype),
+                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.einsum(
+                "bsh,hv->bsv", x,
+                params["lm_head"]["kernel"].astype(x.dtype),
+                preferred_element_type=jnp.float32)
+    return scaled(logits.astype(jnp.float32), cfg.mup.lm_head)
 
 
 def forward(
@@ -508,7 +571,8 @@ def forward(
 
     from ..parallel.sharding import constrain
     emb = params["embed"]["embedding"]
-    x = constrain(emb[tokens].astype(compute_dtype), "activations")
+    x = constrain(scaled(emb[tokens].astype(compute_dtype),
+                         cfg.mup.embedding), "activations")
 
     inv_freq = model_rope_frequencies(cfg)
 
@@ -622,18 +686,24 @@ def _walk_table(params, x, cfg: ModelConfig, positions, segment_ids,
     states) of the state-space or ``K`` layers)."""
     from ..ops import kda, ssm
     blocks = cast_table_blocks(params["blocks"], compute_dtype)
-    recurs = {"M": ssm.recur_window(cfg, segment_ids),
+    window = ssm.recur_window(cfg, segment_ids)
+    recurs = {"M": window, "P": window,
               "K": kda.recur_window(cfg, segment_ids)}
     aux_total = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
     caches, tails, states, latents = [], [], [], []
     for kind, i in table_layers(cfg):
-        cache = (None if kv_cache is None or kind != "*"
+        cache = (None if kv_cache is None or kind not in "*P"
                  else (kv_cache[0][i], kv_cache[1][i]))
         x, state, aux = _block_fn(
             cfg, attn_impl, norm_impl, x.astype(compute_dtype),
             table_layer(blocks, kind, i), positions, segment_ids, inv_freq,
             kv_cache=cache, cache_offset=cache_offset, layer_index=i,
             kind=kind, recur=recurs.get(kind))
+        if kind == "P":
+            # both mixers' states: the dense cache, then (conv tail, state)
+            if cache is not None:
+                caches.append(state[0])
+            state = state[1]
         if kind in recurs:
             tails.append(state[0])
             states.append(state[1])

@@ -15,6 +15,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..config.schema import ModelConfig
@@ -285,6 +286,16 @@ def _activate(x: jax.Array, activation: str) -> jax.Array:
     return jax.nn.relu(x)
 
 
+def scaled(x: jax.Array, multiplier: float) -> jax.Array:
+    """``x`` times one of a model's muP multipliers (``ModelConfig.mup``),
+    in float32, back in x's dtype. A multiplier of exactly 1 (every model
+    but ``falcon_h1``; its ``attention_in_multiplier``) is no operation in
+    the program."""
+    if multiplier == 1.0:
+        return x
+    return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
+
+
 def dense_matmul(a: jax.Array, w: jax.Array) -> jax.Array:
     """[B, S, in] x [in, out]: how a block multiplies a plain weight."""
     return jnp.einsum("bsh,hf->bsf", a, w)
@@ -295,13 +306,14 @@ def mlp_block(x: jax.Array, layer: Params, cfg: ModelConfig,
     """Gated FFN (SwiGLU for silu — reference llama-7b.json activation),
     or with ``cfg.mlp_gated`` False the plain two-kernel
     ``down(act(up(x)))``. ``matmul(a, w)``: see ``decoder_block``."""
+    gate_by, down_by = cfg.mup.mlp
     if cfg.mlp_gated:
-        gate = matmul(x, layer["gate"]["kernel"])
+        gate = scaled(matmul(x, layer["gate"]["kernel"]), gate_by)
         up = matmul(x, layer["up"]["kernel"])
         h = _activate(gate, cfg.activation) * up
     else:
         h = _activate(matmul(x, layer["up"]["kernel"]), cfg.activation)
-    return matmul(h, layer["down"]["kernel"]).astype(x.dtype)
+    return scaled(matmul(h, layer["down"]["kernel"]), down_by).astype(x.dtype)
 
 
 def moe_route(xt: jax.Array, router_kernel: jax.Array, cfg: ModelConfig,
@@ -641,14 +653,16 @@ def decoder_block(
     same experts (plus the shared expert), ``D`` the dense ``mlp_block``,
     ``M`` the Mamba-2 mixer, whose state lives where ``recur`` says, as K
     and V live where ``attend`` says (``ssm_mixer``), ``K`` the Kimi Delta
-    Attention mixer, likewise (``kda_mixer``). With
+    Attention mixer, likewise (``kda_mixer``), ``P`` (``falcon_h1``) the
+    attention AND the Mamba-2 mixer on the SAME normed stream, their
+    outputs summed, both states returned (``attend``'s, ``recur``'s). With
     ``cfg.hc_mult`` > 1 the residual is ``hc_mult`` streams ([B, S, n, H])
     and the table's residual rule is the hyper-connection (``hc_maps``).
 
-    Returns (x, the mixer's state (``attend``'s or ``recur``'s; None for
-    an expert layer), what the caller sums over the layers: None for a
-    dense layer, the ``moe_stats`` of a dropless one, the router's aux
-    loss for the capacity route).
+    Returns (x, the mixer's state (``attend``'s or ``recur``'s, a ``P``
+    layer's pair of them; None for an expert layer), what the caller sums
+    over the layers: None for a dense layer, the ``moe_stats`` of a
+    dropless one, the router's aux loss for the capacity route).
     """
     if kind is not None:
         # a layer of a layer table: ONE norm, ONE mixer. With residual
@@ -672,7 +686,18 @@ def decoder_block(
             out, aux = experts_mixer(h, layer, cfg, live, moe_impl,
                                      layer_index, matmul)
         elif kind == "D":
-            out = mlp_block(h, layer, cfg, matmul=matmul)
+            with jax.named_scope("dense_mlp"):
+                out = mlp_block(h, layer, cfg, matmul=matmul)
+        elif kind == "P":
+            # two mixers under ONE norm: each reads ``h``, the residual
+            # takes their sum, and the layer keeps both kinds of state
+            with jax.named_scope("parallel_attention"):
+                out, kv_state = attention_mixer(
+                    h, layer, cfg, positions, inv_freq, attend, matmul)
+            with jax.named_scope("parallel_ssm"):
+                ssm_out, ssm_state = ssm_mixer(h, layer, cfg, recur, matmul)
+            out, state = out + ssm_out.astype(out.dtype), (kv_state,
+                                                           ssm_state)
         else:
             raise ValueError(f"no layer kind {kind!r}")
         if maps is not None:
@@ -704,10 +729,12 @@ def attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,
     ``attend``'s state)."""
     B, S, _ = h.shape
     D, Nq, Nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    mup = cfg.mup
+    h = scaled(h, mup.attention_in)
     q = qk_project_norm(matmul(h, layer["q"]["kernel"]), layer, "q",
                         cfg).reshape(B, S, Nq, D)
-    k = qk_project_norm(matmul(h, layer["k"]["kernel"]), layer, "k",
-                        cfg).reshape(B, S, Nkv, D)
+    k = qk_project_norm(scaled(matmul(h, layer["k"]["kernel"]), mup.key),
+                        layer, "k", cfg).reshape(B, S, Nkv, D)
     v = matmul(h, layer["v"]["kernel"]).reshape(B, S, Nkv, D)
     if cfg.attention_bias:
         q = q + layer["q"]["bias"].reshape(Nq, D)
@@ -723,7 +750,8 @@ def attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,
             gate = matmul(h, layer["gate"]["kernel"]).reshape(B, S, Nq, D)
             out = (out.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))).astype(h.dtype)
-    out = matmul(out.reshape(B, S, Nq * D), layer["o"]["kernel"])
+    out = scaled(matmul(out.reshape(B, S, Nq * D), layer["o"]["kernel"]),
+                 mup.attention_out)
     # named so remat policies can pin it resident: the flash kernel's output
     # is a custom call, not a dot, so dots_* policies rematerialise it —
     # which re-runs the whole O(S^2) flash forward inside the backward pass
@@ -891,17 +919,29 @@ def ssm_mixer(h: jax.Array, layer: Params, cfg: ModelConfig, recur,
     ``[z | xBC | dt] = h W_in``; ``recur(xBC, dt, layer)`` runs the conv
     and the recurrence wherever its state lives (ops/ssm.py
     ``recur_window`` / ``recur_step``) and returns (y [B, S, d_in], state);
-    then the gated norm and the output projection."""
+    then the gated norm and the output projection. With muP multipliers
+    (``cfg.mup``): ``ssm_in`` on h, ``ssm`` on the five parts z, x, B, C,
+    dt of the in-projection's output (one vector over its columns),
+    ``ssm_out`` on the output."""
     from ..ops.ssm import ssm_gated_norm
-    s = cfg.ssm
+    s, mup = cfg.ssm, cfg.mup
     d_in, C = s.inner_size, s.conv_channels
-    zxbcdt = matmul(h, layer["in_proj"]["kernel"])
+    with jax.named_scope("ssm_in_proj"):
+        zxbcdt = matmul(scaled(h, mup.ssm_in), layer["in_proj"]["kernel"])
+        if any(m != 1.0 for m in mup.ssm):
+            gn = s.n_groups * s.state_size
+            by_column = np.repeat(np.asarray(mup.ssm, np.float32),
+                                  [d_in, d_in, gn, gn, s.num_heads])
+            zxbcdt = (zxbcdt.astype(jnp.float32) * by_column).astype(
+                zxbcdt.dtype)
     z, xbc, dt = (zxbcdt[..., :d_in], zxbcdt[..., d_in:d_in + C],
                   zxbcdt[..., d_in + C:])
     y, state = recur(xbc, dt, layer)
     y = ssm_gated_norm(y, z, layer["gate_norm"]["scale"], s.n_groups,
                        cfg.norm_eps)
-    return matmul(y, layer["out_proj"]["kernel"]), state
+    with jax.named_scope("ssm_out_proj"):
+        return scaled(matmul(y, layer["out_proj"]["kernel"]),
+                      mup.ssm_out), state
 
 
 def kda_mixer(h: jax.Array, layer: Params, cfg: ModelConfig, recur,

@@ -28,7 +28,7 @@ from ..models.gpt import (
     table_period,
     unembed,
 )
-from ..models.layers import decoder_block, model_rope_frequencies
+from ..models.layers import decoder_block, model_rope_frequencies, scaled
 from ..ops import kda, ssm as ssm_ops
 from ..ops.mla_paged_attention import mla_paged_attention
 from ..ops.paged_attention import (
@@ -124,20 +124,21 @@ _shared_windows = jax.jit(_windows, static_argnames=("attn_impl",))
 _shared_sampler = jax.jit(sample_tokens)
 # ... and every recurrent layer's one-token update of all slots over the
 # state pools, at whichever (traced) layer, by the layer's kind
+_ssm_step = jax.jit(ssm_ops.step_pools, static_argnames=("s",))
 _shared_recur_step = {
     "K": jax.jit(kda.step_pools, static_argnames=("kd",)),
-    "M": jax.jit(ssm_ops.step_pools, static_argnames=("s",)),
+    "M": _ssm_step, "P": _ssm_step,
 }
 # a recurrent kind's module: both have ``recur_step`` / ``step_pools`` (T = 1
 # over the pools), ``recur_chunk`` (a window of ONE slot from its state) and
 # ``slot_state`` / ``write_slot_state`` / ``arm_slot_state`` for their own
 # pools' layouts
-_RECURRENT = {"K": kda, "M": ssm_ops}
+_RECURRENT = {"K": kda, "M": ssm_ops, "P": ssm_ops}
 
 
 def recurrent_ops(cfg: ModelConfig):
-    """The module of a recurrent model's kind (a table has ``M`` or ``K``
-    layers, not both): it owns the state pools' layout."""
+    """The module of a recurrent model's kind (a table has ``M`` / ``P``
+    or ``K`` layers, not both): it owns the state pools' layout."""
     return _RECURRENT["K" if cfg.kda_layers else "M"]
 
 
@@ -148,8 +149,10 @@ def can_carry(cfg: ModelConfig) -> bool:
     and both kinds of ``attend`` take a window of one slot. A recurrent
     layer runs a window of ONE slot from the slot's own state
     (``recur_chunk`` of ops/ssm.py and ops/kda.py), in the combinations that
-    are served and tested: state-space (``M``) layers beside K/V pages,
-    delta-rule (``K``) layers beside a latent pool or K/V pages."""
+    are served and tested: state-space (``M``) layers beside K/V pages
+    (``P``: in ONE layer, the attention over the slot's pages and the scan
+    from the slot's state), delta-rule (``K``) layers beside a latent pool
+    or K/V pages."""
     if cfg.is_diffusion:
         # its step is a window of 2 x ``block_length`` rows a slot already,
         # and a ``Piece`` wants T == 1
@@ -307,7 +310,8 @@ def extend_step_forward(
             jnp.ones((B, 1), bool) if write_ok is None else write_ok,
             piece_ok[:, None]])
 
-    x = params["embed"]["embedding"][tokens].astype(compute_dtype)  # [B,T,H]
+    x = scaled(params["embed"]["embedding"][tokens].astype(compute_dtype),
+               cfg.mup.embedding)                                 # [B,T,H]
     inv_freq = model_rope_frequencies(cfg)
 
     # W4A16 weights go through the in-kernel-dequant Pallas matmul on the
@@ -474,9 +478,13 @@ def extend_step_forward(
             x, kp, vp, conv, ssm, stats, piece = carry
             x, state, layer_stats = decoder_block(
                 x, table_layer(blocks, kind, i), cfg, positions, inv_freq,
-                attend_at(kp, vp, i) if kind == "*" else None, matmul=mm,
+                attend_at(kp, vp, i) if kind in "*P" else None, matmul=mm,
                 live=live, layer_index=i, kind=kind,
                 recur=recur_at(kind, conv, ssm, i, piece))
+            if kind == "P":
+                # both mixers' states: the page pools, then the recurrent one
+                (kp, vp), state = state
+                kind = "M"
             if kind == "*":
                 kp, vp = state
             elif kind in "MK" and piece is not None:
@@ -708,8 +716,9 @@ def decode_scan(params, tokens, positions, k_pages, v_pages, block_tables,
                 sampling = [whole(a) for a in sampling]
         keys = jax.vmap(jax.random.fold_in)(
             jax.vmap(jax.random.wrap_key_data)(key_data), fold)
-        nxt = (sample_tokens if ride is None else _shared_sampler)(
-            logits, keys, *sampling)
+        with jax.named_scope("sampler"):
+            nxt = (sample_tokens if ride is None else _shared_sampler)(
+                logits, keys, *sampling)
         first = jnp.int32(0)
         if ride is not None:
             nxt = nxt[:len(toks) + 1]          # without the tile's padding
