@@ -364,6 +364,15 @@ def phase_kernels(env: dict) -> dict:
         if on_tpu and rec["impl"] != "native":
             raise SmokeFailure("data packer fell back to numpy on the chip "
                                f"machine: {rec['detail']}")
+    # (PR 50) the one-token update of the state pools is the kernel at both
+    # mixers' widths (ops/ssm.py step_pools picks by what it can see)
+    updates = [(impl, detail) for op, impl, detail in impl_lines(text)
+               if op == "ssm_decode"]
+    say(f"  kernels: impl ssm_decode={updates}")
+    if on_tpu and (len(updates) < 2
+                   or any(impl != "pallas" for impl, _ in updates)):
+        raise SmokeFailure("kernels: the state pools' decode step is not "
+                           f"ssm_decode=pallas at both widths: {updates}")
     secs, n = compile_seconds(text)
     say(f"  kernels: {n} programs compiled in {secs:.1f}s; phase "
         f"{time.monotonic() - t0:.1f}s")
@@ -1239,6 +1248,20 @@ def child_kernels() -> None:
     decoded, conv_pool, ssm_pool = decode_slot_2(conv_pool, ssm_pool)
     check(f"ssm_mixer decode behind the pieces [{steps} steps, slot 2 of "
           f"{slots} live]", decoded, ref[n_live:])
+    # (PR 50) the idle slots now HOLD states (a former occupant's): the
+    # decode steps leave them bit for bit, and a step with NO live slot
+    # leaves both pools, every layer of them, bit for bit
+    if any(not bool(jnp.array_equal(a[:, others], b))
+           for a, b in zip((conv_pool, ssm_pool), before)):
+        failures.append("ssm decode wrote an idle slot's state")
+    stood = (np.asarray(conv_pool), np.asarray(ssm_pool))
+    _, (conv_pool, ssm_pool) = jax.jit(lambda h, c, s_, p: ssm_mixer(
+        h, p, hy, ssm.recur_step(hy, c, s_, 1, jnp.zeros((slots, 1), bool))),
+        donate_argnums=(1, 2))(h_norm[:, :1].repeat(slots, 0), conv_pool,
+                               ssm_pool, mix)
+    if not (np.array_equal(np.asarray(conv_pool), stood[0])
+            and np.array_equal(np.asarray(ssm_pool), stood[1])):
+        failures.append("an ssm decode step with no live slot wrote a state")
 
     moe_l = jax.tree_util.tree_map(lambda a: a[1], {
         k: v for k, v in blocks["moe"].items() if k not in ("up", "down")})
@@ -1582,6 +1605,18 @@ def child_kernels() -> None:
           jnp.stack(got), want, tol=5e-2)
     if float(jnp.abs(fstate["ssm"][:, jnp.asarray([0, 1, 3])]).max()) != 0.0:
         failures.append("falcon_h1 decode wrote an idle slot's state")
+    # (PR 50) ... and one step with NO live slot: both state pools bit for bit
+    stood = jax.tree_util.tree_map(np.asarray, fstate)
+    out = jax.jit(lambda p, t, pos, kp, vp, st: decode_step_forward(
+        p, t, pos, kp, vp, jnp.asarray(table), fh,
+        active=jnp.zeros((slots,), bool), ssm_state=st),
+        donate_argnums=(3, 4, 5))(
+        fparams, jnp.full((slots,), 7, jnp.int32),
+        jnp.full((slots,), n_live + steps, jnp.int32), kp, vp, fstate)
+    if not all(np.array_equal(np.asarray(out.state[k]), stood[k])
+               for k in stood):
+        failures.append("a falcon_h1 decode step with no live slot wrote a "
+                        "state")
     for wrong in ("drop_attention", "drop_ssm"):
         moved = float(jnp.abs(want - parallel_decoder.logits(
             fparams, seq, pub, positions=range(n_live - 1, len(seq)),

@@ -15,7 +15,9 @@ by the heads of a group ``g(i)``. Two forms of the same recurrence:
   carried. A position with ``dt = 0`` decays by 1 and adds nothing: that
   is how a bucket's padding is kept out of the state.
 - ``ssm_decode``: the one-step update over ``[slots]``, elementwise in
-  float32; the step reads and writes every live slot's state once.
+  float32; the step reads and writes every live slot's state once
+  (``ssm_decode_pool``: the same step as one Pallas kernel over the state
+  pool in place, the chip's form, which visits the live slots alone).
 
 ``recur_window`` / ``recur_step`` / ``recur_chunk`` say where the state
 lives, as ``attend`` does for K and V (models/layers.py ``decoder_block``):
@@ -154,6 +156,175 @@ def ssm_decode(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
                 new.reshape(S, nh, P, N).astype(h.dtype))
 
 
+_BLOCK_BYTES = 1 << 20      # of state a grid step holds (in + out, double-
+                            # buffered: 4 of the 16 MB of scoped VMEM)
+_ROW = 128                  # (head, channel) pairs a row of the step's tiles
+
+
+def _heads_a_block(nh: int, P: int, N: int, G: int) -> int:
+    """Heads of one slot a grid step of ``ssm_decode_pool``: the most whose
+    state [hb, P, N] float32 is at most ``_BLOCK_BYTES``, that divide the
+    heads, that lie inside one B/C group or hold whole ones, and whose
+    (head, channel) pairs are whole rows of 128 a unit of heads sharing a
+    group (8 heads of [128, 256] at the parallel cell's shapes, inside one
+    group of 16; 32 heads of [64, 128] = 4 groups at the hybrid cell's).
+    0: the kernel does not take these shapes (``step_pools`` then keeps
+    the XLA form)."""
+    if N % 128 or P % 8 or nh % G:
+        return 0
+    r = nh // G
+    for hb in range(min(max(_BLOCK_BYTES // (P * N * 4), 1), nh), 0, -1):
+        if (nh % hb == 0 and (hb % r == 0 or r % hb == 0)
+                and (min(hb, r) * P) % _ROW == 0
+                and (P % _ROW == 0 or _ROW % P == 0)
+                and hb * P // _ROW <= _ROW):
+            return hb
+    return 0
+
+
+def _decode_pool_kernel(layer_ref, ids_ref, count_ref, d_ref, x_ref, b_ref,
+                        c_ref, s_ref, y_ref, new_ref):
+    """One grid step: a block of ``hb`` heads of one LIVE slot. The state
+    block [hb, P, N] is walked as rows of 128 (head, channel) pairs, a tile
+    [128, N] each: read once, ``new = h decay + (dt x) B`` written once and
+    ``y = sum_N(new C)`` taken from the same tile. ``d_ref`` [hb, N] holds a
+    head's decay along the lanes, ``b_ref`` / ``c_ref`` [units, N] the B
+    and C of each unit of heads that share a group; ``x_ref`` [R, 128]
+    holds ``dt x`` a row and is turned into columns by ONE transpose of
+    its padded [128, 128] tile. The sum over ``N`` lies on the lanes: the
+    lane tiles of ``new C`` are added, the [128, 128] that is left is
+    transposed and summed over its sublanes, which leaves the row's pairs
+    on the lanes as ``y_ref`` holds them. (Alone on the chip this form,
+    a cross-lane reduce a vreg row with the columns put back by one more
+    transpose, the MXU against C, and ``dt x`` by a transpose a row all
+    read within 1 % at the parallel pool, 76-77 % of the HBM peak, and
+    within 4 % at the hybrid pool, where this one and the MXU's lead:
+    the stream decides, not the reduce; PERF.md 6, PR 50.) A grid step
+    past the live slots' count skips all of it: its blocks are the last
+    live step's (nothing is fetched), and what that step wrote is what the
+    pipeline writes back."""
+    from jax.experimental import pallas as pl
+    i, h = pl.program_id(0), pl.program_id(1)
+    hb, P, N = s_ref.shape
+    R, units = x_ref.shape[0], b_ref.shape[0]
+    f32 = jnp.float32
+
+    @pl.when(i < count_ref[0])
+    def _live():
+        xt = x_ref[...]
+        if R < _ROW:
+            xt = jnp.concatenate([xt, jnp.zeros((_ROW - R, _ROW), f32)], 0)
+        xt = xt.T                                   # [pair of a row, row]
+        rows = []
+        for j in range(R):
+            u = j * units // R
+            if P >= _ROW:       # a row is a slice of one head's channels
+                head, c0 = j * _ROW // P, j * _ROW % P
+                at = (head, slice(c0, c0 + _ROW))
+                decay = d_ref[head:head + 1, :]
+            else:               # a row is a few whole heads
+                at = slice(j * _ROW // P, (j + 1) * _ROW // P)
+                decay = jnp.broadcast_to(d_ref[at][:, None, :],
+                                         (_ROW // P, P, N)).reshape(_ROW, N)
+            tile = s_ref[at]
+            new = (tile.reshape(_ROW, N) * decay
+                   + xt[:, j:j + 1] * b_ref[u:u + 1, :])
+            new_ref[at] = new.reshape(tile.shape)
+            weighed = new * c_ref[u:u + 1, :]
+            fold = weighed[:, :_ROW]
+            for t in range(_ROW, N, _ROW):
+                fold = fold + weighed[:, t:t + _ROW]
+            rows.append(jnp.sum(fold.T, axis=0, keepdims=True))
+        y_ref[...] = jnp.concatenate(rows, axis=0)
+
+    # no live slot at all: the pipeline still writes the one block it
+    # holds back; hand it what it fetched
+    @pl.when((count_ref[0] == 0) & (i == 0) & (h == 0))
+    def _nobody():
+        new_ref[...] = s_ref[...]
+
+
+def ssm_decode_pool(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
+                    Cm: jax.Array, D: jax.Array, pool: jax.Array, layer,
+                    write_ok: Optional[jax.Array] = None,
+                    interpret: bool = False) -> tuple[jax.Array, jax.Array]:
+    """``ssm_decode`` as ONE Pallas kernel over ``pool[layer]`` in place
+    (``pool`` [Lm, slots, nh, P, N] float32, aliased to the output), for
+    the slots ``write_ok`` [slots] marks (None: all): a live slot's state
+    is read once and written once, and ``y`` is summed from the tile that
+    is written (the XLA form at a state of 256 read the new state again for
+    ``y``, and both its passes ran over every slot: 9.3 ms a step of the
+    parallel cell against a floor of 3.9; PERF.md 6, PR 50). A slot that is
+    not marked costs no bytes: the live slots' ids and their count reach
+    the block specs as prefetched scalars, the grid walks the live ids
+    first, and a step past the count names the block the last live step
+    left in VMEM and skips its body. Such a slot's state stays bit for bit
+    and its ``y`` is 0. ``layer`` may be traced (an argument of the jitted
+    ``step_pools`` every layer of a riding program calls). x [slots, nh,
+    P], dt [slots, nh] float32, Bm, Cm [slots, G, N], A, D [nh] float32.
+    Returns (y [slots, nh, P] in x's dtype, the pool). Float32
+    throughout, as ``ssm_decode``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, nh, P = x.shape
+    G, N = Bm.shape[-2:]
+    hb = _heads_a_block(nh, P, N, G)
+    if not hb or pool.dtype != jnp.float32:
+        raise ValueError(f"ssm_decode_pool: {nh} heads of [{P}, {N}] in {G} "
+                         f"groups, state {pool.dtype}")
+    r = nh // G
+    unit = min(hb, r)           # heads of a block that share B and C
+    nhb, units, R = nh // hb, hb // unit, hb * P // _ROW
+    f32 = jnp.float32
+    report_impl("ssm_decode", "pallas-interpret" if interpret else "pallas",
+                f"h{tuple(pool.shape)} {hb} heads a block")
+
+    def block_of(i, h, ly, ids, count):
+        return ids[i], jnp.where(i < count[0], h, nhb - 1)
+    small = lambda *block: pl.BlockSpec(
+        (None, None, *block), lambda *at: (*block_of(*at), 0, 0))
+    state = pl.BlockSpec((None, None, hb, P, N),
+                         lambda *at: (at[2][0], *block_of(*at), 0, 0))
+    with jax.named_scope("ssm_decode"):
+        xf = x.astype(f32)
+        ok = (jnp.ones((S,), bool) if write_ok is None
+              else write_ok.reshape(S))
+        # the live slots' ids first, in order; past their count the last
+        # live one again (0 where nobody is live)
+        rank = jnp.cumsum(ok, dtype=jnp.int32) - 1
+        count = rank[-1] + 1
+        slots = jnp.arange(S, dtype=jnp.int32)
+        ids = jnp.sum(jnp.where(
+            ok & (rank == jnp.minimum(slots, count - 1)[:, None]), slots, 0),
+            axis=1, dtype=jnp.int32)
+        decay = jnp.broadcast_to(jnp.exp(dt * A)[..., None], (S, nh, N))
+        by_unit = lambda m: jnp.repeat(m.astype(f32), r // unit, axis=1
+                                       ).reshape(S, nhb, units, N)
+        y, pool = pl.pallas_call(
+            _decode_pool_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,      # the layer, live ids, their count
+                grid=(S, nhb),
+                in_specs=[small(hb, N), small(R, _ROW), small(units, N),
+                          small(units, N), state],
+                out_specs=[small(R, _ROW), state]),
+            out_shape=[jax.ShapeDtypeStruct((S, nhb, R, _ROW), f32),
+                       jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+            input_output_aliases={7: 1},
+            # in order: a step past the count leans on the one before it
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret,
+            name="ssm_decode",
+        )(jnp.asarray(layer, jnp.int32).reshape(1), ids, count.reshape(1),
+          decay.reshape(S, nhb, hb, N),
+          (dt[..., None] * xf).reshape(S, nhb, R, _ROW),
+          by_unit(Bm), by_unit(Cm), pool)
+        y = jnp.where(ok[:, None, None],
+                      y.reshape(S, nh, P) + D[:, None] * xf, 0.0)
+        return y.astype(x.dtype), pool
+
+
 def ssm_gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array,
                    groups: int, eps: float) -> jax.Array:
     """``y * silu(z)``, THEN an RMS norm over each of ``groups`` equal
@@ -248,19 +419,32 @@ def step_pools(xbc: jax.Array, dt_raw: jax.Array, p: dict,
     act, padded = ssm_conv(xbc, p["conv"]["kernel"], p["conv"]["bias"], tail)
     x, Bm, Cm = _split(act[:, 0], s)
     dt, A, D = _step_sizes(dt_raw[:, 0], p)
-    old = ssm_pool[layer]
-    y, new = ssm_decode(x, dt, A, Bm, Cm, D, old)
+    # the chip takes the kernel wherever its tiles fit the heads (both
+    # cells' do; a test template's 16-wide state does not)
+    kernel = (jax.default_backend() == "tpu"
+              and ssm_pool.dtype == jnp.float32
+              and _heads_a_block(s.num_heads, s.head_dim, s.state_size,
+                                 s.n_groups) > 0)
+    if kernel:
+        y, new_pool = ssm_decode_pool(x, dt, A, Bm, Cm, D, ssm_pool, layer,
+                                      write_ok)
+    else:
+        old = ssm_pool[layer]
+        y, new = ssm_decode(x, dt, A, Bm, Cm, D, old)
     new_tail = padded[:, 1:].astype(conv_pool.dtype)
     # (under the update's scope: XLA fuses the update into the pool's
     # in-place write, and a trace names the fusion by its ROOT)
     with jax.named_scope("ssm_decode"):
         if write_ok is not None:
             ok = write_ok.reshape(B)
-            new = jnp.where(ok[:, None, None, None], new, old)
+            if not kernel:
+                new = jnp.where(ok[:, None, None, None], new, old)
             new_tail = jnp.where(ok[:, None, None], new_tail, tail)
-        return (y.reshape(B, 1, -1),
-                (conv_pool.at[layer].set(new_tail),
-                 ssm_pool.at[layer].set(new)))
+        y = y.reshape(B, 1, -1)
+        conv_pool = conv_pool.at[layer].set(new_tail)
+        if not kernel:
+            new_pool = ssm_pool.at[layer].set(new)
+        return y, (conv_pool, new_pool)
 
 
 def recur_step(cfg, conv_pool: jax.Array, ssm_pool: jax.Array, layer,

@@ -725,6 +725,50 @@ def test_ssm_decode_is_one_step_of_the_scan():
     assert np.abs(np.asarray(state - h)).max() < 1e-4
 
 
+# write_ok over 5 slots: who moves this step
+_LIVE = {"all": [1, 1, 1, 1, 1], "every_other": [1, 0, 1, 0, 1],
+         "one": [0, 0, 1, 0, 0], "none": [0, 0, 0, 0, 0],
+         "last_dead": [1, 1, 1, 1, 0]}
+# (heads, head_dim, state, groups) of the two cells that run the update
+_CELL_HEADS = {"parallel": (32, 128, 256, 2), "hybrid": (64, 64, 128, 8)}
+
+
+@pytest.mark.parametrize("layer_is", ["static", "traced"])
+@pytest.mark.parametrize("live", list(_LIVE))
+@pytest.mark.parametrize("heads", list(_CELL_HEADS))
+def test_ssm_decode_pool_is_ssm_decode_in_place(heads, live, layer_is):
+    """The Pallas form of the one-token update (interpret mode) over a
+    three-layer pool at both cells' head shapes against ``ssm_decode`` and
+    the select ``step_pools`` keeps off the chip: ``y`` and the live
+    slots' states to 1e-5, a slot that ``write_ok`` leaves out and every
+    other layer of the pool BIT FOR BIT (a step with nobody live too) and
+    its ``y`` 0, the layer a Python int or traced."""
+    nh, P, N, G = _CELL_HEADS[heads]
+    S, Lm, layer = 5, 3, 1
+    ok = jnp.asarray(_LIVE[live], bool)
+    ks = jax.random.split(jax.random.PRNGKey(7), 7)
+    ops = (jax.random.normal(ks[0], (S, nh, P)),
+           jax.nn.softplus(jax.random.normal(ks[1], (S, nh))),
+           -jnp.exp(0.5 * jax.random.normal(ks[2], (nh,))),
+           jax.random.normal(ks[3], (S, G, N)),
+           jax.random.normal(ks[4], (S, G, N)),
+           jax.random.uniform(ks[5], (nh,), minval=0.5, maxval=1.5))
+    pool = jax.random.normal(ks[6], (Lm, S, nh, P, N))
+    want_y, want = ssm.ssm_decode(*ops, pool[layer])
+    step = jax.jit(lambda ops, pool, at: ssm.ssm_decode_pool(
+        *ops, pool, layer if layer_is == "static" else at, ok[:, None],
+        interpret=True))
+    y, got = step(ops, pool, jnp.int32(layer))
+    y, got, before = np.asarray(y), np.asarray(got), np.asarray(pool)
+    alive = np.asarray(ok)
+    scale = np.abs(np.asarray(want_y)).max()
+    assert np.abs(y - np.asarray(want_y))[alive].max(initial=0) < 1e-5 * scale
+    assert np.abs(got[layer] - np.asarray(want))[alive].max(initial=0) < 1e-5
+    assert np.array_equal(got[layer][~alive], before[layer][~alive])
+    assert np.array_equal(got[[0, 2]], before[[0, 2]])
+    assert not y[~alive].any()
+
+
 def test_gated_norm_gates_then_norms():
     y = jax.random.normal(jax.random.PRNGKey(0), (3, 32))
     z = jax.random.normal(jax.random.PRNGKey(1), (3, 32))

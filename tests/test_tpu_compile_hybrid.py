@@ -39,6 +39,8 @@ from tpu_compile_support import (
     _compile,
     _sds,
     _no_copy_of,
+    _ssm_decode_pool_compiles,
+    _state_update_is_the_kernel,
 )
 
 
@@ -132,6 +134,7 @@ def _hybrid_decode_program(one_chip):
         # scan, under the names a prefill program's have
         assert ("paged_attention_mq" in text) == bool(carry)
         assert ("ssm_scan_prefill" in text) == bool(carry)
+        _state_update_is_the_kernel(text, "f32[64,64,64,128]")
         _no_copy_of(text, ["bf16[6,64,1856,2688]", "f32[6,64,64,64,128]",
                            "bf16[2,1537,2,64,128]"]
                     # (the carrying program re-lays the 14 MB conv pool at
@@ -146,6 +149,15 @@ def _hybrid_decode_program(one_chip):
 
 
 HYBRID_STATE_POOL = 6 * 64 * 64 * 64 * 128 * 4
+
+
+def test_ssm_decode_kernel_updates_the_state_pool_in_place(one_chip, as_tpu):
+    """The one-token update as a kernel on the hybrid cell's state pool (6
+    layers x 64 slots x 64 heads x [64, 128] float32 = 0.8 GB): 32 heads
+    = 4 B/C groups a grid step, a row of 128 (head, channel) pairs two
+    whole heads."""
+    _ssm_decode_pool_compiles(_sds(one_chip), 6, 64, 64, 64, 128, 8,
+                              heads_a_block=32)
 
 
 def test_hybrid_decode_program_moves_no_pool_and_no_stack(one_chip, as_tpu):

@@ -18,7 +18,14 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tpu_compile_support import D, PS, _no_copy_of, _sds
+from tpu_compile_support import (
+    D,
+    PS,
+    _no_copy_of,
+    _sds,
+    _ssm_decode_pool_compiles,
+    _state_update_is_the_kernel,
+)
 
 ROOT = Path(__file__).resolve().parents[1]
 PAGES = 2384            # 1.25 GB of 8,192 B a token in pages of 64
@@ -105,6 +112,16 @@ def decode_program(one_chip):
     return compile_
 
 
+def test_ssm_decode_kernel_updates_the_state_pool_in_place(one_chip, as_tpu):
+    """The one-token update as a kernel on the cell's state pool (4 layers
+    x 128 slots x 32 heads x [128, 256] float32 = 2.15 GB), 8 heads a grid
+    step inside one B/C group of 16: Mosaic takes the [128, 128]
+    transposes that turn ``dt x`` into columns and put a row's sums over
+    the state back on the lanes."""
+    _ssm_decode_pool_compiles(_sds(one_chip), 4, 128, 32, 128, 256, 2,
+                              heads_a_block=8)
+
+
 def test_decode_program_moves_no_pool_and_no_stack(decode_program, as_tpu):
     """The multi-step decode program: both pools of every layer ride the
     carry and are written at [layer]; no copy of a page pool, of the state
@@ -120,6 +137,7 @@ def test_decode_program_moves_no_pool_and_no_stack(decode_program, as_tpu):
     # program's entry and exit, outside the step loop, once a dispatch: the
     # step wants its 3 columns off the lanes. 2 x 7.9 MB in ~12 steps)
     _no_copy_of(text, NO_COPY + ["bf16[4,5120,9248]"])
+    _state_update_is_the_kernel(text, "f32[128,32,128,256]")
     assert mem.temp_size_in_bytes < 1.3e9, (
         f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries")
     assert mem.alias_size_in_bytes >= STATE_POOL + 2 * 625e6
@@ -136,6 +154,7 @@ def test_carrying_decode_program_fits_the_chip(decode_program, as_tpu):
     import re
     (_, plain), (text, carrying) = decode_program(0), decode_program(2 * PS)
     _no_copy_of(text, NO_COPY, fused_into_at_most=32 << 20)
+    _state_update_is_the_kernel(text, "f32[128,32,128,256]")
     # the named copies are the ENTRY computation's, not a step's
     entry = text[text.index("\nENTRY "):]
     for shape in ("bf16[4,5120,9248]", "bf16[4,5120,2560]"):

@@ -130,3 +130,35 @@ def _no_copy_of(text: str, shapes: list[str],
         assert made is not line and handed_out <= fused_into_at_most, (
             f"a pool- or stack-sized copy: {line[:200]}\n"
             f"(handed out by: {made[:200]})")
+
+
+def _ssm_decode_pool_compiles(sds, Lm, B, nh, P, N, G, heads_a_block):
+    """``ops/ssm.py ssm_decode_pool`` on a cell's whole state pool [Lm, B,
+    nh, P, N] float32, the layer traced and the slots masked, compiled for
+    the described chip: a Mosaic kernel of ``heads_a_block`` heads a grid
+    step, the donated pool aliased to the output, nothing the size of a
+    layer's slab temporary."""
+    from distributed_llm_training_and_inference_system_tpu.ops import ssm
+    assert ssm._heads_a_block(nh, P, N, G) == heads_a_block
+    compiled = jax.jit(ssm.ssm_decode_pool, donate_argnums=(6,)).lower(
+        sds((B, nh, P), jnp.bfloat16), sds((B, nh), jnp.float32),
+        sds((nh,), jnp.float32), sds((B, G, N), jnp.bfloat16),
+        sds((B, G, N), jnp.bfloat16), sds((nh,), jnp.float32),
+        sds((Lm, B, nh, P, N), jnp.float32), sds((), jnp.int32),
+        sds((B, 1), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= Lm * B * nh * P * N * 4
+    assert mem.temp_size_in_bytes < 32 << 20
+
+
+def _state_update_is_the_kernel(text: str, slab: str) -> None:
+    """A decode program's one-token update of the state pool is the Mosaic
+    kernel ``ssm_decode`` on the WHOLE pool (PR 50), and no operation is
+    left that takes or makes a layer's ``slab`` of it (the XLA form's
+    update fusion did, and at a state of 256 the ``multiply_reduce`` that
+    read the new state again for ``y``)."""
+    import re
+    assert re.search(r"%ssm_decode(\.\d+)? = .*custom-call\(", text)
+    held = [line.strip()[:160] for line in text.splitlines() if slab in line]
+    assert not held, held[:3]
