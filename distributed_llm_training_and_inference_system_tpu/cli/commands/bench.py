@@ -384,18 +384,6 @@ def e2e(model_name, mode, steps, batch, seq_len, prompt_len, gen_len,
         def fresh_engine():
             return InferenceEngine(cfg, point_serve_cfg())
 
-        def _reset_counters(eng):
-            # zero EVERY counter stats() derives ratios from — a partial
-            # reset left warmup padded-slot steps in the utilization
-            # denominator's sibling (review r4)
-            eng.total_prefill_tokens = 0
-            eng.total_prefill_padded_tokens = 0
-            eng.total_prefill_ride_tokens = 0
-            eng.total_prefill_ride_steps = 0
-            eng.total_decode_steps = 0
-            eng.total_padded_slot_steps = 0
-            eng.total_short_dispatches = 0
-
         last_engine: list = []
 
         def warmed_fleet():
@@ -440,7 +428,7 @@ def e2e(model_name, mode, steps, batch, seq_len, prompt_len, gen_len,
                 r.engine.generate([list(range(1, prompt_len + 1))],
                                   SamplingParams(temperature=0.0,
                                                  max_tokens=2))
-                _reset_counters(r.engine)
+                r.engine.reset_counters()
             fleet.start()
             last_engine.append(fleet)
             return fleet
@@ -464,7 +452,7 @@ def e2e(model_name, mode, steps, batch, seq_len, prompt_len, gen_len,
             eng = fresh_engine()
             eng.generate([list(range(1, prompt_len + 1))],
                          SamplingParams(temperature=0.0, max_tokens=2))
-            _reset_counters(eng)
+            eng.reset_counters()
             last_engine.append(eng)
             return eng
 
@@ -478,8 +466,12 @@ def e2e(model_name, mode, steps, batch, seq_len, prompt_len, gen_len,
                     "padded_slot_steps", "prefill_tokens", "preemptions",
                     "requeue_cached_tokens", "prefix_cached_tokens",
                     "prefix_fetched_tokens")
-            agg = {k: sum(e.stats().get(k) or 0 for e in engines)
-                   for k in keys}
+            stats = [e.stats() for e in engines]
+            agg = {k: sum(s.get(k) or 0 for s in stats) for k in keys}
+            # what the slot steps were (useful, overrun, prompt_wait and
+            # empty add up to decode_steps x slots) and the tokens credited
+            agg["slot_steps"] = {k: sum(s["slot_steps"][k] for s in stats)
+                                 for k in stats[0]["slot_steps"]}
             B = engines[0].serve_cfg.max_batch_size
             agg["decode_slot_utilization"] = round(
                 1.0 - agg["padded_slot_steps"]
@@ -596,7 +588,7 @@ def e2e(model_name, mode, steps, batch, seq_len, prompt_len, gen_len,
                         kvp._write_pages_idx(
                             np.zeros(bucket, np.int32), z, z)
                         bucket <<= 1
-                    _reset_counters(r.engine)
+                    r.engine.reset_counters()
                     r.engine.kv.flush_prefix_cache()
                 fleet.start()
                 try:
@@ -705,7 +697,7 @@ def e2e(model_name, mode, steps, batch, seq_len, prompt_len, gen_len,
                         lap(0)
                         fleet.pipeline.reset_counters()
                         for r in fleet.replicas:
-                            _reset_counters(r.engine)
+                            r.engine.reset_counters()
                             with r.engine.lock:
                                 r.engine.kv.flush_prefix_cache()
                     return lap(1)
@@ -855,7 +847,7 @@ def e2e(model_name, mode, steps, batch, seq_len, prompt_len, gen_len,
                             SamplingParams(temperature=0.0,
                                            max_tokens=2))
                         n <<= 1
-                    _reset_counters(r.engine)
+                    r.engine.reset_counters()
                     with r.engine.lock:
                         r.engine.kv.flush_prefix_cache()
                 fleet.start()

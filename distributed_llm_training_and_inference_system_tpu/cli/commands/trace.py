@@ -52,6 +52,14 @@ def capture(config_file, model_name, steps, out_dir, serve, seconds):
         done = capture_serve(model_name, out_dir, seconds)
         click.echo(f"captured {done['requests']} requests "
                    f"({done['decode_steps']} decode steps) into {out_dir}")
+        # the engine's own account of the same stretch, to hold against
+        # summarize's "idle by span" (the device trace's gaps by the span
+        # that covers them): busy and nothing in flight, by the span open
+        click.echo(f"the engine was starved {done['starved_s']:.4f} s of "
+                   f"{done['clock_s']:.4f}: " + ", ".join(
+                       f"{name} {s:.4f}" for name, s in sorted(
+                           done["starved_by_phase"].items(),
+                           key=lambda kv: -kv[1])))
         click.echo(f"read it with: llmctl trace summarize {out_dir}")
         return
     from ...config.loader import load_run_config
@@ -126,7 +134,12 @@ def capture_serve(model_name: str, out_dir: str, seconds: float,
         jax.profiler.stop_trace()
     after = engine.stats()
     return {"requests": 16 * rounds,
-            "decode_steps": after["decode_steps"] - before["decode_steps"]}
+            "decode_steps": after["decode_steps"] - before["decode_steps"],
+            "clock_s": after["clock_s"] - before["clock_s"],
+            "starved_s": after["starved_s"] - before["starved_s"],
+            "starved_by_phase": {
+                name: s - before["starved_by_phase"].get(name, 0.0)
+                for name, s in after["starved_by_phase"].items()}}
 
 
 # -- from a profile to what the host did in each gap ---------------------------

@@ -76,6 +76,15 @@ METRICS: dict[str, MetricSpec] = {
     "llmctl_engine_phase_seconds_total": MetricSpec(
         COUNTER, "Engine-thread self time by llmctl.engine.* span",
         ("phase",)),
+    "llmctl_starved_seconds_total": MetricSpec(
+        COUNTER, "Seconds a slot held a request and no program was in "
+                 "flight, by the llmctl.engine.* span the engine thread "
+                 "was in (\"(no span)\" between spans)", ("span",)),
+    "llmctl_slot_steps_total": MetricSpec(
+        COUNTER, "Slots of decode steps by what became of them: useful "
+                 "(a request was credited with the result), overrun (a "
+                 "live slot's result was nobody's), prompt_wait (a seated "
+                 "request was not live yet), empty", ("class",)),
     "llmctl_startup_phase_seconds": MetricSpec(
         GAUGE, "Self seconds of the process's start-up by llmctl.startup.* "
                "span (a program's first call is phase "
@@ -452,6 +461,20 @@ COUNTER_FLOW: tuple[CounterFlow, ...] = (
                 "partial_restores", None),
     CounterFlow("InferenceEngine", "total_padded_slot_steps",
                 "padded_slot_steps", None),
+    # the slot-step ledger (stats()["slot_steps"]): the four classes add
+    # up to decode_steps x slots
+    CounterFlow("InferenceEngine", "total_useful_slot_steps", "useful",
+                "llmctl_slot_steps_total"),
+    CounterFlow("InferenceEngine", "total_overrun_slot_steps", "overrun",
+                "llmctl_slot_steps_total"),
+    CounterFlow("InferenceEngine", "total_prompt_wait_slot_steps",
+                "prompt_wait", "llmctl_slot_steps_total"),
+    CounterFlow("InferenceEngine", "total_empty_slot_steps", "empty",
+                "llmctl_slot_steps_total"),
+    CounterFlow("InferenceEngine", "total_first_tokens", "first_tokens",
+                None),
+    CounterFlow("InferenceEngine", "total_tokens_credited",
+                "tokens_credited", None),
     CounterFlow("InferenceEngine", "total_live_pages", "live_pages", None),
     CounterFlow("InferenceEngine", "total_table_pages", "table_pages",
                 None),

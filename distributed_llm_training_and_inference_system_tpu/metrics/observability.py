@@ -219,6 +219,12 @@ class PrometheusExporter:
         # the engine thread's self time by llmctl.engine.* span
         # (metrics/spans.py), from the running totals of engine.stats()
         self.engine_phase_seconds = mk("llmctl_engine_phase_seconds_total")
+        # seconds a slot held a request and nothing was in flight, by the
+        # span the engine thread was in (engine.stats()["starved_by_phase"])
+        self.starved_seconds = mk("llmctl_starved_seconds_total")
+        # what became of the decode steps' slots
+        # (engine.stats()["slot_steps"]: useful, overrun, prompt_wait, empty)
+        self.slot_steps = mk("llmctl_slot_steps_total")
         # the process's start-up (engine.stats()["startup"]["phases"])
         self.startup_phase_seconds = mk("llmctl_startup_phase_seconds")
         # an MoE model's routing (engine.stats()["moe"]["choices"])
@@ -400,6 +406,14 @@ class PrometheusExporter:
         if "step" in m:   # true optimizer step (events fire at log_interval)
             self.steps.set(m["step"])
 
+    def _inc_to(self, counter, key: str, total: float) -> None:
+        """A running total, as the engine reports them: add what it gained
+        since the last report."""
+        delta = total - self._last_totals.get(key, 0)
+        if delta > 0:
+            counter.inc(delta)
+        self._last_totals[key] = total
+
     def export_inference(self, m: dict) -> None:
         self.infer_requests.inc()
         if "latency_ms" in m:
@@ -411,19 +425,20 @@ class PrometheusExporter:
         if "queue_wait_ms" in m:
             self.infer_queue_wait.snap = m["queue_wait_ms"]
         for phase, cell in m.get("phases", {}).items():
-            key = f"phase:{phase}"
-            delta = cell["s"] - self._last_totals.get(key, 0.0)
-            if delta > 0:
-                self.engine_phase_seconds.labels(phase=phase).inc(delta)
-            self._last_totals[key] = cell["s"]
+            self._inc_to(self.engine_phase_seconds.labels(phase=phase),
+                         f"phase:{phase}", cell["s"])
+        for span, total in m.get("starved_by_phase", {}).items():
+            self._inc_to(self.starved_seconds.labels(span=span),
+                         f"starved:{span}", total)
+        for name in ("useful", "overrun", "prompt_wait", "empty"):
+            if name in m.get("slot_steps", ()):
+                self._inc_to(self.slot_steps.labels(**{"class": name}),
+                             f"slot_steps:{name}", m["slot_steps"][name])
         for phase, cell in m.get("startup_phases", {}).items():
             self.startup_phase_seconds.labels(phase=phase).set(cell["s"])
         for expert, total in enumerate(m.get("moe_choices", ())):
-            key = f"moe:{expert}"
-            delta = total - self._last_totals.get(key, 0)
-            if delta > 0:
-                self.moe_expert_choices.labels(expert=str(expert)).inc(delta)
-            self._last_totals[key] = total
+            self._inc_to(self.moe_expert_choices.labels(expert=str(expert)),
+                         f"moe:{expert}", total)
         if "decode_tokens_per_sec" in m:
             self.decode_tokens_per_sec.set(m["decode_tokens_per_sec"])
         for key, counter in (("preemptions", self.infer_preemptions),
@@ -432,10 +447,7 @@ class PrometheusExporter:
                              ("state_carry_tokens",
                               self.infer_carry_tokens)):
             if key in m:
-                delta = m[key] - self._last_totals.get(key, 0)
-                if delta > 0:
-                    counter.inc(delta)
-                self._last_totals[key] = m[key]
+                self._inc_to(counter, key, m[key])
         if "swapped_host_bytes" in m:
             self.infer_swapped_bytes.set(m["swapped_host_bytes"])
 

@@ -13,7 +13,9 @@
 The recorder also keeps ``in_flight`` (programs dispatched and not yet
 fetched) and ``starved_s``: seconds during which the engine had work
 (``set_busy(True)``) and nothing was in flight — the program's own estimate of
-the time the device sat idle under load.
+the time the device sat idle under load — and ``starved_by_phase``, the same
+seconds by the innermost span the owner thread had open (``NO_SPAN`` between
+spans): what the host was doing while the device had nothing.
 
 Always on; no lock (a sequence number guards ``snapshot()``), no option. It
 belongs to ONE thread: the one that made it, until the loop that steps the
@@ -46,6 +48,8 @@ from .. import _IMPORT_T0
 # upper bounds (ms) of the queue-wait histogram; the last bucket is +inf
 QUEUE_WAIT_LE_MS = (1, 2, 5, 10, 20, 50, 100, 150, 200, 300, 400, 500, 750,
                     1000, 1500, 2500, 5000)
+# ``starved_by_phase``'s key for starved seconds under no span
+NO_SPAN = "(no span)"
 
 
 class _Span(TraceAnnotation):
@@ -63,14 +67,19 @@ class _Span(TraceAnnotation):
         rec._seq += 1
         self._children = 0.0
         self._t0 = rec._clock()
+        if rec._starved_since is not None:
+            rec._flush_starved(self._t0)    # up to here: the span around
         rec._stack.append(self)
         rec._seq += 1
         return self
 
     def __exit__(self, *exc):
         rec = self._rec
-        took = rec._clock() - self._t0
+        now = rec._clock()
+        took = now - self._t0
         rec._seq += 1
+        if rec._starved_since is not None:
+            rec._flush_starved(now)         # up to here: this span
         rec._stack.pop()
         if rec._stack:
             rec._stack[-1]._children += took
@@ -91,7 +100,9 @@ class SpanRecorder:
         self.phases: dict[str, list] = {}      # name -> [self seconds, calls]
         self.in_flight = 0
         self.starved_s = 0.0
+        self.starved_by_phase: dict[str, float] = {}   # span name -> seconds
         self._busy = False
+        # where the starved stretch that is open began, or was last added up
         self._starved_since: float | None = None
         self._stack: list[_Span] = []
         self._seq = 0
@@ -117,11 +128,21 @@ class SpanRecorder:
 
     # -- device starvation ---------------------------------------------------
 
+    def _flush_starved(self, now: float) -> None:
+        """Add the open starved stretch up to ``now`` to ``starved_s`` and
+        to the innermost open span (the caller has made ``_seq`` odd)."""
+        took = now - self._starved_since
+        name = self._stack[-1]._name if self._stack else NO_SPAN
+        self.starved_s += took
+        self.starved_by_phase[name] = self.starved_by_phase.get(
+            name, 0.0) + took
+        self._starved_since = now
+
     def _mark(self, in_flight: int, busy: bool) -> None:
         now = self._clock()
         self._seq += 1
         if self._starved_since is not None:
-            self.starved_s += now - self._starved_since
+            self._flush_starved(now)
         self.in_flight, self._busy = in_flight, busy
         self._starved_since = now if busy and in_flight == 0 else None
         self._seq += 1
@@ -146,12 +167,14 @@ class SpanRecorder:
     # -- reading -------------------------------------------------------------
 
     def snapshot(self) -> dict:
-        """{"clock_s", "phases": {name: {"s", "n"}}, "starved_s"}, all
+        """{"clock_s", "phases": {name: {"s", "n"}}, "starved_s",
+        "starved_by_phase": {name: seconds}}, all
         cumulative; ``clock_s`` is the clock (``time.monotonic()``) now, so that the
         difference of two snapshots carries its own denominator. Spans and
         a starved stretch still open count up to now (a decode wait lasts a
         quarter of a second: left out, two snapshots five seconds apart
-        would not add up); ``n`` counts closed spans."""
+        would not add up); ``n`` counts closed spans. ``starved_by_phase``
+        adds up to ``starved_s``."""
         for _ in range(16):
             seq = self._seq
             if seq & 1:             # the owner is mid-update: let it finish
@@ -170,14 +193,18 @@ class SpanRecorder:
         phases = {k: {"s": v[0], "n": v[1]}
                   for k, v in list(self.phases.items())}
         inner_t0 = now
-        for span in reversed(list(self._stack)):
+        stack, since = list(self._stack), self._starved_since
+        for span in reversed(stack):
             cell = phases.setdefault(span._name, {"s": 0.0, "n": 0})
             cell["s"] += inner_t0 - span._t0 - span._children
             inner_t0 = span._t0
-        since = self._starved_since
-        return {"clock_s": now, "phases": phases,
-                "starved_s": self.starved_s
-                + (now - since if since is not None else 0.0)}
+        starved, by_phase = self.starved_s, dict(self.starved_by_phase)
+        if since is not None:
+            name = stack[-1]._name if stack else NO_SPAN
+            starved += now - since
+            by_phase[name] = by_phase.get(name, 0.0) + now - since
+        return {"clock_s": now, "phases": phases, "starved_s": starved,
+                "starved_by_phase": by_phase}
 
 
 class QueueWaitHistogram:

@@ -509,6 +509,23 @@ class InferenceEngine:
         # decode always runs over all slots (one compiled program); padded
         # slots are wasted work — tracked so batch-size tuning isn't blind
         self.total_padded_slot_steps = 0
+        # the slot-step ledger: every slot of every decode step (forward,
+        # verify window) in ONE class, decided when its group is applied
+        # from the group's submit-time snapshot (``_count_slot_steps``):
+        # its result was credited to a request; the slot was live (or armed
+        # in flight) and the result was nobody's (past a stop, or a chained
+        # group behind a request that had ended); a seated request was not
+        # live yet (its prompt riding, or waiting for a program); nobody
+        # sat there. They add up to ``total_decode_steps`` x slots.
+        self.total_useful_slot_steps = 0
+        self.total_overrun_slot_steps = 0
+        self.total_prompt_wait_slot_steps = 0
+        self.total_empty_slot_steps = 0
+        # tokens no decode slot-step made (a prefill program's or a final
+        # riding piece's), and every token handed to a request, those among
+        # them: what the clients count
+        self.total_first_tokens = 0
+        self.total_tokens_credited = 0
         # how far the paged-attention kernel's page walk engages: pages
         # the slots' lengths cover at each decode dispatch's first step,
         # against the block table's whole width (slots x pages a slot)
@@ -1723,6 +1740,8 @@ class InferenceEngine:
         ctx = req.context_tokens   # BEFORE recording the new token
         n = len(ctx)
         req.record_token(token)
+        self.total_first_tokens += 1
+        self.total_tokens_credited += 1
         if self.on_token is not None:
             with self.spans.phase("llmctl.engine.deliver"):
                 self.on_token(req, [token])
@@ -1945,10 +1964,17 @@ class InferenceEngine:
             "firsts": out.firsts, "denoise_counts": out.counts,
             "next_tokens": out.tokens,
             "next_positions": out.positions,
-            "req_ids": [r.request_id if r is not None else None
-                        for r in self.scheduler.slots],
-            "active": self.active.copy(),
+            **self._who_sits_where(),
         }
+
+    def _who_sits_where(self) -> dict:
+        """The per-slot snapshot a dispatch is submitted under: the seated
+        requests' ids and which slots are live for decode. What its results
+        are applied and its slot-steps classed by, whatever has changed by
+        the time they reach the host."""
+        return {"req_ids": [r.request_id if r is not None else None
+                            for r in self.scheduler.slots],
+                "active": self.active.copy()}
 
     @engine_thread_only
     def _submit_group(self, n_units: int, chain_from=None) -> dict:
@@ -2017,6 +2043,10 @@ class InferenceEngine:
             "next_positions": units[-1]["next_positions"],
             "req_ids": units[0]["req_ids"],
             "active": units[0]["active"],
+            # slot -> the group's step that carries its prompt's last piece
+            # (the device arms it there: ``_arm_in_flight``)
+            "armed_at": {st["req"].slot: step for u in units
+                         for st, _live, step in u["laid"] if step >= 0},
         }
 
     @engine_thread_only
@@ -2129,11 +2159,13 @@ class InferenceEngine:
             w8_kernel_ok=self._w8_kernel_ok)
 
     @engine_thread_only
-    def _spec_device(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    def _spec_device(self) -> dict:
         """One fused speculative dispatch: propose drafts on host (prompt-
         lookup over each slot's prompt+generated context), then verify +
-        K-1 decode steps on device. Returns (emitted [B, T], n_emit [B],
-        decode_seq [K-1, B])."""
+        K-1 decode steps on device. Returns the dispatch's record: emitted
+        [B, T], n_emit [B], decode_seq [K-1, B], and who sat where when it
+        was submitted (``req_ids``, ``active``, ``armed_at``, as a group's
+        record)."""
         with self.spans.phase("llmctl.engine.decode.submit", units=1,
                               active=int(self.active.sum())):
             T = max(self.serve_cfg.speculative_tokens, 2)
@@ -2176,6 +2208,7 @@ class InferenceEngine:
                         ctx, w - 1, self.serve_cfg.speculative_ngram)
                 if draft is not None:
                     tokens[slot, 1:w] = draft
+            snapshot = {**self._who_sits_where(), "armed_at": {}}
             emitted, n_emit, decode_seq = self._keep_pools(self._spec_jit(
                 self.params, self.kv.k_pages, self.kv.v_pages,
                 jnp.asarray(tokens), jnp.asarray(self.positions),
@@ -2193,15 +2226,18 @@ class InferenceEngine:
         self.total_decode_steps += 1 + decode_seq.shape[0]
         self.total_padded_slot_steps += (1 + decode_seq.shape[0]) * int(
             B - self.active.sum())
-        return emitted, n_emit, decode_seq
+        return {"emitted": emitted, "n_emit": n_emit,
+                "decode_seq": decode_seq, **snapshot}
 
     @engine_thread_only
-    def _apply_speculative(self, emitted: np.ndarray, n_emit: np.ndarray,
-                           decode_seq: np.ndarray) -> None:
+    def _apply_speculative(self, record: dict) -> None:
         """Host bookkeeping for one fused dispatch (under self.lock):
         n_emit verified tokens, then the trailing decode-scan rows.
         Positions advance in lockstep with what is recorded so slot length
         always matches the KV state."""
+        emitted, n_emit, decode_seq = (record["emitted"], record["n_emit"],
+                                       record["decode_seq"])
+        credited = np.zeros(len(self.active), np.int64)
         for slot, req in enumerate(self.scheduler.slots):
             if req is None or not self.active[slot]:
                 continue
@@ -2234,6 +2270,11 @@ class InferenceEngine:
                     # state that migrates with the sequence
                     st.observe(acc, w - 1, max_window=T)
             self._deliver(slot, req, accepted)
+            # the steps whose tokens were emitted: the verify window (one
+            # step, however many tokens) and the decode steps behind it
+            credited[slot] = (bool(accepted)
+                              + max(len(accepted) - int(n_emit[slot]), 0))
+        self._count_slot_steps(record, 1 + decode_seq.shape[0], credited)
 
     @engine_thread_only
     def _apply_decode(self, group: dict) -> None:
@@ -2252,20 +2293,22 @@ class InferenceEngine:
         life into freed pages, which is harmless (the device executes any
         subsequent prefill AFTER this program, so reallocated pages are
         overwritten in order) but must not be credited to anyone."""
+        credited = group["credited"]
         for slot, req in enumerate(self.scheduler.slots):
             if (req is None or not self.active[slot]
                     or req.request_id != group["req_ids"][slot]
                     or not group["active"][slot]):
                 continue
             if self.cfg.is_diffusion:
-                self._accept_blocks(slot, req, group)
+                credited[slot] = self._accept_blocks(slot, req, group)
             else:
-                self._accept(slot, req, group["sampled"][:, slot])
+                credited[slot] = self._accept(slot, req,
+                                              group["sampled"][:, slot])
 
     @engine_thread_only
-    def _accept(self, slot: int, req: Request, tokens: np.ndarray) -> None:
+    def _accept(self, slot: int, req: Request, tokens: np.ndarray) -> int:
         """Credit a slot's request with the tokens of consecutive decode
-        steps, up to the one that stops it."""
+        steps, up to the one that stops it. Returns the steps credited."""
         accepted = []
         for tok in tokens.tolist():
             self.positions[slot] += 1
@@ -2276,10 +2319,12 @@ class InferenceEngine:
                     or req.should_stop(self.eos_token_id) is not None):
                 break
         self._deliver(slot, req, accepted)
+        return len(accepted)
 
     def _deliver(self, slot: int, req: Request, accepted: list) -> None:
         """Append the tokens a request was just credited with to its slot's
         context and stream them."""
+        self.total_tokens_credited += len(accepted)
         end = self._ctx_len[slot] + len(accepted)
         self._ctx[slot, self._ctx_len[slot]:end] = accepted
         self._ctx_len[slot] = end
@@ -2288,7 +2333,7 @@ class InferenceEngine:
                 self.on_token(req, accepted)
 
     @engine_thread_only
-    def _accept_blocks(self, slot: int, req: Request, group: dict) -> None:
+    def _accept_blocks(self, slot: int, req: Request, group: dict) -> int:
         """Credit a slot's request with the BLOCKS its forwards of a
         fetched group finished (several tokens a credit, as the
         speculative path's ``n_emit``; a step's row holds the block as the
@@ -2296,11 +2341,15 @@ class InferenceEngine:
         token that stops it, and take over where the group left the slot's
         window. A block's rows that were the prompt's (fixed at -1) are not
         new tokens; a stop token ends the reply where it stands, and the
-        rest of its block is dropped."""
+        rest of its block is dropped. Returns the forwards that advanced
+        the request's block: those up to the one that stopped it."""
         Bd = self.cfg.diffusion.block_length
-        accepted = []
+        accepted, forwards = [], 0
         for row in group["sampled"][:, slot]:
-            if not row[2 * Bd] or req.should_stop(self.eos_token_id):
+            if req.should_stop(self.eos_token_id):
+                continue
+            forwards += 1
+            if not row[2 * Bd]:
                 continue
             self.positions[slot] += Bd
             for tok, at in zip(row[:Bd].tolist(), row[Bd:2 * Bd].tolist()):
@@ -2317,6 +2366,7 @@ class InferenceEngine:
         self._win_step[slot] = step[slot]
         self._win_pending[slot] = pending[slot]
         self._deliver(slot, req, accepted)
+        return forwards
 
     @engine_thread_only
     def _apply_rides(self, group: dict) -> list:
@@ -2357,8 +2407,9 @@ class InferenceEngine:
                                         st["done"] // self.kv.page_size)])
                 self._first_token(req, int(group["firsts"][final_step]))
                 if req.should_stop(self.eos_token_id) is None:
-                    self._accept(req.slot, req,
-                                 group["sampled"][final_step + 1:, req.slot])
+                    group["credited"][req.slot] = self._accept(
+                        req.slot, req,
+                        group["sampled"][final_step + 1:, req.slot])
                 armed.append(req)
         return armed
 
@@ -2369,10 +2420,36 @@ class InferenceEngine:
         and with no lock held the prefill-complete hook of the prompts it
         armed."""
         with self.spans.phase("llmctl.engine.apply"), self.lock:
+            # decode steps (forwards) whose result a request was credited
+            # with, by slot
+            group["credited"] = np.zeros(len(self.active), np.int64)
             self._apply_decode(group)
             armed = self._apply_rides(group)
+            self._count_slot_steps(group, group["sampled"].shape[0],
+                                   group["credited"])
             self.scheduler.step_finished(self.eos_token_id)
         self._prefill_completed(armed)
+
+    def _count_slot_steps(self, record: dict, steps: int,
+                          credited: np.ndarray) -> None:
+        """Put every slot-step of an APPLIED group (or speculative
+        dispatch) of ``steps`` steps in its one class, by who sat where
+        when it was submitted (``record["req_ids"]``, ``["active"]``) and
+        the steps each slot's request was ``credited`` with. A seated slot
+        that was not live waited for its prompt through the whole group,
+        or up to and with the step that carried its last piece
+        (``record["armed_at"]``: that step's token is the FIRST token); what a live
+        or armed slot's steps made and nobody was credited with is
+        overrun."""
+        seated = np.array([rid is not None for rid in record["req_ids"]])
+        wait = np.where(record["active"], 0, steps)
+        for slot, step in record["armed_at"].items():
+            wait[slot] = step + 1
+        self.total_empty_slot_steps += steps * int((~seated).sum())
+        self.total_prompt_wait_slot_steps += int(wait[seated].sum())
+        self.total_useful_slot_steps += int(credited.sum())
+        self.total_overrun_slot_steps += int(
+            (steps - wait - credited)[seated].sum())
 
     def _prefill_completed(self, reqs: list) -> None:
         """The prefill-complete boundary hook (disaggregated serving):
@@ -2727,9 +2804,9 @@ class InferenceEngine:
                 # the spec dispatch builds its drafts and window from host
                 # state, so it must catch up first
                 self._drain_pending()
-                emitted, n_emit, decode_seq = self._spec_device()
+                record = self._spec_device()
                 with spans.phase("llmctl.engine.apply"), self.lock:
-                    self._apply_speculative(emitted, n_emit, decode_seq)
+                    self._apply_speculative(record)
                     self.scheduler.step_finished(self.eos_token_id)
             elif (self.serve_cfg.pipelined_decode and not static
                   and not use_short and not rearmed and not pending
@@ -2901,6 +2978,25 @@ class InferenceEngine:
             / (iters * K) * 1e3
         return out
 
+    def reset_counters(self) -> None:
+        """Zero the counters ``stats()`` derives ratios from, after a
+        warm-up whose steps are not the measurement's: together, so that
+        what adds up (the slot-step ledger to ``decode_steps`` x slots)
+        still does."""
+        self.total_prefill_tokens = 0
+        self.total_prefill_padded_tokens = 0
+        self.total_prefill_ride_tokens = 0
+        self.total_prefill_ride_steps = 0
+        self.total_decode_steps = 0
+        self.total_padded_slot_steps = 0
+        self.total_useful_slot_steps = 0
+        self.total_overrun_slot_steps = 0
+        self.total_prompt_wait_slot_steps = 0
+        self.total_empty_slot_steps = 0
+        self.total_first_tokens = 0
+        self.total_tokens_credited = 0
+        self.total_short_dispatches = 0
+
     def run_until_idle(self, max_steps: int = 100_000) -> None:
         self.spans.bind_thread()
         for _ in range(max_steps):
@@ -2958,6 +3054,17 @@ class InferenceEngine:
             "decode_slot_utilization": round(
                 1.0 - self.total_padded_slot_steps
                 / (steps * self.serve_cfg.max_batch_size), 4),
+            # every slot of every decode step in one class (the four add
+            # up to decode_steps x slots), the first tokens no decode step
+            # made and every token handed to a request
+            "slot_steps": {
+                "useful": self.total_useful_slot_steps,
+                "overrun": self.total_overrun_slot_steps,
+                "prompt_wait": self.total_prompt_wait_slot_steps,
+                "empty": self.total_empty_slot_steps,
+                "first_tokens": self.total_first_tokens,
+                "tokens_credited": self.total_tokens_credited,
+            },
             "spec_dispatches": self.total_spec_dispatches,
             "spec_drafts": self.total_spec_drafts,
             "spec_accepted": self.total_spec_accepted,
@@ -3017,7 +3124,8 @@ class InferenceEngine:
                 "decode_layer_steps": self.moe_decode_layer_steps,
             }} if self.cfg.is_moe else {}),
             # cumulative, with their own clock: "clock_s", "phases"
-            # ({span: {"s": self seconds, "n": calls}}), "starved_s"
+            # ({span: {"s": self seconds, "n": calls}}), "starved_s" and
+            # the same by span, "starved_by_phase"
             **self.spans.snapshot(),
             # the PROCESS's start-up, on the same clock: llmctl.startup.*
             # phases and one "programs" entry a program's first call

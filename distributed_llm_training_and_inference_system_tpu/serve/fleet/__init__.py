@@ -364,13 +364,7 @@ class ServeFleet:
                               SamplingParams(temperature=0.0,
                                              max_tokens=2))
             n <<= 1
-        r.engine.total_prefill_tokens = 0
-        r.engine.total_prefill_padded_tokens = 0
-        r.engine.total_prefill_ride_tokens = 0
-        r.engine.total_prefill_ride_steps = 0
-        r.engine.total_decode_steps = 0
-        r.engine.total_padded_slot_steps = 0
-        r.engine.total_short_dispatches = 0
+        r.engine.reset_counters()
         return r
 
     def _warm_spares(self, ids: list) -> None:

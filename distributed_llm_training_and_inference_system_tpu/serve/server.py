@@ -405,6 +405,8 @@ class InferenceServer:
             # histogram and the engine's spans, not this request's own
             "queue_wait_ms": st["queue_wait_ms"],
             "phases": st["phases"],
+            "starved_by_phase": st["starved_by_phase"],
+            "slot_steps": st["slot_steps"],
             "startup_phases": st["startup"]["phases"],
             "moe_choices": st.get("moe", {}).get("choices", ()),
             "preemptions": st["preemptions"],

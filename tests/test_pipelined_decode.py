@@ -158,6 +158,20 @@ class TestPipelinedWithSpeculation:
             assert eng.scheduler.add_request(greedy)
             eng.run_until_idle()
             outs.append(_tokens(sampled + [greedy]))
+            # the slot-step ledger across plain, chained and speculative
+            # dispatches: every slot of every step in one class, and every
+            # token of the replies credited (a verify window's step counts
+            # once, however many tokens it emitted)
+            eng._drain_pending()
+            stats = eng.stats()
+            ledger = stats["slot_steps"]
+            assert stats["spec_dispatches"] > 0
+            assert sum(ledger[k] for k in ("useful", "overrun", "prompt_wait",
+                                           "empty")) == (
+                stats["decode_steps"] * eng.serve_cfg.max_batch_size)
+            assert ledger["tokens_credited"] == sum(map(len, outs[-1]))
+            assert ledger["first_tokens"] == 3
+            assert 0 < ledger["useful"] <= ledger["tokens_credited"] - 3
         assert outs[0] == outs[1]
 
 

@@ -343,6 +343,61 @@ def test_a_riding_prompt_does_not_break_the_pipelined_chain(engines):
     _idle(eng)
 
 
+def test_a_riding_slots_steps_wait_up_to_its_last_piece_and_then_decode(
+        engines):
+    """The slot-step ledger over the same chain: two residents decode, the
+    prompt's six pieces ride the four steps of one dispatch and steps 0
+    and 1 of the next, the fourth slot is empty. A seated slot waits
+    (``prompt_wait``) through the first dispatch and up to and with the
+    step that carries its last piece, whose token is the FIRST token; the
+    two steps after it are decode steps of a slot that was NOT live when
+    the dispatch was submitted (the accepted ``padded_slot_steps`` calls
+    all four of that slot's steps padded)."""
+    eng, _ = engines
+    eng._drain_pending()
+
+    def gained(since):
+        now = eng.stats()
+        got = {k: v - since["slot_steps"][k]
+               for k, v in now["slot_steps"].items()}
+        steps = now["decode_steps"] - since["decode_steps"]
+        assert sum(got[k] for k in ("useful", "overrun", "prompt_wait",
+                                    "empty")) == SLOTS * steps
+        return dict(got, decode_steps=steps,
+                    padded=now["padded_slot_steps"]
+                    - since["padded_slot_steps"]), now
+
+    start = eng.stats()
+    # (two cold prefills and the first dispatch, then the rider admitted and
+    # its first four pieces chained behind it: the first is applied here)
+    req = _start_riding(eng, _tokens(STEPS * C + 20), "ledger")
+    got, at = gained(start)
+    assert got == {"useful": 2 * STEPS, "overrun": 0, "prompt_wait": 0,
+                   "empty": 2 * STEPS, "first_tokens": 2,
+                   "tokens_credited": 2 + 2 * STEPS, "decode_steps": STEPS,
+                   "padded": 2 * STEPS}
+    eng.step()          # the rest laid chained; the 4 pieces' dispatch applied
+    got, at = gained(at)
+    assert got == {"useful": 2 * STEPS, "overrun": 0, "prompt_wait": STEPS,
+                   "empty": STEPS, "first_tokens": 0,
+                   "tokens_credited": 2 * STEPS, "decode_steps": STEPS,
+                   "padded": 2 * STEPS}
+    eng.step()          # the dispatch that armed the slot at its step 1
+    got, at = gained(at)
+    assert len(req.generated_tokens) == 3
+    assert got == {"useful": 2 * STEPS + 2, "overrun": 0, "prompt_wait": 2,
+                   "empty": STEPS, "first_tokens": 1,
+                   "tokens_credited": 2 * STEPS + 3, "decode_steps": STEPS,
+                   "padded": 2 * STEPS}
+    eng.run_until_idle()
+    eng._drain_pending()
+    got, _ = gained(start)
+    assert got["first_tokens"] == 3 and got["prompt_wait"] == STEPS + 2
+    assert got["tokens_credited"] == 60 + 60 + 8
+    assert got["useful"] == got["tokens_credited"] - 3
+    _idle(eng)
+
+
 @pytest.mark.parametrize("sampling", list(SAMPLING))
 @pytest.mark.parametrize("every", [1, 2])
 def test_the_host_catches_up_once_in_a_while_and_nothing_else_changes(

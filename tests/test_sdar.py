@@ -449,6 +449,13 @@ def test_counters_under_the_schedule():
     assert d["refused"] == {"riding": 0}
     stats = engine.stats()
     assert stats["decode_steps"] == d["forwards"]
+    # the slot-step ledger: every forward advanced a live request's block
+    # (the replies end with their last block's last forward), no first
+    # token, 20 tokens a reply
+    assert stats["slot_steps"] == {
+        "useful": d["slot_forwards"], "overrun": 0, "prompt_wait": 0,
+        "empty": 0, "first_tokens": 0, "tokens_credited": 4 * 20}
+    assert d["slot_forwards"] == 4 * stats["decode_steps"]
     # nothing waits for a prefill program (there is no first token): its
     # routing counts come down with the next dispatch's tokens
     assert "llmctl.engine.prefill.wait" not in stats["phases"]
@@ -482,6 +489,14 @@ def test_slots_at_different_phases_share_a_dispatch():
     assert 0 < d["blocks_committed"] < d["slot_forwards"]
     assert d["commit_slot_forwards"] == 0
     assert d["fused_commits"] == d["blocks_committed"]
+    # the slot-step ledger: a reply that ends inside a dispatch leaves the
+    # forwards behind its last block to nobody
+    stats = engine.stats()
+    ledger = stats["slot_steps"]
+    assert (ledger["useful"] + ledger["overrun"] + ledger["prompt_wait"]
+            + ledger["empty"]) == 8 * stats["decode_steps"]
+    assert ledger["tokens_credited"] == 8 * 14 and ledger["overrun"] > 0
+    assert ledger["first_tokens"] == ledger["prompt_wait"] == 0
 
 
 @pytest.mark.parametrize("pipelined", [True, False])

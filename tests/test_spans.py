@@ -18,11 +18,11 @@ from distributed_llm_training_and_inference_system_tpu.config.presets import (
 from distributed_llm_training_and_inference_system_tpu.config.schema import (
     ServeConfig)
 from distributed_llm_training_and_inference_system_tpu.metrics.spans import (
-    QUEUE_WAIT_LE_MS, QueueWaitHistogram, SpanRecorder)
+    NO_SPAN, QUEUE_WAIT_LE_MS, QueueWaitHistogram, SpanRecorder)
 from distributed_llm_training_and_inference_system_tpu.serve.engine import (
     InferenceEngine)
 from distributed_llm_training_and_inference_system_tpu.serve.scheduler import (
-    SamplingParams)
+    Request, SamplingParams)
 
 TICK = 0.02
 
@@ -127,6 +127,64 @@ def test_starved_stops_at_the_dispatch_and_resumes_at_the_fetch():
     assert rec.in_flight == 0
 
 
+def _starved_between_spans(rec, now):
+    rec.set_busy(True)
+    now[0] += TICK                        # starved, no span open
+    with rec.phase("outer"):
+        now[0] += 2 * TICK
+    rec.dispatched()                      # ... until here
+    with rec.phase("outer"):
+        now[0] += 5 * TICK                # a program runs: not starved
+    return {NO_SPAN: TICK, "outer": 2 * TICK}
+
+
+def _starved_goes_to_the_innermost_span(rec, now):
+    rec.set_busy(True)
+    with rec.phase("outer"):
+        now[0] += TICK
+        with rec.phase("inner"):
+            now[0] += 3 * TICK
+            rec.dispatched()              # inside the span: it is cut here
+            now[0] += 7 * TICK
+            rec.fetched()
+            now[0] += TICK
+        now[0] += 2 * TICK
+    return {"outer": 3 * TICK, "inner": 4 * TICK}
+
+
+def _starved_stretch_still_open(rec, now):
+    with rec.phase("outer"):
+        rec.set_busy(True)
+        now[0] += TICK
+        with rec.phase("inner"):
+            now[0] += TICK
+            mid = rec.snapshot()          # both spans and the stretch open
+            assert mid["starved_by_phase"] == pytest.approx(
+                {"outer": TICK, "inner": TICK})
+            assert mid["starved_s"] == pytest.approx(2 * TICK)
+            now[0] += TICK
+    now[0] += 4 * TICK
+    rec.set_busy(False)
+    now[0] += 9 * TICK                    # idle: nobody is kept waiting
+    return {"outer": TICK, "inner": 2 * TICK, NO_SPAN: 4 * TICK}
+
+
+@pytest.mark.parametrize("case", [_starved_between_spans,
+                                  _starved_goes_to_the_innermost_span,
+                                  _starved_stretch_still_open])
+def test_starved_seconds_go_to_the_innermost_open_span(case):
+    now = [50.0]
+    rec = SpanRecorder(clock=lambda: now[0])
+    want = case(rec, now)
+    snap = rec.snapshot()
+    # (a stretch that opens at the instant a span does leaves 0.0 behind)
+    assert {k: v for k, v in snap["starved_by_phase"].items()
+            if v} == pytest.approx(want)
+    assert sum(snap["starved_by_phase"].values()) == pytest.approx(
+        snap["starved_s"])
+    json.dumps(snap)
+
+
 @pytest.mark.parametrize("ms,bucket", [(0.5, 0), (1.0, 0), (1.5, 1),
                                        (499.0, 11), (5000.0, 16),
                                        (60000.0, 17)])
@@ -169,11 +227,14 @@ def _flat(stats):
 
 
 def test_engine_phases_add_up_to_its_clock(engine, monkeypatch):
-    # a token callback that takes a while, as a stream's does: the steps of
-    # this tiny model last a millisecond, and the few lines of step()
-    # between two spans would otherwise weigh 3 % of it (0.15 % on the chip)
-    monkeypatch.setattr(engine, "on_token",
-                        lambda req, tokens: time.sleep(0.002))
+    # the recorder on a clock the token callback alone steps, as a stream's
+    # write takes a while: every second of the engine's clock then lies in a
+    # ``deliver`` span (inside ``apply``), whatever the machine is busy with,
+    # and the spans' self times have to add up to it exactly
+    now = [50.0]
+    monkeypatch.setattr(engine.spans, "_clock", lambda: now[0])
+    monkeypatch.setattr(engine, "on_token", lambda req, tokens: now.__setitem__(
+        0, now[0] + 0.002))
     before = engine.stats()
     engine.generate(PROMPTS, SamplingParams(temperature=0.0, max_tokens=20))
     mid = engine.stats()
@@ -186,7 +247,9 @@ def test_engine_phases_add_up_to_its_clock(engine, monkeypatch):
         assert all(fb[k] >= v for k, v in fa.items()), (fa, fb)
         clock = b["clock_s"] - a["clock_s"]
         phases = sum(fb[k] - fa.get(k, 0.0) for k in fb if k.endswith(".s"))
-        assert abs(phases - clock) <= 0.05 * clock, (phases, clock)
+        assert clock > 0 and phases == pytest.approx(clock), (phases, clock)
+        assert sum(b["starved_by_phase"].values()) == pytest.approx(
+            b["starved_s"])
     assert after["queue_wait_ms"]["n"] == after["admitted"] == 7
     for name in ("admit", "prefill.host", "prefill.wait", "capacity",
                  "decode.submit", "decode.wait", "apply", "deliver"):
@@ -199,6 +262,104 @@ def test_engine_phases_add_up_to_its_clock(engine, monkeypatch):
     # most the pipelined dispatch that outlived its requests, unfetched
     assert engine.spans.in_flight == (engine._pending is not None)
     assert engine.stats()["starved_s"] == after["starved_s"]
+
+
+# -- the slot-step ledger --------------------------------------------------------
+
+def _requests(tag, max_tokens):
+    return [Request(f"{tag}{i}", list(PROMPTS[i]),
+                    SamplingParams(temperature=0.0, max_tokens=n))
+            for i, n in enumerate(max_tokens)]
+
+
+def _stop_mid_dispatch(engine):
+    """Four replies of 5 tokens: the first from the prefill, 4 of the one
+    dispatch's 8 steps, and the other 4 steps are nobody's."""
+    for r in _requests("stop", [5] * 4):
+        assert engine.scheduler.add_request(r)
+    engine.run_until_idle()
+    return {"useful": 16, "overrun": 16, "prompt_wait": 0, "empty": 0,
+            "first_tokens": 4, "decode_steps": 8}
+
+
+def _cancel_mid_dispatch(engine):
+    """Two of four slots decode (a chain forms); one is cancelled while a
+    dispatch is in flight and the one chained behind it is submitted: what
+    they computed for its slot is credited to nobody."""
+    a, b = _requests("cancel", [40, 40])
+    for r in (a, b):
+        assert engine.scheduler.add_request(r)
+    for _ in range(3):
+        engine.step()
+    assert engine._pending is not None and len(a.generated_tokens) == 17
+    with engine.lock:
+        assert engine.scheduler.cancel(a.request_id)
+    engine.run_until_idle()
+    assert len(a.generated_tokens) == 17 and len(b.generated_tokens) == 40
+    # b: 39 steps of 5 dispatches (the slot's last step is overrun); a: 16
+    # steps of two, then the dispatch in flight at the cancel, whose slot it
+    # still held when that was submitted; from the next on nobody sits there
+    return {"useful": 39 + 16, "overrun": 1 + 8, "prompt_wait": 0,
+            "empty": 5 * 8 * 2 + 2 * 8, "first_tokens": 2,
+            "decode_steps": 5 * 8}
+
+
+def _reseated_behind_a_finished_request(engine):
+    """Five requests over four slots, six dispatches of a chain: the first
+    request ends in the second (1 + 8 + 3 of its 12 tokens: 5 steps
+    overrun) and the third, chained behind it, credits nobody with its
+    slot's 8 steps; the fifth is seated there and its 11 tokens ride the
+    fourth's step 0 (1 step of waiting, 7 useful in a slot that was not
+    live at the submit), then 2 of the fifth's steps end it; the other
+    three get 8 + 8 + 8 + 8 + 7; the sixth dispatch was chained behind the
+    fifth before the host knew that every reply ends there."""
+    reqs = _requests("seat", [12, 40, 40, 40, 10])
+    for r in reqs:
+        assert engine.scheduler.add_request(r)
+    engine.run_until_idle()
+    assert [len(r.generated_tokens) for r in reqs] == [12, 40, 40, 40, 10]
+    return {"useful": 32 + 27 + 24 + 31 + 23,
+            "overrun": 5 + 8 + 6 + 3 + 32, "prompt_wait": 1, "empty": 0,
+            "first_tokens": 5, "decode_steps": 48, "armed_in_flight": 7}
+
+
+def _an_idle_slot(engine):
+    [r] = _requests("idle", [9])
+    assert engine.scheduler.add_request(r)
+    engine.run_until_idle()
+    return {"useful": 8, "overrun": 0, "prompt_wait": 0, "empty": 24,
+            "first_tokens": 1, "decode_steps": 8}
+
+
+@pytest.mark.parametrize("case", [
+    _stop_mid_dispatch, _cancel_mid_dispatch,
+    _reseated_behind_a_finished_request, _an_idle_slot])
+def test_every_slot_step_has_one_class_and_the_tokens_close(engine,
+                                                            monkeypatch, case):
+    seen = []
+    monkeypatch.setattr(engine, "on_token",
+                        lambda req, tokens: seen.append(len(tokens)))
+    engine._drain_pending()     # a dispatch that outlived an earlier case
+    before = engine.stats()
+    want = case(engine)
+    engine._drain_pending()
+    after = engine.stats()
+    got = {k: after["slot_steps"][k] - before["slot_steps"][k]
+           for k in after["slot_steps"]}
+    got["decode_steps"] = after["decode_steps"] - before["decode_steps"]
+    classes = ("useful", "overrun", "prompt_wait", "empty")
+    for stats in (got, {**after["slot_steps"],
+                        "decode_steps": after["decode_steps"]}):
+        assert sum(stats[k] for k in classes) == 4 * stats["decode_steps"]
+    assert got["tokens_credited"] == sum(seen)
+    # one token a step, and a prefill program's first token
+    assert got["useful"] == got["tokens_credited"] - got["first_tokens"]
+    armed_in_flight = want.pop("armed_in_flight", 0)
+    assert {k: got[k] for k in want} == want
+    # what the accepted counter calls padded: the slots not live at the
+    # submit, those the device armed in that very dispatch among them
+    assert (after["padded_slot_steps"] - before["padded_slot_steps"]
+            == got["empty"] + got["prompt_wait"] + armed_in_flight)
 
 
 @pytest.mark.parametrize("prompt_len,pages_of_its_slot", [(9, 1), (70, 2)])
@@ -322,6 +483,11 @@ def test_capture_serve_then_summarize_through_the_cli(tmp_path):
         "capture", "--serve", "--model", "gpt-test", "--seconds", "1",
         "--out", str(tmp_path)])
     assert res.exit_code == 0, res.output[-2000:]
+    # the engine's own account of the stretch, by span, beside the capture
+    said = re.search(r"the engine was starved ([\d.]+) s of [\d.]+: (.*)",
+                     res.output)
+    assert float(said[1]) == pytest.approx(sum(
+        float(x.rsplit(" ", 1)[1]) for x in said[2].split(", ")), abs=1e-3)
     res = runner.invoke(trace_cli.app, ["summarize", str(tmp_path)])
     assert res.exit_code == 0, res.output[-2000:]
     assert "llmctl.engine.decode.wait" in res.output
@@ -346,6 +512,14 @@ def test_counter_wiring_pass_is_clean_with_the_new_names():
         "llmctl_engine_phase_seconds_total"
     assert names.METRICS["llmctl_inference_queue_wait_seconds"].kind == \
         names.HISTOGRAM
+    # the slot-step ledger and starved_s by span, as operators scrape them
+    assert names.METRICS["llmctl_slot_steps_total"].labels == ("class",)
+    assert names.METRICS["llmctl_starved_seconds_total"].labels == ("span",)
+    flows = {f.snapshot_key: f.metric for f in names.COUNTER_FLOW
+             if f.owner == "InferenceEngine"}
+    assert {flows[k] for k in ("useful", "overrun", "prompt_wait",
+                               "empty")} == {"llmctl_slot_steps_total"}
+    assert flows["first_tokens"] is flows["tokens_credited"] is None
 
 
 def test_prometheus_export_of_queue_wait_and_phase_seconds():
@@ -367,14 +541,36 @@ def test_prometheus_export_of_queue_wait_and_phase_seconds():
     waits = QueueWaitHistogram()
     waits.observe(120.0)
     exp.export_inference({"queue_wait_ms": waits.snapshot(),
-                          "phases": {phase: {"s": 1.5, "n": 3}}})
+                          "phases": {phase: {"s": 1.5, "n": 3}},
+                          "starved_by_phase": {phase: 0.25, NO_SPAN: 0.5},
+                          "slot_steps": {"useful": 90, "overrun": 6,
+                                         "prompt_wait": 3, "empty": 1,
+                                         "first_tokens": 4,
+                                         "tokens_credited": 94}})
     waits.observe(7000.0)
     waits.observe(120.0)
     exp.export_inference({"queue_wait_ms": waits.snapshot(),
-                          "phases": {phase: {"s": 2.0, "n": 4}}})
+                          "phases": {phase: {"s": 2.0, "n": 4}},
+                          "starved_by_phase": {phase: 0.75, NO_SPAN: 0.5},
+                          "slot_steps": {"useful": 180, "overrun": 12,
+                                         "prompt_wait": 3, "empty": 5,
+                                         "first_tokens": 8,
+                                         "tokens_credited": 188}})
     assert REGISTRY.get_sample_value(
         "llmctl_engine_phase_seconds_total", {"phase": phase}
     ) == pytest.approx(2.0)
+    # the engine's running totals, by span and by class
+    assert REGISTRY.get_sample_value(
+        "llmctl_starved_seconds_total", {"span": phase}
+    ) == pytest.approx(0.75)
+    assert REGISTRY.get_sample_value(
+        "llmctl_starved_seconds_total", {"span": NO_SPAN}
+    ) == pytest.approx(0.5)
+    assert [REGISTRY.get_sample_value("llmctl_slot_steps_total", {"class": c})
+            for c in ("useful", "overrun", "prompt_wait", "empty")] == [
+                180.0, 12.0, 3.0, 5.0]
+    assert REGISTRY.get_sample_value(
+        "llmctl_slot_steps_total", {"class": "first_tokens"}) is None
     # the scheduler's histogram as it stands: same buckets, counts and sum
     assert sample("count") == 3.0 and sample("sum") == pytest.approx(7.24)
     assert sample("bucket", le="0.1") == 0.0
