@@ -475,6 +475,10 @@ COUNTER_FLOW: tuple[CounterFlow, ...] = (
                 None),
     CounterFlow("InferenceEngine", "total_tokens_credited",
                 "tokens_credited", None),
+    # requests that gave their slot back before the dispatch they end in
+    # was fetched; over ``finished``, the hand-back's hit rate
+    CounterFlow("InferenceEngine", "total_early_handbacks",
+                "early_handbacks", None),
     CounterFlow("InferenceEngine", "total_live_pages", "live_pages", None),
     CounterFlow("InferenceEngine", "total_table_pages", "table_pages",
                 None),

@@ -526,6 +526,10 @@ class InferenceEngine:
         # them: what the clients count
         self.total_first_tokens = 0
         self.total_tokens_credited = 0
+        # requests whose slot was handed on BEFORE the dispatch they end in
+        # was fetched (``_hand_back_early``); against the scheduler's
+        # ``finished``, how often a successor rode the next dispatch
+        self.total_early_handbacks = 0
         # how far the paged-attention kernel's page walk engages: pages
         # the slots' lengths cover at each decode dispatch's first step,
         # against the block table's whole width (slots x pages a slot)
@@ -2047,6 +2051,9 @@ class InferenceEngine:
             # (the device arms it there: ``_arm_in_flight``)
             "armed_at": {st["req"].slot: step for u in units
                          for st, _live, step in u["laid"] if step >= 0},
+            # (request, slot) of those that gave their slot back while this
+            # group was in flight (``_hand_back_early``)
+            "leaving": [],
         }
 
     @engine_thread_only
@@ -2304,30 +2311,54 @@ class InferenceEngine:
             else:
                 credited[slot] = self._accept(slot, req,
                                               group["sampled"][:, slot])
+        # those that gave their slot back while the group was in flight:
+        # the group's own rows for the slot are theirs, whoever sits there
+        # now, and the slot's host arrays are the successor's already; the
+        # group covers every token they could still take, so they end here
+        for req, slot in group["leaving"]:
+            if req.request_id not in self.scheduler.leaving:
+                continue        # cancelled meanwhile: ended there, once
+            accepted = self._record(req, group["sampled"][:, slot])
+            credited[slot] = len(accepted)
+            self._stream(req, accepted)
+            self.scheduler.finish_leaving(
+                req, req.should_stop(self.eos_token_id))
+
+    def _record(self, req: Request, tokens: np.ndarray) -> list:
+        """Record the tokens of consecutive decode steps on a request, up
+        to the one that stops it. Returns those recorded."""
+        accepted = []
+        for tok in tokens.tolist():
+            req.record_token(tok)
+            accepted.append(tok)
+            if (req.cancel_requested
+                    or req.should_stop(self.eos_token_id) is not None):
+                break
+        return accepted
 
     @engine_thread_only
     def _accept(self, slot: int, req: Request, tokens: np.ndarray) -> int:
         """Credit a slot's request with the tokens of consecutive decode
         steps, up to the one that stops it. Returns the steps credited."""
-        accepted = []
-        for tok in tokens.tolist():
-            self.positions[slot] += 1
-            req.record_token(tok)
-            accepted.append(tok)
-            self.last_tokens[slot] = tok
-            if (req.cancel_requested
-                    or req.should_stop(self.eos_token_id) is not None):
-                break
+        accepted = self._record(req, tokens)
+        if accepted:
+            self.positions[slot] += len(accepted)
+            self.last_tokens[slot] = accepted[-1]
         self._deliver(slot, req, accepted)
         return len(accepted)
 
     def _deliver(self, slot: int, req: Request, accepted: list) -> None:
         """Append the tokens a request was just credited with to its slot's
         context and stream them."""
-        self.total_tokens_credited += len(accepted)
         end = self._ctx_len[slot] + len(accepted)
         self._ctx[slot, self._ctx_len[slot]:end] = accepted
         self._ctx_len[slot] = end
+        self._stream(req, accepted)
+
+    def _stream(self, req: Request, accepted: list) -> None:
+        """Count the tokens a request was just credited with and hand them
+        to its client."""
+        self.total_tokens_credited += len(accepted)
         if accepted and self.on_token is not None:
             with self.spans.phase("llmctl.engine.deliver"):
                 self.on_token(req, accepted)
@@ -2654,7 +2685,9 @@ class InferenceEngine:
                     break
                 self._preempt(max(victims, key=lambda j: self._slot_seq[j]))
 
-    def _on_release(self, req: Request) -> None:
+    def _release_seat(self, req: Request) -> None:
+        """Give back what a request holds of the engine: its admission
+        reservation, its pins, and its slot's pages and decode state."""
         # admitted-but-never-prefilled (cancel/failure before _prefill):
         # return the admission reservation so capacity can't leak
         self._reserved_pages -= self._reserved_by.pop(req.request_id, 0)
@@ -2672,11 +2705,52 @@ class InferenceEngine:
             self.positions[slot] = 0
             self.stop_positions[slot] = 0
             self._spec_state[slot] = None
+
+    def _on_release(self, req: Request) -> None:
+        # (nothing left to give back for a request that left its slot early)
+        self._release_seat(req)
         if self.on_finish is not None:
             # an HTTP handler's cancel gets here too: there the span is a
             # bare annotation (SpanRecorder.phase)
             with self.spans.phase("llmctl.engine.deliver"):
                 self.on_finish(req)
+
+    def _hand_back_early(self) -> int:
+        """Give back the slots of requests that CANNOT outlive the dispatch
+        group in flight, before ``admit`` (caller holds self.lock): a slot
+        that was live when the group was submitted gets a token from each
+        of its steps, so a request with no more than that many still to
+        come ends inside it, by length or sooner by a stop token. The
+        request leaves its slot unfinished (``scheduler.hand_back``; its
+        tokens are credited from the group's own rows and it is finished
+        when the group is applied, ``_apply_decode``), and its successor's
+        pieces ride the group submitted in THIS step, not the one after.
+        Safe for the reason a late release is (``_apply_decode``): the group
+        in flight holds its own copy of tables and stops, and whatever the
+        successor runs comes after it in program order, so a page handed on
+        is overwritten only after its last reader.
+
+        Only where a token is a step and somebody waits for a slot: a
+        diffusion slot advances by blocks; a speculating engine's slot
+        carries state the request takes with it; and a slot nobody wants is
+        as well released when its tokens are known. Returns the slots
+        handed back."""
+        group = self._pending
+        if (group is None or not self.scheduler.waiting
+                or self.cfg.is_diffusion or self._spec_jit is not None):
+            return 0
+        steps = len(group["units"]) * self._decode_unit_len
+        handed = 0
+        for slot, req in enumerate(self.scheduler.slots):
+            if (req is None or not group["active"][slot]
+                    or req.request_id != group["req_ids"][slot]
+                    or req.remaining_tokens > steps):
+                continue
+            group["leaving"].append((self.scheduler.hand_back(slot), slot))
+            self._release_seat(req)
+            handed += 1
+        self.total_early_handbacks += handed
+        return handed
 
     @engine_thread_only
     def step(self) -> int:
@@ -2695,6 +2769,9 @@ class InferenceEngine:
             # the next dispatch starts a new chain from exact host state
             self._drain_pending()
         with spans.phase("llmctl.engine.admit"), self.lock:
+            handed = self._hand_back_early()
+            if handed:
+                spans.annotate(early_handbacks=handed)
             if static:
                 # static batches form only when fully drained — there are no
                 # resident streams to protect, so no prefill budget applies
@@ -2776,9 +2853,10 @@ class InferenceEngine:
             # latency-adaptive dispatch decision (needs the lock: it
             # inspects the queue head's admissibility)
             use_short = self._short_dispatch_ok()
-        if any(self.active) or self._riding:
+        if any(self.active) or self._riding or self._pending is not None:
             # (riding prompts alone keep the dispatches coming: the decode
-            # steps are what prefills them)
+            # steps are what prefills them; a group in flight may hold the
+            # last tokens of requests that left their slots early)
             # speculative path only when a greedy stream is resident: for
             # sampled rows a verify dispatch yields 1 token vs K from
             # multi-step decode, so an all-sampled batch stays on decode.
@@ -2995,6 +3073,7 @@ class InferenceEngine:
         self.total_empty_slot_steps = 0
         self.total_first_tokens = 0
         self.total_tokens_credited = 0
+        self.total_early_handbacks = 0
         self.total_short_dispatches = 0
 
     def run_until_idle(self, max_steps: int = 100_000) -> None:
@@ -3064,6 +3143,7 @@ class InferenceEngine:
                 "empty": self.total_empty_slot_steps,
                 "first_tokens": self.total_first_tokens,
                 "tokens_credited": self.total_tokens_credited,
+                "early_handbacks": self.total_early_handbacks,
             },
             "spec_dispatches": self.total_spec_dispatches,
             "spec_drafts": self.total_spec_drafts,

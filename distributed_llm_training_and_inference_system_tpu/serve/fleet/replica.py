@@ -431,6 +431,9 @@ class EngineReplica:
                 if r is not None:
                     victims.append(r)
                     eng.scheduler.slots[i] = None
+            # (those whose last tokens were in the dispatch dropped below)
+            victims.extend(eng.scheduler.leaving.values())
+            eng.scheduler.leaving.clear()
             eng._partial_prefills.clear()
             eng._pending = None
         for r in victims:

@@ -214,6 +214,10 @@ class ContinuousBatchingScheduler:
         self.max_seq_len = max_seq_len
         self.waiting: deque[Request] = deque()
         self.slots: list[Optional[Request]] = [None] * max_batch_size
+        # requests that gave their slot back BEFORE their last tokens reached
+        # the host (``hand_back``): still RUNNING for their clients, seated
+        # nowhere, finished by ``finish_leaving`` when those tokens arrive
+        self.leaving: dict[str, Request] = {}
         self._can_allocate = can_allocate or (lambda r: True)
         self._on_release = on_release or (lambda r: None)
         # capacity check at ADMISSION TIME vs EVER: a request whose KV
@@ -277,6 +281,9 @@ class ContinuousBatchingScheduler:
                     return True
                 self._release_slot(i, "cancelled")
                 return True
+        if request_id in self.leaving:
+            self.finish_leaving(self.leaving[request_id], "cancelled")
+            return True
         return False
 
     def abort_prefill(self, request_id: str) -> bool:
@@ -321,6 +328,10 @@ class ContinuousBatchingScheduler:
                 r.error = error
                 self._release_slot(i, "error")
                 failed.append(r)
+        for r in list(self.leaving.values()):
+            r.error = error
+            self.finish_leaving(r, "error")
+            failed.append(r)
         return failed
 
     # -- scheduling ---------------------------------------------------------
@@ -384,6 +395,25 @@ class ContinuousBatchingScheduler:
         self.waiting.appendleft(r)
         return r
 
+    def hand_back(self, slot: int) -> Request:
+        """Empty ``slot`` for the next admission while its RUNNING request
+        is still owed tokens the device has yet to deliver: the request
+        stays RUNNING, unfinished, among ``leaving``. As with
+        ``preempt_slot`` the caller (engine) releases the slot's KV pages
+        itself and ``_on_release`` is NOT fired: ``finish_leaving`` does
+        that when the tokens have come."""
+        r = self.slots[slot]
+        self.slots[slot] = None
+        r.slot = None
+        self.leaving[r.request_id] = r
+        return r
+
+    def finish_leaving(self, r: Request, reason: str) -> None:
+        """End a request that ``hand_back`` took out of its slot, as
+        ``_release_slot`` ends a seated one."""
+        del self.leaving[r.request_id]
+        self._finish(r, reason)
+
     def running(self) -> list[Request]:
         return [r for r in self.slots if r is not None and r.state == RequestState.RUNNING]
 
@@ -406,6 +436,9 @@ class ContinuousBatchingScheduler:
             return
         self.slots[slot] = None
         r.slot = None
+        self._finish(r, reason)
+
+    def _finish(self, r: Request, reason: str) -> None:
         r.finish_time = time.monotonic()
         r.finish_reason = reason
         r.state = {"cancelled": RequestState.CANCELLED,
@@ -424,7 +457,8 @@ class ContinuousBatchingScheduler:
 
     @property
     def active_count(self) -> int:
-        return sum(1 for r in self.slots if r is not None)
+        """Requests the engine holds past the queue: seated, or leaving."""
+        return sum(1 for r in self.slots if r is not None) + len(self.leaving)
 
     def stats(self) -> dict:
         return {

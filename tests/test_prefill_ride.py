@@ -363,6 +363,7 @@ def test_a_riding_slots_steps_wait_up_to_its_last_piece_and_then_decode(
         steps = now["decode_steps"] - since["decode_steps"]
         assert sum(got[k] for k in ("useful", "overrun", "prompt_wait",
                                     "empty")) == SLOTS * steps
+        assert got.pop("early_handbacks") == 0      # nobody waits here
         return dict(got, decode_steps=steps,
                     padded=now["padded_slot_steps"]
                     - since["padded_slot_steps"]), now

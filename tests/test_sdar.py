@@ -454,7 +454,8 @@ def test_counters_under_the_schedule():
     # token, 20 tokens a reply
     assert stats["slot_steps"] == {
         "useful": d["slot_forwards"], "overrun": 0, "prompt_wait": 0,
-        "empty": 0, "first_tokens": 0, "tokens_credited": 4 * 20}
+        "empty": 0, "first_tokens": 0, "tokens_credited": 4 * 20,
+        "early_handbacks": 0}
     assert d["slot_forwards"] == 4 * stats["decode_steps"]
     # nothing waits for a prefill program (there is no first token): its
     # routing counts come down with the next dispatch's tokens

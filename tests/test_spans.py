@@ -307,20 +307,24 @@ def _cancel_mid_dispatch(engine):
 def _reseated_behind_a_finished_request(engine):
     """Five requests over four slots, six dispatches of a chain: the first
     request ends in the second (1 + 8 + 3 of its 12 tokens: 5 steps
-    overrun) and the third, chained behind it, credits nobody with its
-    slot's 8 steps; the fifth is seated there and its 11 tokens ride the
-    fourth's step 0 (1 step of waiting, 7 useful in a slot that was not
-    live at the submit), then 2 of the fifth's steps end it; the other
-    three get 8 + 8 + 8 + 8 + 7; the sixth dispatch was chained behind the
-    fifth before the host knew that every reply ends there."""
+    overrun), and since it MUST end there and the fifth waits, it gives its
+    slot back before the third is submitted (PR 52): the fifth is seated
+    there and its 11 tokens ride the third's step 0 (1 step of waiting, 7
+    useful in a slot that was not live at the submit), then 2 of the
+    fourth's steps end it (6 overrun; nobody waits, so the fifth dispatch
+    is chained behind with the slot still live: 8 more); the other three
+    get 8 + 8 + 8 + 8 + 7; the sixth dispatch was chained behind the fifth
+    before the host knew that every reply ends there, its fourth slot
+    empty."""
     reqs = _requests("seat", [12, 40, 40, 40, 10])
     for r in reqs:
         assert engine.scheduler.add_request(r)
     engine.run_until_idle()
     assert [len(r.generated_tokens) for r in reqs] == [12, 40, 40, 40, 10]
-    return {"useful": 32 + 27 + 24 + 31 + 23,
-            "overrun": 5 + 8 + 6 + 3 + 32, "prompt_wait": 1, "empty": 0,
-            "first_tokens": 5, "decode_steps": 48, "armed_in_flight": 7}
+    return {"useful": 32 + 27 + 31 + 26 + 21,
+            "overrun": 5 + 6 + 11 + 24, "prompt_wait": 1, "empty": 8,
+            "first_tokens": 5, "decode_steps": 48, "armed_in_flight": 7,
+            "early_handbacks": 1}
 
 
 def _an_idle_slot(engine):
