@@ -33,7 +33,12 @@ the chip):
             the same at Xing4.0's widths (latent attention, 2 layers, 9 of
             16 slots) behind a document's cached pages (the suffix program)
             and at Kimi-Linear's widths (one period K K K *, 65 of 128
-            slots) from position 0 (the chunk and final-chunk programs)
+            slots) from position 0 (the chunk and final-chunk programs);
+            then JoyAI-LLM-Flash's widths (latent attention, the dense and
+            one expert layer, 128 of 256 experts, the prediction module,
+            float32) DRAFTING for itself on constructed weights on which
+            every draft stands and on seeded ones: 2.0 and ~1.0 tokens a
+            slot-step, streams equal to plain greedy decoding's
   train     `cli.main train launch --model gpt-750m --max-steps 8`,
             sequence 2048, micro-batch 4, flash attention, fused AdamW
   launcher  `train launch --restart-on-failure 1 --max-steps 2` at
@@ -570,6 +575,30 @@ def phase_ride(env: dict) -> None:
         if any(n != rec["new"] for n in rec["identical"]):
             raise SmokeFailure("a riding prompt's tokens differ from the "
                                f"prefill program's: {rec}")
+
+
+def phase_selfdraft(env: dict) -> None:
+    text = run_child("selfdraft", [sys.executable, str(ROOT / "chip_smoke.py")],
+                     {**env, PHASE_ENV: "selfdraft"}, timeout=1200)
+    recs = {r["weights"]: r for r in smoke_records(text, "selfdraft")}
+    if set(recs) != {"all-stand", "seeded"}:
+        raise SmokeFailure(f"the self-drafting arms reported {sorted(recs)}")
+    for name, rec in recs.items():
+        say(f"  self-drafting ({rec['model']}, {rec['layers']} layers + the "
+            f"module, float32; {name} weights): {rec['tokens']} tokens in "
+            f"{rec['slot_steps']} slot-steps = {rec['tokens_per_slot_step']:.3f}"
+            f" a step, {rec['accepted']} of {rec['drafts']} drafts stood; "
+            f"tokens equal to plain greedy decoding's: {rec['identical']} of "
+            f"{rec['new']}")
+        if any(n != rec["new"] for n in rec["identical"]):
+            raise SmokeFailure("a self-drafted stream differs from plain "
+                               f"greedy decoding: {rec}")
+    if recs["all-stand"]["tokens_per_slot_step"] != 2.0:
+        raise SmokeFailure("the two-token branch did not run in every step "
+                           f"of the constructed weights: {recs['all-stand']}")
+    if recs["seeded"]["tokens_per_slot_step"] > 1.05:
+        raise SmokeFailure("seeded drafts stand far above chance: "
+                           f"{recs['seeded']}")
 
 
 _STEP_RE = re.compile(r"step (\d+) \| loss ([0-9.naninf-]+) \|.*?"
@@ -2031,10 +2060,109 @@ def child_ride() -> None:
          "chunked_prefill_tokens": published["serve"]["kv_block_size"]})
 
 
+def child_selfdraft() -> None:
+    """JoyAI-LLM-Flash's published widths (``agent-turns-64``'s
+    configuration: the dense layer, one expert layer with 128 of 256 experts
+    held, the prediction module) served twice by the same weights, drafting
+    (``speculative: mtp``: every step a window of two rows a slot through
+    ``mla_paged_attention_mq``, 1 or 2 tokens a slot) and plain
+    (``decode_scan``), in float32 with full-precision matmuls, on two sets
+    of weights: CONSTRUCTED ones on which every draft stands (every output
+    projection zero, so the stream is the token's embedding; ``W_eh`` = [I |
+    0]; plain norms: the module's logits for position i + 2 are the main
+    stack's), where a step makes exactly 2 tokens a slot, and SEEDED ones,
+    where a draft stands at chance. The benchmark cell runs seeded weights:
+    this is where the chip takes the two-token branch."""
+    child_setup()
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llm_training_and_inference_system_tpu.config.presets import (
+        get_model_config)
+    from distributed_llm_training_and_inference_system_tpu.config.schema import (
+        ModelConfig, ServeConfig)
+    from distributed_llm_training_and_inference_system_tpu.models import gpt
+    from distributed_llm_training_and_inference_system_tpu.serve.engine import (
+        InferenceEngine)
+    from distributed_llm_training_and_inference_system_tpu.serve.scheduler import (
+        Request, SamplingParams)
+
+    jax.config.update("jax_default_matmul_precision", "highest")
+    if REHEARSAL:
+        name, cfg = "joyai-test", get_model_config("joyai-test")
+        slots, lengths, new, span, extra = 4, (9, 17, 30, 41), 9, 128, {
+            "kv_block_size": 8}
+    else:
+        published = json.loads((ROOT / "benchmark" / "configs"
+                                / "joyai-llm-flash-8l-ep2.json").read_text())
+        name = published["name"]
+        cfg = ModelConfig.from_published({
+            k: v for k, v in dict(published, num_hidden_layers=2).items()
+            if not isinstance(v, (dict, list))})
+        slots, lengths, new, span, extra = 8, (
+            70, 128, 250, 333, 500, 700, 900, 1000), 65, 2048, {
+            "kv_block_size": published["serve"]["kv_block_size"],
+            "kv_hbm_budget_gb": 1.0}
+    cfg = dataclasses.replace(cfg, dtype="float32")
+    seeded = jax.jit(lambda k: gpt.init(cfg, k, jnp.float32))(
+        jax.random.PRNGKey(0))
+
+    def zeros(tree, *path):
+        """``tree`` with the kernel at ``path`` zero."""
+        if not path:
+            return {"kernel": jnp.zeros_like(tree["kernel"])}
+        return dict(tree, **{path[0]: zeros(tree[path[0]], *path[1:])})
+    blocks = seeded["blocks"]
+    blocks = dict(blocks, attn=zeros(blocks["attn"], "o"),
+                  mlp=zeros(blocks["mlp"], "down"),
+                  moe=zeros(zeros(blocks["moe"], "down"), "shared", "down"))
+    H = cfg.hidden_size
+    standing = dict(seeded, blocks=blocks, mtp=dict(
+        seeded["mtp"], eh_proj={"kernel": jnp.concatenate(
+            [jnp.eye(H, dtype=jnp.float32), jnp.zeros((H, H), jnp.float32)])}))
+    prompts = [prompt_tokens(80 + i, n, cfg.vocab_size)
+               for i, n in enumerate(lengths)]
+
+    def serve(weights, speculative):
+        eng = InferenceEngine(cfg, ServeConfig(
+            model=name, dtype="float32", max_batch_size=slots,
+            max_seq_len=span, speculative=speculative,
+            speculative_min_acceptance=0.0, **extra), params=weights)
+        reqs = [Request(request_id=f"{speculative}-{i}", prompt_tokens=p,
+                        sampling=SamplingParams(temperature=0.0,
+                                                max_tokens=new,
+                                                ignore_eos=True))
+                for i, p in enumerate(prompts)]
+        for r in reqs:
+            if not eng.scheduler.add_request(r):
+                raise RuntimeError(r.error)
+        eng.run_until_idle()
+        stats = eng.stats()
+        eng.release()
+        return [list(r.generated_tokens) for r in reqs], stats
+
+    for which, weights in (("all-stand", standing), ("seeded", seeded)):
+        plain, _ = serve(weights, "off")
+        drafted, stats = serve(weights, "mtp")
+        emit("selfdraft", {
+            "model": name, "weights": which, "new": new,
+            "layers": len(cfg.layer_pattern) // 2,
+            "tokens": stats["mtp_tokens"],
+            "slot_steps": stats["mtp_slot_steps"],
+            "drafts": stats["mtp_drafts"], "accepted": stats["mtp_accepted"],
+            "tokens_per_slot_step": stats["mtp_tokens"] / max(
+                stats["mtp_slot_steps"], 1),
+            "identical": [next((i for i, (a, b) in enumerate(zip(x, y))
+                                if a != b), min(len(x), len(y)))
+                          for x, y in zip(drafted, plain)]})
+
+
 CHILDREN = {"kernels": child_kernels, "mesh_train": child_mesh_train,
             "tp_exact": child_tp_exact, "replicas": child_replicas,
             "decode_memory": child_decode_memory, "seeded": child_seeded,
-            "ride": child_ride}
+            "ride": child_ride, "selfdraft": child_selfdraft}
 
 
 # ---------------------------------------------------------------------------
@@ -2075,6 +2203,7 @@ def main() -> int:
             phase_serve(env, device)
             phase_seeded_replies(env, args.parent)
             phase_ride(env)
+            phase_selfdraft(env)
             phase_train(env, device)
             phase_launcher(env, device)
         else:

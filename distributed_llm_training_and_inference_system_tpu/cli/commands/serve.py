@@ -41,9 +41,11 @@ def app(ctx):
 @click.option("--prometheus-port", default=None, type=int,
               help="Also start a Prometheus scrape endpoint.")
 @click.option("--speculative", default="off", show_default=True,
-              type=click.Choice(["off", "ngram"]),
+              type=click.Choice(["off", "ngram", "mtp"]),
               help="Speculative decoding (ngram = host prompt-lookup "
-                   "drafts, device verification; greedy output unchanged).")
+                   "drafts, device verification; mtp = a model with a "
+                   "next-token prediction module drafts for itself, 1 or 2 "
+                   "tokens a slot a step; greedy output unchanged).")
 @click.option("--spec-tokens", default=8, show_default=True, type=int,
               help="Speculative verify window (drafts per dispatch + 1).")
 @click.option("--prefix-cache/--no-prefix-cache", default=True,

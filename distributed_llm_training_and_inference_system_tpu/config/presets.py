@@ -318,6 +318,61 @@ def solar_open2_test_share(held: int, first: int = 0) -> ModelConfig:
         "router_experts": 16, "first_expert": first})
 
 
+# JoyAI-LLM-Flash as published (``model_type: joyai_llm_flash``, 48B-A2.7B):
+# 40 decoder layers of latent attention behind a 1,536-wide query
+# bottleneck, one leading dense layer, then 256 sigmoid-routed experts of
+# width 768 (8 a token) and a shared one, plain rope, and ONE next-token
+# prediction module, which serves as the drafter of ``speculative: mtp``
+JOYAI_LLM_FLASH_PUBLISHED = {
+    "name": "joyai-llm-flash", "model_type": "joyai_llm_flash",
+    "attention_bias": False, "ep_size": 1, "first_k_dense_replace": 1,
+    "head_dim": 64, "hidden_act": "silu", "hidden_size": 2048,
+    "intermediate_size": 7168, "kv_lora_rank": 512,
+    "max_position_embeddings": 131072, "moe_intermediate_size": 768,
+    "moe_layer_freq": 1, "n_group": 1, "n_routed_experts": 256,
+    "n_shared_experts": 1, "norm_topk_prob": True,
+    "num_attention_heads": 32, "num_experts_per_tok": 8,
+    "num_hidden_layers": 40, "num_key_value_heads": 32,
+    "num_nextn_predict_layers": 1, "q_lora_rank": 1536, "qk_head_dim": 192,
+    "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "rms_norm_eps": 1e-6,
+    "rope_interleave": True, "rope_scaling": None, "rope_theta": 32000000,
+    "routed_scaling_factor": 2.5, "scoring_func": "sigmoid",
+    "tie_word_embeddings": False, "topk_group": 1,
+    "topk_method": "noaux_tc", "v_head_dim": 128, "vocab_size": 129280,
+}
+MODEL_TEMPLATES["joyai-llm-flash"] = ModelConfig.from_published(
+    JOYAI_LLM_FLASH_PUBLISHED)
+
+# ... and its shape in small, in the same keys (the plain reference of the
+# benchmark reads these): a dense layer and two expert layers, 8 experts
+# (3 a token) ALL held, the module behind them; ``joyai_test_share`` is one
+# chip's share
+JOYAI_TEST_PUBLISHED = {
+    **JOYAI_LLM_FLASH_PUBLISHED,
+    "name": "joyai-test", "num_hidden_layers": 3, "hidden_size": 64,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 16,
+    "q_lora_rank": 24, "kv_lora_rank": 32, "qk_head_dim": 24,
+    "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+    "intermediate_size": 96, "moe_intermediate_size": 32,
+    "n_routed_experts": 8, "num_experts_per_tok": 3, "vocab_size": 256,
+    "max_position_embeddings": 512, "rope_theta": 10000.0,
+    "dtype": "float32",
+}
+TEST_TEMPLATES["joyai-test"] = ModelConfig.from_published(
+    JOYAI_TEST_PUBLISHED)
+
+
+def joyai_test_share(held: int, first: int = 0, vocab: int = 256,
+                     **more) -> ModelConfig:
+    """``joyai-test`` with ``held`` of its 8 experts held from ``first`` on
+    and the first ``vocab`` rows of its vocabulary (a chip's share where 8 /
+    ``held`` chips share each layer): the router keeps its 8 outputs."""
+    return ModelConfig.from_published({
+        **JOYAI_TEST_PUBLISHED, "n_routed_experts": held,
+        "router_experts": 8, "first_expert": first, "vocab_size": vocab,
+        **more})
+
+
 # Falcon-H1-34B-Instruct as published (``model_type: falcon_h1``): 72
 # layers, each attention (20 / 4 heads of 128, rope base 1e11) AND a Mamba-2
 # mixer (32 heads of 128, 2 groups, state 256) side by side under one norm,

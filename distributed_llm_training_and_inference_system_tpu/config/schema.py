@@ -538,6 +538,17 @@ class ModelConfig:
     hc_clamp_max: float = 30.0
     # ``falcon_h1``'s muP multipliers (all 1: none)
     mup: MupConfig = field(default_factory=MupConfig)
+    # next-token prediction modules behind the main stack (the published
+    # ``num_nextn_predict_layers``; 0 or 1): each one more decoder layer
+    # (latent attention + experts) over [norm(embedding of the NEXT token) |
+    # norm(the main stack's stream)] through a 2H -> H projection, with its
+    # own final norm and the main model's embedding and head. Its layer is
+    # the LAST entry of the ``attn`` and ``moe`` stacks and of the latent
+    # pool (``kv_layers`` / ``moe_layers`` count it; the layer table does
+    # not: the main stack's walk ends before it). It serves as the drafter
+    # of ``ServeConfig.speculative: mtp`` (serve/decode.py
+    # ``draft_verify_scan``)
+    mtp_layers: int = 0
 
     @property
     def is_moe(self) -> bool:
@@ -587,12 +598,12 @@ class ModelConfig:
         uniform stack."""
         if not self.layer_pattern:
             return self.num_layers
-        return self.layers_of("*") + self.layers_of("P")
+        return self.layers_of("*") + self.layers_of("P") + self.mtp_layers
 
     @property
     def moe_layers(self) -> int:
         if self.layer_pattern:
-            return self.layers_of("E")
+            return self.layers_of("E") + self.mtp_layers
         return self.num_layers if self.is_moe else 0
 
     @property
@@ -794,8 +805,12 @@ class ModelConfig:
             nc, k = self.hc_mult * h, self.hc_mult
             hc = (nc + nc * (2 * k + k * k) + 3 + 2 * k + k * k
                   if k > 1 else 0)
+            # a prediction module: one more attention and expert layer,
+            # the [embedding | stream] -> hidden projection, three norms
+            mtp = self.mtp_layers * (2 * h + mixer["*"] + mixer["E"]
+                                     + 2 * h * h + 3 * h)
             return (v * h + sum(h + hc + mixer[k_] for k_ in self.layer_pattern)
-                    + h + (0 if self.tie_word_embeddings else v * h))
+                    + mtp + h + (0 if self.tie_word_embeddings else v * h))
         if self.activation in ("silu", "gelu"):    # gated: w_gate, w_up, w_down
             mlp_dense = 3 * h * f
         else:
@@ -912,12 +927,25 @@ class ModelConfig:
                 f"mlp_only_layers = {d['mlp_only_layers']}: dense layers "
                 "among a uniform stack's expert layers are not carried "
                 "(state [] or give a layer_pattern)")
-        if int(d.get("num_nextn_predict_layers", 0)) > 0:
+        mtp = int(_take(d, "mtp_layers", "num_nextn_predict_layers",
+                        default=0) or 0)
+        if mtp > 1:
             raise ConfigError(
-                f"num_nextn_predict_layers = {d['num_nextn_predict_layers']}"
-                ": the next-token prediction module is not served (no "
-                "drafter, no verification program over latent pages; "
-                "ROADMAP B7): state 0 to serve the model without it")
+                f"num_nextn_predict_layers = {mtp}: ONE next-token "
+                "prediction module is served (a draft-and-verify window of "
+                "two rows a slot); a chain of modules is not carried "
+                "(ROADMAP B7): state 1, or 0 to serve the model without")
+        if mtp and not (latent and pattern and set(pattern) <= set("*DE")
+                        and "E" in pattern
+                        and int(_take(d, "hc_mult", default=1)) == 1
+                        and not sdar):
+            raise ConfigError(
+                f"num_nextn_predict_layers = {mtp}: the next-token "
+                "prediction module is served for a layer table of latent "
+                "attention, dense and expert layers over ONE residual "
+                "stream (its layer is one more layer of the latent pool "
+                "and of the expert stacks; ROADMAP B7): state 0 to serve "
+                "this model without it")
         if latent:
             d = dict(d, head_dim=mla.qk_nope_head_dim + mla.qk_rope_head_dim)
         cfg = cls(
@@ -970,6 +998,7 @@ class ModelConfig:
             hc_clamp_max=float(_take(d, "hc_clamp_max",
                                      "mhc_h_res_clamp_max", default=30.0)),
             mup=MupConfig.from_dict(d.get("mup"), published=d),
+            mtp_layers=mtp,
             position_embedding=str(_take(
                 d, "position_embedding", default=(
                     "none" if latent and d.get("mla_use_nope")
@@ -1415,7 +1444,14 @@ class ServeConfig:
     cors_origins: str = "*"
     temperature: float = 1.0
     # speculative decoding: "off" | "ngram" (host prompt-lookup drafts,
-    # device verification — serve/speculative.py). Greedy requests accept
+    # device verification — serve/speculative.py) | "mtp" (a model with a
+    # next-token prediction module, ``ModelConfig.mtp_layers``, drafts for
+    # itself: every decode step verifies the slot's draft and makes the
+    # next one, 1 or 2 tokens a slot a step; ``speculative_tokens`` is then
+    # 1 + the module count whatever is stated, and a
+    # ``speculative_min_acceptance`` of 0 keeps the mechanism on whatever
+    # the acceptance; serve/decode.py ``draft_verify_scan``). Greedy
+    # requests under "ngram" accept
     # up to speculative_tokens-1 drafts + 1 bonus token per dispatch; the
     # acceptance rule is draft == argmax of the verify-pass logits, so the
     # output is always a valid greedy chain regardless of draft quality
@@ -1531,9 +1567,10 @@ class ServeConfig:
         # tests/test_tp_serve.py
         # the engine checks `speculative == "ngram"`, so a config-file typo
         # ("n-gram", "medusa") would otherwise silently disable speculation
-        if self.speculative not in ("off", "ngram"):
+        if self.speculative not in ("off", "ngram", "mtp"):
             raise ConfigError(
-                f"speculative must be off|ngram, got {self.speculative!r}")
+                f"speculative must be off|ngram|mtp, got "
+                f"{self.speculative!r}")
         if self.speculative != "off" and self.speculative_tokens < 2:
             raise ConfigError("speculative_tokens must be >= 2")
         if self.scheduler not in ("continuous", "static"):

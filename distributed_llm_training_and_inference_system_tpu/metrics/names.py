@@ -101,6 +101,16 @@ METRICS: dict[str, MetricSpec] = {
         COUNTER, "Prompt tokens prefilled inside decode dispatches, as rows "
                  "of the decode steps (beside llmctl_engine_phase_seconds: "
                  "a busy batch stalls for no prefill program)"),
+    "llmctl_inference_mtp_drafts": MetricSpec(
+        COUNTER, "Drafts of the model's own prediction module that a "
+                 "credited draft-and-verify step verified (speculative: "
+                 "mtp; greedy requests)"),
+    "llmctl_inference_mtp_accepted": MetricSpec(
+        COUNTER, "... of which stood (the step made two tokens)"),
+    "llmctl_inference_mtp_slot_steps": MetricSpec(
+        COUNTER, "Draft-and-verify steps a request was credited with"),
+    "llmctl_inference_mtp_tokens": MetricSpec(
+        COUNTER, "Tokens those steps made (1 or 2 a step)"),
     "llmctl_inference_state_carry_tokens": MetricSpec(
         COUNTER, "Prompt tokens prefilled by chunk programs that read and "
                  "wrote a slot's recurrent state (a K model's chunked "
@@ -490,6 +500,15 @@ COUNTER_FLOW: tuple[CounterFlow, ...] = (
                 "spec_accepted", "llmctl_fleet_spec_accepted"),
     CounterFlow("InferenceEngine", "total_spec_resumes", "spec_resumes",
                 "llmctl_fleet_spec_resumes"),
+    # self-drafting (speculative: mtp; serve/decode.py draft_verify_scan)
+    CounterFlow("InferenceEngine", "total_mtp_drafts", "mtp_drafts",
+                "llmctl_inference_mtp_drafts"),
+    CounterFlow("InferenceEngine", "total_mtp_accepted", "mtp_accepted",
+                "llmctl_inference_mtp_accepted"),
+    CounterFlow("InferenceEngine", "total_mtp_slot_steps",
+                "mtp_slot_steps", "llmctl_inference_mtp_slot_steps"),
+    CounterFlow("InferenceEngine", "total_mtp_tokens", "mtp_tokens",
+                "llmctl_inference_mtp_tokens"),
     # stream-hub counters -> FleetStreamHub.stats() keys (the supervisor
     # snapshot embeds them wholesale; the Prometheus pump deltas the
     # mapped ones)

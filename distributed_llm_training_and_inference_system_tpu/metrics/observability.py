@@ -239,6 +239,12 @@ class PrometheusExporter:
         self.infer_swap_ins = mk("llmctl_inference_swap_ins")
         self.infer_ride_tokens = mk("llmctl_inference_prefill_ride_tokens")
         self.infer_carry_tokens = mk("llmctl_inference_state_carry_tokens")
+        # self-drafting (engine.stats()["mtp_*"])
+        self.infer_mtp = {
+            "mtp_drafts": mk("llmctl_inference_mtp_drafts"),
+            "mtp_accepted": mk("llmctl_inference_mtp_accepted"),
+            "mtp_slot_steps": mk("llmctl_inference_mtp_slot_steps"),
+            "mtp_tokens": mk("llmctl_inference_mtp_tokens")}
         self.infer_swapped_bytes = mk("llmctl_inference_swapped_host_bytes")
         # serve-fleet control plane (serve/fleet/): per-replica health the
         # operator alarms on. Queue depth + outstanding tokens are the
@@ -445,7 +451,8 @@ class PrometheusExporter:
                              ("swap_ins", self.infer_swap_ins),
                              ("prefill_ride_tokens", self.infer_ride_tokens),
                              ("state_carry_tokens",
-                              self.infer_carry_tokens)):
+                              self.infer_carry_tokens),
+                             *self.infer_mtp.items()):
             if key in m:
                 self._inc_to(counter, key, m[key])
         if "swapped_host_bytes" in m:

@@ -76,8 +76,21 @@ def init(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
         return params
 
     if cfg.layer_pattern:
-        return around(_init_table_blocks(cfg, keys, norm_init, dense,
-                                         resid_std, dtype))
+        params = around(_init_table_blocks(cfg, keys, norm_init, dense,
+                                           resid_std, dtype))
+        if cfg.mtp_layers:
+            # the next-token prediction module: its decoder layer is the
+            # last entry of the ``attn`` and ``moe`` stacks; here what it
+            # has beside: the two norms, the [embedding | stream] -> H
+            # projection and its own final norm (embedding and head are the
+            # main model's)
+            params["mtp"] = {
+                "enorm": {"scale": norm_init(H)},
+                "hnorm": {"scale": norm_init(H)},
+                "eh_proj": {"kernel": dense(next(keys), 2 * H, H)},
+                "final_norm": {"scale": norm_init(H)},
+            }
+        return params
 
     blocks = {
         "attn_norm": {"scale": norm_init(L, H)},
@@ -479,6 +492,66 @@ def _remat_wrap(fn, policy: str):
     return jax.checkpoint(fn, policy=dots)
 
 
+def mtp_layer_index(cfg: ModelConfig) -> tuple[int, int]:
+    """Where the prediction module's decoder layer lies in the ``attn``
+    stack (and the latent pool) and in the ``moe`` stack: behind the main
+    stack's layers of each kind."""
+    return cfg.layers_of("*"), cfg.layers_of("E")
+
+
+def mtp_forward(params: Params, blocks: Params, cfg: ModelConfig,
+                next_tokens: jax.Array, stream: jax.Array,
+                positions: jax.Array, inv_freq: jax.Array, attend, *,
+                live=None, matmul=None, norm_impl: str = "xla"):
+    """The next-token prediction module (DeepSeek-V3 technical report,
+    section 2.2; depth 1) over rows whose NEXT token is known:
+
+        z_i  = W_eh [RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(h_i)]
+        z'_i = one decoder layer (latent attention over z' rows 0..i at
+               positions 0..i, then experts) of z_i
+
+    ``stream`` [B, S, H] is the main stack's residual stream h BEFORE its
+    final norm, ``next_tokens`` [B, S] the tokens one position on,
+    ``blocks`` the cast block stacks (the module's layer is their last
+    ``attn`` and ``moe`` entry), ``attend`` where the layer's latent rows
+    live (``attend_fresh`` for a whole sequence; serve/decode.py
+    ``mtp_attend_pages`` over the latent pool). Returns (z' [B, S, H], the
+    attention's state, the expert layer's ``moe_stats``); ``mtp_head``
+    makes the draft logits of t_{i+2}."""
+    from .layers import dense_matmul
+    matmul = matmul or dense_matmul
+    m, dt = params["mtp"], stream.dtype
+    la, le = mtp_layer_index(cfg)
+    with jax.named_scope("mtp_embed_proj"):
+        e = scaled(params["embed"]["embedding"][next_tokens].astype(dt),
+                   cfg.mup.embedding)
+        e = rms_norm(e, m["enorm"]["scale"].astype(dt), cfg.norm_eps,
+                     impl=norm_impl)
+        h = rms_norm(stream, m["hnorm"]["scale"].astype(dt), cfg.norm_eps,
+                     impl=norm_impl)
+        z = matmul(jnp.concatenate([e, h], axis=-1),
+                   m["eh_proj"]["kernel"].astype(dt))
+    with jax.named_scope("mtp_layer"):
+        z, state, _ = decoder_block(
+            z, table_layer(blocks, "*", la), cfg, positions, inv_freq,
+            attend, matmul=matmul, norm_impl=norm_impl, live=live,
+            layer_index=la, kind="*")
+        z, _, stats = decoder_block(
+            z, table_layer(blocks, "E", le), cfg, positions, inv_freq,
+            None, matmul=matmul, norm_impl=norm_impl, live=live,
+            layer_index=le, kind="E")
+    return z, state, stats
+
+
+def mtp_head(params: Params, z: jax.Array, cfg: ModelConfig,
+             norm_impl: str = "xla") -> jax.Array:
+    """The module's draft logits: its own final norm, the main model's
+    head."""
+    with jax.named_scope("mtp_head"):
+        return unembed(dict(params, final_norm=params["mtp"]["final_norm"]),
+                       z, cfg, norm_impl=norm_impl)
+
+
 def unembed(params: Params, x: jax.Array, cfg: ModelConfig,
             norm_impl: str = "xla") -> jax.Array:
     """Final RMSNorm + LM head logits (tied or untied), fp32 output.
@@ -521,6 +594,8 @@ def forward(
     return_moe_stats: bool = False,
     return_ssm_state: bool = False,
     return_latent: bool = False,
+    return_stream: bool = False,
+    return_mtp: bool = False,
 ):
     """Compute logits [B, S, V] (fp32) — or, with ``return_hidden=True``,
     the final-normed hidden states [B, S, H] in the compute dtype (consumed
@@ -561,6 +636,14 @@ def forward(
       writes to the latent pages. With ``cfg.hc_mult`` > 1 the residual is
       ``hc_mult`` streams: copies of the embedding at the start, summed
       before the final norm.
+    - a model with a next-token prediction module (``cfg.mtp_layers``):
+      ``return_stream`` appends the residual stream BEFORE the final norm
+      [B, S, H] (what the module reads; cold prefill runs ``mtp_forward``
+      on it once the first token is sampled), and ``return_mtp`` the
+      module's draft logits [B, S, V] of the whole sequence, row i read
+      with the embedding of ``tokens[i + 1]`` (the last row's wraps around
+      and means nothing) and predicting token i + 2; the main stack's
+      logits are what they are without it.
     """
     compute_dtype = jnp.dtype(cfg.dtype)
     B, S = tokens.shape
@@ -609,11 +692,23 @@ def forward(
             cache_offset, attn_impl, norm_impl, compute_dtype)
         if cfg.hc_mult > 1:
             x = jnp.sum(x.astype(jnp.float32), axis=2).astype(compute_dtype)
+        mtp = []
+        if return_mtp:
+            if not cfg.mtp_layers:
+                raise ValueError("return_mtp needs a model with a "
+                                 "next-token prediction module")
+            z, _rows, _stats = mtp_forward(
+                params, cast_table_blocks(params["blocks"], compute_dtype),
+                cfg, jnp.roll(tokens, -1, axis=1), x, positions, inv_freq,
+                attend_fresh(positions, segment_ids, attn_impl),
+                live=segment_ids, norm_impl=norm_impl)
+            mtp = [mtp_head(params, z, cfg, norm_impl)]
         return _finish_forward(
             params, x, cfg, norm_impl, unembed_positions, return_hidden,
             [new_cache] * (kv_cache is not None or return_latent)
             + [aux_total] * return_moe_stats
-            + [ssm_state] * return_ssm_state)
+            + [ssm_state] * return_ssm_state
+            + [x] * return_stream + mtp)
 
     # plain leaves are cast to the compute dtype ONCE before the scan
     # (casting inside the body would stream fp32 master weights from HBM

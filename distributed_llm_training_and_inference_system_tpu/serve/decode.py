@@ -22,6 +22,9 @@ from ..config.schema import ModelConfig
 from ..models.gpt import (
     cast_table_blocks,
     layer_experts,
+    mtp_forward,
+    mtp_head,
+    mtp_layer_index,
     split_expert_stacks,
     table_layer,
     table_layers,
@@ -75,6 +78,18 @@ class StepResult(NamedTuple):
     v_pages: Any            # None: a latent model
     moe_stats: Any = None   # asked with ``return_moe_stats`` of an MoE model
     state: Any = None       # {"conv", "ssm"}: a recurrent model's pools
+
+
+class StepWithStream(NamedTuple):
+    """``StepResult`` and, asked for with ``return_stream``, the residual
+    stream before the final norm [B, T, H] (what a next-token prediction
+    module reads)."""
+    logits: jax.Array
+    k_pages: Any
+    v_pages: Any
+    moe_stats: Any
+    state: Any
+    stream: jax.Array
 
 
 class DispatchResult(NamedTuple):
@@ -153,9 +168,10 @@ def can_carry(cfg: ModelConfig) -> bool:
     (``P``: in ONE layer, the attention over the slot's pages and the scan
     from the slot's state), delta-rule (``K``) layers beside a latent pool
     or K/V pages."""
-    if cfg.is_diffusion:
-        # its step is a window of 2 x ``block_length`` rows a slot already,
-        # and a ``Piece`` wants T == 1
+    if cfg.is_diffusion or cfg.mtp_layers:
+        # its step is a window already (2 x ``block_length`` rows a slot; a
+        # self-drafting model's last sure token and its draft), and a
+        # ``Piece`` wants T == 1
         return False
     return "M" not in cfg.layer_pattern or not cfg.is_latent
 
@@ -237,6 +253,12 @@ def extend_step_forward(
     head_from: int = 0,       # static: the head runs over the window's rows
                               # from this one on (``denoise_scan``: its
                               # second half), logits [B, T - head_from, V]
+    return_stream: bool = False,  # a layer table: also the residual stream
+                              # before the final norm (what a next-token
+                              # prediction module reads)
+    live_rows: Any = None,    # [B, T] bool: the rows that are tokens, where
+                              # they are not the rows written (a row computed
+                              # again over a cached page writes nothing)
 ) -> StepResult:
     """Paged forward over T tokens per slot: the multi-token sibling of
     ``decode_step_forward``. Returns a ``StepResult``: logits [B, T, V]
@@ -295,7 +317,8 @@ def extend_step_forward(
     compute_dtype = jnp.dtype(cfg.dtype)
     B, T = tokens.shape
     positions = start_positions[:, None] + jnp.arange(T, dtype=jnp.int32)
-    live = write_ok           # [rows, T]: the rows that are tokens
+    # [rows, T]: the rows that are tokens
+    live = write_ok if live_rows is None else live_rows
     if ride is not None:
         if T != 1 or not can_carry(cfg):
             raise ValueError("a piece rides a decode step (T = 1) of a "
@@ -525,10 +548,11 @@ def extend_step_forward(
                 conv, ssm, piece_slot, *piece, piece_live)
         if cfg.hc_mult > 1:
             x = jnp.sum(x.astype(jnp.float32), axis=2).astype(compute_dtype)
-        return StepResult(
+        step = StepResult(
             unembed(params, head_rows(x), cfg), kp, vp,
             stats if return_moe_stats else None,
             {"conv": conv, "ssm": ssm} if ssm_state is not None else None)
+        return StepWithStream(*step, x) if return_stream else step
 
     def body(carry, layer_and_index):
         # the pools must stay a CARRY that every layer writes and reads
@@ -621,6 +645,178 @@ def attend_latent_pages(cfg: ModelConfig, pool: jax.Array, li,
         return jnp.concatenate([out, piece_out[0][:, None]]), (new, None)
     attend.latent = True
     return attend
+
+
+# -- self-drafting: the next-token prediction module over the latent pool ----
+#
+# The module's layer is one more layer of the latent pool. Its row for
+# position i reads the embedding of token i + 1, so it is stored at cache
+# index i + 1, the index of the token it read: a page then holds nothing that
+# depends on a token after the page, and page-hash prefix reuse stays what it
+# is for every other layer (``kv_cache.prefix_page_hashes``). Index 0 has no
+# row; what stands there is a SENTINEL, zeros with ``MTP_SENTINEL`` in the
+# first lane of the row's padding (``MLAConfig.page_width`` > ``latent_size``),
+# and every query of the module carries a 1 in that lane: index 0 scores
+# ``MTP_SENTINEL x scale`` (-2,165 at the published head size), its softmax
+# weight is exactly 0 in float32, and every real row's padding lane is 0.
+MTP_SENTINEL = -30000.0
+
+
+def mtp_attend_pages(cfg: ModelConfig, pool: jax.Array,
+                     block_tables: jax.Array, index_starts: jax.Array,
+                     write_ok: Any = None, attn_impl: str = "auto"):
+    """The module's ``attend``: ``attend_latent_pages`` at the pool's last
+    layer with the window written from cache index ``index_starts`` (the
+    rows' positions + 1) and index 0 masked by the sentinel's lane."""
+    la, _ = mtp_layer_index(cfg)
+    lane = cfg.mla.latent_size
+    if pool.shape[-1] <= lane:
+        raise ValueError(
+            f"{cfg.name}: the prediction module masks cache index 0 through "
+            f"a lane of the latent row's padding, and a row of {lane} values "
+            f"is stored {pool.shape[-1]} wide: no lane is left")
+
+    def attend(q_lat, rows, scale):
+        q = pad_to_page_width(q_lat, pool).at[..., lane].set(1)
+        out, new = _latent_windows(
+            q, rows, pool, block_tables, index_starts, write_ok, la,
+            scale=scale, value_width=cfg.mla.kv_lora_rank,
+            attn_impl=attn_impl)
+        return out, (new, None)
+    attend.latent = True
+    return attend
+
+
+def mtp_sentinel_row(cfg: ModelConfig, pool: jax.Array) -> jax.Array:
+    """What stands at cache index 0 of the module's layer: [W]."""
+    return jnp.zeros((pool.shape[-1],), pool.dtype).at[
+        cfg.mla.latent_size].set(MTP_SENTINEL)
+
+
+def mtp_window(params, cfg: ModelConfig, next_tokens: jax.Array,
+               stream: jax.Array, starts: jax.Array, pool: jax.Array,
+               block_tables: jax.Array, write_ok: jax.Array,
+               attn_impl: str = "auto", first: bool = False):
+    """The module over a window of every slot: rows at positions ``starts``
+    + j read ``next_tokens[:, j]`` (the token at position + 1) and
+    ``stream[:, j]``, their latent rows go to cache index position + 1 of
+    the pool's last layer (``write_ok`` rows alone) and each attends over
+    indices 1 .. its own. ``first`` (a prefill window): a window that
+    starts its sequence also writes the sentinel at index 0. Returns
+    (z' [B, T, H], the pool, the expert layer's ``moe_stats``)."""
+    B, T = next_tokens.shape
+    la, _ = mtp_layer_index(cfg)
+    if first:
+        with jax.named_scope("mla_page_write"):
+            pool = write_window_to_pages(
+                pool, jnp.broadcast_to(mtp_sentinel_row(cfg, pool),
+                                       (B, 1, 1, pool.shape[-1])),
+                block_tables, jnp.zeros_like(starts),
+                (starts == 0)[:, None], la)
+    positions = starts[:, None] + jnp.arange(T, dtype=jnp.int32)
+    z, (pool, _), stats = mtp_forward(
+        params, cast_table_blocks(params["blocks"], jnp.dtype(cfg.dtype)),
+        cfg, next_tokens, stream, positions, model_rope_frequencies(cfg),
+        mtp_attend_pages(cfg, pool, block_tables, starts + 1, write_ok,
+                         attn_impl),
+        live=write_ok)
+    return z, pool, stats
+
+
+# a step's row of ``draft_verify_scan``'s record, a slot: the token the main
+# stack made for the slot's next position, the one it made for the position
+# after (emitted where the draft stood), how many of the two were emitted,
+# and the draft the step verified
+DRAFT_RECORD = ("token", "second", "n_emit", "draft")
+
+
+def draft_verify_scan(params, tokens, positions, k_pages, v_pages,
+                      block_tables, stop_positions, slot_keys, temperature,
+                      top_k, top_p, cfg: ModelConfig, num_steps: int,
+                      attn_impl: str = "auto",
+                      return_moe_stats: bool = False) -> DispatchResult:
+    """``decode_scan`` for a model that drafts for itself
+    (``cfg.mtp_layers``; ``ServeConfig.speculative: mtp``): ``num_steps``
+    draft-and-verify steps chained on the device, 1 or 2 tokens a slot a
+    step.
+
+    ``tokens`` is (tokens [B], drafts [B]): a slot's last sure token, at
+    ``positions`` [B], and the module's draft of the token after it. One
+    step, every slot, static shapes:
+
+    - the main stack runs the WINDOW (token at p, draft at p + 1) over the
+      slot's latent pages (``extend_step_forward`` at T = 2: the
+      multi-query latent kernel) and ONE head matmul makes both rows'
+      logits; g = the token at p + 1 (argmax, or for temperature > 0 the
+      sample a plain step would draw: the slot's key folded by p + 1), and
+      the draft STANDS where it is g, the request is greedy and p + 1 is a
+      position the slot may write;
+    - where it stands, g2 = argmax of the second row is emitted too and the
+      slot moves 2; else it moves 1, and the draft's stale row at p + 1 is
+      overwritten by the next step's window;
+    - the module runs rows p (with the embedding of g) and p + 1 (with that
+      of g2; dead, unwritten and expertless where the draft fell) over its
+      own layer of the pool (``mtp_window``), and its head over the last
+      row that stands makes the next draft.
+
+    A slot at or past ``stop_positions`` writes the scratch page and keeps
+    its carry. The greedy stream is plain greedy decoding of the main stack
+    token for token (a draft is only ever emitted as the main stack's own
+    argmax). Returns a ``DispatchResult``: ``sampled`` [K, B, 4] int32, a
+    step's row a slot as ``DRAFT_RECORD`` has it; the final carry
+    (``tokens`` = (tokens, drafts), ``positions``); the pool; the summed
+    ``moe_stats`` of main stack and module."""
+    if not (cfg.mtp_layers and cfg.is_latent):
+        raise ValueError(f"{cfg.name}: draft_verify_scan needs a latent "
+                         "model with a next-token prediction module")
+    return_moe_stats = return_moe_stats and cfg.is_moe
+    greedy_slot = temperature <= 0.0
+
+    def one(carry, _):
+        (toks, drafts), pos, pool, stats = carry
+        live0, live1 = pos < stop_positions, pos + 1 < stop_positions
+        step = extend_step_forward(
+            params, jnp.stack([toks, drafts], axis=1), pos, pool, v_pages,
+            block_tables, cfg, write_ok=jnp.stack([live0, live1], axis=1),
+            attn_impl=attn_impl, return_moe_stats=return_moe_stats,
+            return_stream=True)
+        with jax.named_scope("draft_verify"):
+            logits = step.logits                              # [B, 2, V]
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            keys = jax.vmap(jax.random.fold_in)(
+                jax.vmap(jax.random.wrap_key_data)(slot_keys), pos + 1)
+            with jax.named_scope("sampler"):
+                sampled = sample_tokens(logits[:, 0], keys, temperature,
+                                        top_k, top_p)
+            g = jnp.where(greedy_slot, greedy[:, 0], sampled)
+            g2 = greedy[:, 1]
+            stands = greedy_slot & (greedy[:, 0] == drafts) & live1
+        # the module's rows: p always (its index p + 1 is the slot's to
+        # write while p + 1 is), p + 1 where the draft stood
+        z, pool, mtp_stats = mtp_window(
+            params, cfg, jnp.stack([g, g2], axis=1), step.stream, pos,
+            step.k_pages, block_tables,
+            jnp.stack([live1, stands & (pos + 2 < stop_positions)], axis=1),
+            attn_impl)
+        with jax.named_scope("draft_verify"):
+            last = jnp.where(stands[:, None, None], z[:, 1:], z[:, :1])
+        draft = jnp.argmax(mtp_head(params, last, cfg)[:, 0],
+                           axis=-1).astype(jnp.int32)
+        with jax.named_scope("draft_verify"):
+            n_emit = 1 + stands.astype(jnp.int32)
+            record = jnp.stack([g, g2, n_emit, drafts], axis=-1)
+            toks = jnp.where(live0, jnp.where(stands, g2, g), toks)
+            drafts = jnp.where(live0, draft, drafts)
+            pos = jnp.where(live0, pos + n_emit, pos)
+        if stats is not None:
+            stats = stats + step.moe_stats + mtp_stats
+        return ((toks, drafts), pos, pool, stats), record
+
+    stats0 = (jnp.zeros((cfg.moe.stats_size,), jnp.int32)
+              if return_moe_stats else None)
+    (toks, pos, pool, stats), records = jax.lax.scan(
+        one, (tokens, positions, k_pages, stats0), None, length=num_steps)
+    return DispatchResult(records, toks, pos, pool, v_pages, stats)
 
 
 def decode_multi_step(params, tokens, positions, k_pages, v_pages,

@@ -43,6 +43,26 @@ logger = logging.getLogger("llmctl.serve.kv_cache")
 _MOVES_PAGES = ("it re-enters or moves K/V pages, and the layers' recurrent "
                 "state is not in them; ROADMAP C2")
 REFUSED = {
+    # a model served with its next-token prediction module (``mtp_layers``;
+    # ``speculative: mtp``, serve/decode.py ``draft_verify_scan``): the
+    # module's rows are one more layer of the latent pool, a slot carries a
+    # draft beside its last token, and a decode step is a window of two rows
+    "self_drafting": {
+        # OFF and counted an admission (``decode.can_carry``)
+        "riding": "a piece wants a step of T = 1, its step is a window of "
+                  "the last sure token and its draft; ROADMAP B7",
+        "preemption: swap": "a swapped slot would carry a draft and the "
+                            "module's rows, and the swap payload is a K and "
+                            "a V pool; a preempted request is recomputed; "
+                            "ROADMAP B7",
+        "measure_device_times": "its decode probe times a token a step; a "
+                                "draft-and-verify step's time is the "
+                                "benchmark's serve_programs."
+                                "selfdraft_step_device_ms",
+        "speculative": "n-gram drafts are verified over K/V pages; a model "
+                       "with a prediction module drafts for itself over "
+                       "its latent pages (speculative: mtp); ROADMAP B7",
+    },
     # ``M`` layers alone: ``K`` layers CARRY a chunk (ops/kda.py
     # ``recur_chunk``), and a ``K`` model with latent attention must chunk.
     # The ``M`` form exists (a riding piece runs it), but no cell and no
@@ -76,8 +96,10 @@ REFUSED = {
     "latent": {
         **dict.fromkeys(
             ("speculative", "preemption: swap"),
-            "no verification program has run over latent pages, and the "
-            "swap payload is a K and a V pool; ROADMAP B4, B7"),
+            "n-gram drafts are verified over K/V pages alone (a latent "
+            "model with a prediction module drafts for itself: "
+            "speculative: mtp), and the swap payload is a K and a V pool; "
+            "ROADMAP B4, B7"),
         "kv_quantization": "a latent row is every head's keys AND values: "
                            "no quantised layout or kernel for it exists; "
                            "ROADMAP B4",
@@ -118,6 +140,8 @@ def refused(cfg: ModelConfig, feature: str, snapshot_entries: int = 0
     if feature == "prefix_caching" and keeps_snapshots(cfg, snapshot_entries):
         return None
     kinds = {
+        "self_drafting": cfg.mtp_layers > 0
+        and "drafts with its prediction module",
         "state_space": cfg.ssm_layers > 0 and f"has {cfg.recurrent_name}",
         "recurrent": cfg.is_recurrent and f"has {cfg.recurrent_name}",
         "latent": cfg.is_latent and "keeps latent pages",

@@ -57,6 +57,14 @@ class Request:
     # its block) at which each generated token was fixed; the completion
     # body's ``return_unmask_steps`` returns it beside ``token_ids``
     unmask_steps: list[int] = field(default_factory=list, repr=False)
+    # self-drafting (``speculative: mtp``): beside each generated token, the
+    # draft the prediction module had made for its position and the main
+    # stack verified (-1: none: the first token, a pair's second, a sampled
+    # token, every token once drafting was switched off) and whether it
+    # stood (the step emitted two); as long as ``generated_tokens``; the
+    # completion body's ``return_draft_tokens`` returns both
+    draft_tokens: list[int] = field(default_factory=list, repr=False)
+    draft_stood: list[bool] = field(default_factory=list, repr=False)
     slot: Optional[int] = None
     # set while PREFILLING (when the slot can't be torn down mid-flight);
     # the engine releases the slot at the next step boundary
@@ -178,6 +186,11 @@ class Request:
         if self.first_token_time is None:
             self.first_token_time = time.monotonic()
         self.generated_tokens.append(token)
+
+    def record_draft(self, draft: int = -1, stood: bool = False) -> None:
+        """Beside the token just recorded: the draft verified there."""
+        self.draft_tokens.append(draft)
+        self.draft_stood.append(stood)
 
     def should_stop(self, eos_token_id: Optional[int]) -> Optional[str]:
         if self.generated_tokens:

@@ -228,6 +228,16 @@ class InferenceServer:
                 "return_unmask_steps must be true or false, and true only "
                 f"for a model that generates by diffusion over blocks "
                 f"({self.model_cfg.name} does not)")}, status=400)
+        # self-drafting (``speculative: mtp``): beside ``token_ids``, the
+        # draft the prediction module had made for each emitted position
+        # and the main stack verified (-1: none), and whether it stood
+        want_drafts = body.get("return_draft_tokens", False)
+        if not isinstance(want_drafts, bool) or (
+                want_drafts and self.serve_cfg.speculative != "mtp"):
+            return web.json_response({"error": (
+                "return_draft_tokens must be true or false, and true only "
+                "for a server that drafts with the model's prediction "
+                "module (speculative: mtp)")}, status=400)
         req = Request(request_id=f"cmpl-{uuid.uuid4().hex[:24]}",
                       prompt_tokens=prompt_tokens, sampling=sampling)
         loop = asyncio.get_running_loop()
@@ -249,7 +259,8 @@ class InferenceServer:
         self._wake.set()
 
         if stream:
-            return await self._stream_response(request, req, token_q)
+            return await self._stream_response(request, req, token_q,
+                                               want_drafts)
 
         try:
             await self._await_request(req, event)
@@ -277,6 +288,7 @@ class InferenceServer:
                 "token_ids": req.generated_tokens,
                 **({"unmask_steps": req.unmask_steps[:n_gen]}
                    if want_steps else {}),
+                **(self._draft_fields(req) if want_drafts else {}),
                 "finish_reason": req.finish_reason,
             }],
             "usage": {
@@ -287,8 +299,18 @@ class InferenceServer:
             "metrics": {"ttft_ms": req.ttft_ms, "latency_ms": latency_ms},
         })
 
+    @staticmethod
+    def _draft_fields(req: Request) -> dict:
+        """``return_draft_tokens``: the draft verified at each generated
+        token's position (-1: none) and whether it stood."""
+        n_gen = len(req.generated_tokens)
+        return {"draft_tokens": req.draft_tokens[:n_gen],
+                "draft_stood": req.draft_stood[:n_gen]}
+
     async def _stream_response(self, http_req: web.Request, req: Request,
-                               token_q: asyncio.Queue) -> web.StreamResponse:
+                               token_q: asyncio.Queue,
+                               want_drafts: bool = False
+                               ) -> web.StreamResponse:
         """Server-sent events (OpenAI `stream: true` wire format): one
         `data: {...}` chunk per decoded token batch, `data: [DONE]` at the
         end. Multi-step decode delivers tokens in bursts of up to K; a
@@ -305,7 +327,7 @@ class InferenceServer:
         })
         await resp.prepare(http_req)
 
-        def chunk(text, finish_reason=None, token_ids=()):
+        def chunk(text, finish_reason=None, token_ids=(), **more):
             # (``token_ids``: the batch's ids beside its text, which drops
             # what the tokenizer cannot render: a caller that sends the
             # reply back as part of its next turn needs the ids)
@@ -314,7 +336,7 @@ class InferenceServer:
                 "model": self.model_cfg.name,
                 "choices": [{"index": 0, "text": text,
                              "token_ids": [int(t) for t in token_ids],
-                             "finish_reason": finish_reason}],
+                             "finish_reason": finish_reason, **more}],
             }) + "\n\n").encode()
 
         # incremental decode against the accumulated token list: batch-
@@ -365,7 +387,9 @@ class InferenceServer:
                 if batch is None:               # request left its slot
                     break
                 await resp.write(chunk(decoder.feed(batch), token_ids=batch))
-            final = chunk(decoder.finish(), req.finish_reason or "error")
+            # (the whole reply's drafts ride the last chunk)
+            final = chunk(decoder.finish(), req.finish_reason or "error",
+                          **(self._draft_fields(req) if want_drafts else {}))
             await resp.write(final)
             await resp.write(b"data: [DONE]\n\n")
         except (ConnectionResetError, asyncio.CancelledError):
@@ -413,6 +437,8 @@ class InferenceServer:
             "swap_ins": st["swap_ins"],
             "prefill_ride_tokens": st["prefill_ride_tokens"],
             "state_carry_tokens": st["state_carry_tokens"],
+            **{k: st[k] for k in ("mtp_drafts", "mtp_accepted",
+                                  "mtp_slot_steps", "mtp_tokens")},
             "swapped_host_bytes": st["swapped_host_bytes"],
         })
 

@@ -47,7 +47,9 @@ from .sampling import sample_tokens
 # from grounded prompt-copying into free generation); the warmup floor
 # keeps one lucky/unlucky first window from whipsawing the window; the
 # grow/shrink thresholds bracket a ~50% acceptance break-even (not
-# measured on the attached chip: no cell speculates).
+# measured on the attached chip: no cell runs n-gram drafts; the cell that
+# speculates drafts with the model's own prediction module, serve/decode.py
+# ``draft_verify_scan``, whose break-even PERF.md has).
 SPEC_EWMA_ALPHA = 0.25
 SPEC_WARMUP_DISPATCHES = 4
 SPEC_GROW_AT = 0.5
@@ -250,8 +252,10 @@ def verify_and_decode(
     per-query prefix streaming), so below some acceptance this still
     trails plain multi-step decode — the engine's adaptive check
     (speculative_min_acceptance) exists for exactly that. Where the
-    crossover lies is not measured on the attached chip: no cell
-    speculates.
+    crossover lies is not measured on the attached chip: no cell runs
+    n-gram drafts (the self-drafting cell's window of two rows over latent
+    pages is ``serve/decode.py draft_verify_scan``, and PERF.md has its
+    break-even acceptance).
 
     Returns a ``DispatchResult`` whose ``sampled`` is (emitted [B, T],
     n_emit [B], decode_seq [R, B]), with the new pools. Host applies

@@ -148,6 +148,12 @@ def _linear_decode_program(one_chip, periods=3, dtype=jnp.bfloat16):
     return compile_
 
 
+# temporaries of the program WITHOUT pieces (the slow case below reads them
+# again; 103 s of compile that the driver's run no longer pays: PR 53)
+LINEAR_PLAIN_TEMP_BYTES = 344_486_400
+
+
+@pytest.mark.slow     # ~100 s: the carrying case below holds every property
 def test_linear_decode_program_moves_no_pool(one_chip, as_tpu):
     """The multi-step decode program at the linear cell's shapes: the latent
     pool (1.5 GB) and both state pools (2.4 GB + 85 MB) ride the carry and
@@ -157,7 +163,7 @@ def test_linear_decode_program_moves_no_pool(one_chip, as_tpu):
     at its exit, outside the step loop: the compiler keeps the slots on the
     lanes inside it, as it computes the 128-row projections.)"""
     _, mem = _linear_decode_program(one_chip)(0)
-    assert mem.temp_size_in_bytes < 512 << 20, (
+    assert mem.temp_size_in_bytes <= LINEAR_PLAIN_TEMP_BYTES < 512 << 20, (
         f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries")
     assert mem.alias_size_in_bytes >= 3.9e9
 
@@ -185,12 +191,11 @@ def test_carrying_linear_decode_program_fits_the_chip(one_chip, as_tpu):
     a slice put the 81 MB pool's 3 columns on the lanes instead: 3.4 GB of
     padding, ``ops/kda.py slot_state``). Both window kernels' scoped VMEM
     is the compile itself."""
-    compile_ = _linear_decode_program(one_chip)
-    (_, plain), (_, carrying) = compile_(0), compile_(LATENT_PS)
+    _, carrying = _linear_decode_program(one_chip)(LATENT_PS)
     assert carrying.alias_size_in_bytes >= 3.9e9
-    assert (carrying.temp_size_in_bytes < plain.temp_size_in_bytes
+    assert (carrying.temp_size_in_bytes < LINEAR_PLAIN_TEMP_BYTES
             + PIECE_ROWS_BYTES + LINEAR_IN_PROJ_BYTES), (
-        plain.temp_size_in_bytes, carrying.temp_size_in_bytes)
+        carrying.temp_size_in_bytes)
 
 
 @pytest.mark.slow     # ~2 min; run it before the smoke's ride phase changes
