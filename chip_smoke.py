@@ -593,6 +593,10 @@ def phase_selfdraft(env: dict) -> None:
         if any(n != rec["new"] for n in rec["identical"]):
             raise SmokeFailure("a self-drafted stream differs from plain "
                                f"greedy decoding: {rec}")
+    if ("window_page_write", "tiles") not in {
+            (op, impl) for op, impl, _ in impl_lines(text)}:
+        raise SmokeFailure("the window of two rows did not stage tiles: no "
+                           "line `impl window_page_write=tiles` in the log")
     if recs["all-stand"]["tokens_per_slot_step"] != 2.0:
         raise SmokeFailure("the two-token branch did not run in every step "
                            f"of the constructed weights: {recs['all-stand']}")

@@ -236,23 +236,41 @@ def write_window_to_pages(
     write_ok: jax.Array = None,  # [B, T] bool
     layer=None,                # int32 scalar: which layer's pages to write
 ) -> jax.Array:
-    """Page-granular window write: what every serve program writes K and V
-    with (``write_token_to_pages`` over B*T rows is its reference).
+    """The window write: what every serve program writes K and V (or a
+    latent row) with (``write_token_to_pages`` over B*T rows is its
+    reference). It stages a part of the pool that holds the window, merges
+    the window's rows in, and scatters the staged part back, by one of two
+    routes chosen from what the call can see (static, reported at trace
+    time as ``window_page_write``):
 
-    A slot's T consecutive tokens span at most n = (T + 2 PS - 2) // PS
-    physical pages (two for a verify window). This gathers those n*B
-    pages out of the pool, merges the window in registers (one-hot select
-    over the n*PS staging positions), and scatters n*B WHOLE pages back —
-    regular page-sized DMAs that leave the pool in the layout the Pallas
-    kernel reads. The B*T-row scatter does not: compiled for the v5e it
-    makes XLA keep the carried pool slot-major and copy it WHOLE to the
-    kernel's layout and back in every layer (3.8 GB of temporaries in the
-    decode program at the benchmark's shapes, PERF.md 6, PR 26), which is
-    why windows longer than a page (suffix and chunked prefill) take this
-    route too. Numerics asserted equal to the scatter in
+    WHOLE PAGES (``_write_window_to_whole_pages``: T == 1, T > 16, and
+    every window over ``QuantPages`` / ``Int4Pages``). A slot's T
+    consecutive tokens span at most n = (T + 2 PS - 2) // PS physical
+    pages. This gathers those n*B pages out of the pool, merges the window
+    in registers (a float32 one-hot select over the n*PS staging
+    positions), and scatters n*B WHOLE pages back: regular page-sized DMAs
+    that leave the pool in the layout the Pallas kernel reads. The
+    B*T-row scatter does not: compiled for the v5e it makes XLA keep the
+    carried pool slot-major and copy it WHOLE to the kernel's layout and
+    back in every layer (3.8 GB of temporaries in the decode program at
+    the benchmark's shapes, PERF.md 6, PR 26), which is why windows longer
+    than a page (suffix and chunked prefill) take this route too.
+
+    TILES (``_write_window_to_tiles``: 1 < T <= 16 over a full-precision
+    pool whose pages are whole sublane tiles). What PR 26 found holds for
+    ROWS; a sublane tile (16 rows of bfloat16, 8 of float32) is the unit
+    the pool's device layout is made of, so the page axis splits into
+    tiles by a reshape that moves no byte, and gathering and scattering
+    the tiles a window touches leaves the pool where it stands (compiled
+    for the v5e: tests/test_tpu_compile_selfdraft.py). A draft-and-verify
+    step's window of two rows staged two pages of 256 rows a slot and layer
+    and merged them through float32, 4.3 ms a step for 1.5 MB of rows; by
+    tiles the same write is 0.4 ms (PERF.md 6, PR 54).
+
+    Both routes are asserted equal to the scatter, bit for bit, in
     tests/test_ops.py::test_window_write_matches_row_scatter.
 
-    ``QuantPages`` take the SAME whole-page route with a fused
+    ``QuantPages`` take the whole-page route with a fused
     quantize-on-write: the window's rows are absmax-quantized once
     ([B, T, Nkv] int8 rows + scales), then values AND scales merge
     through one shared one-hot select and scatter back as whole
@@ -268,6 +286,22 @@ def write_window_to_pages(
     keep their staging content / write scratch page 0, matching the
     scatter path's semantics.
     """
+    T = new_kv.shape[1]
+    rows_a_tile = _window_tile_rows(pages, T)
+    report_impl("window_page_write", "tiles" if rows_a_tile else "pages",
+                f"T={T} over {pages.dtype}{tuple(pages.shape)}")
+    if rows_a_tile:
+        return _write_window_to_tiles(pages, new_kv, block_tables,
+                                      start_positions, write_ok, layer,
+                                      rows_a_tile)
+    return _write_window_to_whole_pages(pages, new_kv, block_tables,
+                                        start_positions, write_ok, layer)
+
+
+def _write_window_to_whole_pages(pages, new_kv, block_tables,
+                                 start_positions, write_ok, layer):
+    """``write_window_to_pages`` by whole pages: n = (T + 2 PS - 2) // PS
+    pages a slot gathered, merged by a one-hot select, scattered back."""
     int4 = isinstance(pages, Int4Pages)
     quant = isinstance(pages, QuantPages)
     B, T, Nkv, D = new_kv.shape
@@ -349,6 +383,66 @@ def write_window_to_pages(
                           pages.scale.at[back].set(merged_s))
     merged = merge_rows(pages[stage], new_kv.astype(pages.dtype), pages.dtype)
     return pages.at[back].set(merged)
+
+
+_MAX_TILE_WINDOW = 16     # rows of the longest window that stages tiles
+
+
+def _window_tile_rows(pages, T: int) -> int:
+    """Rows of the sublane tile a window of ``T`` rows stages instead of
+    whole pages (16 of bfloat16, 8 of float32), or 0 where the window takes
+    the whole-page route: one row, more than ``_MAX_TILE_WINDOW``,
+    quantized pages, or a page that is not whole tiles."""
+    if isinstance(pages, QuantPages) or not 1 < T <= _MAX_TILE_WINDOW:
+        return 0
+    rows = 32 // jnp.dtype(pages.dtype).itemsize
+    return rows if pages.shape[-2] % rows == 0 else 0
+
+
+def _write_window_to_tiles(pages, new_kv, block_tables, start_positions,
+                           write_ok, layer, R: int):
+    """``write_window_to_pages`` for a short window over a full-precision
+    pool: the page axis is viewed as ``PS / R`` tiles of ``R`` rows (a
+    reshape on a tile boundary: no byte moves), the n = (T + 2 R - 2) // R
+    tiles a slot's window can touch (two for T <= R; the second may be the
+    next page's first) are gathered, the window's rows selected in on the
+    pool's own dtype, and the tiles scattered back. A staged tile that
+    takes no row (the window inside one tile, a tile past the table's last
+    page, masked rows, a slot over scratch) goes to scratch page 0."""
+    B, T, Nkv, D = new_kv.shape
+    PS = pages.shape[-2]
+    maxP = block_tables.shape[1]
+    tpp = PS // R                                   # tiles a page
+    n = (T + 2 * R - 2) // R
+    g0 = jnp.clip(start_positions // R, 0, maxP * tpp - 1)    # [B]
+    g = g0[:, None] + jnp.arange(n, dtype=jnp.int32)          # [B, n]
+    phys = jnp.take_along_axis(
+        block_tables, jnp.clip(g // tpp, 0, maxP - 1), axis=1)
+    phys = jnp.where(g < maxP * tpp, phys, 0)                 # [B, n]
+    # row j of the window lands at staging row ``rel`` of the n * R (a
+    # row before position 0 or past the table's end at none of them)
+    rel = (start_positions - g0 * R)[:, None] + jnp.arange(
+        T, dtype=jnp.int32)                                   # [B, T]
+    ok = jnp.take_along_axis(
+        phys, jnp.clip(rel // R, 0, n - 1), axis=1) != 0
+    if write_ok is not None:
+        ok = ok & write_ok
+    hit = (rel[:, :, None] == jnp.arange(n * R, dtype=jnp.int32)
+           ) & ok[:, :, None]                                 # [B, T, n R]
+    used = hit.any(axis=1).reshape(B, n, R).any(axis=2)       # [B, n]
+    phys = jnp.where(used, phys, 0)
+    tile = jnp.where(used, g % tpp, 0)
+    tiles = pages.reshape(*pages.shape[:-2], tpp, R, D)
+    at = _at(layer, phys, slice(None), tile)      # -> [B, n, Nkv, R, D]
+    staged = tiles[at]
+    rows = new_kv.astype(pages.dtype)
+    hit = hit.reshape(B, T, n, R)
+    for j in range(T):      # a staged row takes at most one of the T rows
+        staged = jax.lax.select(
+            jax.lax.broadcast_in_dim(hit[:, j], staged.shape, (0, 1, 3)),
+            jax.lax.broadcast_in_dim(rows[:, j], staged.shape, (0, 2, 4)),
+            staged)
+    return tiles.at[at].set(staged).reshape(pages.shape)
 
 
 def paged_attention_multi(

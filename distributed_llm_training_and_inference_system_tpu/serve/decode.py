@@ -114,11 +114,12 @@ def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl, block=0):
     """Write each slot's window of K and V into its pages at layer ``li``
     and attend the window over them: (out, (new k_pages, new v_pages)).
     ``block`` > 0: under the block rule (``ModelConfig.attention_block``)."""
-    # K and V live in pages. Every T takes the whole-page merge
-    # (T == 1: one page a slot), QuantPages and Int4Pages with
-    # quantize-on-write fused into it: a row scatter lays the pool
-    # out slot-major, the Pallas kernel reads it head-major, and
-    # the WHOLE pool is copied between the two in every layer
+    # K and V live in pages. Every T stages a part of the pool and
+    # merges the window in: whole pages (T == 1: one page a slot),
+    # QuantPages and Int4Pages with quantize-on-write fused into it,
+    # or the sublane tiles a short window touches. A ROW scatter lays
+    # the pool out slot-major, the Pallas kernel reads it head-major,
+    # and the WHOLE pool is copied between the two in every layer
     # (PERF.md 6, PR 26, has both step times)
     with jax.named_scope("kv_page_write"):
         new_k = write_window_to_pages(kp, k, tables, starts, ok, li)
@@ -618,9 +619,9 @@ def attend_latent_pages(cfg: ModelConfig, pool: jax.Array, li,
                         ride: Any = None, two_bodies: bool = False):
     """The latent ``attend`` (``layers.latent_attention_mixer``) over the ONE
     latent pool ``pool`` [La, NP, 1, PS, W], written and read at layer
-    ``li``: the window's rows go in by the whole-page merge every window
-    takes, then every head's absorbed query walks the slot's live pages
-    once. With ``ride`` the B slots' rows go first, as ever, then the
+    ``li``: the window's rows go in by ``write_window_to_pages`` as every
+    window's do, then every head's absorbed query walks the slot's live
+    pages once. With ``ride`` the B slots' rows go first, as ever, then the
     piece's C rows as ONE window over its own slot's pages (the multi-query
     kernel, what a suffix prefill's program runs). The state it returns is
     (the pool, None): there is no second pool."""

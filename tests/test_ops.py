@@ -340,37 +340,76 @@ def test_paged_attention_multi_window_is_causal():
     assert not np.allclose(np.asarray(out1[:, 3]), np.asarray(out2[:, 3]))
 
 
-def test_window_write_matches_row_scatter():
-    """write_window_to_pages (page-granular, 2 whole pages per slot) must
-    be elementwise identical to the B*T row-scatter path, including page-
-    boundary crossings, masked rows, scratch-table slots, and the
-    window-entirely-in-last-page duplicate edge (round 3)."""
-    import numpy as np
+@pytest.mark.parametrize("T", [1, 2, 6, 8, 16, 17])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("layout", ["kv", "latent"])
+def test_window_write_matches_row_scatter(layout, dtype, T, monkeypatch):
+    """write_window_to_pages must be elementwise identical to the B*T
+    row-scatter path on both of its routes: a short window (1 < T <= 16)
+    over a full-precision pool stages the sublane tiles it touches (16 rows
+    of bfloat16, 8 of float32: two tiles, three for 16 rows of float32),
+    every other window whole pages (T = 1 and T = 17 here), and the route
+    is the one ``report_impl``'s line names. K/V pages [NP, Nkv, PS, D]
+    and the latent pool [L, NP, 1, PS, W] written at a traced layer;
+    windows inside one tile, across a tile's end, across a page's end,
+    ending with the table's last page (the staged tile past it is clipped
+    to scratch), running past the table, into a table entry of 0, starting
+    before position 0 (a diffusion slot's first window), a scratch slot,
+    and masked rows."""
+    from distributed_llm_training_and_inference_system_tpu.ops import (
+        paged_attention as pa)
+    seen = []
+    monkeypatch.setattr(pa, "report_impl", lambda *line: seen.append(line))
+    rng = np.random.default_rng(T)
+    dtype = jnp.dtype(dtype)
+    R = 32 // dtype.itemsize
+    NP, PS, maxP, L = 22, 32, 3, 3
+    Nkv, D = (2, 8) if layout == "kv" else (1, 24)
+    shape = (NP, Nkv, PS, D) if layout == "kv" else (L, NP, Nkv, PS, D)
+    layer = None if layout == "kv" else jnp.int32(1)
+    pages0 = jnp.asarray(rng.normal(size=shape), dtype)
+    tables = jnp.asarray([[1, 2, 3],        # inside one tile (T <= R)
+                          [4, 5, 6],        # across a tile's end
+                          [7, 8, 9],        # across a page's end
+                          [10, 11, 12],     # ends with the last page
+                          [13, 14, 15],     # runs past the table
+                          [16, 17, 0],      # runs into an entry of 0
+                          [0, 0, 0],        # a scratch slot
+                          [18, 19, 0],      # all rows masked
+                          [20, 21, 0]], jnp.int32)      # starts before 0
+    starts = jnp.asarray([PS + R, R - 1, PS - 1, maxP * PS - T,
+                          maxP * PS - 1, 2 * PS - 1, 5, 3, -3], jnp.int32)
+    B = len(starts)
+    new_kv = jnp.asarray(rng.normal(size=(B, T, Nkv, D)), dtype)
+    pos = starts[:, None] + jnp.arange(T)
+    # (the row scatter clips a row past the table INTO its last page and
+    # wraps one before position 0 into its first: mask them)
+    ok = jnp.asarray(rng.random((B, T)) > 0.25).at[:4].set(True).at[
+        :, 0].set(True).at[7].set(False) & (pos < maxP * PS) & (pos >= 0)
 
-    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (  # noqa: E501
-        write_token_to_pages, write_window_to_pages)
-    rng = np.random.default_rng(0)
-    NP, Nkv, PS, D, B, T = 12, 2, 8, 4, 4, 6
-    maxP = 3
-    pages0 = jnp.asarray(rng.normal(size=(NP, Nkv, PS, D)), jnp.float32)
-    new_kv = jnp.asarray(rng.normal(size=(B, T, Nkv, D)), jnp.float32)
-    tables = jnp.asarray([[1, 2, 3],      # normal slot
-                          [4, 5, 0],      # short chain
-                          [0, 0, 0],      # inactive (scratch)
-                          [6, 7, 8]], jnp.int32)
-    # starts: mid-page (crosses boundary), page-aligned, zero,
-    # last-page interior (duplicate-page edge: 2*8+1=17, window ends at 22
-    # inside logical page 2 = the final table entry)
-    starts = jnp.asarray([5, 8, 0, 17], jnp.int32)
-    ok = jnp.asarray(rng.random((B, T)) > 0.3)
-
-    flat_pos = (starts[:, None] + jnp.arange(T)).reshape(-1)
-    flat_tab = jnp.repeat(tables, T, axis=0)
-    want = write_token_to_pages(pages0, new_kv.reshape(B * T, Nkv, D),
-                                flat_tab, flat_pos, ok.reshape(-1))
-    got = write_window_to_pages(pages0, new_kv, tables, starts, ok)
-    # scratch page 0 is garbage by contract on both paths — compare the rest
-    np.testing.assert_array_equal(np.asarray(want)[1:], np.asarray(got)[1:])
+    @jax.jit
+    def both(pages, layer):
+        return (pa.write_token_to_pages(
+                    pages, new_kv.reshape(B * T, Nkv, D),
+                    jnp.repeat(tables, T, axis=0), pos.reshape(-1),
+                    ok.reshape(-1), layer),
+                pa.write_window_to_pages(pages, new_kv, tables, starts, ok,
+                                         layer))
+    want, got = both(pages0, layer)
+    route, = {impl for op, impl, _ in seen if op == "window_page_write"}
+    assert route == ("tiles" if 1 < T <= 16 else "pages")
+    assert got.dtype == pages0.dtype and got.shape == pages0.shape
+    if layer is not None:
+        np.testing.assert_array_equal(np.asarray(got[::2], np.float32),
+                                      np.asarray(pages0[::2], np.float32))
+        want, got, pages0 = want[1], got[1], pages0[1]
+    # scratch page 0 is garbage by contract on both paths: compare the rest
+    np.testing.assert_array_equal(np.asarray(want, np.float32)[1:],
+                                  np.asarray(got, np.float32)[1:])
+    wrote = (np.asarray(got, np.float32) != np.asarray(pages0, np.float32)
+             ).any(axis=(1, 2, 3))
+    assert wrote[[2, 4, 7, 12, 15, 17]].all() and not wrote[[18, 19]].any()
+    assert wrote[8] == (T > 1) and wrote[20] == (T > 3) and not wrote[21]
 
 
 def _layered_pool(kv, key, L=3, NP=12, Nkv=2, PS=16, D=64):

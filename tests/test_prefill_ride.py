@@ -61,6 +61,23 @@ def _short_kda_chunks():
     kda.CHUNK = plain
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _a_piece_writes_whole_pages():
+    """A cell's piece is 128 to 256 rows and its write stages whole pages;
+    a window of at most 16 rows stages sublane tiles (PR 54:
+    ``ops/paged_attention.py write_window_to_pages``). This module's piece
+    is ``C`` = 16 rows: keep it on the route every cell's piece takes, so
+    that the programs pinned below are the cells' programs at a small size
+    (the diffusion model's window of 8 rows stages tiles, here as in its
+    cell)."""
+    from distributed_llm_training_and_inference_system_tpu.ops import (
+        paged_attention)
+    plain = paged_attention._MAX_TILE_WINDOW
+    paged_attention._MAX_TILE_WINDOW = C - 1
+    yield
+    paged_attention._MAX_TILE_WINDOW = plain
+
+
 def _tokens(n):
     return [int(t) for t in RNG.integers(1, 250, n)]
 
@@ -547,9 +564,11 @@ PARENTS_DECODE = {
     "olmoe-test": "55e215fc630808589c68ad79c8c7e3d3d2b79fb33059922235717bceadeb5c19",
     "xing-test": "d64f8944fe2118229021681b196c58cd7db4511bed933866e5c2fd8a824d25da",
     # (PR 47 MEANT to move this one: the denoise window is two blocks and
-    # the commit rides the next block's first forward; the hash is of PR
-    # 47's own tree, the parent's was aeb0b472...ffa7b025)
-    "sdar-test": "584661dc89729bd790be67306274f83a07b1e2bc17e713a9e2581f4ed277e604",
+    # the commit rides the next block's first forward; PR 54 MEANT to move
+    # it again: the window of 8 rows stages the sublane tiles it touches,
+    # not two whole pages a slot. The hash is of PR 54's own tree; the
+    # parent's (0fe0c16, PR 47's text) was 584661dc...d277e604)
+    "sdar-test": "9c5ed40f7256ea0a2a98d2ed4b09d28d38910ef29381eab13627846fa7be5c7c",
     LINEAR: "ca8bac7665a0d8b5f1011e2dcd5c2ed45b6ca30666c9e286dbf4477101b24b26",
     HYBRID: "74ad821e06394a100e91d3e4d063dc2463f98e81ca46099914c6db821b82677f",
 }
@@ -559,7 +578,7 @@ PARENTS_DECODE = {
 def test_the_other_models_decode_programs_are_the_parents(name):
     """The RIDING decode programs of the uniform stack (dense, MoE), of the
     latent, the delta-rule and the hybrid table lower to the parent's text,
-    and the diffusion model's denoise program to PR 47's (PR 43 and 44 taught the
+    and the diffusion model's denoise program to PR 54's (PR 43 and 44 taught the
     table walk a recurrent layer's piece by one seam, ``recur_at``; PR 45
     made what a step and a dispatch return a record)."""
     eng = _engine(name)

@@ -37,6 +37,7 @@ from tpu_compile_support import (
     PS,
     MAXP,
     _compile,
+    _no_copy_of,
     _sds,
 )
 
@@ -165,6 +166,13 @@ def test_diffusion_decode_program_fits_the_chip(one_chip, as_tpu):
     copies = [line.strip()[:160] for line in text.splitlines()
               if re.search(rf"= bf16\[{shape}\]\S* copy\(", line)]
     assert not copies, copies
+    _no_copy_of(text, [f"bf16[{shape}]", f"bf16[{shape.split(',', 1)[1]}]"])
+    # the window of two blocks (8 rows) stages the two 16-row tiles it can
+    # touch of K and of V, merged in bfloat16: not two whole pages a slot
+    # through float32 (PR 54)
+    assert f"bf16[{B},2,{cfg.num_kv_heads},16,{D}]" in text
+    assert f"f32[{B},{2 * PS},{cfg.num_kv_heads},{D}]" not in text
+    assert f"[{B},2,{cfg.num_kv_heads},{PS},{D}]" not in text
     mem = compiled.memory_analysis()
     # (the temporaries are the head's and the sampler's: [256, 151936]
     # float32 logits are 156 MB, and the sampling branches that a greedy
