@@ -605,6 +605,92 @@ def phase_selfdraft(env: dict) -> None:
                            f"{recs['seeded']}")
 
 
+def phase_shortconv(env: dict) -> None:
+    """LFM2-8B-A1B at its published widths and a tiny depth (the table's
+    first period ``c c a c``: both dense layers, two of 32 experts' layers)
+    through ``cli.main serve start``: cold prompts, decode, and a prompt
+    that rides two residents' decode steps. Heads of 64 in PAIRS on the
+    pool's 128 lanes: fails if any attention program of the server reports
+    ``gather`` on the chip (``report_impls``)."""
+    say("== shortconv: cli.main serve start --model <lfm2, 4 layers> ==")
+    config = json.loads((ROOT / "benchmark" / "configs"
+                         / "lfm2-8b-a1b-16l.json").read_text())
+    depth = 4
+    model = {k: v for k, v in config.items() if not isinstance(v, dict)
+             and k not in ("assumed", "deployment", "serve_why", "source")}
+    if REHEARSAL:       # the CPU rehearses the control flow at a small width
+        model.update(hidden_size=256, intermediate_size=192,
+                     moe_intermediate_size=128, num_attention_heads=4,
+                     num_key_value_heads=2, num_experts=8,
+                     num_experts_per_tok=2, vocab_size=512)
+    model.update(name="lfm2-smoke", num_hidden_layers=depth,
+                 layer_types=config["layer_types"][:depth])
+    SCRATCH.mkdir(parents=True, exist_ok=True)
+    path = SCRATCH / "lfm2-smoke.json"
+    path.write_text(json.dumps(model))
+    vocab = model["vocab_size"]
+    # (the residents' replies are long enough that the third prompt finds
+    # them decoding: ~3 s of steps on the chip)
+    page, n_long, n_new = (16, 40, 64) if REHEARSAL else (256, 700, 600)
+    srv = Server("shortconv", ["--model", str(path), "--max-batch-size", "4",
+                               "--max-seq-len", str(8 * page),
+                               "--kv-block-size", str(page)], env)
+    try:
+        ready = srv.wait_ready(900)
+        dev = device_from_log(srv.text())
+        say(f"  server ready after {ready:.1f}s; it holds {dev}")
+        DEVICE.update(dev)
+        a = srv.complete(prompt_tokens(31, n_long, vocab), 8)
+        say(f"  (a) {n_long}-token prompt, 8 new: {a['_seconds']:.2f}s "
+            "(first request: includes compiles)")
+        # two residents decode (half the slots), a third prompt rides them
+        results: list = [None] * 3
+        errors: list = []
+
+        steps0 = srv.health()["engine"]["decode_steps"]
+
+        def one(i, behind):
+            try:
+                # the third prompt is sent once the residents decode
+                while behind and (srv.health()["engine"]["decode_steps"]
+                                  < steps0 + 8):
+                    time.sleep(0.05)
+                results[i] = srv.complete(
+                    prompt_tokens(40 + i, n_long + 8 * i, vocab), n_new)
+            except Exception as e:   # surfaced below, in the main thread
+                errors.append(e)
+        threads = [threading.Thread(target=one, args=(i, i == 2))
+                   for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        if errors:
+            raise errors[0]
+        eng = srv.health()["engine"]
+        say(f"  (b) two residents and a third prompt behind them: "
+            f"{eng['prefill_ride_tokens']} prompt tokens rode decode steps; "
+            f"conv pool {eng['shortconv']['state_bytes'] / 1e6:.2f} MB, "
+            f"{eng['shortconv']['slot_steps']} slot-steps")
+        if eng["prefill_ride_tokens"] < 1:
+            raise SmokeFailure("the third prompt did not ride the residents' "
+                               "decode steps: the piece's window kernel and "
+                               "conv did not run")
+        again = srv.complete(prompt_tokens(31, n_long, vocab), 8)
+        if (again["choices"][0]["token_ids"]
+                != a["choices"][0]["token_ids"]):
+            raise SmokeFailure("(a) again, in a reused slot: greedy tokens "
+                               "differ (a window a former occupant left?)")
+        h = srv.health()
+        if (h["_status"] != 200 or h["engine_error_count"] != 0
+                or h["last_engine_error"] is not None):
+            raise SmokeFailure(f"the engine reported errors: see {srv.log}")
+    finally:
+        srv.stop()
+    report_impls("shortconv", srv.text(), ("paged_attention",),
+                 dev["platform"] == "tpu")
+
+
 _STEP_RE = re.compile(r"step (\d+) \| loss ([0-9.naninf-]+) \|.*?"
                       r"\| ([0-9.]+) tok/s")
 
@@ -2186,6 +2272,9 @@ def main() -> int:
                     help="the parent commit unpacked beside this tree (git "
                          "archive): the serve phase also holds this tree's "
                          "greedy and seeded tokens equal to that one's")
+    ap.add_argument("--only", choices=("shortconv",),
+                    help="this phase of the one-chip smoke alone (a server "
+                         "of its own: ~3 min)")
     args = ap.parse_args()
 
     ok = False
@@ -2202,12 +2291,15 @@ def main() -> int:
         if REHEARSAL:
             say("REHEARSAL: gpt-test sizes; this run cannot end ok")
         t0 = time.monotonic()
-        if args.chips == 1:
+        if args.only:
+            phase_shortconv(env)
+        elif args.chips == 1:
             device = phase_kernels(env)
             phase_serve(env, device)
             phase_seeded_replies(env, args.parent)
             phase_ride(env)
             phase_selfdraft(env)
+            phase_shortconv(env)
             phase_train(env, device)
             phase_launcher(env, device)
         else:

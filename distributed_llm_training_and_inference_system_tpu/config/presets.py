@@ -427,6 +427,49 @@ FALCON_H1_TEST_PUBLISHED = {
 TEST_TEMPLATES["falcon-h1-test"] = ModelConfig.from_published(
     FALCON_H1_TEST_PUBLISHED)
 
+# LFM2-8B-A1B as published (``model_type: lfm2_moe``): 24 decoder layers, 18
+# of them a gated short convolution (``conv_L_cache`` 3 taps; the ``C``
+# letter) and 6 grouped-query attention (32 / 8 heads of 64 = hidden /
+# heads: the file has no ``head_dim``; a norm over each head's q and k;
+# rope base 1e6), 2 leading dense MLPs of 7,168 and then 32 sigmoid experts
+# of 1,792, 4 a token, picked by score + ``expert_bias``. Keys that are
+# read: ``layer_types``, ``num_dense_layers``, ``conv_L_cache``,
+# ``conv_bias`` (false alone), ``use_expert_bias``, ``norm_topk_prob``,
+# ``routed_scaling_factor``, ``norm_eps`` and the usual sizes. The family
+# ties its embeddings (the published config's key; stated here)
+LFM2_8B_A1B_PUBLISHED = {
+    "name": "lfm2-8b-a1b", "model_type": "lfm2_moe",
+    "conv_L_cache": 3, "conv_bias": False, "hidden_size": 2048,
+    "intermediate_size": 7168,
+    "layer_types": (["conv", "conv", "full_attention", "conv"] * 5
+                    + ["conv", "full_attention", "conv", "conv"]),
+    "max_position_embeddings": 128000, "moe_intermediate_size": 1792,
+    "norm_eps": 1e-5, "norm_topk_prob": True, "num_attention_heads": 32,
+    "num_dense_layers": 2, "num_experts": 32, "num_experts_per_tok": 4,
+    "num_hidden_layers": 24, "num_key_value_heads": 8,
+    "rope_theta": 1000000, "routed_scaling_factor": 1,
+    "use_expert_bias": True, "vocab_size": 65536,
+    "tie_word_embeddings": True,
+}
+MODEL_TEMPLATES["lfm2-8b-a1b"] = ModelConfig.from_published(
+    LFM2_8B_A1B_PUBLISHED)
+
+# ... and its shape in small, in the same keys (the plain reference of the
+# benchmark reads these): six decoder layers ``c c a c c a`` with the
+# ``CDCD`` head (two leading dense layers), heads of 64 (4 / 2: a PAIR of
+# KV heads on the 128 lanes), 8 experts, 2 a token
+LFM2_TEST_PUBLISHED = {
+    **LFM2_8B_A1B_PUBLISHED,
+    "name": "lfm2-test", "hidden_size": 256, "num_hidden_layers": 6,
+    "layer_types": ["conv", "conv", "full_attention", "conv", "conv",
+                    "full_attention"],
+    "num_attention_heads": 4, "num_key_value_heads": 2,
+    "intermediate_size": 192, "moe_intermediate_size": 128,
+    "num_experts": 8, "num_experts_per_tok": 2, "vocab_size": 320,
+    "max_position_embeddings": 512, "dtype": "float32",
+}
+TEST_TEMPLATES["lfm2-test"] = ModelConfig.from_published(LFM2_TEST_PUBLISHED)
+
 
 def get_model_config(name: str) -> ModelConfig:
     """Look up a template by name (also accepts test templates), or read a

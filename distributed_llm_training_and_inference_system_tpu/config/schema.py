@@ -207,8 +207,11 @@ class MoEConfig:
             return cls()
         # ``kimi_linear`` spells the same router ``moe_router_activation_func``
         # / ``moe_renormalize`` / ``num_experts_per_token`` /
-        # ``num_shared_experts``
-        routed = ("n_routed_experts" in d or str(d.get(
+        # ``num_shared_experts``; ``lfm2_moe`` has no key for the score (its
+        # ``use_expert_bias`` and ``routed_scaling_factor`` are the sigmoid
+        # lineage's) and states the bias by ``use_expert_bias``
+        lfm2 = d.get("model_type") == "lfm2_moe"
+        routed = lfm2 or ("n_routed_experts" in d or str(d.get(
             "moe_router_activation_func", "")) == "sigmoid")
         shared = int(_take(d, "n_shared_experts", "num_shared_experts",
                            default=0) or 0)
@@ -220,7 +223,8 @@ class MoEConfig:
             router_score=str(_take(d, "router_score", default=(
                 "sigmoid" if routed else "softmax"))),
             selection_bias=_parse_bool("selection_bias", _take(
-                d, "selection_bias", default=routed)),
+                d, "selection_bias", default=(
+                    d.get("use_expert_bias", False) if lfm2 else routed))),
             routed_scaling_factor=float(_take(
                 d, "routed_scaling_factor", default=1.0)),
             # (``n_shared_experts`` alone: each is one routed expert wide)
@@ -460,8 +464,11 @@ class MupConfig:
 # ``P`` (this repo's letter): attention AND a Mamba-2 mixer side by side
 # under ONE norm (``falcon_h1``): both read the same normed stream, their
 # outputs are summed, and the layer keeps K/V pages and a recurrent state
+# ``C`` (this repo's letter): a gated short-convolution mixer (``lfm2_moe``):
+# a depthwise causal conv of ``shortconv_kernel`` taps between two gates,
+# whose whole state is the ``shortconv_kernel - 1`` rows before the window
 LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "moe", "D": "mlp", "K": "kda",
-               "P": "par"}
+               "P": "par", "C": "conv"}
 
 
 @dataclass
@@ -508,6 +515,9 @@ class ModelConfig:
     layer_pattern: str = ""
     ssm: SSMConfig = field(default_factory=SSMConfig)
     kda: KDAConfig = field(default_factory=KDAConfig)
+    # taps of a ``C`` layer's depthwise causal conv (``conv_L_cache``): a
+    # slot keeps the ``shortconv_kernel - 1`` rows before its next token
+    shortconv_kernel: int = 3
     # "rope" | "none": ``nemotron_h``'s attention applies no position
     # embedding (positions come from the state-space layers), and
     # ``kimi_linear``'s latent attention carries and scores its ``pe``
@@ -616,16 +626,29 @@ class ModelConfig:
         return self.layers_of("K")
 
     @property
+    def conv_layers(self) -> int:
+        """Layers that keep a short-convolution window a slot (``C``)."""
+        return self.layers_of("C")
+
+    @property
+    def recurrent_kind(self) -> str:
+        """The letter of this model's recurrent layers (a table has one
+        kind: ``K``, ``C``, or ``M`` / ``P``), "" without any."""
+        return ("K" if self.kda_layers else "C" if self.conv_layers
+                else "M" if self.ssm_layers else "")
+
+    @property
     def recurrent_name(self) -> str:
         """What a refusal calls this model's recurrent layers."""
-        return ("delta-rule linear-attention (K) layers" if self.kda_layers
-                else "state-space layers")
+        return {"K": "delta-rule linear-attention (K) layers",
+                "C": "gated short-convolution (C) layers"}.get(
+                    self.recurrent_kind, "state-space layers")
 
     @property
     def is_recurrent(self) -> bool:
         """Some layer keeps a fixed-size state a sequence beside (or in
         place of) K/V or latent pages."""
-        return self.ssm_layers > 0 or self.kda_layers > 0
+        return bool(self.recurrent_kind)
 
     def validate(self) -> None:
         # hidden_size need not equal num_heads*head_dim (projections go
@@ -651,7 +674,8 @@ class ModelConfig:
                     f"layer_pattern {self.layer_pattern!r}: no layer kind "
                     f"{unknown} (known: M state-space, * attention, E "
                     "experts, D dense MLP, K delta-rule linear attention, "
-                    "P attention and state-space side by side)")
+                    "P attention and state-space side by side, C gated "
+                    "short convolution)")
             if len(self.layer_pattern) != self.num_layers:
                 raise ConfigError(
                     f"layer_pattern has {len(self.layer_pattern)} layers, "
@@ -667,11 +691,21 @@ class ModelConfig:
                     "layer_pattern has M or P layers: ssm.num_heads must be "
                     "a positive multiple of ssm.n_groups, conv_kernel >= 2 "
                     f"(got {s})")
-            if self.ssm_layers and self.layers_of("K"):
+            kinds = [k for k, n in (("M", self.ssm_layers),
+                                    ("K", self.kda_layers),
+                                    ("C", self.conv_layers)) if n]
+            if len(kinds) > 1:
                 raise ConfigError(
-                    "layer_pattern has M and K layers (a P layer holds an M "
-                    "mixer): one recurrent kind a model (the state pools "
-                    "hold one kind's rows)")
+                    f"layer_pattern has {' and '.join(kinds)} layers (a P "
+                    "layer holds an M mixer): one recurrent kind a model "
+                    "(the state pools hold one kind's rows)")
+            if self.conv_layers and (
+                    self.shortconv_kernel < 2 or self.is_latent
+                    or self.hc_mult > 1):
+                raise ConfigError(
+                    "layer_pattern has C layers: shortconv_kernel must be "
+                    f">= 2 (got {self.shortconv_kernel}), beside K/V pages "
+                    "and over one residual stream")
             if self.layers_of("P") and (
                     self.layers_of("*") or self.layers_of("M")
                     or self.is_latent):
@@ -799,7 +833,11 @@ class ModelConfig:
                 "K": h * kd.in_proj_size + kd.conv_kernel * kd.conv_channels
                 + 2 * kd.head_dim * kd.inner_size + kd.num_heads
                 + kd.inner_size + kd.head_dim + kd.inner_size * h,
+                # [B | C | u] in, the taps, out
+                "C": 3 * h * h + self.shortconv_kernel * h + h * h,
             }
+            if self.qk_norm == "head":
+                mixer["*"] += 2 * self.head_dim
             # a hyper-connection a sub-layer: the maps' norm, phi, three
             # scalars, two bias vectors and a bias matrix
             nc, k = self.hc_mult * h, self.hc_mult
@@ -912,6 +950,29 @@ class ModelConfig:
                         "the MLP)")
             pattern = "PD" * layers
             layers = len(pattern)
+        lfm2 = d.get("model_type") == "lfm2_moe"
+        if lfm2 and not pattern:
+            # ``lfm2_moe`` names each decoder layer's mixer in ``layer_types``
+            # (``conv``: the gated short convolution, ``C``;
+            # ``full_attention``: ``*``); the first ``num_dense_layers``
+            # feed-forwards are dense MLPs, the rest experts. A decoder
+            # layer is a mixer entry then a feed-forward entry, as above
+            letters = {"conv": "C", "full_attention": "*"}
+            types = list(d.get("layer_types") or [])
+            unknown = sorted(set(types) - set(letters))
+            if unknown or len(types) != layers:
+                raise ConfigError(
+                    f"layer_types must name each of the {layers} decoder "
+                    f"layers conv or full_attention (got {len(types)} "
+                    f"entries, unknown {unknown})")
+            if _parse_bool("conv_bias", d.get("conv_bias", False)):
+                raise ConfigError(
+                    "conv_bias = true: the C layer's conv and its two "
+                    "projections are carried without a bias")
+            dense = int(_take(d, "num_dense_layers", default=0))
+            pattern = "".join(letters[t] + ("D" if i < dense else "E")
+                              for i, t in enumerate(types))
+            layers = len(pattern)
         for key in ("n_group", "topk_group", "num_expert_group"):
             if latent and int(d.get(key, 1)) != 1:
                 raise ConfigError(
@@ -977,19 +1038,23 @@ class ModelConfig:
             # (its ``intermediate_size`` is then ONE expert's width)
             moe=MoEConfig.from_dict(d.get("moe") or d),
             # (``sdar_moe``'s config.json has no key for its per-head norms)
+            # (nor has ``lfm2_moe``'s for its own)
             qk_norm=str(_take(d, "qk_norm",
-                              default="head" if sdar else "none")),
+                              default="head" if sdar or lfm2 else "none")),
             diffusion=DiffusionConfig.from_dict(d.get("diffusion") or d,
                                                 published=sdar),
             layer_pattern=pattern,
             ssm=SSMConfig.from_dict(d.get("ssm"), published=d),
             kda=KDAConfig.from_dict(d.get("kda") or linear),
+            shortconv_kernel=int(_take(d, "shortconv_kernel", "conv_L_cache",
+                                       default=3)),
             mla=mla,
             # (a leading dense layer's width: ``intermediate_size`` beside
             # ``moe_intermediate_size`` where the file has such layers)
             dense_ffn_size=int(_take(d, "dense_ffn_size", default=(
                 d.get("intermediate_size", 0)
-                if d.get("first_k_dense_replace") else 0))),
+                if d.get("first_k_dense_replace")
+                or d.get("num_dense_layers") else 0))),
             hc_mult=int(_take(d, "hc_mult", default=1)),
             hc_sinkhorn_iters=int(_take(d, "hc_sinkhorn_iters", default=20)),
             hc_eps=float(_take(d, "hc_eps", default=1e-6)),
@@ -1024,7 +1089,8 @@ class ModelConfig:
         d["rope"] = {"base": config.get("rope_theta", 10000.0)}
         for group in ("rope_scaling", "linear_attn_config",
                       "mlp_only_layers", "gqa_layers", "ssm_multipliers",
-                      "mlp_multipliers", "attn_layer_indices"):
+                      "mlp_multipliers", "attn_layer_indices",
+                      "layer_types"):
             if config.get(group):
                 d[group] = config[group]
         return cls.from_dict(d)

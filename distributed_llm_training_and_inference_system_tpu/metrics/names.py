@@ -113,8 +113,9 @@ METRICS: dict[str, MetricSpec] = {
         COUNTER, "Tokens those steps made (1 or 2 a step)"),
     "llmctl_inference_state_carry_tokens": MetricSpec(
         COUNTER, "Prompt tokens prefilled by chunk programs that read and "
-                 "wrote a slot's recurrent state (a K model's chunked "
-                 "prefill; stats()[\"kda\"] has the chunks beside them)"),
+                 "wrote a slot's recurrent state (a K or C model's chunked "
+                 "prefill and riding pieces; stats()[\"kda\" | "
+                 "\"shortconv\"] has the chunks beside them)"),
     "llmctl_inference_swapped_host_bytes": MetricSpec(
         GAUGE, "Host bytes held by swapped-out KV"),
     # -- fleet control plane ----------------------------------------------

@@ -214,13 +214,31 @@ def _init_table_blocks(cfg: ModelConfig, keys, norm_init, dense, resid_std,
         }
 
     def attention_stack(La):
-        return {
+        stack = {
             "norm": {"scale": norm_init(La, H)},
             "q": {"kernel": dense(next(keys), La, H, Nq * D, name="q")},
             "k": {"kernel": dense(next(keys), La, H, Nkv * D, name="k")},
             "v": {"kernel": dense(next(keys), La, H, Nkv * D, name="v")},
             "o": {"kernel": dense(next(keys), La, Nq * D, H,
                                   scale=resid_std, name="o")},
+        }
+        if cfg.qk_norm == "head":
+            stack["q_norm"] = {"scale": norm_init(La, D)}
+            stack["k_norm"] = {"scale": norm_init(La, D)}
+        return stack
+    if cfg.conv_layers:
+        # the gated short convolution: [B | C | u] in, one K-tap filter a
+        # channel (uniform in +-1/sqrt(K), as the state-space conv), out
+        Lc, K = cfg.conv_layers, cfg.shortconv_kernel
+        bound = 1.0 / jnp.sqrt(float(K))
+        blocks["conv"] = {
+            "norm": {"scale": norm_init(Lc, H)},
+            "in_proj": {"kernel": dense(next(keys), Lc, H, 3 * H)},
+            "conv": {"kernel": jax.random.uniform(
+                next(keys), (Lc, K, H), jnp.float32, -bound,
+                bound).astype(dtype)},
+            "out_proj": {"kernel": dense(next(keys), Lc, H, H,
+                                         scale=resid_std)},
         }
     if Lm:
         blocks["ssm"] = ssm_stack(Lm)
@@ -396,6 +414,33 @@ def table_period(cfg: ModelConfig) -> tuple[list, list, int]:
             if rest == rest[:p] * (len(rest) // p):
                 return layers[:h], layers[h:h + p], len(rest) // p
     return layers, [], 0
+
+
+def table_period_and_tail(cfg: ModelConfig) -> tuple[list, list, int, list]:
+    """``table_period`` for a table that need not END in its period: (head,
+    unit, repetitions, tail), the tail being the layers behind the last
+    whole repetition: the split that puts most layers under the loop
+    (``lfm2_moe``'s 48 entries: the head ``CDCD``, ``*ECECECE`` x 4, and a
+    tail ``*ECECE*ECECE`` whose last attention layer comes a layer early,
+    where ``table_period`` finds that tail's ``*ECECE`` x 2 alone), taken
+    where it covers more than ``table_period``'s own split AND at least two
+    thirds of the table (a loop over less is not worth a second walk of its
+    layers: the short test tables stay all head); else ``table_period``'s
+    split and no tail."""
+    head, unit, reps = table_period(cfg)
+    layers, kinds = table_layers(cfg), cfg.layer_pattern
+    best = (len(unit) * reps, 0, 0, 0)      # covered, -head, -period, reps
+    for h in range(len(kinds)):
+        for p in range(1, (len(kinds) - h) // 2 + 1):
+            r = 1
+            while kinds[h + r * p:h + (r + 1) * p] == kinds[h:h + p]:
+                r += 1
+            if r >= 2 and r * p > best[0]:
+                best = (r * p, -h, -p, r)
+    covered, h, p, r = best[0], -best[1], -best[2], best[3]
+    if not r or 3 * covered < 2 * len(kinds):
+        return head, unit, reps, []
+    return layers[:h], layers[h:h + p], r, layers[h + covered:]
 
 
 def table_layer(blocks: Params, kind: str, index) -> Params:
@@ -628,7 +673,8 @@ def forward(
       the last live token: what cold prefill arms a slot with. The ``K``
       layers of a ``kimi_linear`` table (a table has ``M`` or ``K`` layers,
       not both) do the same: (conv tails [Lk, B, K-1, 3 d_in], states
-      [Lk, B, nh, dk, dv] float32).
+      [Lk, B, nh, dk, dv] float32); the ``C`` layers of an ``lfm2_moe``
+      table keep their windows alone: (rows [Lc, B, K-1, H],).
     - a model with LATENT attention keeps no dense cache (``kv_cache`` is
       refused): its window attends in the expanded form over its own
       tokens, and ``return_latent`` appends the rows a cache would keep,
@@ -779,13 +825,14 @@ def _walk_table(params, x, cfg: ModelConfig, positions, segment_ids,
     attention layers' updated dense cache (a latent model's rows
     [La, B, S, latent]) or None, the summed ``moe_stats``, (conv tails,
     states) of the state-space or ``K`` layers)."""
-    from ..ops import kda, ssm
+    from ..ops import kda, shortconv, ssm
     blocks = cast_table_blocks(params["blocks"], compute_dtype)
     window = ssm.recur_window(cfg, segment_ids)
     recurs = {"M": window, "P": window,
-              "K": kda.recur_window(cfg, segment_ids)}
+              "K": kda.recur_window(cfg, segment_ids),
+              "C": shortconv.recur_window(cfg, segment_ids)}
     aux_total = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
-    caches, tails, states, latents = [], [], [], []
+    caches, states, latents = [], [], []
     for kind, i in table_layers(cfg):
         cache = (None if kv_cache is None or kind not in "*P"
                  else (kv_cache[0][i], kv_cache[1][i]))
@@ -800,8 +847,7 @@ def _walk_table(params, x, cfg: ModelConfig, positions, segment_ids,
                 caches.append(state[0])
             state = state[1]
         if kind in recurs:
-            tails.append(state[0])
-            states.append(state[1])
+            states.append(state)
         elif kind == "*" and cache is not None:
             caches.append(state)
         elif kind == "*" and cfg.is_latent:
@@ -812,7 +858,10 @@ def _walk_table(params, x, cfg: ModelConfig, positions, segment_ids,
     if caches:
         new_cache = (jnp.stack([c[0] for c in caches]),
                      jnp.stack([c[1] for c in caches]))
-    ssm_state = (jnp.stack(tails), jnp.stack(states)) if tails else None
+    # a part of the kind's state a position: (conv tails, states); a ``C``
+    # layer's windows alone
+    ssm_state = (tuple(jnp.stack(part) for part in zip(*states))
+                 if states else None)
     return x, new_cache, aux_total, ssm_state
 
 

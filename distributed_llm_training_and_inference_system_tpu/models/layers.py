@@ -653,7 +653,9 @@ def decoder_block(
     same experts (plus the shared expert), ``D`` the dense ``mlp_block``,
     ``M`` the Mamba-2 mixer, whose state lives where ``recur`` says, as K
     and V live where ``attend`` says (``ssm_mixer``), ``K`` the Kimi Delta
-    Attention mixer, likewise (``kda_mixer``), ``P`` (``falcon_h1``) the
+    Attention mixer, likewise (``kda_mixer``), ``C`` (``lfm2_moe``) the
+    gated short convolution, whose window of rows is its whole state
+    (``shortconv_mixer``), ``P`` (``falcon_h1``) the
     attention AND the Mamba-2 mixer on the SAME normed stream, their
     outputs summed, both states returned (``attend``'s, ``recur``'s). With
     ``cfg.hc_mult`` > 1 the residual is ``hc_mult`` streams ([B, S, n, H])
@@ -677,6 +679,8 @@ def decoder_block(
             out, state = ssm_mixer(h, layer, cfg, recur, matmul)
         elif kind == "K":
             out, state = kda_mixer(h, layer, cfg, recur, matmul)
+        elif kind == "C":
+            out, state = shortconv_mixer(h, layer, cfg, recur, matmul)
         elif kind == "*":
             mixer = (latent_attention_mixer if cfg.is_latent
                      else attention_mixer)
@@ -965,3 +969,21 @@ def kda_mixer(h: jax.Array, layer: Params, cfg: ModelConfig, recur,
                        layer["gate_norm"]["scale"], kd.num_heads,
                        cfg.norm_eps)
     return matmul(o, layer["out_proj"]["kernel"]), state
+
+
+def shortconv_mixer(h: jax.Array, layer: Params, cfg: ModelConfig, recur,
+                    matmul=dense_matmul) -> tuple[jax.Array, Any]:
+    """The gated short-convolution mixer (``lfm2_moe``'s ``conv`` layers)
+    over the normed stream ``h`` [B, S, H]: ``[B | C | u] = h W_in`` (that
+    order, no bias); ``z = B * u``; ``recur(z, layer)`` runs the depthwise
+    causal conv wherever its window lives (ops/shortconv.py
+    ``recur_window`` / ``recur_step`` / ``recur_chunk``) and returns
+    (y [B, S, H], state); then ``(C * y) W_out``. No activation, no
+    position embedding; the gates multiply in float32."""
+    H = cfg.hidden_size
+    with jax.named_scope("shortconv_mixer"):
+        bcu = matmul(h, layer["in_proj"]["kernel"]).astype(jnp.float32)
+        z = (bcu[..., :H] * bcu[..., 2 * H:]).astype(h.dtype)
+        y, state = recur(z, layer)
+        gated = (bcu[..., H:2 * H] * y.astype(jnp.float32)).astype(h.dtype)
+        return matmul(gated, layer["out_proj"]["kernel"]), state

@@ -41,23 +41,85 @@ def _at(layer, *index):
     return index if layer is None else (layer, *index)
 
 
-def _resolve_impl(op: str, impl: str, q: jax.Array) -> tuple[str, bool]:
+LANES = 128     # the minor axis of the chip's tiles
+
+
+def heads_a_row(num_kv_heads: int, head_dim: int) -> int:
+    """KV heads that share one row of a page: 1, or, for heads of 64 values
+    (an even number of them), 2: a PAIR of heads side by side on the 128
+    lanes, the pool laid out [L, NP, Nkv / 2, PS, 128]. Chosen by the head
+    size alone and never for 128. HBM bytes are those of head_dim 64; the
+    page-streaming kernel reads such a pool as Nkv / 2 heads of 128 with
+    each query padded onto its head's half of the lanes
+    (``_on_own_lanes``), so its dots do twice the work in a kernel that is
+    bound by bytes."""
+    return 2 if 2 * head_dim == LANES and num_kv_heads % 2 == 0 else 1
+
+
+def _heads_packed(pages, head_dim: int) -> int:
+    """How many heads of ``head_dim`` a row of ``pages`` holds (1: the plain
+    layout; a latent pool's padded row is also 1 head)."""
+    f = pages.shape[-1] // head_dim
+    return f if f > 1 and f * head_dim == LANES == pages.shape[-1] else 1
+
+
+def _pack_heads(new_kv: jax.Array, pages) -> jax.Array:
+    """``new_kv`` [..., Nkv, D] as the pool's rows [..., Nkv / f, f D] (heads
+    are contiguous with their values: the reshape moves nothing)."""
+    f = _heads_packed(pages, new_kv.shape[-1])
+    if f == 1 or new_kv.shape[-2] != pages.shape[-3] * f:
+        return new_kv
+    return new_kv.reshape(*new_kv.shape[:-2], pages.shape[-3],
+                          pages.shape[-1])
+
+
+def _on_own_lanes(q: jax.Array, groups: int, f: int) -> jax.Array:
+    """Queries [..., Nq, D] padded to the packed pool's row width
+    [..., Nq, f D]: a query head whose KV head is the j-th of its row keeps
+    its values on the j-th D lanes and zeros elsewhere, so its dot with a
+    packed key row is its dot with its own head's key."""
+    Nq, D = q.shape[-2:]
+    half = (jnp.arange(Nq) // groups) % f                        # [Nq]
+    lanes = jnp.arange(f * D) // D                               # [f D]
+    own = half[:, None] == lanes[None]                           # [Nq, f D]
+    return jnp.where(own, jnp.tile(q, (1,) * (q.ndim - 1) + (f,)), 0)
+
+
+def _own_lanes_of(out: jax.Array, groups: int, f: int) -> jax.Array:
+    """The packed kernel's output [..., Nq, f D] (every head of the row's
+    values under the query's probabilities) -> [..., Nq, D]: each query
+    head keeps the lanes of its own KV head."""
+    Nq, W = out.shape[-2:]
+    D = W // f
+    half = (jnp.arange(Nq) // groups) % f
+    parts = out.reshape(*out.shape[:-1], f, D)
+    return jnp.take_along_axis(
+        parts, half.reshape((1,) * (out.ndim - 2) + (Nq, 1, 1)),
+        axis=-2)[..., 0, :]
+
+
+def _resolve_impl(op: str, impl: str, q: jax.Array, pages=None
+                  ) -> tuple[str, bool]:
     """``auto`` -> the page-streaming Pallas kernel on TPU, the gather
     baseline elsewhere; returns (impl, interpret). The choice is reported
     (once per traced program) — a gather path on the chip is a line in
     the log, never a silent detour."""
     on_tpu = jax.default_backend() == "tpu"
-    D = q.shape[-1]
+    # the width of a page's rows: head_dim, or a pair of 64-wide heads
+    D = q.shape[-1] if pages is None else pages.shape[-1]
     detail = f"q{tuple(q.shape)}"
+    if D != q.shape[-1]:
+        detail += f" over rows of {D}"
     if impl == "auto":
-        # the Pallas kernels tile head_dim onto the 128-lane axis; D < 128
-        # (e.g. gpt-350m's 64) fails Mosaic layout inference ("unsupported
-        # shape cast") — those shapes take the gather path instead of
-        # crashing the serve engine
+        # the Pallas kernels tile a page's rows onto the 128-lane axis; a
+        # row under 128 (heads of 64 that could not pair: an odd count, a
+        # quantised or sharded pool; gpt-test's 16) fails Mosaic layout
+        # inference ("unsupported shape cast") — those shapes take the
+        # gather path instead of crashing the serve engine
         if not on_tpu:
             impl, detail = "gather", detail + f", backend {jax.default_backend()}"
-        elif D % 128:
-            impl, detail = "gather", detail + f", head_dim {D} % 128 != 0"
+        elif D % LANES:
+            impl, detail = "gather", detail + f", page row {D} % 128 != 0"
         else:
             impl = "pallas"
     else:
@@ -174,12 +236,12 @@ def paged_attention(
     traffic proportional to live length) and this gather baseline
     elsewhere.
     """
-    impl, interpret = _resolve_impl("paged_attention", impl, q)
+    impl, interpret = _resolve_impl("paged_attention", impl, q, k_pages)
     if impl == "pallas":
-        from .paged_attention_pallas import paged_attention_pallas
-        return paged_attention_pallas(
-            q, k_pages, v_pages, block_tables, lengths,
-            layer=layer, interpret=interpret)
+        # (the T = 1 case of the window kernel: one body for both)
+        return paged_attention_multi(
+            q[:, None], k_pages, v_pages, block_tables,
+            lengths.astype(jnp.int32) - 1, impl=impl, layer=layer)[:, 0]
     return _gather_attention(q, k_pages, v_pages, block_tables, lengths,
                              layer)
 
@@ -189,7 +251,8 @@ def _gather_attention(q, k_pages, v_pages, block_tables, lengths,
     """The portable baseline: materialise each row's [Nkv, maxP*PS, D]
     prefix through the block table, then plain masked attention."""
     B, Nq, D = q.shape
-    Nkv, PS = k_pages.shape[-3:-1]
+    f = _heads_packed(k_pages, D)
+    Nkv, PS = k_pages.shape[-3] * f, k_pages.shape[-2]
     maxP = block_tables.shape[1]
     groups = Nq // Nkv
     idx = _at(layer, block_tables)      # one gather: pages[layer, table]
@@ -208,6 +271,9 @@ def _gather_attention(q, k_pages, v_pages, block_tables, lengths,
                  * pages.scale[idx][..., None]).astype(q.dtype)
         else:
             g = pages[idx]
+        if f > 1:       # a row holds f heads side by side: apart again
+            g = g.reshape(B, maxP, Nkv // f, PS, f, D).transpose(
+                0, 1, 2, 4, 3, 5).reshape(B, maxP, Nkv, PS, D)
         return g.transpose(0, 2, 1, 3, 4).reshape(B, Nkv, maxP * PS, D)
 
     k = gather(k_pages)
@@ -286,6 +352,7 @@ def write_window_to_pages(
     keep their staging content / write scratch page 0, matching the
     scatter path's semantics.
     """
+    new_kv = _pack_heads(new_kv, pages)
     T = new_kv.shape[1]
     rows_a_tile = _window_tile_rows(pages, T)
     report_impl("window_page_write", "tiles" if rows_a_tile else "pages",
@@ -468,13 +535,23 @@ def paged_attention_multi(
     cost is not measured on the attached chip).
     """
     B, T, Nq, D = q.shape
-    # same D % 128 == 0 constraint as paged_attention (Mosaic lane
-    # tiling); small-head models serve via the gather fallback. Every
-    # window size takes the kernel (it tiles long windows itself).
+    # the kernel wants a page's rows whole lane tiles (Mosaic): heads of
+    # 128, or heads of 64 in pairs (``heads_a_row``); any other small head
+    # serves via the gather fallback. Every window size takes the kernel
+    # (it tiles long windows itself).
     impl, interpret = _resolve_impl(
-        "paged_attention" if T == 1 else "paged_attention_multi", impl, q)
+        "paged_attention" if T == 1 else "paged_attention_multi", impl, q,
+        k_pages)
     if impl == "pallas":
         from .paged_attention_pallas import paged_attention_pallas_multi
+        f = _heads_packed(k_pages, D)
+        if f > 1:
+            groups = Nq // (k_pages.shape[-3] * f)
+            out = paged_attention_pallas_multi(
+                _on_own_lanes(q, groups, f), k_pages, v_pages, block_tables,
+                start_positions, layer=layer, interpret=interpret,
+                block=block, scale=float(D) ** -0.5)
+            return _own_lanes_of(out, groups, f)
         return paged_attention_pallas_multi(
             q, k_pages, v_pages, block_tables, start_positions,
             layer=layer, interpret=interpret, block=block)
@@ -526,7 +603,9 @@ def _prompt_page_layout(pages, dense: jax.Array) -> jax.Array:
     if dense.ndim == 3:         # latent rows: no head axis
         return pad_to_page_width(dense, pages).reshape(
             L, bucket // PS, 1, PS, -1).astype(pages.dtype)
-    # dense [L, bucket, Nkv, D] -> paged [L, n_pages, Nkv, PS, D]
+    # dense [L, bucket, Nkv, D] -> paged [L, n_pages, Nkv, PS, D] (heads
+    # of 64 in pairs: [.., Nkv / 2, PS, 128])
+    dense = _pack_heads(dense, pages)
     return dense.reshape(L, bucket // PS, PS,
                          *dense.shape[2:]).transpose(0, 1, 3, 2, 4)
 
@@ -562,6 +641,7 @@ def write_token_to_pages(
     decode continuing past a row's token budget) — harmlessly overwrite
     scratch page 0 instead of corrupting pages beyond the block table.
     ``QuantPages`` get the token quantized per (row, head) on the way in."""
+    new_kv = _pack_heads(new_kv, pages)
     page_size = pages.shape[-2]
     maxP = block_tables.shape[1]
     logical_page = jnp.clip(positions // page_size, 0, maxP - 1)

@@ -273,6 +273,8 @@ def paged_attention_pallas_multi(
     layer=None,                # int32 scalar: which layer's pages to read
     interpret: bool = False,
     block: int = 0,            # static: the block rule's block length
+    scale: float | None = None,  # of the scores; None: D ** -0.5 (a pool
+                               # of heads in pairs states its heads' own)
 ) -> jax.Array:
     """Returns [B, T, Nq, D]; query j attends over [0, start+j] via pages
     (the window's own K/V must already be written to the pages). With
@@ -292,7 +294,8 @@ def paged_attention_pallas_multi(
     Nkv, PS = k_pages.shape[-3:-1]
     maxP = block_tables.shape[1]
     groups = Nq // Nkv
-    scale = 1.0 / float(D) ** 0.5
+    if scale is None:
+        scale = 1.0 / float(D) ** 0.5
 
     # long windows (suffix / chunked prefill) are tiled along the query
     # axis: one more grid dimension, each tile an independent online-

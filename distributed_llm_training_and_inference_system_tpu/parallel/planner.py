@@ -726,6 +726,10 @@ class ServePlanner:
         and K-1 conv columns in bf16, in every such layer, whatever
         the sequences' lengths. 0 for a model without such layers."""
         m, s = self.model, self.model.ssm
+        if m.conv_layers:
+            # a C layer's window alone: K-1 rows of the hidden size
+            return (m.conv_layers * slots * (m.shortconv_kernel - 1)
+                    * m.hidden_size * BYTES_BF16)
         if m.kda_layers:
             # a K layer's [nh, dk, dv] state and its conv window over
             # q | k | v (ops/kda.py)

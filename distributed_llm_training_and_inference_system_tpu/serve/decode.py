@@ -28,11 +28,11 @@ from ..models.gpt import (
     split_expert_stacks,
     table_layer,
     table_layers,
-    table_period,
+    table_period_and_tail,
     unembed,
 )
 from ..models.layers import decoder_block, model_rope_frequencies, scaled
-from ..ops import kda, ssm as ssm_ops
+from ..ops import kda, shortconv, ssm as ssm_ops
 from ..ops.mla_paged_attention import mla_paged_attention
 from ..ops.paged_attention import (
     pad_to_page_width,
@@ -144,18 +144,21 @@ _ssm_step = jax.jit(ssm_ops.step_pools, static_argnames=("s",))
 _shared_recur_step = {
     "K": jax.jit(kda.step_pools, static_argnames=("kd",)),
     "M": _ssm_step, "P": _ssm_step,
+    "C": jax.jit(shortconv.step_pools),
 }
-# a recurrent kind's module: both have ``recur_step`` / ``step_pools`` (T = 1
+# a recurrent kind's module: each has ``recur_step`` / ``step_pools`` (T = 1
 # over the pools), ``recur_chunk`` (a window of ONE slot from its state) and
-# ``slot_state`` / ``write_slot_state`` / ``arm_slot_state`` for their own
-# pools' layouts
-_RECURRENT = {"K": kda, "M": ssm_ops, "P": ssm_ops}
+# ``slot_state`` / ``write_slot_state`` / ``arm_slot_state`` for its own
+# pools' layouts. The pools are the engine's ``state`` dict's values in
+# order: a conv pool and a state pool (``M``, ``K``), or a ``C`` model's conv
+# pool alone; a window's rows of them ride as a tuple of the same length
+_RECURRENT = {"K": kda, "M": ssm_ops, "P": ssm_ops, "C": shortconv}
 
 
 def recurrent_ops(cfg: ModelConfig):
-    """The module of a recurrent model's kind (a table has ``M`` / ``P``
-    or ``K`` layers, not both): it owns the state pools' layout."""
-    return _RECURRENT["K" if cfg.kda_layers else "M"]
+    """The module of a recurrent model's kind (a table has ``M`` / ``P``,
+    ``K`` or ``C`` layers, one kind): it owns the state pools' layout."""
+    return _RECURRENT[cfg.recurrent_kind or "M"]
 
 
 def can_carry(cfg: ModelConfig) -> bool:
@@ -168,7 +171,8 @@ def can_carry(cfg: ModelConfig) -> bool:
     are served and tested: state-space (``M``) layers beside K/V pages
     (``P``: in ONE layer, the attention over the slot's pages and the scan
     from the slot's state), delta-rule (``K``) layers beside a latent pool
-    or K/V pages."""
+    or K/V pages, gated short-convolution (``C``) layers beside K/V pages
+    (the piece's conv runs from the slot's two rows)."""
     if cfg.is_diffusion or cfg.mtp_layers:
         # its step is a window already (2 x ``block_length`` rows a slot; a
         # self-drafting model's last sure token and its draft), and a
@@ -242,7 +246,8 @@ def extend_step_forward(
                               # route needs a measured per-chip win first
     return_moe_stats: bool = False,
     ssm_state: Any = None,    # {"conv": [Lm, B, K-1, C], "ssm": [Lm, B, nh,
-                              # P, N]}: the state-space layers' pools
+                              # P, N]}: the state-space layers' pools (a
+                              # ``C`` model's: {"conv"} alone)
     ride: Any = None,         # a Piece (T == 1, ``can_carry`` models): its
                               # C rows join the B rows of the step
     two_bodies: bool = False,  # this step is one of the two bodies of a
@@ -431,36 +436,36 @@ def extend_step_forward(
             raise ValueError(f"a model with {cfg.recurrent_name} needs its "
                              "ssm_state pools")
 
-        def recur_at(kind, conv, ssm, i, piece):
+        def recur_at(kind, pools, i, piece):
             ops = _RECURRENT.get(kind)
             if ops is None:
                 return None
             # the B rows one token a slot over the pools; a program that
             # rides calls ONE jitted form from both its bodies
             step = ops.recur_step(
-                cfg, conv, ssm, i, write_ok,
+                cfg, *pools, i, write_ok,
                 step=_shared_recur_step[kind] if two_bodies
                 else ops.step_pools)
             if piece is None:
                 return step
             # ONE slot's window [1, T] from that slot's own state: a chunk
             # of its prompt, or the piece a decode step carries
-            chunk = ops.recur_chunk(cfg, piece[0][i], piece[1][i], piece_rows)
+            chunk = ops.recur_chunk(cfg, *(a[i] for a in piece), piece_rows)
 
             def recur(*acts_and_layer):
-                # (``M``: xBC, dt; ``K``: qkv, f, b; then the layer.) The
-                # state: (the pools, the window's (conv window, state)
-                # after this layer)
+                # (``M``: xBC, dt; ``K``: qkv, f, b; ``C``: z; then the
+                # layer.) The state: (the pools, the window's (conv window,
+                # state) after this layer)
                 *acts, p = acts_and_layer
                 if ride is None:        # the window is all the rows
                     out, after = chunk(*acts, p)
-                    return out, ((conv, ssm), after)
+                    return out, (pools, after)
                 # (the piece's slot is not armed: ``step`` leaves its rows
                 # of the pools bit for bit)
-                out, pools = step(*(a[:B] for a in acts), p)
+                out, stepped = step(*(a[:B] for a in acts), p)
                 piece_out, after = chunk(*(a[B:, 0][None] for a in acts), p)
                 return (jnp.concatenate([out, piece_out[0][:, None]]),
-                        (pools, after))
+                        (stepped, after))
             return recur
         blocks = cast_table_blocks(params["blocks"], compute_dtype)
         kp, vp = k_pages, v_pages
@@ -476,9 +481,9 @@ def extend_step_forward(
                     cfg, kp, li, block_tables, start_positions, write_ok,
                     attn_impl, ride=ride, two_bodies=two_bodies)
             return attend_pages(kp, vp, li)
-        conv, ssm = (ssm_state["conv"], ssm_state["ssm"]) \
-            if ssm_state is not None else (None, None)
-        # a window of ONE slot through the recurrent (``M`` or ``K``)
+        # (the state dict's pools in its own order: conv, then ssm if any)
+        pools = tuple(ssm_state.values()) if ssm_state is not None else ()
+        # a window of ONE slot through the recurrent (``M``, ``K`` or ``C``)
         # layers, a chunk of its prompt or the piece a decode step carries:
         # the slot's rows of the pools are read here, once ((conv windows,
         # states), stacked [Lm | Lk, ...]), ride the layer walk's carry,
@@ -495,31 +500,31 @@ def extend_step_forward(
                 ride.slot, ride.start[None], piece_ok[None],
                 ride.live > 0)      # False: a step that carries nothing
         if piece_slot is not None:
-            piece = recurrent.slot_state(conv, ssm, piece_slot, piece_start)
+            piece = recurrent.slot_state(*pools, piece_slot, piece_start)
         stats = jnp.zeros((cfg.moe.stats_size,), jnp.int32)
 
         def sub_layer(carry, kind, i):
-            x, kp, vp, conv, ssm, stats, piece = carry
+            x, kp, vp, pools, stats, piece = carry
             x, state, layer_stats = decoder_block(
                 x, table_layer(blocks, kind, i), cfg, positions, inv_freq,
                 attend_at(kp, vp, i) if kind in "*P" else None, matmul=mm,
                 live=live, layer_index=i, kind=kind,
-                recur=recur_at(kind, conv, ssm, i, piece))
+                recur=recur_at(kind, pools, i, piece))
             if kind == "P":
                 # both mixers' states: the page pools, then the recurrent one
                 (kp, vp), state = state
                 kind = "M"
             if kind == "*":
                 kp, vp = state
-            elif kind in "MK" and piece is not None:
-                (conv, ssm), after = state
+            elif kind in "MKC" and piece is not None:
+                pools, after = state
                 piece = tuple(a.at[i].set(new.astype(a.dtype))
                               for a, new in zip(piece, after))
-            elif kind in "MK":
-                conv, ssm = state
+            elif kind in "MKC":
+                pools = state
             elif kind == "E":
                 stats = stats + layer_stats
-            return x, kp, vp, conv, ssm, stats, piece
+            return x, kp, vp, pools, stats, piece
         # A program that rides holds two step bodies, and a table walked by
         # a Python loop holds every layer's kernels once a body: the latent
         # cell's executable grew from 57 to 148 MB and its first call from
@@ -530,9 +535,9 @@ def extend_step_forward(
         # loop over traced layer indices, as the uniform stack's scan does;
         # the pools are the loop's carry, written in place. Any other
         # program walks it whole.
-        head, unit, reps = (table_period(cfg) if two_bodies
-                            else (table_layers(cfg), [], 0))
-        carry = (x, kp, vp, conv, ssm, stats, piece)
+        head, unit, reps, tail = (table_period_and_tail(cfg) if two_bodies
+                                  else (table_layers(cfg), [], 0, []))
+        carry = (x, kp, vp, pools, stats, piece)
         for kind, i in head:
             carry = sub_layer(carry, kind, i)
         if reps:
@@ -543,16 +548,18 @@ def extend_step_forward(
                     carry = sub_layer(carry, kind, i + r * per_rep[kind])
                 return carry
             carry = jax.lax.fori_loop(0, reps, period, carry)
-        x, kp, vp, conv, ssm, stats, piece = carry
+        for kind, i in tail:    # (a table that does not end in its period)
+            carry = sub_layer(carry, kind, i)
+        x, kp, vp, pools, stats, piece = carry
         if piece is not None:
-            conv, ssm = recurrent.write_slot_state(
-                conv, ssm, piece_slot, *piece, piece_live)
+            pools = recurrent.write_slot_state(
+                *pools, piece_slot, *piece, piece_live)
         if cfg.hc_mult > 1:
             x = jnp.sum(x.astype(jnp.float32), axis=2).astype(compute_dtype)
         step = StepResult(
             unembed(params, head_rows(x), cfg), kp, vp,
             stats if return_moe_stats else None,
-            {"conv": conv, "ssm": ssm} if ssm_state is not None else None)
+            dict(zip(ssm_state, pools)) if ssm_state is not None else None)
         return StepWithStream(*step, x) if return_stream else step
 
     def body(carry, layer_and_index):

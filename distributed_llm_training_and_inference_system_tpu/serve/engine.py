@@ -898,10 +898,12 @@ class InferenceEngine:
             # gather fallback (CPU / tensor-parallel) re-streams the whole
             # prefix once PER SUFFIX TOKEN — there a small hit on a long
             # tail costs more than a cold dense prefill, so it is dropped.
+            # (a page's rows are whole lane tiles: heads of 128, heads of
+            # 64 in pairs, a latent row: ops/paged_attention.py
+            # ``_resolve_impl``'s own condition)
             pallas_suffix = (self._attn_impl == "auto"
                              and jax.default_backend() == "tpu"
-                             and (self.cfg.head_dim % 128 == 0
-                                  or self.cfg.is_latent))
+                             and self.kv.k_pages.shape[-1] % 128 == 0)
             computed = n - len(pins) * self.kv.page_size
             if pins and not pallas_suffix and computed > max(
                     len(pins) * self.kv.page_size,
@@ -1020,9 +1022,8 @@ class InferenceEngine:
                     # both pools' rows are overwritten with the state after
                     # the prompt's last live token, whatever a former
                     # occupant (or its trailing decode steps) left there
-                    conv, ssm = recurrent_ops(cfg).arm_slot_state(
-                        state["conv"], state["ssm"], slot, *next(more))
-                    state = {"conv": conv, "ssm": ssm}
+                    state = dict(zip(state, recurrent_ops(cfg).arm_slot_state(
+                        *state.values(), slot, *next(more))))
                 def sample():
                     return sample_tokens(logits[:, 0], key[None], temp[None],
                                          top_k[None], top_p[None])[0]
@@ -3427,8 +3428,9 @@ class InferenceEngine:
                if self._mtp else {}),
             "compiled_programs": self.compiled_programs(),
             # the recurrent kind's counters, under the kind's name
-            # ("ssm": M layers; "kda": K layers)
-            **({("kda" if self.cfg.kda_layers else "ssm"): {
+            # ("ssm": M layers; "kda": K layers; "shortconv": C layers)
+            **({{"K": "kda", "C": "shortconv"}.get(
+                    self.cfg.recurrent_kind, "ssm"): {
                 "state_bytes": self.kv.state_bytes(),
                 "slot_steps": self.ssm_slot_steps,
                 # every prompt token of such a model goes through the

@@ -80,14 +80,17 @@ REFUSED = {
         "measure_device_times": "its probes write scratch pages, and would "
                                 "arm and advance live slots' state",
         # OFF (it is on by default) where no SNAPSHOT pool exists
-        # (``ServeConfig.state_snapshot_entries`` 0, or ``M`` layers, whose
-        # layout has no take / arm pair yet): a page hit would skip tokens
+        # (``ServeConfig.state_snapshot_entries`` 0, or ``M`` or ``C``
+        # layers, whose layouts have no take / arm pair yet: a ``C`` model's
+        # snapshot would be its windows at a page boundary, two rows a
+        # layer): a page hit would skip tokens
         # whose recurrent state nobody kept. With a pool, a ``K`` model
         # keeps ONE snapshot a prompt, at its last whole page boundary, and
         # a hit is followed as far as a snapshot stands (``lookup_prefix``)
         "prefix_caching": "no snapshot of the recurrent state at a page "
                           "boundary is kept (state_snapshot_entries 0, or "
-                          "state-space layers): no page hash is registered "
+                          "state-space or short-convolution layers): no "
+                          "page hash is registered "
                           "or looked up, a repeated prompt is prefilled "
                           "again",
     },
@@ -283,8 +286,16 @@ class PagedKVCache:
         # [L, NP, Nkv, PS, D] — (PS, D) minor-most so the Pallas decode
         # kernel can DMA one [PS, D] page tile per (kv-head, page) grid step
         # (TPU block shapes must end in the tiled dims)
-        shape = (cfg.kv_layers, num_pages, cfg.num_kv_heads, page_size,
-                 cfg.head_dim)
+        # Heads of 64 values lie in PAIRS on the 128 lanes ([.., Nkv / 2, PS,
+        # 128]: ops/paged_attention.py ``heads_a_row``), so that the
+        # page-streaming kernel serves them; a quantised pool (a scale a
+        # token and head) or one sharded over its heads keeps the plain
+        # layout and the gather route.
+        from ..ops.paged_attention import heads_a_row
+        pair = (1 if self.quantized or page_sharding is not None
+                else heads_a_row(cfg.num_kv_heads, cfg.head_dim))
+        shape = (cfg.kv_layers, num_pages, cfg.num_kv_heads // pair,
+                 page_size, cfg.head_dim * pair)
         self.page_sharding = page_sharding
         if cfg.is_latent:
             # the third kind of cache state: ONE pool of latent rows
@@ -360,14 +371,20 @@ class PagedKVCache:
         self._demote_pending: list[tuple[bytes, int]] = []
 
     def new_state(self):
-        """Zeroed state pools of the recurrent layers (``M`` or ``K``: a
-        model has one kind), or None: ``conv`` [layer, slot, K-1, C] (``K``
-        layers: [layer, K-1, slot, C], whole tiles of [slots, C]; ops/kda.py
-        ``kda_conv_step``) in the cache's dtype and ``ssm``
-        [layer, slot, heads, ., .] float32."""
+        """Zeroed state pools of the recurrent layers (``M``, ``K`` or
+        ``C``: a model has one kind), or None: ``conv`` [layer, slot, K-1, C]
+        (``K`` and ``C`` layers: [layer, K-1, slot, C], whole tiles of
+        [slots, C]; ops/kda.py ``kda_conv_step``) in the cache's dtype and
+        ``ssm`` [layer, slot, heads, ., .] float32. A ``C`` (gated
+        short-convolution) layer's window IS its state: such a model's
+        pools are ``conv`` alone."""
         cfg = self.cfg
         if not cfg.is_recurrent:
             return None
+        if cfg.conv_layers:
+            return {"conv": jnp.zeros(
+                (cfg.conv_layers, cfg.shortconv_kernel - 1, self.num_slots,
+                 cfg.hidden_size), self.dtype)}
         if cfg.kda_layers:
             k = cfg.kda
             return {
@@ -800,8 +817,7 @@ class PagedKVCache:
     def _validate_pages_shapes(self, content: dict, n: int) -> None:
         from ..ops.paged_attention import Int4Pages, QuantPages
         cfg = self.cfg
-        expect = (cfg.kv_layers, n, cfg.num_kv_heads, self.page_size,
-                  cfg.head_dim)
+        expect = (cfg.kv_layers, n, *self.k_pages.shape[2:])
         for name, buf in (("k", self.k_pages), ("v", self.v_pages)):
             data = content[name]
             if isinstance(buf, QuantPages):

@@ -127,3 +127,11 @@ def report_impl(op: str, impl: str, detail: str = "") -> None:
     _impl_reported.add(line)
     _impl_logger.info("impl %s=%s%s", op, impl,
                       f" ({detail})" if detail else "")
+
+
+def reported_impls() -> list[tuple[str, str, str]]:
+    """Every (op, impl, detail) this process has reported, sorted: which
+    implementation each traced program's hot ops took (the benchmark's
+    short-conv runner holds a run to them: a gather route on the chip is
+    ``correct: false``, not a slow number)."""
+    return sorted(_impl_reported)

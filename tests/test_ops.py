@@ -611,7 +611,17 @@ _WALK = {
          [0, 1, 12, 44, 15, 4])}
 
 
-@pytest.mark.parametrize("heads", ["gqa", "mha"])
+def _in_pairs(pool):
+    """A full-precision pool [.., Nkv, PS, 64] laid out as the cache lays
+    heads of 64: PAIRS side by side on 128 lanes, [.., Nkv / 2, PS, 128]."""
+    *lead, Nkv, PS, D = pool.shape
+    n = len(lead)
+    return pool.reshape(*lead, Nkv // 2, 2, PS, D).transpose(
+        *range(n), n, n + 2, n + 1, n + 3).reshape(*lead, Nkv // 2, PS,
+                                                   2 * D)
+
+
+@pytest.mark.parametrize("heads", ["gqa", "mha", "pairs"])
 @pytest.mark.parametrize("layered", [False, True], ids=["one-layer", "pool"])
 @pytest.mark.parametrize("kv", ["bf16", "int8", "int4"])
 @pytest.mark.parametrize("T", [1, 20], ids=["decode", "tiled-window"])
@@ -623,17 +633,28 @@ def test_paged_kernel_walks_exactly_the_live_pages(monkeypatch, T, kv,
     index into an [L, NP, ...] pool, GQA and MHA, and the lengths of
     ``_WALK``. The kernel reads a pool whose scratch page is NaN;
     the gather route, which gathers the table's whole width, reads the same
-    pool with a finite scratch page."""
+    pool with a finite scratch page.
+
+    ``pairs``: heads of 64 as the cache lays them, two a 128-lane row (GQA
+    4 : 1 over 4 KV heads = 2 pairs): the kernel over the PAIRED pool, each
+    query on its own head's lanes, against the gather route over the PLAIN
+    pool, and the gather route over the paired pool against the same. (A
+    quantised pool keeps the plain layout: a scale a token and head.)"""
     from distributed_llm_training_and_inference_system_tpu.ops import (
         paged_attention_pallas as pap)
     from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (  # noqa: E501
         paged_attention_multi)
 
-    Nkv, PS, D, L = 2, 16, 64, 2
-    Nq = 4 if heads == "gqa" else Nkv
+    pairs = heads == "pairs"
+    if pairs and kv != "bf16":
+        pytest.skip("a quantised pool keeps the plain layout")
+    Nkv, PS, D, L = (4 if pairs else 2), 16, 64, 2
+    Nq = 16 if pairs else 4 if heads == "gqa" else Nkv
     if T > 1:
-        monkeypatch.setattr(pap, "_MAX_SCORE_ELEMS", Nq * 8 * Nkv * PS)
-        assert pap._query_tile(T, Nq, Nkv, PS) == 8
+        # (the kernel sees a paired pool as Nkv / 2 heads)
+        seen = Nkv // 2 if pairs else Nkv
+        monkeypatch.setattr(pap, "_MAX_SCORE_ELEMS", Nq * 8 * seen * PS)
+        assert pap._query_tile(T, Nq, seen, PS) == 8
     ks = jax.random.split(jax.random.PRNGKey(11), 3)
     k_pool = _layered_pool(kv, ks[0], L, NP=16, Nkv=Nkv, PS=PS, D=D)
     v_pool = _layered_pool(kv, ks[1], L, NP=16, Nkv=Nkv, PS=PS, D=D)
@@ -645,6 +666,12 @@ def test_paged_kernel_walks_exactly_the_live_pages(monkeypatch, T, kv,
 
     want = paged_attention_multi(q, k_pool, v_pool, tables, starts,
                                  impl="gather", layer=layer)
+    if pairs:
+        k_pool, v_pool = _in_pairs(k_pool), _in_pairs(v_pool)
+        assert k_pool.shape[-3:] == (Nkv // 2, PS, 128)
+        again = paged_attention_multi(q, k_pool, v_pool, tables, starts,
+                                      impl="gather", layer=layer)
+        np.testing.assert_array_equal(np.asarray(again), np.asarray(want))
     got = paged_attention_multi(q, _poison_page0(k_pool),
                                 _poison_page0(v_pool), tables, starts,
                                 impl="pallas", layer=layer)
