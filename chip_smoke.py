@@ -182,6 +182,17 @@ def impl_lines(text: str) -> list:
     return out
 
 
+def require_tile_write(text: str, rows: int, what: str) -> None:
+    """Fail unless the log holds ``impl window_page_write=tiles`` for a
+    window of ``rows`` rows: ``what`` did not stage sublane tiles."""
+    if not any(op == "window_page_write" and impl == "tiles"
+               and (detail or "").startswith(f"T={rows} ")
+               for op, impl, detail in impl_lines(text)):
+        raise SmokeFailure(f"{what} did not stage tiles: no line `impl "
+                           f"window_page_write=tiles (T={rows} ...)` in the "
+                           "log")
+
+
 def report_impls(phase: str, text: str, required: tuple, on_tpu: bool,
                  allowed: dict | None = None) -> None:
     """Print every implementation choice of a phase and fail it when, on
@@ -469,6 +480,7 @@ def phase_serve(env: dict, device: dict) -> None:
     report_impls("serve", text,
                  ("prefill_attention", "paged_attention",
                   "paged_attention_multi", "rms_norm"), on_tpu)
+    require_tile_write(text, 1, "a decode step's one row a slot")
     secs, n = compile_seconds(text)
     say(f"  serve: {n} programs compiled in {secs:.1f}s")
     # the server is gone and the chip free: one more child builds the same
@@ -593,10 +605,7 @@ def phase_selfdraft(env: dict) -> None:
         if any(n != rec["new"] for n in rec["identical"]):
             raise SmokeFailure("a self-drafted stream differs from plain "
                                f"greedy decoding: {rec}")
-    if ("window_page_write", "tiles") not in {
-            (op, impl) for op, impl, _ in impl_lines(text)}:
-        raise SmokeFailure("the window of two rows did not stage tiles: no "
-                           "line `impl window_page_write=tiles` in the log")
+    require_tile_write(text, 2, "the window of two rows")
     if recs["all-stand"]["tokens_per_slot_step"] != 2.0:
         raise SmokeFailure("the two-token branch did not run in every step "
                            f"of the constructed weights: {recs['all-stand']}")
@@ -689,6 +698,7 @@ def phase_shortconv(env: dict) -> None:
         srv.stop()
     report_impls("shortconv", srv.text(), ("paged_attention",),
                  dev["platform"] == "tpu")
+    require_tile_write(srv.text(), 1, "a decode step's one row a slot")
 
 
 _STEP_RE = re.compile(r"step (\d+) \| loss ([0-9.naninf-]+) \|.*?"

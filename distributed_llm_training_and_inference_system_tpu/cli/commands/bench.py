@@ -929,7 +929,7 @@ def e2e(model_name, mode, steps, batch, seq_len, prompt_len, gen_len,
 @click.option("--steps", default=50, show_default=True)
 @click.option("--write-mode", default="paged", show_default=True,
               type=click.Choice(["paged", "scatter"]),
-              help="KV append path: whole-page merge (fused "
+              help="KV append path: staged tile or page merge (fused "
                    "quantize-on-write for int8) vs per-row scatter.")
 def kv_decode(slots, kv_heads, head_dim, q_heads, page_size, context,
               layers, steps, write_mode):
@@ -946,8 +946,8 @@ def kv_decode(slots, kv_heads, head_dim, q_heads, page_size, context,
     import jax.numpy as jnp
 
     from ...ops.paged_attention import (
-        Int4Pages, QuantPages, paged_attention, quantize_kv_token,
-        write_token_to_pages, write_window_to_pages)
+        Int4Pages, QuantPages, _window_tile_rows, paged_attention,
+        quantize_kv_token, write_token_to_pages, write_window_to_pages)
     from ...ops.quantization import pack_int4_rows, quantize_int4_rows
 
     q_heads = q_heads or kv_heads
@@ -1002,11 +1002,13 @@ def kv_decode(slots, kv_heads, head_dim, q_heads, page_size, context,
         sec = (time.perf_counter() - t0) / steps
         # per-token HBM ledger at this shape, whole model (layers x):
         # attention must stream every live K/V row once; the append
-        # writes (and, page-granular, re-reads) whole pages
+        # stages (reads, then writes back) the row's sublane tile of a
+        # full-precision pool, its whole page of quantized pages
         row = row_bytes[name]
         read_attn = 2 * B * context * row
         if write_mode == "paged":
-            write_rw = 2 * B * 2 * PS * row        # K+V staging gather+scatter
+            staged = _window_tile_rows(pages, 1) or PS
+            write_rw = 2 * B * 2 * staged * row    # K+V staging gather+scatter
         else:
             write_rw = 2 * B * row                 # K+V row scatter (ideal)
         # capacity ledger: a resident decode slot at this context costs

@@ -309,29 +309,35 @@ def write_window_to_pages(
     routes chosen from what the call can see (static, reported at trace
     time as ``window_page_write``):
 
-    WHOLE PAGES (``_write_window_to_whole_pages``: T == 1, T > 16, and
-    every window over ``QuantPages`` / ``Int4Pages``). A slot's T
-    consecutive tokens span at most n = (T + 2 PS - 2) // PS physical
-    pages. This gathers those n*B pages out of the pool, merges the window
-    in registers (a float32 one-hot select over the n*PS staging
-    positions), and scatters n*B WHOLE pages back: regular page-sized DMAs
-    that leave the pool in the layout the Pallas kernel reads. The
-    B*T-row scatter does not: compiled for the v5e it makes XLA keep the
-    carried pool slot-major and copy it WHOLE to the kernel's layout and
-    back in every layer (3.8 GB of temporaries in the decode program at
-    the benchmark's shapes, PERF.md 6, PR 26), which is why windows longer
-    than a page (suffix and chunked prefill) take this route too.
+    WHOLE PAGES (``_write_window_to_whole_pages``: T > 16, every window
+    over ``QuantPages`` / ``Int4Pages``, and a page that is not whole
+    sublane tiles). A slot's T consecutive tokens span at most
+    n = (T + 2 PS - 2) // PS physical pages. This gathers those n*B pages
+    out of the pool, merges the window in registers (a float32 one-hot
+    select over the n*PS staging positions), and scatters n*B WHOLE pages
+    back: regular page-sized DMAs that leave the pool in the layout the
+    Pallas kernel reads. The B*T-row scatter does not: compiled for the v5e
+    it makes XLA keep the carried pool slot-major and copy it WHOLE to the
+    kernel's layout and back in every layer (3.8 GB of temporaries in the
+    decode program at the benchmark's shapes, PERF.md 6, PR 26), which is
+    why windows longer than a page (a riding piece, suffix and chunked
+    prefill) take this route.
 
-    TILES (``_write_window_to_tiles``: 1 < T <= 16 over a full-precision
-    pool whose pages are whole sublane tiles). What PR 26 found holds for
-    ROWS; a sublane tile (16 rows of bfloat16, 8 of float32) is the unit
-    the pool's device layout is made of, so the page axis splits into
-    tiles by a reshape that moves no byte, and gathering and scattering
-    the tiles a window touches leaves the pool where it stands (compiled
-    for the v5e: tests/test_tpu_compile_selfdraft.py). A draft-and-verify
-    step's window of two rows staged two pages of 256 rows a slot and layer
-    and merged them through float32, 4.3 ms a step for 1.5 MB of rows; by
-    tiles the same write is 0.4 ms (PERF.md 6, PR 54).
+    TILES (``_write_window_to_tiles``: 1 <= T <= 16 over a full-precision
+    pool whose pages are whole sublane tiles: every decode step's one row,
+    a draft-and-verify step's two, a denoise step's eight). What PR 26
+    found holds for ROWS; a sublane tile (16 rows of bfloat16, 8 of
+    float32) is the unit the pool's device layout is made of, so the page
+    axis splits into tiles by a reshape that moves no byte, and gathering
+    and scattering the tiles a window touches leaves the pool where it
+    stands (compiled for the v5e: tests/test_tpu_compile_selfdraft.py,
+    tests/test_tpu_compile_shortconv.py). A draft-and-verify step's window
+    of two rows staged two pages of 256 rows a slot and layer and merged
+    them through float32, 4.3 ms a step for 1.5 MB of rows; by tiles the
+    same write is 0.4 ms (PERF.md 6, PR 54). A decode step's one row
+    staged one whole page a slot, pool and layer: 1.07 GB moved a step to
+    store 2 MB of K/V rows at 256 slots over pages of 256 rows; its one
+    tile is a sixteenth of that (PERF.md 6, PR 56).
 
     Both routes are asserted equal to the scatter, bit for bit, in
     tests/test_ops.py::test_window_write_matches_row_scatter.
@@ -377,10 +383,10 @@ def _write_window_to_whole_pages(pages, new_kv, block_tables,
     PS = pages.shape[-2]
     maxP = block_tables.shape[1]
     # T consecutive tokens starting anywhere in a page touch at most this
-    # many pages: 1 for T == 1 (it never crosses a boundary — a second
-    # page would be gathered and rewritten byte-identical on the hottest
-    # per-step path), 2 up to T == PS + 1 (verify windows), 5 and 9 for
-    # the 256- and 512-token suffix / chunked-prefill buckets at PS 64
+    # many pages: 1 for T == 1 (it never crosses a boundary: one row over
+    # quantized pages, or over a page that is not whole tiles), 2 up to
+    # T == PS + 1, 5 and 9 for the 256- and 512-token suffix /
+    # chunked-prefill buckets at PS 64
     n_stage = (T + 2 * PS - 2) // PS
     offs = jnp.arange(T, dtype=jnp.int32)
     pos = start_positions[:, None] + offs                     # [B, T]
@@ -458,9 +464,9 @@ _MAX_TILE_WINDOW = 16     # rows of the longest window that stages tiles
 def _window_tile_rows(pages, T: int) -> int:
     """Rows of the sublane tile a window of ``T`` rows stages instead of
     whole pages (16 of bfloat16, 8 of float32), or 0 where the window takes
-    the whole-page route: one row, more than ``_MAX_TILE_WINDOW``,
-    quantized pages, or a page that is not whole tiles."""
-    if isinstance(pages, QuantPages) or not 1 < T <= _MAX_TILE_WINDOW:
+    the whole-page route: more than ``_MAX_TILE_WINDOW`` rows, quantized
+    pages, or a page that is not whole tiles."""
+    if isinstance(pages, QuantPages) or not 1 <= T <= _MAX_TILE_WINDOW:
         return 0
     rows = 32 // jnp.dtype(pages.dtype).itemsize
     return rows if pages.shape[-2] % rows == 0 else 0
@@ -471,11 +477,12 @@ def _write_window_to_tiles(pages, new_kv, block_tables, start_positions,
     """``write_window_to_pages`` for a short window over a full-precision
     pool: the page axis is viewed as ``PS / R`` tiles of ``R`` rows (a
     reshape on a tile boundary: no byte moves), the n = (T + 2 R - 2) // R
-    tiles a slot's window can touch (two for T <= R; the second may be the
-    next page's first) are gathered, the window's rows selected in on the
-    pool's own dtype, and the tiles scattered back. A staged tile that
-    takes no row (the window inside one tile, a tile past the table's last
-    page, masked rows, a slot over scratch) goes to scratch page 0."""
+    tiles a slot's window can touch (one for a single row, which crosses
+    nothing; two for 1 < T <= R, the second may be the next page's first)
+    are gathered, the window's rows selected in on the pool's own dtype,
+    and the tiles scattered back. A staged tile that takes no row (the
+    window inside one tile, a tile past the table's last page, masked
+    rows, a slot over scratch) goes to scratch page 0."""
     B, T, Nkv, D = new_kv.shape
     PS = pages.shape[-2]
     maxP = block_tables.shape[1]

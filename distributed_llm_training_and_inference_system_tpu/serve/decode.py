@@ -115,9 +115,10 @@ def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl, block=0):
     and attend the window over them: (out, (new k_pages, new v_pages)).
     ``block`` > 0: under the block rule (``ModelConfig.attention_block``)."""
     # K and V live in pages. Every T stages a part of the pool and
-    # merges the window in: whole pages (T == 1: one page a slot),
-    # QuantPages and Int4Pages with quantize-on-write fused into it,
-    # or the sublane tiles a short window touches. A ROW scatter lays
+    # merges the window in: the sublane tiles a window of 1 to 16 rows
+    # touches (a decode step's one row: one tile a slot), whole pages
+    # for a longer window, and for QuantPages and Int4Pages with
+    # quantize-on-write fused into it. A ROW scatter lays
     # the pool out slot-major, the Pallas kernel reads it head-major,
     # and the WHOLE pool is copied between the two in every layer
     # (PERF.md 6, PR 26, has both step times)

@@ -3,20 +3,22 @@ against the tile route of ``ops/paged_attention.py write_window_to_pages``.
 
     chiprun -- python experiments/window_write_alone.py [--out FILE]
 
-Two pools at the cells' shapes: the self-drafting cell's latent pool
+Four pools at the cells' shapes: the self-drafting cell's latent pool
 (joyai-llm-flash-8l-ep2: 9 layers x 1,017 pages x 1 x 256 rows x 640 bf16,
 one write a layer under ``mla_page_write``) and the diffusion cell's K/V
 pools (sdar-30b-a3b-7l: 7 layers x 2,179 pages x 4 heads x 64 rows x 128
-bf16 each, K and V written a layer under ``kv_page_write``), 64 slots, every
-slot's window at a position of its own (a few cross a tile, a few a page).
-One jitted program walks the layers of the donated pools ``ROUNDS`` times,
-so the host clock around ``block_until_ready`` reads the writes and not the
-dispatch (the fastest of five batches). For T = 1, 2, 8 rows a slot and
-each route: us a layer (K and V together for the K/V pools), and the two
-routes' pools held equal bit for bit outside scratch page 0. The function
-itself takes the tile route for 1 < T <= 16 alone; T = 1 through the tile
-route is what the follow-up in ROADMAP asks about. Fails (exit 2) without a
-TPU.
+bf16 each, K and V written a layer under ``kv_page_write``), 64 slots, for
+T = 1, 2, 8 rows a slot; the short-conv cell's K/V pools (lfm2-8b-a1b-16l:
+4 layers x 1,430 pages x 4 paired heads x 256 rows x 128, 256 slots) and
+Mistral's (mistral-7b-16l: 16 layers x 715 pages x 8 heads x 64 rows x 128,
+32 slots) for the decode step's T = 1. Every slot's window at a position of
+its own (a few cross a tile, a few a page). One jitted program walks the
+layers of the donated pools ``ROUNDS`` times, so the host clock around
+``block_until_ready`` reads the writes and not the dispatch (the fastest of
+five batches). For each T and each route, called directly
+(``_write_window_to_whole_pages``, ``_write_window_to_tiles``): us a layer
+(K and V together for the K/V pools), and the two routes' pools held equal
+bit for bit outside scratch page 0. Fails (exit 2) without a TPU.
 """
 
 from __future__ import annotations
@@ -35,20 +37,21 @@ import numpy as np
 
 PKG = "distributed_llm_training_and_inference_system_tpu"
 # (pools a layer, layers, pages, kv heads, page rows, row width, pages a
-# slot holds, the table's width)
-POOLS = {"joyai_latent": (1, 9, 1017, 1, 256, 640, 15, 49),
-         "sdar_kv": (2, 7, 2179, 4, 64, 128, 32, 32)}
-SLOTS, WINDOWS = 64, (1, 2, 8)
+# slot holds, the table's width, slots, windows)
+POOLS = {"joyai_latent": (1, 9, 1017, 1, 256, 640, 15, 49, 64, (1, 2, 8)),
+         "sdar_kv": (2, 7, 2179, 4, 64, 128, 32, 32, 64, (1, 2, 8)),
+         "lfm2_kv": (2, 4, 1430, 4, 256, 128, 5, 8, 256, (1,)),
+         "mistral_kv": (2, 16, 715, 8, 64, 128, 22, 32, 32, (1,))}
 ROUNDS, BATCHES = 8, 5
 
 
-def traffic(rng, pages, held, width, PS, T):
+def traffic(rng, pages, held, width, PS, T, slots):
     """Tables of distinct pages a slot (scratch page 0 nobody's) and starts
     that put some windows across a tile's and some across a page's end."""
-    ids = 1 + rng.permutation(pages - 1)[:SLOTS * held].reshape(SLOTS, held)
-    tables = np.zeros((SLOTS, width), np.int32)
+    ids = 1 + rng.permutation(pages - 1)[:slots * held].reshape(slots, held)
+    tables = np.zeros((slots, width), np.int32)
     tables[:, :held] = ids
-    starts = rng.integers(0, held * PS - T, SLOTS)
+    starts = rng.integers(0, held * PS - T, slots)
     starts[:8] = starts[:8] // 16 * 16 + 15         # the last row of a tile
     starts[8:12] = starts[8:12] // PS * PS + PS - 1   # ... and of a page
     starts[12] = held * PS - T                      # ends in the last page
@@ -57,7 +60,7 @@ def traffic(rng, pages, held, width, PS, T):
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--out", default="chiprun_out/pr54/window_write_alone.json")
+    ap.add_argument("--out", default="chiprun_out/window_write_alone.json")
     a = ap.parse_args()
     if jax.default_backend() != "tpu":
         print("no TPU: nothing measured", file=sys.stderr)
@@ -81,16 +84,17 @@ def main() -> int:
     results = {"device": str(jax.devices()[0].device_kind), "cases": []}
     bad = []
     rng = np.random.default_rng(54)
-    for name, (n, L, NP, Nkv, PS, D, held, width) in POOLS.items():
-        for T in WINDOWS:
-            tables, starts = traffic(rng, NP, held, width, PS, T)
-            ok = jnp.asarray(rng.random((SLOTS, T)) > 0.1)
+    for name, (n, L, NP, Nkv, PS, D, held, width, slots, windows) in (
+            POOLS.items()):
+        for T in windows:
+            tables, starts = traffic(rng, NP, held, width, PS, T, slots)
+            ok = jnp.asarray(rng.random((slots, T)) > 0.1)
             keys = jax.random.split(jax.random.PRNGKey(T), 2 * n)
-            rows = tuple(jax.random.normal(k, (SLOTS, T, Nkv, D),
+            rows = tuple(jax.random.normal(k, (slots, T, Nkv, D),
                                            jnp.bfloat16) for k in keys[:n])
             fresh = lambda: tuple(jax.random.normal(
                 k, (L, NP, Nkv, PS, D), jnp.bfloat16) for k in keys[n:])
-            row = {"pool": name, "T": T}
+            row = {"pool": name, "slots": slots, "T": T}
             kept = {}
             for route, write in routes.items():
                 fn = walk(write, L)

@@ -63,3 +63,34 @@ def test_chip_peaks_cpu_has_none_and_unknown_tpu_is_an_error():
     assert platform.chip_peaks("cpu", "cpu") is None
     with pytest.raises(platform.UnknownChipError):
         platform.chip_peaks("tpu", "TPU v99")
+
+
+_TILES_1 = ("12:00:01 INFO llmctl.impl: impl window_page_write=tiles "
+            "(T=1 over bfloat16(4, 1430, 4, 256, 128))")
+_TILES_2 = ("12:00:01 INFO llmctl.impl: impl window_page_write=tiles "
+            "(T=2 over bfloat16(9, 1017, 1, 256, 640))")
+_PAGES_1 = ("12:00:01 INFO llmctl.impl: impl window_page_write=pages "
+            "(T=1 over int8(16, 715, 8, 64, 128))")
+_PAGES_256 = ("12:00:02 INFO llmctl.impl: impl window_page_write=pages "
+              "(T=256 over bfloat16(4, 1430, 4, 256, 128))")
+
+
+@pytest.mark.parametrize("log,rows,passes", [
+    ([_TILES_1, _PAGES_256], 1, True),      # a decode step and its piece
+    ([_TILES_2, _PAGES_256], 2, True),      # a draft-and-verify step
+    ([_TILES_2, _PAGES_256], 1, False),     # tiles, but not the one row
+    ([_PAGES_1, _PAGES_256], 1, False),     # the one row by whole pages
+    ([], 1, False),
+], ids=["one-row", "two-rows", "other-window", "whole-pages", "no-line"])
+def test_smoke_demands_the_tile_write_of_the_window_it_names(log, rows,
+                                                             passes):
+    """The ``serve`` and ``shortconv`` phases fail unless the server's log
+    says a decode step's one row staged tiles, the ``selfdraft`` phase
+    unless its window of two did."""
+    import chip_smoke
+    text = "\n".join(["server ready", *log, "bye"])
+    if passes:
+        chip_smoke.require_tile_write(text, rows, "the window")
+    else:
+        with pytest.raises(chip_smoke.SmokeFailure, match=f"T={rows} "):
+            chip_smoke.require_tile_write(text, rows, "the window")

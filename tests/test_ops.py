@@ -1,5 +1,7 @@
 """Pallas kernels vs XLA reference numerics (interpret mode on CPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -345,11 +347,11 @@ def test_paged_attention_multi_window_is_causal():
 @pytest.mark.parametrize("layout", ["kv", "latent"])
 def test_window_write_matches_row_scatter(layout, dtype, T, monkeypatch):
     """write_window_to_pages must be elementwise identical to the B*T
-    row-scatter path on both of its routes: a short window (1 < T <= 16)
+    row-scatter path on both of its routes: a short window (1 <= T <= 16)
     over a full-precision pool stages the sublane tiles it touches (16 rows
-    of bfloat16, 8 of float32: two tiles, three for 16 rows of float32),
-    every other window whole pages (T = 1 and T = 17 here), and the route
-    is the one ``report_impl``'s line names. K/V pages [NP, Nkv, PS, D]
+    of bfloat16, 8 of float32: one tile for one row, else two, three for 16
+    rows of float32), every other window whole pages (T = 17 here), and the
+    route is the one ``report_impl``'s line names. K/V pages [NP, Nkv, PS, D]
     and the latent pool [L, NP, 1, PS, W] written at a traced layer;
     windows inside one tile, across a tile's end, across a page's end,
     ending with the table's last page (the staged tile past it is clipped
@@ -397,7 +399,7 @@ def test_window_write_matches_row_scatter(layout, dtype, T, monkeypatch):
                                          layer))
     want, got = both(pages0, layer)
     route, = {impl for op, impl, _ in seen if op == "window_page_write"}
-    assert route == ("tiles" if 1 < T <= 16 else "pages")
+    assert route == ("tiles" if 1 <= T <= 16 else "pages")
     assert got.dtype == pages0.dtype and got.shape == pages0.shape
     if layer is not None:
         np.testing.assert_array_equal(np.asarray(got[::2], np.float32),
@@ -438,6 +440,138 @@ def _assert_pages_equal(got, want, first_page=0):
         np.testing.assert_array_equal(
             np.asarray(g).view(np.uint8)[first_page:],
             np.asarray(w).view(np.uint8)[first_page:])
+
+
+# One row a slot (every decode step's write): (table, start, written?,
+# the page and row it lands in). PS = 32, three pages a slot; R is the
+# tile's rows (16 of bfloat16, 8 of float32). "plain" is live in every case.
+_ROW_PS, _ROW_MAXP = 32, 3
+_ONE_ROW = {
+    "tile-first-row": ([1, 2, 3], lambda R: _ROW_PS + R, True, 2),
+    "tile-last-row": ([4, 5, 6], lambda R: R - 1, True, 4),
+    "page-first-row": ([7, 8, 9], lambda R: _ROW_PS, True, 8),
+    "page-last-row": ([10, 11, 12], lambda R: _ROW_PS - 1, True, 10),
+    "table-last-row": ([13, 14, 15], lambda R: _ROW_MAXP * _ROW_PS - 1,
+                       True, 15),
+    "past-the-table": ([16, 17, 18], lambda R: _ROW_MAXP * _ROW_PS + 2,
+                       True, None),
+    "entry-of-0": ([19, 0, 0], lambda R: _ROW_PS + 3, True, None),
+    "scratch-slot": ([0, 0, 0], lambda R: 5, True, None),
+    "masked-row": ([20, 21, 22], lambda R: _ROW_PS + 8, False, None),
+    "before-0": ([23, 24, 25], lambda R: -3, True, None),
+    "plain": ([26, 27, 28], lambda R: 2 * _ROW_PS + 5, True, 28),
+}
+
+
+@functools.partial(jax.jit, static_argnames=("R",))
+def _one_row_three_ways(pages, rows, tables, starts, ok, in_table, layer, R):
+    """The row scatter (which clips a row past the table INTO its last page
+    and wraps one before position 0: masked there, and there alone), the
+    function, and both private routes."""
+    from distributed_llm_training_and_inference_system_tpu.ops import (
+        paged_attention as pa)
+    window = rows[:, None]
+    return (pa.write_token_to_pages(pages, rows, tables, starts,
+                                    ok & in_table, layer),
+            pa.write_window_to_pages(pages, window, tables, starts,
+                                     ok[:, None], layer),
+            pa._write_window_to_tiles(pages, window, tables, starts,
+                                      ok[:, None], layer, R),
+            pa._write_window_to_whole_pages(pages, window, tables, starts,
+                                            ok[:, None], layer))
+
+
+@pytest.mark.parametrize("where", ["all", *(w for w in _ONE_ROW
+                                            if w != "plain")])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("layout", ["kv", "latent"])
+def test_one_row_window_write_matches_row_scatter(layout, dtype, where,
+                                                  monkeypatch):
+    """A decode step's window of ONE row takes the tile route and stages
+    one tile a slot (n = (1 + 2 R - 2) // R = 1: a row crosses nothing).
+    Each placement alone beside a plain slot (every other slot over
+    scratch), and all of them in one batch: the pool is the row scatter's,
+    the whole-page route's and the one written out by hand here, bit for
+    bit outside scratch page 0. The window routes take the rows UNMASKED
+    past the table's end and before position 0 and must drop them."""
+    from distributed_llm_training_and_inference_system_tpu.ops import (
+        paged_attention as pa)
+    seen = []
+    monkeypatch.setattr(pa, "report_impl", lambda *line: seen.append(line))
+    dtype = jnp.dtype(dtype)
+    R = 32 // dtype.itemsize
+    rng = np.random.default_rng(len(where))
+    NP, PS, maxP, L = 29, _ROW_PS, _ROW_MAXP, 3
+    Nkv, D = (2, 8) if layout == "kv" else (1, 24)
+    shape = (NP, Nkv, PS, D) if layout == "kv" else (L, NP, Nkv, PS, D)
+    layer = None if layout == "kv" else jnp.int32(1)
+    pages0 = jnp.asarray(rng.normal(size=shape), dtype)
+    live = [w in (where, "plain") or where == "all" for w in _ONE_ROW]
+    tables = jnp.asarray([t if on else [0, 0, 0] for (t, *_), on in
+                          zip(_ONE_ROW.values(), live)], jnp.int32)
+    starts = jnp.asarray([s(R) for _, s, *_ in _ONE_ROW.values()], jnp.int32)
+    ok = jnp.asarray([o for *_, o, _ in _ONE_ROW.values()])
+    rows = jnp.asarray(rng.normal(size=(len(_ONE_ROW), Nkv, D)), dtype)
+    scatter, got, tiles, whole = _one_row_three_ways(
+        pages0, rows, tables, starts, ok,
+        (starts >= 0) & (starts < maxP * PS), layer, R=R)
+    # (the jitted helper is traced once a layout and dtype: trace the
+    # function again for the line it reports)
+    seen.clear()
+    jax.eval_shape(lambda *call: pa.write_window_to_pages(*call), pages0,
+                   rows[:, None], tables, starts, ok[:, None], layer)
+    assert seen == [("window_page_write", "tiles",
+                     f"T=1 over {dtype.name}{shape}")]
+    by_hand = np.array(pages0)
+    mine = by_hand if layer is None else by_hand[1]
+    for b, ((_, start, _, page), on) in enumerate(zip(_ONE_ROW.values(),
+                                                      live)):
+        if on and page is not None:
+            mine[page, :, start(R) % PS] = np.asarray(rows[b])
+    for name, pool in (("scatter", scatter), ("function", got),
+                       ("tiles", tiles), ("pages", whole)):
+        assert pool.dtype == dtype and pool.shape == shape, name
+        pool = np.asarray(pool)
+        if layer is not None:       # the other layers keep every byte
+            np.testing.assert_array_equal(pool[::2], by_hand[::2], name)
+            pool = pool[1]
+        np.testing.assert_array_equal(pool[1:], mine[1:], name)
+
+
+@pytest.mark.parametrize("pool", ["int8", "int4", "ragged-page"])
+def test_one_row_window_keeps_whole_pages_where_tiles_cannot(pool,
+                                                             monkeypatch):
+    """T = 1 over ``QuantPages``, over ``Int4Pages`` and over a page that
+    is not whole sublane tiles (24 rows of bfloat16) still stages whole
+    pages, says so, and equals the row scatter: a row mid-page, in the
+    table's last page, into an entry of 0, a scratch slot, a masked row."""
+    from distributed_llm_training_and_inference_system_tpu.ops import (
+        paged_attention as pa)
+    seen = []
+    monkeypatch.setattr(pa, "report_impl", lambda *line: seen.append(line))
+    L, NP, Nkv, PS, D, B = 2, 12, 2, 24, 64, 5
+    ks = jax.random.split(jax.random.PRNGKey(56), 2)
+    pages0 = _layered_pool("bf16" if pool == "ragged-page" else pool, ks[0],
+                           L, NP, Nkv, PS, D)
+    rows = jax.random.normal(ks[1], (B, Nkv, D), jnp.float32)
+    tables = jnp.asarray([[1, 2, 3], [4, 5, 6], [7, 0, 0], [0, 0, 0],
+                          [8, 9, 10]], jnp.int32)
+    starts = jnp.asarray([PS + 7, 3 * PS - 1, PS + 1, 4, 2 * PS], jnp.int32)
+    ok = jnp.asarray([True, True, True, True, False])
+    layer = jnp.int32(1)
+    got, want = jax.jit(lambda pages: (
+        pa.write_window_to_pages(pages, rows[:, None], tables, starts,
+                                 ok[:, None], layer),
+        pa.write_token_to_pages(pages, rows, tables, starts, ok, layer))
+    )(pages0)
+    assert {line[1] for line in seen if line[0] == "window_page_write"} == {
+        "pages"}
+    _assert_pages_equal(_layer_of(got, 1), _layer_of(want, 1), first_page=1)
+    _assert_pages_equal(_layer_of(got, 0), _layer_of(pages0, 0))
+    wrote = np.asarray(jax.tree.leaves(_layer_of(got, 1))[0]) != np.asarray(
+        jax.tree.leaves(_layer_of(pages0, 1))[0])
+    assert wrote[[2, 6]].any(axis=(1, 2, 3)).all()
+    assert not wrote[[1, 3, 4, 5, 7, 8, 9, 10, 11]].any()
 
 
 _WINDOW_TABLES = [[1, 2, 3],      # window crosses a page boundary

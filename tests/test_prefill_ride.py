@@ -560,25 +560,35 @@ def test_a_layer_table_model_keeps_the_parents_decode_program(name, over):
 
 # the decode program (``_decode_jit``, pieces and all) of this module's engine
 PARENTS_DECODE = {
-    "gpt-test": "54a27c5775c96003af68301635b64e0cd98140d0860de0d3759c8b6b23d64ddc",
-    "olmoe-test": "55e215fc630808589c68ad79c8c7e3d3d2b79fb33059922235717bceadeb5c19",
-    "xing-test": "d64f8944fe2118229021681b196c58cd7db4511bed933866e5c2fd8a824d25da",
+    # (PR 56 MEANT to move these five: a decode step's window of ONE row
+    # stages the sublane tile it touches, not a whole page a slot. The
+    # hashes are of PR 56's own tree; the parent's (be7b4a5, the text PR 45's
+    # parent lowered) were 54a27c57...23d64ddc, 55e215fc...adeb5c19,
+    # d64f8944...824d25da, ca8bac76...01b24b26 and 74ad821e...1b82677f. This
+    # module's pages are 8 rows of float32, one tile each: the text moves
+    # though the staged bytes here do not. The piece of 16 rows in the same
+    # programs keeps whole pages, as do all the prefill programs below.)
+    "gpt-test": "88c47416ff8572777baa8b64cbbae043f6b08767f2eec3c12cf1d2a28f089c76",
+    "olmoe-test": "d0733c28aac2ef91a3a4d13b92ea72ddc6574b84c8dc3b6c35b3aae4b891c977",
+    "xing-test": "3755ca797e79d49e178d1335aab466a28358acbcf4a4b503bbede6048687bcc9",
     # (PR 47 MEANT to move this one: the denoise window is two blocks and
     # the commit rides the next block's first forward; PR 54 MEANT to move
     # it again: the window of 8 rows stages the sublane tiles it touches,
     # not two whole pages a slot. The hash is of PR 54's own tree; the
-    # parent's (0fe0c16, PR 47's text) was 584661dc...d277e604)
+    # parent's (0fe0c16, PR 47's text) was 584661dc...d277e604. PR 56 left
+    # it where it was)
     "sdar-test": "9c5ed40f7256ea0a2a98d2ed4b09d28d38910ef29381eab13627846fa7be5c7c",
-    LINEAR: "ca8bac7665a0d8b5f1011e2dcd5c2ed45b6ca30666c9e286dbf4477101b24b26",
-    HYBRID: "74ad821e06394a100e91d3e4d063dc2463f98e81ca46099914c6db821b82677f",
+    LINEAR: "a0f4446cfa5c5724085dd4de9cb1ef0cef09aecf5a60cb43d30ba2d8b62e7001",
+    HYBRID: "f7ca03e9c55c9e6112f84a5b3be00fbc95d0eb5684e51d0687d549279946fab3",
 }
 
 
 @pytest.mark.parametrize("name", list(PARENTS_DECODE))
 def test_the_other_models_decode_programs_are_the_parents(name):
     """The RIDING decode programs of the uniform stack (dense, MoE), of the
-    latent, the delta-rule and the hybrid table lower to the parent's text,
-    and the diffusion model's denoise program to PR 54's (PR 43 and 44 taught the
+    latent, the delta-rule and the hybrid table lower to PR 56's text (the
+    parent's but for the one-row write, which stages a tile), and the
+    diffusion model's denoise program to PR 54's (PR 43 and 44 taught the
     table walk a recurrent layer's piece by one seam, ``recur_at``; PR 45
     made what a step and a dispatch return a record)."""
     eng = _engine(name)

@@ -128,7 +128,10 @@ def test_carrying_decode_program_fits_the_chip(decode_program, as_tpu):
     and through the conv from its slot's two rows, the grouped matmul over
     all 32 experts. No copy of a page pool, of an expert stack or of the
     embedding; the conv pool, the K and V pools donated and aliased;
-    weights + pools + temporaries inside the chip's 15.75 GiB."""
+    weights + pools + temporaries inside the chip's 15.75 GiB. A step's one
+    row a slot stages its sublane tile and not its page (PR 56): the
+    program holds no whole page of each of the 256 slots, in the pool's
+    dtype or as the float32 one-hot product that merged a row into it."""
     text, mem, weights = decode_program(PS)
     assert "tpu_custom_call" in text
     for kernel in ("paged_attention", "paged_attention_mq", "moe_gmm"):
@@ -139,6 +142,12 @@ def test_carrying_decode_program_fits_the_chip(decode_program, as_tpu):
                        "bf16[" + ",".join(map(str, POOL[1:])) + "]",
                        "bf16[14,32,2048,1792]", "bf16[14,32,1792,2048]",
                        "bf16[65536,2048]"], fused_into_at_most=32 << 20)
+    B, (_, _, pairs, rows, lanes) = CONFIG["serve"]["max_batch_size"], POOL
+    for staged in (f"bf16[{B},{pairs},{rows},{lanes}]",
+                   f"bf16[{B},{rows},{pairs},{lanes}]",
+                   f"f32[{B},{rows},{pairs},{lanes}]"):
+        assert staged not in text, f"a whole page a slot is staged: {staged}"
+    assert f"bf16[{B},{pairs},16,{lanes}]" in text      # the tile a slot
     pools = 2 * 2 * POOL[0] * POOL[1] * POOL[2] * POOL[3] * POOL[4]
     conv = 12 * 2 * 256 * 2048 * 2
     assert 10.75e9 < weights < 10.9e9
