@@ -138,6 +138,9 @@ class FleetKVStore:
         # — it never made it down a tier).
         self._pending: "OrderedDict[bytes, tuple]" = OrderedDict()
         self._pending_max = 256
+        # the page the worker has popped and is still encoding: the
+        # barrier (flush_pending) waits for it too
+        self._encoding = False
         self._work = threading.Event()
         self._encoder: Optional[threading.Thread] = None
         self._dram: "OrderedDict[bytes, _Entry]" = OrderedDict()
@@ -203,7 +206,11 @@ class FleetKVStore:
                     if not self._pending:
                         break
                     h, (batch, col) = self._pending.popitem(last=False)
-                self._demote_page(h, _page_slice(batch, col))
+                    self._encoding = True
+                try:
+                    self._demote_page(h, _page_slice(batch, col))
+                finally:
+                    self._encoding = False
 
     def flush_pending(self, timeout_s: float = 10.0) -> None:
         """Wait until the background encoder drained its queue (tests,
@@ -212,7 +219,7 @@ class FleetKVStore:
         self._work.set()
         while time.monotonic() < deadline:
             with self._lock:
-                busy = bool(self._pending)
+                busy = bool(self._pending) or self._encoding
             if not busy:
                 return
             time.sleep(0.002)

@@ -16,16 +16,10 @@ pages of 8 tokens, 8 steps a dispatch (the cells' length), a closed loop of
 
 import collections
 
-import jax
 import numpy as np
 import pytest
 
-from distributed_llm_training_and_inference_system_tpu.config import get_model_config
-from distributed_llm_training_and_inference_system_tpu.config.schema import ServeConfig
-from distributed_llm_training_and_inference_system_tpu.models import init
-from distributed_llm_training_and_inference_system_tpu.ops import kda
 from distributed_llm_training_and_inference_system_tpu.serve import (
-    InferenceEngine,
     Request,
     SamplingParams,
 )
@@ -34,29 +28,18 @@ from distributed_llm_training_and_inference_system_tpu.serve.scheduler import (
     RequestState,
 )
 
-PS, STEPS, SLOTS, CALLERS = 8, 8, 4, 8
-LINEAR = "kimi-linear-test"
+import serving_support as support
+from serving_support import LINEAR, SLOTS, idle
+
+pytestmark = pytest.mark.usefixtures("short_kda_chunks")
+
+# 8 steps a dispatch, the cells' length, where the shared shapes have 4: a
+# reply of 12 tokens hands its slot back with its last tokens still owed,
+# and the stop-token case picks its token among those
+STEPS, CALLERS = 8, 8
+EIGHT_STEPS = dict(decode_steps_per_dispatch=STEPS)
 MODELS = ["gpt-test", "olmoe-test", "xing-test", "nemotron-h-test", LINEAR]
 CLASSES = ("useful", "overrun", "prompt_wait", "empty")
-
-
-@pytest.fixture(autouse=True, scope="module")
-def _short_kda_chunks():
-    plain, kda.CHUNK = kda.CHUNK, 8
-    yield
-    kda.CHUNK = plain
-
-
-def _engine(name, **over):
-    cfg = get_model_config(name)
-    opts = dict(model=name, max_batch_size=SLOTS, max_seq_len=256,
-                prefill_chunk=32, kv_block_size=PS, dtype="float32",
-                decode_steps_per_dispatch=STEPS)
-    if name == LINEAR:
-        opts["chunked_prefill_tokens"] = InferenceEngine.RIDE_PAGES * PS
-    opts.update(over)
-    return InferenceEngine(cfg, ServeConfig(**opts),
-                           params=init(cfg, jax.random.PRNGKey(0)), seed=0)
 
 
 def _late(eng):
@@ -99,14 +82,12 @@ def _closed_loop(eng, reqs, callers=CALLERS):
 
 
 def _idle(eng):
+    """... and no dispatch in flight, nobody leaving, nothing pinned."""
     assert eng._pending is None and not eng.scheduler.leaving
-    assert eng._reserved_pages == 0 and not eng._reserved_by
-    assert not eng._riding and not eng._req_slot and not eng.active.any()
+    assert not eng._reserved_by
     assert not eng._prefix_pins and not eng._snapshot_pins
-    assert all(r is None for r in eng.scheduler.slots)
     assert eng.scheduler.active_count == 0
-    # every page is free or kept for a prefix hit: none is held by a slot
-    assert eng.kv.free_pages == eng.kv.num_pages - 1
+    idle(eng)
 
 
 @pytest.fixture(scope="module", params=MODELS)
@@ -115,8 +96,9 @@ def loops(request):
     early, and releasing at the apply. (engine, requests, what the clients
     saw, stats) of each."""
     out = []
-    for make in (_engine, lambda name: _late(_engine(name))):
-        eng, reqs = make(request.param), _pool(24)
+    for release in (lambda eng: eng, _late):
+        eng = release(support.engine(request.param, **EIGHT_STEPS))
+        reqs = _pool(24)
         out.append((eng, reqs, _closed_loop(eng, reqs), eng.stats()))
     return out
 
@@ -175,10 +157,13 @@ def test_the_counter_is_reset_with_the_ledger(loops):
 
 # -- a request that has left its slot ---------------------------------------
 
-@pytest.fixture(scope="module", params=MODELS)
-def engine(request):
-    """One engine a model for the cases below: each leaves it idle."""
-    return _engine(request.param)
+@pytest.fixture(scope="module")
+def engine(loops):
+    """One engine a model for the cases below, the loop's that hands back
+    early, found idle with its counters reset: each leaves it idle."""
+    eng = loops[0][0]
+    _idle(eng)
+    return eng
 
 
 class _Boom(Exception):
@@ -339,12 +324,9 @@ def test_a_leaving_request_in_flight_at_a_failure_is_failed_once(engine, then):
 # -- the paths that keep the late release ------------------------------------
 
 def test_generation_by_diffusion_never_hands_back():
-    cfg = get_model_config("sdar-test")
-    eng = InferenceEngine(
-        cfg, ServeConfig(model=cfg.name, max_batch_size=SLOTS,
-                         max_seq_len=128, kv_block_size=16, dtype="float32",
-                         prefill_chunk=16, decode_steps_per_dispatch=STEPS),
-        params=init(cfg, jax.random.PRNGKey(0)), seed=0)
+    # (pages and buckets of its block of 16 tokens)
+    eng = support.engine("sdar-test", kv_block_size=16, prefill_chunk=16,
+                         **EIGHT_STEPS)
     pipelined = []
     step = eng.step
     def spy():
@@ -364,7 +346,7 @@ def test_generation_by_diffusion_never_hands_back():
     dict(pipelined_decode=False),
 ], ids=["static scheduler", "no dispatch in flight"])
 def test_an_engine_with_no_dispatch_in_flight_never_hands_back(over):
-    eng = _engine("gpt-test", **over)
+    eng = support.engine("gpt-test", **EIGHT_STEPS, **over)
     reqs = _pool(12)
     seen = _closed_loop(eng, reqs)
     assert all(reason == "length" and n == 1 for _, reason, n in seen.values())
@@ -375,7 +357,7 @@ def test_an_engine_with_no_dispatch_in_flight_never_hands_back(over):
 def test_nobody_waiting_nobody_hands_back():
     """As many requests as slots: each ends inside a dispatch in flight
     with nobody waiting for its slot, and the release stays at the apply."""
-    eng = _engine("gpt-test")
+    eng = support.engine("gpt-test", **EIGHT_STEPS)
     in_flight, step = [], eng.step
 
     def spy():
@@ -396,7 +378,8 @@ def test_a_successor_that_does_not_ride_is_prefilled_behind_the_dispatch():
     """An engine whose prompts never ride (a prefill-complete hook is set)
     still hands back: the successor's prefill program queues behind the
     dispatch in flight, which is fetched and applied in the same step."""
-    engines = [_engine("gpt-test"), _late(_engine("gpt-test"))]
+    engines = [support.engine("gpt-test", **EIGHT_STEPS),
+               _late(support.engine("gpt-test", **EIGHT_STEPS))]
     seen = []
     for eng in engines:
         eng.on_prefill_complete = lambda req: None

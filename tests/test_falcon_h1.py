@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from benchmark.reference import parallel_decoder
 from distributed_llm_training_and_inference_system_tpu.config import get_model_config
 from distributed_llm_training_and_inference_system_tpu.config.presets import (
@@ -33,11 +34,9 @@ from distributed_llm_training_and_inference_system_tpu.config.schema import (
     ConfigError,
     ModelConfig,
     MupConfig,
-    ServeConfig,
 )
 from distributed_llm_training_and_inference_system_tpu.models import gpt
 from distributed_llm_training_and_inference_system_tpu.serve import (
-    InferenceEngine,
     Request,
     SamplingParams,
     kv_cache,
@@ -69,7 +68,7 @@ def seeded(cfg, seed=0):
     """``gpt.init`` with the vectors it leaves trivial made visible: the
     skip ``D``, the gated norm's scale and every layer norm's scale (a
     dropped ``D`` or a unit norm hides behind its own absence)."""
-    params = gpt.init(cfg, jax.random.PRNGKey(seed))
+    params = support.params_of(cfg, seed)
     key = jax.random.PRNGKey(seed + 100)
 
     def uniform(i, like, lo, hi):
@@ -89,11 +88,6 @@ def seeded(cfg, seed=0):
 @pytest.fixture(scope="module")
 def params(cfg):
     return seeded(cfg)
-
-
-def _tokens(n, seed=0):
-    return [int(t) for t in
-            np.random.default_rng(seed).integers(1, 250, n)]
 
 
 def _ref(params, tokens, wrong=None):
@@ -196,9 +190,9 @@ def test_the_benchmarks_configuration_counts_as_the_issue_reckons():
 # -- the forward, the gradient, the mutations ------------------------------------
 
 def test_forward_matches_the_reference(cfg, params):
-    toks = _tokens(50)
+    toks = support.tokens(50)
     with jax.default_matmul_precision("highest"):
-        got = gpt.forward(params, jnp.asarray(toks)[None], cfg)[0]
+        got = support.forward(params, [toks], cfg)[0]
     want = _ref(params, toks)
     assert np.abs(np.asarray(got) - want).max() < TOL
     assert want.std() > 1.0             # logits that say something
@@ -210,15 +204,15 @@ def test_the_gradient_is_the_references(cfg, params):
     multiplier) against ``jax.grad`` through the reference's loop. Float32
     on both sides; leaves are held to 1e-3 of their own largest entry (the
     order of additions again, through a backward pass)."""
-    toks = jnp.asarray(_tokens(40, seed=3))
+    toks = jnp.asarray(support.tokens(40, seed=3))
 
     def loss_of(logits):
         logp = jax.nn.log_softmax(logits[:-1], -1)
         return -jnp.mean(jnp.take_along_axis(logp, toks[1:, None], -1))
 
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(lambda p: loss_of(
-            gpt.forward(p, toks[None], cfg)[0]))(params)
+        got = jax.jit(jax.grad(lambda p: loss_of(
+            gpt.forward(p, toks[None], cfg)[0])))(params)
     want = jax.grad(lambda p: loss_of(
         parallel_decoder.logits(p, toks, PUBLISHED)))(params)
     flat_got = jax.tree_util.tree_leaves_with_path(got)
@@ -249,9 +243,9 @@ def test_the_comparison_fails_each_mutation(cfg, params, wrong):
     program: the tolerance sees a dropped branch, each multiplier set to 1,
     the gate moved behind the norm, one group for two, B and C swapped, the
     skip dropped, a bfloat16 state, no rope, float8 operands."""
-    toks = _tokens(50)
+    toks = support.tokens(50)
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(gpt.forward(params, jnp.asarray(toks)[None], cfg)[0])
+        got = np.asarray(support.forward(params, [toks], cfg)[0])
     moved = np.abs(got - _ref(params, toks, wrong)).max()
     assert moved > MUTATIONS[wrong] > TOL
 
@@ -273,6 +267,21 @@ def _pools(cfg, slots=4, n_pages=40):
     return jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32), state
 
 
+def _cold_program(params, padded, live, *, cfg):
+    return gpt.forward(
+        params, padded, cfg,
+        kv_cache=gpt.init_kv_cache(cfg, 1, padded.shape[1],
+                                   dtype=jnp.float32),
+        cache_offset=jnp.zeros((1,), jnp.int32), segment_ids=live,
+        return_ssm_state=True)
+
+
+def _decode_program(params, toks, pos, kp, vp, table, active, state, ride,
+                    *, cfg):
+    return decode_step_forward(params, toks, pos, kp, vp, table, cfg,
+                               active=active, ssm_state=state, ride=ride)
+
+
 def _cold_prefill(cfg, params, tokens, bucket, kp, vp, state, pages, slot):
     """What the engine's prefill program does: the dense forward over a
     padded bucket, every layer's K/V scattered into ``pages`` AND the
@@ -281,11 +290,8 @@ def _cold_prefill(cfg, params, tokens, bucket, kp, vp, state, pages, slot):
     padded = np.full((1, bucket), 7, np.int32)      # garbage padding
     padded[0, :n] = tokens
     live = (jnp.arange(bucket)[None] < n).astype(jnp.int32)
-    logits, (kd, vd), (tails, hs) = gpt.forward(
-        params, jnp.asarray(padded), cfg,
-        kv_cache=gpt.init_kv_cache(cfg, 1, bucket, dtype=jnp.float32),
-        cache_offset=jnp.zeros((1,), jnp.int32), segment_ids=live,
-        return_ssm_state=True)
+    logits, (kd, vd), (tails, hs) = support.program(_cold_program, cfg)(
+        params, jnp.asarray(padded), live)
 
     def paged(d):
         return d[:, 0].reshape(cfg.kv_layers, bucket // PS, PS,
@@ -307,11 +313,10 @@ def _decode(cfg, params, tok, pos, kp, vp, state, ride=None):
     """One decode step of four slots of which slot 1 is live."""
     toks = np.full(4, 11, np.int32)                 # idle slots' garbage
     toks[1] = tok
-    return decode_step_forward(
+    return support.program(_decode_program, cfg)(
         params, jnp.asarray(toks), jnp.full((4,), pos, jnp.int32), kp, vp,
-        jnp.asarray(TABLE), cfg,
-        active=jnp.asarray([False, True, False, False]), ssm_state=state,
-        ride=ride)
+        jnp.asarray(TABLE), jnp.asarray([False, True, False, False]), state,
+        ride)
 
 
 def test_prefill_then_decode_matches_the_reference(cfg, params):
@@ -319,7 +324,7 @@ def test_prefill_then_decode_matches_the_reference(cfg, params):
     cold prefill (padded bucket, garbage padding), which writes every
     layer's pages and arms the slot's state, then eight decode steps that
     read and write both pools of every layer. Idle slots' state stays."""
-    seq, n = _tokens(37 + 8, seed=2), 37
+    seq, n = support.tokens(37 + 8, seed=2), 37
     kp, vp, state = _pools(cfg)
     state = jax.tree_util.tree_map(lambda a: a + 0.5, state)   # leftovers
     got = np.zeros((len(seq), cfg.vocab_size), np.float32)
@@ -344,7 +349,7 @@ def test_the_key_multiplier_sits_on_the_keys(cfg, params):
     bilinear) and the same logits: no comparison of logits can fail it.
     What tells the two apart is what the cache KEEPS: the first layer's
     key rows in the pages are (a W_k) * key_multiplier, rotated."""
-    toks = _tokens(16, seed=4)
+    toks = support.tokens(16, seed=4)
     kp, vp, state = _pools(cfg)
     with jax.default_matmul_precision("highest"):
         _, kp, _, _ = _cold_prefill(cfg, params, toks, 16, kp, vp, state,
@@ -371,7 +376,7 @@ def test_a_riding_piece_matches_the_reference(cfg, params, n):
     row gives the prompt's logits, slot 1's rows stay the reference's, and
     slot 2 then decodes behind the pieces from what they left."""
     assert can_carry(cfg)
-    seq, prompt, C = _tokens(30 + 6, seed=5), _tokens(n + 3, seed=6), 16
+    seq, prompt, C = support.tokens(30 + 6, seed=5), support.tokens(n + 3, seed=6), 16
     kp, vp, state = _pools(cfg)
     want_seq, want_prompt = _ref(params, seq), _ref(params, prompt)
     with jax.default_matmul_precision("highest"):
@@ -395,12 +400,11 @@ def test_a_riding_piece_matches_the_reference(cfg, params, n):
         for j in range(n, n + 3):
             toks = np.full(4, 11, np.int32)
             toks[1], toks[2] = seq[pos], prompt[j]
-            step = decode_step_forward(
+            step = support.program(_decode_program, cfg)(
                 params, jnp.asarray(toks),
                 jnp.asarray([0, pos, j, 0], jnp.int32), kp, vp,
-                jnp.asarray(TABLE), cfg,
-                active=jnp.asarray([False, True, True, False]),
-                ssm_state=state)
+                jnp.asarray(TABLE), jnp.asarray([False, True, True, False]),
+                state, None)
             kp, vp, state = step.k_pages, step.v_pages, step.state
             lg = np.asarray(step.logits)
             assert np.abs(lg[1] - want_seq[pos]).max() < TOL
@@ -410,35 +414,24 @@ def test_a_riding_piece_matches_the_reference(cfg, params, n):
 
 # -- the engine ------------------------------------------------------------------
 
-def _serve_cfg(**over):
-    return ServeConfig(**{**dict(
-        model="falcon-h1-test", max_batch_size=4, max_seq_len=128,
-        dtype="float32", kv_block_size=PS, prefill_chunk=16,
-        decode_steps_per_dispatch=4), **over})
-
-
 @pytest.fixture(scope="module")
 def engine(cfg, params):
-    return InferenceEngine(cfg, _serve_cfg(), params=params)
-
-
-def _gaps(params, prompt, served):
-    lg = _ref(params, prompt + served[:-1])[len(prompt) - 1:]
-    return lg.max(-1) - lg[np.arange(len(served)), served]
+    return support.engine(cfg, params)
 
 
 def test_engine_serves_the_references_tokens(cfg, params, engine):
     """Six prompts over four slots (slots are REUSED after a release, and
     the later prompts RIDE the residents' decode steps): every served
     token is the reference's argmax."""
-    prompts = [_tokens(n, seed=s) for s, n in enumerate((36, 20, 36, 20, 36,
+    prompts = [support.tokens(n, seed=s) for s, n in enumerate((36, 20, 36, 20, 36,
                                                          20))]
     with jax.default_matmul_precision("highest"):
         reqs = engine.generate(prompts, SamplingParams(temperature=0.0,
                                                        max_tokens=10))
     for p, r in zip(prompts, reqs):
         assert len(r.generated_tokens) == 10
-        assert _gaps(params, p, r.generated_tokens).max() == 0.0
+        assert support.gaps(_ref, params, p,
+                            r.generated_tokens).max() == 0.0
     st = engine.stats()
     assert st["ssm"]["state_bytes"] == engine.kv.state_bytes() > 0
     assert st["ssm"]["slot_steps"] > 0
@@ -450,27 +443,28 @@ def test_engine_serves_the_references_tokens(cfg, params, engine):
 def test_a_prompt_rides_a_busy_engine_to_the_same_tokens(cfg, params):
     """Two residents decode (half the slots): what is admitted next rides
     their dispatches, through both mixers of every layer."""
-    eng = InferenceEngine(cfg, _serve_cfg(), params=params)
+    eng = support.engine(cfg, params)
     long = SamplingParams(temperature=0.0, max_tokens=40)
     with jax.default_matmul_precision("highest"):
         for i, n in enumerate((9, 13)):
             assert eng.scheduler.add_request(Request(
-                f"resident-{i}", _tokens(n, seed=20 + i), long))
+                f"resident-{i}", support.tokens(n, seed=20 + i), long))
         while eng.active.sum() < 2:
             eng.step()
-        prompt = _tokens(45, seed=30)
+        prompt = support.tokens(45, seed=30)
         req = Request("rider", prompt, SamplingParams(temperature=0.0,
                                                       max_tokens=8))
         assert eng.scheduler.add_request(req)
         eng.run_until_idle()
     assert eng.stats()["prefill_ride_tokens"] == 45
-    assert _gaps(params, prompt, req.generated_tokens).max() == 0.0
+    assert support.gaps(_ref, params, prompt,
+                        req.generated_tokens).max() == 0.0
 
 
 def test_a_repeated_prompt_is_prefilled_again(engine):
     """Prefix reuse by page hash is ON by default and wrong for a layer
     with a recurrent state: turned off and counted."""
-    prompt = _tokens(36, seed=5)
+    prompt = support.tokens(36, seed=5)
     before = engine.stats()
     sp = SamplingParams(temperature=0.0, max_tokens=4)
     a, = engine.generate([prompt], sp)
@@ -521,7 +515,7 @@ def test_what_the_kv_side_allows_stays_allowed(cfg, feature):
 ])
 def test_the_engine_refuses_by_name(cfg, params, over, word):
     with pytest.raises(ValueError, match=word):
-        InferenceEngine(cfg, _serve_cfg(**over), params=params)
+        support.engine(cfg, params, **over)
 
 
 def test_page_transfers_and_fleets_are_refused_by_name(cfg, params, engine):
@@ -537,7 +531,8 @@ def test_page_transfers_and_fleets_are_refused_by_name(cfg, params, engine):
             (lambda: setattr(engine, "prefix_fetch_hook", lambda *a: None),
              "prefix fetch"),
             (lambda: engine.measure_device_times(), "measure_device_times"),
-            (lambda: EngineReplica(0, cfg, _serve_cfg(), params=params),
+            (lambda: EngineReplica(0, cfg, support.serve_config(cfg.name),
+                                   params=params),
              "fleet serving is refused")]:
         with pytest.raises(ValueError, match=word):
             call()

@@ -262,6 +262,27 @@ class TestMigration:
             time.sleep(0.005)
             assert time.monotonic() < deadline, "migration test hung"
 
+    def _drain_at_token(self, fleet, n_tokens):
+        """Have the replica that serves a request ask for its own drain
+        from its ENGINE thread, in the step that delivers the request's
+        ``n_tokens``-th token: the drain finds the sequence mid-decode
+        however late the main thread runs (waiting for the tokens and
+        draining from here lost that race under six workers: a replica
+        drained with nothing left to migrate). Installed before the
+        submit; returns (the event that says it was asked, the list that
+        names the replica). ``fleet.drain`` of that replica afterwards is
+        the router's half."""
+        asked, home = threading.Event(), []
+        for rep in fleet.replicas:
+            def hook(req, toks, rep=rep, forward=rep.engine.on_token):
+                forward(req, toks)
+                if not home and len(req.generated_tokens) >= n_tokens:
+                    home.append(rep.replica_id)
+                    rep.request_drain()
+                    asked.set()
+            rep.engine.on_token = hook
+        return asked, home
+
     def _wait_decoding(self, reqs, events, n_tokens=2, timeout=120.0,
                       mode=all):
         deadline = time.monotonic() + timeout
@@ -472,6 +493,7 @@ class TestCourierChaos:
     _submit = TestMigration._submit
     _await_all = TestMigration._await_all
     _wait_decoding = TestMigration._wait_decoding
+    _drain_at_token = TestMigration._drain_at_token
 
     CHAOS_KW = dict(courier_chunk_bytes=1024, courier_max_retries=12,
                     courier_retry_backoff_ms=0.2,
@@ -554,10 +576,9 @@ class TestCourierChaos:
                            serve_kw={"kv_quantization": "int8"},
                            fleet_kw=dict(self.CHAOS_KW))
         try:
+            asked, home = self._drain_at_token(fleet, n_tokens=4)
             reqs, events = self._submit(fleet, [PROMPTS[0]], greedy)
-            self._wait_decoding(reqs, events, n_tokens=4)
-            src = fleet.router.replica_of(reqs[0].request_id)
-            assert fleet.drain(src)
+            assert asked.wait(120) and fleet.drain(home[0])
             self._await_all(fleet, events)
             assert reqs[0].generated_tokens == ref[0]
             cour = fleet.status()["courier"]
@@ -652,6 +673,7 @@ class TestCourierCompressed:
     _submit = TestMigration._submit
     _await_all = TestMigration._await_all
     _wait_decoding = TestMigration._wait_decoding
+    _drain_at_token = TestMigration._drain_at_token
 
     COMP_KW = dict(TestCourierChaos.CHAOS_KW, courier_codec="delta-zlib")
 
@@ -713,10 +735,9 @@ class TestCourierCompressed:
                            serve_kw={"kv_quantization": "int8"},
                            fleet_kw=dict(self.COMP_KW))
         try:
+            asked, home = self._drain_at_token(fleet, n_tokens=4)
             reqs, events = self._submit(fleet, [PROMPTS[0]], sampled)
-            self._wait_decoding(reqs, events, n_tokens=4)
-            src = fleet.router.replica_of(reqs[0].request_id)
-            assert fleet.drain(src)
+            assert asked.wait(120) and fleet.drain(home[0])
             self._await_all(fleet, events)
             assert reqs[0].generated_tokens == ref[0], (
                 "compressed int8 seeded migration diverged")

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from benchmark.reference import hybrid_decoder
 from distributed_llm_training_and_inference_system_tpu.config import get_model_config
 from distributed_llm_training_and_inference_system_tpu.config.schema import (
@@ -78,7 +79,7 @@ def seeded(cfg, seed=0):
     """Seeded weights with every vector the init leaves trivial made
     NON-trivial: norm scales, the gated norm's scale, ``D``, the selection
     bias (a zero bias or a unit norm hides its own absence)."""
-    p = gpt.init(cfg, jax.random.PRNGKey(seed))
+    p = support.params_of(cfg, seed)
     keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 16))
     b = p["blocks"]
 
@@ -102,10 +103,6 @@ def seeded(cfg, seed=0):
 @pytest.fixture(scope="module")
 def params(cfg):
     return seeded(cfg)
-
-
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(3, 256, n).tolist()
 
 
 def _ref(params, tokens, wrong=None, **over):
@@ -167,8 +164,8 @@ def test_a_table_that_cannot_be_built_is_refused(change, word):
 # -- the forward against the reference -------------------------------------------
 
 def test_forward_matches_the_reference(cfg, params):
-    tokens = _tokens(45)
-    got = np.asarray(gpt.forward(params, jnp.asarray([tokens]), cfg))[0]
+    tokens = support.tokens(45)
+    got = np.asarray(support.forward(params, [tokens], cfg))[0]
     assert np.abs(got - _ref(params, tokens)).max() < TOL
 
 
@@ -179,7 +176,7 @@ def test_the_tolerance_sees_each_wrong_model(cfg, params, wrong, least):
     """Each of the six wrong models the chip check is shown to catch moves
     the reference's own logits by far more than ``TOL``: a program that
     computed it would fail the logit tests here."""
-    tokens = _tokens(45)
+    tokens = support.tokens(45)
     right = _ref(params, tokens)
     moved = np.abs(_ref(params, tokens, wrong=wrong, prompt_len=37,
                         pad_to=48)[-8:] - right[-8:]).max()
@@ -328,6 +325,21 @@ def test_pieces_from_the_slots_state_are_the_whole_prompt(cfg, params, n):
 
 # -- prefill, then decode, through the pools -------------------------------------
 
+def _cold_program(params, padded, live, *, cfg):
+    return gpt.forward(
+        params, padded, cfg,
+        kv_cache=gpt.init_kv_cache(cfg, 1, padded.shape[1],
+                                   dtype=jnp.float32),
+        cache_offset=jnp.zeros((1,), jnp.int32), segment_ids=live,
+        return_moe_stats=True, return_ssm_state=True)
+
+
+def _decode_program(params, toks, pos, kp, vp, table, active, state, *, cfg):
+    return decode_step_forward(params, toks, pos, kp, vp, table, cfg,
+                               active=active, return_moe_stats=True,
+                               ssm_state=state)
+
+
 def _cold_prefill(cfg, params, tokens, bucket, kp, vp, state, pages, slot):
     """What the engine's prefill program does: the dense forward over a
     padded bucket, the attention layers' K/V scattered into ``pages`` and
@@ -336,11 +348,8 @@ def _cold_prefill(cfg, params, tokens, bucket, kp, vp, state, pages, slot):
     padded = np.full((1, bucket), 7, np.int32)      # garbage padding
     padded[0, :n] = tokens
     live = (jnp.arange(bucket)[None] < n).astype(jnp.int32)
-    logits, (kd, vd), stats, (tails, hs) = gpt.forward(
-        params, jnp.asarray(padded), cfg,
-        kv_cache=gpt.init_kv_cache(cfg, 1, bucket, dtype=jnp.float32),
-        cache_offset=jnp.zeros((1,), jnp.int32), segment_ids=live,
-        return_moe_stats=True, return_ssm_state=True)
+    logits, (kd, vd), stats, (tails, hs) = support.program(
+        _cold_program, cfg)(params, jnp.asarray(padded), live)
 
     def paged(d):
         return d[:, 0].reshape(cfg.kv_layers, bucket // PS, PS,
@@ -372,11 +381,10 @@ def _serve_sequence(cfg, params, seq, n, state_dtype=jnp.float32):
     for pos in range(n, len(seq)):
         toks = np.full(4, 11, np.int32)             # idle slots' garbage
         toks[1] = seq[pos]
-        lg, kp, vp, stats, state = decode_step_forward(
+        lg, kp, vp, stats, state = support.program(_decode_program, cfg)(
             params, jnp.asarray(toks), jnp.full((4,), pos, jnp.int32), kp,
-            vp, jnp.asarray(table), cfg,
-            active=jnp.asarray([False, True, False, False]),
-            return_moe_stats=True, ssm_state=state)
+            vp, jnp.asarray(table),
+            jnp.asarray([False, True, False, False]), state)
         got[pos] = np.asarray(lg)[1]
         assert np.asarray(stats)[-1] == cfg.moe_layers * 3   # one live token
     return got, state
@@ -387,7 +395,7 @@ def test_prefill_then_decode_matches_the_reference(cfg, params):
     reference's full forward: the prompt through cold prefill (padded
     bucket, garbage padding), then eight decode steps over the state
     pools. Idle slots' state stays as it was."""
-    seq = _tokens(37 + 8, seed=2)
+    seq = support.tokens(37 + 8, seed=2)
     got, state = _serve_sequence(cfg, params, seq, 37)
     assert np.abs(got - _ref(params, seq)).max() < TOL
     for name in ("conv", "ssm"):
@@ -403,7 +411,7 @@ def test_the_float32_state_is_held_on_logits(cfg, params):
     their own: float32 state reads 2e-7 to 6e-7 over the eight decode
     steps, bfloat16 state 1.6e-5 (its 8 bits of mantissa, step after
     step); ``STATE_TOL`` lies between with room on both sides."""
-    seq = _tokens(37 + 8, seed=2)
+    seq = support.tokens(37 + 8, seed=2)
     want = _ref(params, seq)[37:]
     got, _ = _serve_sequence(cfg, params, seq, 37)
     low, _ = _serve_sequence(cfg, params, seq, 37, state_dtype=jnp.bfloat16)
@@ -477,7 +485,7 @@ def test_the_expert_stack_lies_as_its_width_says(cfg, width, up_shape):
     p = seeded(c, seed=3)
     assert p["blocks"]["moe"]["up"]["kernel"].shape[-2:] == up_shape
     assert p["blocks"]["moe"]["down"]["kernel"].shape[-2:] == (width, 64)
-    tokens = _tokens(21, seed=width)
+    tokens = support.tokens(21, seed=width)
     got = np.asarray(gpt.forward(p, jnp.asarray([tokens]), c)[0])
     want = _ref(p, tokens, moe_intermediate_size=width)
     assert np.abs(got - want).max() < TOL
@@ -488,7 +496,7 @@ def test_the_reference_gives_the_routing_margin(cfg, params):
     layers, of the k-th largest biased score less the k+1-th; the logits
     beside it are the plain ones. ``float8_experts`` rounds the routed
     experts' operands alone: it moves the logits, less than ``float8``."""
-    tokens = _tokens(23, seed=5)
+    tokens = support.tokens(23, seed=5)
     lg, margin = hybrid_decoder.logits(params, tokens, PUBLISHED,
                                        with_margin=True, round_to=16)
     assert lg.shape == (23, 256) and margin.shape == (23,)
@@ -575,33 +583,22 @@ def test_column_and_k_tiles_at_the_published_widths():
 
 # -- the engine ------------------------------------------------------------------
 
-def _serve_cfg(**over):
-    return ServeConfig(**{**dict(
-        model="nemotron-h-test", max_batch_size=4, max_seq_len=128,
-        dtype="float32", kv_block_size=PS, prefill_chunk=16,
-        decode_steps_per_dispatch=4), **over})
-
-
 @pytest.fixture(scope="module")
 def engine(cfg, params):
-    return InferenceEngine(cfg, _serve_cfg(), params=params)
-
-
-def _gaps(params, prompt, served):
-    lg = _ref(params, prompt + served[:-1])[len(prompt) - 1:]
-    return lg.max(-1) - lg[np.arange(len(served)), served]
+    return support.engine(cfg, params)
 
 
 def test_engine_serves_the_references_tokens(cfg, params, engine):
     """Six prompts over four slots (so slots are REUSED after a release):
     every served token is the reference's argmax."""
-    prompts = [_tokens(n, seed=s) for s, n in enumerate((36, 20, 36, 20, 36,
+    prompts = [support.tokens(n, seed=s) for s, n in enumerate((36, 20, 36, 20, 36,
                                                          20))]
     reqs = engine.generate(prompts, SamplingParams(temperature=0.0,
                                                    max_tokens=10))
     for p, r in zip(prompts, reqs):
         assert len(r.generated_tokens) == 10
-        assert _gaps(params, p, r.generated_tokens).max() == 0.0
+        assert support.gaps(_ref, params, p,
+                            r.generated_tokens).max() == 0.0
     st = engine.stats()
     assert st["ssm"]["state_bytes"] == engine.kv.state_bytes() > 0
     assert st["kv"]["state_bytes"] == st["ssm"]["state_bytes"]
@@ -617,7 +614,7 @@ def test_a_repeated_prompt_is_prefilled_again_to_the_same_tokens(engine):
     """Prefix reuse by page hash is ON by default and wrong for a recurrent
     layer: the engine registers and looks up no hash, says so, and a
     repeated prompt is computed again, to the same tokens."""
-    prompt = _tokens(36, seed=5)
+    prompt = support.tokens(36, seed=5)
     before = engine.stats()
     a, = engine.generate([prompt], SamplingParams(temperature=0.0,
                                                   max_tokens=6))
@@ -635,28 +632,29 @@ def test_a_repeated_prompt_is_prefilled_again_to_the_same_tokens(engine):
 def test_a_reused_slot_starts_from_a_zero_state(cfg, params):
     """One slot, two requests one after the other: the second is served as
     on a fresh engine, whatever the first left in the slot's state."""
-    one = InferenceEngine(cfg, _serve_cfg(max_batch_size=1), params=params)
-    first, second = _tokens(20, seed=8), _tokens(36, seed=9)
+    one = support.engine(cfg, params, max_batch_size=1)
+    first, second = support.tokens(20, seed=8), support.tokens(36, seed=9)
     sp = SamplingParams(temperature=0.0, max_tokens=10)
     one.generate([first], sp)
     assert np.abs(np.asarray(one.kv.state["ssm"])).max() > 0   # left behind
     reused, = one.generate([second], sp)
-    assert _gaps(params, second, reused.generated_tokens).max() == 0.0
+    assert support.gaps(_ref, params, second,
+                        reused.generated_tokens).max() == 0.0
 
 
 def test_recompute_preemption_rebuilds_the_state(cfg, params):
     """The default preemption re-prefills prompt plus generated tokens,
     which rebuilds the recurrent state: a pool too small for both requests
     preempts one, and both still serve the reference's tokens."""
-    small = InferenceEngine(cfg, _serve_cfg(max_batch_size=2,
-                                            kv_num_blocks=9), params=params)
-    prompts = [_tokens(20, seed=3), _tokens(20, seed=4)]
+    small = support.engine(cfg, params, max_batch_size=2, kv_num_blocks=9)
+    prompts = [support.tokens(20, seed=3), support.tokens(20, seed=4)]
     reqs = small.generate(prompts, SamplingParams(temperature=0.0,
                                                   max_tokens=26))
     assert small.stats()["preemptions"] >= 1
     for p, r in zip(prompts, reqs):
         assert len(r.generated_tokens) == 26
-        assert _gaps(params, p, r.generated_tokens).max() == 0.0
+        assert support.gaps(_ref, params, p,
+                            r.generated_tokens).max() == 0.0
 
 
 @pytest.mark.parametrize("over,word", [
@@ -668,7 +666,7 @@ def test_recompute_preemption_rebuilds_the_state(cfg, params):
 def test_a_feature_the_state_cannot_follow_is_refused_by_name(cfg, params,
                                                               over, word):
     with pytest.raises(ValueError, match=word):
-        InferenceEngine(cfg, _serve_cfg(**over), params=params)
+        support.engine(cfg, params, **over)
 
 
 def test_page_transfers_and_fleets_are_refused_by_name(cfg, params, engine):
@@ -686,7 +684,8 @@ def test_page_transfers_and_fleets_are_refused_by_name(cfg, params, engine):
             (lambda: setattr(engine, "prefix_fetch_hook", lambda *a: None),
              "prefix fetch"),
             (lambda: engine.measure_device_times(), "measure_device_times"),
-            (lambda: EngineReplica(0, cfg, _serve_cfg(), params=params),
+            (lambda: EngineReplica(0, cfg, support.serve_config(cfg.name),
+                                   params=params),
              "fleet serving is refused")]:
         with pytest.raises(ValueError, match=word):
             call()

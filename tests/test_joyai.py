@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from benchmark.reference import selfdraft_decoder as ref
 from distributed_llm_training_and_inference_system_tpu.config.presets import (
     JOYAI_TEST_PUBLISHED,
@@ -65,7 +66,7 @@ def _seeded(cfg, seed=0):
     """Seeded weights with every norm's scale (the module's three among
     them) and the selection bias made non-trivial: at ``gpt.init``'s zeros
     a missing norm weight or bias would not show."""
-    tree = gpt.init(cfg, jax.random.PRNGKey(seed))
+    tree = support.params_of(cfg, seed)
     key = jax.random.PRNGKey(5 + seed)
 
     def seeded(path, x):
@@ -111,10 +112,6 @@ def _standing(params, leak=0.0):
     return dict(p, blocks=blocks, mtp=mtp)
 
 
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(3, 250, n).tolist()
-
-
 def _reference(params, tokens, next_token=0, **kw):
     out = ref.forward(params, tokens, C, next_token=next_token, **kw)
     return np.asarray(out["main"]), np.asarray(out["draft"])
@@ -126,7 +123,7 @@ def _reference(params, tokens, next_token=0, **kw):
 def test_the_full_forward_is_the_reference(cfg, params, dtype):
     """Main and draft logits of a whole sequence, row i of the module read
     with token i + 1 (the last row's wraps around to token 0)."""
-    tokens = _tokens(40)
+    tokens = support.tokens(40)
     c = dataclasses.replace(cfg, dtype=dtype)
     with jax.default_matmul_precision("highest"):
         main, draft = gpt.forward(params, jnp.asarray([tokens]), c,
@@ -145,7 +142,7 @@ def test_each_departure_moves_the_logits(params, wrong):
     """What the chip's check must refuse moves the reference's own draft
     logits (the module's departures) or both (float8 operands) by more than
     20 x TOL; the module's departures leave the main logits alone."""
-    tokens = _tokens(24, seed=2)
+    tokens = support.tokens(24, seed=2)
     main, draft = _reference(params, tokens)
     w_main, w_draft = _reference(params, tokens, wrong=wrong)
     assert np.abs(w_draft[:-1] - draft[:-1]).max() > 20 * TOL
@@ -156,7 +153,7 @@ def test_each_departure_moves_the_logits(params, wrong):
 
 
 def test_the_reference_padded_and_compiled_is_the_reference(params):
-    tokens = _tokens(45, seed=3)
+    tokens = support.tokens(45, seed=3)
     plain = ref.forward(params, tokens, C, next_token=7, with_scores=True)
     ref._compiled_sub_layers.cache_clear()
     got = ref.forward(params, tokens, C, next_token=7, round_to=64,
@@ -224,7 +221,7 @@ def _paged(cfg, params, tokens, windows, program):
          "chunks-then-steps-over-page-boundaries"])
 def test_windows_through_the_pool_are_the_reference(cfg, params, windows,
                                                     window_program):
-    tokens = _tokens(sum(windows), seed=4)
+    tokens = support.tokens(sum(windows), seed=4)
     main, draft = _paged(cfg, params, tokens, windows, window_program)
     want_main, want_draft = _reference(params, tokens)
     assert np.abs(main - want_main).max() < TOL
@@ -247,27 +244,25 @@ def test_the_sentinel_of_index_0_is_one_lane_of_the_rows_padding(cfg):
 
 # -- the engine: every prefill path, then draft-and-verify steps ----------------
 
-def _engine(cfg, params, **serve):
-    opts = dict(model="joyai-test", dtype="float32", max_batch_size=3,
-                max_seq_len=256, kv_block_size=PS, kv_hbm_budget_gb=0.001,
-                chunked_prefill_tokens=32, prefill_chunk=16,
+# three slots; chunks of 32 tokens; the module drafts whatever its
+# acceptance: the counts and the two-token steps below are of this engine
+DRAFTING = dict(max_batch_size=3, chunked_prefill_tokens=32,
                 prefix_caching=True, speculative="mtp",
-                speculative_min_acceptance=0.0, decode_steps_per_dispatch=4)
-    opts.update(serve)
-    return InferenceEngine(cfg, ServeConfig(**opts), params=params)
+                speculative_min_acceptance=0.0)
 
 
 @pytest.fixture(scope="module")
 def drafting(cfg, params):
     """ONE engine that drafts for every test below (its programs take the
     weights as an argument: ``_serve`` swaps them)."""
-    return _engine(cfg, params)
+    return support.engine(cfg, params, **DRAFTING)
 
 
 @pytest.fixture(scope="module")
 def plain(cfg, params):
     """... and one that serves the same model by ``decode_scan``."""
-    return _engine(cfg, params, speculative="off")
+    return support.engine(cfg, params,
+                          **{**DRAFTING, "speculative": "off"})
 
 
 def _serve(eng, weights, prompts, n=16, flush=True, **sampling):
@@ -317,7 +312,7 @@ def _held_to_the_reference(params, req, config=C):
 def test_prefill_then_steps_serve_the_references_tokens_and_drafts(
         drafting, params, path, n_prompt):
     before = dict(drafting.stats()["compiled_programs"])
-    (req,), mtp = _serve(drafting, params, [_tokens(n_prompt, seed=8)], n=12)
+    (req,), mtp = _serve(drafting, params, [support.tokens(n_prompt, seed=8)], n=12)
     _held_to_the_reference(params, req)
     assert mtp["slot_steps"] == mtp["drafts"] > 0
     assert mtp["tokens"] == mtp["slot_steps"] + mtp["accepted"] == 11
@@ -336,10 +331,10 @@ def test_a_suffix_over_cached_pages_serves_the_references(
     starts one row early (the module's row of the last cached position
     reads the first uncached token), and tokens AND drafts are the
     reference's."""
-    shared = _tokens(cached + 3, seed=9)
+    shared = support.tokens(cached + 3, seed=9)
     _serve(drafting, params, [shared], n=2)
     (req,), _ = _serve(drafting, params,
-                       [shared[:cached] + _tokens(tail, seed=10)], n=10,
+                       [shared[:cached] + support.tokens(tail, seed=10)], n=10,
                        flush=False)
     assert req.prefix_cached_tokens == cached
     _held_to_the_reference(params, req)
@@ -352,8 +347,8 @@ def test_two_prompts_equal_over_a_page_and_different_behind_it_share_it(
     the next cache index (in the next page). Two prompts that agree on a
     page and differ in the very next token share the page, and both are
     served right."""
-    page = _tokens(PS, seed=12)
-    a, b = page + [11] + _tokens(5, seed=13), page + [12] + _tokens(5, seed=14)
+    page = support.tokens(PS, seed=12)
+    a, b = page + [11] + support.tokens(5, seed=13), page + [12] + support.tokens(5, seed=14)
     (first,), _ = _serve(drafting, params, [a], n=8)
     (second,), _ = _serve(drafting, params, [b], n=8, flush=False)
     assert (first.prefix_cached_tokens, second.prefix_cached_tokens) == (0, PS)
@@ -372,7 +367,7 @@ def _weights(params, which):
             "a-mix": _standing(params, leak=0.05)}[which]
 
 
-PROMPTS = [_tokens(19, seed=20), _tokens(33, seed=21), _tokens(8, seed=22)]
+PROMPTS = [support.tokens(19, seed=20), support.tokens(33, seed=21), support.tokens(8, seed=22)]
 
 
 @pytest.mark.parametrize("which, share", [
@@ -405,7 +400,7 @@ def test_max_tokens_on_the_first_token_of_a_standing_pair_drops_the_second(
         drafting, plain, params):
     weights = _standing(params)
     lengths = [1, 2, 3, 4, 7]
-    prompts = [_tokens(19, seed=50 + n) for n in lengths]
+    prompts = [support.tokens(19, seed=50 + n) for n in lengths]
     want, _ = _serve(plain, weights, prompts, n=lengths)
     got, _ = _serve(drafting, weights, prompts, n=lengths)
     for n, a, b in zip(lengths, want, got):
@@ -419,7 +414,7 @@ def test_a_stop_token_on_either_token_of_a_standing_pair_ends_there(
     """Tokens 1 and 3 are a standing pair's FIRST (the second is dropped),
     2 and 4 its second."""
     weights = _standing(params)
-    prompt = _tokens(19, seed=25)
+    prompt = support.tokens(19, seed=25)
     (free,), _ = _serve(plain, weights, [prompt], n=9)
     tried = 0
     for at in (1, 2, 3, 4):
@@ -458,7 +453,8 @@ def test_the_adaptive_switch_off_ends_drafting_and_keeps_the_stream(
     the drafts falling, the steps are ``decode_scan``'s again, mid-request;
     0 (every other test's engine) keeps the mechanism on whatever stands."""
     monkeypatch.setattr(InferenceEngine, "MTP_SWITCH_OFF_AFTER", 3)
-    eng = _engine(cfg, params, speculative_min_acceptance=0.5)
+    eng = support.engine(cfg, params, **{
+        **DRAFTING, "speculative_min_acceptance": 0.5})
     want, _ = _serve(plain, params, PROMPTS, n=40)
     got, mtp = _serve(eng, params, PROMPTS, n=40)
     for a, b in zip(want, got):
@@ -479,7 +475,7 @@ def test_many_requests_through_few_slots_keep_their_streams(
     that end inside a dispatch: every stream is plain greedy decoding, and
     the slot-step ledger counts a step once whatever it made."""
     weights = _standing(params, leak=0.05)
-    prompts = [_tokens(9 + 3 * i, seed=30 + i) for i in range(9)]
+    prompts = [support.tokens(9 + 3 * i, seed=30 + i) for i in range(9)]
     lengths = [5, 12, 7, 16, 3, 9, 14, 6, 11]
     want, _ = _serve(plain, weights, prompts, n=lengths)
     before = drafting.stats()
@@ -598,7 +594,7 @@ def test_a_chips_share_serves_its_share_of_the_reference(cfg):
     weights = _seeded(share, seed=1)
     config = dict(C, n_routed_experts=4, router_experts=8, first_expert=0,
                   vocab_size=128)
-    eng = _engine(share, weights)
+    eng = support.engine(share, weights, **DRAFTING)
     prompt = np.random.default_rng(40).integers(3, 128, 21).tolist()
     (req,), _ = _serve(eng, weights, [prompt], n=10)
     _held_to_the_reference(weights, req, config)
@@ -630,7 +626,7 @@ def test_mtp_on_a_model_without_a_module_is_refused_by_name(params):
     without = ModelConfig.from_published(dict(C, num_nextn_predict_layers=0))
     with pytest.raises(ValueError, match="speculative: mtp is refused .the "
                                          "model has no next-token prediction"):
-        _engine(without, gpt.init(without, jax.random.PRNGKey(0)))
+        support.engine(without, **DRAFTING)
 
 
 @pytest.mark.parametrize("serve, match", [
@@ -644,7 +640,7 @@ def test_what_self_drafting_refuses_is_refused_by_name(cfg, params, serve,
     assert set(REFUSED["self_drafting"]) == {
         "riding", "preemption: swap", "measure_device_times", "speculative"}
     with pytest.raises(ValueError, match=match):
-        _engine(cfg, params, **serve)
+        support.engine(cfg, params, **{**DRAFTING, **serve})
 
 
 def test_riding_is_off_and_counted_and_the_probe_is_refused(cfg, drafting):

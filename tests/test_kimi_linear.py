@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from benchmark.reference import linear_decoder as ref
 from distributed_llm_training_and_inference_system_tpu.config.presets import (
     KIMI_LINEAR_TEST_PUBLISHED,
@@ -30,7 +31,6 @@ from distributed_llm_training_and_inference_system_tpu.config.presets import (
 from distributed_llm_training_and_inference_system_tpu.config.schema import (
     ConfigError,
     ModelConfig,
-    ServeConfig,
 )
 from distributed_llm_training_and_inference_system_tpu.models import gpt
 from distributed_llm_training_and_inference_system_tpu.models.layers import (
@@ -38,9 +38,6 @@ from distributed_llm_training_and_inference_system_tpu.models.layers import (
 )
 from distributed_llm_training_and_inference_system_tpu.ops import kda
 from distributed_llm_training_and_inference_system_tpu.serve import decode
-from distributed_llm_training_and_inference_system_tpu.serve.engine import (
-    InferenceEngine,
-)
 from distributed_llm_training_and_inference_system_tpu.serve.kv_cache import (
     PagedKVCache,
 )
@@ -54,14 +51,10 @@ PS = 8
 ROOT = Path(__file__).resolve().parents[1]
 
 
-@pytest.fixture(autouse=True, scope="module")
-def _short_kda_chunks():
-    """A chunk of 8 in place of ``ops/kda.py CHUNK`` = 64, so that the tiny
-    windows of this file (a prompt of 40 tokens, an engine chunk of 32) run
-    several chunks with the state carried between them."""
-    plain, kda.CHUNK = kda.CHUNK, 8
-    yield
-    kda.CHUNK = plain
+# a chunk of 8 in place of ``ops/kda.py CHUNK`` = 64, so that the tiny windows
+# of this file (a prompt of 40 tokens, an engine chunk of 32) run several
+# chunks with the state carried between them
+pytestmark = pytest.mark.usefixtures("short_kda_chunks")
 
 
 @pytest.fixture(scope="module")
@@ -73,7 +66,7 @@ def seeded(cfg, seed=0):
     """Seeded weights with every norm's scale and the selection bias made
     non-trivial (at ``gpt.init``'s zeros a missing norm weight or bias
     would not show), and a router sharp enough that its scores differ."""
-    tree = gpt.init(cfg, jax.random.PRNGKey(seed))
+    tree = support.params_of(cfg, seed)
     key = jax.random.PRNGKey(seed + 5)
 
     def one(path, x):
@@ -95,10 +88,6 @@ def params(cfg):
     return seeded(cfg)
 
 
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(3, 250, n).tolist()
-
-
 def _reference(params, tokens, positions=None, wrong=None):
     return np.asarray(ref.logits(params, tokens, C, positions=positions,
                                  wrong=wrong))
@@ -110,6 +99,12 @@ def _pools(cfg, slots=3):
     return kv, kv.k_pages, kv.state
 
 
+def _cold_program(params, padded, live, *, cfg):
+    return gpt.forward(params, padded, cfg, segment_ids=live,
+                       return_latent=True, return_moe_stats=True,
+                       return_ssm_state=True)
+
+
 def _cold(cfg, params, kv, pool, state, slot, tokens, bucket):
     """Cold prefill as the engine's program does it: the forward over a
     padded bucket from a zero state, the latent rows written to the slot's
@@ -118,9 +113,8 @@ def _cold(cfg, params, kv, pool, state, slot, tokens, bucket):
     padded = np.full((1, bucket), 7, np.int32)       # garbage padding
     padded[0, :n] = tokens
     live = (jnp.arange(bucket)[None] < n).astype(jnp.int32)
-    logits, rows, _, (tails, states) = gpt.forward(
-        params, jnp.asarray(padded), cfg, segment_ids=live,
-        return_latent=True, return_moe_stats=True, return_ssm_state=True)
+    logits, rows, _, (tails, states) = support.program(_cold_program, cfg)(
+        params, jnp.asarray(padded), live)
     entries = jnp.asarray(kv.block_tables[slot, :bucket // PS])
     rows = jnp.pad(rows[:, 0], ((0, 0), (0, 0),
                                 (0, pool.shape[-1] - rows.shape[-1])))
@@ -131,43 +125,55 @@ def _cold(cfg, params, kv, pool, state, slot, tokens, bucket):
     return np.asarray(logits)[0, :n], pool, state
 
 
+def _chunk_program(params, window, start, pool, table, ok, state, slot, *,
+                   cfg):
+    return decode.extend_step_forward(
+        params, window, start, pool, None, table, cfg, write_ok=ok,
+        ssm_state=state, state_slot=slot)
+
+
 def _chunk(cfg, params, kv, pool, state, slot, tokens, start, bucket):
     """One chunk of ``slot``'s prompt through the chunk program's forward:
     (logits of its live rows, pool, state)."""
     m = len(tokens)
     window = np.full((1, bucket), 9, np.int32)
     window[0, :m] = tokens
-    lg, pool, _, _, state = decode.extend_step_forward(
+    lg, pool, _, _, state = support.program(_chunk_program, cfg)(
         params, jnp.asarray(window), jnp.asarray([start], jnp.int32), pool,
-        None, jnp.asarray(kv.block_tables[slot][None]), cfg,
-        write_ok=jnp.arange(bucket)[None] < m, ssm_state=state,
-        state_slot=jnp.int32(slot))
+        jnp.asarray(kv.block_tables[slot][None]),
+        jnp.arange(bucket)[None] < m, state, jnp.int32(slot))
     return np.asarray(lg)[0, :m], pool, state
+
+
+def _decode_program(params, tokens, positions, pool, tables, active, state,
+                    *, cfg):
+    return decode.decode_step_forward(
+        params, tokens, positions, pool, None, tables, cfg, active=active,
+        ssm_state=state)
 
 
 def _decode(cfg, params, kv, pool, state, tokens, positions, active):
     """One decode step of every slot: (logits [slots, V], pool, state)."""
-    lg, pool, _, _, state = decode.decode_step_forward(
+    lg, pool, _, _, state = support.program(_decode_program, cfg)(
         params, jnp.asarray(tokens, jnp.int32),
-        jnp.asarray(positions, jnp.int32), pool, None,
-        jnp.asarray(kv.block_tables), cfg, active=jnp.asarray(active),
-        ssm_state=state)
+        jnp.asarray(positions, jnp.int32), pool,
+        jnp.asarray(kv.block_tables), jnp.asarray(active), state)
     return np.asarray(lg), pool, state
 
 
 # -- the model against the reference ---------------------------------------------
 
 def test_the_full_forward_is_the_reference(cfg, params):
-    tokens = _tokens(45)
+    tokens = support.tokens(45)
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(gpt.forward(params, jnp.asarray([tokens]), cfg))[0]
+        got = np.asarray(support.forward(params, [tokens], cfg))[0]
     want = _reference(params, tokens)
     assert np.abs(want).max() > 0.1
     assert np.abs(got - want).max() < TOL
 
 
 def test_the_reference_padded_and_compiled_is_the_reference(params):
-    tokens = _tokens(40, seed=3)
+    tokens = support.tokens(40, seed=3)
     want = _reference(params, tokens)
     got, margin = ref.logits(params, tokens, C, pad_to=64, compiled=True,
                              with_margin=True)
@@ -179,7 +185,7 @@ def test_cold_prefill_then_decode_through_the_pools(cfg, params):
     """A prompt through cold prefill (a padded bucket), then decode steps
     over the latent pool and the state pools in a batch of three slots, two
     of them idle: every position's logits are the reference's."""
-    seq, n = _tokens(40, seed=1), 29
+    seq, n = support.tokens(40, seed=1), 29
     kv, pool, state = _pools(cfg)
     kv.allocate(1, len(seq))
     with jax.default_matmul_precision("highest"):
@@ -205,7 +211,7 @@ def test_a_chunked_prompt_carries_its_state_and_equals_the_cold_path(
     every decode logit equal to the cold path's on the same prompt. The
     slot's rows start FULL of another sequence's state: a chunk that starts
     its sequence reads none of it."""
-    seq, n = _tokens(70, seed=2), 61
+    seq, n = support.tokens(70, seed=2), 61
     kv, pool, state = _pools(cfg)
     kv.allocate(2, len(seq))
     state = jax.tree_util.tree_map(lambda a: a + 3.0, state)   # a former
@@ -238,7 +244,7 @@ def test_a_chunked_prompt_carries_its_state_and_equals_the_cold_path(
 def test_two_slots_of_different_lengths_do_not_leak_state(cfg, params):
     """Two sequences decode side by side in one batch, prefilled to
     different lengths: each slot's logits are its own sequence's."""
-    a, b = _tokens(30, seed=4), _tokens(44, seed=5)
+    a, b = support.tokens(30, seed=4), support.tokens(44, seed=5)
     na, nb = 9, 31
     kv, pool, state = _pools(cfg)
     kv.allocate(0, len(a) + 8)
@@ -262,7 +268,7 @@ def test_two_slots_of_different_lengths_do_not_leak_state(cfg, params):
 @pytest.mark.parametrize("wrong", ["bf16_state", "rotated_pe", "no_beta",
                                    "per_head_decay", "no_renorm", "float8"])
 def test_each_departure_moves_the_logits(params, wrong):
-    tokens = _tokens(45)
+    tokens = support.tokens(45)
     moved = np.abs(_reference(params, tokens, wrong=wrong)
                    - _reference(params, tokens)).max()
     assert moved > 20 * TOL, f"{wrong} moves the logits by {moved:.2e}"
@@ -405,27 +411,22 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(cfg, params):
 
 # -- the engine: the latent pool and the state pools in one cache ----------------
 
-def _engine(cfg, params, **serve):
-    opts = dict(model="kimi-linear-test", dtype="float32", max_batch_size=4,
-                max_seq_len=256, kv_block_size=PS, kv_hbm_budget_gb=0.001,
-                chunked_prefill_tokens=32, prefill_chunk=16)
-    opts.update(serve)
-    return InferenceEngine(cfg, ServeConfig(**opts), params=params)
+# chunks of 32 tokens where the shared shapes have a riding piece's 16, and 8
+# steps a dispatch: the counts below are of prompts over 32 tokens, 32 a
+# chunk, admitted when dispatches of 8 steps free their slots
+CHUNKS_OF_32 = dict(chunked_prefill_tokens=32, decode_steps_per_dispatch=8)
 
 
-def _greedy(params, prompt, n):
-    out = []
-    for _ in range(n):
-        # (one compiled length for every step of every prompt)
-        lg = ref.logits(params, prompt + out, C, pad_to=192, compiled=True,
-                        positions=[len(prompt) + len(out) - 1])
-        out.append(int(lg[0].argmax()))
-    return out
+def _last_logits(params):
+    # (one compiled length for every step of every prompt)
+    return lambda context: ref.logits(
+        params, context, C, pad_to=192, compiled=True,
+        positions=[len(context) - 1])[0]
 
 
 def test_the_engine_serves_cold_and_chunked_prompts_from_one_cache(cfg,
                                                                    params):
-    eng = _engine(cfg, params)
+    eng = support.engine(cfg, params, **CHUNKS_OF_32)
     kv = eng.stats()["kv"]
     assert kv["kind"] == "latent" and eng.kv.v_pages is None
     assert kv["bytes_per_token"] == 2 * cfg.mla.page_width * 4
@@ -435,15 +436,15 @@ def test_the_engine_serves_cold_and_chunked_prompts_from_one_cache(cfg,
     assert kv["state_bytes"] == 6 * 4 * (4 * 16 * 16 + 3 * 192) * 4
     greedy = SamplingParams(temperature=0.0, max_tokens=6)
     with jax.default_matmul_precision("highest"):
-        for prompt in (_tokens(20, 1), _tokens(100, 2), _tokens(77, 3)):
+        for prompt in (support.tokens(20, 1), support.tokens(100, 2), support.tokens(77, 3)):
             got = eng.generate([prompt], greedy)[0].generated_tokens
-            assert got == _greedy(params, prompt, 6)
+            assert got == support.greedy(_last_logits(params), prompt, 6)
         # a batch of cold and chunked prompts, more prompts than slots: a
         # released slot serves the next request from a zero state
-        prompts = [_tokens(n, 10 + n) for n in (20, 90, 33, 70, 12, 65, 9)]
+        prompts = [support.tokens(n, 10 + n) for n in (20, 90, 33, 70, 12, 65, 9)]
         got = eng.generate(prompts, greedy)
         assert [r.generated_tokens for r in got] == [
-            _greedy(params, p, 6) for p in prompts]
+            support.greedy(_last_logits(params), p, 6) for p in prompts]
     st = eng.stats()
     assert "ssm" not in st
     # 100 and 77 tokens, then 90, 33, 70 and 65, went chunk by chunk (32 a
@@ -468,20 +469,21 @@ def test_the_engine_serves_cold_and_chunked_prompts_from_one_cache(cfg,
 def test_what_a_k_state_cannot_follow_is_refused_by_name(cfg, params, serve,
                                                          match):
     with pytest.raises(ValueError, match=match):
-        _engine(cfg, params, **serve)
+        support.engine(cfg, params, **CHUNKS_OF_32, **serve)
 
 
 def test_prefix_reuse_is_turned_off_and_page_transfer_refused(cfg, params):
-    eng = _engine(cfg, params, prefix_caching=True)
+    eng = support.engine(cfg, params, prefix_caching=True,
+                         **CHUNKS_OF_32)
     assert not eng._prefix_caching
-    eng.generate([_tokens(20)], SamplingParams(temperature=0.0, max_tokens=2))
+    eng.generate([support.tokens(20)], SamplingParams(temperature=0.0, max_tokens=2))
     assert eng.stats()["kda"]["refused"] == {"prefix_caching": 1}
     with pytest.raises(ValueError, match="fleet prefix fetch is refused"):
         eng.prefix_fetch_hook = lambda req, hashes: None
     with pytest.raises(ValueError, match=r"\(K\) layers: fleet prefix export"):
         eng.kv.extract_pages([1])
     with pytest.raises(ValueError, match="dropless inference forward only"):
-        gpt.forward(params, jnp.asarray([_tokens(8)]), cfg,
+        gpt.forward(params, jnp.asarray([support.tokens(8)]), cfg,
                     moe_impl="capacity")
 
 
@@ -489,28 +491,14 @@ def test_state_space_layers_still_refuse_chunked_prefill_by_name():
     hybrid = get_model_config("nemotron-h-test")
     with pytest.raises(ValueError, match="has state-space layers: "
                                          "chunked_prefill_tokens is refused"):
-        InferenceEngine(hybrid, ServeConfig(
-            model="nemotron-h-test", dtype="float32", max_batch_size=2,
-            max_seq_len=64, kv_block_size=PS, kv_hbm_budget_gb=0.001,
-            chunked_prefill_tokens=32), params=gpt.init(
-                hybrid, jax.random.PRNGKey(0)))
+        support.engine(hybrid, chunked_prefill_tokens=32)
 
 
 # -- the schema ------------------------------------------------------------------
 
-def _catalog_row():
-    rows = Path("/opt/skills/guides/model-configs/architectures.jsonl")
-    if not rows.exists():
-        pytest.skip("no model-configs catalog here")
-    for line in rows.read_text().splitlines():
-        row = json.loads(line)
-        if row["name"] == "Kimi-Linear-48B-A3B-Instruct":
-            return row["config"]
-    pytest.skip("the catalog has no Kimi-Linear row")
-
-
 def test_the_published_config_parses_to_the_54_entry_table():
-    m = ModelConfig.from_published(_catalog_row())
+    m = ModelConfig.from_published(
+        support.catalog_row("Kimi-Linear-48B-A3B-Instruct"))
     assert len(m.layer_pattern) == m.num_layers == 54
     assert m.layer_pattern == "KDKEKE*E" + "KEKEKE*E" * 5 + "KEKE*E"
     assert (m.kda_layers, m.kv_layers, m.moe_layers) == (20, 7, 26)

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from benchmark.reference import latent_decoder as ref
 from distributed_llm_training_and_inference_system_tpu.config.presets import (
     XING_TEST_PUBLISHED,
@@ -27,7 +28,6 @@ from distributed_llm_training_and_inference_system_tpu.config.presets import (
 from distributed_llm_training_and_inference_system_tpu.config.schema import (
     ConfigError,
     ModelConfig,
-    ServeConfig,
 )
 from distributed_llm_training_and_inference_system_tpu.models import gpt, layers
 from distributed_llm_training_and_inference_system_tpu.ops import (
@@ -59,7 +59,7 @@ def params(cfg):
     """Seeded weights with every norm's scale, the selection bias and the
     hyper-connections' biases made non-trivial (at ``gpt.init``'s zeros a
     missing norm weight or bias would not show)."""
-    tree = gpt.init(cfg, jax.random.PRNGKey(0))
+    tree = support.params_of(cfg)
     key = jax.random.PRNGKey(5)
 
     def seeded(path, x):
@@ -74,13 +74,14 @@ def params(cfg):
     return jax.tree_util.tree_map_with_path(seeded, tree)
 
 
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(3, 250, n).tolist()
-
-
 def _reference(params, tokens, positions=None, wrong=None):
     return np.asarray(ref.logits(params, tokens, C, positions=positions,
                                  wrong=wrong))
+
+
+def _window_program(params, window, starts, pool, tables, ok, *, cfg):
+    return decode.extend_step_forward(params, window, starts, pool, None,
+                                      tables, cfg, write_ok=ok)
 
 
 def _paged(cfg, params, tokens, windows):
@@ -97,10 +98,10 @@ def _paged(cfg, params, tokens, windows):
             window[1] = tokens[at:at + t]
             ok = np.zeros((3, t), bool)
             ok[1] = True
-            lg, pool, none, *_ = decode.extend_step_forward(
+            lg, pool, none, *_ = support.program(_window_program, cfg)(
                 params, jnp.asarray(window),
-                jnp.asarray([0, at, 0], jnp.int32), pool, None, tables, cfg,
-                write_ok=jnp.asarray(ok))
+                jnp.asarray([0, at, 0], jnp.int32), pool, tables,
+                jnp.asarray(ok))
             assert none is None
             out.append(np.asarray(lg[1]))
             at += t
@@ -110,9 +111,9 @@ def _paged(cfg, params, tokens, windows):
 # -- the model against the reference ---------------------------------------------
 
 def test_the_full_forward_is_the_reference(cfg, params):
-    tokens = _tokens(40)
+    tokens = support.tokens(40)
     with jax.default_matmul_precision("highest"):
-        lg = gpt.forward(params, jnp.asarray([tokens]), cfg)
+        lg = support.forward(params, [tokens], cfg)
     assert np.abs(np.asarray(lg[0]) - _reference(params, tokens)).max() < TOL
 
 
@@ -126,7 +127,7 @@ def test_the_reference_padded_and_compiled_is_the_reference(
     an expert's rows take several blocks and the last is not full."""
     monkeypatch.setattr(ref, "QUERY_BLOCK", blocks[0])
     monkeypatch.setattr(ref, "EXPERT_ROWS", blocks[1])
-    tokens = _tokens(n, seed=3)
+    tokens = support.tokens(n, seed=3)
     plain, margin = ref.logits(params, tokens, C, with_margin=True)
     ref._compiled_sub_layers.cache_clear()
     got, got_margin = ref.logits(params, tokens, C, with_margin=True,
@@ -144,15 +145,25 @@ def test_the_reference_padded_and_compiled_is_the_reference(
     [1] * 12,                  # decode from the first token
 ], ids=["prefill-then-decode", "chunked", "suffix-mid-page", "decode-only"])
 def test_the_paged_programs_are_the_reference(cfg, params, windows):
-    tokens = _tokens(sum(windows), seed=1)
+    tokens = support.tokens(sum(windows), seed=1)
     got = _paged(cfg, params, tokens, windows)
     assert np.abs(got - _reference(params, tokens)).max() < TOL
+
+
+def _cold_program(params, padded, live, *, cfg):
+    return gpt.forward(params, padded, cfg, segment_ids=live,
+                       return_latent=True, return_moe_stats=True)
+
+
+def _decode_program(params, tokens, positions, pool, tables, *, cfg):
+    return decode.decode_step_forward(params, tokens, positions, pool, None,
+                                      tables, cfg)
 
 
 def test_cold_prefill_rows_then_decode_through_the_pages(cfg, params):
     """``forward(return_latent=True)``'s rows written to pages, then decode
     steps over them: what the engine's cold prefill does."""
-    tokens = _tokens(30, seed=2)
+    tokens = support.tokens(30, seed=2)
     n = 20
     kv = PagedKVCache(cfg, num_slots=1, max_seq_len=64, page_size=PS,
                       num_pages=12, dtype=jnp.float32)
@@ -160,8 +171,8 @@ def test_cold_prefill_rows_then_decode_through_the_pages(cfg, params):
     with jax.default_matmul_precision("highest"):
         padded = jnp.asarray([tokens[:n] + [0] * 4])
         live = (jnp.arange(24)[None] < n).astype(jnp.int32)
-        lg, rows, _ = gpt.forward(params, padded, cfg, segment_ids=live,
-                                  return_latent=True, return_moe_stats=True)
+        lg, rows, _ = support.program(_cold_program, cfg)(params, padded,
+                                                          live)
         assert rows.shape == (3, 1, 24, cfg.mla.latent_size)
         rows = jnp.pad(rows[:, 0], ((0, 0), (0, 0), (
             0, cfg.mla.page_width - cfg.mla.latent_size)))
@@ -169,9 +180,9 @@ def test_cold_prefill_rows_then_decode_through_the_pages(cfg, params):
             rows.reshape(3, 3, 1, PS, -1))
         outs = [np.asarray(lg[0, :n])]
         for i in range(n, len(tokens)):
-            step, pool, *_ = decode.decode_step_forward(
+            step, pool, *_ = support.program(_decode_program, cfg)(
                 params, jnp.asarray([tokens[i]]), jnp.asarray([i]), pool,
-                None, jnp.asarray(kv.block_tables), cfg)
+                jnp.asarray(kv.block_tables))
             outs.append(np.asarray(step))
     assert np.abs(np.concatenate(outs)
                   - _reference(params, tokens)).max() < TOL
@@ -274,14 +285,14 @@ def test_the_residual_map_is_doubly_stochastic_and_clamped(cfg, params):
     "scale_without_mscale", "yarn_interpolation", "no_sinkhorn",
     "one_stream", "softmax_scores"])
 def test_each_departure_moves_the_logits(params, wrong):
-    tokens = _tokens(48, seed=3)
+    tokens = support.tokens(48, seed=3)
     moved = np.abs(_reference(params, tokens, wrong=wrong)
                    - _reference(params, tokens)).max()
     assert moved > 20 * TOL, (wrong, moved)
 
 
 def test_a_hit_on_another_documents_pages_moves_the_logits(params):
-    doc, other, q = _tokens(32, 4), _tokens(32, 5), _tokens(8, 6)
+    doc, other, q = support.tokens(32, 4), support.tokens(32, 5), support.tokens(8, 6)
     at = range(32, 40)
     moved = np.abs(_reference(params, doc + q, at)
                    - _reference(params, other + q, at)).max()
@@ -290,38 +301,32 @@ def test_a_hit_on_another_documents_pages_moves_the_logits(params):
 
 # -- the engine: one latent pool, prefix reuse, chunking -------------------------
 
-def _engine(cfg, params, **serve):
-    opts = dict(model="xing-test", dtype="float32", max_batch_size=4,
-                max_seq_len=256, kv_block_size=PS, kv_hbm_budget_gb=0.001,
-                chunked_prefill_tokens=32, prefill_chunk=16)
-    opts.update(serve)
-    return InferenceEngine(cfg, ServeConfig(**opts), params=params)
+# a prompt over 32 tokens goes chunk by chunk (the shared shapes chunk none):
+# the cases below serve the cold, the chunk and the suffix program
+CHUNKS_OF_32 = dict(chunked_prefill_tokens=32)
 
 
-def _greedy(params, prompt, n):
-    out = []
-    for _ in range(n):
-        # (one compiled length for every step of every prompt)
-        lg = ref.logits(params, prompt + out, C, round_to=128, compiled=True,
-                        positions=[len(prompt) + len(out) - 1])
-        out.append(int(lg[0].argmax()))
-    return out
+def _last_logits(params):
+    # (one compiled length for every step of every prompt)
+    return lambda context: ref.logits(
+        params, context, C, round_to=128, compiled=True,
+        positions=[len(context) - 1])[0]
 
 
 def test_the_engine_serves_from_one_latent_pool(cfg, params):
-    eng = _engine(cfg, params)
+    eng = support.engine(cfg, params, **CHUNKS_OF_32)
     kv = eng.stats()["kv"]
     assert kv["kind"] == "latent" and eng.kv.v_pages is None
     assert kv["bytes_per_token"] == 3 * cfg.mla.page_width * 4
     assert eng.kv.k_pages.shape == (3, eng.kv.num_pages, 1, PS, 128)
     greedy = SamplingParams(temperature=0.0, max_tokens=6)
-    doc = _tokens(100, seed=7)
-    cold_short = _tokens(20, seed=8)             # cold: under a chunk
+    doc = support.tokens(100, seed=7)
+    cold_short = support.tokens(20, seed=8)             # cold: under a chunk
     first = doc + [5, 6, 7]                      # chunked: over a chunk
     second = doc + [9, 10, 11, 12]               # a hit on the document
     for prompt in (cold_short, first, second, first):
         got = eng.generate([prompt], greedy)[0].generated_tokens
-        assert got == _greedy(params, prompt, 6)
+        assert got == support.greedy(_last_logits(params), prompt, 6)
     st = eng.stats()
     programs = st["compiled_programs"]
     assert programs["prefill_dense_buckets"] == 1       # the cold rung
@@ -335,10 +340,10 @@ def test_the_engine_serves_from_one_latent_pool(cfg, params):
 
 def test_a_long_prompt_is_chunked_even_where_no_chunk_is_configured(cfg,
                                                                     params):
-    eng = _engine(cfg, params, chunked_prefill_tokens=0, max_seq_len=2048,
-                  kv_hbm_budget_gb=0.01)
+    eng = support.engine(cfg, params, max_seq_len=2048,
+                         kv_hbm_budget_gb=0.01)
     assert eng._chunk_tokens == InferenceEngine.LATENT_COLD_TOKENS == 1024
-    prompt = _tokens(1100, seed=9)
+    prompt = support.tokens(1100, seed=9)
     got = eng.generate([prompt], SamplingParams(temperature=0.0,
                                                 max_tokens=2))
     assert len(got[0].generated_tokens) == 2
@@ -353,14 +358,15 @@ def test_concurrent_document_requests_share_pages_and_all_are_admitted(
     requests on one resident document are admitted four a step (the slots),
     not one, and the pages promised to a request the budget stopped are not
     promised twice."""
-    eng = _engine(cfg, params, prefill_budget_tokens=64)
+    eng = support.engine(cfg, params, prefill_budget_tokens=64,
+                         **CHUNKS_OF_32)
     greedy = SamplingParams(temperature=0.0, max_tokens=4)
-    doc = _tokens(100, seed=10)
+    doc = support.tokens(100, seed=10)
     eng.generate([doc + [4]], greedy)
-    prompts = [doc + _tokens(5, seed=20 + i) for i in range(8)]
+    prompts = [doc + support.tokens(5, seed=20 + i) for i in range(8)]
     got = eng.generate(prompts, greedy)
     assert [r.generated_tokens for r in got] == [
-        _greedy(params, p, 4) for p in prompts]
+        support.greedy(_last_logits(params), p, 4) for p in prompts]
     assert eng._reserved_pages == 0 and not eng._prefix_pins
     assert eng.kv.free_pages == eng.kv.num_pages - 1
 
@@ -378,12 +384,12 @@ def test_concurrent_document_requests_share_pages_and_all_are_admitted(
 def test_what_the_latent_pool_does_not_carry_is_refused_by_name(
         cfg, params, serve, match):
     with pytest.raises(ValueError, match=match):
-        _engine(cfg, params, **serve)
+        support.engine(cfg, params, **CHUNKS_OF_32, **serve)
 
 
 def test_page_transfer_and_the_drafter_and_training_are_refused(cfg, params):
-    eng = _engine(cfg, params)
-    eng.generate([_tokens(20)], SamplingParams(temperature=0.0, max_tokens=2))
+    eng = support.engine(cfg, params, **CHUNKS_OF_32)
+    eng.generate([support.tokens(20)], SamplingParams(temperature=0.0, max_tokens=2))
     with pytest.raises(ValueError, match="keeps latent pages: fleet prefix "
                                          "export"):
         eng.kv.extract_pages([1])
@@ -394,10 +400,10 @@ def test_page_transfer_and_the_drafter_and_training_are_refused(cfg, params):
     with pytest.raises(ConfigError, match="n_group = 2"):
         ModelConfig.from_published(dict(C, n_group=2))
     with pytest.raises(ValueError, match="dropless inference forward only"):
-        gpt.forward(params, jnp.asarray([_tokens(8)]), cfg,
+        gpt.forward(params, jnp.asarray([support.tokens(8)]), cfg,
                     moe_impl="capacity")
     with pytest.raises(ValueError, match="keeps no dense K/V cache"):
-        gpt.forward(params, jnp.asarray([_tokens(8)]), cfg,
+        gpt.forward(params, jnp.asarray([support.tokens(8)]), cfg,
                     kv_cache=gpt.init_kv_cache(cfg, 1, 8))
 
 

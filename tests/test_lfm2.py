@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from benchmark.reference import shortconv_decoder
 from distributed_llm_training_and_inference_system_tpu.config import get_model_config
 from distributed_llm_training_and_inference_system_tpu.config.presets import (
@@ -34,12 +35,10 @@ from distributed_llm_training_and_inference_system_tpu.config.presets import (
 from distributed_llm_training_and_inference_system_tpu.config.schema import (
     ConfigError,
     ModelConfig,
-    ServeConfig,
 )
 from distributed_llm_training_and_inference_system_tpu.models import gpt, layers
 from distributed_llm_training_and_inference_system_tpu.ops import shortconv
 from distributed_llm_training_and_inference_system_tpu.serve import (
-    InferenceEngine,
     Request,
     SamplingParams,
     kv_cache,
@@ -76,7 +75,7 @@ def seeded(cfg, seed=0):
     norm's scale (q / k head norms too) and the experts' selection bias,
     at +-0.05, where it changes which experts are picked. (The taps are
     seeded asymmetrically by ``gpt.init`` itself: uniform a tap.)"""
-    params = gpt.init(cfg, jax.random.PRNGKey(seed))
+    params = support.params_of(cfg, seed)
     key = jax.random.PRNGKey(seed + 100)
     count = iter(range(1000))
 
@@ -97,11 +96,6 @@ def seeded(cfg, seed=0):
 @pytest.fixture(scope="module")
 def params(cfg):
     return seeded(cfg)
-
-
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(
-        0, PUBLISHED["vocab_size"], size=n).tolist()
 
 
 def _ref(params, tokens, wrong=None):
@@ -244,15 +238,15 @@ def test_the_mixer_matches_the_reference_over_a_whole_sequence(cfg, params):
 
 
 def test_forward_matches_the_reference(cfg, params):
-    toks = _tokens(50, seed=1)
+    toks = support.tokens(50, seed=1)
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(gpt.forward(params, jnp.asarray([toks]), cfg))[0]
+        got = np.asarray(support.forward(params, [toks], cfg))[0]
     assert np.abs(got - _ref(params, toks)).max() < TOL
 
 
 def test_the_head_is_the_embeddings_transpose(cfg, params):
     assert "lm_head" not in params
-    toks = _tokens(12, seed=2)
+    toks = support.tokens(12, seed=2)
     x = shortconv_decoder.hidden(params, toks, PUBLISHED)[0]
     x = shortconv_decoder._norm(x, params["final_norm"]["scale"], eps=1e-5)
     want = np.asarray(jnp.matmul(x, params["embed"]["embedding"].T,
@@ -288,7 +282,7 @@ MUTATIONS = {"swap_bc": 0.1, "taps_reversed": 0.1, "stale_window": 0.05,
 
 @pytest.mark.parametrize("wrong", sorted(MUTATIONS))
 def test_the_comparison_fails_each_mutation(cfg, params, wrong):
-    toks = _tokens(50, seed=1)
+    toks = support.tokens(50, seed=1)
     moved = np.abs(_ref(params, toks, wrong) - _ref(params, toks)).max()
     assert moved > MUTATIONS[wrong] > 10 * TOL
 
@@ -321,6 +315,27 @@ def test_the_pools_are_pairs_of_heads_and_two_rows_a_slot(cfg):
     assert recurrent_ops(cfg) is shortconv
 
 
+def _cold_program(params, padded, live, *, cfg):
+    return gpt.forward(
+        params, padded, cfg,
+        kv_cache=gpt.init_kv_cache(cfg, 1, padded.shape[1],
+                                   dtype=jnp.float32),
+        cache_offset=jnp.zeros((1,), jnp.int32), segment_ids=live,
+        return_moe_stats=True, return_ssm_state=True)
+
+
+def _chunk_program(params, rows, start, kp, vp, table, ok, state, slot, *,
+                   cfg):
+    return extend_step_forward(params, rows, start, kp, vp, table, cfg,
+                               write_ok=ok, ssm_state=state, state_slot=slot)
+
+
+def _decode_program(params, toks, pos, kp, vp, table, active, state, ride,
+                    *, cfg):
+    return decode_step_forward(params, toks, pos, kp, vp, table, cfg,
+                               active=active, ssm_state=state, ride=ride)
+
+
 def _cold_prefill(cfg, params, tokens, bucket, kp, vp, state, pages, slot):
     """What the engine's prefill program does: the dense forward over a
     padded bucket, every attention layer's K/V laid out as pages AND the
@@ -331,11 +346,8 @@ def _cold_prefill(cfg, params, tokens, bucket, kp, vp, state, pages, slot):
     padded = np.full((1, bucket), 7, np.int32)      # garbage padding
     padded[0, :n] = tokens
     live = (jnp.arange(bucket)[None] < n).astype(jnp.int32)
-    logits, cache, stats, windows = gpt.forward(
-        params, jnp.asarray(padded), cfg,
-        kv_cache=gpt.init_kv_cache(cfg, 1, bucket, dtype=jnp.float32),
-        cache_offset=jnp.zeros((1,), jnp.int32), segment_ids=live,
-        return_moe_stats=True, return_ssm_state=True)
+    logits, cache, stats, windows = support.program(_cold_program, cfg)(
+        params, jnp.asarray(padded), live)
     kp, vp = write_prompt_to_pages((kp, vp), cache,
                                    jnp.asarray(pages[:bucket // PS]))
     state = dict(zip(state, shortconv.arm_slot_state(
@@ -344,10 +356,9 @@ def _cold_prefill(cfg, params, tokens, bucket, kp, vp, state, pages, slot):
 
 
 def _decode(cfg, params, toks, pos, kp, vp, state, active, ride=None):
-    return decode_step_forward(
+    return support.program(_decode_program, cfg)(
         params, jnp.asarray(toks, jnp.int32), jnp.asarray(pos, jnp.int32),
-        kp, vp, jnp.asarray(TABLE), cfg, active=jnp.asarray(active),
-        ssm_state=state, ride=ride)
+        kp, vp, jnp.asarray(TABLE), jnp.asarray(active), state, ride)
 
 
 def _decode_one(cfg, params, tok, pos, kp, vp, state, ride=None):
@@ -363,7 +374,7 @@ def test_prefill_then_decode_matches_the_reference(cfg, params):
     cold prefill (padded bucket, garbage padding), which writes the
     attention layers' pages and arms the slot's windows, then eight decode
     steps that read and write both. Idle slots' windows stay."""
-    seq, n = _tokens(37 + 8, seed=2), 37
+    seq, n = support.tokens(37 + 8, seed=2), 37
     kp, vp, state = _pools(cfg)
     state = {"conv": state["conv"] + 0.5}                    # leftovers
     got = np.zeros((len(seq), cfg.vocab_size), np.float32)
@@ -387,7 +398,7 @@ def test_a_prompt_split_across_chunks_matches_the_reference(cfg, params):
     chunk reads the slot's two rows, and a chunk boundary carries them.
     The first chunk starts its sequence and reads ZEROS, whatever the slot
     held."""
-    seq, n = _tokens(37 + 3, seed=7), 37
+    seq, n = support.tokens(37 + 3, seed=7), 37
     kp, vp, state = _pools(cfg)
     state = {"conv": state["conv"] + 0.5}                    # leftovers
     want = _ref(params, seq)
@@ -396,11 +407,10 @@ def test_a_prompt_split_across_chunks_matches_the_reference(cfg, params):
             live = min(16, n - start)
             rows = np.full((1, 16), 9, np.int32)
             rows[0, :live] = seq[start:start + live]
-            step = extend_step_forward(
+            step = support.program(_chunk_program, cfg)(
                 params, jnp.asarray(rows), jnp.asarray([start], jnp.int32),
-                kp, vp, jnp.asarray(TABLE[1:2]), cfg,
-                write_ok=(jnp.arange(16) < live)[None], ssm_state=state,
-                state_slot=jnp.int32(1))
+                kp, vp, jnp.asarray(TABLE[1:2]),
+                (jnp.arange(16) < live)[None], state, jnp.int32(1))
             kp, vp, state = step.k_pages, step.v_pages, step.state
             got = np.asarray(step.logits)[0, :live]
             assert np.abs(got - want[start:start + live]).max() < TOL
@@ -421,7 +431,7 @@ def test_a_riding_piece_matches_the_reference(cfg, params, n):
     reference's, and slot 2 then decodes behind the pieces from what they
     left (a prompt of ONE token leaves a zero row before its own)."""
     assert can_carry(cfg)
-    seq, prompt, C = _tokens(30 + 6, seed=5), _tokens(n + 3, seed=6), 16
+    seq, prompt, C = support.tokens(30 + 6, seed=5), support.tokens(n + 3, seed=6), 16
     kp, vp, state = _pools(cfg)
     state = {"conv": state["conv"] + 0.5}           # slot 2's former occupant
     want_seq, want_prompt = _ref(params, seq), _ref(params, prompt)
@@ -458,21 +468,9 @@ def test_a_riding_piece_matches_the_reference(cfg, params, n):
 
 # -- the engine ------------------------------------------------------------------
 
-def _serve_cfg(**over):
-    return ServeConfig(**{**dict(
-        model="lfm2-test", max_batch_size=4, max_seq_len=128,
-        dtype="float32", kv_block_size=PS, prefill_chunk=16,
-        decode_steps_per_dispatch=4), **over})
-
-
 @pytest.fixture(scope="module")
 def engine(cfg, params):
-    return InferenceEngine(cfg, _serve_cfg(), params=params)
-
-
-def _gaps(params, prompt, served):
-    lg = _ref(params, prompt + served[:-1])[len(prompt) - 1:]
-    return lg.max(-1) - lg[np.arange(len(served)), served]
+    return support.engine(cfg, params)
 
 
 def test_engine_serves_the_references_tokens(cfg, params, engine):
@@ -480,14 +478,14 @@ def test_engine_serves_the_references_tokens(cfg, params, engine):
     short prompt after a longer request: its windows start from zeros) and
     the later prompts RIDE the residents' decode steps. Every served token
     is the reference's argmax (or within float32 noise of it)."""
-    prompts = [_tokens(n, seed=s) for s, n in enumerate(
+    prompts = [support.tokens(n, seed=s) for s, n in enumerate(
         (36, 20, 36, 20, 3, 1, 36, 2))]
     with jax.default_matmul_precision("highest"):
         reqs = engine.generate(prompts, SamplingParams(temperature=0.0,
                                                        max_tokens=10))
     for p, r in zip(prompts, reqs):
         assert len(r.generated_tokens) == 10
-        assert _gaps(params, p, r.generated_tokens).max() < TOL
+        assert support.gaps(_ref, params, p, r.generated_tokens).max() < TOL
     st = engine.stats()
     assert "ssm" not in st and "kda" not in st
     assert st["shortconv"]["state_bytes"] == engine.kv.state_bytes() \
@@ -503,15 +501,15 @@ def test_a_prompt_rides_a_busy_engine_to_the_same_tokens(cfg, params):
     their dispatches in pieces, through the conv layers' windows and the
     attention layers' pages; past its first piece a piece reads the
     slot's state (counted)."""
-    eng = InferenceEngine(cfg, _serve_cfg(), params=params)
+    eng = support.engine(cfg, params)
     long = SamplingParams(temperature=0.0, max_tokens=40)
     with jax.default_matmul_precision("highest"):
         for i, n in enumerate((9, 13)):
             assert eng.scheduler.add_request(Request(
-                f"resident-{i}", _tokens(n, seed=20 + i), long))
+                f"resident-{i}", support.tokens(n, seed=20 + i), long))
         while eng.active.sum() < 2:
             eng.step()
-        prompt = _tokens(45, seed=30)
+        prompt = support.tokens(45, seed=30)
         req = Request("rider", prompt, SamplingParams(temperature=0.0,
                                                       max_tokens=8))
         assert eng.scheduler.add_request(req)
@@ -519,26 +517,27 @@ def test_a_prompt_rides_a_busy_engine_to_the_same_tokens(cfg, params):
     st = eng.stats()
     assert st["prefill_ride_tokens"] == 45
     assert st["shortconv"]["state_carry_chunks"] >= 1
-    assert _gaps(params, prompt, req.generated_tokens).max() < TOL
+    assert support.gaps(_ref, params, prompt,
+                        req.generated_tokens).max() < TOL
 
 
 def test_chunked_prefill_carries_the_windows(cfg, params):
     """``chunked_prefill_tokens``: a long prompt goes through the chunk
     programs, each chunk behind the two rows the last one left."""
-    eng = InferenceEngine(cfg, _serve_cfg(chunked_prefill_tokens=16),
-                          params=params)
-    prompt = _tokens(53, seed=31)
+    eng = support.engine(cfg, params, chunked_prefill_tokens=16)
+    prompt = support.tokens(53, seed=31)
     with jax.default_matmul_precision("highest"):
         req, = eng.generate([prompt], SamplingParams(temperature=0.0,
                                                      max_tokens=6))
     assert eng.stats()["shortconv"]["state_carry_chunks"] >= 2
-    assert _gaps(params, prompt, req.generated_tokens).max() < TOL
+    assert support.gaps(_ref, params, prompt,
+                        req.generated_tokens).max() < TOL
 
 
 def test_a_repeated_prompt_is_prefilled_again(engine):
     """Prefix reuse by page hash is ON by default and wrong for a layer
     with a recurrent state: turned off and counted."""
-    prompt = _tokens(36, seed=5)
+    prompt = support.tokens(36, seed=5)
     before = engine.stats()
     sp = SamplingParams(temperature=0.0, max_tokens=4)
     a, = engine.generate([prompt], sp)
@@ -581,4 +580,4 @@ def test_what_a_conv_window_allows_stays_allowed(cfg, feature):
 ])
 def test_the_engine_refuses_by_name(cfg, params, over, word):
     with pytest.raises(ValueError, match=word):
-        InferenceEngine(cfg, _serve_cfg(**over), params=params)
+        support.engine(cfg, params, **over)

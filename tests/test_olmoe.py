@@ -13,12 +13,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from benchmark.reference import moe_decoder
 from distributed_llm_training_and_inference_system_tpu.config import get_model_config
 from distributed_llm_training_and_inference_system_tpu.config.schema import (
     ConfigError,
     ModelConfig,
-    ServeConfig,
 )
 from distributed_llm_training_and_inference_system_tpu.models import gpt
 from distributed_llm_training_and_inference_system_tpu.models.layers import (
@@ -27,7 +27,6 @@ from distributed_llm_training_and_inference_system_tpu.models.layers import (
 )
 from distributed_llm_training_and_inference_system_tpu.ops import moe_gmm
 from distributed_llm_training_and_inference_system_tpu.serve import (
-    InferenceEngine,
     SamplingParams,
 )
 from distributed_llm_training_and_inference_system_tpu.serve.decode import (
@@ -65,7 +64,7 @@ def cfg():
 @pytest.fixture(scope="module")
 def params(cfg):
     """Seeded weights with NON-trivial norm scales (init leaves them 0)."""
-    p = gpt.init(cfg, jax.random.PRNGKey(0))
+    p = support.params_of(cfg)
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 8))
     for name in ("attn_norm", "mlp_norm", "q_norm", "k_norm"):
         s = p["blocks"][name]["scale"]
@@ -75,10 +74,6 @@ def params(cfg):
     p["blocks"]["moe"]["router"]["kernel"] = \
         p["blocks"]["moe"]["router"]["kernel"] * 20.0
     return p
-
-
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(3, 256, n).tolist()
 
 
 def _ref(params, tokens, **over):
@@ -131,7 +126,7 @@ def test_param_count_is_the_tree(name):
 # -- (1) the training-side forward against the reference --------------------
 
 def test_forward_matches_the_reference(cfg, params):
-    tokens = _tokens(40)
+    tokens = support.tokens(40)
     got = np.asarray(gpt.forward(params, jnp.asarray([tokens]), cfg))[0]
     want = _ref(params, tokens)
     assert np.abs(got - want).max() < TOL
@@ -141,7 +136,7 @@ def test_forward_matches_the_reference(cfg, params):
 @pytest.mark.parametrize("wrong,least", [
     ({"norm_topk_prob": True}, 1e-3), ({"qk_norm": "none"}, 1e-3)])
 def test_the_tolerance_sees_what_is_left_out(cfg, params, wrong, least):
-    tokens = _tokens(40)
+    tokens = support.tokens(40)
     got = np.asarray(gpt.forward(params, jnp.asarray([tokens]), cfg))[0]
     assert np.abs(got - _ref(params, tokens, **wrong)).max() > max(
         least, 10 * TOL)
@@ -151,7 +146,7 @@ def test_capacity_route_drops_and_the_serving_route_does_not(cfg, params):
     """Training's capacity dispatch loses (token, expert) pairs when an
     expert overflows; the reference never does, so only the dropless route
     agrees with it."""
-    tokens = _tokens(40)
+    tokens = support.tokens(40)
     want = _ref(params, tokens)
     capacity = np.asarray(gpt.forward(params, jnp.asarray([tokens]), cfg,
                                       moe_impl="capacity"))[0]
@@ -191,7 +186,7 @@ def test_prefill_then_decode_matches_the_reference(cfg, params, route):
     reference's full forward: the prompt through cold prefill (padded
     bucket), suffix prefill over cached pages, or chunked prefill; then
     eight decode steps in a batch of four slots of which one is live."""
-    seq = _tokens(37 + 8, seed=2)
+    seq = support.tokens(37 + 8, seed=2)
     n = 37
     want = _ref(params, seq)
     kp, vp = _pages(cfg)
@@ -234,7 +229,7 @@ def test_a_request_does_not_depend_on_its_companions(cfg, params):
     """One request's logits alone, in a full batch of other requests, and
     behind a longer padded prefill bucket are equal: no row displaces
     another, and padding and idle slots are never routed."""
-    seq = _tokens(21, seed=3)
+    seq = support.tokens(21, seed=3)
     table = np.zeros((4, 8), np.int32)
     for slot in range(4):
         table[slot, :4] = 1 + 4 * slot + np.arange(4)
@@ -249,7 +244,7 @@ def test_a_request_does_not_depend_on_its_companions(cfg, params):
         return np.asarray(lg)[slot]
 
     alone = extend([seq], 0)
-    others = [_tokens(21, seed=10 + i) for i in range(3)]
+    others = [support.tokens(21, seed=10 + i) for i in range(3)]
     crowded = extend([others[0], others[1], seq, others[2]], 2)
     # same rows at another place in another batch: the same logits to the
     # last float32 ulps of a differently tiled matmul
@@ -266,11 +261,8 @@ def test_a_request_does_not_depend_on_its_companions(cfg, params):
 
 def test_engine_serves_the_same_tokens_alone_and_in_a_full_batch(cfg, params):
     def engine():
-        return InferenceEngine(cfg, ServeConfig(
-            model="olmoe-test", max_batch_size=4, max_seq_len=96,
-            prefill_chunk=16, kv_block_size=PS, dtype="float32"),
-            params=params)
-    prompts = [_tokens(n, seed=20 + n) for n in (30, 9, 17, 25)]
+        return support.engine(cfg, params)
+    prompts = [support.tokens(n, seed=20 + n) for n in (30, 9, 17, 25)]
     greedy = SamplingParams(temperature=0.0, max_tokens=10)
     together = [r.generated_tokens
                 for r in engine().generate(prompts, greedy)]
@@ -315,14 +307,12 @@ def test_qk_norm_under_tensor_parallel_reduces_across_the_shards():
         s = params["blocks"][name]["scale"]
         params["blocks"][name]["scale"] = 0.3 * jax.random.normal(
             jax.random.PRNGKey(7 + i), s.shape, s.dtype)
-    prompts = [_tokens(19, seed=5), _tokens(11, seed=6)]
+    prompts = [support.tokens(19, seed=5), support.tokens(11, seed=6)]
     greedy = SamplingParams(temperature=0.0, max_tokens=8)
     out = []
     for tp in (1, 2):
-        eng = InferenceEngine(dense, ServeConfig(
-            model="olmoe-test", max_batch_size=2, max_seq_len=64,
-            prefill_chunk=16, kv_block_size=PS, dtype="float32",
-            tensor_parallel=tp), params=params)
+        eng = support.engine(dense, params, max_batch_size=2,
+                             tensor_parallel=tp)
         out.append([r.generated_tokens for r in eng.generate(prompts, greedy)])
     assert out[0] == out[1]
 
@@ -334,10 +324,7 @@ def test_an_moe_model_under_tensor_parallel_is_refused(cfg, params):
     if len(jax.devices()) < 2:
         pytest.skip("needs 2 devices")
     with pytest.raises(ValueError, match="MoE model is refused"):
-        InferenceEngine(cfg, ServeConfig(
-            model="olmoe-test", max_batch_size=2, max_seq_len=64,
-            kv_block_size=PS, dtype="float32", tensor_parallel=2),
-            params=params)
+        support.engine(cfg, params, tensor_parallel=2)
 
 
 # -- the dropless block and its kernel ------------------------------------------
@@ -421,9 +408,7 @@ def test_dense_model_programs_carry_nothing_of_this():
     cfg = get_model_config("gpt-test")
     tree = jax.eval_shape(lambda k: gpt.init(cfg, k), jax.random.PRNGKey(0))
     assert "q_norm" not in tree["blocks"] and "moe" not in tree["blocks"]
-    eng = InferenceEngine(cfg, ServeConfig(
-        model="gpt-test", max_batch_size=2, max_seq_len=64, prefill_chunk=16,
-        kv_block_size=PS, dtype="float32"), seed=0)
+    eng = support.engine(cfg)
     eng.generate([[5, 6, 7]], SamplingParams(temperature=0.0, max_tokens=3))
     assert "moe" not in eng.stats()
 

@@ -30,12 +30,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from distributed_llm_training_and_inference_system_tpu.config import get_model_config
-from distributed_llm_training_and_inference_system_tpu.config.schema import ServeConfig
-from distributed_llm_training_and_inference_system_tpu.models import init
 from distributed_llm_training_and_inference_system_tpu.models.gpt import (
     table_period)
-from distributed_llm_training_and_inference_system_tpu.ops import kda
 from distributed_llm_training_and_inference_system_tpu.serve import (
     InferenceEngine,
     Request,
@@ -45,20 +43,18 @@ from distributed_llm_training_and_inference_system_tpu.serve.decode import (
     PIECE_META, can_carry, decode_scan, extend_step_forward)
 from distributed_llm_training_and_inference_system_tpu.serve.scheduler import (
     RequestState)
+from serving_support import (
+    LINEAR, PS, SLOTS, STEPS, fresh_tokens, idle)
 
-PS, STEPS, SLOTS = 8, 4, 4
+pytestmark = pytest.mark.usefixtures("short_kda_chunks")
+
 C = InferenceEngine.RIDE_PAGES * PS
-LINEAR = "kimi-linear-test"
 HYBRID = "nemotron-h-test"
 MODELS = ["gpt-test", "olmoe-test", "xing-test", LINEAR, HYBRID]
 RNG = np.random.default_rng(36)
-
-
-@pytest.fixture(autouse=True, scope="module")
-def _short_kda_chunks():
-    plain, kda.CHUNK = kda.CHUNK, 8
-    yield
-    kda.CHUNK = plain
+# the engine shapes the hash pins below were taken at: a slot's table of 24
+# pages is in every program's text
+PINNED = dict(max_seq_len=192)
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -78,13 +74,9 @@ def _a_piece_writes_whole_pages():
     paged_attention._MAX_TILE_WINDOW = plain
 
 
-def _tokens(n):
-    return [int(t) for t in RNG.integers(1, 250, n)]
-
-
 def _residents(n=2):
     """Fresh prompts for the slots that decode while the others ride."""
-    return [_tokens(9), _tokens(13)][:n]
+    return [fresh_tokens(9), fresh_tokens(13)][:n]
 
 
 # what a prompt's pieces look like over a dispatch's 4 steps
@@ -101,18 +93,6 @@ SAMPLING = {
 }
 
 
-def _engine(name, cfg=None, **over):
-    cfg = cfg or get_model_config(name)
-    opts = dict(model=name, max_batch_size=SLOTS, max_seq_len=192,
-                prefill_chunk=32, kv_block_size=PS, dtype="float32",
-                decode_steps_per_dispatch=STEPS)
-    if name == LINEAR:
-        opts["chunked_prefill_tokens"] = C
-    opts.update(over)
-    return InferenceEngine(cfg, ServeConfig(**opts),
-                           params=init(cfg, jax.random.PRNGKey(0)), seed=0)
-
-
 def _normalised_sha256(text):
     """sha256 of a lowered program's StableHLO without the two things a
     result RECORD in place of a tuple and a renamed closure change (PR 45):
@@ -125,11 +105,16 @@ def _normalised_sha256(text):
     return hashlib.sha256(text.encode()).hexdigest()
 
 
+@functools.cache
+def _pair(name):
+    return support.engine(name), support.engine(name)
+
+
 @pytest.fixture(scope="module", params=MODELS)
 def engines(request):
     """(the engine whose prompts ride, the cold reference) of one model,
     shared by the module's cases: every case leaves both idle."""
-    return _engine(request.param), _engine(request.param)
+    return _pair(request.param)
 
 
 def _keep_pages(eng, kept):
@@ -163,14 +148,6 @@ def _serve(eng, prompts, sampling, residents=2, tag=""):
     return reqs
 
 
-def _idle(eng):
-    assert eng._reserved_pages == 0 and not eng._riding
-    assert not eng._req_slot and not eng.active.any()
-    assert all(r is None for r in eng.scheduler.slots)
-    # every page is free or kept for a prefix hit: none is held by a slot
-    assert eng.kv.free_pages == eng.kv.num_pages - 1
-
-
 # where a riding prompt's first piece starts: at 0, or behind the pages of a
 # prefix another request left in the cache (the prompt's ``cached`` tokens:
 # the reference at the idle engine is then the SUFFIX program; 8 pages,
@@ -188,13 +165,13 @@ def test_a_riding_prompt_is_served_the_cold_programs_tokens_and_pages(
         pytest.skip("a recurrent model reuses no prefix by page hash")
     tag = f"-{scenario}-{sampling}-{start}"
     # (fresh tokens a case: a repeated prompt would be a prefix hit)
-    prefix = _tokens(CACHED[start])
+    prefix = fresh_tokens(CACHED[start])
     if prefix:
         for eng in engines:     # the prefix's whole pages into the cache
-            _serve(eng, [prefix + _tokens(3)], SAMPLING["greedy"],
+            _serve(eng, [prefix + fresh_tokens(3)], SAMPLING["greedy"],
                    residents=0, tag=tag + "-prefix")
     # the scenario's lengths are what is left to prefill
-    prompts = [prefix + _tokens(n) for n in SCENARIOS[scenario]]
+    prompts = [prefix + fresh_tokens(n) for n in SCENARIOS[scenario]]
     rode, pages_rode, pages_cold = riding.stats(), {}, {}
     _keep_pages(riding, pages_rode)
     _keep_pages(cold, pages_cold)
@@ -217,22 +194,22 @@ def test_a_riding_prompt_is_served_the_cold_programs_tokens_and_pages(
         assert a.generated_tokens == b.generated_tokens
         for x, y in zip(pages_rode[a.request_id], pages_cold[b.request_id]):
             np.testing.assert_allclose(x, y, rtol=2e-4, atol=2e-5)
-    _idle(riding)
-    _idle(cold)
+    idle(riding)
+    idle(cold)
 
 
 def test_under_half_occupancy_the_cold_program_runs_and_nothing_rides(engines):
     eng, _ = engines
     before = eng.stats()
     # one resident of four slots: under the gate
-    [r] = _serve(eng, [_tokens(40)], SAMPLING["greedy"],
+    [r] = _serve(eng, [fresh_tokens(40)], SAMPLING["greedy"],
                  residents=1, tag="-gate")
     after = eng.stats()
     assert r.state is RequestState.FINISHED
     assert after["prefill_ride_tokens"] == before["prefill_ride_tokens"]
     assert after["prefill_ride_steps"] == before["prefill_ride_steps"]
     assert after["prefill_tokens"] - before["prefill_tokens"] == 9 + 40
-    _idle(eng)
+    idle(eng)
 
 
 def test_the_riding_rows_are_counted_as_prefill_rows(engines):
@@ -241,7 +218,7 @@ def test_the_riding_rows_are_counted_as_prefill_rows(engines):
     keeps reading the truth."""
     eng, _ = engines
     before = eng.stats()
-    _serve(eng, [_tokens(2 * C + 1)], SAMPLING["greedy"], tag="-rows")
+    _serve(eng, [fresh_tokens(2 * C + 1)], SAMPLING["greedy"], tag="-rows")
     after = eng.stats()
     # (the two residents are prefilled cold: 9 and 13 tokens, 32 rows each)
     assert (after["prefill_tokens"] - before["prefill_tokens"]
@@ -269,7 +246,7 @@ def _start_riding(eng, prompt, tag):
 
 def test_a_cancelled_riding_prompt_gives_its_pages_and_slot_back(engines):
     eng, _ = engines
-    req = _start_riding(eng, _tokens(STEPS * C + 20), "cancel")
+    req = _start_riding(eng, fresh_tokens(STEPS * C + 20), "cancel")
     held = eng.kv.free_pages
     with eng.lock:
         assert eng.scheduler.cancel(req.request_id)
@@ -278,12 +255,12 @@ def test_a_cancelled_riding_prompt_gives_its_pages_and_slot_back(engines):
     assert req.request_id not in eng._riding and not req.generated_tokens
     assert eng.kv.free_pages > held
     eng.run_until_idle()
-    _idle(eng)
+    idle(eng)
 
 
 def test_a_preempted_riding_prompt_is_requeued_and_served(engines):
     eng, cold = engines
-    prompt = _tokens(STEPS * C + 20)
+    prompt = fresh_tokens(STEPS * C + 20)
     req = _start_riding(eng, prompt, "preempt")
     slot, preemptions = req.slot, eng.total_preemptions
     with eng.lock:
@@ -297,18 +274,18 @@ def test_a_preempted_riding_prompt_is_requeued_and_served(engines):
                     tag="-preempt")
     assert req.state is RequestState.FINISHED
     assert req.generated_tokens == want.generated_tokens[:8]
-    _idle(eng)
+    idle(eng)
 
 
 def test_a_dry_pool_preempts_the_riding_prompt_first():
     """The riding prompt is the newest admission: when a resident cannot
     grow its chain, it is the victim, and everything is still served."""
-    eng = _engine("gpt-test", kv_num_blocks=14, prefix_caching=False)
+    eng = support.engine("gpt-test", kv_num_blocks=14, prefix_caching=False)
     long = SamplingParams(temperature=0.0, max_tokens=40)
     for i, p in enumerate(_residents()):
         assert eng.scheduler.add_request(Request(f"res{i}", p, long))
     eng.step()
-    rider = Request("rider", _tokens(40),
+    rider = Request("rider", fresh_tokens(40),
                     SamplingParams(temperature=0.0, max_tokens=4))
     assert eng.scheduler.add_request(rider)
     eng.run_until_idle()
@@ -321,12 +298,12 @@ def test_a_dry_pool_preempts_the_riding_prompt_first():
 
 def test_fail_all_drops_the_riding_prompts(engines):
     eng, _ = engines
-    req = _start_riding(eng, _tokens(STEPS * C + 20), "fail")
+    req = _start_riding(eng, fresh_tokens(STEPS * C + 20), "fail")
     eng.fail_all("boom")
     assert req.state is RequestState.FAILED and not eng._riding
     assert eng._pending is None
     eng.run_until_idle()
-    _idle(eng)
+    idle(eng)
 
 
 def test_a_riding_prompt_does_not_break_the_pipelined_chain(engines):
@@ -335,7 +312,7 @@ def test_a_riding_prompt_does_not_break_the_pipelined_chain(engines):
     dispatch chains on, and the host, which learns the first token a
     dispatch later, already counts the slot resident."""
     eng, _ = engines
-    req = _start_riding(eng, _tokens(STEPS * C + 20), "chain")
+    req = _start_riding(eng, fresh_tokens(STEPS * C + 20), "chain")
     submits = eng.stats()["phases"]["llmctl.engine.decode.submit"]["n"]
     first = eng._pending         # carries 4 pieces, ends no prompt
     assert first is not None and not eng.active[req.slot]
@@ -357,7 +334,7 @@ def test_a_riding_prompt_does_not_break_the_pipelined_chain(engines):
             == submits + 2)
     eng.run_until_idle()
     assert len(req.generated_tokens) == 8
-    _idle(eng)
+    idle(eng)
 
 
 def test_a_riding_slots_steps_wait_up_to_its_last_piece_and_then_decode(
@@ -388,7 +365,7 @@ def test_a_riding_slots_steps_wait_up_to_its_last_piece_and_then_decode(
     start = eng.stats()
     # (two cold prefills and the first dispatch, then the rider admitted and
     # its first four pieces chained behind it: the first is applied here)
-    req = _start_riding(eng, _tokens(STEPS * C + 20), "ledger")
+    req = _start_riding(eng, fresh_tokens(STEPS * C + 20), "ledger")
     got, at = gained(start)
     assert got == {"useful": 2 * STEPS, "overrun": 0, "prompt_wait": 0,
                    "empty": 2 * STEPS, "first_tokens": 2,
@@ -413,7 +390,7 @@ def test_a_riding_slots_steps_wait_up_to_its_last_piece_and_then_decode(
     assert got["first_tokens"] == 3 and got["prompt_wait"] == STEPS + 2
     assert got["tokens_credited"] == 60 + 60 + 8
     assert got["useful"] == got["tokens_credited"] - 3
-    _idle(eng)
+    idle(eng)
 
 
 @pytest.mark.parametrize("sampling", list(SAMPLING))
@@ -426,7 +403,7 @@ def test_the_host_catches_up_once_in_a_while_and_nothing_else_changes(
     both sides of a catch-up is served the cold program's tokens."""
     eng, cold = engines
     monkeypatch.setattr(eng, "CHAIN_DISPATCHES", every)
-    prompts = [_tokens(100), _tokens(C + 3)]
+    prompts = [fresh_tokens(100), fresh_tokens(C + 3)]
     tag = f"-catch{every}-{sampling}"
     long = SamplingParams(temperature=0.0, max_tokens=60)
     for i, p in enumerate(_residents()):
@@ -452,8 +429,8 @@ def test_the_host_catches_up_once_in_a_while_and_nothing_else_changes(
     for a, b in zip(got, want):
         assert a.state is RequestState.FINISHED
         assert a.generated_tokens == b.generated_tokens
-    _idle(eng)
-    _idle(cold)
+    idle(eng)
+    idle(cold)
 
 
 def test_an_engine_with_a_prefill_complete_hook_does_not_ride(engines):
@@ -464,18 +441,18 @@ def test_an_engine_with_a_prefill_complete_hook_does_not_ride(engines):
     seen, before = [], eng.stats()["prefill_ride_tokens"]
     eng.on_prefill_complete = lambda r: seen.append(
         (r.request_id, r.state, len(r.generated_tokens)))
-    _serve(eng, [_tokens(20)], SAMPLING["greedy"], tag="-hook")
+    _serve(eng, [fresh_tokens(20)], SAMPLING["greedy"], tag="-hook")
     eng.on_prefill_complete = None
     assert ("p-hook0", RequestState.RUNNING, 1) in seen
     assert eng.stats()["prefill_ride_tokens"] == before
-    _idle(eng)
+    idle(eng)
 
 
 def test_a_prompt_that_rode_is_a_prefix_hit_for_the_next(engines):
     eng, _ = engines
     if eng.cfg.is_recurrent:
         pytest.skip("a recurrent model reuses no prefix by page hash")
-    prompt = _tokens(3 * PS + 3)
+    prompt = fresh_tokens(3 * PS + 3)
     [a] = _serve(eng, [prompt], SAMPLING["greedy"], tag="-hit-a")
     cached = eng.stats()["prefix_cached_tokens"]
     [b] = _serve(eng, [prompt], SAMPLING["greedy"], tag="-hit-b")
@@ -488,9 +465,9 @@ def test_a_prompt_that_rode_is_a_prefix_hit_for_the_next(engines):
     dict(quantization="int8"), dict(tensor_parallel=2)],
     ids=["speculative", "static scheduler", "int8 weights", "tp 2"])
 def test_engines_that_keep_todays_path_never_ride(over):
-    eng = _engine("gpt-test", **over)
+    eng = support.engine("gpt-test", **over)
     assert eng._ride_rows == 0 and eng._decode_tail_args() == (None, None)
-    reqs = _serve(eng, [_tokens(20)], SAMPLING["greedy"])
+    reqs = _serve(eng, [fresh_tokens(20)], SAMPLING["greedy"])
     assert all(r.state is RequestState.FINISHED for r in reqs)
     assert eng.stats()["prefill_ride_tokens"] == 0
 
@@ -504,7 +481,7 @@ def test_the_carry_is_read_off_the_page_size(name, page, rows):
     (128 rows); ONE page where a page alone holds as many (a latent model's
     pages of 256: two would be a window of 512 rows in one step; the
     delta-rule model's page is four sub-chunks of 64)."""
-    eng = _engine(name, kv_block_size=page, max_seq_len=512)
+    eng = support.engine(name, kv_block_size=page, max_seq_len=512)
     assert eng._ride_rows == rows
     _state, pieces = eng._decode_tail_args()
     assert pieces.shape == (STEPS, PIECE_META + rows)
@@ -519,12 +496,8 @@ def test_a_layer_table_model_keeps_the_parents_decode_program(name, over):
     text of the parent's ``_decode_impl_n`` (written out below as it stood
     before PR 36, a tuple for its result): the table walked whole,
     ``recur_step`` unjitted."""
-    cfg = get_model_config(name)
-    eng = InferenceEngine(
-        cfg, ServeConfig(model=name, max_batch_size=SLOTS, max_seq_len=128,
-                         dtype="float32", kv_block_size=PS, prefill_chunk=16,
-                         decode_steps_per_dispatch=STEPS, **over),
-        params=init(cfg, jax.random.PRNGKey(0)))
+    eng = support.engine(name, **over)
+    cfg = eng.cfg
     assert eng._ride_rows == 0
     args = (eng.params, eng.kv.k_pages, eng.kv.v_pages,
             jnp.asarray(eng.last_tokens), jnp.asarray(eng.positions),
@@ -591,7 +564,7 @@ def test_the_other_models_decode_programs_are_the_parents(name):
     diffusion model's denoise program to PR 54's (PR 43 and 44 taught the
     table walk a recurrent layer's piece by one seam, ``recur_at``; PR 45
     made what a step and a dispatch return a record)."""
-    eng = _engine(name)
+    eng = support.engine(name, **PINNED)
     assert eng._ride_rows == (0 if name == "sdar-test" else C)
     text = eng._decode_jit.lower(
         eng.params, eng.kv.k_pages, eng.kv.v_pages,
@@ -650,13 +623,9 @@ def _prefill_texts(eng, bucket=32, programs=("cold", "suffix", "chunk")):
 def _linear_prefill_texts():
     """{program: lowered text} of the linear test model's cold, chunk and
     final-chunk programs as an engine jits them."""
-    cfg = get_model_config(LINEAR)
-    eng = InferenceEngine(
-        cfg, ServeConfig(model=LINEAR, max_batch_size=SLOTS, max_seq_len=128,
-                         dtype="float32", kv_block_size=PS, prefill_chunk=16,
-                         chunked_prefill_tokens=32,
-                         decode_steps_per_dispatch=STEPS),
-        params=init(cfg, jax.random.PRNGKey(0)))
+    # (pinned at a table of 16 pages, buckets of 16 and chunks of 32)
+    eng = support.engine(LINEAR, max_seq_len=128, prefill_chunk=16,
+                         chunked_prefill_tokens=32)
     texts = _prefill_texts(eng)
     return {"cold": texts["cold"], "chunk": texts["chunk"],
             "final chunk": texts["suffix"]}
@@ -669,7 +638,8 @@ HYBRID_COLD_PREFILL = "ffc2768f7018028473533fbea7e1b8e2311e2623ee681d2061d650237
 
 
 def _hybrid_cold_prefill_text():
-    return _prefill_texts(_engine(HYBRID), programs=("cold",))["cold"]
+    return _prefill_texts(support.engine(HYBRID, **PINNED),
+                          programs=("cold",))["cold"]
 
 
 def test_the_hybrid_models_cold_prefill_program_is_the_parents():
@@ -692,7 +662,8 @@ SDAR_PREFILL = {
 
 def test_the_diffusion_models_prefill_programs_are_the_parents():
     assert {name: _normalised_sha256(text) for name, text in
-            _prefill_texts(_engine("sdar-test")).items()} == SDAR_PREFILL
+            _prefill_texts(support.engine("sdar-test", **PINNED)).items()
+            } == SDAR_PREFILL
 
 
 def test_the_linear_models_prefill_programs_are_pinned():
@@ -755,9 +726,11 @@ def _serve_program_hashes(name):
     """{program: normalised hash} of one template's prefill programs: one
     engine and one lowering a program (no compile), whatever the cases."""
     programs = tuple(p for n, p in SERVE_PROGRAMS if n == name and "," not in p)
-    hashes = _prefill_texts(_engine(name), programs=programs)
+    hashes = _prefill_texts(support.engine(name, **PINNED),
+                            programs=programs)
     for pages in [p for n, p in SERVE_PROGRAMS if n == name and "," in p]:
-        eng = _engine(name, kv_quantization=pages.split()[1])
+        eng = support.engine(name, kv_quantization=pages.split()[1],
+                             **PINNED)
         hashes[pages] = _prefill_texts(eng, programs=("cold",))["cold"]
     return {p: _normalised_sha256(text) for p, text in hashes.items()}
 
@@ -835,7 +808,7 @@ def test_a_step_without_a_piece_samples_what_the_plain_step_samples(name):
     every slot bit for bit, the idle slot's untouched), and no first
     token."""
     cfg = get_model_config(name)
-    params = init(cfg, jax.random.PRNGKey(0))
+    params = support.params_of(cfg)
     B, pages = 3, 9
     tables = jnp.asarray([[1, 2], [3, 4], [5, 6]], jnp.int32)
     args = (jnp.asarray([5, 6, 7], jnp.int32), jnp.asarray([3, 9, 0]),
@@ -873,7 +846,7 @@ def test_a_riding_programs_two_bodies_share_what_they_do_alike(name):
     cfg = (get_model_config(name) if name in MODELS
            else _hybrid_cfg(HYBRID_CELLS_TABLE) if "hybrid" in name
            else _linear_cfg(CELLS_TABLE))
-    params = init(cfg, jax.random.PRNGKey(0))
+    params = support.params_of(cfg)
     B, pages = 3, 9
     args = (params, jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
             *_pools(cfg, pages),
@@ -999,7 +972,7 @@ def _linear_step_case(live, start, table=None):
     a former occupant's state; (cfg, params, decode_scan's arguments, the
     state pools, the piece's row)."""
     cfg = _linear_cfg(table)
-    params = init(cfg, jax.random.PRNGKey(0))
+    params = support.params_of(cfg)
     B, pages = 4, 12
     tables = jnp.asarray([[1, 2, 0, 0], [3, 4, 0, 0], [5, 6, 7, 8],
                           [0, 0, 0, 0]], jnp.int32)
@@ -1079,7 +1052,7 @@ def test_a_piece_leaves_the_pools_a_chunk_program_leaves(live, start, table):
 
 @pytest.fixture(scope="module")
 def linear_engines():
-    return _engine(LINEAR), _engine(LINEAR)
+    return _pair(LINEAR)
 
 
 def test_a_document_rides_from_zero_in_a_slot_that_held_a_state(
@@ -1092,7 +1065,7 @@ def test_a_document_rides_from_zero_in_a_slot_that_held_a_state(
     eng.kv.state = {name: jnp.asarray(RNG.normal(size=pool.shape) * 0.3,
                                       pool.dtype)
                     for name, pool in eng.kv.state.items()}
-    prompt = _tokens(5 * PS)
+    prompt = fresh_tokens(5 * PS)
     before = eng.stats()
     [got] = _serve(eng, [prompt], SAMPLING["greedy"], tag="-occupied")
     [want] = _serve(cold, [prompt], SAMPLING["greedy"], residents=0,
@@ -1106,8 +1079,8 @@ def test_a_document_rides_from_zero_in_a_slot_that_held_a_state(
     assert after["kda"]["state_carry_tokens"] == after["state_carry_tokens"]
     # (the clean engine's chunk programs: every chunk but the first)
     assert cold.stats()["state_carry_chunks"] >= 2
-    _idle(eng)
-    _idle(cold)
+    idle(eng)
+    idle(cold)
 
 
 @pytest.mark.parametrize("how", ["cancel", "preempt", "fail_all"])
@@ -1118,7 +1091,7 @@ def test_a_slot_is_reused_after_a_riding_document_was_dropped(
     them takes that slot) ride from zero and are served the chunk
     programs' tokens."""
     eng, cold = linear_engines
-    req = _start_riding(eng, _tokens(STEPS * C + 20), f"dropped-{how}")
+    req = _start_riding(eng, fresh_tokens(STEPS * C + 20), f"dropped-{how}")
     slot = req.slot
     with eng.lock:
         if how == "cancel":
@@ -1130,8 +1103,8 @@ def test_a_slot_is_reused_after_a_riding_document_was_dropped(
         eng.fail_all("boom")
     eng.run_until_idle()
     assert req.state in (RequestState.CANCELLED, RequestState.FAILED)
-    _idle(eng)
-    prompts = [_tokens(2 * C + 3), _tokens(C + 1), _tokens(3 * C)]
+    idle(eng)
+    prompts = [fresh_tokens(2 * C + 3), fresh_tokens(C + 1), fresh_tokens(3 * C)]
     tag = f"-after-{how}"
     # two residents and three riders: every slot is taken, ``slot`` too
     got = _serve(eng, prompts, SAMPLING["greedy"], tag=tag)
@@ -1139,8 +1112,8 @@ def test_a_slot_is_reused_after_a_riding_document_was_dropped(
     for a, b in zip(got, want):
         assert a.state is RequestState.FINISHED
         assert a.generated_tokens == b.generated_tokens
-    _idle(eng)
-    _idle(cold)
+    idle(eng)
+    idle(cold)
 
 
 # -- a state-space (``M``) model's piece: one chunk of the scan from its slot's
@@ -1182,7 +1155,7 @@ def _hybrid_step_case(n, table=None):
     params, decode_scan's arguments, the state pools, the pieces, the
     prompt)."""
     cfg = _hybrid_cfg(table)
-    params = init(cfg, jax.random.PRNGKey(0))
+    params = support.params_of(cfg)
     B, pages = 4, 14
     tables = np.zeros((B, 8), np.int32)
     tables[0, :2], tables[1, :2], tables[2] = (1, 2), (3, 4), range(5, 13)
@@ -1276,7 +1249,7 @@ def hybrid_cell_engines():
     """(riding, cold) engines of the hybrid test model with the CELL's
     two-motif table: the riding program walks it by a loop."""
     cfg = _hybrid_cfg(HYBRID_CELLS_TABLE)
-    return _engine(HYBRID, cfg), _engine(HYBRID, cfg)
+    return support.engine(cfg), support.engine(cfg)
 
 
 def test_prompts_of_one_two_and_four_pieces_ride_to_the_cold_tokens(
@@ -1291,7 +1264,7 @@ def test_prompts_of_one_two_and_four_pieces_ride_to_the_cold_tokens(
     eng.kv.state = {name: jnp.asarray(RNG.normal(size=pool.shape) * 0.3,
                                       pool.dtype)
                     for name, pool in eng.kv.state.items()}
-    prompts = [_tokens(C - 3), _tokens(2 * C - 5), _tokens(3 * C + 1)]
+    prompts = [fresh_tokens(C - 3), fresh_tokens(2 * C - 5), fresh_tokens(3 * C + 1)]
     before = eng.stats()
     got = _serve(eng, prompts, SAMPLING["greedy"], tag="-pieces")
     want = _serve(cold, prompts, SAMPLING["greedy"], residents=0,
@@ -1312,8 +1285,8 @@ def test_prompts_of_one_two_and_four_pieces_ride_to_the_cold_tokens(
     assert (after["ssm"]["slot_steps"] - before["ssm"]["slot_steps"]
             <= (after["decode_steps"] - before["decode_steps"]) * SLOTS)
     assert cold.stats()["state_carry_chunks"] == 0      # cold programs alone
-    _idle(eng)
-    _idle(cold)
+    idle(eng)
+    idle(cold)
 
 
 @pytest.mark.parametrize("how", ["cancel", "preempt", "fail_all"])
@@ -1324,7 +1297,7 @@ def test_a_slot_is_reused_after_a_riding_hybrid_prompt_was_dropped(
     them takes that slot) ride from zero and decode as on a fresh engine
     (the cold programs' tokens)."""
     eng, cold = hybrid_cell_engines
-    req = _start_riding(eng, _tokens(STEPS * C + 20), f"dropped-h-{how}")
+    req = _start_riding(eng, fresh_tokens(STEPS * C + 20), f"dropped-h-{how}")
     slot = req.slot
     with eng.lock:
         if how == "cancel":
@@ -1337,8 +1310,8 @@ def test_a_slot_is_reused_after_a_riding_hybrid_prompt_was_dropped(
     eng.run_until_idle()
     assert req.state in (RequestState.CANCELLED, RequestState.FAILED)
     assert np.abs(np.asarray(eng.kv.state["ssm"][:, slot])).max() > 0
-    _idle(eng)
-    prompts = [_tokens(2 * C + 3), _tokens(C + 1), _tokens(3 * C)]
+    idle(eng)
+    prompts = [fresh_tokens(2 * C + 3), fresh_tokens(C + 1), fresh_tokens(3 * C)]
     tag = f"-after-h-{how}"
     # two residents and three riders: every slot is taken, ``slot`` too
     got = _serve(eng, prompts, SAMPLING["greedy"], tag=tag)
@@ -1346,5 +1319,5 @@ def test_a_slot_is_reused_after_a_riding_hybrid_prompt_was_dropped(
     for a, b in zip(got, want):
         assert a.state is RequestState.FINISHED
         assert a.generated_tokens == b.generated_tokens
-    _idle(eng)
-    _idle(cold)
+    idle(eng)
+    idle(cold)

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from chip_smoke import seeded_reference
 from distributed_llm_training_and_inference_system_tpu.config import (
     get_model_config)
@@ -75,19 +76,15 @@ def test_another_prng_implementation_is_refused_by_name():
 
 # -- (b) every prefill path against the direct reference ------------------------
 
-SPAN, PS, NEW = 128, 8, 12
+SPAN, NEW = 128, 12       # the reference's one padded length; new tokens
 SEEDED = dict(temperature=0.8, top_k=40, top_p=0.9)
-
-
-def _tokens(n, seed):
-    return np.random.default_rng(seed).integers(3, 256, n).tolist()
 
 
 @pytest.fixture(scope="module", params=["gpt-test", "olmoe-test",
                                         "nemotron-h-test"])
 def model(request):
     cfg = get_model_config(request.param)
-    params = gpt.init(cfg, jax.random.PRNGKey(0))
+    params = support.params_of(cfg)
     forward = jax.jit(lambda p, t: gpt.forward(p, t, cfg))
 
     def next_logits(context):
@@ -97,17 +94,9 @@ def model(request):
     return cfg, params, next_logits
 
 
-def _engine(model, **over):
-    cfg, params, _ = model
-    return InferenceEngine(cfg, ServeConfig(**{**dict(
-        model=cfg.name, max_batch_size=4, max_seq_len=SPAN, dtype="float32",
-        kv_block_size=PS, prefill_chunk=16, decode_steps_per_dispatch=4),
-        **over}), params=params)
-
-
 def _cold(model):
-    eng = _engine(model)
-    prompts = [_tokens(37, seed=1)]
+    eng = support.engine(model[0])
+    prompts = [support.tokens(37, seed=1)]
     reqs = eng.generate(prompts, SamplingParams(max_tokens=NEW, seed=41,
                                                 **SEEDED))
     assert eng.stats()["prefix_cached_tokens"] == 0
@@ -116,10 +105,10 @@ def _cold(model):
 
 
 def _suffix(model):
-    eng = _engine(model)
-    first = _tokens(40, seed=2)
+    eng = support.engine(model[0])
+    first = support.tokens(40, seed=2)
     eng.generate([first], SamplingParams(temperature=0.0, max_tokens=2))
-    reqs = eng.generate([first[:32] + _tokens(11, seed=3)],
+    reqs = eng.generate([first[:32] + support.tokens(11, seed=3)],
                         SamplingParams(max_tokens=NEW, seed=42, **SEEDED))
     assert eng.stats()["prefix_cached_tokens"] == 32
     assert eng.compiled_programs()["prefill_extend_buckets"] == 1
@@ -127,8 +116,8 @@ def _suffix(model):
 
 
 def _chunked(model):
-    eng = _engine(model, chunked_prefill_tokens=16)
-    reqs = eng.generate([_tokens(43, seed=4)],
+    eng = support.engine(model[0], chunked_prefill_tokens=16)
+    reqs = eng.generate([support.tokens(43, seed=4)],
                         SamplingParams(max_tokens=NEW, seed=43, **SEEDED))
     programs = eng.compiled_programs()
     assert programs["prefill_chunk_buckets"] >= 1
@@ -140,9 +129,9 @@ def _chunked(model):
 def _swap_in(model):
     """Two requests that outgrow a pool of 10 pages: one is swapped out and
     comes back through ``_restore_swapped``, which seeds the slot anew."""
-    eng = _engine(model, admission="ondemand", preemption="swap",
+    eng = support.engine(model[0], admission="ondemand", preemption="swap",
                   kv_num_blocks=11)
-    reqs = eng.generate([_tokens(16, seed=5), _tokens(16, seed=6)],
+    reqs = eng.generate([support.tokens(16, seed=5), support.tokens(16, seed=6)],
                         SamplingParams(max_tokens=40, seed=44, **SEEDED))
     assert eng.total_swap_ins > 0
     return reqs

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from benchmark.reference import sessions_decoder as ref
 from distributed_llm_training_and_inference_system_tpu.config.presets import (
     SOLAR_OPEN2_TEST_PUBLISHED,
@@ -42,9 +43,6 @@ from distributed_llm_training_and_inference_system_tpu.models.layers import (
 )
 from distributed_llm_training_and_inference_system_tpu.ops import kda
 from distributed_llm_training_and_inference_system_tpu.serve import decode
-from distributed_llm_training_and_inference_system_tpu.serve.engine import (
-    InferenceEngine,
-)
 from distributed_llm_training_and_inference_system_tpu.serve.kv_cache import (
     PagedKVCache,
     refused,
@@ -60,13 +58,7 @@ PS = 8
 ROOT = Path(__file__).resolve().parents[1]
 
 
-@pytest.fixture(autouse=True, scope="module")
-def _short_kda_chunks():
-    """A chunk of 8 in place of ``ops/kda.py CHUNK`` = 64, so that the tiny
-    windows of this file run several chunks with the state carried."""
-    plain, kda.CHUNK = kda.CHUNK, 8
-    yield
-    kda.CHUNK = plain
+pytestmark = pytest.mark.usefixtures("short_kda_chunks")
 
 
 @pytest.fixture(scope="module")
@@ -78,7 +70,7 @@ def seeded(cfg, seed=0):
     """Seeded weights with every norm's scale and the selection bias made
     non-trivial (at ``gpt.init``'s zeros a missing norm weight or bias
     would not show), and a router sharp enough that its scores differ."""
-    tree = gpt.init(cfg, jax.random.PRNGKey(seed))
+    tree = support.params_of(cfg, seed)
     key = jax.random.PRNGKey(seed + 5)
 
     def one(path, x):
@@ -98,10 +90,6 @@ def seeded(cfg, seed=0):
 @pytest.fixture(scope="module")
 def params(cfg):
     return seeded(cfg)
-
-
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(3, 250, n).tolist()
 
 
 def _reference(params, tokens, positions=None, wrong=None, config=C, **kw):
@@ -127,7 +115,7 @@ def test_the_forward_is_the_reference(cfg, params, held, first):
             n: {"kernel": params["blocks"]["moe"][n]["kernel"][
                 :, first:first + held]} for n in ("gate", "up", "down")})
         tree = dict(params, blocks=dict(params["blocks"], moe=moe))
-    tokens = _tokens(70, 1)
+    tokens = support.tokens(70, 1)
     with jax.default_matmul_precision("highest"):
         got = gpt.forward(tree, jnp.asarray([tokens]), share)[0]
     want = _reference(tree, tokens, config=dict(
@@ -140,7 +128,7 @@ def test_the_forward_is_the_reference(cfg, params, held, first):
     "beta_unscaled", "no_gate", "rope", "bf16_state", "zero_at_hit",
     "stale_at_hit", "no_renorm"])
 def test_each_departure_moves_the_logits(params, wrong):
-    tokens = _tokens(70, 1)
+    tokens = support.tokens(70, 1)
     right = _reference(params, tokens)
     moved = _reference(params, tokens, wrong=wrong, hit=32, page=PS)
     assert np.abs(moved - right).max() > 20 * TOL
@@ -264,7 +252,7 @@ def test_windows_a_snapshot_and_decode_steps_over_the_pools(cfg, params):
     rest: the reference's logits again, and those of a slot that starts
     there from whatever it held are not."""
     window, step = _programs(cfg)
-    tokens = _tokens(60, 2)
+    tokens = support.tokens(60, 2)
     want = _reference(params, tokens)
     kv = _pools(cfg)
     kv.allocate(1, 64)
@@ -307,7 +295,7 @@ def test_an_entry_goes_with_the_page_it_stands_on(cfg):
         import prefix_page_hashes
     kv = PagedKVCache(cfg, num_slots=2, max_seq_len=64, page_size=PS,
                       num_pages=6, dtype=jnp.float32, snapshot_entries=2)
-    hashes = prefix_page_hashes(_tokens(24, 9), PS)
+    hashes = prefix_page_hashes(support.tokens(24, 9), PS)
     kv.allocate(0, 24)
     pages = [int(p) for p in kv.block_tables[0, :3]]
     kv.register_pages(list(zip(hashes, pages)))
@@ -322,7 +310,7 @@ def test_an_entry_goes_with_the_page_it_stands_on(cfg):
     assert kv.snapshot_at(hashes[1]) is None and kv.lookup_prefix(hashes) == []
     assert (kv.snapshot_evictions, len(kv._snap_free)) == (1, 2)
     # room: the least recently used entry goes, a pinned one never
-    other = prefix_page_hashes(_tokens(24, 10), PS)
+    other = prefix_page_hashes(support.tokens(24, 10), PS)
     e0, e1 = kv.claim_snapshot(other[0]), kv.claim_snapshot(other[1])
     kv.pin_snapshot(other[0])
     assert kv.claim_snapshot(other[2]) == e1 and kv.snapshot_at(other[1]) is None
@@ -336,23 +324,16 @@ def test_an_entry_goes_with_the_page_it_stands_on(cfg):
 
 # -- the engine ------------------------------------------------------------------
 
-def _engine(cfg, params, **serve):
-    opts = dict(model="solar-open2-test", dtype="float32", max_batch_size=4,
-                max_seq_len=256, kv_block_size=PS, kv_hbm_budget_gb=0.001,
-                chunked_prefill_tokens=32, prefill_chunk=16,
-                decode_steps_per_dispatch=4, state_snapshot_entries=8)
-    opts.update(serve)
-    return InferenceEngine(cfg, ServeConfig(**opts), params=params)
+# chunks of 32 tokens and snapshots of the state at 8 of the page boundaries
+# they end on: the properties below are of prefix reuse behind a snapshot
+SNAPSHOTS = dict(chunked_prefill_tokens=32, state_snapshot_entries=8)
 
 
-def _greedy(params, prompt, n):
-    out = []
-    for _ in range(n):
-        # (one compiled length for every step of every prompt)
-        lg = ref.logits(params, prompt + out, C, pad_to=192, compiled=True,
-                        positions=[len(prompt) + len(out) - 1])
-        out.append(int(lg[0].argmax()))
-    return out
+def _last_logits(params):
+    # (one compiled length for every step of every prompt)
+    return lambda context: ref.logits(
+        params, context, C, pad_to=192, compiled=True,
+        positions=[len(context) - 1])[0]
 
 
 GREEDY = SamplingParams(temperature=0.0, max_tokens=6)
@@ -366,7 +347,7 @@ def _busy(eng, tag):
     long = SamplingParams(temperature=0.0, max_tokens=40)
     for i, n in enumerate((9, 13)):
         assert eng.scheduler.add_request(Request(
-            f"res-{tag}-{i}", _tokens(n, next(_resident_seeds)), long))
+            f"res-{tag}-{i}", support.tokens(n, next(_resident_seeds)), long))
     while eng.active.sum() < 2:     # (two chunks each: one ends at the cut)
         eng.step()
 
@@ -382,7 +363,7 @@ def _serve(eng, prompt, tag, busy=False):
 
 @pytest.fixture(scope="module")
 def engine(cfg, params):
-    return _engine(cfg, params)
+    return support.engine(cfg, params, **SNAPSHOTS)
 
 
 def test_cold_and_chunked_prompts_and_the_decode_steps_behind_them(
@@ -398,9 +379,9 @@ def test_cold_and_chunked_prompts_and_the_decode_steps_behind_them(
         # under a page: the cold program from a zero state, no snapshot;
         # longer: chunk by chunk with one chunk ending at the cut
         for n, seed in ((7, 1), (20, 2), (100, 3)):
-            prompt = _tokens(n, seed)
-            assert _serve(engine, prompt, f"plain{n}") == _greedy(
-                params, prompt, 6)
+            prompt = support.tokens(n, seed)
+            assert _serve(engine, prompt, f"plain{n}") == support.greedy(
+                _last_logits(params), prompt, 6)
     st = engine.stats()
     assert st["compiled_programs"]["prefill_dense_buckets"] == 1
     assert st["kda"]["snapshots_taken"] == 2
@@ -415,13 +396,13 @@ def test_a_second_turn_is_served_from_its_snapshot(cfg, params, engine, busy):
     the decode steps of a busy one: the reference's tokens either way."""
     tag = "ride" if busy else "chunk"
     before = engine.stats()
-    first = _tokens(70, 20 + busy)
+    first = support.tokens(70, 20 + busy)
     with jax.default_matmul_precision("highest"):
         reply = _serve(engine, first, f"t1{tag}", busy)
-        assert reply == _greedy(params, first, 6)
-        second = first + reply + _tokens(21, 30 + busy)
+        assert reply == support.greedy(_last_logits(params), first, 6)
+        second = first + reply + support.tokens(21, 30 + busy)
         got = _serve(engine, second, f"t2{tag}", busy)
-        assert got == _greedy(params, second, 6)
+        assert got == support.greedy(_last_logits(params), second, 6)
     after = engine.stats()
     d = {k: after["kda"][k] - before["kda"][k] for k in (
         "snapshots_taken", "snapshot_hits", "snapshot_misses",
@@ -439,14 +420,16 @@ def test_a_chain_without_a_snapshot_is_prefilled_from_zero(cfg, params):
     """ONE entry: a second session's snapshot takes the first's room. The
     first session's next turn finds its pages hashed and no snapshot on
     them: a miss, prefilled from zero, and still right."""
-    eng = _engine(cfg, params, state_snapshot_entries=1)
-    a, b = _tokens(70, 40), _tokens(70, 41)
+    eng = support.engine(cfg, params, **{**SNAPSHOTS,
+                                       "state_snapshot_entries": 1})
+    a, b = support.tokens(70, 40), support.tokens(70, 41)
     with jax.default_matmul_precision("highest"):
         ra = _serve(eng, a, "a1")
         _serve(eng, b, "b1")
         assert eng.kv.snapshot_evictions == 1
-        again = a + ra + _tokens(11, 42)
-        assert _serve(eng, again, "a2") == _greedy(params, again, 6)
+        again = a + ra + support.tokens(11, 42)
+        assert _serve(eng, again, "a2") == support.greedy(
+            _last_logits(params), again, 6)
     st = eng.stats()["kda"]
     assert (st["snapshot_hits"], st["snapshot_misses"]) == (0, 1)
     assert st["snapshot_tokens_skipped"] == 0
@@ -458,12 +441,14 @@ def test_a_snapshot_pool_adds_two_programs_and_changes_none(cfg, params,
     """``state_snapshot_entries`` 0 is the engine as it was: no pool, prefix
     reuse off and counted, and the SAME decode program text as with a pool
     (the copies are programs of their own)."""
-    plain = _engine(cfg, params, state_snapshot_entries=0)
+    plain = support.engine(cfg, params, **{**SNAPSHOTS,
+                                         "state_snapshot_entries": 0})
     assert plain.kv.snapshots is None and not plain._prefix_caching
     assert plain._snapshot_take is None
     with jax.default_matmul_precision("highest"):
-        prompt = _tokens(20, 80)        # the cold program, three pages
-        assert _serve(plain, prompt, "off") == _greedy(params, prompt, 6)
+        prompt = support.tokens(20, 80)        # the cold program, three pages
+        assert _serve(plain, prompt, "off") == support.greedy(
+            _last_logits(params), prompt, 6)
     st = plain.stats()
     assert st["compiled_programs"]["prefill_dense_buckets"] == 1
     assert st["kda"]["refused"] == {"prefix_caching": 1}
@@ -498,19 +483,9 @@ def test_what_keeps_no_snapshot_keeps_prefix_reuse_off():
 
 # -- the schema ------------------------------------------------------------------
 
-def _catalog_row():
-    rows = Path("/opt/skills/guides/model-configs/architectures.jsonl")
-    if not rows.exists():
-        pytest.skip("no model-configs catalog here")
-    for line in rows.read_text().splitlines():
-        row = json.loads(line)
-        if row["name"] == "Solar-Open2-250B":
-            return row["config"]
-    pytest.skip("the catalog has no Solar-Open2 row")
-
-
 def test_the_published_config_parses_to_the_96_entry_table():
-    m = ModelConfig.from_published(_catalog_row())
+    m = ModelConfig.from_published(
+        support.catalog_row("Solar-Open2-250B"))
     assert m == dataclasses.replace(get_model_config("solar-open2-250b"),
                                     name=m.name)
     assert len(m.layer_pattern) == m.num_layers == 96
