@@ -14,6 +14,8 @@ import urllib.request
 
 import click
 
+from .serve import kv_block_size_option
+
 
 def _get(url: str) -> dict:
     with urllib.request.urlopen(url, timeout=10) as resp:
@@ -335,7 +337,7 @@ def migrate(request_id, replica, url):
 @click.option("--prefill-chunk", default=0, show_default=True, type=int,
               help="Finest step of the prefill bucket ladder (0 = engine "
                    "default).")
-@click.option("--kv-block-size", default=64, show_default=True, type=int)
+@kv_block_size_option
 @click.option("--dtype", default=None,
               type=click.Choice(["bfloat16", "float32"]))
 @click.option("--kv-quantization", default="none", show_default=True,
@@ -535,7 +537,7 @@ def worker(model_name, artifact, replica_id, role, host, port,
                    "must see the same path).")
 @click.option("--max-batch-size", default=8, show_default=True, type=int)
 @click.option("--max-seq-len", default=2048, show_default=True, type=int)
-@click.option("--kv-block-size", default=64, show_default=True, type=int)
+@kv_block_size_option
 @click.option("--probe-interval", default=0.1, show_default=True,
               type=float, help="Supervisor poll cadence on this front "
               "(also the store heartbeat cadence).")

@@ -10,6 +10,14 @@ from __future__ import annotations
 import click
 
 
+# one option for `serve start`, `fleet worker` and `fleet front`
+kv_block_size_option = click.option(
+    "--kv-block-size", default=0, show_default=True, type=int,
+    help="Tokens per KV page; 0 = by the model's rows: the smallest power "
+         "of two >= 64 whose K + V copy for one layer reaches 512 KB, at "
+         "most 128 (a prefix-cache hit is whole pages).")
+
+
 @click.group(name="serve", invoke_without_command=True)
 @click.pass_context
 def app(ctx):
@@ -29,8 +37,7 @@ def app(ctx):
 @click.option("--port", default=8080, show_default=True, type=int)
 @click.option("--max-batch-size", default=8, show_default=True, type=int)
 @click.option("--max-seq-len", default=2048, show_default=True, type=int)
-@click.option("--kv-block-size", default=64, show_default=True, type=int,
-              help="Tokens per KV page (64 = one Pallas DMA tile).")
+@kv_block_size_option
 @click.option("--kv-hbm-gb", default=4.0, show_default=True, type=float,
               help="HBM budget for the paged KV cache.")
 @click.option("--scheduler", default="continuous", show_default=True,
@@ -629,8 +636,14 @@ def start(model_name, artifact, host, port, max_batch_size, max_seq_len,
         # HA front tier: this process becomes the tier babysitter; each
         # front is its own `llmctl fleet front` child over the shared
         # state store and the same remote workers
+        from ...serve.engine import InferenceEngine
         from ...serve.fleet.front import FleetFrontTier, default_spawn_cmd
         from ...serve.fleet.state import SharedFileStateStore
+        from ...serve.kv_cache import resolve_page_size
+        # a front knows nothing of the workers' dtype: it is told the page
+        # as THIS process's dtype resolves it, as a worker here would
+        resolve_page_size(model_cfg, serve_cfg,
+                          most=InferenceEngine.RIDE_ROWS)
         store = SharedFileStateStore(fleet_cfg.state_store_dir,
                                      front_id="tier")
         tier = FleetFrontTier(
@@ -643,7 +656,7 @@ def start(model_name, artifact, host, port, max_batch_size, max_seq_len,
                 host=host, artifact=artifact,
                 extra=["--max-seq-len", str(max_seq_len),
                        "--max-batch-size", str(max_batch_size),
-                       "--kv-block-size", str(kv_block_size)]),
+                       "--kv-block-size", str(serve_cfg.kv_block_size)]),
             fronts=fleet_cfg.fronts)
         ports = tier.start()
         click.echo(f"HA front tier up: {fleet_cfg.fronts} fronts on "

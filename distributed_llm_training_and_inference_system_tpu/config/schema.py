@@ -1495,10 +1495,22 @@ class ServeConfig:
     # 116.4), with light-load p50 TTFT unchanged (the occupancy gate —
     # 185.3 ms device vs 182-184 unpipelined at 7B) and p99 improved.
     pipelined_decode: bool = True
-    # tokens per KV-cache page: 64 makes each page a [64, D] DMA tile for
-    # the Pallas decode kernel (16-token pages measured 2.4x slower — DMA
-    # too small); internal fragmentation is at most page_size-1 tokens/seq
-    kv_block_size: int = 64
+    # tokens per KV-cache page. 0 = by the model's rows, as kv_num_blocks 0
+    # is "from the HBM budget": the smallest power of two, at least 64 (a
+    # [64, D] DMA tile a head; 16-token pages measured 2.4x slower), whose
+    # K + V copy for ONE layer, as stored (kv heads x head width x item
+    # size x 2; a quantised pool's values and scales; a latent pool's one
+    # row), reaches serve/kv_cache.py PAGE_COPY_BYTES (512 KB: a page
+    # kernel's copy costs max(~0.47 us, bytes / ~750 GB/s), so a smaller
+    # page pays for bytes it does not move: PERF.md 6, PR 58), and at most
+    # a decode step's carry (InferenceEngine.RIDE_ROWS, 128). bf16 pages of
+    # 128-wide heads: up to 8 kv heads 128 tokens, 16 and over 64. The
+    # engine resolves it once and writes the size back here. A stated size
+    # is used as it is. What follows from a larger page: the prefix cache
+    # hashes WHOLE pages, so a hit re-computes up to page-1 tokens of a
+    # shared prefix (127, not 63), and a sequence's last page wastes page/2
+    # tokens of the pool on average (64, not 32)
+    kv_block_size: int = 0
     kv_num_blocks: int = 0          # 0 = auto-size from HBM budget
     kv_hbm_budget_gb: float = 4.0
     max_queue: int = 256
@@ -1612,6 +1624,9 @@ class ServeConfig:
     def validate(self) -> None:
         if self.kv_quantization not in ("none", "int8", "int4"):
             raise ConfigError("kv_quantization must be none|int8|int4")
+        if self.kv_block_size < 0:
+            raise ConfigError("kv_block_size must be >= 0 (0 = by the "
+                              "model's rows)")
         if self.kv_quantization == "int4" and self.kv_block_size % 2:
             raise ConfigError(
                 f"kv_quantization=int4 packs two page slots per byte; "

@@ -48,7 +48,7 @@ from .decode import (DENOISE_COUNTS, PIECE_META, UNFIXED, DispatchResult,
                      can_carry, decode_scan, denoise_scan,
                      draft_verify_scan, extend_step_forward, mtp_sentinel_row,
                      mtp_window, recurrent_ops)
-from .kv_cache import PagedKVCache, refuse, refused
+from .kv_cache import PagedKVCache, refuse, refused, resolve_page_size
 from .sampling import fold_in_key_data, sample_tokens, seed_key_data
 from .scheduler import (ContinuousBatchingScheduler, Request, RequestState,
                         SamplingParams)
@@ -152,6 +152,11 @@ class InferenceEngine:
                 params, model_cfg, self.quantization = self._load_params(
                     model_cfg, serve_cfg, seed, dtype)
         self.cfg = model_cfg
+        # the page: a stated size as it is; none stated (0), by the stored
+        # row's bytes, resolved ONCE, here, before its first reader, and
+        # written back onto the caller's object
+        page_size_stated = resolve_page_size(model_cfg, serve_cfg,
+                                             most=self.RIDE_ROWS)
         Bd, PS = model_cfg.diffusion.block_length, serve_cfg.kv_block_size
         if Bd and PS % Bd:
             raise ValueError(
@@ -296,7 +301,11 @@ class InferenceEngine:
                 page_sharding=page_sharding,
                 quantized=serve_cfg.kv_quantization,
                 snapshot_entries=(snapshots if serve_cfg.prefix_caching
-                                  else 0))
+                                  else 0),
+                page_size_stated=page_size_stated)
+        # the start-up log line says which rule sized the pool
+        STARTUP.note("kv_pool", **{k: self.kv.stats()[k] for k in (
+            "num_pages", "page_size", "page_bytes", "page_size_stated")})
 
         self._req_slot: dict[str, int] = {}
         # pages promised to admitted-but-not-yet-prefilled requests; without
@@ -442,9 +451,8 @@ class InferenceEngine:
         # submitted dispatches; ``done``: tokens of dispatches applied).
         # Rows a decode step can carry: 0 where the engine never rides.
         self._riding: dict[str, dict] = {}
-        PS = self.kv.page_size
-        self._ride_rows = 0 if not self._can_ride(pre_quantized) else (
-            PS if PS >= self.RIDE_ROWS else self.RIDE_PAGES * PS)
+        self._ride_rows = (self.piece_rows(self.kv.page_size)
+                           if self._can_ride(pre_quantized) else 0)
         # decode: ONE compiled executable for every dispatch length.
         # With latency-adaptive dispatch (L > 0) the unit is L steps and
         # a full dispatch chains ceil(K/L) units on the device-resident
@@ -600,6 +608,13 @@ class InferenceEngine:
     # of 256: two would be a window of 512 padded rows through the
     # multi-query latent kernel in one step, PERF.md 6, PR 41)
     RIDE_ROWS = 128
+
+    @classmethod
+    def piece_rows(cls, page_size: int) -> int:
+        """Rows of the piece a decode step of an engine that rides carries
+        over pages of ``page_size`` tokens."""
+        return (page_size if page_size >= cls.RIDE_ROWS
+                else cls.RIDE_PAGES * page_size)
 
     # dispatches that chain onto one another before the host catches up
     # with the device ONCE (``step`` fetches and applies the one in flight

@@ -34,6 +34,8 @@ import time
 from typing import Callable, Optional, Sequence
 
 from ...config.schema import FleetConfig, ModelConfig, ServeConfig
+from ..engine import InferenceEngine
+from ..kv_cache import resolve_page_size
 from ..scheduler import Request, SamplingParams
 from .faults import (DestUnreachable, FaultInjector, FaultPlan,
                      InjectedCrash, ProbeTimeout, RpcBlackhole)
@@ -233,7 +235,12 @@ class ServeFleet:
         self._seed = seed
         self._eos_token_id = eos_token_id
         # fleet-global prefix cache: hints need the page size the
-        # engines actually hash with; 0 disables the whole plane
+        # engines actually hash with; 0 disables the whole plane. (A local
+        # replica's engine has resolved an unstated size by now; over
+        # remote replicas alone the fleet resolves it as they do, from the
+        # model's rows at THIS configuration's dtype)
+        resolve_page_size(model_cfg, serve_cfg,
+                          most=InferenceEngine.RIDE_ROWS)
         page_size = (serve_cfg.kv_block_size
                      if (serve_cfg.prefix_caching
                          and self.fleet_cfg.prefix_fetch) else 0)

@@ -167,6 +167,66 @@ def refuse(cfg: ModelConfig, *features: str, what: str = "",
                              f"refused ({why[1]}){advice}")
 
 
+# The bytes at which a page's copy costs what its bytes cost. The page kernels
+# (ops/paged_attention_pallas.py, ops/mla_paged_attention.py) copy ONE
+# layer's page a loop step, and a copy has a latency whatever it moves:
+# alone on a v5e a page costs max(~0.47-0.5 us, bytes / ~745 GB/s)
+# (experiments/paged_kernel_alone.py --sweep, PR 58; the table is PERF.md 6):
+# K + V pages of 64 / 128 / 256 KB all read 0.47-0.52 us (140 / 270 / 510-530
+# GB/s), 512 KB 0.71-0.74 (714-743 GB/s), 1 MB 1.41-1.43 and 2 MB 2.81 (744-
+# 745 GB/s). 512 KB is the first size of that table over 90 % of what the
+# largest reads; in a cell, pages of 131 KB read 31-32 % of the HBM peak.
+PAGE_COPY_BYTES = 512 * 1024
+# ... and the fewest tokens a page holds whatever its rows: a [64, D] tile a
+# head (16-token pages measured 2.4x slower, round 3)
+MIN_PAGE_TOKENS = 64
+
+
+def kv_row_bytes(cfg: ModelConfig, itemsize: int = 2,
+                 quantized: str = "none") -> int:
+    """Bytes one token's K + V occupy in ONE layer's page AS STORED: every
+    kv head's K and V (heads of 64 stored in pairs are the same bytes), a
+    quantised pool's values and its float32 scale a token and head, or a
+    latent pool's ONE padded row."""
+    if cfg.is_latent:
+        return cfg.mla.page_width * itemsize
+    per_head = {
+        # packed nibbles (D/2 bytes) + fp32 per-(token, kv-head) scale —
+        # the 2x-over-int8 capacity claim
+        "int4": cfg.head_dim // 2 + 4,
+        # int8 values + fp32 per-(token, kv-head) scale
+        "int8": cfg.head_dim + 4,
+    }.get(quantized, cfg.head_dim * itemsize)
+    return 2 * cfg.num_kv_heads * per_head
+
+
+def page_size_by_rows(row_bytes: int, most: int) -> int:
+    """Tokens a page holds where none are stated: the smallest power of
+    two, at least ``MIN_PAGE_TOKENS``, whose copy for ONE layer (the row's
+    stored bytes x the tokens) reaches ``PAGE_COPY_BYTES``, and never more
+    than ``most`` (a step's carry, ``InferenceEngine.RIDE_ROWS``: the piece
+    a decode step carries is a page once a page is that large)."""
+    tokens = MIN_PAGE_TOKENS
+    while tokens * row_bytes < PAGE_COPY_BYTES and tokens * 2 <= most:
+        tokens *= 2
+    return tokens
+
+
+def resolve_page_size(cfg: ModelConfig, serve_cfg, most: int) -> bool:
+    """Was ``serve_cfg.kv_block_size`` stated? Where it was not (0) it is
+    ``page_size_by_rows`` from here on, WRITTEN BACK onto the caller's
+    object: whoever built the engine or the fleet divides by the size the
+    engines hash and bucket with (a benchmark's warm-up, the fleet's prefix
+    hints, a spawned worker's command line). An object used again for
+    another engine then states that size."""
+    stated = serve_cfg.kv_block_size > 0
+    if not stated:
+        serve_cfg.kv_block_size = page_size_by_rows(
+            kv_row_bytes(cfg, jnp.dtype(serve_cfg.dtype).itemsize,
+                         serve_cfg.kv_quantization), most)
+    return stated
+
+
 def prefix_page_hashes(tokens, page_size: int) -> list[bytes]:
     """Chain hashes for every FULL page of a token prefix.
 
@@ -238,11 +298,13 @@ class PagedKVCache:
                                 # tensor-parallel serving (None = one device)
         quantized=False,        # False|"none" | True|"int8" | "int4"
         snapshot_entries: int = 0,  # snapshots of a ``K`` model's state
+        page_size_stated: bool = True,  # False: ``page_size_by_rows`` gave it
     ):
         self.cfg = cfg
         self.num_slots = num_slots
         self.max_seq_len = max_seq_len
         self.page_size = page_size
+        self.page_size_stated = page_size_stated
         self.max_pages_per_slot = math.ceil(max_seq_len / page_size)
         # normalize the quantization kind: legacy bool callers mean int8
         if quantized is True:
@@ -262,19 +324,9 @@ class PagedKVCache:
         self.quantized = kind != "none"
         if self.quantized:
             refuse(cfg, "kv_quantization", what=f"kv_quantization {kind}")
-        if kind == "int4":
-            # packed nibbles (D/2 bytes) + fp32 per-(token, kv-head)
-            # scale, K and V — the 2x-over-int8 capacity claim
-            self.bytes_per_token = (2 * cfg.kv_layers * cfg.num_kv_heads
-                                    * (cfg.head_dim // 2 + 4))
-        elif kind == "int8":
-            # int8 values + fp32 per-(token, kv-head) scale, K and V
-            self.bytes_per_token = (2 * cfg.kv_layers * cfg.num_kv_heads
-                                    * (cfg.head_dim + 4))
-        else:
-            # K and V of every kv head, or ONE padded latent row
-            self.bytes_per_token = cfg.kv_bytes_per_token(
-                jnp.dtype(dtype).itemsize)
+        # what a token costs the pool: its row in every layer that keeps one
+        self.row_bytes = kv_row_bytes(cfg, jnp.dtype(dtype).itemsize, kind)
+        self.bytes_per_token = cfg.kv_layers * self.row_bytes
         if num_pages <= 0:
             num_pages = max(int(hbm_budget_gb * 1e9
                                 // (self.bytes_per_token * page_size)), 2)
@@ -1053,6 +1105,10 @@ class PagedKVCache:
             "num_pages": self.num_pages,
             "free_pages": self.free_pages,
             "page_size": self.page_size,
+            # one layer's K + V page as stored, and which rule sized it: a
+            # stated ``kv_block_size``, or ``page_size_by_rows``
+            "page_bytes": self.page_size * self.row_bytes,
+            "page_size_stated": self.page_size_stated,
             "kv_quantization": self.quant_kind,
             # what a token costs the pool, and of which kind its rows are:
             # "kv" K and V of every kv head, "latent" ONE compressed row

@@ -5,10 +5,17 @@ bucket, as ``InferenceEngine`` jits them, for one test configuration of each
 kind the benchmark serves (GQA dense, uniform MoE, hybrid, latent, linear).
 
     JAX_PLATFORMS=cpu python experiments/lower_engine_programs.py --out DIR
-        [--tree OTHER_CHECKOUT]
+        [--tree OTHER_CHECKOUT] [--cells]
     python experiments/lower_block_programs.py --compare DIR_A DIR_B
 
 Small sizes, on the CPU: what is compared is the program's text.
+
+``--cells`` (PR 58) lowers the decode dispatch of every SERVING configuration
+under ``benchmark/configs`` instead, at its published widths and its own
+``serve`` section (its page size, stated or not, above all), over abstract
+weights, ``CELL_SLOTS`` slots and a pool of 0.05 GB (nothing of a cell's
+size is allocated or compiled here; slots and pool are cut alike in both
+trees): which cells' programs a change to the engine's defaults moves.
 """
 
 from __future__ import annotations
@@ -21,6 +28,47 @@ import sys
 MODELS = ("gpt-test", "olmoe-test", "nemotron-h-test", "xing-test",
           "kimi-linear-test")
 PKG = "distributed_llm_training_and_inference_system_tpu"
+CELL_SLOTS = 8
+
+
+def shapes(tree):
+    import jax
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+
+def lower_cells(tree: str, out: str) -> None:
+    """The decode dispatch of each serving configuration, lowered."""
+    import glob
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    schema = importlib.import_module(f"{PKG}.config.schema")
+    engine_mod = importlib.import_module(f"{PKG}.serve.engine")
+    gpt = importlib.import_module(f"{PKG}.models.gpt")
+
+    for file in sorted(glob.glob(os.path.join(tree, "benchmark", "configs",
+                                              "*.json"))):
+        with open(file) as f:
+            config = json.load(f)
+        if "serve" not in config:       # a training configuration
+            continue
+        cfg = schema.ModelConfig.from_published(config)
+        serve = schema.ServeConfig(model=config["name"], **{
+            **config["serve"], "max_batch_size": CELL_SLOTS,
+            "kv_hbm_budget_gb": 0.05})
+        dtype = jnp.dtype(serve.dtype)
+        params = jax.eval_shape(lambda k: gpt.init(cfg, k, dtype),
+                                jax.random.PRNGKey(0))
+        eng = engine_mod.InferenceEngine(cfg, serve, params=params)
+        args = shapes((eng.params, eng.kv.k_pages, eng.kv.v_pages,
+                       *eng._decode_head_args(), *eng._shared_decode_args(),
+                       *eng._decode_tail_args()))
+        path = os.path.join(out, f"{config['name']}.decode.stablehlo.txt")
+        with open(path, "w") as f:
+            f.write(eng._decode_jit.lower(*args).as_text())
+        print(path, "page", eng.kv.page_size, flush=True)
 
 
 def main() -> None:
@@ -28,9 +76,13 @@ def main() -> None:
     ap.add_argument("--out", required=True)
     ap.add_argument("--tree", default=os.getcwd())
     ap.add_argument("--models", nargs="*", default=MODELS)
+    ap.add_argument("--cells", action="store_true")
     args = ap.parse_args()
     sys.path.insert(0, os.path.abspath(args.tree))
     os.makedirs(args.out, exist_ok=True)
+    if args.cells:
+        lower_cells(os.path.abspath(args.tree), args.out)
+        return
     import jax
     import jax.numpy as jnp
     presets = importlib.import_module(f"{PKG}.config.presets")
@@ -39,9 +91,6 @@ def main() -> None:
     sampling_mod = importlib.import_module(f"{PKG}.serve.sampling")
     scheduler = importlib.import_module(f"{PKG}.serve.scheduler")
 
-    def shapes(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
     i32 = jnp.int32
     for name in args.models:
         cfg = presets.get_model_config(name)

@@ -206,7 +206,8 @@ def engine():
     eng = InferenceEngine(
         get_model_config("gpt-test"),
         ServeConfig(model="gpt-test", max_batch_size=4, max_seq_len=128,
-                    kv_hbm_budget_gb=0.01, dtype="float32"))
+                    kv_block_size=64, kv_hbm_budget_gb=0.01,
+                    dtype="float32"))
     seen = []
     eng.on_token = lambda req, tokens: seen.append(len(tokens))
     eng.on_finish = lambda req: None

@@ -34,8 +34,8 @@ import pytest
 
 from tpu_compile_support import (
     D,
-    PS,
-    MAXP,
+    page_tokens,
+    table_width,
     _compile,
     _no_copy_of,
     _sds,
@@ -93,18 +93,26 @@ def test_fsdp4_train_step_moves_rows_not_the_head(topo, as_tpu):
 
 # -- generation by diffusion over blocks (benchmark/configs/sdar-30b-a3b-7l) ---
 
+# the SDAR cell's pool: 4 K/V heads and no stated page, so the rule's (128
+# tokens), and the pages 2.0 GB buy of 7 layers x 2,048 B a token (1,089;
+# 2,179 of 64)
+PS = page_tokens(4)
+MAXP = table_width(PS)
+SDAR_PAGES = int(2.0e9) // (7 * 2048 * PS)
+
+
 @pytest.mark.parametrize("B, T", [(64, 4), (64, 8), (1, 256), (1, 512)],
                          ids=["one-block", "denoise-window", "suffix-256",
                               "suffix-512"])
 def test_block_rule_page_kernel_compiles_at_the_cells_shapes(one_chip, as_tpu,
                                                              B, T):
     """The page kernel under the block rule (``paged_attention_blk``) on
-    the SDAR cell's pool (7 layers, 2,179 pages, GQA 32 / 4): one block and
+    the SDAR cell's pool (7 layers, ``SDAR_PAGES``, GQA 32 / 4): one block and
     the denoise window of two over 64 slots, and a prefill window of many."""
     from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
         paged_attention_multi)
     sds = _sds(one_chip)
-    pool = sds((7, 2179, 4, PS, D), jnp.bfloat16)
+    pool = sds((7, SDAR_PAGES, 4, PS, D), jnp.bfloat16)
 
     def call(q, kp, vp, tables, starts, layer):
         return paged_attention_multi(q, kp, vp, tables, starts, impl="auto",
@@ -134,7 +142,7 @@ def test_diffusion_decode_program_fits_the_chip(one_chip, as_tpu):
                          / "sdar-30b-a3b-7l.json").read_text())
     cfg = ModelConfig.from_dict(harness.model_dict(config))
     sds = _sds(one_chip)
-    B, Bd, num_pages = 64, cfg.diffusion.block_length, 2179
+    B, Bd, num_pages = 64, cfg.diffusion.block_length, SDAR_PAGES
     params = jax.tree.map(
         lambda a: sds(a.shape, a.dtype),
         jax.eval_shape(lambda k: gpt.init(cfg, k, jnp.bfloat16),

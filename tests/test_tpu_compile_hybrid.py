@@ -34,8 +34,8 @@ import pytest
 
 from tpu_compile_support import (
     D,
-    PS,
-    MAXP,
+    page_tokens,
+    table_width,
     _compile,
     _sds,
     _no_copy_of,
@@ -48,8 +48,9 @@ from tpu_compile_support import (
 
 def _hybrid_cell(one_chip):
     """(model config, shapes of params / page pool / state pools) of the
-    hybrid cell as its configuration file states it: 64 slots, 1,537 pages
-    of 64, 6 state-space layers, 64 of 128 experts held."""
+    hybrid cell as its configuration file states it: 64 slots, the pages
+    its budget buys at the size the rule gives 2 K/V heads (128 tokens), 6
+    state-space layers, 64 of 128 experts held."""
     import json
     from pathlib import Path
 
@@ -67,7 +68,10 @@ def _hybrid_cell(one_chip):
         lambda a: sds(a.shape, a.dtype),
         jax.eval_shape(lambda k: gpt.init(cfg, k, jnp.bfloat16),
                        jax.random.PRNGKey(0)))
-    pool = sds((cfg.kv_layers, 1537, cfg.num_kv_heads, PS, D), jnp.bfloat16)
+    PS = page_tokens(cfg.num_kv_heads)
+    pages = (int(config["serve"]["kv_hbm_budget_gb"] * 1e9)
+             // (cfg.kv_bytes_per_token(2) * PS))
+    pool = sds((cfg.kv_layers, pages, cfg.num_kv_heads, PS, D), jnp.bfloat16)
     s = cfg.ssm
     state = {"conv": sds((cfg.ssm_layers, B, s.conv_kernel - 1,
                           s.conv_channels), jnp.bfloat16),
@@ -125,7 +129,8 @@ def _hybrid_decode_program(one_chip):
     def compile_(carry):
         ride = (i32(K, PIECE_META + carry),) if carry else ()
         compiled = jax.jit(program, donate_argnums=(1, 2, 11)).lower(
-            params, pool, pool, i32(B), i32(B), i32(B, MAXP), i32(B),
+            params, pool, pool, i32(B), i32(B),
+            i32(B, table_width(pool.shape[3])), i32(B),
             sds((B, 2), jnp.uint32), sds((B,), jnp.float32), i32(B),
             sds((B,), jnp.float32), state, *ride).compile()
         text = compiled.as_text()
@@ -136,7 +141,7 @@ def _hybrid_decode_program(one_chip):
         assert ("ssm_scan_prefill" in text) == bool(carry)
         _state_update_is_the_kernel(text, "f32[64,64,64,128]")
         _no_copy_of(text, ["bf16[6,64,1856,2688]", "f32[6,64,64,64,128]",
-                           "bf16[2,1537,2,64,128]"]
+                           "bf16[%s]" % ",".join(map(str, pool.shape))]
                     # (the carrying program re-lays the 14 MB conv pool at
                     # its entry and its exit, outside the step loop, as
                     # the linear cell's does its own: below)
@@ -192,7 +197,7 @@ HYBRID_IN_PROJ_BYTES = 6 * 2688 * 10304 * 2
 
 def test_carrying_hybrid_decode_program_fits_the_chip(one_chip, as_tpu):
     """The hybrid decode program with a prompt's piece riding every step
-    (PR 44): 128 rows (two pages of 64: ONE chunk of the scan) beside the
+    (PR 44): 128 rows (one page of 128: ONE chunk of the scan) beside the
     64 slots' rows, through ``paged_attention_mq`` in the 2 attention
     layers and through ``ssm_scan_prefill`` from the slot's own float32
     state in the 6 ``M`` layers, the table's two motifs walked by a loop
@@ -208,10 +213,13 @@ def test_carrying_hybrid_decode_program_fits_the_chip(one_chip, as_tpu):
     matmuls of its 3 expert layers, the one T = 1 page kernel, and the
     carrying body the one multi-query kernel."""
     import re
-    text, carrying = _hybrid_decode_program(one_chip)(2 * PS)
+    from distributed_llm_training_and_inference_system_tpu.serve.engine import (
+        InferenceEngine)
+    rows = InferenceEngine.piece_rows(page_tokens(2))
+    text, carrying = _hybrid_decode_program(one_chip)(rows)
     assert carrying.alias_size_in_bytes >= HYBRID_STATE_POOL
     assert (carrying.temp_size_in_bytes < HYBRID_PLAIN_TEMP_BYTES
-            + (2 * PS << 20) + HYBRID_IN_PROJ_BYTES), (
+            + (rows << 20) + HYBRID_IN_PROJ_BYTES), (
         carrying.temp_size_in_bytes)
 
     def kernels(name):

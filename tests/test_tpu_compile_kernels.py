@@ -34,89 +34,132 @@ import pytest
 
 from tpu_compile_support import (
     LAYOUTS,
+    PAGES,
     D,
-    MAXP,
     _compile,
     _kernel_grids,
     _sds,
     _pages,
     _cell_call,
+    table_width,
 )
 
 
+@pytest.mark.parametrize("page", PAGES)
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("layout", list(LAYOUTS))
-def test_paged_decode_kernel_compiles(one_chip, as_tpu, layout, kv):
-    """Single-query decode attention: 8 slots, 32 pages of 64 per slot."""
+def test_paged_decode_kernel_compiles(one_chip, as_tpu, layout, kv, page):
+    """Single-query decode attention: 8 slots, 2,048 tokens of pages of 64
+    or 128 a slot."""
     from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
         paged_attention)
     nq, nkv = LAYOUTS[layout]
     sds = _sds(one_chip)
-    B = 8
-    pages = _pages(sds, B * MAXP + 1, nkv, kv)
+    B, maxp = 8, table_width(page)
+    pages = _pages(sds, B * maxp + 1, nkv, kv, page=page)
     _compile(functools.partial(paged_attention, impl="auto"),
              sds((B, nq, D), jnp.bfloat16), pages, pages,
-             sds((B, MAXP), jnp.int32), sds((B,), jnp.int32))
+             sds((B, maxp), jnp.int32), sds((B,), jnp.int32))
 
 
+@pytest.mark.parametrize("page", PAGES)
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("layout", list(LAYOUTS))
 def test_paged_decode_kernel_compiles_at_the_cells_shapes(one_chip, as_tpu,
-                                                          layout, kv):
+                                                          layout, kv, page):
     """The decode kernel as the serving cells run it: 32 slots, a block
-    table 32 pages wide, the whole [L, 715, ...] pool with a traced layer
-    index. Its grid is one step a slot: the page axis is a loop inside."""
+    table of 2,048 tokens, the whole [L, NP, ...] pool of the cell's budget
+    (715 pages of 64) with a traced layer index. Its grid is one step a
+    slot: the page axis is a loop inside."""
     from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
         paged_attention)
     call, args = _cell_call(paged_attention, _sds(one_chip), layout, kv,
-                            lambda slots, nq: (slots, nq, D))
+                            lambda slots, nq: (slots, nq, D), page=page)
     compiled = _compile(call, *args)
     assert "paged_attention" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 32 * MAXP * 4096, \
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 32 * 4096, \
         "a pool-sized temporary beside the kernel"
     assert _kernel_grids(call, *args) == [(32, 1)]
 
 
+@pytest.mark.parametrize("page", PAGES)
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("layout", list(LAYOUTS))
 @pytest.mark.parametrize("window", [8, 64, 128, 256, 512])
 def test_paged_multi_query_kernel_compiles(one_chip, as_tpu, window, layout,
-                                           kv):
+                                           kv, page):
     """Every window the engine can hand the multi-query kernel at default
     settings: the speculative verify window (8, all slots) and the
     cached-prefix / chunked-prefill suffix buckets 64..512 (one slot;
-    engine._suffix_bucket, prefill_chunk 256)."""
+    engine._suffix_bucket, prefill_chunk 256), over pages of 64 and of 128
+    (``_query_tile`` halves the tile where the page doubles)."""
     from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
         paged_attention_multi)
     nq, nkv = LAYOUTS[layout]
     sds = _sds(one_chip)
-    B = 8 if window == 8 else 1
-    pages = _pages(sds, 8 * MAXP + 1, nkv, kv)
+    B, maxp = 8 if window == 8 else 1, table_width(page)
+    pages = _pages(sds, 8 * maxp + 1, nkv, kv, page=page)
     _compile(functools.partial(paged_attention_multi, impl="auto"),
              sds((B, window, nq, D), jnp.bfloat16), pages, pages,
-             sds((B, MAXP), jnp.int32), sds((B,), jnp.int32))
+             sds((B, maxp), jnp.int32), sds((B,), jnp.int32))
 
 
+@pytest.mark.parametrize("page", PAGES)
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("layout", list(LAYOUTS))
 def test_paged_multi_query_kernel_compiles_at_the_cells_shapes(
-        one_chip, as_tpu, layout, kv):
+        one_chip, as_tpu, layout, kv, page):
     """The speculative-verify window (8 tokens) over all 32 slots of a
-    cell's pool, and a 512-token suffix tiled 8 x 64 along the query axis:
-    the grid is (slots, query tiles) and never the table's width."""
+    cell's pool, and a 512-token suffix tiled along the query axis (8 x 64
+    over pages of 64, 16 x 32 over pages of 128: the same score tile): the
+    grid is (slots, query tiles) and never the table's width."""
     from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
         paged_attention_multi)
     call, args = _cell_call(paged_attention_multi, _sds(one_chip), layout, kv,
-                            lambda slots, nq: (slots, 8, nq, D))
+                            lambda slots, nq: (slots, 8, nq, D), page=page)
     _compile(call, *args)
     assert _kernel_grids(call, *args) == [(32, 1)]
     nq, nkv = LAYOUTS[layout]
     sds = _sds(one_chip)
-    pages = _pages(sds, 8 * MAXP + 1, nkv, kv)
+    maxp = table_width(page)
+    pages = _pages(sds, 8 * maxp + 1, nkv, kv, page=page)
     assert _kernel_grids(
         functools.partial(paged_attention_multi, impl="auto"),
         sds((1, 512, nq, D), jnp.bfloat16), pages, pages,
-        sds((1, MAXP), jnp.int32), sds((1,), jnp.int32)) == [(1, 8)]
+        sds((1, maxp), jnp.int32), sds((1,), jnp.int32)) == [
+            (1, 8 * page // 64)]
+
+
+# the layouts whose default page the rule takes to its cap: Falcon-H1's
+# 20 / 4, SDAR's 32 / 4 under the block rule, Nemotron-3-Nano's 32 / 2
+FEW_KV_HEADS = {"gqa20x4": (20, 4, 0), "gqa32x4-blocks-of-4": (32, 4, 4),
+                "gqa32x2": (32, 2, 0)}
+
+
+@pytest.mark.parametrize("layout,window", [
+    (layout, window) for layout, (_, _, block) in FEW_KV_HEADS.items()
+    for window in (1, 8, 128, 256, 512)
+    if not window % max(block, 1)])  # a diffusion model's window: whole blocks
+def test_paged_kernels_compile_over_few_kv_heads_at_pages_of_128(
+        one_chip, as_tpu, layout, window):
+    """4 and 2 K/V heads over the 128-token pages the rule gives them: the
+    decode step (window 1; the diffusion model's is a window of blocks, 8
+    rows and more), a 128-row piece of a carrying step over all 64 slots,
+    and the suffix buckets of one slot."""
+    from distributed_llm_training_and_inference_system_tpu.ops.paged_attention_pallas import (
+        paged_attention_pallas_multi)
+    nq, nkv, block = FEW_KV_HEADS[layout]
+    sds = _sds(one_chip)
+    page = 128
+    B, maxp = 64 if window <= 128 else 1, table_width(page)
+    pages = _pages(sds, B * maxp + 1, nkv, "bf16", page=page)
+    compiled = _compile(
+        functools.partial(paged_attention_pallas_multi, block=block),
+        sds((B, window, nq, D), jnp.bfloat16), pages, pages,
+        sds((B, maxp), jnp.int32), sds((B,), jnp.int32))
+    assert ("paged_attention_blk" if block else
+            "paged_attention" if window == 1 else
+            "paged_attention_mq") in compiled.as_text()
 
 
 @pytest.mark.parametrize("rows,tm", [(256, 16), (2048, 32), (4096, 64),

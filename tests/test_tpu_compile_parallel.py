@@ -18,9 +18,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from distributed_llm_training_and_inference_system_tpu.serve.engine import (
+    InferenceEngine)
 from tpu_compile_support import (
     D,
-    PS,
+    page_tokens,
     _no_copy_of,
     _sds,
     _ssm_decode_pool_compiles,
@@ -28,8 +30,10 @@ from tpu_compile_support import (
 )
 
 ROOT = Path(__file__).resolve().parents[1]
-PAGES = 2384            # 1.25 GB of 8,192 B a token in pages of 64
-MAXP = 64               # max_seq_len 4096 / page 64
+PS = page_tokens(4)     # 4 K/V heads and no stated page: the rule's, 128
+RIDE_ROWS = InferenceEngine.piece_rows(PS)          # a decode step's carry
+PAGES = int(1.25e9) // (8192 * PS)  # 1.25 GB of 8,192 B a token: 1,192
+MAXP = 4096 // PS       # max_seq_len 4096: 32 pages a slot
 
 
 def _parallel_cell(one_chip):
@@ -63,7 +67,7 @@ STATE_POOL = 4 * 128 * 32 * 128 * 256 * 4           # 2.15 GB
 # embedding (2.67 GB each), the state pool, a page pool (625 MB)
 NO_COPY = ["bf16[4,5120,21504]", "bf16[4,21504,5120]", "bf16[5120,261120]",
            "bf16[261120,5120]", "f32[4,128,32,128,256]",
-           "bf16[4,2384,4,64,128]"]
+           f"bf16[4,{PAGES},4,{PS},128]"]
 # ... and the ones the carrying program DOES copy once a dispatch, in its
 # entry computation, outside its step loops, named here (as the hybrid and
 # linear cells' are): the in-projections' stack [4, 5120, 9248], whose 9,248
@@ -152,7 +156,7 @@ def test_carrying_decode_program_fits_the_chip(decode_program, as_tpu):
     a dispatch, in the entry computation; no more temporaries than the
     plain program's plus a MB a piece row and those copies."""
     import re
-    (_, plain), (text, carrying) = decode_program(0), decode_program(2 * PS)
+    (_, plain), (text, carrying) = decode_program(0), decode_program(RIDE_ROWS)
     _no_copy_of(text, NO_COPY, fused_into_at_most=32 << 20)
     _state_update_is_the_kernel(text, "f32[128,32,128,256]")
     # the named copies are the ENTRY computation's, not a step's
@@ -163,7 +167,7 @@ def test_carrying_decode_program_fits_the_chip(decode_program, as_tpu):
             rf" = {re.escape(shape)}\S* copy\(", entry)) <= 1, shape
     assert carrying.alias_size_in_bytes >= STATE_POOL + 2 * 625e6
     assert (carrying.temp_size_in_bytes < plain.temp_size_in_bytes
-            + (2 * PS << 20) + IN_PROJ_BYTES + QKV_BYTES), (
+            + (RIDE_ROWS << 20) + IN_PROJ_BYTES + QKV_BYTES), (
         plain.temp_size_in_bytes, carrying.temp_size_in_bytes)
     # weights 8.79 GB + pools 3.4 GB + temporaries fit the chip's 16 GB
     assert 8.79e9 + 3.4e9 + carrying.temp_size_in_bytes < 15.7e9

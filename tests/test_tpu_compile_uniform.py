@@ -35,9 +35,9 @@ import pytest
 from tpu_compile_support import (
     LAYOUTS,
     D,
-    PS,
-    MAXP,
-    CELL_POOLS,
+    cell_pool,
+    page_tokens,
+    table_width,
     _sds,
     _pages,
 )
@@ -47,7 +47,8 @@ from tpu_compile_support import (
 def test_decode_program_updates_pool_in_place(one_chip, as_tpu, kv):
     """The multi-step decode program (serve.decode.decode_scan, what the
     engine jits as ``_decode_impl_n``) at the GQA 32/8 layout, 4 layers,
-    8 slots, donated pools of 2,049 pages: the pools ride the step and
+    8 slots, donated pools of eight tables' pages (2,049 of 64 tokens) at
+    the page the rule gives the layout: the pools ride the step and
     layer loops as carries and every layer writes and reads them by its
     index, so nothing pool-sized is a temporary. With the pools as scanned
     inputs and stacked outputs the program held a second copy of both
@@ -65,6 +66,7 @@ def test_decode_program_updates_pool_in_place(one_chip, as_tpu, kv):
         ffn_size=1408, dtype="bfloat16")
     assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim) == (nq, nkv, D)
     sds = _sds(one_chip)
+    MAXP = table_width(page_tokens(nkv, kv))
     B, num_pages = 8, 8 * 8 * MAXP + 1
     params = jax.tree.map(
         lambda a: sds(a.shape, a.dtype),
@@ -95,9 +97,10 @@ def test_decode_program_updates_pool_in_place(one_chip, as_tpu, kv):
 
 def _mistral_decode_program(one_chip, layers, num_pages, dtype):
     """``decode_scan`` at mistral-7b's widths over ``layers`` layers, 32
-    slots, 8 steps, donated pools of ``num_pages`` pages: (config, the
-    compile of it for pieces of ``InferenceEngine.RIDE_PAGES`` pages or,
-    ``carrying=False``, for none)."""
+    slots, 8 steps, donated pools of ``num_pages`` pages of the size the
+    rule gives the layout and ``dtype``: (config, the page, the compile of it
+    for the pieces such an engine carries, ``RIDE_PAGES`` pages or one of
+    ``RIDE_ROWS``, or, ``carrying=False``, for none)."""
     import dataclasses
 
     from distributed_llm_training_and_inference_system_tpu.config.presets import (
@@ -112,7 +115,9 @@ def _mistral_decode_program(one_chip, layers, num_pages, dtype):
                               num_layers=layers, dtype=jnp.dtype(dtype).name)
     assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim) == (nq, nkv, D)
     sds = _sds(one_chip)
-    B, K, C = 32, 8, InferenceEngine.RIDE_PAGES * PS
+    PS = page_tokens(nkv, itemsize=jnp.dtype(dtype).itemsize)
+    MAXP = table_width(PS)
+    B, K, C = 32, 8, InferenceEngine.piece_rows(PS)
     params = jax.tree.map(
         lambda a: sds(a.shape, a.dtype),
         jax.eval_shape(lambda k: gpt.init(cfg, k, dtype),
@@ -139,15 +144,15 @@ def _mistral_decode_program(one_chip, layers, num_pages, dtype):
         # the piece's window is the multi-query kernel, the slots' the T = 1
         assert ("paged_attention_mq" in text) == carrying
         return compiled
-    return cfg, compile_
+    return cfg, PS, compile_
 
 
 def test_carrying_decode_program_holds_no_pool_and_no_stack(one_chip, as_tpu):
     """The decode program with a prompt's piece riding every step
     (``decode_scan(ride=...)``: what an engine that rides jits as
     ``_decode_impl_n``) at ``mistral-7b-16l``'s shapes: 16 layers at the
-    published widths, 32 slots, donated pools of 715 pages, 8 steps, pieces
-    of ``InferenceEngine.RIDE_PAGES`` pages. A step carries its piece or
+    published widths, 32 slots, donated pools of the cell's budget (357
+    pages of 128), 8 steps, pieces of one page. A step carries its piece or
     branches to the plain step through two loops of one or no trip, which
     carry the pools in place as the step and layer loops do; as the
     branches of a ``cond`` they were copied whole (2.18 GB of temporaries
@@ -156,9 +161,9 @@ def test_carrying_decode_program_holds_no_pool_and_no_stack(one_chip, as_tpu):
     re-layouts, PERF.md 5): no more than one layer's K pool beyond it, and
     under the smallest of a pool and the gate / up / down stacks."""
     nkv = LAYOUTS["gqa32x8"][1]
-    layers, num_pages = CELL_POOLS["gqa32x8"]
-    cfg, compile_ = _mistral_decode_program(one_chip, layers, num_pages,
-                                            jnp.bfloat16)
+    layers, num_pages = cell_pool("gqa32x8")
+    cfg, PS, compile_ = _mistral_decode_program(one_chip, layers, num_pages,
+                                                jnp.bfloat16)
     layer_pool_bytes = num_pages * nkv * PS * D * 2
     ffn_stack_bytes = layers * cfg.hidden_size * cfg.ffn_size * 2
     temps = {name: compile_(carrying).memory_analysis().temp_size_in_bytes
@@ -177,7 +182,9 @@ def test_float32_carrying_decode_program_fits_the_kernels_vmem(one_chip,
     chip refused the program (my chip run, PR 36, call E2), though the
     kernel ALONE compiles at that tile; ``_query_tile`` gives 4-byte
     operands half the tile."""
-    _cfg, compile_ = _mistral_decode_program(one_chip, 4, 953, jnp.float32)
+    _cfg, PS, compile_ = _mistral_decode_program(one_chip, 4, 953,
+                                                 jnp.float32)
+    assert PS == 64         # a float32 row is twice the bytes
     compile_(carrying=True)
 
 
@@ -234,6 +241,8 @@ def test_olmoe_decode_program_takes_the_expert_stacks_whole(one_chip, as_tpu):
     cfg = dataclasses.replace(get_model_config("olmoe-1b-7b"), num_layers=3,
                               dtype="bfloat16")
     sds = _sds(one_chip)
+    PS = page_tokens(cfg.num_kv_heads)
+    MAXP = table_width(PS)
     B, num_pages = 8, 8 * MAXP + 1
     params = jax.tree.map(
         lambda a: sds(a.shape, a.dtype),

@@ -9,8 +9,32 @@ import jax.numpy as jnp
 
 # gpt-1b / gpt-750m head layout and the mistral-7b GQA layout
 LAYOUTS = {"mha16": (16, 16), "gqa32x8": (32, 8)}
-D, PS, MAXP = 128, 64, 32          # head_dim, ServeConfig.kv_block_size,
-                                   # max_seq_len 2048 / page 64
+D, MAX_SEQ_LEN = 128, 2048         # head_dim, the cells' max_seq_len
+PAGES = (64, 128)                  # a 16-head bf16 page by the rule, and
+                                   # every smaller row's (the rule's cap)
+
+
+def page_tokens(nkv: int, kv: str = "bf16", itemsize: int = 2) -> int:
+    """The page an engine builds over ``nkv`` K/V heads of ``D`` where its
+    configuration states none (``ServeConfig.kv_block_size`` 0): the rule's,
+    ``serve/kv_cache.py page_size_by_rows``, not a literal."""
+    import types
+
+    from distributed_llm_training_and_inference_system_tpu.serve.engine import (
+        InferenceEngine)
+    from distributed_llm_training_and_inference_system_tpu.serve.kv_cache import (
+        kv_row_bytes, page_size_by_rows)
+    heads = types.SimpleNamespace(is_latent=False, num_kv_heads=nkv,
+                                  head_dim=D)
+    row = kv_row_bytes(heads, itemsize, "none" if kv == "bf16" else kv)
+    return page_size_by_rows(row, most=InferenceEngine.RIDE_ROWS)
+
+
+def table_width(page: int, max_seq_len: int = MAX_SEQ_LEN) -> int:
+    """Pages a slot's block table names."""
+    return max_seq_len // page
+
+
 # the latent cell's page size, pages a slot and pages of the pool (the linear
 # cell's latent pool has pages of the same size)
 LATENT_PS, LATENT_MAXP, LATENT_PAGES = 256, 68, 1307
@@ -59,32 +83,46 @@ def _sds(sharding):
                                                      sharding=sharding)
 
 
-def _pages(sds, num_pages, nkv, kv, layers=()):
-    """One layer's pages, or the [L, NP, ...] pool with ``layers=(L,)``."""
+def _pages(sds, num_pages, nkv, kv, layers=(), page=None):
+    """One layer's pages, or the [L, NP, ...] pool with ``layers=(L,)``, of
+    ``page`` tokens (None: what the rule gives the layout)."""
     from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
         QuantPages)
+    page = page or page_tokens(nkv, kv)
     if kv == "int8":
-        return QuantPages(sds((*layers, num_pages, nkv, PS, D), jnp.int8),
-                          sds((*layers, num_pages, nkv, PS), jnp.float32))
-    return sds((*layers, num_pages, nkv, PS, D), jnp.bfloat16)
+        return QuantPages(sds((*layers, num_pages, nkv, page, D), jnp.int8),
+                          sds((*layers, num_pages, nkv, page), jnp.float32))
+    return sds((*layers, num_pages, nkv, page, D), jnp.bfloat16)
 
 
-# the serving cells' pools (benchmark/configs): layers, pages a layer
-CELL_POOLS = {"gqa32x8": (16, 715), "mha16": (10, 715)}
+# the serving cells' pools (benchmark/configs): layers, kv_hbm_budget_gb
+CELL_POOLS = {"gqa32x8": (16, 3.0), "mha16": (10, 3.75)}
 
 
-def _cell_call(fn, sds, layout, kv, q_shape):
+def cell_pool(layout, page=None):
+    """(layers, pages a layer) of a cell's bf16 pool at pages of ``page``
+    tokens (None: the rule's), as ``PagedKVCache`` sizes it from the
+    budget: 715 pages of 64."""
+    n_layers, budget_gb = CELL_POOLS[layout]
+    nkv = LAYOUTS[layout][1]
+    page = page or page_tokens(nkv)
+    return n_layers, int(budget_gb * 1e9) // (
+        n_layers * 2 * nkv * D * 2 * page)
+
+
+def _cell_call(fn, sds, layout, kv, q_shape, page=None):
     """``fn`` on a cell's whole pool with a traced layer index, 32 slots:
     (callable, argument shapes)."""
     nq, nkv = LAYOUTS[layout]
-    n_layers, num_pages = CELL_POOLS[layout]
-    pool = _pages(sds, num_pages, nkv, kv, layers=(n_layers,))
+    page = page or page_tokens(nkv, kv)
+    n_layers, num_pages = cell_pool(layout, page)
+    pool = _pages(sds, num_pages, nkv, kv, layers=(n_layers,), page=page)
 
     def call(q, kp, vp, tables, lengths, layer):
         return fn(q, kp, vp, tables, lengths, impl="auto", layer=layer)
     return call, (sds(q_shape(32, nq), jnp.bfloat16), pool, pool,
-                  sds((32, MAXP), jnp.int32), sds((32,), jnp.int32),
-                  sds((), jnp.int32))
+                  sds((32, table_width(page)), jnp.int32),
+                  sds((32,), jnp.int32), sds((), jnp.int32))
 
 
 _HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
