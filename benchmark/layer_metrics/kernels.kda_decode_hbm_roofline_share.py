@@ -1,18 +1,12 @@
 """Least time the one-step delta-rule update could take (every live slot's
 state read once and written once in every ``K`` layer, over the HBM peak) as
 a share of ``kda_decode``'s measured time a step. Bytes are the measure: a
-slot's update is a handful of operations an element of a 2 MB state."""
-from benchmark import flops, flops_linear, linear_counters
-from benchmark.layer_metrics import load
-
-_kernel = load("kernels.kda_decode_ms_per_decode_step")
+slot's update is a handful of operations an element of a 2 MB state. The
+bytes are the run's family's (``benchmark/families/<runner>.py
+kda_decode_hbm_roofline_share``: the sessions family counts the kernel's
+operands too)."""
+from benchmark import families
 
 
 def read(run):
-    kernel_ms = _kernel.read(run)
-    slots = linear_counters.live_slots_per_step(run)
-    if not kernel_ms or slots is None:
-        return None
-    floor_s = (flops_linear.state_step_bytes(run["config"], slots)
-               / flops.peaks(run["device"]["kind"])["hbm_bytes_per_s"])
-    return 100.0 * floor_s / (kernel_ms * 1e-3)
+    return families.read(run, "kda_decode_hbm_roofline_share")

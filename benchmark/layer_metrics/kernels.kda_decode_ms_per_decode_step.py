@@ -1,12 +1,9 @@
 """Device time of the ``K`` layers' one-step state update (``kda_decode`` in
 the runner's by-scope seconds) in the traced stretch / decode steps on the
-device (executions x steps per dispatch)."""
-from benchmark import linear_counters
+device (executions x steps per dispatch). Through the run's family
+(``benchmark/families/<runner>.py kda_decode_ms_per_decode_step``)."""
+from benchmark import families
 
 
 def read(run):
-    s = linear_counters.scope_seconds(run, "kda_decode")
-    steps = linear_counters.traced_decode_steps(run)
-    if not s or not steps:
-        return None
-    return 1e3 * s / steps
+    return families.read(run, "kda_decode_ms_per_decode_step")

@@ -1,18 +1,10 @@
 """Least time the one-step state update could take (every live slot's state
 read once and written once in every state-space layer, over the HBM peak)
-as a share of ``ssm_decode*``'s measured time a step. Bytes are the
-measure: a slot's update is 4 operations an element of a 2 MB state."""
-from benchmark import flops, flops_hybrid, hybrid_counters
-from benchmark.layer_metrics import load
-
-_kernel = load("kernels.ssm_decode_ms_per_decode_step")
+as a share of ``ssm_decode``'s measured time a step. Bytes are the measure:
+a slot's update is 4 operations an element of its state. Through the run's
+family (``benchmark/families/<runner>.py ssm_decode_hbm_roofline_share``)."""
+from benchmark import families
 
 
 def read(run):
-    kernel_ms = _kernel.read(run)
-    slots = hybrid_counters.live_slots_per_step(run)
-    if not kernel_ms or slots is None:
-        return None
-    floor_s = (flops_hybrid.state_step_bytes(run["config"], slots)
-               / flops.peaks(run["device"]["kind"])["hbm_bytes_per_s"])
-    return 100.0 * floor_s / (kernel_ms * 1e-3)
+    return families.read(run, "ssm_decode_hbm_roofline_share")

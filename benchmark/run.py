@@ -3,7 +3,8 @@
     python benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Loads the cell from ``BENCHMARK.json``, picks the runner by the traffic
-file's ``kind`` (``serve-open`` -> ``runners/serve.py``), and prints as its
+file's ``kind`` (``serve-open`` -> ``runners/serve.py``; the same word names
+the run's family file, ``families/serve.py``), and prints as its
 last line one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
 per-layer metrics), ``device`` and, traced, ``breakdown``. Fails (exit 2,
@@ -98,13 +99,17 @@ def main(argv=None) -> int:
     a = ap.parse_args(argv)
     spec = load_cell(a.workload)
     kind = json.loads(Path(spec["traffic_path"]).read_text())["kind"]
-    runner = import_module(f"benchmark.runners.{kind.split('-')[0]}")
+    family = kind.split('-')[0]
+    runner = import_module(f"benchmark.runners.{family}")
     try:
         run = runner.run(spec["cell"], spec["config"], spec["traffic_path"],
                          a.seed, a.seconds, bool(a.trace), _T_PROCESS_START)
     except harness.NoAccelerator as e:
         print(f"benchmark/run.py: {e}", file=sys.stderr)
         return 2
+    # which benchmark/families/<name>.py the shared per-layer readers ask
+    # for this run's bytes and counters
+    run["runner"] = family
     if a.dump:
         Path(a.dump).parent.mkdir(parents=True, exist_ok=True)
         Path(a.dump).write_text(json.dumps(run, default=str))

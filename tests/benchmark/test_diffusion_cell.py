@@ -14,6 +14,7 @@ from benchmark import (diffusion_counters, end_to_end, flops,
 from benchmark.reference import diffusion_decoder
 from benchmark.run import load_cell, result_line
 from benchmark.runners import diffusion as runner
+from manifest_pins import assert_lists, entry, listed_by
 
 ROOT = Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -42,7 +43,7 @@ NEW_METRICS = {
     "kernels.block_attention_ms_per_forward": "ms",
     "kernels.block_attention_hbm_roofline_share": "%",
     "kernels.diffusion_moe_gmm_ms_per_forward": "ms",
-    "kernels.diffusion_moe_gmm_hbm_roofline_share": "%",
+    "kernels.moe_gmm_hbm_roofline_share": "%",
     "kernels.block_attention_live_page_share": "%"}
 LISTED = (
     "engine.decode_slot_utilization", "device_idle.serve",
@@ -66,9 +67,13 @@ TINY = {"name": "tiny-sdar", "model_type": "sdar_moe", "hidden_size": 64,
         "block_length": 4, "denoising_steps": 4, "mask_token_id": 300,
         "remasking_strategy": "low_confidence_dynamic",
         "confidence_threshold": 0.9,
+        # the page is STATED: the rehearsal counts on a prompt of the pool
+        # coming round again with one whole page cached, and the default page
+        # follows the K/V row's bytes since PR 58 (128 tokens here, longer
+        # than any of these prompts: no hit, no suffix program)
         "serve": {"dtype": "float32", "max_batch_size": 4,
                   "max_seq_len": 256, "kv_hbm_budget_gb": 0.01,
-                  "prefill_chunk": 64}}
+                  "kv_block_size": 64, "prefill_chunk": 64}}
 TINY_TRAFFIC = {
     "kind": "diffusion-closed", "clients": 6, "pool_per_client": 50,
     "prompt_tokens": {"dist": "lognormal", "median": 40, "sigma": 0.5,
@@ -99,51 +104,47 @@ def test_the_cell_and_its_configuration_are_in_the_manifest_by_name():
 
 
 @pytest.mark.parametrize("name", sorted(NEW_METRICS))
-def test_each_new_metric_lists_this_cell_alone_and_has_a_reader(name):
-    metric = {m["name"]: m for m in MANIFEST["per_layer"]}[name]
-    assert metric["workloads"] == [CELL]
-    assert (metric["unit"], metric["moves"]) == (NEW_METRICS[name],
-                                                 "serve_tokens_per_s")
+def test_each_new_metric_lists_this_cell_and_has_a_reader(name):
+    metric = assert_lists(name, CELL, unit=NEW_METRICS[name],
+                          moves="serve_tokens_per_s")
+    if name.startswith(("diffusion.", "serve_programs.diffusion",
+                        "kernels.block_attention", "kernels.diffusion")):
+        assert metric["workloads"] == [CELL]    # the mechanism's own
     assert set(metric) == {"name", "unit", "better", "source", "layer",
                            "moves", "workloads"}
-    assert callable(layer_metrics.load(name).read)
+    assert callable(layer_metrics.load(metric["name"]).read)
 
 
 @pytest.mark.parametrize("name", LISTED)
 def test_the_accepted_readers_that_read_this_program_rightly_list_the_cell(
         name):
-    metric = {m["name"]: m for m in MANIFEST["per_layer"]}[name]
-    assert CELL in metric["workloads"]
+    assert_lists(name, CELL)
 
 
 def test_what_the_cell_lists_moves_a_metric_the_cell_reports():
     reported = {m["name"] for m in load_cell(CELL, MANIFEST)["end_to_end"]}
-    listed = [m for m in MANIFEST["per_layer"]
-              if CELL in m.get("workloads", [])]
-    assert len(listed) == len(NEW_METRICS) + len(LISTED)
-    for metric in listed:
-        assert metric["moves"] in reported, metric["name"]
+    assert set(NEW_METRICS) | set(LISTED) <= listed_by(CELL)
+    for name in listed_by(CELL):
+        assert entry(name)["moves"] in reported, name
 
 
 def test_accepted_readers_that_would_miscount_this_model_do_not_list_it():
     """A forward is a decode step in ``stats()``, but its kernels run
-    under other names and its bytes are counted otherwise: the accepted
-    decode-step kernel and roofline readers (``flops_moe`` reads
-    ``intermediate_size``, 6144 here and used by no layer), the riding
-    shares (this model refuses to ride) and the prefill stall (no
+    under other names and its bytes are counted otherwise: the decode-step
+    kernel times and the whole step's roofline share (no family function
+    for them in ``families/diffusion.py``), the riding shares (this model
+    refuses to ride) and the prefill stall (no
     ``llmctl.engine.prefill.wait`` span: nothing is fetched at a prefill,
     so a 0 there would say nothing of what queues behind one) may not list
     the cell. The routing counters' readers read this program as they
-    read the others, and do (``LISTED``)."""
+    read the others, and do (``LISTED``); the grouped matmuls' share of
+    their roofline is one entry since PR 59, read through the family's own
+    time a FORWARD and hits a forward."""
     for metric in MANIFEST["per_layer"]:
         if metric["name"].startswith((
-                "kernels.paged_attention", "kernels.moe_gmm", "kernels.hybrid",
-                "kernels.ssm", "kernels.latent", "kernels.mla_",
-                "kernels.kda", "kernels.linear", "ssm.", "kda.", "kv.",
-                "residual.", "moe.held", "moe.linear", "moe.diffusion",
-                "serve_programs.decode_",
-                "serve_programs.moe_decode", "serve_programs.hybrid",
-                "serve_programs.latent", "serve_programs.linear",
+                "kernels.paged_attention", "kernels.moe_gmm_ms",
+                "kernels.ssm", "kernels.mla_", "kernels.kda", "ssm.", "kda.",
+                "kv.", "residual.", "moe.held", "serve_programs.decode_",
                 "engine.prefill_ride", "engine.prefill_state",
                 "engine.prefill_stall")):
             assert CELL not in metric.get("workloads", []), metric["name"]
@@ -230,6 +231,7 @@ def _run():
                         decode_experts_hit=7 * 400 * 120,
                         decode_layer_steps=7 * 400)
     return {"config": CONFIG, "device": {"kind": "TPU v5e"},
+            "runner": "diffusion",
             "serve_cfg": {"decode_steps_per_dispatch": 8,
                           "max_batch_size": 64},
             "stats": {"before": before, "after": after},
@@ -267,7 +269,7 @@ def test_the_readers_on_a_hand_made_run():
     assert read("kernels.block_attention_hbm_roofline_share") == \
         pytest.approx(100 * pages / peak / 0.7e-3)
     experts = flops_diffusion.expert_bytes(CONFIG, 7 * 120)
-    assert read("kernels.diffusion_moe_gmm_hbm_roofline_share") == \
+    assert read("kernels.moe_gmm_hbm_roofline_share") == \
         pytest.approx(100 * experts / peak / 11e-3)
     whole = flops_diffusion.forward_bytes(CONFIG, 200, 64, 7 * 120)
     share = read("serve_programs.diffusion_forward_hbm_roofline_share")
@@ -425,6 +427,7 @@ def test_diffusion_runner_rehearsal(tmp_path, monkeypatch):
     cell = {"name": "tiny.mix", "chips": 1}
     run = runner.run(cell, TINY, str(path), 3000000019, 4.0, False,
                      time.monotonic(), require_tpu=False)
+    run["runner"] = "diffusion"     # as run.py stamps it
     assert run["kind"] == "serve" and run["stamps"]["kind"] == "serve-closed"
     check = run["check"]
     assert check["ok"] and run["compiled_in_window"] == 0
@@ -464,8 +467,11 @@ def test_diffusion_runner_rehearsal(tmp_path, monkeypatch):
     assert not {n for n in NEW_METRICS if n.endswith((
         "_ms_per_forward", "_device_ms", "_roofline_share"))} \
         & set(traced["metrics"])
+    # ``block_length`` tokens in ``denoising_steps`` forwards since PR 47
+    # (a block's commit rides the next block's first forward): 1.0 at 4 and
+    # 4, where the schedule with a forward for the commit alone gave 0.8
     assert 0 < traced["metrics"]["diffusion.tokens_per_slot_forward"][
-        "value"] <= 0.8
+        "value"] <= 1.0
     assert diffusion_counters.block_length(run) == 4
 
 

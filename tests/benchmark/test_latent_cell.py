@@ -13,6 +13,7 @@ from benchmark import (end_to_end, flops, flops_latent, latent_counters,
                        layer_metrics, loadgen_docqa)
 from benchmark.run import load_cell, result_line
 from benchmark.runners import latent as latent_runner
+from manifest_pins import assert_lists
 
 ROOT = Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -23,10 +24,10 @@ TRAFFIC = json.loads((ROOT / "benchmark/traffic/doc-qa-64.json").read_text())
 NEW_METRICS = (
     "kernels.mla_attention_ms_per_decode_step",
     "kernels.mla_attention_roofline_share", "kernels.mla_live_page_share",
-    "serve_programs.latent_decode_hbm_roofline_share",
+    "serve_programs.decode_hbm_roofline_share",
     "kv.latent_share_of_decode_bytes", "kv.prefix_cached_token_share",
     "residual.hc_ms_per_decode_step",
-    "kernels.latent_moe_gmm_hbm_roofline_share")
+    "kernels.moe_gmm_hbm_roofline_share")
 
 
 # -- the manifest ----------------------------------------------------------------
@@ -46,10 +47,12 @@ def test_the_cell_and_its_configuration_are_in_the_manifest_by_name():
 
 
 @pytest.mark.parametrize("name", NEW_METRICS)
-def test_each_new_metric_lists_this_cell_alone_and_has_a_reader(name):
-    metric = {m["name"]: m for m in MANIFEST["per_layer"]}[name]
-    assert metric["workloads"] == [CELL] and metric["moves"] == "tpot_p95_ms"
-    assert metric["unit"] == ("ms" if name.endswith("_step") else "%")
+def test_each_new_metric_lists_this_cell_and_has_a_reader(name):
+    metric = assert_lists(
+        name, CELL, unit="ms" if name.endswith("_step") else "%")
+    if name in ("kv.latent_share_of_decode_bytes",
+                "residual.hc_ms_per_decode_step"):
+        assert metric["workloads"] == [CELL]        # this model's alone
     assert callable(layer_metrics.load(name).read)
 
 
@@ -59,22 +62,25 @@ def test_each_new_metric_lists_this_cell_alone_and_has_a_reader(name):
     "engine.prefill_stall_ms_per_decode_step", "engine.device_starved_share"])
 def test_the_accepted_readers_that_read_this_program_rightly_list_the_cell(
         name):
-    metric = {m["name"]: m for m in MANIFEST["per_layer"]}[name]
-    assert CELL in metric["workloads"]
+    assert_lists(name, CELL)
 
 
 def test_accepted_readers_that_would_miscount_this_model_do_not_list_it():
     """``moe_counters.decode_experts_hit_per_step`` divides by
-    ``num_hidden_layers`` (7 here, 6 expert layers), ``flops.py`` counts a
-    GQA decoder's bytes, and ``paged_attention`` in a kernel's name is the
-    K/V kernel's: none of their metrics may list this cell."""
+    ``num_hidden_layers`` (7 here, 6 expert layers) and ``paged_attention``
+    in a kernel's name is the K/V kernel's: no metric that reads those may
+    list this cell. (The whole step's and the grouped matmuls' shares of
+    their rooflines list it since PR 59: they take the bytes and the hits a
+    step from ``families/latent.py``, which divides by the EXPERT layers.)"""
     for metric in MANIFEST["per_layer"]:
         if metric["name"].startswith((
-                "kernels.paged_attention", "kernels.moe_gmm",
-                "kernels.hybrid", "kernels.ssm", "moe.", "ssm.",
-                "serve_programs.decode_hbm", "serve_programs.moe_decode",
-                "serve_programs.hybrid")):
+                "kernels.paged_attention", "kernels.moe_gmm_ms",
+                "kernels.ssm", "moe.", "ssm.")):
             assert CELL not in metric["workloads"], metric["name"]
+    from benchmark import families
+    run = _run()
+    assert families.read(run, "expert_bytes") == flops_latent.expert_bytes(
+        CONFIG, 330) != 0       # 64 x 330 hits over 64 x 6 layer-steps
 
 
 # -- the configuration -----------------------------------------------------------
@@ -211,6 +217,7 @@ def _run(**trace):
                      "layer_steps": 0}}
     stats = {"before": before, "after": after}
     return {"config": CONFIG, "device": {"kind": "TPU v5e"},
+            "runner": "latent",
             "serve_cfg": {"decode_steps_per_dispatch": 8,
                           "max_batch_size": 64},
             "stats": stats, "trace_stats": stats,
@@ -242,9 +249,9 @@ def test_the_readers_compute_what_they_say_by_hand():
         + 330 * 22_020_096 + latent
     assert np.isclose(read("kv.latent_share_of_decode_bytes"),
                       100 * latent / total)
-    assert np.isclose(read("serve_programs.latent_decode_hbm_roofline_share"),
+    assert np.isclose(read("serve_programs.decode_hbm_roofline_share"),
                       100 * (total / 819e9) / 0.030)
-    assert np.isclose(read("kernels.latent_moe_gmm_hbm_roofline_share"),
+    assert np.isclose(read("kernels.moe_gmm_hbm_roofline_share"),
                       100 * (330 * 22_020_096 / 819e9) / 0.0120)
     assert np.isclose(read("kv.prefix_cached_token_share"),
                       100 * 99000 / 100000)
@@ -265,7 +272,7 @@ def test_a_program_without_the_spans_or_counters_reads_nothing():
     for name in ("kernels.mla_attention_ms_per_decode_step",
                  "kernels.mla_attention_roofline_share",
                  "residual.hc_ms_per_decode_step",
-                 "kernels.latent_moe_gmm_hbm_roofline_share"):
+                 "kernels.moe_gmm_hbm_roofline_share"):
         assert layer_metrics.load(name).read(run) is None, name
 
 
@@ -305,6 +312,7 @@ def test_latent_runner_rehearsal(tmp_path, monkeypatch):
     run = latent_runner.run({"name": "tiny.mix", "chips": 1}, _tiny(),
                             str(path), 3000000019, 4.0, False,
                             time.monotonic(), require_tpu=False)
+    run["runner"] = "latent"        # as run.py stamps it
     spec = load_cell(CELL, MANIFEST)
     line = result_line(run, spec["end_to_end"], end_to_end.load, False)
     assert line["correct"] and line["failed"] == 0 and line["attempted"] >= 1

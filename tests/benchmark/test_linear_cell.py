@@ -14,6 +14,7 @@ from benchmark import (end_to_end, facts, flops, flops_linear, layer_metrics,
                        linear_counters, loadgen_linear)
 from benchmark.run import load_cell, result_line
 from benchmark.runners import linear as linear_runner
+from manifest_pins import assert_lists
 
 ROOT = Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -29,14 +30,14 @@ NEW_METRICS = {
     "kernels.kda_decode_hbm_roofline_share": "%",
     "kernels.kda_prefill_roofline_share": "%",
     "kda.state_share_of_decode_bytes": "%",
-    "serve_programs.linear_decode_hbm_roofline_share": "%",
+    "serve_programs.decode_hbm_roofline_share": "%",
     "engine.prefill_state_carry_token_share": "%",
-    "kernels.linear_moe_gmm_ms_per_decode_step": "ms",
-    "kernels.linear_moe_gmm_hbm_roofline_share": "%",
-    "moe.linear_held_experts_hit_share": "%",
-    "kernels.linear_mla_attention_ms_per_decode_step": "ms",
-    "kernels.linear_mla_attention_roofline_share": "%",
-    "kernels.linear_mla_live_page_share": "%"}
+    "kernels.moe_gmm_ms_per_decode_step": "ms",
+    "kernels.moe_gmm_hbm_roofline_share": "%",
+    "moe.held_experts_hit_share": "%",
+    "kernels.mla_attention_ms_per_decode_step": "ms",
+    "kernels.mla_attention_roofline_share": "%",
+    "kernels.mla_live_page_share": "%"}
 LISTED = (
     "engine.decode_slot_utilization", "startup.import_s",
     "startup.program_lowering_s", "startup.program_compile_s",
@@ -44,8 +45,7 @@ LISTED = (
     "startup.unattributed_s",
     # what moves ``tpot_p95_ms``, read over the replies that ended in the
     # window (``runners/linear.py ended_in_window``)
-    "serve_programs.decode_step_device_ms",
-    "serve_programs.prefill_device_ms_per_ktok", "device_idle.serve",
+    "serve_programs.decode_step_device_ms", "device_idle.serve",
     "engine.host_ms_per_decode_step",
     "engine.prefill_stall_ms_per_decode_step", "engine.device_starved_share",
     "moe.held_choice_share", "moe.held_expert_load_imbalance")
@@ -70,20 +70,24 @@ def test_the_cell_and_its_configuration_are_in_the_manifest_by_name():
         "tpot_p95_ms", "serve_tokens_per_s", "setup_s"}
 
 
+# this mechanism's alone; the others are entries other cells list too
+OWN = ("kernels.kda_prefill_roofline_share", "kda.state_share_of_decode_bytes",
+       "engine.prefill_state_carry_token_share")
+
+
 @pytest.mark.parametrize("name", sorted(NEW_METRICS))
-def test_each_new_metric_lists_this_cell_alone_and_has_a_reader(name):
-    metric = {m["name"]: m for m in MANIFEST["per_layer"]}[name]
-    assert metric["workloads"] == [CELL]
-    assert (metric["unit"], metric["moves"]) == (NEW_METRICS[name],
-                                                 "serve_tokens_per_s")
+def test_each_new_metric_lists_this_cell_and_has_a_reader(name):
+    metric = assert_lists(name, CELL, unit=NEW_METRICS[name])
+    if name in OWN:
+        assert metric["workloads"] == [CELL]
+        assert metric["moves"] == "serve_tokens_per_s"
     assert callable(layer_metrics.load(name).read)
 
 
 @pytest.mark.parametrize("name", LISTED)
 def test_the_accepted_readers_that_read_this_program_rightly_list_the_cell(
         name):
-    metric = {m["name"]: m for m in MANIFEST["per_layer"]}[name]
-    assert CELL in metric["workloads"]
+    assert_lists(name, CELL)
 
 
 def test_what_the_cell_lists_moves_a_metric_the_cell_reports():
@@ -94,27 +98,24 @@ def test_what_the_cell_lists_moves_a_metric_the_cell_reports():
 
 
 def test_accepted_readers_that_would_miscount_this_model_do_not_list_it():
-    """``moe.held_experts_hit_share`` reads ``n_routed_experts`` (this file
-    says ``num_experts``), ``kernels.mla_attention_roofline_share`` counts
-    ``num_hidden_layers`` latent layers (12 here, 3 of them latent),
-    ``flops_latent`` reads ``q_lora_rank`` (null here), ``flops_hybrid``
-    the ``nemotron_h`` keys, and ``paged_attention`` in a kernel's name is
-    the K/V kernel's: none of their metrics may list this cell. (The two
-    latent metrics whose readers WOULD read it rightly,
-    ``kernels.mla_attention_ms_per_decode_step`` and
-    ``kernels.mla_live_page_share``, are pinned to the latent cell alone by
-    ``test_latent_cell.py``, an accepted file: not listed either.) What
-    they measure is read for this cell by the ``*.linear_*`` readers."""
+    """``flops_hybrid`` reads the ``nemotron_h`` keys and ``paged_attention``
+    in a kernel's name is the K/V kernel's: none of their metrics may list
+    this cell, nor may the window-wide routing shares that divide by
+    ``num_hidden_layers``. What the un-prefixed entries measure is read for
+    this cell through ``families/linear.py`` since PR 59: the experts held
+    are this file's ``num_experts`` (not ``n_routed_experts``), the latent
+    layers its 3 ``*`` layers (not all 12), and no ``q_lora_rank`` is
+    read."""
     for metric in MANIFEST["per_layer"]:
         if metric["name"].startswith((
-                "kernels.paged_attention", "kernels.moe_gmm",
-                "kernels.hybrid", "kernels.ssm", "kernels.latent", "ssm.",
-                "kernels.mla_", "kv.", "residual.",
-                "moe.held_experts_hit", "moe.experts", "moe.expert_load",
-                "serve_programs.decode_hbm", "serve_programs.moe_decode",
-                "serve_programs.hybrid", "serve_programs.latent",
-                "engine.prefill_ride")):
+                "kernels.paged_attention", "kernels.ssm", "ssm.", "kv.",
+                "residual.", "moe.experts", "moe.expert_load")):
             assert CELL not in metric.get("workloads", []), metric["name"]
+    from benchmark import families
+    linear, hybrid = families.load("linear"), families.load("hybrid")
+    assert linear.held_experts_hit_share is not hybrid.held_experts_hit_share
+    assert linear.mla_attention_roofline_share is not families.load(
+        "latent").mla_attention_roofline_share
 
 
 # -- the configuration -----------------------------------------------------------
@@ -292,6 +293,7 @@ def _run(**trace):
                      "state_carry_tokens": 1000 + 4000}}
     stats = {"before": before, "after": after}
     return {"config": CONFIG, "device": {"kind": "TPU v5e"},
+            "runner": "linear",
             "serve_cfg": {"decode_steps_per_dispatch": 8,
                           "max_batch_size": 128},
             "stats": stats, "trace_stats": stats,
@@ -319,7 +321,7 @@ def test_the_readers_compute_what_they_say_by_hand():
              + 330 * 14_155_776 + state + 700 * 256 * 3840)
     assert np.isclose(read("kda.state_share_of_decode_bytes"),
                       100 * state / total)
-    assert np.isclose(read("serve_programs.linear_decode_hbm_roofline_share"),
+    assert np.isclose(read("serve_programs.decode_hbm_roofline_share"),
                       100 * (total / 819e9) / 0.020)
     # 12,288 rows x 9 layers: bytes 40,960 B a row (50 ns) over FLOPs
     # 4.46 M a row (22.6 ns)
@@ -329,23 +331,23 @@ def test_the_readers_compute_what_they_say_by_hand():
                       100 * 12288 * 9 * per_row / 0.5)
     assert np.isclose(read("engine.prefill_state_carry_token_share"), 40.0)
     # the grouped matmuls: 330 hit (layer, expert) pairs x 14.16 MB a step
-    assert np.isclose(read("kernels.linear_moe_gmm_ms_per_decode_step"), 6.4)
-    assert np.isclose(read("kernels.linear_moe_gmm_hbm_roofline_share"),
+    assert np.isclose(read("kernels.moe_gmm_ms_per_decode_step"), 6.4)
+    assert np.isclose(read("kernels.moe_gmm_hbm_roofline_share"),
                       100 * (330 * 14_155_776 / 819e9) / 0.0064)
     # 28 of the 32 held experts hit a layer a step
-    assert np.isclose(read("moe.linear_held_experts_hit_share"), 87.5)
+    assert np.isclose(read("moe.held_experts_hit_share"), 87.5)
     # the latent walk: 179,200 live rows x 640 x 2 B in each of THREE
     # layers (0.28 ms each; the absorbed form's 12.5 GFLOP take 0.06)
-    assert np.isclose(read("kernels.linear_mla_attention_ms_per_decode_step"),
+    assert np.isclose(read("kernels.mla_attention_ms_per_decode_step"),
                       1.2)
     rows = 700 * 256
     assert flops_linear.mla_kernel_bytes(CONFIG, rows) == rows * 1280
     assert flops_linear.mla_kernel_flops(CONFIG, rows) == (
         2 * 32 * rows * (576 + 512))
     assert rows * 1280 / 819e9 > 2 * 32 * rows * 1088 / 197e12
-    assert np.isclose(read("kernels.linear_mla_attention_roofline_share"),
+    assert np.isclose(read("kernels.mla_attention_roofline_share"),
                       100 * 3 * (rows * 1280 / 819e9) / 0.0012)
-    assert np.isclose(read("kernels.linear_mla_live_page_share"),
+    assert np.isclose(read("kernels.mla_live_page_share"),
                       100 * 8 * 700 / (8 * 128 * 64))
     assert flops.peaks("TPU v5e")["hbm_bytes_per_s"] == 819e9
     for name in NEW_METRICS:
@@ -367,14 +369,14 @@ def test_a_program_without_the_spans_or_counters_reads_nothing():
     for name in ("kernels.kda_decode_ms_per_decode_step",
                  "kernels.kda_decode_hbm_roofline_share",
                  "kernels.kda_prefill_roofline_share",
-                 "kernels.linear_moe_gmm_ms_per_decode_step",
-                 "kernels.linear_moe_gmm_hbm_roofline_share",
-                 "kernels.linear_mla_attention_ms_per_decode_step",
-                 "kernels.linear_mla_attention_roofline_share"):
+                 "kernels.moe_gmm_ms_per_decode_step",
+                 "kernels.moe_gmm_hbm_roofline_share",
+                 "kernels.mla_attention_ms_per_decode_step",
+                 "kernels.mla_attention_roofline_share"):
         assert layer_metrics.load(name).read(run) is None, name
     run["trace"] = {}                         # an untraced run
     assert layer_metrics.load(
-        "serve_programs.linear_decode_hbm_roofline_share").read(run) is None
+        "serve_programs.decode_hbm_roofline_share").read(run) is None
 
 
 # -- the runner ------------------------------------------------------------------
@@ -421,6 +423,7 @@ def test_linear_runner_rehearsal(tmp_path, monkeypatch):
     run = linear_runner.run({"name": "tiny.mix", "chips": 1}, _tiny(),
                             str(path), 3000000019, 5.0, False,
                             time.monotonic(), require_tpu=False)
+    run["runner"] = "linear"        # as run.py stamps it
     spec = load_cell(CELL, MANIFEST)
     line = result_line(run, spec["end_to_end"], end_to_end.load, False)
     assert line["correct"] and line["failed"] == 0 and line["attempted"] >= 4
@@ -438,12 +441,12 @@ def test_linear_runner_rehearsal(tmp_path, monkeypatch):
     traced = result_line(run, spec["per_layer"], layer_metrics.load, True)
     assert {"engine.prefill_state_carry_token_share",
             "kda.state_share_of_decode_bytes",
-            "moe.linear_held_experts_hit_share",
-            "kernels.linear_mla_live_page_share",
+            "moe.held_experts_hit_share",
+            "kernels.mla_live_page_share",
             "engine.decode_slot_utilization"} <= set(traced["metrics"])
     assert not {"kernels.kda_decode_ms_per_decode_step",
                 "kernels.kda_prefill_roofline_share",
-                "kernels.linear_moe_gmm_ms_per_decode_step",
+                "kernels.moe_gmm_ms_per_decode_step",
                 "device_idle.serve"} & set(traced["metrics"])
     share = traced["metrics"]["engine.prefill_state_carry_token_share"]
     assert 30 < share["value"] < 100

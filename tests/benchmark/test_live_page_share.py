@@ -2,23 +2,20 @@
 hand-made runs, on a run of a program without the counters, and the entry
 that lists it, pinned by name and not by place."""
 
-import json
-from pathlib import Path
-
 import pytest
 
 from benchmark import layer_metrics
 from benchmark.run import load_cell
+from manifest_pins import MANIFEST, assert_lists, entry
 
-ROOT = Path(__file__).resolve().parents[2]
-MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
 METRIC = "kernels.paged_attention_live_page_share"
 SERVING = ["mistral-7b-16l.chat", "mistral-7b-16l.batch-64",
            "olmoe-1b-7b-10l.moe-batch-64"]
 
 
 def run_with(before_kv: dict, after_kv: dict) -> dict:
-    return {"stats": {"before": {"kv": before_kv}, "after": {"kv": after_kv}}}
+    return {"stats": {"before": {"kv": before_kv}, "after": {"kv": after_kv}},
+            "runner": "serve"}
 
 
 def kv(live=None, table=None) -> dict:
@@ -41,14 +38,11 @@ def test_reader_on_a_hand_made_run(before, after, want):
 
 
 def test_the_entry_names_the_kernels_layer_and_the_serving_cells():
-    entries = [m for m in MANIFEST["per_layer"] if m["name"] == METRIC]
-    assert len(entries) == 1
-    entry = entries[0]
-    kernel_ms = next(m for m in MANIFEST["per_layer"]
-                     if m["name"] == "kernels.paged_attention_ms_per_decode_step")
-    assert entry == {"name": METRIC, "unit": "%", "better": "higher",
-                     "source": "program_counter", "layer": kernel_ms["layer"],
-                     "moves": "tpot_p95_ms", "workloads": SERVING}
+    kernel_ms = entry("kernels.paged_attention_ms_per_decode_step")
+    for cell in SERVING:
+        assert_lists(METRIC, cell, unit="%", better="higher",
+                     source="program_counter", layer=kernel_ms["layer"],
+                     moves="tpot_p95_ms")
 
 
 @pytest.mark.parametrize("cell,listed", [

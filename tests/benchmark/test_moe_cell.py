@@ -18,11 +18,12 @@ from benchmark import (end_to_end, flops_moe, harness, layer_metrics,
 from benchmark.reference import moe_decoder
 from benchmark.run import load_cell, result_line
 from benchmark.runners import moe as moe_runner
+from manifest_pins import assert_lists, entry, listed_by
 
 ROOT = Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELL = "olmoe-1b-7b-10l.moe-batch-64"
-NEW_METRICS = ["serve_programs.moe_decode_hbm_roofline_share",
+NEW_METRICS = ["serve_programs.decode_hbm_roofline_share",
                "kernels.moe_gmm_ms_per_decode_step",
                "kernels.moe_gmm_hbm_roofline_share",
                "moe.experts_hit_share", "moe.expert_load_imbalance"]
@@ -104,38 +105,26 @@ def test_the_traffic_is_batch_64s_letter_for_letter():
 
 
 def test_the_cell_lists_the_metrics_the_issue_names():
+    """By name and by membership: how many entries list the cell, and which
+    cells joined these entries since, is nobody's pin."""
     spec = load_cell(CELL, MANIFEST)
     assert spec["cell"]["chips"] == 1
     assert {m["name"] for m in spec["end_to_end"]} == {
         "tpot_p95_ms", "serve_tokens_per_s", "setup_s"}
-    per_layer = {m["name"] for m in spec["per_layer"]}
-    assert set(NEW_METRICS) <= per_layer
-    assert "serve_programs.decode_hbm_roofline_share" not in per_layer
-    assert len(per_layer) == 13
-    for m in MANIFEST["per_layer"]:
-        if m["name"] in NEW_METRICS:
-            assert m["workloads"] == [CELL]
-
-
-# what BENCHMARK.json's lists held before this PR, in order (git show
-# 898e733:BENCHMARK.json): a later PR appends and never reorders
-EARLIER_PER_LAYER = [
-    "loadgen.lateness_p95_ms", "engine.ttft_p95_ms",
-    "engine.decode_slot_utilization", "serve_programs.decode_step_device_ms",
-    "serve_programs.prefill_device_ms_per_ktok",
-    "serve_programs.decode_hbm_roofline_share", "device_idle.serve",
-    "input.data_wait_ms", "trainer.mfu", "device_idle.train",
-    "collectives.exposed_share", "scheduler.queue_wait_p95_ms",
-    "engine.host_ms_per_decode_step",
-    "engine.prefill_stall_ms_per_decode_step", "engine.device_starved_share",
-    "kernels.paged_attention_ms_per_decode_step"]
-EARLIER_CELLS = ["mistral-7b-16l.chat", "internlm2-1.8b-6l.pretrain-4k",
-                 "mistral-7b-16l.batch-64",
-                 "internlm2-1.8b.pretrain-4k-fsdp4"]
+    for name in NEW_METRICS:
+        assert_lists(name, CELL)
+    assert set(NEW_METRICS) <= {m["name"] for m in spec["per_layer"]}
 
 
 # PR 25's five, as tests/benchmark/test_span_metrics.py names them
-SPAN_METRICS = EARLIER_PER_LAYER[-5:]
+SPAN_METRICS = ["scheduler.queue_wait_p95_ms",
+                "engine.host_ms_per_decode_step",
+                "engine.prefill_stall_ms_per_decode_step",
+                "engine.device_starved_share",
+                "kernels.paged_attention_ms_per_decode_step"]
+EARLIER_CELLS = ["mistral-7b-16l.chat", "internlm2-1.8b-6l.pretrain-4k",
+                 "mistral-7b-16l.batch-64",
+                 "internlm2-1.8b.pretrain-4k-fsdp4"]
 
 
 @pytest.mark.parametrize("cell,names", [
@@ -145,47 +134,36 @@ SPAN_METRICS = EARLIER_PER_LAYER[-5:]
     (CELL, SPAN_METRICS[1:]),
 ])
 def test_span_metrics_keep_their_cells_layers_and_keys(cell, names):
-    """What test_span_metrics.py's
-    test_new_entries_are_at_the_end_and_name_layers_that_exist still says
-    truly once a later PR has appended after PR 25's entries (it pins them
-    to the END of the list, so its three cases fail from PR 27 on, and
-    tests/benchmark/ is a `benchmark` PR's to edit): every cell reports
-    PR 25's metrics in that order, and each entry keeps its layer, what it
-    moves and its keys."""
-    at = len(EARLIER_PER_LAYER) - len(SPAN_METRICS)
-    entries = MANIFEST["per_layer"][at:len(EARLIER_PER_LAYER)]
-    assert [m["name"] for m in entries] == SPAN_METRICS
-    old_layers = {m["layer"] for m in MANIFEST["per_layer"][:at]}
-    for m in entries:
-        assert m["layer"] in old_layers and m["moves"] == "tpot_p95_ms"
+    """Every cell lists PR 25's metrics that it listed, and each entry keeps
+    its layer, what it moves and its keys."""
+    layers = {m["layer"] for m in MANIFEST["per_layer"]
+              if m["name"] not in SPAN_METRICS}
+    for name in SPAN_METRICS:
+        m = entry(name)
+        assert m["layer"] in layers and m["moves"] == "tpot_p95_ms"
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    reported = [m["name"] for m in load_cell(cell, MANIFEST)["per_layer"]]
-    assert [n for n in reported if n in SPAN_METRICS] == names
+    for name in names:
+        assert_lists(name, cell)
+    assert listed_by(cell) & set(SPAN_METRICS) == set(names)
 
 
-def test_earlier_entries_stay_where_they_were():
-    """New entries go at the END of every list, and an earlier metric's
-    cells keep their order with the new cell after them."""
-    per_layer = [m["name"] for m in MANIFEST["per_layer"]]
-    n = len(EARLIER_PER_LAYER)
-    assert per_layer[:n] == EARLIER_PER_LAYER
-    assert per_layer[n:n + len(NEW_METRICS)] == NEW_METRICS
-    assert [c["name"] for c in MANIFEST["workloads"]][:5] == (
-        EARLIER_CELLS + [CELL])
-    assert [c["name"] for c in MANIFEST["configs"]][:4] == [
-        "mistral-7b-16l", "internlm2-1.8b-6l", "internlm2-1.8b",
-        "olmoe-1b-7b-10l"]
-    layers = {m["layer"] for m in MANIFEST["per_layer"][:n]}
+def test_earlier_entries_keep_their_cells_and_their_keys():
+    """An earlier metric that lists the cell keeps the cells it had, and no
+    entry has a key the contract does not name."""
+    cells = [c["name"] for c in MANIFEST["workloads"]]
+    assert set(EARLIER_CELLS + [CELL]) <= set(cells)
+    assert {"mistral-7b-16l", "internlm2-1.8b-6l", "internlm2-1.8b",
+            "olmoe-1b-7b-10l"} <= {c["name"] for c in MANIFEST["configs"]}
+    layers = {m["layer"] for m in MANIFEST["per_layer"]
+              if m["name"] not in NEW_METRICS}
     for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
-        cells = m.get("workloads", [])
-        if CELL in cells:
-            assert cells[-1] == CELL or cells.index(CELL) >= len(
-                [c for c in cells if c in EARLIER_CELLS])
+        listed = m.get("workloads", [])
+        assert listed == [c for c in cells if c in listed]   # manifest order
         assert set(m) <= {"name", "unit", "better", "bound", "source",
                           "layer", "moves", "workloads"}
-    for m in MANIFEST["per_layer"][n:n + 3]:
-        assert m["layer"] in layers          # the kernels' layer exists
+    for name in NEW_METRICS[:3]:
+        assert entry(name)["layer"] in layers    # the kernels' layer exists
 
 
 # -- operations and bytes, by hand ----------------------------------------------
@@ -216,6 +194,7 @@ def test_moe_bytes_and_operations_by_hand():
 
 def _run(before, after, ops, decode=(10, 2.0)):
     return {"config": _config(), "device": {"kind": "TPU v5 lite"},
+            "runner": "moe",
             "serve_cfg": {"decode_steps_per_dispatch": 8,
                           "max_batch_size": 32},
             "stats": {"before": before, "after": after},
@@ -250,9 +229,9 @@ def test_moe_readers_on_hand_made_counters():
         100 * 625 * 12_582_912 / 819e9 / 12.5e-3)
     # 16 requests of 985 + 15 tokens live over the stretch: 16,000
     floor = (2 * 272_105_472 + 625 * 12_582_912 + 16_000 * 81_920) / 819e9
-    assert read("serve_programs.moe_decode_hbm_roofline_share") == \
+    assert read("serve_programs.decode_hbm_roofline_share") == \
         pytest.approx(100 * floor / 25e-3)
-    assert read("serve_programs.moe_decode_hbm_roofline_share") < 100
+    assert read("serve_programs.decode_hbm_roofline_share") < 100
 
 
 def test_moe_readers_say_nothing_where_there_is_nothing_to_read():
@@ -302,6 +281,7 @@ def test_moe_runner_rehearsal(tmp_path, monkeypatch):
     cell = {"name": "tiny.mix", "chips": 1}
     run = moe_runner.run(cell, TINY, str(path), 3000000019, 4.0, False,
                          time.monotonic(), require_tpu=False)
+    run["runner"] = "moe"           # as run.py stamps it
     assert run["kind"] == "serve" and run["stamps"]["kind"] == "serve-closed"
     assert run["check"]["ok"] and run["compiled_in_window"] == 0
     assert run["check"]["tol"] == pytest.approx(
@@ -319,7 +299,7 @@ def test_moe_runner_rehearsal(tmp_path, monkeypatch):
             "engine.decode_slot_utilization"} <= set(traced["metrics"])
     assert not {"kernels.moe_gmm_ms_per_decode_step",
                 "kernels.moe_gmm_hbm_roofline_share",
-                "serve_programs.moe_decode_hbm_roofline_share",
+                "serve_programs.decode_hbm_roofline_share",
                 "serve_programs.decode_step_device_ms"} & set(
         traced["metrics"])
     assert 0 < traced["metrics"]["moe.experts_hit_share"]["value"] <= 100

@@ -18,23 +18,24 @@ from benchmark import (end_to_end, flops_parallel, harness, layer_metrics,
 from benchmark.reference import parallel_decoder
 from benchmark.run import load_cell, result_line
 from benchmark.runners import parallel as parallel_runner
+from manifest_pins import assert_lists, entry, listed_by
 
 ROOT = Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
 CONFIG = "falcon-h1-34b-4l"
 CELL = CONFIG + ".chat-batch-128"
 NEW_METRICS = {
-    "serve_programs.parallel_decode_hbm_roofline_share": "serve_tokens_per_s",
-    "kernels.parallel_ssm_decode_ms_per_decode_step": "serve_tokens_per_s",
-    "kernels.parallel_ssm_decode_hbm_roofline_share": "serve_tokens_per_s",
-    "kernels.parallel_ssm_prefill_roofline_share": "tpot_p95_ms",
-    "kernels.parallel_paged_attention_ms_per_decode_step": "tpot_p95_ms",
-    "kernels.parallel_paged_attention_roofline_share": "tpot_p95_ms",
-    "ssm.parallel_state_share_of_decode_bytes": "serve_tokens_per_s",
+    "serve_programs.decode_hbm_roofline_share": "serve_tokens_per_s",
+    "kernels.ssm_decode_ms_per_decode_step": "serve_tokens_per_s",
+    "kernels.ssm_decode_hbm_roofline_share": "serve_tokens_per_s",
+    "kernels.ssm_prefill_roofline_share": "tpot_p95_ms",
+    "kernels.paged_attention_ms_per_decode_step": "tpot_p95_ms",
+    "kernels.paged_attention_roofline_share": "tpot_p95_ms",
+    "ssm.state_share_of_decode_bytes": "serve_tokens_per_s",
     "serve_programs.parallel_mixers_share_of_decode_step":
         "serve_tokens_per_s",
     "serve_programs.parallel_head_ms_per_decode_step": "tpot_p95_ms",
-    "engine.prefill_ride_token_share.chat-batch": "serve_tokens_per_s",
+    "engine.prefill_ride_token_share": "serve_tokens_per_s",
 }
 APPENDED_TO = ["engine.decode_slot_utilization",
                "serve_programs.decode_step_device_ms", "device_idle.serve",
@@ -164,21 +165,29 @@ def test_the_traffic_is_the_issues():
     assert t["clients"] == 2 * spec["config"]["serve"]["max_batch_size"]
 
 
+# this cell's alone; the others are entries other cells list too
+OWN = ("serve_programs.parallel_mixers_share_of_decode_step",
+       "serve_programs.parallel_head_ms_per_decode_step")
+
+
 def test_the_cell_reports_the_metrics_the_issue_names():
+    """By name and by membership (PR 59): the un-prefixed entries list the
+    cell beside others, and move what PR 59's rule gives a shared entry."""
     spec = load_cell(CELL, MANIFEST)
     assert {m["name"] for m in spec["end_to_end"]} == {
         "tpot_p95_ms", "serve_tokens_per_s", "setup_s"}
-    reported = {m["name"]: m for m in spec["per_layer"]}
-    assert set(reported) == set(NEW_METRICS) | set(APPENDED_TO)
+    assert set(NEW_METRICS) | set(APPENDED_TO) <= listed_by(CELL)
+    for name in APPENDED_TO:
+        assert_lists(name, CELL)
     for name, moves in NEW_METRICS.items():
-        m = reported[name]
-        assert m["workloads"] == [CELL] and m["moves"] == moves, name
+        m = assert_lists(name, CELL)
+        if name in OWN:
+            assert m["workloads"] == [CELL] and m["moves"] == moves, name
         assert ("roofline" in name) <= (m["unit"] == "%"), name
-        assert (ROOT / "benchmark" / "layer_metrics" / (name + ".py")
-                ).is_file(), name
+    # no layer of its own: every one is a layer some other cell's metric names
     layers = {m["layer"] for m in MANIFEST["per_layer"]
-              if CELL not in m["workloads"]}
-    assert {reported[n]["layer"] for n in NEW_METRICS} <= layers
+              if set(m["workloads"]) - {CELL}}
+    assert {entry(n)["layer"] for n in NEW_METRICS} <= layers
 
 
 def test_the_reference_imports_nothing_of_the_program():
@@ -249,6 +258,7 @@ def _run(ssm, scopes, decode=(10, 1.6), kv=True):
     before = stats(0, 0, {k: 0 for k in ssm} if ssm else None, 0, 0)
     after = stats(80, 8192, ssm, 10 * 1400, 6000)
     return {"config": _config(), "device": {"kind": "TPU v5 lite"},
+            "runner": "parallel",
             "serve_cfg": {"decode_steps_per_dispatch": 8,
                           "max_batch_size": 128},
             "stats": {"before": before, "after": after},
@@ -274,26 +284,26 @@ def test_parallel_readers_on_a_hand_made_run():
     state = 2 * 4 * 120 * 4_225_024
     floor = 6_114_829_056 + state + 8192 * 89_600
     assert parallel_counters.decode_step_bytes(run) == floor
-    assert read("serve_programs.parallel_decode_hbm_roofline_share") == \
+    assert read("serve_programs.decode_hbm_roofline_share") == \
         pytest.approx(100 * (floor / 819e9) / 20e-3)
-    assert read("kernels.parallel_ssm_decode_ms_per_decode_step") == \
+    assert read("kernels.ssm_decode_ms_per_decode_step") == \
         pytest.approx(7.0)
-    assert read("kernels.parallel_ssm_decode_hbm_roofline_share") == \
+    assert read("kernels.ssm_decode_hbm_roofline_share") == \
         pytest.approx(100 * (state / 819e9) / 7e-3)
     per_row = max(5_373_952 / 197e12, 18_560 / 819e9)
-    assert read("kernels.parallel_ssm_prefill_roofline_share") == \
+    assert read("kernels.ssm_prefill_roofline_share") == \
         pytest.approx(100 * 8192 * 4 * per_row / 0.002)
-    assert read("kernels.parallel_paged_attention_ms_per_decode_step") == \
+    assert read("kernels.paged_attention_ms_per_decode_step") == \
         pytest.approx(2.0)
-    assert read("kernels.parallel_paged_attention_roofline_share") == \
+    assert read("kernels.paged_attention_roofline_share") == \
         pytest.approx(100 * (8192 * 89_600 / 819e9) / 2e-3)
-    assert read("ssm.parallel_state_share_of_decode_bytes") == \
+    assert read("ssm.state_share_of_decode_bytes") == \
         pytest.approx(100 * state / floor)
     assert read("serve_programs.parallel_mixers_share_of_decode_step") == \
         pytest.approx(100 * 1.04 / 1.6)
     assert read("serve_programs.parallel_head_ms_per_decode_step") == \
         pytest.approx(4.0)
-    assert read("engine.prefill_ride_token_share.chat-batch") == \
+    assert read("engine.prefill_ride_token_share") == \
         pytest.approx(100 * 6000 / 6144)
     for name in NEW_METRICS:
         if "roofline" in name:
@@ -318,7 +328,7 @@ def test_parallel_readers_say_nothing_where_there_is_nothing_to_read():
     for half in old["stats"].values():
         del half["prefill_ride_tokens"]
     assert layer_metrics.load(
-        "engine.prefill_ride_token_share.chat-batch").read(old) is None
+        "engine.prefill_ride_token_share").read(old) is None
 
 
 def test_an_operation_counts_under_every_scope_it_lies_in():
@@ -360,9 +370,10 @@ def rehearsal(tmp_path_factory):
     path = tmp_path_factory.mktemp("parallel") / "mix.json"
     path.write_text(json.dumps(TINY_TRAFFIC))
     try:
-        return parallel_runner.run(
+        run = parallel_runner.run(
             {"name": "tiny.mix", "chips": 1}, TINY, str(path), 3000000019,
             4.0, False, time.monotonic(), require_tpu=False)
+        return dict(run, runner="parallel")     # as run.py stamps it
     finally:
         platform.enable_compile_cache = held
 
@@ -385,7 +396,7 @@ def test_parallel_runner_rehearsal(rehearsal):
     traced = result_line(run, load_cell(CELL, MANIFEST)["per_layer"],
                          layer_metrics.load, traced=True)
     assert {"engine.decode_slot_utilization",
-            "engine.prefill_ride_token_share.chat-batch"} <= set(
+            "engine.prefill_ride_token_share"} <= set(
                 traced["metrics"])
     assert not {n for n in NEW_METRICS if n.startswith(
         ("kernels.", "serve_programs."))} & set(traced["metrics"])

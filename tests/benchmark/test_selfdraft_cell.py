@@ -13,6 +13,7 @@ from benchmark import (end_to_end, flops_selfdraft, layer_metrics,
                        loadgen_docqa)
 from benchmark.run import load_cell, result_line
 from benchmark.runners import selfdraft as runner
+from manifest_pins import assert_lists
 
 ROOT = Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -26,14 +27,14 @@ NEW_METRICS = (
     "selfdraft.accepted_draft_share", "selfdraft.tokens_per_slot_step",
     "serve_programs.selfdraft_step_device_ms",
     "serve_programs.selfdraft_draft_share_of_step",
-    "serve_programs.selfdraft_decode_hbm_roofline_share",
+    "serve_programs.decode_hbm_roofline_share",
     "kernels.selfdraft_mla_attention_ms_per_step",
-    "kernels.selfdraft_mla_attention_roofline_share",
-    "kernels.selfdraft_mla_live_page_share",
+    "kernels.mla_attention_roofline_share",
+    "kernels.mla_live_page_share",
     "kernels.selfdraft_moe_gmm_ms_per_step",
-    "kernels.selfdraft_moe_gmm_hbm_roofline_share",
-    "moe.selfdraft_held_experts_hit_share",
-    "kv.selfdraft_prefix_cached_token_share")
+    "kernels.moe_gmm_hbm_roofline_share",
+    "moe.held_experts_hit_share",
+    "kv.prefix_cached_token_share")
 # (NOT the nine of PR 51, ``engine.slot_steps.*``, ``engine.wall_ms_...``,
 # ``engine.ledger_tokens_per_s``, ``engine.seat_to_first_token_mean_ms``,
 # ``engine.starved_ms_per_decode_step.*``: ``test_slot_step_metrics.py`` pins
@@ -67,9 +68,11 @@ def test_the_cell_and_its_configuration_are_in_the_manifest_by_name():
 
 @pytest.mark.parametrize("name", NEW_METRICS)
 def test_each_new_metric_lists_the_cell_and_has_a_reader(name):
-    metric = {m["name"]: m for m in MANIFEST["per_layer"]}[name]
-    assert CELL in metric["workloads"]
-    assert metric["moves"] == "serve_tokens_per_s"
+    metric = assert_lists(name, CELL)
+    # (the whole step's share moves ``tpot_p95_ms`` since PR 59: the chat
+    # cell, which reports no tokens per second, is in its list)
+    assert metric["moves"] == ("serve_tokens_per_s" if "mistral-7b-16l.chat"
+                               not in metric["workloads"] else "tpot_p95_ms")
     if "roofline" in name:
         assert metric["unit"] == "%" and metric["source"] == "device_trace"
     assert callable(layer_metrics.load(name).read)
@@ -77,8 +80,7 @@ def test_each_new_metric_lists_the_cell_and_has_a_reader(name):
 
 @pytest.mark.parametrize("name", SHARED_METRICS)
 def test_the_serving_cells_shared_metrics_list_the_cell(name):
-    metric = {m["name"]: m for m in MANIFEST["per_layer"]}[name]
-    assert CELL in metric["workloads"]
+    assert_lists(name, CELL)
 
 
 # -- the configuration -----------------------------------------------------------
@@ -178,7 +180,7 @@ def _run(mtp=True):
         **({"mtp": {"refused": {"riding": 0}}} if mtp else {}),
     } for n, side in ((1, "before"), (2, "after"))}
     return {"stats": stats, "trace_stats": stats, "config": CONFIG,
-            "device": {"kind": "TPU v5 lite"},
+            "device": {"kind": "TPU v5 lite"}, "runner": "selfdraft",
             "serve_cfg": {"decode_steps_per_dispatch": 8},
             "trace": {"programs": {"decode": (5, 1.2)}, "decode_scope_s": {
                 "mla_paged_attention_mq": (360, 0.4), "moe_gmm": (960, 0.48),
@@ -196,12 +198,12 @@ def test_the_readers_read_the_counters_and_the_decode_programs_scopes():
     assert read["kernels.selfdraft_moe_gmm_ms_per_step"] == 12.0
     assert abs(read["serve_programs.selfdraft_draft_share_of_step"]
                - 100 * 0.16 / 1.2) < 1e-9
-    assert abs(read["kv.selfdraft_prefix_cached_token_share"]
+    assert abs(read["kv.prefix_cached_token_share"]
                - 100 * 9000 / 9300) < 1e-9
     # 1,000 live pages a dispatch of 8 steps... the counter is a DISPATCH's
     pages = 1000 / (40 / 8)
     floor = 9 * pages * 256 * 640 * 2 / 819e9
-    assert abs(read["kernels.selfdraft_mla_attention_roofline_share"]
+    assert abs(read["kernels.mla_attention_roofline_share"]
                - 100 * floor / 0.010) < 1e-6
     for name in NEW_METRICS:
         if "roofline" in name:
@@ -262,6 +264,7 @@ def test_selfdraft_runner_rehearsal(tmp_path, monkeypatch):
     run = runner.run({"name": "tiny.mix", "chips": 1}, _tiny(), str(path),
                      3000000019, 4.0, False, time.monotonic(),
                      require_tpu=False)
+    run["runner"] = "selfdraft"     # as run.py stamps it
     spec = load_cell(CELL, MANIFEST)
     line = result_line(run, spec["end_to_end"], end_to_end.load, False)
     assert line["correct"] and line["failed"] == 0 and line["attempted"] >= 1
@@ -276,12 +279,12 @@ def test_selfdraft_runner_rehearsal(tmp_path, monkeypatch):
     traced = result_line(run, spec["per_layer"], layer_metrics.load, True)
     assert {"selfdraft.accepted_draft_share",
             "selfdraft.tokens_per_slot_step",
-            "kernels.selfdraft_mla_live_page_share",
-            "moe.selfdraft_held_experts_hit_share",
-            "kv.selfdraft_prefix_cached_token_share",
+            "kernels.mla_live_page_share",
+            "moe.held_experts_hit_share",
+            "kv.prefix_cached_token_share",
             "engine.decode_slot_utilization",
             "engine.device_starved_share"} <= set(traced["metrics"])
-    assert traced["metrics"]["kv.selfdraft_prefix_cached_token_share"][
+    assert traced["metrics"]["kv.prefix_cached_token_share"][
         "value"] > 60
     assert 1.0 <= traced["metrics"]["selfdraft.tokens_per_slot_step"][
         "value"] <= 2.0

@@ -13,6 +13,7 @@ from benchmark import (end_to_end, flops, flops_sessions, layer_metrics,
                        loadgen_sessions, sessions_counters)
 from benchmark.run import load_cell, result_line
 from benchmark.runners import sessions as runner
+from manifest_pins import assert_lists
 
 ROOT = Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -27,14 +28,14 @@ NEW_METRICS = {
     "kv.state_snapshot_token_share": "%",
     "kv.state_snapshot_miss_share": "%",
     "kv.state_snapshot_copy_ms_per_decode_step": "ms",
-    "kernels.sessions_kda_decode_ms_per_decode_step": "ms",
-    "kernels.sessions_kda_decode_hbm_roofline_share": "%",
-    "kernels.sessions_paged_attention_ms_per_decode_step": "ms",
-    "kernels.sessions_paged_attention_roofline_share": "%",
-    "kernels.sessions_moe_gmm_hbm_roofline_share": "%",
-    "moe.sessions_held_experts_hit_share": "%",
-    "serve_programs.sessions_decode_hbm_roofline_share": "%",
-    "engine.prefill_ride_token_share.sessions": "%"}
+    "kernels.kda_decode_ms_per_decode_step": "ms",
+    "kernels.kda_decode_hbm_roofline_share": "%",
+    "kernels.paged_attention_ms_per_decode_step": "ms",
+    "kernels.paged_attention_roofline_share": "%",
+    "kernels.moe_gmm_hbm_roofline_share": "%",
+    "moe.held_experts_hit_share": "%",
+    "serve_programs.decode_hbm_roofline_share": "%",
+    "engine.prefill_ride_token_share": "%"}
 LISTED = (
     "engine.decode_slot_utilization", "serve_programs.decode_step_device_ms",
     "device_idle.serve", "engine.host_ms_per_decode_step",
@@ -66,11 +67,11 @@ def test_the_cell_and_its_configuration_are_in_the_manifest_by_name():
 
 
 @pytest.mark.parametrize("name", sorted(NEW_METRICS))
-def test_each_new_metric_lists_this_cell_alone_and_has_a_reader(name):
-    metric = {m["name"]: m for m in MANIFEST["per_layer"]}[name]
-    assert metric["workloads"] == [CELL]
-    assert (metric["unit"], metric["moves"]) == (NEW_METRICS[name],
-                                                 "serve_tokens_per_s")
+def test_each_new_metric_lists_this_cell_and_has_a_reader(name):
+    metric = assert_lists(name, CELL, unit=NEW_METRICS[name])
+    if name.startswith("kv.state_snapshot"):    # the mechanism's own
+        assert metric["workloads"] == [CELL]
+        assert metric["moves"] == "serve_tokens_per_s"
     assert callable(layer_metrics.load(name).read)
     layers = {m["layer"] for m in MANIFEST["per_layer"]
               if m["name"] not in NEW_METRICS}
@@ -80,8 +81,7 @@ def test_each_new_metric_lists_this_cell_alone_and_has_a_reader(name):
 @pytest.mark.parametrize("name", LISTED)
 def test_the_accepted_readers_that_read_this_program_rightly_list_the_cell(
         name):
-    metric = {m["name"]: m for m in MANIFEST["per_layer"]}[name]
-    assert CELL in metric["workloads"]
+    assert_lists(name, CELL)
 
 
 def test_what_the_cell_lists_moves_a_metric_the_cell_reports():
@@ -93,23 +93,26 @@ def test_what_the_cell_lists_moves_a_metric_the_cell_reports():
 
 def test_accepted_readers_that_would_miscount_this_model_do_not_list_it():
     """``serve_programs.prefill_device_ms_per_ktok`` reads nothing in a
-    riding cell (the ledger's standing note at PR 45); ``flops_linear``
-    reads the ``kimi_linear`` layer lists, ``flops_hybrid`` the
-    ``nemotron_h`` keys, the ``mla`` and ``latent`` readers a latent pool,
-    and ``kernels.paged_attention_ms_per_decode_step`` the ten longest
-    operations, among which this model's one softmax layer's kernel is not:
-    none of their metrics may list this cell."""
+    riding cell (the ledger's standing note since PR 45); the ``mla``
+    readers want a latent pool, the ``ssm`` ones a state-space mixer, and
+    the window-wide routing shares divide by ``num_hidden_layers``: none of
+    their metrics may list this cell. What the un-prefixed entries measure
+    is read for this cell through ``families/sessions.py`` since PR 59: the
+    page kernel by scope (the ten longest operations do not hold the one
+    softmax layer's kernel), the state update with its operands' bytes."""
     for metric in MANIFEST["per_layer"]:
         if metric["name"].startswith((
-                "serve_programs.prefill_device", "kernels.paged_attention",
-                "kernels.moe_gmm", "kernels.hybrid", "kernels.ssm",
-                "kernels.latent", "kernels.linear", "kernels.kda_", "ssm.",
-                "kda.", "kernels.mla_", "kv.latent", "kv.prefix",
-                "residual.", "moe.experts", "moe.expert_load", "moe.linear",
-                "serve_programs.decode_hbm", "serve_programs.moe_decode",
-                "serve_programs.hybrid", "serve_programs.latent",
-                "serve_programs.linear", "diffusion.")):
+                "serve_programs.prefill_device", "kernels.moe_gmm_ms",
+                "kernels.ssm", "ssm.", "kda.", "kernels.mla_", "kv.latent",
+                "kv.prefix", "residual.", "moe.experts", "moe.expert_load",
+                "diffusion.")):
             assert CELL not in metric.get("workloads", []), metric["name"]
+    from benchmark import families
+    sessions, linear = families.load("sessions"), families.load("linear")
+    assert (sessions.kda_decode_hbm_roofline_share
+            is not linear.kda_decode_hbm_roofline_share)
+    assert (sessions.paged_attention_ms_per_decode_step is not families.load(
+        "serve").paged_attention_ms_per_decode_step)
 
 
 # -- the configuration -----------------------------------------------------------
@@ -282,6 +285,7 @@ def _run(**trace):
                      "snapshot_tokens_skipped": 1000 + 55_000}}
     stats = {"before": before, "after": after}
     return {"config": CONFIG, "device": {"kind": "TPU v5e"},
+            "runner": "sessions",
             "serve_cfg": {"decode_steps_per_dispatch": 8,
                           "max_batch_size": 64},
             "stats": stats, "trace_stats": stats,
@@ -302,7 +306,7 @@ def test_the_readers_compute_what_they_say_by_hand():
                       100 * 55_000 / 62_500)
     assert np.isclose(read("kv.state_snapshot_miss_share"), 10.0)
     assert np.isclose(read("kv.state_snapshot_copy_ms_per_decode_step"), 0.03)
-    assert np.isclose(read("kernels.sessions_kda_decode_ms_per_decode_step"),
+    assert np.isclose(read("kernels.kda_decode_ms_per_decode_step"),
                       3.0)
     assert sessions_counters.live_slots_per_step(run) == 60
     assert sessions_counters.decode_experts_hit_per_step(run) == 120
@@ -311,24 +315,24 @@ def test_the_readers_compute_what_they_say_by_hand():
     # operands = 1.60 GB at 819 GB/s = 1.95 ms
     state = 2 * 3 * 60 * 4_341_760
     operands = 3 * 60 * 6 * 8192 * 4
-    assert np.isclose(read("kernels.sessions_kda_decode_hbm_roofline_share"),
+    assert np.isclose(read("kernels.kda_decode_hbm_roofline_share"),
                       100 * ((state + operands) / 819e9) / 0.0030)
     # the one softmax layer: 384,000 live rows x 4,096 B = 1.92 ms
     assert np.isclose(
-        read("kernels.sessions_paged_attention_ms_per_decode_step"), 2.5)
-    assert np.isclose(read("kernels.sessions_paged_attention_roofline_share"),
+        read("kernels.paged_attention_ms_per_decode_step"), 2.5)
+    assert np.isclose(read("kernels.paged_attention_roofline_share"),
                       100 * (384_000 * 4096 / 819e9) / 0.0025)
     # the grouped matmuls: 120 hit (layer, expert) pairs x 31.46 MB a step
-    assert np.isclose(read("kernels.sessions_moe_gmm_hbm_roofline_share"),
+    assert np.isclose(read("kernels.moe_gmm_hbm_roofline_share"),
                       100 * (120 * 31_457_280 / 819e9) / 0.0060)
     # 30 of the 40 held experts hit a layer a step
-    assert np.isclose(read("moe.sessions_held_experts_hit_share"), 75.0)
+    assert np.isclose(read("moe.held_experts_hit_share"), 75.0)
     total = (flops_sessions.once_a_step_weight_bytes(CONFIG)
              + 120 * 31_457_280 + state + 384_000 * 4096)
     assert np.isclose(
-        read("serve_programs.sessions_decode_hbm_roofline_share"),
+        read("serve_programs.decode_hbm_roofline_share"),
         100 * (total / 819e9) / 0.016)
-    assert np.isclose(read("engine.prefill_ride_token_share.sessions"), 80.0)
+    assert np.isclose(read("engine.prefill_ride_token_share"), 80.0)
     assert flops.peaks("TPU v5e")["hbm_bytes_per_s"] == 819e9
     for name in NEW_METRICS:
         if NEW_METRICS[name] == "%":
@@ -352,7 +356,7 @@ def test_a_program_without_the_spans_or_counters_reads_nothing():
             assert layer_metrics.load(name).read(run) is None, name
     run["trace"] = {}                         # an untraced run
     assert layer_metrics.load(
-        "serve_programs.sessions_decode_hbm_roofline_share").read(run) is None
+        "serve_programs.decode_hbm_roofline_share").read(run) is None
     del run["stats"]["after"]["kda"]["snapshot_hits"]   # a K model, no pool
     assert layer_metrics.load(
         "kv.state_snapshot_miss_share").read(run) is None
@@ -399,6 +403,7 @@ def test_sessions_runner_rehearsal(tmp_path, monkeypatch):
     run = runner.run({"name": "tiny.mix", "chips": 1}, _tiny(), str(path),
                      3000000019, 4.0, False, time.monotonic(),
                      require_tpu=False)
+    run["runner"] = "sessions"      # as run.py stamps it
     spec = load_cell(CELL, MANIFEST)
     line = result_line(run, spec["end_to_end"], end_to_end.load, False)
     assert line["correct"] and line["failed"] == 0 and line["attempted"] >= 8
@@ -421,11 +426,11 @@ def test_sessions_runner_rehearsal(tmp_path, monkeypatch):
     assert max(r["turn"] for r in records) >= 2
     traced = result_line(run, spec["per_layer"], layer_metrics.load, True)
     assert {"kv.state_snapshot_token_share", "kv.state_snapshot_miss_share",
-            "moe.sessions_held_experts_hit_share",
-            "engine.prefill_ride_token_share.sessions",
+            "moe.held_experts_hit_share",
+            "engine.prefill_ride_token_share",
             "engine.decode_slot_utilization", "moe.held_choice_share"
             } <= set(traced["metrics"])
-    assert not {"kernels.sessions_kda_decode_ms_per_decode_step",
+    assert not {"kernels.kda_decode_ms_per_decode_step",
                 "kv.state_snapshot_copy_ms_per_decode_step",
                 "device_idle.serve"} & set(traced["metrics"])
     assert traced["metrics"]["kv.state_snapshot_token_share"]["value"] > 50

@@ -3,16 +3,12 @@ runs: what they compute, and that a run of a program without the keys (every
 commit before the PR) reads as None and the metric is left out of the line.
 """
 
-import json
-from pathlib import Path
-
 import pytest
 
 from benchmark import layer_metrics, span_counters
-from benchmark.run import load_cell, result_line
+from benchmark.run import result_line
+from manifest_pins import MANIFEST, assert_lists, entry, listed_by
 
-ROOT = Path(__file__).resolve().parents[2]
-MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
 NEW = ["scheduler.queue_wait_p95_ms", "engine.host_ms_per_decode_step",
        "engine.prefill_stall_ms_per_decode_step",
        "engine.device_starved_share",
@@ -37,6 +33,7 @@ def run_with(before, after, trace=None) -> dict:
     return {"stats": {"before": before, "after": after},
             "trace_stats": {"before": before, "after": after},
             "trace": trace or {},
+            "runner": "serve",      # whose family file the merged readers ask
             "serve_cfg": {"decode_steps_per_dispatch": 8,
                           "max_batch_size": 32}}
 
@@ -125,16 +122,20 @@ def test_no_steps_or_no_clock_reads_as_nothing():
     ("mistral-7b-16l.batch-64", NEW[1:]),
     ("internlm2-1.8b-6l.pretrain-4k", []),
 ])
-def test_new_entries_are_at_the_end_and_name_layers_that_exist(cell, names):
-    per_layer = [m["name"] for m in MANIFEST["per_layer"]]
-    assert per_layer[-len(NEW):] == NEW
-    old_layers = {m["layer"] for m in MANIFEST["per_layer"][:-len(NEW)]}
-    for m in MANIFEST["per_layer"][-len(NEW):]:
-        assert m["layer"] in old_layers and m["moves"] == "tpot_p95_ms"
+def test_the_entries_by_name_list_the_cells_and_name_layers_that_exist(
+        cell, names):
+    """By name and by membership (PR 59): where an entry stands in the list,
+    and which later cells joined it, is nobody's pin."""
+    others = {m["layer"] for m in MANIFEST["per_layer"]
+              if m["name"] not in NEW}
+    for name in NEW:
+        m = entry(name)
+        assert m["layer"] in others and m["moves"] == "tpot_p95_ms"
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    reported = [m["name"] for m in load_cell(cell, MANIFEST)["per_layer"]]
-    assert [n for n in reported if n in NEW] == names
+    for name in names:
+        assert_lists(name, cell)
+    assert listed_by(cell) & set(NEW) == set(names)
 
 
 def test_result_line_leaves_out_what_the_parent_cannot_report():
