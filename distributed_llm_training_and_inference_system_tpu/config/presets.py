@@ -470,6 +470,41 @@ LFM2_TEST_PUBLISHED = {
 }
 TEST_TEMPLATES["lfm2-test"] = ModelConfig.from_published(LFM2_TEST_PUBLISHED)
 
+# Ouro-2.6B as published (``model_type: ouro``, a looped language model): 48
+# layers of hidden 2,048, 16 heads of 128 with as many K/V heads, a SwiGLU of
+# 5,632, an untied head over 49,152, rope base 1e6, walked ``total_ut_steps``
+# 4 times over ONE set of weights. The type brings what the file has no key
+# for: four norms a layer (the second of each pair on the sub-layer's output,
+# inside the residual), the final norm after EVERY pass (its output is what
+# the next pass reads), an exit gate on each pass's normed state, K and V
+# kept per (pass, layer). ``early_exit_threshold`` 1: every token runs every
+# pass (a threshold below 1 is refused at load)
+OURO_2_6B_PUBLISHED = {
+    "name": "ouro-2.6b", "model_type": "ouro", "head_dim": 128,
+    "hidden_act": "silu", "hidden_size": 2048, "intermediate_size": 5632,
+    "layer_types": ["full_attention"] * 48,
+    "max_position_embeddings": 65536, "max_window_layers": 48,
+    "num_attention_heads": 16, "num_hidden_layers": 48,
+    "num_key_value_heads": 16, "rms_norm_eps": 1e-6, "rope_scaling": None,
+    "rope_theta": 1000000, "sliding_window": None,
+    "tie_word_embeddings": False, "total_ut_steps": 4,
+    "early_exit_threshold": 1, "use_sliding_window": False,
+    "vocab_size": 49152,
+}
+MODEL_TEMPLATES["ouro-2.6b"] = ModelConfig.from_published(OURO_2_6B_PUBLISHED)
+
+# ... and its shape in small, in the same keys: 2 passes over 3 layers of
+# hidden 128 (6 planes a pool)
+OURO_TEST_PUBLISHED = {
+    **OURO_2_6B_PUBLISHED,
+    "name": "ouro-test", "hidden_size": 128, "intermediate_size": 256,
+    "num_hidden_layers": 3, "layer_types": ["full_attention"] * 3,
+    "max_window_layers": 3, "num_attention_heads": 2,
+    "num_key_value_heads": 2, "head_dim": 64, "total_ut_steps": 2,
+    "vocab_size": 320, "max_position_embeddings": 512, "dtype": "float32",
+}
+TEST_TEMPLATES["ouro-test"] = ModelConfig.from_published(OURO_TEST_PUBLISHED)
+
 
 def get_model_config(name: str) -> ModelConfig:
     """Look up a template by name (also accepts test templates), or read a

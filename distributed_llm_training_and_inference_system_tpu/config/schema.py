@@ -559,6 +559,25 @@ class ModelConfig:
     # of ``ServeConfig.speculative: mtp`` (serve/decode.py
     # ``draft_verify_scan``)
     mtp_layers: int = 0
+    # a LOOPED stack (``model_type: ouro``): the uniform stack is walked
+    # ``num_passes`` times (the published ``total_ut_steps``) over ONE set
+    # of weights; the final norm closes every pass and its output is what
+    # the next pass reads; an exit gate (one output, a bias) reads each
+    # pass's normed state; K and V are kept per (pass, layer), so a pool has
+    # ``num_passes x num_layers`` planes (``kv_layers``), pass t's layer l
+    # at ``t * num_layers + l``. A token leaves at the first pass where the
+    # exit distribution's running sum reaches ``exit_threshold`` (the
+    # published ``early_exit_threshold``; 1: after the last pass)
+    num_passes: int = 1
+    exit_threshold: float = 1.0
+    # a second RMSNorm on the attention's and the feed-forward's OUTPUT,
+    # before the residual takes it (``ouro``'s ``input_layernorm_2`` and
+    # ``post_attention_layernorm_2``)
+    sandwich_norm: bool = False
+
+    @property
+    def is_looped(self) -> bool:
+        return self.num_passes > 1
 
     @property
     def is_moe(self) -> bool:
@@ -605,9 +624,9 @@ class ModelConfig:
     def kv_layers(self) -> int:
         """Layers that keep K and V: the attention layers of a table (a
         ``P`` layer is one, and a state-space layer too), every layer of a
-        uniform stack."""
+        uniform stack, once a pass of a looped one."""
         if not self.layer_pattern:
-            return self.num_layers
+            return self.num_passes * self.num_layers
         return self.layers_of("*") + self.layers_of("P") + self.mtp_layers
 
     @property
@@ -760,6 +779,26 @@ class ModelConfig:
                 f"moe: router_score softmax|sigmoid, and the held experts "
                 f"{m.first_expert}..<{m.first_expert + m.num_experts} must "
                 f"lie inside the router's {m.router_width} (got {m})")
+        if self.num_passes < 1:
+            raise ConfigError(
+                f"total_ut_steps = {self.num_passes}: a stack is walked at "
+                "least once")
+        if self.is_looped or self.sandwich_norm:
+            if self.layer_pattern or self.is_moe or self.is_diffusion:
+                raise ConfigError(
+                    "a looped stack (total_ut_steps > 1) and sandwich norms "
+                    "are carried by the dense uniform stack: a layer table's "
+                    "pools are addressed by the kind's layer alone, the "
+                    "expert statistics count a layer once, and a denoise "
+                    "window has no pass")
+            if self.exit_threshold != 1.0:
+                raise ConfigError(
+                    f"early_exit_threshold = {self.exit_threshold}: a "
+                    "threshold below 1 is refused (a token that leaves at "
+                    "pass t writes no K/V in the planes of passes t+1.."
+                    f"{self.num_passes}, and which rows later tokens then "
+                    "attend there is a rule the published config does not "
+                    "give): state 1, every token runs every pass")
         if self.arch != "decoder-only":
             raise ConfigError(f"unsupported arch {self.arch!r} (decoder-only only)")
         if self.qk_norm not in ("none", "projection", "head"):
@@ -857,7 +896,7 @@ class ModelConfig:
             mlp = self.moe.num_experts * mlp_dense + h * self.moe.num_experts
         else:
             mlp = mlp_dense
-        norms = 2 * h
+        norms = (4 if self.sandwich_norm else 2) * h
         if self.qk_norm == "projection":
             norms += q_dim + kv_dim
         elif self.qk_norm == "head":
@@ -866,7 +905,9 @@ class ModelConfig:
         emb = v * h
         head = 0 if self.tie_word_embeddings else v * h
         final_norm = h
-        return emb + self.num_layers * per_layer + final_norm + head
+        # the exit gate: one output and its bias
+        gate = h + 1 if self.is_looped else 0
+        return emb + self.num_layers * per_layer + final_norm + gate + head
 
     @classmethod
     def from_dict(cls, d: dict[str, Any]) -> "ModelConfig":
@@ -979,6 +1020,7 @@ class ModelConfig:
                     f"{key} = {d[key]}: group-limited routing is not "
                     "carried (the router takes its top-k over all experts)")
         sdar = d.get("model_type") == "sdar_moe"
+        ouro = d.get("model_type") == "ouro"
         if int(d.get("decoder_sparse_step", 1)) != 1:
             raise ConfigError(
                 f"decoder_sparse_step = {d['decoder_sparse_step']}: every "
@@ -1064,6 +1106,13 @@ class ModelConfig:
                                      "mhc_h_res_clamp_max", default=30.0)),
             mup=MupConfig.from_dict(d.get("mup"), published=d),
             mtp_layers=mtp,
+            num_passes=int(_take(d, "num_passes", "total_ut_steps",
+                                 default=1)),
+            exit_threshold=float(_take(d, "exit_threshold",
+                                       "early_exit_threshold", default=1.0)),
+            # (``ouro``'s config.json has no key for its four norms a layer)
+            sandwich_norm=_parse_bool("sandwich_norm", _take(
+                d, "sandwich_norm", default=ouro)),
             position_embedding=str(_take(
                 d, "position_embedding", default=(
                     "none" if latent and d.get("mla_use_nope")

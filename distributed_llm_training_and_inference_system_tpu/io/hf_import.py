@@ -21,6 +21,20 @@ split-half rotate):
   model.norm.weight                    -> final_norm.scale
   lm_head.weight (HF [V,H])            -> lm_head.kernel [H,V] (absent when
                                           tied: embed is reused)
+
+A norm's HF ``weight`` w multiplies the normed value; this program's norm
+multiplies by ``1 + scale``, so every norm comes in as ``scale = w - 1``.
+
+``model_type: ouro`` (a looped stack with sandwich norms,
+``cfg.sandwich_norm`` / ``cfg.is_looped``) adds
+
+  model.layers.{i}.input_layernorm_2          -> blocks.attn_out_norm.scale[i]
+  model.layers.{i}.post_attention_layernorm_2 -> blocks.mlp_out_norm.scale[i]
+  model.early_exit_gate.weight (HF [1,H])     -> exit_gate.kernel [H,1]
+  model.early_exit_gate.bias   [1]            -> exit_gate.bias
+
+(the names as ``benchmark/configs/ouro-2.6b.json`` lists them under
+``assumed``: the published ``modeling_ouro.py`` is not on this machine.)
 """
 
 from __future__ import annotations
@@ -91,11 +105,14 @@ def hf_llama_to_params(tensors: dict[str, np.ndarray],
             mats = [m.T for m in mats]
         return np.stack(mats)
 
+    def norm(name):
+        # HF multiplies by the weight, this program by 1 + scale
+        return {"scale": stack(f"model.layers.{{i}}.{name}.weight")
+                - np.asarray(1, dtype)}
+
     blocks = {
-        "attn_norm": {"scale": stack(
-            "model.layers.{i}.input_layernorm.weight")},
-        "mlp_norm": {"scale": stack(
-            "model.layers.{i}.post_attention_layernorm.weight")},
+        "attn_norm": norm("input_layernorm"),
+        "mlp_norm": norm("post_attention_layernorm"),
         "mlp": {
             "gate": {"kernel": stack(
                 "model.layers.{i}.mlp.gate_proj.weight", transpose=True)},
@@ -116,13 +133,22 @@ def hf_llama_to_params(tensors: dict[str, np.ndarray],
             blocks[name]["bias"] = stack(
                 f"model.layers.{{i}}.self_attn.{name}_proj.bias")
 
+    if cfg.sandwich_norm:
+        blocks["attn_out_norm"] = norm("input_layernorm_2")
+        blocks["mlp_out_norm"] = norm("post_attention_layernorm_2")
+
     params = {
         "embed": {"embedding": get("model.embed_tokens.weight")},
         "blocks": blocks,
-        "final_norm": {"scale": get("model.norm.weight")},
+        "final_norm": {"scale": get("model.norm.weight")
+                       - np.asarray(1, dtype)},
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = {"kernel": get("lm_head.weight").T}
+    if cfg.is_looped:
+        params["exit_gate"] = {
+            "kernel": get("model.early_exit_gate.weight").T,
+            "bias": get("model.early_exit_gate.bias")}
 
     # shape validation against the model config
     H, V = cfg.hidden_size, cfg.vocab_size

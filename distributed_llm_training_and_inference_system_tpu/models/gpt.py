@@ -21,7 +21,7 @@ configures (configs/models/llama-7b.json, init.py MODEL_TEMPLATES).
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +73,10 @@ def init(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
         if not cfg.tie_word_embeddings:
             params["lm_head"] = {"kernel": dense(
                 next(keys), H, V, scale=mup_std.get("lm_head", std))}
+        if cfg.is_looped:
+            # the exit gate on each pass's normed state: one output, a bias
+            params["exit_gate"] = {"kernel": dense(next(keys), H, 1),
+                                   "bias": jnp.zeros((1,), dtype)}
         return params
 
     if cfg.layer_pattern:
@@ -100,6 +104,9 @@ def init(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
         "o": {"kernel": dense(next(keys), L, Nq * D, H, scale=resid_std)},
         "mlp_norm": {"scale": norm_init(L, H)},
     }
+    if cfg.sandwich_norm:
+        blocks["attn_out_norm"] = {"scale": norm_init(L, H)}
+        blocks["mlp_out_norm"] = {"scale": norm_init(L, H)}
     if cfg.qk_norm == "projection":
         blocks["q_norm"] = {"scale": norm_init(L, Nq * D)}
         blocks["k_norm"] = {"scale": norm_init(L, Nkv * D)}
@@ -604,8 +611,18 @@ def unembed(params: Params, x: jax.Array, cfg: ModelConfig,
     Shared by the plain forward and the pipeline-parallel runner so the
     head semantics can never diverge between them.
     """
-    x = rms_norm(x, params["final_norm"]["scale"].astype(x.dtype),
-                 cfg.norm_eps, impl=norm_impl)
+    return head_logits(params, final_norm(params, x, cfg, norm_impl), cfg)
+
+
+def final_norm(params: Params, x: jax.Array, cfg: ModelConfig,
+               norm_impl: str = "xla") -> jax.Array:
+    return rms_norm(x, params["final_norm"]["scale"].astype(x.dtype),
+                    cfg.norm_eps, impl=norm_impl)
+
+
+def head_logits(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The LM head over a NORMED state (``unembed`` behind its norm; what a
+    looped stack's last pass leaves is normed already), fp32 output."""
     with jax.named_scope("lm_head"):
         if cfg.tie_word_embeddings:
             logits = jnp.einsum(
@@ -618,6 +635,54 @@ def unembed(params: Params, x: jax.Array, cfg: ModelConfig,
                 params["lm_head"]["kernel"].astype(x.dtype),
                 preferred_element_type=jnp.float32)
     return scaled(logits.astype(jnp.float32), cfg.mup.lm_head)
+
+
+class ExitState(NamedTuple):
+    """What a looped stack carries from pass to pass of a row's way out:
+    ``out`` the normed state of the pass the row LEFT at (what the head
+    reads), ``stay`` the product of (1 - g) over the passes so far, ``total``
+    the exit distribution's running sum, ``left`` whether the row has left."""
+    out: jax.Array      # [B, S, H]
+    stay: jax.Array     # [B, S] float32
+    total: jax.Array    # [B, S] float32
+    left: jax.Array     # [B, S] bool
+
+    @classmethod
+    def start(cls, x: jax.Array) -> "ExitState":
+        rows = x.shape[:2]
+        return cls(jnp.zeros_like(x), jnp.ones(rows, jnp.float32),
+                   jnp.zeros(rows, jnp.float32), jnp.zeros(rows, bool))
+
+
+def close_pass(params: Params, x: jax.Array, cfg: ModelConfig, t,
+               exits: ExitState, norm_impl: str = "xla"):
+    """What closes pass ``t`` (0-based; static or traced) of a looped stack
+    over its stream ``x`` [B, S, H]:
+
+        z_t = N_f(x)                      the ONE final norm, every pass
+        g_t = sigmoid(w_g . z_t + b_g)    the exit gate, float32
+        p_t = g_t prod_{j<t}(1 - g_j)     (the last pass takes what is left)
+
+    a row leaves at the first pass where the running sum of p reaches
+    ``cfg.exit_threshold`` (at the last pass every row that is still there),
+    and the head reads the z of the pass it left at. Every pass RUNS for
+    every row whatever the gate says (its K and V are written in every
+    plane): at the published threshold 1 that is the model, and a threshold
+    below 1 is refused at load. Returns (z_t, what the next pass reads;
+    g_t [B, S]; the new ``ExitState``)."""
+    z = final_norm(params, x, cfg, norm_impl)
+    with jax.named_scope("exit_gate"):
+        gate = params["exit_gate"]
+        g = jax.nn.sigmoid(
+            jnp.sum(z.astype(jnp.float32)
+                    * gate["kernel"][:, 0].astype(jnp.float32), axis=-1)
+            + gate["bias"].astype(jnp.float32)[0])
+        last = t == cfg.num_passes - 1
+        total = exits.total + jnp.where(last, exits.stay, g * exits.stay)
+        leaves = ~exits.left & ((total >= cfg.exit_threshold) | last)
+        exits = ExitState(jnp.where(leaves[..., None], z, exits.out),
+                          exits.stay * (1.0 - g), total, exits.left | leaves)
+    return z, g, exits
 
 
 def forward(
@@ -641,6 +706,7 @@ def forward(
     return_latent: bool = False,
     return_stream: bool = False,
     return_mtp: bool = False,
+    return_passes: bool = False,
 ):
     """Compute logits [B, S, V] (fp32) — or, with ``return_hidden=True``,
     the final-normed hidden states [B, S, H] in the compute dtype (consumed
@@ -690,6 +756,11 @@ def forward(
       with the embedding of ``tokens[i + 1]`` (the last row's wraps around
       and means nothing) and predicting token i + 2; the main stack's
       logits are what they are without it.
+    - a LOOPED stack (``cfg.num_passes`` > 1) walks the uniform stack's
+      scan once a pass inside a scan over the passes, ``close_pass``
+      between them; ``kv_cache`` is [passes x L, ...], pass t's layer l at
+      ``t * L + l``. ``return_passes`` appends (every pass's normed state
+      [passes, B, S, H], every pass's gate [passes, B, S] float32).
     """
     compute_dtype = jnp.dtype(cfg.dtype)
     B, S = tokens.shape
@@ -779,19 +850,20 @@ def forward(
         aux0 = jnp.float32(0.0)
     block = _remat_wrap(block, remat)
 
-    if kv_cache is None:
-        def body(carry, layer_and_index):
-            x, aux = carry
-            layer, li = layer_and_index
-            x, _, aux_l = block(x.astype(compute_dtype),
-                                _cast(layer, compute_dtype), positions,
-                                segment_ids, inv_freq, layer_index=li)
-            return (x, aux + aux_l), None
+    def walk(x, aux, cache):
+        """ONE walk of the L layers: (x, aux, that walk's planes of the
+        dense cache [L, ...] twice, or None)."""
+        if cache is None:
+            def body(carry, layer_and_index):
+                x, aux = carry
+                layer, li = layer_and_index
+                x, _, aux_l = block(x.astype(compute_dtype),
+                                    _cast(layer, compute_dtype), positions,
+                                    segment_ids, inv_freq, layer_index=li)
+                return (x, aux + aux_l), None
 
-        (x, aux_total), _ = jax.lax.scan(body, (x, aux0), (blocks, layer_ids))
-        new_cache = None
-    else:
-        k_cache, v_cache = kv_cache
+            (x, aux), _ = jax.lax.scan(body, (x, aux), (blocks, layer_ids))
+            return x, aux, None
 
         def body(carry, layer_and_cache):
             x, aux = carry
@@ -803,17 +875,48 @@ def forward(
                                      layer_index=li)
             return (x, aux + aux_l), new_kv
 
-        (x, aux_total), new_kvs = jax.lax.scan(
-            body, (x, aux0), (blocks, layer_ids, k_cache, v_cache))
-        new_cache = new_kvs
+        (x, aux), new_kvs = jax.lax.scan(
+            body, (x, aux), (blocks, layer_ids, *cache))
+        return x, aux, new_kvs
+
+    if return_passes and not cfg.is_looped:
+        raise ValueError("return_passes needs a looped stack "
+                         "(total_ut_steps > 1)")
+    passes = None
+    if cfg.is_looped:
+        # the stack's scan inside a scan over the passes: the same weights
+        # every pass, the cache's planes [T, L, ...] a pass at a time
+        T, L = cfg.num_passes, cfg.num_layers
+
+        def one_pass(carry, t_and_cache):
+            x, aux, exits = carry
+            t, cache = t_and_cache
+            with jax.named_scope("loop_pass"):
+                x, aux, new = walk(x, aux, cache)
+            z, g, exits = close_pass(params, x, cfg, t, exits, norm_impl)
+            return (z, aux, exits), (new, (z, g) if return_passes else None)
+
+        planes = (None if kv_cache is None else tuple(
+            c.reshape(T, L, *c.shape[1:]) for c in kv_cache))
+        (_, aux_total, exits), (new_cache, passes) = jax.lax.scan(
+            one_pass, (x, aux0, ExitState.start(x)),
+            (jnp.arange(T, dtype=jnp.int32), planes))
+        if new_cache is not None:
+            new_cache = tuple(c.reshape(T * L, *c.shape[2:])
+                              for c in new_cache)
+        x = exits.out           # NORMED: the head has no norm left to do
+    else:
+        x, aux_total, new_cache = walk(x, aux0, kv_cache)
 
     extras = []
     if kv_cache is not None:
         extras.append(new_cache)
     if return_aux or return_moe_stats:
         extras.append(aux_total)
+    if return_passes:
+        extras.append(passes)
     return _finish_forward(params, x, cfg, norm_impl, unembed_positions,
-                           return_hidden, extras)
+                           return_hidden, extras, normed=cfg.is_looped)
 
 
 def _walk_table(params, x, cfg: ModelConfig, positions, segment_ids,
@@ -866,19 +969,22 @@ def _walk_table(params, x, cfg: ModelConfig, positions, segment_ids,
 
 
 def _finish_forward(params, x, cfg: ModelConfig, norm_impl,
-                    unembed_positions, return_hidden, extras: list):
+                    unembed_positions, return_hidden, extras: list,
+                    normed: bool = False):
     """The head of ``forward``: logits (or the final-normed hidden states)
-    at all or one position a row, then ``extras`` in order."""
+    at all or one position a row, then ``extras`` in order. ``normed``: x
+    IS the final-normed state (a looped stack's)."""
     if unembed_positions is not None:
         x = jnp.take_along_axis(
             x, unembed_positions[:, None, None].astype(jnp.int32), axis=1)
+    if not normed:
+        x = final_norm(params, x, cfg, norm_impl)
     if return_hidden:
         # final-normed hidden [B,S,H] for chunked-loss consumers
         # (models.loss.chunked_next_token_loss) — skips the [S,V] unembed
-        out = rms_norm(x, params["final_norm"]["scale"].astype(x.dtype),
-                       cfg.norm_eps, impl=norm_impl)
+        out = x
     else:
-        out = unembed(params, x, cfg, norm_impl=norm_impl)
+        out = head_logits(params, x, cfg)
     result = [out, *extras]
     return tuple(result) if len(result) > 1 else result[0]
 
@@ -911,9 +1017,10 @@ def flops_per_token(cfg: ModelConfig, seq_len: int) -> float:
     else:
         ffn = 3 * H * F if cfg.activation in ("silu", "gelu") else 2 * H * F
     head = H * V
-    matmul_params = L * (attn_proj + ffn) + head
+    # (a looped stack multiplies by every layer's weights once a pass)
+    matmul_params = cfg.num_passes * L * (attn_proj + ffn) + head
     # fwd 2 flops/param/token, bwd 4
     dense_flops = 6.0 * matmul_params
     # attention scores+values: 2 * 2 * Nq * D * S per token fwd, x3 with bwd
-    attn_flops = 12.0 * L * Nq * D * seq_len
+    attn_flops = 12.0 * cfg.num_passes * L * Nq * D * seq_len
     return dense_flops + attn_flops

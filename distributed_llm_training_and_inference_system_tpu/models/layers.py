@@ -646,7 +646,10 @@ def decoder_block(
     ``moe_block_capacity``.
 
     ``kind`` None is a layer of the uniform stack: attention THEN
-    feed-forward under two norms (``attn_norm``, ``mlp_norm``). A layer of
+    feed-forward under two norms (``attn_norm``, ``mlp_norm``), and with
+    ``cfg.sandwich_norm`` a second norm on each sub-layer's OUTPUT before
+    the residual takes it (``attn_out_norm``, ``mlp_out_norm``:
+    ``x + N2(Attn(N1(x)))``). A layer of
     a layer table (``cfg.layer_pattern``) is ONE norm (``layer["norm"]``)
     and ONE mixer, chosen by ``kind``: ``*`` the same attention (or, for a
     model with latent attention, ``latent_attention_mixer``), ``E`` the
@@ -708,10 +711,18 @@ def decoder_block(
             return hc_write(x, out, maps), state, aux
         return x + out.astype(x.dtype), state, aux
 
+    def out_norm(out, name):
+        # the sandwich's second norm: on the sub-layer's OUTPUT, inside the
+        # residual
+        if not cfg.sandwich_norm:
+            return out
+        return rms_norm(out.astype(x.dtype), layer[name]["scale"],
+                        cfg.norm_eps, impl=norm_impl)
+
     h = rms_norm(x, layer["attn_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
     out, state = attention_mixer(h, layer, cfg, positions, inv_freq, attend,
                                  matmul)
-    x = x + out.astype(x.dtype)
+    x = x + out_norm(out, "attn_out_norm").astype(x.dtype)
 
     h = rms_norm(x, layer["mlp_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
     if cfg.is_moe:
@@ -719,7 +730,7 @@ def decoder_block(
                                  layer_index, matmul)
     else:
         ffn, aux = mlp_block(h, layer["mlp"], cfg, matmul=matmul), None
-    return x + ffn.astype(x.dtype), state, aux
+    return x + out_norm(ffn, "mlp_out_norm").astype(x.dtype), state, aux
 
 
 def attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,

@@ -51,6 +51,16 @@ def _constrain(x, spec):
         x, jax.sharding.NamedSharding(mesh, spec))
 
 
+def _refuse_looped(model_cfg: ModelConfig) -> None:
+    if model_cfg.is_looped:
+        raise ValueError(
+            f"{model_cfg.name} walks its stack {model_cfg.num_passes} times "
+            "(total_ut_steps): pipeline stages are refused for a looped "
+            "stack (a stage holds a slice of the layers and a micro-batch "
+            "would have to come back to stage 0 after every pass, through "
+            "the final norm and the exit gate: a schedule that is not here)")
+
+
 def make_pipeline_loss_fn(
     model_cfg: ModelConfig,
     par: ParallelConfig,
@@ -61,6 +71,7 @@ def make_pipeline_loss_fn(
     Plugs into exec.make_train_step(loss_fn=...) so the optimizer/clip/
     metrics path is shared with the non-pipelined step.
     """
+    _refuse_looped(model_cfg)
     pp = par.pipeline_parallel
     M = par.num_microbatches
     L = model_cfg.num_layers
@@ -206,6 +217,7 @@ def make_pipeline_grad_fn(
     Dense models only (MoE's aux-loss gradient path needs the autodiff
     schedule — ShardedTrainer falls back to GPipe for MoE).
     """
+    _refuse_looped(model_cfg)
     pp = par.pipeline_parallel
     M = par.num_microbatches
     L = model_cfg.num_layers

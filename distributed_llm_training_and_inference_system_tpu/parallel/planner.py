@@ -757,6 +757,19 @@ class ServePlanner:
         idle = m.moe.num_experts - chosen
         return m.param_count - m.moe_layers * idle * self._expert_params()
 
+    def pass_multiple(self) -> float:
+        """How many times over a token's forward reads (and multiplies by)
+        the weights, as a multiple of reading each once: 1 for a stack
+        walked once; a looped stack's layers are read once a PASS (4.93 GB
+        of layers do not stay on the chip between passes), its embedding,
+        head, final norm and gate once."""
+        m = self.model
+        if not m.is_looped:
+            return 1.0
+        once = (m.vocab_size * m.hidden_size
+                * (1 if m.tie_word_embeddings else 2) + 2 * m.hidden_size + 1)
+        return 1.0 + (m.num_passes - 1) * (1.0 - once / m.param_count)
+
     def moe_decode_weight_fraction(self, batch: int) -> float:
         """Share of the weights a decode step of ``batch`` live tokens
         reads: serving is dropless and the grouped matmul streams only the
@@ -821,6 +834,7 @@ class ServePlanner:
         bw = hw.hbm_bw_gbps * 1e9 * self.decode_efficiency
         # (and reads and writes every live slot's recurrent state)
         decode_s = (wb * self.moe_decode_weight_fraction(batch)
+                    * self.pass_multiple()
                     + kv_read + 2 * state) / max(bw, 1.0)
         if kv_quant in ("int8", "int4"):
             # int8 KV pages switch the page writes to the per-row scatter
@@ -846,7 +860,8 @@ class ServePlanner:
             overhead = max(1.0, 1.18 + 0.45 * (nkv_chip - 16) / 16)
             decode_s *= overhead
         # prefill: FLOPs-bound on this chip's share
-        flops = 2.0 * self.active_param_count() * prompt_len / tp
+        flops = (2.0 * self.active_param_count() * self.pass_multiple()
+                 * prompt_len / tp)
         prefill_s = flops / (hw.peak_bf16_tflops * 1e12 * self.mfu_prefill)
 
         return ServePlan(

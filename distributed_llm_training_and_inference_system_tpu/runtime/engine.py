@@ -47,6 +47,14 @@ class TrainingEngine:
                 "llmctl train is refused (there is no masked-diffusion loss "
                 "here, and the next-token loss under a causal mask would "
                 "train another model)")
+        if cfg.model.is_looped:
+            raise ValueError(
+                f"{cfg.model.name} walks its stack {cfg.model.num_passes} "
+                "times (total_ut_steps): llmctl train is refused (the "
+                "published objective weighs every pass's loss by the exit "
+                "distribution and adds an entropy term, which is not here; "
+                "the next-token loss of the last pass alone would train "
+                "another model)")
         devices = devices if devices is not None else platform.devices()
         self.par = infer_data_parallel(cfg.parallel, len(devices))
         self._start_step = 0

@@ -20,7 +20,10 @@ import jax.numpy as jnp
 
 from ..config.schema import ModelConfig
 from ..models.gpt import (
+    ExitState,
     cast_table_blocks,
+    close_pass,
+    head_logits,
     layer_experts,
     mtp_forward,
     mtp_head,
@@ -297,7 +300,10 @@ def extend_step_forward(
     T tokens' K/V are written into the pages first, then attention runs
     with per-query length ``start + j + 1``. The pools are the layer
     loop's carry, written and read at ``pages[layer, page]``: nothing
-    pool-sized is sliced out per layer or stacked back. This one
+    pool-sized is sliced out per layer or stacked back. A LOOPED stack
+    (``cfg.num_passes`` > 1) walks its layers once a pass inside a loop over
+    the passes, the pools the carry of both loops: layer l's weights, the
+    pools' plane ``pass * L + l``. This one
     primitive powers both speculative-decode verification
     (serve/speculative.py: score K draft tokens in one weight-streaming
     pass — decode is HBM-bound on weights, so T<=8 tokens cost nearly
@@ -563,40 +569,66 @@ def extend_step_forward(
             dict(zip(ssm_state, pools)) if ssm_state is not None else None)
         return StepWithStream(*step, x) if return_stream else step
 
-    def body(carry, layer_and_index):
-        # the pools must stay a CARRY that every layer writes and reads
-        # by its index: as scanned inputs and stacked outputs XLA slices
-        # each layer's slab out (94 MB at mistral-7b, 715 pages), writes
-        # it back, and copies the step's fresh pool whole
-        x, kp, vp, stats = carry
-        layer, li = layer_and_index
-        # per-layer cast/dequant: quantized serving weights either stay
-        # packed for the Pallas matmuls above (TPU) or materialise one
-        # layer of bf16 at a time (ops.quantization)
-        layer = cast_params(layer, compute_dtype, keep_w4=use_w4_kernel,
-                            keep_w8=use_w8_kernel)
-        moe_li = None
-        if cfg.is_moe:
-            # moe_block contracts expert weights directly (no matmul
-            # injection) — a passed-through Quant[4]Tensor would hit
-            # `a @ w` untyped; experts take the dequant path
-            layer = dict(layer, moe=cast_params(layer["moe"], compute_dtype))
-            layer, moe_li = layer_experts(layer, expert_stacks, li)
+    def walk(x, kp, vp, stats, first_plane=None):
+        """ONE walk of the L layers over the pools: layer l's weights, the
+        pools' plane ``first_plane + l`` (l itself where the stack is
+        walked once)."""
+        def body(carry, layer_and_index):
+            # the pools must stay a CARRY that every layer writes and reads
+            # by its index: as scanned inputs and stacked outputs XLA slices
+            # each layer's slab out (94 MB at mistral-7b, 715 pages), writes
+            # it back, and copies the step's fresh pool whole
+            x, kp, vp, stats = carry
+            layer, li = layer_and_index
+            plane = li if first_plane is None else first_plane + li
+            # per-layer cast/dequant: quantized serving weights either stay
+            # packed for the Pallas matmuls above (TPU) or materialise one
+            # layer of bf16 at a time (ops.quantization)
+            layer = cast_params(layer, compute_dtype, keep_w4=use_w4_kernel,
+                                keep_w8=use_w8_kernel)
+            moe_li = None
+            if cfg.is_moe:
+                # moe_block contracts expert weights directly (no matmul
+                # injection) — a passed-through Quant[4]Tensor would hit
+                # `a @ w` untyped; experts take the dequant path
+                layer = dict(layer, moe=cast_params(layer["moe"],
+                                                    compute_dtype))
+                layer, moe_li = layer_experts(layer, expert_stacks, li)
 
-        x, (kp, vp), layer_stats = decoder_block(
-            x, layer, cfg, positions, inv_freq, attend_pages(kp, vp, li),
-            matmul=mm, live=live, layer_index=moe_li)
-        if stats is not None:
-            stats = stats + layer_stats
-        return (x, kp, vp, stats), None
+            x, (kp, vp), layer_stats = decoder_block(
+                x, layer, cfg, positions, inv_freq,
+                attend_pages(kp, vp, plane), matmul=mm, live=live,
+                layer_index=moe_li)
+            if stats is not None:
+                stats = stats + layer_stats
+            return (x, kp, vp, stats), None
+
+        (x, kp, vp, stats), _ = jax.lax.scan(
+            body, (x, kp, vp, stats),
+            (blocks, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+        return x, kp, vp, stats
 
     stats0 = (jnp.zeros((cfg.moe.stats_size,), jnp.int32)
               if return_moe_stats else None)
-    (x, new_k, new_v, stats), _ = jax.lax.scan(
-        body, (x, k_pages, v_pages, stats0),
-        (blocks, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+    if not cfg.is_looped:
+        x, new_k, new_v, stats = walk(x, k_pages, v_pages, stats0)
+        return StepResult(unembed(params, head_rows(x), cfg), new_k, new_v,
+                          stats)
 
-    return StepResult(unembed(params, head_rows(x), cfg), new_k, new_v, stats)
+    # a LOOPED stack: the same walk once a pass, the pools the carry of both
+    # loops, pass t's planes t * L .. (t + 1) * L; ``close_pass`` norms the
+    # stream for the next pass and keeps what the head reads
+    def one_pass(t, carry):
+        x, kp, vp, exits = carry
+        with jax.named_scope("loop_pass"):
+            x, kp, vp, _ = walk(x, kp, vp, None, t * cfg.num_layers)
+        z, _, exits = close_pass(params, x, cfg, t, exits)
+        return z, kp, vp, exits
+    _, new_k, new_v, exits = jax.lax.fori_loop(
+        0, cfg.num_passes, one_pass,
+        (x, k_pages, v_pages, ExitState.start(x)))
+    return StepResult(head_logits(params, head_rows(exits.out), cfg),
+                      new_k, new_v, stats0)
 
 
 def _latent_windows(q_lat, rows, pool, tables, starts, ok, li, scale,

@@ -3483,6 +3483,16 @@ class InferenceEngine:
                 "block_length": self.cfg.diffusion.block_length,
                 "refused": dict(self.turned_off),
             }} if self.cfg.is_diffusion else {}),
+            # a looped stack: the passes a decode step's token ran (every
+            # one at the published exit threshold 1, which is all that
+            # loads: early exit would move the ratio) and the pools' planes
+            **({"loop": {
+                "passes": self.cfg.num_passes,
+                "pool_planes": self.cfg.kv_layers,
+                "decode_tokens": self.total_useful_slot_steps,
+                "decode_token_passes": (self.total_useful_slot_steps
+                                        * self.cfg.num_passes),
+            }} if self.cfg.is_looped else {}),
             **({"moe": {
                 "choices": self.moe_choices.tolist(),
                 # live choices on the experts HELD here, beside those over

@@ -126,6 +126,25 @@ REFUSED = {
         # OFF and counted an admission (``decode.can_carry``)
         "riding": "a piece wants a step of T = 1, its step is a window",
     },
+    # a looped stack (``total_ut_steps`` > 1): the pools' planes are passes
+    # x layers and every program addresses them so (``decode.
+    # extend_step_forward``), so prefix reuse, chunked and suffix prefill,
+    # riding, both kinds of preemption, the page payload (its planes are
+    # the pool's) and n-gram verification are what they are for any K/V
+    # model (tests/test_ouro.py runs each). What no test or cell has run
+    # over such a pool is refused by name
+    "looped": {
+        **dict.fromkeys(
+            ("fleet serving", "fleet prefix fetch"),
+            "no fleet test has moved pages of passes x layers planes "
+            "between replicas; ROADMAP B13"),
+        "kv_quantization": "no test or cell has run a quantised pool of "
+                           "passes x layers planes; ROADMAP B13",
+        "measure_device_times": "its probes have not been run over a "
+                                "looped stack's programs; the benchmark's "
+                                "serve_programs.decode_step_device_ms reads "
+                                "the step",
+    },
 }
 
 
@@ -149,6 +168,8 @@ def refused(cfg: ModelConfig, feature: str, snapshot_entries: int = 0
         "recurrent": cfg.is_recurrent and f"has {cfg.recurrent_name}",
         "latent": cfg.is_latent and "keeps latent pages",
         "diffusion": cfg.is_diffusion and "generates by diffusion over blocks",
+        "looped": cfg.is_looped
+        and f"walks its stack {cfg.num_passes} times",
     }
     for kind, is_a in kinds.items():
         if is_a and feature in REFUSED[kind]:

@@ -217,15 +217,21 @@ def params_to_hf_dict(params, cfg):
     """Write a native param tree under HF llama names (HF stores [out, in];
     bias rows emitted when cfg.attention_bias) — shared by the import
     round-trip tests."""
+    # (an HF norm's weight multiplies; the program's norm is 1 + scale)
     hf = {"model.embed_tokens.weight": np.asarray(
         params["embed"]["embedding"]),
-        "model.norm.weight": np.asarray(params["final_norm"]["scale"])}
+        "model.norm.weight": 1 + np.asarray(params["final_norm"]["scale"])}
     for i in range(cfg.num_layers):
         b = params["blocks"]
-        hf[f"model.layers.{i}.input_layernorm.weight"] = np.asarray(
+        hf[f"model.layers.{i}.input_layernorm.weight"] = 1 + np.asarray(
             b["attn_norm"]["scale"][i])
-        hf[f"model.layers.{i}.post_attention_layernorm.weight"] = np.asarray(
-            b["mlp_norm"]["scale"][i])
+        hf[f"model.layers.{i}.post_attention_layernorm.weight"] = \
+            1 + np.asarray(b["mlp_norm"]["scale"][i])
+        if cfg.sandwich_norm:
+            hf[f"model.layers.{i}.input_layernorm_2.weight"] = \
+                1 + np.asarray(b["attn_out_norm"]["scale"][i])
+            hf[f"model.layers.{i}.post_attention_layernorm_2.weight"] = \
+                1 + np.asarray(b["mlp_out_norm"]["scale"][i])
         for n in ("q", "k", "v", "o"):
             hf[f"model.layers.{i}.self_attn.{n}_proj.weight"] = np.asarray(
                 b[n]["kernel"][i]).T
@@ -238,6 +244,11 @@ def params_to_hf_dict(params, cfg):
                 b["mlp"][n]["kernel"][i]).T
     if not cfg.tie_word_embeddings:
         hf["lm_head.weight"] = np.asarray(params["lm_head"]["kernel"]).T
+    if cfg.is_looped:
+        hf["model.early_exit_gate.weight"] = np.asarray(
+            params["exit_gate"]["kernel"]).T
+        hf["model.early_exit_gate.bias"] = np.asarray(
+            params["exit_gate"]["bias"])
     return hf
 
 def test_hf_llama_import_roundtrip(tmp_path):
