@@ -264,4 +264,8 @@ class ShardCache:
             Path(str(self._dest(j)) + ".idx.json").unlink(missing_ok=True)
 
     def close(self) -> None:
-        self._pool.shutdown(wait=False, cancel_futures=True)
+        # what is queued is dropped; a download that is RUNNING is waited for,
+        # since its owner removes the directory next and a file written into
+        # it meanwhile leaves the directory behind (seen under six busy
+        # workers: PR 61)
+        self._pool.shutdown(wait=True, cancel_futures=True)

@@ -7,6 +7,7 @@ equivalent — its SLURM/MPI/torchrun paths are untested.
 """
 
 import contextlib
+import faulthandler
 import os
 import shutil
 import tempfile
@@ -65,6 +66,35 @@ def pytest_terminal_summary(terminalreporter):
         terminalreporter.write_line(line)
 
 
+# -- a limit a case -----------------------------------------------------------
+# The driver cuts the whole run at 1,470 s, and a run that is cut writes no
+# junit file and names nothing (PR 60's tree). So a case (set-up and tear-down
+# included) still running after CASE_LIMIT_S has every thread's stack written
+# to the run's stderr, the engine threads' too, and in an xdist worker the
+# worker exits: xdist prints "[gwN] node down", fails THAT case by name, starts
+# a fresh worker and deals the rest on. In one process the case goes on. One
+# timer a process, so pyproject's `faulthandler_timeout` stays unset.
+# The dearest tier-1 case under the driver's six workers is a described-TPU
+# compile in the run's last phase: 165 s (PR 61's run of PR 60's tree,
+# CHANGES.md). Three times that is over a quarter of the driver's clock, the
+# most one case may cost the run: 360 s. A case that cannot end inside it gets
+# no longer limit: it is too dear for tier 1.
+CASE_LIMIT_S = 360
+
+_STDERR = pytest.StashKey[int]()
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    faulthandler.dump_traceback_later(
+        CASE_LIMIT_S, exit=not _RUNS_THE_SESSION,
+        file=item.config.stash[_STDERR])
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
 @pytest.fixture(scope="module")
 def short_kda_chunks():
     """A chunk of 8 in place of ``ops/kda.py CHUNK`` = 64 for a module's
@@ -89,6 +119,11 @@ def devices8():
 # libtpu is loaded and the topology described INSIDE the module-scoped
 # fixture, when a test of those files first asks for it: never at import
 # (every xdist worker imports every test file and this one).
+# By name these files sort last: the run ENDS on their compiles, six abreast (a
+# third of its CPU time in 45 cases), and that is the cheapest place for them.
+# Measured (PR 61, CHANGES.md): spread through the run, one or two compiling
+# beside the other files' cases, every case slowed (1,281 s for 1,008); dealt
+# to the workers' first chunks, 1,227 s. Leave the order alone.
 
 @contextlib.contextmanager
 def _without_the_compile_cache():
@@ -147,14 +182,16 @@ def as_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 # -- slow-test marking --------------------------------------------------------
-# The rule: a case is listed here when it takes over ~60 s under the driver's
-# six workers AND a cheaper case that stays in tier 1 (`-m "not slow"`, the
-# driver's gate) asserts the same property; name that case beside it. The
-# members from before PR 53 were listed by duration alone (rounds 3 and 4,
-# >= ~6-12 s on the CI CPU of the time) and stay as they are. One central
-# list by test name (not per-file decorators), every name of which matches a
-# collected test (`pytest --collect-only -q -m slow`); five cases carry the
-# marker themselves, with their reason beside it (tests/test_models.py, the
+# The rule: a case that cannot end inside CASE_LIMIT_S under the driver's six
+# workers is not a tier-1 case, and none should take over a third of it. A
+# case is listed here when it takes over ~60 s under those workers AND a
+# cheaper case that stays in tier 1 (`-m "not slow"`, the driver's gate)
+# asserts the same property; name that case beside it. The members from
+# before PR 53 were listed by duration alone (rounds 3 and 4, >= ~6-12 s on
+# the CI CPU of the time) and stay as they are. One central list by test name
+# (not per-file decorators), every name of which matches a collected test
+# (`pytest --collect-only -q -m slow`); seven cases carry the marker
+# themselves, with their reason beside it (tests/test_models.py, the
 # plain-program compiles of tests/test_tpu_compile_*.py).
 
 SLOW_TESTS = {
@@ -274,6 +311,9 @@ SLOW_TESTS = {
 
 
 def pytest_configure(config):
+    # the run's own stderr, taken while pytest's capture is suspended (during a
+    # case file descriptor 2 is the case's capture file)
+    config.stash[_STDERR] = os.dup(2)
     config.addinivalue_line(
         "markers", "slow: listed in conftest.SLOW_TESTS (dear, and covered "
                    "by a cheaper tier-1 case); excluded by -m 'not slow'")

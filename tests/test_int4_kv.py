@@ -28,13 +28,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from distributed_llm_training_and_inference_system_tpu.config import get_model_config
 from distributed_llm_training_and_inference_system_tpu.config.schema import (
     ConfigError,
     FleetConfig,
     ServeConfig,
 )
-from distributed_llm_training_and_inference_system_tpu.models import init
 from distributed_llm_training_and_inference_system_tpu.ops.paged_attention import (
     Int4Pages,
     QuantPages,
@@ -75,16 +75,7 @@ def model_cfg():
 
 @pytest.fixture(scope="module")
 def params(model_cfg):
-    return init(model_cfg, jax.random.PRNGKey(0))
-
-
-def make_engine(model_cfg, params, **overrides) -> InferenceEngine:
-    kw = dict(model="gpt-test", max_batch_size=4, max_seq_len=128,
-              prefill_chunk=32, kv_block_size=8, dtype="float32",
-              kv_quantization="int4")
-    kw.update(overrides)
-    return InferenceEngine(model_cfg, ServeConfig(**kw), params=params,
-                           seed=0)
+    return support.params_of(model_cfg)
 
 
 # -- pack/unpack bitwise units ------------------------------------------------
@@ -361,11 +352,10 @@ def _fleet_cfg(**kw):
 
 
 def _serve_cfg(**kw):
-    base = dict(model="gpt-test", max_batch_size=2, max_seq_len=128,
-                prefill_chunk=32, kv_block_size=8, dtype="float32",
-                kv_quantization="int4")
-    base.update(kw)
-    return ServeConfig(**base)
+    # two slots a replica: the four prompts queue, so a drain or a handoff
+    # finds requests waiting as well as running
+    return support.serve_config("gpt-test", kv_quantization="int4",
+                                max_batch_size=2, **kw)
 
 
 PROMPTS = [[3, 1, 4, 1, 5, 9], [2, 7, 1, 8, 2], [6, 1, 8, 0],

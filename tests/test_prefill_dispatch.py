@@ -17,12 +17,9 @@ import jax
 import numpy
 import pytest
 
-from distributed_llm_training_and_inference_system_tpu.config import (
-    get_model_config)
-from distributed_llm_training_and_inference_system_tpu.config.schema import (
-    ServeConfig)
+import serving_support as support
 from distributed_llm_training_and_inference_system_tpu.serve import (
-    InferenceEngine, SamplingParams)
+    SamplingParams)
 from distributed_llm_training_and_inference_system_tpu.serve import (
     engine as engine_mod)
 
@@ -91,56 +88,46 @@ def _watch_method(eng, name, monkeypatch, seen):
     monkeypatch.setattr(eng, name, wrapper)
 
 
-def _engine(model, **over):
-    return InferenceEngine(get_model_config(model), ServeConfig(**{**dict(
-        model=model, max_batch_size=2, max_seq_len=128, dtype="float32",
-        kv_block_size=8, prefill_chunk=16, decode_steps_per_dispatch=4),
-        **over}))
-
-
-def _tokens(n, seed):
-    return numpy.random.default_rng(seed).integers(3, 256, n).tolist()
-
-
 @pytest.mark.parametrize("model,program", [
     ("gpt-test", "jit(prefill)"), ("olmoe-test", "jit(prefill)"),
     ("nemotron-h-test", "jit(prefill)")])
 def test_a_cold_prefill_is_one_program_and_fetches_nothing(
         model, program, monkeypatch):
-    eng = _engine(model)
+    eng = support.engine(model)
     sp = SamplingParams(max_tokens=3, seed=7, **SEEDED)
-    eng.generate([_tokens(20, seed=1)], sp)           # warm-up: same bucket
+    eng.generate([support.tokens(20, seed=1)], sp)    # warm-up: same bucket
     jax.clear_caches()
     seen = []
     _watch_method(eng, "_prefill", monkeypatch, seen)
-    eng.generate([_tokens(21, seed=2)], sp)
+    eng.generate([support.tokens(21, seed=2)], sp)
     assert seen == [([program], [])]
 
 
 def test_a_suffix_prefill_is_one_program_and_fetches_nothing(monkeypatch):
-    eng = _engine("gpt-test")
+    eng = support.engine("gpt-test")
     sp = SamplingParams(max_tokens=3, seed=7, **SEEDED)
-    shared = _tokens(32, seed=3)
-    eng.generate([shared + _tokens(9, seed=4)], sp)   # registers 4 pages
-    eng.generate([shared + _tokens(10, seed=5)], sp)  # warm-up: a prefix hit
+    shared = support.tokens(32, seed=3)
+    eng.generate([shared + support.tokens(9, seed=4)], sp)   # 4 pages kept
+    eng.generate([shared + support.tokens(10, seed=5)], sp)  # warm-up: a hit
     jax.clear_caches()
     seen = []
     _watch_method(eng, "_prefill", monkeypatch, seen)
     before = eng.stats()["prefix_cached_tokens"]
-    eng.generate([shared + _tokens(11, seed=6)], sp)
+    eng.generate([shared + support.tokens(11, seed=6)], sp)
     assert eng.stats()["prefix_cached_tokens"] == before + 32
     assert seen == [(["jit(extend_prefill)"], [])]
 
 
 def test_a_chunked_prefill_is_one_program_a_chunk(monkeypatch):
-    eng = _engine("gpt-test", chunked_prefill_tokens=16)
+    # (a budget of two pages: the one shape this property is about)
+    eng = support.engine("gpt-test", chunked_prefill_tokens=16)
     sp = SamplingParams(max_tokens=3, seed=7, **SEEDED)
-    eng.generate([_tokens(41, seed=8)], sp)           # warm-up: 16 + 16 + 9
+    eng.generate([support.tokens(41, seed=8)], sp)    # warm-up: 16 + 16 + 9
     jax.clear_caches()
     seen = []
     _watch_method(eng, "_start_chunked_prefill", monkeypatch, seen)
     _watch_method(eng, "_advance_chunked_prefills", monkeypatch, seen)
-    eng.generate([_tokens(42, seed=9)], sp)
+    eng.generate([support.tokens(42, seed=9)], sp)
     # admission dispatches nothing; the 16-row chunk program compiles once
     # and runs twice; the last chunk is the sampling program
     assert seen == [([], []), (["jit(extend_chunk)"], []), ([], []),

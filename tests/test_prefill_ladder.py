@@ -12,9 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_support as support
 from benchmark.runners.serve import Served
-from distributed_llm_training_and_inference_system_tpu.config import (
-    get_model_config)
 from distributed_llm_training_and_inference_system_tpu.config.schema import (
     ServeConfig)
 from distributed_llm_training_and_inference_system_tpu.models import gpt
@@ -129,15 +128,24 @@ def test_the_benchmarks_warm_up_touches_every_rung_the_cells_reach(traffic):
 
 
 # -- one prompt through two rungs, float32 on the CPU ---------------------------
+# at the harness's shapes (pages of 8, a chunk of 32): 25 tokens fill three
+# pages and a row, the 32-row program pads inside the last page and the
+# 64-row program (the one shape this property is about) by four pages more
 
-PROMPT = np.random.default_rng(30).integers(1, 250, 200).tolist()
+PROMPT = support.tokens(25, seed=30)
+
+
+def _last_logits(params, tokens, at, *, cfg):
+    logits, _ = gpt.forward(
+        params, tokens, cfg,
+        kv_cache=gpt.init_kv_cache(cfg, 1, tokens.shape[1],
+                                   dtype=jnp.float32),
+        cache_offset=jnp.zeros((1,), jnp.int32), unembed_positions=at)
+    return logits[0, 0]
 
 
 def serve_through(model: str, chunk: int) -> dict:
-    cfg = get_model_config(model)
-    eng = InferenceEngine(cfg, ServeConfig(
-        model=model, max_batch_size=2, max_seq_len=1024, prefill_chunk=chunk,
-        kv_block_size=PAGE, dtype="float32"), seed=0)
+    eng = support.engine(model, prefill_chunk=chunk)
     assert not np.asarray(eng.kv.k_pages).any()
     req = Request("r", PROMPT, SamplingParams(temperature=0.0, max_tokens=32))
     assert eng.scheduler.add_request(req)
@@ -148,39 +156,36 @@ def serve_through(model: str, chunk: int) -> dict:
     bucket = eng._bucket(len(PROMPT))
     tokens = np.zeros((1, bucket), np.int32)
     tokens[0, :len(PROMPT)] = PROMPT
-    logits, _ = gpt.forward(
-        eng.params, jnp.asarray(tokens), cfg,
-        kv_cache=gpt.init_kv_cache(cfg, 1, bucket, dtype=jnp.float32),
-        cache_offset=jnp.zeros((1,), jnp.int32),
-        unembed_positions=jnp.asarray([len(PROMPT) - 1]))
+    logits = support.program(_last_logits, eng.cfg)(
+        eng.params, jnp.asarray(tokens), jnp.asarray([len(PROMPT) - 1]))
     stats = eng.stats()
     return {"tokens": list(req.generated_tokens), "bucket": bucket,
             "live": (k[:, live, :, :], v[:, live, :, :]),
             "scratch_written": bool(k[:, 0].any()),
             "elsewhere_written": bool(k[:, untouched].any()
                                       or v[:, untouched].any()),
-            "logits": np.asarray(logits[0, 0]),
+            "logits": np.asarray(logits),
             "rows": stats["prefill_padded_tokens"],
             "prefill_tokens": stats["prefill_tokens"]}
 
 
 @pytest.mark.parametrize("model", ["gpt-test", "olmoe-test"])
 def test_a_prompt_is_served_the_same_through_either_rung(model):
-    fine, coarse = serve_through(model, 256), serve_through(model, 512)
-    assert (fine["bucket"], coarse["bucket"]) == (256, 512)
-    assert (fine["rows"], coarse["rows"]) == (256, 512)
+    fine, coarse = serve_through(model, 32), serve_through(model, 64)
+    assert (fine["bucket"], coarse["bucket"]) == (32, 64)
+    assert (fine["rows"], coarse["rows"]) == (32, 64)
     assert fine["prefill_tokens"] == coarse["prefill_tokens"] == len(PROMPT)
     assert len(fine["tokens"]) >= 1 and fine["tokens"] == coarse["tokens"]
     np.testing.assert_allclose(fine["logits"], coarse["logits"], atol=1e-5)
     assert int(fine["logits"].argmax()) == fine["tokens"][0]
-    # the prompt's 200 rows (and the decode steps after them) in its pages
-    prompt_rows = len(PROMPT) % PAGE
+    # the prompt's 25 rows (and the decode steps after them) in its pages
+    prompt_rows = len(PROMPT) % support.PS
     for a, b in zip(fine["live"], coarse["live"]):
         np.testing.assert_allclose(a[:, :-1], b[:, :-1], atol=1e-5)
         np.testing.assert_allclose(a[:, -1, :, :prompt_rows],
                                    b[:, -1, :, :prompt_rows], atol=1e-5)
         assert a[:, :-1].any()
-    # a 256-row program has no page of padding for 200 tokens in pages of
-    # 64; the 512-row program's four land on scratch page 0, nowhere else
+    # a 32-row program has no page of padding for 25 tokens in pages of 8;
+    # the 64-row program's four land on scratch page 0, nowhere else
     assert not fine["elsewhere_written"] and not coarse["elsewhere_written"]
     assert coarse["scratch_written"]

@@ -135,7 +135,10 @@ def _keep_pages(eng, kept):
 
 def _serve(eng, prompts, sampling, residents=2, tag=""):
     """``residents`` first (2: half the slots decode), then ``prompts``."""
-    long = SamplingParams(temperature=0.0, max_tokens=60)
+    # six dispatches of decoding: the longest scenario rides two of them, and
+    # what the residents make after the last piece is waiting, not coverage
+    # (60 tokens were 15 dispatches a case, most of the file's 420 s: PR 61)
+    long = SamplingParams(temperature=0.0, max_tokens=6 * STEPS)
     for i, p in enumerate(_residents(residents)):
         assert eng.scheduler.add_request(Request(f"res{tag}{i}", p, long))
     if residents:
@@ -217,6 +220,10 @@ def test_the_riding_rows_are_counted_as_prefill_rows(engines):
     prompt's tokens and C rows a carrying step, so the live-row share
     keeps reading the truth."""
     eng, _ = engines
+    # (a cold prompt first: on a worker that is dealt the module from here,
+    # the residents' 32-row program is not compiled yet)
+    _serve(eng, [fresh_tokens(5)], SAMPLING["greedy"], residents=0,
+           tag="-rows-cold")
     before = eng.stats()
     _serve(eng, [fresh_tokens(2 * C + 1)], SAMPLING["greedy"], tag="-rows")
     after = eng.stats()

@@ -126,6 +126,13 @@ def test_ssm_decode_kernel_updates_the_state_pool_in_place(one_chip, as_tpu):
                               heads_a_block=8)
 
 
+# temporaries of the program WITHOUT pieces (the slow case below reads them
+# again; a 40 s compile on 3.6 cores that the driver's run no longer pays:
+# PR 61)
+PLAIN_TEMP_BYTES = 1_125_128_704
+
+
+@pytest.mark.slow     # ~90 s: the carrying case below holds every property
 def test_decode_program_moves_no_pool_and_no_stack(decode_program, as_tpu):
     """The multi-step decode program: both pools of every layer ride the
     carry and are written at [layer]; no copy of a page pool, of the state
@@ -142,8 +149,8 @@ def test_decode_program_moves_no_pool_and_no_stack(decode_program, as_tpu):
     # step wants its 3 columns off the lanes. 2 x 7.9 MB in ~12 steps)
     _no_copy_of(text, NO_COPY + ["bf16[4,5120,9248]"])
     _state_update_is_the_kernel(text, "f32[128,32,128,256]")
-    assert mem.temp_size_in_bytes < 1.3e9, (
-        f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries")
+    assert mem.temp_size_in_bytes <= PLAIN_TEMP_BYTES < 1.3e9, (
+        f"{mem.temp_size_in_bytes} bytes of temporaries")
     assert mem.alias_size_in_bytes >= STATE_POOL + 2 * 625e6
 
 
@@ -156,7 +163,7 @@ def test_carrying_decode_program_fits_the_chip(decode_program, as_tpu):
     a dispatch, in the entry computation; no more temporaries than the
     plain program's plus a MB a piece row and those copies."""
     import re
-    (_, plain), (text, carrying) = decode_program(0), decode_program(RIDE_ROWS)
+    text, carrying = decode_program(RIDE_ROWS)
     _no_copy_of(text, NO_COPY, fused_into_at_most=32 << 20)
     _state_update_is_the_kernel(text, "f32[128,32,128,256]")
     # the named copies are the ENTRY computation's, not a step's
@@ -166,9 +173,9 @@ def test_carrying_decode_program_fits_the_chip(decode_program, as_tpu):
         assert len(copies) == len(re.findall(
             rf" = {re.escape(shape)}\S* copy\(", entry)) <= 1, shape
     assert carrying.alias_size_in_bytes >= STATE_POOL + 2 * 625e6
-    assert (carrying.temp_size_in_bytes < plain.temp_size_in_bytes
+    assert (carrying.temp_size_in_bytes < PLAIN_TEMP_BYTES
             + (RIDE_ROWS << 20) + IN_PROJ_BYTES + QKV_BYTES), (
-        plain.temp_size_in_bytes, carrying.temp_size_in_bytes)
+        carrying.temp_size_in_bytes)
     # weights 8.79 GB + pools 3.4 GB + temporaries fit the chip's 16 GB
     assert 8.79e9 + 3.4e9 + carrying.temp_size_in_bytes < 15.7e9
 

@@ -17,9 +17,10 @@ a second process's ``topo`` skips):
   (``tests/conftest.py``), never at import, never in a
   ``skipif``/``parametrize`` argument; shardings and shapes are built in
   fixtures/tests;
-- a family of programs a file (PR 45 split the one file by family so that
-  ``--dist loadfile`` spreads them over the workers), compiled in the test's
-  own process;
+- a family of programs a file, compiled in the test's own process. The
+  driver runs ``--dist load``, and these files end the run, six compiles
+  abreast, as their names have it: the cheapest place for them
+  (``tests/conftest.py``, beside ``topo``, has what was measured);
 - the kernels pick ``interpret`` from ``jax.default_backend()``, which
   still says ``cpu`` here: the ``as_tpu`` fixture steers that, and every
   test asserts ``tpu_custom_call`` is in the compiled text so an
@@ -147,6 +148,25 @@ def _mistral_decode_program(one_chip, layers, num_pages, dtype):
     return cfg, PS, compile_
 
 
+# temporaries of the program WITHOUT pieces at the cell's shapes (the slow
+# case below reads them again; a 40 s compile on 3.6 cores that the driver's
+# run no longer pays: PR 61)
+CELL_PLAIN_TEMP_BYTES = 820_287_488
+
+
+@pytest.mark.slow     # ~45 s: the carrying case below is held against it
+def test_plain_decode_program_at_the_cells_shapes_holds_what_was_read(
+        one_chip, as_tpu):
+    """``mistral-7b-16l``'s decode program WITHOUT pieces: 0.82 GB of
+    temporaries, the q / k / v stacks' re-layouts (PERF.md 5), and no
+    more than was read when the constant was written."""
+    layers, num_pages = cell_pool("gqa32x8")
+    _cfg, _PS, compile_ = _mistral_decode_program(one_chip, layers, num_pages,
+                                                  jnp.bfloat16)
+    temp = compile_(False).memory_analysis().temp_size_in_bytes
+    assert temp <= CELL_PLAIN_TEMP_BYTES, temp
+
+
 def test_carrying_decode_program_holds_no_pool_and_no_stack(one_chip, as_tpu):
     """The decode program with a prompt's piece riding every step
     (``decode_scan(ride=...)``: what an engine that rides jits as
@@ -166,8 +186,8 @@ def test_carrying_decode_program_holds_no_pool_and_no_stack(one_chip, as_tpu):
                                                 jnp.bfloat16)
     layer_pool_bytes = num_pages * nkv * PS * D * 2
     ffn_stack_bytes = layers * cfg.hidden_size * cfg.ffn_size * 2
-    temps = {name: compile_(carrying).memory_analysis().temp_size_in_bytes
-             for name, carrying in (("plain", False), ("carrying", True))}
+    temps = {"plain": CELL_PLAIN_TEMP_BYTES,
+             "carrying": compile_(True).memory_analysis().temp_size_in_bytes}
     assert temps["carrying"] < temps["plain"] + layer_pool_bytes, temps
     assert temps["carrying"] < min(layers * layer_pool_bytes,
                                    ffn_stack_bytes), temps
