@@ -25,6 +25,12 @@ the same recurrence, as ``ops/ssm.py`` has them:
   between chunks ``S`` is carried. The pair decays are formed as
   ``exp(G_t - G_s)`` with ``s <= t``: never the exponential of a positive
   number, so no strength of decay overflows (dividing by ``exp(G_s)`` would).
+  Inside a sub-block of 16 rows that is one exponential a pair a channel,
+  on the vector unit; for ``s`` in an earlier sub-block than ``t`` it is
+  the product ``exp(G_t - G_r) exp(G_r - G_s)`` through the first row ``r``
+  of ``t``'s sub-block, ``s < r <= t``, so both exponents are <= 0 too (a
+  factor that underflows to 0 stands for a product that is smaller still),
+  and the sum over the channels is a float32 matmul (``pair_products``).
   A position with ``beta = 0`` and ``g = 0`` leaves the state as it was:
   that is how a bucket's padding is kept out of it.
 - ``kda_decode``: the one-step update over ``[slots]``, elementwise in
@@ -103,14 +109,16 @@ def l2norm(x: jax.Array) -> jax.Array:
         jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + L2_EPS)
 
 
-_SOLVE_BLOCK = 16       # rows a diagonal block of the triangular inverse
+# rows a sub-block of a chunk: a diagonal block of the triangular inverse, and
+# of the pair products (``unit_lower_inverse``, ``pair_products``)
+_SUB_BLOCK = 16
 
 
 def unit_lower_inverse(A: jax.Array) -> jax.Array:
     """(I + A)^-1 for a batch of STRICTLY lower-triangular A [..., Q, Q],
-    float32. The diagonal blocks of ``_SOLVE_BLOCK`` rows are inverted by
+    float32. The diagonal blocks of ``_SUB_BLOCK`` rows are inverted by
     forward substitution, all of them at once (row t of a block's inverse is
-    e_t - A[t, :t] T[:t]: ``_SOLVE_BLOCK`` steps whatever the batch), and
+    e_t - A[t, :t] T[:t]: ``_SUB_BLOCK`` steps whatever the batch), and
     pairs of inverted blocks are merged by two matmuls a level,
 
         [[A11, 0], [A21, A22]]^-1 = [[T11, 0], [-T22 A21 T11, T22]],
@@ -119,7 +127,7 @@ def unit_lower_inverse(A: jax.Array) -> jax.Array:
     chip took 1.4 ms a K layer for the 512 systems of a 1,024-row window,
     a fifth of the chunk program (PERF.md 6, PR 40)."""
     Q = A.shape[-1]
-    base = _SOLVE_BLOCK
+    base = _SUB_BLOCK
     nb = Q // base
     if Q % base or nb & (nb - 1):
         base, nb = Q, 1                 # one block: substitution alone
@@ -151,6 +159,74 @@ def unit_lower_inverse(A: jax.Array) -> jax.Array:
     return blocks[0][0]
 
 
+def _pairs_on_the_vector_unit(a: jax.Array, k: jax.Array, G: jax.Array
+                              ) -> tuple[jax.Array, jax.Array]:
+    """(kk, ak) [..., n, n] over EVERY pair of the n rows of k, a, G
+    [..., n, dk]: ``sum_d x_t[d] k_s[d] exp(G_t[d] - G_s[d])`` for x = k and
+    x = a where s <= t, 0 elsewhere. A multiply-reduce over [..., n, n, dk]
+    with an exponential a pair a channel."""
+    n = G.shape[-2]
+    causal = jnp.arange(n)[:, None] >= jnp.arange(n)[None, :]
+    pair = jnp.exp(jnp.where(
+        causal[:, :, None], G[..., :, None, :] - G[..., None, :, :],
+        -jnp.inf))                                       # [..., n, n, dk]
+    kk = jnp.sum(k[..., :, None, :] * k[..., None, :, :] * pair, -1)
+    ak = jnp.sum(a[..., :, None, :] * k[..., None, :, :] * pair, -1)
+    return kk, ak
+
+
+def _pair_block(Q: int) -> int:
+    """Rows a block of a chunk's pair products: ``_SUB_BLOCK`` where the
+    chunk of Q rows is a whole number of them, else the chunk is one."""
+    return Q if Q % _SUB_BLOCK else _SUB_BLOCK
+
+
+def pair_products(a: jax.Array, k: jax.Array, G: jax.Array
+                  ) -> tuple[jax.Array, jax.Array]:
+    """The chunk's pair products (kk, ak) [..., Q, Q], float32:
+
+        xk[t, s] = sum_d x_t[d] k_s[d] exp(G_t[d] - G_s[d])   (s <= t, else 0)
+
+    for x = k and x = a (the queries), from k, a and the cumulative log
+    decays G [..., Q, dk] (float32, non-increasing along Q). The chunk is
+    cut into sub-blocks of ``_SUB_BLOCK`` rows. A pair inside ONE sub-block
+    is formed on the vector unit (``_pairs_on_the_vector_unit``). For s in
+    an EARLIER sub-block than t, with r the first row of t's sub-block
+    (s < r <= t),
+
+        exp(G_t - G_s) = exp(G_t - G_r) * exp(G_r - G_s),
+
+    both exponents <= 0, so the product over the channels is a matmul of
+    ``x_t exp(G_t - G_r)`` [Q/R, R, dk] against ``k_s exp(G_r - G_s)``
+    [Q/R, Q, dk] (one scaling of the chunk's keys a sub-block, 0 where
+    s >= r; shared by kk and ak), float32 at full precision: kk enters the
+    triangular inverse. A factor that underflows to 0 stands for a product
+    that is smaller still. A chunk that is no whole number of sub-blocks (a
+    window shorter than a chunk) is one block."""
+    Q, dk = G.shape[-2:]
+    R = _pair_block(Q)
+    if R == Q:
+        return _pairs_on_the_vector_unit(a, k, G)
+    nb, lead = Q // R, G.shape[:-2]
+    blocks = lambda x: x.reshape(*lead, nb, R, dk)
+    ab, kb, Gb = blocks(a), blocks(k), blocks(G)
+    kk_in, ak_in = _pairs_on_the_vector_unit(ab, kb, Gb)   # [..., nb, R, R]
+    first = Gb[..., :1, :]                               # G_r [..., nb, 1, dk]
+    since = jnp.exp(Gb - first)                          # t side, t >= r
+    earlier = jnp.arange(Q)[None, :] < R * jnp.arange(nb)[:, None]
+    before = k[..., None, :, :] * jnp.exp(jnp.where(
+        earlier[..., None], first - G[..., None, :, :], -jnp.inf))
+    hi = jax.lax.Precision.HIGHEST                       # [..., nb, Q, dk]
+    kk, ak = (jnp.einsum("...itd,...isd->...its", x * since, before,
+                         precision=hi, preferred_element_type=jnp.float32)
+              for x in (kb, ab))                         # [..., nb, R, Q]
+    own = jnp.eye(nb, dtype=bool)[:, None, :, None]      # s in t's sub-block
+    whole = lambda out, ins: jnp.where(
+        own, ins[..., :, :, None, :], out.reshape(*lead, nb, R, nb, R)
+    ).reshape(*lead, Q, Q)
+    return whole(kk, kk_in), whole(ak, ak_in)
+
+
 def kda_chunk_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
                       g: jax.Array, beta: jax.Array, state: jax.Array,
                       chunk: int) -> tuple[jax.Array, jax.Array]:
@@ -161,8 +237,8 @@ def kda_chunk_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
     position must not enter the state); beta [B, S, nh] float32 (0 there
     too); ``state`` [B, nh, dk, dv] float32. Returns (o [B, S, nh, dv] in
     v's dtype, the state after the window, float32). Matmul operands are
-    the compute dtype with float32 accumulation; decays, the solve and the
-    carried state are float32."""
+    the compute dtype with float32 accumulation; decays, the pair products
+    (``pair_products``), the solve and the carried state are float32."""
     B, S, nh, dk = q.shape
     dv = v.shape[-1]
     Q = min(chunk, S)
@@ -172,8 +248,10 @@ def kda_chunk_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
                       for a in (q, k, v, g))
         beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
     nc = (S + pad) // Q
+    R = _pair_block(Q)
     report_impl("kda_chunk_prefill", "xla",
-                f"q{tuple(q.shape)} chunks {nc}x{Q}")
+                f"q{tuple(q.shape)} chunks {nc}x{Q} pairs {R}x{R}"
+                + (" + matmul" if R < Q else ""))
     f32, dt = jnp.float32, v.dtype
 
     def chunks(a):          # [B, S, nh, ...] -> [nc, B, nh, Q, ...]
@@ -184,13 +262,7 @@ def kda_chunk_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
         qc, kc, vc = (chunks(a).astype(f32) for a in (q, k, v))
         bc = chunks(beta[..., None])                     # [nc,B,nh,Q,1]
         G = jnp.cumsum(chunks(g), axis=3)                # inclusive, <= 0
-        # the pair decays exp(G_t - G_s), s <= t: exponents <= 0 alone
-        causal = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
-        pair = jnp.exp(jnp.where(
-            causal[:, :, None], G[..., :, None, :] - G[..., None, :, :],
-            -jnp.inf))                                   # [nc,B,nh,Q,Q,dk]
-        kk = jnp.sum(kc[..., :, None, :] * kc[..., None, :, :] * pair, -1)
-        qk = jnp.sum(qc[..., :, None, :] * kc[..., None, :, :] * pair, -1)
+        kk, qk = pair_products(qc, kc, G)                # [nc,B,nh,Q,Q]
         strict = jnp.arange(Q)[:, None] > jnp.arange(Q)[None, :]
         A = jnp.where(strict, bc * kk, 0.0)              # [nc,B,nh,Q,Q]
         decay = jnp.exp(G)                               # [nc,B,nh,Q,dk]

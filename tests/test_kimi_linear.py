@@ -285,28 +285,95 @@ def _token_by_token(q, k, v, g, beta, S):
     return jnp.stack(out, 1), S
 
 
-@pytest.mark.parametrize("strength", [0.05, 3.0, 60.0],
-                         ids=["weak", "strong", "extreme"])
-def test_the_chunked_form_is_the_recurrence_at_any_decay(strength):
-    """The chunked form against the one-step form applied token by token,
-    from a non-zero state, over a window that is no whole number of chunks,
-    with decays up to exp(-60) a token (exp(-3,840) over a chunk: the
-    cumulative decay underflows and its inverse would overflow; the pair
-    decays exp(G_t - G_s), s <= t, do neither)."""
-    B, S, nh, d = 2, 150, 3, 16
+def _window(B, S, nh, d, strength, beta_from=0.0):
+    """(q, k, v, g, beta, S0) of a window: normalised q and k, log decays
+    uniform in (-``strength``, 0) a channel, beta ``beta_from`` + a sigmoid,
+    a non-zero state."""
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     q = kda.l2norm(jax.random.normal(ks[0], (B, S, nh, d))) * d ** -0.5
     k = kda.l2norm(jax.random.normal(ks[1], (B, S, nh, d)))
     v = jax.random.normal(ks[2], (B, S, nh, d))
     g = -strength * jax.random.uniform(ks[3], (B, S, nh, d))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, nh)))
-    S0 = jax.random.normal(ks[5], (B, nh, d, d))
+    beta = beta_from + jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, nh)))
+    return q, k, v, g, beta, jax.random.normal(ks[5], (B, nh, d, d))
+
+
+# (decay strength, [B, S, nh, d], beta's lower end): the first three are one
+# window at three strengths; then the cells' head width over four whole
+# chunks; a window shorter than a chunk whose length is no multiple of 16
+# (its pairs formed as ONE block); beta in (1, 2), the sessions
+# configuration's negative eigenvalues
+_DECAY_CASES = {
+    "weak": (0.05, (2, 150, 3, 16), 0.0),
+    "strong": (3.0, (2, 150, 3, 16), 0.0),
+    "extreme": (60.0, (2, 150, 3, 16), 0.0),
+    "cell_heads": (1.0, (1, 256, 2, 128), 0.0),
+    "one_block": (3.0, (2, 40, 3, 16), 0.0),
+    "reflecting": (3.0, (2, 150, 3, 16), 1.0),
+}
+
+
+@pytest.mark.parametrize("case", list(_DECAY_CASES))
+def test_the_chunked_form_is_the_recurrence_at_any_decay(case):
+    """The chunked form against the one-step form applied token by token,
+    from a non-zero state, over a window that is no whole number of chunks,
+    with decays up to exp(-60) a token (exp(-3,840) over a chunk: the
+    cumulative decay underflows and its inverse would overflow; the pair
+    decays exp(G_t - G_s), s <= t, formed whole inside a sub-block of 16 rows
+    and as exp(G_t - G_r) exp(G_r - G_s) through the first row r of t's
+    sub-block across sub-blocks, do neither)."""
+    strength, shape, beta_from = _DECAY_CASES[case]
+    q, k, v, g, beta, S0 = _window(*shape, strength, beta_from)
     with jax.default_matmul_precision("highest"):
         o, S1 = kda.kda_chunk_prefill(q, k, v, g, beta, S0, 64)
     want_o, want_S = _token_by_token(q, k, v, g, beta, S0)
     assert np.isfinite(np.asarray(o)).all()
     assert np.abs(np.asarray(o - want_o)).max() < 5e-6
     assert np.abs(np.asarray(S1 - want_S)).max() < 5e-6
+    if case == "one_block":
+        # the same 40 rows inside a padded 64 (sub-blocks of 16 and the
+        # matmuls between them) leave the same state
+        n = shape[1]
+        pad = lambda a: jnp.pad(a, ((0, 0), (0, 64 - n)) + ((0, 0),) * (
+            a.ndim - 2))
+        with jax.default_matmul_precision("highest"):
+            o64, S64 = kda.kda_chunk_prefill(
+                *(pad(a) for a in (q, k, v, g, beta)), S0, 64)
+        assert np.abs(np.asarray(S64 - S1)).max() < 5e-6
+        assert np.abs(np.asarray(o64[:, :n] - o)).max() < 5e-6
+
+
+def _pairs_over_the_whole_chunk(a, k, G):
+    """The pair products as ONE block of Q x Q x dk on the vector unit (the
+    form before PR 62): (kk, ak) [..., Q, Q]."""
+    Q = G.shape[-2]
+    causal = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+    pair = jnp.exp(jnp.where(
+        causal[:, :, None], G[..., :, None, :] - G[..., None, :, :],
+        -jnp.inf))
+    return (jnp.sum(k[..., :, None, :] * k[..., None, :, :] * pair, -1),
+            jnp.sum(a[..., :, None, :] * k[..., None, :, :] * pair, -1))
+
+
+@pytest.mark.parametrize("strength", [3.0, 60.0], ids=["strong", "extreme"])
+def test_the_sub_block_pairs_are_the_whole_chunks_pairs(strength):
+    """``pair_products`` over a chunk of 64 (sub-blocks of 16 on the vector
+    unit, float32 matmuls at full precision between them) against every pair
+    formed at once: ``A`` (what enters the triangular inverse) and ``qk``
+    to 1e-6, also where a sub-block's decay underflows (exp(-60) a token:
+    a factor that reads 0 stands for a product that is smaller still)."""
+    q, k, _, g, beta, _ = _window(2, 64, 3, 16, strength)
+    qc, kc, G = (jnp.moveaxis(a, 1, 2) for a in (q, k, jnp.cumsum(g, 1)))
+    assert kda._pair_block(64) == 16
+    kk, qk = kda.pair_products(qc, kc, G)
+    want_kk, want_qk = _pairs_over_the_whole_chunk(qc, kc, G)
+    strict = jnp.arange(64)[:, None] > jnp.arange(64)[None, :]
+    A, want_A = (jnp.where(strict, jnp.moveaxis(beta, 1, 2)[..., None] * x,
+                           0.0) for x in (kk, want_kk))
+    assert np.isfinite(np.asarray(A)).all()
+    assert np.abs(np.asarray(A - want_A)).max() < 1e-6
+    assert np.abs(np.asarray(qk - want_qk)).max() < 1e-6
+    assert float(jnp.abs(want_A).max()) > 1e-3      # not all underflowed
 
 
 def test_padding_leaves_the_state_as_it_was():

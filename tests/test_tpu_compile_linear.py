@@ -36,6 +36,8 @@ import pytest
 from tpu_compile_support import (
     LATENT_PS,
     PIECE_ROWS_BYTES,
+    _largest_f32_under,
+    _pair_forms_traced,
     _sds,
     _no_copy_of,
 )
@@ -180,7 +182,11 @@ def test_carrying_linear_decode_program_fits_the_chip(one_chip, as_tpu):
     (PR 43): ONE page of 256 rows beside the 128 slots' rows, through
     ``mla_paged_attention_mq`` in the 3 latent layers and through
     ``kda_chunk_prefill`` (4 sub-chunks of 64 from the slot's own float32
-    state) in the 9 ``K`` layers, the table's periodic part walked by a
+    state; the pair decays exp(G_t - G_s) formed whole inside a sub-block
+    of 16 rows, and between sub-blocks as exp(G_t - G_r) exp(G_r - G_s)
+    through the first row r of t's sub-block, two float32 matmuls over the
+    channels: never the exponential of a positive number, s < r <= t) in
+    the 9 ``K`` layers, the table's periodic part walked by a
     loop (the one-step kernel takes its layer as a prefetched scalar, the
     piece's rows of the pools ride the loop's carry). The piece's slot's
     rows are read once before the first layer and written once after the
@@ -190,8 +196,20 @@ def test_carrying_linear_decode_program_fits_the_chip(one_chip, as_tpu):
     the ``K`` in-projections the loop costs (a slot's conv windows read by
     a slice put the 81 MB pool's 3 columns on the lanes instead: 3.4 GB of
     padding, ``ops/kda.py slot_state``). Both window kernels' scoped VMEM
-    is the compile itself."""
-    _, carrying = _linear_decode_program(one_chip)(LATENT_PS)
+    is the compile itself. Nothing under ``kda_chunk_prefill`` walks a
+    float32 array of chunk x chunk x 128 a head any more (the pair decays
+    of every (t, s) of a chunk, 67 M elements a layer: two multiply-reduce
+    fusions on the vector unit before PR 62); the largest is the diagonal
+    sub-blocks' 16 x 16 x 128 a sub-block (a quarter), and the matmuls'
+    s-side operand, the chunk's keys scaled once a sub-block
+    ([4 chunks, 32 heads, 4 sub-blocks, 64, 128] float32: 16.8 MB a layer
+    if the compiler keeps it, 4 times the keys), stays inside the pins."""
+    text, carrying = _linear_decode_program(one_chip)(LATENT_PS)
+    chunks, heads, Q, d = LATENT_PS // 64, 32, 64, 128
+    most, shape = _largest_f32_under(text, "kda_chunk_prefill")
+    assert most <= chunks * heads * Q * 16 * d < chunks * heads * Q * Q * d, (
+        shape)
+    assert _pair_forms_traced(LATENT_PS, heads) == {"16x16 + matmul"}
     assert carrying.alias_size_in_bytes >= 3.9e9
     assert (carrying.temp_size_in_bytes < LINEAR_PLAIN_TEMP_BYTES
             + PIECE_ROWS_BYTES + LINEAR_IN_PROJ_BYTES), (
@@ -234,6 +252,7 @@ def test_linear_chunk_program_reads_a_slots_state_once(one_chip, as_tpu):
         i32()).compile()
     text = compiled.as_text()
     assert "mla_paged_attention_mq" in text and "moe_gmm_prefill" in text
+    assert _pair_forms_traced(T, 32) == {"16x16 + matmul"}
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 512 << 20, (
         f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries")

@@ -15,7 +15,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tpu_compile_support import _no_copy_of, _sds
+from tpu_compile_support import (
+    _largest_f32_under,
+    _no_copy_of,
+    _pair_forms_traced,
+    _sds,
+)
 
 PS = 256
 
@@ -82,9 +87,21 @@ def test_the_riding_decode_program_fits_the_chip(one_chip, as_tpu):
     (one query a slot, 8 query heads a K/V head) and its multi-query form
     over the piece's window in the softmax layer, the gate, the grouped
     matmuls. The pools ride the carry in place (K and V pages 2 x 2.15 GB,
-    the 0.8 GB state pool): no copy of either, nor of an expert stack."""
+    the 0.8 GB state pool): no copy of either, nor of an expert stack.
+    The chunked form's pair products walk no float32 array of chunk x chunk
+    x 128 a head (134 M elements a layer at 64 heads, on the vector unit
+    before PR 62): sub-blocks of 16 x 16 x 128 on the diagonal, float32
+    matmuls between sub-blocks whose s-side operand is the chunk's keys
+    scaled once a sub-block ([4 chunks, 64 heads, 4 sub-blocks, 64, 128]
+    float32: 33.5 MB a layer if the compiler keeps it; 134 MB in the chunk
+    program of 1,024 rows, inside its pin below)."""
     cfg, serve, *_ = _cell(one_chip)
     text, mem = _decode_program(one_chip, PS)
+    chunks, heads, Q, d = PS // 64, cfg.kda.num_heads, 64, cfg.kda.head_dim
+    most, shape = _largest_f32_under(text, "kda_chunk_prefill")
+    assert most <= chunks * heads * Q * 16 * d < chunks * heads * Q * Q * d, (
+        shape)
+    assert _pair_forms_traced(PS, heads) == {"16x16 + matmul"}
     for kernel in ("moe_gmm", "paged_attention", "paged_attention_mq",
                    "kda_decode", "kda_chunk_prefill", "attn_gate"):
         assert kernel in text, kernel
@@ -122,6 +139,7 @@ def test_the_chunk_program_reads_a_slots_state_once(one_chip, as_tpu):
         i32(1, serve["max_seq_len"] // PS), state, i32()).compile()
     text = compiled.as_text()
     assert "paged_attention_mq" in text and "moe_gmm_prefill" in text
+    assert _pair_forms_traced(T, cfg.kda.num_heads) == {"16x16 + matmul"}
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30, (
         f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries")

@@ -200,3 +200,29 @@ def _state_update_is_the_kernel(text: str, slab: str) -> None:
     assert re.search(r"%ssm_decode(\.\d+)? = .*custom-call\(", text)
     held = [line.strip()[:160] for line in text.splitlines() if slab in line]
     assert not held, held[:3]
+
+
+def _largest_f32_under(text: str, scope: str) -> tuple[int, str]:
+    """(elements, shape) of the largest float32 array any operation under the
+    named ``scope`` of an optimised HLO text makes or reads, inside a fused
+    computation or out of one: what a fusion walks over counts as what a
+    program keeps does."""
+    import math
+    import re
+    shapes = {m.group(0) for line in text.splitlines() if f"/{scope}/" in line
+              for m in re.finditer(r"f32\[[\d,]+\]", line)}
+    assert shapes, f"no operation under {scope}"
+    return max((math.prod(int(n) for n in s[4:-1].split(",")), s)
+               for s in shapes)
+
+
+def _pair_forms_traced(rows: int, heads: int) -> set[str]:
+    """How this process has traced ``kda_chunk_prefill`` over windows of
+    ``rows`` x ``heads`` heads of 128: the ``pairs ...`` part of every such
+    ``report_impl`` line (``ops/kda.py``: the form is static a program)."""
+    from distributed_llm_training_and_inference_system_tpu.utils.platform import (
+        reported_impls)
+    return {detail.split(" pairs ", 1)[1] for op, _, detail in reported_impls()
+            if op == "kda_chunk_prefill"
+            and detail.startswith(f"q(1, {rows}, {heads}, 128)")}
+
