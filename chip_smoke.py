@@ -1754,6 +1754,100 @@ def child_kernels() -> None:
             failures.append(f"falcon_h1: {wrong} moves the logits by under "
                             "a quarter of them: the check cannot see it")
 
+    # (PR 63) ``mellum``: ONE window layer (1,024 keys, plain rope) and ONE
+    # full layer (YaRN, cos / sin x attention_factor) at Mellum2-12B-A2.5B's
+    # widths (GQA 32 / 4 x 128, per-head q/k norms, 64 experts of 896, 8 a
+    # token; 8,192 rows of the vocabulary), in FLOAT32 with full-precision
+    # matmuls: a 1,536-row context goes through pages of 128 in chunks of
+    # two pages, the window layer's rows into a ring of 10 pages (which the
+    # context takes round once), then 300 decode steps write pages 12-14
+    # over the ring's entries 2-4; every decode step's logits beside the
+    # float32 reference's. In float32 the window's edge is visible: the
+    # reference with a window of 1,023 or 1,025 keys must read further off
+    # than the program does (no check on bfloat16 tokens can see that)
+    from benchmark.reference import windowed_decoder
+    from distributed_llm_training_and_inference_system_tpu.serve.decode import (
+        extend_step_forward)
+    from distributed_llm_training_and_inference_system_tpu.serve.kv_cache import (
+        PagedKVCache)
+    base = get_model_config("mellum-test" if small else "mellum2-12b-a2.5b")
+    mel = dataclasses.replace(
+        base, num_layers=2, layer_types=("sliding", "full"), dtype="float32",
+        vocab_size=base.vocab_size if small else 8192)
+    mel.validate()
+    r = mel.rope
+    mpub = {
+        "head_dim": mel.head_dim, "num_attention_heads": mel.num_heads,
+        "num_key_value_heads": mel.num_kv_heads,
+        "rms_norm_eps": mel.norm_eps, "sliding_window": mel.sliding_window,
+        "num_experts_per_tok": mel.moe.experts_per_token,
+        "norm_topk_prob": True,
+        "layer_types": ["sliding_attention", "full_attention"],
+        "rope_parameters": {
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": r.base,
+                "factor": r.scaling_factor, "beta_fast": r.beta_fast,
+                "beta_slow": r.beta_slow,
+                "original_max_position_embeddings": r.original_max_position,
+                "attention_factor": r.attention_factor},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": mel.window_rope.base}}}
+    mps, chunk, ctx, steps = (8, 16, 48, 30) if small else (128, 256, 1536,
+                                                           300)
+    with jax.default_matmul_precision("highest"):
+        mparams = jax.jit(lambda k: gpt.init(mel, k, jnp.float32))(next(key))
+        mparams = jax.tree_util.tree_map_with_path(
+            lambda path, leaf: jax.random.uniform(
+                jax.random.fold_in(jax.random.PRNGKey(63), leaf.size),
+                leaf.shape, leaf.dtype, -0.3, 0.3)
+            if path[-1].key == "scale" else leaf, mparams)
+        seq = jax.random.randint(next(key), (ctx + steps,), 1,
+                                 mel.vocab_size).tolist()
+        positions = range(ctx - 1, len(seq) - 1)
+        want = windowed_decoder.logits(mparams, seq, mpub,
+                                       positions=positions, round_to=2048)
+        mkv = PagedKVCache(mel, num_slots=4, max_seq_len=2048 if not small
+                           else 128, page_size=mps, num_pages=24,
+                           dtype=jnp.float32, window_rows=chunk)
+        mkv.allocate(2, len(seq))
+        mtable = jnp.asarray(mkv.block_tables)
+        chunk_fn = jax.jit(lambda p, t, s, kp, vp: extend_step_forward(
+            p, t, s, kp, vp, mtable[2][None], mel), donate_argnums=(3, 4))
+        kp, vp = mkv.k_pages, mkv.v_pages
+        for start in range(0, ctx, chunk):
+            out = chunk_fn(mparams, jnp.asarray([seq[start:start + chunk]]),
+                           jnp.asarray([start], jnp.int32), kp, vp)
+            kp, vp = out.k_pages, out.v_pages
+        got = [out.logits[0, -1]]
+        mstep = jax.jit(lambda p, t, pos, kp, vp: decode_step_forward(
+            p, t, pos, kp, vp, mtable, mel, active=jnp.arange(4) == 2),
+            donate_argnums=(3, 4))
+        for t in range(ctx, len(seq) - 1):
+            out = mstep(mparams, jnp.full((4,), seq[t], jnp.int32),
+                        jnp.full((4,), t, jnp.int32), kp, vp)
+            kp, vp = out.k_pages, out.v_pages
+            got.append(out.logits[2])
+        got = jnp.stack(got)
+        check(f"mellum window + full layer: {ctx} rows through pages of "
+              f"{mps} in chunks of {chunk} then {len(got) - 1} decode steps "
+              f"over a ring of {mkv.ring_entries} pages, {mel.num_heads} / "
+              f"{mel.num_kv_heads} heads, window {mel.sliding_window}, "
+              "float32 logits against the float32 reference", got, want,
+              tol=2e-3)
+        off = float(jnp.abs(got - want).max())
+        for wrong in ("window_minus_1", "window_plus_1", "all_full",
+                      "no_attention_factor"):
+            moved = float(jnp.abs(want - windowed_decoder.logits(
+                mparams, seq, mpub, positions=positions, wrong=wrong,
+                round_to=2048)).max())
+            emit("kernel", {"name": f"mellum reference {wrong} moves the "
+                            "logits by", "max_abs_diff": moved,
+                            "program_off_by": off})
+            if moved < 4 * off:
+                failures.append(
+                    f"mellum: {wrong} moves the logits by {moved:.2e}, the "
+                    f"program is off by {off:.2e}: the check cannot see it")
+
     if failures:
         print("kernel check failures:\n  " + "\n  ".join(failures),
               file=sys.stderr)

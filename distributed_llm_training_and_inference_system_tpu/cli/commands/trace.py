@@ -159,7 +159,9 @@ def load_profile(path: str) -> dict:
     "page_walk" sums the ``live_pages`` / ``table_pages`` ids that every
     decode dispatch's span carries (serve/engine.py ``_submit_group``), and
     for a model with state-space layers its ``ssm_slot_steps`` (``K``
-    layers: ``kda_slot_steps``), the prompt
+    layers: ``kda_slot_steps``), for one with window layers the rows its
+    two kinds of layer saw (``window_rows``, ``window_rows_unwindowed``,
+    ``full_rows``, ``ring_wraps``), the prompt
     rows that rode the dispatch's steps (``ride_rows``), for one
     with latent attention the bytes of a latent page over its layers
     (``latent_page_bytes``, the last seen, not a sum);
@@ -174,7 +176,10 @@ def load_profile(path: str) -> dict:
     devices: dict = {}
     host_spans: dict = {}
     page_walk = {"live_pages": 0, "table_pages": 0, "ssm_slot_steps": 0,
-                 "kda_slot_steps": 0, "latent_page_bytes": 0, "ride_rows": 0}
+                 "kda_slot_steps": 0, "latent_page_bytes": 0, "ride_rows": 0,
+                 # window layers (serve/engine.py ``_window_ids``)
+                 "window_rows": 0, "window_rows_unwindowed": 0,
+                 "full_rows": 0, "ring_wraps": 0}
     prefill_rows = {"rows": 0, "tokens": 0, "cached": 0, "state_carry": 0}
     startup_programs: list = []
     for plane in profile.planes:
@@ -367,6 +372,14 @@ def summarize(trace_dir):
         click.echo(f"delta-rule (K) layers advanced {walk['kda_slot_steps']} "
                    f"slot states (live slots x decode steps), summed over "
                    f"the decode dispatches")
+    if walk["window_rows_unwindowed"]:
+        click.echo(f"window layers saw {walk['window_rows']} K/V rows of the "
+                   f"{walk['window_rows_unwindowed']} they would have seen "
+                   f"as full layers "
+                   f"({100 * walk['window_rows'] / walk['window_rows_unwindowed']:.1f}"
+                   f" %); full layers {walk['full_rows']}; "
+                   f"{walk['ring_wraps']} ring wraps, summed over the decode "
+                   f"dispatches")
     if loaded["prefill_rows"]["state_carry"]:
         click.echo(f"{loaded['prefill_rows']['state_carry']} prompt tokens "
                    f"went chunk by chunk through programs that read and "

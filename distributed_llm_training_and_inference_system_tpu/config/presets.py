@@ -118,6 +118,30 @@ MODEL_TEMPLATES: dict[str, ModelConfig] = {
         moe=MoEConfig(num_experts=64, experts_per_token=8,
                       norm_topk_prob=False),
     ),
+    # Mellum2-12B-A2.5B-Instruct (huggingface.co/JetBrains/..., config.json,
+    # model_type mellum) at its published sizes: 28 layers, three WINDOW
+    # layers (1,024 keys, plain rope) to every full layer (YaRN, factor 16
+    # over 8,192, cos / sin x attention_factor), GQA 32 / 4 heads of 128
+    # with per-head q/k norms, 64 experts of width 896, 8 a token,
+    # renormalised, no shared expert; 12.1 B parameters, 2.5 B active.
+    # Served with the window layers' K/V in a ring of pages a slot
+    # (serve/kv_cache.py); 24 GB of bfloat16 weights: one chip holds a cut
+    # (benchmark/configs/mellum2-12b-a2.5b-8l.json)
+    "mellum2-12b-a2.5b": ModelConfig(
+        name="mellum2-12b-a2.5b", num_layers=28, hidden_size=2304,
+        ffn_size=896, num_heads=32, num_kv_heads=4, head_dim=128,
+        vocab_size=98304, max_position_embeddings=131072,
+        activation="silu", norm_eps=1e-6, qk_norm="head",
+        rope=RopeConfig(base=500000.0, scaling="yarn", scaling_factor=16.0,
+                        original_max_position=8192, beta_fast=32.0,
+                        beta_slow=1.0,
+                        attention_factor=1.2772588722239782),
+        window_rope=RopeConfig(base=500000.0),
+        sliding_window=1024,
+        layer_types=("sliding", "sliding", "sliding", "full") * 7,
+        moe=MoEConfig(num_experts=64, experts_per_token=8,
+                      norm_topk_prob=True),
+    ),
     # NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 (huggingface.co/nvidia/...,
     # config.json, model_type nemotron_h) at its published sizes: a LAYER
     # TABLE of 52 layers, each one norm and one mixer: 23 Mamba-2
@@ -198,6 +222,24 @@ TEST_TEMPLATES: dict[str, ModelConfig] = {
                       norm_topk_prob=True),
         diffusion=DiffusionConfig(block_length=4, denoising_steps=4,
                                   mask_token_id=255),
+    ),
+    # mellum's shape in small: two periods of three window layers (16 keys,
+    # plain rope) and a full one (YaRN with an attention factor), GQA with
+    # per-head q/k norms, renormalised top-2 of 8 experts. Served over
+    # pages of 8, its window is two pages.
+    "mellum-test": ModelConfig(
+        name="mellum-test", num_layers=8, hidden_size=128, ffn_size=32,
+        num_heads=4, num_kv_heads=2, head_dim=16, vocab_size=256,
+        max_position_embeddings=256, activation="silu", dtype="float32",
+        norm_eps=1e-6, qk_norm="head",
+        rope=RopeConfig(base=10000.0, scaling="yarn", scaling_factor=4.0,
+                        original_max_position=64, beta_fast=32.0,
+                        beta_slow=1.0, attention_factor=1.1386294361119891),
+        window_rope=RopeConfig(base=10000.0),
+        sliding_window=16,
+        layer_types=("sliding", "sliding", "sliding", "full") * 2,
+        moe=MoEConfig(num_experts=8, experts_per_token=2,
+                      norm_topk_prob=True),
     ),
     # nemotron_h's shape in small: one 7-layer motif of its layer table,
     # state-space mixers beside GQA attention without rope and sigmoid-

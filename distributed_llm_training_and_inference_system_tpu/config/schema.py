@@ -60,6 +60,10 @@ class RopeConfig:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+    # what cos and sin are multiplied by (a published ``rope_parameters``
+    # group's ``attention_factor``: the scores then carry its square); 1:
+    # the plain rotation
+    attention_factor: float = 1.0
 
     @property
     def softmax_mscale(self) -> float:
@@ -79,15 +83,19 @@ class RopeConfig:
         d = dict(d or {})
         if published:
             kind = str(_take(published, "type", "rope_type", default="none"))
-            if kind != "yarn":
+            if "rope_theta" in published:   # a ``rope_parameters`` group
+                d["base"] = published["rope_theta"]
+            if kind == "yarn":
+                d.update(scaling="yarn", **{
+                    k: published[k] for k in (
+                        "factor", "beta_fast", "beta_slow", "mscale",
+                        "mscale_all_dim", "attention_factor")
+                    if k in published})
+                d["original_max_position"] = published.get(
+                    "original_max_position_embeddings", 4096)
+            elif kind != "default":     # (a group's plain rope: its theta)
                 raise ConfigError(f"rope_scaling type {kind!r}: yarn is the "
                                   "one published form read")
-            d.update(scaling="yarn", **{
-                k: published[k] for k in (
-                    "factor", "beta_fast", "beta_slow", "mscale",
-                    "mscale_all_dim") if k in published})
-            d["original_max_position"] = published.get(
-                "original_max_position_embeddings", 4096)
         if not d:
             return cls()
         return cls(
@@ -100,6 +108,7 @@ class RopeConfig:
             beta_slow=float(_take(d, "beta_slow", default=1.0)),
             mscale=float(_take(d, "mscale", default=1.0)),
             mscale_all_dim=float(_take(d, "mscale_all_dim", default=0.0)),
+            attention_factor=float(_take(d, "attention_factor", default=1.0)),
         )
 
 
@@ -574,10 +583,45 @@ class ModelConfig:
     # before the residual takes it (``ouro``'s ``input_layernorm_2`` and
     # ``post_attention_layernorm_2``)
     sandwich_norm: bool = False
+    # WINDOW layers beside full ones (``model_type: mellum``): a layer of
+    # the uniform stack whose ``layer_types`` entry is "sliding" sees, of the
+    # keys at or before it, the last ``sliding_window`` alone (itself
+    # included) and rotates by ``window_rope`` (the published
+    # ``rope_parameters`` are keyed by layer kind); a "full" layer sees
+    # every earlier key and rotates by ``rope``. () = every layer full. A
+    # window layer KEEPS only what it can see: its K/V live in a ring of
+    # pages a slot (serve/kv_cache.py), the full layers' in a growing chain
+    sliding_window: int = 0
+    layer_types: tuple = ()
+    window_rope: RopeConfig = field(default_factory=RopeConfig)
 
     @property
     def is_looped(self) -> bool:
         return self.num_passes > 1
+
+    @property
+    def has_window(self) -> bool:
+        """Some layer of the uniform stack is a window layer."""
+        return "sliding" in self.layer_types
+
+    @property
+    def window_layers(self) -> int:
+        return sum(t == "sliding" for t in self.layer_types)
+
+    @property
+    def window_period(self) -> tuple:
+        """The shortest run of layer kinds that ``layer_types`` repeats
+        (("sliding", "sliding", "sliding", "full") x 7): the serve programs
+        scan over periods, a layer's kind static inside one."""
+        types = tuple(self.layer_types)
+        for p in range(1, len(types) + 1):
+            if len(types) % p == 0 and types == types[:p] * (len(types) // p):
+                return types[:p]
+        return types
+
+    def layer_rope(self, kind: str) -> RopeConfig:
+        """The rope of a layer of ``kind`` ("sliding" | "full")."""
+        return self.window_rope if kind == "sliding" else self.rope
 
     @property
     def is_moe(self) -> bool:
@@ -608,13 +652,17 @@ class ModelConfig:
         YaRN's m^2 where the rope has it."""
         return self.head_dim ** -0.5 * self.rope.softmax_mscale ** 2
 
-    def kv_bytes_per_token(self, itemsize: int = 2) -> int:
+    def kv_bytes_per_token(self, itemsize: int = 2,
+                           kind: str = "") -> int:
         """Cache bytes one token costs over all the layers that keep any:
-        K and V of every kv head, or ONE padded latent row."""
+        K and V of every kv head, or ONE padded latent row. ``kind``
+        ("sliding" | "full"): over the layers of that kind alone (a window
+        layer keeps a token's rows only while a query can see them)."""
         if self.is_latent:
             return self.kv_layers * self.mla.page_width * itemsize
-        return (2 * self.kv_layers * self.num_kv_heads * self.head_dim
-                * itemsize)
+        layers = {"": self.kv_layers, "sliding": self.window_layers,
+                  "full": self.kv_layers - self.window_layers}[kind]
+        return 2 * layers * self.num_kv_heads * self.head_dim * itemsize
 
     def layers_of(self, kind: str) -> int:
         """How many layers of the table are ``kind`` (M | * | E)."""
@@ -779,6 +827,29 @@ class ModelConfig:
                 f"moe: router_score softmax|sigmoid, and the held experts "
                 f"{m.first_expert}..<{m.first_expert + m.num_experts} must "
                 f"lie inside the router's {m.router_width} (got {m})")
+        if self.layer_types:
+            kinds = sorted(set(self.layer_types) - {"sliding", "full"})
+            if kinds or len(self.layer_types) != self.num_layers:
+                raise ConfigError(
+                    f"layer_types must name each of the {self.num_layers} "
+                    "layers sliding or full (got "
+                    f"{len(self.layer_types)} entries, unknown {kinds})")
+        if self.has_window:
+            if self.sliding_window < 1:
+                raise ConfigError(
+                    f"sliding_window = {self.sliding_window}: a window "
+                    "layer sees at least itself")
+            for what, has in (("a layer table (layer_pattern)",
+                               self.layer_pattern),
+                              ("latent attention", self.is_latent),
+                              ("a looped stack", self.is_looped),
+                              ("generation by diffusion", self.is_diffusion)):
+                if has:
+                    raise ConfigError(
+                        f"window layers beside {what} are refused: the "
+                        "window's ring of pages and its term in the mask "
+                        "are carried by the uniform stack over K/V pages, "
+                        "walked once, under the causal rule (ROADMAP B3)")
         if self.num_passes < 1:
             raise ConfigError(
                 f"total_ut_steps = {self.num_passes}: a stack is walked at "
@@ -1021,6 +1092,38 @@ class ModelConfig:
                     "carried (the router takes its top-k over all experts)")
         sdar = d.get("model_type") == "sdar_moe"
         ouro = d.get("model_type") == "ouro"
+        mellum = d.get("model_type") == "mellum"
+        rope, window_rope = d.get("rope"), d.get("window_rope")
+        rope_published = d.get("rope_scaling")
+        window, layer_types = int(_take(d, "sliding_window", default=0) or 0), ()
+        if "layer_types" in d and not lfm2:
+            # ``mellum`` names each layer ``sliding_attention`` or
+            # ``full_attention`` and keys ``rope_parameters`` by those names
+            names = {"sliding_attention": "sliding", "sliding": "sliding",
+                     "full_attention": "full", "full": "full"}
+            unknown = sorted({str(t) for t in d["layer_types"]} - set(names))
+            if unknown:
+                raise ConfigError(
+                    f"layer_types {unknown}: a layer of the uniform stack is "
+                    "sliding_attention or full_attention")
+            layer_types = tuple(names[str(t)] for t in d["layer_types"])
+            if not _parse_bool("use_sliding_window",
+                               d.get("use_sliding_window", True)):
+                layer_types = ("full",) * len(layer_types)
+        if mellum:
+            if set(d.get("mlp_layer_types") or ["sparse"]) != {"sparse"}:
+                raise ConfigError(
+                    "mlp_layer_types: every layer of a mellum stack is "
+                    "carried sparse (dense layers among a uniform stack's "
+                    "expert layers are not carried)")
+            by_kind = d.get("rope_parameters") or {}
+            if set(by_kind) != {"full_attention", "sliding_attention"}:
+                raise ConfigError(
+                    "rope_parameters must hold a full_attention and a "
+                    f"sliding_attention group (got {sorted(by_kind)})")
+            rope_published = by_kind["full_attention"]
+            window_rope = RopeConfig.from_dict(
+                None, by_kind["sliding_attention"])
         if int(d.get("decoder_sparse_step", 1)) != 1:
             raise ConfigError(
                 f"decoder_sparse_step = {d['decoder_sparse_step']}: every "
@@ -1067,7 +1170,11 @@ class ModelConfig:
             vocab_size=int(_take(d, "vocab_size", default=50304)),
             max_position_embeddings=int(_take(d, "max_position_embeddings", "max_seq_len",
                                               default=2048)),
-            rope=RopeConfig.from_dict(d.get("rope"), d.get("rope_scaling")),
+            rope=RopeConfig.from_dict(rope, rope_published),
+            sliding_window=window,
+            layer_types=layer_types,
+            window_rope=(window_rope if isinstance(window_rope, RopeConfig)
+                         else RopeConfig.from_dict(window_rope)),
             activation=activation,
             norm_eps=float(_take(d, "layer_norm_eps", "norm_eps", "rms_norm_eps",
                                  "layer_norm_epsilon", default=1e-5)),
@@ -1082,7 +1189,8 @@ class ModelConfig:
             # (``sdar_moe``'s config.json has no key for its per-head norms)
             # (nor has ``lfm2_moe``'s for its own)
             qk_norm=str(_take(d, "qk_norm",
-                              default="head" if sdar or lfm2 else "none")),
+                              default=("head" if sdar or lfm2 or mellum
+                                       else "none"))),
             diffusion=DiffusionConfig.from_dict(d.get("diffusion") or d,
                                                 published=sdar),
             layer_pattern=pattern,
@@ -1139,7 +1247,7 @@ class ModelConfig:
         for group in ("rope_scaling", "linear_attn_config",
                       "mlp_only_layers", "gqa_layers", "ssm_multipliers",
                       "mlp_multipliers", "attn_layer_indices",
-                      "layer_types"):
+                      "layer_types", "mlp_layer_types", "rope_parameters"):
             if config.get(group):
                 d[group] = config[group]
         return cls.from_dict(d)

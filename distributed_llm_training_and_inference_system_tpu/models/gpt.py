@@ -31,8 +31,10 @@ from .layers import (
     attend_dense_cache,
     attend_fresh,
     decoder_block,
+    layer_kinds,
     model_rope_frequencies,
     rms_norm,
+    rope_scale,
     scaled,
 )
 
@@ -498,22 +500,29 @@ def cast_table_blocks(blocks: Params, dtype) -> Params:
 def _block_fn(cfg: ModelConfig, attn_impl: str, norm_impl: str,
               x, layer, positions, segment_ids, inv_freq,
               kv_cache=None, cache_offset=None, *, moe_impl: str = "dropless",
-              expert_stacks=None, layer_index=None, kind=None, recur=None):
+              expert_stacks=None, layer_index=None, kind=None, recur=None,
+              layer_kind=None):
     """``decoder_block`` with no cache or over one layer's dense
     ``kv_cache``. Returns (x, new_kv_cache, aux): ``aux`` is the router's
     load-balancing loss, or under ``moe_impl="dropless"`` the layer's
-    ``moe_stats`` (``segment_ids`` 0 marks a token that is not live)."""
+    ``moe_stats`` (``segment_ids`` 0 marks a token that is not live).
+    ``layer_kind`` (a stack with window layers: this layer's
+    ``LayerKind``, traced) gives the mask its window and the rope its
+    frequencies in place of ``inv_freq``."""
+    window, scale = None, rope_scale(cfg.rope)
+    if layer_kind is not None:
+        window, inv_freq, scale = layer_kind
     attend = (attend_fresh(positions, segment_ids, attn_impl,
-                           cfg.attention_block)
+                           cfg.attention_block, window)
               if kv_cache is None
               else attend_dense_cache(kv_cache, cache_offset, positions,
-                                      cfg.attention_block))
+                                      cfg.attention_block, window))
     if cfg.is_moe and moe_impl == "dropless" and kind is None:
         layer, layer_index = layer_experts(layer, expert_stacks, layer_index)
     x, new_cache, aux = decoder_block(
         x, layer, cfg, positions, inv_freq, attend, norm_impl=norm_impl,
         live=segment_ids, moe_impl=moe_impl, layer_index=layer_index,
-        kind=kind, recur=recur)
+        kind=kind, recur=recur, rope_scale=scale)
     if aux is None:
         aux = jnp.float32(0.0)
     # anchor GSPMD propagation at the block boundary (no-op off-mesh;
@@ -849,6 +858,11 @@ def forward(
                                   moe_impl="capacity")
         aux0 = jnp.float32(0.0)
     block = _remat_wrap(block, remat)
+    # a stack with WINDOW layers: each layer's window, frequencies and rope
+    # scale ride the scan beside its weights (None, an empty pytree, for
+    # every other model: its scan is what it was). The dense cache stays
+    # one plane a layer, full-length and masked: the ring is the pages'
+    kinds = layer_kinds(cfg)
 
     def walk(x, aux, cache):
         """ONE walk of the L layers: (x, aux, that walk's planes of the
@@ -856,27 +870,29 @@ def forward(
         if cache is None:
             def body(carry, layer_and_index):
                 x, aux = carry
-                layer, li = layer_and_index
+                layer, li, kind = layer_and_index
                 x, _, aux_l = block(x.astype(compute_dtype),
                                     _cast(layer, compute_dtype), positions,
-                                    segment_ids, inv_freq, layer_index=li)
+                                    segment_ids, inv_freq, layer_index=li,
+                                    layer_kind=kind)
                 return (x, aux + aux_l), None
 
-            (x, aux), _ = jax.lax.scan(body, (x, aux), (blocks, layer_ids))
+            (x, aux), _ = jax.lax.scan(body, (x, aux),
+                                       (blocks, layer_ids, kinds))
             return x, aux, None
 
         def body(carry, layer_and_cache):
             x, aux = carry
-            layer, li, kc, vc = layer_and_cache
+            layer, li, kind, kc, vc = layer_and_cache
             x, new_kv, aux_l = block(x.astype(compute_dtype),
                                      _cast(layer, compute_dtype), positions,
                                      segment_ids, inv_freq,
                                      kv_cache=(kc, vc), cache_offset=cache_offset,
-                                     layer_index=li)
+                                     layer_index=li, layer_kind=kind)
             return (x, aux + aux_l), new_kv
 
         (x, aux), new_kvs = jax.lax.scan(
-            body, (x, aux), (blocks, layer_ids, *cache))
+            body, (x, aux), (blocks, layer_ids, kinds, *cache))
         return x, aux, new_kvs
 
     if return_passes and not cfg.is_looped:

@@ -11,7 +11,7 @@ fp32-master friendly, XLA-fusable, with hooks for Pallas kernels in ops/.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -77,18 +77,64 @@ def rope_frequencies(head_dim: int, base: float = 10000.0,
     return inv_freq
 
 
-def model_rope_frequencies(cfg: ModelConfig) -> jax.Array:
+def model_rope_frequencies(cfg: ModelConfig, kind: str = "full"
+                           ) -> jax.Array:
     """``rope_frequencies`` of the values ``cfg``'s attention rotates: the
-    whole head, or a latent-attention head's ``qk_rope_head_dim``."""
-    return rope_frequencies(cfg.rope_dim, cfg.rope.base, cfg.rope.scaling,
-                            cfg.rope.scaling_factor, yarn=cfg.rope)
+    whole head, or a latent-attention head's ``qk_rope_head_dim``; of a
+    layer of ``kind`` (a window layer has a rope of its own)."""
+    rope = cfg.layer_rope(kind)
+    return rope_frequencies(cfg.rope_dim, rope.base, rope.scaling,
+                            rope.scaling_factor, yarn=rope)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array) -> jax.Array:
-    """Rotate [..., S, N, D] by position. positions: [..., S] int32."""
+class LayerKind(NamedTuple):
+    """What tells a WINDOW layer of the uniform stack from a full one
+    (``ModelConfig.layer_types``), as ``decoder_block`` and the ``attend``
+    makers take it: static numbers where the kind is (serve/decode.py scans
+    over periods), traced ones where it rides a scan over layers
+    (models/gpt.py)."""
+    window: Any        # keys a query sees, itself included; 0: every one
+    inv_freq: Any      # [D/2]: the kind's rope
+    rope_scale: Any    # cos and sin are multiplied by it (None: not at all)
+
+
+def rope_scale(rope) -> Optional[float]:
+    """What ``apply_rope`` multiplies cos and sin by under ``rope`` (a
+    ``RopeConfig``): its ``attention_factor``, None where that is 1 (no
+    operation in the program)."""
+    return None if rope.attention_factor == 1.0 else rope.attention_factor
+
+
+def layer_kind(cfg: ModelConfig, kind: str) -> LayerKind:
+    """The ``LayerKind`` of a layer of ``kind`` ("sliding" | "full")."""
+    return LayerKind(cfg.sliding_window if kind == "sliding" else 0,
+                     model_rope_frequencies(cfg, kind),
+                     rope_scale(cfg.layer_rope(kind)))
+
+
+def layer_kinds(cfg: ModelConfig) -> Optional[LayerKind]:
+    """Every layer's ``LayerKind`` stacked [L, ...] (window int32, a full
+    layer's 0), the per-layer operands of a scan over the layers; None for
+    a model without window layers (an empty pytree: its scan and its
+    program are what they were)."""
+    if not cfg.has_window:
+        return None
+    kinds = [layer_kind(cfg, t) for t in cfg.layer_types]
+    return LayerKind(
+        jnp.asarray([k.window for k in kinds], jnp.int32),
+        jnp.stack([k.inv_freq for k in kinds]),
+        jnp.asarray([k.rope_scale or 1.0 for k in kinds], jnp.float32))
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array,
+               scale: Any = None) -> jax.Array:
+    """Rotate [..., S, N, D] by position. positions: [..., S] int32.
+    ``scale``: cos and sin times it (YaRN's ``attention_factor``)."""
     angles = positions[..., :, None].astype(jnp.float32) * inv_freq  # [...,S,D/2]
     cos = jnp.cos(angles)[..., :, None, :]   # [...,S,1,D/2]
     sin = jnp.sin(angles)[..., :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -102,32 +148,37 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def block_visible(q_positions: jax.Array, kv_positions: jax.Array,
-                  block: int = 0) -> jax.Array:
+                  block: int = 0, window: Any = None) -> jax.Array:
     """Which keys a query may see by POSITION, [..., Sq, Skv]: those at or
     before it, or, with ``block`` > 0 (generation by diffusion over blocks,
     ``ModelConfig.attention_block``), those of its own block of ``block``
     positions and of every earlier one: the rows of a block see each other
-    whole, blocks are causal among themselves."""
+    whole, blocks are causal among themselves. ``window`` (a WINDOW layer;
+    an int or a traced int32, 0 = none): of those at or before it the last
+    ``window`` alone, itself included: key j iff i - window < j <= i."""
     q, kv = q_positions[..., :, None], kv_positions[..., None, :]
     if block > 0:
         return q // block >= kv // block
-    return q >= kv
+    if window is None or (isinstance(window, int) and window == 0):
+        return q >= kv
+    return (q >= kv) & ((kv > q - window) | (window == 0))
 
 
 def attention_mask(q_positions: jax.Array, kv_positions: jax.Array,
                    q_segments: Optional[jax.Array] = None,
                    kv_segments: Optional[jax.Array] = None,
-                   causal: bool = True, block: int = 0) -> jax.Array:
+                   causal: bool = True, block: int = 0,
+                   window: Any = None) -> jax.Array:
     """Boolean [B, Sq, Skv] mask: True = attend.
 
     Packed-sequence aware: tokens attend only within their own segment
-    (segment id 0 = padding, never attended). ``block``: see
+    (segment id 0 = padding, never attended). ``block``, ``window``: see
     ``block_visible``.
     """
     mask = jnp.ones(q_positions.shape[:-1] + (q_positions.shape[-1],
                     kv_positions.shape[-1]), dtype=bool)
     if causal:
-        mask = block_visible(q_positions, kv_positions, block)
+        mask = block_visible(q_positions, kv_positions, block, window)
     if q_segments is not None and kv_segments is not None:
         same = q_segments[..., :, None] == kv_segments[..., None, :]
         valid = kv_segments[..., None, :] != 0
@@ -210,17 +261,23 @@ def qk_project_norm(x: jax.Array, layer: Params, which: str,
 
 
 def attend_fresh(positions: jax.Array, segment_ids: Optional[jax.Array],
-                 attn_impl: str = "xla", block: int = 0):
+                 attn_impl: str = "xla", block: int = 0, window: Any = None):
     """``attend`` for a block that keeps no cache (training, evaluation,
     the pipeline stages, calibration): causal attention of the window's own
     q over its own k and v, packed sequences apart by ``segment_ids``,
     through ``attn_impl`` (xla | flash | ring | ulysses). With ``block``
-    (``block_visible``) the mask is the block rule's, which only the xla
+    or ``window`` (``block_visible``) the mask has a term which only the xla
     route has."""
     if block > 0 and attn_impl != "xla":
         raise ValueError(f"attn_impl={attn_impl!r} has no block rule: a "
                          "model that generates by diffusion over blocks "
                          "attends through xla outside the page kernels")
+    if window is not None and attn_impl != "xla":
+        raise ValueError(
+            f"attn_impl={attn_impl!r} has no window term: a model with "
+            "window layers (sliding_window) attends through xla outside the "
+            "page kernels (the flash kernel, ring and ulysses attention "
+            "mask causally alone; ROADMAP B3)")
 
     def attend(q, k, v):
         if attn_impl == "flash":
@@ -236,7 +293,7 @@ def attend_fresh(positions: jax.Array, segment_ids: Optional[jax.Array],
         else:
             report_impl("attention", "xla", f"q{tuple(q.shape)}")
             mask = attention_mask(positions, positions, segment_ids,
-                                  segment_ids, block=block)
+                                  segment_ids, block=block, window=window)
             out = dot_product_attention(q, k, v, mask)
         return out, None
     return attend
@@ -244,12 +301,13 @@ def attend_fresh(positions: jax.Array, segment_ids: Optional[jax.Array],
 
 def attend_dense_cache(kv_cache: tuple[jax.Array, jax.Array],
                        cache_offset: jax.Array, positions: jax.Array,
-                       block: int = 0):
+                       block: int = 0, window: Any = None):
     """``attend`` over one layer's dense cache ``(k_cache, v_cache)`` of
     shape [B, S_max, Nkv, D] (cold prefill, ``evals/``): the new K/V are
     written at each row's ``cache_offset`` [B] (its current length) and
     attention runs over the cache; the state returned is the updated
-    cache."""
+    cache. A window layer's cache is full-length too, masked by
+    ``window`` (``block_visible``)."""
     k_cache, v_cache = kv_cache
 
     def attend(q, k, v):
@@ -264,7 +322,7 @@ def attend_dense_cache(kv_cache: tuple[jax.Array, jax.Array],
                     f"q{tuple(q.shape)} over a [{B}, {S_max}] cache")
         kv_positions = jnp.arange(S_max)[None, :].repeat(B, axis=0)
         valid = kv_positions < (cache_offset[:, None] + S)
-        mask = block_visible(positions, kv_positions, block) \
+        mask = block_visible(positions, kv_positions, block, window) \
             & valid[:, None, :]
         out = dot_product_attention(q, kc.astype(q.dtype),
                                     vc.astype(q.dtype), mask)
@@ -618,6 +676,7 @@ def decoder_block(
     layer_index=None,
     kind: Optional[str] = None,
     recur=None,
+    rope_scale: Any = None,
 ) -> tuple[jax.Array, Any, Any]:
     """One pre-norm transformer block: THE layer equations. Training,
     evaluation and the pipeline stages (models/gpt.py ``_block_fn``), cold
@@ -639,6 +698,11 @@ def decoder_block(
       ``layer[...]["kernel"]`` as the caller's tree holds it (an array, a
       packed int4 / int8 tensor, a tagged kernel), so a caller's matmul
       dispatches on its type.
+
+    A layer of a stack with WINDOW layers differs from its neighbours by
+    its ``LayerKind`` alone: the window is in the caller's ``attend``, the
+    kind's frequencies come as ``inv_freq`` and ``rope_scale`` multiplies
+    cos and sin (None: a model without window layers).
 
     The feed-forward is chosen from ``cfg`` and ``moe_impl``: the dense
     ``mlp_block``; the dropless ``moe_block`` (``live``, ``layer["moe"]``
@@ -721,7 +785,7 @@ def decoder_block(
 
     h = rms_norm(x, layer["attn_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
     out, state = attention_mixer(h, layer, cfg, positions, inv_freq, attend,
-                                 matmul)
+                                 matmul, rope_scale)
     x = x + out_norm(out, "attn_out_norm").astype(x.dtype)
 
     h = rms_norm(x, layer["mlp_norm"]["scale"], cfg.norm_eps, impl=norm_impl)
@@ -735,10 +799,12 @@ def decoder_block(
 
 def attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,
                     positions: jax.Array, inv_freq: jax.Array, attend,
-                    matmul=dense_matmul) -> tuple[jax.Array, Any]:
+                    matmul=dense_matmul, rope_scale: Any = None
+                    ) -> tuple[jax.Array, Any]:
     """Grouped-query attention over the normed stream ``h`` [B, S, H]:
     projections, optional q/k norms and biases, rope unless
-    ``cfg.position_embedding`` is "none", ``attend``, with
+    ``cfg.position_embedding`` is "none" (``rope_scale``: ``apply_rope``'s),
+    ``attend``, with
     ``cfg.attention_gate`` the output times ``sigmoid(h W_g)`` elementwise
     (``solar_open2``), the output projection. Returns (out [B, S, H],
     ``attend``'s state)."""
@@ -756,8 +822,8 @@ def attention_mixer(h: jax.Array, layer: Params, cfg: ModelConfig,
         k = k + layer["k"]["bias"].reshape(Nkv, D)
         v = v + layer["v"]["bias"].reshape(Nkv, D)
     if cfg.position_embedding == "rope":
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
+        q = apply_rope(q, positions, inv_freq, rope_scale)
+        k = apply_rope(k, positions, inv_freq, rope_scale)
 
     out, state = attend(q, k, v)
     if cfg.attention_gate:

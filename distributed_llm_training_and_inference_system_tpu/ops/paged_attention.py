@@ -199,6 +199,74 @@ class Int4Pages(QuantPages):
         return dequantize_int4_rows(self.values, self.scale, dtype)
 
 
+@jax.tree_util.register_pytree_node_class
+class SplitPages:
+    """The K (or V) pages of a stack with WINDOW layers
+    (``ModelConfig.layer_types``), two pools under one name: ``full``
+    [L_full, NP, Nkv, PS, D], the full layers' pages, a slot's a chain that
+    grows with its sequence, and ``window`` [L_win, NP_ring, Nkv, PS, D],
+    the window layers', a slot's a RING of ``ring`` pages it holds for life:
+    token t's rows of a window layer lie in ring entry ``(t // PS) % ring``
+    and are overwritten ``ring`` pages later, when no query can see them any
+    more (serve/kv_cache.py ``ring_pages``). ``is_window`` says, a layer of
+    the stack, which pool holds it (static, like ``ring``: both are the
+    pytree's aux data, so the pair rides jits, donation and scan carries
+    as one argument where every other model has one array). A slot's row of
+    the block tables holds its chain and, last, its ``ring`` entries
+    (``tables_of``)."""
+
+    def __init__(self, full, window, ring: int, is_window: tuple):
+        self.full, self.window = full, window
+        self.ring, self.is_window = ring, tuple(is_window)
+
+    def tree_flatten(self):
+        return (self.full, self.window), (self.ring, self.is_window)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+    def of(self, full, window) -> "SplitPages":
+        """The same split over two other arrays."""
+        return SplitPages(full, window, self.ring, self.is_window)
+
+    def replace(self, **pool) -> "SplitPages":
+        """... over another ``full=`` or ``window=`` array."""
+        return self.of(pool.get("full", self.full),
+                       pool.get("window", self.window))
+
+    @property
+    def shape(self):
+        """The full pool's (what a consumer that asks a pool's geometry
+        means: heads, page size and row width are the window pool's too)."""
+        return self.full.shape
+
+    @property
+    def dtype(self):
+        return self.full.dtype
+
+    def delete(self) -> None:
+        self.full.delete()
+        self.window.delete()
+
+    def layer_indices(self) -> tuple:
+        """(the stack's layers in the full pool, those in the window pool),
+        each in the order of its pool's planes."""
+        return (tuple(i for i, w in enumerate(self.is_window) if not w),
+                tuple(i for i, w in enumerate(self.is_window) if w))
+
+    def tables_of(self, block_tables: jax.Array) -> tuple:
+        """A slot's two tables out of its row [.., chain | ring]: the full
+        layers' chain [.., maxP] as it is, and the window layers' as wide,
+        logical page p naming ring entry ``p % ring``: every reader and
+        writer of pages addresses ``table[b, position // PS]`` and walks a
+        ring without knowing it."""
+        chain = block_tables[..., :-self.ring]
+        ring = block_tables[..., -self.ring:]
+        at = jnp.arange(chain.shape[-1], dtype=jnp.int32) % self.ring
+        return chain, jnp.take(ring, at, axis=-1)
+
+
 def quantize_kv_token(new_kv: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Per-(row, head) absmax int8 of a token's K or V [..., Nkv, D] ->
     (int8 values, fp32 scale [..., Nkv]). One implementation of the
@@ -247,9 +315,11 @@ def paged_attention(
 
 
 def _gather_attention(q, k_pages, v_pages, block_tables, lengths,
-                      layer=None):
+                      layer=None, window: int = 0):
     """The portable baseline: materialise each row's [Nkv, maxP*PS, D]
-    prefix through the block table, then plain masked attention."""
+    prefix through the block table, then plain masked attention (``window``
+    > 0: over the last ``window`` of the ``lengths`` keys alone; what a
+    ring's table names at the positions before them is masked)."""
     B, Nq, D = q.shape
     f = _heads_packed(k_pages, D)
     Nkv, PS = k_pages.shape[-3] * f, k_pages.shape[-2]
@@ -286,6 +356,8 @@ def _gather_attention(q, k_pages, v_pages, block_tables, lengths,
 
     kv_pos = jnp.arange(maxP * PS, dtype=jnp.int32)[None, :]        # [1,Lmax]
     valid = kv_pos < lengths[:, None]                                # [B,Lmax]
+    if window:
+        valid = valid & (kv_pos >= lengths[:, None] - window)
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
 
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
@@ -528,12 +600,17 @@ def paged_attention_multi(
     impl: str = "auto",
     layer=None,                # int32 scalar: which layer's pages to read
     block: int = 0,            # static: > 0 = the block rule
+    window: int = 0,           # static: > 0 = a WINDOW layer's keys
 ) -> jax.Array:
     """Multi-query paged attention: query j of slot b attends causally over
     [0, start_b + j] through the pages (the window's own K/V must already
     be written). Returns [B, T, Nq, D]. With ``block`` > 0 (generation by
     diffusion over blocks: block-aligned starts) query j sees its whole
-    block: [0, start_b + (j // block + 1) * block).
+    block: [0, start_b + (j // block + 1) * block). With ``window`` > 0 (a
+    window layer over its ring of pages, ``SplitPages.tables_of``) query j
+    sees the last ``window`` keys alone, (start_b + j - window, start_b +
+    j]: the kernel walks from the first page that holds one (the name it
+    runs under is ``window_attention``), the fallback masks.
 
     On TPU this runs the head-folded Pallas kernel (each page DMA'd once
     per SLOT — all kv heads, all T queries); the fallback flattens to
@@ -546,12 +623,23 @@ def paged_attention_multi(
     # 128, or heads of 64 in pairs (``heads_a_row``); any other small head
     # serves via the gather fallback. Every window size takes the kernel
     # (it tiles long windows itself).
+    op = "window_attention" if window else "paged_attention"
     impl, interpret = _resolve_impl(
-        "paged_attention" if T == 1 else "paged_attention_multi", impl, q,
-        k_pages)
+        op if T == 1 else f"{op}_multi", impl, q, k_pages)
+    if block and window:
+        raise ValueError("the block rule and a window are not carried "
+                         "together")
     if impl == "pallas":
         from .paged_attention_pallas import paged_attention_pallas_multi
         f = _heads_packed(k_pages, D)
+        if window:
+            if f > 1:
+                raise ValueError("a window layer's pages hold heads of 128 "
+                                 "(heads of 64 in pairs have no window "
+                                 "kernel)")
+            return paged_attention_pallas_multi(
+                q, k_pages, v_pages, block_tables, start_positions,
+                layer=layer, interpret=interpret, sliding=window)
         if f > 1:
             groups = Nq // (k_pages.shape[-3] * f)
             out = paged_attention_pallas_multi(
@@ -570,7 +658,7 @@ def paged_attention_multi(
                     + jnp.arange(T, dtype=jnp.int32)).reshape(B * T)
     out = _gather_attention(
         q.reshape(B * T, Nq, D), k_pages, v_pages,
-        jnp.repeat(block_tables, T, axis=0), flat_pos + 1, layer)
+        jnp.repeat(block_tables, T, axis=0), flat_pos + 1, layer, window)
     return out.reshape(B, T, Nq, D)
 
 
@@ -595,6 +683,19 @@ def write_prompt_to_pages(pages, dense, entries: jax.Array):
     in, a scale a token, as the other two writers do. Given a pair of pools
     and caches (K and V; a None pool is handed through) every cache is laid
     out before any pool is written: the cold program's order."""
+    if isinstance(pages, tuple) and isinstance(pages[0], SplitPages):
+        # a stack with window layers: the full layers' planes of the caches
+        # into the chain (``entries[0]``), the window layers' into the ring
+        # (``entries[1]``: the prompt's last ``ring`` pages name their ring
+        # entries, every earlier page, overwritten before a query could
+        # read it, the scratch page)
+        both = [write_prompt_to_pages(
+            tuple(getattr(p, pool) for p in pages),
+            tuple(d[jnp.asarray(layers)] for d in dense), entries[e])
+            for e, (pool, layers) in enumerate(zip(
+                ("full", "window"), pages[0].layer_indices()))]
+        return tuple(p.of(full, window)
+                     for p, full, window in zip(pages, *both))
     if isinstance(pages, tuple):
         laid = [None if d is None else _prompt_page_layout(p, d)
                 for p, d in zip(pages, dense)]

@@ -55,7 +55,7 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
                    q_ref, *refs,
                    page_size: int, scale: float, groups: int,
                    window: int, queries: int, num_kv: int, kv_quant: str,
-                   block: int = 0):
+                   block: int = 0, sliding: int = 0):
     """One grid step: one slot's query tile against that slot's LIVE pages.
 
     ``refs``: (the slot's [maxP, Nkv, PS] scale tiles for K and V, when the
@@ -74,7 +74,14 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
     page ``_PAGES_AHEAD`` places further on in grid order (this tile's, or
     the next grid step's once this tile's are all under way), waits for
     its own page and scores it. A slot at length 0 waits for the one page
-    fetched for it and scores nothing.
+    fetched for it and scores nothing. With ``sliding`` > 0 (a WINDOW
+    layer; the table is the slot's ring laid out by logical page,
+    ``SplitPages.tables_of``) row j sees the last ``sliding`` keys alone,
+    (start + j - sliding, start + j]: the loop STARTS at the page that holds
+    the first key the tile's first query sees (``first_page``) and runs
+    ceil((sliding + window - 1) / PS) + 1 times at most, whatever the
+    slot's length; the rows of that first page behind a query's window are
+    masked.
 
     Head folding: q rows [Nkv*T*G, D] against the whole page [Nkv*PS, D]
     in ONE dot pair per page. Cross-head score blocks are masked to
@@ -114,6 +121,15 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
         return jnp.clip((visible(slot, tile) + page_size - 1) // page_size,
                         1, max_pages)
 
+    def first_page(slot, tile):
+        # the page of the first key the tile's FIRST query sees (a lead that
+        # has run past the last slot reads the last slot's start)
+        if not sliding:
+            return 0
+        first = (starts_ref[jnp.minimum(slot, n_slots - 1)] + tile * window
+                 - (sliding - 1))
+        return jnp.maximum(first, 0) // page_size
+
     def page_copies(slot, p, buf):
         page = tables_ref[slot, p]
         return [pltpu.make_async_copy(pool.at[layer, page], ring.at[buf],
@@ -137,9 +153,10 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
 
         tile_done = p + 1 >= live_pages(jnp.minimum(slot, n_slots - 1), tile)
         slot_done = tile_done & (tile + 1 >= n_tiles)
-        return (jnp.where(slot_done, slot + 1, slot),
-                jnp.where(slot_done, 0, jnp.where(tile_done, tile + 1, tile)),
-                jnp.where(tile_done, 0, p + 1),
+        slot = jnp.where(slot_done, slot + 1, slot)
+        tile = jnp.where(slot_done, 0, jnp.where(tile_done, tile + 1, tile))
+        return (slot, tile,
+                jnp.where(tile_done, first_page(slot, tile), p + 1),
                 next_buffer(buf))
 
     # the ring's state rides SMEM from one grid step to the next: where
@@ -147,7 +164,8 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
     # the next page to score
     @pl.when((b == 0) & (t == 0))
     def _prime():
-        lead = (jnp.int32(0),) * 4
+        lead = (jnp.int32(0), jnp.int32(0), jnp.int32(first_page(0, 0)),
+                jnp.int32(0))
         for _ in range(n_bufs - 1):
             lead = fetch_next(lead)
         for i, x in enumerate((*lead, jnp.int32(0))):
@@ -189,7 +207,10 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
         row_j = (row % tg) // groups
         same_head = (row // tg) == (col // page_size)
         last_seen = ((row_j // block + 1) * block - 1 if block else row_j)
-        s = jnp.where(same_head & (pos <= start + last_seen), s, NEG_INF)
+        seen = same_head & (pos <= start + last_seen)
+        if sliding:
+            seen = seen & (pos > start + row_j - sliding)
+        s = jnp.where(seen, s, NEG_INF)
 
         m_prev = m_ref[...]                            # [Nkv*TG, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -213,7 +234,7 @@ def _extend_kernel(tables_ref, starts_ref, layer_ref,   # scalar prefetch
 
         return (*lead, next_buffer(buf))
 
-    ring = jax.lax.fori_loop(0, live_pages(b, t), one_page,
+    ring = jax.lax.fori_loop(first_page(b, t), live_pages(b, t), one_page,
                              tuple(ring_ref[i] for i in range(5)))
     for i, x in enumerate(ring):
         ring_ref[i] = x
@@ -275,13 +296,16 @@ def paged_attention_pallas_multi(
     block: int = 0,            # static: the block rule's block length
     scale: float | None = None,  # of the scores; None: D ** -0.5 (a pool
                                # of heads in pairs states its heads' own)
+    sliding: int = 0,          # static: a window layer's keys (0: all)
 ) -> jax.Array:
     """Returns [B, T, Nq, D]; query j attends over [0, start+j] via pages
     (the window's own K/V must already be written to the pages). With
     ``block`` > 0 query j attends over [0, start + (j // block + 1) *
     block): every start a whole number of blocks, ``block`` dividing the
     page size, so a block never straddles two pages (the kernel then runs
-    under the name ``paged_attention_blk``).
+    under the name ``paged_attention_blk``). With ``sliding`` > 0 query j
+    attends over (start + j - sliding, start + j] and the table is a ring's
+    (the kernel's name is ``window_attention`` / ``window_attention_mq``).
 
     The pools are operands as they are, never a layer's slice of them: the
     layer rides the scalar prefetch beside the block table and the body's
@@ -358,12 +382,14 @@ def paged_attention_pallas_multi(
     # one query a sequence is the decode step; a window is the
     # speculative verify or a suffix prefill
     name = ("paged_attention_blk" if block else
-            "paged_attention" if T_in == 1 else "paged_attention_mq")
+            ("window_attention" if sliding else "paged_attention")
+            + ("" if T_in == 1 else "_mq"))
     with jax.named_scope(name):
         out = pl.pallas_call(
             functools.partial(_extend_kernel, page_size=PS, scale=scale,
                               groups=groups, window=tile, queries=T_in,
-                              num_kv=Nkv, kv_quant=kv_quant, block=block),
+                              num_kv=Nkv, kv_quant=kv_quant, block=block,
+                              sliding=sliding),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, Nkv, T * groups, D), q.dtype),
             # the ring of page copies runs across grid steps: in order,

@@ -61,6 +61,16 @@ def _refuse_looped(model_cfg: ModelConfig) -> None:
             "the final norm and the exit gate: a schedule that is not here)")
 
 
+def _refuse_windowed(model_cfg: ModelConfig) -> None:
+    if model_cfg.has_window:
+        raise ValueError(
+            f"{model_cfg.name} has window layers (sliding_window "
+            f"{model_cfg.sliding_window}): pipeline stages are refused (a "
+            "stage's scan hands every layer one rope and one causal mask; "
+            "the layer kinds' window, frequencies and rope scale do not "
+            "ride it; ROADMAP B3)")
+
+
 def make_pipeline_loss_fn(
     model_cfg: ModelConfig,
     par: ParallelConfig,
@@ -72,6 +82,7 @@ def make_pipeline_loss_fn(
     metrics path is shared with the non-pipelined step.
     """
     _refuse_looped(model_cfg)
+    _refuse_windowed(model_cfg)
     pp = par.pipeline_parallel
     M = par.num_microbatches
     L = model_cfg.num_layers
@@ -218,6 +229,7 @@ def make_pipeline_grad_fn(
     schedule — ShardedTrainer falls back to GPipe for MoE).
     """
     _refuse_looped(model_cfg)
+    _refuse_windowed(model_cfg)
     pp = par.pipeline_parallel
     M = par.num_microbatches
     L = model_cfg.num_layers

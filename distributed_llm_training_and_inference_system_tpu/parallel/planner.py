@@ -703,7 +703,12 @@ class ServePlanner:
         return (embed + head + m.hidden_size) * BYTES_BF16 + block * per
 
     def page_bytes(self, page_size: int, kv_quant: str = "none") -> float:
+        """One page of the pool whose pages a sequence's length costs: every
+        layer that keeps K/V, or with window layers the FULL layers alone
+        (the window layers' ring is ``ring_pool_bytes``)."""
         m = self.model
+        if m.has_window:
+            return page_size * m.kv_bytes_per_token(int(BYTES_BF16), "full")
         if m.is_latent:
             # ONE latent row a token a layer (serve/kv_cache.py), in bf16:
             # quantised latent pages are refused
@@ -719,6 +724,18 @@ class ServePlanner:
                 * (m.head_dim / 2 + 4)
         return 2 * m.kv_layers * page_size * m.num_kv_heads \
             * m.head_dim * BYTES_BF16
+
+    def ring_pool_bytes(self, slots: int, page_size: int) -> float:
+        """The window layers' pool for ``slots`` slots (serve/kv_cache.py):
+        a ring of pages a slot, whatever its context, and the scratch page;
+        0 without window layers."""
+        m = self.model
+        if not m.has_window:
+            return 0.0
+        from ..serve.kv_cache import ring_pages
+        ring = ring_pages(m.sliding_window, page_size)
+        return ((slots * ring + 1) * page_size
+                * m.kv_bytes_per_token(int(BYTES_BF16), "sliding"))
 
     def state_bytes(self, slots: int) -> float:
         """The state pools of a model's state-space (or ``K``) layers for
@@ -814,7 +831,8 @@ class ServePlanner:
         wb = self.weight_bytes(quant) / tp
         hbm = hw.hbm_gb_per_chip * 1e9
         state = self.state_bytes(batch)
-        pool = (hbm - wb - state - self.workspace_gb * 1e9
+        ring = self.ring_pool_bytes(batch, page_size)
+        pool = (hbm - wb - state - ring - self.workspace_gb * 1e9
                 - self.moe_dispatch_bytes(max(prompt_len, batch)))
         pb = self.page_bytes(page_size, kv_quant) / tp
         pages = max(int(pool // pb), 0)
@@ -831,6 +849,10 @@ class ServePlanner:
 
         # decode: one step reads all weights + the resident KV
         kv_read = batch * context_len * (pb / max(page_size, 1))
+        if m.has_window:
+            # ... and of the window layers the rows a query sees alone
+            kv_read += (batch * min(context_len, m.sliding_window)
+                        * m.kv_bytes_per_token(int(BYTES_BF16), "sliding"))
         bw = hw.hbm_bw_gbps * 1e9 * self.decode_efficiency
         # (and reads and writes every live slot's recurrent state)
         decode_s = (wb * self.moe_decode_weight_fraction(batch)

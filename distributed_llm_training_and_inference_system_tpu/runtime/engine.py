@@ -59,8 +59,19 @@ class TrainingEngine:
         self.par = infer_data_parallel(cfg.parallel, len(devices))
         self._start_step = 0
         attn_impl = cfg.training.attn_impl
+        if cfg.model.has_window and (self.par.sequence_parallel > 1
+                                     or attn_impl not in ("auto", "xla")):
+            raise ValueError(
+                f"{cfg.model.name} has window layers (sliding_window "
+                f"{cfg.model.sliding_window}): llmctl train runs them under "
+                "the XLA mask alone (attn_impl xla or auto, "
+                "sequence_parallel 1): the flash kernel, ring and ulysses "
+                "attention mask causally and have no window term; "
+                "ROADMAP B3")
         if attn_impl == "auto":
-            if self.par.sequence_parallel > 1:
+            if cfg.model.has_window:
+                attn_impl = "xla"         # the one route with the window
+            elif self.par.sequence_parallel > 1:
                 # ring vs ulysses by the planner's priced selection rule
                 # (measured per-scheme efficiencies when `tune sp` has
                 # calibrated this chip; analytic FLOPs/comm model otherwise)

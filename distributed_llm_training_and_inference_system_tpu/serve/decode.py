@@ -34,7 +34,13 @@ from ..models.gpt import (
     table_period_and_tail,
     unembed,
 )
-from ..models.layers import decoder_block, model_rope_frequencies, scaled
+from ..models.layers import (
+    decoder_block,
+    layer_kind,
+    model_rope_frequencies,
+    rope_scale,
+    scaled,
+)
 from ..ops import kda, shortconv, ssm as ssm_ops
 from ..ops.mla_paged_attention import mla_paged_attention
 from ..ops.paged_attention import (
@@ -43,6 +49,7 @@ from ..ops.paged_attention import (
     write_window_to_pages,
 )
 from ..ops.quantization import cast_params, precast_params
+from .kv_cache import ring_pages
 from .sampling import sample_tokens, sample_tokens_with_prob, transfer_rows
 
 
@@ -113,10 +120,15 @@ class DispatchResult(NamedTuple):
     counts: Any = None      # ``DENOISE_COUNTS`` of a denoise dispatch
 
 
-def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl, block=0):
+def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl, block=0,
+             window=0):
     """Write each slot's window of K and V into its pages at layer ``li``
     and attend the window over them: (out, (new k_pages, new v_pages)).
-    ``block`` > 0: under the block rule (``ModelConfig.attention_block``)."""
+    ``block`` > 0: under the block rule (``ModelConfig.attention_block``).
+    ``window`` > 0: a WINDOW layer over its pool's plane ``li``, ``tables``
+    the slots' rings by logical page (``SplitPages.tables_of``): a row is
+    written at its page's ring entry, over rows no query sees any more, and
+    a query walks the last ``window`` keys alone."""
     # K and V live in pages. Every T stages a part of the pool and
     # merges the window in: the sublane tiles a window of 1 to 16 rows
     # touches (a decode step's one row: one tile a slot), whole pages
@@ -129,7 +141,8 @@ def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl, block=0):
         new_k = write_window_to_pages(kp, k, tables, starts, ok, li)
         new_v = write_window_to_pages(vp, v, tables, starts, ok, li)
     out = paged_attention_multi(q, new_k, new_v, tables, starts,
-                                impl=attn_impl, layer=li, block=block)
+                                impl=attn_impl, layer=li, block=block,
+                                window=window)
     return out, (new_k, new_v)
 
 
@@ -140,7 +153,7 @@ def _windows(q, k, v, kp, vp, tables, starts, ok, li, attn_impl, block=0):
 # sampler) goes through these jitted forms, so it is traced and lowered
 # once and called twice, and the compiler meets identical computations
 # (PERF.md 6, PR 36: the program's set-up).
-_shared_windows = jax.jit(_windows, static_argnames=("attn_impl",))
+_shared_windows = jax.jit(_windows, static_argnames=("attn_impl", "window"))
 _shared_sampler = jax.jit(sample_tokens)
 # ... and every recurrent layer's one-token update of all slots over the
 # state pools, at whichever (traced) layer, by the layer's kind
@@ -398,29 +411,30 @@ def extend_step_forward(
         blocks, expert_stacks = split_expert_stacks(blocks)
     return_moe_stats = return_moe_stats and cfg.is_moe
 
-    def attend_pages(kp, vp, li):
+    def attend_pages(kp, vp, li, tables=block_tables, **window):
         """``attend`` over the page pools, written and read at layer
-        ``li``."""
+        ``li`` through ``tables`` (``window``: a window layer's, over its
+        ring; ``_windows``)."""
         def attend(q, k, v):
             slots = _shared_windows if two_bodies else _windows
             if cfg.is_diffusion:
                 # every window of such a model starts on a block and sees
                 # by the block rule: the denoise window of one block, a
                 # suffix or chunked prefill's of many
-                return slots(q, k, v, kp, vp, block_tables, start_positions,
+                return slots(q, k, v, kp, vp, tables, start_positions,
                              write_ok, li, attn_impl, cfg.attention_block)
             if ride is None:
-                return slots(q, k, v, kp, vp, block_tables, start_positions,
-                             write_ok, li, attn_impl)
+                return slots(q, k, v, kp, vp, tables, start_positions,
+                             write_ok, li, attn_impl, **window)
             # the B rows as ever, then the piece's C rows as ONE window
             # over its own slot's pages: [C, 1, N, D] -> [1, C, N, D]
             out, (new_k, new_v) = slots(
-                q[:B], k[:B], v[:B], kp, vp, block_tables, start_positions,
-                write_ok, li, attn_impl)
+                q[:B], k[:B], v[:B], kp, vp, tables, start_positions,
+                write_ok, li, attn_impl, **window)
             piece_out, state = _windows(
                 *(a[B:, 0][None] for a in (q, k, v)), new_k, new_v,
-                block_tables[ride.slot][None], ride.start[None],
-                piece_ok[None], li, attn_impl)
+                tables[ride.slot][None], ride.start[None],
+                piece_ok[None], li, attn_impl, **window)
             return jnp.concatenate([out, piece_out[0][:, None]]), state
         return attend
 
@@ -598,7 +612,7 @@ def extend_step_forward(
             x, (kp, vp), layer_stats = decoder_block(
                 x, layer, cfg, positions, inv_freq,
                 attend_pages(kp, vp, plane), matmul=mm, live=live,
-                layer_index=moe_li)
+                layer_index=moe_li, rope_scale=rope_scale(cfg.rope))
             if stats is not None:
                 stats = stats + layer_stats
             return (x, kp, vp, stats), None
@@ -608,8 +622,75 @@ def extend_step_forward(
             (blocks, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
         return x, kp, vp, stats
 
+    def walk_windowed(x, kp, vp, stats):
+        """The walk of a stack with WINDOW layers: a scan over the PERIODS
+        of ``cfg.layer_types`` (three window layers and a full one), a
+        layer's kind static inside one. A full layer writes and walks the
+        full pool's plane through the slot's chain, as every model's layer
+        does; a window layer the window pool's plane through the slot's
+        ring, from the first page that holds a visible key, under the
+        kind's own rope. Both pools (``SplitPages``) are the scan's carry,
+        written in place."""
+        period = cfg.window_period
+        reps = cfg.num_layers // len(period)
+        ring = kp.ring
+        T_rows = max(T, ride.tokens.shape[0] if ride is not None else 1)
+        if ring < ring_pages(cfg.sliding_window, kp.shape[-2], T_rows):
+            raise ValueError(
+                f"{cfg.name}: a window of {T_rows} rows over a ring of "
+                f"{ring} pages of {kp.shape[-2]} would overwrite rows its "
+                f"own queries see (sliding_window {cfg.sliding_window}: "
+                "kv_cache.ring_pages)")
+        tables = dict(zip(("full", "window"), kp.tables_of(block_tables)))
+        kinds = {k: layer_kind(cfg, k) for k in set(period)}
+        # which plane of its pool the period's j-th layer is, from the
+        # period's first plane of that pool
+        before = [sum(k == kind for k in period[:j])
+                  for j, kind in enumerate(period)]
+        per_period = {k: period.count(k) for k in set(period)}
+
+        def body(carry, layers_and_period):
+            x, kp, vp, stats = carry
+            layers, r = layers_and_period
+            for j, kind in enumerate(period):
+                layer = jax.tree_util.tree_map(lambda a: a[j], layers)
+                li = r * len(period) + j
+                layer = cast_params(layer, compute_dtype,
+                                    keep_w4=use_w4_kernel,
+                                    keep_w8=use_w8_kernel)
+                moe_li = None
+                if cfg.is_moe:
+                    layer = dict(layer, moe=cast_params(layer["moe"],
+                                                        compute_dtype))
+                    layer, moe_li = layer_experts(layer, expert_stacks, li)
+                plane = r * per_period[kind] + before[j]
+                lk = kinds[kind]
+                pool = "window" if kind == "sliding" else "full"
+                attend = attend_pages(
+                    getattr(kp, pool), getattr(vp, pool), plane, tables[pool],
+                    **({"window": lk.window} if lk.window else {}))
+                x, (new_k, new_v), layer_stats = decoder_block(
+                    x, layer, cfg, positions, lk.inv_freq, attend, matmul=mm,
+                    live=live, layer_index=moe_li, rope_scale=lk.rope_scale)
+                kp, vp = (kp.replace(**{pool: new_k}),
+                          vp.replace(**{pool: new_v}))
+                if stats is not None:
+                    stats = stats + layer_stats
+            return (x, kp, vp, stats), None
+
+        by_period = jax.tree_util.tree_map(
+            lambda a: a.reshape(reps, len(period), *a.shape[1:]), blocks)
+        (x, kp, vp, stats), _ = jax.lax.scan(
+            body, (x, kp, vp, stats),
+            (by_period, jnp.arange(reps, dtype=jnp.int32)))
+        return x, kp, vp, stats
+
     stats0 = (jnp.zeros((cfg.moe.stats_size,), jnp.int32)
               if return_moe_stats else None)
+    if cfg.has_window:
+        x, new_k, new_v, stats = walk_windowed(x, k_pages, v_pages, stats0)
+        return StepResult(unembed(params, head_rows(x), cfg), new_k, new_v,
+                          stats)
     if not cfg.is_looped:
         x, new_k, new_v, stats = walk(x, k_pages, v_pages, stats0)
         return StepResult(unembed(params, head_rows(x), cfg), new_k, new_v,

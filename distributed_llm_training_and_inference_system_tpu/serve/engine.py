@@ -48,7 +48,8 @@ from .decode import (DENOISE_COUNTS, PIECE_META, UNFIXED, DispatchResult,
                      can_carry, decode_scan, denoise_scan,
                      draft_verify_scan, extend_step_forward, mtp_sentinel_row,
                      mtp_window, recurrent_ops)
-from .kv_cache import PagedKVCache, refuse, refused, resolve_page_size
+from .kv_cache import (WINDOW_CHUNK_PAGES, PagedKVCache, refuse, refused,
+                       resolve_page_size)
 from .sampling import fold_in_key_data, sample_tokens, seed_key_data
 from .scheduler import (ContinuousBatchingScheduler, Request, RequestState,
                         SamplingParams)
@@ -302,7 +303,11 @@ class InferenceEngine:
                 quantized=serve_cfg.kv_quantization,
                 snapshot_entries=(snapshots if serve_cfg.prefix_caching
                                   else 0),
-                page_size_stated=page_size_stated)
+                page_size_stated=page_size_stated,
+                # (window layers: the ring holds the longest window any
+                # program writes in one call, a chunk or a riding piece)
+                window_rows=max(self._chunk_tokens,
+                                self.piece_rows(serve_cfg.kv_block_size)))
         # the start-up log line says which rule sized the pool
         STARTUP.note("kv_pool", **{k: self.kv.stats()[k] for k in (
             "num_pages", "page_size", "page_bytes", "page_size_stated")})
@@ -563,6 +568,13 @@ class InferenceEngine:
         # against the block table's whole width (slots x pages a slot)
         self.total_live_pages = 0
         self.total_table_pages = 0
+        # a model with window layers, counted once a decode dispatch as
+        # ``live_pages`` is (``_window_ids``): K/V rows the window layers'
+        # kernel calls see, the rows they would see as full layers, the
+        # full layers' rows, and slots whose ring wrapped in the dispatch
+        self.window_counts = dict.fromkeys(
+            ("window_rows", "window_rows_unwindowed", "full_rows",
+             "ring_wraps"), 0)
         # speculative-decode accounting (acceptance rate drives the
         # use-it-or-not decision per deployment)
         self.total_spec_dispatches = 0
@@ -681,6 +693,13 @@ class InferenceEngine:
         C = self.serve_cfg.chunked_prefill_tokens
         if self.cfg.is_latent and C <= 0:
             return self.LATENT_COLD_TOKENS
+        if self.cfg.has_window and C <= 0:
+            # a cold program attends its whole bucket under the XLA mask
+            # ([heads, rows, rows] of scores: the flash kernel has no
+            # window term), and a ring holds a window as long as its pages
+            # allow (``kv_cache.ring_pages``): prompts go in chunks of
+            # ``WINDOW_CHUNK_PAGES`` pages over the ring
+            return WINDOW_CHUNK_PAGES * self.serve_cfg.kv_block_size
         return C
 
     @property
@@ -1782,9 +1801,8 @@ class InferenceEngine:
                 # (a drafting engine's module row of the last prompt token
                 # lies at cache index ``run``: one row more)
                 bucket = self._bucket(run + self._mtp)
-                entries = np.zeros(bucket // PS, np.int32)
-                used = self.kv.pages_needed(run + self._mtp)
-                entries[:used] = self.kv.block_tables[slot, :used]
+                entries = self.kv.prompt_entries(slot, run + self._mtp,
+                                                 bucket // PS)
             table_row = self.kv.block_tables[slot].copy()
         if self.kv.snapshot_entries:
             self._arm_from_snapshot(req)
@@ -1963,6 +1981,32 @@ class InferenceEngine:
         self._arm_slot(req, 0, run, ctx)
 
     # -- decode --------------------------------------------------------------
+
+    def _window_ids(self, lag: int, steps: int) -> dict:
+        """What this dispatch's first step asks of the page kernels of a
+        model with window layers, a live slot at length ``position + 1``:
+        the window layers see min(length, window) rows each, as full layers
+        they would see the length, the full layers do; and the slots whose
+        ring wraps within the dispatch's ``steps`` (a write passes from the
+        ring's last entry to its first). Summed into ``window_counts`` and
+        laid on the span as ids."""
+        cfg, kv = self.cfg, self.kv
+        live = self.active
+        start = (self.positions + lag) * live
+        lengths = (start + 1) * live
+        n_win, n_full = kv.window_layers, cfg.kv_layers - kv.window_layers
+        around = kv.ring_entries * kv.page_size
+        ids = {
+            "window_rows": int(np.minimum(lengths, cfg.sliding_window).sum()
+                               ) * n_win,
+            "window_rows_unwindowed": int(lengths.sum()) * n_win,
+            "full_rows": int(lengths.sum()) * n_full,
+            "ring_wraps": int((((start + steps) // around
+                                > start // around) & live).sum()),
+        }
+        for name, n in ids.items():
+            self.window_counts[name] += n
+        return ids
 
     def _decode_impl_n(self, params, k_pages, v_pages, tokens, positions,
                        tables, stops, slot_keys, temp, top_k, top_p,
@@ -2203,8 +2247,10 @@ class InferenceEngine:
             (self.positions + lag * self.active) // self.kv.page_size + 1,
             1, self.kv.max_pages_per_slot).sum())
         self.total_live_pages += live_pages
-        self.total_table_pages += self.kv.block_tables.size
+        self.total_table_pages += self.kv.table_pages
         ids = {}
+        if self.cfg.has_window:
+            ids = self._window_ids(lag, n_units * self._decode_unit_len)
         if self.cfg.is_recurrent:
             # state updates this dispatch asks of the state-space (or K)
             # layers (live slots x steps; stats()["ssm" | "kda"]
@@ -2220,7 +2266,7 @@ class InferenceEngine:
         with self.spans.phase("llmctl.engine.decode.submit", units=n_units,
                               active=int(self.active.sum()),
                               live_pages=live_pages,
-                              table_pages=self.kv.block_tables.size, **ids):
+                              table_pages=self.kv.table_pages, **ids):
             laid = [(None, []) for _ in range(n_units)]
             if self._ride_rows:
                 laid = self._lay_pieces(n_units)
@@ -3247,8 +3293,7 @@ class InferenceEngine:
                 buf = getattr(self.kv, name)
                 if any(leaf.is_deleted()
                        for leaf in jax.tree_util.tree_leaves(buf)):
-                    setattr(self.kv, name,
-                            self.kv._new_pages(buf.shape, self.kv.dtype))
+                    setattr(self.kv, name, self.kv.fresh_pool(buf))
                     reallocated = True
             if self.kv.state is not None and any(
                     leaf.is_deleted() for leaf in self.kv.state.values()):
@@ -3493,6 +3538,18 @@ class InferenceEngine:
                 "decode_token_passes": (self.total_useful_slot_steps
                                         * self.cfg.num_passes),
             }} if self.cfg.is_looped else {}),
+            # window layers beside full ones: the window, a slot's ring, the
+            # two pools' bytes, and the rows the decode dispatches' kernels
+            # were asked for (``_window_ids``)
+            **({"window": {
+                "window": self.cfg.sliding_window,
+                "window_layers": self.kv.window_layers,
+                "ring_pages": self.kv.ring_entries,
+                "window_pool_bytes": self.kv.pool_bytes("window"),
+                "full_pool_bytes": self.kv.pool_bytes("full"),
+                **self.window_counts,
+                "refused": dict(self.turned_off),
+            }} if self.cfg.has_window else {}),
             **({"moe": {
                 "choices": self.moe_choices.tolist(),
                 # live choices on the experts HELD here, beside those over
@@ -3543,10 +3600,11 @@ class InferenceEngine:
         for key_, program in list(self._prefill_cache.items()):
             if isinstance(key_, int):       # a cold bucket
                 args = (vec(1, key_), vec(1), *common[1:],
-                        vec(key_ // self.kv.page_size), *sampling)
+                        shapes(self.kv.prompt_entries(
+                            0, 1, key_ // self.kv.page_size)), *sampling)
             elif chunks:                    # ("extend" | "chunk", bucket)
                 args = (vec(1, key_[1]), vec(1), vec(1), *common[1:],
-                        vec(1, self.kv.max_pages_per_slot),
+                        vec(1, self.kv.block_tables.shape[1]),
                         *(sampling if key_[0] == "extend" else ()))
                 if self._mtp:
                     args += shapes(self._mtp_args(

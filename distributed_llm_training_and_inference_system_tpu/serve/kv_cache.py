@@ -21,6 +21,7 @@ import math
 from collections import OrderedDict
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -145,7 +146,59 @@ REFUSED = {
                                 "serve_programs.decode_step_device_ms reads "
                                 "the step",
     },
+    # a stack with WINDOW layers (``ModelConfig.layer_types``): a slot's
+    # window layers keep a RING of pages it holds for life, its full layers
+    # a chain (``PagedKVCache``). Whatever would read, move or share a page
+    # of the ring as if it held the tokens its place in a chain says is
+    # refused by name
+    "windowed": {
+        # OFF and counted an admission
+        "prefix_caching": "a page hit in the full layers is no hit in a "
+                          "ring that was overwritten since: it needs a "
+                          "snapshot of the ring at a page boundary (the "
+                          "snapshot pool in a second layout); no page hash "
+                          "is registered or looked up; ROADMAP B3",
+        **dict.fromkeys(
+            ("preemption: swap", "page payload", "fleet serving",
+             "fleet prefix fetch"),
+            "the page payload is a chain of K and V pages, and a slot's "
+            "ring is not in it; a preempted request is recomputed; "
+            "ROADMAP B3"),
+        "speculative": "a draft-and-verify window may be rolled back, and "
+                       "the rows it overwrote in the ring are gone; "
+                       "ROADMAP B3",
+        "kv_quantization": "no quantised layout of the ring pool, and no "
+                           "window term in the quantised kernel's tests; "
+                           "ROADMAP B3",
+        "tensor_parallel": "pages sharded over heads take the gather "
+                           "route, which gathers the ring at the table's "
+                           "whole width; ROADMAP B3",
+        "measure_device_times": "its probes write scratch pages through one "
+                                "table; the benchmark's serve_programs."
+                                "decode_step_device_ms reads the step",
+    },
 }
+
+
+# pages of a chunk of a model with window layers where none is stated
+# (``InferenceEngine._chunk_tokens``): 256 rows over pages of 128, a ring of
+# 8 + 2
+WINDOW_CHUNK_PAGES = 2
+
+
+def ring_pages(window: int, page_size: int, rows: int = 0) -> int:
+    """Pages of a slot's RING: the least count for which no program
+    overwrites a row that a query of the same call may still see. A call
+    writes ``rows`` consecutive rows from ``start`` and its first query
+    sees back to ``start - window + 1``, so the pages of ``window - 1 +
+    rows`` consecutive rows must all be distinct entries: one row a slot
+    (a decode step) starts anywhere, ceil((window - 1) / PS) + 1 pages; a
+    longer window (a riding piece, a chunk of a prompt) starts on a page,
+    ceil((window - 1) / PS) + ceil(rows / PS). 1,024 keys over pages of 128
+    with pieces of 128 rows and chunks of 256: 8 + 2 = 10. ``rows`` 0:
+    a chunk of ``WINDOW_CHUNK_PAGES`` pages."""
+    rows = rows or WINDOW_CHUNK_PAGES * page_size
+    return -(-(window - 1) // page_size) + -(-rows // page_size)
 
 
 def keeps_snapshots(cfg: ModelConfig, snapshot_entries: int) -> bool:
@@ -170,6 +223,8 @@ def refused(cfg: ModelConfig, feature: str, snapshot_entries: int = 0
         "diffusion": cfg.is_diffusion and "generates by diffusion over blocks",
         "looped": cfg.is_looped
         and f"walks its stack {cfg.num_passes} times",
+        "windowed": cfg.has_window
+        and "keeps its window layers' K/V in a ring of pages",
     }
     for kind, is_a in kinds.items():
         if is_a and feature in REFUSED[kind]:
@@ -320,6 +375,9 @@ class PagedKVCache:
         quantized=False,        # False|"none" | True|"int8" | "int4"
         snapshot_entries: int = 0,  # snapshots of a ``K`` model's state
         page_size_stated: bool = True,  # False: ``page_size_by_rows`` gave it
+        window_rows: int = 0,   # a model with window layers: rows of the
+                                # longest window a program of the engine
+                                # writes in one call (0: ``ring_pages``'s)
     ):
         self.cfg = cfg
         self.num_slots = num_slots
@@ -347,9 +405,24 @@ class PagedKVCache:
             refuse(cfg, "kv_quantization", what=f"kv_quantization {kind}")
         # what a token costs the pool: its row in every layer that keeps one
         self.row_bytes = kv_row_bytes(cfg, jnp.dtype(dtype).itemsize, kind)
-        self.bytes_per_token = cfg.kv_layers * self.row_bytes
+        # A stack with WINDOW layers keeps TWO pools: the full layers' pages
+        # are the chain every model has, and what a token costs is its rows
+        # in those layers alone; the window layers' pool is sized exactly:
+        # ``ring_entries`` pages a slot and the scratch page. It comes out
+        # of the budget first
+        self.window_layers = cfg.window_layers if cfg.has_window else 0
+        self.ring_entries = ring_bytes = 0
+        if self.window_layers:
+            if page_sharding is not None:
+                refuse(cfg, "tensor_parallel")
+            self.ring_entries = ring_pages(cfg.sliding_window, page_size,
+                                           window_rows)
+            ring_bytes = (self.window_layers * self.row_bytes * page_size
+                          * (num_slots * self.ring_entries + 1))
+        self.bytes_per_token = ((cfg.kv_layers - self.window_layers)
+                                * self.row_bytes)
         if num_pages <= 0:
-            num_pages = max(int(hbm_budget_gb * 1e9
+            num_pages = max(int((hbm_budget_gb * 1e9 - ring_bytes)
                                 // (self.bytes_per_token * page_size)), 2)
         # never more than every slot fully resident (+1 scratch)
         num_pages = min(num_pages, num_slots * self.max_pages_per_slot + 1)
@@ -367,8 +440,8 @@ class PagedKVCache:
         from ..ops.paged_attention import heads_a_row
         pair = (1 if self.quantized or page_sharding is not None
                 else heads_a_row(cfg.num_kv_heads, cfg.head_dim))
-        shape = (cfg.kv_layers, num_pages, cfg.num_kv_heads // pair,
-                 page_size, cfg.head_dim * pair)
+        shape = (cfg.kv_layers - self.window_layers, num_pages,
+                 cfg.num_kv_heads // pair, page_size, cfg.head_dim * pair)
         self.page_sharding = page_sharding
         if cfg.is_latent:
             # the third kind of cache state: ONE pool of latent rows
@@ -380,6 +453,15 @@ class PagedKVCache:
         self.k_pages = self._new_pages(shape, dtype)
         self.v_pages = (None if cfg.is_latent
                         else self._new_pages(shape, dtype))
+        if self.window_layers:
+            from ..ops.paged_attention import SplitPages
+            ring_shape = (self.window_layers,
+                          num_slots * self.ring_entries + 1, *shape[2:])
+            is_window = tuple(t == "sliding" for t in cfg.layer_types)
+            self.k_pages, self.v_pages = (
+                SplitPages(pool, self._new_pages(ring_shape, dtype),
+                           self.ring_entries, is_window)
+                for pool in (self.k_pages, self.v_pages))
         # the second kind of cache state: a state-space layer keeps, for
         # each SLOT, the last K-1 pre-activation conv columns and its
         # [nh, P, N] state, whatever the sequence's length. A slot costs
@@ -418,8 +500,18 @@ class PagedKVCache:
         self._free: list[int] = list(range(1, num_pages))
         self._owned: dict[int, list[int]] = {}            # slot -> pages
         self._chain_len: dict[int, int] = {}   # slot -> table entries used
-        self.block_tables = np.zeros((num_slots, self.max_pages_per_slot),
-                                     np.int32)
+        # a slot's row: its chain and, with window layers, its ring's
+        # entries LAST (``SplitPages.tables_of`` tells them apart on the
+        # device; every other model's row is its chain)
+        self.block_tables = np.zeros(
+            (num_slots, self.max_pages_per_slot + self.ring_entries),
+            np.int32)
+        # the window pool's pages (page 0 its scratch): ``ring_entries`` a
+        # resident slot, taken at admission and held until release
+        self._ring_free: list[int] = list(
+            range(1, num_slots * self.ring_entries + 1))[::-1]
+        self._ring_owned: dict[int, list[int]] = {}
+        self.ring_wraps = 0     # see ``count_ring_wraps``
 
         # prefix cache: refcounted shared pages + LRU of evictable ones.
         # A page is in exactly one of: _free, referenced (_ref > 0), or
@@ -600,6 +692,15 @@ class PagedKVCache:
             return jax.device_put(buf, self.page_sharding)
         return buf
 
+    def fresh_pool(self, buf):
+        """A zeroed pool of ``buf``'s geometry (engine recovery: a failed
+        program's donated pools are gone)."""
+        from ..ops.paged_attention import SplitPages
+        if isinstance(buf, SplitPages):
+            return buf.of(self._new_pages(buf.full.shape, self.dtype),
+                          self._new_pages(buf.window.shape, self.dtype))
+        return self._new_pages(buf.shape, self.dtype)
+
     # -- accounting ----------------------------------------------------------
 
     @property
@@ -610,7 +711,33 @@ class PagedKVCache:
         return math.ceil(max(num_tokens, 1) / self.page_size)
 
     def can_allocate(self, num_tokens: int) -> bool:
-        return self.pages_needed(num_tokens) <= self.free_pages
+        """Pages for ``num_tokens`` in the chain AND, with window layers, a
+        ring for the slot."""
+        return (self.pages_needed(num_tokens) <= self.free_pages
+                and len(self._ring_free) >= self.ring_entries)
+
+    def _take_ring(self, slot: int) -> None:
+        """Give ``slot`` its ring (a slot that holds one keeps it: growth,
+        and a chain allocated anew, leave the ring where it is)."""
+        if not self.ring_entries or slot in self._ring_owned:
+            return
+        if len(self._ring_free) < self.ring_entries:
+            raise RuntimeError(
+                f"KV cache OOM: no ring of {self.ring_entries} window pages "
+                f"for slot {slot} ({len(self._ring_free)} free)")
+        ring = [self._ring_free.pop() for _ in range(self.ring_entries)]
+        self._ring_owned[slot] = ring
+        self.block_tables[slot, self.max_pages_per_slot:] = ring
+
+    @property
+    def table_pages(self) -> int:
+        """Entries of the slots' chains (a ring's entries are not pages a
+        sequence's length covers)."""
+        return self.num_slots * self.max_pages_per_slot
+
+    @property
+    def free_ring_pages(self) -> int:
+        return len(self._ring_free)
 
     def can_ever_allocate(self, num_tokens: int) -> bool:
         """Whether an EMPTY cache could hold this many tokens (page 0 is
@@ -624,9 +751,20 @@ class PagedKVCache:
                 return buf.values.size + buf.scale.size * 4
             if buf is None:             # a latent pool has no second one
                 return 0
-            return int(np.prod(buf.shape)) * jnp.dtype(self.dtype).itemsize
+            return sum(leaf.nbytes
+                       for leaf in jax.tree_util.tree_leaves(buf))
         return (one(self.k_pages) + one(self.v_pages) + self.state_bytes()
                 + self.snapshot_bytes())
+
+    def pool_bytes(self, kind: str) -> int:
+        """Bytes of the K and V pools of the layers of ``kind`` ("full":
+        every model's pages; "window": the ring pool, 0 without one)."""
+        pools = [p for p in (self.k_pages, self.v_pages) if p is not None]
+        if self.window_layers:
+            pools = [getattr(p, kind) for p in pools]
+        elif kind == "window":
+            return 0
+        return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(pools))
 
     # -- alloc / grow / free -------------------------------------------------
 
@@ -700,9 +838,10 @@ class PagedKVCache:
         # the engine per request and dropped via unpin_pages on release
         self._owned[slot] = pages
         table = list(prefix_pages) + pages
-        self.block_tables[slot, :] = 0
+        self.block_tables[slot, :self.max_pages_per_slot] = 0
         self.block_tables[slot, :len(table)] = table
         self._chain_len[slot] = len(table)
+        self._take_ring(slot)
         self._flush_demotions()
 
     def slot_capacity_tokens(self, slot: int) -> int:
@@ -740,8 +879,29 @@ class PagedKVCache:
         state whatever is left here."""
         for page in self._owned.pop(slot, []):
             self._drop_ref(page)
+        self._ring_free.extend(self._ring_owned.pop(slot, [])[::-1])
         self.block_tables[slot, :] = 0
         self._chain_len.pop(slot, None)
+
+    def prompt_entries(self, slot: int, tokens: int, bucket_pages: int
+                       ) -> np.ndarray:
+        """The table entries a COLD prefill of ``tokens`` tokens writes its
+        bucket's ``bucket_pages`` pages at (``write_prompt_to_pages``): the
+        slot's chain, pages past the prompt the scratch page. With window
+        layers [2, bucket_pages]: the chain's, and the ring's, where only
+        the prompt's LAST ``ring_entries`` pages are written (an earlier
+        page shares its entry with a later one, and no query will see it
+        again)."""
+        used = self.pages_needed(tokens)
+        entries = np.zeros(bucket_pages, np.int32)
+        entries[:used] = self.block_tables[slot, :used]
+        if not self.ring_entries:
+            return entries
+        ring = np.zeros(bucket_pages, np.int32)
+        kept = np.arange(max(used - self.ring_entries, 0), used)
+        ring[kept] = self.block_tables[
+            slot, self.max_pages_per_slot + kept % self.ring_entries]
+        return np.stack([entries, ring])
 
     # -- swap (preemption to host memory) ------------------------------------
 
@@ -1134,6 +1294,9 @@ class PagedKVCache:
             # what a token costs the pool, and of which kind its rows are:
             # "kv" K and V of every kv head, "latent" ONE compressed row
             "kind": "latent" if self.cfg.is_latent else "kv",
+            # (with window layers: of the FULL layers' pool, the one whose
+            # pages these counts are; the ring pool's numbers are
+            # ``engine.stats()["window"]``)
             "bytes_per_token": self.bytes_per_token,
             "hbm_bytes": self.hbm_bytes(),
             "state_bytes": self.state_bytes(),
