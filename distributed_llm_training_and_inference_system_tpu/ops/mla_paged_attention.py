@@ -17,12 +17,15 @@ kernel on a TPU (``mla_paged_attention`` for one query a slot,
 
 The kernel is PR 28's live-page walk (ops/paged_attention_pallas.py) over
 one pool: grid (slots, query tiles), the pool whole in HBM, a ring of VMEM
-page buffers whose copies run ``_PAGES_AHEAD`` pages ahead in grid order and
-across grid steps, the loop's trip count the slot's live pages. What differs:
-one copy a page (not K and V), no head folding (all N heads' rows are the
-query tile: [tile * N, W] against the page's [PS, W], no cross-head mask),
-matmul operands in the pool's dtype with float32 accumulation, and the
-value product over the page's first ``value_width`` lanes.
+page buffers whose copies run ``_PAGES_AHEAD`` loop steps ahead in grid order
+and across grid steps, the loop's trip count the slot's live pages. What
+differs: one copy a page (not K and V), no head folding (all N heads' rows
+are the query tile: [tile * N, W] against the page's [PS, W], no cross-head
+mask), matmul operands in the pool's dtype with float32 accumulation, the
+value product over the page's first ``value_width`` lanes, and (PR 64) a
+loop step that scores a GROUP of 2 (4) small pages under one softmax update
+where the query tile is small (``_pages_a_step``: read off the call's
+shapes; ``report_impl``'s line says ``group=``).
 """
 
 from __future__ import annotations
@@ -63,6 +66,8 @@ def mla_paged_attention(
             detail += f", backend {jax.default_backend()}"
     else:
         detail += ", requested by caller"
+    if impl == "pallas":
+        detail += f", group={_tiling(q, pool)[1]}"
     report_impl(op, kernel_impl() if impl == "pallas" else impl, detail)
     if impl == "pallas":
         return mla_paged_attention_pallas(
@@ -93,23 +98,78 @@ def _gather(q, pool, block_tables, start_positions, scale, value_width,
     return out.astype(q.dtype)
 
 
-# how many pages the copies run ahead (the ring holds one buffer more): PR
-# 28's value for K/V pages; the page-size sweep of experiments/
-# mla_kernel_alone.py is made at it
+# how many loop steps the copies run ahead (the ring holds one step's buffers
+# more): PR 28's value for K/V pages, and the sweeps of experiments/
+# mla_kernel_alone.py are made at it. At two pages a step (below) 2 ahead
+# read what 3 do (1,501 | 1,488 us a call at T = 1, 1,644 | 1,654 at T = 2)
+# and 1 ahead 1,749 | 1,896: the ring stays 3 steps deep at every group
 _PAGES_AHEAD = 3
 # query rows (tokens x heads) a grid step holds: the float32 accumulator
 # [rows, value_width] is 2 MB at 1,024 x 512, the score tile [rows, PS] 1 MB
 # at pages of 256
 _MAX_QUERY_ROWS = 1024
+# The most query rows (bfloat16; float32 half, as the tile's) at which a
+# loop step scores a GROUP of pages. Alone on a v5e (the doc-qa shape: 64
+# slots, 32 heads, ~12.9k live tokens a slot, pages of 256 x 640 bf16 = 328
+# KB; us a call at 1 | 2 pages a step; PERF.md 6, PR 64):
+#   rows   32 (T = 1)  1,968 | 1,488    (0.602 | 0.455 us a page, 66 | 88 %
+#   rows   64 (T = 2)  2,475 | 1,654     of the HBM peak at T = 1)
+#   rows  128 (T = 4)  2,885 | 2,206
+#   rows  256 (T = 8)  4,317 | 3,631
+#   rows  512 (T = 16) 7,512 | 6,501
+# a group saves 0.15-0.3 us a page at every tile measured; the tile of 1,024
+# rows (a riding piece, a suffix: ~3 us of arithmetic a page) was not
+# measured and keeps its score tile and its VMEM. Pages of 128 | 64 rows
+# (164 | 82 KB) at T = 1, 1 | 2 | 4 pages a step: 3,355 | 2,095 | 1,486 and
+# 6,158 | 3,576 | 2,309.
+_GROUP_MAX_ROWS = 512
+
+
+def _pages_a_step(page_bytes: int, rows: int, itemsize: int) -> int:
+    """Pages a loop step of the walk scores under one softmax update. A step
+    costs a serial chain (wait, load, q . page^T, row maximum, exp, row sum,
+    p . values, rescale) of ~0.45 us whatever the page holds, and a page
+    under ``PAGE_COPY_BYTES`` is copied in less: 2 of them pay the chain
+    once (4 where even two are under those bytes). A larger query tile's
+    step is its arithmetic and keeps one page."""
+    from ..serve.kv_cache import PAGE_COPY_BYTES  # serve imports ops
+    if page_bytes >= PAGE_COPY_BYTES \
+            or rows > _GROUP_MAX_ROWS * 2 // max(itemsize, 2):
+        return 1
+    return 2 if 2 * page_bytes >= PAGE_COPY_BYTES else 4
+
+
+def _tiling(q, pool) -> tuple[int, int]:
+    """(query tokens a grid step holds, pages a loop step scores) of a call.
+    A long window (suffix / chunked prefill) is tiled along the queries: each
+    tile an independent online-softmax pass over the slot's pages (4-byte
+    operands take half the rows: the tile's query and output blocks are
+    twice the bytes, and 1,024 rows of 32 heads x 640 float32 asked for more
+    than the kernel's 16 MB of VMEM inside a decode program: chip_smoke.py's
+    float32 arm, PERF.md 6, PR 41)."""
+    T, N = q.shape[1:3]
+    max_rows = _MAX_QUERY_ROWS * 2 // max(q.dtype.itemsize, 2)
+    tile = T if T * N <= max_rows else max(max_rows // N, 1)
+    page_bytes = pool.shape[-2] * pool.shape[-1] * pool.dtype.itemsize
+    return tile, _pages_a_step(page_bytes, tile * N, q.dtype.itemsize)
 
 
 def _kernel(tables_ref, starts_ref, layer_ref,          # scalar prefetch
             q_ref, pool_hbm, o_ref, acc_ref, m_ref, l_ref, buf, sems,
             ring_ref, *, page_size: int, scale: float, heads: int,
-            window: int, queries: int, value_width: int):
+            window: int, queries: int, value_width: int, group: int):
     """One grid step: one slot's query tile ([window * heads, W], token
     major) against that slot's LIVE latent pages. The ring's bookkeeping is
-    ops/paged_attention_pallas.py ``_extend_kernel``'s."""
+    ops/paged_attention_pallas.py ``_extend_kernel``'s, a GROUP of ``group``
+    consecutive pages a loop step: the lead starts a group's copies into
+    adjacent buffers (``p`` counts pages, ``i`` buffers, both by ``group``)
+    and the step scores them as one [group * PS, W] operand under one
+    softmax update. A tile's last group may be short: its missing members
+    copy the tile's LAST live page again, so that the buffer holds finite
+    rows of the pool (never what the ring held before), and the causal mask
+    takes every column of theirs (their positions lie past the tile's last
+    query), so they reach ``acc`` as 0 x finite. ``group`` 1 is the walk a
+    page a step, its text unchanged."""
     b, t = pl.program_id(0), pl.program_id(1)
     n_slots, n_tiles = pl.num_programs(0), pl.num_programs(1)
     max_pages = tables_ref.shape[1]
@@ -128,7 +188,7 @@ def _kernel(tables_ref, starts_ref, layer_ref,          # scalar prefetch
             pool_hbm.at[layer, tables_ref[slot, p]], buf.at[i], sems.at[i])
 
     def next_buffer(i):
-        return jnp.where(i + 1 == n_bufs, 0, i + 1)
+        return jnp.where(i + group == n_bufs, 0, i + group)
 
     def fetch_next(lead):
         slot, tile, p, i = lead
@@ -136,18 +196,22 @@ def _kernel(tables_ref, starts_ref, layer_ref,          # scalar prefetch
         @pl.when(slot < n_slots)
         def _start():
             page_copy(slot, p, i).start()
+            for g in range(1, group):
+                page_copy(slot, jnp.minimum(p + g, live_pages(slot, tile) - 1),
+                          i + g).start()
 
-        tile_done = p + 1 >= live_pages(jnp.minimum(slot, n_slots - 1), tile)
+        tile_done = p + group >= live_pages(jnp.minimum(slot, n_slots - 1),
+                                            tile)
         slot_done = tile_done & (tile + 1 >= n_tiles)
         return (jnp.where(slot_done, slot + 1, slot),
                 jnp.where(slot_done, 0, jnp.where(tile_done, tile + 1, tile)),
-                jnp.where(tile_done, 0, p + 1),
+                jnp.where(tile_done, 0, p + group),
                 next_buffer(i))
 
     @pl.when((b == 0) & (t == 0))
     def _prime():
         lead = (jnp.int32(0),) * 4
-        for _ in range(n_bufs - 1):
+        for _ in range(n_bufs // group - 1):
             lead = fetch_next(lead)
         for i, x in enumerate((*lead, jnp.int32(0))):
             ring_ref[i] = x
@@ -160,10 +224,13 @@ def _kernel(tables_ref, starts_ref, layer_ref,          # scalar prefetch
     max_len = visible(b, t)
 
     def score_page(p, i):
-        page = buf[i, 0]                                     # [PS, W]
+        if group == 1:
+            page = buf[i, 0]                                 # [PS, W]
+        else:                                        # [group * PS, W]
+            page = buf[pl.ds(i, group), 0].reshape(group * page_size, -1)
         s = jax.lax.dot_general(
             q_ref[...].astype(page.dtype), page, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [rows, PS]
+            preferred_element_type=jnp.float32) * scale  # [rows, group * PS]
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(p * page_size + col <= start + row // heads, s,
@@ -181,7 +248,11 @@ def _kernel(tables_ref, starts_ref, layer_ref,          # scalar prefetch
     def one_page(p, ring):
         *lead, i = ring
         lead = fetch_next(lead)
+        if group > 1:
+            p = p * group                     # the loop counts groups
         page_copy(b, p, i).wait()
+        for g in range(1, group):
+            page_copy(b, p, i + g).wait()
 
         @pl.when(p * page_size < max_len)       # false only at length 0
         def _score():
@@ -189,7 +260,10 @@ def _kernel(tables_ref, starts_ref, layer_ref,          # scalar prefetch
 
         return (*lead, next_buffer(i))
 
-    ring = jax.lax.fori_loop(0, live_pages(b, t), one_page,
+    steps = live_pages(b, t)
+    if group > 1:
+        steps = (steps + group - 1) // group
+    ring = jax.lax.fori_loop(0, steps, one_page,
                              tuple(ring_ref[i] for i in range(5)))
     for i, x in enumerate(ring):
         ring_ref[i] = x
@@ -207,14 +281,8 @@ def mla_paged_attention_pallas(q, pool, block_tables, start_positions, *,
     if layer is None:
         pool, layer = pool[None], 0
 
-    # a long window (suffix / chunked prefill) is tiled along the queries:
-    # each tile an independent online-softmax pass over the slot's pages
-    # (4-byte operands take half the rows: the tile's query and output
-    # blocks are twice the bytes, and 1,024 rows of 32 heads x 640 float32
-    # asked for more than the kernel's 16 MB of VMEM inside a decode
-    # program: chip_smoke.py's float32 arm, PERF.md 6, PR 41)
-    max_rows = _MAX_QUERY_ROWS * 2 // max(q.dtype.itemsize, 2)
-    tile = T_in if T_in * N <= max_rows else max(max_rows // N, 1)
+    tile, group = _tiling(q, pool)
+    n_bufs = (_PAGES_AHEAD + 1) * group
     T = -(-T_in // tile) * tile
     if T != T_in:
         q = jnp.pad(q, ((0, 0), (0, T - T_in), (0, 0), (0, 0)))
@@ -232,8 +300,8 @@ def mla_paged_attention_pallas(q, pool, block_tables, start_positions, *,
             pltpu.VMEM((rows, value_width), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((_PAGES_AHEAD + 1, *pool.shape[2:]), pool.dtype),
-            pltpu.SemaphoreType.DMA((_PAGES_AHEAD + 1,)),
+            pltpu.VMEM((n_bufs, *pool.shape[2:]), pool.dtype),
+            pltpu.SemaphoreType.DMA((n_bufs,)),
             pltpu.SMEM((5,), jnp.int32),
         ],
     )
@@ -242,7 +310,7 @@ def mla_paged_attention_pallas(q, pool, block_tables, start_positions, *,
         out = pl.pallas_call(
             functools.partial(_kernel, page_size=PS, scale=scale, heads=N,
                               window=tile, queries=T_in,
-                              value_width=value_width),
+                              value_width=value_width, group=group),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, T * N, value_width), q.dtype),
             # the ring of page copies runs across grid steps: in order

@@ -243,9 +243,12 @@ def refuse(cfg: ModelConfig, *features: str, what: str = "",
                              f"refused ({why[1]}){advice}")
 
 
-# The bytes at which a page's copy costs what its bytes cost. The page kernels
-# (ops/paged_attention_pallas.py, ops/mla_paged_attention.py) copy ONE
-# layer's page a loop step, and a copy has a latency whatever it moves:
+# The bytes at which a page costs what its bytes cost. The page kernels
+# (ops/paged_attention_pallas.py, ops/mla_paged_attention.py) score ONE
+# layer's page a loop step, and a step has a serial cost whatever the page
+# holds (wait, load, scores, softmax update, values: no ring depth beyond 3
+# hides it, PR 28; the latent kernel pays it once for 2 or 4 pages under
+# these bytes, PR 64):
 # alone on a v5e a page costs max(~0.47-0.5 us, bytes / ~745 GB/s)
 # (experiments/paged_kernel_alone.py --sweep, PR 58; the table is PERF.md 6):
 # K + V pages of 64 / 128 / 256 KB all read 0.47-0.52 us (140 / 270 / 510-530

@@ -94,3 +94,23 @@ def test_smoke_demands_the_tile_write_of_the_window_it_names(log, rows,
     else:
         with pytest.raises(chip_smoke.SmokeFailure, match=f"T={rows} "):
             chip_smoke.require_tile_write(text, rows, "the window")
+
+
+def test_the_kernels_phase_prints_a_record_of_any_check(monkeypatch, capsys):
+    """A child's ``SMOKE kernel`` record is a difference beside its limit,
+    or a check of another kind (bits equal, a wrong reference's distance):
+    the phase prints each and reaches its own checks behind them."""
+    import chip_smoke
+    child = "\n".join([
+        'SMOKE device {"platform": "cpu", "kind": "cpu", "count": 1}',
+        'SMOKE kernel {"name": "a", "tol": 0.02, "normalised": 0.001, '
+        '"max_abs_diff": 0.002, "ref_max": 2.0}',
+        'SMOKE kernel {"name": "bits", "equal": true}',
+        'SMOKE kernel {"name": "moved by", "max_abs_diff": 0.1, '
+        '"program_off_by": 1e-7}'])
+    monkeypatch.setattr(chip_smoke, "run_child", lambda *a, **kw: child)
+    assert chip_smoke.phase_kernels({})["platform"] == "cpu"
+    out = capsys.readouterr().out
+    assert "kernel a: max_abs_diff 2.000e-03" in out
+    assert "kernel bits: {'equal': True}" in out
+    assert "kernel moved by: {'max_abs_diff': 0.1" in out

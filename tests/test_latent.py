@@ -11,6 +11,7 @@ Every departure the chip's check is asked to refuse moves the reference's
 logits by more than 20 x TOL (``test_each_departure_moves_the_logits``).
 """
 
+import functools
 import json
 import math
 
@@ -33,7 +34,10 @@ from distributed_llm_training_and_inference_system_tpu.models import gpt, layers
 from distributed_llm_training_and_inference_system_tpu.ops import (
     mla_paged_attention as mla,
 )
-from distributed_llm_training_and_inference_system_tpu.serve import decode
+from distributed_llm_training_and_inference_system_tpu.serve import (
+    decode,
+    kv_cache,
+)
 from distributed_llm_training_and_inference_system_tpu.serve.engine import (
     InferenceEngine,
 )
@@ -215,23 +219,66 @@ def test_absorbed_attention_is_expanded_attention(cfg, params):
 
 # -- the kernel against its XLA twin ---------------------------------------------
 
-@pytest.mark.parametrize("T,starts", [(1, [0, 17, 63]), (5, [0, 9, 40]),
-                                      (300, [5])],
-                         ids=["decode", "window", "tiled-window"])
-def test_the_kernel_in_interpret_mode_is_its_twin(T, starts):
+# pages of PS = 8 rows: slots that see 3, 4, 1, 1 (the first token: length 0
+# before it), 3 (whole pages exactly at T = 1), 19, 38 and 10 pages: every
+# remainder of a group of 2 and of 4, and the ring (4 groups of buffers)
+# walked round many times across the slots
+_GROUPED = [17, 30, 3, 0, 23, 150, 301, 77]
+
+
+@pytest.mark.parametrize("T,starts,group", [
+    (1, [0, 17, 63], 4), (5, [0, 9, 40], 4), (300, [5], 1),
+    (1, _GROUPED, 2), (2, _GROUPED, 2), (1, _GROUPED, 4), (2, _GROUPED, 4),
+    (100, [17, 30], 1),
+], ids=["decode", "window", "tiled-window", "decode-by-2", "window-of-2-by-2",
+        "decode-by-4", "window-of-2-by-4", "rows-over-the-group"])
+def test_the_kernel_in_interpret_mode_is_its_twin(monkeypatch, T, starts,
+                                                  group):
+    """``group``: the pages a loop step scores, READ OFF THE CALL: these
+    pages are 4 KB, so 4 where the tile's rows allow a group, and 2 with the
+    bytes a page's copy is worth (``PAGE_COPY_BYTES``) brought down to
+    where two of them reach it."""
     L, NP, W, R, N = 2, 48, 128, 64, 4
     pool = jax.random.normal(jax.random.PRNGKey(0), (L, NP, 1, PS, W))
+    if group == 2:
+        monkeypatch.setattr(kv_cache, "PAGE_COPY_BYTES", 2 * PS * W * 4)
     rng = np.random.default_rng(0)
     tables = jnp.asarray(rng.permutation(np.arange(1, 1 + 40 * len(starts))
                                          % (NP - 1) + 1)
                          .reshape(len(starts), 40).astype(np.int32))
     q = jax.random.normal(jax.random.PRNGKey(T), (len(starts), T, N, W))
     args = (q, pool, tables, jnp.asarray(starts, jnp.int32))
+    assert mla._tiling(q, pool)[1] == group
     twin, kernel = (mla.mla_paged_attention(
         *args, scale=0.1, value_width=R, impl=impl, layer=1)
         for impl in ("gather", "pallas"))
     assert twin.shape == (len(starts), T, N, R)
     assert np.abs(np.asarray(twin) - np.asarray(kernel)).max() < 2e-6
+    if T == 100:       # over the row threshold: the walk a page a step
+        def text():
+            return jax.jit(functools.partial(
+                mla.mla_paged_attention_pallas, scale=0.1, value_width=R,
+                layer=1, interpret=True)).lower(*args).as_text()
+        as_called = text()
+        monkeypatch.setattr(mla, "_pages_a_step", lambda *_: 1)
+        assert text() == as_called
+        monkeypatch.setattr(mla, "_pages_a_step", lambda *_: 2)
+        assert text() != as_called
+
+
+@pytest.mark.parametrize("page_rows,dtype,rows,group", [
+    (256, "bfloat16", 32, 2), (256, "bfloat16", 64, 2),
+    (256, "bfloat16", 512, 2), (256, "bfloat16", 1024, 1),
+    (128, "bfloat16", 32, 4), (64, "bfloat16", 64, 4),
+    (512, "bfloat16", 32, 1), (256, "float32", 32, 1),
+    (128, "float32", 256, 2), (128, "float32", 512, 1),
+])
+def test_pages_a_step_at_the_published_row(page_rows, dtype, rows, group):
+    """640-wide rows: a decode step (32 heads), the self-drafting window of
+    two, a riding piece's tile of 1,024 rows, smaller and larger pages."""
+    q = jax.ShapeDtypeStruct((1, rows // 32, 32, 640), jnp.dtype(dtype))
+    pool = jax.ShapeDtypeStruct((7, 9, 1, page_rows, 640), jnp.dtype(dtype))
+    assert mla._tiling(q, pool) == (rows // 32, group)
 
 
 # -- YaRN, the scale, the maps, by hand ------------------------------------------

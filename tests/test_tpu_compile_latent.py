@@ -102,12 +102,16 @@ def test_a_latent_engine_that_rides_holds_the_parents_programs():
 # program at the published widths and the configuration's page size ---------
 
 
-@pytest.mark.parametrize("B,T", [(64, 1), (1, 512), (1, 1024)],
-                         ids=["decode", "suffix-512", "chunk-1024"])
+@pytest.mark.parametrize("B,T", [(64, 1), (1, 512), (1, 1024), (64, 2),
+                                 (64, 16)],
+                         ids=["decode", "suffix-512", "chunk-1024",
+                              "window-of-2", "window-of-16"])
 def test_latent_paged_attention_kernel_compiles(one_chip, as_tpu, B, T):
     """32 heads over ONE pool of 640-wide rows (576 + padding), pages of
     256: one query a slot, and the windows of suffix and chunked prefill
-    tiled 32 tokens a grid step."""
+    tiled 32 tokens a grid step; a decode step, the self-drafting window of
+    two and the largest tile that scores two pages a loop step (512 rows)
+    hold the ring of 8 pages."""
     from distributed_llm_training_and_inference_system_tpu.ops.mla_paged_attention import (
         mla_paged_attention)
     sds = _sds(one_chip)
